@@ -234,9 +234,7 @@ def run_bench(quick: bool = False, out_path: str | None = None) -> dict:
         "per_round_diffs_ms": [round(d, 2) for d in diffs],
         "note": ("CPU-host numbers: median of 5 paired (back-to-back, "
                  "end-of-window-sync) round differences; per-round spread "
-                 "shows the box noise floor. On the v5e chip the norm "
-                 "reduction is 7.8 ms of a 505 ms step (PERF_STEP.json "
-                 "r05), so grad_norm_every=8 reclaims ~1.4% of step time."),
+                 "shows the box noise floor. Not measured on a chip."),
     }
 
     out_path = out_path or os.path.join(REPO_ROOT, "PERF_MULTISLICE.json")

@@ -2,7 +2,7 @@
 
 Contenders: our Pallas flash kernel, jax's bundled pallas flash_attention,
 jax's splash attention, and plain XLA dot attention (materialized scores).
-Slope-timed (see prof_blocks.py protocol).
+Each point is the per-step slope between two scan lengths.
 """
 import functools
 import time

@@ -1,4 +1,4 @@
-"""Minimal repro for the dots+ remat TPU compile failure (BENCH_r02 tail)."""
+"""Minimal repro for the dots+ remat TPU compile failure."""
 import sys
 
 import jax

@@ -1,8 +1,7 @@
 """Can the 1B train step run with remat='none' (no recompute) on one v5e?
 
-profile_step.py shows fwd-only at 137 ms and fwd+bwd(dots) at 476 ms —
-if the full activation set fits in HBM the backward drops the recompute
-entirely. Probes batch 2 and 4.
+If the full activation set fits in HBM the backward drops the recompute
+entirely. Probes batch 2, 4 and 8.
 """
 import time
 

@@ -59,6 +59,20 @@ def chips_per_host(pod_type: str) -> int:
     return min(chips, GENERATIONS[gen]["chips_per_host"])
 
 
+def require_tpu(program: str):
+    """JAX's default device if it is a TPU; otherwise the process exits
+    non-zero with the platform it found. For programs that measure on the
+    chip or not at all (bench.py, bench_serve.py, devbench probes): they
+    never fall back to another backend."""
+    import jax
+
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        raise SystemExit(f"{program}: needs a TPU, JAX found platform="
+                         f"{device.platform!r} ({device.device_kind})")
+    return device
+
+
 def slice_head_resource(pod_type: str) -> str:
     """Marker resource placed only on worker 0 of a slice, used to reserve
     whole slices atomically (reference: TPU-{pod_type}-head)."""

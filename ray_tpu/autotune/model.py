@@ -41,47 +41,26 @@ from dataclasses import dataclass, field
 
 from ray_tpu.autotune.space import Candidate
 
-# Known usable-HBM budgets by TPU generation (GB). Preferred source is the
-# live backend's memory_stats()["bytes_limit"]; this table is the offline
-# fallback (e.g. pricing for a chip from a CPU host). Ordered most-specific
-# first: 'v5p' (95 GB) must match before the bare 'v5' (v5e/lite, 16 GB) —
-# a 16 GB fallback on a v5p would wrongly prune every large-batch config.
-_HBM_BY_GEN_GB = [
-    ("v5p", 95), ("v5e", 16), ("v5", 16),   # bare v5 / "v5 lite" = v5e
-    ("v6e", 32), ("v6", 32),
-    ("v2", 8), ("v3", 16), ("v4", 32), ("v7", 192),
-]
-
-
 def device_hbm_budget_bytes(device=None) -> int | None:
-    """Usable HBM of the accelerator the bench will run on, or None when
-    unknown (CPU hosts without an override — callers then skip pruning).
-    RTPU_HBM_BUDGET_GB always wins (float GB)."""
+    """Usable HBM of the accelerator the bench will run on: the backend's
+    own ``memory_stats()["bytes_limit"]``. None on a host without a TPU
+    (callers then skip pruning); a TPU that does not report its limit is an
+    error, not a guess by device name. RTPU_HBM_BUDGET_GB always wins
+    (float GB), which is also how to price for a chip from a CPU host."""
     env = os.environ.get("RTPU_HBM_BUDGET_GB")
     if env:
-        try:
-            return int(float(env) * (1 << 30))
-        except ValueError:
-            pass
-    try:
-        import jax
+        return int(float(env) * (1 << 30))
+    import jax
 
-        d = device if device is not None else jax.devices()[0]
-        if d.platform != "tpu":
-            return None
-        try:
-            limit = d.memory_stats().get("bytes_limit")
-            if limit:
-                return int(limit)
-        except Exception:
-            pass
-        kind = d.device_kind.lower()
-        for gen, gb in _HBM_BY_GEN_GB:
-            if gen in kind:
-                return gb << 30
-    except Exception:
-        pass
-    return None
+    d = device if device is not None else jax.devices()[0]
+    if d.platform != "tpu":
+        return None
+    limit = (d.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        raise RuntimeError(
+            f"{d.device_kind!r} reports no memory_stats()['bytes_limit']; "
+            "set RTPU_HBM_BUDGET_GB to price candidates for it")
+    return int(limit)
 
 
 @dataclass

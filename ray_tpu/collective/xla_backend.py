@@ -26,17 +26,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 top-level API
-    from jax import shard_map
-except ImportError:  # older jax: experimental module, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, /, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
 
 from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 

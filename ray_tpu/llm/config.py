@@ -56,8 +56,8 @@ class LLMConfig:
     # Burst decoding: run up to this many decode+sample steps in ONE jitted
     # dispatch (lax.scan feeds each sampled token into the next step on
     # device). Amortizes the host→device dispatch + token-fetch roundtrip —
-    # the dominant per-token cost whenever the accelerator is remote or the
-    # model is small — across D tokens; 1 restores step-per-dispatch. The
+    # a large per-token cost when the model is small — across D tokens; 1
+    # restores step-per-dispatch. The
     # burst length adapts down (powers of two) near request token budgets,
     # so only {8,4,2} shapes ever compile. Sampling inside a burst supports
     # temperature/top-p; a top-k request in the batch falls back to

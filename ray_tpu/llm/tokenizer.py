@@ -25,8 +25,17 @@ class ByteTokenizer:
         return ([self.bos_id] + ids) if add_bos else ids
 
     def decode(self, ids: list[int]) -> str:
-        return bytes(i for i in ids if i < 256).decode("utf-8",
-                                                       errors="replace")
+        """Bytes decode as UTF-8 and the three specials vanish. Any other id
+        (from a model whose vocabulary is larger than this tokenizer's) is
+        written as ``<|id|>``: text never hides a token, so two outputs
+        compare equal only if their tokens do."""
+        out = bytearray()
+        for i in ids:
+            if i < 256:
+                out.append(i)
+            elif i > self.pad_id:
+                out += f"<|{i}|>".encode()
+        return out.decode("utf-8", errors="replace")
 
     def apply_chat_template(self, messages: list[dict]) -> str:
         parts = []

@@ -28,6 +28,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import blockwise_attention, flash_attention
+from ray_tpu.ops.kernels import KernelMesh
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ring_attention import ring_attention_local
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -138,26 +139,29 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> dict:
     return params
 
 
-def _attention(cfg: LlamaConfig, q, k, v, attn_impl: str, sp_axis: str | None):
+def _attention(cfg: LlamaConfig, q, k, v, attn_impl: str, sp_axis: str | None,
+               kmesh: KernelMesh | None = None):
     """q: [B, H, S, D], k/v: [B, Hkv, S, D] (already rope'd)."""
     if sp_axis is not None:
         # Context parallel: sequence is sharded over sp_axis (we are inside
         # shard_map); the ring handles cross-shard causality.
         return ring_attention_local(q, k, v, axis_name=sp_axis, causal=True)
     if attn_impl == "flash":
-        return flash_attention(q, k, v, True, None, True)
+        return flash_attention(q, k, v, True, None, True, kmesh)
     return blockwise_attention(q, k, v, causal=True)
 
 
 def _layer(cfg: LlamaConfig, x, layer_params, inv_freq, positions,
-           attn_impl: str, sp_axis: str | None):
-    """One transformer block. x: [B, S, H]."""
+           attn_impl: str, sp_axis: str | None,
+           kmesh: KernelMesh | None = None):
+    """One transformer block. x: [B, S, H]. ``kmesh``: the mesh the caller's
+    arrays are sharded over, for the Pallas kernels (ops/kernels.py)."""
     b, s, h = x.shape
     lp = layer_params
     dt = x.dtype
 
     # -- attention ----------------------------------------------------------
-    xn = checkpoint_name(rms_norm(x, lp["attn_norm"], cfg.norm_eps),
+    xn = checkpoint_name(rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh),
                          "norm_out")
     q = (xn @ lp["wq"]).reshape(b, s, cfg.num_heads, cfg.head_dim)
     k = (xn @ lp["wk"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
@@ -168,12 +172,12 @@ def _layer(cfg: LlamaConfig, x, layer_params, inv_freq, positions,
     q = checkpoint_name(apply_rope(q, positions, inv_freq), "rope_out")
     k = checkpoint_name(apply_rope(k, positions, inv_freq), "rope_out")
     v = checkpoint_name(v, "v_out")
-    o = _attention(cfg, q, k, v, attn_impl, sp_axis)
+    o = _attention(cfg, q, k, v, attn_impl, sp_axis, kmesh)
     o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.num_heads * cfg.head_dim)
     x = x + checkpoint_name((o @ lp["wo"]).astype(dt), "attn_proj")
 
     # -- mlp (SwiGLU) -------------------------------------------------------
-    xn = checkpoint_name(rms_norm(x, lp["mlp_norm"], cfg.norm_eps),
+    xn = checkpoint_name(rms_norm(x, lp["mlp_norm"], cfg.norm_eps, kmesh),
                          "norm_out")
     gate = checkpoint_name(
         jax.nn.silu((xn @ lp["w_gate"]).astype(jnp.float32)).astype(dt),
@@ -271,7 +275,8 @@ def _remat_wrap(layer_fn, remat):
 def forward_hidden(cfg: LlamaConfig, params: dict, tokens: jax.Array,
                    positions: jax.Array | None = None,
                    attn_impl: str = "flash", sp_axis: str | None = None,
-                   remat: bool | str | tuple = True) -> jax.Array:
+                   remat: bool | str | tuple = True,
+                   kmesh: KernelMesh | None = None) -> jax.Array:
     """tokens [B, S] → final-norm hidden states [B, S, H].
 
     ``remat`` is a single policy (see :func:`_remat_wrap`) or a per-layer
@@ -285,7 +290,7 @@ def forward_hidden(cfg: LlamaConfig, params: dict, tokens: jax.Array,
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
 
     base_fn = partial(_layer, cfg, inv_freq=inv_freq, positions=positions,
-                      attn_impl=attn_impl, sp_axis=sp_axis)
+                      attn_impl=attn_impl, sp_axis=sp_axis, kmesh=kmesh)
     remat = normalize_remat(remat, cfg.num_layers)
 
     if isinstance(remat, tuple):
@@ -300,7 +305,7 @@ def forward_hidden(cfg: LlamaConfig, params: dict, tokens: jax.Array,
             run_params = jax.tree.map(lambda a: a[start:end],
                                       params["layers"])
             x, _ = lax.scan(scan_body, x, run_params)
-        return rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
 
     layer_fn = _remat_wrap(base_fn, remat)
 
@@ -308,7 +313,7 @@ def forward_hidden(cfg: LlamaConfig, params: dict, tokens: jax.Array,
         return layer_fn(x, lp), None
 
     x, _ = lax.scan(scan_body, x, params["layers"])
-    return rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
 
 
 def unembed_weights(cfg: LlamaConfig, params: dict) -> jax.Array:
@@ -319,11 +324,12 @@ def unembed_weights(cfg: LlamaConfig, params: dict) -> jax.Array:
 
 def forward(cfg: LlamaConfig, params: dict, tokens: jax.Array,
             positions: jax.Array | None = None, attn_impl: str = "flash",
-            sp_axis: str | None = None, remat: bool | str = True) -> jax.Array:
+            sp_axis: str | None = None, remat: bool | str = True,
+            kmesh: KernelMesh | None = None) -> jax.Array:
     """tokens [B, S] → logits [B, S, V] (fp32). bf16 MXU matmul with fp32
     accumulation — a fp32×fp32 dot would run off the MXU fast path."""
     x = forward_hidden(cfg, params, tokens, positions, attn_impl, sp_axis,
-                       remat)
+                       remat, kmesh)
     head = unembed_weights(cfg, params)
     return jnp.einsum("bsh,hv->bsv", x, head,
                       preferred_element_type=jnp.float32)

@@ -192,28 +192,30 @@ def moe_block(cfg: MixtralConfig, x: jax.Array, lp: dict):
     return y.reshape(b, s, h), aux
 
 
-def _layer(cfg: MixtralConfig, x, lp, inv_freq, positions, attn_impl):
+def _layer(cfg: MixtralConfig, x, lp, inv_freq, positions, attn_impl,
+           kmesh=None):
     b, s, h = x.shape
     dt = x.dtype
-    xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
     q = (xn @ lp["wq"]).reshape(b, s, cfg.num_heads, cfg.head_dim).transpose(0, 2, 1, 3)
     k = (xn @ lp["wk"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim).transpose(0, 2, 1, 3)
     v = (xn @ lp["wv"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim).transpose(0, 2, 1, 3)
     q = apply_rope(q, positions, inv_freq)
     k = apply_rope(k, positions, inv_freq)
-    o = _llama._attention(cfg, q, k, v, attn_impl, None)
+    o = _llama._attention(cfg, q, k, v, attn_impl, None, kmesh)
     o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.num_heads * cfg.head_dim)
     x = x + (o @ lp["wo"]).astype(dt)
 
-    xn = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    xn = rms_norm(x, lp["mlp_norm"], cfg.norm_eps, kmesh)
     y, aux = moe_block(cfg, xn, lp)
     return x + y.astype(dt), aux
 
 
 def forward(cfg: MixtralConfig, params: dict, tokens: jax.Array,
             positions: jax.Array | None = None, attn_impl: str = "flash",
-            remat: bool = True):
-    """tokens [B, S] → (logits [B, S, V] fp32, mean aux loss)."""
+            remat: bool = True, kmesh=None):
+    """tokens [B, S] → (logits [B, S, V] fp32, mean aux loss). ``kmesh``:
+    the caller's mesh for the Pallas kernels (ops/kernels.py)."""
     b, s = tokens.shape
     if positions is None:
         positions = jnp.arange(s)
@@ -224,7 +226,7 @@ def forward(cfg: MixtralConfig, params: dict, tokens: jax.Array,
 
     layer_fn = _remat_wrap(
         partial(_layer, cfg, inv_freq=inv_freq, positions=positions,
-                attn_impl=attn_impl),
+                attn_impl=attn_impl, kmesh=kmesh),
         remat)
 
     def scan_body(x, lp):
@@ -232,7 +234,7 @@ def forward(cfg: MixtralConfig, params: dict, tokens: jax.Array,
         return x, aux
 
     x, aux = lax.scan(scan_body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
     # bf16 MXU matmul with f32 accumulation — casting both operands to f32
     # would fall off the MXU fast path (see llama.forward).
     logits = jnp.einsum("bsh,hv->bsv", x, params["lm_head"],

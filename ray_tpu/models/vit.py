@@ -136,24 +136,27 @@ def patchify(cfg: ViTConfig, images: jax.Array) -> jax.Array:
     return x.reshape(b, (hh // p) * (ww // p), p * p * c)
 
 
-def _layer(cfg: ViTConfig, x, lp, attn_impl: str):
+def _layer(cfg: ViTConfig, x, lp, attn_impl: str, kmesh=None):
     b, s, h = x.shape
     nh, hd = cfg.num_heads, cfg.head_dim
-    xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
     q = (xn @ lp["wq"]).reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
     k = (xn @ lp["wk"]).reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
     v = (xn @ lp["wv"]).reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
     use_pallas = attn_impl == "flash"
-    attn = flash_attention(q, k, v, False, None, use_pallas)  # bidirectional
+    attn = flash_attention(q, k, v, False, None, use_pallas,
+                           kmesh)  # bidirectional
     attn = attn.transpose(0, 2, 1, 3).reshape(b, s, nh * hd)
     x = x + attn @ lp["wo"]
-    xn = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    xn = rms_norm(x, lp["mlp_norm"], cfg.norm_eps, kmesh)
     return x + (jax.nn.gelu(xn @ lp["w_up"]) @ lp["w_down"])
 
 
 def forward(cfg: ViTConfig, params: dict, images: jax.Array,
-            attn_impl: str = "flash", remat: bool | str = False) -> jax.Array:
-    """[B, H, W, C] images (float in [0, 1]) -> [B, num_classes] logits."""
+            attn_impl: str = "flash", remat: bool | str = False,
+            kmesh=None) -> jax.Array:
+    """[B, H, W, C] images (float in [0, 1]) -> [B, num_classes] logits.
+    ``kmesh``: the caller's mesh for the Pallas kernels (ops/kernels.py)."""
     dt = cfg.jnp_dtype
     x = patchify(cfg, images.astype(dt)) @ params["patch_embed"]
     cls = jnp.broadcast_to(params["cls_token"], (x.shape[0], 1,
@@ -164,20 +167,22 @@ def forward(cfg: ViTConfig, params: dict, images: jax.Array,
     # outputs + flash residuals; True/'full' recomputes everything).
     from ray_tpu.models.llama import _remat_wrap
 
-    layer_fn = _remat_wrap(partial(_layer, cfg, attn_impl=attn_impl), remat)
+    layer_fn = _remat_wrap(
+        partial(_layer, cfg, attn_impl=attn_impl, kmesh=kmesh), remat)
 
     def scan_body(x, lp):
         return layer_fn(x, lp), None
 
     x, _ = jax.lax.scan(scan_body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
     return (x[:, 0, :] @ params["head"]).astype(jnp.float32)  # cls token
 
 
 def loss_fn(cfg: ViTConfig, params: dict, images: jax.Array,
             labels: jax.Array, attn_impl: str = "flash",
-            remat: bool | str = False) -> jax.Array:
-    logits = forward(cfg, params, images, attn_impl=attn_impl, remat=remat)
+            remat: bool | str = False, kmesh=None) -> jax.Array:
+    logits = forward(cfg, params, images, attn_impl=attn_impl, remat=remat,
+                     kmesh=kmesh)
     logp = jax.nn.log_softmax(logits, axis=-1)
     return -jnp.take_along_axis(logp, labels[:, None], axis=-1).mean()
 

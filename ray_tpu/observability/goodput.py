@@ -216,10 +216,11 @@ class RankLedger:
         number: classified + open tail vs. the elapsed monotonic clock."""
         with self._lock:
             total = sum(self.phase_s.values())
-            open_s = 0.0 if self._finished \
-                else max(0.0, time.monotonic() - self._mark)
-            elapsed = (self._mark if self._finished
-                       else time.monotonic()) - self._t0_mono
+            # One clock read: two would leave the time between them
+            # unattributed.
+            now = self._mark if self._finished else time.monotonic()
+            open_s = max(0.0, now - self._mark)
+            elapsed = now - self._t0_mono
             return {
                 "run": self.run,
                 "rank": self.rank,

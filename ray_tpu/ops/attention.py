@@ -27,13 +27,15 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.ops.kernels import (
+    KernelMesh,
+    kernel_backend,
+    target_device_kind,
+)
+
 NEG_INF = -1e30
 LOG2E = 1.4426950408889634  # log2(e): kernels run base-2 softmax (exp2 is
 LN2 = 0.6931471805599453    # the VPU-native transcendental; exp = mul+exp2)
-
-# When True, Pallas kernels run in interpreter mode (and the Pallas path is
-# taken off-TPU too) — lets CPU tests exercise the exact kernel code.
-INTERPRET = False
 
 # Fused dq+dkv backward (one kernel, 5 matmuls per block pair instead of 7
 # across the split kernels). RTPU_FLASH_FUSED_BWD=0 falls back to the split
@@ -65,55 +67,40 @@ def flash_blocks(block_q: int | None = None,
     return (block_q or _env_int("RTPU_FLASH_BLOCK_Q", 512),
             block_k or _env_int("RTPU_FLASH_BLOCK_K", 512))
 
-# Scoped-VMEM ceiling for the flash kernels, by TPU generation: v5e/v5p/v6
-# expose 128 MB of VMEM per core, where the compiler's default 16 MB scoped
-# limit is too tight for packed blocks but a flat 96 MB would OVERSUBSCRIBE
-# the 16 MB VMEM of v2-v4 (the compiler rejects or spills). Unknown chips
-# (and CPU interpret runs) keep the compiler default. Override with
+# Scoped-VMEM ceiling for the flash kernels, by TPU generation. The v5e has
+# 128 MB of VMEM per core, and the compiler's default 16 MB scoped limit is
+# too tight for packed blocks. Only generations the kernels were compiled
+# for are listed; any other device keeps the compiler default. Override with
 # RTPU_FLASH_VMEM_LIMIT_MB (0 = force the compiler default).
-_VMEM_LIMIT_MB_BY_GEN = {"v5": 96, "v6": 96, "v7": 96}
-_vmem_limit_cache: list = []  # [int | None] once resolved
-
-
-def _compiler_params(pltpu, **kwargs):
-    """pltpu.CompilerParams across jax versions (older releases ship it
-    as TPUCompilerParams; same fields)."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+_VMEM_LIMIT_MB_BY_GEN = {"v5": 96}
 
 
 def _flash_vmem_limit_bytes() -> int | None:
-    """vmem_limit_bytes for pallas CompilerParams, derived from the
-    detected TPU generation; None means 'leave the compiler default'."""
-    if _vmem_limit_cache:
-        return _vmem_limit_cache[0]
-    limit: int | None = None
+    """vmem_limit_bytes for pltpu.CompilerParams, from the generation of the
+    device the kernel compiles for; None leaves the compiler default."""
     env = _os.environ.get("RTPU_FLASH_VMEM_LIMIT_MB")
     if env is not None:
-        try:
-            mb = int(env)
-            limit = mb * 1024 * 1024 if mb > 0 else None
-        except ValueError:
-            limit = None
-    else:
-        try:
-            kind = jax.devices()[0].device_kind.lower()  # e.g. "tpu v5 lite"
-            gen = None
-            for tok in kind.replace("tpu", " ").split():
-                if tok.startswith("v") and len(tok) >= 2 and \
-                        tok[1].isdigit():
-                    gen = tok[:2]
-                    break
-            if gen is not None:
-                mb = _VMEM_LIMIT_MB_BY_GEN.get(gen)
-                if mb is not None:
-                    limit = mb * 1024 * 1024
-        except Exception:
-            limit = None  # backend unavailable: compiler default
-    _vmem_limit_cache.append(limit)
-    return limit
+        mb = int(env)
+        return mb * 1024 * 1024 if mb > 0 else None
+    kind = target_device_kind().lower()  # e.g. "tpu v5 lite"
+    for tok in kind.replace("tpu", " ").split():
+        if tok.startswith("v") and tok[1:2].isdigit():
+            mb = _VMEM_LIMIT_MB_BY_GEN.get(tok[:2])
+            return mb * 1024 * 1024 if mb is not None else None
+    return None
+
+
+def _flash_compiler_params(pltpu):
+    """Grid semantics of every flash kernel here, plus the scoped-VMEM
+    ceiling where the generation has one."""
+    limit = _flash_vmem_limit_bytes()
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        **({"vmem_limit_bytes": limit} if limit is not None else {}))
+
+
+def _interpret() -> bool:
+    return kernel_backend() == "interpret"
 
 
 def _repeat_kv(k: jax.Array, num_heads: int) -> jax.Array:
@@ -365,16 +352,9 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float,
             # the official kernel's 128-lane broadcast copy of every row.
             jax.ShapeDtypeStruct((g, pack, sq), jnp.float32),
         ],
-        compiler_params=_compiler_params(
-            pltpu,
-            dimension_semantics=("parallel", "arbitrary"),
-            # Generation-derived scoped-vmem ceiling (96 MB on 128 MB-VMEM
-            # chips, compiler default elsewhere) — leaves headroom for
-            # pipelining without oversubscribing small-VMEM generations.
-            **({"vmem_limit_bytes": _flash_vmem_limit_bytes()}
-               if _flash_vmem_limit_bytes() is not None else {}),
-        ),
-        interpret=INTERPRET,
+        compiler_params=_flash_compiler_params(pltpu),
+        interpret=_interpret(),
+        name="flash_fwd",
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d), lse.reshape(b, h, sq)
 
@@ -619,16 +599,9 @@ def _flash_bwd_fused_pallas(q, k, v, out, lse, g, causal: bool,
             pltpu.VMEM((skv, d), jnp.float32),
             pltpu.VMEM((skv, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
-            pltpu,
-            dimension_semantics=("parallel", "arbitrary"),
-            # Generation-derived scoped-vmem ceiling (96 MB on 128 MB-VMEM
-            # chips, compiler default elsewhere) — leaves headroom for
-            # pipelining without oversubscribing small-VMEM generations.
-            **({"vmem_limit_bytes": _flash_vmem_limit_bytes()}
-               if _flash_vmem_limit_bytes() is not None else {}),
-        ),
-        interpret=INTERPRET,
+        compiler_params=_flash_compiler_params(pltpu),
+        interpret=_interpret(),
+        name="flash_bwd",
     )(qf, kf, vf, dof, lsef, deltaf)
     dq = dq.reshape(b, h, sq, d)
     if kv_div > 1:  # fold the remaining head groups per kv head, in f32
@@ -677,11 +650,10 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal: bool, sm_scale: float,
         ],
         out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        compiler_params=_compiler_params(
-            pltpu,
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=INTERPRET,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_interpret(),
+        name="flash_bwd_dq",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     dk, dv = pl.pallas_call(
@@ -705,11 +677,10 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal: bool, sm_scale: float,
             jax.ShapeDtypeStruct((b * h, skv, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, skv, d), q.dtype),
         ],
-        compiler_params=_compiler_params(
-            pltpu,
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=INTERPRET,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, skv, d),
@@ -728,8 +699,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal: bool, sm_scale: float,
 # generalize them: the static path's compile-time diagonal skip (upper
 # bound on the kv loop) is worth ~2x on long causal self-attention and
 # cannot survive runtime positions. Optimization levers landed in one pair
-# (ones-column row-sum, scale folding — see PERF_STEP.json) must be
-# mirrored in the other.
+# (ones-column row-sum, scale folding) must be mirrored in the other.
 
 
 def _flash_chunk_fwd_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref,
@@ -902,17 +872,9 @@ def _flash_chunk_fwd_pallas(q, k, v, qpos, kpos, causal, sm_scale,
             jax.ShapeDtypeStruct((b * h, sq, d), jnp.float32),
             jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
         ],
-        compiler_params=_compiler_params(
-            pltpu,
-            dimension_semantics=("parallel", "arbitrary"),
-            # Ring shards can be long (skv-sized K/V + f32 scratch):
-            # generation-derived scoped-vmem ceiling (see
-            # _flash_vmem_limit_bytes), compiler default on small-VMEM
-            # or unknown chips.
-            **({"vmem_limit_bytes": _flash_vmem_limit_bytes()}
-               if _flash_vmem_limit_bytes() is not None else {}),
-        ),
-        interpret=INTERPRET,
+        compiler_params=_flash_compiler_params(pltpu),
+        interpret=_interpret(),
+        name="flash_chunk_fwd",
     )(qposf, kposf, qf, kf, vf)
     return out.reshape(b, h, sq, d), lse.reshape(b, h, sq)
 
@@ -962,17 +924,9 @@ def _flash_chunk_bwd_pallas(q, k, v, qpos, kpos, out, lse, g_out, g_lse,
             pltpu.VMEM((skv, d), jnp.float32),
             pltpu.VMEM((skv, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
-            pltpu,
-            dimension_semantics=("parallel", "arbitrary"),
-            # Ring shards can be long (skv-sized K/V + f32 scratch):
-            # generation-derived scoped-vmem ceiling (see
-            # _flash_vmem_limit_bytes), compiler default on small-VMEM
-            # or unknown chips.
-            **({"vmem_limit_bytes": _flash_vmem_limit_bytes()}
-               if _flash_vmem_limit_bytes() is not None else {}),
-        ),
-        interpret=INTERPRET,
+        compiler_params=_flash_compiler_params(pltpu),
+        interpret=_interpret(),
+        name="flash_chunk_bwd",
     )(qposf, kposf, qf, kf, vf, dof, lsef, deltaf, glsef)
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, skv, d),
             dv.reshape(b, h, skv, d))
@@ -1020,22 +974,33 @@ def _chunk_bwd(causal, sm_scale, res, cts):
 flash_attention_chunk.defvjp(_chunk_fwd, _chunk_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = True,
-                    sm_scale: float | None = None, use_pallas: bool = True):
+                    sm_scale: float | None = None, use_pallas: bool = True,
+                    kmesh: KernelMesh | None = None):
     """Flash attention: Pallas TPU kernels for forward AND backward
     (dq/dk/dv with p recomputed inside the kernel from the saved lse).
 
-    Falls back to ``blockwise_attention`` off-TPU (or use_pallas=False).
+    Runs ``blockwise_attention`` where ops/kernels.py picks the reference
+    implementation (off the TPU) or with ``use_pallas=False``. Under a mesh
+    of several devices pass its ``kmesh``: the kernels then run per shard,
+    batch over the data axes and heads over the head axis.
     """
-    return _flash_fwd(q, k, v, causal, sm_scale, use_pallas)[0]
+    return _flash_fwd(q, k, v, causal, sm_scale, use_pallas, kmesh)[0]
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, use_pallas):
+def _flash_fwd(q, k, v, causal, sm_scale, use_pallas, kmesh):
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    on_tpu = jax.default_backend() == "tpu"
-    if use_pallas and (on_tpu or INTERPRET):
-        out, lse = _flash_fwd_pallas(q, k, v, causal, scale)
+    if use_pallas and kernel_backend() != "reference":
+        fwd = functools.partial(_flash_fwd_pallas, causal=causal,
+                                sm_scale=scale)
+        if kmesh is not None:
+            # GQA: the kv heads split over the head axis like the query
+            # heads, so each shard keeps whole query-head groups.
+            s4, s3 = kmesh.heads_spec(4), kmesh.heads_spec(3)
+            fwd = kmesh.shard(fwd, in_specs=(s4, s4, s4),
+                              out_specs=(s4, s3))
+        out, lse = fwd(q, k, v)
         out = out.astype(q.dtype)
         # Under jax.checkpoint, a policy that saves 'flash_resid' keeps these
         # residuals across the remat boundary so the backward pass does NOT
@@ -1047,28 +1012,35 @@ def _flash_fwd(q, k, v, causal, sm_scale, use_pallas):
     return out, (q, k, v, None, None)
 
 
-def _flash_bwd(causal, sm_scale, use_pallas, res, g):
+def _flash_bwd_kernels(q, k, v, out, lse, g, *, causal, scale):
+    """dq, dk, dv with dk/dv folded to the kv heads."""
+    if FUSED_BWD:
+        # dk/dv come back already folded to kv heads (pack-group fold
+        # inside the kernel, remainder inside the wrapper).
+        return _flash_bwd_fused_pallas(q, k, v, out, lse, g, causal, scale)
+    dq, dk, dv = _flash_bwd_pallas(q, k, v, out, lse, g, causal, scale)
+    h, hkv = q.shape[1], k.shape[1]
+    if hkv != h:  # GQA: fold the repeated query-head groups back
+        b, _, skv, d = dk.shape
+        rep = h // hkv
+        dk = dk.astype(jnp.float32).reshape(b, hkv, rep, skv, d).sum(2)
+        dv = dv.astype(jnp.float32).reshape(b, hkv, rep, skv, d).sum(2)
+    return dq, dk, dv
+
+
+def _flash_bwd(causal, sm_scale, use_pallas, kmesh, res, g):
     q, k, v, out, lse = res
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if lse is not None:
-        h, hkv = q.shape[1], k.shape[1]
-        if FUSED_BWD:
-            # dk/dv come back already folded to kv heads (pack-group fold
-            # inside the kernel, remainder inside the wrapper).
-            dq, dk, dv = _flash_bwd_fused_pallas(q, k, v, out, lse, g,
-                                                 causal, scale)
-        else:
-            dq, dk, dv = _flash_bwd_pallas(q, k, v, out, lse, g, causal,
-                                           scale)
-            if hkv != h:  # GQA: fold the repeated query-head groups back
-                b, _, skv, d = dk.shape
-                rep = h // hkv
-                dk = dk.astype(jnp.float32).reshape(
-                    b, hkv, rep, skv, d).sum(2)
-                dv = dv.astype(jnp.float32).reshape(
-                    b, hkv, rep, skv, d).sum(2)
+        bwd = functools.partial(_flash_bwd_kernels, causal=causal,
+                                scale=scale)
+        if kmesh is not None:
+            s4, s3 = kmesh.heads_spec(4), kmesh.heads_spec(3)
+            bwd = kmesh.shard(bwd, in_specs=(s4, s4, s4, s4, s3, s4),
+                              out_specs=(s4, s4, s4))
+        dq, dk, dv = bwd(q, k, v, out, lse, g)
         return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
-    # Off-TPU: recompute through the differentiable blockwise path.
+    # Reference path: differentiate through the blockwise implementation.
     _, vjp = jax.vjp(
         lambda q_, k_, v_: blockwise_attention(q_, k_, v_, causal=causal,
                                                sm_scale=sm_scale),

@@ -3,9 +3,9 @@
 The TPU framework owns its normalization kernels (the reference delegates to
 torch). RMSNorm (no mean subtraction) is the transformer default (Llama-family).
 The Pallas kernel fuses the reduction, rsqrt, and scale multiply in VMEM; the
-jnp path is used off-TPU and for autodiff (XLA fuses it into neighbors anyway
-— the kernel exists for the cases XLA's fusion boundary splits, e.g. ahead of
-a sharded matmul).
+jnp path is used off the TPU (ops/kernels.py decides) and for autodiff (XLA
+fuses it into neighbors anyway — the kernel exists for the cases XLA's fusion
+boundary splits, e.g. ahead of a sharded matmul).
 """
 
 from __future__ import annotations
@@ -14,6 +14,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.ops.kernels import KernelMesh, kernel_backend
 
 
 def rms_norm_reference(x, weight, eps: float = 1e-6):
@@ -38,40 +41,55 @@ def rms_norm_pallas(x, weight, eps: float = 1e-6, block_rows: int = 256):
     d = orig_shape[-1]
     rows = x.size // d
     x2 = x.reshape(rows, d)
+    # Fewer rows than a block: one block spanning the array. Otherwise the
+    # grid rounds up and the last block runs partly out of bounds, which
+    # Pallas pads on read and drops on write; rows are independent, so the
+    # padding never reaches a kept row.
     block_rows = min(block_rows, rows)
-    if rows % block_rows != 0:
-        return rms_norm_reference(x, weight, eps)
     out = pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),
-        grid=(rows // block_rows,),
+        grid=(pl.cdiv(rows, block_rows),),
         in_specs=[
             pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
             pl.BlockSpec((d,), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
+        interpret=kernel_backend() == "interpret",
+        name="rms_norm",
     )(x2, weight)
     return out.reshape(orig_shape)
 
 
-def rms_norm(x, weight, eps: float = 1e-6):
-    """Dispatch: Pallas on TPU forward, reference elsewhere (and for grad —
-    custom_vjp recomputes via the reference path)."""
-    if jax.default_backend() == "tpu":
-        return _rms_norm_cv(x, weight, eps)
-    return rms_norm_reference(x, weight, eps)
+def rms_norm(x, weight, eps: float = 1e-6, kmesh: KernelMesh | None = None):
+    """x: [batch, ..., d]. The Pallas kernel forward where ops/kernels.py
+    picks it, the reference elsewhere and for the gradient (custom_vjp
+    recomputes through the reference). Under a mesh of several devices pass
+    its ``kmesh``: the kernel then runs on each device's rows, the batch dim
+    split over the data axes."""
+    if kernel_backend() == "reference":
+        return rms_norm_reference(x, weight, eps)
+    return _rms_norm_cv(x, weight, eps, kmesh)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _rms_norm_cv(x, weight, eps):
-    return rms_norm_pallas(x, weight, eps)
+def _rms_fwd_kernel(x, weight, eps, kmesh):
+    fwd = functools.partial(rms_norm_pallas, eps=eps)
+    if kmesh is not None:
+        rows = kmesh.rows_spec(x.ndim)
+        fwd = kmesh.shard(fwd, in_specs=(rows, P()), out_specs=rows)
+    return fwd(x, weight)
 
 
-def _rms_fwd(x, weight, eps):
-    return rms_norm_pallas(x, weight, eps), (x, weight)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _rms_norm_cv(x, weight, eps, kmesh):
+    return _rms_fwd_kernel(x, weight, eps, kmesh)
 
 
-def _rms_bwd(eps, res, g):
+def _rms_fwd(x, weight, eps, kmesh):
+    return _rms_fwd_kernel(x, weight, eps, kmesh), (x, weight)
+
+
+def _rms_bwd(eps, kmesh, res, g):
     x, weight = res
     _, vjp = jax.vjp(lambda x_, w_: rms_norm_reference(x_, w_, eps), x, weight)
     return vjp(g)

@@ -30,6 +30,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops.attention import NEG_INF, _repeat_kv
+from ray_tpu.ops.kernels import kernel_backend
 
 
 def _ring_step_combine(q, k, v, o, m, l, scale, causal, q_offset, kv_offset,
@@ -59,20 +60,18 @@ def ring_attention_local(q, k, v, axis_name: str, causal: bool = True,
     ``impl``: "flash" runs each ring step through the Pallas chunk kernel
     (ops/attention.py flash_attention_chunk — data-driven causal positions,
     differentiable lse) and combines chunks by (out, lse) log-sum-exp;
-    "einsum" is the materialized-score XLA path; "auto" picks flash on TPU.
+    "einsum" is the materialized-score XLA path; "auto" picks flash wherever
+    ops/kernels.py runs Pallas kernels (on the TPU).
     """
     b, h, sq, d = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    if hasattr(lax, "axis_size"):
-        n = lax.axis_size(axis_name)
-    else:  # jax < 0.6 spelling: psum of a literal constant-folds to the size
-        n = int(lax.psum(1, axis_name))
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     chunk = sq
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     if impl == "auto":
-        impl = "flash" if jax.default_backend() == "tpu" else "einsum"
+        impl = "einsum" if kernel_backend() == "reference" else "flash"
 
     if impl == "flash":
         from ray_tpu.ops.attention import flash_attention_chunk
@@ -145,12 +144,9 @@ def shard_map_ring(mesh: Mesh, axis: str, causal: bool, sm_scale, spec: P,
     body = functools.partial(ring_attention_local, axis_name=axis,
                              causal=causal, sm_scale=sm_scale, impl=impl)
 
-    # compat shim: jax >= 0.6 jax.shard_map / older experimental check_rep
-    from ray_tpu.collective.xla_backend import shard_map
-
     @jax.jit
     def fn(q, k, v):
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False,
         )(q, k, v)

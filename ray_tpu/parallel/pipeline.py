@@ -28,10 +28,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
-# shard_map via the collective backend's jax-version compat shim (jax >= 0.6
-# exports jax.shard_map; older releases spell it experimental + check_rep).
-from ray_tpu.collective.xla_backend import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.llama import (

@@ -16,6 +16,8 @@ from dataclasses import dataclass, field
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_tpu.ops.kernels import KernelMesh
+
 # Default rule table for transformer training (MaxText-style conventions):
 # logical axis name -> mesh axis (or tuple of mesh axes, or None = replicate).
 DEFAULT_RULES: dict[str, object] = {
@@ -70,20 +72,22 @@ class ShardingRules:
         return ShardingRules({**self.rules, **updates})
 
 
-def normalize_spec(spec: P | None) -> P:
-    """Canonical PartitionSpec form: 1-tuples collapse to their bare axis
-    and empty tuples to None, so specs compare by MEANING across jax
-    versions (jax >= 0.5 normalizes at construction; 0.4.x keeps
-    ``P(("fsdp",),)`` and ``P("fsdp")`` distinct-but-equivalent objects,
-    which breaks naive equality)."""
-    if spec is None:
-        return P()
-    out = []
-    for e in spec:
-        if isinstance(e, tuple):
-            e = e if len(e) > 1 else (e[0] if e else None)
-        out.append(e)
-    return P(*out)
+def kernel_mesh(mesh: Mesh, rules: ShardingRules | None = None,
+                batch: tuple[str, ...] | None = None) -> KernelMesh | None:
+    """What the Pallas kernel wrappers need to run per shard under ``mesh``
+    (ops/kernels.py): the axes the rule table shards the batch over
+    (``batch`` overrides them — the multi-slice step keeps its DCN axes for
+    an outer vmap) and the axis of the attention heads. None on one device,
+    where the kernels are called directly."""
+    if mesh.size == 1:
+        return None
+    rules = rules or ShardingRules()
+    if batch is None:
+        batch = batch_axes(rules)
+    heads = rules.rules.get("act_heads")
+    return KernelMesh(
+        mesh, tuple(a for a in batch if a in mesh.axis_names),
+        heads if heads in mesh.axis_names else None)
 
 
 def tree_shardings(mesh: Mesh, logical_tree, rules: ShardingRules | None = None):

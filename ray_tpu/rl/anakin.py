@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.rl.ppo import compute_gae, init_policy, mlp_apply
 from ray_tpu.rl.vec_env import make_jax_env
@@ -163,9 +164,8 @@ class AnakinPPO:
                              env.observation_size, env.num_actions,
                              cfg.hidden)
         opt_state = self.optimizer.init(params)
-        devices = jax.local_devices()[: self.num_devices]
-        self.params = jax.device_put_replicated(params, devices)
-        self.opt_state = jax.device_put_replicated(opt_state, devices)
+        self.params = self._replicate(params)
+        self.opt_state = self._replicate(opt_state)
 
         static = (cfg.clip, cfg.vf_coef, cfg.ent_coef, cfg.num_minibatches,
                   cfg.num_epochs)
@@ -246,6 +246,15 @@ class AnakinPPO:
         return jax.tree.map(lambda x: np.asarray(x[0]), self.params)
 
     def set_params(self, params) -> None:
+        self.params = self._replicate(jax.tree.map(jnp.asarray, params))
+
+    def _replicate(self, tree):
+        """One copy per device on a leading device axis, the layout pmap
+        takes without a transfer."""
         devices = jax.local_devices()[: self.num_devices]
-        self.params = jax.device_put_replicated(
-            jax.tree.map(jnp.asarray, params), devices)
+        per_device = NamedSharding(Mesh(np.array(devices), (_AXIS,)),
+                                   P(_AXIS))
+        return jax.tree.map(
+            lambda x: jax.device_put(
+                jnp.broadcast_to(x, (len(devices),) + x.shape), per_device),
+            tree)

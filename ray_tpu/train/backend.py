@@ -39,7 +39,7 @@ def _init_jax_distributed(coordinator_addr: str, num_processes: int,
     """Runs ON each worker. Idempotent per process."""
     import jax
 
-    if getattr(jax.distributed, "is_initialized", lambda: False)():
+    if jax.distributed.is_initialized():
         return
     jax.distributed.initialize(
         coordinator_address=coordinator_addr,

@@ -27,9 +27,11 @@ from ray_tpu.models.llama import (
 from ray_tpu.parallel.sharding import (
     ShardingRules,
     batch_axes,
+    kernel_mesh,
     tree_shardings,
     zero1_shardings,
 )
+from ray_tpu.utils.compile_cache import ensure_compile_cache
 
 
 @dataclass
@@ -47,7 +49,7 @@ jax.tree_util.register_dataclass(
 def make_train_step(
     mesh: Mesh,
     *,
-    loss: Callable,          # loss(params, tokens, targets) -> scalar
+    loss: Callable,          # loss(params, tokens, targets, kmesh) -> scalar
     init_fn: Callable,       # init_fn(rng_key) -> params pytree
     logical_axes: Any,       # pytree of logical-axis tuples (see sharding.py)
     rules: ShardingRules | None = None,
@@ -61,7 +63,9 @@ def make_train_step(
     dcn_quant_bucket: int | None = None,
 ) -> tuple[Callable, Callable, Callable]:
     """Model-agnostic SPMD step factory: any pure loss + init + axis table
-    becomes one jitted, donated, mesh-sharded train step.
+    becomes one jitted, donated, mesh-sharded train step. ``loss`` receives
+    the step's ``KernelMesh`` (None on one device) as its fourth argument and
+    hands it to the ops that run Pallas kernels (ops/kernels.py).
 
     Multi-slice / ZeRO-1 options:
 
@@ -100,6 +104,7 @@ def make_train_step(
       optimizer shardings from the rule table and batch over (dp, fsdp).
     - data_sharder(host_array) -> global sharded array.
     """
+    ensure_compile_cache()
     rules = rules or ShardingRules()
     optimizer = optimizer or optax.adamw(3e-4, weight_decay=0.1,
                                          mu_dtype=jnp.bfloat16)
@@ -155,6 +160,11 @@ def make_train_step(
     # fight the leaf shapes.
     explicit_hier = bool(dcn_data) and bool(update_axes or dcn_quant)
     flat_update = explicit_hier and bool(update_axes)
+    # The hierarchical path vmaps the loss over the slice dim, which carries
+    # the DCN axes; inside it the batch splits over the ICI axes only.
+    kmesh = kernel_mesh(mesh, rules,
+                        batch=ici_data if explicit_hier else data_axes)
+    loss = partial(loss, kmesh=kmesh)
 
     bucket = int(dcn_quant_bucket or
                  get_config().collective_dcn_quant_bucket)
@@ -304,7 +314,8 @@ def make_train_step(
             return wsc(xs, NamedSharding(mesh, spec))
 
         # per-slice: [grad_accum, mb, ...] through the shared scan body
-        lv, g_slice = jax.vmap(_scan_microbatches, in_axes=(None, 0, 0))(
+        lv, g_slice = jax.vmap(_scan_microbatches, in_axes=(None, 0, 0),
+                               spmd_axis_name=dcn_lead)(
             params, split(tokens), split(targets))
 
         slice_rows = NamedSharding(mesh, P(dcn_lead))
@@ -512,8 +523,9 @@ def make_llama_train_step(
     (tuple / "pol:N,pol:N" string — models/llama.normalize_remat)."""
     return make_train_step(
         mesh,
-        loss=lambda p, tokens, targets: loss_fn(
-            cfg, p, tokens, targets, attn_impl=attn_impl, remat=remat),
+        loss=lambda p, tokens, targets, kmesh: loss_fn(
+            cfg, p, tokens, targets, attn_impl=attn_impl, remat=remat,
+            kmesh=kmesh),
         init_fn=partial(init_params, cfg),
         logical_axes=param_logical_axes(cfg),
         rules=rules, optimizer=optimizer, seed=seed, **step_options,
@@ -536,8 +548,9 @@ def make_mixtral_train_step(
 
     return make_train_step(
         mesh,
-        loss=lambda p, tokens, targets: mixtral.loss_fn(
-            cfg, p, tokens, targets, attn_impl=attn_impl, remat=remat),
+        loss=lambda p, tokens, targets, kmesh: mixtral.loss_fn(
+            cfg, p, tokens, targets, attn_impl=attn_impl, remat=remat,
+            kmesh=kmesh),
         init_fn=partial(mixtral.init_params, cfg),
         logical_axes=mixtral.param_logical_axes(cfg),
         rules=rules, optimizer=optimizer, seed=seed, **step_options,
@@ -561,8 +574,9 @@ def make_vit_train_step(
 
     return make_train_step(
         mesh,
-        loss=lambda p, images, labels: vit.loss_fn(
-            cfg, p, images, labels, attn_impl=attn_impl, remat=remat),
+        loss=lambda p, images, labels, kmesh: vit.loss_fn(
+            cfg, p, images, labels, attn_impl=attn_impl, remat=remat,
+            kmesh=kmesh),
         init_fn=partial(vit.init_params, cfg),
         logical_axes=vit.param_logical_axes(cfg),
         rules=rules, optimizer=optimizer, seed=seed, **step_options,

@@ -257,9 +257,10 @@ class Config:
 
     # --- train ---
     # Compute the grad-norm metric every N steps (1 = every step, the
-    # old behavior). The global-norm reduction costs ~1.6% of a Llama-1B
-    # step (PERF_STEP.json r05: 7.8 ms of 505); skipped steps report
-    # grad_norm = -1. Default for make_train_step(grad_norm_every=None).
+    # old behavior). The global-norm reduction is a full pass over the
+    # gradients (its share of a step is not measured since the benchmark
+    # was redefined); skipped steps report grad_norm = -1. Default for
+    # make_train_step(grad_norm_every=None).
     train_grad_norm_every: int = 1
     # Set latency-hiding-scheduler / async-collective LIBTPU flags on train
     # workers before backend init, so DCN collectives overlap the next
@@ -482,9 +483,6 @@ class Config:
     #   RTPU_CONTAINER_RUNNER ("podman"): container runtime binary for
     #     runtime_env containers; tests point it at a stub
     #     (runtime_env/container.py).
-    #   RTPU_JAX_PLATFORMS (unset): forces jax.config platforms in worker
-    #     processes BEFORE backend init (worker_main.py) — the dryrun
-    #     uses it to pin forked workers to cpu.
     #   RTPU_HEAD / RTPU_NODE_DAEMON (internal): head / daemon host:port
     #     a forked worker connects back to.
     #   RTPU_NODE_ID (internal): hex node id of the owning daemon,

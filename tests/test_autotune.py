@@ -355,7 +355,8 @@ def test_search_analysis_only_mode():
     assert all("predicted_hbm_gb" in r for r in res.trace)
 
 
-def test_search_winner_falls_back_to_cache_on_total_failure(tmp_path):
+def test_search_has_no_winner_when_every_measurement_fails(tmp_path):
+    """A cached number orders the candidates; it is never the result."""
     cfg = _bench_cfg()
     cache = AutotuneCache(path=str(tmp_path / "cache.json"))
     geo = geometry_sig(cfg, 2048, 1)
@@ -369,11 +370,17 @@ def test_search_winner_falls_back_to_cache_on_total_failure(tmp_path):
         cfg, 2048, [Candidate(batch=4, remat="attn")],
         hbm_budget_bytes=None, measure_fn=broken, max_measure=2,
         cache=cache, device_kind="v5e")
-    assert res.winner == "b4/attn/flash/lowmem"
-    assert res.tokens_per_sec == 16573.5
+    assert res.winner is None and res.tokens_per_sec == 0.0
     assert any("error" in r for r in res.trace)
     # failed attempts must not be reported as successful measurements
     assert res.measured == 0 and res.failed == 1
+    # ... and analysis-only mode ranks without reporting a throughput
+    res = autotune_train_configs(
+        cfg, 2048, [Candidate(batch=4, remat="attn")],
+        hbm_budget_bytes=None, measure_fn=None, cache=cache,
+        device_kind="v5e")
+    assert res.winner == "b4/attn/flash/lowmem"
+    assert res.tokens_per_sec == 0.0
 
 
 # ---------------------------------------------------------------------------

@@ -591,11 +591,13 @@ class TestSpeculativeDecoding:
         import ray_tpu.llm.engine as engine_mod
         orig_prefill = engine_mod.prefill_chunk
 
-        def failing_prefill(cfg, params, cache, toks, start, end, slot):
+        def failing_prefill(cfg, params, cache, toks, start, end, slot,
+                            **kw):
             if cfg is eng.draft_cfg and \
                     eng._slots.get(int(slot)) is victim:
                 raise RuntimeError("injected draft prefill failure")
-            return orig_prefill(cfg, params, cache, toks, start, end, slot)
+            return orig_prefill(cfg, params, cache, toks, start, end, slot,
+                                **kw)
 
         try:
             engine_mod.prefill_chunk = failing_prefill

@@ -635,10 +635,7 @@ class TestHotPathMetrics:
     def test_collective_op_metrics(self, cpu_mesh_devices):
         import numpy as np
 
-        try:
-            import ray_tpu.collective as col
-        except ImportError as e:  # pre-existing env gap (jax.shard_map)
-            pytest.skip(f"collective backend unimportable here: {e}")
+        import ray_tpu.collective as col
 
         col.init_collective_group(backend="xla", group_name="obs_coll",
                                   devices=cpu_mesh_devices, world_size=8)

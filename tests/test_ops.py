@@ -8,12 +8,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.attention import (
     attention_reference,
     blockwise_attention,
     flash_attention,
 )
+from ray_tpu.ops.kernels import force_kernel_backend
 from ray_tpu.ops.norms import rms_norm_reference
 from ray_tpu.ops.ring_attention import ring_attention_sharded
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -66,23 +68,9 @@ def test_flash_attention_cpu_fallback_and_grad():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
 
 
-def _force_interpret_mode():
-    """pltpu.force_tpu_interpret_mode appeared after jax 0.4.37 — skip
-    with the reason instead of erroring (same compat policy as the
-    shard_map shim in parallel/): the kernel code paths are still covered
-    by the attn_mod.INTERPRET tests below on old releases."""
-    import jax
-    from jax.experimental.pallas import tpu as pltpu
-
-    if not hasattr(pltpu, "force_tpu_interpret_mode"):
-        pytest.skip("pltpu.force_tpu_interpret_mode unavailable on jax "
-                    f"{jax.__version__} (added in later releases)")
-    return pltpu.force_tpu_interpret_mode()
-
-
 def test_flash_pallas_interpret_matches_reference():
     q, k, v = _qkv(b=1, h=2, s=256, d=64)
-    with _force_interpret_mode():
+    with pltpu.force_tpu_interpret_mode():
         from ray_tpu.ops.attention import _flash_fwd_pallas
 
         out, lse = _flash_fwd_pallas(q, k, v, causal=True, sm_scale=1.0 / 8.0,
@@ -118,14 +106,13 @@ def test_flash_pallas_backward_matches_reference(h, hkv, causal, fused):
     def loss(f):
         return lambda q, k, v: (f(q, k, v).astype(jnp.float32) * w).sum()
 
-    attn_mod.INTERPRET = True
     old_fused = attn_mod.FUSED_BWD
     attn_mod.FUSED_BWD = fused
     try:
-        g = jax.grad(loss(lambda q, k, v: flash_attention(
-            q, k, v, causal, None, True)), argnums=(0, 1, 2))(q, k, v)
+        with force_kernel_backend("interpret"):
+            g = jax.grad(loss(lambda q, k, v: flash_attention(
+                q, k, v, causal, None, True)), argnums=(0, 1, 2))(q, k, v)
     finally:
-        attn_mod.INTERPRET = False
         attn_mod.FUSED_BWD = old_fused
     g_ref = jax.grad(loss(lambda q, k, v: attention_reference(
         q, k, v, causal=causal)), argnums=(0, 1, 2))(q, k, v)
@@ -180,8 +167,25 @@ def test_rms_norm_pallas_interpret():
 
     x = jax.random.normal(jax.random.PRNGKey(1), (256, 128))
     w = jax.random.normal(jax.random.PRNGKey(2), (128,))
-    with _force_interpret_mode():
+    with pltpu.force_tpu_interpret_mode():
         out = rms_norm_pallas(x, w)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(rms_norm_reference(x, w)), atol=1e-5)
+
+
+@pytest.mark.parametrize("rows", [8, 424, 1000])
+def test_rms_norm_kernel_any_row_count(rows):
+    """Row counts that no block divides go through the kernel too (a
+    partial last block), never around it: 424 is a prefill chunk clamped to
+    the cache tail, 1000 leaves a ragged block after three full ones."""
+    from ray_tpu.ops.norms import rms_norm
+
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, rows, 128))
+    w = jax.random.normal(jax.random.PRNGKey(2), (128,))
+    with force_kernel_backend("interpret"):
+        jaxpr = jax.make_jaxpr(rms_norm)(x, w)
+        out = rms_norm(x, w)
+    assert "pallas_call" in str(jaxpr)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(rms_norm_reference(x, w)), atol=1e-5)
 
@@ -346,14 +350,13 @@ def test_flash_fused_backward_multiblock(causal):
     def loss(f):
         return lambda q, k, v: (f(q, k, v).astype(jnp.float32) ** 2).sum()
 
-    attn_mod.INTERPRET = True
     old = attn_mod.FUSED_BWD
     attn_mod.FUSED_BWD = True
     try:
-        g = jax.grad(loss(lambda q, k, v: flash_attention(
-            q, k, v, causal, None, True)), argnums=(0, 1, 2))(q, k, v)
+        with force_kernel_backend("interpret"):
+            g = jax.grad(loss(lambda q, k, v: flash_attention(
+                q, k, v, causal, None, True)), argnums=(0, 1, 2))(q, k, v)
     finally:
-        attn_mod.INTERPRET = False
         attn_mod.FUSED_BWD = old
     g_ref = jax.grad(loss(lambda q, k, v: attention_reference(
         q, k, v, causal=causal)), argnums=(0, 1, 2))(q, k, v)
@@ -363,20 +366,47 @@ def test_flash_fused_backward_multiblock(causal):
         assert np.abs(a - b).max() / denom < 2e-2, name
 
 
+def test_kernels_under_a_mesh_match_reference(cpu_mesh_devices):
+    """With a KernelMesh the kernels run per shard (batch rows over dp, GQA
+    head groups over tp) and must give what the whole arrays give."""
+    from ray_tpu.ops.norms import rms_norm
+    from ray_tpu.parallel.sharding import kernel_mesh
+
+    mesh = build_mesh(MeshSpec(dp=2, tp=2), cpu_mesh_devices[:4])
+    kmesh = kernel_mesh(mesh)
+    assert kmesh.batch == ("dp", "fsdp") and kmesh.heads == "tp"
+    q, k, v = _qkv(b=2, h=4, hkv=2, s=128, d=32)
+    w = jnp.asarray(np.linspace(0.5, 1.5, 32), jnp.float32)
+    weight = jnp.asarray(
+        np.linspace(0.5, 1.5, q.size).reshape(q.shape), jnp.float32)
+
+    def loss(attn, norm):
+        def f(q, k, v, w):
+            return (attn(norm(q, w), k, v).astype(jnp.float32)
+                    * weight).sum()
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2, 3)))
+
+    with force_kernel_backend("interpret"):
+        out, g = loss(
+            lambda q, k, v: flash_attention(q, k, v, True, None, True, kmesh),
+            lambda x, w: rms_norm(x, w, 1e-6, kmesh))(q, k, v, w)
+    ref, g_ref = loss(lambda q, k, v: attention_reference(q, k, v),
+                      rms_norm_reference)(q, k, v, w)
+    np.testing.assert_allclose(float(out), float(ref), rtol=2e-2)
+    for name, a, b in zip("dq dk dv dw".split(), g, g_ref):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() / np.abs(b).max() < 2e-2, name
+
+
 class TestRingFlashChunk:
     """Ring attention over the Pallas chunk kernel (flash_attention_chunk:
     data-driven causal positions, differentiable lse) must match the
-    reference exactly like the einsum path does. INTERPRET runs the real
-    kernel code on CPU."""
+    reference exactly like the einsum path does. The interpret backend
+    runs the real kernel code on CPU."""
 
     def _with_interpret(self, fn):
-        import ray_tpu.ops.attention as attn_mod
-
-        attn_mod.INTERPRET = True
-        try:
+        with force_kernel_backend("interpret"):
             return fn()
-        finally:
-            attn_mod.INTERPRET = False
 
     def test_forward_matches_reference(self, cpu_mesh_devices):
         mesh = build_mesh(MeshSpec(sp=4), cpu_mesh_devices[:4])

@@ -315,8 +315,14 @@ class TestContainerRuntimeEnv:
         crasher.write_text("#!/usr/bin/env python3\nraise SystemExit(125)\n")
         crasher.chmod(0o755)
         monkeypatch.setenv("RTPU_CONTAINER_RUNNER", str(crasher))
-        # Fast corpse reaping so the failure budget is spent quickly.
+        # Fast corpse reaping so the failure budget is spent quickly. The
+        # node daemon runs in this process and reads the cached config, so
+        # drop the cache an earlier test may have filled (and again on the
+        # way out, so no later test inherits the short TTL).
         monkeypatch.setenv("RTPU_WORKER_IDLE_TTL_S", "1")
+        import ray_tpu.utils.config as config_mod
+
+        monkeypatch.setattr(config_mod, "_global_config", None)
 
         ray_tpu.shutdown()
         ray_tpu.init(address="local-cluster", num_cpus=2)

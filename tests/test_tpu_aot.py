@@ -1,0 +1,204 @@
+"""Compile for the TPU without one.
+
+``libtpu`` can describe a ``v5e:2x2`` host and compile for it with no chip
+attached, so two things stay checked on every PR: the Pallas kernels still
+compile (Mosaic runs inside ``.compile()``), and every program that reaches
+them still partitions over a mesh of several chips. XLA cannot partition a
+Mosaic call by itself; the wrappers in ray_tpu/ops run it per shard under
+``jax.shard_map`` (ops/kernels.py), and a caller that forgets to pass its mesh
+down fails here with "Mosaic kernels cannot be automatically partitioned".
+
+Compilations, not runs: what the chip does with them is chip_smoke.py's job.
+"""
+
+import collections
+import dataclasses
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from ray_tpu.models.llama import LlamaConfig, init_params, param_logical_axes
+from ray_tpu.ops.kernels import force_kernel_backend
+from ray_tpu.parallel.hlo_stats import collective_stats
+from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+from ray_tpu.parallel.sharding import kernel_mesh, tree_shardings
+from ray_tpu.train.spmd import (
+    TrainState,
+    _opt_shardings,
+    make_llama_train_step,
+    make_mixtral_train_step,
+    make_vit_train_step,
+)
+
+# Head width 64 like Llama-3.2-1B, GQA 2:1, everything else small. Weights
+# stay under the per-device q bytes of the train batch below, so a gathered
+# activation cannot hide among gathered weights.
+CFG = LlamaConfig(vocab_size=512, hidden_size=256, intermediate_size=512,
+                  num_layers=2, num_heads=8, num_kv_heads=4, head_dim=64,
+                  max_seq_len=256, dtype="bfloat16", tie_embeddings=True)
+BATCH, SEQ = 8, 256
+MOSAIC = 'custom_call_target="tpu_custom_call"'
+
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as e:  # noqa: BLE001 - no libtpu, or it cannot describe
+        pytest.skip(f"cannot create a v5e:2x2 topology: {e!r}")
+    return topo.devices
+
+
+@pytest.fixture()
+def mosaic(v5e_2x2):
+    with force_kernel_backend("mosaic", v5e_2x2[0].device_kind):
+        yield v5e_2x2
+
+
+def _sds(tree, shardings):
+    return jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        tree, shardings)
+
+
+def _abstract_state(mesh, init_state, logical_axes, optimizer):
+    """init_state()'s shapes with the shardings it would place them on
+    (compile-only devices hold no arrays)."""
+    shapes = jax.eval_shape(init_state)
+    repl = NamedSharding(mesh, P())
+    param_sh = tree_shardings(mesh, logical_axes)
+    opt_sh = jax.tree.map(
+        lambda s: s if s is not None else repl,
+        _opt_shardings(optimizer, shapes.params, param_sh),
+        is_leaf=lambda x: x is None)
+    return TrainState(params=_sds(shapes.params, param_sh),
+                      opt_state=_sds(shapes.opt_state, opt_sh),
+                      step=jax.ShapeDtypeStruct((), jnp.int32, sharding=repl))
+
+
+def _collectives(text: str, n: int) -> collections.Counter:
+    stats = collective_stats(text, lambda p: 0, n_partitions=n)
+    assert stats.skipped_ops == 0
+    return collections.Counter((o.op, o.payload_bytes) for o in stats.ops)
+
+
+def _compile_step(factory, cfg, mesh, logical_axes, batch_shapes):
+    opt = optax.adamw(3e-4)
+    step_fn, init_state, _ = factory(cfg, mesh, optimizer=opt,
+                                     attn_impl="flash")
+    state = _abstract_state(mesh, init_state, logical_axes, opt)
+    batch_sh = NamedSharding(mesh, P(("dp", "fsdp")))
+    batch = [jax.ShapeDtypeStruct(shape, dtype, sharding=batch_sh)
+             for shape, dtype in batch_shapes]
+    return step_fn.lower(state, *batch).compile().as_text()
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_kernels_compile_on_one_chip(mosaic, head_dim):
+    from ray_tpu.ops.attention import flash_attention
+    from ray_tpu.ops.norms import rms_norm
+
+    dev = NamedSharding(build_mesh(MeshSpec(), mosaic[:1]), P())
+    q = jax.ShapeDtypeStruct((2, 8, 512, head_dim), jnp.bfloat16, sharding=dev)
+    kv = jax.ShapeDtypeStruct((2, 4, 512, head_dim), jnp.bfloat16,
+                              sharding=dev)
+    w = jax.ShapeDtypeStruct((head_dim,), jnp.bfloat16, sharding=dev)
+
+    def loss(q, k, v, w):
+        o = flash_attention(rms_norm(q, w), k, v, True, None, True)
+        return o.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv, w).compile().as_text()
+    assert text.count(MOSAIC) == 3  # flash fwd, fused flash bwd, rms_norm
+
+
+@pytest.mark.parametrize("axis", ["dp", "fsdp", "tp"])
+def test_llama_step_partitions_over_four_chips(mosaic, axis):
+    mesh = build_mesh(MeshSpec(**{axis: 4}), mosaic)
+    text = _compile_step(
+        partial(make_llama_train_step, remat="attn"), CFG, mesh,
+        param_logical_axes(CFG), [((BATCH, SEQ), jnp.int32)] * 2)
+    assert "num_partitions=4" in text
+    # Per layer: 2 rms_norm + flash fwd in the forward, the rms_norm pair
+    # again under remat, the fused flash bwd; plus the final norm.
+    assert text.count(MOSAIC) == 7
+    # Nothing as large as one device's q may be gathered: the kernels run on
+    # each chip's own batch rows (dp, fsdp) or heads (tp). FSDP gathers
+    # weights, and no weight of CFG is that large.
+    q_bytes = BATCH * CFG.num_heads * SEQ * CFG.head_dim * 2 // 4
+    gathered = [n for (op, n) in _collectives(text, 4) if op == "all-gather"]
+    assert all(n < q_bytes for n in gathered), (gathered, q_bytes)
+    if axis == "fsdp":
+        assert gathered  # the weights
+    else:
+        assert not gathered  # dp and tp reduce; they gather nothing
+
+
+def test_mixtral_step_partitions_over_expert_parallel_chips(mosaic):
+    from ray_tpu.models import mixtral
+
+    cfg = dataclasses.replace(
+        mixtral.MixtralConfig.tiny(), hidden_size=256, num_heads=8,
+        num_kv_heads=4, head_dim=64, dtype="bfloat16")
+    mesh = build_mesh(MeshSpec(ep=4), mosaic)
+    text = _compile_step(make_mixtral_train_step, cfg, mesh,
+                         mixtral.param_logical_axes(cfg),
+                         [((4, 128), jnp.int32)] * 2)
+    assert "num_partitions=4" in text and text.count(MOSAIC) > 0
+
+
+def test_vit_step_partitions_over_four_chips(mosaic):
+    from ray_tpu.models import vit
+
+    # 64 patches + cls = 65 tokens: ViT sequences are not block multiples.
+    cfg = dataclasses.replace(vit.ViTConfig.tiny(), image_size=32,
+                              hidden_size=128, num_heads=2, dtype="bfloat16")
+    mesh = build_mesh(MeshSpec(dp=4), mosaic)
+    text = _compile_step(
+        make_vit_train_step, cfg, mesh, vit.param_logical_axes(cfg),
+        [((8, 32, 32, cfg.num_channels), jnp.float32), ((8,), jnp.int32)])
+    assert "num_partitions=4" in text and text.count(MOSAIC) > 0
+
+
+def test_engine_programs_partition_over_tensor_parallel_chips(mosaic):
+    from ray_tpu.llm import engine
+
+    slots, max_seq = 4, 256
+    mesh = build_mesh(MeshSpec(tp=4), mosaic)
+    kmesh = kernel_mesh(mesh)
+    repl = NamedSharding(mesh, P())
+    params = _sds(
+        jax.eval_shape(partial(init_params, CFG), jax.random.PRNGKey(0)),
+        tree_shardings(mesh, param_logical_axes(CFG)))
+    cache = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=NamedSharding(mesh, P(None, None, "tp"))),
+        jax.eval_shape(partial(engine.init_kv_cache, CFG, slots, max_seq)))
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
+
+    # 40 tokens: a chunk clamped to the cache tail, not a power of two.
+    prefill = engine.prefill_chunk.lower(
+        CFG, params, cache, arg((40,)), arg(()), arg(()), arg(()),
+        kmesh=kmesh).compile().as_text()
+    decode = engine.decode_burst.lower(
+        CFG, params, cache, arg((slots,)), arg((slots,)),
+        arg((slots,), jnp.bool_), arg((slots,), jnp.float32),
+        arg((slots,), jnp.float32), arg((2,), jnp.uint32), 4, False,
+        kmesh=kmesh).compile().as_text()
+    for text in (prefill, decode):
+        assert "num_partitions=4" in text
+        assert text.count(MOSAIC) == 3  # attn_norm, mlp_norm, final_norm
+        # Activations are replicated over tp; only reductions cross chips
+        # (and the sampled tokens' few bytes).
+        assert all(op == "all-reduce" or n <= 64
+                   for (op, n) in _collectives(text, 4))

@@ -303,14 +303,11 @@ def test_spmd_train_step_factory(cpu_mesh_devices):
     state, m2 = step_fn(state, tokens, targets)
     assert float(m2["loss"]) < float(m1["loss"])
     assert int(state.step) == 2
-    # params stayed sharded per rules (normalize both sides: jax 0.4.x
-    # keeps P(("fsdp",)) and P("fsdp") distinct objects; >=0.5 normalizes
-    # at construction)
+    # params stayed sharded per rules
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.parallel.sharding import normalize_spec
-    assert normalize_spec(state.params["layers"]["wq"].sharding.spec) == \
-        normalize_spec(P(None, ("fsdp",), "tp"))
+    assert state.params["layers"]["wq"].sharding.spec == \
+        P(None, ("fsdp",), "tp")
 
 
 def test_elastic_restart_at_smaller_world_size(tmp_path):
