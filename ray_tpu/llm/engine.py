@@ -701,6 +701,26 @@ class LLMEngine:
         # caller that a kernel or a program did not run.
         self.device_failures = 0
         self.requests_failed = 0
+        # Work done and time waited, counted where it happens: cumulative,
+        # never reset (not by a device failure either), written by the
+        # scheduler thread alone and read through stats(), so a reader
+        # that polls takes window deltas. decode_steps are device steps
+        # (a burst of 8 counts 8; each computes every slot), decode_tokens
+        # the tokens they gave that a request still wanted (so not its
+        # first, which prefill gives); queue_wait_s sums admit - submit
+        # over `admitted`, first_token_wait_s first token - admit over
+        # `first_tokens`.
+        self.ticks = 0
+        self.admitted = 0
+        self.finished = 0
+        self.prompt_tokens_prefilled = 0
+        self.prefill_chunks = 0
+        self.decode_dispatches = 0
+        self.decode_steps = 0
+        self.decode_tokens = 0
+        self.first_tokens = 0
+        self.queue_wait_s = 0.0
+        self.first_token_wait_s = 0.0
         # KV layout: dense [slots, max_seq] lines, or the block pool (see
         # the blocked-cache section above and LLMConfig.kv_block_size).
         self.block_size = int(getattr(config, "kv_block_size", 0) or 0)
@@ -795,7 +815,8 @@ class LLMEngine:
         self._pending_burst = None
         self._stop = threading.Event()
         self._work = threading.Event()
-        self._thread = threading.Thread(target=self._loop, daemon=True)
+        self._thread = threading.Thread(target=self._loop, daemon=True,
+                                        name="llm-engine")
         self._thread.start()
 
     # ---- public API ----
@@ -1016,7 +1037,17 @@ class LLMEngine:
                "prefix_cached_slots": len(self._prefix_cached),
                "prefix_block": self.prefix_block,
                "device_failures": self.device_failures,
-               "requests_failed": self.requests_failed}
+               "requests_failed": self.requests_failed,
+               "ticks": self.ticks, "admitted": self.admitted,
+               "finished": self.finished,
+               "prompt_tokens_prefilled": self.prompt_tokens_prefilled,
+               "prefill_chunks": self.prefill_chunks,
+               "decode_dispatches": self.decode_dispatches,
+               "decode_steps": self.decode_steps,
+               "decode_tokens": self.decode_tokens,
+               "first_tokens": self.first_tokens,
+               "queue_wait_s": self.queue_wait_s,
+               "first_token_wait_s": self.first_token_wait_s}
         if self.blocked:
             out["kv_blocks_total"] = self.num_blocks
             out["kv_blocks_free"] = len(self._free_blocks)
@@ -1034,6 +1065,7 @@ class LLMEngine:
     # ---- scheduler ----
 
     def _loop(self) -> None:
+        tracing.name_thread()
         while not self._stop.is_set():
             try:
                 worked = self._tick()
@@ -1044,15 +1076,31 @@ class LLMEngine:
                 # still escapes is logged and backed off, never hot-spun.
                 logger.exception("LLMEngine scheduler tick failed")
                 worked = False
-            if not worked:
-                self._work.wait(timeout=0.02)
-                self._work.clear()
+            if worked:
+                self.ticks += 1
+            else:
+                self._wait_for_work()
         # Drain a chained burst so its requests get their final tokens
         # instead of hanging to their timeouts.
         try:
             self._resolve_pending_burst()
         except Exception:  # noqa: BLE001 - shutdown path
             pass
+
+    def _wait_for_work(self) -> None:
+        """Sleep until work is signalled: one ``engine.wait`` phase for
+        the whole idle stretch. While anything is queued, slotted or in
+        flight the sleep ends after 20 ms at the latest, so a tick that
+        failed is tried again; an empty engine has nothing to try."""
+        with tracing.phase("engine.wait"):
+            while not self._work.wait(timeout=0.02):
+                if (self._stop.is_set() or self._pending_burst is not None
+                        or self._preempted or not self._waiting.empty()
+                        or not self._released.empty()
+                        or any(r is not None
+                               for r in self._slots.values())):
+                    break
+        self._work.clear()
 
     def _tick(self) -> bool:
         """One scheduler step: a bounded budget of prefill chunks (their
@@ -1071,17 +1119,21 @@ class LLMEngine:
         # burst's dispatch, so that burst's write mask provably excludes
         # it — only slots freed BY the pending resolve (mid-burst
         # finishes) must wait for it, and those are still occupied here.
-        self._process_releases()
-        worked = self._admit()
-        deferred: list = []
-        try:
-            return self._tick_inner(deferred) or worked
-        finally:
-            # An exception between a prefill dispatch and its resolution
-            # must not strand the deferred first-token fetches — the
-            # requests would report prefilled but never start decoding
-            # (hang to client timeout). Whatever survived, resolve it.
-            self._resolve_prefills(deferred)
+        # engine.tick encloses the tick's other phases: what a profile
+        # shows in it and in none of them is the scheduler's own glue.
+        with tracing.phase("engine.tick"):
+            self._process_releases()
+            worked = self._admit()
+            deferred: list = []
+            try:
+                return self._tick_inner(deferred) or worked
+            finally:
+                # An exception between a prefill dispatch and its
+                # resolution must not strand the deferred first-token
+                # fetches — the requests would report prefilled but never
+                # start decoding (hang to client timeout). Whatever
+                # survived, resolve it.
+                self._resolve_prefills(deferred)
 
     def _tick_inner(self, deferred: list) -> bool:
         worked = False
@@ -1145,7 +1197,8 @@ class LLMEngine:
                 # token of the re-prefill.
                 continue
             try:
-                tok = int(np.asarray(out)[0])
+                with tracing.phase("engine.fetch", which="prefill"):
+                    tok = int(np.asarray(out)[0])
             except Exception as e:  # noqa: BLE001 - async dispatch error
                 # surfaces at materialization; engine state is suspect.
                 logger.exception("deferred prefill sample failed for %s",
@@ -1153,7 +1206,8 @@ class LLMEngine:
                 self._recover_device_failure(f"prefill failed: {e!r}")
                 return
             req.next_pos = len(req.prompt_ids)
-            self._emit(req, tok)
+            with tracing.phase("engine.emit", tokens=1):
+                self._emit(req, tok)
 
     # Minimum adopted-prefix length that justifies a cross-slot KV copy
     # (the copy moves whole cache lines; tiny prefixes aren't worth it).
@@ -1165,17 +1219,33 @@ class LLMEngine:
     PREFILL_PRIORITY_BURST = 8
 
     def _admit(self) -> bool:
-        """Move waiting requests into unoccupied slots (prefill starts on
+        """Move waiting requests into unoccupied slots, as one
+        ``engine.admit`` phase when there is a request and a slot for
+        it."""
+        if self._waiting.empty() and not self._preempted:
+            return False
+        if all(o is not None for o in self._slots.values()):
+            return False
+        with tracing.phase("engine.admit") as ph:
+            admitted = self._admit_waiting()
+            ph.set(requests=admitted)
+        return admitted > 0
+
+    def _admit_waiting(self) -> int:
+        """The requests moved into unoccupied slots (prefill starts on
         subsequent ticks), adopting cached prompt prefixes when a donor
         slot shares one (vLLM-APC semantics: the final prompt token is
         always recomputed so its logits seed decoding)."""
-        admitted = False
+        admitted = 0
         while any(o is None for o in self._slots.values()):
             try:
                 req = self._next_waiting()
             except queue.Empty:
                 break
-            req.admit_ts = time.time()
+            if not req.admit_ts:  # not a preempted request coming back
+                req.admit_ts = time.time()
+                self.admitted += 1
+                self.queue_wait_s += req.admit_ts - req.submit_ts
             if req.preloaded is not None:
                 slot = self._take_slot()
                 try:
@@ -1183,7 +1253,7 @@ class LLMEngine:
                 except Exception as e:  # noqa: BLE001 - bad KV payload
                     self._slots[slot] = None
                     self._fail(req, f"KV import failed: {e!r}")
-                admitted = True
+                admitted += 1
                 continue
             donor, adopt, retired = self._best_prefix(req.prompt_ids)
             req.prefilled_len = 0
@@ -1218,7 +1288,7 @@ class LLMEngine:
                 req.next_pos = -1
                 req.last_slot = slot
                 self._slots[slot] = req
-                admitted = True
+                admitted += 1
                 continue
             if donor is not None and adopt < self.PREFIX_COPY_MIN:
                 # Trivial LCP (e.g. a shared few-token template label):
@@ -1280,7 +1350,7 @@ class LLMEngine:
             req.next_pos = -1
             req.last_slot = slot
             self._slots[slot] = req
-            admitted = True
+            admitted += 1
         return admitted
 
     # ---- blocked-KV pool accounting (scheduler thread only) ----
@@ -1464,41 +1534,55 @@ class LLMEngine:
             self._prefill_rr = slot
             bucket, take = self._chunk_bucket(req.prefilled_len,
                                               p - req.prefilled_len)
-            toks = np.zeros((bucket,), np.int32)
-            toks[:take] = req.prompt_ids[req.prefilled_len:
-                                         req.prefilled_len + take]
-            if self.blocked and not self._ensure_blocks(
-                    slot, req.prefilled_len + bucket - 1):
-                self._slots[slot] = None
-                self._free_slot_blocks(slot)
-                self._fail(req, "KV block pool exhausted "
-                                f"({self.num_blocks} blocks x "
-                                f"{self.block_size} tokens)")
-                return True
-            try:
-                if self.blocked:
-                    self.cache, logits = prefill_chunk_blocked(
-                        self.model_cfg, self.params, self.cache,
-                        jnp.asarray(self._tables[slot]), jnp.asarray(toks),
-                        jnp.int32(req.prefilled_len), jnp.int32(p),
-                        kmesh=self.kmesh)
-                else:
-                    self.cache, logits = prefill_chunk(
-                        self.model_cfg, self.params, self.cache,
-                        jnp.asarray(toks), jnp.int32(req.prefilled_len),
-                        jnp.int32(p), jnp.int32(slot), kmesh=self.kmesh)
-                req.prefilled_len += take
-                if req.prefilled_len >= p:  # final chunk: sample 1st token
-                    # The slot now holds the full prompt's KV: it becomes a
-                    # prefix donor for later shared-prefix requests.
-                    self._prefix_live[slot] = tuple(req.prompt_ids)
-                    out = self._sample_dispatch(logits[None], [req])
-                    deferred.append((req, req.prefill_gen, out))
-            except Exception as e:  # noqa: BLE001 - e.g. OOM on long prompt
-                logger.exception("prefill failed for %s", req.request_id)
-                self._recover_device_failure(f"prefill failed: {e!r}")
+            with tracing.phase("engine.prefill_dispatch", tokens=take,
+                               bucket=bucket):
+                self._dispatch_prefill_chunk(slot, req, bucket, take,
+                                             deferred)
             return True
         return False
+
+    def _dispatch_prefill_chunk(self, slot: int, req: GenerationRequest,
+                                bucket: int, take: int,
+                                deferred: list) -> None:
+        """The host side of one chunk: pad it to its bucket, dispatch it,
+        and on the prompt's last chunk dispatch the first token's sample
+        too (its fetch is deferred, see _prefill_step)."""
+        p = len(req.prompt_ids)
+        toks = np.zeros((bucket,), np.int32)
+        toks[:take] = req.prompt_ids[req.prefilled_len:
+                                     req.prefilled_len + take]
+        if self.blocked and not self._ensure_blocks(
+                slot, req.prefilled_len + bucket - 1):
+            self._slots[slot] = None
+            self._free_slot_blocks(slot)
+            self._fail(req, "KV block pool exhausted "
+                            f"({self.num_blocks} blocks x "
+                            f"{self.block_size} tokens)")
+            return
+        try:
+            if self.blocked:
+                self.cache, logits = prefill_chunk_blocked(
+                    self.model_cfg, self.params, self.cache,
+                    jnp.asarray(self._tables[slot]), jnp.asarray(toks),
+                    jnp.int32(req.prefilled_len), jnp.int32(p),
+                    kmesh=self.kmesh)
+            else:
+                self.cache, logits = prefill_chunk(
+                    self.model_cfg, self.params, self.cache,
+                    jnp.asarray(toks), jnp.int32(req.prefilled_len),
+                    jnp.int32(p), jnp.int32(slot), kmesh=self.kmesh)
+            req.prefilled_len += take
+            self.prefill_chunks += 1
+            self.prompt_tokens_prefilled += take
+            if req.prefilled_len >= p:  # final chunk: sample 1st token
+                # The slot now holds the full prompt's KV: it becomes a
+                # prefix donor for later shared-prefix requests.
+                self._prefix_live[slot] = tuple(req.prompt_ids)
+                out = self._sample_dispatch(logits[None], [req])
+                deferred.append((req, req.prefill_gen, out))
+        except Exception as e:  # noqa: BLE001 - e.g. OOM on long prompt
+            logger.exception("prefill failed for %s", req.request_id)
+            self._recover_device_failure(f"prefill failed: {e!r}")
 
     def _recover_device_failure(self, err: str) -> None:
         """After a failed prefill/decode dispatch the KV cache is gone —
@@ -1579,6 +1663,47 @@ class LLMEngine:
             active = self._ensure_decode_blocks(active, burst)
             if not active:
                 return True
+        if burst > 1:
+            return self._decode_burst(active, burst)
+        try:
+            with tracing.phase("engine.decode_dispatch", steps=1,
+                               slots=len(active)):
+                tokens, positions, write = self._decode_inputs(active)
+                if self.blocked:
+                    self.cache, logits = decode_step_blocked(
+                        self.model_cfg, self.params, self.cache,
+                        jnp.asarray(self._tables), jnp.asarray(tokens),
+                        jnp.asarray(positions), jnp.asarray(write),
+                        kmesh=self.kmesh)
+                else:
+                    self.cache, logits = decode_step(
+                        self.model_cfg, self.params, self.cache,
+                        jnp.asarray(tokens), jnp.asarray(positions),
+                        jnp.asarray(write), kmesh=self.kmesh)
+        except Exception as e:  # noqa: BLE001 - cache donated & lost
+            logger.exception("decode step failed (%d active)", len(active))
+            self._recover_device_failure(f"decode failed: {e!r}")
+            return False
+        self.decode_dispatches += 1
+        self.decode_steps += 1
+        try:
+            reqs = [active.get(s) for s in range(self.max_slots)]
+            with tracing.phase("engine.fetch", which="step"):
+                sampled = self._sample_one(logits, reqs)
+        except Exception as e:  # noqa: BLE001 - cache survived; only this
+            # batch's requests lack tokens — fail them, keep other contexts.
+            logger.exception("sampling failed (%d active)", len(active))
+            for req in active.values():
+                self._fail(req, f"sampling failed: {e!r}")
+            return True
+        with tracing.phase("engine.emit", tokens=len(active)):
+            for slot, req in active.items():
+                req.next_pos += 1
+                self._emit(req, int(sampled[slot]))
+        return True
+
+    def _decode_inputs(self, active: dict[int, GenerationRequest]):
+        """(last token, position, write mask) over the static slot array."""
         tokens = np.zeros((self.max_slots,), np.int32)
         positions = np.zeros((self.max_slots,), np.int32)
         write = np.zeros((self.max_slots,), bool)
@@ -1586,41 +1711,10 @@ class LLMEngine:
             tokens[slot] = req.out_tokens[-1]
             positions[slot] = req.next_pos
             write[slot] = True
-        if burst > 1:
-            return self._decode_burst(active, burst, tokens, positions,
-                                      write)
-        try:
-            if self.blocked:
-                self.cache, logits = decode_step_blocked(
-                    self.model_cfg, self.params, self.cache,
-                    jnp.asarray(self._tables), jnp.asarray(tokens),
-                    jnp.asarray(positions), jnp.asarray(write),
-                    kmesh=self.kmesh)
-            else:
-                self.cache, logits = decode_step(
-                    self.model_cfg, self.params, self.cache,
-                    jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(write), kmesh=self.kmesh)
-        except Exception as e:  # noqa: BLE001 - cache donated & lost
-            logger.exception("decode step failed (%d active)", len(active))
-            self._recover_device_failure(f"decode failed: {e!r}")
-            return False
-        try:
-            reqs = [active.get(s) for s in range(self.max_slots)]
-            sampled = self._sample_one(logits, reqs)
-        except Exception as e:  # noqa: BLE001 - cache survived; only this
-            # batch's requests lack tokens — fail them, keep other contexts.
-            logger.exception("sampling failed (%d active)", len(active))
-            for req in active.values():
-                self._fail(req, f"sampling failed: {e!r}")
-            return True
-        for slot, req in active.items():
-            req.next_pos += 1
-            self._emit(req, int(sampled[slot]))
-        return True
+        return tokens, positions, write
 
     def _decode_burst(self, active: dict[int, GenerationRequest],
-                      burst: int, tokens, positions, write) -> bool:
+                      burst: int) -> bool:
         """Emit ``burst`` tokens per active slot from one device dispatch.
         A request finishing mid-burst (EOS/stop token) simply stops
         emitting; the extra KV the device wrote past its end sits at
@@ -1632,28 +1726,33 @@ class LLMEngine:
         last token forward — the fetch roundtrip then overlaps the next
         burst's compute. The chained burst is resolved at the next tick's
         start (_resolve_pending_burst)."""
-        temps = np.zeros((self.max_slots,), np.float32)
-        top_ps = np.ones((self.max_slots,), np.float32)
-        for slot, req in active.items():
-            temps[slot] = req.sampling.temperature
-            top_ps[slot] = req.sampling.top_p
-        need_top_p = bool((top_ps < 1.0).any())
-        self._rng_key, sub = jax.random.split(self._rng_key)
         try:
-            if self.blocked:
-                self.cache, toks = decode_burst_blocked(
-                    self.model_cfg, self.params, self.cache,
-                    jnp.asarray(self._tables), jnp.asarray(tokens),
-                    jnp.asarray(positions), jnp.asarray(write),
-                    jnp.asarray(temps), jnp.asarray(top_ps), sub, burst,
-                    need_top_p, kmesh=self.kmesh)
-            else:
-                self.cache, toks = decode_burst(
-                    self.model_cfg, self.params, self.cache,
-                    jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(write), jnp.asarray(temps),
-                    jnp.asarray(top_ps), sub, burst, need_top_p,
-                    kmesh=self.kmesh)
+            with tracing.phase("engine.decode_dispatch", steps=burst,
+                               slots=len(active)):
+                tokens, positions, write = self._decode_inputs(active)
+                temps = np.zeros((self.max_slots,), np.float32)
+                top_ps = np.ones((self.max_slots,), np.float32)
+                for slot, req in active.items():
+                    temps[slot] = req.sampling.temperature
+                    top_ps[slot] = req.sampling.top_p
+                need_top_p = bool((top_ps < 1.0).any())
+                self._rng_key, sub = jax.random.split(self._rng_key)
+                if self.blocked:
+                    self.cache, toks = decode_burst_blocked(
+                        self.model_cfg, self.params, self.cache,
+                        jnp.asarray(self._tables), jnp.asarray(tokens),
+                        jnp.asarray(positions), jnp.asarray(write),
+                        jnp.asarray(temps), jnp.asarray(top_ps), sub, burst,
+                        need_top_p, kmesh=self.kmesh)
+                else:
+                    self.cache, toks = decode_burst(
+                        self.model_cfg, self.params, self.cache,
+                        jnp.asarray(tokens), jnp.asarray(positions),
+                        jnp.asarray(write), jnp.asarray(temps),
+                        jnp.asarray(top_ps), sub, burst, need_top_p,
+                        kmesh=self.kmesh)
+            self.decode_dispatches += 1
+            self.decode_steps += burst
             chain = self._should_chain(active, burst)
             if chain and self.blocked:
                 # A chain must never evict someone: skip it unless every
@@ -1662,23 +1761,29 @@ class LLMEngine:
                     s, r.next_pos + 2 * burst - 1, preempt=False)
                     for s, r in active.items())
             if chain:
-                self._rng_key, sub2 = jax.random.split(self._rng_key)
-                if self.blocked:
-                    self.cache, toks2 = decode_burst_blocked(
-                        self.model_cfg, self.params, self.cache,
-                        jnp.asarray(self._tables), toks[burst - 1],
-                        jnp.asarray(positions) + burst, jnp.asarray(write),
-                        jnp.asarray(temps), jnp.asarray(top_ps), sub2,
-                        burst, need_top_p, kmesh=self.kmesh)
-                else:
-                    self.cache, toks2 = decode_burst(
-                        self.model_cfg, self.params, self.cache,
-                        toks[burst - 1], jnp.asarray(positions) + burst,
-                        jnp.asarray(write), jnp.asarray(temps),
-                        jnp.asarray(top_ps), sub2, burst, need_top_p,
-                        kmesh=self.kmesh)
+                with tracing.phase("engine.decode_dispatch", steps=burst,
+                                   slots=len(active), chained=1):
+                    self._rng_key, sub2 = jax.random.split(self._rng_key)
+                    if self.blocked:
+                        self.cache, toks2 = decode_burst_blocked(
+                            self.model_cfg, self.params, self.cache,
+                            jnp.asarray(self._tables), toks[burst - 1],
+                            jnp.asarray(positions) + burst,
+                            jnp.asarray(write), jnp.asarray(temps),
+                            jnp.asarray(top_ps), sub2, burst, need_top_p,
+                            kmesh=self.kmesh)
+                    else:
+                        self.cache, toks2 = decode_burst(
+                            self.model_cfg, self.params, self.cache,
+                            toks[burst - 1], jnp.asarray(positions) + burst,
+                            jnp.asarray(write), jnp.asarray(temps),
+                            jnp.asarray(top_ps), sub2, burst, need_top_p,
+                            kmesh=self.kmesh)
+                self.decode_dispatches += 1
+                self.decode_steps += burst
                 self._pending_burst = (dict(active), burst, toks2)
-            toks = np.asarray(toks)  # [burst, max_slots]
+            with tracing.phase("engine.fetch", which="burst"):
+                toks = np.asarray(toks)  # [burst, max_slots]
         except Exception as e:  # noqa: BLE001 - cache donated & lost
             logger.exception("burst decode failed (%d active, burst %d)",
                              len(active), burst)
@@ -1718,7 +1823,8 @@ class LLMEngine:
         active, burst, toks_dev = self._pending_burst
         self._pending_burst = None
         try:
-            toks = np.asarray(toks_dev)
+            with tracing.phase("engine.fetch", which="pending"):
+                toks = np.asarray(toks_dev)
         except Exception as e:  # noqa: BLE001 - surfaces at materialization
             logger.exception("pipelined burst failed (%d slots)", len(active))
             self._recover_device_failure(f"decode failed: {e!r}")
@@ -1727,12 +1833,15 @@ class LLMEngine:
         return True
 
     def _emit_burst(self, active, burst: int, toks) -> None:
-        for j in range(burst):
-            for slot, req in active.items():
-                if req.done.is_set():
-                    continue
-                req.next_pos += 1
-                self._emit(req, int(toks[j, slot]))
+        with tracing.phase("engine.emit") as ph:
+            before = self.decode_tokens
+            for j in range(burst):
+                for slot, req in active.items():
+                    if req.done.is_set():
+                        continue
+                    req.next_pos += 1
+                    self._emit(req, int(toks[j, slot]))
+            ph.set(tokens=self.decode_tokens - before)
 
     def _spec_decode(self, active: dict[int, GenerationRequest]) -> None:
         """One speculative tick: draft proposes spec_k tokens per slot in
@@ -1778,41 +1887,52 @@ class LLMEngine:
             pos0[slot] = req.next_pos
             write[slot] = True
         try:
-            self.draft_cache, proposals = draft_propose(
-                self.draft_cfg, self.draft_params, self.draft_cache,
-                jnp.asarray(token0), jnp.asarray(pos0), k,
-                jnp.asarray(write), kmesh=self.kmesh)
-            proposals = np.asarray(proposals)  # [B, k]
-            verify_tokens = np.concatenate(
-                [token0[:, None], proposals], axis=1)  # [B, k+1]
-            self.cache, logits = spec_verify_step(
-                self.model_cfg, self.params, self.cache,
-                jnp.asarray(verify_tokens), jnp.asarray(pos0),
-                jnp.asarray(write), kmesh=self.kmesh)
-            greedy = np.asarray(jnp.argmax(logits, axis=-1))  # [B, k+1]
+            # One phase for draft and verify: each is fetched as soon as
+            # it is dispatched, so dispatch and fetch do not come apart.
+            with tracing.phase("engine.decode_dispatch", steps=k + 1,
+                               slots=len(active), speculative=1):
+                self.draft_cache, proposals = draft_propose(
+                    self.draft_cfg, self.draft_params, self.draft_cache,
+                    jnp.asarray(token0), jnp.asarray(pos0), k,
+                    jnp.asarray(write), kmesh=self.kmesh)
+                proposals = np.asarray(proposals)  # [B, k]
+                verify_tokens = np.concatenate(
+                    [token0[:, None], proposals], axis=1)  # [B, k+1]
+                self.cache, logits = spec_verify_step(
+                    self.model_cfg, self.params, self.cache,
+                    jnp.asarray(verify_tokens), jnp.asarray(pos0),
+                    jnp.asarray(write), kmesh=self.kmesh)
+                greedy = np.asarray(jnp.argmax(logits, axis=-1))  # [B, k+1]
         except Exception as e:  # noqa: BLE001 - caches donated & lost
             logger.exception("speculative step failed (%d active)",
                              len(active))
             self._recover_device_failure(f"speculative decode failed: {e!r}")
             return
         self.spec_ticks += 1
-        for slot, req in active.items():
-            accepted = 0
-            while accepted < k and \
-                    proposals[slot, accepted] == greedy[slot, accepted]:
-                accepted += 1
-            self.spec_proposed += k
-            self.spec_accepted += accepted
-            emit = [int(t) for t in proposals[slot, :accepted]]
-            emit.append(int(greedy[slot, accepted]))  # corrected/bonus
-            for tok in emit:
-                if req.done.is_set():
-                    break
-                req.next_pos += 1
-                self._emit(req, tok)
-            # Draft KV is valid through the accepted prefix; draft_propose
-            # writes k+1 entries, covering even the all-accepted case.
-            req.draft_len = req.next_pos
+        # The verify step computes k + 1 positions of every slot.
+        self.decode_dispatches += 1
+        self.decode_steps += k + 1
+        with tracing.phase("engine.emit") as ph:
+            before = self.decode_tokens
+            for slot, req in active.items():
+                accepted = 0
+                while accepted < k and \
+                        proposals[slot, accepted] == greedy[slot, accepted]:
+                    accepted += 1
+                self.spec_proposed += k
+                self.spec_accepted += accepted
+                emit = [int(t) for t in proposals[slot, :accepted]]
+                emit.append(int(greedy[slot, accepted]))  # corrected/bonus
+                for tok in emit:
+                    if req.done.is_set():
+                        break
+                    req.next_pos += 1
+                    self._emit(req, tok)
+                # Draft KV is valid through the accepted prefix;
+                # draft_propose writes k+1 entries, covering even the
+                # all-accepted case.
+                req.draft_len = req.next_pos
+            ph.set(tokens=self.decode_tokens - before)
 
     def _chunk_bucket(self, start: int, remaining: int) -> tuple[int, int]:
         """(bucket, take) for one prefill chunk starting at ``start``:
@@ -1895,24 +2015,14 @@ class LLMEngine:
 
     def _emit(self, req: GenerationRequest, token: int) -> None:
         req.out_tokens.append(token)
-        if len(req.out_tokens) == 1 and req.trace_ctx is not None:
-            # First token: stamp the TTFT phase breakdown onto the
-            # request's trace — queue wait (submit→admit) and the prefill
-            # (or P/D KV import) interval ending at this emission.
+        if len(req.out_tokens) > 1:
+            self.decode_tokens += 1
+        else:
             now = req.first_token_ts = time.time()
-            if req.admit_ts and req.submit_ts:
-                tracing.record_span(
-                    "engine.queue", req.submit_ts, req.admit_ts,
-                    ctx=req.trace_ctx,
-                    attributes={"request_id": req.request_id})
-            tracing.record_span(
-                "engine.kv_import" if req.kv_imported
-                else "engine.prefill",
-                req.admit_ts or req.submit_ts or now, now,
-                ctx=req.trace_ctx,
-                attributes={"request_id": req.request_id,
-                            "prompt_tokens": len(req.prompt_ids),
-                            "prefix_adopted": req.prefilled_len})
+            self.first_tokens += 1
+            self.first_token_wait_s += now - (req.admit_ts or now)
+            if req.trace_ctx is not None:
+                self._stamp_first_token_spans(req, now)
         if req.stream_queue is not None:
             req.stream_queue.put(token)
         eos = {self.tokenizer.eos_id, *req.sampling.stop_token_ids}
@@ -1926,6 +2036,23 @@ class LLMEngine:
         if finish:
             self._finish(req, finish)
 
+    def _stamp_first_token_spans(self, req: GenerationRequest,
+                                 now: float) -> None:
+        """The TTFT phase breakdown on the request's own trace: queue wait
+        (submit→admit) and the prefill (or P/D KV import) interval ending
+        at the first token's emission."""
+        if req.admit_ts and req.submit_ts:
+            tracing.record_span(
+                "engine.queue", req.submit_ts, req.admit_ts,
+                ctx=req.trace_ctx,
+                attributes={"request_id": req.request_id})
+        tracing.record_span(
+            "engine.kv_import" if req.kv_imported else "engine.prefill",
+            req.admit_ts or req.submit_ts or now, now, ctx=req.trace_ctx,
+            attributes={"request_id": req.request_id,
+                        "prompt_tokens": len(req.prompt_ids),
+                        "prefix_adopted": req.prefilled_len})
+
     def _fail(self, req: GenerationRequest, err: str) -> None:
         """Fail one request: record the error, free its slot and any staged
         KV payload, and wake its waiter — the engine keeps serving others."""
@@ -1937,6 +2064,7 @@ class LLMEngine:
 
     def _finish(self, req: GenerationRequest, reason: str) -> None:
         req.finish_reason = reason
+        self.finished += 1
         if req.trace_ctx is not None and req.first_token_ts:
             tracing.record_span(
                 "engine.decode", req.first_token_ts, time.time(),

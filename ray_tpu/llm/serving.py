@@ -10,14 +10,17 @@ llm_config.py:141). The engine here is the JAX continuous-batching engine
 from __future__ import annotations
 
 import json
+import threading
 import time
 from typing import Any
 
 from ray_tpu import serve
+from ray_tpu.devtools.annotations import guarded_by
 from ray_tpu.llm.config import LLMConfig, SamplingParams
 from ray_tpu.llm.engine import LLMEngine
 
 
+@guarded_by("_lag_lock", "first_frames", "first_frame_lag_s")
 class LLMServer:
     """One replica = one engine instance (the engine batches across the
     replica's concurrent requests)."""
@@ -27,6 +30,13 @@ class LLMServer:
         self.engine = LLMEngine(llm_config)
         self._model_id = (llm_config.model if isinstance(llm_config.model, str)
                           else "llama")
+        # This layer's own share of the time to a first token: from the
+        # engine's first-token stamp to the stream method holding that
+        # token, ready to frame and yield. Summed over `first_frames`;
+        # the replica's pool threads stream concurrently, hence the lock.
+        self._lag_lock = threading.Lock()
+        self.first_frames = 0
+        self.first_frame_lag_s = 0.0
 
     # -- handle API --
 
@@ -67,10 +77,7 @@ class LLMServer:
         prompt = self.engine.tokenizer.apply_chat_template(messages)
         req = self.engine.submit(prompt, sampling, stream=True)
         rid = f"chatcmpl-{req.request_id}"
-        while True:
-            item = req.stream_queue.get()
-            if item is None:
-                break
+        for item in self._stream_tokens(req):
             delta = self.engine.tokenizer.decode([item])
             frame = {"id": rid, "object": "chat.completion.chunk",
                      "model": self._model_id,
@@ -89,10 +96,7 @@ class LLMServer:
         sampling = _sampling_from(kw)
         req = self.engine.submit(prompt, sampling, stream=True)
         rid = f"cmpl-{req.request_id}"
-        while True:
-            item = req.stream_queue.get()
-            if item is None:
-                break
+        for item in self._stream_tokens(req):
             frame = {"id": rid, "object": "text_completion",
                      "model": self._model_id,
                      "choices": [{"index": 0,
@@ -106,8 +110,26 @@ class LLMServer:
         yield f"data: {json.dumps(done)}\n\n"
         yield "data: [DONE]\n\n"
 
+    def _stream_tokens(self, req):
+        """The request's tokens as the engine emits them."""
+        first = True
+        while True:
+            item = req.stream_queue.get()
+            if item is None:
+                return
+            if first:
+                first = False
+                lag = time.time() - req.first_token_ts
+                with self._lag_lock:
+                    self.first_frames += 1
+                    self.first_frame_lag_s += lag
+            yield item
+
     def stats(self) -> dict:
-        return self.engine.stats()
+        with self._lag_lock:
+            own = {"first_frames": self.first_frames,
+                   "first_frame_lag_s": self.first_frame_lag_s}
+        return {**self.engine.stats(), **own}
 
     def router_prefix_blocks(self) -> dict | None:
         """KV-block-aware routing publication (serve/prefix.py): the serve

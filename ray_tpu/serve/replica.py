@@ -71,6 +71,23 @@ def _get_replica_metrics():
         return _replica_metrics
 
 
+def _steps_in_context(gen, ctx: dict):
+    """``gen``, each of its steps run inside the propagated trace context
+    ``ctx`` (and the thread's own context put back between steps: pool
+    threads are shared with other requests)."""
+    try:
+        while True:
+            with tracing.propagate_only(ctx):
+                try:
+                    chunk = next(gen)
+                except StopIteration:
+                    return
+            yield chunk
+    finally:
+        with tracing.propagate_only(ctx):
+            gen.close()
+
+
 @guarded_by("_lock", "_ongoing", "_total", "_shed", "_expired")
 class ServeReplica:
     """Created by the controller with max_concurrency == max_ongoing_requests
@@ -245,11 +262,25 @@ class ServeReplica:
 
     def handle_request_streaming(self, method_name: str, args: tuple,
                                  kwargs: dict):
-        """Streaming data plane: a generator actor method (called with
-        num_returns="streaming"). First yield is a meta dict
+        """Streaming data plane: an actor method that returns a generator
+        (called with num_returns="streaming"). First yield is a meta dict
         {"streaming": bool}; then either the single complete result or the
         user generator's chunks as they are produced (reference:
         replica.py streaming call path + proxy_request streaming)."""
+        # The request's trace context is captured NOW, while the worker
+        # span of this call is the thread's context: the runtime drives
+        # the returned generator after that span has left the thread, and
+        # each step of it re-enters the context (_steps_in_context), so
+        # that what the user's generator submits (an LLM engine request)
+        # joins the request's trace.
+        gen = self._stream_request(method_name, args, kwargs,
+                                   tracing.current_trace_id())
+        if tracing.current_context() is None:
+            return gen
+        return _steps_in_context(gen, tracing.inject())
+
+    def _stream_request(self, method_name: str, args: tuple, kwargs: dict,
+                        tid: str | None):
         import inspect
 
         from ray_tpu.serve.multiplex import _set_multiplexed_model_id
@@ -258,9 +289,6 @@ class ServeReplica:
         deadline = kwargs.pop(DEADLINE_KEY, None)
         self._begin_request(deadline)
         _set_current_deadline(deadline, self.deployment_name)
-        # Exemplar trace id captured NOW: the generator body runs after
-        # the submitting worker span has left the thread-local context.
-        tid = tracing.current_trace_id()
         t0 = time.perf_counter()
         try:
             self._chaos_probe(method_name)

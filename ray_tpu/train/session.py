@@ -14,6 +14,8 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any
 
+from ray_tpu.util import tracing
+
 # Per-worker step-time window feeding straggler attribution (the head ranks
 # workers from the decile summaries streamed with every telemetry push).
 _STEP_WINDOW = 256
@@ -268,17 +270,22 @@ def report(metrics: dict[str, Any], checkpoint: str | None = None) -> None:
     (train_step_time_s / train_tokens_per_s / train_mfu) so throughput is
     readable off /metrics, not just the report stream."""
     ctx = get_context()
-    _maybe_chaos(ctx, metrics)
-    try:
-        _instrument_report(ctx, metrics)
-    except Exception:
-        pass  # metrics must never fail a training step
-    with ctx._report_lock:
-        # "ts" is the worker-stamped report instant: the controller closes
-        # restart-downtime windows on it instead of its own observation
-        # time, so poll/RPC delivery lag never inflates the attribution.
-        ctx._reports.append({"metrics": dict(metrics), "checkpoint": checkpoint,
-                             "ts": time.time()})
+    # The one piece of host work this library does between two steps: as
+    # a phase it shows on a profiler's timeline between the step programs.
+    with tracing.phase("train.report"):
+        _maybe_chaos(ctx, metrics)
+        try:
+            _instrument_report(ctx, metrics)
+        except Exception:
+            pass  # metrics must never fail a training step
+        with ctx._report_lock:
+            # "ts" is the worker-stamped report instant: the controller
+            # closes restart-downtime windows on it instead of its own
+            # observation time, so poll/RPC delivery lag never inflates
+            # the attribution.
+            ctx._reports.append({"metrics": dict(metrics),
+                                 "checkpoint": checkpoint,
+                                 "ts": time.time()})
 
 
 def _maybe_chaos(ctx: TrainContext, metrics: dict[str, Any]) -> None:

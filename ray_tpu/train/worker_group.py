@@ -24,6 +24,7 @@ import ray_tpu
 from ray_tpu.devtools.annotations import guarded_by
 from ray_tpu.core.exceptions import GetTimeoutError
 from ray_tpu.train.session import TrainContext, drain_reports, set_context
+from ray_tpu.util import tracing
 
 
 @guarded_by("_res_lock", "_result", "_error")
@@ -96,6 +97,7 @@ class TrainWorker:
         def main():
             import inspect
 
+            tracing.name_thread()   # a profiler's line reads train-fn-<rank>
             set_context(self.ctx)
             try:
                 if len(inspect.signature(train_fn).parameters) >= 1:

@@ -33,7 +33,6 @@ off" arm of devbench/trace_bench.py).
 
 from __future__ import annotations
 
-import contextlib
 import os
 import threading
 import time
@@ -570,6 +569,104 @@ def task_span(name: str, trace_ctx: dict | None, kind: str = "worker",
     return span(name, kind=kind, attributes=attributes, ctx=trace_ctx)
 
 
+_annotation = None  # jax.profiler.TraceAnnotation; False: no JAX here
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        except Exception:  # noqa: BLE001 - a process without JAX
+            _annotation = False
+    return _annotation
+
+
+def _lane_id() -> str:
+    """One trace id per thread for phases that belong to no request: a
+    scheduler's phases then share a row of the chrome timeline, and
+    ``ray_tpu trace <id>`` lays them out as one waterfall."""
+    lane = getattr(_ctx, "lane", None)
+    if lane is None:
+        lane = _ctx.lane = _new_id(16)
+    return lane
+
+
+class _PhaseCM:
+    """Context manager behind :func:`phase`."""
+
+    __slots__ = ("_name", "_counts", "_ann", "_t0")
+
+    def __init__(self, name: str, counts: dict, ann):
+        self._name = name
+        self._counts = counts
+        self._ann = ann
+        self._t0 = 0.0
+
+    def __enter__(self) -> "_PhaseCM":
+        if self._ann is not None:
+            self._ann.__enter__()
+        if _enabled:
+            self._t0 = time.time()
+        return self
+
+    def set(self, **counts) -> None:
+        """Counts known only once the work is done (requests admitted,
+        tokens emitted)."""
+        self._counts.update(counts)
+        if self._ann is not None:
+            self._ann.set_metadata(**counts)
+
+    def __exit__(self, etype, exc, tb):
+        if self._ann is not None:
+            self._ann.__exit__(etype, exc, tb)
+        if self._t0:
+            ctx = inject() if current_context() else \
+                {"trace_id": _lane_id(), "parent_span_id": None}
+            record_span(self._name, self._t0, time.time(),
+                        attributes=self._counts, ctx=ctx)
+        return False
+
+
+def name_thread() -> None:
+    """Give the calling thread its ``threading`` name at the operating
+    system too (Linux, 15 bytes). A profiler labels a host thread's line
+    with that name, and before Python 3.14 ``threading`` names a thread
+    for Python alone: every Python thread's line then reads as the
+    process's own name, and a reader that keys lines by name keeps one
+    of them."""
+    import ctypes
+
+    try:
+        prctl = ctypes.CDLL(None).prctl
+    except (OSError, AttributeError):  # not Linux: the Python name stays
+        return
+    prctl.argtypes = [ctypes.c_int, ctypes.c_char_p, ctypes.c_ulong,
+                      ctypes.c_ulong, ctypes.c_ulong]
+    prctl.restype = ctypes.c_int
+    pr_set_name = 15
+    prctl(pr_set_name, threading.current_thread().name.encode()[:15],
+          0, 0, 0)
+
+
+def phase(name: str, **counts) -> _PhaseCM:
+    """A phase of a thread that owns a device (the engine's scheduler
+    loop, a train worker between two steps), named once and seen twice.
+    It always enters a ``jax.profiler.TraceAnnotation``: about a
+    microsecond while no profiler session is open, and during one the
+    phase lies on the device trace's own clock, between the programs it
+    dispatched, with ``counts`` as the event's stats. With
+    :func:`enable_tracing` on, the same interval is also recorded as a
+    :class:`Span`, under the thread's current trace or else under the
+    thread's own lane. Without JAX only the span remains. Phases are
+    milliseconds long and a handful a scheduler tick: not for per-token
+    work."""
+    ann = _trace_annotation()
+    return _PhaseCM(name, counts, ann(name, **counts) if ann else None)
+
+
 def spans() -> list[Span]:
     with _lock:
         return list(_spans)
@@ -684,26 +781,3 @@ def export_otlp() -> dict:
             }],
         }]
     }
-
-
-def save_otlp(path: str) -> str:
-    import json
-
-    with open(path, "w") as f:
-        json.dump(export_otlp(), f)
-    return path
-
-
-@contextlib.contextmanager
-def profile(logdir: str):
-    """XLA profiler capture around a block: writes an xplane trace viewable
-    in TensorBoard/XProf alongside a framework span (reference: SURVEY §5 —
-    hooks to dump jax.profiler traces into the same timeline channel)."""
-    import jax
-
-    with span("jax.profile", attributes={"logdir": logdir}):
-        jax.profiler.start_trace(logdir)
-        try:
-            yield
-        finally:
-            jax.profiler.stop_trace()
