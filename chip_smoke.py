@@ -151,7 +151,8 @@ def kernel_phase(cfg: SmokeConfig, n_devices: int) -> dict:
     """The Pallas kernels against their jnp references on a small input:
     rms_norm and causal GQA flash attention, forward and gradients, per
     shard over the local chips when there are several; then rms_norm on a
-    row count that no block divides. Returns the largest relative errors."""
+    row count that no block divides, and the serving decode kernels.
+    Returns the largest relative errors."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -195,12 +196,51 @@ def kernel_phase(cfg: SmokeConfig, n_devices: int) -> dict:
     errors["rms_norm_424_rows"] = relative_error(
         jax.jit(lambda x, w: rms_norm(x, w, 1e-5))(x, wx),
         rms_norm_reference(x, wx, 1e-5))
+    errors.update(_decode_kernel_errors(cfg, kmesh, keys, b, hkv, d))
     bad = {name: e for name, e in errors.items()
            if not np.isfinite(e) or e > 2e-2}
     if bad:
         raise AssertionError(f"kernels disagree with their references "
                              f"(relative error > 2e-2): {bad}")
     return errors
+
+
+def _decode_kernel_errors(cfg: SmokeConfig, kmesh, keys, b: int, hkv: int,
+                          d: int) -> dict:
+    """The serving decode kernels on layer 1 of a stack of two: one new row
+    a slot written in place, then grouped attention over the live blocks,
+    per shard over the slots; lines empty, of one row, across a block edge
+    and full."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import decode_attention as da
+
+    s = 2 * cfg.kernel_seq
+    lengths = jnp.asarray([(0, 129, 1, s)[i % 4] for i in range(b)],
+                          jnp.int32)
+    write, pos = lengths > 0, jnp.maximum(lengths - 1, 0)
+    q = jax.random.normal(keys[0], (b, 2 * hkv, 1, d), jnp.bfloat16)
+    kc = jax.random.normal(keys[1], (2, b, hkv, s, d), jnp.bfloat16)
+    vc = jax.random.normal(keys[2], (2, b, hkv, s, d), jnp.bfloat16)
+    nk = jax.random.normal(keys[3], (b, hkv, 1, d), jnp.bfloat16)
+    nv = jax.random.normal(keys[4], (b, hkv, 1, d), jnp.bfloat16)
+
+    @jax.jit
+    def kernels(kc, vc):
+        kc, vc = da.kv_row_write(kc, vc, nk, nv, 1, pos, write, kmesh=kmesh)
+        return kc, da.decode_attention(q, kc, vc, 1, lengths, pos,
+                                       kmesh=kmesh, block=128)
+
+    @jax.jit
+    def references(kc, vc):
+        kc, vc = da.kv_row_write_reference(kc, vc, nk, nv, 1, pos, write)
+        return kc, da.decode_attention_reference(q, kc, vc, 1, lengths, pos)
+
+    (got_k, got), (want_k, want) = kernels(kc, vc), references(kc, vc)
+    return {"kv_row_write": float(not np.array_equal(got_k, want_k)),
+            "decode_attention": relative_error(got, want)}
 
 
 # ---------------------------------------------------------------------- train

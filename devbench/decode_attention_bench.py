@@ -1,0 +1,204 @@
+"""The decode-attention kernels and the decode program, timed on the chip.
+
+    chiprun -- python3 devbench/decode_attention_bench.py [kernel] [burst]
+
+- ``kernel``: ``ops.decode_attention`` and ``kv_row_write`` alone over all
+  layers of a stacked cache at the two serving shapes of the benchmark
+  (32 slots x 2,048 and 16 x 3,200, Mistral-7B widths), each at a few block
+  sizes, against the bytes of the live K/V at 819 GB/s; and the kernel's
+  result against the jnp reference on the same inputs.
+- ``burst``: ``engine.decode_burst(steps=8)`` as the engine calls it, random
+  weights, ms a step, with the weight bytes' floor beside it.
+
+Prints one JSON object as its last line. Times are host clock around
+``block_until_ready`` over repeated calls of one jitted program.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+from functools import partial
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+HBM_BYTES_PER_S = 819e9  # TPU v5e, Google Cloud documentation
+
+# Mistral-7B widths; per cell: layers as served, slots, max_seq, live lengths
+# drawn from [lo, hi), share of busy slots, blocks to sweep.
+WIDTHS = dict(hidden_size=4096, intermediate_size=14336, num_heads=32,
+              num_kv_heads=8, head_dim=128, vocab_size=32768)
+SHAPES = {"chat": (12, 32, 2048, (64, 900), 0.72, (128, 256, 512, 1024)),
+          "docqa": (16, 16, 3200, (1100, 3100), 0.8, (128, 640))}
+
+
+def _time(fn, *args, reps: int = 20):
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / reps
+
+
+def _lengths(rng, slots: int, lo: int, hi: int, busy: float):
+    import numpy as np
+
+    lens = rng.integers(lo, hi, size=slots).astype(np.int32)
+    lens[rng.random(slots) > busy] = 0
+    return lens
+
+
+def bench_kernel() -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from ray_tpu.ops import decode_attention as da
+    from ray_tpu.ops.kernels import force_kernel_backend
+
+    out = {}
+    hkv, d = WIDTHS["num_kv_heads"], WIDTHS["head_dim"]
+    g = WIDTHS["num_heads"] // hkv
+    for name, (layers, slots, s, (lo, hi), busy, blocks) in SHAPES.items():
+        rng = np.random.default_rng(0)
+        lens = _lengths(rng, slots, lo, hi, busy)
+        keys = jax.random.split(jax.random.PRNGKey(0), 3)
+        q = jax.random.normal(keys[0], (slots, hkv * g, 1, d), jnp.bfloat16)
+        kc = jax.random.normal(keys[1], (layers, slots, hkv, s, d),
+                               jnp.bfloat16)
+        vc = jax.random.normal(keys[2], (layers, slots, hkv, s, d),
+                               jnp.bfloat16)
+        lens_d = jnp.asarray(lens)
+        pos = jnp.maximum(lens_d - 1, 0)
+        live_bytes = int(lens.sum()) * layers * 2 * hkv * d * 2
+        row = {"slots": slots, "max_seq": s, "live_positions": int(lens.sum()),
+               "floor_ms": 1e3 * live_bytes / HBM_BYTES_PER_S}
+
+        def all_layers(q, kc, vc, lens, pos, block):
+            def body(layer, q):
+                return da.decode_attention(q, kc, vc, layer, lens, pos,
+                                           block=block)
+            return lax.fori_loop(0, layers, body, q)
+
+        for block in blocks:
+            fn = jax.jit(partial(all_layers, block=block))
+            row[f"attn_ms_block{block}"] = 1e3 * _time(fn, q, kc, vc, lens_d,
+                                                       pos)
+            read = int(da.kv_positions_read(lens, block).sum())
+            row[f"read_positions_block{block}"] = read
+        got = jax.jit(partial(da.decode_attention, block=None))(
+            q, kc, vc, 3, lens_d, pos)
+        with force_kernel_backend("reference"):
+            want = jax.jit(da.decode_attention)(q, kc, vc, 3, lens_d, pos)
+        row["default_block"] = da.decode_kv_block(s, d)
+        row["max_abs_diff_vs_reference"] = float(jnp.max(jnp.abs(
+            got.astype(jnp.float32) - want.astype(jnp.float32))))
+
+        for k_tok in (1, 5):
+            nk = jax.random.normal(keys[0], (slots, hkv, k_tok, d),
+                                   jnp.bfloat16)
+            mask = lens_d > 0
+
+            def write_all(kc, vc, nk, pos, mask):
+                def body(layer, c):
+                    return da.kv_row_write(c[0], c[1], nk, nk, layer, pos,
+                                           mask)
+                return lax.fori_loop(0, layers, body, (kc, vc))
+
+            fn = jax.jit(write_all, donate_argnums=(0, 1))
+            kc, vc = fn(kc, vc, nk, pos, mask)
+            jax.block_until_ready(kc)
+            t0 = time.perf_counter()
+            for _ in range(10):
+                kc, vc = fn(kc, vc, nk, pos, mask)
+            jax.block_until_ready(kc)
+            row[f"write_ms_k{k_tok}"] = 1e3 * (time.perf_counter() - t0) / 10
+            with force_kernel_backend("reference"):
+                wk, _ = jax.jit(da.kv_row_write)(
+                    kc[:1], vc[:1], nk + 1, nk, 0, pos, mask)
+            gk, _ = jax.jit(da.kv_row_write)(kc[:1], vc[:1], nk + 1, nk, 0,
+                                             pos, mask)
+            row[f"write_matches_reference_k{k_tok}"] = bool(
+                jnp.array_equal(wk, gk))
+        out[name] = row
+        del kc, vc
+    return out
+
+
+def bench_burst() -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.llm import engine
+    from ray_tpu.models.llama import LlamaConfig, init_params
+
+    out = {}
+    for name, (layers, slots, s, (lo, hi), busy, _) in SHAPES.items():
+        cfg = LlamaConfig(
+            num_layers=layers, max_seq_len=s, dtype="bfloat16",
+            tie_embeddings=False, rope_theta=1e6, **WIDTHS)
+        params = jax.jit(partial(init_params, cfg))(jax.random.PRNGKey(0))
+        cache = engine.init_kv_cache(cfg, slots, s)
+        rng = np.random.default_rng(0)
+        lens = _lengths(rng, slots, lo, hi, busy)
+        write = jnp.asarray(lens > 0)
+        pos = jnp.asarray(np.maximum(lens - 1, 0))
+        tok = jnp.zeros((slots,), jnp.int32)
+        temps = jnp.zeros((slots,), jnp.float32)
+        top_ps = jnp.ones((slots,), jnp.float32)
+        key = jax.random.PRNGKey(1)
+        steps = 8
+
+        def run(cache):
+            return engine.decode_burst(cfg, params, cache, tok, pos, write,
+                                       temps, top_ps, key, steps, False)
+
+        cache, toks = run(cache)
+        jax.block_until_ready(toks)
+        t0 = time.perf_counter()
+        reps = 5
+        for _ in range(reps):
+            cache, toks = run(cache)
+        jax.block_until_ready(toks)
+        ms_step = 1e3 * (time.perf_counter() - t0) / reps / steps
+        weight_bytes = 2 * sum(
+            int(np.prod(a.shape)) for a in jax.tree.leaves(params)
+        ) - 2 * cfg.vocab_size * cfg.hidden_size  # the embedding is gathered
+        kv_bytes = (int(lens.sum()) * layers * 2 * cfg.num_kv_heads
+                    * cfg.head_dim * 2)
+        out[name] = {
+            "layers": layers, "slots": slots, "max_seq": s,
+            "ms_per_step": ms_step,
+            "floor_ms": 1e3 * (weight_bytes + kv_bytes) / HBM_BYTES_PER_S,
+            "memory_peak_bytes": (jax.devices()[0].memory_stats() or {}).get(
+                "peak_bytes_in_use")}
+        del params, cache
+    return out
+
+
+def main(argv: list[str]) -> int:
+    import jax
+
+    which = argv or ["kernel", "burst"]
+    out = {"device_kind": jax.devices()[0].device_kind,
+           "platform": jax.devices()[0].platform}
+    if out["platform"] != "tpu":
+        print(json.dumps({**out, "error": "needs a TPU"}))
+        return 1
+    if "kernel" in which:
+        out["kernel"] = bench_kernel()
+    if "burst" in which:
+        out["burst"] = bench_burst()
+    print(json.dumps(out))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

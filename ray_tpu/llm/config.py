@@ -57,7 +57,10 @@ class LLMConfig:
     # dispatch (lax.scan feeds each sampled token into the next step on
     # device). Amortizes the host→device dispatch + token-fetch roundtrip —
     # a large per-token cost when the model is small — across D tokens; 1
-    # restores step-per-dispatch. The
+    # restores step-per-dispatch. A step reads the weights and the live
+    # K/V blocks of the decoding slots once and writes one row a slot in
+    # place (ops/decode_attention.py), so a burst costs D such steps and
+    # nothing that grows with max_seq_len or with idle slots. The
     # burst length adapts down (powers of two) near request token budgets,
     # so only {8,4,2} shapes ever compile. Sampling inside a burst supports
     # temperature/top-p; a top-k request in the batch falls back to

@@ -9,7 +9,12 @@ wrapper:
   the KV cache is a dense [layers, slots, kv_heads, max_seq, head_dim]
   pool; a sequence owns one slot for its lifetime — slot admission is the
   scheduling unit, like vLLM's paged blocks but shaped for XLA/TPU (no
-  dynamic page tables; dynamic_update_slice writes, masked reads).
+  dynamic page tables). Prefill writes a chunk with dynamic_update_slice
+  and reads its slot's line under a mask. A decode step writes its one
+  row a slot and layer in place and reads only the live blocks of each
+  line, grouped over the query heads of a KV head
+  (ops/decode_attention.py): the stacked cache is loop carry, and no
+  operation of a decode program has a whole layer of it as operand.
 - **Continuous batching**: every engine tick admits waiting requests into
   free slots (bucketed prefill) and then decodes ALL active slots in one
   batched jitted step — new requests join mid-flight without stalling
@@ -48,6 +53,12 @@ from ray_tpu.llm.config import LLMConfig, SamplingParams
 from ray_tpu.util import tracing
 from ray_tpu.llm.tokenizer import get_tokenizer
 from ray_tpu.models.llama import LlamaConfig, init_params, param_logical_axes
+from ray_tpu.ops.decode_attention import (
+    decode_attention,
+    decode_kv_block,
+    kv_positions_read,
+    kv_row_write,
+)
 from ray_tpu.ops.kernels import KernelMesh
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -238,51 +249,42 @@ def _multi_token_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
 
     tokens: [B, K]; positions0: [B] — tokens[:, j] is written at
     positions0 + j (contiguous); query j attends kv through its own
-    position. Returns (cache, logits [B, K, V])."""
+    position. Returns (cache, logits [B, K, V]).
+
+    The stacked cache rides the layer loop as carry, not as scan xs/ys: a
+    layer writes its K new rows of each slot in place and
+    ops/decode_attention.py reads the layer's live blocks straight out of
+    the stack, so no operation of the program has a whole layer of the
+    cache, or the whole cache, as operand or result. A slot with
+    ``write_mask`` false has length 0: nothing of its line is read, and its
+    logits mean nothing."""
     b, k = tokens.shape
-    max_seq = cache["k"].shape[3]
+    num_layers = cache["k"].shape[0]
     x = params["embed_tokens"][tokens]  # [B, K, H]
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                 cfg.rope_scaling)
-    n_rep = cfg.num_heads // cfg.num_kv_heads
     positions = positions0[:, None] + jnp.arange(k)[None, :]  # [B, K]
-    kv_mask = (jnp.arange(max_seq)[None, None, :]
-               <= positions[:, :, None])[:, None]  # [B, 1, K, S]
+    lengths = jnp.where(write_mask, positions0 + k, 0)
 
-    def write(cache_l, new, p0):
-        # cache_l: [B, Hkv, S, D]; new: [B, Hkv, K, D]; p0: [B]
-        # Slice-merge-write touches only the K-row window: a full-line
-        # jnp.where(en, updated, c) would read+write the whole [Hkv, S, D]
-        # cache line per slot per layer on every decode step.
-        def upd(c, n, p, en):
-            window = lax.dynamic_slice(
-                c, (0, p, 0), (c.shape[0], n.shape[1], c.shape[2]))
-            merged = jnp.where(en, n.astype(c.dtype), window)
-            return lax.dynamic_update_slice(c, merged, (0, p, 0))
-        return jax.vmap(upd)(cache_l, new, p0, write_mask)
-
-    def body(x, scanned):
-        lp, k_l, v_l = scanned
+    def body(carry, scanned):
+        x, k_all, v_all = carry
+        lp, layer = scanned
         xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
         q, kk, v = _project_qkv(cfg, lp, xn, b, k)
         q = apply_rope(q, positions, inv_freq)
         kk = apply_rope(kk, positions, inv_freq)
-        k_l = write(k_l, kk, positions0)
-        v_l = write(v_l, v, positions0)
-        kr = _repeat_kv(k_l.astype(x.dtype), n_rep)  # [B, H, S, D]
-        vr = _repeat_kv(v_l.astype(x.dtype), n_rep)
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, kr).astype(jnp.float32)
-        scores = scores / np.sqrt(cfg.head_dim)
-        scores = scores + jnp.where(kv_mask, 0.0, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        o = jnp.einsum("bhqk,bhkd->bhqd", probs, vr)
+        k_all, v_all = kv_row_write(k_all, v_all, kk, v, layer, positions0,
+                                    write_mask, kmesh=kmesh)
+        o = decode_attention(q, k_all, v_all, layer, lengths, positions0,
+                             kmesh=kmesh)
         o = o.transpose(0, 2, 1, 3).reshape(b, k, -1)
         x = x + (o @ lp["wo"]).astype(x.dtype)
         x = _mlp(cfg, lp, x, kmesh)
-        return x, (k_l, v_l)
+        return (x, k_all, v_all), None
 
-    x, (new_k, new_v) = lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
+    (x, new_k, new_v), _ = lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(num_layers)))
     logits = _lm_head(cfg, params, x, kmesh)  # [B, K, V]
     return {"k": new_k, "v": new_v}, logits
 
@@ -489,14 +491,11 @@ def _multi_token_impl_blocked(cfg: LlamaConfig, params, cache, tables,
     scatter out of bounds and are dropped."""
     b, k = tokens.shape
     _, nb, _, bs, _ = cache["k"].shape
-    mb = tables.shape[1]
     x = params["embed_tokens"][tokens]  # [B, K, H]
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                 cfg.rope_scaling)
-    n_rep = cfg.num_heads // cfg.num_kv_heads
     positions = positions0[:, None] + jnp.arange(k)[None, :]  # [B, K]
-    kv_mask = (jnp.arange(mb * bs)[None, None, :]
-               <= positions[:, :, None])[:, None]  # [B, 1, K, S]
+    lengths = jnp.where(write_mask, positions0 + k, 0)
     # Per-token pool coordinates; masked writes target block NB → dropped.
     blk = jnp.take_along_axis(tables, positions // bs, axis=1)  # [B, K]
     blk = jnp.where(write_mask[:, None], blk, nb)
@@ -515,13 +514,12 @@ def _multi_token_impl_blocked(cfg: LlamaConfig, params, cache, tables,
         kk = apply_rope(kk, positions, inv_freq)
         k_l = write(k_l, kk)
         v_l = write(v_l, v)
-        kr = _repeat_kv(_gather_batch_kv(k_l, tables, x.dtype), n_rep)
-        vr = _repeat_kv(_gather_batch_kv(v_l, tables, x.dtype), n_rep)
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, kr).astype(jnp.float32)
-        scores = scores / np.sqrt(cfg.head_dim)
-        scores = scores + jnp.where(kv_mask, 0.0, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        o = jnp.einsum("bhqk,bhkd->bhqd", probs, vr)
+        # The gathered lines are a stack of one layer to the same op the
+        # dense path reads its cache with (a table-aware kernel is D2's).
+        o = decode_attention(
+            q, _gather_batch_kv(k_l, tables, x.dtype)[None],
+            _gather_batch_kv(v_l, tables, x.dtype)[None], 0, lengths,
+            positions0, kmesh=kmesh)
         o = o.transpose(0, 2, 1, 3).reshape(b, k, -1)
         x = x + (o @ lp["wo"]).astype(x.dtype)
         x = _mlp(cfg, lp, x, kmesh)
@@ -709,7 +707,11 @@ class LLMEngine:
         # the tokens they gave that a request still wanted (so not its
         # first, which prefill gives); queue_wait_s sums admit - submit
         # over `admitted`, first_token_wait_s first token - admit over
-        # `first_tokens`.
+        # `first_tokens`. kv_positions_read / kv_positions_reserved say how
+        # far the decode kernel's skipping of dead blocks engages: per
+        # decode step (one kernel call a layer; a verify step is one),
+        # the positions of every decoding slot's line the kernel fetches
+        # (its length rounded up to the kernel's block) over slots x max_seq.
         self.ticks = 0
         self.admitted = 0
         self.finished = 0
@@ -718,6 +720,11 @@ class LLMEngine:
         self.decode_dispatches = 0
         self.decode_steps = 0
         self.decode_tokens = 0
+        self.kv_positions_read = 0
+        self.kv_positions_reserved = 0
+        self._kv_block = decode_kv_block(
+            self.max_seq, self.model_cfg.head_dim,
+            self.model_cfg.jnp_dtype.itemsize)
         self.first_tokens = 0
         self.queue_wait_s = 0.0
         self.first_token_wait_s = 0.0
@@ -1045,6 +1052,8 @@ class LLMEngine:
                "decode_dispatches": self.decode_dispatches,
                "decode_steps": self.decode_steps,
                "decode_tokens": self.decode_tokens,
+               "kv_positions_read": self.kv_positions_read,
+               "kv_positions_reserved": self.kv_positions_reserved,
                "first_tokens": self.first_tokens,
                "queue_wait_s": self.queue_wait_s,
                "first_token_wait_s": self.first_token_wait_s}
@@ -1686,6 +1695,7 @@ class LLMEngine:
             return False
         self.decode_dispatches += 1
         self.decode_steps += 1
+        self._count_kv_positions(positions, write, 1)
         try:
             reqs = [active.get(s) for s in range(self.max_slots)]
             with tracing.phase("engine.fetch", which="step"):
@@ -1712,6 +1722,18 @@ class LLMEngine:
             positions[slot] = req.next_pos
             write[slot] = True
         return tokens, positions, write
+
+    def _count_kv_positions(self, positions, write, steps: int,
+                            k: int = 1) -> None:
+        """One decode dispatch's part of kv_positions_read/_reserved:
+        ``steps`` kernel calls a layer, call i over lines of
+        positions + i + k where ``write``, 0 elsewhere."""
+        lengths = (positions + k)[None, :] + np.arange(steps)[:, None]
+        lengths = np.where(write[None, :], np.minimum(lengths, self.max_seq),
+                           0)
+        self.kv_positions_read += int(
+            kv_positions_read(lengths, self._kv_block).sum())
+        self.kv_positions_reserved += steps * self.max_slots * self.max_seq
 
     def _decode_burst(self, active: dict[int, GenerationRequest],
                       burst: int) -> bool:
@@ -1753,6 +1775,7 @@ class LLMEngine:
                         kmesh=self.kmesh)
             self.decode_dispatches += 1
             self.decode_steps += burst
+            self._count_kv_positions(positions, write, burst)
             chain = self._should_chain(active, burst)
             if chain and self.blocked:
                 # A chain must never evict someone: skip it unless every
@@ -1781,6 +1804,7 @@ class LLMEngine:
                             kmesh=self.kmesh)
                 self.decode_dispatches += 1
                 self.decode_steps += burst
+                self._count_kv_positions(positions + burst, write, burst)
                 self._pending_burst = (dict(active), burst, toks2)
             with tracing.phase("engine.fetch", which="burst"):
                 toks = np.asarray(toks)  # [burst, max_slots]
@@ -1912,6 +1936,7 @@ class LLMEngine:
         # The verify step computes k + 1 positions of every slot.
         self.decode_dispatches += 1
         self.decode_steps += k + 1
+        self._count_kv_positions(pos0, write, 1, k + 1)
         with tracing.phase("engine.emit") as ph:
             before = self.decode_tokens
             for slot, req in active.items():

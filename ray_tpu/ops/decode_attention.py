@@ -1,0 +1,352 @@
+"""Decode attention: a few query rows per slot against a stacked KV cache.
+
+A decode step gives every slot K new tokens (1, or ``speculative_tokens +
+1`` under verification) that attend to that slot's cache line. The line is
+long (``max_seq``), mostly not yet written, and shared by the
+``num_heads // num_kv_heads`` query heads of each KV head. So the op is
+
+- **grouped**: the G query heads of one KV head times the K tokens are one
+  small tile of G*K rows against a single read of that head's keys and
+  values; no ``[B, Hkv, G, S, D]`` copy exists;
+- **length-aware**: it takes a per-slot length (0: an empty or prefilling
+  slot). Blocks of the sequence axis at or past the length are neither
+  fetched (the index map clamps to the last live block, so the pipeline
+  keeps the buffer it has) nor computed. Lengths, positions and the layer
+  are run-time scalars (scalar prefetch): one program serves every length;
+- **in place**: it receives the whole stacked cache ``[L, B, Hkv, S, D]``
+  and the layer index, and its block specs index the layer. A caller that
+  handed it ``cache[l]`` would make XLA materialise that slice on every
+  layer of every step.
+
+Scores, the running maximum and sum, and the PV accumulation are float32;
+the operands stay in the cache's dtype. Query j of a slot sees key positions
+``<= positions0 + j`` and ``< length``; a row that sees nothing (an empty
+slot) gives zeros.
+
+``kv_row_write`` is the other half of the convention: the step's K new rows
+of each slot go into the same stack in place, through a kernel too, because
+XLA would re-lay the whole cache out around a row update of its own.
+
+Both come in the three implementations of ops/kernels.py: the Mosaic kernel
+on a TPU, the same body through the Pallas interpreter for tests, and a jnp
+reference elsewhere.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.ops.kernels import KernelMesh, kernel_backend
+
+NEG_INF = -1e30
+
+# One block of one KV head's keys, in bytes. A grid step that computes costs
+# about half a microsecond whatever its block, so long lines want long
+# blocks, and a block read past a line's end costs little beside that: on
+# the v5e, at Mistral-7B widths, 16 lines of 3,200 took 3.2 ms in blocks of
+# 640 and 5.0 ms in blocks of 128, and 32 of 2,048 took the same 2.5 to 2.7
+# ms in blocks of 128, 256 and 512 (devbench/decode_attention_bench.py).
+# With head_dim 128 in bf16 the cap is 640 positions.
+_HEAD_BLOCK_BYTES = 160 * 1024
+
+
+def decode_kv_block(max_seq: int, head_dim: int, itemsize: int = 2) -> int:
+    """Positions per block of the sequence axis: the largest multiple of 128
+    that divides ``max_seq`` and keeps one head's block within
+    ``_HEAD_BLOCK_BYTES`` (512 of 2,048, 640 of 3,200); the whole line where
+    no multiple of 128 divides it (tiny test caches). The scheduler's
+    ``kv_positions_read`` counter rounds lengths up with this function."""
+    cap = max(128, _HEAD_BLOCK_BYTES // (head_dim * itemsize))
+    fits = [b for b in range(128, min(cap, max_seq) + 1, 128)
+            if max_seq % b == 0]
+    return fits[-1] if fits else max_seq
+
+
+def _stack_spec(kmesh: KernelMesh) -> P:
+    """The stacked cache [L, B, Hkv, S, D]: slots over the batch axes, KV
+    heads over the head axis, like the [B, H, ...] operands beside it."""
+    return P(None, kmesh.batch or None, kmesh.heads, None, None)
+
+
+def kv_positions_read(lengths, block: int):
+    """Positions of each line the kernel fetches: the length rounded up to
+    whole blocks (numpy or jnp integers)."""
+    return -(-lengths // block) * block
+
+
+def decode_attention_reference(q, k_cache, v_cache, layer, lengths,
+                               positions0, sm_scale: float | None = None):
+    """Masked softmax over the whole line, grouped like the kernel (no
+    repeated K/V), float32 scores and accumulation."""
+    b, h, k, d = q.shape
+    hkv, s = k_cache.shape[2], k_cache.shape[3]
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
+    kl = lax.dynamic_index_in_dim(k_cache, layer, 0, keepdims=False)
+    vl = lax.dynamic_index_in_dim(v_cache, layer, 0, keepdims=False)
+    qg = q.reshape(b, hkv, (h // hkv) * k, d)
+    scores = jnp.einsum("bhrd,bhsd->bhrs", qg, kl.astype(q.dtype),
+                        preferred_element_type=jnp.float32) * scale
+    kpos = jnp.arange(s)[None, None, :]
+    qpos = positions0[:, None] + jnp.arange(k)[None, :]      # [B, K]
+    visible = ((kpos <= qpos[:, :, None])
+               & (kpos < lengths[:, None, None]))            # [B, K, S]
+    visible = jnp.tile(visible, (1, h // hkv, 1))[:, None]   # rows g*K + j
+    scores = jnp.where(visible, scores, NEG_INF)
+    p = jnp.where(visible,
+                  jnp.exp(scores - scores.max(-1, keepdims=True)), 0.0)
+    denom = jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+    out = jnp.einsum("bhrs,bhsd->bhrd", p.astype(q.dtype),
+                     vl.astype(q.dtype),
+                     preferred_element_type=jnp.float32) / denom
+    return out.astype(q.dtype).reshape(b, h, k, d)
+
+
+def _decode_attention_kernel(len_ref, pos_ref, layer_ref, q_ref, k_ref,
+                             v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                             block: int, k_tokens: int, sm_scale: float):
+    from jax.experimental import pallas as pl
+
+    del layer_ref  # read by the block specs' index maps
+    slot, blk = pl.program_id(0), pl.program_id(1)
+    length = len_ref[slot]
+    hkv, rows, _ = q_ref.shape
+
+    @pl.when(blk == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(blk * block < length)
+    def _():
+        kpos = blk * block + lax.broadcasted_iota(jnp.int32, (rows, block), 1)
+        # Row r of the tile is query head g, token j, r = g * K + j.
+        tok = lax.rem(lax.broadcasted_iota(jnp.int32, (rows, block), 0),
+                      k_tokens)
+        visible = (kpos <= pos_ref[slot] + tok) & (kpos < length)
+        for h in range(hkv):
+            s = lax.dot_general(q_ref[h], k_ref[h], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            s = jnp.where(visible, s * sm_scale, NEG_INF)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            # The select keeps a row with nothing visible yet at zero
+            # (exp(NEG_INF - NEG_INF) would be one).
+            p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = alpha * l_ref[h] + p.sum(axis=-1, keepdims=True)
+            acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
+                p.astype(v_ref.dtype), v_ref[h],
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+
+    @pl.when(blk == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
+            o_ref.dtype)
+
+
+def _decode_attention_pallas(q, k_cache, v_cache, layer, lengths, positions0,
+                             *, sm_scale: float, block: int | None = None):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, k, d = q.shape
+    hkv, s = k_cache.shape[2], k_cache.shape[3]
+    block = block or decode_kv_block(s, d, k_cache.dtype.itemsize)
+    if s % block:
+        raise ValueError(f"decode_attention: block {block} does not divide "
+                         f"the cache line of {s} positions")
+    # G*K rows a KV head, padded to whole sublane tiles of the operand dtype.
+    rows = (h // hkv) * k
+    tile = 32 // q.dtype.itemsize
+    rows_p = -(-rows // tile) * tile
+    qg = q.reshape(b, hkv, rows, d)
+    if rows_p != rows:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_p - rows), (0, 0)))
+
+    def kv_index(i, j, lens, pos, lyr):
+        last_live = jnp.maximum(pl.cdiv(lens[i], block) - 1, 0)
+        return (lyr[0], i, 0, jnp.minimum(j, last_live), 0)
+
+    def q_index(i, j, lens, pos, lyr):
+        return (i, 0, 0, 0)
+
+    kv_spec = pl.BlockSpec((None, None, hkv, block, d), kv_index)
+    q_spec = pl.BlockSpec((None, hkv, rows_p, d), q_index)
+    out = pl.pallas_call(
+        functools.partial(_decode_attention_kernel, block=block, k_tokens=k,
+                          sm_scale=sm_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, s // block),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((hkv, rows_p, 1), jnp.float32),
+                            pltpu.VMEM((hkv, rows_p, 1), jnp.float32),
+                            pltpu.VMEM((hkv, rows_p, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rows_p, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            # K and V blocks of every head, double-buffered, and as much
+            # again for what the body keeps; never under the default.
+            vmem_limit_bytes=max(
+                16 << 20, 8 * hkv * block * d * k_cache.dtype.itemsize)),
+        interpret=kernel_backend() == "interpret",
+        name="decode_attention",
+    )(jnp.minimum(lengths, s).astype(jnp.int32), positions0.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, k_cache, v_cache)
+    return out[:, :, :rows].reshape(b, h, k, d)
+
+
+def decode_attention(q, k_cache, v_cache, layer, lengths, positions0, *,
+                     sm_scale: float | None = None,
+                     kmesh: KernelMesh | None = None,
+                     block: int | None = None):
+    """q: [B, H, K, D] (K new tokens a slot, query head h of KV head
+    ``h // (H // Hkv)``); k_cache, v_cache: [L, B, Hkv, S, D], the new rows
+    already written; layer: int32 scalar; lengths, positions0: [B] int32.
+    Returns [B, H, K, D]. ``block`` overrides :func:`decode_kv_block`
+    (tests and the kernel's own benchmark). Under a mesh of several devices
+    pass its ``kmesh``: the kernel then runs on each device's heads."""
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    if kernel_backend() == "reference":
+        return decode_attention_reference(q, k_cache, v_cache, layer,
+                                          lengths, positions0, scale)
+    fn = functools.partial(_decode_attention_pallas, sm_scale=scale,
+                           block=block)
+    if kmesh is not None:
+        heads, cache = kmesh.heads_spec(4), _stack_spec(kmesh)
+        rows = kmesh.rows_spec(1)
+        fn = kmesh.shard(fn, in_specs=(heads, cache, cache, P(), rows, rows),
+                         out_specs=heads)
+    return fn(q, k_cache, v_cache, jnp.asarray(layer, jnp.int32), lengths,
+              positions0)
+
+
+def kv_row_write_reference(k_cache, v_cache, new_k, new_v, layer,
+                           positions0, write_mask):
+    b, _, k, _ = new_k.shape
+    s = k_cache.shape[3]
+    pos = positions0[:, None] + jnp.arange(k)[None, :]
+    pos = jnp.where(write_mask[:, None], pos, s)  # out of bounds: dropped
+    slots = jnp.arange(b)[:, None]
+
+    def put(stack, new):
+        rows = new.transpose(0, 2, 1, 3).astype(stack.dtype)  # [B, K, Hkv, D]
+        return stack.at[layer, slots, :, pos, :].set(rows, mode="drop")
+    return put(k_cache, new_k), put(v_cache, new_v)
+
+
+def _kv_window(max_seq: int) -> int:
+    """Rows of the window the write kernel reads, merges and writes back:
+    one packed tile of a 16-bit dtype (two of a 32-bit one)."""
+    return 16 if max_seq % 16 == 0 else max_seq
+
+
+def _window_index(p0, t, window: int, k_tokens: int):
+    """Window of grid step ``t`` of a slot whose rows start at ``p0``: the
+    one holding ``p0``, then (K > 1, rows across a boundary) the next; the
+    same window twice where the rows do not cross, so nothing moves on the
+    second step. A masked slot (negative ``p0``) visits window 0 and hits
+    no row."""
+    first = jnp.maximum(p0, 0) // window
+    last = jnp.maximum(p0 + k_tokens - 1, 0) // window
+    return jnp.minimum(first + t, last)
+
+
+def _kv_row_write_kernel(pos_ref, layer_ref, nk_ref, nv_ref, kw_ref, vw_ref,
+                         ko_ref, vo_ref, *, window: int, k_tokens: int):
+    from jax.experimental import pallas as pl
+
+    del layer_ref  # read by the block specs' index maps
+    p0 = pos_ref[pl.program_id(0)]
+    base = _window_index(p0, pl.program_id(1), window, k_tokens) * window
+    row = base + lax.broadcasted_iota(jnp.int32, kw_ref.shape, 1)
+    kw, vw = kw_ref[...], vw_ref[...]
+    for j in range(k_tokens):
+        hit = row == p0 + j
+        kw = jnp.where(hit, nk_ref[j], kw)
+        vw = jnp.where(hit, nv_ref[j], vw)
+    ko_ref[...] = kw
+    vo_ref[...] = vw
+
+
+def _kv_row_write_pallas(k_cache, v_cache, new_k, new_v, layer, positions0,
+                         write_mask):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, hkv, k, d = new_k.shape
+    s = k_cache.shape[3]
+    window = _kv_window(s)
+    if k > window:
+        raise ValueError(f"kv_row_write: {k} rows a slot exceed the window "
+                         f"of {window}")
+    steps = 1 if k == 1 else 2
+    # [B, K, Hkv, 1, D]: a row is a tile of its own, broadcast over a window.
+    rows = [n.astype(k_cache.dtype).transpose(0, 2, 1, 3)[:, :, :, None, :]
+            for n in (new_k, new_v)]
+    # A masked slot's rows sit at negative positions: no window row is hit.
+    # Positions are the engine's to keep inside the line; clamping the
+    # window is only so that a wrong one cannot index past the array.
+    pos = jnp.where(write_mask, positions0, -k).astype(jnp.int32)
+
+    def win_index(i, t, pos, lyr):
+        w = jnp.minimum(_window_index(pos[i], t, window, k),
+                        s // window - 1)
+        return (lyr[0], i, 0, w, 0)
+
+    def new_index(i, t, pos, lyr):
+        return (i, 0, 0, 0, 0)
+
+    win_spec = pl.BlockSpec((None, None, hkv, window, d), win_index)
+    new_spec = pl.BlockSpec((None, k, hkv, 1, d), new_index)
+    return pl.pallas_call(
+        functools.partial(_kv_row_write_kernel, window=window, k_tokens=k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, steps),
+            in_specs=[new_spec, new_spec, win_spec, win_spec],
+            out_specs=[win_spec, win_spec]),
+        out_shape=[jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
+                   jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)],
+        # Operands count the scalar-prefetch arguments: 4 and 5 are the
+        # caches, written in place.
+        input_output_aliases={4: 0, 5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=kernel_backend() == "interpret",
+        name="kv_row_write",
+    )(pos, jnp.asarray(layer, jnp.int32).reshape(1), *rows, k_cache, v_cache)
+
+
+def kv_row_write(k_cache, v_cache, new_k, new_v, layer, positions0,
+                 write_mask, *, kmesh: KernelMesh | None = None):
+    """Write the K new rows of every slot into layer ``layer`` of the
+    stacked caches, in place: new_k, new_v [B, Hkv, K, D] go to
+    ``[layer, b, :, positions0[b] : positions0[b] + K]`` where
+    ``write_mask[b]``; a masked slot's line is left as it is. Returns the
+    caches.
+
+    A kernel and not a dynamic_update_slice: XLA lays a cache it updates by
+    rows out position-major, and then copies the whole cache into the
+    layout the attention kernel reads, on every layer of every step."""
+    if kernel_backend() == "reference":
+        return kv_row_write_reference(k_cache, v_cache, new_k, new_v, layer,
+                                      positions0, write_mask)
+    fn = _kv_row_write_pallas
+    if kmesh is not None:
+        heads, cache = kmesh.heads_spec(4), _stack_spec(kmesh)
+        rows = kmesh.rows_spec(1)
+        fn = kmesh.shard(
+            fn, in_specs=(cache, cache, heads, heads, P(), rows, rows),
+            out_specs=(cache, cache))
+    return fn(k_cache, v_cache, new_k, new_v, jnp.asarray(layer, jnp.int32),
+              positions0, write_mask)

@@ -840,3 +840,160 @@ def test_engine_loads_hf_checkpoint_dir(tmp_path):
         assert 0 < len(r.token_ids) <= 5
     finally:
         eng.shutdown()
+
+
+class TestDecodeKernelBody:
+    """The decode programs with ops/decode_attention.py's kernel bodies run
+    through the Pallas interpreter (the CPU default is their jnp reference).
+    A config of its own, so that no program traced elsewhere is reused."""
+
+    @pytest.fixture(scope="class")
+    def model(self):
+        from dataclasses import replace
+
+        cfg = replace(LlamaConfig.tiny(), vocab_size=264)
+        return cfg, init_params(cfg, jax.random.PRNGKey(0))
+
+    @pytest.fixture(autouse=True)
+    def _interpret(self):
+        from ray_tpu.ops.kernels import force_kernel_backend
+
+        with force_kernel_backend("interpret"):
+            yield
+
+    def _prefilled(self, cfg, params, prompt, slot, max_seq=32):
+        cache = init_kv_cache(cfg, max_slots=2, max_seq=max_seq)
+        toks = np.zeros((16,), np.int32)
+        toks[:len(prompt)] = prompt
+        return prefill(cfg, params, cache, jnp.asarray(toks),
+                       jnp.int32(len(prompt)), jnp.int32(slot))
+
+    def test_prefill_then_kernel_decode_matches_full_forward(self, model):
+        cfg, params = model
+        prompt = np.array([5, 7, 11, 13], np.int32)
+        extra = np.array([17, 19, 23], np.int32)
+        ref = np.asarray(forward(
+            cfg, params, jnp.asarray(np.concatenate([prompt, extra]))[None],
+            attn_impl="blockwise", remat=False))[0]
+        cache, _ = self._prefilled(cfg, params, prompt, slot=1)
+        for i, t in enumerate(extra):
+            cache, logits = decode_step(
+                cfg, params, cache, jnp.asarray([0, t], np.int32),
+                jnp.asarray([0, 4 + i], np.int32),
+                jnp.asarray([False, True]))
+            np.testing.assert_allclose(np.asarray(logits[1]), ref[4 + i],
+                                       rtol=2e-4, atol=2e-4)
+
+    def test_burst_of_eight_is_eight_single_steps(self, model):
+        from ray_tpu.llm.engine import decode_burst
+
+        cfg, params = model
+        prompt = np.array([5, 7, 11, 13, 17], np.int32)
+        c1, last = self._prefilled(cfg, params, prompt, slot=0, max_seq=64)
+        c2 = jax.tree.map(jnp.copy, c1)
+        tok0 = int(np.argmax(np.asarray(last)))
+        write = jnp.asarray([True, False])
+        c2, burst = decode_burst(
+            cfg, params, c2, jnp.asarray([tok0, 0], np.int32),
+            jnp.asarray([len(prompt), 0], np.int32), write,
+            jnp.zeros((2,), jnp.float32), jnp.ones((2,), jnp.float32),
+            jax.random.PRNGKey(0), 8, False)
+        singles, tok = [], tok0
+        for j in range(8):
+            c1, logits = decode_step(
+                cfg, params, c1, jnp.asarray([tok, 0], np.int32),
+                jnp.asarray([len(prompt) + j, 0], np.int32), write)
+            tok = int(np.argmax(np.asarray(logits[0])))
+            singles.append(tok)
+        assert np.asarray(burst)[:, 0].tolist() == singles
+        np.testing.assert_allclose(np.asarray(c1["k"]), np.asarray(c2["k"]),
+                                   rtol=1e-5, atol=1e-5)
+        # ... and the whole sequence is what one full forward picks.
+        seq = np.concatenate([prompt, [tok0], singles[:-1]]).astype(np.int32)
+        ref = np.asarray(forward(cfg, params, jnp.asarray(seq)[None],
+                                 attn_impl="blockwise", remat=False))[0]
+        assert np.argmax(ref[len(prompt):], -1).tolist() == singles
+
+    def test_verify_of_three_is_three_single_steps(self, model):
+        from ray_tpu.llm.engine import spec_verify_step
+
+        cfg, params = model
+        prompt = np.array([5, 7, 11, 13], np.int32)
+        toks = np.array([17, 19, 23], np.int32)
+        c1, _ = self._prefilled(cfg, params, prompt, slot=0)
+        c2 = jax.tree.map(jnp.copy, c1)
+        write = jnp.asarray([True, False])
+        seq_logits = []
+        for j, t in enumerate(toks):
+            c1, lg = decode_step(cfg, params, c1,
+                                 jnp.asarray([t, 0], np.int32),
+                                 jnp.asarray([len(prompt) + j, 0], np.int32),
+                                 write)
+            seq_logits.append(np.asarray(lg[0]))
+        c2, logits = spec_verify_step(
+            cfg, params, c2,
+            jnp.asarray(np.stack([toks, np.zeros_like(toks)])),
+            jnp.asarray([len(prompt), 0], np.int32), write)
+        np.testing.assert_allclose(np.asarray(logits[0]),
+                                   np.stack(seq_logits), rtol=2e-4, atol=2e-4)
+        np.testing.assert_array_equal(np.asarray(c1["k"]),
+                                      np.asarray(c2["k"]))
+
+    def test_engine_burst_matches_single_steps(self):
+        """Through the scheduler: bursts of 8 (and the 4, 2 and single
+        steps a token budget ends on) give the tokens single steps give."""
+        from dataclasses import replace
+
+        model = replace(LlamaConfig.tiny(), vocab_size=512,
+                        rope_theta=20000.0)
+        engines = [LLMEngine(LLMConfig(model=model, max_num_seqs=2,
+                                       max_seq_len=64, decode_burst=d))
+                   for d in (1, 8)]
+        try:
+            for prompt, n in [("kernel body", 15), ("x", 3)]:
+                r1, r8 = (e.generate(prompt, SamplingParams(max_tokens=n))
+                          for e in engines)
+                assert r1.token_ids == r8.token_ids, (prompt, n)
+                assert len(r8.token_ids) == n
+        finally:
+            for e in engines:
+                e.shutdown()
+
+
+def test_kv_position_counters_follow_the_block_formula():
+    """kv_positions_read is what the decode kernel fetches (lengths rounded
+    up to its block, nothing for an idle slot), kv_positions_reserved what
+    a kernel blind to lengths would."""
+    from ray_tpu.ops.decode_attention import decode_kv_block
+
+    cfg = LLMConfig(model="tiny", max_num_seqs=2, max_seq_len=64,
+                    decode_burst=4, decode_pipeline=False)
+    eng = LLMEngine(cfg)
+    try:
+        assert eng.stats()["kv_positions_read"] == 0
+        eng.generate("count me", SamplingParams(max_tokens=9))
+        st = eng.stats()
+        block = decode_kv_block(64, eng.model_cfg.head_dim,
+                                eng.model_cfg.jnp_dtype.itemsize)
+        assert block == 64  # one block a line at this size
+        # One request in two slots: every step fetches its line's one block.
+        assert st["kv_positions_read"] == st["decode_steps"] * block
+        assert st["kv_positions_reserved"] == st["decode_steps"] * 2 * 64
+        assert 0 < st["kv_positions_read"] <= st["kv_positions_reserved"]
+
+        # Scripted, with blocks shorter than the line: a burst of 4 from
+        # positions 125 and 300 (slot 2 idle), in blocks of 128 of 512.
+        eng.max_seq, eng.max_slots, eng._kv_block = 512, 3, 128
+        before = (eng.kv_positions_read, eng.kv_positions_reserved)
+        eng._count_kv_positions(np.array([125, 300, 0]),
+                                np.array([True, True, False]), steps=4)
+        # lengths 126..129 -> 128, 128, 128, 256; 301..304 -> 384 each.
+        assert eng.kv_positions_read - before[0] == 128 * 3 + 256 + 384 * 4
+        assert eng.kv_positions_reserved - before[1] == 4 * 3 * 512
+        # A verify step of 5 tokens is one kernel call over position + 5.
+        eng._count_kv_positions(np.array([125, 300, 0]),
+                                np.array([True, False, False]), steps=1, k=5)
+        assert eng.kv_positions_read - before[0] == (
+            128 * 3 + 256 + 384 * 4 + 256)
+    finally:
+        eng.shutdown()

@@ -451,3 +451,106 @@ class TestRingFlashChunk:
             b = np.asarray(b, np.float32)
             denom = max(np.abs(b).max(), 1e-9)
             assert np.abs(a - b).max() / denom < 3e-2, name
+
+
+# ---------------------------------------------------------- decode attention
+
+DECODE_BLOCK = 128
+
+
+def _plain_decode_attention(q, kc, vc, layer, lengths, pos0):
+    """float32 masked softmax over the full line, K/V repeated per query
+    head: what the grouped, length-aware op has to equal."""
+    b, h, k, d = q.shape
+    hkv, s = kc.shape[2], kc.shape[3]
+    kl = jnp.repeat(kc[layer].astype(jnp.float32), h // hkv, axis=1)
+    vl = jnp.repeat(vc[layer].astype(jnp.float32), h // hkv, axis=1)
+    scores = jnp.einsum("bhkd,bhsd->bhks", q.astype(jnp.float32), kl,
+                        precision="highest") / np.sqrt(d)
+    kpos = jnp.arange(s)[None, None, :]
+    qpos = (pos0[:, None] + jnp.arange(k))[:, :, None]
+    visible = ((kpos <= qpos) & (kpos < lengths[:, None, None]))[:, None]
+    scores = jnp.where(visible, scores, -jnp.inf)
+    top = jnp.max(scores, -1, keepdims=True)
+    p = jnp.where(visible, jnp.exp(scores - jnp.where(
+        jnp.isfinite(top), top, 0.0)), 0.0)
+    p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+    return jnp.einsum("bhks,bhsd->bhkd", p, vl, precision="highest")
+
+
+@pytest.mark.parametrize("backend", ["interpret", "reference"])
+@pytest.mark.parametrize("layer", [0, 1])
+@pytest.mark.parametrize("s", [256, 384])  # 384: no power of two, as 3,200
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("k_tokens", [1, 5])
+def test_decode_attention_matches_plain_softmax(k_tokens, group, s, layer,
+                                                backend):
+    from ray_tpu.ops.decode_attention import decode_attention
+
+    layers, hkv, d = 2, 2, 64
+    # An empty slot beside a full one, and every length around a block edge.
+    lengths = np.array([0, s, 1, DECODE_BLOCK - 1, DECODE_BLOCK,
+                        DECODE_BLOCK + 1, k_tokens], np.int32)
+    b = len(lengths)
+    keys = jax.random.split(jax.random.PRNGKey(k_tokens * 7 + group), 3)
+    q = jax.random.normal(keys[0], (b, hkv * group, k_tokens, d),
+                          jnp.bfloat16)
+    live = (np.arange(s)[None, :] < lengths[:, None])[None, :, None, :, None]
+    # Rows past the length hold large garbage: a block read by mistake, or
+    # the wrong layer of the stack, shows.
+    kc = jnp.where(live, jax.random.normal(
+        keys[1], (layers, b, hkv, s, d)), 3e4).astype(jnp.bfloat16)
+    vc = jnp.where(live, jax.random.normal(
+        keys[2], (layers, b, hkv, s, d)), -3e4).astype(jnp.bfloat16)
+    pos0 = jnp.asarray(lengths - k_tokens)
+    want = _plain_decode_attention(q, kc, vc, layer, jnp.asarray(lengths),
+                                   pos0)
+    with force_kernel_backend(backend):
+        got = decode_attention(q, kc, vc, layer, jnp.asarray(lengths), pos0,
+                               block=DECODE_BLOCK)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    # bf16 probabilities and output: 2^-8 relative on values of order one.
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), atol=3e-2)
+    assert not np.asarray(got[0], np.float32).any()  # the empty slot
+
+
+@pytest.mark.parametrize("backend", ["interpret", "reference"])
+@pytest.mark.parametrize("k_tokens", [1, 5])
+def test_kv_row_write_touches_only_its_rows(k_tokens, backend):
+    from ray_tpu.ops.decode_attention import kv_row_write
+
+    layers, hkv, s, d = 2, 2, 64, 64
+    # Window edges (16 rows), the line's end, and a masked slot.
+    pos = np.array([0, 13, 15, 16, s - k_tokens, 30], np.int32)
+    mask = np.array([True, True, True, True, True, False])
+    b = len(pos)
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    kc = jax.random.normal(keys[0], (layers, b, hkv, s, d), jnp.bfloat16)
+    vc = jax.random.normal(keys[1], (layers, b, hkv, s, d), jnp.bfloat16)
+    nk = jax.random.normal(keys[2], (b, hkv, k_tokens, d), jnp.bfloat16)
+    nv = jax.random.normal(keys[3], (b, hkv, k_tokens, d), jnp.bfloat16)
+    want_k, want_v = np.array(kc), np.array(vc)
+    for i in range(b):
+        if mask[i]:
+            want_k[1, i, :, pos[i]:pos[i] + k_tokens] = np.asarray(nk[i])
+            want_v[1, i, :, pos[i]:pos[i] + k_tokens] = np.asarray(nv[i])
+    with force_kernel_backend(backend):
+        got_k, got_v = jax.jit(kv_row_write)(
+            kc, vc, nk, nv, 1, jnp.asarray(pos), jnp.asarray(mask))
+    np.testing.assert_array_equal(np.asarray(got_k), want_k)
+    np.testing.assert_array_equal(np.asarray(got_v), want_v)
+
+
+def test_decode_kv_block_divides_the_serving_lines():
+    from ray_tpu.ops.decode_attention import (
+        decode_kv_block,
+        kv_positions_read,
+    )
+
+    for s in (2048, 3200, 4096, 256, 384):
+        block = decode_kv_block(s, 128)
+        assert s % block == 0 and block % 128 == 0, (s, block)
+    assert decode_kv_block(64, 16, 4) == 64  # no multiple of 128: one block
+    got = kv_positions_read(np.array([0, 1, 512, 513, 2048]), 512)
+    assert got.tolist() == [0, 512, 512, 1024, 2048]

@@ -195,10 +195,114 @@ def test_engine_programs_partition_over_tensor_parallel_chips(mosaic):
         arg((slots,), jnp.bool_), arg((slots,), jnp.float32),
         arg((slots,), jnp.float32), arg((2,), jnp.uint32), 4, False,
         kmesh=kmesh).compile().as_text()
+    # attn_norm, mlp_norm, final_norm; decode also writes its rows and
+    # attends through ops/decode_attention.py, each on its shard's heads.
+    assert prefill.count(MOSAIC) == 3 and decode.count(MOSAIC) == 5
     for text in (prefill, decode):
         assert "num_partitions=4" in text
-        assert text.count(MOSAIC) == 3  # attn_norm, mlp_norm, final_norm
         # Activations are replicated over tp; only reductions cross chips
         # (and the sampled tokens' few bytes).
         assert all(op == "all-reduce" or n <= 64
                    for (op, n) in _collectives(text, 4))
+
+
+# Mistral-7B widths, two layers: the decode program of the two serving cells.
+MISTRAL = LlamaConfig(vocab_size=32768, hidden_size=4096,
+                      intermediate_size=14336, num_layers=2, num_heads=32,
+                      num_kv_heads=8, head_dim=128, max_seq_len=4096,
+                      dtype="bfloat16", tie_embeddings=False, rope_theta=1e6)
+
+
+def _decode_burst_compiled(mesh, kmesh, slots, max_seq, steps=8):
+    from ray_tpu.llm import engine
+
+    repl = NamedSharding(mesh, P())
+    params = _sds(
+        jax.eval_shape(partial(init_params, MISTRAL), jax.random.PRNGKey(0)),
+        tree_shardings(mesh, param_logical_axes(MISTRAL)))
+    cache = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=NamedSharding(mesh, P(None, None, "tp"))),
+        jax.eval_shape(partial(engine.init_kv_cache, MISTRAL, slots,
+                               max_seq)))
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
+
+    return engine.decode_burst.lower(
+        MISTRAL, params, cache, arg((slots,)), arg((slots,)),
+        arg((slots,), jnp.bool_), arg((slots,), jnp.float32),
+        arg((slots,), jnp.float32), arg((2,), jnp.uint32), steps, False,
+        kmesh=kmesh).compile()
+
+
+def _opcodes_with_shape(text: str, shape: str) -> set[str]:
+    """Opcodes of the instructions whose result (or a fused computation's
+    parameter) has ``shape``; compiled HLO prints operands by name only."""
+    import re
+
+    ops = set()
+    for line in text.splitlines():
+        if shape in line:
+            m = re.search(r"\s([a-z][a-z-]*)\(", line.split(" = ", 1)[-1])
+            if m:
+                ops.add(m.group(1))
+    return ops
+
+
+@pytest.mark.parametrize("slots,max_seq", [(32, 2048), (16, 3200)])
+def test_decode_burst_moves_no_whole_cache_at_mistral_widths(mosaic, slots,
+                                                             max_seq):
+    compiled = _decode_burst_compiled(
+        build_mesh(MeshSpec(), mosaic[:1]), None, slots, max_seq)
+    text = compiled.as_text()
+    assert text.count(MOSAIC) == 5
+    assert '"decode_attention"' in text and '"kv_row_write"' in text
+    hkv, d = MISTRAL.num_kv_heads, MISTRAL.head_dim
+    group = MISTRAL.num_heads // hkv
+    # No K/V repeated over the query heads of a group.
+    assert f"[{slots},{hkv},{group},{max_seq},{d}]" not in text
+    # The stacked cache and a layer of it only pass through: program
+    # arguments, loop carries, and the two kernels' in-place operands.
+    passing = {"parameter", "get-tuple-element", "tuple", "while",
+               "custom-call", "bitcast"}
+    for shape in (f"[{MISTRAL.num_layers},{slots},{hkv},{max_seq},{d}]",
+                  f"[{slots},{hkv},{max_seq},{d}]"):
+        assert _opcodes_with_shape(text, shape) <= passing, shape
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_decode_burst_partitions_at_mistral_widths(mosaic):
+    mesh = build_mesh(MeshSpec(tp=2), mosaic[:2])
+    text = _decode_burst_compiled(mesh, kernel_mesh(mesh), 32, 2048).as_text()
+    assert "num_partitions=2" in text and text.count(MOSAIC) == 5
+    assert all(op == "all-reduce" or n <= 256
+               for (op, n) in _collectives(text, 2))
+
+
+def test_decode_kernels_compile_for_speculative_verify(mosaic):
+    """K = 5 rows a slot (``speculative_tokens + 1``): 20 query rows a KV
+    head, and a write that may cross a 16-row window."""
+    from ray_tpu.ops.decode_attention import decode_attention, kv_row_write
+
+    dev = NamedSharding(build_mesh(MeshSpec(), mosaic[:1]), P())
+    slots, k, s = 32, 5, 2048
+    hkv, d, h = MISTRAL.num_kv_heads, MISTRAL.head_dim, MISTRAL.num_heads
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    def step(q, kc, vc, nk, nv, layer, pos, write):
+        kc, vc = kv_row_write(kc, vc, nk, nv, layer, pos, write)
+        lengths = jnp.where(write, pos + k, 0)
+        return kc, vc, decode_attention(q, kc, vc, layer, lengths, pos)
+
+    cache = sds((2, slots, hkv, s, d))
+    text = jax.jit(step, donate_argnums=(1, 2)).lower(
+        sds((slots, h, k, d)), cache, cache, sds((slots, hkv, k, d)),
+        sds((slots, hkv, k, d)), sds((), jnp.int32),
+        sds((slots,), jnp.int32), sds((slots,), jnp.bool_)).compile().as_text()
+    assert text.count(MOSAIC) == 2
+    assert _opcodes_with_shape(text, f"[2,{slots},{hkv},{s},{d}]") <= {
+        "parameter", "get-tuple-element", "tuple", "custom-call", "bitcast"}
