@@ -67,6 +67,22 @@ def decode_step_bytes(c: dict, layers: int, live_kv_tokens: float,
             + live_kv_tokens * kv_bytes_per_token(c, layers, dtype_bytes))
 
 
+def decode_attention_bytes(c: dict, layers: int, positions: float,
+                           dtype_bytes: int = 2) -> float:
+    """Bytes ``ops/decode_attention.py``'s kernel fetches from HBM for
+    ``positions`` cached positions, summed over its one call a layer: a
+    position is a key row and a value row of ``head_dim`` elements in each
+    of the KV heads, so 2 x 8 x 128 x 2 bytes = 4 KiB a layer for
+    Mistral-7B (``kv_bytes_per_token``). ``positions`` is the engine's
+    ``kv_positions_read``: per decode step, each decoding slot's length
+    rounded up to the kernel's block (the kernel fetches whole blocks and
+    skips the blocks past a line's length, and the lines of slots that do
+    not decode). Left out, so the count is a floor: the query rows and the
+    output (slots x 32 heads x 128 x 2 bytes each, 0.26 MB a call against
+    4 KiB x thousands of positions) and the lengths."""
+    return positions * kv_bytes_per_token(c, layers, dtype_bytes)
+
+
 def flash_kernel_work(c: dict, batch: int, seq_len: int) -> dict:
     """FLOPs and bytes of one call of the causal flash kernels on
     [batch, heads, seq, head_dim]: forward 2 matmuls over the causal half,

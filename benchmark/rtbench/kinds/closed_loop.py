@@ -6,6 +6,8 @@ served, counted pro rata. See serve_common."""
 from rtbench import gen
 from rtbench.kinds import serve_common
 
+ADAPTER_NEEDS = ("REFERENCE", "model_config", "reference_weights")
+
 
 def run(ctx: dict) -> None:
-    serve_common.run(ctx, gen.closed_loop_plan)
+    serve_common.run(ctx, gen.closed_loop_plan, "closed_loop")

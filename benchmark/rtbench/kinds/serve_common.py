@@ -108,9 +108,15 @@ def _events(proc, on_event) -> None:
             common.log(f"loadgen: {line}")
 
 
-def run(ctx: dict, plan_for) -> None:
+def run(ctx: dict, plan_for, loop: str) -> None:
+    """One run of a serving cell. ``plan_for(traffic, seed, seconds)``
+    makes the requests, ``loop`` (``open_loop`` or ``closed_loop``) says
+    how the load generator sends them and which end-to-end metrics the
+    window gives: a traffic kind of its own passes its plan and one of the
+    two."""
     cell, clock, seed = ctx["cell"], ctx["clock"], ctx["seed"]
     traffic, model_json = cell["traffic"], cell["config"]
+    ctx["loop"] = loop
     jax, devices, counter = common.start_jax(cell["workload"]["chips"])
     import ray_tpu
     from ray_tpu import serve
@@ -146,7 +152,7 @@ def run(ctx: dict, plan_for) -> None:
         url = f"http://127.0.0.1:{serve.http_port()}/v1/completions"
 
         plan = {"url": url, "seed": seed, "vocab": model_json["vocab_size"],
-                "kind": traffic["kind"], "seconds": ctx["seconds"],
+                "loop": loop, "seconds": ctx["seconds"],
                 "timeout_s": traffic["timeout_s"],
                 "drain_s": traffic.get("drain_s", 30),
                 "stagger_s": traffic.get("stagger_s", 0.0),
@@ -253,6 +259,38 @@ def ttft_ms(due: list[dict], t_close: float, timeout_s: float) -> list[float]:
             for r in due]
 
 
+def finished_inside(records: list[dict], t_open: float,
+                    t_close: float) -> list[dict]:
+    """The sound requests whose last token came inside the window."""
+    return [r for r in records if not request_failed(r)
+            and not r["abandoned"] and r["last_t"] is not None
+            and t_open <= r["last_t"] <= t_close]
+
+
+def tpot_ms(records: list[dict], t_open: float, t_close: float) -> list[float]:
+    """Milliseconds per output token, (last token - first) / (tokens - 1)
+    at the client, of each request that finished inside the window."""
+    return [x for x in (stats.tpot_ms(r["first_t"], r["last_t"], r["frames"])
+                        for r in finished_inside(records, t_open, t_close))
+            if x is not None]
+
+
+def tpot_mean_ms(records: list[dict], t_open: float,
+                 t_close: float) -> float:
+    """Mean milliseconds between two output tokens at the client, over
+    every token of the requests that finished inside the window: the sum of
+    (last token - first) over the sum of (tokens - 1). All the decoding
+    time of the window over all its tokens, so a request weighs as its
+    answer's length and no single request decides the number. Raises where
+    no request gave two tokens, as ``stats.percentile`` does on no values."""
+    done = [r for r in finished_inside(records, t_open, t_close)
+            if r["frames"] >= 2]
+    gaps = sum(r["frames"] - 1 for r in done)
+    if not gaps:
+        raise ValueError("no finished request with two tokens")
+    return sum(r["last_t"] - r["first_t"] for r in done) / gaps * 1e3
+
+
 def _finish(ctx, cell, adapter, jax, devices, device, records, state,
             final_stats, params) -> None:
     traffic, model_json = cell["traffic"], cell["config"]
@@ -264,25 +302,22 @@ def _finish(ctx, cell, adapter, jax, devices, device, records, state,
                    f"{r['frames']}/{r['max_tokens']} finish {r['finish']}")
 
     values: dict = {"setup_s": t_open - ctx["clock"].t_start}
-    if traffic["kind"] == "open_loop":
+    if ctx["loop"] == "open_loop":
         due = [r for r in counted if r["phase"] == "window"]
         late = [r["send_t"] - r["due_t"] for r in counted if r["send_t"]]
         common.log(f"generator lateness ms: median "
                    f"{stats.percentile(late, 50) * 1e3:.3f} worst "
                    f"{max(late) * 1e3:.3f} over {len(late)} requests")
         ttft = ttft_ms(due, t_close, traffic["timeout_s"])
-        done = [r for r in counted if not request_failed(r)
-                and not r["abandoned"] and r["last_t"] is not None
-                and t_open <= r["last_t"] <= t_close]
-        tpot = [x for x in (stats.tpot_ms(r["first_t"], r["last_t"],
-                                          r["frames"]) for r in done)
-                if x is not None]
-        values["tpot_p90_ms"] = stats.percentile(tpot, 90)
-        common.log(f"window: {len(due)} requests due, {len(done)} finished "
+        tpot = tpot_ms(counted, t_open, t_close)
+        values["tpot_mean_ms"] = tpot_mean_ms(counted, t_open, t_close)
+        common.log(f"window: {len(due)} requests due, {len(tpot)} finished "
                    f"inside, ttft p50 {stats.percentile(ttft, 50):.1f} p90 "
                    f"{stats.percentile(ttft, 90):.1f} ms, tpot p50 "
                    f"{stats.percentile(tpot, 50):.2f} p90 "
-                   f"{values['tpot_p90_ms']:.2f} ms")
+                   f"{stats.percentile(tpot, 90):.2f} mean of requests "
+                   f"{sum(tpot) / len(tpot):.2f} of tokens "
+                   f"{values['tpot_mean_ms']:.2f} ms")
         attempted = len(due)
         n_failed = sum(1 for r in due if request_failed(r))
     else:
@@ -329,6 +364,52 @@ def _finish(ctx, cell, adapter, jax, devices, device, records, state,
     ctx["emit"](correct, attempted, n_failed, values, device, breakdown)
 
 
+def readable_ids(texts: list[str]) -> list[int]:
+    """The ids of an answer's leading tokens, up to the first one whose
+    text hides which id it was."""
+    ids = []
+    for t in texts:
+        x = token_id(t)
+        if x is None:
+            break
+        ids.append(x)
+    return ids
+
+
+def pick_sample(records, spec: dict, t_open: float, t_close: float):
+    """The requests the reference is run on, each with the generated ids
+    that are compared: finished requests of the window whose every token
+    can be read back from the stream, the shortest first to keep the check
+    cheap. Where answers are so long that a window may hold too few of
+    those, ``min_readable`` also admits a request whose first that many
+    tokens can be read; it is compared up to its first hidden token, and
+    nothing after it (the reference would need the hidden id as input)."""
+    found = []
+    for r in records:
+        if r["error"] or r["abandoned"] or r["frames"] != r["max_tokens"] \
+                or not (t_open <= (r["last_t"] or 0) <= t_close):
+            continue
+        ids = readable_ids(r["texts"])
+        cut = len(ids) < r["frames"]
+        if cut and len(ids) < spec.get("min_readable", r["frames"]):
+            continue
+        found.append((cut, r["prompt_tokens"] + len(ids), r, ids))
+    found.sort(key=lambda f: f[:2])
+    return [(r, ids) for _c, _n, r, ids in found[:spec["requests"]]]
+
+
+def worst_margin(prompt: list[int], out_ids: list[int], logits_of) -> float:
+    """How far, at worst, a generated token's logit lies under the top
+    logit of its position, by ``logits_of(sequence) -> [positions, vocab]``
+    (the logits at position p choose token p + 1)."""
+    import numpy as np
+
+    n = len(prompt) + len(out_ids)
+    rows = logits_of(prompt + out_ids)[len(prompt) - 1:n - 1]
+    chosen = rows[np.arange(len(out_ids)), np.asarray(out_ids)]
+    return float((rows.max(axis=1) - chosen).max())
+
+
 def _reference_check(cell, adapter, jax, records, params, seed, t_open,
                      t_close) -> bool:
     """After the server has given the cache's memory back: the reference's
@@ -341,19 +422,7 @@ def _reference_check(cell, adapter, jax, records, params, seed, t_open,
     traffic, model_json = cell["traffic"], cell["config"]
     spec = traffic["check"]
     t0 = time.monotonic()
-    # The first finished requests of the window whose every token can be
-    # read back from the stream, shortest first to keep the check cheap.
-    sample = []
-    for r in sorted(records, key=lambda r: r["prompt_tokens"] + r["frames"]):
-        if r["error"] or r["abandoned"] or r["frames"] != r["max_tokens"] \
-                or not (t_open <= (r["last_t"] or 0) <= t_close):
-            continue
-        ids = [token_id(t) for t in r["texts"]]
-        if any(i is None for i in ids):
-            continue
-        sample.append((r, ids))
-        if len(sample) == spec["requests"]:
-            break
+    sample = pick_sample(records, spec, t_open, t_close)
     if len(sample) < spec["requests"]:
         common.log(f"reference: only {len(sample)} requests to compare")
         return False
@@ -363,22 +432,21 @@ def _reference_check(cell, adapter, jax, records, params, seed, t_open,
     common.log(f"reference: device bytes in use after shutdown {in_use}")
     reference = importlib.import_module(adapter.REFERENCE)
     weights = adapter.reference_weights(params)
+
+    def logits_of(seq):
+        padded = seq + [0] * (-len(seq) % 512)   # causal: the tail is inert
+        return np.asarray(reference.logits(
+            model_json, weights, jax.numpy.asarray(padded, jax.numpy.int32)))
+
     worst = 0.0
     for r, out_ids in sample:
         prompt = gen.prompt_ids(seed, r["index"], r["prompt_tokens"],
                                 model_json["vocab_size"])
-        seq = prompt + out_ids
-        n = len(seq)
-        padded = seq + [0] * (-n % 512)   # causal: the tail changes nothing
-        lg = reference.logits(model_json, weights,
-                              jax.numpy.asarray(padded, jax.numpy.int32))
-        # The logits at position p choose token p + 1.
-        rows = np.asarray(lg[len(prompt) - 1:n - 1])
-        chosen = rows[np.arange(len(out_ids)), np.asarray(out_ids)]
-        margin = float((rows.max(axis=1) - chosen).max())
+        margin = worst_margin(prompt, out_ids, logits_of)
         worst = max(worst, margin)
         common.log(f"reference: request {r['index']} ({len(prompt)} + "
-                   f"{len(out_ids)} tokens) worst margin {margin:.4f}")
+                   f"{len(out_ids)} of {r['frames']} tokens) worst margin "
+                   f"{margin:.4f}")
     common.log(f"reference: worst margin {worst:.4f} (allowed "
                f"{spec['margin']}) in {time.monotonic() - t0:.1f}s")
     return worst <= spec["margin"]

@@ -21,6 +21,9 @@ import time
 
 from rtbench import common, gen, readers
 
+ADAPTER_NEEDS = ("REFERENCE", "model_config", "reference_weights",
+                 "train_step")
+
 
 def _batch(np, seed: int, step: int, vocab: int, batch: int, seq: int):
     rng = np.random.default_rng(gen.train_batch_seed(seed, step))

@@ -5,9 +5,9 @@
 The plan (written by the harness from the cell's traffic file and the
 seed) holds the server's URL, the warm-up requests, and either an open
 loop's ramp and window requests with their due times or a closed loop's
-request list and client count. Prompts are token ids, sent as a list
-through ``/v1/completions`` with ``stream: true``, so lengths are exact and
-no tokenizer is in the way.
+request list and client count (``loop`` says which). Prompts are token
+ids, sent as a list through ``/v1/completions`` with ``stream: true``, so
+lengths are exact and no tokenizer is in the way.
 
 It prints one JSON event per line (``warm_done``, ``window_open``,
 ``window_close``, ``done``), stamps each request with the time it was due
@@ -250,7 +250,7 @@ def main(argv: list[str]) -> int:
         plan = json.load(f)
     records: list = []
     warm_up(plan, records)
-    {"open_loop": open_loop, "closed_loop": closed_loop}[plan["kind"]](
+    {"open_loop": open_loop, "closed_loop": closed_loop}[plan["loop"]](
         plan, records)
     with open(plan["out"], "w") as f:
         json.dump(records, f)

@@ -5,6 +5,7 @@ Never imports JAX.
 
 from __future__ import annotations
 
+import ast
 import json
 import os
 import re
@@ -88,6 +89,90 @@ def load_cell(name: str, root: str | None = None) -> dict:
             "config": config, "traffic": traffic,
             "end_to_end": metrics_of(m, "end_to_end", name),
             "per_layer": layer, "run_seconds": m["run_seconds"]}
+
+
+def module_names(path: str) -> dict | None:
+    """The names a module defines at its top level, each with its value
+    where that is a literal (None otherwise), read from the source and
+    never by importing it (an adapter or a kind imports JAX and the
+    program). None where there is no such file."""
+    if not os.path.exists(path):
+        return None
+    with open(path) as f:
+        tree = ast.parse(f.read(), path)
+    out: dict = {}
+    for node in tree.body:
+        if isinstance(node, (ast.FunctionDef, ast.ClassDef)):
+            out[node.name] = None
+        elif isinstance(node, (ast.Import, ast.ImportFrom)):
+            for a in node.names:
+                out[(a.asname or a.name).split(".")[0]] = None
+        elif isinstance(node, (ast.Assign, ast.AnnAssign)):
+            targets = node.targets if isinstance(node, ast.Assign) \
+                else [node.target]
+            try:
+                value = ast.literal_eval(node.value)
+            except (ValueError, TypeError, SyntaxError):
+                value = None
+            for t in targets:
+                if isinstance(t, ast.Name):
+                    out[t.id] = value
+    return out
+
+
+def check_modules(m: dict, root: str | None = None) -> list[str]:
+    """What a cell's files name has to be there before a run: the traffic
+    file's ``kind`` is a module ``rtbench/kinds/<kind>.py`` with ``run``;
+    the configuration's ``adapter`` is a module ``rtbench/adapters/
+    <adapter>.py`` that has ``depth`` and every name the kind and the
+    cell's readers say they call on an adapter (their ``ADAPTER_NEEDS``, a
+    literal tuple), and whose ``REFERENCE`` names a module under
+    ``benchmark/reference/``; each per-layer metric's ``reader`` is a module
+    with ``read``. A model kind or a traffic kind the harness has not seen
+    passes by bringing its module. Nothing is imported."""
+    errs: list[str] = []
+    root = root or repo_root()
+    rt = os.path.join(bench_dir(root), "rtbench")
+    for w in m["workloads"]:
+        try:
+            cell = load_cell(w["name"], root)
+        except (OSError, KeyError, ValueError) as e:
+            errs.append(f"cell {w.get('name')}: {e!r}")
+            continue
+        kind = cell["traffic"].get("kind")
+        names = module_names(os.path.join(rt, "kinds", f"{kind}.py"))
+        if names is None or "run" not in names:
+            errs.append(f"cell {w['name']}: traffic kind {kind!r} needs "
+                        f"rtbench/kinds/{kind}.py with run()")
+            continue
+        needs = {"depth", *(names.get("ADAPTER_NEEDS") or ())}
+        for x in cell["per_layer"]:
+            reader = module_names(os.path.join(
+                rt, "readers", f"{x.get('reader')}.py"))
+            if reader is None or "read" not in reader:
+                errs.append(f"metric {x['name']}: reader "
+                            f"{x.get('reader')!r} needs rtbench/readers/"
+                            f"{x.get('reader')}.py with read()")
+                continue
+            needs |= set(reader.get("ADAPTER_NEEDS") or ())
+        adapter = cell["config"].get("adapter")
+        have = module_names(os.path.join(rt, "adapters", f"{adapter}.py"))
+        if have is None:
+            errs.append(f"cell {w['name']}: adapter {adapter!r} needs "
+                        f"rtbench/adapters/{adapter}.py")
+            continue
+        if needs - set(have):
+            errs.append(f"cell {w['name']}: adapter {adapter!r} lacks "
+                        f"{sorted(needs - set(have))}")
+        ref = have.get("REFERENCE")
+        if "REFERENCE" in needs and not (
+                isinstance(ref, str) and ref.startswith("reference.")
+                and os.path.exists(os.path.join(
+                    bench_dir(root), *ref.split(".")) + ".py")):
+            errs.append(f"cell {w['name']}: adapter {adapter!r} names the "
+                        f"reference {ref!r}, which benchmark/reference/ "
+                        "lacks")
+    return errs
 
 
 def check(m: dict, root: str | None = None) -> list[str]:
@@ -236,4 +321,4 @@ def check(m: dict, root: str | None = None) -> list[str]:
                         "metric")
         if not metrics_of(m, "per_layer", c):
             errs.append(f"cell {c}: needs a per-layer metric")
-    return errs
+    return errs or check_modules(m, root)

@@ -4,6 +4,8 @@ over the chip's HBM bandwidth, over the time a decode step took."""
 
 from rtbench.readers import adapter_of, decode_ms_per_step, serve_trace
 
+ADAPTER_NEEDS = ("decode_step_bytes", "depth")
+
 
 def read(obs, params):
     got = decode_ms_per_step.steps_and_seconds(obs, params)

@@ -5,6 +5,8 @@ found by kernel name."""
 
 from rtbench.readers import adapter_of
 
+ADAPTER_NEEDS = ("flash_kernel_work",)
+
 
 def read(obs, params):
     trace = obs.get("trace")

@@ -4,6 +4,8 @@ token is routed to) x tokens/s/chip over the chip's bf16 peak."""
 
 from rtbench.readers import adapter_of
 
+ADAPTER_NEEDS = ("train_flops_per_token", "depth")
+
 
 def read(obs, params):
     if obs.get("kind") != "train":

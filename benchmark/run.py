@@ -14,6 +14,11 @@ Earlier lines of standard output carry the set-up breakdown, the count of
 compilations inside the window and the generator's lateness; the last line
 is the result, one JSON object. Without a TPU, or with fewer chips than the
 cell asks for, the exit code is not 0 and no result is printed.
+
+Once the result line is out the process ends itself (``os._exit`` after a
+flush): ``serve.shutdown()`` leaves the engine's scheduler thread alive,
+and a run of PR 25 that had printed its result never exited (50
+chip-minutes). Run a cell under ``timeout`` all the same.
 """
 
 from __future__ import annotations
@@ -65,5 +70,21 @@ def main(argv: list[str] | None = None) -> int:
     return 0
 
 
+def _exit_now(code: int) -> None:
+    """Leave without waiting for a thread the program left behind. The
+    kinds have stopped and waited for every process they started."""
+    sys.stdout.flush()
+    sys.stderr.flush()
+    os._exit(code)
+
+
 if __name__ == "__main__":
-    sys.exit(main())
+    try:
+        _exit_now(main())
+    except SystemExit as e:   # no TPU, a bad argument: the code it names
+        _exit_now(e.code if isinstance(e.code, int) else int(bool(e.code)))
+    except BaseException:     # noqa: BLE001 - print it, then leave at once
+        import traceback
+
+        traceback.print_exc()
+        _exit_now(1)

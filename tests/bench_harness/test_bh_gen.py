@@ -103,3 +103,37 @@ def test_train_batch_seed_takes_large_seeds_and_negative_steps():
         for step in (-2, -1, 0, 5):
             np.random.default_rng(gen.train_batch_seed(seed, step))
     assert gen.train_batch_seed(2 ** 33, 1) != gen.train_batch_seed(0, 1)
+
+
+# ------------------------------------------------ the serve plans, pinned
+PINNED = {   # sha256 of json.dumps(plan, sort_keys=True) at 51 s: chat and
+    # docqa as PR 25's generator made them, reason as PR 26 added it. A
+    # change to the generator that moves a committed cell's plan shows here.
+    ("serve-chat", 1):
+        "02b637e4691bcdb9e0d0b6f3d3e08272101193dab84ff2ebaf0905a24d5e39c6",
+    ("serve-chat", 2147483700):
+        "468b443859029a1140d28ffe8cc9ab7b8078d3fc1e5b7ada250a5977e1c850c2",
+    ("serve-docqa", 1):
+        "e3ad0ae7e3517d51cd25bde43202480439dc632467f3bdab2dd8a77df83e1d01",
+    ("serve-docqa", 2147483700):
+        "7ab5425bcb01ae1c91517a914360537636633153d1feff56631e6266e66d6c34",
+    ("serve-reason", 1): 
+        "2a26abc4a5f5f8e7a8884607d33832ebaf4c44f54e90d6232db896ccb6bd36b1",
+    ("serve-reason", 2147483700): 
+        "bca6ced6c69dbb00daacf97bd9aeddc03f738a24f90a8624fa0876e9f7d45ece",
+}
+
+
+def _digest(name, seed):
+    import hashlib
+
+    t = traffic(name)
+    plan = (gen.open_loop_plan if t["kind"] == "open_loop"
+            else gen.closed_loop_plan)(t, seed, 51)
+    return hashlib.sha256(
+        json.dumps(plan, sort_keys=True).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("name,seed", sorted(PINNED))
+def test_a_committed_serve_plan_is_what_it_was(name, seed):
+    assert _digest(name, seed) == PINNED[name, seed]

@@ -6,8 +6,9 @@ import pytest
 
 from conftest import REPO
 from rtbench import manifest, trace_reduce as tr
-from rtbench.readers import (counter_ratio, idle_in_phases, phases,
-                             program_per_count, read_all)
+from rtbench.readers import (counter_ratio, decode_attention_roofline,
+                             idle_in_phases, phases, program_per_count,
+                             read_all, tpot_percentile)
 
 
 def _polls(*rows):
@@ -183,6 +184,90 @@ def test_phases_load_from_a_recorded_trace_with_their_counts(tmp_path):
     assert [p.name for p in phases.of(obs, "train.")] == ["train.report"]
 
 
+# ------------------------------------------------- decode_attention_roofline
+def _kernel_obs(polls, kernel_events, layers=16):
+    """A traced span of 10..14 s of the host's clock; kernel events on the
+    trace's own clock; polls of ``stats()`` around the span."""
+    ops = [tr.Event('%decode_attention.6 = bf16[32,8,16,128]{3,2,1,0} '
+                    'custom-call()', s, e) for s, e in kernel_events]
+    ops.append(tr.Event("%fusion.1 = f32[8]{0} fusion()", 0.0, 0.001))
+    tr._self_times(ops)
+    cell = {"config": {"adapter": "llama", "num_key_value_heads": 8,
+                       "head_dim": 128,
+                       "num_hidden_layers": {"serve": layers}},
+            "traffic": {"use": "serve"}}
+    return {"kind": "serve", "cell": cell, "trace_span": (10.0, 14.0),
+            "trace": tr.Trace([tr.DeviceTrace(0, ops, [], [])], {}),
+            "polls": polls, "peaks": {"hbm_bytes_per_s": 819e9}}
+
+
+def test_decode_attention_roofline_is_bytes_a_step_over_time_a_step():
+    # Between the polls that bracket the span the engine took 100 decode
+    # steps and counted 100 x 20,000 positions; the poll inside the span
+    # and those further out are not the brackets. A position is 2 x 8 x
+    # 128 x 2 bytes = 4 KiB a layer. The trace saw 32 kernel calls (2
+    # steps of 16 layers) of 0.25 ms each.
+    polls = [(9.0, {"kv_positions_read": 1, "decode_steps": 1}),
+             (9.95, {"kv_positions_read": 1_000_000, "decode_steps": 500}),
+             (12.0, {"kv_positions_read": 2_200_000, "decode_steps": 560}),
+             (14.05, {"kv_positions_read": 3_000_000, "decode_steps": 600}),
+             (15.0, {"kv_positions_read": 9_000_000, "decode_steps": 900})]
+    events = [(1.0 + i * 0.001, 1.0 + i * 0.001 + 0.00025)
+              for i in range(32)]
+    got = decode_attention_roofline.read(_kernel_obs(polls, events),
+                                         {"kernel": "decode_attention"})
+    bytes_a_step = 20_000 * 4096 * 16
+    assert got == pytest.approx(
+        100.0 * (bytes_a_step / 819e9) / (0.00025 * 16))
+    assert 30 < got < 50
+    # half the depth: half the bytes a step and half the calls a step
+    assert decode_attention_roofline.read(
+        _kernel_obs(polls, events, layers=8),
+        {"kernel": "decode_attention"}) == pytest.approx(got)
+
+
+@pytest.mark.parametrize("polls,events", [
+    ([(9.9, {"kv_positions_read": 0, "decode_steps": 0}),
+      (14.1, {"kv_positions_read": 9, "decode_steps": 3})], []),
+    ([], [(1.0, 1.1)]),
+    ([(9.9, {"kv_positions_read": 0, "decode_steps": 0})], [(1.0, 1.1)]),
+    ([(10.5, {"kv_positions_read": 0, "decode_steps": 0}),
+      (14.1, {"kv_positions_read": 9, "decode_steps": 3})], [(1.0, 1.1)]),
+    ([(9.9, {"waiting": 0}), (14.1, {"waiting": 1})], [(1.0, 1.1)]),
+    ([(9.9, {"kv_positions_read": 5, "decode_steps": 3}),
+      (14.1, {"kv_positions_read": 5, "decode_steps": 3})], [(1.0, 1.1)]),
+], ids=["no-kernel-events", "no-polls", "no-poll-after", "no-poll-before",
+        "no-such-counter", "no-decode-step"])
+def test_decode_attention_roofline_with_nothing_to_read_is_none(polls,
+                                                                events):
+    assert decode_attention_roofline.read(
+        _kernel_obs(polls, events), {"kernel": "decode_attention"}) is None
+    assert decode_attention_roofline.read(
+        {"trace": None}, {"kernel": "decode_attention"}) is None
+
+
+# ---------------------------------------------------------- tpot_percentile
+@pytest.mark.parametrize("q,want", [(50, 150.0), (90, 190.0), (100, 200.0)])
+def test_tpot_percentile_is_over_requests_that_finished_in_the_window(q,
+                                                                      want):
+    def rec(first_t, last_t, **kw):
+        return dict({"abandoned": False, "error": None, "frames": 11,
+                     "max_tokens": 11, "finish": "length",
+                     "first_t": first_t, "last_t": last_t}, **kw)
+
+    # 100 and 200 ms a token inside the window; one request ends after it
+    obs = dict(WINDOW, kind="serve", records=[
+        rec(11.0, 12.0), rec(11.0, 13.0), rec(11.0, 25.0)])
+    assert tpot_percentile.read(obs, {"q": q}) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("obs", [
+    {"kind": "train"}, dict(WINDOW, kind="serve", records=[])],
+    ids=["not-a-serve-run", "no-finished-request"])
+def test_tpot_percentile_with_nothing_to_read_is_none(obs):
+    assert tpot_percentile.read(obs, {"q": 90}) is None
+
+
 # --------------------------------------------------------------- the entries
 NEW = {
     "mistral7b-serve-chat": {
@@ -191,7 +276,12 @@ NEW = {
         "decode_ms_per_step.counted", "idle_in_scheduler_share.tpot"},
     "mistral7b-serve-docqa": {
         "admit_to_first_token_mean_ms.tok_s", "decode_slot_use_share.tok_s",
-        "prefill_ms_per_ktok.counted", "idle_in_scheduler_share.tok_s"},
+        "prefill_ms_per_ktok.counted", "idle_in_scheduler_share.tok_s",
+        "decode_ms_per_step.tok_s"},
+    "mistral7b-serve-reason": {
+        "admit_to_first_token_mean_ms.tok_s", "decode_slot_use_share.tok_s",
+        "prefill_ms_per_ktok.counted", "idle_in_scheduler_share.tok_s",
+        "decode_ms_per_step.tok_s"},
 }
 
 
@@ -224,6 +314,7 @@ def test_the_cells_report_the_new_metrics_from_one_observation(cell):
             "first_frame_lag_mean_ms": 5.0,
             "decode_slot_use_share": 75.0,
             "decode_ms_per_step.counted": 100.0,
+            "decode_ms_per_step": 100.0,
             "prefill_ms_per_ktok.counted": 250.0,
             "idle_in_scheduler_share": 100.0 * 0.2 / 2.1}
     got = read_all(specs, obs)

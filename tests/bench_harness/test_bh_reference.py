@@ -119,6 +119,39 @@ def test_engine_prefill_then_decode_matches_the_reference(dense_case):
                                    atol=LOGIT_ATOL, rtol=0)
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_the_fp8_control_comes_out_as_not_correct(seed):
+    """benchmark/control.py at a size a test run can hold: the reference on
+    weights rounded through fp8 chooses tokens that lie further under the
+    float32 reference's top logit than the serving cells allow, while the
+    precision the configuration states (bfloat16 weights) stays far inside.
+    At the cells' own sizes the readings are PERF.md's (section 4)."""
+    import importlib.util
+
+    from ray_tpu.models.llama import init_params
+
+    spec = importlib.util.spec_from_file_location(
+        "bench_control", os.path.join(BENCH, "control.py"))
+    control = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(control)
+    with open(os.path.join(BENCH, "traffic", "serve-chat.json")) as f:
+        limit = json.load(f)["check"]["margin"]
+
+    c = tiny_config("mistral-7b-v0.3")
+    cfg = llama_adapter.model_config(c, "serve", 128)
+    weights = llama_adapter.reference_weights(
+        init_params(cfg, jax.random.PRNGKey(seed)))
+    tokens = jax.random.randint(jax.random.PRNGKey(9), (96,), 0, 512)
+    want = dense.logits(c, weights, tokens)
+    fp8 = control.margin(
+        want, dense.logits(c, control.to_fp8(weights), tokens), 24)
+    bf16 = control.margin(want, dense.logits(c, jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16), weights), tokens), 24)
+    assert fp8 > limit          # the control is not correct
+    assert bf16 < limit / 5     # the stated precision is, with room
+    assert fp8 > 3 * max(bf16, 0.02)
+
+
 @pytest.fixture(scope="module")
 def sparse_case():
     from ray_tpu.models import mixtral

@@ -48,3 +48,89 @@ def test_trace_joins_count_what_happened_inside_the_span():
     # 2 s; request 1 holds 1,000 + 5.5 for 1 s
     assert serve_trace.mean_live_kv_tokens(recs, 5.0, 7.0) == \
         pytest.approx(((200 + 50.5) * 2 + (1000 + 5.5) * 1) / 2)
+
+
+# ------------------------------------------ the sample and its hidden tokens
+def _done(index, texts, prompt_tokens=10, last_t=5.0, **kw):
+    return dict({"index": index, "error": None, "abandoned": False,
+                 "frames": len(texts), "max_tokens": len(texts),
+                 "prompt_tokens": prompt_tokens, "last_t": last_t,
+                 "texts": texts}, **kw)
+
+
+@pytest.mark.parametrize("min_readable,want", [
+    (None, [3, 1]),          # readable throughout, the shortest first
+    (2, [3, 1, 9]),          # then one cut at its first hidden token
+    (1, [3, 1, 4, 9]),       # the shorter cut one first
+])
+def test_pick_sample_takes_whole_answers_first_then_readable_starts(
+        min_readable, want):
+    recs = [_done(1, ["A", "<|300|>", "b"], prompt_tokens=20),
+            _done(2, ["\ufffd", "", "c"]),              # hidden from the start
+            _done(3, ["A", "<|300|>"]),
+            _done(4, ["A", "\ufffd"], prompt_tokens=5),
+            _done(5, ["", "ab"]),
+            _done(6, ["A", "B"], last_t=50.0),          # after the window
+            _done(7, ["A", "B"], error="boom"),
+            _done(8, ["A"], max_tokens=2),              # stopped short
+            _done(9, ["A", "<|301|>", "", "B"])]
+    spec = {"requests": 9}
+    if min_readable is not None:
+        spec["min_readable"] = min_readable
+    got = serve_common.pick_sample(recs, spec, 0.0, 10.0)
+    assert [r["index"] for r, _ids in got] == want
+    ids = dict((r["index"], ids) for r, ids in got)
+    assert ids[3] == [65, 300]
+    if 9 in ids:        # compared up to the hidden token, nothing after it
+        assert ids[9] == [65, 301]
+    assert [r["index"] for r, _ in serve_common.pick_sample(
+        recs, dict(spec, requests=1), 0.0, 10.0)] == want[:1]
+
+
+@pytest.mark.parametrize("out,want", [
+    ([65, 200, 310], 0.0),       # the reference's own choice everywhere
+    ([65, 200], 0.0),            # a readable start of it
+    ([65, 201, 310], 1.0),       # a wrong token shows at its own position
+    ([65, 200, 399], 0.1),       # a near miss by its distance
+])
+def test_worst_margin_is_the_furthest_a_token_lies_under_the_top(out, want):
+    import numpy as np
+
+    vocab, prompt, truth = 400, [300, 301], [65, 200, 310]
+
+    def logits_of(seq):
+        # the right continuation of the true sequence scores 1.0, and only
+        # while the sequence so far is the true one; id 399 scores 0.9
+        rows = np.zeros((len(seq), vocab), np.float32)
+        full = prompt + truth
+        for p in range(len(seq) - 1):
+            if seq[:p + 1] == full[:p + 1]:
+                rows[p, full[p + 1]] = 1.0
+        rows[:, 399] = 0.9
+        return rows
+
+    assert serve_common.worst_margin(prompt, out, logits_of) == \
+        pytest.approx(want)
+
+
+def test_tpot_ms_counts_requests_that_finished_inside_the_window():
+    def rec(first_t, last_t, frames=11, **kw):
+        return _rec(first_t=first_t, last_t=last_t, frames=frames,
+                    max_tokens=frames, **kw)
+
+    recs = [rec(1.0, 2.0),                      # 100 ms a token
+            rec(1.0, 3.0),                      # 200
+            rec(1.0, 12.0),                     # ends after the window
+            rec(1.0, 2.0, error="boom"),
+            rec(1.0, 2.0, abandoned=True),
+            rec(2.0, 2.0, frames=1)]            # one token: no gap to time
+    assert serve_common.tpot_ms(recs, 0.0, 10.0) == pytest.approx(
+        [100.0, 200.0])
+    # all the decoding time over all the tokens: 3 s over 20 gaps
+    assert serve_common.tpot_mean_ms(recs, 0.0, 10.0) == pytest.approx(150.0)
+    recs.append(rec(1.0, 7.0, frames=41))       # a long answer weighs more
+    assert serve_common.tpot_mean_ms(recs, 0.0, 10.0) == pytest.approx(150.0)
+    assert serve_common.tpot_mean_ms(recs[:-1] + [rec(1.0, 9.0, frames=41)],
+                                     0.0, 10.0) == pytest.approx(11e3 / 60)
+    with pytest.raises(ValueError):
+        serve_common.tpot_mean_ms(recs, 20.0, 30.0)
