@@ -13,6 +13,10 @@ from dataclasses import dataclass, field
 from typing import Any
 
 from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.models.longcat import LongcatConfig
+
+# A model's own configuration: what llm/engine.served_model knows a model by.
+ModelConfig = LlamaConfig | LongcatConfig
 
 
 @dataclass
@@ -27,7 +31,7 @@ class SamplingParams:
 
 @dataclass
 class LLMConfig:
-    model: LlamaConfig | str = "tiny"  # a config or a named geometry
+    model: ModelConfig | str = "tiny"  # a config or a named geometry
     tokenizer: str = "byte"            # "byte" or a HF tokenizer path
     max_num_seqs: int = 8              # continuous-batching slots
     max_seq_len: int | None = None     # default: model.max_seq_len
@@ -113,7 +117,7 @@ class LLMConfig:
     #              copy per hop). Kept for A/B benching and as a fallback.
     pd_transfer_mode: str = "store"
 
-    def model_config(self) -> LlamaConfig:
+    def model_config(self) -> ModelConfig:
         return _resolve_model(self.model, self.dtype)
 
     def draft_model_config(self) -> LlamaConfig | None:
@@ -122,8 +126,9 @@ class LLMConfig:
         return _resolve_model(self.speculative_model, self.dtype)
 
 
-def _resolve_model(model: "LlamaConfig | str", dtype: str | None) -> LlamaConfig:
-    if isinstance(model, LlamaConfig):
+def _resolve_model(model: "ModelConfig | str",
+                   dtype: str | None) -> ModelConfig:
+    if isinstance(model, ModelConfig):
         cfg = model
     elif model == "tiny":
         from dataclasses import replace

@@ -38,6 +38,7 @@ import threading
 import time
 import uuid
 from collections import deque
+from collections.abc import Callable
 from dataclasses import dataclass, field, replace
 from functools import partial
 from typing import Any
@@ -52,7 +53,8 @@ from ray_tpu.devtools.annotations import guarded_by
 from ray_tpu.llm.config import LLMConfig, SamplingParams
 from ray_tpu.util import tracing
 from ray_tpu.llm.tokenizer import get_tokenizer
-from ray_tpu.models.llama import LlamaConfig, init_params, param_logical_axes
+from ray_tpu.models import llama as llama_model
+from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.decode_attention import (
     decode_attention,
     decode_kv_block,
@@ -608,6 +610,89 @@ def sample_tokens(logits, temps, top_ps, top_k: int, key,
     return jnp.where(temps <= 0.0, greedy, sampled)
 
 
+@dataclass(frozen=True)
+class ServedModel:
+    """What a model supplies for the engine to serve it. The engine owns
+    the schedule (admission, chunked prefill, bursts and chaining, sampling,
+    prefix adoption, the counters) and knows a model only through this:
+
+    - ``init_params(cfg, key)`` and ``param_logical_axes(cfg)``;
+    - ``init_cache(cfg, slots, max_seq)``: the slot cache, a pytree whose
+      leaves the programs below take donated and give back;
+    - ``prefill_chunk``, ``decode_step``, ``decode_burst``,
+      ``copy_prefix_kv``: jitted programs with the signatures of this
+      module's own (the Llama ones), under these very names so that a
+      device trace shows ``jit_prefill_chunk`` and ``jit_decode_burst``
+      whatever the model. A model with ``counters`` returns one more
+      value from the first three: an int32 array of that many counts,
+      which the scheduler adds into ``stats()`` under those names where it
+      fetches the tokens; ``constants(cfg)`` gives what ``stats()`` carries
+      beside them unchanged (a denominator of theirs);
+    - ``kv_block(cfg, max_seq)``: the positions its decode attention
+      fetches at a time, behind ``kv_positions_read``;
+    - ``kv_handoff``: whether a line can be exported and imported as
+      per-head K/V (the prefill/decode hand-off);
+    - ``refuse(config)``: raises ValueError for an ``LLMConfig`` it cannot
+      serve (None: it serves them all)."""
+
+    init_params: Callable
+    param_logical_axes: Callable
+    init_cache: Callable
+    prefill_chunk: Callable
+    decode_step: Callable
+    decode_burst: Callable
+    copy_prefix_kv: Callable
+    kv_block: Callable
+    counters: tuple[str, ...] = ()
+    constants: Callable | None = None
+    kv_handoff: bool = True
+    refuse: Callable | None = None
+
+
+# The Llama programs are this module's own, reached through its names when
+# they are called (a test puts a failing one in a name's place).
+_LLAMA = ServedModel(
+    init_params=llama_model.init_params,
+    param_logical_axes=llama_model.param_logical_axes,
+    init_cache=init_kv_cache,
+    prefill_chunk=lambda *a, **kw: prefill_chunk(*a, **kw),
+    decode_step=lambda *a, **kw: decode_step(*a, **kw),
+    decode_burst=lambda *a, **kw: decode_burst(*a, **kw),
+    copy_prefix_kv=lambda *a, **kw: copy_prefix_kv(*a, **kw),
+    kv_block=lambda cfg, max_seq: decode_kv_block(
+        max_seq, cfg.head_dim, cfg.jnp_dtype.itemsize),
+)
+
+
+def served_model(cfg) -> ServedModel:
+    """The model behind a configuration, by its type."""
+    if isinstance(cfg, LlamaConfig):
+        return _LLAMA
+    from ray_tpu.models.longcat import LongcatConfig
+
+    if isinstance(cfg, LongcatConfig):
+        from ray_tpu.llm.longcat_serving import SERVED
+
+        return SERVED
+    raise TypeError(f"the engine serves no {type(cfg).__name__}")
+
+
+def require_kv_handoff(cfg) -> None:
+    """Raise unless the model's cache lines can be handed from a prefill
+    engine to a decode engine (llm/pd.py asks before it builds one)."""
+    if not served_model(cfg).kv_handoff:
+        raise ValueError(
+            f"{type(cfg).__name__} does not support the prefill/decode "
+            "hand-off: its cache is not per-head K/V")
+
+
+def init_params(cfg, key):
+    """The served model's own initialiser. The engine reaches it through
+    this module-level name, looked up when it is called, so that a caller
+    may put a jitted copy in its place."""
+    return served_model(cfg).init_params(cfg, key)
+
+
 @dataclass
 class GenerationRequest:
     request_id: str
@@ -628,6 +713,9 @@ class GenerationRequest:
     spec_disabled: bool = False  # excluded from speculation (see _spec_decode)
     arrival_seq: int = 0  # admission order; blocked-KV preemption evicts newest
     prefill_gen: int = 0  # bumped on preemption: stale deferred fetches no-op
+    # Unfetched model counts of this prompt's earlier chunks (a model with
+    # ServedModel.counters); fetched with the first token.
+    chunk_counts: list = field(default_factory=list)
     # Request tracing: the submitter's propagated context (None = untraced)
     # plus the phase timestamps the scheduler thread stamps engine spans
     # from (engine.queue / engine.prefill / engine.decode — the TTFT
@@ -658,6 +746,9 @@ class LLMEngine:
         ensure_compile_cache()
         self.config = config
         self.model_cfg = config.model_config()
+        self.model = served_model(self.model_cfg)
+        if self.model.refuse is not None:
+            self.model.refuse(config)
         self.tokenizer = get_tokenizer(config.tokenizer)
         self.max_slots = config.max_num_seqs
 
@@ -722,9 +813,11 @@ class LLMEngine:
         self.decode_tokens = 0
         self.kv_positions_read = 0
         self.kv_positions_reserved = 0
-        self._kv_block = decode_kv_block(
-            self.max_seq, self.model_cfg.head_dim,
-            self.model_cfg.jnp_dtype.itemsize)
+        self._kv_block = self.model.kv_block(self.model_cfg, self.max_seq)
+        # What the model's programs count themselves (ServedModel.counters).
+        self.model_counts = dict.fromkeys(self.model.counters, 0)
+        if self.model.constants is not None:
+            self.model_counts.update(self.model.constants(self.model_cfg))
         self.first_tokens = 0
         self.queue_wait_s = 0.0
         self.first_token_wait_s = 0.0
@@ -872,6 +965,7 @@ class LLMEngine:
                      sampling: SamplingParams | None = None) -> dict:
         """Run ONLY the prompt prefill; return the KV slice + first sampled
         token for hand-off to a decode engine."""
+        require_kv_handoff(self.model_cfg)
         if self.blocked:
             raise ValueError(
                 "prefill/decode disaggregation exports dense KV lines; "
@@ -961,6 +1055,7 @@ class LLMEngine:
                          sampling: SamplingParams | None = None,
                          stream: bool = False) -> GenerationRequest:
         """Continue decoding from a shipped prefill (KV import)."""
+        require_kv_handoff(self.model_cfg)
         if self.blocked:
             raise ValueError(
                 "KV import writes dense KV lines; run the decode engine "
@@ -1056,7 +1151,8 @@ class LLMEngine:
                "kv_positions_reserved": self.kv_positions_reserved,
                "first_tokens": self.first_tokens,
                "queue_wait_s": self.queue_wait_s,
-               "first_token_wait_s": self.first_token_wait_s}
+               "first_token_wait_s": self.first_token_wait_s,
+               **self.model_counts}
         if self.blocked:
             out["kv_blocks_total"] = self.num_blocks
             out["kv_blocks_free"] = len(self._free_blocks)
@@ -1197,6 +1293,7 @@ class LLMEngine:
         and start those requests decoding. Runs AFTER the tick's decode
         dispatch so the fetch overlaps the queued device work."""
         for req, gen, out in deferred:
+            counts, req.chunk_counts = req.chunk_counts, []
             if req.done.is_set():  # failed meanwhile (device recovery)
                 continue
             if gen != req.prefill_gen:
@@ -1207,7 +1304,9 @@ class LLMEngine:
                 continue
             try:
                 with tracing.phase("engine.fetch", which="prefill"):
-                    tok = int(np.asarray(out)[0])
+                    out, counts = jax.device_get((out, counts))
+                    tok = int(out[0])
+                self._add_model_counts(*counts)
             except Exception as e:  # noqa: BLE001 - async dispatch error
                 # surfaces at materialization; engine state is suspect.
                 logger.exception("deferred prefill sample failed for %s",
@@ -1332,7 +1431,7 @@ class LLMEngine:
                     # both hold intact KV) into the fresh slot, preserving
                     # the donor for future siblings.
                     try:
-                        self.cache = copy_prefix_kv(
+                        self.cache = self.model.copy_prefix_kv(
                             self.model_cfg, self.cache, jnp.int32(donor),
                             jnp.int32(slot))
                         req.prefilled_len = adopt
@@ -1576,10 +1675,11 @@ class LLMEngine:
                     jnp.int32(req.prefilled_len), jnp.int32(p),
                     kmesh=self.kmesh)
             else:
-                self.cache, logits = prefill_chunk(
+                self.cache, logits, *counts = self.model.prefill_chunk(
                     self.model_cfg, self.params, self.cache,
                     jnp.asarray(toks), jnp.int32(req.prefilled_len),
                     jnp.int32(p), jnp.int32(slot), kmesh=self.kmesh)
+                req.chunk_counts += counts
             req.prefilled_len += take
             self.prefill_chunks += 1
             self.prompt_tokens_prefilled += take
@@ -1684,8 +1784,9 @@ class LLMEngine:
                         jnp.asarray(self._tables), jnp.asarray(tokens),
                         jnp.asarray(positions), jnp.asarray(write),
                         kmesh=self.kmesh)
+                    counts = []
                 else:
-                    self.cache, logits = decode_step(
+                    self.cache, logits, *counts = self.model.decode_step(
                         self.model_cfg, self.params, self.cache,
                         jnp.asarray(tokens), jnp.asarray(positions),
                         jnp.asarray(write), kmesh=self.kmesh)
@@ -1699,7 +1800,9 @@ class LLMEngine:
         try:
             reqs = [active.get(s) for s in range(self.max_slots)]
             with tracing.phase("engine.fetch", which="step"):
-                sampled = self._sample_one(logits, reqs)
+                sampled, counts = jax.device_get(
+                    (self._sample_dispatch(logits, reqs), counts))
+            self._add_model_counts(*counts)
         except Exception as e:  # noqa: BLE001 - cache survived; only this
             # batch's requests lack tokens — fail them, keep other contexts.
             logger.exception("sampling failed (%d active)", len(active))
@@ -1766,8 +1869,9 @@ class LLMEngine:
                         jnp.asarray(positions), jnp.asarray(write),
                         jnp.asarray(temps), jnp.asarray(top_ps), sub, burst,
                         need_top_p, kmesh=self.kmesh)
+                    counts = []
                 else:
-                    self.cache, toks = decode_burst(
+                    self.cache, toks, *counts = self.model.decode_burst(
                         self.model_cfg, self.params, self.cache,
                         jnp.asarray(tokens), jnp.asarray(positions),
                         jnp.asarray(write), jnp.asarray(temps),
@@ -1795,8 +1899,9 @@ class LLMEngine:
                             jnp.asarray(write), jnp.asarray(temps),
                             jnp.asarray(top_ps), sub2, burst, need_top_p,
                             kmesh=self.kmesh)
+                        counts2 = []
                     else:
-                        self.cache, toks2 = decode_burst(
+                        self.cache, toks2, *counts2 = self.model.decode_burst(
                             self.model_cfg, self.params, self.cache,
                             toks[burst - 1], jnp.asarray(positions) + burst,
                             jnp.asarray(write), jnp.asarray(temps),
@@ -1805,9 +1910,11 @@ class LLMEngine:
                 self.decode_dispatches += 1
                 self.decode_steps += burst
                 self._count_kv_positions(positions + burst, write, burst)
-                self._pending_burst = (dict(active), burst, toks2)
+                self._pending_burst = (dict(active), burst, toks2, counts2)
             with tracing.phase("engine.fetch", which="burst"):
-                toks = np.asarray(toks)  # [burst, max_slots]
+                # [burst, max_slots], and the model's counts with it
+                toks, counts = jax.device_get((toks, counts))
+            self._add_model_counts(*counts)
         except Exception as e:  # noqa: BLE001 - cache donated & lost
             logger.exception("burst decode failed (%d active, burst %d)",
                              len(active), burst)
@@ -1844,17 +1951,25 @@ class LLMEngine:
         """Fetch + emit the burst chained by the previous tick."""
         if self._pending_burst is None:
             return False
-        active, burst, toks_dev = self._pending_burst
+        active, burst, toks_dev, counts = self._pending_burst
         self._pending_burst = None
         try:
             with tracing.phase("engine.fetch", which="pending"):
-                toks = np.asarray(toks_dev)
+                toks, counts = jax.device_get((toks_dev, counts))
+            self._add_model_counts(*counts)
         except Exception as e:  # noqa: BLE001 - surfaces at materialization
             logger.exception("pipelined burst failed (%d slots)", len(active))
             self._recover_device_failure(f"decode failed: {e!r}")
             return True
         self._emit_burst(active, burst, toks)
         return True
+
+    def _add_model_counts(self, *fetched) -> None:
+        """Add fetched count arrays of the model's programs (none for a
+        model without counters) into ``model_counts``."""
+        for counts in fetched:
+            for name, n in zip(self.model.counters, counts):
+                self.model_counts[name] += int(n)
 
     def _emit_burst(self, active, burst: int, toks) -> None:
         with tracing.phase("engine.emit") as ph:
@@ -2035,9 +2150,6 @@ class LLMEngine:
                              jnp.asarray(top_ps), top_k, sub,
                              bool((top_ps < 1.0).any()))
 
-    def _sample_one(self, logits, reqs) -> np.ndarray:
-        return np.asarray(self._sample_dispatch(logits, reqs))
-
     def _emit(self, req: GenerationRequest, token: int) -> None:
         req.out_tokens.append(token)
         if len(req.out_tokens) > 1:
@@ -2130,12 +2242,13 @@ class LLMEngine:
 
     # ---- device placement ----
 
-    def _shard_params(self, params, cfg: LlamaConfig):
+    def _shard_params(self, params, cfg):
         if self.mesh is None:
             return params
-        return shard_params(params, self.mesh, param_logical_axes(cfg))
+        return shard_params(params, self.mesh,
+                            served_model(cfg).param_logical_axes(cfg))
 
-    def _new_cache(self, cfg: LlamaConfig, dense: bool = False):
+    def _new_cache(self, cfg, dense: bool = False):
         """A zeroed KV cache in this engine's layout (``dense`` forces slot
         lines: the draft model's cache is never blocked), its kv-head dim
         split over tp like the k/v projections that fill it."""
@@ -2143,7 +2256,8 @@ class LLMEngine:
             cache = init_kv_cache_blocked(cfg, self.num_blocks,
                                           self.block_size)
         else:
-            cache = init_kv_cache(cfg, self.max_slots, self.max_seq)
+            cache = served_model(cfg).init_cache(cfg, self.max_slots,
+                                                 self.max_seq)
         if self.mesh is None:
             return cache
         # Both layouts are [layers, slots|blocks, Hkv, positions, D].

@@ -28,7 +28,7 @@ from typing import Any
 
 from ray_tpu import serve
 from ray_tpu.llm.config import LLMConfig
-from ray_tpu.llm.engine import LLMEngine
+from ray_tpu.llm.engine import LLMEngine, require_kv_handoff
 from ray_tpu.llm.serving import _sampling_from
 from ray_tpu.util import tracing
 
@@ -138,11 +138,18 @@ def resolve_kv_payload(payload: dict) -> dict:
     return out
 
 
+def _handoff_engine(llm_config: LLMConfig) -> LLMEngine:
+    """The engine of one side of the hand-off, refused before anything is
+    built for a model whose cache lines are not per-head K/V."""
+    require_kv_handoff(llm_config.model_config())
+    return LLMEngine(llm_config)
+
+
 class PrefillServer:
     """Computes prompt KV + the first token; no decode loop runs here."""
 
     def __init__(self, llm_config: LLMConfig):
-        self.engine = LLMEngine(llm_config)
+        self.engine = _handoff_engine(llm_config)
         self._mode = getattr(llm_config, "pd_transfer_mode", "store")
 
     def prefill(self, prompt_ids: list[int], sampling_kw: dict) -> dict:
@@ -164,7 +171,7 @@ class DecodeServer:
     """Continues generation from shipped KV; never prefills."""
 
     def __init__(self, llm_config: LLMConfig):
-        self.engine = LLMEngine(llm_config)
+        self.engine = _handoff_engine(llm_config)
 
     def decode(self, payload: dict, sampling_kw: dict) -> dict:
         req = self.engine.submit_prefilled(

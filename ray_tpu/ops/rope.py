@@ -1,5 +1,6 @@
 """Rotary position embeddings (RoPE), Llama-3 style with NTK frequency
-scaling. Pure jnp — XLA fuses the elementwise rotation into the surrounding
+scaling, and the adjacent-pair rotation of the latent-attention family.
+Pure jnp — XLA fuses the elementwise rotation into the surrounding
 projections, so no kernel is needed.
 """
 
@@ -45,3 +46,21 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def apply_rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
+                           inv_freq: jnp.ndarray) -> jnp.ndarray:
+    """Rotate adjacent pairs (2i, 2i + 1), the convention of the latent
+    (MLA) attention family, where :func:`apply_rope` rotates (i, i + D/2).
+    x: [B, H, S, D]; positions: [S] or [B, S] absolute. The result keeps the
+    interleaved layout, so a dot product of two vectors rotated here equals
+    that of the same vectors permuted to halves and rotated there."""
+    if positions.ndim == 1:
+        positions = positions[None, :]
+    angles = positions[:, :, None].astype(jnp.float32) * inv_freq[None, None, :]
+    cos = jnp.cos(angles)[:, None, :, :]  # [B, 1, S, D/2]
+    sin = jnp.sin(angles)[:, None, :, :]
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)

@@ -306,3 +306,66 @@ def test_decode_kernels_compile_for_speculative_verify(mosaic):
     assert text.count(MOSAIC) == 2
     assert _opcodes_with_shape(text, f"[2,{slots},{hkv},{s},{d}]") <= {
         "parameter", "get-tuple-element", "tuple", "custom-call", "bitcast"}
+
+
+# LongCat-Flash at the published widths, one double layer, 4 of 512 experts:
+# the programs of llm/longcat_serving.py as the serving cell compiles them.
+def _longcat_programs(mosaic, slots=32, max_seq=8192):
+    from ray_tpu.llm import longcat_serving as serving
+    from ray_tpu.models import longcat
+
+    cfg = longcat.LongcatConfig(num_layers=1, vocab_size=16384,
+                                max_seq_len=max_seq, expert_shards=128)
+    dev = NamedSharding(build_mesh(MeshSpec(), mosaic[:1]), P())
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=dev), tree)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    params = placed(jax.eval_shape(partial(longcat.init_params, cfg),
+                                   jax.random.PRNGKey(0)))
+    cache = placed(jax.eval_shape(partial(serving.init_cache, cfg, slots,
+                                          max_seq)))
+    prefill = serving.prefill_chunk.lower(
+        cfg, params, cache, arg((512,)), arg(()), arg(()), arg(())).compile()
+    burst = serving.decode_burst.lower(
+        cfg, params, cache, arg((slots,)), arg((slots,)),
+        arg((slots,), jnp.bool_), arg((slots,), jnp.float32),
+        arg((slots,), jnp.float32), arg((2,), jnp.uint32), 8,
+        False).compile()
+    return cfg, prefill, burst
+
+
+def test_longcat_programs_move_no_whole_cache_and_copy_no_layer(mosaic):
+    """What the first compile of PR 27 got wrong, kept from coming back: a
+    576-wide cache is stored positions-innermost and copied whole around
+    every kernel call (so rows are 640 wide), and a scanned slice that the
+    pair of a double layer shares is copied out of the stack (so every leaf
+    is indexed where it is used)."""
+    cfg, prefill, burst = _longcat_programs(mosaic)
+    assert cfg.latent_row == 640
+    stack = f"[2,32,8192,{cfg.latent_row}]"
+    for compiled, kernels, passing in (
+            (burst, ("latent_decode_attention", "latent_row_write",
+                     "moe_grouped_matmul"),
+             {"parameter", "get-tuple-element", "tuple", "while",
+              "custom-call", "bitcast"}),
+            (prefill, ("moe_grouped_matmul",),
+             {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
+              "dynamic-update-slice", "dynamic-slice", "fusion"})):
+        text = compiled.as_text()
+        for name in kernels:
+            assert f"%{name}." in text
+        assert _opcodes_with_shape(text, stack) <= passing
+        # No whole dense FFN matrix as the result of a copy or a slice
+        # fusion at the top of the layer loop.
+        for line in text.splitlines():
+            head = line.split(" = ", 1)
+            if len(head) == 2 and head[1].startswith("bf16[6144,12288]"):
+                assert " convolution(" in line or " parameter(" in line \
+                    or "bitcast" in line or "fused_computation" in line \
+                    or "dynamic-slice" in line, line[:200]
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
