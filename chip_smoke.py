@@ -151,7 +151,8 @@ def kernel_phase(cfg: SmokeConfig, n_devices: int) -> dict:
     """The Pallas kernels against their jnp references on a small input:
     rms_norm and causal GQA flash attention, forward and gradients, per
     shard over the local chips when there are several; then rms_norm on a
-    row count that no block divides, and the serving decode kernels.
+    row count that no block divides, and the serving decode and prefill
+    kernels.
     Returns the largest relative errors."""
     import jax
     import jax.numpy as jnp
@@ -196,7 +197,7 @@ def kernel_phase(cfg: SmokeConfig, n_devices: int) -> dict:
     errors["rms_norm_424_rows"] = relative_error(
         jax.jit(lambda x, w: rms_norm(x, w, 1e-5))(x, wx),
         rms_norm_reference(x, wx, 1e-5))
-    errors.update(_decode_kernel_errors(cfg, kmesh, keys, b, hkv, d))
+    errors.update(_serving_kernel_errors(cfg, kmesh, keys, b, hkv, d))
     bad = {name: e for name, e in errors.items()
            if not np.isfinite(e) or e > 2e-2}
     if bad:
@@ -205,12 +206,12 @@ def kernel_phase(cfg: SmokeConfig, n_devices: int) -> dict:
     return errors
 
 
-def _decode_kernel_errors(cfg: SmokeConfig, kmesh, keys, b: int, hkv: int,
+def _serving_kernel_errors(cfg: SmokeConfig, kmesh, keys, b: int, hkv: int,
                           d: int) -> dict:
     """The serving decode kernels on layer 1 of a stack of two: one new row
     a slot written in place, then grouped attention over the live blocks,
     per shard over the slots; lines empty, of one row, across a block edge
-    and full."""
+    and full. Then the prefill kernel on the same stack."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -240,7 +241,36 @@ def _decode_kernel_errors(cfg: SmokeConfig, kmesh, keys, b: int, hkv: int,
 
     (got_k, got), (want_k, want) = kernels(kc, vc), references(kc, vc)
     return {"kv_row_write": float(not np.array_equal(got_k, want_k)),
-            "decode_attention": relative_error(got, want)}
+            "decode_attention": relative_error(got, want),
+            "prefill_attention": _prefill_kernel_error(kmesh, keys, kc, vc)}
+
+
+def _prefill_kernel_error(kmesh, keys, kc, vc) -> float:
+    """The serving prefill kernel on layer 1 of the same stack: a padded
+    final chunk clamped to the tail of the last slot's line (neither its
+    size nor its first row aligned to anything), written in place, then
+    attended to through ops/prefill_attention.py."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import prefill_attention as pa
+
+    _, b, hkv, s, d = kc.shape
+    c = s // 2 - 24
+    slot, kv_len, length = b - 1, s - c, s - 3
+    q = jax.random.normal(keys[0], (2 * hkv, c, d), jnp.bfloat16)
+    nk = jax.random.normal(keys[3], (hkv, c, d), jnp.bfloat16)
+    nv = jax.random.normal(keys[4], (hkv, c, d), jnp.bfloat16)
+
+    def run(attend):
+        def f(kc, vc):
+            kc, vc = pa.prefill_kv_write(kc, vc, nk, nv, 1, slot, kv_len)
+            return attend(q, kc, vc, 1, slot, kv_len, length)
+        return jax.jit(f)(kc, vc)[:, :length - kv_len]
+
+    return relative_error(
+        run(lambda *a: pa.prefill_attention(*a, kmesh=kmesh, block_k=128)),
+        run(pa.prefill_attention_reference))
 
 
 # ---------------------------------------------------------------------- train

@@ -1,6 +1,7 @@
 """The decode-attention kernels and the decode program, timed on the chip.
 
     chiprun -- python3 devbench/decode_attention_bench.py [kernel] [burst]
+                                          [prefill_kernel] [prefill]
 
 - ``kernel``: ``ops.decode_attention`` and ``kv_row_write`` alone over all
   layers of a stacked cache at the two serving shapes of the benchmark
@@ -9,6 +10,12 @@
   result against the jnp reference on the same inputs.
 - ``burst``: ``engine.decode_burst(steps=8)`` as the engine calls it, random
   weights, ms a step, with the weight bytes' floor beside it.
+- ``prefill_kernel``: ``ops.prefill_attention`` alone over all layers for a
+  chunk of 512 at 0 / 1,024 / 2,560 cached rows, at a few tile sizes, and
+  its result against the jnp reference.
+- ``prefill``: ``engine.prefill_chunk(512)`` as the engine calls it at the
+  same cached rows (docqa's and reason's shapes), ms a chunk, with the time
+  its matmuls need at the chip's peak beside it.
 
 Prints one JSON object as its last line. Times are host clock around
 ``block_until_ready`` over repeated calls of one jitted program.
@@ -25,6 +32,7 @@ from functools import partial
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 HBM_BYTES_PER_S = 819e9  # TPU v5e, Google Cloud documentation
+PEAK_FLOPS = 197e12      # bf16, same source
 
 # Mistral-7B widths; per cell: layers as served, slots, max_seq, live lengths
 # drawn from [lo, hi), share of busy slots, blocks to sweep.
@@ -32,6 +40,10 @@ WIDTHS = dict(hidden_size=4096, intermediate_size=14336, num_heads=32,
               num_kv_heads=8, head_dim=128, vocab_size=32768)
 SHAPES = {"chat": (12, 32, 2048, (64, 900), 0.72, (128, 256, 512, 1024)),
           "docqa": (16, 16, 3200, (1100, 3100), 0.8, (128, 640))}
+# The prefill side: layers as served, slots, max_seq; a chunk of 512 behind
+# each of CACHED_ROWS (2,560 + 512 is the end of reason's line).
+PREFILL_SHAPES = {"docqa": (16, 16, 3200), "reason": (12, 32, 3072)}
+CHUNK, CACHED_ROWS = 512, (0, 1024, 2560)
 
 
 def _time(fn, *args, reps: int = 20):
@@ -183,6 +195,99 @@ def bench_burst() -> dict:
     return out
 
 
+def bench_prefill_kernel() -> dict:
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ray_tpu.ops import prefill_attention as pa
+    from ray_tpu.ops.decode_attention import decode_kv_block
+    from ray_tpu.ops.kernels import force_kernel_backend
+
+    out = {}
+    hkv, d, h = WIDTHS["num_kv_heads"], WIDTHS["head_dim"], WIDTHS["num_heads"]
+    for name, (layers, slots, s) in PREFILL_SHAPES.items():
+        keys = jax.random.split(jax.random.PRNGKey(0), 3)
+        q = jax.random.normal(keys[0], (h, CHUNK, d), jnp.bfloat16)
+        kc = jax.random.normal(keys[1], (layers, slots, hkv, s, d),
+                               jnp.bfloat16)
+        vc = jax.random.normal(keys[2], (layers, slots, hkv, s, d),
+                               jnp.bfloat16)
+        blocks_k = [b for b in (128, 256, 512, 640) if s % b == 0]
+        row = {"default_block_q": pa.prefill_q_block(CHUNK, h // hkv),
+               "default_block_k": decode_kv_block(s, d)}
+
+        def all_layers(q, kc, vc, kv_len, bq, bk):
+            def body(layer, q):
+                return pa.prefill_attention(q, kc, vc, layer, 3, kv_len,
+                                            kv_len + CHUNK, block_q=bq,
+                                            block_k=bk)
+            return lax.fori_loop(0, layers, body, q)
+
+        for bq in (128, 256, 512):
+            for bk in blocks_k:
+                fn = jax.jit(partial(all_layers, bq=bq, bk=bk))
+                for kv_len in CACHED_ROWS:
+                    row[f"attn_ms_q{bq}_k{bk}_at{kv_len}"] = 1e3 * _time(
+                        fn, q, kc, vc, jnp.int32(kv_len), reps=10)
+        for kv_len in CACHED_ROWS:
+            # Causal: a query sees the cached rows and half the chunk.
+            flops = 4 * h * CHUNK * (kv_len + CHUNK / 2) * d * layers
+            row[f"floor_ms_at{kv_len}"] = 1e3 * flops / PEAK_FLOPS
+        args = (q, kc, vc, 2, 3, 1061, 1061 + CHUNK - 7)
+        got = jax.jit(pa.prefill_attention)(*args)
+        with force_kernel_backend("reference"):
+            want = jax.jit(pa.prefill_attention)(*args)
+        row["max_abs_diff_vs_reference"] = float(jnp.max(jnp.abs(
+            got.astype(jnp.float32) - want.astype(jnp.float32))))
+        out[name] = row
+        del kc, vc
+    return out
+
+
+def bench_prefill() -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.llm import engine
+    from ray_tpu.models.llama import LlamaConfig, init_params
+
+    out = {}
+    for name, (layers, slots, s) in PREFILL_SHAPES.items():
+        cfg = LlamaConfig(
+            num_layers=layers, max_seq_len=s, dtype="bfloat16",
+            tie_embeddings=False, rope_theta=1e6, **WIDTHS)
+        params = jax.jit(partial(init_params, cfg))(jax.random.PRNGKey(0))
+        cache = engine.init_kv_cache(cfg, slots, s)
+        toks = jnp.asarray(np.random.default_rng(0).integers(
+            0, cfg.vocab_size, size=CHUNK).astype(np.int32))
+        layer_params = sum(int(np.prod(a.shape))
+                           for a in jax.tree.leaves(params["layers"]))
+        row = {"layers": layers, "slots": slots, "max_seq": s,
+               "matmul_floor_ms": 1e3 * 2 * CHUNK * layer_params / PEAK_FLOPS}
+        for kv_len in CACHED_ROWS:
+            def run(cache):
+                return engine.prefill_chunk(
+                    cfg, params, cache, toks, jnp.int32(kv_len),
+                    jnp.int32(kv_len + CHUNK), jnp.int32(3))
+
+            cache, logits = run(cache)
+            jax.block_until_ready(logits)
+            t0 = time.perf_counter()
+            reps = 10
+            for _ in range(reps):
+                cache, logits = run(cache)
+            jax.block_until_ready(logits)
+            row[f"chunk_ms_at{kv_len}"] = (
+                1e3 * (time.perf_counter() - t0) / reps)
+        row["memory_peak_bytes"] = (
+            jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
+        out[name] = row
+        del params, cache
+    return out
+
+
 def main(argv: list[str]) -> int:
     import jax
 
@@ -196,6 +301,10 @@ def main(argv: list[str]) -> int:
         out["kernel"] = bench_kernel()
     if "burst" in which:
         out["burst"] = bench_burst()
+    if "prefill_kernel" in which:
+        out["prefill_kernel"] = bench_prefill_kernel()
+    if "prefill" in which:
+        out["prefill"] = bench_prefill()
     print(json.dumps(out))
     return 0
 

@@ -31,7 +31,7 @@ def test_smoke_phases_run_on_cpu(cpu_mesh_devices):
     train, serve = out["train"], out["serve"]
     assert set(out["kernels"]) == {"out", "dq", "dk", "dv", "dw",
                                    "rms_norm_424_rows", "kv_row_write",
-                                   "decode_attention"}
+                                   "decode_attention", "prefill_attention"}
     assert len(train["losses"]) == 4 and train["losses"][-1] < train["losses"][0]
     assert all(n > 0 for n in train["kernels"].values()), train["kernels"]
     assert len(train["memory"]) == 2

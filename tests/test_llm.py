@@ -155,38 +155,106 @@ def test_llm_server_openai_surface():
         ray_tpu.shutdown()
 
 
-def test_chunked_prefill_matches_full(tiny):
+# (adopted, [(start, bucket, take), ...]) over a prompt of 30 tokens in a
+# line of 32: chunks of one size; a prefill that starts where an adopted
+# prefix ends, aligned to nothing, in padded buckets; and a last chunk whose
+# bucket is clamped to the cache's tail (12 rows, no power of two).
+CHUNK_SCHEDULES = {
+    "even": (0, [(0, 8, 8), (8, 8, 8), (16, 8, 8), (24, 8, 6)]),
+    "adopted_prefix": (5, [(5, 16, 16), (21, 8, 8), (29, 2, 1)]),
+    "clamped_to_tail": (0, [(0, 16, 16), (16, 4, 4), (20, 12, 10)]),
+}
+
+
+@pytest.mark.parametrize("backend", ["reference", "interpret"])
+@pytest.mark.parametrize("schedule", sorted(CHUNK_SCHEDULES))
+def test_chunked_prefill_matches_full(tiny, schedule, backend):
     """prefill_chunk over N chunks must equal one whole-prompt prefill
     (same cache contents, same last-token logits)."""
-    from ray_tpu.llm.engine import prefill_chunk
+    from ray_tpu.llm.engine import copy_prefix_kv, prefill_chunk
+    from ray_tpu.ops.kernels import force_kernel_backend
 
     cfg, params = tiny
-    prompt = np.arange(1, 13, dtype=np.int32)  # 12 tokens
+    prompt = np.arange(1, 31, dtype=np.int32)  # 30 tokens
     p = len(prompt)
+    adopted, chunks = CHUNK_SCHEDULES[schedule]
 
-    cache_full = init_kv_cache(cfg, max_slots=2, max_seq=32)
-    toks = np.zeros((16,), np.int32)
-    toks[:p] = prompt
-    cache_full, last_full = prefill(cfg, params, cache_full,
-                                    jnp.asarray(toks), jnp.int32(p),
-                                    jnp.int32(1))
+    def whole(slot):
+        toks = np.zeros((32,), np.int32)
+        toks[:p] = prompt
+        return prefill(cfg, params,
+                       init_kv_cache(cfg, max_slots=2, max_seq=32),
+                       jnp.asarray(toks), jnp.int32(p), jnp.int32(slot))
 
+    cache_full, last_full = whole(1)
     cache_c = init_kv_cache(cfg, max_slots=2, max_seq=32)
+    if adopted:
+        # Slot 0 holds the prompt; slot 1 adopts its line and prefills
+        # from the end of the shared prefix.
+        cache_c, _ = whole(0)
+        cache_c = copy_prefix_kv(cfg, cache_c, jnp.int32(0), jnp.int32(1))
+        donor = np.asarray(cache_c["k"][:, 0]).copy()
     last_c = None
-    for start in range(0, p, 4):  # 3 chunks of 4
-        chunk = np.zeros((4,), np.int32)
-        chunk[:] = prompt[start:start + 4]
-        cache_c, last_c = prefill_chunk(cfg, params, cache_c,
-                                        jnp.asarray(chunk),
-                                        jnp.int32(start), jnp.int32(p),
-                                        jnp.int32(1))
+    with force_kernel_backend(backend):
+        for start, bucket, take in chunks:
+            chunk = np.zeros((bucket,), np.int32)
+            chunk[:take] = prompt[start:start + take]
+            cache_c, last_c = prefill_chunk(cfg, params, cache_c,
+                                            jnp.asarray(chunk),
+                                            jnp.int32(start), jnp.int32(p),
+                                            jnp.int32(1))
+    assert start + take == p
     np.testing.assert_allclose(np.asarray(last_c), np.asarray(last_full),
                                rtol=2e-4, atol=2e-4)
     # cache contents match where real tokens live
-    np.testing.assert_allclose(
-        np.asarray(cache_c["k"][:, 1, :, :p]).astype(np.float32),
-        np.asarray(cache_full["k"][:, 1, :, :p]).astype(np.float32),
-        rtol=2e-3, atol=2e-3)
+    for name in ("k", "v"):
+        np.testing.assert_allclose(
+            np.asarray(cache_c[name][:, 1, :, :p]).astype(np.float32),
+            np.asarray(cache_full[name][:, 1, :, :p]).astype(np.float32),
+            rtol=2e-3, atol=2e-3)
+    if adopted:  # the other slot's line is as it was
+        np.testing.assert_array_equal(np.asarray(cache_c["k"][:, 0]), donor)
+    else:
+        assert not np.asarray(cache_c["k"][:, 0]).any()
+
+
+def test_prefill_kv_position_counters_grow_a_chunk_at_a_time():
+    """prefill_kv_positions_read is what a length-aware prefill attention
+    has to visit a chunk (the cached rows and the chunk's bucket),
+    prefill_kv_positions_reserved the whole line a dense one scores."""
+    cfg = LLMConfig(model="tiny", max_num_seqs=2, max_seq_len=96)
+    cfg.prefill_chunk = 16
+    eng = LLMEngine(cfg)
+    try:
+        st = eng.stats()
+        assert st["prefill_kv_positions_read"] == 0
+        assert st["prefill_kv_positions_reserved"] == 0
+        prompt = [int(t) for t in
+                  np.random.default_rng(0).integers(1, 200, 40)]
+        eng.generate(prompt, SamplingParams(max_tokens=2, temperature=0.0),
+                     timeout=120)
+        st = eng.stats()
+        # Chunks at 0, 16 and 32, the last one 8 tokens in a bucket of 16
+        # (prefill_bucket_min): kv_len + bucket each.
+        assert st["prefill_chunks"] == 3
+        assert st["prefill_kv_positions_read"] == 16 + 32 + 48
+        assert st["prefill_kv_positions_reserved"] == 3 * 96
+        # The same prompt again adopts its prefix and prefills the rest in
+        # one chunk that starts where the prefix ends.
+        eng.generate(prompt[:37] + [7, 8, 9],
+                     SamplingParams(max_tokens=2, temperature=0.0),
+                     timeout=120)
+        now = eng.stats()
+        assert now["prefix_hits"] == 1
+        saved = now["prefix_tokens_saved"]
+        assert 0 < saved <= 37
+        chunks = now["prefill_chunks"] - 3
+        assert now["prefill_kv_positions_reserved"] == (3 + chunks) * 96
+        grew = now["prefill_kv_positions_read"] - st[
+            "prefill_kv_positions_read"]
+        assert saved * chunks < grew <= 96 * chunks
+    finally:
+        eng.shutdown()
 
 
 def test_decode_write_mask_protects_prefilling_slot(tiny):

@@ -4,6 +4,8 @@ Pallas kernels run in interpret mode on CPU via pltpu force_tpu_interpret_mode
 where exercised; numerical ground truth is the O(S²) reference.
 """
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -554,3 +556,120 @@ def test_decode_kv_block_divides_the_serving_lines():
     assert decode_kv_block(64, 16, 4) == 64  # no multiple of 128: one block
     got = kv_positions_read(np.array([0, 1, 512, 513, 2048]), 512)
     assert got.tolist() == [0, 512, 512, 1024, 2048]
+
+
+# --------------------------------------------------------- prefill attention
+
+def _plain_prefill_attention(q, kc, vc, layer, slot, kv_len, length):
+    """float32 masked softmax over the slot's full line, K/V repeated per
+    query head: what the grouped, length-aware op has to equal."""
+    h, c, d = q.shape
+    hkv, s = kc.shape[2], kc.shape[3]
+    kl = jnp.repeat(kc[layer, slot].astype(jnp.float32), h // hkv, axis=0)
+    vl = jnp.repeat(vc[layer, slot].astype(jnp.float32), h // hkv, axis=0)
+    scores = jnp.einsum("hcd,hsd->hcs", q.astype(jnp.float32), kl,
+                        precision="highest") / np.sqrt(d)
+    kpos = jnp.arange(s)[None, :]
+    qpos = kv_len + jnp.arange(c)[:, None]
+    visible = ((kpos <= qpos) & (kpos < length))[None]
+    p = jax.nn.softmax(jnp.where(visible, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("hcs,hsd->hcd", p, vl, precision="highest")
+
+
+# Buckets: the smallest, a chunk clamped to the cache's tail (no power of
+# two), the largest. Cached rows: none, unaligned (a prefill that starts
+# where an adopted prefix ends), several blocks deep.
+PREFILL_CASES = [(c, kv) for c in (16, 40, 512) for kv in (0, 37, 300)]
+
+
+@pytest.mark.parametrize("backend", ["interpret", "reference"])
+@pytest.mark.parametrize("pad", [0, 5])  # 5: a padded final chunk
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("chunk,kv_len", PREFILL_CASES)
+def test_prefill_attention_matches_plain_softmax(chunk, kv_len, group, pad,
+                                                 backend):
+    from ray_tpu.ops.prefill_attention import prefill_attention
+
+    layers, slots, hkv, s, d = 2, 3, 2, 1024, 64
+    layer, slot, length = 1, 2, kv_len + chunk - pad
+    keys = jax.random.split(jax.random.PRNGKey(chunk + kv_len + group), 3)
+    q = jax.random.normal(keys[0], (hkv * group, chunk, d), jnp.bfloat16)
+    # Every row but the prompt's own in its own layer and slot holds large
+    # garbage: a block read by mistake, or the wrong line, shows.
+    live = np.zeros((layers, slots, 1, s, 1), bool)
+    live[layer, slot, :, :length] = True
+    kc = jnp.where(live, jax.random.normal(
+        keys[1], (layers, slots, hkv, s, d)), 3e4).astype(jnp.bfloat16)
+    vc = jnp.where(live, jax.random.normal(
+        keys[2], (layers, slots, hkv, s, d)), -3e4).astype(jnp.bfloat16)
+    want = _plain_prefill_attention(q, kc, vc, layer, slot, kv_len, length)
+    with force_kernel_backend(backend):
+        got = jax.jit(partial(prefill_attention, block_k=DECODE_BLOCK))(
+            q, kc, vc, layer, slot, kv_len, length)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    # bf16 probabilities and output: 2^-8 relative on values of order one.
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), atol=3e-2)
+
+
+@pytest.mark.parametrize("backend", ["interpret", "reference"])
+def test_prefill_attention_tiles_of_the_chunk_agree(backend):
+    """Several tiles of queries (the serving shape has two of 256 tokens),
+    each with its own last live block, against one tile of the whole
+    chunk."""
+    from ray_tpu.ops.prefill_attention import (
+        prefill_attention,
+        prefill_q_block,
+    )
+
+    assert prefill_q_block(512, 4) == 256 and prefill_q_block(40, 4) == 48
+    assert prefill_q_block(16, 1) == 16 and prefill_q_block(424, 4) == 256
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(keys[0], (8, 128, 64), jnp.bfloat16)
+    kc = jax.random.normal(keys[1], (1, 2, 2, 512, 64), jnp.bfloat16)
+    vc = jax.random.normal(keys[2], (1, 2, 2, 512, 64), jnp.bfloat16)
+    with force_kernel_backend(backend):
+        one, four = (prefill_attention(q, kc, vc, 0, 1, 201, 329,
+                                       block_q=bq, block_k=DECODE_BLOCK)
+                     for bq in (128, 32))
+    np.testing.assert_allclose(np.asarray(one, np.float32),
+                               np.asarray(four, np.float32), atol=2e-2)
+
+
+@pytest.mark.parametrize("chunk,kv_len", PREFILL_CASES)
+def test_prefill_kv_write_touches_only_its_rows(chunk, kv_len):
+    from ray_tpu.ops.prefill_attention import prefill_kv_write
+
+    layers, slots, hkv, s, d = 2, 3, 2, 1024, 64
+    keys = jax.random.split(jax.random.PRNGKey(chunk), 4)
+    kc = jax.random.normal(keys[0], (layers, slots, hkv, s, d), jnp.bfloat16)
+    vc = jax.random.normal(keys[1], (layers, slots, hkv, s, d), jnp.bfloat16)
+    nk = jax.random.normal(keys[2], (hkv, chunk, d), jnp.bfloat16)
+    nv = jax.random.normal(keys[3], (hkv, chunk, d), jnp.bfloat16)
+    want_k, want_v = np.array(kc), np.array(vc)
+    want_k[1, 2, :, kv_len:kv_len + chunk] = np.asarray(nk)
+    want_v[1, 2, :, kv_len:kv_len + chunk] = np.asarray(nv)
+    got_k, got_v = jax.jit(prefill_kv_write)(kc, vc, nk, nv, 1, 2, kv_len)
+    np.testing.assert_array_equal(np.asarray(got_k), want_k)
+    np.testing.assert_array_equal(np.asarray(got_v), want_v)
+
+
+def test_prefill_attention_under_a_mesh_runs_on_each_shards_heads(
+        cpu_mesh_devices):
+    """tensor_parallel_size > 1 shards the KV heads: the kernel runs per
+    shard on its heads of the stack and gives what the whole arrays give."""
+    from ray_tpu.ops.prefill_attention import prefill_attention
+    from ray_tpu.parallel.sharding import kernel_mesh
+
+    kmesh = kernel_mesh(build_mesh(MeshSpec(tp=2), cpu_mesh_devices[:2]))
+    assert kmesh.heads == "tp"
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(keys[0], (8, 40, 64), jnp.bfloat16)
+    kc = jax.random.normal(keys[1], (2, 2, 4, 256, 64), jnp.bfloat16)
+    vc = jax.random.normal(keys[2], (2, 2, 4, 256, 64), jnp.bfloat16)
+    want = _plain_prefill_attention(q, kc, vc, 1, 1, 100, 137)
+    with force_kernel_backend("interpret"):
+        got = jax.jit(partial(prefill_attention, kmesh=kmesh))(
+            q, kc, vc, 1, 1, 100, 137)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), atol=3e-2)

@@ -195,9 +195,11 @@ def test_engine_programs_partition_over_tensor_parallel_chips(mosaic):
         arg((slots,), jnp.bool_), arg((slots,), jnp.float32),
         arg((slots,), jnp.float32), arg((2,), jnp.uint32), 4, False,
         kmesh=kmesh).compile().as_text()
-    # attn_norm, mlp_norm, final_norm; decode also writes its rows and
-    # attends through ops/decode_attention.py, each on its shard's heads.
-    assert prefill.count(MOSAIC) == 3 and decode.count(MOSAIC) == 5
+    # attn_norm, mlp_norm, final_norm; prefill attends through
+    # ops/prefill_attention.py, decode writes its rows and attends through
+    # ops/decode_attention.py, each on its shard's heads.
+    assert prefill.count(MOSAIC) == 4 and decode.count(MOSAIC) == 5
+    assert '"prefill_attention"' in prefill
     for text in (prefill, decode):
         assert "num_partitions=4" in text
         # Activations are replicated over tp; only reductions cross chips
@@ -213,7 +215,9 @@ MISTRAL = LlamaConfig(vocab_size=32768, hidden_size=4096,
                       dtype="bfloat16", tie_embeddings=False, rope_theta=1e6)
 
 
-def _decode_burst_compiled(mesh, kmesh, slots, max_seq, steps=8):
+def _mistral_state(mesh, slots, max_seq):
+    """Shapes of MISTRAL's weights and of a cache of ``slots`` lines on
+    ``mesh`` (KV heads over tp), and a maker of replicated arguments."""
     from ray_tpu.llm import engine
 
     repl = NamedSharding(mesh, P())
@@ -230,6 +234,13 @@ def _decode_burst_compiled(mesh, kmesh, slots, max_seq, steps=8):
     def arg(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
 
+    return params, cache, arg
+
+
+def _decode_burst_compiled(mesh, kmesh, slots, max_seq, steps=8):
+    from ray_tpu.llm import engine
+
+    params, cache, arg = _mistral_state(mesh, slots, max_seq)
     return engine.decode_burst.lower(
         MISTRAL, params, cache, arg((slots,)), arg((slots,)),
         arg((slots,), jnp.bool_), arg((slots,), jnp.float32),
@@ -270,6 +281,41 @@ def test_decode_burst_moves_no_whole_cache_at_mistral_widths(mosaic, slots,
     for shape in (f"[{MISTRAL.num_layers},{slots},{hkv},{max_seq},{d}]",
                   f"[{slots},{hkv},{max_seq},{d}]"):
         assert _opcodes_with_shape(text, shape) <= passing, shape
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("slots,max_seq",
+                         [(32, 2048), (16, 3200), (32, 3072)])
+def test_prefill_chunk_moves_no_whole_cache_at_mistral_widths(mosaic, slots,
+                                                              max_seq):
+    """The three serving shapes of the benchmark, a chunk of 512: the cache
+    rides the layer loop as carry, the chunk's rows go in by an in-place
+    dynamic-update-slice, attention reads the stack through the kernel."""
+    from ray_tpu.llm import engine
+
+    chunk = 512
+    params, cache, arg = _mistral_state(
+        build_mesh(MeshSpec(), mosaic[:1]), slots, max_seq)
+    compiled = engine.prefill_chunk.lower(
+        MISTRAL, params, cache, arg((chunk,)), arg(()), arg(()),
+        arg(())).compile()
+    text = compiled.as_text()
+    assert text.count(MOSAIC) == 4 and '"prefill_attention"' in text
+    hkv, d, h = MISTRAL.num_kv_heads, MISTRAL.head_dim, MISTRAL.num_heads
+    # No K/V repeated over the query heads of a group, no slot's line
+    # sliced out, no dense [C, max_seq] scores, no logits of every row.
+    for shape in (f"[{slots},{hkv},{h // hkv},{max_seq},{d}]",
+                  f"[1,{h},{max_seq},{d}]", f"[1,{hkv},{max_seq},{d}]",
+                  f"[1,{h},{chunk},{max_seq}]", f"[{h},{chunk},{max_seq}]",
+                  f"[{chunk},{MISTRAL.vocab_size}]"):
+        assert shape not in text, shape
+    # The stacked cache only passes through, or is written in place; a
+    # layer of it is never an operand or a result.
+    passing = {"parameter", "get-tuple-element", "tuple", "while",
+               "custom-call", "bitcast", "dynamic-update-slice"}
+    stack = f"[{MISTRAL.num_layers},{slots},{hkv},{max_seq},{d}]"
+    assert _opcodes_with_shape(text, stack) <= passing
+    assert not _opcodes_with_shape(text, f"[{slots},{hkv},{max_seq},{d}]")
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
