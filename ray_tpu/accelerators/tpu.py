@@ -62,8 +62,8 @@ def chips_per_host(pod_type: str) -> int:
 def require_tpu(program: str):
     """JAX's default device if it is a TPU; otherwise the process exits
     non-zero with the platform it found. For programs that measure on the
-    chip or not at all (bench.py, bench_serve.py, devbench probes): they
-    never fall back to another backend."""
+    chip or not at all (the devbench probes): they never fall
+    back to another backend."""
     import jax
 
     device = jax.devices()[0]

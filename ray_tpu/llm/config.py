@@ -83,20 +83,10 @@ class LLMConfig:
     # of one tick each — at the cost of that many chunks of prefill compute
     # between decode steps (time-per-output-token under prefill load).
     prefill_chunks_per_tick: int = 4
-    # Block-pooled KV cache (reference capability: vLLM PagedAttention,
-    # llm/_internal/serve/engines/vllm/vllm_models.py:148 — re-designed
-    # TPU-first): instead of every slot reserving a dense [max_seq] KV
-    # line, K/V live in a shared pool of fixed-size blocks addressed
-    # through a per-slot block table. HBM scales with ACTUAL sequence
-    # lengths, so the same memory serves ~2× the slots at typical
-    # utilization; on pool exhaustion the newest request is preempted
-    # (recompute-style: requeued, its tokens re-prefilled on readmission).
-    # Static shapes throughout — block tables are plain int32 arrays and
-    # reads are gathers, so everything stays jit/XLA-friendly. 0 = dense.
+    # Always 0. The engine has one KV layout (slot lines) and refuses any
+    # other value; the field stays only because the benchmark's traffic
+    # files pass it (ROADMAP D1), and goes when they drop the key.
     kv_block_size: int = 0
-    # Total blocks in the pool; 0 = auto (max_num_seqs × max_seq_len / 2
-    # worth of tokens, i.e. the 2×-slots-at-equal-HBM point).
-    kv_num_blocks: int = 0
     # Prefix-cache publication granularity: prompt token ids are hashed in
     # chained blocks of this many tokens (serve/prefix.py); the engine
     # publishes the chain hashes of every cached prompt prefix so the serve

@@ -9,7 +9,9 @@ wrapper:
   the KV cache is a dense [layers, slots, kv_heads, max_seq, head_dim]
   pool; a sequence owns one slot for its lifetime — slot admission is the
   scheduling unit, like vLLM's paged blocks but shaped for XLA/TPU (no
-  dynamic page tables). A prefill chunk writes its rows of its slot and
+  dynamic page tables). It is the engine's one KV layout; a pool of
+  blocks shared between lines comes with ROADMAP R3, as tables the
+  attention kernels read. A prefill chunk writes its rows of its slot and
   layer in place and reads only the live blocks of that slot's line
   (ops/prefill_attention.py); a decode step does the same for its one
   row a slot (ops/decode_attention.py), both grouped over the query
@@ -37,7 +39,6 @@ import queue
 import threading
 import time
 import uuid
-from collections import deque
 from collections.abc import Callable
 from dataclasses import dataclass, field, replace
 from functools import partial
@@ -392,194 +393,6 @@ def copy_prefix_kv(cfg: LlamaConfig, cache, src_slot, dst_slot):
     }
 
 
-# ---------------------------------------------------------------------------
-# Block-pooled KV cache (reference capability: vLLM PagedAttention behind
-# ray.llm — vllm_models.py:148 — re-designed TPU-first). The pool is
-# [layers, num_blocks, Hkv, block_size, D]; a per-slot block TABLE maps
-# virtual position p to pool block table[slot, p // block_size]. All
-# shapes are static: tables are int32 arrays, reads gather the slot's
-# blocks into a virtual [max_blocks*block_size] sequence (the same masked
-# attention the dense path runs), writes scatter whole blocks (prefill —
-# chunks are block-aligned) or single rows (decode). No device-side page
-# tables, no dynamic shapes — XLA sees gathers and scatters it can fuse.
-
-
-def init_kv_cache_blocked(cfg: LlamaConfig, num_blocks: int,
-                          block_size: int):
-    shape = (cfg.num_layers, num_blocks, cfg.num_kv_heads, block_size,
-             cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.jnp_dtype),
-            "v": jnp.zeros(shape, cfg.jnp_dtype)}
-
-
-def _gather_slot_kv(kv_l, table_row, dtype):
-    """kv_l [NB, Hkv, bs, D] + table_row [MB] -> [1, Hkv, MB*bs, D]
-    virtual sequence for one slot."""
-    g = kv_l[table_row]                       # [MB, Hkv, bs, D]
-    mb, hkv, bs, d = g.shape
-    return g.transpose(1, 0, 2, 3).reshape(1, hkv, mb * bs, d).astype(dtype)
-
-
-def _gather_batch_kv(kv_l, tables, dtype):
-    """kv_l [NB, Hkv, bs, D] + tables [B, MB] -> [B, Hkv, MB*bs, D]."""
-    g = kv_l[tables]                          # [B, MB, Hkv, bs, D]
-    b, mb, hkv, bs, d = g.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, mb * bs, d).astype(
-        dtype)
-
-
-@partial(jax.jit, static_argnums=(0,), static_argnames=("kmesh",),
-         donate_argnums=(2,))
-def prefill_chunk_blocked(cfg: LlamaConfig, params, cache, table_row,
-                          tokens, kv_len, length, *,
-                          kmesh: KernelMesh | None = None):
-    """Blocked-cache chunked prefill for ONE slot. ``table_row`` [MB] is
-    the slot's block table; the engine guarantees kv_len and the chunk
-    bucket are multiples of block_size, so the chunk writes whole blocks.
-    Returns (cache, last-token logits [V])."""
-    c = tokens.shape[0]
-    bs = cache["k"].shape[3]
-    mb = table_row.shape[0]
-    nblk = c // bs
-    x = params["embed_tokens"][tokens][None]  # [1, C, H]
-    positions = kv_len + jnp.arange(c)
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
-                                cfg.rope_scaling)
-    n_rep = cfg.num_heads // cfg.num_kv_heads
-    kpos = jnp.arange(mb * bs)
-    mask = (kpos[None, :] <= positions[:, None]) & (kpos[None, :] < length)
-    mask = mask[None, None]
-    blk0 = kv_len // bs  # first block index within the table (traced)
-
-    def body(x, scanned):
-        lp, k_l, v_l = scanned  # [NB, Hkv, bs, D]
-        b, c_, _ = x.shape
-        xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
-        q, k, v = _project_qkv(cfg, lp, xn, b, c_)
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
-        # Whole-block writes: chunk j lands in pool block table[blk0+j].
-        kb = k[0].astype(k_l.dtype)  # [Hkv, C, D]
-        vb = v[0].astype(v_l.dtype)
-        for j in range(nblk):
-            blk = table_row[blk0 + j]
-            k_l = k_l.at[blk].set(
-                lax.dynamic_slice_in_dim(kb, j * bs, bs, 1))
-            v_l = v_l.at[blk].set(
-                lax.dynamic_slice_in_dim(vb, j * bs, bs, 1))
-        ks = _gather_slot_kv(k_l, table_row, x.dtype)
-        vs = _gather_slot_kv(v_l, table_row, x.dtype)
-        kr, vr = _repeat_kv(ks, n_rep), _repeat_kv(vs, n_rep)
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, kr).astype(jnp.float32)
-        scores = scores / np.sqrt(cfg.head_dim) + jnp.where(mask, 0.0,
-                                                            NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        o = jnp.einsum("bhqk,bhkd->bhqd", probs, vr)
-        o = o.transpose(0, 2, 1, 3).reshape(b, c_, -1)
-        x = x + (o @ lp["wo"]).astype(x.dtype)
-        x = _mlp(cfg, lp, x, kmesh)
-        return x, (k_l, v_l)
-
-    x, (new_k, new_v) = lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
-    logits = _lm_head(cfg, params, x, kmesh)[0]  # [C, V]
-    last = logits[jnp.clip(length - 1 - kv_len, 0, c - 1)]
-    return {"k": new_k, "v": new_v}, last
-
-
-def _multi_token_impl_blocked(cfg: LlamaConfig, params, cache, tables,
-                              tokens, positions0, write_mask, kmesh=None):
-    """Blocked-cache analog of _multi_token_impl: K tokens per slot
-    against the pool through per-slot block tables [B, MB]. Decode writes
-    are row scatters (block = tables[b, p//bs], row = p%bs); masked slots
-    scatter out of bounds and are dropped."""
-    b, k = tokens.shape
-    _, nb, _, bs, _ = cache["k"].shape
-    x = params["embed_tokens"][tokens]  # [B, K, H]
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
-                                cfg.rope_scaling)
-    positions = positions0[:, None] + jnp.arange(k)[None, :]  # [B, K]
-    lengths = jnp.where(write_mask, positions0 + k, 0)
-    # Per-token pool coordinates; masked writes target block NB → dropped.
-    blk = jnp.take_along_axis(tables, positions // bs, axis=1)  # [B, K]
-    blk = jnp.where(write_mask[:, None], blk, nb)
-    row = positions % bs
-
-    def write(cache_l, new):
-        # cache_l [NB, Hkv, bs, D]; new [B, Hkv, K, D] -> rows [B, K, Hkv, D]
-        rows = new.transpose(0, 2, 1, 3).astype(cache_l.dtype)
-        return cache_l.at[blk, :, row, :].set(rows, mode="drop")
-
-    def body(x, scanned):
-        lp, k_l, v_l = scanned
-        xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
-        q, kk, v = _project_qkv(cfg, lp, xn, b, k)
-        q = apply_rope(q, positions, inv_freq)
-        kk = apply_rope(kk, positions, inv_freq)
-        k_l = write(k_l, kk)
-        v_l = write(v_l, v)
-        # The gathered lines are a stack of one layer to the same op the
-        # dense path reads its cache with (a table-aware kernel is D2's).
-        o = decode_attention(
-            q, _gather_batch_kv(k_l, tables, x.dtype)[None],
-            _gather_batch_kv(v_l, tables, x.dtype)[None], 0, lengths,
-            positions0, kmesh=kmesh)
-        o = o.transpose(0, 2, 1, 3).reshape(b, k, -1)
-        x = x + (o @ lp["wo"]).astype(x.dtype)
-        x = _mlp(cfg, lp, x, kmesh)
-        return x, (k_l, v_l)
-
-    x, (new_k, new_v) = lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
-    logits = _lm_head(cfg, params, x, kmesh)  # [B, K, V]
-    return {"k": new_k, "v": new_v}, logits
-
-
-@partial(jax.jit, static_argnums=(0,), static_argnames=("kmesh",),
-         donate_argnums=(2,))
-def decode_step_blocked(cfg: LlamaConfig, params, cache, tables, tokens,
-                        positions, write_mask, *,
-                        kmesh: KernelMesh | None = None):
-    cache, logits = _multi_token_impl_blocked(
-        cfg, params, cache, tables, tokens[:, None], positions, write_mask,
-        kmesh)
-    return cache, logits[:, 0]
-
-
-@partial(jax.jit, static_argnums=(0, 10, 11), static_argnames=("kmesh",),
-         donate_argnums=(2,))
-def decode_burst_blocked(cfg: LlamaConfig, params, cache, tables, token0,
-                         positions0, write_mask, temps, top_ps, key,
-                         steps: int, need_top_p: bool = True, *,
-                         kmesh: KernelMesh | None = None):
-    """Blocked-cache decode_burst: the engine pre-allocates blocks
-    covering positions0+steps for every active slot before dispatch."""
-
-    def step(carry, j):
-        c, tok, pos = carry
-        c, logits = _multi_token_impl_blocked(
-            cfg, params, c, tables, tok[:, None], pos, write_mask, kmesh)
-        nxt = sample_tokens(logits[:, 0].astype(jnp.float32), temps,
-                            top_ps, 0, jax.random.fold_in(key, j),
-                            need_top_p).astype(jnp.int32)
-        return (c, nxt, pos + 1), nxt
-
-    (cache, _, _), toks = lax.scan(step, (cache, token0, positions0),
-                                   jnp.arange(steps))
-    return cache, toks
-
-
-@partial(jax.jit, donate_argnums=(0,))
-def copy_blocks(cache, src_blocks, dst_blocks):
-    """Copy pool blocks src[i] → dst[i], all layers (prefix adoption in
-    blocked mode — content copy; block sharing would need refcounts the
-    preemption path doesn't justify yet)."""
-    return {
-        "k": cache["k"].at[:, dst_blocks].set(cache["k"][:, src_blocks]),
-        "v": cache["v"].at[:, dst_blocks].set(cache["v"][:, src_blocks]),
-    }
-
-
 @partial(jax.jit, static_argnums=(3, 5))
 def sample_tokens(logits, temps, top_ps, top_k: int, key,
                   need_top_p: bool = True):
@@ -713,8 +526,6 @@ class GenerationRequest:
     draft_len: int = 0  # draft-cache positions filled (speculative decoding)
     draft_fail_count: int = 0  # consecutive draft catch-up failures
     spec_disabled: bool = False  # excluded from speculation (see _spec_decode)
-    arrival_seq: int = 0  # admission order; blocked-KV preemption evicts newest
-    prefill_gen: int = 0  # bumped on preemption: stale deferred fetches no-op
     # Unfetched model counts of this prompt's earlier chunks (a model with
     # ServedModel.counters); fetched with the first token.
     chunk_counts: list = field(default_factory=list)
@@ -749,6 +560,11 @@ class LLMEngine:
         self.config = config
         self.model_cfg = config.model_config()
         self.model = served_model(self.model_cfg)
+        if config.kv_block_size:
+            raise ValueError(
+                "kv_block_size must be 0: the engine has one KV layout, "
+                "slot lines read in place; the block pool comes back with "
+                "ROADMAP R3, as tables the attention kernels read")
         if self.model.refuse is not None:
             self.model.refuse(config)
         self.tokenizer = get_tokenizer(config.tokenizer)
@@ -829,29 +645,6 @@ class LLMEngine:
         self.first_tokens = 0
         self.queue_wait_s = 0.0
         self.first_token_wait_s = 0.0
-        # KV layout: dense [slots, max_seq] lines, or the block pool (see
-        # the blocked-cache section above and LLMConfig.kv_block_size).
-        self.block_size = int(getattr(config, "kv_block_size", 0) or 0)
-        self.blocked = self.block_size > 0
-        if self.blocked:
-            if config.speculative_model is not None:
-                raise ValueError(
-                    "speculative decoding requires the dense KV layout "
-                    "(kv_block_size=0)")
-            if self.block_size & (self.block_size - 1):
-                raise ValueError("kv_block_size must be a power of two")
-            if self.max_seq % self.block_size:
-                raise ValueError(
-                    "max_seq_len must be a multiple of kv_block_size")
-            self.blocks_per_slot = self.max_seq // self.block_size
-            self.num_blocks = int(
-                getattr(config, "kv_num_blocks", 0)
-                or (self.max_slots * self.blocks_per_slot + 1) // 2)
-            self._tables = np.zeros(
-                (self.max_slots, self.blocks_per_slot), np.int32)
-            self._free_blocks: list[int] = list(range(self.num_blocks))
-            self._slot_nblk = [0] * self.max_slots
-            self.preemptions = 0
         self.cache = self._new_cache(self.model_cfg)
 
         # Speculative decoding: draft model + its own KV cache. The draft
@@ -876,7 +669,7 @@ class LLMEngine:
                 dp = init_params(self.draft_cfg,
                                  jax.random.PRNGKey(config.seed + 7))
             self.draft_params = self._shard_params(dp, self.draft_cfg)
-            self.draft_cache = self._new_cache(self.draft_cfg, dense=True)
+            self.draft_cache = self._new_cache(self.draft_cfg)
 
         self._slots: dict[int, GenerationRequest | None] = {
             i: None for i in range(self.max_slots)}
@@ -908,14 +701,10 @@ class LLMEngine:
         # scheduler thread frees + retires them at tick start — slot and
         # prefix-cache registries have a single mutating thread.
         self._released: queue.Queue[GenerationRequest] = queue.Queue()
-        # Preempted (blocked-KV) requests re-admit ahead of the queue.
-        self._preempted: deque[GenerationRequest] = deque()
-        self._arrival_seq = 0
         self._requests: dict[str, GenerationRequest] = {}
         # Serve replicas submit from max_concurrency pool threads: the
-        # arrival counter and request-table insert must not interleave
-        # (rtlint R1 — the same non-atomic += class as the PR-12 seq_no
-        # bug). The scheduler thread takes it only for its table pop.
+        # request-table insert must not interleave with another (rtlint
+        # R1). The scheduler thread takes the lock only for its table pop.
         self._submit_lock = threading.Lock()
         self._rng_key = jax.random.PRNGKey(config.seed + 1)
         # Pipelined decode: (active snapshot, burst, device tokens) of a
@@ -947,8 +736,6 @@ class LLMEngine:
             else None
         req.submit_ts = time.time()
         with self._submit_lock:
-            self._arrival_seq += 1
-            req.arrival_seq = self._arrival_seq
             self._requests[req.request_id] = req
         self._waiting.put(req)
         self._work.set()
@@ -974,10 +761,6 @@ class LLMEngine:
         """Run ONLY the prompt prefill; return the KV slice + first sampled
         token for hand-off to a decode engine."""
         require_kv_handoff(self.model_cfg)
-        if self.blocked:
-            raise ValueError(
-                "prefill/decode disaggregation exports dense KV lines; "
-                "run the prefill engine with kv_block_size=0")
         sampling = sampling or SamplingParams()
         ids = (self.tokenizer.encode(prompt) if isinstance(prompt, str)
                else list(prompt))
@@ -1050,10 +833,8 @@ class LLMEngine:
                 if r is req:
                     self._slots[slot] = None
                     self._prefix_live.pop(slot, None)
-                    if self.blocked:
-                        self._free_slot_blocks(slot)
-                    elif (req.finish_reason not in (None, "error")
-                          and not req.error):
+                    if (req.finish_reason not in (None, "error")
+                            and not req.error):
                         # Clean completed prefill: the slot's KV holds
                         # exactly req.prompt_ids' prefix — retire it.
                         self._prefix_cached[slot] = (
@@ -1064,10 +845,6 @@ class LLMEngine:
                          stream: bool = False) -> GenerationRequest:
         """Continue decoding from a shipped prefill (KV import)."""
         require_kv_handoff(self.model_cfg)
-        if self.blocked:
-            raise ValueError(
-                "KV import writes dense KV lines; run the decode engine "
-                "with kv_block_size=0")
         sampling = sampling or SamplingParams()
         req = GenerationRequest(
             request_id=uuid.uuid4().hex[:12],
@@ -1164,11 +941,6 @@ class LLMEngine:
                "queue_wait_s": self.queue_wait_s,
                "first_token_wait_s": self.first_token_wait_s,
                **self.model_counts}
-        if self.blocked:
-            out["kv_blocks_total"] = self.num_blocks
-            out["kv_blocks_free"] = len(self._free_blocks)
-            out["kv_block_size"] = self.block_size
-            out["preemptions"] = self.preemptions
         if self.draft_cfg is not None:
             out["spec_ticks"] = self.spec_ticks
             out["spec_proposed"] = self.spec_proposed
@@ -1211,7 +983,7 @@ class LLMEngine:
         with tracing.phase("engine.wait"):
             while not self._work.wait(timeout=0.02):
                 if (self._stop.is_set() or self._pending_burst is not None
-                        or self._preempted or not self._waiting.empty()
+                        or not self._waiting.empty()
                         or not self._released.empty()
                         or any(r is not None
                                for r in self._slots.values())):
@@ -1303,15 +1075,9 @@ class LLMEngine:
         """Fetch the deferred first tokens (dispatched in _prefill_step)
         and start those requests decoding. Runs AFTER the tick's decode
         dispatch so the fetch overlaps the queued device work."""
-        for req, gen, out in deferred:
+        for req, out in deferred:
             counts, req.chunk_counts = req.chunk_counts, []
             if req.done.is_set():  # failed meanwhile (device recovery)
-                continue
-            if gen != req.prefill_gen:
-                # Preempted (and possibly re-admitted) after this fetch was
-                # dispatched: the token belongs to a KV state that no
-                # longer exists — emitting it would duplicate the first
-                # token of the re-prefill.
                 continue
             try:
                 with tracing.phase("engine.fetch", which="prefill"):
@@ -1341,7 +1107,7 @@ class LLMEngine:
         """Move waiting requests into unoccupied slots, as one
         ``engine.admit`` phase when there is a request and a slot for
         it."""
-        if self._waiting.empty() and not self._preempted:
+        if self._waiting.empty():
             return False
         if all(o is not None for o in self._slots.values()):
             return False
@@ -1358,13 +1124,12 @@ class LLMEngine:
         admitted = 0
         while any(o is None for o in self._slots.values()):
             try:
-                req = self._next_waiting()
+                req = self._waiting.get_nowait()
             except queue.Empty:
                 break
-            if not req.admit_ts:  # not a preempted request coming back
-                req.admit_ts = time.time()
-                self.admitted += 1
-                self.queue_wait_s += req.admit_ts - req.submit_ts
+            req.admit_ts = time.time()
+            self.admitted += 1
+            self.queue_wait_s += req.admit_ts - req.submit_ts
             if req.preloaded is not None:
                 slot = self._take_slot()
                 try:
@@ -1376,39 +1141,6 @@ class LLMEngine:
                 continue
             donor, adopt, retired = self._best_prefix(req.prompt_ids)
             req.prefilled_len = 0
-            if self.blocked:
-                # Block-pool prefix adoption: whole-block content copy
-                # from a LIVE donor (no retired-slot cache — finished
-                # requests release their blocks back to the pool).
-                slot = self._take_slot()
-                adopt = (adopt // self.block_size) * self.block_size
-                if (donor is not None and not retired
-                        and adopt >= max(self.PREFIX_COPY_MIN,
-                                         self.block_size)
-                        # preempt=False: with eviction allowed the victim
-                        # could be the DONOR, whose freed blocks would be
-                        # re-issued as the copy's destination while its
-                        # table row still points at them.
-                        and self._ensure_blocks(slot, adopt - 1,
-                                                preempt=False)):
-                    nb = adopt // self.block_size
-                    src = jnp.asarray(self._tables[donor, :nb])
-                    dst = jnp.asarray(self._tables[slot, :nb])
-                    try:
-                        self.cache = copy_blocks(self.cache, src, dst)
-                        req.prefilled_len = adopt
-                        self.prefix_hits += 1
-                        self.prefix_tokens_saved += adopt
-                    except Exception as e:  # noqa: BLE001 - donated cache
-                        logger.exception("block prefix copy failed")
-                        self._recover_device_failure(
-                            f"prefix copy failed: {e!r}")
-                        req.prefilled_len = 0
-                req.next_pos = -1
-                req.last_slot = slot
-                self._slots[slot] = req
-                admitted += 1
-                continue
             if donor is not None and adopt < self.PREFIX_COPY_MIN:
                 # Trivial LCP (e.g. a shared few-token template label):
                 # not worth a copy, and NEVER worth destroying a donor.
@@ -1471,92 +1203,6 @@ class LLMEngine:
             self._slots[slot] = req
             admitted += 1
         return admitted
-
-    # ---- blocked-KV pool accounting (scheduler thread only) ----
-
-    def _ensure_blocks(self, slot: int, upto_pos: int,
-                       preempt: bool = True) -> bool:
-        """Grow ``slot``'s block table to cover position ``upto_pos``,
-        preempting the newest other request on pool exhaustion (unless
-        ``preempt`` is False — e.g. a speculative chained burst is never
-        worth an eviction). False if the pool cannot cover it."""
-        need = min(upto_pos // self.block_size + 1, self.blocks_per_slot)
-        while self._slot_nblk[slot] < need:
-            if not self._free_blocks and not (
-                    preempt and self._preempt_for_blocks(slot)):
-                return False
-            self._tables[slot, self._slot_nblk[slot]] = \
-                self._free_blocks.pop()
-            self._slot_nblk[slot] += 1
-        return True
-
-    def _free_slot_blocks(self, slot: int) -> None:
-        n = self._slot_nblk[slot]
-        if n:
-            self._free_blocks.extend(int(b) for b in self._tables[slot, :n])
-            self._slot_nblk[slot] = 0
-
-    def _preempt_for_blocks(self, exclude_slot: int) -> bool:
-        """Evict the NEWEST other request (vLLM preemption order: latest
-        arrivals yield to earlier ones) by recompute: free its blocks and
-        requeue it; on readmission its prompt+generated tokens re-prefill
-        and decoding continues — emitted tokens are never re-emitted."""
-        victims = [(s, r) for s, r in self._slots.items()
-                   if r is not None and s != exclude_slot
-                   and not r.done.is_set() and not r.hold_slot
-                   and r.preloaded is None]
-        if not victims:
-            return False
-        # An in-flight chained burst still emits for its snapshot: resolve
-        # it first so a preempted request can't receive its tokens.
-        self._resolve_pending_burst()
-        if self._free_blocks:
-            return True  # the resolve's finishes freed enough — no eviction
-        victims = [(s, r) for s, r in victims
-                   if self._slots.get(s) is r and not r.done.is_set()]
-        if not victims:
-            return False
-        slot, req = max(victims, key=lambda sr: sr[1].arrival_seq)
-        self._preempt_slot(slot, req)
-        return True
-
-    def _preempt_slot(self, slot: int, req: "GenerationRequest") -> None:
-        self.preemptions += 1
-        self._prefix_live.pop(slot, None)
-        self._slots[slot] = None
-        self._free_slot_blocks(slot)
-        req.prompt_ids = list(req.prompt_ids) + list(req.out_tokens)
-        req.prefilled_len = 0
-        req.next_pos = -1
-        req.prefill_gen += 1  # invalidate in-flight deferred fetches
-        if len(req.prompt_ids) >= self.max_seq:
-            self._finish(req, "length")
-        else:
-            self._preempted.append(req)
-
-    def _ensure_decode_blocks(self, active: dict, burst: int) -> dict:
-        """Cover positions next_pos..next_pos+burst-1 for every active
-        slot before a decode dispatch; a slot the pool cannot cover (even
-        after evicting newer requests) is itself preempted."""
-        out = {}
-        for slot, req in active.items():
-            if self._slots.get(slot) is not req or req.done.is_set():
-                continue  # evicted by an earlier slot's ensure
-            if self._ensure_blocks(slot, req.next_pos + burst - 1):
-                out[slot] = req
-            else:
-                self._preempt_slot(slot, req)
-        # A LATER slot's ensure may have evicted a request accepted above —
-        # dispatching it anyway would write through its stale table into
-        # blocks the pool already re-issued. Re-filter against live slots.
-        return {s: r for s, r in out.items()
-                if self._slots.get(s) is r and not r.done.is_set()}
-
-    def _next_waiting(self) -> "GenerationRequest":
-        """Preempted requests re-admit ahead of fresh arrivals."""
-        if self._preempted:
-            return self._preempted.popleft()
-        return self._waiting.get_nowait()
 
     def _take_slot(self) -> int:
         """An unoccupied slot: prefer one with no cached prefix; otherwise
@@ -1670,27 +1316,12 @@ class LLMEngine:
         toks = np.zeros((bucket,), np.int32)
         toks[:take] = req.prompt_ids[req.prefilled_len:
                                      req.prefilled_len + take]
-        if self.blocked and not self._ensure_blocks(
-                slot, req.prefilled_len + bucket - 1):
-            self._slots[slot] = None
-            self._free_slot_blocks(slot)
-            self._fail(req, "KV block pool exhausted "
-                            f"({self.num_blocks} blocks x "
-                            f"{self.block_size} tokens)")
-            return
         try:
-            if self.blocked:
-                self.cache, logits = prefill_chunk_blocked(
-                    self.model_cfg, self.params, self.cache,
-                    jnp.asarray(self._tables[slot]), jnp.asarray(toks),
-                    jnp.int32(req.prefilled_len), jnp.int32(p),
-                    kmesh=self.kmesh)
-            else:
-                self.cache, logits, *counts = self.model.prefill_chunk(
-                    self.model_cfg, self.params, self.cache,
-                    jnp.asarray(toks), jnp.int32(req.prefilled_len),
-                    jnp.int32(p), jnp.int32(slot), kmesh=self.kmesh)
-                req.chunk_counts += counts
+            self.cache, logits, *counts = self.model.prefill_chunk(
+                self.model_cfg, self.params, self.cache,
+                jnp.asarray(toks), jnp.int32(req.prefilled_len),
+                jnp.int32(p), jnp.int32(slot), kmesh=self.kmesh)
+            req.chunk_counts += counts
             self.prefill_kv_positions_read += min(
                 req.prefilled_len + bucket, self.max_seq)
             self.prefill_kv_positions_reserved += self.max_seq
@@ -1702,7 +1333,7 @@ class LLMEngine:
                 # prefix donor for later shared-prefix requests.
                 self._prefix_live[slot] = tuple(req.prompt_ids)
                 out = self._sample_dispatch(logits[None], [req])
-                deferred.append((req, req.prefill_gen, out))
+                deferred.append((req, out))
         except Exception as e:  # noqa: BLE001 - e.g. OOM on long prompt
             logger.exception("prefill failed for %s", req.request_id)
             self._recover_device_failure(f"prefill failed: {e!r}")
@@ -1729,15 +1360,11 @@ class LLMEngine:
         self._slots = {i: None for i in range(self.max_slots)}
         self._prefix_live.clear()
         self._prefix_cached.clear()
-        if self.blocked:
-            self._tables[:] = 0
-            self._free_blocks = list(range(self.num_blocks))
-            self._slot_nblk = [0] * self.max_slots
         self.cache = self._new_cache(self.model_cfg)
         if self.draft_cfg is not None:
             # The draft cache may have been donated by the failing
             # speculative dispatch — rebuild it alongside.
-            self.draft_cache = self._new_cache(self.draft_cfg, dense=True)
+            self.draft_cache = self._new_cache(self.draft_cfg)
 
     def _burst_len(self, active: dict[int, GenerationRequest]) -> int:
         """Largest safe burst length for this decode batch. The decode
@@ -1782,28 +1409,16 @@ class LLMEngine:
         (_recover_device_failure ran) — callers mid-tick must then abandon
         the rest of the tick rather than dispatch into rebuilt caches."""
         burst = self._burst_len(active)
-        if self.blocked:
-            active = self._ensure_decode_blocks(active, burst)
-            if not active:
-                return True
         if burst > 1:
             return self._decode_burst(active, burst)
         try:
             with tracing.phase("engine.decode_dispatch", steps=1,
                                slots=len(active)):
                 tokens, positions, write = self._decode_inputs(active)
-                if self.blocked:
-                    self.cache, logits = decode_step_blocked(
-                        self.model_cfg, self.params, self.cache,
-                        jnp.asarray(self._tables), jnp.asarray(tokens),
-                        jnp.asarray(positions), jnp.asarray(write),
-                        kmesh=self.kmesh)
-                    counts = []
-                else:
-                    self.cache, logits, *counts = self.model.decode_step(
-                        self.model_cfg, self.params, self.cache,
-                        jnp.asarray(tokens), jnp.asarray(positions),
-                        jnp.asarray(write), kmesh=self.kmesh)
+                self.cache, logits, *counts = self.model.decode_step(
+                    self.model_cfg, self.params, self.cache,
+                    jnp.asarray(tokens), jnp.asarray(positions),
+                    jnp.asarray(write), kmesh=self.kmesh)
         except Exception as e:  # noqa: BLE001 - cache donated & lost
             logger.exception("decode step failed (%d active)", len(active))
             self._recover_device_failure(f"decode failed: {e!r}")
@@ -1876,51 +1491,25 @@ class LLMEngine:
                     top_ps[slot] = req.sampling.top_p
                 need_top_p = bool((top_ps < 1.0).any())
                 self._rng_key, sub = jax.random.split(self._rng_key)
-                if self.blocked:
-                    self.cache, toks = decode_burst_blocked(
-                        self.model_cfg, self.params, self.cache,
-                        jnp.asarray(self._tables), jnp.asarray(tokens),
-                        jnp.asarray(positions), jnp.asarray(write),
-                        jnp.asarray(temps), jnp.asarray(top_ps), sub, burst,
-                        need_top_p, kmesh=self.kmesh)
-                    counts = []
-                else:
-                    self.cache, toks, *counts = self.model.decode_burst(
-                        self.model_cfg, self.params, self.cache,
-                        jnp.asarray(tokens), jnp.asarray(positions),
-                        jnp.asarray(write), jnp.asarray(temps),
-                        jnp.asarray(top_ps), sub, burst, need_top_p,
-                        kmesh=self.kmesh)
+                self.cache, toks, *counts = self.model.decode_burst(
+                    self.model_cfg, self.params, self.cache,
+                    jnp.asarray(tokens), jnp.asarray(positions),
+                    jnp.asarray(write), jnp.asarray(temps),
+                    jnp.asarray(top_ps), sub, burst, need_top_p,
+                    kmesh=self.kmesh)
             self.decode_dispatches += 1
             self.decode_steps += burst
             self._count_kv_positions(positions, write, burst)
-            chain = self._should_chain(active, burst)
-            if chain and self.blocked:
-                # A chain must never evict someone: skip it unless every
-                # slot's blocks for the second burst are already coverable.
-                chain = all(self._ensure_blocks(
-                    s, r.next_pos + 2 * burst - 1, preempt=False)
-                    for s, r in active.items())
-            if chain:
+            if self._should_chain(active, burst):
                 with tracing.phase("engine.decode_dispatch", steps=burst,
                                    slots=len(active), chained=1):
                     self._rng_key, sub2 = jax.random.split(self._rng_key)
-                    if self.blocked:
-                        self.cache, toks2 = decode_burst_blocked(
-                            self.model_cfg, self.params, self.cache,
-                            jnp.asarray(self._tables), toks[burst - 1],
-                            jnp.asarray(positions) + burst,
-                            jnp.asarray(write), jnp.asarray(temps),
-                            jnp.asarray(top_ps), sub2, burst, need_top_p,
-                            kmesh=self.kmesh)
-                        counts2 = []
-                    else:
-                        self.cache, toks2, *counts2 = self.model.decode_burst(
-                            self.model_cfg, self.params, self.cache,
-                            toks[burst - 1], jnp.asarray(positions) + burst,
-                            jnp.asarray(write), jnp.asarray(temps),
-                            jnp.asarray(top_ps), sub2, burst, need_top_p,
-                            kmesh=self.kmesh)
+                    self.cache, toks2, *counts2 = self.model.decode_burst(
+                        self.model_cfg, self.params, self.cache,
+                        toks[burst - 1], jnp.asarray(positions) + burst,
+                        jnp.asarray(write), jnp.asarray(temps),
+                        jnp.asarray(top_ps), sub2, burst, need_top_p,
+                        kmesh=self.kmesh)
                 self.decode_dispatches += 1
                 self.decode_steps += burst
                 self._count_kv_positions(positions + burst, write, burst)
@@ -1948,7 +1537,7 @@ class LLMEngine:
             return False
         if self._pending_burst is not None or self.draft_params is not None:
             return False
-        if not self._waiting.empty() or self._preempted:
+        if not self._waiting.empty():
             return False
         for r in self._slots.values():
             if r is not None and r.next_pos < 0:
@@ -2095,11 +1684,6 @@ class LLMEngine:
         max_seq would make dynamic_update_slice clamp its start index and
         silently overwrite earlier positions."""
         bucket = self.config.prefill_bucket_min
-        if self.blocked:
-            # Chunks write whole pool blocks: buckets are power-of-two
-            # multiples of block_size and starts stay block-aligned
-            # (take == bucket on every non-final chunk).
-            bucket = max(bucket, self.block_size)
         while bucket < min(remaining, self.config.prefill_chunk):
             bucket *= 2
         bucket = min(bucket, self.max_seq - start)
@@ -2139,7 +1723,7 @@ class LLMEngine:
                 logger.warning("disabling speculation for %s after %d "
                                "failed draft catch-ups", req.request_id,
                                req.draft_fail_count)
-            self.draft_cache = self._new_cache(self.draft_cfg, dense=True)
+            self.draft_cache = self._new_cache(self.draft_cfg)
             for r in self._slots.values():
                 if r is not None:
                     r.draft_len = 0
@@ -2229,11 +1813,6 @@ class LLMEngine:
                 toks = self._prefix_live.pop(slot, None)
                 if not req.hold_slot:
                     self._slots[slot] = None
-                    if self.blocked:
-                        # Pool mode: blocks go back to the pool instead of
-                        # retiring as a cached prefix line.
-                        self._free_slot_blocks(slot)
-                        continue
                     if toks is not None and reason != "error":
                         # Retire, don't discard: the slot's KV stays intact
                         # until the slot is reclaimed, so an identical or
@@ -2262,19 +1841,14 @@ class LLMEngine:
         return shard_params(params, self.mesh,
                             served_model(cfg).param_logical_axes(cfg))
 
-    def _new_cache(self, cfg, dense: bool = False):
-        """A zeroed KV cache in this engine's layout (``dense`` forces slot
-        lines: the draft model's cache is never blocked), its kv-head dim
-        split over tp like the k/v projections that fill it."""
-        if self.blocked and not dense:
-            cache = init_kv_cache_blocked(cfg, self.num_blocks,
-                                          self.block_size)
-        else:
-            cache = served_model(cfg).init_cache(cfg, self.max_slots,
-                                                 self.max_seq)
+    def _new_cache(self, cfg):
+        """A zeroed slot cache of ``cfg``'s model, its kv-head dim split
+        over tp like the k/v projections that fill it."""
+        cache = served_model(cfg).init_cache(cfg, self.max_slots,
+                                             self.max_seq)
         if self.mesh is None:
             return cache
-        # Both layouts are [layers, slots|blocks, Hkv, positions, D].
+        # [layers, slots, Hkv, positions, D]
         return jax.device_put(
             cache, NamedSharding(self.mesh, P(None, None, "tp")))
 

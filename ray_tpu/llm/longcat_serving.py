@@ -170,9 +170,6 @@ def copy_prefix_kv(cfg: LongcatConfig, cache, src_slot, dst_slot):
 def _refuse(config) -> None:
     """What this model does not run, said at construction."""
     for bad, what in (
-            (config.kv_block_size > 0,
-             "the block pool (kv_block_size > 0): the latent cache has "
-             "slot lines only"),
             (config.speculative_model is not None,
              "a speculative draft"),
             (config.tensor_parallel_size > 1,

@@ -6,7 +6,7 @@ aggregates whole-thread stacks into collapsed-stack flamegraph lines
 (``root;child;leaf count``). Sampling is cooperative-with-the-GIL: each
 sample briefly holds the GIL while copying frame references, so the cost is
 O(stack depth × threads) per tick — at the default rate this stays well
-under the 2%% overhead budget PERF_PROFILER.json tracks.
+under the 2%% overhead budget devbench/profile_overhead.py measures.
 
 Frames are keyed by declaration line (``co_firstlineno``), not the executing
 line: per-sample line numbers would explode one logical frame into hundreds

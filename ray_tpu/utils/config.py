@@ -448,7 +448,7 @@ class Config:
 
     # --- on-demand profiler (ray_tpu/profiling) ---
     # Python stack-sampler rate for `profile` captures. 100 Hz keeps the
-    # measured overhead within the <=2% budget PERF_PROFILER.json tracks;
+    # overhead within the <=2% budget devbench/profile_overhead.py measures;
     # raise for finer flamegraphs on beefy hosts. The sampler clamps any
     # requested rate to 1 kHz — above that the per-sample GIL cost
     # approaches the interval and a single profile request would busy-loop

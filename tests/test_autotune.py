@@ -426,23 +426,34 @@ def test_per_layer_remat_validation():
 
 
 # ---------------------------------------------------------------------------
-# End-to-end measure path (tiny geometry, CPU, one AOT compile)
+# A candidate is a train step (tiny geometry, CPU, one AOT compile)
 # ---------------------------------------------------------------------------
 
-def test_measure_fn_records_hbm_provenance():
-    """bench._make_measure_fn: AOT compile + memory-analysis provenance +
-    a real timed step, at test-size geometry."""
-    import sys
+def test_a_candidate_builds_compiles_and_steps():
+    """What a search's ``measure_fn`` does with a candidate: its kernel
+    environment and ``step_options()`` build the Llama train step, the AOT
+    compile's memory has a named source, and the step runs."""
+    import jax
+    import numpy as np
 
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if repo_root not in sys.path:
-        sys.path.insert(0, repo_root)
-    from bench import _make_measure_fn
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+    from ray_tpu.train.optim import adamw_lowmem
+    from ray_tpu.train.spmd import make_llama_train_step
 
-    cfg = LlamaConfig.tiny()
-    measure = _make_measure_fn(cfg, 32, steps=2, warmup=1)
-    m = measure(Candidate(batch=2, remat="attn", attn="blockwise",
-                          grad_accum=2, zero1=True))
-    assert m["tokens_per_sec"] > 0
-    assert m["measured_hbm_gb"] and m["measured_hbm_gb"] > 0
-    assert m["hbm_source"] in ("memory_analysis", "hlo_liveness")
+    cfg, seq = LlamaConfig.tiny(), 32
+    cand = Candidate(batch=2, remat="attn", attn="blockwise", grad_accum=2,
+                     zero1=True)
+    mesh = build_mesh(MeshSpec(dp=1), jax.devices()[:1])
+    with cand.applied_env():
+        step_fn, init_state, shard = make_llama_train_step(
+            cfg, mesh, optimizer=adamw_lowmem(3e-4, weight_decay=0.1),
+            attn_impl=cand.attn, remat=cand.remat, **cand.step_options())
+        state = init_state()
+        tokens = shard(np.random.default_rng(0).integers(
+            0, cfg.vocab_size, (cand.batch, seq), dtype=np.int32))
+        targets = shard(np.roll(np.asarray(tokens), -1, axis=1))
+        compiled = step_fn.lower(state, tokens, targets).compile()
+    hbm, source = compiled_hbm_bytes(compiled)
+    assert hbm > 0 and source in ("memory_analysis", "hlo_liveness")
+    state, metrics = compiled(state, tokens, targets)
+    assert np.isfinite(float(metrics["loss"]))

@@ -366,7 +366,7 @@ def test_multiplex_lru_eviction():
         assert got == mid
 
 
-def test_route_hint_affinity():
+def test_route_hint_affinity(wait_for):
     """The same route hint lands on the same replica while it has capacity
     (reference: prefix-aware routing policy shape)."""
     @serve.deployment(num_replicas=3, max_ongoing_requests=8)
@@ -380,8 +380,16 @@ def test_route_hint_affinity():
             return self.pid_tag
 
     h = serve.run(Who.bind())
-    tags = {h.options(route_hint="prefix-xyz").remote().result()
-            for _ in range(6)}
+    router = h._ensure_router()
+    tags = set()
+    for _ in range(6):
+        tags.add(h.options(route_hint="prefix-xyz").remote().result())
+        # "While it has capacity": a slot is released by the router's
+        # reaper thread some time after the result is out, and a hinted
+        # replica more than HINT_BALANCE_DELTA above its siblings yields
+        # to balancing. Wait for the release, not for a time.
+        wait_for(lambda: not any(router._inflight.values()), timeout=30,
+                 interval=0.002, desc="the slot's release")
     assert len(tags) == 1  # all six routed to one replica
 
 

@@ -1,0 +1,128 @@
+"""What the engine holds a served model to, for both models it serves.
+
+The scheduler reaches a model only through ``engine.ServedModel``: four
+jitted programs that take the slot cache donated and give it back, under the
+names the benchmark's readers look for in a device trace
+(``jit_prefill_chunk``, ``jit_decode_burst``). The engine has one KV layout,
+slot lines; ``kv_block_size`` is a field that accepts 0.
+"""
+
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
+from ray_tpu.llm import engine, longcat_serving
+from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.models.longcat import LongcatConfig
+
+SLOTS, MAX_SEQ, CHUNK = 3, 64, 16
+
+
+def _llama():
+    return engine, dataclasses.replace(LlamaConfig.tiny(), vocab_size=512)
+
+
+def _longcat():
+    return longcat_serving, LongcatConfig.tiny(expert_shards=2,
+                                               max_seq_len=MAX_SEQ)
+
+
+# The Llama single step is jit(_decode_step_impl), so the benchmark's
+# readers of ``jit_decode_step`` would miss it; only a request with top_k
+# takes a single step, and no cell sends one. The name is part of the
+# program's text, so it changes with the move of ROADMAP D1, not before.
+_NOT_YET_NAMED = {(engine, "decode_step"): "jit__decode_step_impl"}
+
+
+def _arguments(program, params):
+    """The arguments of ``program`` after (cfg, [params,] cache): slot 0
+    holds CHUNK rows, slots 0 and 1 decode."""
+    i32 = jnp.int32
+    slots = jnp.zeros((SLOTS,), i32)
+    positions = jnp.array([CHUNK, 1, 0], i32)
+    write = jnp.array([True, True, False])
+    return {
+        "prefill_chunk": (params, jnp.arange(CHUNK, dtype=i32), i32(0),
+                          i32(CHUNK), i32(0)),
+        "decode_step": (params, slots, positions, write),
+        "decode_burst": (params, slots, positions, write,
+                         jnp.zeros((SLOTS,), jnp.float32),
+                         jnp.ones((SLOTS,), jnp.float32),
+                         jax.random.PRNGKey(0), 2, False),
+        "copy_prefix_kv": (i32(0), i32(2)),
+    }[program]
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode_step",
+                                     "decode_burst", "copy_prefix_kv"])
+@pytest.mark.parametrize("model", [_llama, _longcat],
+                         ids=["llama", "longcat"])
+def test_a_program_keeps_its_name_and_gives_the_donated_cache_back(model,
+                                                                    program):
+    module, cfg = model()
+    served = engine.served_model(cfg)
+    params = served.init_params(cfg, jax.random.PRNGKey(0))
+    cache = served.init_cache(cfg, SLOTS, MAX_SEQ)
+    went_in = jax.tree.map(lambda a: (a.shape, a.dtype), cache)
+    head = (cfg, cache)
+    rest = _arguments(program, params)
+    if program != "copy_prefix_kv":
+        head, rest = (cfg, rest[0], cache), rest[1:]
+
+    # The module's own jitted function carries the name a trace shows.
+    lowered = getattr(module, program).lower(*head, *rest)
+    named = re.search(r"module @(\w+)", lowered.as_text()).group(1)
+    assert named == _NOT_YET_NAMED.get((module, program), f"jit_{program}")
+
+    # Through the entry the scheduler calls: the cache is the first result.
+    out = getattr(served, program)(*head, *rest)
+    came_back = out
+    if program != "copy_prefix_kv":
+        # (cache, tokens or logits), and the model's counts if it has any
+        came_back = out[0]
+        assert len(out) == 2 + bool(served.counters)
+    assert jax.tree.structure(came_back) == jax.tree.structure(cache)
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), came_back) == went_in
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(cache))
+
+
+def test_the_engine_has_one_kv_layout_and_refuses_the_block_pool():
+    with pytest.raises(ValueError, match=r"block pool.*R3"):
+        LLMEngine(LLMConfig(model="tiny", max_num_seqs=2, max_seq_len=64,
+                            kv_block_size=16))
+    assert not hasattr(LLMConfig(), "kv_num_blocks")
+
+
+def test_stats_after_mixed_requests_carry_the_slot_layout_only():
+    """Long and short prompts, greedy and sampled, more requests than
+    slots: every request ends, no key of the block pool is left in stats(),
+    and the decode kernel never reads more of the lines than they hold."""
+    eng = LLMEngine(LLMConfig(model="tiny", max_num_seqs=3, max_seq_len=128,
+                              prefill_chunk=32, decode_burst=4))
+    try:
+        prompts = [list(range(260, 330)), [261, 262, 263],
+                   list(range(260, 300)), list(range(300, 345)), [270] * 9]
+        sampling = [SamplingParams(max_tokens=11),
+                    SamplingParams(max_tokens=5, temperature=0.8, top_p=0.9),
+                    SamplingParams(max_tokens=17),
+                    SamplingParams(max_tokens=3, top_k=4, temperature=1.0),
+                    SamplingParams(max_tokens=8)]
+        reqs = [eng.submit(p, s) for p, s in zip(prompts, sampling)]
+        assert all(r.done.wait(120) for r in reqs)
+        # (a sampled request may draw the end-of-sequence token early)
+        assert all(1 <= len(r.out_tokens) <= s.max_tokens and not r.error
+                   for r, s in zip(reqs, sampling))
+        stats = eng.stats()
+    finally:
+        eng.shutdown()
+    assert not {"kv_blocks_total", "kv_blocks_free", "kv_block_size",
+                "preemptions"} & set(stats)
+    assert stats["finished"] == stats["admitted"] == 5
+    assert stats["requests_failed"] == stats["device_failures"] == 0
+    assert 0 < stats["kv_positions_read"] <= stats["kv_positions_reserved"]
+    assert 0 < stats["prefill_kv_positions_read"] <= \
+        stats["prefill_kv_positions_reserved"]

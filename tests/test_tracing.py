@@ -60,6 +60,24 @@ def _patch_submission(monkeypatch, result="ok"):
     monkeypatch.setattr(ray_tpu, "get", lambda ref, **k: result)
 
 
+def _first_attempt_dies(resp, why, never_sent):
+    """A ``ray_tpu.get`` that loses ``resp``'s first attempt with its
+    replica, for every caller: the response asks for that ref, and so does
+    the router's reaper, which observes a completed ref's outcome on a
+    thread of its own; threads that earlier tests left behind ask for refs
+    of theirs. (Failing the first call, whoever made it, let any of them
+    take the error in the response's place.)"""
+    dying = resp._attempts[0][0]
+
+    def get(ref, **k):
+        if ref is dying:
+            raise ActorDiedError(next(iter(resp._tried)), why,
+                                 never_sent=never_sent)
+        return "ok"
+
+    return get
+
+
 def _traced_router(settings, n=1, cap=8):
     reps = _replicas(n, cap=cap, settings=settings)
     router = Router("d", lambda: reps)
@@ -203,18 +221,11 @@ class TestServePropagation:
             trace_sample_rate=1.0,
             retry=RetryPolicy(max_retries=2, backoff_s=0.0)), n=2)
         _patch_submission(monkeypatch)
-        calls = {"n": 0}
-
-        def flaky_get(ref, **k):
-            calls["n"] += 1
-            if calls["n"] == 1:  # in-flight on the dying incarnation
-                raise ActorDiedError(next(iter(resp._tried)),
-                                     "restarted", never_sent=False)
-            return "ok"
-
-        monkeypatch.setattr(ray_tpu, "get", flaky_get)
         tracing.enable_tracing()
         resp = DeploymentResponse(router, "m", (), {})
+        # the first attempt is in flight on the dying incarnation
+        monkeypatch.setattr(ray_tpu, "get", _first_attempt_dies(
+            resp, "restarted", never_sent=False))
         assert resp.result(timeout=5) == "ok"
         root = next(s for s in tracing.spans()
                     if s.name == "serve.request.d")
@@ -232,18 +243,10 @@ class TestServePropagation:
         router = _traced_router(ResilienceSettings(
             trace_sample_rate=1.0, retry=RetryPolicy(max_retries=0)), n=2)
         _patch_submission(monkeypatch)
-        calls = {"n": 0}
-
-        def never_sent_get(ref, **k):
-            calls["n"] += 1
-            if calls["n"] == 1:
-                raise ActorDiedError(next(iter(resp._tried)),
-                                     "mailbox drained", never_sent=True)
-            return "ok"
-
-        monkeypatch.setattr(ray_tpu, "get", never_sent_get)
         tracing.enable_tracing()
         resp = DeploymentResponse(router, "m", (), {})
+        monkeypatch.setattr(ray_tpu, "get", _first_attempt_dies(
+            resp, "mailbox drained", never_sent=True))
         assert resp.result(timeout=5) == "ok"
         root = next(s for s in tracing.spans()
                     if s.name == "serve.request.d")

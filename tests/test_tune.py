@@ -2,6 +2,7 @@
 test_tune_basic, searcher/scheduler unit tests)."""
 
 import random
+import threading
 
 import pytest
 
@@ -131,39 +132,59 @@ def test_tune_errors_surface_in_results():
     assert grid.get_best_result().config["i"] == 0
 
 
+# At module level because a trial's class travels to its actor by name, and
+# the trials and the test share this event: set when a trial is rebuilt from
+# a donor's checkpoint.
+_cloned = threading.Event()
+
+
+class _Rate(tune.Trainable):
+    """Its improvement rate IS its hyperparameter. A trial ends after eight
+    steps once some trial has been cloned, not before: with a fixed horizon
+    the strong trial could end all its steps before the weak one reached a
+    perturbation with a donor known to the scheduler, and whether it did
+    was the box's load. So both stay in the population until PBT has acted
+    (or the cap says it never will)."""
+
+    def setup(self, config):
+        self.w = 0.0
+
+    def step(self):
+        self.w += self.config["rate"]
+        steps = self.iteration + 1
+        return {"score": self.w,
+                "done": (steps >= 8 and _cloned.is_set()) or steps >= 20_000}
+
+    def save_checkpoint(self):
+        return {"w": self.w}
+
+    def load_checkpoint(self, ckpt):
+        self.w = ckpt["w"]
+        _cloned.set()
+
+
 def test_pbt_exploits_and_explores():
-    # Trainable whose improvement rate IS its hyperparameter; PBT should
-    # propagate high-rate configs/weights to low-rate trials.
-    class Rate(tune.Trainable):
-        def setup(self, config):
-            self.w = 0.0
-
-        def step(self):
-            self.w += self.config["rate"]
-            return {"score": self.w}
-
-        def save_checkpoint(self):
-            return {"w": self.w}
-
-        def load_checkpoint(self, ckpt):
-            self.w = ckpt["w"]
-
+    # PBT should propagate high-rate configs/weights to low-rate trials.
+    _cloned.clear()
     rng = random.Random(0)
     sched = PopulationBasedTraining(
         perturbation_interval=2,
         hyperparam_mutations={"rate": lambda: rng.uniform(0.5, 1.0)},
         quantile_fraction=0.5, seed=0)
     grid = tune.Tuner(
-        Rate,
+        _Rate,
         param_space={"rate": tune.grid_search([0.01, 1.0])},
         tune_config=tune.TuneConfig(metric="score", mode="max",
                                     scheduler=sched),
-        stop={"training_iteration": 8},
     ).fit()
-    # The weak trial must have been boosted by an exploit (its final score
-    # would be ~0.08 without PBT).
+    assert not any(r.error for r in grid.results)
+    # Exploit: a trial was rebuilt from a donor's weights, and the weak one
+    # was boosted (its score would be ~0.08 after eight steps without PBT).
+    assert _cloned.is_set()
     scores = sorted(r.metrics["score"] for r in grid.results)
     assert scores[0] > 0.5
+    # Explore: the clone runs a perturbed copy of the donor's config.
+    assert {r.config["rate"] for r in grid.results} - {0.01, 1.0}
 
 
 def test_trainer_under_tune():
