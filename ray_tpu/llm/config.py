@@ -70,18 +70,24 @@ class LLMConfig:
     # temperature/top-p; a top-k request in the batch falls back to
     # single-step ticks.
     decode_burst: int = 8
-    # Pipeline bursts: in steady-state decode (no admissions/prefills
-    # pending, budgets allow a full second burst) the NEXT burst is
-    # dispatched BEFORE the current one's tokens are fetched, feeding the
-    # on-device last token forward — the host⇄device roundtrip overlaps
-    # the next burst's compute instead of serializing with it. Output is
-    # identical (emission truncates finished requests either way).
+    # Look-ahead of one: while any line decodes, the scheduler dispatches
+    # the next program group (the chunks of a waiting prompt, then a burst)
+    # BEFORE it reads the oldest result in flight, every burst and also
+    # while a request waits or prefills, so a burst is always queued behind
+    # the one that runs and the host's work (the read, emission, admission,
+    # the next inputs) costs the device no gap. Tokens pass from burst to
+    # burst, and from a prompt's last chunk to its first burst, on the
+    # device; the host plans lengths and budgets from its own state plus the
+    # steps in flight. A line that ends on a stop token is found one burst
+    # late (at most one burst of wasted slot-steps). False: strictly serial,
+    # a tick reads all it dispatched before the next begins. Greedy output
+    # is the same either way, token for token.
     decode_pipeline: bool = True
-    # Prefill chunks dispatched per scheduler tick. The tick defers every
-    # prefill's first-token fetch until after its decode dispatch, so a
-    # bigger budget admits a burst of new requests in ONE roundtrip instead
-    # of one tick each — at the cost of that many chunks of prefill compute
-    # between decode steps (time-per-output-token under prefill load).
+    # Prefill chunks dispatched per admission pass of a scheduler tick (a
+    # tick has two: before and after its blocking read). A bigger budget
+    # brings a burst of new requests to their first tokens sooner, at the
+    # cost of that many chunks of prefill compute between two decode
+    # bursts (time-per-output-token under prefill load).
     prefill_chunks_per_tick: int = 4
     # Always 0. The engine has one KV layout (slot lines) and refuses any
     # other value; the field stays only because the benchmark's traffic

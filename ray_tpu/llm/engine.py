@@ -22,11 +22,13 @@ wrapper:
   batched jitted step — new requests join mid-flight without stalling
   running ones.
 - **Roundtrip-lean scheduling**: decode runs up to ``decode_burst`` steps
-  per dispatch (sampled tokens fed forward on device via lax.scan), and a
-  tick's prefill first-token fetches are deferred until its decode work is
-  queued — so one tick costs ONE host⇄device roundtrip regardless of how
-  many prefills and decode tokens it covers. It matters when the model is
-  small enough that dispatch latency rivals compute.
+  per dispatch (sampled tokens fed forward on device via lax.scan), and the
+  scheduler dispatches the next program before it reads the last: what it
+  has dispatched and not read waits in one first-in-first-out list
+  (``_in_flight``), a burst is queued behind the one that runs, tokens pass
+  from program to program on the device, and results are read in the order
+  the device makes them. The host reads, emits, admits and prepares inputs
+  beside a running burst, not between two.
 - **Sampling on-device**: temperature/top-k/top-p in fp32 logits, one
   fused jit; greedy when temperature == 0.
 - Cache buffers are donated through jit so XLA updates them in place.
@@ -39,6 +41,7 @@ import queue
 import threading
 import time
 import uuid
+from collections import deque
 from collections.abc import Callable
 from dataclasses import dataclass, field, replace
 from functools import partial
@@ -425,10 +428,25 @@ def sample_tokens(logits, temps, top_ps, top_k: int, key,
     return jnp.where(temps <= 0.0, greedy, sampled)
 
 
+@jax.jit
+def _last_row(toks):
+    """A burst's tokens [steps, B] -> its last step's [B]: every continuing
+    line's input to the next burst, left on the device."""
+    return toks[-1]
+
+
+@jax.jit
+def _join_token(tokens, first, slot):
+    """tokens [B] with ``first`` [1], a prompt's sampled first token, at
+    ``slot``: the line joins a burst without a host read of that token."""
+    return lax.dynamic_update_slice(tokens, first.astype(tokens.dtype),
+                                    (slot,))
+
+
 @dataclass(frozen=True)
 class ServedModel:
     """What a model supplies for the engine to serve it. The engine owns
-    the schedule (admission, chunked prefill, bursts and chaining, sampling,
+    the schedule (admission, chunked prefill, bursts and the look-ahead, sampling,
     prefix adoption, the counters) and knows a model only through this:
 
     - ``init_params(cfg, key)`` and ``param_logical_axes(cfg)``;
@@ -519,6 +537,7 @@ class GenerationRequest:
     error: str | None = None
     finish_reason: str | None = None
     next_pos: int = 0  # position the next token will occupy; <0 = prefilling
+    ahead: int = 0  # decode steps dispatched for it and not yet read
     prefilled_len: int = 0  # prompt tokens already in the KV cache
     preloaded: tuple | None = None  # (kv_k, kv_v, first_token) P/D import
     last_slot: int = -1  # slot the request last occupied (KV export)
@@ -538,6 +557,20 @@ class GenerationRequest:
     admit_ts: float = 0.0
     first_token_ts: float = 0.0
     kv_imported: bool = False
+
+
+@dataclass
+class _InFlight:
+    """A dispatched program whose tokens the host has not read: a prompt's
+    first token (``steps`` 0, ``toks`` [1]) or a burst (``toks``
+    [steps, slots]; ``last_row`` its final row, the next burst's input).
+    ``reqs`` are the lines it computes for, by slot; ``counts`` the model's
+    own (ServedModel.counters), fetched with the tokens."""
+    reqs: dict[int, GenerationRequest]
+    toks: Any
+    counts: list = field(default_factory=list)
+    steps: int = 0
+    last_row: Any = None
 
 
 @dataclass
@@ -614,7 +647,9 @@ class LLMEngine:
         # that polls takes window deltas. decode_steps are device steps
         # (a burst of 8 counts 8; each computes every slot), decode_tokens
         # the tokens they gave that a request still wanted (so not its
-        # first, which prefill gives); queue_wait_s sums admit - submit
+        # first, which prefill gives); decode_dispatches_ahead those
+        # dispatches made while an earlier program's result was still
+        # unread, so the device had work; queue_wait_s sums admit - submit
         # over `admitted`, first_token_wait_s first token - admit over
         # `first_tokens`. kv_positions_read / kv_positions_reserved say how
         # far the decode kernel's skipping of dead blocks engages: per
@@ -631,6 +666,7 @@ class LLMEngine:
         self.prompt_tokens_prefilled = 0
         self.prefill_chunks = 0
         self.decode_dispatches = 0
+        self.decode_dispatches_ahead = 0
         self.decode_steps = 0
         self.decode_tokens = 0
         self.kv_positions_read = 0
@@ -707,9 +743,14 @@ class LLMEngine:
         # R1). The scheduler thread takes the lock only for its table pop.
         self._submit_lock = threading.Lock()
         self._rng_key = jax.random.PRNGKey(config.seed + 1)
-        # Pipelined decode: (active snapshot, burst, device tokens) of a
-        # chained burst awaiting resolution at the next tick's start.
-        self._pending_burst = None
+        # Programs dispatched and not yet read, oldest first: the device
+        # runs them in this order and the host reads them in it. Pipelined,
+        # a tick leaves one burst queued behind the one that runs;
+        # otherwise (and with a draft model, whose ticks read the host's
+        # tokens) a tick reads out all it dispatched.
+        self._in_flight: deque[_InFlight] = deque()
+        self._pipelined = bool(config.decode_pipeline) \
+            and self.draft_cfg is None
         self._stop = threading.Event()
         self._work = threading.Event()
         self._thread = threading.Thread(target=self._loop, daemon=True,
@@ -930,6 +971,7 @@ class LLMEngine:
                "prompt_tokens_prefilled": self.prompt_tokens_prefilled,
                "prefill_chunks": self.prefill_chunks,
                "decode_dispatches": self.decode_dispatches,
+               "decode_dispatches_ahead": self.decode_dispatches_ahead,
                "decode_steps": self.decode_steps,
                "decode_tokens": self.decode_tokens,
                "kv_positions_read": self.kv_positions_read,
@@ -959,8 +1001,8 @@ class LLMEngine:
                 worked = self._tick()
             except Exception:  # noqa: BLE001 - one bad request must not
                 # kill the scheduler thread (every queued request would
-                # hang to its timeout). _prefill_step/_decode fail the
-                # offending requests where attributable; anything that
+                # hang to its timeout). The dispatch and read paths fail
+                # the offending requests where attributable; anything that
                 # still escapes is logged and backed off, never hot-spun.
                 logger.exception("LLMEngine scheduler tick failed")
                 worked = False
@@ -968,10 +1010,10 @@ class LLMEngine:
                 self.ticks += 1
             else:
                 self._wait_for_work()
-        # Drain a chained burst so its requests get their final tokens
+        # Read out what is in flight so its requests get their tokens
         # instead of hanging to their timeouts.
         try:
-            self._resolve_pending_burst()
+            self._read_all()
         except Exception:  # noqa: BLE001 - shutdown path
             pass
 
@@ -982,7 +1024,7 @@ class LLMEngine:
         failed is tried again; an empty engine has nothing to try."""
         with tracing.phase("engine.wait"):
             while not self._work.wait(timeout=0.02):
-                if (self._stop.is_set() or self._pending_burst is not None
+                if (self._stop.is_set() or self._in_flight
                         or not self._waiting.empty()
                         or not self._released.empty()
                         or any(r is not None
@@ -991,67 +1033,73 @@ class LLMEngine:
         self._work.clear()
 
     def _tick(self) -> bool:
-        """One scheduler step: a bounded budget of prefill chunks (their
-        first-token fetches deferred), then one decode batch over the
-        decoding slots. Chunking + the per-tick budget stop a long prompt
-        from head-of-line-blocking every active decode (reference shape:
-        vLLM chunked prefill scheduling); deferring the prefill fetches
-        until the decode work is queued means the whole tick pays ONE
-        host⇄device roundtrip however many prefills it ran."""
-        # Admit into CURRENTLY-empty slots and dispatch their prefill
-        # chunks BEFORE blocking on the pipelined burst's fetch: the
-        # prefill rides the device queue behind the in-flight burst and
-        # its first token is ready ~one prefill after that burst, instead
-        # of TTFT paying a full extra burst+chain. This is safe because a
-        # slot that is empty now was freed at or before the pending
-        # burst's dispatch, so that burst's write mask provably excludes
-        # it — only slots freed BY the pending resolve (mid-burst
-        # finishes) must wait for it, and those are still occupied here.
+        """One scheduler step: dispatch a program group (a bounded budget
+        of prefill chunks, then one decode batch over the decoding slots)
+        behind what the device runs, having read the oldest result in
+        flight. Chunking + the budget stop a long prompt from
+        head-of-line-blocking every active decode (reference shape: vLLM
+        chunked prefill scheduling).
+
+        Pipelined, the blocking read in the middle returns when the
+        running burst ends and the one queued behind it starts: emitting,
+        admitting and the next group's dispatch run beside that burst, so
+        the device queue is never empty while a line decodes, and never
+        holds more than one group behind the running one (a deeper queue
+        would make a new arrival wait longer). The host therefore plans
+        from projected state (_steps_left): its own plus the steps in
+        flight. A line that ends on a stop token is discovered one burst
+        late; its extra rows lie beyond every cached prefix and are
+        overwritten before they are read, and its slot may be admitted at
+        once, because the new prompt's chunks are dispatched, and so run,
+        after the burst that still writes there."""
         # engine.tick encloses the tick's other phases: what a profile
         # shows in it and in none of them is the scheduler's own glue.
         with tracing.phase("engine.tick"):
             self._process_releases()
+            # Per-PASS chunk budget: the tick has two admission passes
+            # (before and after the blocking read) and each gets a full
+            # prefill_chunks_per_tick. A shared budget was measured ~25%
+            # worse p50 TTFT at c8: completions arrive in bursts, and an
+            # arrival landing after the read must not wait a whole burst
+            # because the pass before it spent the budget.
             worked = self._admit()
-            deferred: list = []
-            try:
-                return self._tick_inner(deferred) or worked
-            finally:
-                # An exception between a prefill dispatch and its
-                # resolution must not strand the deferred first-token
-                # fetches — the requests would report prefilled but never
-                # start decoding (hang to client timeout). Whatever
-                # survived, resolve it.
-                self._resolve_prefills(deferred)
+            worked = self._prefill_steps() or worked
+            # The read may finish requests and free slots for the second
+            # pass. It blocks: a thread that polls while the tokens compute
+            # competes for the cores that run the HTTP/router/SSE threads.
+            worked = self._read_oldest() or worked
+            worked = self._admit() or worked
+            worked = self._prefill_steps() or worked
+            if self._dispatch_decode():
+                return True
+            # Nothing to queue behind what runs (every line in flight ends
+            # there, or only first tokens are): read it out.
+            if self._in_flight:
+                self._read_all()
+                return True
+            return worked
 
-    def _tick_inner(self, deferred: list) -> bool:
-        worked = False
-        # Per-PASS chunk budget: the tick has two admission passes (before
-        # and after resolving the pipelined burst) and each gets a full
-        # prefill_chunks_per_tick. A shared budget was measured ~25%
-        # worse p50 TTFT at c8: completions arrive in bursts, and an
-        # arrival landing after the resolve must not wait a whole
-        # burst+chain because the pre-resolve pass spent the budget.
+    def _prefill_steps(self) -> bool:
         budget = max(1, int(getattr(self.config,
                                     "prefill_chunks_per_tick", 1) or 1))
         spent = 0
-        while spent < budget and self._prefill_step(deferred):
+        while spent < budget and self._prefill_step():
             spent += 1
-            worked = True
-        # Resolve the pipelined burst next: its emissions may finish
-        # requests and free slots for the SECOND admission pass below.
-        # The fetch blocks: a thread that polls for admissions while
-        # toks_dev computes competes for the cores that run the
-        # HTTP/router/SSE threads, and the blocking fetch yields to them.
-        worked = self._resolve_pending_burst() or worked
-        worked = self._admit() or worked
-        spent = 0
-        while spent < budget and self._prefill_step(deferred):
-            spent += 1
-            worked = True
-        decoding = {s: r for s, r in self._slots.items()
-                    if r is not None and r.next_pos >= 0
-                    and not r.done.is_set()}
-        if decoding and self.draft_params is not None:
+        return spent > 0
+
+    def _decoding(self) -> dict[int, GenerationRequest]:
+        return {s: r for s, r in self._slots.items()
+                if r is not None and r.next_pos >= 0
+                and not r.done.is_set()}
+
+    def _dispatch_decode(self) -> bool:
+        """The tick's decode batch. A burst goes behind whatever is in
+        flight; the serial paths (speculative ticks, a single step: top_k
+        or a last token) first read out everything, because they take
+        their tokens from the host."""
+        if self.draft_params is not None:
+            self._read_all()
+            decoding = self._decoding()
             # Speculative path serves greedy requests with spec headroom;
             # the rest (stochastic sampling, near end-of-cache) ride the
             # normal decode in the same tick.
@@ -1061,38 +1109,88 @@ class LLMEngine:
             rest = {s: r for s, r in decoding.items() if s not in spec}
             if spec:
                 self._spec_decode(spec)
-                worked = True
             if rest:
                 self._decode(rest)
-                worked = True
-            return worked
-        if decoding:
-            self._decode(decoding)
-            worked = True
-        return worked
+            return bool(decoding)
+        # Lines that still decode once everything in flight is read.
+        active = {s: r for s, r in self._decoding().items()
+                  if self._steps_left(r) > 0}
+        if not active:
+            return False
+        # A line whose newest token only the host has (a KV import) cannot
+        # ride behind a burst that runs without it.
+        host_only = (any(e.steps for e in self._in_flight)
+                     and any(r.out_tokens and not r.ahead
+                             for r in active.values()))
+        if self._in_flight and (host_only or self._burst_len(active) <= 1):
+            self._read_all()
+            active = self._decoding()
+        if active:
+            self._decode(active)
+        return True
 
-    def _resolve_prefills(self, deferred: list) -> None:
-        """Fetch the deferred first tokens (dispatched in _prefill_step)
-        and start those requests decoding. Runs AFTER the tick's decode
-        dispatch so the fetch overlaps the queued device work."""
-        for req, out in deferred:
+    def _steps_left(self, req: GenerationRequest) -> int:
+        """Decode steps the line can still take once everything in flight
+        is read: the fewer of its token budget and its cache line's end,
+        both less the steps in flight (a decoding line without a token has
+        its first in flight too). 0: it ends there, whatever it samples."""
+        tokens = len(req.out_tokens) + req.ahead + (not req.out_tokens)
+        return min(req.sampling.max_tokens - tokens,
+                   self.max_seq - 1 - req.next_pos - req.ahead)
+
+    def _read_oldest(self) -> bool:
+        """Pipelined: read results, oldest first, until one burst is left
+        in flight (the one the device runs or is about to), and a first
+        token at the head once a burst is queued behind it (its line has
+        joined that burst on the device; its chunk ran, or runs now)."""
+        read = False
+        while self._in_flight:
+            bursts = sum(1 for e in self._in_flight if e.steps)
+            # the last burst stays, and a first token no burst follows
+            if bursts <= 1 and (self._in_flight[0].steps or not bursts):
+                break
+            read = True
+            if not self._read(self._in_flight.popleft()):
+                break
+        return read
+
+    def _read_all(self) -> bool:
+        """Read out everything in flight, oldest first. False iff a device
+        failure wiped the engine state on the way."""
+        while self._in_flight:
+            if not self._read(self._in_flight.popleft()):
+                return False
+        return True
+
+    def _read(self, entry: _InFlight) -> bool:
+        """Block on one program's tokens and emit them. False iff they did
+        not come: an asynchronous dispatch error surfaces at
+        materialization, and the engine state is suspect."""
+        counts = entry.counts
+        if not entry.steps:
+            (req,) = entry.reqs.values()
             counts, req.chunk_counts = req.chunk_counts, []
-            if req.done.is_set():  # failed meanwhile (device recovery)
-                continue
-            try:
-                with tracing.phase("engine.fetch", which="prefill"):
-                    out, counts = jax.device_get((out, counts))
-                    tok = int(out[0])
-                self._add_model_counts(*counts)
-            except Exception as e:  # noqa: BLE001 - async dispatch error
-                # surfaces at materialization; engine state is suspect.
-                logger.exception("deferred prefill sample failed for %s",
-                                 req.request_id)
-                self._recover_device_failure(f"prefill failed: {e!r}")
-                return
-            req.next_pos = len(req.prompt_ids)
+            if req.done.is_set():  # failed meanwhile
+                return True
+        try:
+            with tracing.phase("engine.fetch",
+                               which="burst" if entry.steps else "prefill"):
+                toks, counts = jax.device_get((entry.toks, counts))
+            self._add_model_counts(*counts)
+        except Exception as e:  # noqa: BLE001 - cache donated & lost
+            what = "decode" if entry.steps else "prefill"
+            logger.exception("%s in flight failed (%d lines)", what,
+                             len(entry.reqs))
+            self._recover_device_failure(f"{what} failed: {e!r}")
+            return False
+        if entry.steps:
+            for req in entry.reqs.values():
+                req.ahead -= entry.steps
+            self._emit_burst(entry.reqs, entry.steps, toks)
+        else:
             with tracing.phase("engine.emit", tokens=1):
-                self._emit(req, tok)
+                self._emit(req, int(toks[0]))
+        return True
 
     # Minimum adopted-prefix length that justifies a cross-slot KV copy
     # (the copy moves whole cache lines; tiny prefixes aren't worth it).
@@ -1274,15 +1372,10 @@ class LLMEngine:
         self._prefix_live[slot] = tuple(req.prompt_ids)  # imported KV = donor
         self._emit(req, first_token)
 
-    def _prefill_step(self, deferred: list) -> bool:
+    def _prefill_step(self) -> bool:
         """Run ONE chunk of ONE prefilling request, rotating across slots so
         concurrent long prompts interleave chunks (true round-robin — a
-        lowest-slot rescan would monopolize prefill for one prompt).
-
-        A final chunk's first-token sample is DISPATCHED but not fetched:
-        (req, device_tokens) is appended to ``deferred`` for the caller to
-        resolve after it has queued the tick's decode work — one
-        host⇄device roundtrip per tick instead of one per prefill."""
+        lowest-slot rescan would monopolize prefill for one prompt)."""
         slots = list(self._slots.keys())
         n = len(slots)
         for i in range(n):
@@ -1290,28 +1383,21 @@ class LLMEngine:
             req = self._slots.get(slot)
             if req is None or req.next_pos >= 0:
                 continue
-            p = len(req.prompt_ids)
-            if req.prefilled_len >= p:
-                # Fully prefilled, first-token fetch still deferred this
-                # tick — re-prefilling would dispatch a zero-take chunk and
-                # sample (emit!) a duplicate first token.
-                continue
             self._prefill_rr = slot
-            bucket, take = self._chunk_bucket(req.prefilled_len,
-                                              p - req.prefilled_len)
+            bucket, take = self._chunk_bucket(
+                req.prefilled_len, len(req.prompt_ids) - req.prefilled_len)
             with tracing.phase("engine.prefill_dispatch", tokens=take,
                                bucket=bucket):
-                self._dispatch_prefill_chunk(slot, req, bucket, take,
-                                             deferred)
+                self._dispatch_prefill_chunk(slot, req, bucket, take)
             return True
         return False
 
     def _dispatch_prefill_chunk(self, slot: int, req: GenerationRequest,
-                                bucket: int, take: int,
-                                deferred: list) -> None:
+                                bucket: int, take: int) -> None:
         """The host side of one chunk: pad it to its bucket, dispatch it,
         and on the prompt's last chunk dispatch the first token's sample
-        too (its fetch is deferred, see _prefill_step)."""
+        too. That token goes in flight unread: the line decodes from here
+        on, and joins the next burst on the device (_input_tokens)."""
         p = len(req.prompt_ids)
         toks = np.zeros((bucket,), np.int32)
         toks[:take] = req.prompt_ids[req.prefilled_len:
@@ -1333,7 +1419,8 @@ class LLMEngine:
                 # prefix donor for later shared-prefix requests.
                 self._prefix_live[slot] = tuple(req.prompt_ids)
                 out = self._sample_dispatch(logits[None], [req])
-                deferred.append((req, out))
+                req.next_pos = p
+                self._in_flight.append(_InFlight({slot: req}, out))
         except Exception as e:  # noqa: BLE001 - e.g. OOM on long prompt
             logger.exception("prefill failed for %s", req.request_id)
             self._recover_device_failure(f"prefill failed: {e!r}")
@@ -1346,7 +1433,7 @@ class LLMEngine:
         cache so the engine keeps serving NEW traffic."""
         self.device_failures += 1
         self._cache_gen += 1  # invalidates in-flight prefill_only exports
-        self._pending_burst = None  # chained into the lost cache
+        self._in_flight.clear()  # dispatched into the lost cache
         for req in list(self._slots.values()):
             if req is None:
                 continue
@@ -1367,7 +1454,8 @@ class LLMEngine:
             self.draft_cache = self._new_cache(self.draft_cfg)
 
     def _burst_len(self, active: dict[int, GenerationRequest]) -> int:
-        """Largest safe burst length for this decode batch. The decode
+        """Largest safe burst length for this decode batch, counted from
+        where its lines stand once everything in flight is read. The decode
         batch is the STATIC slot array, so a request finishing mid-burst
         costs nothing extra — the host just stops emitting its tokens
         (max_tokens/EOS truncation happens in _emit) and the spare KV
@@ -1395,9 +1483,9 @@ class LLMEngine:
         for req in active.values():
             if req.sampling.top_k:  # static-k sampling: single-step only
                 return 1
-            burst = min(burst, self.max_seq - 1 - req.next_pos)
-            budget = max(budget,
-                         req.sampling.max_tokens - len(req.out_tokens))
+            burst = min(burst,
+                        self.max_seq - 1 - req.next_pos - req.ahead)
+            budget = max(budget, self._steps_left(req))
         burst = min(burst, budget)
         d = 1
         while d * 2 <= burst:
@@ -1410,14 +1498,19 @@ class LLMEngine:
         the rest of the tick rather than dispatch into rebuilt caches."""
         burst = self._burst_len(active)
         if burst > 1:
-            return self._decode_burst(active, burst)
+            ok = self._decode_burst(active, burst)
+            # Not pipelined: strictly serial, a tick reads what it
+            # dispatched before the next one begins.
+            return ok if self._pipelined else ok and self._read_all()
+        # A single step takes each line's token from the host: nothing of
+        # these lines is in flight (_dispatch_decode read it out).
         try:
             with tracing.phase("engine.decode_dispatch", steps=1,
                                slots=len(active)):
-                tokens, positions, write = self._decode_inputs(active)
+                positions, write = self._decode_inputs(active)
                 self.cache, logits, *counts = self.model.decode_step(
                     self.model_cfg, self.params, self.cache,
-                    jnp.asarray(tokens), jnp.asarray(positions),
+                    self._host_tokens(active), jnp.asarray(positions),
                     jnp.asarray(write), kmesh=self.kmesh)
         except Exception as e:  # noqa: BLE001 - cache donated & lost
             logger.exception("decode step failed (%d active)", len(active))
@@ -1445,15 +1538,42 @@ class LLMEngine:
         return True
 
     def _decode_inputs(self, active: dict[int, GenerationRequest]):
-        """(last token, position, write mask) over the static slot array."""
-        tokens = np.zeros((self.max_slots,), np.int32)
+        """(position, write mask) over the static slot array: where each
+        line's next token is written once everything in flight is read."""
         positions = np.zeros((self.max_slots,), np.int32)
         write = np.zeros((self.max_slots,), bool)
         for slot, req in active.items():
-            tokens[slot] = req.out_tokens[-1]
-            positions[slot] = req.next_pos
+            positions[slot] = req.next_pos + req.ahead
             write[slot] = True
-        return tokens, positions, write
+        return positions, write
+
+    def _host_tokens(self, active: dict[int, GenerationRequest]):
+        """Each line's newest token as the host has it, on the device."""
+        tokens = np.zeros((self.max_slots,), np.int32)
+        for slot, req in active.items():
+            if req.out_tokens:
+                tokens[slot] = req.out_tokens[-1]
+        return jnp.asarray(tokens)
+
+    def _input_tokens(self, active: dict[int, GenerationRequest]):
+        """int32[slots] on the device: each line's input to a burst, with
+        no host read of anything in flight. A continuing line's is the last
+        row of the newest burst in flight (every line that still decodes is
+        in it); with no burst in flight the host has them all. A line whose
+        first token is in flight and in no burst yet takes it from its
+        chunk's sampled device value, so it joins the first burst
+        dispatched after that chunk."""
+        prev = next((e for e in reversed(self._in_flight) if e.steps), None)
+        tokens = (prev.last_row if prev is not None
+                  else self._host_tokens(active))
+        for entry in self._in_flight:
+            if entry.steps:
+                continue
+            for slot, req in entry.reqs.items():
+                if active.get(slot) is req and not req.ahead:
+                    tokens = _join_token(tokens, entry.toks,
+                                         jnp.int32(slot))
+        return tokens
 
     def _count_kv_positions(self, positions, write, steps: int,
                             k: int = 1) -> None:
@@ -1469,21 +1589,16 @@ class LLMEngine:
 
     def _decode_burst(self, active: dict[int, GenerationRequest],
                       burst: int) -> bool:
-        """Emit ``burst`` tokens per active slot from one device dispatch.
-        A request finishing mid-burst (EOS/stop token) simply stops
-        emitting; the extra KV the device wrote past its end sits at
-        positions a later slot reuse overwrites (same free-rollback
-        property speculative decoding relies on).
-
-        In steady state a SECOND burst is chained before this one's
-        tokens are fetched (see _should_chain), feeding the on-device
-        last token forward — the fetch roundtrip then overlaps the next
-        burst's compute. The chained burst is resolved at the next tick's
-        start (_resolve_pending_burst)."""
+        """Dispatch ``burst`` decode steps over the active slots behind
+        whatever is in flight; _read emits its tokens. A request finishing
+        mid-burst (EOS/stop token) simply stops emitting; the extra KV the
+        device wrote past its end sits at positions a later slot reuse
+        overwrites (same free-rollback property speculative decoding
+        relies on)."""
         try:
             with tracing.phase("engine.decode_dispatch", steps=burst,
                                slots=len(active)):
-                tokens, positions, write = self._decode_inputs(active)
+                positions, write = self._decode_inputs(active)
                 temps = np.zeros((self.max_slots,), np.float32)
                 top_ps = np.ones((self.max_slots,), np.float32)
                 for slot, req in active.items():
@@ -1493,78 +1608,27 @@ class LLMEngine:
                 self._rng_key, sub = jax.random.split(self._rng_key)
                 self.cache, toks, *counts = self.model.decode_burst(
                     self.model_cfg, self.params, self.cache,
-                    jnp.asarray(tokens), jnp.asarray(positions),
+                    self._input_tokens(active), jnp.asarray(positions),
                     jnp.asarray(write), jnp.asarray(temps),
                     jnp.asarray(top_ps), sub, burst, need_top_p,
                     kmesh=self.kmesh)
-            self.decode_dispatches += 1
-            self.decode_steps += burst
-            self._count_kv_positions(positions, write, burst)
-            if self._should_chain(active, burst):
-                with tracing.phase("engine.decode_dispatch", steps=burst,
-                                   slots=len(active), chained=1):
-                    self._rng_key, sub2 = jax.random.split(self._rng_key)
-                    self.cache, toks2, *counts2 = self.model.decode_burst(
-                        self.model_cfg, self.params, self.cache,
-                        toks[burst - 1], jnp.asarray(positions) + burst,
-                        jnp.asarray(write), jnp.asarray(temps),
-                        jnp.asarray(top_ps), sub2, burst, need_top_p,
-                        kmesh=self.kmesh)
-                self.decode_dispatches += 1
-                self.decode_steps += burst
-                self._count_kv_positions(positions + burst, write, burst)
-                self._pending_burst = (dict(active), burst, toks2, counts2)
-            with tracing.phase("engine.fetch", which="burst"):
-                # [burst, max_slots], and the model's counts with it
-                toks, counts = jax.device_get((toks, counts))
-            self._add_model_counts(*counts)
+                # Every burst leaves its last row on the device, wanted or
+                # not: a lone request then walks the helper at every burst
+                # length, and no later mix of lengths compiles anything.
+                last_row = _last_row(toks)
         except Exception as e:  # noqa: BLE001 - cache donated & lost
             logger.exception("burst decode failed (%d active, burst %d)",
                              len(active), burst)
             self._recover_device_failure(f"decode failed: {e!r}")
             return False
-        self._emit_burst(active, burst, toks)
-        return True
-
-    def _should_chain(self, active: dict[int, GenerationRequest],
-                      burst: int) -> bool:
-        """Chain a second burst only when the device would otherwise sit
-        idle through the fetch: steady decode (nothing waiting to admit,
-        no prefilling slot, no draft model interleaving the cache), every
-        slot has cache headroom for TWO bursts, and someone still needs
-        more than one burst of tokens."""
-        if burst <= 1 or not getattr(self.config, "decode_pipeline", False):
-            return False
-        if self._pending_burst is not None or self.draft_params is not None:
-            return False
-        if not self._waiting.empty():
-            return False
-        for r in self._slots.values():
-            if r is not None and r.next_pos < 0:
-                return False  # a prefill wants the next tick
-        budget = 0
+        self.decode_dispatches += 1
+        self.decode_dispatches_ahead += bool(self._in_flight)
+        self.decode_steps += burst
+        self._count_kv_positions(positions, write, burst)
         for req in active.values():
-            if self.max_seq - 1 - req.next_pos < 2 * burst:
-                return False
-            budget = max(budget,
-                         req.sampling.max_tokens - len(req.out_tokens))
-        return budget > burst
-
-    def _resolve_pending_burst(self) -> bool:
-        """Fetch + emit the burst chained by the previous tick."""
-        if self._pending_burst is None:
-            return False
-        active, burst, toks_dev, counts = self._pending_burst
-        self._pending_burst = None
-        try:
-            with tracing.phase("engine.fetch", which="pending"):
-                toks, counts = jax.device_get((toks_dev, counts))
-            self._add_model_counts(*counts)
-        except Exception as e:  # noqa: BLE001 - surfaces at materialization
-            logger.exception("pipelined burst failed (%d slots)", len(active))
-            self._recover_device_failure(f"decode failed: {e!r}")
-            return True
-        self._emit_burst(active, burst, toks)
+            req.ahead += burst
+        self._in_flight.append(
+            _InFlight(dict(active), toks, counts, burst, last_row))
         return True
 
     def _add_model_counts(self, *fetched) -> None:

@@ -359,6 +359,31 @@ def test_prefill_decode_kv_handoff(tiny):
         dec.shutdown()
 
 
+def test_kv_import_while_bursts_are_in_flight():
+    """An imported line's first token is the host's, so it cannot ride
+    behind a burst that runs without it: the decode engine reads out what
+    is in flight, then carries both lines on, each with the tokens a
+    single engine gives it."""
+    cfg = LLMConfig(model="tiny", max_num_seqs=2, max_seq_len=128, seed=3,
+                    decode_burst=4)
+    pre, dec = LLMEngine(cfg), LLMEngine(cfg)
+    try:
+        sp = SamplingParams(max_tokens=40)
+        prompts = [_ids(12, 1), _ids(20, 2)]
+        want = [pre.generate(p, sp, timeout=120).token_ids for p in prompts]
+        payload = pre.prefill_only(prompts[1])
+        running = dec.submit(prompts[0], sp)
+        _wait_for(lambda: len(running.out_tokens) >= 6)
+        imported = dec.submit_prefilled(payload, sp)
+        for req, toks in zip((running, imported), want):
+            assert req.done.wait(120) and not req.error
+            assert dec._result(req).token_ids == toks
+        assert dec.stats()["decode_dispatches_ahead"] > 4
+    finally:
+        pre.shutdown()
+        dec.shutdown()
+
+
 def test_engine_bad_kv_payload_fails_cleanly():
     """A decode engine receiving an incompatible KV payload must fail that
     request (error surfaced, waiter woken) without leaking it in _requests
@@ -768,6 +793,84 @@ class TestSpeculativeDecoding:
             eng.shutdown()
 
 
+def _wait_for(cond, timeout=60.0):
+    import time
+
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, "condition never held"
+        time.sleep(0.002)
+
+
+def _outcomes(reqs):
+    for r in reqs:
+        assert r.done.wait(120) and not r.error, r.error
+    return [(list(r.out_tokens), r.finish_reason) for r in reqs]
+
+
+def _ids(n, seed):
+    return [int(t) for t in np.random.default_rng(seed).integers(1, 200, n)]
+
+
+def _steady(eng):
+    """Long generations, one after the other, then two at once."""
+    sp = [SamplingParams(max_tokens=n) for n in (40, 21, 33, 37)]
+    first = [eng.submit("pipeline me", sp[0])]
+    first[0].done.wait(120)
+    first.append(eng.submit("zz", sp[1]))
+    first[1].done.wait(120)
+    return first + [eng.submit("together", sp[2]),
+                    eng.submit("with this one", sp[3])]
+
+
+def _admitted_mid_flight(eng):
+    """Prompts of several chunks (16 a chunk) arrive while bursts run."""
+    reqs = [eng.submit(_ids(9, 0), SamplingParams(max_tokens=60))]
+    for i, (n, m) in enumerate([(50, 30), (37, 25), (70, 12)]):
+        _wait_for(lambda: len(reqs[0].out_tokens) >= 6 * (i + 1)
+                  or reqs[0].done.is_set())
+        reqs.append(eng.submit(_ids(n, i + 1), SamplingParams(max_tokens=m)))
+    return reqs
+
+
+def _stop_then_reuse(eng):
+    """One slot: a line stops on a stop token in the middle of a burst
+    (its 7th token: bursts of 4 after the first), and the freed slot goes
+    to a longer prompt, then to a shorter one, while the look-ahead burst
+    that still computes the stopped line is in flight."""
+    probe = eng.generate(_ids(24, 7), SamplingParams(max_tokens=12))
+    stop = probe.token_ids[6]
+    assert stop not in probe.token_ids[:6]
+    return [eng.submit(_ids(24, 7), SamplingParams(
+                max_tokens=40, stop_token_ids=(stop,))),
+            eng.submit(_ids(45, 8), SamplingParams(max_tokens=18)),
+            eng.submit(_ids(5, 9), SamplingParams(max_tokens=18))]
+
+
+def _ends_inside_lookahead(eng):
+    """Lines that end at max_tokens at every offset into a burst, and at
+    the end of their cache line (max_seq 48) at every offset."""
+    reqs = [eng.submit(_ids(6, n), SamplingParams(max_tokens=n))
+            for n in (1, 2, 3, 5, 6, 9, 12, 14)]
+    reqs += [eng.submit(_ids(p, p), SamplingParams(max_tokens=100))
+             for p in (28, 33, 38, 43, 47)]
+    return reqs
+
+
+# name -> (LLMConfig fields, script, least decode dispatches made ahead)
+_LOOKAHEAD_CASES = {
+    "steady": (dict(max_num_seqs=2, max_seq_len=128), _steady, 20),
+    "admitted_mid_flight": (
+        dict(max_num_seqs=4, max_seq_len=128, prefill_chunk=16),
+        _admitted_mid_flight, 8),
+    "stop_then_longer_and_shorter": (
+        dict(max_num_seqs=1, max_seq_len=128, prefill_chunk=16),
+        _stop_then_reuse, 6),
+    "ends_at_max_tokens_and_max_seq": (
+        dict(max_num_seqs=3, max_seq_len=48), _ends_inside_lookahead, 4),
+}
+
+
 class TestBurstDecoding:
     """decode_burst: D chained decode+sample steps per dispatch
     (engine.py decode_burst) must be invisible to outputs."""
@@ -826,22 +929,98 @@ class TestBurstDecoding:
         finally:
             eng.shutdown()
 
-    def test_pipelined_bursts_match_unpipelined(self):
-        """Chained bursts (decode_pipeline) must be output-invisible:
-        long generations where chaining engages every steady tick."""
-        base = LLMConfig(model="tiny", max_num_seqs=2, max_seq_len=128,
-                         decode_burst=4, decode_pipeline=False)
-        piped = LLMConfig(model="tiny", max_num_seqs=2, max_seq_len=128,
-                          decode_burst=4, decode_pipeline=True)
-        e1, e2 = LLMEngine(base), LLMEngine(piped)
+    @pytest.mark.parametrize("case", sorted(_LOOKAHEAD_CASES))
+    def test_pipelined_bursts_match_unpipelined(self, case):
+        """Dispatching the next program before the last is read must be
+        output-invisible: under greedy sampling every request gets, token
+        for token and with the same finish reason, what the strictly
+        serial schedule (decode_pipeline=False) gives it."""
+        kwargs, script, ahead = _LOOKAHEAD_CASES[case]
+        kwargs = {"model": "tiny", "decode_burst": 4, **kwargs}
+        serial = LLMEngine(LLMConfig(**kwargs, decode_pipeline=False))
+        piped = LLMEngine(LLMConfig(**kwargs, decode_pipeline=True))
         try:
-            for prompt, n in [("pipeline me", 40), ("zz", 21)]:
-                r1 = e1.generate(prompt, SamplingParams(max_tokens=n))
-                r2 = e2.generate(prompt, SamplingParams(max_tokens=n))
-                assert r1.token_ids == r2.token_ids, (prompt, n)
+            want = _outcomes(script(serial))
+            got = _outcomes(script(piped))
+            assert got == want
+            # serial: only a prompt's first burst goes behind anything
+            # unread (that prompt's last chunk)
+            s = serial.stats()
+            assert s["decode_dispatches_ahead"] <= s["admitted"], s
+            if ahead:   # the look-ahead engaged, it was not bypassed
+                s = piped.stats()
+                assert s["decode_dispatches_ahead"] >= ahead, s
+            assert not piped._in_flight and not serial._in_flight
         finally:
-            e1.shutdown()
-            e2.shutdown()
+            serial.shutdown()
+            piped.shutdown()
+
+    def test_failure_with_two_programs_in_flight_fails_their_requests(
+            self, monkeypatch):
+        """A program that fails on the device surfaces when its tokens are
+        read, with the next burst already queued behind it: exactly the
+        requests in those two programs fail, and the one that waited for a
+        slot is served, with the tokens it would have got anyway."""
+        import ray_tpu.llm.engine as eng_mod
+
+        cfg = dict(model="tiny", max_num_seqs=2, max_seq_len=128,
+                   decode_burst=4)
+        oracle = LLMEngine(LLMConfig(**cfg, decode_pipeline=False))
+        eng = LLMEngine(LLMConfig(**cfg))
+        real_get = jax.device_get
+        seen = {"bursts": 0, "in_flight": None}
+
+        def flaky_get(tree):
+            toks = tree[0] if isinstance(tree, tuple) else tree
+            if threading.current_thread() is eng._thread \
+                    and getattr(toks, "ndim", 0) == 2:
+                seen["bursts"] += 1
+                if seen["bursts"] == 3:
+                    # the burst being read and the one behind it
+                    seen["in_flight"] = 1 + len(eng._in_flight)
+                    raise RuntimeError("injected device failure")
+            return real_get(tree)
+
+        try:
+            sp = SamplingParams(max_tokens=40)
+            want = oracle.generate("the one that waits", sp).token_ids
+            monkeypatch.setattr(eng_mod.jax, "device_get", flaky_get)
+            a, b = eng.submit("first line", sp), eng.submit("second", sp)
+            c = eng.submit("the one that waits", sp)
+            for r in (a, b, c):
+                assert r.done.wait(120)
+            assert seen["in_flight"] == 2
+            assert "decode failed" in a.error and "decode failed" in b.error
+            assert c.error is None and eng._result(c).token_ids == want
+            s = eng.stats()
+            assert s["device_failures"] == 1 and s["requests_failed"] == 2
+        finally:
+            monkeypatch.undo()
+            oracle.shutdown()
+            eng.shutdown()
+
+    def test_shutdown_reads_out_what_is_in_flight(self):
+        """Tokens of programs already dispatched reach their request."""
+        cfg = dict(model="tiny", max_num_seqs=2, max_seq_len=256,
+                   decode_burst=4)
+        oracle = LLMEngine(LLMConfig(**cfg, decode_pipeline=False))
+        eng = LLMEngine(LLMConfig(**cfg))
+        try:
+            sp = SamplingParams(max_tokens=200)
+            want = oracle.generate("read me out", sp).token_ids
+            req = eng.submit("read me out", sp)
+            _wait_for(lambda: len(req.out_tokens) >= 9)
+            eng.shutdown()
+            assert not eng._thread.is_alive() and not eng._in_flight
+            assert req.ahead == 0
+            got = list(req.out_tokens)
+            assert len(got) >= 9 and got == want[:len(got)]
+            # what was dispatched was emitted: steps x 1 line, plus the first
+            assert eng.stats()["decode_steps"] == len(got) - 1 \
+                or req.done.is_set()
+        finally:
+            oracle.shutdown()
+            eng.shutdown()
 
 
 def test_hf_checkpoint_conversion_numerical_parity(tmp_path):

@@ -126,3 +126,36 @@ def test_stats_after_mixed_requests_carry_the_slot_layout_only():
     assert 0 < stats["kv_positions_read"] <= stats["kv_positions_reserved"]
     assert 0 < stats["prefill_kv_positions_read"] <= \
         stats["prefill_kv_positions_reserved"]
+
+
+@pytest.mark.parametrize("model", [_llama, _longcat],
+                         ids=["llama", "longcat"])
+def test_the_look_ahead_schedule_gives_the_serial_schedules_tokens(model):
+    """The scheduler knows no model: with either one's programs behind it,
+    lines that join bursts in flight (prompts of several chunks, more
+    requests than slots) get the tokens the strictly serial schedule gives
+    them, and a model's own counters still add up to the same picks."""
+    _, cfg = model()
+    prompts = [[7 + i for i in range(n)] for n in (5, 40, 23, 9, 31)]
+    budgets = [30, 17, 22, 9, 13]
+    outs, stats = [], []
+    for pipelined in (False, True):
+        eng = LLMEngine(LLMConfig(
+            model=cfg, max_num_seqs=SLOTS, max_seq_len=MAX_SEQ,
+            prefill_chunk=CHUNK, decode_burst=4, decode_pipeline=pipelined))
+        try:
+            reqs = [eng.submit(p, SamplingParams(max_tokens=n))
+                    for p, n in zip(prompts, budgets)]
+            assert all(r.done.wait(180) and not r.error for r in reqs)
+            outs.append([(r.out_tokens, r.finish_reason) for r in reqs])
+            stats.append(eng.stats())
+        finally:
+            eng.shutdown()
+    assert outs[0] == outs[1]
+    serial, ahead = stats
+    assert ahead["decode_dispatches_ahead"] > serial["decode_dispatches_ahead"]
+    assert ahead["decode_tokens"] == serial["decode_tokens"] == \
+        sum(len(toks) - 1 for toks, _ in outs[0])
+    served = engine.served_model(cfg)
+    for name in served.counters:   # counted for valid tokens only
+        assert ahead[name] > 0

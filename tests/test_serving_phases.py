@@ -300,3 +300,59 @@ def test_stream_steps_run_inside_the_captured_context_only():
         between.append(tracing.current_trace_id())
     assert seen == ["t" * 32] * 3
     assert between == [None] * 3     # the pool thread's own context is back
+
+
+def test_a_burst_is_dispatched_before_the_last_is_read():
+    """The order itself, on the phase record of 32 lines decoding
+    steadily: the scheduler dispatches burst n+1 between the reads of
+    burst n-1 and burst n, every burst and not every second one, so the
+    device has burst n to run while the host works; and a prompt's first
+    token is read before the burst dispatched after its last chunk."""
+    from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
+
+    eng = LLMEngine(LLMConfig(model="tiny", max_num_seqs=32, max_seq_len=128,
+                              seed=5, decode_burst=4, prefix_block_tokens=0))
+    try:
+        _run(eng, _prompts(2, 6), [6, 6])   # compile outside the record
+        before = eng.stats()
+        tracing.enable_tracing()
+        _run(eng, _prompts(32, 7), [65] * 32)
+        tracing.disable_tracing()
+        after = eng.stats()
+    finally:
+        eng.shutdown()
+    record = sorted((s for s in tracing.spans()
+                     if s.name in ("engine.fetch", "engine.decode_dispatch",
+                                   "engine.prefill_dispatch")),
+                    key=lambda s: s.start_ts)
+    kinds = [s.attributes.get("which", s.name) for s in record]
+    # between two consecutive reads of bursts there is a decode dispatch
+    reads = [i for i, k in enumerate(kinds) if k == "burst"]
+    assert len(reads) > 12
+    # (but for the last, which nothing is left to follow: 65 tokens are a
+    # first and 16 bursts of 4, so no line ends on a single step, the
+    # serial path that reads out everything in flight before it)
+    for a, b in zip(reads[:-1], reads[1:-1]):
+        assert "engine.decode_dispatch" in kinds[a:b], (a, b, kinds[a:b])
+    assert "step" not in kinds
+    # ... which goes behind a burst not yet read: dispatches run one ahead
+    # of reads from the second burst on, to the last
+    assert kinds.index("burst") > [i for i, k in enumerate(kinds)
+                                   if k == "engine.decode_dispatch"][1]
+    # first tokens: read after their own chunk, before the read of the
+    # burst dispatched after it (the k-th read of a first token follows
+    # the k-th last chunk; here every prompt is one chunk)
+    chunks = [i for i, k in enumerate(kinds)
+              if k == "engine.prefill_dispatch"]
+    firsts = [i for i, k in enumerate(kinds) if k == "prefill"]
+    assert len(chunks) == len(firsts) == 32
+    for chunk, first in zip(chunks, firsts):
+        assert chunk < first
+        joined = kinds.index("engine.decode_dispatch", chunk)
+        # reads are in dispatch order: count the bursts dispatched and
+        # read before each point
+        dispatched = kinds[:joined].count("engine.decode_dispatch")
+        assert kinds[:first].count("burst") <= dispatched
+    ahead = after["decode_dispatches_ahead"] - before["decode_dispatches_ahead"]
+    total = after["decode_dispatches"] - before["decode_dispatches"]
+    assert ahead / total > 0.9, (ahead, total)

@@ -319,6 +319,30 @@ def test_prefill_chunk_moves_no_whole_cache_at_mistral_widths(mosaic, slots,
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+@pytest.mark.parametrize("slots", [32, 16])
+def test_token_hand_off_helpers_compile_beside_the_programs_they_feed(mosaic,
+                                                                      slots):
+    """The two helpers that keep a burst's input tokens on the device, at
+    the serving cells' slot counts (32 is chat's, reason's and LongCat's)
+    and every burst length the scheduler chooses: a slice and an in-place
+    update of int32[slots], nothing else, so a step's time does not see
+    them."""
+    from ray_tpu.llm import engine
+
+    mesh = build_mesh(MeshSpec(), mosaic[:1])
+    repl = NamedSharding(mesh, P())
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=repl)
+
+    for steps in (8, 4, 2):
+        text = engine._last_row.lower(arg((steps, slots))).compile().as_text()
+        assert f"s32[{slots}]" in text and MOSAIC not in text
+    text = engine._join_token.lower(
+        arg((slots,)), arg((1,)), arg(())).compile().as_text()
+    assert "dynamic-update-slice" in text and MOSAIC not in text
+
+
 def test_decode_burst_partitions_at_mistral_widths(mosaic):
     mesh = build_mesh(MeshSpec(tp=2), mosaic[:2])
     text = _decode_burst_compiled(mesh, kernel_mesh(mesh), 32, 2048).as_text()
