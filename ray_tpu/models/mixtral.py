@@ -6,15 +6,17 @@ the reference has no first-class MoE implementation — SURVEY.md §2.4 EP row).
 Here MoE is first-class and TPU-native:
 
 - GShard/Switch-style capacity-based routing: top-k gates, per-expert token
-  slots, dispatch/combine einsums. Everything is STATIC-shaped — no gather by
-  dynamic token counts — so XLA tiles it onto the MXU and the ``expert``-
-  sharded einsums lower to all-to-all over the mesh's ``ep`` axis
-  automatically (the TPU-idiomatic equivalent of the reference's explicit
-  collective all-to-all).
+  slots ``[E, C, H]`` of STATIC shape, filled and read back by index: a small
+  integer plan (``routing_plan``: which slot each claim holds, which token
+  each slot holds) and two row gathers, so no tensor of tokens x experts x
+  capacity exists. Under expert parallelism the batch is replicated over the
+  mesh's ``ep`` axis (it shards over dp/fsdp only), so no token changes chips
+  and there is no all-to-all: each chip serves the experts it holds and the
+  parts are summed, one all-reduce of [T, H] a layer forward and one backward.
 - Attention/rope/norms are shared with the Llama family; only the MLP is
   replaced by the expert layer; layers still scan-stacked.
 - Load-balancing auxiliary loss (Switch Transformer form) returned alongside
-  the LM loss.
+  the LM loss; ``routing_stats`` reads the routers' load and drops.
 """
 
 from __future__ import annotations
@@ -22,10 +24,12 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models import llama as _llama
 from ray_tpu.ops.norms import rms_norm
@@ -127,73 +131,168 @@ def init_params(cfg: MixtralConfig, key: jax.Array) -> dict:
     }
 
 
-def compute_routing(cfg: MixtralConfig, logits: jax.Array, capacity: int):
-    """Router logits [T, E] → (dispatch [T,E,C], combine [T,E,C], aux).
+class RoutingPlan(NamedTuple):
+    """Who holds which capacity slot, read from both sides. Slots are numbered
+    ``e * capacity + c``; the two maps are inverse permutations with holes."""
 
-    Top-k gates renormalized to sum to 1 per token; slot positions assigned by
-    running claim count per expert (token-major priority); claims beyond
-    ``capacity`` are dropped. For a kept token, combine[t].sum() == 1.
+    gate: jax.Array           # [T, K] float32, renormalised top-k weights
+    slot_of_claim: jax.Array  # [T, K] int32; E * C for a dropped claim
+    token_of_slot: jax.Array  # [E * C] int32; T for an empty slot
+    gate_of_slot: jax.Array   # [E * C] float32; 0 for an empty slot
+    claims: jax.Array         # [E] int32, claims made on each expert
+    aux: jax.Array            # Switch load-balancing loss
+
+
+def routing_plan(cfg: MixtralConfig, logits: jax.Array,
+                 capacity: int) -> RoutingPlan:
+    """Router logits [T, E] -> the plan of a capacity-routed layer.
+
+    Top-k gates renormalised to sum to 1 a token; a claim's position in its
+    expert is the running count of earlier claims on that expert (token-major,
+    first choice before second); claims at or past ``capacity`` are dropped.
+    A stable sort of the claims by expert lists every expert's claims in that
+    same order, which is the inverse map.
     """
     T = logits.shape[0]
     E, K, C = cfg.num_experts, cfg.top_k, capacity
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gate_vals, gate_idx = lax.top_k(probs, K)  # [T, K]
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)  # renormalize top-k
+    gate, gate_idx = lax.top_k(probs, K)  # [T, K]
+    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
 
-    # Slot assignment: for the k-th choice of each token, its position within
-    # the chosen expert is the running count of earlier claims on that expert.
-    expert_onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.int32)  # [T, K, E]
-    flat_claims = expert_onehot.reshape(T * K, E)  # priority: token-major, k-minor
-    position = jnp.cumsum(flat_claims, axis=0) - flat_claims  # claims before us
-    position = (position * flat_claims).sum(-1).reshape(T, K)  # [T, K]
-    kept = position < C
+    expert = gate_idx.reshape(T * K)  # priority: token-major, k-minor
+    running = jnp.cumsum(
+        (expert[:, None] == jnp.arange(E)).astype(jnp.int32), axis=0)
+    position = jnp.take_along_axis(running, expert[:, None], axis=1)[:, 0] - 1
+    slot_of_claim = jnp.where(position < C, expert * C + position, E * C)
 
-    # dispatch[t, e, c] = 1 where token t owns slot c of expert e
-    slot_onehot = jax.nn.one_hot(position, C, dtype=jnp.float32)  # [T, K, C]
-    dispatch = jnp.einsum("tke,tkc->tec", expert_onehot.astype(jnp.float32),
-                          slot_onehot * kept[..., None])
-    combine = jnp.einsum("tk,tke,tkc->tec",
-                         gate_vals * kept, expert_onehot.astype(jnp.float32),
-                         slot_onehot)
+    claims = running[-1]
+    first = jnp.cumsum(claims) - claims  # an expert's first claim, sorted
+    c = jnp.arange(C)
+    claim_of_slot = jnp.argsort(expert, stable=True)[
+        jnp.minimum(first[:, None] + c, T * K - 1)]  # [E, C]
+    filled = c < claims[:, None]
+    token_of_slot = jnp.where(filled, claim_of_slot // K, T)
+    gate_of_slot = jnp.where(filled, gate.reshape(-1)[claim_of_slot], 0.0)
 
-    # Switch load-balancing loss: E * Σ_e (token fraction)·(mean router prob).
-    token_frac = dispatch.sum((0, 2)) / jnp.maximum(dispatch.sum(), 1.0)
-    prob_frac = probs.mean(0)
-    aux = E * jnp.sum(token_frac * prob_frac)
-    return dispatch, combine, aux
+    # Switch load-balancing loss: E * sum_e (share of kept claims) x (mean
+    # router probability).
+    kept = jnp.minimum(claims, C).astype(jnp.float32)
+    aux = E * jnp.sum(kept / jnp.maximum(kept.sum(), 1.0) * probs.mean(0))
+    return RoutingPlan(gate, slot_of_claim.reshape(T, K).astype(jnp.int32),
+                       token_of_slot.reshape(E * C).astype(jnp.int32),
+                       gate_of_slot.reshape(E * C), claims, aux)
 
 
-def moe_block(cfg: MixtralConfig, x: jax.Array, lp: dict):
-    """Capacity-routed expert MLP. x: [B, S, H] → ([B, S, H], aux_loss).
+def _rows(src, idx):
+    """src[idx] [N, H]; zeros where ``idx == len(src)``."""
+    m = src.shape[0]
+    rows = src.at[jnp.minimum(idx, m - 1)].get(mode="promise_in_bounds")
+    return jnp.where((idx < m)[:, None], rows, 0)
 
-    Static-shape dispatch: tokens → [E, C, H] slots via one-hot einsum (the
-    ``e``-sharded operands make XLA emit the ep all-to-all), per-expert SwiGLU
-    as batched einsums on the MXU, combine back with the gate weights.
-    Overflowing tokens beyond an expert's capacity are dropped (their residual
-    stream passes through unchanged) — Switch/GShard semantics.
+
+def _gather_sum(src, w, idx):
+    """out[n] = sum_j w[n, j] * src[idx[n, j]], summed in float32 and rounded
+    once; ``idx == len(src)`` adds nothing; ``w`` None weighs every row 1.
+    One gather of [N, H] a column of ``idx`` (top_k of them at most): a
+    gathered [N, J, H] would be tiled with J as a minor dimension and copied
+    to be summed."""
+    out = 0.0
+    for j in range(idx.shape[1]):
+        rows = _rows(src, idx[:, j]).astype(jnp.float32)
+        out = out + (rows if w is None else rows * w[:, j, None])
+    return out.astype(src.dtype)
+
+
+@jax.custom_vjp
+def gather_rows(src, w, idx, inv_w, inv_idx):
+    """Rows of ``src`` [M, H] moved by index: ``_gather_sum(src, w, idx)``
+    with ``idx`` [N, J]. ``(inv_w, inv_idx)`` [M, J'] describe the same
+    pairing from ``src``'s side (row m goes to ``inv_idx[m, :]``), so the
+    gradient to ``src`` is the same gather of the cotangent the other way
+    round and no scatter-add is ever built."""
+    return _gather_sum(src, w, idx)
+
+
+def _gather_rows_fwd(src, w, idx, inv_w, inv_idx):
+    return _gather_sum(src, w, idx), (src, w, idx, inv_w, inv_idx)
+
+
+def _gather_rows_bwd(res, d_out):
+    src, w, idx, inv_w, inv_idx = res
+    d_w = None
+    if w is not None:
+        d_w = jnp.stack([jnp.einsum(
+            "nh,nh->n", d_out, _rows(src, idx[:, j]),
+            preferred_element_type=jnp.float32)
+            for j in range(idx.shape[1])], axis=1)
+    return _gather_sum(d_out, inv_w, inv_idx), d_w, None, None, None
+
+
+gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+def _routed(cfg: MixtralConfig, x: jax.Array, lp: dict, kmesh=None):
+    """Capacity-routed expert MLP. x: [B, S, H] -> ([B, S, H], its plan).
+
+    Rows move by index over static ``[E, C, H]`` slots: dispatch gathers each
+    slot's token, the per-expert SwiGLU runs as batched einsums on the MXU,
+    combine gathers each token's slots back with the gate weights. Claims
+    beyond an expert's capacity are dropped (the residual stream passes
+    through unchanged): Switch/GShard semantics.
+
+    The slots are handled in ``G`` groups of whole experts, one group to a
+    chip of the ``ep`` axis of ``kmesh``'s mesh (one group without one). The
+    batch does not shard over ``ep``: every chip holds every token, fills and
+    reads back the slots of its own group, and the groups' parts of y are
+    summed, which XLA lowers to one all-reduce of [T, H] over ``ep``; the
+    gradient to x is summed the same way in the backward pass.
     """
     b, s, h = x.shape
-    T = b * s
+    T, E = b * s, cfg.num_experts
     C = cfg.capacity(T)
+    G = kmesh.mesh.shape.get("ep", 1) if kmesh is not None else 1
+    n = E // G * C  # slots a group
     dt = x.dtype
     xt = x.reshape(T, h)
 
-    logits = (xt @ lp["router"]).astype(jnp.float32)  # [T, E]
-    dispatch, combine, aux = compute_routing(cfg, logits, C)
+    def plan_of(xt, router):
+        return routing_plan(cfg, (xt @ router).astype(jnp.float32), C)
 
-    # [E, C, H] expert inputs — this einsum is the ep all-to-all boundary.
-    expert_in = jnp.einsum("tec,th->ech", dispatch.astype(dt), xt)
+    if G > 1:
+        # Every chip plans the whole routing from its own copy of the tokens.
+        # Left to itself XLA splits the top-k over the copies and passes the
+        # pieces round: eight all-to-all and a dozen small sums a layer.
+        plan_of = jax.shard_map(plan_of, mesh=kmesh.mesh, axis_names={"ep"},
+                                in_specs=P(), out_specs=P())
+    plan = plan_of(xt, lp["router"])
+    token_of_slot = plan.token_of_slot.reshape(G, n, 1)
+    gate_of_slot = plan.gate_of_slot.reshape(G, n, 1)
+    # A claim's slot as each group sees it: its own, or n (not held here).
+    local = plan.slot_of_claim - (jnp.arange(G) * n)[:, None, None]
+    slot_of_claim = jnp.where((local >= 0) & (local < n), local, n)
+
+    # Dispatch: slot (e, c) reads its token's row; an empty slot reads zeros.
+    expert_in = jax.vmap(gather_rows, (None, None, 0, None, 0))(
+        xt, None, token_of_slot, None, slot_of_claim).reshape(E, C, h)
     gate = jax.nn.silu(jnp.einsum(
         "ech,ehi->eci", expert_in, lp["we_gate"]).astype(jnp.float32)).astype(dt)
     up = jnp.einsum("ech,ehi->eci", expert_in, lp["we_up"])
     expert_out = jnp.einsum("eci,eih->ech", gate * up, lp["we_down"])
-    y = jnp.einsum("tec,ech->th", combine.astype(dt), expert_out)
-    return y.reshape(b, s, h), aux
+    # Combine: a token reads back the slots of its kept claims, gate-weighted.
+    y = jax.vmap(gather_rows, (0, None, 0, 0, 0))(
+        expert_out.reshape(G, n, h), plan.gate, slot_of_claim, gate_of_slot,
+        token_of_slot).sum(0)
+    return y.reshape(b, s, h), plan
 
 
-def _layer(cfg: MixtralConfig, x, lp, inv_freq, positions, attn_impl,
-           kmesh=None):
+def moe_block(cfg: MixtralConfig, x: jax.Array, lp: dict, kmesh=None):
+    """``_routed`` as a layer uses it: x [B, S, H] -> ([B, S, H], aux_loss)."""
+    y, plan = _routed(cfg, x, lp, kmesh)
+    return y, plan.aux
+
+
+def _attend(cfg: MixtralConfig, x, lp, inv_freq, positions, attn_impl, kmesh):
+    """The attention half of a layer, residual included."""
     b, s, h = x.shape
     dt = x.dtype
     xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
@@ -204,11 +303,15 @@ def _layer(cfg: MixtralConfig, x, lp, inv_freq, positions, attn_impl,
     k = apply_rope(k, positions, inv_freq)
     o = _llama._attention(cfg, q, k, v, attn_impl, None, kmesh)
     o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.num_heads * cfg.head_dim)
-    x = x + (o @ lp["wo"]).astype(dt)
+    return x + (o @ lp["wo"]).astype(dt)
 
+
+def _layer(cfg: MixtralConfig, x, lp, inv_freq, positions, attn_impl,
+           kmesh=None):
+    x = _attend(cfg, x, lp, inv_freq, positions, attn_impl, kmesh)
     xn = rms_norm(x, lp["mlp_norm"], cfg.norm_eps, kmesh)
-    y, aux = moe_block(cfg, xn, lp)
-    return x + y.astype(dt), aux
+    y, aux = moe_block(cfg, xn, lp, kmesh)
+    return x + y.astype(x.dtype), aux
 
 
 def forward(cfg: MixtralConfig, params: dict, tokens: jax.Array,
@@ -254,3 +357,28 @@ def loss_fn(cfg: MixtralConfig, params: dict, tokens: jax.Array,
     mask = mask.astype(jnp.float32)
     lm = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
     return lm + cfg.router_aux_coef * aux
+
+
+@partial(jax.jit, static_argnums=0, static_argnames=("attn_impl", "kmesh"))
+def routing_stats(cfg: MixtralConfig, params: dict, tokens: jax.Array,
+                  attn_impl: str = "flash", kmesh=None) -> dict:
+    """What the routers of ``forward`` ask for on tokens [B, S] and what
+    capacity refuses, layer by layer: ``expert_load`` [L, E], the claims on
+    each expert over all B * S * top_k claims (1 / E each when balanced), and
+    ``dropped_share`` [L], the claims past an expert's capacity over all
+    claims. A reading beside the train step, not a part of it."""
+    b, s = tokens.shape
+    claims_all = b * s * cfg.top_k
+    C = cfg.capacity(b * s)
+    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta, None)
+
+    def scan_body(x, lp):
+        x = _attend(cfg, x, lp, inv_freq, jnp.arange(s), attn_impl, kmesh)
+        xn = rms_norm(x, lp["mlp_norm"], cfg.norm_eps, kmesh)
+        y, plan = _routed(cfg, xn, lp, kmesh)
+        return x + y.astype(x.dtype), plan.claims
+
+    _, claims = lax.scan(scan_body, params["embed_tokens"][tokens],
+                         params["layers"])
+    return {"expert_load": claims / claims_all,
+            "dropped_share": jnp.maximum(claims - C, 0).sum(-1) / claims_all}

@@ -542,8 +542,10 @@ def make_mixtral_train_step(
     seed: int = 0,
     **step_options,
 ) -> tuple[Callable, Callable, Callable]:
-    """MoE specialization: expert weights shard over the mesh ``ep`` axis;
-    the dispatch/combine einsums become ep all-to-alls under XLA."""
+    """MoE specialization: expert weights shard over the mesh ``ep`` axis.
+    The batch does not (it shards over dp/fsdp), so every ``ep`` chip routes
+    every token to the experts it holds and the layer's output is one
+    all-reduce of [T, H] over ``ep`` (models/mixtral.py ``moe_block``)."""
     from ray_tpu.models import mixtral
 
     return make_train_step(

@@ -148,10 +148,22 @@ def test_mixtral_step_partitions_over_expert_parallel_chips(mosaic):
         mixtral.MixtralConfig.tiny(), hidden_size=256, num_heads=8,
         num_kv_heads=4, head_dim=64, dtype="bfloat16")
     mesh = build_mesh(MeshSpec(ep=4), mosaic)
+    # 1,024 tokens: a mask of tokens x local experts x capacity (640) has
+    # more elements than the layer's [T, H] sum has bytes.
     text = _compile_step(make_mixtral_train_step, cfg, mesh,
                          mixtral.param_logical_axes(cfg),
-                         [((4, 128), jnp.int32)] * 2)
+                         [((8, 128), jnp.int32)] * 2)
     assert "num_partitions=4" in text and text.count(MOSAIC) > 0
+    # The routed layer moves rows by index and every chip holds every token:
+    # nothing is gathered or passed round, no collective is as large as a
+    # mask, and the largest sum is the layer's [T, H] in bfloat16 (the
+    # gradients of the replicated embedding and head come next).
+    tokens, local = 8 * 128, cfg.num_experts // 4
+    mask = tokens * local * cfg.capacity(tokens)
+    ops = _collectives(text, 4)
+    assert {op for (op, _) in ops} == {"all-reduce"}, ops
+    assert max(n for (_, n) in ops) == tokens * cfg.hidden_size * 2 < mask, ops
+    assert f"[{tokens},{local},{cfg.capacity(tokens)}]" not in text
 
 
 def test_vit_step_partitions_over_four_chips(mosaic):
