@@ -14,9 +14,10 @@ from typing import Any
 
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
+from ray_tpu.models.ouro import OuroConfig
 
 # A model's own configuration: what llm/engine.served_model knows a model by.
-ModelConfig = LlamaConfig | LongcatConfig
+ModelConfig = LlamaConfig | LongcatConfig | OuroConfig
 
 
 @dataclass

@@ -451,7 +451,10 @@ class ServedModel:
 
     - ``init_params(cfg, key)`` and ``param_logical_axes(cfg)``;
     - ``init_cache(cfg, slots, max_seq)``: the slot cache, a pytree whose
-      leaves the programs below take donated and give back;
+      leaves the programs below take donated and give back. A leaf's
+      leading dimension is cache *lines*, of which a model may have more
+      than layers (two attentions a layer, or a line for every pass of a
+      looped stack); the slot is the second;
     - ``prefill_chunk``, ``decode_step``, ``decode_burst``,
       ``copy_prefix_kv``: jitted programs with the signatures of this
       module's own (the Llama ones), under these very names so that a
@@ -507,7 +510,14 @@ def served_model(cfg) -> ServedModel:
         from ray_tpu.llm.longcat_serving import SERVED
 
         return SERVED
-    raise TypeError(f"the engine serves no {type(cfg).__name__}")
+    from ray_tpu.models.ouro import OuroConfig
+
+    if isinstance(cfg, OuroConfig):
+        from ray_tpu.llm.ouro_serving import SERVED
+
+        return SERVED
+    raise TypeError(f"the engine serves no {type(cfg).__name__}: it serves "
+                    "LlamaConfig, LongcatConfig and OuroConfig")
 
 
 def require_kv_handoff(cfg) -> None:
@@ -1348,14 +1358,15 @@ class LLMEngine:
         from jax import lax
 
         kv_k, kv_v, first_token = req.preloaded
-        want = (self.model_cfg.num_layers, self.model_cfg.num_kv_heads,
-                self.model_cfg.head_dim)
+        # The cache's own lines, which a model may have more of than layers.
+        lines, _, heads, _, dim = self.cache["k"].shape
+        want = (lines, heads, dim)
         got = (kv_k.shape[0], kv_k.shape[1], kv_k.shape[3])
         p = kv_k.shape[2]
         if got != want or p > self.max_seq or kv_v.shape != kv_k.shape:
             raise ValueError(
                 f"payload KV shape {kv_k.shape} incompatible with this "
-                f"engine (layers/kv_heads/head_dim {want}, max_seq "
+                f"engine (lines/kv_heads/head_dim {want}, max_seq "
                 f"{self.max_seq})")
         self.cache["k"] = lax.dynamic_update_slice(
             self.cache["k"],

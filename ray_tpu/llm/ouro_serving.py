@@ -1,0 +1,186 @@
+"""What models/ouro.py supplies to llm/engine.py: a cache with a line for
+every (pass, layer) and the programs that run the looped stack against it.
+
+The cache is the engine's slot layout, ``{"k", "v"}`` of ``[lines, slots,
+kv_heads, max_seq, head_dim]``, with ``lines = total_ut_steps *
+num_layers``: the weights are shared by the passes, the keys and values
+are not, so a cached position costs ``total_ut_steps`` times a plain
+decoder's. The programs loop over the passes around the scan over the
+layers; the stacked weights are read again by every pass and the cache
+rides both loops as carry, never as scan xs/ys: line ``cfg.cache_line(t,
+l)`` is handed to the engine's own kernels (ops/prefill_attention.py,
+ops/decode_attention.py) as their ``layer``.
+
+The programs keep the engine's names (``prefill_chunk``, ``decode_step``,
+``decode_burst``: a device trace shows ``jit_<name>``) and signatures, and
+return models/ouro.LOOP_COUNTERS (int32[2], over valid tokens) beside their
+result; the scheduler adds them up where it fetches the tokens.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.llm.engine import ServedModel, copy_prefix_kv, sample_tokens
+from ray_tpu.models import ouro
+from ray_tpu.models.ouro import OuroConfig
+from ray_tpu.ops.decode_attention import (
+    decode_attention,
+    decode_kv_block,
+    kv_row_write,
+)
+from ray_tpu.ops.kernels import KernelMesh
+from ray_tpu.ops.prefill_attention import prefill_attention, prefill_kv_write
+from ray_tpu.ops.rope import rope_frequencies
+
+
+def init_cache(cfg: OuroConfig, max_slots: int, max_seq: int):
+    shape = (cfg.cache_lines, max_slots, cfg.num_kv_heads, max_seq,
+             cfg.head_dim)
+    return {"k": jnp.zeros(shape, cfg.jnp_dtype),
+            "v": jnp.zeros(shape, cfg.jnp_dtype)}
+
+
+def _run_loop(cfg, params, x, cache, positions, attend_line, valid, kmesh):
+    """Every pass over every layer, the cache as carry of both loops.
+    ``attend_line(line, q, k, v, (k_all, v_all)) -> (o, (k_all, v_all))``
+    writes the new rows into ``line`` and attends there. Returns (the
+    picked pass's normed state, cache, counts)."""
+    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta)
+
+    def stack(x, step, kv):
+        def body(carry, scanned):
+            x, kv = carry
+            lp, layer = scanned
+            x, kv = ouro.block(
+                cfg, lp, x, positions, inv_freq,
+                partial(attend_line, cfg.cache_line(step, layer)), kv, kmesh)
+            return (x, kv), None
+
+        return lax.scan(body, (x, kv), (params["layers"],
+                                        jnp.arange(cfg.num_layers)))[0]
+
+    x, (k_all, v_all), _, chosen = ouro.loop(
+        cfg, params, x, stack, (cache["k"], cache["v"]), kmesh)
+    return x, {"k": k_all, "v": v_all}, ouro.loop_counts(chosen, valid)
+
+
+@partial(jax.jit, static_argnums=(0,), static_argnames=("kmesh",),
+         donate_argnums=(2,))
+def prefill_chunk(cfg: OuroConfig, params, cache, tokens, kv_len, length,
+                  slot, *, kmesh: KernelMesh | None = None):
+    """Prefill ONE chunk of one sequence (the engine's contract, see
+    llm/engine.prefill_chunk). Returns (cache, last-token logits [V],
+    counts)."""
+    c = tokens.shape[0]
+    x = params["embed_tokens"][tokens][None]                  # [1, C, H]
+    positions = kv_len + jnp.arange(c)
+
+    def attend_line(line, q, k, v, kv):
+        kv = prefill_kv_write(*kv, k[0], v[0], line, slot, kv_len)
+        o = prefill_attention(q[0], *kv, line, slot, kv_len, length,
+                              kmesh=kmesh)
+        return o[None], kv
+
+    x, cache, counts = _run_loop(cfg, params, x, cache, positions,
+                                 attend_line, (positions < length)[None],
+                                 kmesh)
+    # The head on the one row that is kept.
+    last = x[0, jnp.clip(length - 1 - kv_len, 0, c - 1)]
+    return cache, ouro.lm_head(params, last), counts
+
+
+def _multi_token_impl(cfg: OuroConfig, params, cache, tokens, positions0,
+                      write_mask, kmesh=None):
+    """K tokens per slot in one pass of the whole loop against the cache
+    (the engine's contract, see llm/engine._multi_token_impl). Returns
+    (cache, logits [B, K, V], counts)."""
+    b, k = tokens.shape
+    x = params["embed_tokens"][tokens]                        # [B, K, H]
+    positions = positions0[:, None] + jnp.arange(k)[None, :]
+    lengths = jnp.where(write_mask, positions0 + k, 0)
+
+    def attend_line(line, q, kk, v, kv):
+        kv = kv_row_write(*kv, kk, v, line, positions0, write_mask,
+                          kmesh=kmesh)
+        return decode_attention(q, *kv, line, lengths, positions0,
+                                kmesh=kmesh), kv
+
+    x, cache, counts = _run_loop(
+        cfg, params, x, cache, positions, attend_line,
+        jnp.broadcast_to(write_mask[:, None], (b, k)), kmesh)
+    return cache, ouro.lm_head(params, x), counts
+
+
+@partial(jax.jit, static_argnums=(0,), static_argnames=("kmesh",),
+         donate_argnums=(2,))
+def decode_step(cfg: OuroConfig, params, cache, tokens, positions,
+                write_mask, *, kmesh: KernelMesh | None = None):
+    """One decode step for every slot. Returns (cache, logits [B, V],
+    counts)."""
+    cache, logits, counts = _multi_token_impl(
+        cfg, params, cache, tokens[:, None], positions, write_mask, kmesh)
+    return cache, logits[:, 0], counts
+
+
+@partial(jax.jit, static_argnums=(0, 9, 10), static_argnames=("kmesh",),
+         donate_argnums=(2,))
+def decode_burst(cfg: OuroConfig, params, cache, token0, positions0,
+                 write_mask, temps, top_ps, key, steps: int,
+                 need_top_p: bool = True, *,
+                 kmesh: KernelMesh | None = None):
+    """``steps`` chained decode+sample steps in one dispatch. Returns
+    (cache, tokens [steps, B], counts)."""
+
+    def step(carry, j):
+        c, tok, pos, counts = carry
+        c, logits, n = _multi_token_impl(cfg, params, c, tok[:, None], pos,
+                                         write_mask, kmesh)
+        nxt = sample_tokens(logits[:, 0], temps, top_ps, 0,
+                            jax.random.fold_in(key, j),
+                            need_top_p).astype(jnp.int32)
+        return (c, nxt, pos + 1, counts + n), nxt
+
+    zero = jnp.zeros((len(ouro.LOOP_COUNTERS),), jnp.int32)
+    (cache, _, _, counts), toks = lax.scan(
+        step, (cache, token0, positions0, zero), jnp.arange(steps))
+    return cache, toks, counts
+
+
+def _refuse(config) -> None:
+    """What this model does not run, said at construction."""
+    cfg = config.model_config()
+    for bad, what in (
+            (cfg.early_exit_threshold < 1,
+             f"early_exit_threshold {cfg.early_exit_threshold} (under 1): a "
+             "token that leaves the loop early still owes its later passes' "
+             "cache lines to the tokens after it, and the scheduler's bursts "
+             "and its count of cached positions assume equal work a token"),
+            (config.speculative_model is not None,
+             "a speculative draft"),
+            (config.tensor_parallel_size > 1,
+             "tensor_parallel_size > 1: its programs run on one device")):
+        if bad:
+            raise ValueError(f"OuroConfig does not support {what}")
+
+
+SERVED = ServedModel(
+    init_params=ouro.init_params,
+    param_logical_axes=ouro.param_logical_axes,
+    init_cache=init_cache,
+    prefill_chunk=prefill_chunk,
+    decode_step=decode_step,
+    decode_burst=decode_burst,
+    # The engine's own: every leaf's second axis is the slot, so it moves
+    # all the lines a slot has, here one a (pass, layer).
+    copy_prefix_kv=copy_prefix_kv,
+    kv_block=lambda cfg, max_seq: decode_kv_block(
+        max_seq, cfg.head_dim, cfg.jnp_dtype.itemsize),
+    counters=ouro.LOOP_COUNTERS,
+    constants=lambda cfg: {"loop_steps": cfg.total_ut_steps},
+    refuse=_refuse,
+)

@@ -1,4 +1,4 @@
-"""What the engine holds a served model to, for both models it serves.
+"""What the engine holds a served model to, for every model it serves.
 
 The scheduler reaches a model only through ``engine.ServedModel``: four
 jitted programs that take the slot cache donated and give it back, under the
@@ -15,9 +15,10 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
-from ray_tpu.llm import engine, longcat_serving
+from ray_tpu.llm import engine, longcat_serving, ouro_serving
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
+from ray_tpu.models.ouro import OuroConfig
 
 SLOTS, MAX_SEQ, CHUNK = 3, 64, 16
 
@@ -30,6 +31,13 @@ def _longcat():
     return longcat_serving, LongcatConfig.tiny(expert_shards=2,
                                                max_seq_len=MAX_SEQ)
 
+
+def _ouro():
+    return ouro_serving, OuroConfig.tiny(max_seq_len=MAX_SEQ)
+
+
+MODELS = dict(argvalues=[_llama, _longcat, _ouro],
+              ids=["llama", "longcat", "ouro"])
 
 # The Llama single step is jit(_decode_step_impl), so the benchmark's
 # readers of ``jit_decode_step`` would miss it; only a request with top_k
@@ -59,8 +67,7 @@ def _arguments(program, params):
 
 @pytest.mark.parametrize("program", ["prefill_chunk", "decode_step",
                                      "decode_burst", "copy_prefix_kv"])
-@pytest.mark.parametrize("model", [_llama, _longcat],
-                         ids=["llama", "longcat"])
+@pytest.mark.parametrize("model", **MODELS)
 def test_a_program_keeps_its_name_and_gives_the_donated_cache_back(model,
                                                                     program):
     module, cfg = model()
@@ -128,8 +135,7 @@ def test_stats_after_mixed_requests_carry_the_slot_layout_only():
         stats["prefill_kv_positions_reserved"]
 
 
-@pytest.mark.parametrize("model", [_llama, _longcat],
-                         ids=["llama", "longcat"])
+@pytest.mark.parametrize("model", **MODELS)
 def test_the_look_ahead_schedule_gives_the_serial_schedules_tokens(model):
     """The scheduler knows no model: with either one's programs behind it,
     lines that join bursts in flight (prompts of several chunks, more

@@ -451,3 +451,57 @@ def test_longcat_programs_move_no_whole_cache_and_copy_no_layer(mosaic):
                     or "bitcast" in line or "fused_computation" in line \
                     or "dynamic-slice" in line, line[:200]
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# Ouro-2.6B whole at the shapes of its serving cell (8 slots x 768): the
+# programs of llm/ouro_serving.py as the cell compiles them.
+@pytest.mark.parametrize("program", ["prefill_chunk(512)",
+                                     "prefill_chunk(64)", "decode_burst(8)"])
+def test_ouro_programs_copy_no_weight_stack_and_fit_the_chip(mosaic,
+                                                             program):
+    """The looped stack reads its stacked weights once a pass and carries a
+    cache of 192 lines through two loops. What the first compile of PR 34
+    got wrong, kept from coming back: the split of q, k and v into heads
+    folded into their products made XLA copy three stacked matrices
+    (bf16[48,2048,2048], 1.1 GiB of temporaries) at the top of every
+    program. The cache, a pass's lines of it and every stacked weight only
+    pass through; arguments and temporaries fit the chip's 15.75 GiB."""
+    from devbench import ouro_bench as bench
+
+    cfg = bench.config()
+    dev = NamedSharding(build_mesh(MeshSpec(), mosaic[:1]), P())
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=dev), tree)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    params, cache = bench.shapes(cfg, placed)
+    compiled = bench.lowerings(cfg, params, cache, arg)[program]().compile()
+    passing = {"parameter", "get-tuple-element", "tuple", "while",
+               "custom-call", "bitcast"}
+    if program.startswith("prefill"):
+        kernels = ("prefill_attention",)
+        passing.add("dynamic-update-slice")
+    else:
+        kernels = ("decode_attention", "kv_row_write")
+    text = compiled.as_text()
+    for name in kernels:
+        assert f'"{name}"' in text
+    big = bench.big_shapes(cfg)
+    assert cfg.cache_lines == 192 and big["cache"].startswith("[192,8,16,768,")
+    assert _opcodes_with_shape(text, big["cache"]) <= passing
+    # a pass's 48 lines, one line, one slot's line: never cut out
+    for shape in (big["pass_lines"], big["line"],
+                  f"[1,{cfg.num_kv_heads},{bench.MAX_SEQ},{cfg.head_dim}]"):
+        assert not _opcodes_with_shape(text, shape), shape
+    carried = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
+               "fusion", "dynamic-slice"}   # a fusion's parameter, its slice
+    for name in ("w_attn", "w_up", "w_down"):
+        assert _opcodes_with_shape(text, big[name]) <= carried, name
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 28
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30 \
+        < 15.5
