@@ -4,10 +4,16 @@
                                           [prefill_kernel] [prefill]
 
 - ``kernel``: ``ops.decode_attention`` and ``kv_row_write`` alone over all
-  layers of a stacked cache at the two serving shapes of the benchmark
-  (32 slots x 2,048 and 16 x 3,200, Mistral-7B widths), each at a few block
-  sizes, against the bytes of the live K/V at 819 GB/s; and the kernel's
-  result against the jnp reference on the same inputs.
+  layers of a stacked cache at the serving shapes of the benchmark (chat 32
+  slots x 2,048, docqa 16 x 3,200, reason 32 x 3,072 with every slot busy,
+  at Mistral-7B widths; Ouro's 192 lines of 8 x 768 at 16 KV heads and one
+  query head each), each at a few block sizes: microseconds a call beside
+  the live blocks, the dead blocks and the empty slots of that call, and
+  three patterns at the cell's own block (no line, one block a line, every
+  line whole) from which a block's exposed fetch, a live step's compute
+  and a dead step's cost can be fitted; the bytes of the live K/V at 819
+  GB/s; and the kernel's result against the jnp reference. The same file
+  runs against an older tree laid in ``.parent/`` (copy it in).
 - ``burst``: ``engine.decode_burst(steps=8)`` as the engine calls it, random
   weights, ms a step, with the weight bytes' floor beside it.
 - ``prefill_kernel``: ``ops.prefill_attention`` alone over all layers for a
@@ -34,12 +40,19 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 HBM_BYTES_PER_S = 819e9  # TPU v5e, Google Cloud documentation
 PEAK_FLOPS = 197e12      # bf16, same source
 
-# Mistral-7B widths; per cell: layers as served, slots, max_seq, live lengths
-# drawn from [lo, hi), share of busy slots, blocks to sweep.
+# Mistral-7B widths; the kernel's shapes below carry their own heads.
 WIDTHS = dict(hidden_size=4096, intermediate_size=14336, num_heads=32,
               num_kv_heads=8, head_dim=128, vocab_size=32768)
-SHAPES = {"chat": (12, 32, 2048, (64, 900), 0.72, (128, 256, 512, 1024)),
-          "docqa": (16, 16, 3200, (1100, 3100), 0.8, (128, 640))}
+# Per cell: layers as served (Ouro: cache lines, one a (pass, layer)),
+# slots, max_seq, live lengths drawn from [lo, hi), share of busy slots,
+# blocks to sweep, KV heads and query heads a KV head.
+SHAPES = {"chat": (12, 32, 2048, (64, 900), 0.72, (128, 256, 512, 1024),
+                   8, 4),
+          "docqa": (16, 16, 3200, (1100, 3100), 0.8, (128, 640), 8, 4),
+          "reason": (12, 32, 3072, (1024, 2900), 1.0, (128, 256, 512, 768),
+                     8, 4),
+          "ouro": (192, 8, 768, (200, 700), 1.0, (128, 256, 384, 768), 16, 1)}
+BURST_SHAPES = ("chat", "docqa")
 # The prefill side: layers as served, slots, max_seq; a chunk of 512 behind
 # each of CACHED_ROWS (2,560 + 512 is the end of reason's line).
 PREFILL_SHAPES = {"docqa": (16, 16, 3200), "reason": (12, 32, 3072)}
@@ -65,6 +78,15 @@ def _lengths(rng, slots: int, lo: int, hi: int, busy: float):
     return lens
 
 
+def _block_counts(lens, slots: int, s: int, block: int) -> dict:
+    """What the terms of a call's time count (PERF.md section 6, PR 35):
+    blocks that hold live positions, the other blocks of the rectangle
+    slots x (max_seq // block), and slots with no live position."""
+    live = int((-(-lens // block)).sum())
+    return {"live_blocks": live, "dead_blocks": slots * (s // block) - live,
+            "empty_slots": int((lens == 0).sum())}
+
+
 def bench_kernel() -> dict:
     import jax
     import jax.numpy as jnp
@@ -75,40 +97,63 @@ def bench_kernel() -> dict:
     from ray_tpu.ops.kernels import force_kernel_backend
 
     out = {}
-    hkv, d = WIDTHS["num_kv_heads"], WIDTHS["head_dim"]
-    g = WIDTHS["num_heads"] // hkv
-    for name, (layers, slots, s, (lo, hi), busy, blocks) in SHAPES.items():
+    for name, (layers, slots, s, (lo, hi), busy, blocks, hkv,
+               g) in SHAPES.items():
+        d = WIDTHS["head_dim"]
         rng = np.random.default_rng(0)
         lens = _lengths(rng, slots, lo, hi, busy)
         keys = jax.random.split(jax.random.PRNGKey(0), 3)
         q = jax.random.normal(keys[0], (slots, hkv * g, 1, d), jnp.bfloat16)
-        kc = jax.random.normal(keys[1], (layers, slots, hkv, s, d),
-                               jnp.bfloat16)
-        vc = jax.random.normal(keys[2], (layers, slots, hkv, s, d),
-                               jnp.bfloat16)
-        lens_d = jnp.asarray(lens)
-        pos = jnp.maximum(lens_d - 1, 0)
+        # One line's worth of random rows under every layer: the time does
+        # not read the values, and 192 lines drawn at once do not fit.
+        kc, vc = (jnp.tile(jax.random.normal(key, (1, slots, hkv, s, d),
+                                             jnp.bfloat16),
+                           (layers, 1, 1, 1, 1)) for key in keys[1:])
+        default = da.decode_kv_block(s, d)
         live_bytes = int(lens.sum()) * layers * 2 * hkv * d * 2
-        row = {"slots": slots, "max_seq": s, "live_positions": int(lens.sum()),
+        row = {"layers": layers, "slots": slots, "max_seq": s,
+               "kv_heads": hkv, "group": g, "default_block": default,
+               "live_positions": int(lens.sum()),
                "floor_ms": 1e3 * live_bytes / HBM_BYTES_PER_S}
 
         def all_layers(q, kc, vc, lens, pos, block):
+            # A tree with the flat walk builds its plan once a step, like
+            # the engine; the tree before it has none to build.
+            plan = ({"plan": da.decode_plan(lens, block, s)}
+                    if hasattr(da, "decode_plan") else {})
+
             def body(layer, q):
                 return da.decode_attention(q, kc, vc, layer, lens, pos,
-                                           block=block)
+                                           block=block, **plan)
             return lax.fori_loop(0, layers, body, q)
 
-        for block in blocks:
+        def us_a_call(lens, block):
+            lens_d = jnp.asarray(lens)
             fn = jax.jit(partial(all_layers, block=block))
-            row[f"attn_ms_block{block}"] = 1e3 * _time(fn, q, kc, vc, lens_d,
-                                                       pos)
-            read = int(da.kv_positions_read(lens, block).sum())
-            row[f"read_positions_block{block}"] = read
-        got = jax.jit(partial(da.decode_attention, block=None))(
-            q, kc, vc, 3, lens_d, pos)
+            return 1e6 * _time(fn, q, kc, vc, lens_d,
+                               jnp.maximum(lens_d - 1, 0)) / layers
+
+        # The cell's lengths at every block; then, at the block the cell
+        # runs, three patterns that isolate a term each: no line at all,
+        # one block a line, every line whole.
+        for block in blocks:
+            row[f"block{block}"] = {
+                "us_a_call": us_a_call(lens, block),
+                "block_us_at_hbm": 1e6 * 2 * hkv * block * d * 2
+                / HBM_BYTES_PER_S,
+                "read_positions": int(da.kv_positions_read(lens, block).sum()),
+                **_block_counts(lens, slots, s, block)}
+        for what, value in (("empty", 0), ("one_block", default),
+                            ("whole", s)):
+            pat = np.full(slots, value, np.int32)
+            row[f"pattern_{what}"] = {
+                "us_a_call": us_a_call(pat, default),
+                **_block_counts(pat, slots, s, default)}
+        lens_d = jnp.asarray(lens)
+        pos = jnp.maximum(lens_d - 1, 0)
+        got = jax.jit(da.decode_attention)(q, kc, vc, 3, lens_d, pos)
         with force_kernel_backend("reference"):
             want = jax.jit(da.decode_attention)(q, kc, vc, 3, lens_d, pos)
-        row["default_block"] = da.decode_kv_block(s, d)
         row["max_abs_diff_vs_reference"] = float(jnp.max(jnp.abs(
             got.astype(jnp.float32) - want.astype(jnp.float32))))
 
@@ -152,7 +197,8 @@ def bench_burst() -> dict:
     from ray_tpu.models.llama import LlamaConfig, init_params
 
     out = {}
-    for name, (layers, slots, s, (lo, hi), busy, _) in SHAPES.items():
+    for name in BURST_SHAPES:
+        layers, slots, s, (lo, hi), busy = SHAPES[name][:5]
         cfg = LlamaConfig(
             num_layers=layers, max_seq_len=s, dtype="bfloat16",
             tie_embeddings=False, rope_theta=1e6, **WIDTHS)

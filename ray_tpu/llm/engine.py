@@ -62,6 +62,7 @@ from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.decode_attention import (
     decode_attention,
     decode_kv_block,
+    decode_plan_of,
     kv_positions_read,
     kv_row_write,
 )
@@ -273,6 +274,9 @@ def _multi_token_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
                                 cfg.rope_scaling)
     positions = positions0[:, None] + jnp.arange(k)[None, :]  # [B, K]
     lengths = jnp.where(write_mask, positions0 + k, 0)
+    # Every layer attends at the same lengths: one walk of the live blocks,
+    # planned here and not in the loop.
+    plan = decode_plan_of(lengths, cache["k"], kmesh=kmesh)
 
     def body(carry, scanned):
         x, k_all, v_all = carry
@@ -284,7 +288,7 @@ def _multi_token_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
         k_all, v_all = kv_row_write(k_all, v_all, kk, v, layer, positions0,
                                     write_mask, kmesh=kmesh)
         o = decode_attention(q, k_all, v_all, layer, lengths, positions0,
-                             kmesh=kmesh)
+                             plan=plan, kmesh=kmesh)
         o = o.transpose(0, 2, 1, 3).reshape(b, k, -1)
         x = x + (o @ lp["wo"]).astype(x.dtype)
         x = _mlp(cfg, lp, x, kmesh)

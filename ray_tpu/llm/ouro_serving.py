@@ -31,6 +31,7 @@ from ray_tpu.models.ouro import OuroConfig
 from ray_tpu.ops.decode_attention import (
     decode_attention,
     decode_kv_block,
+    decode_plan_of,
     kv_row_write,
 )
 from ray_tpu.ops.kernels import KernelMesh
@@ -103,12 +104,15 @@ def _multi_token_impl(cfg: OuroConfig, params, cache, tokens, positions0,
     x = params["embed_tokens"][tokens]                        # [B, K, H]
     positions = positions0[:, None] + jnp.arange(k)[None, :]
     lengths = jnp.where(write_mask, positions0 + k, 0)
+    # All 192 lines attend at the same lengths: one walk of the live
+    # blocks, planned here and not in the loops.
+    plan = decode_plan_of(lengths, cache["k"], kmesh=kmesh)
 
     def attend_line(line, q, kk, v, kv):
         kv = kv_row_write(*kv, kk, v, line, positions0, write_mask,
                           kmesh=kmesh)
         return decode_attention(q, *kv, line, lengths, positions0,
-                                kmesh=kmesh), kv
+                                plan=plan, kmesh=kmesh), kv
 
     x, cache, counts = _run_loop(
         cfg, params, x, cache, positions, attend_line,

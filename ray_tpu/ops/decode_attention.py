@@ -9,10 +9,22 @@ long (``max_seq``), mostly not yet written, and shared by the
   small tile of G*K rows against a single read of that head's keys and
   values; no ``[B, Hkv, G, S, D]`` copy exists;
 - **length-aware**: it takes a per-slot length (0: an empty or prefilling
-  slot). Blocks of the sequence axis at or past the length are neither
-  fetched (the index map clamps to the last live block, so the pipeline
-  keeps the buffer it has) nor computed. Lengths, positions and the layer
-  are run-time scalars (scalar prefetch): one program serves every length;
+  slot), and its grid is the live blocks of this step and nothing else:
+  one flat list of (slot, block) pairs in slot order (:func:`decode_plan`),
+  as long as the lengths make it (a run-time grid bound). An empty slot
+  has no step, so nothing of its line is fetched; a block at or past a
+  line's length has none either; and consecutive steps being consecutive
+  live blocks, the pipeline's look-ahead of one step always has the next
+  live block in flight, of this line or of the next, while the current one
+  is multiplied. Lengths, positions, the layer and the plan are run-time
+  scalars (scalar prefetch): one program serves every length. The grid
+  this replaced was the rectangle (slots, blocks of a line): a dead block
+  was skipped by ``pl.when`` and its index clamped to the line's last live
+  block. On the v5e that cost 0.13 us a dead step, a fetch of block 0 for
+  every empty slot (its clamp is 0 and the slot changes), and every
+  line's first block exposed behind the dead steps of the line before it
+  (2.9 us a line at Mistral-7B's widths, where a live step is 2.8): 208
+  us a call at 25 lines of 64 to 900 in 32 slots of 2,048, now 114;
 - **in place**: it receives the whole stacked cache ``[L, B, Hkv, S, D]``
   and the layer index, and its block specs index the layer. A caller that
   handed it ``cache[l]`` would make XLA materialise that slice on every
@@ -36,6 +48,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -46,13 +59,19 @@ from ray_tpu.ops.kernels import KernelMesh, kernel_backend
 
 NEG_INF = -1e30
 
-# One block of one KV head's keys, in bytes. A grid step that computes costs
-# about half a microsecond whatever its block, so long lines want long
-# blocks, and a block read past a line's end costs little beside that: on
-# the v5e, at Mistral-7B widths, 16 lines of 3,200 took 3.2 ms in blocks of
-# 640 and 5.0 ms in blocks of 128, and 32 of 2,048 took the same 2.5 to 2.7
-# ms in blocks of 128, 256 and 512 (devbench/decode_attention_bench.py).
-# With head_dim 128 in bf16 the cap is 640 positions.
+# One block of one KV head's keys, in bytes. A grid step takes the longer of
+# its bytes (about 745 GB/s through the pipeline) and its compute, and the
+# compute hardly grows with the block: the products of 16 rows a head are
+# bound by their passes, not their width. On the v5e at Mistral-7B widths a
+# step of 128 positions is 1.3 us, of 256 2.3, of 512 2.8 (its bytes), so a
+# short block pays for steps what it saves in rows read past a line's end:
+# 32 lines of 1,024 to 2,900 in 3,072 took 664 / 627 / 417 / 421 us a layer
+# in blocks of 128 / 256 / 512 / 768, 25 lines of 64 to 900 in 2,048 139 /
+# 149 / 114 / 148 in 128 / 256 / 512 / 1,024, 16 lines of 3,200 269 in 128
+# and 164 in 640, and at 16 KV heads of one query row 8 lines of 768 took
+# 69 / 74 / 63 / 75 in 128 / 256 / 384 / 768 (devbench/
+# decode_attention_bench.py, on the walk of live blocks). With head_dim 128
+# in bf16 the cap is 640 positions.
 _HEAD_BLOCK_BYTES = 160 * 1024
 
 
@@ -107,62 +126,126 @@ def decode_attention_reference(q, k_cache, v_cache, layer, lengths,
     return out.astype(q.dtype).reshape(b, h, k, d)
 
 
-def _decode_attention_kernel(len_ref, pos_ref, layer_ref, q_ref, k_ref,
-                             v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                             block: int, k_tokens: int, sm_scale: float):
+class DecodePlan(NamedTuple):
+    """The kernel's grid for one decode step: the live blocks of every
+    line, in slot order. ``n_live`` int32[1] is how many there are; the
+    other four are int32 over the static worst case ``slots * (max_seq //
+    block)`` and mean something below ``n_live`` only: step t works on
+    block ``block[t]`` of slot ``slot[t]``, which is that slot's ``first``
+    and / or ``last`` live block. Under a mesh that divides the slots every
+    field is the concatenation of the shards' own plans."""
+
+    n_live: jax.Array
+    slot: jax.Array
+    block: jax.Array
+    first: jax.Array
+    last: jax.Array
+
+
+def _plan_spec(kmesh: KernelMesh) -> DecodePlan:
+    """Every field over the batch axes, like the lengths it is made from."""
+    return DecodePlan(*[kmesh.rows_spec(1)] * 5)
+
+
+def _decode_plan(lengths, block: int, max_seq: int) -> DecodePlan:
+    blocks_of = -(-jnp.clip(lengths, 0, max_seq).astype(jnp.int32) // block)
+    ends = jnp.cumsum(blocks_of)
+    t = jnp.arange(lengths.shape[0] * (max_seq // block), dtype=jnp.int32)
+    # Slots whose blocks all lie before step t: the slot step t belongs to.
+    # Past the last live step it is clamped, so that every entry indexes
+    # the cache.
+    slot = jnp.minimum((ends[None, :] <= t[:, None]).sum(-1, dtype=jnp.int32),
+                       lengths.shape[0] - 1)
+    blk = jnp.clip(t - (ends - blocks_of)[slot], 0, max_seq // block - 1)
+    live = t < ends[-1]
+    return DecodePlan(ends[-1:], slot, blk,
+                      (live & (blk == 0)).astype(jnp.int32),
+                      (live & (blk == blocks_of[slot] - 1)).astype(jnp.int32))
+
+
+def decode_plan(lengths, block: int, max_seq: int, *,
+                kmesh: KernelMesh | None = None) -> DecodePlan:
+    """The walk :func:`decode_attention` makes at these ``lengths`` [B]
+    (0: an empty slot; clamped to ``max_seq``) in blocks of ``block``:
+    ``sum(cdiv(length, block))`` steps. It depends on nothing else, so a
+    program whose layers all attend at the same lengths builds it once,
+    before its layer loop, and hands it to every call. Under a ``kmesh``
+    each device plans its own slots."""
+    fn = functools.partial(_decode_plan, block=block, max_seq=max_seq)
+    if kmesh is not None:
+        fn = kmesh.shard(fn, in_specs=(kmesh.rows_spec(1),),
+                         out_specs=_plan_spec(kmesh))
+    return fn(lengths)
+
+
+def decode_plan_of(lengths, k_cache, *, kmesh: KernelMesh | None = None):
+    """:func:`decode_plan` at the block in which :func:`decode_attention`
+    reads this stacked cache ``[L, B, Hkv, S, D]``."""
+    s, d = k_cache.shape[3:]
+    return decode_plan(lengths, decode_kv_block(s, d, k_cache.dtype.itemsize),
+                       s, kmesh=kmesh)
+
+
+def _decode_attention_kernel(len_ref, pos_ref, layer_ref, slot_ref, blk_ref,
+                             first_ref, last_ref, q_ref, k_ref, v_ref, o_ref,
+                             m_ref, l_ref, acc_ref, *, block: int,
+                             k_tokens: int, sm_scale: float):
+    """Grid step t: block ``blk[t]`` of slot ``slot[t]``, all KV heads. The
+    one axis carries a slot's running maximum, sum and accumulator from its
+    first live block to its last, so it is ``"arbitrary"``; the v5e has one
+    TensorCore and loses nothing. A chip with two would want the list cut
+    where the live blocks halve, each half a walk of its own."""
     from jax.experimental import pallas as pl
 
     del layer_ref  # read by the block specs' index maps
-    slot, blk = pl.program_id(0), pl.program_id(1)
+    t = pl.program_id(0)
+    slot, blk = slot_ref[t], blk_ref[t]
     length = len_ref[slot]
     hkv, rows, _ = q_ref.shape
 
-    @pl.when(blk == 0)
+    @pl.when(first_ref[t] == 1)
     def _():
         m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    @pl.when(blk * block < length)
-    def _():
-        kpos = blk * block + lax.broadcasted_iota(jnp.int32, (rows, block), 1)
-        # Row r of the tile is query head g, token j, r = g * K + j.
-        tok = lax.rem(lax.broadcasted_iota(jnp.int32, (rows, block), 0),
-                      k_tokens)
-        visible = (kpos <= pos_ref[slot] + tok) & (kpos < length)
-        for h in range(hkv):
-            s = lax.dot_general(q_ref[h], k_ref[h], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-            s = jnp.where(visible, s * sm_scale, NEG_INF)
-            m_prev = m_ref[h]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            # The select keeps a row with nothing visible yet at zero
-            # (exp(NEG_INF - NEG_INF) would be one).
-            p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[h] = alpha * l_ref[h] + p.sum(axis=-1, keepdims=True)
-            acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
-                p.astype(v_ref.dtype), v_ref[h],
-                preferred_element_type=jnp.float32)
-            m_ref[h] = m_new
+    kpos = blk * block + lax.broadcasted_iota(jnp.int32, (rows, block), 1)
+    # Row r of the tile is query head g, token j, r = g * K + j.
+    tok = lax.rem(lax.broadcasted_iota(jnp.int32, (rows, block), 0), k_tokens)
+    visible = (kpos <= pos_ref[slot] + tok) & (kpos < length)
+    for h in range(hkv):
+        s = lax.dot_general(q_ref[h], k_ref[h], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        s = jnp.where(visible, s * sm_scale, NEG_INF)
+        m_prev = m_ref[h]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        # The select keeps a row with nothing visible yet at zero
+        # (exp(NEG_INF - NEG_INF) would be one).
+        p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[h] = alpha * l_ref[h] + p.sum(axis=-1, keepdims=True)
+        acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[h],
+            preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
 
-    @pl.when(blk == pl.num_programs(1) - 1)
+    @pl.when(last_ref[t] == 1)
     def _():
         o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
             o_ref.dtype)
 
 
 def _decode_attention_pallas(q, k_cache, v_cache, layer, lengths, positions0,
-                             *, sm_scale: float, block: int | None = None):
+                             plan, *, sm_scale: float, block: int):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, k, d = q.shape
     hkv, s = k_cache.shape[2], k_cache.shape[3]
-    block = block or decode_kv_block(s, d, k_cache.dtype.itemsize)
-    if s % block:
-        raise ValueError(f"decode_attention: block {block} does not divide "
-                         f"the cache line of {s} positions")
+    if plan.slot.shape[0] != b * (s // block):
+        raise ValueError(
+            f"decode_attention: a plan of {plan.slot.shape[0]} steps for "
+            f"{b} lines of {s // block} blocks of {block}")
     # G*K rows a KV head, padded to whole sublane tiles of the operand dtype.
     rows = (h // hkv) * k
     tile = 32 // q.dtype.itemsize
@@ -171,21 +254,23 @@ def _decode_attention_pallas(q, k_cache, v_cache, layer, lengths, positions0,
     if rows_p != rows:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_p - rows), (0, 0)))
 
-    def kv_index(i, j, lens, pos, lyr):
-        last_live = jnp.maximum(pl.cdiv(lens[i], block) - 1, 0)
-        return (lyr[0], i, 0, jnp.minimum(j, last_live), 0)
+    def kv_index(t, lens, pos, lyr, slot, blk, first, last):
+        return (lyr[0], slot[t], 0, blk[t], 0)
 
-    def q_index(i, j, lens, pos, lyr):
-        return (i, 0, 0, 0)
+    def q_index(t, lens, pos, lyr, slot, blk, first, last):
+        return (slot[t], 0, 0, 0)
 
     kv_spec = pl.BlockSpec((None, None, hkv, block, d), kv_index)
     q_spec = pl.BlockSpec((None, hkv, rows_p, d), q_index)
+    # The lengths go in as given: the plan keeps the walk inside the line,
+    # and no position lies past its end for a longer length to unmask.
     out = pl.pallas_call(
         functools.partial(_decode_attention_kernel, block=block, k_tokens=k,
                           sm_scale=sm_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(b, s // block),
+            num_scalar_prefetch=7,
+            # A run-time bound: the steps that exist are the live blocks.
+            grid=(plan.n_live[0],),
             in_specs=[q_spec, kv_spec, kv_spec],
             out_specs=q_spec,
             scratch_shapes=[pltpu.VMEM((hkv, rows_p, 1), jnp.float32),
@@ -193,41 +278,59 @@ def _decode_attention_pallas(q, k_cache, v_cache, layer, lengths, positions0,
                             pltpu.VMEM((hkv, rows_p, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows_p, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            # One axis, and it carries a slot's accumulators from block to
+            # block.
+            dimension_semantics=("arbitrary",),
             # K and V blocks of every head, double-buffered, and as much
             # again for what the body keeps; never under the default.
             vmem_limit_bytes=max(
                 16 << 20, 8 * hkv * block * d * k_cache.dtype.itemsize)),
         interpret=kernel_backend() == "interpret",
         name="decode_attention",
-    )(jnp.minimum(lengths, s).astype(jnp.int32), positions0.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), qg, k_cache, v_cache)
+    )(lengths.astype(jnp.int32), positions0.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), plan.slot, plan.block,
+      plan.first, plan.last, qg, k_cache, v_cache)
+    # The walk never visits a slot with no live block, so nothing wrote its
+    # rows of the output.
+    out = jnp.where((lengths > 0)[:, None, None, None], out, 0)
     return out[:, :, :rows].reshape(b, h, k, d)
 
 
 def decode_attention(q, k_cache, v_cache, layer, lengths, positions0, *,
+                     plan: DecodePlan | None = None,
                      sm_scale: float | None = None,
                      kmesh: KernelMesh | None = None,
                      block: int | None = None):
     """q: [B, H, K, D] (K new tokens a slot, query head h of KV head
     ``h // (H // Hkv)``); k_cache, v_cache: [L, B, Hkv, S, D], the new rows
     already written; layer: int32 scalar; lengths, positions0: [B] int32.
-    Returns [B, H, K, D]. ``block`` overrides :func:`decode_kv_block`
+    Returns [B, H, K, D]. ``plan`` is :func:`decode_plan` of these lengths,
+    the cache's ``decode_kv_block`` and S (and this ``kmesh``), built by a
+    caller that attends many layers at the same lengths; without one the
+    call plans for itself. ``block`` overrides :func:`decode_kv_block`
     (tests and the kernel's own benchmark). Under a mesh of several devices
     pass its ``kmesh``: the kernel then runs on each device's heads."""
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if kernel_backend() == "reference":
         return decode_attention_reference(q, k_cache, v_cache, layer,
                                           lengths, positions0, scale)
+    s = k_cache.shape[3]
+    block = block or decode_kv_block(s, q.shape[-1], k_cache.dtype.itemsize)
+    if s % block:
+        raise ValueError(f"decode_attention: block {block} does not divide "
+                         f"the cache line of {s} positions")
+    if plan is None:
+        plan = decode_plan(lengths, block, s, kmesh=kmesh)
     fn = functools.partial(_decode_attention_pallas, sm_scale=scale,
                            block=block)
     if kmesh is not None:
         heads, cache = kmesh.heads_spec(4), _stack_spec(kmesh)
         rows = kmesh.rows_spec(1)
-        fn = kmesh.shard(fn, in_specs=(heads, cache, cache, P(), rows, rows),
+        fn = kmesh.shard(fn, in_specs=(heads, cache, cache, P(), rows, rows,
+                                       _plan_spec(kmesh)),
                          out_specs=heads)
     return fn(q, k_cache, v_cache, jnp.asarray(layer, jnp.int32), lengths,
-              positions0)
+              positions0, plan)
 
 
 def kv_row_write_reference(k_cache, v_cache, new_k, new_v, layer,

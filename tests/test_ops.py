@@ -480,19 +480,37 @@ def _plain_decode_attention(q, kc, vc, layer, lengths, pos0):
     return jnp.einsum("bhks,bhsd->bhkd", p, vl, precision="highest")
 
 
-@pytest.mark.parametrize("backend", ["interpret", "reference"])
-@pytest.mark.parametrize("layer", [0, 1])
-@pytest.mark.parametrize("s", [256, 384])  # 384: no power of two, as 3,200
-@pytest.mark.parametrize("group", [1, 4])
-@pytest.mark.parametrize("k_tokens", [1, 5])
+def _decode_lengths(pattern: str, s: int, k_tokens: int) -> np.ndarray:
+    """Seven slots: every length around a block edge beside an empty slot
+    and a full one, or lines that leave the walk nothing, or one line at
+    either end of it."""
+    return np.array({
+        "edges": [0, s, 1, DECODE_BLOCK - 1, DECODE_BLOCK, DECODE_BLOCK + 1,
+                  k_tokens],
+        "all_empty": [0] * 7,
+        "first_only": [DECODE_BLOCK + 1] + [0] * 6,
+        "last_only": [0] * 6 + [s]}[pattern], np.int32)
+
+
+# Every backend, layer and line at the mixed lengths; the patterns that leave
+# slots (or every slot) out of the kernel's walk through the kernel's body.
+_DECODE_CASES = [
+    (k, g, s, layer, backend, "edges")
+    for k in (1, 5) for g in (1, 4) for s in (256, 384)  # 384: as 3,200
+    for layer in (0, 1) for backend in ("interpret", "reference")
+] + [(k, g, 384, 1, "interpret", pattern)
+     for k in (1, 5) for g in (1, 4)
+     for pattern in ("all_empty", "first_only", "last_only")]
+
+
+@pytest.mark.parametrize("k_tokens,group,s,layer,backend,pattern",
+                         _DECODE_CASES)
 def test_decode_attention_matches_plain_softmax(k_tokens, group, s, layer,
-                                                backend):
+                                                backend, pattern):
     from ray_tpu.ops.decode_attention import decode_attention
 
     layers, hkv, d = 2, 2, 64
-    # An empty slot beside a full one, and every length around a block edge.
-    lengths = np.array([0, s, 1, DECODE_BLOCK - 1, DECODE_BLOCK,
-                        DECODE_BLOCK + 1, k_tokens], np.int32)
+    lengths = _decode_lengths(pattern, s, k_tokens)
     b = len(lengths)
     keys = jax.random.split(jax.random.PRNGKey(k_tokens * 7 + group), 3)
     q = jax.random.normal(keys[0], (b, hkv * group, k_tokens, d),
@@ -514,7 +532,104 @@ def test_decode_attention_matches_plain_softmax(k_tokens, group, s, layer,
     # bf16 probabilities and output: 2^-8 relative on values of order one.
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want), atol=3e-2)
-    assert not np.asarray(got[0], np.float32).any()  # the empty slot
+    # A slot that holds nothing gives zeros, visited or not.
+    assert not np.asarray(got, np.float32)[lengths == 0].any()
+
+
+@pytest.mark.parametrize("lengths", [
+    [0, 1, 128, 129, 512],       # empty, one row, a block, a block + 1, whole
+    [0, 0, 0, 0, 0],
+    [300, 0, 0, 0, 0],
+    [0, 0, 0, 0, 300],
+    [512, 512, 512, 512, 512],   # the static worst case, no step to spare
+    [600, 0, 129, 0, 7],         # past the line's end: clamped to it
+], ids=["edges", "all_empty", "first_only", "last_only", "all_whole",
+        "clamped"])
+def test_decode_plan_is_the_live_blocks_in_slot_order(lengths):
+    from ray_tpu.ops.decode_attention import decode_plan
+
+    block, max_seq = 128, 512
+    plan = jax.tree.map(np.asarray, decode_plan(
+        jnp.asarray(lengths, jnp.int32), block, max_seq))
+    blocks_of = [-(-min(n, max_seq) // block) for n in lengths]
+    want = [(i, j) for i, n in enumerate(blocks_of) for j in range(n)]
+    n_live = int(plan.n_live[0])
+    assert plan.n_live.shape == (1,) and n_live == sum(blocks_of)
+    steps = len(lengths) * (max_seq // block)
+    for field in plan[1:]:
+        assert field.shape == (steps,) and field.dtype == np.int32
+    assert list(zip(plan.slot[:n_live].tolist(),
+                    plan.block[:n_live].tolist())) == want
+    assert plan.first[:n_live].tolist() == [int(j == 0) for _, j in want]
+    assert plan.last[:n_live].tolist() == [
+        int(j == blocks_of[i] - 1) for i, j in want]
+    # What lies past the walk starts and ends nothing, and indexes the cache.
+    assert not plan.first[n_live:].any() and not plan.last[n_live:].any()
+    assert (plan.slot >= 0).all() and (plan.slot < len(lengths)).all()
+    assert (plan.block >= 0).all() and (plan.block < max_seq // block).all()
+
+
+def test_decode_attention_takes_its_callers_plan():
+    """A caller with many layers at the same lengths plans once: the result
+    is the call's own, and a plan for another block is refused."""
+    from ray_tpu.ops.decode_attention import decode_attention, decode_plan
+
+    layers, b, hkv, s, d = 2, 3, 2, 256, 64
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(keys[0], (b, hkv * 4, 1, d), jnp.bfloat16)
+    kc = jax.random.normal(keys[1], (layers, b, hkv, s, d), jnp.bfloat16)
+    vc = jax.random.normal(keys[2], (layers, b, hkv, s, d), jnp.bfloat16)
+    lengths = jnp.asarray([200, 0, 129], jnp.int32)
+    pos0 = lengths - 1
+
+    def two_layers(plan):
+        return [decode_attention(q, kc, vc, layer, lengths, pos0, plan=plan,
+                                 block=DECODE_BLOCK) for layer in range(2)]
+
+    with force_kernel_backend("interpret"):
+        own = jax.jit(lambda: two_layers(None))()
+        planned = jax.jit(lambda: two_layers(
+            decode_plan(lengths, DECODE_BLOCK, s)))()
+        for a, c in zip(own, planned):
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(c, np.float32))
+        with pytest.raises(ValueError, match="a plan of 3 steps"):
+            decode_attention(q, kc, vc, 0, lengths, pos0,
+                             plan=decode_plan(lengths, s, s),
+                             block=DECODE_BLOCK)
+
+
+def test_decode_attention_plans_each_devices_own_slots_under_a_mesh():
+    """Slots over one mesh axis, KV heads over another: the plan is the
+    shards' own plans side by side, and each device walks its own."""
+    from jax.sharding import Mesh
+
+    from ray_tpu.ops.decode_attention import decode_attention, decode_plan
+    from ray_tpu.ops.kernels import KernelMesh
+
+    kmesh = KernelMesh(Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                            ("dp", "tp")), batch=("dp",), heads="tp")
+    layers, b, hkv, s, d = 2, 4, 2, 256, 64
+    keys = jax.random.split(jax.random.PRNGKey(9), 3)
+    q = jax.random.normal(keys[0], (b, hkv * 4, 1, d), jnp.bfloat16)
+    kc = jax.random.normal(keys[1], (layers, b, hkv, s, d), jnp.bfloat16)
+    vc = jax.random.normal(keys[2], (layers, b, hkv, s, d), jnp.bfloat16)
+    lengths = jnp.asarray([0, 200, 129, 0], jnp.int32)
+    pos0 = jnp.maximum(lengths - 1, 0)
+    want = _plain_decode_attention(q, kc, vc, 1, lengths, pos0)
+    with force_kernel_backend("interpret"):
+        plan = jax.jit(lambda n: decode_plan(n, DECODE_BLOCK, s,
+                                             kmesh=kmesh))(lengths)
+        got = jax.jit(lambda plan: decode_attention(
+            q, kc, vc, 1, lengths, pos0, plan=plan, block=DECODE_BLOCK,
+            kmesh=kmesh))(plan)
+    # Two slots a device, in its own numbering.
+    assert plan.n_live.tolist() == [2, 2]
+    assert plan.slot[:2].tolist() == [1, 1]
+    assert plan.slot[4:6].tolist() == [0, 0]
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), atol=3e-2)
+    assert not np.asarray(got, np.float32)[np.asarray(lengths) == 0].any()
 
 
 @pytest.mark.parametrize("backend", ["interpret", "reference"])

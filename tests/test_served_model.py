@@ -97,6 +97,42 @@ def test_a_program_keeps_its_name_and_gives_the_donated_cache_back(model,
     assert all(leaf.is_deleted() for leaf in jax.tree.leaves(cache))
 
 
+def _cumsums(jaxpr, in_loop=False):
+    """(outside, inside): cumulative sums of a jaxpr outside and inside its
+    loops, through every nested jaxpr."""
+    outside = inside = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cumsum":
+            outside, inside = outside + (not in_loop), inside + in_loop
+        loop = in_loop or eqn.primitive.name in ("scan", "while")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            o, i = _cumsums(sub, loop)
+            outside, inside = outside + o, inside + i
+    return outside, inside
+
+
+@pytest.mark.parametrize("model", argvalues=[_llama, _ouro],
+                         ids=["llama", "ouro"])
+def test_a_decode_step_plans_its_walk_once_before_the_layer_loop(model):
+    """``decode_attention`` walks the live blocks of every line by a plan
+    that depends on the lengths alone, so the step builds it once
+    (``decode_plan``: a cumulative sum over the slots) and every layer, or
+    every one of a looped stack's 192 cache lines, is handed the same."""
+    from ray_tpu.ops.kernels import force_kernel_backend
+
+    module, cfg = model()
+    served = engine.served_model(cfg)
+    params = jax.eval_shape(lambda: served.init_params(
+        cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: served.init_cache(cfg, SLOTS, MAX_SEQ))
+    tokens, positions, write = _arguments("decode_step", None)[1:]
+    with force_kernel_backend("interpret"):
+        jaxpr = jax.make_jaxpr(
+            lambda p, c: module._multi_token_impl(
+                cfg, p, c, tokens[:, None], positions, write))(params, cache)
+    assert _cumsums(jaxpr.jaxpr) == (1, 0)
+
+
 def test_the_engine_has_one_kv_layout_and_refuses_the_block_pool():
     with pytest.raises(ValueError, match=r"block pool.*R3"):
         LLMEngine(LLMConfig(model="tiny", max_num_seqs=2, max_seq_len=64,

@@ -274,6 +274,21 @@ def _opcodes_with_shape(text: str, shape: str) -> set[str]:
     return ops
 
 
+def _plans_outside_the_layer_loop(text: str) -> bool:
+    """``decode_plan`` is a cumulative sum (a ``reduce-window`` here) and
+    comparisons: the compiled program has it, once a step, and the one
+    computation that holds the ``decode_attention`` call, the body of the
+    loop over layers (or over a looped stack's cache lines), has neither it
+    nor a sort."""
+    import re
+
+    bodies = [c for c in re.split(r"\n(?=(?:ENTRY )?%[\w.-]+ \()", text)
+              if '"decode_attention"' in c]
+    return (len(bodies) == 1 and "reduce-window(" in text
+            and "reduce-window(" not in bodies[0]
+            and " sort(" not in bodies[0])
+
+
 @pytest.mark.parametrize("slots,max_seq", [(32, 2048), (16, 3200)])
 def test_decode_burst_moves_no_whole_cache_at_mistral_widths(mosaic, slots,
                                                              max_seq):
@@ -293,6 +308,8 @@ def test_decode_burst_moves_no_whole_cache_at_mistral_widths(mosaic, slots,
     for shape in (f"[{MISTRAL.num_layers},{slots},{hkv},{max_seq},{d}]",
                   f"[{slots},{hkv},{max_seq},{d}]"):
         assert _opcodes_with_shape(text, shape) <= passing, shape
+    # The walk of the live blocks is planned once a step, not once a layer.
+    assert _plans_outside_the_layer_loop(text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
@@ -490,6 +507,10 @@ def test_ouro_programs_copy_no_weight_stack_and_fit_the_chip(mosaic,
     text = compiled.as_text()
     for name in kernels:
         assert f'"{name}"' in text
+    if program.startswith("decode"):
+        # 192 calls a step: a plan rebuilt at each would spend what the
+        # walk of the live blocks saves.
+        assert _plans_outside_the_layer_loop(text)
     big = bench.big_shapes(cfg)
     assert cfg.cache_lines == 192 and big["cache"].startswith("[192,8,16,768,")
     assert _opcodes_with_shape(text, big["cache"]) <= passing
