@@ -110,6 +110,7 @@ def _repeat_kv(x, n_rep: int):
         b, h * n_rep, s, d)
 
 
+@tracing.part("mlp")
 def _mlp(cfg: LlamaConfig, lp, x, kmesh):
     dt = x.dtype
     xn = rms_norm(x, lp["mlp_norm"], cfg.norm_eps, kmesh)
@@ -123,6 +124,7 @@ def _mlp(cfg: LlamaConfig, lp, x, kmesh):
     return x + (act @ lp["w_down"]).astype(dt)
 
 
+@tracing.part("head")
 def _lm_head(cfg: LlamaConfig, params, x, kmesh):
     """x: [B, S, H] → fp32 logits [B, S, V]."""
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
@@ -145,40 +147,48 @@ def prefill(cfg: LlamaConfig, params, cache, tokens, length, slot, *,
     returns (cache, next_token_logits [V]).
     """
     s = tokens.shape[0]
-    x = params["embed_tokens"][tokens][None]  # [1, S, H]
-    positions = jnp.arange(s)
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
-    n_rep = cfg.num_heads // cfg.num_kv_heads
-    causal = (positions[None, :] <= positions[:, None])  # [S, S]
-    valid = positions[None, :] < length
-    mask = (causal & valid)[None, None]  # [1, 1, S, S]
+    with tracing.part("embed"):
+        x = params["embed_tokens"][tokens][None]  # [1, S, H]
+    with tracing.part("attn"):
+        positions = jnp.arange(s)
+        inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
+                                    cfg.rope_scaling)
+        n_rep = cfg.num_heads // cfg.num_kv_heads
+        causal = (positions[None, :] <= positions[:, None])  # [S, S]
+        valid = positions[None, :] < length
+        mask = (causal & valid)[None, None]  # [1, 1, S, S]
 
     def body(x, scanned):
         lp, k_l, v_l = scanned  # k_l/v_l: [slots, Hkv, max_seq, D]
         b, s_, _ = x.shape
-        xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
-        q, k, v = _project_qkv(cfg, lp, xn, b, s_)
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
-        # Write this layer's K/V into the slot (positions 0..S).
-        k_l = lax.dynamic_update_slice(k_l, k[0].astype(k_l.dtype)[None],
-                                       (slot, 0, 0, 0))
-        v_l = lax.dynamic_update_slice(v_l, v[0].astype(v_l.dtype)[None],
-                                       (slot, 0, 0, 0))
-        kr, vr = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, kr).astype(jnp.float32)
-        scores = scores / np.sqrt(cfg.head_dim) + jnp.where(mask, 0.0, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        o = jnp.einsum("bhqk,bhkd->bhqd", probs, vr)
-        o = o.transpose(0, 2, 1, 3).reshape(b, s_, -1)
-        x = x + (o @ lp["wo"]).astype(x.dtype)
+        with tracing.part("attn"):
+            xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
+            q, k, v = _project_qkv(cfg, lp, xn, b, s_)
+            q = apply_rope(q, positions, inv_freq)
+            k = apply_rope(k, positions, inv_freq)
+            # Write this layer's K/V into the slot (positions 0..S).
+            with tracing.part("cache"):
+                k_l = lax.dynamic_update_slice(
+                    k_l, k[0].astype(k_l.dtype)[None], (slot, 0, 0, 0))
+                v_l = lax.dynamic_update_slice(
+                    v_l, v[0].astype(v_l.dtype)[None], (slot, 0, 0, 0))
+            kr, vr = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
+            scores = jnp.einsum("bhqd,bhkd->bhqk", q, kr).astype(jnp.float32)
+            scores = scores / np.sqrt(cfg.head_dim) \
+                + jnp.where(mask, 0.0, NEG_INF)
+            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+            o = jnp.einsum("bhqk,bhkd->bhqd", probs, vr)
+            o = o.transpose(0, 2, 1, 3).reshape(b, s_, -1)
+            x = x + (o @ lp["wo"]).astype(x.dtype)
         x = _mlp(cfg, lp, x, kmesh)
         return x, (k_l, v_l)
 
-    x, (new_k, new_v) = lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
+    with tracing.part("stack"):
+        x, (new_k, new_v) = lax.scan(
+            body, x, (params["layers"], cache["k"], cache["v"]))
     logits = _lm_head(cfg, params, x, kmesh)[0]  # [S, V]
-    last = logits[jnp.maximum(length - 1, 0)]
+    with tracing.part("head"):
+        last = logits[jnp.maximum(length - 1, 0)]
     return {"k": new_k, "v": new_v}, last
 
 
@@ -203,32 +213,39 @@ def prefill_chunk(cfg: LlamaConfig, params, cache, tokens, kv_len, length,
     """
     c = tokens.shape[0]
     num_layers = cache["k"].shape[0]
-    x = params["embed_tokens"][tokens][None]  # [1, C, H]
-    positions = kv_len + jnp.arange(c)
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
+    with tracing.part("embed"):
+        x = params["embed_tokens"][tokens][None]  # [1, C, H]
+    with tracing.part("attn"):
+        positions = kv_len + jnp.arange(c)
+        inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
+                                    cfg.rope_scaling)
 
     def body(carry, scanned):
         x, k_all, v_all = carry
         lp, layer = scanned
-        xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
-        q, k, v = _project_qkv(cfg, lp, xn, 1, c)
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
-        k_all, v_all = prefill_kv_write(k_all, v_all, k[0], v[0], layer,
-                                        slot, kv_len)
-        o = prefill_attention(q[0], k_all, v_all, layer, slot, kv_len,
-                              length, kmesh=kmesh)
-        o = o.transpose(1, 0, 2).reshape(1, c, -1)
-        x = x + (o @ lp["wo"]).astype(x.dtype)
+        with tracing.part("attn"):
+            xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
+            q, k, v = _project_qkv(cfg, lp, xn, 1, c)
+            q = apply_rope(q, positions, inv_freq)
+            k = apply_rope(k, positions, inv_freq)
+            with tracing.part("cache"):
+                k_all, v_all = prefill_kv_write(k_all, v_all, k[0], v[0],
+                                                layer, slot, kv_len)
+            o = prefill_attention(q[0], k_all, v_all, layer, slot, kv_len,
+                                  length, kmesh=kmesh)
+            o = o.transpose(1, 0, 2).reshape(1, c, -1)
+            x = x + (o @ lp["wo"]).astype(x.dtype)
         x = _mlp(cfg, lp, x, kmesh)
         return (x, k_all, v_all), None
 
-    (x, new_k, new_v), _ = lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (params["layers"], jnp.arange(num_layers)))
+    with tracing.part("stack"):
+        (x, new_k, new_v), _ = lax.scan(
+            body, (x, cache["k"], cache["v"]),
+            (params["layers"], jnp.arange(num_layers)))
     # The head on the one row that is kept.
-    last = lax.dynamic_slice_in_dim(
-        x, jnp.clip(length - 1 - kv_len, 0, c - 1), 1, 1)  # [1, 1, H]
+    with tracing.part("head"):
+        last = lax.dynamic_slice_in_dim(
+            x, jnp.clip(length - 1 - kv_len, 0, c - 1), 1, 1)  # [1, 1, H]
     return {"k": new_k, "v": new_v}, _lm_head(cfg, params, last, kmesh)[0, 0]
 
 
@@ -249,7 +266,8 @@ def _decode_step_impl(cfg: LlamaConfig, params, cache, tokens, positions,
         write_mask = jnp.ones(tokens.shape, bool)
     cache, logits = _multi_token_impl(cfg, params, cache, tokens[:, None],
                                       positions, write_mask, kmesh)
-    return cache, logits[:, 0]
+    with tracing.part("head"):
+        return cache, logits[:, 0]
 
 
 def _multi_token_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
@@ -269,34 +287,40 @@ def _multi_token_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
     logits mean nothing."""
     b, k = tokens.shape
     num_layers = cache["k"].shape[0]
-    x = params["embed_tokens"][tokens]  # [B, K, H]
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
-                                cfg.rope_scaling)
-    positions = positions0[:, None] + jnp.arange(k)[None, :]  # [B, K]
-    lengths = jnp.where(write_mask, positions0 + k, 0)
-    # Every layer attends at the same lengths: one walk of the live blocks,
-    # planned here and not in the loop.
-    plan = decode_plan_of(lengths, cache["k"], kmesh=kmesh)
+    with tracing.part("embed"):
+        x = params["embed_tokens"][tokens]  # [B, K, H]
+    with tracing.part("attn"):
+        inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
+                                    cfg.rope_scaling)
+        positions = positions0[:, None] + jnp.arange(k)[None, :]  # [B, K]
+        lengths = jnp.where(write_mask, positions0 + k, 0)
+        # Every layer attends at the same lengths: one walk of the live
+        # blocks, planned here and not in the loop.
+        plan = decode_plan_of(lengths, cache["k"], kmesh=kmesh)
 
     def body(carry, scanned):
         x, k_all, v_all = carry
         lp, layer = scanned
-        xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
-        q, kk, v = _project_qkv(cfg, lp, xn, b, k)
-        q = apply_rope(q, positions, inv_freq)
-        kk = apply_rope(kk, positions, inv_freq)
-        k_all, v_all = kv_row_write(k_all, v_all, kk, v, layer, positions0,
-                                    write_mask, kmesh=kmesh)
-        o = decode_attention(q, k_all, v_all, layer, lengths, positions0,
-                             plan=plan, kmesh=kmesh)
-        o = o.transpose(0, 2, 1, 3).reshape(b, k, -1)
-        x = x + (o @ lp["wo"]).astype(x.dtype)
+        with tracing.part("attn"):
+            xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
+            q, kk, v = _project_qkv(cfg, lp, xn, b, k)
+            q = apply_rope(q, positions, inv_freq)
+            kk = apply_rope(kk, positions, inv_freq)
+            with tracing.part("cache"):
+                k_all, v_all = kv_row_write(k_all, v_all, kk, v, layer,
+                                            positions0, write_mask,
+                                            kmesh=kmesh)
+            o = decode_attention(q, k_all, v_all, layer, lengths, positions0,
+                                 plan=plan, kmesh=kmesh)
+            o = o.transpose(0, 2, 1, 3).reshape(b, k, -1)
+            x = x + (o @ lp["wo"]).astype(x.dtype)
         x = _mlp(cfg, lp, x, kmesh)
         return (x, k_all, v_all), None
 
-    (x, new_k, new_v), _ = lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (params["layers"], jnp.arange(num_layers)))
+    with tracing.part("stack"):
+        (x, new_k, new_v), _ = lax.scan(
+            body, (x, cache["k"], cache["v"]),
+            (params["layers"], jnp.arange(num_layers)))
     logits = _lm_head(cfg, params, x, kmesh)  # [B, K, V]
     return {"k": new_k, "v": new_v}, logits
 
@@ -323,13 +347,15 @@ def decode_burst(cfg: LlamaConfig, params, cache, token0, positions0,
         c, tok, pos = carry
         c, logits = _decode_step_impl(cfg, params, c, tok, pos, write_mask,
                                       kmesh=kmesh)
-        nxt = sample_tokens(logits.astype(jnp.float32), temps, top_ps, 0,
-                            jax.random.fold_in(key, j),
-                            need_top_p).astype(jnp.int32)
-        return (c, nxt, pos + 1), nxt
+        with tracing.part("sample"):
+            nxt = sample_tokens(logits.astype(jnp.float32), temps, top_ps, 0,
+                                jax.random.fold_in(key, j),
+                                need_top_p).astype(jnp.int32)
+            return (c, nxt, pos + 1), nxt
 
-    (cache, _, _), toks = lax.scan(step, (cache, token0, positions0),
-                                   jnp.arange(steps))
+    with tracing.part("stack"):
+        (cache, _, _), toks = lax.scan(step, (cache, token0, positions0),
+                                       jnp.arange(steps))
     return cache, toks
 
 
@@ -355,15 +381,18 @@ def draft_propose(cfg: LlamaConfig, params, cache, token0, positions0,
         c, tok, pos = carry
         c, logits = _decode_step_impl(cfg, params, c, tok, pos, write_mask,
                                       kmesh=kmesh)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (c, nxt, pos + 1), nxt
+        with tracing.part("sample"):
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (c, nxt, pos + 1), nxt
 
     # k+1 iterations: the extra step writes the LAST proposal's KV inside
     # this same dispatch (its own proposal is discarded), so a
     # full-acceptance tick needs no separate one-token catch-up prefill.
-    (cache, _, _), toks = lax.scan(step, (cache, token0, positions0),
-                                   None, length=k + 1)
-    return cache, toks.T[:, :k]  # [B, k]
+    with tracing.part("stack"):
+        (cache, _, _), toks = lax.scan(step, (cache, token0, positions0),
+                                       None, length=k + 1)
+    with tracing.part("sample"):
+        return cache, toks.T[:, :k]  # [B, k]
 
 
 @partial(jax.jit, static_argnums=(0,), static_argnames=("kmesh",),
@@ -383,6 +412,7 @@ def spec_verify_step(cfg: LlamaConfig, params, cache, tokens, positions0,
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+@tracing.part("cache")
 def copy_prefix_kv(cfg: LlamaConfig, cache, src_slot, dst_slot):
     """Copy one slot's whole KV line to another slot, all layers at once
     (prefix-cache adoption from a LIVE donor). Copying the full max_seq
@@ -401,6 +431,7 @@ def copy_prefix_kv(cfg: LlamaConfig, cache, src_slot, dst_slot):
 
 
 @partial(jax.jit, static_argnums=(3, 5))
+@tracing.part("sample")
 def sample_tokens(logits, temps, top_ps, top_k: int, key,
                   need_top_p: bool = True):
     """logits [B, V] fp32; temps/top_ps [B]. Greedy where temp == 0.
@@ -433,6 +464,7 @@ def sample_tokens(logits, temps, top_ps, top_k: int, key,
 
 
 @jax.jit
+@tracing.part("sample")
 def _last_row(toks):
     """A burst's tokens [steps, B] -> its last step's [B]: every continuing
     line's input to the next burst, left on the device."""
@@ -440,6 +472,7 @@ def _last_row(toks):
 
 
 @jax.jit
+@tracing.part("sample")
 def _join_token(tokens, first, slot):
     """tokens [B] with ``first`` [1], a prompt's sampled first token, at
     ``slot``: the line joins a burst without a host read of that token."""

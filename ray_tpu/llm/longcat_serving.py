@@ -35,6 +35,7 @@ from ray_tpu.ops.latent_attention import (
     latent_prefill_attention,
     latent_row_write,
 )
+from ray_tpu.util import tracing
 
 
 def init_cache(cfg: LongcatConfig, max_slots: int, max_seq: int):
@@ -51,11 +52,14 @@ def _run_layers(cfg, params, x, lat, attn, valid, kmesh):
         x, lat, counts = carry
         x, (lat, _), c = longcat.double_layer(
             cfg, params["layers"], layer, x, attn, (lat, layer), valid, kmesh)
-        return (x, lat, counts + c), None
+        with tracing.part("moe_combine"):
+            return (x, lat, counts + c), None
 
-    (x, lat, counts), _ = lax.scan(
-        body, (x, lat, jnp.zeros((len(longcat.MOE_COUNTERS),), jnp.int32)),
-        jnp.arange(cfg.num_layers))
+    with tracing.part("stack"):
+        (x, lat, counts), _ = lax.scan(
+            body,
+            (x, lat, jnp.zeros((len(longcat.MOE_COUNTERS),), jnp.int32)),
+            jnp.arange(cfg.num_layers))
     return x, lat, counts
 
 
@@ -67,16 +71,19 @@ def prefill_chunk(cfg: LongcatConfig, params, cache, tokens, kv_len, length,
     llm/engine.prefill_chunk). Returns (cache, last-token logits [V],
     counts)."""
     c = tokens.shape[0]
-    x = params["embed_tokens"][tokens][None]                  # [1, C, H]
-    positions = kv_len + jnp.arange(c)
-    valid = (positions < length)[None]
+    with tracing.part("embed"):
+        x = params["embed_tokens"][tokens][None]              # [1, C, H]
+    with tracing.part("attn"):
+        positions = kv_len + jnp.arange(c)
+        valid = (positions < length)[None]
 
     def attn(i, ap, xn, state):
         lat, layer = state
         a = 2 * layer + i
         q_n, q_r, rows = longcat.mla_project(cfg, ap, xn, positions, kmesh)
-        lat = lax.dynamic_update_slice(lat, rows.astype(lat.dtype)[None],
-                                       (a, slot, kv_len, 0))
+        with tracing.part("cache"):
+            lat = lax.dynamic_update_slice(
+                lat, rows.astype(lat.dtype)[None], (a, slot, kv_len, 0))
         w_kb, w_vb = longcat.kv_up_projections(cfg, ap["wkv_b"])
         o = latent_prefill_attention(q_n[0], q_r[0], lat, w_kb, w_vb, a,
                                      slot, kv_len, length,
@@ -86,7 +93,8 @@ def prefill_chunk(cfg: LongcatConfig, params, cache, tokens, kv_len, length,
 
     x, lat, counts = _run_layers(cfg, params, x, cache["latent"], attn,
                                  valid, kmesh)
-    last = x[0, jnp.clip(length - 1 - kv_len, 0, c - 1)]
+    with tracing.part("head"):
+        last = x[0, jnp.clip(length - 1 - kv_len, 0, c - 1)]
     return {"latent": lat}, longcat.lm_head(cfg, params, last, kmesh), counts
 
 
@@ -96,17 +104,20 @@ def _multi_token_impl(cfg: LongcatConfig, params, cache, tokens, positions0,
     contract, see llm/engine._multi_token_impl). Returns (cache, logits
     [B, K, V], counts)."""
     b, k = tokens.shape
-    x = params["embed_tokens"][tokens]                        # [B, K, H]
-    positions = positions0[:, None] + jnp.arange(k)[None, :]
-    lengths = jnp.where(write_mask, positions0 + k, 0)
-    valid = jnp.broadcast_to(write_mask[:, None], (b, k))
+    with tracing.part("embed"):
+        x = params["embed_tokens"][tokens]                    # [B, K, H]
+    with tracing.part("attn"):
+        positions = positions0[:, None] + jnp.arange(k)[None, :]
+        lengths = jnp.where(write_mask, positions0 + k, 0)
+        valid = jnp.broadcast_to(write_mask[:, None], (b, k))
 
     def attn(i, ap, xn, state):
         lat, layer = state
         a = 2 * layer + i
         q_n, q_r, rows = longcat.mla_project(cfg, ap, xn, positions, kmesh)
-        lat = latent_row_write(lat, rows, a, positions0, write_mask,
-                               kmesh=kmesh)
+        with tracing.part("cache"):
+            lat = latent_row_write(lat, rows, a, positions0, write_mask,
+                                   kmesh=kmesh)
         w_kb, w_vb = longcat.kv_up_projections(cfg, ap["wkv_b"])
         # Absorbed: the key up-projection goes into the query, the value
         # up-projection onto the mix of latent rows.
@@ -147,18 +158,21 @@ def decode_burst(cfg: LongcatConfig, params, cache, token0, positions0,
         c, tok, pos, counts = carry
         c, logits, n = _multi_token_impl(cfg, params, c, tok[:, None], pos,
                                          write_mask, kmesh)
-        nxt = sample_tokens(logits[:, 0], temps, top_ps, 0,
-                            jax.random.fold_in(key, j),
-                            need_top_p).astype(jnp.int32)
-        return (c, nxt, pos + 1, counts + n), nxt
+        with tracing.part("sample"):
+            nxt = sample_tokens(logits[:, 0], temps, top_ps, 0,
+                                jax.random.fold_in(key, j),
+                                need_top_p).astype(jnp.int32)
+            return (c, nxt, pos + 1, counts + n), nxt
 
     zero = jnp.zeros((len(longcat.MOE_COUNTERS),), jnp.int32)
-    (cache, _, _, counts), toks = lax.scan(
-        step, (cache, token0, positions0, zero), jnp.arange(steps))
+    with tracing.part("stack"):
+        (cache, _, _, counts), toks = lax.scan(
+            step, (cache, token0, positions0, zero), jnp.arange(steps))
     return cache, toks, counts
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+@tracing.part("cache")
 def copy_prefix_kv(cfg: LongcatConfig, cache, src_slot, dst_slot):
     """Copy one slot's whole latent line to another slot, all attentions
     at once (prefix adoption from a live donor)."""

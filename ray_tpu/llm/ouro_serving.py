@@ -37,6 +37,7 @@ from ray_tpu.ops.decode_attention import (
 from ray_tpu.ops.kernels import KernelMesh
 from ray_tpu.ops.prefill_attention import prefill_attention, prefill_kv_write
 from ray_tpu.ops.rope import rope_frequencies
+from ray_tpu.util import tracing
 
 
 def init_cache(cfg: OuroConfig, max_slots: int, max_seq: int):
@@ -51,7 +52,8 @@ def _run_loop(cfg, params, x, cache, positions, attend_line, valid, kmesh):
     ``attend_line(line, q, k, v, (k_all, v_all)) -> (o, (k_all, v_all))``
     writes the new rows into ``line`` and attends there. Returns (the
     picked pass's normed state, cache, counts)."""
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta)
+    with tracing.part("attn"):
+        inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta)
 
     def stack(x, step, kv):
         def body(carry, scanned):
@@ -62,8 +64,9 @@ def _run_loop(cfg, params, x, cache, positions, attend_line, valid, kmesh):
                 partial(attend_line, cfg.cache_line(step, layer)), kv, kmesh)
             return (x, kv), None
 
-        return lax.scan(body, (x, kv), (params["layers"],
-                                        jnp.arange(cfg.num_layers)))[0]
+        with tracing.part("stack"):
+            return lax.scan(body, (x, kv), (params["layers"],
+                                            jnp.arange(cfg.num_layers)))[0]
 
     x, (k_all, v_all), _, chosen = ouro.loop(
         cfg, params, x, stack, (cache["k"], cache["v"]), kmesh)
@@ -78,20 +81,24 @@ def prefill_chunk(cfg: OuroConfig, params, cache, tokens, kv_len, length,
     llm/engine.prefill_chunk). Returns (cache, last-token logits [V],
     counts)."""
     c = tokens.shape[0]
-    x = params["embed_tokens"][tokens][None]                  # [1, C, H]
-    positions = kv_len + jnp.arange(c)
+    with tracing.part("embed"):
+        x = params["embed_tokens"][tokens][None]              # [1, C, H]
+    with tracing.part("attn"):
+        positions = kv_len + jnp.arange(c)
+        valid = (positions < length)[None]
 
     def attend_line(line, q, k, v, kv):
-        kv = prefill_kv_write(*kv, k[0], v[0], line, slot, kv_len)
+        with tracing.part("cache"):
+            kv = prefill_kv_write(*kv, k[0], v[0], line, slot, kv_len)
         o = prefill_attention(q[0], *kv, line, slot, kv_len, length,
                               kmesh=kmesh)
         return o[None], kv
 
     x, cache, counts = _run_loop(cfg, params, x, cache, positions,
-                                 attend_line, (positions < length)[None],
-                                 kmesh)
+                                 attend_line, valid, kmesh)
     # The head on the one row that is kept.
-    last = x[0, jnp.clip(length - 1 - kv_len, 0, c - 1)]
+    with tracing.part("head"):
+        last = x[0, jnp.clip(length - 1 - kv_len, 0, c - 1)]
     return cache, ouro.lm_head(params, last), counts
 
 
@@ -101,22 +108,25 @@ def _multi_token_impl(cfg: OuroConfig, params, cache, tokens, positions0,
     (the engine's contract, see llm/engine._multi_token_impl). Returns
     (cache, logits [B, K, V], counts)."""
     b, k = tokens.shape
-    x = params["embed_tokens"][tokens]                        # [B, K, H]
-    positions = positions0[:, None] + jnp.arange(k)[None, :]
-    lengths = jnp.where(write_mask, positions0 + k, 0)
-    # All 192 lines attend at the same lengths: one walk of the live
-    # blocks, planned here and not in the loops.
-    plan = decode_plan_of(lengths, cache["k"], kmesh=kmesh)
+    with tracing.part("embed"):
+        x = params["embed_tokens"][tokens]                    # [B, K, H]
+    with tracing.part("attn"):
+        positions = positions0[:, None] + jnp.arange(k)[None, :]
+        lengths = jnp.where(write_mask, positions0 + k, 0)
+        valid = jnp.broadcast_to(write_mask[:, None], (b, k))
+        # All 192 lines attend at the same lengths: one walk of the live
+        # blocks, planned here and not in the loops.
+        plan = decode_plan_of(lengths, cache["k"], kmesh=kmesh)
 
     def attend_line(line, q, kk, v, kv):
-        kv = kv_row_write(*kv, kk, v, line, positions0, write_mask,
-                          kmesh=kmesh)
+        with tracing.part("cache"):
+            kv = kv_row_write(*kv, kk, v, line, positions0, write_mask,
+                              kmesh=kmesh)
         return decode_attention(q, *kv, line, lengths, positions0,
                                 plan=plan, kmesh=kmesh), kv
 
-    x, cache, counts = _run_loop(
-        cfg, params, x, cache, positions, attend_line,
-        jnp.broadcast_to(write_mask[:, None], (b, k)), kmesh)
+    x, cache, counts = _run_loop(cfg, params, x, cache, positions,
+                                 attend_line, valid, kmesh)
     return cache, ouro.lm_head(params, x), counts
 
 
@@ -144,14 +154,16 @@ def decode_burst(cfg: OuroConfig, params, cache, token0, positions0,
         c, tok, pos, counts = carry
         c, logits, n = _multi_token_impl(cfg, params, c, tok[:, None], pos,
                                          write_mask, kmesh)
-        nxt = sample_tokens(logits[:, 0], temps, top_ps, 0,
-                            jax.random.fold_in(key, j),
-                            need_top_p).astype(jnp.int32)
-        return (c, nxt, pos + 1, counts + n), nxt
+        with tracing.part("sample"):
+            nxt = sample_tokens(logits[:, 0], temps, top_ps, 0,
+                                jax.random.fold_in(key, j),
+                                need_top_p).astype(jnp.int32)
+            return (c, nxt, pos + 1, counts + n), nxt
 
     zero = jnp.zeros((len(ouro.LOOP_COUNTERS),), jnp.int32)
-    (cache, _, _, counts), toks = lax.scan(
-        step, (cache, token0, positions0, zero), jnp.arange(steps))
+    with tracing.part("stack"):
+        (cache, _, _, counts), toks = lax.scan(
+            step, (cache, token0, positions0, zero), jnp.arange(steps))
     return cache, toks, counts
 
 

@@ -32,6 +32,7 @@ from ray_tpu.ops.kernels import KernelMesh
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ring_attention import ring_attention_local
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ray_tpu.util import tracing
 
 
 @dataclass(frozen=True)
@@ -160,30 +161,31 @@ def _layer(cfg: LlamaConfig, x, layer_params, inv_freq, positions,
     lp = layer_params
     dt = x.dtype
 
-    # -- attention ----------------------------------------------------------
-    xn = checkpoint_name(rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh),
-                         "norm_out")
-    q = (xn @ lp["wq"]).reshape(b, s, cfg.num_heads, cfg.head_dim)
-    k = (xn @ lp["wk"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    v = (xn @ lp["wv"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    q = q.transpose(0, 2, 1, 3)
-    k = k.transpose(0, 2, 1, 3)
-    v = v.transpose(0, 2, 1, 3)
-    q = checkpoint_name(apply_rope(q, positions, inv_freq), "rope_out")
-    k = checkpoint_name(apply_rope(k, positions, inv_freq), "rope_out")
-    v = checkpoint_name(v, "v_out")
-    o = _attention(cfg, q, k, v, attn_impl, sp_axis, kmesh)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.num_heads * cfg.head_dim)
-    x = x + checkpoint_name((o @ lp["wo"]).astype(dt), "attn_proj")
+    with tracing.part("attn"):
+        xn = checkpoint_name(
+            rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh), "norm_out")
+        q = (xn @ lp["wq"]).reshape(b, s, cfg.num_heads, cfg.head_dim)
+        k = (xn @ lp["wk"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+        v = (xn @ lp["wv"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+        q = q.transpose(0, 2, 1, 3)
+        k = k.transpose(0, 2, 1, 3)
+        v = v.transpose(0, 2, 1, 3)
+        q = checkpoint_name(apply_rope(q, positions, inv_freq), "rope_out")
+        k = checkpoint_name(apply_rope(k, positions, inv_freq), "rope_out")
+        v = checkpoint_name(v, "v_out")
+        o = _attention(cfg, q, k, v, attn_impl, sp_axis, kmesh)
+        o = o.transpose(0, 2, 1, 3).reshape(b, s,
+                                            cfg.num_heads * cfg.head_dim)
+        x = x + checkpoint_name((o @ lp["wo"]).astype(dt), "attn_proj")
 
-    # -- mlp (SwiGLU) -------------------------------------------------------
-    xn = checkpoint_name(rms_norm(x, lp["mlp_norm"], cfg.norm_eps, kmesh),
-                         "norm_out")
-    gate = checkpoint_name(
-        jax.nn.silu((xn @ lp["w_gate"]).astype(jnp.float32)).astype(dt),
-        "mlp_gate")
-    up = xn @ lp["w_up"]
-    x = x + ((gate * up) @ lp["w_down"]).astype(dt)
+    with tracing.part("mlp"):          # SwiGLU
+        xn = checkpoint_name(
+            rms_norm(x, lp["mlp_norm"], cfg.norm_eps, kmesh), "norm_out")
+        gate = checkpoint_name(
+            jax.nn.silu((xn @ lp["w_gate"]).astype(jnp.float32)).astype(dt),
+            "mlp_gate")
+        up = xn @ lp["w_up"]
+        x = x + ((gate * up) @ lp["w_down"]).astype(dt)
     return x
 
 
@@ -285,9 +287,13 @@ def forward_hidden(cfg: LlamaConfig, params: dict, tokens: jax.Array,
     HBM on cheap-to-save early layers while the deep layers stay lean."""
     b, s = tokens.shape
     if positions is None:
-        positions = jnp.arange(s)
-    x = params["embed_tokens"][tokens]
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
+        with tracing.part("attn"):
+            positions = jnp.arange(s)
+    with tracing.part("embed"):
+        x = params["embed_tokens"][tokens]
+    with tracing.part("attn"):
+        inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
+                                    cfg.rope_scaling)
 
     base_fn = partial(_layer, cfg, inv_freq=inv_freq, positions=positions,
                       attn_impl=attn_impl, sp_axis=sp_axis, kmesh=kmesh)
@@ -302,18 +308,22 @@ def forward_hidden(cfg: LlamaConfig, params: dict, tokens: jax.Array,
             def scan_body(x, lp, _fn=layer_fn):
                 return _fn(x, lp), None
 
-            run_params = jax.tree.map(lambda a: a[start:end],
-                                      params["layers"])
-            x, _ = lax.scan(scan_body, x, run_params)
-        return rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
+            with tracing.part("stack"):
+                run_params = jax.tree.map(lambda a: a[start:end],
+                                          params["layers"])
+                x, _ = lax.scan(scan_body, x, run_params)
+        with tracing.part("head"):
+            return rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
 
     layer_fn = _remat_wrap(base_fn, remat)
 
     def scan_body(x, lp):
         return layer_fn(x, lp), None
 
-    x, _ = lax.scan(scan_body, x, params["layers"])
-    return rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
+    with tracing.part("stack"):
+        x, _ = lax.scan(scan_body, x, params["layers"])
+    with tracing.part("head"):
+        return rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
 
 
 def unembed_weights(cfg: LlamaConfig, params: dict) -> jax.Array:
@@ -330,9 +340,10 @@ def forward(cfg: LlamaConfig, params: dict, tokens: jax.Array,
     accumulation — a fp32×fp32 dot would run off the MXU fast path."""
     x = forward_hidden(cfg, params, tokens, positions, attn_impl, sp_axis,
                        remat, kmesh)
-    head = unembed_weights(cfg, params)
-    return jnp.einsum("bsh,hv->bsv", x, head,
-                      preferred_element_type=jnp.float32)
+    with tracing.part("head"):
+        head = unembed_weights(cfg, params)
+        return jnp.einsum("bsh,hv->bsv", x, head,
+                          preferred_element_type=jnp.float32)
 
 
 def loss_fn(cfg: LlamaConfig, params: dict, tokens: jax.Array,
@@ -343,13 +354,18 @@ def loss_fn(cfg: LlamaConfig, params: dict, tokens: jax.Array,
         from ray_tpu.ops.loss import default_ce_chunk, fused_cross_entropy
 
         x = forward_hidden(cfg, params, tokens, **fwd_kwargs)
-        head = unembed_weights(cfg, params)
-        return fused_cross_entropy(x, head, targets, mask,
-                                   default_ce_chunk())
+        with tracing.part("head"):
+            head = unembed_weights(cfg, params)
+        # The head matmul runs inside the loss's chunks: booked with it.
+        with tracing.part("loss"):
+            return fused_cross_entropy(x, head, targets, mask,
+                                       default_ce_chunk())
     logits = forward(cfg, params, tokens, **fwd_kwargs)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    if mask is None:
-        mask = jnp.ones_like(targets, jnp.float32)
-    mask = mask.astype(jnp.float32)
-    return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+    with tracing.part("loss"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None],
+                                   axis=-1)[..., 0]
+        if mask is None:
+            mask = jnp.ones_like(targets, jnp.float32)
+        mask = mask.astype(jnp.float32)
+        return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)

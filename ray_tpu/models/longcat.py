@@ -52,6 +52,7 @@ from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.kernels import KernelMesh
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope_interleaved, rope_frequencies
+from ray_tpu.util import tracing
 
 # Rows of one tile of the grouped matmul: a packed sublane tile of bfloat16.
 MOE_TILE = 16
@@ -397,33 +398,36 @@ def moe_block(cfg: LongcatConfig, layers: dict, layer, u, valid):
     in the order of MOE_COUNTERS)."""
     t, _ = u.shape
     held, topk = cfg.experts_held, cfg.moe_topk
-    idx, w = route(cfg, _layer_of(layers["router"], layer),
-                   _layer_of(layers["router_bias"], layer), u)
-    lo = cfg.expert_shard * held
-    chosen = valid[:, None]
-    local = chosen & (idx >= lo) & (idx < lo + held)
-    zero = chosen & (idx >= cfg.n_routed_experts)
-    keys = jnp.where(local, idx - lo, held).reshape(-1).astype(jnp.int32)
-    pick_of_row, row_of_pick, tile_expert, n_live, sizes = dispatch_plan(
-        keys, held, MOE_TILE)
-    x_rows = jnp.where((pick_of_row >= 0)[:, None],
-                       u[jnp.maximum(pick_of_row, 0) // topk], 0)
-    with jax.named_scope("longcat.moe.experts"):
+    with tracing.part("moe_route"):
+        idx, w = route(cfg, _layer_of(layers["router"], layer),
+                       _layer_of(layers["router_bias"], layer), u)
+        lo = cfg.expert_shard * held
+        chosen = valid[:, None]
+        local = chosen & (idx >= lo) & (idx < lo + held)
+        zero = chosen & (idx >= cfg.n_routed_experts)
+        keys = jnp.where(local, idx - lo, held).reshape(-1).astype(jnp.int32)
+        pick_of_row, row_of_pick, tile_expert, n_live, sizes = dispatch_plan(
+            keys, held, MOE_TILE)
+    with tracing.part("moe_dispatch"):
+        x_rows = jnp.where((pick_of_row >= 0)[:, None],
+                           u[jnp.maximum(pick_of_row, 0) // topk], 0)
+    with tracing.part("moe_experts"):
         hidden = grouped_matmul(x_rows, layers["we_gate"], layer, tile_expert,
                                 n_live, tm=MOE_TILE, w2=layers["we_up"])
         out_rows = grouped_matmul(hidden, layers["we_down"], layer,
                                   tile_expert, n_live, tm=MOE_TILE)
-    # A select, not a product: rows of dead tiles were never written.
-    picked = out_rows[jnp.where(local, row_of_pick.reshape(t, topk), 0)]
-    y = jnp.sum(jnp.where(local[..., None],
-                          w[..., None] * picked.astype(jnp.float32), 0.0),
-                axis=1)
-    y += jnp.sum(jnp.where(zero, w, 0.0), axis=1,
-                 keepdims=True) * u.astype(jnp.float32)
-    counts = jnp.stack([
-        valid.sum() * topk, local.sum(), zero.sum(), (sizes > 0).sum(),
-        jnp.ones((), jnp.int32)]).astype(jnp.int32)
-    return y.astype(u.dtype), counts
+    with tracing.part("moe_combine"):
+        # A select, not a product: rows of dead tiles were never written.
+        picked = out_rows[jnp.where(local, row_of_pick.reshape(t, topk), 0)]
+        y = jnp.sum(jnp.where(local[..., None],
+                              w[..., None] * picked.astype(jnp.float32), 0.0),
+                    axis=1)
+        y += jnp.sum(jnp.where(zero, w, 0.0), axis=1,
+                     keepdims=True) * u.astype(jnp.float32)
+        counts = jnp.stack([
+            valid.sum() * topk, local.sum(), zero.sum(), (sizes > 0).sum(),
+            jnp.ones((), jnp.int32)]).astype(jnp.int32)
+        return y.astype(u.dtype), counts
 
 
 def _layer_of(stack, index):
@@ -445,27 +449,32 @@ def double_layer(cfg: LongcatConfig, layers: dict, layer, h, attn, state,
     whatever it threads (a cache). ``valid`` [B, S] marks real tokens for
     the router's counters. Returns (h, state, counts)."""
     b, s, hid = h.shape
-    p0, p1 = ({k: _layer_of(layers[k], 2 * layer + i)
-               for k in SUBLAYER_LEAVES} for i in (0, 1))
-    with jax.named_scope("longcat.mla"):
+    with tracing.part("stack"):
+        p0, p1 = ({k: _layer_of(layers[k], 2 * layer + i)
+                   for k in SUBLAYER_LEAVES} for i in (0, 1))
+    with tracing.part("attn"):
         o, state = attn(0, p0, rms_norm(h, p0["attn_norm"], cfg.norm_eps,
                                         kmesh), state)
-    a1 = h + o
-    u = rms_norm(a1, p0["post_norm"], cfg.norm_eps, kmesh)
-    with jax.named_scope("longcat.moe"):
-        m, counts = moe_block(cfg, layers, layer, u.reshape(b * s, hid),
-                              valid.reshape(b * s))
-    f1 = a1 + swiglu(u, p0["w_gate"], p0["w_up"], p0["w_down"])
-    with jax.named_scope("longcat.mla"):
+        a1 = h + o
+    with tracing.part("mlp"):
+        u = rms_norm(a1, p0["post_norm"], cfg.norm_eps, kmesh)
+    m, counts = moe_block(cfg, layers, layer, u.reshape(b * s, hid),
+                          valid.reshape(b * s))
+    with tracing.part("mlp"):
+        f1 = a1 + swiglu(u, p0["w_gate"], p0["w_up"], p0["w_down"])
+    with tracing.part("attn"):
         o, state = attn(1, p1, rms_norm(f1, p1["attn_norm"], cfg.norm_eps,
                                         kmesh), state)
-    a2 = f1 + o
-    x = rms_norm(a2, p1["post_norm"], cfg.norm_eps, kmesh)
-    out = a2 + swiglu(x, p1["w_gate"], p1["w_up"], p1["w_down"]) \
-        + m.reshape(b, s, hid)
+        a2 = f1 + o
+    with tracing.part("mlp"):
+        x = rms_norm(a2, p1["post_norm"], cfg.norm_eps, kmesh)
+        out = a2 + swiglu(x, p1["w_gate"], p1["w_up"], p1["w_down"])
+    with tracing.part("moe_combine"):
+        out = out + m.reshape(b, s, hid)
     return out, state, counts
 
 
+@tracing.part("head")
 def lm_head(cfg: LongcatConfig, params, x, kmesh=None):
     """x: [..., H] -> float32 logits [..., V] (untied head)."""
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
@@ -477,7 +486,8 @@ def forward(cfg: LongcatConfig, params: dict, tokens, *,
     """tokens [B, S] -> (float32 logits [B, S, V], router counts int32[5]).
     Whole sequences, no cache: the shape of a training forward pass and of
     the parity tests."""
-    x = params["embed_tokens"][tokens]
+    with tracing.part("embed"):
+        x = params["embed_tokens"][tokens]
     valid = jnp.ones(tokens.shape, bool)
 
     def attn(i, ap, xn, state):
@@ -487,9 +497,11 @@ def forward(cfg: LongcatConfig, params: dict, tokens, *,
         x, counts = carry
         x, _, c = double_layer(cfg, params["layers"], layer, x, attn, None,
                                valid, kmesh)
-        return (x, counts + c), None
+        with tracing.part("moe_combine"):
+            return (x, counts + c), None
 
-    (x, counts), _ = lax.scan(
-        body, (x, jnp.zeros((len(MOE_COUNTERS),), jnp.int32)),
-        jnp.arange(cfg.num_layers))
+    with tracing.part("stack"):
+        (x, counts), _ = lax.scan(
+            body, (x, jnp.zeros((len(MOE_COUNTERS),), jnp.int32)),
+            jnp.arange(cfg.num_layers))
     return lm_head(cfg, params, x, kmesh), counts

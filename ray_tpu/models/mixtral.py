@@ -34,6 +34,7 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.models import llama as _llama
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ray_tpu.util import tracing
 
 
 @dataclass(frozen=True)
@@ -264,25 +265,31 @@ def _routed(cfg: MixtralConfig, x: jax.Array, lp: dict, kmesh=None):
         # pieces round: eight all-to-all and a dozen small sums a layer.
         plan_of = jax.shard_map(plan_of, mesh=kmesh.mesh, axis_names={"ep"},
                                 in_specs=P(), out_specs=P())
-    plan = plan_of(xt, lp["router"])
-    token_of_slot = plan.token_of_slot.reshape(G, n, 1)
-    gate_of_slot = plan.gate_of_slot.reshape(G, n, 1)
-    # A claim's slot as each group sees it: its own, or n (not held here).
-    local = plan.slot_of_claim - (jnp.arange(G) * n)[:, None, None]
-    slot_of_claim = jnp.where((local >= 0) & (local < n), local, n)
+    with tracing.part("moe_route"):
+        plan = plan_of(xt, lp["router"])
+        token_of_slot = plan.token_of_slot.reshape(G, n, 1)
+        gate_of_slot = plan.gate_of_slot.reshape(G, n, 1)
+        # A claim's slot as each group sees it: its own, or n (not held
+        # here).
+        local = plan.slot_of_claim - (jnp.arange(G) * n)[:, None, None]
+        slot_of_claim = jnp.where((local >= 0) & (local < n), local, n)
 
     # Dispatch: slot (e, c) reads its token's row; an empty slot reads zeros.
-    expert_in = jax.vmap(gather_rows, (None, None, 0, None, 0))(
-        xt, None, token_of_slot, None, slot_of_claim).reshape(E, C, h)
-    gate = jax.nn.silu(jnp.einsum(
-        "ech,ehi->eci", expert_in, lp["we_gate"]).astype(jnp.float32)).astype(dt)
-    up = jnp.einsum("ech,ehi->eci", expert_in, lp["we_up"])
-    expert_out = jnp.einsum("eci,eih->ech", gate * up, lp["we_down"])
+    with tracing.part("moe_dispatch"):
+        expert_in = jax.vmap(gather_rows, (None, None, 0, None, 0))(
+            xt, None, token_of_slot, None, slot_of_claim).reshape(E, C, h)
+    with tracing.part("moe_experts"):
+        gate = jax.nn.silu(jnp.einsum(
+            "ech,ehi->eci", expert_in,
+            lp["we_gate"]).astype(jnp.float32)).astype(dt)
+        up = jnp.einsum("ech,ehi->eci", expert_in, lp["we_up"])
+        expert_out = jnp.einsum("eci,eih->ech", gate * up, lp["we_down"])
     # Combine: a token reads back the slots of its kept claims, gate-weighted.
-    y = jax.vmap(gather_rows, (0, None, 0, 0, 0))(
-        expert_out.reshape(G, n, h), plan.gate, slot_of_claim, gate_of_slot,
-        token_of_slot).sum(0)
-    return y.reshape(b, s, h), plan
+    with tracing.part("moe_combine"):
+        y = jax.vmap(gather_rows, (0, None, 0, 0, 0))(
+            expert_out.reshape(G, n, h), plan.gate, slot_of_claim,
+            gate_of_slot, token_of_slot).sum(0)
+        return y.reshape(b, s, h), plan
 
 
 def moe_block(cfg: MixtralConfig, x: jax.Array, lp: dict, kmesh=None):
@@ -291,6 +298,7 @@ def moe_block(cfg: MixtralConfig, x: jax.Array, lp: dict, kmesh=None):
     return y, plan.aux
 
 
+@tracing.part("attn")
 def _attend(cfg: MixtralConfig, x, lp, inv_freq, positions, attn_impl, kmesh):
     """The attention half of a layer, residual included."""
     b, s, h = x.shape
@@ -309,9 +317,11 @@ def _attend(cfg: MixtralConfig, x, lp, inv_freq, positions, attn_impl, kmesh):
 def _layer(cfg: MixtralConfig, x, lp, inv_freq, positions, attn_impl,
            kmesh=None):
     x = _attend(cfg, x, lp, inv_freq, positions, attn_impl, kmesh)
-    xn = rms_norm(x, lp["mlp_norm"], cfg.norm_eps, kmesh)
+    with tracing.part("moe_route"):    # the routed layer's input norm
+        xn = rms_norm(x, lp["mlp_norm"], cfg.norm_eps, kmesh)
     y, aux = moe_block(cfg, xn, lp, kmesh)
-    return x + y.astype(x.dtype), aux
+    with tracing.part("moe_combine"):
+        return x + y.astype(x.dtype), aux
 
 
 def forward(cfg: MixtralConfig, params: dict, tokens: jax.Array,
@@ -321,9 +331,12 @@ def forward(cfg: MixtralConfig, params: dict, tokens: jax.Array,
     the caller's mesh for the Pallas kernels (ops/kernels.py)."""
     b, s = tokens.shape
     if positions is None:
-        positions = jnp.arange(s)
-    x = params["embed_tokens"][tokens]
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta, None)
+        with tracing.part("attn"):
+            positions = jnp.arange(s)
+    with tracing.part("embed"):
+        x = params["embed_tokens"][tokens]
+    with tracing.part("attn"):
+        inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta, None)
 
     from ray_tpu.models.llama import _remat_wrap
 
@@ -336,13 +349,16 @@ def forward(cfg: MixtralConfig, params: dict, tokens: jax.Array,
         x, aux = layer_fn(x, lp)
         return x, aux
 
-    x, aux = lax.scan(scan_body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
-    # bf16 MXU matmul with f32 accumulation — casting both operands to f32
-    # would fall off the MXU fast path (see llama.forward).
-    logits = jnp.einsum("bsh,hv->bsv", x, params["lm_head"],
-                        preferred_element_type=jnp.float32)
-    return logits, aux.mean()
+    with tracing.part("stack"):
+        x, aux = lax.scan(scan_body, x, params["layers"])
+    with tracing.part("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
+        # bf16 MXU matmul with f32 accumulation — casting both operands to
+        # f32 would fall off the MXU fast path (see llama.forward).
+        logits = jnp.einsum("bsh,hv->bsv", x, params["lm_head"],
+                            preferred_element_type=jnp.float32)
+    with tracing.part("moe_combine"):
+        return logits, aux.mean()
 
 
 def loss_fn(cfg: MixtralConfig, params: dict, tokens: jax.Array,
@@ -350,13 +366,14 @@ def loss_fn(cfg: MixtralConfig, params: dict, tokens: jax.Array,
             **fwd_kwargs) -> jax.Array:
     """LM cross-entropy + router load-balancing loss."""
     logits, aux = forward(cfg, params, tokens, **fwd_kwargs)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    if mask is None:
-        mask = jnp.ones_like(targets, jnp.float32)
-    mask = mask.astype(jnp.float32)
-    lm = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
-    return lm + cfg.router_aux_coef * aux
+    with tracing.part("loss"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        if mask is None:
+            mask = jnp.ones_like(targets, jnp.float32)
+        mask = mask.astype(jnp.float32)
+        lm = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+        return lm + cfg.router_aux_coef * aux
 
 
 @partial(jax.jit, static_argnums=0, static_argnames=("attn_impl", "kmesh"))
@@ -374,9 +391,11 @@ def routing_stats(cfg: MixtralConfig, params: dict, tokens: jax.Array,
 
     def scan_body(x, lp):
         x = _attend(cfg, x, lp, inv_freq, jnp.arange(s), attn_impl, kmesh)
-        xn = rms_norm(x, lp["mlp_norm"], cfg.norm_eps, kmesh)
+        with tracing.part("moe_route"):
+            xn = rms_norm(x, lp["mlp_norm"], cfg.norm_eps, kmesh)
         y, plan = _routed(cfg, xn, lp, kmesh)
-        return x + y.astype(x.dtype), plan.claims
+        with tracing.part("moe_combine"):
+            return x + y.astype(x.dtype), plan.claims
 
     _, claims = lax.scan(scan_body, params["embed_tokens"][tokens],
                          params["layers"])

@@ -42,6 +42,7 @@ from ray_tpu.ops.attention import blockwise_attention, flash_attention
 from ray_tpu.ops.kernels import KernelMesh
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ray_tpu.util import tracing
 
 # What the programs count on the device, over valid tokens only: tokens
 # that went through the loop, and the sum over them of the pass (1-based)
@@ -175,30 +176,33 @@ def block(cfg: OuroConfig, lp, x, positions, inv_freq, attend, state,
     b, s, _ = x.shape
     dt = x.dtype
     eps = cfg.norm_eps
-    xn = rms_norm(x, lp["attn_norm"], eps, kmesh)
-    # Arrays of their own before they are split into heads: XLA otherwise
-    # folds the split into the product as a convolution over the heads,
-    # wants each stacked matrix transposed for it, and copies all three
-    # (3 x 0.375 GiB at the published widths) at the top of every program
-    # (the first AOT compile, PR 34).
-    q, k, v = lax.optimization_barrier(
-        (xn @ lp["wq"], xn @ lp["wk"], xn @ lp["wv"]))
-    q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
-    k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    q = apply_rope(q.transpose(0, 2, 1, 3), positions, inv_freq)
-    k = apply_rope(k.transpose(0, 2, 1, 3), positions, inv_freq)
-    o, state = attend(q, k, v.transpose(0, 2, 1, 3), state)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, -1)
-    x = x + rms_norm((o @ lp["wo"]).astype(dt), lp["attn_post_norm"], eps,
-                     kmesh)
-    xn = rms_norm(x, lp["mlp_norm"], eps, kmesh)
-    gate = jax.nn.silu((xn @ lp["w_gate"]).astype(jnp.float32)).astype(dt)
-    # Kept as an array of its own, as llm/engine._mlp keeps it: fused into
-    # the down projection XLA computes it again for every tile of the output.
-    act = lax.optimization_barrier(gate * (xn @ lp["w_up"]))
-    x = x + rms_norm((act @ lp["w_down"]).astype(dt), lp["mlp_post_norm"],
-                     eps, kmesh)
+    with tracing.part("attn"):
+        xn = rms_norm(x, lp["attn_norm"], eps, kmesh)
+        # Arrays of their own before they are split into heads: XLA
+        # otherwise folds the split into the product as a convolution over
+        # the heads, wants each stacked matrix transposed for it, and copies
+        # all three (3 x 0.375 GiB at the published widths) at the top of
+        # every program (the first AOT compile, PR 34).
+        q, k, v = lax.optimization_barrier(
+            (xn @ lp["wq"], xn @ lp["wk"], xn @ lp["wv"]))
+        q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
+        k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+        v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+        q = apply_rope(q.transpose(0, 2, 1, 3), positions, inv_freq)
+        k = apply_rope(k.transpose(0, 2, 1, 3), positions, inv_freq)
+        o, state = attend(q, k, v.transpose(0, 2, 1, 3), state)
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, -1)
+        x = x + rms_norm((o @ lp["wo"]).astype(dt), lp["attn_post_norm"],
+                         eps, kmesh)
+    with tracing.part("mlp"):
+        xn = rms_norm(x, lp["mlp_norm"], eps, kmesh)
+        gate = jax.nn.silu((xn @ lp["w_gate"]).astype(jnp.float32)).astype(dt)
+        # Kept as an array of its own, as llm/engine._mlp keeps it: fused
+        # into the down projection XLA computes it again for every tile of
+        # the output.
+        act = lax.optimization_barrier(gate * (xn @ lp["w_up"]))
+        x = x + rms_norm((act @ lp["w_down"]).astype(dt),
+                         lp["mlp_post_norm"], eps, kmesh)
     return x, state
 
 
@@ -216,26 +220,30 @@ def loop(cfg: OuroConfig, params, x, stack, state,
     def one_pass(carry, t):
         x, state, left, reached, picked, chosen = carry
         x, state = stack(x, t, state)
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
-        g = jax.nn.sigmoid(x.astype(jnp.float32) @ gw + gb)
-        last = t == steps - 1
-        p = jnp.where(last, left, g * left)
-        reached = reached + p
-        take = (chosen == 0) & (last | (reached >= cfg.early_exit_threshold))
-        picked = jnp.where(take[..., None], x, picked)
-        chosen = jnp.where(take, t + 1, chosen)
-        return (x, state, left * (1.0 - g), reached, picked, chosen), p
+        with tracing.part("loop"):
+            x = rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
+            g = jax.nn.sigmoid(x.astype(jnp.float32) @ gw + gb)
+            last = t == steps - 1
+            p = jnp.where(last, left, g * left)
+            reached = reached + p
+            take = (chosen == 0) & (
+                last | (reached >= cfg.early_exit_threshold))
+            picked = jnp.where(take[..., None], x, picked)
+            chosen = jnp.where(take, t + 1, chosen)
+            return (x, state, left * (1.0 - g), reached, picked, chosen), p
 
     shape = x.shape[:2]
-    (_, state, _, _, picked, chosen), pdf = lax.scan(
-        one_pass,
-        (x, state, jnp.ones(shape, jnp.float32),
-         jnp.zeros(shape, jnp.float32), jnp.zeros_like(x),
-         jnp.zeros(shape, jnp.int32)),
-        jnp.arange(steps))
+    with tracing.part("stack"):
+        (_, state, _, _, picked, chosen), pdf = lax.scan(
+            one_pass,
+            (x, state, jnp.ones(shape, jnp.float32),
+             jnp.zeros(shape, jnp.float32), jnp.zeros_like(x),
+             jnp.zeros(shape, jnp.int32)),
+            jnp.arange(steps))
     return picked, state, pdf, chosen
 
 
+@tracing.part("loop")
 def loop_counts(chosen, valid):
     """``LOOP_COUNTERS`` of one program: int32[2] over the tokens ``valid``
     marks (idle slots and padding count nowhere)."""
@@ -243,6 +251,7 @@ def loop_counts(chosen, valid):
                      ).astype(jnp.int32)
 
 
+@tracing.part("head")
 def lm_head(params, x):
     """x: [..., H], a pass's normed state -> float32 logits [..., V]."""
     return jnp.dot(x, params["lm_head"], preferred_element_type=jnp.float32)
@@ -254,8 +263,9 @@ def forward(cfg: OuroConfig, params: dict, tokens, *,
     """tokens [B, S] -> (float32 logits [B, S, V], exit distribution
     [B, S, T]). Whole sequences, no cache: every pass is causal over its
     own keys and values."""
-    positions = jnp.arange(tokens.shape[1])
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta)
+    with tracing.part("attn"):
+        positions = jnp.arange(tokens.shape[1])
+        inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta)
 
     def attend(q, k, v, state):
         if attn_impl == "flash":
@@ -267,8 +277,12 @@ def forward(cfg: OuroConfig, params: dict, tokens, *,
             return block(cfg, lp, x, positions, inv_freq, attend, None,
                          kmesh)[0], None
 
-        return lax.scan(body, x, params["layers"])[0], state
+        with tracing.part("stack"):
+            return lax.scan(body, x, params["layers"])[0], state
 
-    x, _, pdf, _ = loop(cfg, params, params["embed_tokens"][tokens], stack,
-                        None, kmesh)
-    return lm_head(params, x), pdf.transpose(1, 2, 0)
+    with tracing.part("embed"):
+        x = params["embed_tokens"][tokens]
+    x, _, pdf, _ = loop(cfg, params, x, stack, None, kmesh)
+    with tracing.part("loop"):
+        pdf = pdf.transpose(1, 2, 0)
+    return lm_head(params, x), pdf

@@ -31,6 +31,7 @@ from ray_tpu.parallel.sharding import (
     tree_shardings,
     zero1_shardings,
 )
+from ray_tpu.util import tracing
 from ray_tpu.utils.compile_cache import ensure_compile_cache
 
 
@@ -264,13 +265,15 @@ def make_train_step(
         def body(carry, mb):
             l_acc, g_acc = carry
             lv, g = jax.value_and_grad(loss)(params, *mb)
-            return (l_acc + lv, jax.tree.map(jnp.add, g_acc, g)), None
+            with tracing.part("optim"):
+                return (l_acc + lv, jax.tree.map(jnp.add, g_acc, g)), None
 
         zeros = jax.tree.map(jnp.zeros_like, params)
         (l_sum, g_sum), _ = jax.lax.scan(
             body, (jnp.zeros((), jnp.float32), zeros), (tok, tgt))
         inv = 1.0 / grad_accum
-        return l_sum * inv, jax.tree.map(lambda g: g * inv, g_sum)
+        with tracing.part("optim"):
+            return l_sum * inv, jax.tree.map(lambda g: g * inv, g_sum)
 
     def _grads_accum(params, tokens, targets):
         b = tokens.shape[0]
@@ -394,8 +397,9 @@ def make_train_step(
             g = g[:orig].reshape(shape1)
             return (g / n_slices).astype(dt)
 
-        grads = jax.tree.map(combine, g_slice)
-        return jnp.mean(lv), grads
+        with tracing.part("optim"):
+            grads = jax.tree.map(combine, g_slice)
+            return jnp.mean(lv), grads
 
     def _unflatten_params(flats, params_like):
         """Inverse of :func:`_flatten_params` for the post-update params:
@@ -428,38 +432,40 @@ def make_train_step(
         else:
             loss_val, grads = _grads_flat(params_in, tokens, targets)
 
-        if grad_norm_every > 1:
-            gnorm = jax.lax.cond(
-                state.step % grad_norm_every == 0,
-                lambda g: optax.global_norm(g).astype(jnp.float32),
-                lambda g: jnp.float32(-1.0), grads)
-        else:
-            gnorm = optax.global_norm(grads)
+        with tracing.part("optim"):
+            if grad_norm_every > 1:
+                gnorm = jax.lax.cond(
+                    state.step % grad_norm_every == 0,
+                    lambda g: optax.global_norm(g).astype(jnp.float32),
+                    lambda g: jnp.float32(-1.0), grads)
+            else:
+                gnorm = optax.global_norm(grads)
 
-        if flat_update:
-            # Sharded flat-space update: grads arrived as padded 1-D shards;
-            # moments and the adamw math stay 1/N per device, then the
-            # params gather back through the DCN→ICI chain.
-            p_flat = _flatten_params(params_in)
-            updates, opt_state = optimizer.update(grads, state.opt_state,
-                                                  p_flat)
-            new_flat = optax.apply_updates(p_flat, updates)
-            params = _unflatten_params(new_flat, params_in)
-        else:
-            if update_axes and not explicit_hier:
-                # The update-sharding constraint lowers the gradient sync to
-                # reduce-scatter over ICI (single-slice here — dcn_data is
-                # empty whenever this branch runs).
-                grads = jax.tree.map(lambda g, s: wsc(g, s), grads, ici_sh)
-            updates, opt_state = optimizer.update(grads, state.opt_state,
-                                                  params_in)
-            params = optax.apply_updates(params_in, updates)
-            if update_axes and not explicit_hier:
-                params = jax.tree.map(lambda p, s: wsc(p, s), params,
-                                      param_sh)
+            if flat_update:
+                # Sharded flat-space update: grads arrived as padded 1-D
+                # shards; moments and the adamw math stay 1/N per device,
+                # then the params gather back through the DCN→ICI chain.
+                p_flat = _flatten_params(params_in)
+                updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                      p_flat)
+                new_flat = optax.apply_updates(p_flat, updates)
+                params = _unflatten_params(new_flat, params_in)
+            else:
+                if update_axes and not explicit_hier:
+                    # The update-sharding constraint lowers the gradient
+                    # sync to reduce-scatter over ICI (single-slice here —
+                    # dcn_data is empty whenever this branch runs).
+                    grads = jax.tree.map(lambda g, s: wsc(g, s), grads,
+                                         ici_sh)
+                updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                      params_in)
+                params = optax.apply_updates(params_in, updates)
+                if update_axes and not explicit_hier:
+                    params = jax.tree.map(lambda p, s: wsc(p, s), params,
+                                          param_sh)
+            step = state.step + 1
         return (
-            TrainState(params=params, opt_state=opt_state,
-                       step=state.step + 1),
+            TrainState(params=params, opt_state=opt_state, step=step),
             {"loss": loss_val, "grad_norm": gnorm},
         )
 

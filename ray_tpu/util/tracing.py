@@ -667,6 +667,49 @@ def phase(name: str, **counts) -> _PhaseCM:
     return _PhaseCM(name, counts, ann(name, **counts) if ann else None)
 
 
+# The parts of a jitted program, one flat vocabulary for every model. A
+# device operation belongs to the innermost part on its name-stack path;
+# benchmark/rtbench/xplane_meta.py reads them back from the device trace.
+# None may equal a JAX primitive or name-stack wrapper (``transpose``,
+# ``while``, ``body``, ``checkpoint``, ...): tests/test_tracing_parts.py.
+PARTS = (
+    "embed",         # the token gather
+    "attn",          # input norm, q/k/v or latent projections, rope, the
+                     # attention kernel or XLA attention, output projection
+    "cache",         # kv_row_write, prefill_kv_write, latent_row_write,
+                     # copy_prefix_kv
+    "mlp",           # post norm and the dense FFN
+    "moe_route",     # router matmul, softmax or sigmoid, top-k, the plan
+    "moe_dispatch",  # rows gathered into capacity slots or tiles
+    "moe_experts",   # the expert matmuls
+    "moe_combine",   # gather back, weighted sum, all-reduce, auxiliary loss
+    "head",          # final norm, the head matmul, the last row
+    "loss",          # cross entropy and the sum with the auxiliary loss
+    "sample",        # sample_tokens, a burst's token hand-over
+    "loop",          # a looped stack's exit gate, the norm between passes
+    "optim",         # gradient norm and clip, optimizer update, apply
+    "stack",         # what lax.scan adds around a layer body (slices of
+                     # the weight and cache stacks); the body's own parts
+                     # are innermost and win
+)
+
+
+def part(name: str):
+    """A part of a jitted program: where :func:`phase` is the host's
+    interval on the profiler's clock, this is the device's. It returns
+    ``jax.named_scope(name)``, which exists only while JAX traces the
+    function: the name lands on the name stack of every operation traced
+    under it (``tf_op`` in the device trace, the grouping in XProf) and
+    costs nothing once the program is compiled. A name outside
+    :data:`PARTS` is refused here, at trace time, and not silently in a
+    reader."""
+    if name not in PARTS:
+        raise ValueError(f"tracing.part({name!r}): not one of {PARTS}")
+    import jax
+
+    return jax.named_scope(name)
+
+
 def spans() -> list[Span]:
     with _lock:
         return list(_spans)

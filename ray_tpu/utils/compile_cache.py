@@ -13,6 +13,13 @@ import os
 import sys
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+# JAX leaves location metadata (name stacks, source lines) out of the
+# cache's key by default, and an executable keeps the metadata it was
+# compiled with: a program cached before a ``tracing.part`` scope existed
+# was served to the tree that had it, and its device trace showed the old
+# name stacks (my chip run, PR 36: ``devbench/trace_parts_probe.py stale``).
+# With the metadata in the key the trace names what the source says.
+METADATA_IN_KEY = "jax_compilation_cache_include_metadata_in_key"
 
 # <checkout>/.jax_cache (git-ignored). Fixed: never a temp dir, a pid or a
 # timestamp, so every process of every run of this checkout shares it.
@@ -32,13 +39,17 @@ def ensure_compile_cache() -> str:
     not pay for the import, and must not touch a backend, before a task
     asks for JAX); a process that has imported it gets the same value
     through ``jax.config``. The cache's own thresholds (a program that
-    compiles in under a second is not stored) stay at JAX's defaults."""
+    compiles in under a second is not stored) stay at JAX's defaults.
+    Either way the key holds the programs' metadata
+    (:data:`METADATA_IN_KEY`), set the same two ways."""
+    os.environ[METADATA_IN_KEY.upper()] = "true"
     path = os.environ.get(ENV_VAR)
-    if path:
-        return path
-    os.environ[ENV_VAR] = DEFAULT_DIR
+    if not path:
+        path = os.environ[ENV_VAR] = DEFAULT_DIR
     if "jax" in sys.modules:
         import jax
 
-        jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
-    return DEFAULT_DIR
+        jax.config.update(METADATA_IN_KEY, True)
+        if path == DEFAULT_DIR:
+            jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    return path

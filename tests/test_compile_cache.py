@@ -11,14 +11,20 @@ from ray_tpu.utils.compile_cache import DEFAULT_DIR, ENV_VAR
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # A process that has imported JAX (driver, trainer, engine) and one that has
-# not yet (a cluster worker at start).
+# not yet (a cluster worker at start). In both the cache's key holds the
+# programs' metadata, so a cached program carries the name stacks
+# (``tracing.part``) of the source that asks for it.
 _AFTER_IMPORT = ("import jax; "
                  "from ray_tpu.utils.compile_cache import ensure_compile_cache;"
                  " ensure_compile_cache(); "
+                 "assert jax.config."
+                 "jax_compilation_cache_include_metadata_in_key; "
                  "print(jax.config.jax_compilation_cache_dir)")
 _BEFORE_IMPORT = ("from ray_tpu.utils.compile_cache import "
                   "ensure_compile_cache; ensure_compile_cache(); "
                   "import sys; assert 'jax' not in sys.modules; import jax; "
+                  "assert jax.config."
+                  "jax_compilation_cache_include_metadata_in_key; "
                   "print(jax.config.jax_compilation_cache_dir)")
 
 

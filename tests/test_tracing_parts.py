@@ -1,0 +1,190 @@
+"""``tracing.part``: the parts of the jitted programs, one vocabulary for
+every model.
+
+A part is a ``jax.named_scope`` from ``tracing.PARTS``; its name lands on
+the name stack of every operation traced under it, which a device trace
+carries as ``tf_op`` (benchmark/rtbench/xplane_meta.py reads it back).
+Here, on the CPU: the vocabulary, and for every model and program that the
+parts it must have are in the lowered program's locations.
+"""
+
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.llm import engine
+from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.models.longcat import LongcatConfig
+from ray_tpu.models.mixtral import MixtralConfig
+from ray_tpu.models.ouro import OuroConfig
+from ray_tpu.util import tracing
+# The tiny served models and the programs' arguments, as the engine's own
+# contract test builds them.
+from test_served_model import (MAX_SEQ, SLOTS, _arguments, _llama, _longcat,
+                               _ouro)
+
+# What JAX itself puts on a name stack besides primitives' names.
+WRAPPERS = {"transpose", "jvp", "vmap", "pmap", "jit", "pjit", "while",
+            "body", "cond", "scan", "closed_call", "core_call", "checkpoint",
+            "remat", "remat2", "rematted_computation", "custom_jvp_call",
+            "custom_vjp_call", "custom_vjp_call_jaxpr", "shard_map",
+            "branch_0_fun", "branch_1_fun", "pallas_call", "named"}
+
+
+def test_part_refuses_a_name_outside_the_vocabulary():
+    with pytest.raises(ValueError, match="atn"):
+        tracing.part("atn")
+    with pytest.raises(ValueError):
+        tracing.part("longcat.mla")
+
+
+def test_part_is_a_named_scope_and_nothing_else():
+    def f(x):
+        with tracing.part("mlp"):
+            return x * 2
+
+    text = jax.jit(f).lower(jnp.ones(4)).as_text(debug_info=True)
+    assert "mlp/mul" in text
+
+
+def test_no_part_is_a_jax_primitive_or_wrapper():
+    import jax.extend.core
+
+    primitives = {
+        v.name for m in list(sys.modules.values())
+        if getattr(m, "__name__", "").startswith("jax")
+        for v in list(vars(m).values())
+        if isinstance(v, jax.extend.core.Primitive)}
+    assert len(primitives) > 100 and "dot_general" in primitives
+    assert not set(tracing.PARTS) & (primitives | WRAPPERS)
+    assert len(set(tracing.PARTS)) == len(tracing.PARTS)
+    assert all(re.fullmatch(r"[a-z_]+", p) for p in tracing.PARTS)
+
+
+def parts_in(lowered) -> tuple[set, str]:
+    """The parts on the name stacks of a lowered program's operations,
+    from the locations of its text: every ``/`` segment, unwrapped of the
+    transformations' names, that is in the vocabulary."""
+    found = set()
+    text = lowered.as_text(debug_info=True)
+    for loc in re.findall(r'loc\("([^"]*)"', text):
+        for seg in loc.split("/"):
+            while seg.startswith(("transpose(", "jvp(", "vmap(")):
+                seg = seg[seg.index("(") + 1:]
+            if "(" not in seg and seg.rstrip(")") in tracing.PARTS:
+                found.add(seg.rstrip(")"))
+    return found, text
+
+
+DENSE = {"embed", "attn", "cache", "mlp", "head", "stack"}
+ROUTED = {"moe_route", "moe_dispatch", "moe_experts", "moe_combine"}
+SERVED = {
+    "llama": (_llama, DENSE),
+    "longcat": (_longcat, DENSE | ROUTED),
+    "ouro": (_ouro, DENSE | {"loop"}),
+}
+LONGCAT_GONE = ("longcat.mla", "longcat.moe", "longcat.moe.experts")
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode_burst",
+                                     "decode_step"])
+@pytest.mark.parametrize("model", sorted(SERVED))
+def test_a_serving_program_opens_its_parts(model, program):
+    make, must = SERVED[model]
+    module, cfg = make()
+    served = engine.served_model(cfg)
+    params = jax.eval_shape(
+        lambda: served.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: served.init_cache(cfg, SLOTS, MAX_SEQ))
+    rest = _arguments(program, params)
+    lowered = getattr(module, program).lower(cfg, rest[0], cache, *rest[1:])
+    found, text = parts_in(lowered)
+    if program == "decode_burst":
+        must = must | {"sample"}
+    assert must <= found, sorted(must - found)
+    # nothing trains here: no optimizer, no loss
+    assert not found & {"optim", "loss"}
+    for gone in LONGCAT_GONE:
+        assert gone not in text
+
+
+def _train_step(name):
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+    from ray_tpu.train import spmd
+
+    mesh = build_mesh(MeshSpec(), jax.devices("cpu")[:1])
+    if name == "llama":
+        cfg = LlamaConfig.tiny()
+        make = spmd.make_llama_train_step
+    else:
+        cfg = MixtralConfig.tiny()
+        make = spmd.make_mixtral_train_step
+    step_fn, init_state, shard = make(cfg, mesh, attn_impl="blockwise",
+                                      remat=True)
+    tokens = shard(np.zeros((2, 16), np.int32))
+    return step_fn.lower(jax.eval_shape(init_state), tokens, tokens)
+
+
+def _loss_grad(name):
+    if name == "longcat":
+        from ray_tpu.models import longcat as model
+
+        cfg = LongcatConfig.tiny(expert_shards=2, max_seq_len=MAX_SEQ)
+
+        def loss(p, tokens):
+            return model.forward(cfg, p, tokens)[0].sum()
+    else:
+        from ray_tpu.models import ouro as model
+
+        cfg = OuroConfig.tiny(max_seq_len=MAX_SEQ)
+
+        def loss(p, tokens):
+            return model.forward(cfg, p, tokens)[0].sum()
+    params = jax.eval_shape(
+        lambda: model.init_params(cfg, jax.random.PRNGKey(0)))
+    return jax.jit(jax.grad(loss)).lower(
+        params, jnp.zeros((2, 16), jnp.int32))
+
+
+TRAINED = {
+    "llama": (_train_step, {"embed", "attn", "mlp", "head", "loss", "optim",
+                            "stack"}),
+    "mixtral": (_train_step, {"embed", "attn", "head", "loss", "optim",
+                              "stack"} | ROUTED),
+    # No train step of their own: the whole-sequence forward under grad.
+    "longcat": (_loss_grad, {"embed", "attn", "mlp", "head", "stack"}
+                | ROUTED),
+    "ouro": (_loss_grad, {"embed", "attn", "mlp", "head", "stack", "loop"}),
+}
+
+
+@pytest.mark.parametrize("model", sorted(TRAINED))
+def test_a_loss_and_gradient_step_opens_its_parts(model):
+    lower, must = TRAINED[model]
+    found, text = parts_in(lower(model))
+    assert must <= found, sorted(must - found)
+    # a step that reads no cache opens none, and samples nothing
+    assert not found & {"cache", "sample"}
+    # the backward pass carries the parts through the transformations
+    assert re.search(r"transpose\(jvp\((stack|head|loss|embed)\)\)", text)
+    for gone in LONGCAT_GONE:
+        assert gone not in text
+
+
+def test_named_scope_is_opened_in_one_place():
+    """``jax.named_scope`` is called in util/tracing.py and nowhere else
+    under ray_tpu/: a scope outside the vocabulary would be a name no
+    reader knows."""
+    import pathlib
+
+    import ray_tpu
+
+    root = pathlib.Path(ray_tpu.__file__).parent
+    callers = sorted(
+        str(p.relative_to(root)) for p in root.rglob("*.py")
+        if re.search(r"named_scope\(", p.read_text()))
+    assert callers == ["util/tracing.py"]
