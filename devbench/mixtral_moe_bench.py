@@ -1,14 +1,19 @@
-"""Mixtral's routed layer alone, forward and backward under ``ep=4`` at the
-shapes of ``mixtral8x7b-train-4chip`` (16,384 tokens, 8 experts of 14,336,
-capacity 5,120), and the whole train step compiled for the same host.
+"""Mixtral's routed layer alone, and its head and loss alone, forward and
+backward under ``ep=4`` at the shapes of ``mixtral8x7b-train-4chip`` (16,384
+tokens, 8 experts of 14,336, capacity 5,120, a vocabulary of 32,000), and
+the whole train step compiled for the same host.
 
-    chiprun --chips 4 -- python3 devbench/mixtral_moe_bench.py layer stats
+    chiprun --chips 4 -- python3 devbench/mixtral_moe_bench.py layer head stats
     python3 devbench/mixtral_moe_bench.py aot-layer aot-step     # no chip
 
 ``layer``: wall milliseconds of one ``value_and_grad`` of ``moe_block`` (the
 clock stops on ``block_until_ready``), then the ten largest device operations
 and the collectives' seconds of a traced span of TRACED calls; every device
-operation of that span goes to ``chiprun_out/moe_layer_ops.json``. ``stats``:
+operation of that span goes to ``chiprun_out/moe_layer_ops.json``. ``head``:
+the same of final norm -> ``lm_head`` -> token losses, once with every chip
+on all 16,384 tokens and once with each on its quarter of every sequence
+(``mixtral._head_spec``'s layout: the head's gradient summed over ``ep``, the
+cotangent gathered back); the difference is what a step can save. ``stats``:
 ``mixtral.routing_stats`` on the cell's probe batch. ``aot-layer``
 and ``aot-step`` compile the same program, and the cell's whole step, for a
 described ``v5e:2x2`` (nothing runs: no time comes out of them) and print the
@@ -89,7 +94,6 @@ def layer() -> dict:
     import jax.numpy as jnp
 
     from ray_tpu.parallel.mesh import MeshSpec, build_mesh
-    from rtbench import trace_reduce
 
     cfg = _cfg(1)
     mesh = build_mesh(MeshSpec(ep=4), jax.devices()[:4])
@@ -102,39 +106,114 @@ def layer() -> dict:
                                  * 0.02).astype(s.dtype),
         out_shardings=lp_s[name].sharding)(k)
         for name, k in zip(LAYER_KEYS, keys[1:])}
+    return {"mode": "layer", "device": jax.devices()[0].device_kind,
+            "tokens": BATCH * SEQ, "capacity": cfg.capacity(BATCH * SEQ),
+            **_time_and_trace(step, (x, lp), "moe_layer")}
+
+
+def _time_and_trace(step, args, name: str) -> dict:
+    """Wall ms a call of ``step(*args)`` over TIMED calls, then a traced span
+    of TRACED calls: busy and collective seconds, the ten largest device
+    operations, and all of them in ``chiprun_out/<name>_ops.json``."""
+    import jax
+
+    from rtbench import trace_reduce
+
     t0 = time.monotonic()
-    jax.block_until_ready(step(x, lp))
+    jax.block_until_ready(step(*args))
     compile_s = time.monotonic() - t0
-    jax.block_until_ready(step(x, lp))
+    jax.block_until_ready(step(*args))
     t0 = time.monotonic()
     for _ in range(TIMED):
-        out = step(x, lp)
+        out = step(*args)
     jax.block_until_ready(out)
     wall_ms = (time.monotonic() - t0) / TIMED * 1e3
 
-    trace_dir = os.path.join(ROOT, ".bench_trace", "moe_layer")
+    trace_dir = os.path.join(ROOT, ".bench_trace", name)
     shutil.rmtree(trace_dir, ignore_errors=True)
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
     for _ in range(TRACED):
-        out = step(x, lp)
+        out = step(*args)
     jax.block_until_ready(out)
     jax.profiler.stop_trace()
     trace = trace_reduce.load(trace_reduce.find_xplane(trace_dir))
     coll_s, exposed_s = trace.collective_seconds()
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(ROOT, "chiprun_out", "moe_layer_ops.json"),
+    with open(os.path.join(ROOT, "chiprun_out", f"{name}_ops.json"),
               "w") as f:
         json.dump(trace.top_device_ops(10 ** 6), f)
-    return {"mode": "layer", "device": jax.devices()[0].device_kind,
-            "tokens": BATCH * SEQ, "capacity": cfg.capacity(BATCH * SEQ),
-            "first_call_s": round(compile_s, 2),
+    return {"first_call_s": round(compile_s, 2),
             "fwd_bwd_wall_ms": round(wall_ms, 3), "loss": float(out[0]),
             "traced_calls": TRACED, "busy_s": round(trace.busy_s(), 4),
             "collective_s": round(coll_s, 4),
             "collective_exposed_s": round(exposed_s, 4),
             "device_ops": trace.top_device_ops(10)}
+
+
+def _head_program(cfg, mesh, split: bool):
+    """Jitted value_and_grad of the last part of ``mixtral.loss_fn`` (final
+    norm, ``lm_head``, mean token loss) over x [B, S, H] as the layers leave
+    it, every token on every ``ep`` chip. ``split``: each chip takes its
+    quarter of every sequence after the norm, as ``mixtral._head_spec`` lays
+    it; the gradients come back whole either way."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.models import mixtral
+    from ray_tpu.ops.norms import rms_norm
+    from ray_tpu.parallel.sharding import kernel_mesh
+
+    kmesh = kernel_mesh(mesh)
+    rows = NamedSharding(mesh, kmesh.rows_spec(1))
+    over_ep = NamedSharding(mesh, mixtral._head_spec(kmesh, BATCH, SEQ))
+    repl = NamedSharding(mesh, P())
+
+    def loss(x, norm_w, head_w, targets):
+        x = rms_norm(x, norm_w, cfg.norm_eps, kmesh)
+        if split:
+            x = jax.lax.with_sharding_constraint(x, over_ep)
+            targets = jax.lax.with_sharding_constraint(targets, over_ep)
+        logits = jnp.einsum("bsh,hv->bsv", x, head_w,
+                            preferred_element_type=jnp.float32)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return -jnp.take_along_axis(logp, targets[..., None], axis=-1).mean()
+
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                   out_shardings=(repl, (rows, repl, repl))), rows, repl
+
+
+def head() -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    cfg = _cfg(1)
+    mesh = build_mesh(MeshSpec(ep=4), jax.devices()[:4])
+    out = {"mode": "head", "device": jax.devices()[0].device_kind,
+           "tokens": BATCH * SEQ}
+    programs = {name: _head_program(cfg, mesh, split) for name, split in
+                (("replicated", False), ("split_over_ep", True))}
+    _, rows, repl = programs["replicated"]
+    kx, kw, kt = jax.random.split(jax.random.PRNGKey(0), 3)
+    dt = cfg.jnp_dtype
+    args = (
+        jax.jit(lambda k: jax.random.normal(
+            k, (BATCH, SEQ, cfg.hidden_size), dt), out_shardings=rows)(kx),
+        jax.device_put(jnp.ones((cfg.hidden_size,), dt), repl),
+        jax.jit(lambda k: (jax.random.normal(
+            k, (cfg.hidden_size, cfg.vocab_size), jnp.float32)
+            * cfg.hidden_size ** -0.5).astype(dt), out_shardings=repl)(kw),
+        jax.jit(lambda k: jax.random.randint(
+            k, (BATCH, SEQ), 0, cfg.vocab_size), out_shardings=rows)(kt))
+    for name, (step, _, _) in programs.items():
+        out[name] = _time_and_trace(step, args, f"moe_head_{name}")
+    out["saved_ms"] = round(out["replicated"]["fwd_bwd_wall_ms"]
+                            - out["split_over_ep"]["fwd_bwd_wall_ms"], 3)
+    return out
 
 
 # ------------------------------------------------------- compiled, not run
@@ -268,7 +347,7 @@ def stats() -> dict:
             "device": jax.devices()[0].device_kind, "seeds": rows}
 
 
-MODES = {"layer": layer, "stats": stats, "aot-layer": aot_layer,
+MODES = {"layer": layer, "head": head, "stats": stats, "aot-layer": aot_layer,
          "aot-step": aot_step}
 
 if __name__ == "__main__":

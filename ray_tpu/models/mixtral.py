@@ -9,10 +9,12 @@ Here MoE is first-class and TPU-native:
   slots ``[E, C, H]`` of STATIC shape, filled and read back by index: a small
   integer plan (``routing_plan``: which slot each claim holds, which token
   each slot holds) and two row gathers, so no tensor of tokens x experts x
-  capacity exists. Under expert parallelism the batch is replicated over the
-  mesh's ``ep`` axis (it shards over dp/fsdp only), so no token changes chips
-  and there is no all-to-all: each chip serves the experts it holds and the
-  parts are summed, one all-reduce of [T, H] a layer forward and one backward.
+  capacity exists. Under expert parallelism the layers keep the batch
+  replicated over the mesh's ``ep`` axis (it shards over dp/fsdp only), so no
+  token changes chips and there is no all-to-all: each chip serves the experts
+  it holds and the parts are summed, one all-reduce of [T, H] a layer forward
+  and one backward. Past the last layer nothing needs every token everywhere:
+  the head and the loss run on each ``ep`` chip's own share (``_head_spec``).
 - Attention/rope/norms are shared with the Llama family; only the MLP is
   replaced by the expert layer; layers still scan-stacked.
 - Load-balancing auxiliary loss (Switch Transformer form) returned alongside
@@ -29,7 +31,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import llama as _llama
 from ray_tpu.ops.norms import rms_norm
@@ -242,11 +244,13 @@ def _routed(cfg: MixtralConfig, x: jax.Array, lp: dict, kmesh=None):
     through unchanged): Switch/GShard semantics.
 
     The slots are handled in ``G`` groups of whole experts, one group to a
-    chip of the ``ep`` axis of ``kmesh``'s mesh (one group without one). The
-    batch does not shard over ``ep``: every chip holds every token, fills and
-    reads back the slots of its own group, and the groups' parts of y are
-    summed, which XLA lowers to one all-reduce of [T, H] over ``ep``; the
-    gradient to x is summed the same way in the backward pass.
+    chip of the ``ep`` axis of ``kmesh``'s mesh (one group without one). In
+    this layer, as in the whole scan of layers, the batch does not shard over
+    ``ep``: every chip holds every token, fills and reads back the slots of
+    its own group, and the groups' parts of y are summed, which XLA lowers to
+    one all-reduce of [T, H] over ``ep``; the gradient to x is summed the
+    same way in the backward pass. (Only what follows the layers splits the
+    tokens over ``ep``: ``_head_spec``.)
     """
     b, s, h = x.shape
     T, E = b * s, cfg.num_experts
@@ -324,12 +328,41 @@ def _layer(cfg: MixtralConfig, x, lp, inv_freq, positions, attn_impl,
         return x + y.astype(x.dtype), aux
 
 
+def _head_spec(kmesh, b: int, s: int) -> P | None:
+    """Where the tokens [B, S] go once the layers are done, under a mesh with
+    an ``ep`` axis: split over it as well, along the sequence where ``ep``
+    divides it (past the last layer no position depends on another), else
+    along the batch beside its own axes. The layers keep every token on
+    every ``ep`` chip (``_routed``); the head and the loss do not need to,
+    and a chip that runs them on all of them repeats its neighbours' work.
+    None, the layers' layout as it is: no mesh, ``ep`` of 1, or a shape
+    ``ep`` divides on neither dimension."""
+    ep = kmesh.mesh.shape.get("ep", 1) if kmesh is not None else 1
+    if ep == 1:
+        return None
+    if s % ep == 0:
+        return P(kmesh.batch or None, "ep")
+    if b % (ep * math.prod(kmesh.mesh.shape[ax] for ax in kmesh.batch)) == 0:
+        return P((*kmesh.batch, "ep"))
+    return None
+
+
+def _lay(a: jax.Array, kmesh, spec: P | None) -> jax.Array:
+    """``a`` laid out by ``spec`` on ``kmesh``'s mesh; None asks for nothing."""
+    if spec is None:
+        return a
+    return lax.with_sharding_constraint(a, NamedSharding(kmesh.mesh, spec))
+
+
 def forward(cfg: MixtralConfig, params: dict, tokens: jax.Array,
             positions: jax.Array | None = None, attn_impl: str = "flash",
             remat: bool = True, kmesh=None):
     """tokens [B, S] → (logits [B, S, V] fp32, mean aux loss). ``kmesh``:
-    the caller's mesh for the Pallas kernels (ops/kernels.py)."""
+    the caller's mesh for the Pallas kernels (ops/kernels.py); where it has
+    an ``ep`` axis the logits come back with their tokens split over it
+    (``_head_spec``), one global array all the same."""
     b, s = tokens.shape
+    head = _head_spec(kmesh, b, s)
     if positions is None:
         with tracing.part("attn"):
             positions = jnp.arange(s)
@@ -351,8 +384,13 @@ def forward(cfg: MixtralConfig, params: dict, tokens: jax.Array,
 
     with tracing.part("stack"):
         x, aux = lax.scan(scan_body, x, params["layers"])
+        # The split below stops here, forward and backward: left to itself
+        # XLA carries it into the scan and gathers the tokens again round
+        # every kernel of every layer.
+        x = _lay(x, kmesh, None if head is None else kmesh.rows_spec(3))
     with tracing.part("head"):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
+        x = _lay(rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh),
+                 kmesh, head)
         # bf16 MXU matmul with f32 accumulation — casting both operands to
         # f32 would fall off the MXU fast path (see llama.forward).
         logits = jnp.einsum("bsh,hv->bsv", x, params["lm_head"],
@@ -364,14 +402,19 @@ def forward(cfg: MixtralConfig, params: dict, tokens: jax.Array,
 def loss_fn(cfg: MixtralConfig, params: dict, tokens: jax.Array,
             targets: jax.Array, mask: jax.Array | None = None,
             **fwd_kwargs) -> jax.Array:
-    """LM cross-entropy + router load-balancing loss."""
+    """LM cross-entropy + router load-balancing loss. The token losses are
+    computed where ``forward`` left the logits: under an ``ep`` axis, on each
+    chip's own share of the tokens."""
     logits, aux = forward(cfg, params, tokens, **fwd_kwargs)
+    kmesh = fwd_kwargs.get("kmesh")
+    head = _head_spec(kmesh, *tokens.shape)
     with tracing.part("loss"):
+        targets = _lay(targets, kmesh, head)
         logp = jax.nn.log_softmax(logits, axis=-1)
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
         if mask is None:
             mask = jnp.ones_like(targets, jnp.float32)
-        mask = mask.astype(jnp.float32)
+        mask = _lay(mask.astype(jnp.float32), kmesh, head)
         lm = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
         return lm + cfg.router_aux_coef * aux
 

@@ -549,9 +549,9 @@ def make_mixtral_train_step(
     **step_options,
 ) -> tuple[Callable, Callable, Callable]:
     """MoE specialization: expert weights shard over the mesh ``ep`` axis.
-    The batch does not (it shards over dp/fsdp), so every ``ep`` chip routes
-    every token to the experts it holds and the layer's output is one
-    all-reduce of [T, H] over ``ep`` (models/mixtral.py ``moe_block``)."""
+    Through the layers every ``ep`` chip holds every token of its dp/fsdp
+    shard and a layer's output is one all-reduce of [T, H] over ``ep``; the
+    head and the loss split those tokens over ``ep`` (models/mixtral.py)."""
     from ray_tpu.models import mixtral
 
     return make_train_step(

@@ -7,12 +7,14 @@ single-device and expert-parallel (ep) sharded runs.
 """
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models.mixtral import (
     MixtralConfig,
@@ -285,6 +287,86 @@ class TestMoeTraining:
             lambda want, got: np.testing.assert_allclose(
                 np.asarray(want), np.asarray(got), rtol=2e-4, atol=2e-5),
             ref_grads, ep_grads)
+
+    def test_ep_train_step_matches_single_device(self, cfg):
+        """Under ep the head and the loss run on each chip's own quarter of
+        the tokens (a batch of 2 over ep=4: the sequence splits) and the
+        head's gradients are summed over ep: one whole step gives the loss,
+        the gradient norm and the weights next to the head that one device
+        gives. SGD at rate 1, so a weight moves by exactly its gradient."""
+        from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+        from ray_tpu.train.spmd import make_mixtral_train_step
+
+        tokens = np.arange(32, dtype=np.int32).reshape(2, 16) % cfg.vocab_size
+        targets = np.roll(tokens, -1, axis=1)
+
+        def one_step(spec, n):
+            mesh = build_mesh(spec, jax.devices("cpu")[:n])
+            step_fn, init_state, shard = make_mixtral_train_step(
+                cfg, mesh, optimizer=optax.sgd(1.0), attn_impl="blockwise",
+                remat=False)
+            state, metrics = step_fn(init_state(), shard(tokens),
+                                     shard(targets))
+            return state.params, metrics
+
+        want, want_m = one_step(MeshSpec(), 1)
+        got, got_m = one_step(MeshSpec(ep=4), 4)
+        for name in ("loss", "grad_norm"):
+            np.testing.assert_allclose(float(want_m[name]),
+                                       float(got_m[name]), rtol=2e-4)
+        for name in ("lm_head", "final_norm", "embed_tokens"):
+            np.testing.assert_allclose(
+                np.asarray(want[name]), np.asarray(got[name]),
+                rtol=2e-4, atol=2e-5, err_msg=name)
+
+    @pytest.mark.parametrize("shape, spec", [
+        ((2, 16), P(("dp", "fsdp"), "ep")),   # ep divides the sequence
+        ((4, 15), P(("dp", "fsdp", "ep"))),   # only the batch
+        ((3, 15), None),    # neither: the layers' layout as it is
+    ])
+    def test_head_layout_follows_the_shape(self, cfg, shape, spec):
+        from jax.sharding import NamedSharding
+
+        from ray_tpu.models.mixtral import _head_spec
+        from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+        from ray_tpu.parallel.sharding import (ShardingRules, kernel_mesh,
+                                               tree_shardings)
+
+        mesh = build_mesh(MeshSpec(ep=4), jax.devices("cpu")[:4])
+        kmesh = kernel_mesh(mesh)
+        assert _head_spec(kmesh, *shape) == spec
+
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        tokens = (jnp.arange(shape[0] * shape[1], dtype=jnp.int32)
+                  .reshape(shape) % cfg.vocab_size)
+        targets = jnp.roll(tokens, -1, axis=1)
+
+        def loss(p, kmesh=None):
+            return loss_fn(cfg, p, tokens, targets, attn_impl="blockwise",
+                           remat=False, kmesh=kmesh)
+
+        # A shape ep cannot split asks for no layout at all.
+        pins = str(jax.make_jaxpr(partial(loss, kmesh=kmesh))(params)).count(
+            "sharding_constraint")
+        assert pins == (0 if spec is None else 4), pins
+
+        want_l, want = jax.jit(jax.value_and_grad(loss))(params)
+        sharded = jax.tree.map(
+            jax.device_put, params,
+            tree_shardings(mesh, param_logical_axes(cfg), ShardingRules()))
+        got_l, got = jax.jit(jax.value_and_grad(partial(loss, kmesh=kmesh)))(
+            sharded)
+        if spec is not None:
+            logits, _ = jax.jit(partial(
+                forward, cfg, attn_impl="blockwise", remat=False,
+                kmesh=kmesh))(sharded, tokens)
+            assert logits.sharding.is_equivalent_to(
+                NamedSharding(mesh, spec), logits.ndim), logits.sharding
+        np.testing.assert_allclose(float(want_l), float(got_l), rtol=2e-4)
+        jax.tree.map(
+            lambda w, g: np.testing.assert_allclose(
+                np.asarray(w), np.asarray(g), rtol=2e-4, atol=2e-5),
+            want, got)
 
     def test_ep_plus_dp_train_step(self, cfg):
         """Combined dp×ep mesh runs a full train step and improves."""

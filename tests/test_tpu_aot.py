@@ -13,6 +13,7 @@ Compilations, not runs: what the chip does with them is chip_smoke.py's job.
 
 import collections
 import dataclasses
+import re
 from functools import partial
 
 import jax
@@ -144,26 +145,46 @@ def test_llama_step_partitions_over_four_chips(mosaic, axis):
 def test_mixtral_step_partitions_over_expert_parallel_chips(mosaic):
     from ray_tpu.models import mixtral
 
+    # A vocabulary no other width of the model equals: the logits are the
+    # only arrays of [.., 384].
     cfg = dataclasses.replace(
         mixtral.MixtralConfig.tiny(), hidden_size=256, num_heads=8,
-        num_kv_heads=4, head_dim=64, dtype="bfloat16")
+        num_kv_heads=4, head_dim=64, vocab_size=384, dtype="bfloat16")
     mesh = build_mesh(MeshSpec(ep=4), mosaic)
     # 1,024 tokens: a mask of tokens x local experts x capacity (640) has
     # more elements than the layer's [T, H] sum has bytes.
+    batch, seq = 8, 128
     text = _compile_step(make_mixtral_train_step, cfg, mesh,
                          mixtral.param_logical_axes(cfg),
-                         [((8, 128), jnp.int32)] * 2)
+                         [((batch, seq), jnp.int32)] * 2)
     assert "num_partitions=4" in text and text.count(MOSAIC) > 0
-    # The routed layer moves rows by index and every chip holds every token:
-    # nothing is gathered or passed round, no collective is as large as a
-    # mask, and the largest sum is the layer's [T, H] in bfloat16 (the
-    # gradients of the replicated embedding and head come next).
-    tokens, local = 8 * 128, cfg.num_experts // 4
+    # The routed layer moves rows by index and every chip holds every token
+    # through the layers: nothing is passed round, no collective is as large
+    # as a mask, and the largest are the layer's [T, H] sums in bfloat16.
+    tokens, local = batch * seq, cfg.num_experts // 4
     mask = tokens * local * cfg.capacity(tokens)
+    rows = tokens * cfg.hidden_size * 2
     ops = _collectives(text, 4)
-    assert {op for (op, _) in ops} == {"all-reduce"}, ops
-    assert max(n for (_, n) in ops) == tokens * cfg.hidden_size * 2 < mask, ops
+    assert {op for (op, _) in ops} == {"all-reduce", "all-gather"}, ops
+    assert max(n for (_, n) in ops) == rows < mask, ops
     assert f"[{tokens},{local},{cfg.capacity(tokens)}]" not in text
+    # The head and the loss run on each chip's quarter of every sequence
+    # (mixtral._head_spec): no float32 [T, V] exists whole, and the one
+    # gather is the [T, H] cotangent on its way back into the layers (one
+    # channel, however many pieces the scheduler cuts it into).
+    assert f"f32[{batch},{seq // 4},{cfg.vocab_size}]" in text
+    for whole in (f"[{batch},{seq},{cfg.vocab_size}]",
+                  f"[{tokens},{cfg.vocab_size}]"):
+        assert whole not in text, whole
+    assert {n for (op, n) in ops if op == "all-gather"} == {rows}, ops
+    assert len(set(re.findall(
+        r" all-gather(?:-start)?\(.*?channel_id=(\d+)", text))) == 1
+    # Attention does not follow: the flash calls keep every sequence whole.
+    flash = [line for line in text.splitlines()
+             if MOSAIC in line and "= (" in line and "flash_" in line]
+    q = (f"bf16[{batch * cfg.num_kv_heads},"
+         f"{cfg.num_heads // cfg.num_kv_heads},{seq},{cfg.head_dim}]")
+    assert len(flash) >= 2 and all(q in line for line in flash), flash
 
 
 def test_vit_step_partitions_over_four_chips(mosaic):
