@@ -1,0 +1,272 @@
+"""The LFM2-MoE serving programs at the shapes of ``lfm2-24b-serve-extract-8k``
+(the first 10 layers of LFM2-24B-A2B with all 64 experts, 64 slots x 8,192):
+compiled for a described v5e with no chip, and timed on one.
+
+    python3 devbench/lfm2_bench.py aot            # no chip, about a minute
+    chiprun -- python3 devbench/lfm2_bench.py step parity
+
+``aot``: ``llm/lfm2_serving.py``'s ``prefill_chunk`` at the buckets 16 and
+512 and ``decode_burst(8)``, compiled for ``v5e:2x2``'s first device
+(nothing runs: no time comes out of it): XLA's ``memory_analysis``
+(arguments, temporaries, their sum against the chip's 15.75 GiB), the bytes
+a cached position takes (the ``kv`` leaf over slots x positions), the
+Mosaic calls, and every instruction whose result has the shape of the
+cache, of the convolutions' state or of a stacked weight, by opcode (a copy
+of one of those is up to 4.5 GiB moved a program). ``step``: wall
+milliseconds of one decode step inside a burst of 8 at 64 lines of 1,024,
+3,072 and 6,144 live positions, and of a prefill chunk of 512 at 0 and
+4,096 cached rows (the clock stops on a host read of the result).
+``parity``: the programs against ``models/lfm2.forward`` in bfloat16 and
+both against the float32 reference over 1,024 tokens (prefill in chunks of
+512, then 8 decode steps), as the reference's top logit minus its logit of
+the program's top token. One JSON object a mode.
+"""
+
+from __future__ import annotations
+
+import collections
+import json
+import os
+import re
+import sys
+import time
+from functools import partial
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+
+SLOTS, MAX_SEQ = 64, 8192
+BUCKETS = (16, 512)
+GIB = float(1 << 30)
+
+
+def config_json() -> dict:
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "lfm2-24b-a2b.json")) as f:
+        return json.load(f)
+
+
+def config(slots_seq: int = MAX_SEQ):
+    from rtbench.adapters import lfm2 as adapter
+
+    return adapter.model_config(config_json(), "serve_extract", slots_seq)
+
+
+def shapes(cfg, place, slots: int = SLOTS):
+    import jax
+
+    from ray_tpu.llm import lfm2_serving as serving
+    from ray_tpu.models import lfm2
+
+    params = place(jax.eval_shape(partial(lfm2.init_params, cfg),
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(partial(serving.init_cache, cfg, slots,
+                                         MAX_SEQ)))
+    return params, cache
+
+
+def lowerings(cfg, params, cache, arg, slots: int = SLOTS) -> dict:
+    """{name: a function that lowers that program} at the cell's shapes."""
+    import jax.numpy as jnp
+
+    from ray_tpu.llm import lfm2_serving as serving
+
+    def chunk(b):
+        return lambda: serving.prefill_chunk.lower(
+            cfg, params, cache, arg((b,)), arg(()), arg(()), arg(()))
+
+    out = {f"prefill_chunk({b})": chunk(b) for b in BUCKETS}
+    out["decode_burst(8)"] = lambda: serving.decode_burst.lower(
+        cfg, params, cache, arg((slots,)), arg((slots,)),
+        arg((slots,), jnp.bool_), arg((slots,), jnp.float32),
+        arg((slots,), jnp.float32), arg((2,), jnp.uint32), 8, False)
+    return out
+
+
+def big_shapes(cfg, slots: int = SLOTS) -> dict:
+    """The shapes no instruction should produce: the cache's two leaves,
+    one line of each, and each stacked matrix."""
+    h, f, fe = (cfg.hidden_size, cfg.intermediate_size,
+                cfg.moe_intermediate_size)
+    line = f"{slots},{cfg.num_kv_heads},{MAX_SEQ},{2 * cfg.head_dim}]"
+    state = f"{slots},{(cfg.conv_L_cache - 1) * h}]"
+    nm, e = cfg.num_routed_layers, cfg.experts_held
+    return {"kv": f"bf16[{cfg.attention_lines},{line}",
+            "kv_line": f"bf16[{line}",
+            "kv_slot": f"bf16[1,{cfg.num_kv_heads},{MAX_SEQ},"
+                       f"{2 * cfg.head_dim}]",
+            "conv": f"bf16[{cfg.conv_lines},{state}",
+            "experts_up": f"bf16[{nm},{e},{h},{fe}]",
+            "experts_down": f"bf16[{nm},{e},{fe},{h}]",
+            "experts_layer_up": f"bf16[{e},{h},{fe}]",
+            "experts_layer_down": f"bf16[{e},{fe},{h}]",
+            "conv_in": f"bf16[{cfg.conv_lines},{h},{3 * h}]",
+            "conv_out": f"bf16[{cfg.conv_lines},{h},{h}]",
+            "dense_up": f"bf16[{cfg.num_dense_layers},{h},{f}]",
+            "dense_down": f"bf16[{cfg.num_dense_layers},{f},{h}]",
+            "embed": f"bf16[{cfg.vocab_size},{h}]",
+            "embed_f32": f"f32[{cfg.vocab_size},{h}]"}
+
+
+def opcodes_with_shape(text: str, shape: str) -> dict:
+    ops: collections.Counter = collections.Counter()
+    for line in text.splitlines():
+        head = line.split(" = ", 1)
+        if len(head) == 2 and shape in head[1].split("(", 1)[0]:
+            m = re.search(r"\s([a-z][a-z-]*)\(", " " + head[1])
+            if m:
+                ops[m.group(1)] += 1
+    return dict(ops)
+
+
+def aot(slots: int = SLOTS) -> dict:
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.ops.kernels import force_kernel_backend
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    devices = topologies.get_topology_desc("v5e:2x2", "tpu").devices
+    cfg = config()
+    out = {"mode": "aot", "slots": slots, "max_seq": MAX_SEQ, "programs": {}}
+    with force_kernel_backend("mosaic", devices[0].device_kind):
+        dev = NamedSharding(build_mesh(MeshSpec(), devices[:1]), P())
+
+        def place(tree):
+            return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=dev), tree)
+
+        def arg(shape, dtype=jnp.int32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+        params, cache = shapes(cfg, place, slots)
+        kv = cache["kv"]
+        out["cached_position_bytes"] = (
+            kv.size * kv.dtype.itemsize // (slots * MAX_SEQ))
+        for name, lower in lowerings(cfg, params, cache, arg, slots).items():
+            t0 = time.monotonic()
+            compiled = lower().compile()
+            text = compiled.as_text()
+            mem = compiled.memory_analysis()
+            out["programs"][name] = {
+                "compile_s": round(time.monotonic() - t0, 1),
+                "arguments_gib": round(mem.argument_size_in_bytes / GIB, 3),
+                "temporaries_gib": round(mem.temp_size_in_bytes / GIB, 3),
+                "sum_gib": round((mem.argument_size_in_bytes
+                                  + mem.temp_size_in_bytes) / GIB, 3),
+                "mosaic_calls": text.count(
+                    'custom_call_target="tpu_custom_call"'),
+                "big": {k: opcodes_with_shape(text, s)
+                        for k, s in big_shapes(cfg, slots).items()}}
+            if os.environ.get("LFM2_BENCH_HLO"):
+                with open(os.path.join(os.environ["LFM2_BENCH_HLO"],
+                                       name + ".hlo.txt"), "w") as f:
+                    f.write(text)
+    return out
+
+
+def _programs():
+    import jax
+
+    from ray_tpu.llm import lfm2_serving as serving
+    from ray_tpu.models import lfm2
+
+    cfg = config()
+    params = jax.jit(lfm2.init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(0))
+    return cfg, params, serving, serving.init_cache(cfg, SLOTS, MAX_SEQ)
+
+
+def step() -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    cfg, params, serving, cache = _programs()
+    i32 = jnp.int32
+    out = {"mode": "step", "device": jax.devices()[0].device_kind,
+           "decode_ms_per_step": {}, "prefill_chunk_ms": {}}
+    for kv_len in (0, 4096):
+        times = []
+        for _ in range(4):
+            t0 = time.monotonic()
+            cache, logits, counts = serving.prefill_chunk(
+                cfg, params, cache, jnp.arange(512, dtype=i32) + 300,
+                i32(kv_len), i32(kv_len + 512), i32(0))
+            np.asarray(logits[:1])
+            times.append((time.monotonic() - t0) * 1e3)
+        out["prefill_chunk_ms"][kv_len] = round(min(times[1:]), 2)
+        out["prefill_counts"] = [int(n) for n in counts]
+    temps = jnp.zeros((SLOTS,), jnp.float32)
+    for live in (1024, 3072, 6144):
+        times = []
+        for _ in range(4):
+            t0 = time.monotonic()
+            cache, toks, counts = serving.decode_burst(
+                cfg, params, cache, jnp.arange(SLOTS, dtype=i32) + 300,
+                jnp.full((SLOTS,), live, i32), jnp.ones((SLOTS,), bool),
+                temps, temps + 1.0, jax.random.PRNGKey(1), 8, False)
+            np.asarray(toks)
+            times.append((time.monotonic() - t0) * 1e3 / 8)
+        out["decode_ms_per_step"][live] = round(min(times[1:]), 2)
+        out["decode_counts"] = [int(n) for n in counts]
+    return out
+
+
+def parity(tokens: int = 1024, seed: int = 7) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from reference import lfm2 as reference
+    from rtbench import gen
+    from rtbench.adapters import lfm2 as adapter
+
+    from ray_tpu.models import lfm2
+
+    cfg, params, serving, cache = _programs()
+    cj = config_json()
+    ids = np.asarray(gen.prompt_ids(seed, 1, tokens + 8, cj["vocab_size"]),
+                     np.int32)
+    want = np.asarray(reference.logits(
+        cj, adapter.reference_weights(params), jnp.asarray(ids)))
+
+    def margin(got, rows):
+        pick = np.asarray(got).argmax(axis=-1)
+        w = want[rows]
+        return float((w.max(axis=1) - w[np.arange(len(pick)), pick]).max())
+
+    fwd, _ = jax.jit(lfm2.forward, static_argnums=0)(cfg, params,
+                                                     jnp.asarray(ids)[None])
+    out = {"mode": "parity", "tokens": tokens,
+           "forward_margin": margin(fwd[0], np.arange(tokens + 8))}
+    slot, last = 3, []
+    for a in range(0, tokens, 512):
+        cache, logits, _ = serving.prefill_chunk(
+            cfg, params, cache, jnp.asarray(ids[a:a + 512]), jnp.int32(a),
+            jnp.int32(tokens), jnp.int32(slot))
+        last.append(np.asarray(logits))
+    out["prefill_margin"] = margin(last[-1][None], np.array([tokens - 1]))
+    write = np.zeros(SLOTS, bool)
+    write[slot] = True
+    rows = []
+    for p in range(tokens, tokens + 8):
+        tok = np.zeros(SLOTS, np.int32)
+        pos = np.zeros(SLOTS, np.int32)
+        tok[slot], pos[slot] = ids[p], p
+        cache, logits, _ = serving.decode_step(
+            cfg, params, cache, jnp.asarray(tok), jnp.asarray(pos),
+            jnp.asarray(write))
+        rows.append(np.asarray(logits[slot]))
+    out["decode_margin"] = margin(np.stack(rows),
+                                  np.arange(tokens, tokens + 8))
+    return out
+
+
+MODES = {"aot": aot, "step": step, "parity": parity}
+
+if __name__ == "__main__":
+    for mode in sys.argv[1:] or ["aot"]:
+        print(json.dumps(MODES[mode]()), flush=True)

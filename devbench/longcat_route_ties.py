@@ -46,7 +46,7 @@ def main(argv: list[str]) -> int:
     from rtbench.adapters import longcat as adapter
 
     from ray_tpu.llm import engine
-    from ray_tpu.models import longcat
+    from ray_tpu.models import longcat, routed
 
     with open(os.path.join(ROOT, "benchmark", "configs",
                            "longcat-flash-chat.json")) as f:
@@ -58,19 +58,19 @@ def main(argv: list[str]) -> int:
                       jnp.int32)
 
     program_choice: list = []
-    inner_route = longcat.route
+    inner_route = routed.route
 
-    def route(cfg, router, bias, u):
-        idx, w = inner_route(cfg, router, bias, u)
+    def route(rule, router, bias, u):
+        idx, w = inner_route(rule, router, bias, u)
         jax.debug.callback(lambda i: program_choice.append(np.asarray(i)),
                            idx, ordered=True)
         return idx, w
 
-    longcat.route = route
+    routed.route = route
     got, _ = jax.jit(longcat.forward, static_argnums=0)(cfg, params,
                                                         ids[None])
     got = np.asarray(jax.block_until_ready(got)[0])
-    longcat.route = inner_route
+    routed.route = inner_route
 
     reference_choice: list = []
     inner = reference._route

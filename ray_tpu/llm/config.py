@@ -12,12 +12,13 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any
 
+from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
 from ray_tpu.models.ouro import OuroConfig
 
 # A model's own configuration: what llm/engine.served_model knows a model by.
-ModelConfig = LlamaConfig | LongcatConfig | OuroConfig
+ModelConfig = LlamaConfig | LongcatConfig | OuroConfig | Lfm2Config
 
 
 @dataclass

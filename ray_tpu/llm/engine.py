@@ -505,6 +505,13 @@ class ServedModel:
       fetches at a time, behind ``kv_positions_read``;
     - ``kv_handoff``: whether a line can be exported and imported as
       per-head K/V (the prefill/decode hand-off);
+    - ``prefix_from_line``: whether a prompt's first tokens can be adopted
+      from another slot's line, at any common length. False for a model
+      that also keeps a state of fixed size a slot (a short convolution's,
+      a recurrence's): the state at the adopted length is nowhere unless
+      it was saved then. The engine then adopts nothing, counts no hit,
+      publishes no prefix to the router and never calls
+      ``copy_prefix_kv``, which may be None;
     - ``refuse(config)``: raises ValueError for an ``LLMConfig`` it cannot
       serve (None: it serves them all)."""
 
@@ -514,11 +521,12 @@ class ServedModel:
     prefill_chunk: Callable
     decode_step: Callable
     decode_burst: Callable
-    copy_prefix_kv: Callable
     kv_block: Callable
+    copy_prefix_kv: Callable | None = None
     counters: tuple[str, ...] = ()
     constants: Callable | None = None
     kv_handoff: bool = True
+    prefix_from_line: bool = True
     refuse: Callable | None = None
 
 
@@ -553,8 +561,14 @@ def served_model(cfg) -> ServedModel:
         from ray_tpu.llm.ouro_serving import SERVED
 
         return SERVED
+    from ray_tpu.models.lfm2 import Lfm2Config
+
+    if isinstance(cfg, Lfm2Config):
+        from ray_tpu.llm.lfm2_serving import SERVED
+
+        return SERVED
     raise TypeError(f"the engine serves no {type(cfg).__name__}: it serves "
-                    "LlamaConfig, LongcatConfig and OuroConfig")
+                    "Lfm2Config, LlamaConfig, LongcatConfig and OuroConfig")
 
 
 def require_kv_handoff(cfg) -> None:
@@ -775,7 +789,7 @@ class LLMEngine:
         # cache is keyed by the prompt tuple and pruned to the live donor
         # set on every publish.
         self.prefix_block = int(getattr(config, "prefix_block_tokens", 32)
-                                or 0)
+                                or 0) if self.model.prefix_from_line else 0
         self._prefix_hash_cache: dict[tuple, tuple[int, ...]] = {}
         self._cache_gen = 0  # bumped when a device failure rebuilds the cache
         self._prefill_rr = -1  # last slot that ran a prefill chunk
@@ -1284,7 +1298,9 @@ class LLMEngine:
                     self._fail(req, f"KV import failed: {e!r}")
                 admitted += 1
                 continue
-            donor, adopt, retired = self._best_prefix(req.prompt_ids)
+            donor, adopt, retired = (
+                self._best_prefix(req.prompt_ids)
+                if self.model.prefix_from_line else (None, 0, False))
             req.prefilled_len = 0
             if donor is not None and adopt < self.PREFIX_COPY_MIN:
                 # Trivial LCP (e.g. a shared few-token template label):

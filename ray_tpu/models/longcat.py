@@ -48,18 +48,19 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops.grouped_matmul import grouped_matmul
+# MOE_COUNTERS, MOE_TILE and dispatch_plan are this module's names too.
+from ray_tpu.models.routed import (  # noqa: F401
+    MOE_COUNTERS,
+    MOE_TILE,
+    RouterRule,
+    dispatch_plan,
+    layer_of as _layer_of,
+    moe_block,
+)
 from ray_tpu.ops.kernels import KernelMesh
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope_interleaved, rope_frequencies
 from ray_tpu.util import tracing
-
-# Rows of one tile of the grouped matmul: a packed sublane tile of bfloat16.
-MOE_TILE = 16
-# What moe_block counts, in this order (llm/engine.py adds them up).
-MOE_COUNTERS = ("moe_picks", "moe_picks_local", "moe_picks_zero",
-                "moe_experts_touched", "moe_layer_steps")
-
 
 @dataclass(frozen=True)
 class LongcatConfig:
@@ -91,13 +92,7 @@ class LongcatConfig:
     expert_shards: int = 1
 
     def __post_init__(self):
-        if self.n_routed_experts % self.expert_shards:
-            raise ValueError(
-                f"{self.n_routed_experts} routed experts do not divide "
-                f"into {self.expert_shards} shards")
-        if not 0 <= self.expert_shard < self.expert_shards:
-            raise ValueError(f"expert_shard {self.expert_shard} outside "
-                             f"0..{self.expert_shards - 1}")
+        self.router_rule  # refuses a share the experts do not divide into
 
     @staticmethod
     def tiny(**kw) -> "LongcatConfig":
@@ -123,6 +118,18 @@ class LongcatConfig:
     @property
     def router_outputs(self) -> int:
         return self.n_routed_experts + self.zero_expert_num
+
+    @property
+    def router_rule(self) -> RouterRule:
+        """Softmax over routed and zero experts, the choice by score +
+        bias, the weights ``routed_scaling_factor * p`` as they are."""
+        return RouterRule(
+            experts=self.n_routed_experts, topk=self.moe_topk,
+            score="softmax", use_bias=True, renormalize=False,
+            scaling_factor=self.routed_scaling_factor,
+            zero_experts=self.zero_expert_num,
+            expert_shard=self.expert_shard,
+            expert_shards=self.expert_shards)
 
     @property
     def latent_dim(self) -> int:
@@ -339,102 +346,6 @@ def mla_full(cfg: LongcatConfig, ap: dict, xn, kmesh=None):
     return (o @ ap["wo"]).astype(xn.dtype)
 
 
-def route(cfg: LongcatConfig, router, bias, u):
-    """u: [T, H] -> (idx [T, topk] over all router outputs, w [T, topk]
-    float32). Softmax scores in float32 (true float32: a TPU's default
-    float32 matmul is one bfloat16 pass), the choice by score + bias, the
-    weights by score alone, scaled and not renormalised."""
-    logits = jnp.dot(u.astype(jnp.float32), router.astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST)
-    p = jax.nn.softmax(logits, axis=-1)
-    _, idx = lax.top_k(p + bias, cfg.moe_topk)
-    w = cfg.routed_scaling_factor * jnp.take_along_axis(p, idx, axis=-1)
-    return idx, w
-
-
-def dispatch_plan(keys, held: int, tm: int):
-    """Where each local pick goes among rows sorted by expert in tiles of
-    ``tm``. keys: [P] int32, a pick's held expert (0..held-1) or ``held``
-    (not here). Returns ``pick_of_row`` [Mp] (-1: an empty row),
-    ``row_of_pick`` [P] (meaningless for a pick that is not here),
-    ``tile_expert`` [Mp // tm], ``n_live`` and the group sizes [held].
-    Mp = (P // tm + held) * tm holds the worst case: every pick local."""
-    p = keys.shape[0]
-    max_tiles = p // tm + held
-    onehot = keys[:, None] == jnp.arange(held)[None, :]          # [P, held]
-    csum = jnp.cumsum(onehot.astype(jnp.int32), axis=0)
-    sizes = csum[-1]
-    rank = jnp.take_along_axis(
-        csum, jnp.minimum(keys, held - 1)[:, None], axis=1)[:, 0] - 1
-    tiles = (sizes + tm - 1) // tm
-    tile_end = jnp.cumsum(tiles)
-    tile_start = tile_end - tiles
-    n_live = tile_end[-1]
-    group_start = jnp.cumsum(sizes) - sizes
-    # Tile t belongs to the first expert whose tiles end past it; a dead
-    # tile to the last live tile's expert (a fetch it repeats, not a new
-    # one, where a backend visits dead tiles at all).
-    t = jnp.minimum(jnp.arange(max_tiles), jnp.maximum(n_live - 1, 0))
-    tile_expert = jnp.minimum(
-        jnp.searchsorted(tile_end, t, side="right"), held - 1)
-    order = jnp.argsort(keys, stable=True)                       # [P]
-    rows = jnp.arange(max_tiles * tm)
-    e = tile_expert[rows // tm]
-    r = rows - tile_start[e] * tm
-    live = (rows // tm < n_live) & (r < sizes[e])
-    pick_of_row = jnp.where(
-        live, order[jnp.clip(group_start[e] + r, 0, p - 1)], -1)
-    row_of_pick = tile_start[jnp.minimum(keys, held - 1)] * tm + rank
-    return pick_of_row, row_of_pick, tile_expert.astype(jnp.int32), \
-        n_live.astype(jnp.int32), sizes
-
-
-def moe_block(cfg: LongcatConfig, layers: dict, layer, u, valid):
-    """The routed layer on u [T, H]: this shard's experts' part and the
-    zero experts' part of ``sum_e w_e E_e(u)``. ``layers`` is the whole
-    stacked ``params["layers"]`` (the expert stacks are read in place),
-    ``layer`` the double layer's index. A token with ``valid`` false (padding, an idle slot) is
-    routed nowhere and counted nowhere. Returns (y [T, H], counts int32[5]
-    in the order of MOE_COUNTERS)."""
-    t, _ = u.shape
-    held, topk = cfg.experts_held, cfg.moe_topk
-    with tracing.part("moe_route"):
-        idx, w = route(cfg, _layer_of(layers["router"], layer),
-                       _layer_of(layers["router_bias"], layer), u)
-        lo = cfg.expert_shard * held
-        chosen = valid[:, None]
-        local = chosen & (idx >= lo) & (idx < lo + held)
-        zero = chosen & (idx >= cfg.n_routed_experts)
-        keys = jnp.where(local, idx - lo, held).reshape(-1).astype(jnp.int32)
-        pick_of_row, row_of_pick, tile_expert, n_live, sizes = dispatch_plan(
-            keys, held, MOE_TILE)
-    with tracing.part("moe_dispatch"):
-        x_rows = jnp.where((pick_of_row >= 0)[:, None],
-                           u[jnp.maximum(pick_of_row, 0) // topk], 0)
-    with tracing.part("moe_experts"):
-        hidden = grouped_matmul(x_rows, layers["we_gate"], layer, tile_expert,
-                                n_live, tm=MOE_TILE, w2=layers["we_up"])
-        out_rows = grouped_matmul(hidden, layers["we_down"], layer,
-                                  tile_expert, n_live, tm=MOE_TILE)
-    with tracing.part("moe_combine"):
-        # A select, not a product: rows of dead tiles were never written.
-        picked = out_rows[jnp.where(local, row_of_pick.reshape(t, topk), 0)]
-        y = jnp.sum(jnp.where(local[..., None],
-                              w[..., None] * picked.astype(jnp.float32), 0.0),
-                    axis=1)
-        y += jnp.sum(jnp.where(zero, w, 0.0), axis=1,
-                     keepdims=True) * u.astype(jnp.float32)
-        counts = jnp.stack([
-            valid.sum() * topk, local.sum(), zero.sum(), (sizes > 0).sum(),
-            jnp.ones((), jnp.int32)]).astype(jnp.int32)
-        return y.astype(u.dtype), counts
-
-
-def _layer_of(stack, index):
-    """One layer of a stacked leaf, by a run-time index."""
-    return lax.dynamic_index_in_dim(stack, index, 0, keepdims=False)
-
-
 SUBLAYER_LEAVES = ("attn_norm", "post_norm", "wq_a", "q_a_norm", "wq_b",
                    "wkv_a", "kv_a_norm", "wkv_b", "wo", "w_gate", "w_up",
                    "w_down")
@@ -458,8 +369,8 @@ def double_layer(cfg: LongcatConfig, layers: dict, layer, h, attn, state,
         a1 = h + o
     with tracing.part("mlp"):
         u = rms_norm(a1, p0["post_norm"], cfg.norm_eps, kmesh)
-    m, counts = moe_block(cfg, layers, layer, u.reshape(b * s, hid),
-                          valid.reshape(b * s))
+    m, counts = moe_block(cfg.router_rule, layers, layer,
+                          u.reshape(b * s, hid), valid.reshape(b * s))
     with tracing.part("mlp"):
         f1 = a1 + swiglu(u, p0["w_gate"], p0["w_up"], p0["w_down"])
     with tracing.part("attn"):

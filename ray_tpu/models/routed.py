@@ -1,0 +1,176 @@
+"""The routed expert layer that keeps every token, shared by the models
+that have one (models/longcat.py, models/lfm2.py).
+
+What differs between the families is the router's rule, and a
+:class:`RouterRule` states it: how a router output becomes a score (softmax
+over all outputs, or a sigmoid of each), whether a selection bias is added
+for the choice (never for the weights), whether the chosen weights are
+renormalised to sum to one, the factor they are scaled by, how many of the
+router's outputs are zero-compute experts (the identity: a weighted add of
+the layer's input), and which of the routed experts this program holds
+(``expert_shard`` of ``expert_shards`` equal shares).
+
+What is shared is everything after the rule: the layer routes over all the
+router's outputs, keeps every pick that falls on a held expert (no capacity,
+no drop), sorts the picks by expert into tiles of ``MOE_TILE`` rows
+(:func:`dispatch_plan`), multiplies the live tiles only
+(ops/grouped_matmul.py) and computes the part of ``sum_e w_e E_e(u)`` that
+its own experts give, plus the zero experts' part, which every shard
+computes for its own tokens. What the absent shards' experts would add is
+an expert-parallel exchange this module does not have.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.util import tracing
+
+# Rows of one tile of the grouped matmul: a packed sublane tile of bfloat16.
+MOE_TILE = 16
+# What moe_block counts, in this order (llm/engine.py adds them up).
+MOE_COUNTERS = ("moe_picks", "moe_picks_local", "moe_picks_zero",
+                "moe_experts_touched", "moe_layer_steps")
+
+
+@dataclass(frozen=True)
+class RouterRule:
+    """A routed layer's rule, from the model's configuration."""
+
+    experts: int                  # routed experts in the whole model
+    topk: int                     # experts a token
+    score: str = "softmax"        # or "sigmoid"
+    use_bias: bool = True         # added to the score for the choice alone
+    renormalize: bool = False     # chosen weights / (their sum + 1e-6)
+    scaling_factor: float = 1.0
+    zero_experts: int = 0         # router outputs after the routed experts
+    expert_shard: int = 0
+    expert_shards: int = 1
+
+    def __post_init__(self):
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router score {self.score!r}: softmax or "
+                             "sigmoid")
+        if self.experts % self.expert_shards:
+            raise ValueError(
+                f"{self.experts} routed experts do not divide "
+                f"into {self.expert_shards} shards")
+        if not 0 <= self.expert_shard < self.expert_shards:
+            raise ValueError(f"expert_shard {self.expert_shard} outside "
+                             f"0..{self.expert_shards - 1}")
+
+    @property
+    def held(self) -> int:
+        return self.experts // self.expert_shards
+
+    @property
+    def outputs(self) -> int:
+        return self.experts + self.zero_experts
+
+
+def layer_of(stack, index):
+    """One layer of a stacked leaf, by a run-time index."""
+    return lax.dynamic_index_in_dim(stack, index, 0, keepdims=False)
+
+
+def route(rule: RouterRule, router, bias, u):
+    """u: [T, H] -> (idx [T, topk] over all router outputs, w [T, topk]
+    float32). Scores in float32 (true float32: a TPU's default float32
+    matmul is one bfloat16 pass), the choice by score (+ bias), the weights
+    by score alone, renormalised or not, then scaled."""
+    logits = jnp.dot(u.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    if rule.score == "softmax":
+        p = jax.nn.softmax(logits, axis=-1)
+    else:
+        p = jax.nn.sigmoid(logits)
+    _, idx = lax.top_k(p + bias if rule.use_bias else p, rule.topk)
+    w = jnp.take_along_axis(p, idx, axis=-1)
+    if rule.renormalize:
+        w = w / (w.sum(axis=-1, keepdims=True) + 1e-6)
+    return idx, rule.scaling_factor * w
+
+
+def dispatch_plan(keys, held: int, tm: int):
+    """Where each local pick goes among rows sorted by expert in tiles of
+    ``tm``. keys: [P] int32, a pick's held expert (0..held-1) or ``held``
+    (not here). Returns ``pick_of_row`` [Mp] (-1: an empty row),
+    ``row_of_pick`` [P] (meaningless for a pick that is not here),
+    ``tile_expert`` [Mp // tm], ``n_live`` and the group sizes [held].
+    Mp = (P // tm + held) * tm holds the worst case: every pick local."""
+    p = keys.shape[0]
+    max_tiles = p // tm + held
+    onehot = keys[:, None] == jnp.arange(held)[None, :]          # [P, held]
+    csum = jnp.cumsum(onehot.astype(jnp.int32), axis=0)
+    sizes = csum[-1]
+    rank = jnp.take_along_axis(
+        csum, jnp.minimum(keys, held - 1)[:, None], axis=1)[:, 0] - 1
+    tiles = (sizes + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles)
+    tile_start = tile_end - tiles
+    n_live = tile_end[-1]
+    group_start = jnp.cumsum(sizes) - sizes
+    # Tile t belongs to the first expert whose tiles end past it; a dead
+    # tile to the last live tile's expert (a fetch it repeats, not a new
+    # one, where a backend visits dead tiles at all).
+    t = jnp.minimum(jnp.arange(max_tiles), jnp.maximum(n_live - 1, 0))
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, t, side="right"), held - 1)
+    order = jnp.argsort(keys, stable=True)                       # [P]
+    rows = jnp.arange(max_tiles * tm)
+    e = tile_expert[rows // tm]
+    r = rows - tile_start[e] * tm
+    live = (rows // tm < n_live) & (r < sizes[e])
+    pick_of_row = jnp.where(
+        live, order[jnp.clip(group_start[e] + r, 0, p - 1)], -1)
+    row_of_pick = tile_start[jnp.minimum(keys, held - 1)] * tm + rank
+    return pick_of_row, row_of_pick, tile_expert.astype(jnp.int32), \
+        n_live.astype(jnp.int32), sizes
+
+
+def moe_block(rule: RouterRule, layers: dict, layer, u, valid):
+    """The routed layer on u [T, H]: this shard's experts' part and the
+    zero experts' part of ``sum_e w_e E_e(u)``. ``layers`` holds the stacked
+    ``router``, ``router_bias``, ``we_gate``, ``we_up`` and ``we_down`` (the
+    expert stacks are read in place), ``layer`` is the routed layer's index
+    on their leading axis. A token with ``valid`` false
+    (padding, an idle slot) is routed nowhere and counted nowhere. Returns
+    (y [T, H], counts int32[5] in the order of MOE_COUNTERS)."""
+    t, _ = u.shape
+    held, topk = rule.held, rule.topk
+    with tracing.part("moe_route"):
+        idx, w = route(rule, layer_of(layers["router"], layer),
+                       layer_of(layers["router_bias"], layer), u)
+        lo = rule.expert_shard * held
+        chosen = valid[:, None]
+        local = chosen & (idx >= lo) & (idx < lo + held)
+        zero = chosen & (idx >= rule.experts)
+        keys = jnp.where(local, idx - lo, held).reshape(-1).astype(jnp.int32)
+        pick_of_row, row_of_pick, tile_expert, n_live, sizes = dispatch_plan(
+            keys, held, MOE_TILE)
+    with tracing.part("moe_dispatch"):
+        x_rows = jnp.where((pick_of_row >= 0)[:, None],
+                           u[jnp.maximum(pick_of_row, 0) // topk], 0)
+    with tracing.part("moe_experts"):
+        hidden = grouped_matmul(x_rows, layers["we_gate"], layer, tile_expert,
+                                n_live, tm=MOE_TILE, w2=layers["we_up"])
+        out_rows = grouped_matmul(hidden, layers["we_down"], layer,
+                                  tile_expert, n_live, tm=MOE_TILE)
+    with tracing.part("moe_combine"):
+        # A select, not a product: rows of dead tiles were never written.
+        picked = out_rows[jnp.where(local, row_of_pick.reshape(t, topk), 0)]
+        y = jnp.sum(jnp.where(local[..., None],
+                              w[..., None] * picked.astype(jnp.float32), 0.0),
+                    axis=1)
+        if rule.zero_experts:
+            y += jnp.sum(jnp.where(zero, w, 0.0), axis=1,
+                         keepdims=True) * u.astype(jnp.float32)
+        counts = jnp.stack([
+            valid.sum() * topk, local.sum(), zero.sum(), (sizes > 0).sum(),
+            jnp.ones((), jnp.int32)]).astype(jnp.int32)
+        return y.astype(u.dtype), counts

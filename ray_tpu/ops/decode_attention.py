@@ -35,6 +35,19 @@ the operands stay in the cache's dtype. Query j of a slot sees key positions
 ``<= positions0 + j`` and ``< length``; a row that sees nothing (an empty
 slot) gives zeros.
 
+**Heads of half a lane row.** A head of 64 values fills half of the 128
+lanes a row of the cache is stored in, so a ``[..., S, 64]`` cache costs a
+cached position, in HBM and in every read, what a head of 128 costs. Such a
+model keeps keys and values of a head side by side in one row instead: one
+*packed* stack ``[L, B, Hkv, S, 2 D]``, keys in the first D lanes, values
+in the last, passed as ``k_cache`` with ``v_cache=None`` to every function
+here and in ops/prefill_attention.py. The kernel's body does not change:
+the queries are padded with D zeros, so their product with a packed row is
+the product with its key; the probabilities' product with the packed rows
+carries the values' mix in its last D lanes, which is what is kept. On a
+128-wide MXU neither product costs more than its unpacked form, and a
+block is fetched once.
+
 ``kv_row_write`` is the other half of the convention: the step's K new rows
 of each slot go into the same stack in place, through a kernel too, because
 XLA would re-lay the whole cache out around a row update of its own.
@@ -107,7 +120,10 @@ def decode_attention_reference(q, k_cache, v_cache, layer, lengths,
     hkv, s = k_cache.shape[2], k_cache.shape[3]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     kl = lax.dynamic_index_in_dim(k_cache, layer, 0, keepdims=False)
-    vl = lax.dynamic_index_in_dim(v_cache, layer, 0, keepdims=False)
+    if v_cache is None:  # packed: keys, then values, in one row
+        kl, vl = kl[..., :d], kl[..., d:]
+    else:
+        vl = lax.dynamic_index_in_dim(v_cache, layer, 0, keepdims=False)
     qg = q.reshape(b, hkv, (h // hkv) * k, d)
     scores = jnp.einsum("bhrd,bhsd->bhrs", qg, kl.astype(q.dtype),
                         preferred_element_type=jnp.float32) * scale
@@ -235,13 +251,24 @@ def _decode_attention_kernel(len_ref, pos_ref, layer_ref, slot_ref, blk_ref,
             o_ref.dtype)
 
 
+def packed_kernel(kernel, n_scalars: int):
+    """``kernel(*scalars, q_ref, k_ref, v_ref, o_ref, *scratch)`` for a
+    packed stack: the one block read is its keys and its values."""
+    def packed(*refs):
+        q, kv = n_scalars, n_scalars + 1
+        return kernel(*refs[:kv + 1], refs[kv], *refs[kv + 1:])
+    return packed
+
+
 def _decode_attention_pallas(q, k_cache, v_cache, layer, lengths, positions0,
                              plan, *, sm_scale: float, block: int):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, h, k, d = q.shape
-    hkv, s = k_cache.shape[2], k_cache.shape[3]
+    b, h, k, dq = q.shape
+    hkv, s, d = k_cache.shape[2:]
+    packed = v_cache is None
+    caches = (k_cache,) if packed else (k_cache, v_cache)
     if plan.slot.shape[0] != b * (s // block):
         raise ValueError(
             f"decode_attention: a plan of {plan.slot.shape[0]} steps for "
@@ -250,9 +277,9 @@ def _decode_attention_pallas(q, k_cache, v_cache, layer, lengths, positions0,
     rows = (h // hkv) * k
     tile = 32 // q.dtype.itemsize
     rows_p = -(-rows // tile) * tile
-    qg = q.reshape(b, hkv, rows, d)
-    if rows_p != rows:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_p - rows), (0, 0)))
+    qg = q.reshape(b, hkv, rows, dq)
+    if rows_p != rows or packed:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_p - rows), (0, d - dq)))
 
     def kv_index(t, lens, pos, lyr, slot, blk, first, last):
         return (lyr[0], slot[t], 0, blk[t], 0)
@@ -264,14 +291,15 @@ def _decode_attention_pallas(q, k_cache, v_cache, layer, lengths, positions0,
     q_spec = pl.BlockSpec((None, hkv, rows_p, d), q_index)
     # The lengths go in as given: the plan keeps the walk inside the line,
     # and no position lies past its end for a longer length to unmask.
+    kernel = functools.partial(_decode_attention_kernel, block=block,
+                               k_tokens=k, sm_scale=sm_scale)
     out = pl.pallas_call(
-        functools.partial(_decode_attention_kernel, block=block, k_tokens=k,
-                          sm_scale=sm_scale),
+        packed_kernel(kernel, 7) if packed else kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=7,
             # A run-time bound: the steps that exist are the live blocks.
             grid=(plan.n_live[0],),
-            in_specs=[q_spec, kv_spec, kv_spec],
+            in_specs=[q_spec] + [kv_spec] * len(caches),
             out_specs=q_spec,
             scratch_shapes=[pltpu.VMEM((hkv, rows_p, 1), jnp.float32),
                             pltpu.VMEM((hkv, rows_p, 1), jnp.float32),
@@ -289,11 +317,11 @@ def _decode_attention_pallas(q, k_cache, v_cache, layer, lengths, positions0,
         name="decode_attention",
     )(lengths.astype(jnp.int32), positions0.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), plan.slot, plan.block,
-      plan.first, plan.last, qg, k_cache, v_cache)
+      plan.first, plan.last, qg, *caches)
     # The walk never visits a slot with no live block, so nothing wrote its
     # rows of the output.
     out = jnp.where((lengths > 0)[:, None, None, None], out, 0)
-    return out[:, :, :rows].reshape(b, h, k, d)
+    return out[:, :, :rows, d - dq:].reshape(b, h, k, dq)
 
 
 def decode_attention(q, k_cache, v_cache, layer, lengths, positions0, *,
@@ -302,7 +330,8 @@ def decode_attention(q, k_cache, v_cache, layer, lengths, positions0, *,
                      kmesh: KernelMesh | None = None,
                      block: int | None = None):
     """q: [B, H, K, D] (K new tokens a slot, query head h of KV head
-    ``h // (H // Hkv)``); k_cache, v_cache: [L, B, Hkv, S, D], the new rows
+    ``h // (H // Hkv)``); k_cache, v_cache: [L, B, Hkv, S, D], or the packed
+    stack [L, B, Hkv, S, 2 D] and None, the new rows
     already written; layer: int32 scalar; lengths, positions0: [B] int32.
     Returns [B, H, K, D]. ``plan`` is :func:`decode_plan` of these lengths,
     the cache's ``decode_kv_block`` and S (and this ``kmesh``), built by a
@@ -315,7 +344,8 @@ def decode_attention(q, k_cache, v_cache, layer, lengths, positions0, *,
         return decode_attention_reference(q, k_cache, v_cache, layer,
                                           lengths, positions0, scale)
     s = k_cache.shape[3]
-    block = block or decode_kv_block(s, q.shape[-1], k_cache.dtype.itemsize)
+    block = block or decode_kv_block(s, k_cache.shape[-1],
+                                     k_cache.dtype.itemsize)
     if s % block:
         raise ValueError(f"decode_attention: block {block} does not divide "
                          f"the cache line of {s} positions")
@@ -326,11 +356,17 @@ def decode_attention(q, k_cache, v_cache, layer, lengths, positions0, *,
     if kmesh is not None:
         heads, cache = kmesh.heads_spec(4), _stack_spec(kmesh)
         rows = kmesh.rows_spec(1)
-        fn = kmesh.shard(fn, in_specs=(heads, cache, cache, P(), rows, rows,
-                                       _plan_spec(kmesh)),
+        fn = kmesh.shard(fn, in_specs=(heads, cache,
+                                       None if v_cache is None else cache,
+                                       P(), rows, rows, _plan_spec(kmesh)),
                          out_specs=heads)
     return fn(q, k_cache, v_cache, jnp.asarray(layer, jnp.int32), lengths,
               positions0, plan)
+
+
+def packed_rows(new_k, new_v):
+    """A packed stack's rows: a head's key, then its value."""
+    return jnp.concatenate([new_k, new_v], axis=-1)
 
 
 def kv_row_write_reference(k_cache, v_cache, new_k, new_v, layer,
@@ -344,6 +380,8 @@ def kv_row_write_reference(k_cache, v_cache, new_k, new_v, layer,
     def put(stack, new):
         rows = new.transpose(0, 2, 1, 3).astype(stack.dtype)  # [B, K, Hkv, D]
         return stack.at[layer, slots, :, pos, :].set(rows, mode="drop")
+    if v_cache is None:
+        return put(k_cache, packed_rows(new_k, new_v)), None
     return put(k_cache, new_k), put(v_cache, new_v)
 
 
@@ -364,21 +402,23 @@ def _window_index(p0, t, window: int, k_tokens: int):
     return jnp.minimum(first + t, last)
 
 
-def _kv_row_write_kernel(pos_ref, layer_ref, nk_ref, nv_ref, kw_ref, vw_ref,
-                         ko_ref, vo_ref, *, window: int, k_tokens: int):
+def _kv_row_write_kernel(pos_ref, layer_ref, *refs, window: int,
+                         k_tokens: int):
+    """``refs``: the new rows, the windows read and the windows written of
+    each stack (keys and values, or the one packed stack)."""
     from jax.experimental import pallas as pl
 
     del layer_ref  # read by the block specs' index maps
+    n = len(refs) // 3
     p0 = pos_ref[pl.program_id(0)]
     base = _window_index(p0, pl.program_id(1), window, k_tokens) * window
-    row = base + lax.broadcasted_iota(jnp.int32, kw_ref.shape, 1)
-    kw, vw = kw_ref[...], vw_ref[...]
-    for j in range(k_tokens):
-        hit = row == p0 + j
-        kw = jnp.where(hit, nk_ref[j], kw)
-        vw = jnp.where(hit, nv_ref[j], vw)
-    ko_ref[...] = kw
-    vo_ref[...] = vw
+    row = base + lax.broadcasted_iota(jnp.int32, refs[n].shape, 1)
+    for new_ref, win_ref, out_ref in zip(refs[:n], refs[n:2 * n],
+                                         refs[2 * n:]):
+        win = win_ref[...]
+        for j in range(k_tokens):
+            win = jnp.where(row == p0 + j, new_ref[j], win)
+        out_ref[...] = win
 
 
 def _kv_row_write_pallas(k_cache, v_cache, new_k, new_v, layer, positions0,
@@ -386,7 +426,12 @@ def _kv_row_write_pallas(k_cache, v_cache, new_k, new_v, layer, positions0,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, hkv, k, d = new_k.shape
+    if v_cache is None:
+        caches, news = (k_cache,), (packed_rows(new_k, new_v),)
+    else:
+        caches, news = (k_cache, v_cache), (new_k, new_v)
+    n = len(caches)
+    b, hkv, k, d = news[0].shape
     s = k_cache.shape[3]
     window = _kv_window(s)
     if k > window:
@@ -394,8 +439,8 @@ def _kv_row_write_pallas(k_cache, v_cache, new_k, new_v, layer, positions0,
                          f"of {window}")
     steps = 1 if k == 1 else 2
     # [B, K, Hkv, 1, D]: a row is a tile of its own, broadcast over a window.
-    rows = [n.astype(k_cache.dtype).transpose(0, 2, 1, 3)[:, :, :, None, :]
-            for n in (new_k, new_v)]
+    rows = [new.astype(k_cache.dtype).transpose(0, 2, 1, 3)[:, :, :, None, :]
+            for new in news]
     # A masked slot's rows sit at negative positions: no window row is hit.
     # Positions are the engine's to keep inside the line; clamping the
     # window is only so that a wrong one cannot index past the array.
@@ -411,23 +456,23 @@ def _kv_row_write_pallas(k_cache, v_cache, new_k, new_v, layer, positions0,
 
     win_spec = pl.BlockSpec((None, None, hkv, window, d), win_index)
     new_spec = pl.BlockSpec((None, k, hkv, 1, d), new_index)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kv_row_write_kernel, window=window, k_tokens=k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, steps),
-            in_specs=[new_spec, new_spec, win_spec, win_spec],
-            out_specs=[win_spec, win_spec]),
-        out_shape=[jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-                   jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)],
-        # Operands count the scalar-prefetch arguments: 4 and 5 are the
-        # caches, written in place.
-        input_output_aliases={4: 0, 5: 1},
+            in_specs=[new_spec] * n + [win_spec] * n,
+            out_specs=[win_spec] * n),
+        out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in caches],
+        # Operands count the scalar-prefetch arguments: the caches come
+        # after them and the new rows, and are written in place.
+        input_output_aliases={2 + n + i: i for i in range(n)},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=kernel_backend() == "interpret",
         name="kv_row_write",
-    )(pos, jnp.asarray(layer, jnp.int32).reshape(1), *rows, k_cache, v_cache)
+    )(pos, jnp.asarray(layer, jnp.int32).reshape(1), *rows, *caches)
+    return (out[0], None) if v_cache is None else tuple(out)
 
 
 def kv_row_write(k_cache, v_cache, new_k, new_v, layer, positions0,
@@ -436,7 +481,9 @@ def kv_row_write(k_cache, v_cache, new_k, new_v, layer, positions0,
     stacked caches, in place: new_k, new_v [B, Hkv, K, D] go to
     ``[layer, b, :, positions0[b] : positions0[b] + K]`` where
     ``write_mask[b]``; a masked slot's line is left as it is. Returns the
-    caches.
+    caches. With ``v_cache=None`` ``k_cache`` is the packed stack: each
+    row gets a head's key and value side by side, and (stack, None) comes
+    back.
 
     A kernel and not a dynamic_update_slice: XLA lays a cache it updates by
     rows out position-major, and then copies the whole cache into the
@@ -448,8 +495,9 @@ def kv_row_write(k_cache, v_cache, new_k, new_v, layer, positions0,
     if kmesh is not None:
         heads, cache = kmesh.heads_spec(4), _stack_spec(kmesh)
         rows = kmesh.rows_spec(1)
+        v_spec = None if v_cache is None else cache
         fn = kmesh.shard(
-            fn, in_specs=(cache, cache, heads, heads, P(), rows, rows),
-            out_specs=(cache, cache))
+            fn, in_specs=(cache, v_spec, heads, heads, P(), rows, rows),
+            out_specs=(cache, v_spec))
     return fn(k_cache, v_cache, new_k, new_v, jnp.asarray(layer, jnp.int32),
               positions0, write_mask)

@@ -44,7 +44,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops.decode_attention import NEG_INF, decode_kv_block
+from ray_tpu.ops.decode_attention import (
+    NEG_INF,
+    decode_kv_block,
+    packed_kernel,
+    packed_rows,
+)
 from ray_tpu.ops.kernels import KernelMesh, kernel_backend
 
 # Rows of one tile of queries: G heads times a block of the chunk's tokens.
@@ -70,9 +75,15 @@ def prefill_attention_reference(q, k_cache, v_cache, layer, slot, kv_len,
     h, c, d = q.shape
     hkv, s = k_cache.shape[2], k_cache.shape[3]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    kl, vl = (lax.dynamic_slice(stack, (layer, slot, 0, 0, 0),
-                                (1, 1, hkv, s, d))[0, 0]
-              for stack in (k_cache, v_cache))
+
+    def line(stack):
+        return lax.dynamic_slice(stack, (layer, slot, 0, 0, 0),
+                                 (1, 1, hkv, s, stack.shape[-1]))[0, 0]
+
+    if v_cache is None:  # packed: keys, then values, in one row
+        kl, vl = line(k_cache)[..., :d], line(k_cache)[..., d:]
+    else:
+        kl, vl = line(k_cache), line(v_cache)
     qg = q.reshape(hkv, (h // hkv) * c, d)
     scores = jnp.einsum("hrd,hsd->hrs", qg, kl.astype(q.dtype),
                         preferred_element_type=jnp.float32) * scale
@@ -164,8 +175,10 @@ def _prefill_attention_pallas(q, k_cache, v_cache, layer, slot, kv_len,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    h, c, d = q.shape
-    hkv, s = k_cache.shape[2], k_cache.shape[3]
+    h, c, dq = q.shape
+    hkv, s, d = k_cache.shape[2:]
+    packed = v_cache is None
+    caches = (k_cache,) if packed else (k_cache, v_cache)
     group = h // hkv
     block_k = block_k or decode_kv_block(s, d, k_cache.dtype.itemsize)
     if s % block_k:
@@ -173,9 +186,10 @@ def _prefill_attention_pallas(q, k_cache, v_cache, layer, slot, kv_len,
                          f"divide the cache line of {s} positions")
     block_q = block_q or prefill_q_block(c, group, q.dtype.itemsize)
     c_pad = -(-c // block_q) * block_q
-    qg = q.reshape(hkv, group, c, d)
-    if c_pad != c:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, c_pad - c), (0, 0)))
+    qg = q.reshape(hkv, group, c, dq)
+    if c_pad != c or packed:
+        # A packed row's first lanes are its key: zeros meet its value.
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, c_pad - c), (0, d - dq)))
     # The chunk's own rows end at kv_len + c: a padded query sees no further.
     limit = jnp.clip(jnp.minimum(kv_len + c, length), 0, s)
     scalars = jnp.stack([jnp.asarray(layer, jnp.int32),
@@ -194,13 +208,14 @@ def _prefill_attention_pallas(q, k_cache, v_cache, layer, slot, kv_len,
     rows = group * block_q
     kv_spec = pl.BlockSpec((None, None, None, block_k, d), kv_index)
     q_spec = pl.BlockSpec((None, group, block_q, d), q_index)
+    kernel = functools.partial(_prefill_attention_kernel, block_q=block_q,
+                               block_k=block_k, sm_scale=sm_scale)
     out = pl.pallas_call(
-        functools.partial(_prefill_attention_kernel, block_q=block_q,
-                          block_k=block_k, sm_scale=sm_scale),
+        packed_kernel(kernel, 1) if packed else kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(hkv, c_pad // block_q, s // block_k),
-            in_specs=[q_spec, kv_spec, kv_spec],
+            in_specs=[q_spec] + [kv_spec] * len(caches),
             out_specs=q_spec,
             scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
                             pltpu.VMEM((rows, 1), jnp.float32),
@@ -213,8 +228,8 @@ def _prefill_attention_pallas(q, k_cache, v_cache, layer, slot, kv_len,
             vmem_limit_bytes=max(32 << 20, 12 * rows * block_k * 4)),
         interpret=kernel_backend() == "interpret",
         name="prefill_attention",
-    )(scalars, qg, k_cache, v_cache)
-    return out[:, :, :c].reshape(h, c, d)
+    )(scalars, qg, *caches)
+    return out[:, :, :c, d - dq:].reshape(h, c, dq)
 
 
 def prefill_attention(q, k_cache, v_cache, layer, slot, kv_len, length, *,
@@ -224,7 +239,8 @@ def prefill_attention(q, k_cache, v_cache, layer, slot, kv_len, length, *,
                       block_k: int | None = None):
     """q: [H, C, D], the chunk's queries at positions ``kv_len + arange(C)``
     (query head h of KV head ``h // (H // Hkv)``); k_cache, v_cache:
-    [L, B, Hkv, S, D], the chunk's rows already written; layer, slot, kv_len,
+    [L, B, Hkv, S, D], or the packed stack [L, B, Hkv, S, 2 D] and None
+    (ops/decode_attention.py), the chunk's rows already written; layer, slot, kv_len,
     length: int32 scalars. Returns [H, C, D]. ``block_q`` and ``block_k``
     override :func:`prefill_q_block` and ``decode_kv_block`` (tests and the
     kernel's own benchmark). Under a mesh of several devices pass its
@@ -239,8 +255,9 @@ def prefill_attention(q, k_cache, v_cache, layer, slot, kv_len, length, *,
         heads = P(kmesh.heads, None, None)
         # One slot's line: the slots reach every device whole.
         cache = P(None, None, kmesh.heads, None, None)
-        fn = kmesh.shard(fn, in_specs=(heads, cache, cache, P(), P(), P(),
-                                       P()),
+        fn = kmesh.shard(fn, in_specs=(heads, cache,
+                                       None if v_cache is None else cache,
+                                       P(), P(), P(), P()),
                          out_specs=heads)
     as_i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
     return fn(q, k_cache, v_cache, as_i32(layer), as_i32(slot),
@@ -250,7 +267,7 @@ def prefill_attention(q, k_cache, v_cache, layer, slot, kv_len, length, *,
 def prefill_kv_write(k_cache, v_cache, new_k, new_v, layer, slot, kv_len):
     """Write the chunk's rows into the stacked caches in place: new_k, new_v
     [Hkv, C, D] go to ``[layer, slot, :, kv_len : kv_len + C]``. Returns the
-    caches. The caller keeps ``kv_len + C`` within the line
+    caches ((stack, None) for a packed stack and ``v_cache=None``). The caller keeps ``kv_len + C`` within the line
     (dynamic_update_slice would clamp the start and overwrite earlier rows).
 
     A dynamic_update_slice and not a kernel like ``kv_row_write``: the update
@@ -261,4 +278,6 @@ def prefill_kv_write(k_cache, v_cache, new_k, new_v, layer, slot, kv_len):
         return lax.dynamic_update_slice(
             stack, new.astype(stack.dtype)[None, None],
             (layer, slot, 0, kv_len, 0))
+    if v_cache is None:
+        return put(k_cache, packed_rows(new_k, new_v)), None
     return put(k_cache, new_k), put(v_cache, new_v)

@@ -694,6 +694,18 @@ PARTS = (
 )
 
 
+# Finer names a model opens *inside* a part. The readers that partition a
+# trace by PARTS book such an operation to the part around it (the innermost
+# name they know); a reader that is given these names finds them on the same
+# path (benchmark/rtbench/readers/scope_share.py). The same rule on clashes.
+SUBPARTS = (
+    "conv",          # a gated short convolution inside ``attn`` (the
+                     # operator's place): projection in, gates, the taps,
+                     # projection out
+    "conv_state",    # reading and writing that convolution's state
+)
+
+
 def part(name: str):
     """A part of a jitted program: where :func:`phase` is the host's
     interval on the profiler's clock, this is the device's. It returns
@@ -701,10 +713,11 @@ def part(name: str):
     function: the name lands on the name stack of every operation traced
     under it (``tf_op`` in the device trace, the grouping in XProf) and
     costs nothing once the program is compiled. A name outside
-    :data:`PARTS` is refused here, at trace time, and not silently in a
-    reader."""
-    if name not in PARTS:
-        raise ValueError(f"tracing.part({name!r}): not one of {PARTS}")
+    :data:`PARTS` and :data:`SUBPARTS` is refused here, at trace time, and
+    not silently in a reader."""
+    if name not in PARTS and name not in SUBPARTS:
+        raise ValueError(f"tracing.part({name!r}): not one of "
+                         f"{PARTS + SUBPARTS}")
     import jax
 
     return jax.named_scope(name)

@@ -788,3 +788,96 @@ def test_prefill_attention_under_a_mesh_runs_on_each_shards_heads(
             q, kc, vc, 1, 1, 100, 137)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want), atol=3e-2)
+
+
+# ------------------------------------------------- packed stacks (heads of 64)
+
+def _packed_case(seed, layers, slots, hkv, s, d):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    kc = jax.random.normal(keys[0], (layers, slots, hkv, s, d), jnp.bfloat16)
+    vc = jax.random.normal(keys[1], (layers, slots, hkv, s, d), jnp.bfloat16)
+    return kc, vc, jnp.concatenate([kc, vc], axis=-1)
+
+
+@pytest.mark.parametrize("backend", ["interpret", "reference"])
+@pytest.mark.parametrize("k_tokens,group", [(1, 4), (5, 1)])
+def test_decode_attention_reads_a_packed_stack_as_keys_and_values(
+        k_tokens, group, backend):
+    """Keys and values of a head side by side in one row of 128 (a head of
+    64 alone fills half a lane row) give what the two stacks give."""
+    from ray_tpu.ops.decode_attention import decode_attention
+
+    hkv, s, d = 2, 384, 64
+    lengths = _decode_lengths("edges", s, k_tokens)
+    kc, vc, packed = _packed_case(11, 2, len(lengths), hkv, s, d)
+    q = jax.random.normal(jax.random.PRNGKey(12),
+                          (len(lengths), hkv * group, k_tokens, d),
+                          jnp.bfloat16)
+    pos0 = jnp.asarray(lengths - k_tokens)
+    want = _plain_decode_attention(q, kc, vc, 1, jnp.asarray(lengths), pos0)
+    with force_kernel_backend(backend):
+        got = decode_attention(q, packed, None, 1, jnp.asarray(lengths), pos0,
+                               block=DECODE_BLOCK)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), atol=3e-2)
+    assert not np.asarray(got, np.float32)[lengths == 0].any()
+
+
+@pytest.mark.parametrize("backend", ["interpret", "reference"])
+@pytest.mark.parametrize("k_tokens", [1, 5])
+def test_kv_row_write_packs_a_heads_key_and_value_into_one_row(k_tokens,
+                                                                backend):
+    from ray_tpu.ops.decode_attention import kv_row_write
+
+    hkv, s, d = 2, 64, 64
+    pos = np.array([0, 13, 15, 16, s - k_tokens, 30], np.int32)
+    mask = np.array([True, True, True, True, True, False])
+    _, _, packed = _packed_case(13, 2, len(pos), hkv, s, d)
+    keys = jax.random.split(jax.random.PRNGKey(14), 2)
+    nk = jax.random.normal(keys[0], (len(pos), hkv, k_tokens, d),
+                           jnp.bfloat16)
+    nv = jax.random.normal(keys[1], (len(pos), hkv, k_tokens, d),
+                           jnp.bfloat16)
+    want = np.array(packed)
+    for i in range(len(pos)):
+        if mask[i]:
+            want[1, i, :, pos[i]:pos[i] + k_tokens, :d] = np.asarray(nk[i])
+            want[1, i, :, pos[i]:pos[i] + k_tokens, d:] = np.asarray(nv[i])
+    with force_kernel_backend(backend):
+        got, none = jax.jit(kv_row_write)(
+            packed, None, nk, nv, 1, jnp.asarray(pos), jnp.asarray(mask))
+    assert none is None
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("backend", ["interpret", "reference"])
+@pytest.mark.parametrize("chunk,kv_len,pad", [(16, 0, 0), (40, 37, 5),
+                                              (512, 300, 0)])
+def test_prefill_attention_reads_a_packed_stack_as_keys_and_values(
+        chunk, kv_len, pad, backend):
+    from ray_tpu.ops.prefill_attention import (
+        prefill_attention,
+        prefill_kv_write,
+    )
+
+    hkv, group, s, d = 2, 4, 1024, 64
+    length = kv_len + chunk - pad
+    kc, vc, packed = _packed_case(15, 2, 3, hkv, s, d)
+    keys = jax.random.split(jax.random.PRNGKey(chunk), 3)
+    q = jax.random.normal(keys[0], (hkv * group, chunk, d), jnp.bfloat16)
+    nk = jax.random.normal(keys[1], (hkv, chunk, d), jnp.bfloat16)
+    nv = jax.random.normal(keys[2], (hkv, chunk, d), jnp.bfloat16)
+    kc, vc = prefill_kv_write(kc, vc, nk, nv, 1, 2, kv_len)
+    packed, none = jax.jit(prefill_kv_write)(packed, None, nk, nv, 1, 2,
+                                             kv_len)
+    assert none is None
+    np.testing.assert_array_equal(
+        np.asarray(packed), np.asarray(jnp.concatenate([kc, vc], axis=-1)))
+    want = _plain_prefill_attention(q, kc, vc, 1, 2, kv_len, length)
+    with force_kernel_backend(backend):
+        got = jax.jit(partial(prefill_attention, block_k=DECODE_BLOCK))(
+            q, packed, None, 1, 2, kv_len, length)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), atol=3e-2)

@@ -1,0 +1,122 @@
+"""The routed layer both families share (``models/routed.py``), under each
+family's rule, against a dense sum over all experts."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import routed
+from ray_tpu.models.lfm2 import Lfm2Config
+from ray_tpu.models.longcat import LongcatConfig
+from ray_tpu.ops.kernels import force_kernel_backend
+
+H, F, LAYERS, TOKENS = 32, 48, 2, 40
+
+RULES = {
+    # LongCat's: softmax over routed and zero experts, bias, no
+    # renormalisation, a factor; this shard holds experts 4 to 7 of 8.
+    "longcat": LongcatConfig.tiny(
+        hidden_size=H, expert_ffn_hidden_size=F, n_routed_experts=8,
+        zero_expert_num=4, moe_topk=3, routed_scaling_factor=2.5,
+        expert_shards=2, expert_shard=1).router_rule,
+    # LFM2's: sigmoid, bias, renormalised, factor 1, every expert held.
+    "lfm2": Lfm2Config.tiny(hidden_size=H, moe_intermediate_size=F,
+                            num_experts=8, num_experts_per_tok=3).router_rule,
+    "lfm2, a factor and no bias": Lfm2Config.tiny(
+        hidden_size=H, moe_intermediate_size=F, num_experts=8,
+        num_experts_per_tok=3, use_expert_bias=False,
+        routed_scaling_factor=1.5).router_rule,
+}
+
+
+def _layers(rule, key):
+    keys = jax.random.split(key, 5)
+    normal = lambda k, *shape: jax.random.normal(k, shape, jnp.float32)  # noqa: E731
+    return {"router": normal(keys[0], LAYERS, H, rule.outputs) / np.sqrt(H),
+            "router_bias": 0.2 * normal(keys[1], LAYERS, rule.outputs),
+            "we_gate": normal(keys[2], LAYERS, rule.held, H, F) / np.sqrt(H),
+            "we_up": normal(keys[3], LAYERS, rule.held, H, F) / np.sqrt(H),
+            "we_down": normal(keys[4], LAYERS, rule.held, F, H) / np.sqrt(F)}
+
+
+def _dense(rule, layers, layer, u, valid):
+    """Every held expert on every token, weighted by the rule's own weight
+    where the token chose it and zero elsewhere; a zero expert is the
+    identity. numpy float64 from the router's scores on."""
+    logits = np.asarray(u, np.float64) @ np.asarray(layers["router"][layer],
+                                                    np.float64)
+    if rule.score == "softmax":
+        e = np.exp(logits - logits.max(-1, keepdims=True))
+        s = e / e.sum(-1, keepdims=True)
+    else:
+        s = 1.0 / (1.0 + np.exp(-logits))
+    by = s + np.asarray(layers["router_bias"][layer]) if rule.use_bias else s
+    idx = np.argsort(-by, axis=-1, kind="stable")[:, :rule.topk]
+    w = np.take_along_axis(s, idx, axis=-1)
+    if rule.renormalize:
+        w = w / (w.sum(-1, keepdims=True) + 1e-6)
+    weights = np.zeros_like(s)
+    np.put_along_axis(weights, idx, w * rule.scaling_factor, axis=-1)
+    weights *= np.asarray(valid)[:, None]
+    x = np.asarray(u, np.float64)
+    out = weights[:, rule.experts:].sum(-1, keepdims=True) * x
+    lo = rule.expert_shard * rule.held
+    for e in range(rule.held):
+        gate = x @ np.asarray(layers["we_gate"][layer, e], np.float64)
+        up = x @ np.asarray(layers["we_up"][layer, e], np.float64)
+        y = (gate / (1.0 + np.exp(-gate)) * up) @ np.asarray(
+            layers["we_down"][layer, e], np.float64)
+        out += weights[:, lo + e][:, None] * y
+    local = ((idx >= lo) & (idx < lo + rule.held)
+             & np.asarray(valid)[:, None])
+    zero = (idx >= rule.experts) & np.asarray(valid)[:, None]
+    touched = len({int(i) for i in idx[local]})
+    return out, [int(np.asarray(valid).sum()) * rule.topk, int(local.sum()),
+                 int(zero.sum()), touched, 1]
+
+
+@pytest.mark.parametrize("backend", ["reference", "interpret"])
+@pytest.mark.parametrize("name", list(RULES))
+def test_the_shared_layer_is_the_dense_sum_under_each_family_s_rule(name,
+                                                                   backend):
+    rule = RULES[name]
+    layers = _layers(rule, jax.random.PRNGKey(len(name)))
+    u = jax.random.normal(jax.random.PRNGKey(9), (TOKENS, H), jnp.float32)
+    valid = jnp.arange(TOKENS) % 7 != 3          # padding is routed nowhere
+    want, want_counts = _dense(rule, layers, 1, u, valid)
+    with force_kernel_backend(backend):
+        got, counts = jax.jit(routed.moe_block, static_argnums=0)(
+            rule, layers, 1, u, valid)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+    assert [int(c) for c in counts] == want_counts
+    assert not np.asarray(got)[~np.asarray(valid)].any()
+    if name == "longcat":
+        assert 0 < want_counts[2] and want_counts[1] < want_counts[0]
+    else:
+        assert want_counts[2] == 0 and want_counts[1] == want_counts[0]
+
+
+def test_a_rule_refuses_what_it_cannot_be():
+    with pytest.raises(ValueError, match="softmax or sigmoid"):
+        routed.RouterRule(experts=8, topk=2, score="relu")
+    with pytest.raises(ValueError, match="do not divide"):
+        routed.RouterRule(experts=8, topk=2, expert_shards=3)
+    with pytest.raises(ValueError, match="outside"):
+        routed.RouterRule(experts=8, topk=2, expert_shards=2, expert_shard=2)
+    rule = routed.RouterRule(experts=8, topk=2, zero_experts=4,
+                             expert_shards=2)
+    assert (rule.held, rule.outputs) == (4, 12)
+
+
+def test_longcat_s_names_for_the_shared_layer_are_the_shared_layer_s():
+    from ray_tpu.models import longcat
+
+    assert longcat.dispatch_plan is routed.dispatch_plan
+    assert longcat.moe_block is routed.moe_block
+    assert longcat.MOE_COUNTERS is routed.MOE_COUNTERS
+    assert longcat.MOE_TILE == routed.MOE_TILE == 16
+    rule = longcat.LongcatConfig().router_rule
+    assert (rule.score, rule.use_bias, rule.renormalize, rule.scaling_factor,
+            rule.zero_experts, rule.outputs, rule.topk) == \
+        ("softmax", True, False, 6.0, 256, 768, 12)

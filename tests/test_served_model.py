@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
-from ray_tpu.llm import engine, longcat_serving, ouro_serving
+from ray_tpu.llm import engine, lfm2_serving, longcat_serving, ouro_serving
+from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
 from ray_tpu.models.ouro import OuroConfig
@@ -36,8 +37,12 @@ def _ouro():
     return ouro_serving, OuroConfig.tiny(max_seq_len=MAX_SEQ)
 
 
-MODELS = dict(argvalues=[_llama, _longcat, _ouro],
-              ids=["llama", "longcat", "ouro"])
+def _lfm2():
+    return lfm2_serving, Lfm2Config.tiny(max_seq_len=MAX_SEQ)
+
+
+MODELS = dict(argvalues=[_llama, _longcat, _ouro, _lfm2],
+              ids=["llama", "longcat", "ouro", "lfm2"])
 
 # The Llama single step is jit(_decode_step_impl), so the benchmark's
 # readers of ``jit_decode_step`` would miss it; only a request with top_k
@@ -72,6 +77,11 @@ def test_a_program_keeps_its_name_and_gives_the_donated_cache_back(model,
                                                                     program):
     module, cfg = model()
     served = engine.served_model(cfg)
+    if getattr(served, program) is None:
+        # Only a model whose prefix cannot be adopted from a line may lack
+        # the program that copies one.
+        assert program == "copy_prefix_kv" and not served.prefix_from_line
+        return
     params = served.init_params(cfg, jax.random.PRNGKey(0))
     cache = served.init_cache(cfg, SLOTS, MAX_SEQ)
     went_in = jax.tree.map(lambda a: (a.shape, a.dtype), cache)
@@ -111,8 +121,8 @@ def _cumsums(jaxpr, in_loop=False):
     return outside, inside
 
 
-@pytest.mark.parametrize("model", argvalues=[_llama, _ouro],
-                         ids=["llama", "ouro"])
+@pytest.mark.parametrize("model", argvalues=[_llama, _ouro, _lfm2],
+                         ids=["llama", "ouro", "lfm2"])
 def test_a_decode_step_plans_its_walk_once_before_the_layer_loop(model):
     """``decode_attention`` walks the live blocks of every line by a plan
     that depends on the lengths alone, so the step builds it once
@@ -126,11 +136,19 @@ def test_a_decode_step_plans_its_walk_once_before_the_layer_loop(model):
         cfg, jax.random.PRNGKey(0)))
     cache = jax.eval_shape(lambda: served.init_cache(cfg, SLOTS, MAX_SEQ))
     tokens, positions, write = _arguments("decode_step", None)[1:]
+    if module is lfm2_serving:   # one token a slot, and a router's plans
+        def step(p, c):
+            return module._decode_impl(cfg, p, c, tokens, positions, write)
+    else:
+        def step(p, c):
+            return module._multi_token_impl(cfg, p, c, tokens[:, None],
+                                            positions, write)
     with force_kernel_backend("interpret"):
-        jaxpr = jax.make_jaxpr(
-            lambda p, c: module._multi_token_impl(
-                cfg, p, c, tokens[:, None], positions, write))(params, cache)
-    assert _cumsums(jaxpr.jaxpr) == (1, 0)
+        jaxpr = jax.make_jaxpr(step)(params, cache)
+    outside, inside = _cumsums(jaxpr.jaxpr)
+    # the routed layer's dispatch plan sums too, inside the layer loop: its
+    # picks differ a layer; the walk of the cache is the one outside
+    assert outside == 1 and (inside == 0 or module is lfm2_serving)
 
 
 def test_the_engine_has_one_kv_layout_and_refuses_the_block_pool():
@@ -199,5 +217,9 @@ def test_the_look_ahead_schedule_gives_the_serial_schedules_tokens(model):
     assert ahead["decode_tokens"] == serial["decode_tokens"] == \
         sum(len(toks) - 1 for toks, _ in outs[0])
     served = engine.served_model(cfg)
+    rule = getattr(cfg, "router_rule", None)
     for name in served.counters:   # counted for valid tokens only
-        assert ahead[name] > 0
+        if name == "moe_picks_zero" and not rule.zero_experts:
+            assert ahead[name] == 0    # a router with no zero expert
+        else:
+            assert ahead[name] > 0

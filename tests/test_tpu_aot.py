@@ -547,3 +547,73 @@ def test_ouro_programs_copy_no_weight_stack_and_fit_the_chip(mosaic,
     assert mem.temp_size_in_bytes < 1 << 28
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30 \
         < 15.5
+
+
+# The first 10 layers of LFM2-24B-A2B with all 64 experts at the shapes of
+# its serving cell (64 slots x 8,192): the programs of llm/lfm2_serving.py
+# as the cell compiles them.
+@pytest.mark.parametrize("program", ["prefill_chunk(512)", "decode_burst(8)"])
+def test_lfm2_programs_move_no_cache_nor_expert_stack_and_fit_the_chip(
+        mosaic, program):
+    """Two kinds of cache leaf ride every loop as carry: the packed
+    attention lines (a head of 64 beside its value in one row of 128, so a
+    cached position costs its 4 KiB and not 8) and the convolutions' state.
+    Neither, nor a line or a slot of them, nor the 9 GiB of stacked experts
+    or a layer of them, is the result of anything but a parameter, a loop's
+    tuple, a kernel's in-place operand or an update in place; arguments and
+    temporaries fit the chip's 15.75 GiB. What XLA does copy whole, and
+    this allows: one of the two small dense stacks (the leading layers'
+    SwiGLU, bf16[2,11776,2048], 96 MB) into fast memory at the top of their
+    loop's body, which costs a tenth of a millisecond a step (PERF.md)."""
+    from devbench import lfm2_bench as bench
+
+    cfg = bench.config()
+    assert (cfg.attention_lines, cfg.conv_lines, cfg.experts_held) == \
+        (2, 8, 64)
+    dev = NamedSharding(build_mesh(MeshSpec(), mosaic[:1]), P())
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=dev), tree)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    params, cache = bench.shapes(cfg, placed)
+    kv = cache["kv"]
+    assert kv.shape == (2, bench.SLOTS, 8, bench.MAX_SEQ, 128)
+    assert kv.size * kv.dtype.itemsize // (bench.SLOTS * bench.MAX_SEQ) \
+        == 4096
+    compiled = bench.lowerings(cfg, params, cache, arg)[program]().compile()
+    text = compiled.as_text()
+    carried = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+    if program.startswith("prefill"):
+        kernels = ("prefill_attention", "moe_grouped_matmul")
+        # written in place; the attention kernel's line names its operand
+        in_place = {"dynamic-update-slice", "custom-call"}
+    else:
+        kernels = ("decode_attention", "kv_row_write", "moe_grouped_matmul")
+        in_place = {"custom-call"}
+        assert _plans_outside_the_layer_loop(text)
+    for name in kernels:
+        assert f'"{name}"' in text or f"%{name}." in text, name
+    big = bench.big_shapes(cfg)
+    assert _opcodes_with_shape(text, big["kv"]) <= carried | in_place
+    # the state: read a line (a fusion's parameter), written in place
+    assert _opcodes_with_shape(text, big["conv"]) <= \
+        carried | {"dynamic-update-slice", "fusion"}
+    for shape in ("kv_line", "kv_slot", "experts_layer_up",
+                  "experts_layer_down", "embed_f32"):
+        got = _opcodes_with_shape(text, big[shape])
+        # inside a fusion the float32 embedding is a convert that is never
+        # stored (the temporaries below hold the program to that)
+        assert got <= ({"convert", "broadcast", "multiply"}
+                       if shape == "embed_f32" else set()), (shape, got)
+    for shape in ("experts_up", "experts_down", "conv_in", "conv_out",
+                  "embed"):
+        assert _opcodes_with_shape(text, big[shape]) <= \
+            carried | {"fusion", "custom-call", "dynamic-slice"}, shape
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 28
+    assert 11.5 < (mem.argument_size_in_bytes + mem.temp_size_in_bytes) \
+        / 2 ** 30 < 12.5

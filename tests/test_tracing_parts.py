@@ -24,8 +24,8 @@ from ray_tpu.models.ouro import OuroConfig
 from ray_tpu.util import tracing
 # The tiny served models and the programs' arguments, as the engine's own
 # contract test builds them.
-from test_served_model import (MAX_SEQ, SLOTS, _arguments, _llama, _longcat,
-                               _ouro)
+from test_served_model import (MAX_SEQ, SLOTS, _arguments, _lfm2, _llama,
+                               _longcat, _ouro)
 
 # What JAX itself puts on a name stack besides primitives' names.
 WRAPPERS = {"transpose", "jvp", "vmap", "pmap", "jit", "pjit", "while",
@@ -40,6 +40,18 @@ def test_part_refuses_a_name_outside_the_vocabulary():
         tracing.part("atn")
     with pytest.raises(ValueError):
         tracing.part("longcat.mla")
+
+
+def test_the_finer_names_are_a_vocabulary_of_their_own():
+    """``SUBPARTS`` are opened inside a part and never stand for one: the
+    benchmark's partition (``xplane_meta.PARTS == tracing.PARTS``) does not
+    know them and books their operations to the part around them."""
+    assert tracing.SUBPARTS == ("conv", "conv_state")
+    assert not set(tracing.SUBPARTS) & set(tracing.PARTS)
+    assert all(re.fullmatch(r"[a-z_]+", p) for p in tracing.SUBPARTS)
+    for name in tracing.SUBPARTS:
+        with tracing.part(name):
+            pass
 
 
 def test_part_is_a_named_scope_and_nothing_else():
@@ -61,6 +73,7 @@ def test_no_part_is_a_jax_primitive_or_wrapper():
         if isinstance(v, jax.extend.core.Primitive)}
     assert len(primitives) > 100 and "dot_general" in primitives
     assert not set(tracing.PARTS) & (primitives | WRAPPERS)
+    assert not set(tracing.SUBPARTS) & (primitives | WRAPPERS)
     assert len(set(tracing.PARTS)) == len(tracing.PARTS)
     assert all(re.fullmatch(r"[a-z_]+", p) for p in tracing.PARTS)
 
@@ -86,6 +99,7 @@ SERVED = {
     "llama": (_llama, DENSE),
     "longcat": (_longcat, DENSE | ROUTED),
     "ouro": (_ouro, DENSE | {"loop"}),
+    "lfm2": (_lfm2, DENSE | ROUTED),
 }
 LONGCAT_GONE = ("longcat.mla", "longcat.moe", "longcat.moe.experts")
 
@@ -110,6 +124,12 @@ def test_a_serving_program_opens_its_parts(model, program):
     assert not found & {"optim", "loss"}
     for gone in LONGCAT_GONE:
         assert gone not in text
+    if model == "lfm2":
+        # The convolution's finer names lie inside ``attn``, the operator's
+        # place, on the path of its operations.
+        assert re.search(r"attn/conv/dot_general", text)
+        assert re.search(r"attn/conv_state/", text)
+        assert not re.search(r"[^/\w](conv|conv_state)/", text)
 
 
 def _train_step(name):
