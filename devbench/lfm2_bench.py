@@ -4,6 +4,7 @@ compiled for a described v5e with no chip, and timed on one.
 
     python3 devbench/lfm2_bench.py aot            # no chip, about a minute
     chiprun -- python3 devbench/lfm2_bench.py step parity
+    chiprun -- python3 devbench/lfm2_bench.py tile       # about 3 minutes
 
 ``aot``: ``llm/lfm2_serving.py``'s ``prefill_chunk`` at the buckets 16 and
 512 and ``decode_burst(8)``, compiled for ``v5e:2x2``'s first device
@@ -19,7 +20,14 @@ milliseconds of one decode step inside a burst of 8 at 64 lines of 1,024,
 ``parity``: the programs against ``models/lfm2.forward`` in bfloat16 and
 both against the float32 reference over 1,024 tokens (prefill in chunks of
 512, then 8 decode steps), as the reference's top logit minus its logit of
-the program's top token. One JSON object a mode.
+the program's top token. ``tile``: the fit behind ``models/routed.row_tile``.
+Every row tile of ``routed.ROW_TILES`` put in the rule's place, at a prefill
+chunk of 512 tokens and at a decode step of 64 lines: one routed layer
+(``moe_block`` whole, and its two ``grouped_matmul`` calls alone on the same
+plan) with tiles a call, experts touched and the share of the touched
+experts' bytes at 819 GB/s in the two calls' time; then the whole
+``prefill_chunk(512)`` and a step of ``decode_burst(8)``. One JSON object a
+mode.
 """
 
 from __future__ import annotations
@@ -180,38 +188,56 @@ def _programs():
     return cfg, params, serving, serving.init_cache(cfg, SLOTS, MAX_SEQ)
 
 
-def step() -> dict:
+def _time_chunk(serving, cfg, params, cache, kv_len: int = 0):
+    """(cache, ms, counts) of ``prefill_chunk(512)`` against ``kv_len``
+    cached rows: the best of three calls after a first that may compile."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    i32, times = jnp.int32, []
+    for _ in range(4):
+        t0 = time.monotonic()
+        cache, logits, counts = serving.prefill_chunk(
+            cfg, params, cache, jnp.arange(512, dtype=i32) + 300,
+            i32(kv_len), i32(kv_len + 512), i32(0))
+        np.asarray(logits[:1])
+        times.append((time.monotonic() - t0) * 1e3)
+    return cache, round(min(times[1:]), 2), counts
+
+
+def _time_step(serving, cfg, params, cache, live: int):
+    """(cache, ms a step, counts) of ``decode_burst(8)`` at every line
+    ``live`` long, timed as ``_time_chunk`` does."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    i32, times = jnp.int32, []
+    temps = jnp.zeros((SLOTS,), jnp.float32)
+    for _ in range(4):
+        t0 = time.monotonic()
+        cache, toks, counts = serving.decode_burst(
+            cfg, params, cache, jnp.arange(SLOTS, dtype=i32) + 300,
+            jnp.full((SLOTS,), live, i32), jnp.ones((SLOTS,), bool),
+            temps, temps + 1.0, jax.random.PRNGKey(1), 8, False)
+        np.asarray(toks)
+        times.append((time.monotonic() - t0) * 1e3 / 8)
+    return cache, round(min(times[1:]), 2), counts
+
+
+def step() -> dict:
+    import jax
+
     cfg, params, serving, cache = _programs()
-    i32 = jnp.int32
     out = {"mode": "step", "device": jax.devices()[0].device_kind,
            "decode_ms_per_step": {}, "prefill_chunk_ms": {}}
     for kv_len in (0, 4096):
-        times = []
-        for _ in range(4):
-            t0 = time.monotonic()
-            cache, logits, counts = serving.prefill_chunk(
-                cfg, params, cache, jnp.arange(512, dtype=i32) + 300,
-                i32(kv_len), i32(kv_len + 512), i32(0))
-            np.asarray(logits[:1])
-            times.append((time.monotonic() - t0) * 1e3)
-        out["prefill_chunk_ms"][kv_len] = round(min(times[1:]), 2)
+        cache, out["prefill_chunk_ms"][kv_len], counts = _time_chunk(
+            serving, cfg, params, cache, kv_len)
         out["prefill_counts"] = [int(n) for n in counts]
-    temps = jnp.zeros((SLOTS,), jnp.float32)
     for live in (1024, 3072, 6144):
-        times = []
-        for _ in range(4):
-            t0 = time.monotonic()
-            cache, toks, counts = serving.decode_burst(
-                cfg, params, cache, jnp.arange(SLOTS, dtype=i32) + 300,
-                jnp.full((SLOTS,), live, i32), jnp.ones((SLOTS,), bool),
-                temps, temps + 1.0, jax.random.PRNGKey(1), 8, False)
-            np.asarray(toks)
-            times.append((time.monotonic() - t0) * 1e3 / 8)
-        out["decode_ms_per_step"][live] = round(min(times[1:]), 2)
+        cache, out["decode_ms_per_step"][live], counts = _time_step(
+            serving, cfg, params, cache, live)
         out["decode_counts"] = [int(n) for n in counts]
     return out
 
@@ -265,7 +291,91 @@ def parity(tokens: int = 1024, seed: int = 7) -> dict:
     return out
 
 
-MODES = {"aot": aot, "step": step, "parity": parity}
+def _ms_a_call(fn, calls: int = 20) -> float:
+    """Wall milliseconds a call of ``calls`` dispatched back to back (the
+    device runs them in order; the clock stops when the last is done)."""
+    import jax
+
+    jax.block_until_ready(fn())
+    t0 = time.monotonic()
+    for _ in range(calls - 1):
+        fn()
+    jax.block_until_ready(fn())
+    return (time.monotonic() - t0) * 1e3 / calls
+
+
+def tile() -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import routed
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+    cfg, params, serving, cache = _programs()
+    rule, layers = cfg.router_rule, params["layers"]
+    expert_bytes = 3 * cfg.hidden_size * cfg.moe_intermediate_size * 2
+    i32 = jnp.int32
+    shapes_ = {"chunk(512)": 512, f"decode({SLOTS})": SLOTS}
+    out = {"mode": "tile", "device": jax.devices()[0].device_kind,
+           "rule_picks": {name: routed.row_tile(t, rule.topk, rule.outputs)
+                          for name, t in shapes_.items()},
+           "layer": {name: {} for name in shapes_}, "prefill_chunk_ms": {},
+           "decode_ms_per_step": {}}
+    rule_s_own = routed.row_tile
+    try:
+        for tm in routed.ROW_TILES:
+            routed.row_tile = lambda *_, tm=tm: tm
+            jax.clear_caches()
+            for name, t in shapes_.items():
+                u = jax.random.normal(jax.random.PRNGKey(t),
+                                      (t, cfg.hidden_size), jnp.bfloat16)
+                valid = jnp.ones((t,), bool)
+                block = jax.jit(partial(routed.moe_block, rule))
+
+                @jax.jit
+                def plan(layers, u):
+                    idx, _ = routed.route(
+                        rule, layers["router"][1], layers["router_bias"][1],
+                        u)
+                    pick_of_row, _, tile_expert, n_live, sizes = \
+                        routed.dispatch_plan(idx.reshape(-1).astype(i32),
+                                             rule.held, tm)
+                    return (u[jnp.maximum(pick_of_row, 0) // rule.topk],
+                            tile_expert, n_live, (sizes > 0).sum())
+
+                @jax.jit
+                def kernels(layers, x_rows, tile_expert, n_live):
+                    hidden = grouped_matmul(
+                        x_rows, layers["we_gate"], 1, tile_expert, n_live,
+                        tm=tm, w2=layers["we_up"])
+                    return grouped_matmul(hidden, layers["we_down"], 1,
+                                          tile_expert, n_live, tm=tm)
+
+                x_rows, tile_expert, n_live, touched = plan(layers, u)
+                kernels_ms = _ms_a_call(
+                    lambda: kernels(layers, x_rows, tile_expert, n_live))
+                layer_ms = _ms_a_call(
+                    lambda: block(layers, i32(1), u, valid))
+                floor_ms = int(touched) * expert_bytes / 819e9 * 1e3
+                out["layer"][name][tm] = {
+                    "moe_block_ms": round(layer_ms, 3),
+                    "kernels_ms": round(kernels_ms, 3),
+                    "tiles": int(n_live), "experts_touched": int(touched),
+                    "rows_held": int(x_rows.shape[0]),
+                    "bytes_share_pct": round(100 * floor_ms / kernels_ms, 1)}
+            for key, timed in (
+                    ("prefill_chunk_ms", _time_chunk),
+                    ("decode_ms_per_step", partial(_time_step, live=3072))):
+                cache, ms, counts = timed(serving, cfg, params, cache)
+                out[key][tm] = {"ms": ms, "tiles_per_expert": round(
+                    int(counts[5]) / int(counts[3]), 3)}
+    finally:
+        routed.row_tile = rule_s_own
+        jax.clear_caches()
+    return out
+
+
+MODES = {"aot": aot, "step": step, "parity": parity, "tile": tile}
 
 if __name__ == "__main__":
     for mode in sys.argv[1:] or ["aot"]:

@@ -28,7 +28,7 @@ a line:
 
 The programs keep the engine's names (``prefill_chunk``, ``decode_step``,
 ``decode_burst``: a device trace shows ``jit_<name>``) and signatures, and
-return the routed layers' counts (models/routed.MOE_COUNTERS, int32[5],
+return the routed layers' counts (models/routed.MOE_COUNTERS, int32[6],
 summed over the program's layers and steps) beside their result; the
 scheduler adds them up where it fetches the tokens.
 """

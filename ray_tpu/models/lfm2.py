@@ -428,7 +428,7 @@ def run_layers(cfg: Lfm2Config, params, x, operators: dict, state, valid,
                kmesh=None):
     """Every layer over x [B, S, H], ``state`` as carry of every loop: one
     scan a segment, over the repeats of its period. Returns (x, state,
-    counts int32[5] summed over the routed layers)."""
+    counts int32[6] summed over the routed layers)."""
     counts = jnp.zeros((len(MOE_COUNTERS),), jnp.int32)
     for seg in cfg.segments:
         def body(carry, repeat, seg=seg):
@@ -459,7 +459,7 @@ def lm_head(cfg: Lfm2Config, params, x, kmesh=None):
 
 def forward(cfg: Lfm2Config, params: dict, tokens, *,
             kmesh: KernelMesh | None = None):
-    """tokens [B, S] -> (float32 logits [B, S, V], router counts int32[5]).
+    """tokens [B, S] -> (float32 logits [B, S, V], router counts int32[6]).
     Whole sequences, no cache and no state: the convolution starts from
     zeros, the attention is causal over the sequence."""
     b, s = tokens.shape

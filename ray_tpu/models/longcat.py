@@ -394,7 +394,7 @@ def lm_head(cfg: LongcatConfig, params, x, kmesh=None):
 
 def forward(cfg: LongcatConfig, params: dict, tokens, *,
             kmesh: KernelMesh | None = None):
-    """tokens [B, S] -> (float32 logits [B, S, V], router counts int32[5]).
+    """tokens [B, S] -> (float32 logits [B, S, V], router counts int32[6]).
     Whole sequences, no cache: the shape of a training forward pass and of
     the parity tests."""
     with tracing.part("embed"):

@@ -12,8 +12,10 @@ the layer's input), and which of the routed experts this program holds
 
 What is shared is everything after the rule: the layer routes over all the
 router's outputs, keeps every pick that falls on a held expert (no capacity,
-no drop), sorts the picks by expert into tiles of ``MOE_TILE`` rows
-(:func:`dispatch_plan`), multiplies the live tiles only
+no drop), sorts the picks by expert into tiles of rows
+(:func:`dispatch_plan`; the kernel fetches an expert's weights once a tile,
+so the tile follows the rows a call's shapes promise an expert:
+:func:`row_tile`, ``MOE_TILE`` at the least), multiplies the live tiles only
 (ops/grouped_matmul.py) and computes the part of ``sum_e w_e E_e(u)`` that
 its own experts give, plus the zero experts' part, which every shard
 computes for its own tokens. What the absent shards' experts would add is
@@ -31,11 +33,13 @@ from jax import lax
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.util import tracing
 
-# Rows of one tile of the grouped matmul: a packed sublane tile of bfloat16.
+# The fewest rows of one tile of the grouped matmul: a packed sublane tile of
+# bfloat16. row_tile picks among these.
 MOE_TILE = 16
+ROW_TILES = (MOE_TILE, 32, 64, 128)
 # What moe_block counts, in this order (llm/engine.py adds them up).
 MOE_COUNTERS = ("moe_picks", "moe_picks_local", "moe_picks_zero",
-                "moe_experts_touched", "moe_layer_steps")
+                "moe_experts_touched", "moe_layer_steps", "moe_tiles")
 
 
 @dataclass(frozen=True)
@@ -71,6 +75,18 @@ class RouterRule:
     @property
     def outputs(self) -> int:
         return self.experts + self.zero_experts
+
+
+def row_tile(tokens: int, topk: int, outputs: int) -> int:
+    """Rows of a tile for a call on ``tokens`` tokens: the smallest of
+    ``ROW_TILES`` that holds twice the rows an expert gets on average,
+    ``tokens * topk / outputs``, so that an expert fuller than the mean is
+    still one tile and its weights one fetch; the largest where none does.
+    All three are static: a program's shape and its rule's integers."""
+    for tm in ROW_TILES:
+        if tm * outputs >= 2 * tokens * topk:
+            return tm
+    return ROW_TILES[-1]
 
 
 def layer_of(stack, index):
@@ -140,9 +156,10 @@ def moe_block(rule: RouterRule, layers: dict, layer, u, valid):
     expert stacks are read in place), ``layer`` is the routed layer's index
     on their leading axis. A token with ``valid`` false
     (padding, an idle slot) is routed nowhere and counted nowhere. Returns
-    (y [T, H], counts int32[5] in the order of MOE_COUNTERS)."""
+    (y [T, H], counts int32[6] in the order of MOE_COUNTERS)."""
     t, _ = u.shape
     held, topk = rule.held, rule.topk
+    tm = row_tile(t, topk, rule.outputs)
     with tracing.part("moe_route"):
         idx, w = route(rule, layer_of(layers["router"], layer),
                        layer_of(layers["router_bias"], layer), u)
@@ -152,15 +169,15 @@ def moe_block(rule: RouterRule, layers: dict, layer, u, valid):
         zero = chosen & (idx >= rule.experts)
         keys = jnp.where(local, idx - lo, held).reshape(-1).astype(jnp.int32)
         pick_of_row, row_of_pick, tile_expert, n_live, sizes = dispatch_plan(
-            keys, held, MOE_TILE)
+            keys, held, tm)
     with tracing.part("moe_dispatch"):
         x_rows = jnp.where((pick_of_row >= 0)[:, None],
                            u[jnp.maximum(pick_of_row, 0) // topk], 0)
     with tracing.part("moe_experts"):
         hidden = grouped_matmul(x_rows, layers["we_gate"], layer, tile_expert,
-                                n_live, tm=MOE_TILE, w2=layers["we_up"])
+                                n_live, tm=tm, w2=layers["we_up"])
         out_rows = grouped_matmul(hidden, layers["we_down"], layer,
-                                  tile_expert, n_live, tm=MOE_TILE)
+                                  tile_expert, n_live, tm=tm)
     with tracing.part("moe_combine"):
         # A select, not a product: rows of dead tiles were never written.
         picked = out_rows[jnp.where(local, row_of_pick.reshape(t, topk), 0)]
@@ -172,5 +189,5 @@ def moe_block(rule: RouterRule, layers: dict, layer, u, valid):
                          keepdims=True) * u.astype(jnp.float32)
         counts = jnp.stack([
             valid.sum() * topk, local.sum(), zero.sum(), (sizes > 0).sum(),
-            jnp.ones((), jnp.int32)]).astype(jnp.int32)
+            jnp.ones((), jnp.int32), n_live]).astype(jnp.int32)
         return y.astype(u.dtype), counts

@@ -105,7 +105,8 @@ def test_forward_matches_the_reference(params, tokens, want):
     assert [int(c) for c in counts] == [
         n * CFG.num_experts_per_tok * CFG.num_routed_layers,
         n * CFG.num_experts_per_tok * CFG.num_routed_layers, 0,
-        int(counts[3]), CFG.num_routed_layers]
+        int(counts[3]), CFG.num_routed_layers, int(counts[5])]
+    assert int(counts[5]) >= int(counts[3]) > 0    # a tile a touched expert
 
 
 @pytest.mark.parametrize("leaf,index", [

@@ -79,11 +79,13 @@ def test_forward_matches_the_reference(case):
         cfg, params, tokens[None])
     np.testing.assert_allclose(np.asarray(got[0]), want, atol=LOGIT_ATOL,
                                rtol=0)
-    picks, local, zero, touched, layer_steps = (int(c) for c in counts)
+    picks, local, zero, touched, layer_steps, tiles = (
+        int(c) for c in counts)
     assert picks == 40 * cfg.moe_topk * cfg.num_layers
     assert layer_steps == cfg.num_layers
     assert 0 < local < picks and 0 < zero < picks
     assert 0 < touched <= cfg.experts_held * cfg.num_layers
+    assert touched <= tiles <= touched + local // 16
 
 
 def test_the_reference_sees_a_wrong_mask(case):

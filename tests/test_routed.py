@@ -9,6 +9,7 @@ import pytest
 from ray_tpu.models import routed
 from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.longcat import LongcatConfig
+from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.kernels import force_kernel_backend
 
 H, F, LAYERS, TOKENS = 32, 48, 2, 40
@@ -71,9 +72,11 @@ def _dense(rule, layers, layer, u, valid):
     local = ((idx >= lo) & (idx < lo + rule.held)
              & np.asarray(valid)[:, None])
     zero = (idx >= rule.experts) & np.asarray(valid)[:, None]
-    touched = len({int(i) for i in idx[local]})
+    sizes = np.bincount(idx[local] - lo, minlength=rule.held)
+    tm = routed.row_tile(len(x), rule.topk, rule.outputs)
     return out, [int(np.asarray(valid).sum()) * rule.topk, int(local.sum()),
-                 int(zero.sum()), touched, 1]
+                 int(zero.sum()), int((sizes > 0).sum()), 1,
+                 int((-(-sizes // tm)).sum())]
 
 
 @pytest.mark.parametrize("backend", ["reference", "interpret"])
@@ -95,6 +98,101 @@ def test_the_shared_layer_is_the_dense_sum_under_each_family_s_rule(name,
         assert 0 < want_counts[2] and want_counts[1] < want_counts[0]
     else:
         assert want_counts[2] == 0 and want_counts[1] == want_counts[0]
+
+
+@pytest.mark.parametrize("tokens,topk,outputs,want", [
+    (512, 4, 64, 64),      # LFM2's prefill chunk: 32 rows an expert
+    (64, 4, 64, 16),       # its decode step of 64 lines: 4
+    (16, 4, 64, 16),       # its smallest bucket: 1
+    (512, 12, 768, 16),    # LongCat's chunk: 8
+    (32, 12, 768, 16),     # its decode step: 0.5
+    (128, 4, 64, 16), (129, 4, 64, 32), (256, 4, 64, 32), (257, 4, 64, 64),
+    (1024, 4, 64, 128), (8192, 8, 64, 128),    # the largest where none holds
+])
+def test_the_row_tile_holds_twice_the_mean_fill(tokens, topk, outputs, want):
+    assert routed.row_tile(tokens, topk, outputs) == want
+    assert want in routed.ROW_TILES and routed.ROW_TILES[0] == routed.MOE_TILE
+
+
+# Group sizes of 5 held experts at tile tm, and picks that are not here.
+FILLS = {"none, a tile, a tile and a row, a few":
+         lambda tm: ([0, tm, tm + 1, 3, 0], 7),
+         "every pick on one expert": lambda tm: ([0, 0, 3 * tm + 5, 0, 0], 0),
+         "no pick here": lambda tm: ([0, 0, 0, 0, 0], 9)}
+
+
+@pytest.mark.parametrize("backend", ["reference", "interpret"])
+@pytest.mark.parametrize("fill", list(FILLS))
+@pytest.mark.parametrize("tm", routed.ROW_TILES)
+def test_a_plan_at_any_tile_gives_each_pick_its_own_expert_s_product(
+        tm, fill, backend):
+    sizes, absent = FILLS[fill](tm)
+    held = len(sizes)
+    rng = np.random.default_rng(tm + len(fill))
+    keys = rng.permutation(np.repeat(np.arange(held + 1), sizes + [absent]))
+    x = rng.standard_normal((len(keys), H)).astype(np.float32)
+    w = {k: rng.standard_normal(shape).astype(np.float32) / 6
+         for k, shape in (("gate", (LAYERS, held, H, F)),
+                          ("up", (LAYERS, held, H, F)),
+                          ("down", (LAYERS, held, F, H)))}
+
+    @jax.jit
+    def run(keys, x):
+        pick_of_row, row_of_pick, tile_expert, n_live, got_sizes = \
+            routed.dispatch_plan(keys, held, tm)
+        x_rows = jnp.where((pick_of_row >= 0)[:, None],
+                           x[jnp.maximum(pick_of_row, 0)], 0)
+        hidden = grouped_matmul(x_rows, w["gate"], 1, tile_expert, n_live,
+                                tm=tm, w2=w["up"])
+        out = grouped_matmul(hidden, w["down"], 1, tile_expert, n_live, tm=tm)
+        return pick_of_row, row_of_pick, tile_expert, n_live, got_sizes, \
+            out[jnp.where(keys < held, row_of_pick, 0)]
+
+    with force_kernel_backend(backend):
+        pick_of_row, row_of_pick, tile_expert, n_live, got_sizes, y = \
+            (np.asarray(a) for a in run(jnp.asarray(keys, jnp.int32), x))
+    assert got_sizes.tolist() == sizes
+    assert int(n_live) == sum(-(-n // tm) for n in sizes)
+    assert len(pick_of_row) == (len(keys) // tm + held) * tm
+    here = np.flatnonzero(keys < held)
+    # every local pick has a row of its own, in a live tile of its expert
+    assert sorted(pick_of_row[pick_of_row >= 0]) == here.tolist()
+    assert (pick_of_row[row_of_pick[here]] == here).all()
+    assert (tile_expert[row_of_pick[here] // tm] == keys[here]).all()
+    assert (row_of_pick[here] // tm < n_live).all()
+    for p in here:
+        xe = x[p].astype(np.float64)
+        gate = xe @ w["gate"][1, keys[p]]
+        want = (gate / (1 + np.exp(-gate)) * (xe @ w["up"][1, keys[p]])) \
+            @ w["down"][1, keys[p]]
+        np.testing.assert_allclose(y[p], want, atol=2e-5)
+
+
+@pytest.mark.parametrize("backend", ["reference", "interpret"])
+@pytest.mark.parametrize("name,tokens,tm", [
+    ("lfm2", 16, 16), ("lfm2", 40, 32), ("lfm2", 80, 64), ("lfm2", 160, 128),
+    ("longcat", 100, 64)])
+def test_the_shared_layer_is_the_dense_sum_at_the_tile_its_fill_picks(
+        name, tokens, tm, backend):
+    """A router biased so that every token picks held expert 1 (more rows
+    than a tile where tokens > tm), and none picks held expert 2."""
+    rule = RULES[name]
+    assert routed.row_tile(tokens, rule.topk, rule.outputs) == tm
+    layers = _layers(rule, jax.random.PRNGKey(tokens))
+    lo = rule.expert_shard * rule.held
+    layers["router_bias"] = layers["router_bias"].at[:, lo + 1].set(30.0) \
+        .at[:, lo + 2].set(-30.0)
+    u = jax.random.normal(jax.random.PRNGKey(tm), (tokens, H), jnp.float32)
+    valid = jnp.arange(tokens) % 11 != 5
+    want, want_counts = _dense(rule, layers, 0, u, valid)
+    with force_kernel_backend(backend):
+        got, counts = jax.jit(routed.moe_block, static_argnums=0)(
+            rule, layers, 0, u, valid)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+    assert [int(c) for c in counts] == want_counts
+    n_valid = int(valid.sum())
+    assert want_counts[5] >= -(-n_valid // tm) + want_counts[3] - 1
+    assert want_counts[3] < rule.held            # expert 2 got no row
 
 
 def test_a_rule_refuses_what_it_cannot_be():

@@ -428,6 +428,13 @@ def test_decode_kernels_compile_for_speculative_verify(mosaic):
         "parameter", "get-tuple-element", "tuple", "custom-call", "bitcast"}
 
 
+def _grouped_matmul_rows(text: str) -> set[int]:
+    """The rows of every grouped matmul's result in a compiled program:
+    (picks // tile + experts held) * tile, so they tell the tile."""
+    return {int(n) for n in re.findall(
+        r"%moe_grouped_matmul[.\d]* = bf16\[(\d+),", text)}
+
+
 # LongCat-Flash at the published widths, one double layer, 4 of 512 experts:
 # the programs of llm/longcat_serving.py as the serving cell compiles them.
 def _longcat_programs(mosaic, slots=32, max_seq=8192):
@@ -468,17 +475,22 @@ def test_longcat_programs_move_no_whole_cache_and_copy_no_layer(mosaic):
     cfg, prefill, burst = _longcat_programs(mosaic)
     assert cfg.latent_row == 640
     stack = f"[2,32,8192,{cfg.latent_row}]"
-    for compiled, kernels, passing in (
+    # Picks in tiles of 16 in both: a chunk brings a held expert 8 rows, a
+    # step of 32 lines half a row (models/routed.row_tile).
+    for compiled, kernels, passing, picks in (
             (burst, ("latent_decode_attention", "latent_row_write",
                      "moe_grouped_matmul"),
              {"parameter", "get-tuple-element", "tuple", "while",
-              "custom-call", "bitcast"}),
+              "custom-call", "bitcast"}, 32 * cfg.moe_topk),
             (prefill, ("moe_grouped_matmul",),
              {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
-              "dynamic-update-slice", "dynamic-slice", "fusion"})):
+              "dynamic-update-slice", "dynamic-slice", "fusion"},
+             512 * cfg.moe_topk)):
         text = compiled.as_text()
         for name in kernels:
             assert f"%{name}." in text
+        assert _grouped_matmul_rows(text) == {
+            (picks // 16 + cfg.experts_held) * 16}
         assert _opcodes_with_shape(text, stack) <= passing
         # No whole dense FFN matrix as the result of a copy or a slice
         # fusion at the top of the layer loop.
@@ -591,10 +603,15 @@ def test_lfm2_programs_move_no_cache_nor_expert_stack_and_fit_the_chip(
         kernels = ("prefill_attention", "moe_grouped_matmul")
         # written in place; the attention kernel's line names its operand
         in_place = {"dynamic-update-slice", "custom-call"}
+        # 512 x 4 picks over 64 experts are 32 rows each: tiles of 64, so
+        # that an expert's weights are fetched once (models/routed.row_tile)
+        assert _grouped_matmul_rows(text) == {(2048 // 64 + 64) * 64}
     else:
         kernels = ("decode_attention", "kv_row_write", "moe_grouped_matmul")
         in_place = {"custom-call"}
         assert _plans_outside_the_layer_loop(text)
+        # 64 lines x 4 picks are 4 rows an expert: tiles of 16 as ever
+        assert _grouped_matmul_rows(text) == {(256 // 16 + 64) * 16}
     for name in kernels:
         assert f'"{name}"' in text or f"%{name}." in text, name
     big = bench.big_shapes(cfg)
