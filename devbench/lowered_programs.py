@@ -1,0 +1,79 @@
+"""sha256 of every served model's lowered programs at tiny sizes, for a
+described TPU (the Mosaic kernels in) and for the CPU's reference backend:
+the whole StableHLO, the StableHLO outside the kernels' serialized bodies
+(those carry source lines, so they differ whenever a line of a caller
+moves), and the jaxpr, which has the kernels' bodies and no source lines.
+
+    cd <tree> && JAX_PLATFORMS=cpu python3 devbench/lowered_programs.py
+
+To compare two trees lay them at the same path in turn (a kernel's body
+names its files) and diff the two outputs: a PR that touches code the
+models share shows with it that their programs are what they were. Uses
+only names that trees since PR 38 have. DUMP=<dir> also writes the texts."""
+import dataclasses
+import hashlib
+import json
+import os
+import re
+import sys
+
+sys.path.insert(0, os.getcwd())
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import NamedSharding, PartitionSpec as P
+from ray_tpu.llm import engine, lfm2_serving, longcat_serving, ouro_serving
+from ray_tpu.models.lfm2 import Lfm2Config
+from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.models.longcat import LongcatConfig
+from ray_tpu.models.ouro import OuroConfig
+from ray_tpu.ops.kernels import force_kernel_backend
+from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+SLOTS, MAX_SEQ, CHUNK = 4, 256, 32
+MODELS = {"llama": (engine, dataclasses.replace(LlamaConfig.tiny(), vocab_size=512, dtype="bfloat16")),
+          "longcat": (longcat_serving, LongcatConfig.tiny(expert_shards=2, max_seq_len=MAX_SEQ, dtype="bfloat16")),
+          "ouro": (ouro_serving, OuroConfig.tiny(max_seq_len=MAX_SEQ, dtype="bfloat16")),
+          "lfm2": (lfm2_serving, Lfm2Config.tiny(max_seq_len=MAX_SEQ, dtype="bfloat16"))}
+
+def run(backend):
+    out = {}
+    if backend == "mosaic":
+        devices = topologies.get_topology_desc("v5e:2x2", "tpu").devices
+        ctx = force_kernel_backend("mosaic", devices[0].device_kind)
+        dev = NamedSharding(build_mesh(MeshSpec(), devices[:1]), P())
+    else:
+        ctx = force_kernel_backend("reference")
+        dev = None
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev) if dev is not None else jax.ShapeDtypeStruct(a.shape, a.dtype)
+    def arg(shape, dtype=jnp.int32):
+        return sds(jax.ShapeDtypeStruct(shape, dtype))
+    with ctx:
+        for name, (module, cfg) in MODELS.items():
+            served = engine.served_model(cfg)
+            params = jax.tree.map(sds, jax.eval_shape(lambda: served.init_params(cfg, jax.random.PRNGKey(0))))
+            cache = jax.tree.map(sds, jax.eval_shape(lambda: served.init_cache(cfg, SLOTS, MAX_SEQ)))
+            progs = {
+              "prefill_chunk": (cfg, params, cache, arg((CHUNK,)), arg(()), arg(()), arg(())),
+              "decode_step": (cfg, params, cache, arg((SLOTS,)), arg((SLOTS,)), arg((SLOTS,), jnp.bool_)),
+              "decode_burst": (cfg, params, cache, arg((SLOTS,)), arg((SLOTS,)), arg((SLOTS,), jnp.bool_), arg((SLOTS,), jnp.float32), arg((SLOTS,), jnp.float32), arg((2,), jnp.uint32), 4, False),
+            }
+            for prog, args in progs.items():
+                text = getattr(module, prog).lower(*args).as_text()
+                out[f"{name}.{prog}.{backend}"] = hashlib.sha256(text.encode()).hexdigest()[:16]
+                # outside the kernels' serialized bodies (which carry source lines)
+                bare = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""', text)
+                out[f"{name}.{prog}.{backend}.outside_kernels"] = hashlib.sha256(bare.encode()).hexdigest()[:16]
+                fn = getattr(module, prog)
+                static = tuple(i for i, a in enumerate(args) if not hasattr(a, "shape") and not isinstance(a, dict))
+                jp = str(jax.make_jaxpr(fn, static_argnums=static)(*args))
+                out[f"{name}.{prog}.{backend}.jaxpr"] = hashlib.sha256(jp.encode()).hexdigest()[:16]
+                if os.environ.get("DUMP"):
+                    open(os.path.join(os.environ["DUMP"], f"{name}.{prog}.{backend}.jaxpr.txt"), "w").write(jp)
+                    open(os.path.join(os.environ["DUMP"], f"{name}.{prog}.{backend}.mlir.txt"), "w").write(bare)
+    return out
+res = {}
+for b in ("reference", "mosaic"):
+    res.update(run(b))
+print(json.dumps(res, indent=0, sort_keys=True))

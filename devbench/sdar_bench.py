@@ -1,0 +1,363 @@
+"""The SDAR-MoE serving programs at the shapes of ``sdar-30b-serve-generate-512``
+(the first 6 layers of SDAR-30B-A3B-Chat with all 128 experts, 128 slots x
+1,536): compiled for a described v5e with no chip, and timed on one.
+
+    python3 devbench/sdar_bench.py aot            # no chip, about a minute
+    chiprun -- python3 devbench/sdar_bench.py step parity
+
+``aot``: ``llm/sdar_serving.py``'s ``prefill_chunk`` at the buckets 16 and
+512 and ``decode_burst`` of 1 and 2 blocks, compiled for ``v5e:2x2``'s first
+device (nothing runs: no time comes out of it): XLA's ``memory_analysis``
+(arguments, temporaries, their sum against the chip's 15.75 GiB), the bytes
+a cached position takes, the Mosaic calls, and every instruction whose
+result has the shape of a cache leaf, of one layer or line of it, or of a
+stacked weight, by opcode (a copy of one of those is up to 6.75 GiB moved a
+program). ``step``: wall milliseconds of one block (5 forwards) inside a
+burst of 2 at 128 lines of 256, 768 and 1,280 live positions, a forward
+being a fifth of it, and of a prefill chunk of 512 at 0 and 512 cached rows
+(the clock stops on a host read of the result). ``parity``: a block-causal
+prefill of 768 positions and 64 blocks decided by the program, against the
+float32 reference over the finished sequence, as the harness compares
+them: the reference's top logit minus its logit of the program's token,
+worst over the generated positions. ``engine`` and ``engine_stream``: the
+engine's schedule alone under the cell's traffic, no HTTP and no router: 128
+closed-loop clients on ``LLMEngine.submit`` for 20 s (the second with
+``stream=True`` and a thread a client that drains the token queue, as the
+server's generator does): tokens a second as the cell counts them, wall
+milliseconds a forward (the device's forward plus whatever gap the host
+leaves: compare ``decode_ms_per_step.tok_s`` of a traced run); one of the
+two a process (an engine's weights stay on the chip after ``shutdown``).
+One JSON object a mode.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+from functools import partial
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+
+from devbench.lfm2_bench import GIB, opcodes_with_shape  # noqa: E402
+
+SLOTS, MAX_SEQ = 128, 1536
+BUCKETS = (16, 512)
+BURSTS = (1, 2)
+
+
+def config_json() -> dict:
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "sdar-30b-a3b-chat.json")) as f:
+        return json.load(f)
+
+
+def config():
+    from rtbench.adapters import sdar as adapter
+
+    return adapter.model_config(config_json(), "serve_generate", MAX_SEQ)
+
+
+def shapes(cfg, place, slots: int = SLOTS):
+    import jax
+
+    from ray_tpu.llm import sdar_serving as serving
+    from ray_tpu.models import sdar
+
+    params = place(jax.eval_shape(partial(sdar.init_params, cfg),
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(partial(serving.init_kv_cache, cfg, slots,
+                                         MAX_SEQ)))
+    return params, cache
+
+
+def lowerings(cfg, params, cache, arg, slots: int = SLOTS) -> dict:
+    """{name: a function that lowers that program} at the cell's shapes."""
+    import jax.numpy as jnp
+
+    from ray_tpu.llm import sdar_serving as serving
+
+    def chunk(b):
+        return lambda: serving.prefill_chunk.lower(
+            cfg, params, cache, arg((b,)), arg(()), arg(()), arg(()))
+
+    def burst(n):
+        return lambda: serving.decode_burst.lower(
+            cfg, params, cache, arg((slots, cfg.block_length)),
+            arg((slots,)), arg((slots,), jnp.bool_),
+            arg((slots,), jnp.float32), arg((slots,), jnp.float32),
+            arg((2,), jnp.uint32), n, False)
+
+    out = {f"prefill_chunk({b})": chunk(b) for b in BUCKETS}
+    out.update({f"decode_burst({n})": burst(n) for n in BURSTS})
+    return out
+
+
+def big_shapes(cfg, slots: int = SLOTS) -> dict:
+    """The shapes no instruction should produce: a cache leaf, one layer of
+    it, one line, and each stacked matrix."""
+    h, fe, L, e = (cfg.hidden_size, cfg.moe_intermediate_size,
+                   cfg.num_layers, cfg.num_experts)
+    line = f"{cfg.num_kv_heads},{MAX_SEQ},{cfg.head_dim}]"
+    return {"kv": f"bf16[{L},{slots},{line}",
+            "kv_layer": f"bf16[{slots},{line}",
+            "kv_line": f"bf16[1,{line}",
+            "experts_up": f"bf16[{L},{e},{h},{fe}]",
+            "experts_down": f"bf16[{L},{e},{fe},{h}]",
+            "experts_layer_up": f"bf16[{e},{h},{fe}]",
+            "experts_layer_down": f"bf16[{e},{fe},{h}]",
+            "wq": f"bf16[{L},{h},{cfg.num_heads * cfg.head_dim}]",
+            "embed": f"bf16[{cfg.vocab_size},{h}]",
+            "head": f"bf16[{h},{cfg.vocab_size}]",
+            "head_f32": f"f32[{h},{cfg.vocab_size}]"}
+
+
+def aot(slots: int = SLOTS) -> dict:
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.ops.kernels import force_kernel_backend
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    devices = topologies.get_topology_desc("v5e:2x2", "tpu").devices
+    cfg = config()
+    out = {"mode": "aot", "slots": slots, "max_seq": MAX_SEQ, "programs": {}}
+    with force_kernel_backend("mosaic", devices[0].device_kind):
+        dev = NamedSharding(build_mesh(MeshSpec(), devices[:1]), P())
+
+        def place(tree):
+            return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=dev), tree)
+
+        def arg(shape, dtype=jnp.int32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+        params, cache = shapes(cfg, place, slots)
+        k = cache["k"]
+        out["cached_position_bytes"] = (
+            2 * k.size * k.dtype.itemsize // (slots * MAX_SEQ))
+        for name, lower in lowerings(cfg, params, cache, arg, slots).items():
+            t0 = time.monotonic()
+            compiled = lower().compile()
+            text = compiled.as_text()
+            mem = compiled.memory_analysis()
+            out["programs"][name] = {
+                "compile_s": round(time.monotonic() - t0, 1),
+                "arguments_gib": round(mem.argument_size_in_bytes / GIB, 3),
+                "temporaries_gib": round(mem.temp_size_in_bytes / GIB, 3),
+                "sum_gib": round((mem.argument_size_in_bytes
+                                  + mem.temp_size_in_bytes) / GIB, 3),
+                "mosaic_calls": text.count(
+                    'custom_call_target="tpu_custom_call"'),
+                "big": {k: opcodes_with_shape(text, s)
+                        for k, s in big_shapes(cfg, slots).items()}}
+            if os.environ.get("SDAR_BENCH_HLO"):
+                with open(os.path.join(os.environ["SDAR_BENCH_HLO"],
+                                       name + ".hlo.txt"), "w") as f:
+                    f.write(text)
+    return out
+
+
+def _programs():
+    import jax
+
+    from ray_tpu.llm import sdar_serving as serving
+    from ray_tpu.models import sdar
+
+    cfg = config()
+    params = jax.jit(sdar.init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(0))
+    return cfg, params, serving, serving.init_kv_cache(cfg, SLOTS, MAX_SEQ)
+
+
+def _time_chunk(serving, cfg, params, cache, kv_len: int = 0):
+    """(cache, ms, counts) of ``prefill_chunk(512)`` against ``kv_len``
+    cached rows: the best of three calls after a first that may compile."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    i32, times = jnp.int32, []
+    for _ in range(4):
+        t0 = time.monotonic()
+        cache, _, counts = serving.prefill_chunk(
+            cfg, params, cache, jnp.arange(512, dtype=i32) + 300,
+            i32(kv_len), i32(kv_len + 512), i32(0))
+        np.asarray(counts)
+        times.append((time.monotonic() - t0) * 1e3)
+    return cache, round(min(times[1:]), 2), counts
+
+
+def _time_block(serving, cfg, params, cache, live: int, blocks: int = 2):
+    """(cache, ms a block, counts) of ``decode_burst(blocks)`` at every line
+    ``live`` long, timed as ``_time_chunk`` does."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    i32, times = jnp.int32, []
+    temps = jnp.zeros((SLOTS,), jnp.float32)
+    for _ in range(4):
+        t0 = time.monotonic()
+        cache, toks, counts = serving.decode_burst(
+            cfg, params, cache, jnp.full((SLOTS, cfg.block_length), -1, i32),
+            jnp.full((SLOTS,), live, i32), jnp.ones((SLOTS,), bool),
+            temps, temps + 1.0, jax.random.PRNGKey(1), blocks, False)
+        np.asarray(toks)
+        times.append((time.monotonic() - t0) * 1e3 / blocks)
+    return cache, round(min(times[1:]), 2), counts
+
+
+def step() -> dict:
+    import jax
+
+    cfg, params, serving, cache = _programs()
+    out = {"mode": "step", "device": jax.devices()[0].device_kind,
+           "block_ms": {}, "forward_ms": {}, "prefill_chunk_ms": {}}
+    for kv_len in (0, 512):
+        cache, out["prefill_chunk_ms"][kv_len], counts = _time_chunk(
+            serving, cfg, params, cache, kv_len)
+        out["prefill_counts"] = [int(n) for n in counts]
+    for live in (256, 768, 1280):
+        cache, ms, counts = _time_block(serving, cfg, params, cache, live)
+        out["block_ms"][live] = ms
+        out["forward_ms"][live] = round(ms / (cfg.denoising_steps + 1), 2)
+        out["decode_counts"] = [int(n) for n in counts]
+    return out
+
+
+def parity(prompt: int = 770, blocks: int = 64, seed: int = 7) -> dict:
+    """The programs' tokens against the float32 reference, as
+    ``kinds/serve_common.worst_margin`` compares a run's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from reference import sdar as reference
+    from rtbench import gen
+    from rtbench.adapters import sdar as adapter
+    from rtbench.kinds.serve_common import worst_margin
+
+    cfg, params, serving, cache = _programs()
+    cj = config_json()
+    k = cfg.block_length
+    ids = gen.prompt_ids(seed, 1, prompt, cj["vocab_size"])
+    whole = prompt - prompt % k
+    slot = 3
+    for a in range(0, whole, 512):
+        toks = np.zeros((512,), np.int32)
+        take = min(512, whole - a)
+        toks[:take] = ids[a:a + take]
+        cache, _, _ = serving.prefill_chunk(
+            cfg, params, cache, jnp.asarray(toks), jnp.int32(a),
+            jnp.int32(whole), jnp.int32(slot))
+    write = np.zeros(SLOTS, bool)
+    write[slot] = True
+    temps = jnp.zeros((SLOTS,), jnp.float32)
+    out_ids: list[int] = []
+    for j in range(0, blocks, 2):
+        tok = np.full((SLOTS, k), -1, np.int32)
+        pos = np.zeros(SLOTS, np.int32)
+        pos[slot] = whole + j * k
+        if j == 0:
+            tok[slot, :prompt - whole] = ids[whole:]
+        cache, toks, _ = serving.decode_burst(
+            cfg, params, cache, jnp.asarray(tok), jnp.asarray(pos),
+            jnp.asarray(write), temps, temps + 1.0, jax.random.PRNGKey(j), 2,
+            False)
+        out_ids += np.asarray(toks)[:, slot].reshape(-1).tolist()
+    out_ids = out_ids[prompt - whole:]
+    weights = adapter.reference_weights(params)
+
+    def logits_of(seq):
+        padded = seq + [0] * (-len(seq) % 512)
+        return np.asarray(reference.logits(cj, weights,
+                                           jnp.asarray(padded, jnp.int32)))
+
+    return {"mode": "parity", "prompt": prompt, "generated": len(out_ids),
+            "device": jax.devices()[0].device_kind,
+            "worst_margin": worst_margin(ids, out_ids, logits_of)}
+
+
+def engine(stream: bool = False, window_s: float = 20.0) -> dict:
+    import threading
+
+    import jax
+    from rtbench import gen
+
+    from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
+    from ray_tpu.llm import engine as engine_mod
+
+    with open(os.path.join(ROOT, "benchmark", "traffic",
+                           "serve-generate-512.json")) as f:
+        traffic = json.load(f)
+    jitted = jax.jit(engine_mod.init_params, static_argnums=0)
+    engine_mod.init_params = lambda cfg, key: jitted(cfg, key)
+    eng_kw = {k: v for k, v in traffic["engine"].items()
+              if k != "max_ongoing_requests"}
+    eng = LLMEngine(LLMConfig(model=config(), **eng_kw))
+    vocab = config_json()["vocab_size"]
+    for w in traffic["warmup"]:
+        eng.generate(gen.prompt_ids(0, 0, w["prompt_tokens"], vocab),
+                     SamplingParams(max_tokens=w["max_tokens"]))
+    plan = gen.closed_loop_plan(traffic, 7, 60)["requests"]
+    lock, served, stop = threading.Lock(), [], threading.Event()
+
+    def client():
+        while not stop.is_set():
+            with lock:
+                r = plan.pop(0)
+            ids = gen.prompt_ids(7, r["index"], r["prompt_tokens"], vocab)
+            t0 = time.monotonic()
+            req = eng.submit(ids, SamplingParams(max_tokens=r["max_tokens"]),
+                             stream=stream)
+            n = 0
+            if stream:
+                while req.stream_queue.get() is not None:
+                    n += 1
+            req.done.wait(300)
+            served.append((time.monotonic() - t0, len(req.out_tokens), n))
+
+    threads = [threading.Thread(target=client, daemon=True)
+               for _ in range(traffic["clients"])]
+    for i, t in enumerate(threads):
+        t.start()
+        time.sleep(traffic["stagger_s"] / len(threads))
+    time.sleep(15.0)                     # every line at its own depth
+    s0, t_open = eng.stats(), time.monotonic()
+    time.sleep(window_s)
+    s1, t_close = eng.stats(), time.monotonic()
+    stop.set()
+    forwards = s1["decode_steps"] - s0["decode_steps"]
+    out = {"mode": "engine_stream" if stream else "engine",
+           "device": jax.devices()[0].device_kind,
+           "serve_tok_s_by_counters": round(
+               (s1["decode_tokens"] - s0["decode_tokens"]
+                + s1["prompt_tokens_prefilled"]
+                - s0["prompt_tokens_prefilled"]) / (t_close - t_open), 1),
+           "requests_finished": s1["finished"] - s0["finished"],
+           "decode_tok_s": round((s1["decode_tokens"] - s0["decode_tokens"])
+                                 / (t_close - t_open), 1),
+           "wall_ms_per_forward": round((t_close - t_open) * 1e3
+                                        / max(forwards, 1), 2),
+           "lines_per_forward": round(
+               (s1["diffusion_forwards"] - s0["diffusion_forwards"])
+               / max(forwards, 1), 1),
+           "prefill_chunks": s1["prefill_chunks"] - s0["prefill_chunks"],
+           "ahead_share": round(
+               (s1["decode_dispatches_ahead"] - s0["decode_dispatches_ahead"])
+               / max(s1["decode_dispatches"] - s0["decode_dispatches"], 1),
+               3)}
+    eng.shutdown()
+    return out
+
+
+MODES = {"aot": aot, "step": step, "parity": parity, "engine": engine,
+         "engine_stream": partial(engine, stream=True)}
+
+if __name__ == "__main__":
+    for mode in sys.argv[1:] or ["aot"]:
+        print(json.dumps(MODES[mode]()), flush=True)

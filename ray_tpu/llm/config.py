@@ -16,9 +16,11 @@ from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
 from ray_tpu.models.ouro import OuroConfig
+from ray_tpu.models.sdar import SdarConfig
 
 # A model's own configuration: what llm/engine.served_model knows a model by.
-ModelConfig = LlamaConfig | LongcatConfig | OuroConfig | Lfm2Config
+ModelConfig = (LlamaConfig | LongcatConfig | OuroConfig | Lfm2Config
+               | SdarConfig)
 
 
 @dataclass

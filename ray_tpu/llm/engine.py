@@ -258,9 +258,9 @@ def _decode_step_impl(cfg: LlamaConfig, params, cache, tokens, positions,
     or empty must not have garbage K/V written into their cache (False =
     keep the existing cache line). Returns (cache, logits [B, V]).
 
-    Exactly the K=1 case of the multi-token body speculative verification
-    uses — ONE implementation of the masked-attention/KV-write math, so
-    the two paths can never diverge.
+    Exactly the K=1 case of the multi-token body (speculative
+    verification runs it at K > 1) — ONE implementation of the
+    masked-attention/KV-write math, so the two paths can never diverge.
     """
     if write_mask is None:
         write_mask = jnp.ones(tokens.shape, bool)
@@ -501,6 +501,32 @@ class ServedModel:
       which the scheduler adds into ``stats()`` under those names where it
       fetches the tokens; ``constants(cfg)`` gives what ``stats()`` carries
       beside them unchanged (a denominator of theirs);
+    - ``step(cfg)``: (positions, forwards), the size of one decode step of
+      one line: the positions it takes in and gives back decided, and the
+      forwards of the stack it costs. None is (1, 1), a token in and a
+      token out by one forward: a prompt's last chunk gives the first
+      token (``prefill_chunk`` returns that row's logits) and a step's
+      input is the token the step before sampled, handed on on the device.
+      A model that decides a block of K positions by several forwards says
+      (K, forwards), and everything else that sets it apart follows from
+      this one statement (``prefill_token``; nothing else is looked at):
+      the scheduler counts a line's progress, its budget and its cache
+      line's end in steps of K positions, a burst is whole steps
+      (``decode_burst`` takes tokens ``[slots, K]`` and returns ``[steps,
+      slots, K]``; a line's last step may decide more positions than its
+      request wants, and the surplus is not emitted), ``decode_steps`` and
+      the dispatch phase's ``steps`` count forwards. Such a model samples
+      between its forwards, on the device, so it has no ``decode_step``
+      (None, and only then: a mix is refused here, where it is built): a
+      lone step is a burst of one, and a request with ``top_k`` is
+      refused. Its prefill gives no token: the prompt's whole steps are
+      prefilled (``len(prompt) - len(prompt) % K`` tokens;
+      ``prefill_chunk`` returns None for the logits), nothing is emitted
+      for them, and the tokens past them ride into the line's first step
+      in their places (-1 at every position a step has to decide); the
+      first token comes when that step is read. A step's input is then
+      known to the host before the step before it has run, and a burst is
+      queued behind another with nothing handed over;
     - ``kv_block(cfg, max_seq)``: the positions its decode attention
       fetches at a time, behind ``kv_positions_read``;
     - ``kv_handoff``: whether a line can be exported and imported as
@@ -519,15 +545,30 @@ class ServedModel:
     param_logical_axes: Callable
     init_cache: Callable
     prefill_chunk: Callable
-    decode_step: Callable
+    decode_step: Callable | None
     decode_burst: Callable
     kv_block: Callable
     copy_prefix_kv: Callable | None = None
     counters: tuple[str, ...] = ()
     constants: Callable | None = None
+    step: Callable | None = None
     kv_handoff: bool = True
     prefix_from_line: bool = True
     refuse: Callable | None = None
+
+    def __post_init__(self):
+        if (self.step is None) == (self.decode_step is None):
+            raise ValueError(
+                "a ServedModel has a decode_step or states its step, one "
+                "of the two: a step of several positions samples on the "
+                "device and has no single-step program, and a model with "
+                "such a program takes a token in and gives a token out")
+
+    @property
+    def prefill_token(self) -> bool:
+        """Whether a prompt's last chunk gives the first token: for every
+        model but one that states its ``step``."""
+        return self.step is None
 
 
 # The Llama programs are this module's own, reached through its names when
@@ -567,8 +608,15 @@ def served_model(cfg) -> ServedModel:
         from ray_tpu.llm.lfm2_serving import SERVED
 
         return SERVED
+    from ray_tpu.models.sdar import SdarConfig
+
+    if isinstance(cfg, SdarConfig):
+        from ray_tpu.llm.sdar_serving import SERVED
+
+        return SERVED
     raise TypeError(f"the engine serves no {type(cfg).__name__}: it serves "
-                    "Lfm2Config, LlamaConfig, LongcatConfig and OuroConfig")
+                    "SdarConfig, Lfm2Config, LlamaConfig, LongcatConfig and "
+                    "OuroConfig")
 
 
 def require_kv_handoff(cfg) -> None:
@@ -597,7 +645,9 @@ class GenerationRequest:
     done: threading.Event = field(default_factory=threading.Event)
     error: str | None = None
     finish_reason: str | None = None
-    next_pos: int = 0  # position the next token will occupy; <0 = prefilling
+    # Position the line's next step starts at (prompt positions among them,
+    # where a prompt's tail rides into the first step); <0 = prefilling.
+    next_pos: int = 0
     ahead: int = 0  # decode steps dispatched for it and not yet read
     prefilled_len: int = 0  # prompt tokens already in the KV cache
     preloaded: tuple | None = None  # (kv_k, kv_v, first_token) P/D import
@@ -623,8 +673,9 @@ class GenerationRequest:
 @dataclass
 class _InFlight:
     """A dispatched program whose tokens the host has not read: a prompt's
-    first token (``steps`` 0, ``toks`` [1]) or a burst (``toks``
-    [steps, slots]; ``last_row`` its final row, the next burst's input).
+    first token (``steps`` 0, ``toks`` [1]) or a burst of ``steps`` steps
+    (``toks`` [steps, slots], or [steps, slots, K] from a model whose step
+    is K positions; ``last_row`` its final row, the next burst's input).
     ``reqs`` are the lines it computes for, by slot; ``counts`` the model's
     own (ServedModel.counters), fetched with the tokens."""
     reqs: dict[int, GenerationRequest]
@@ -705,10 +756,11 @@ class LLMEngine:
         # Work done and time waited, counted where it happens: cumulative,
         # never reset (not by a device failure either), written by the
         # scheduler thread alone and read through stats(), so a reader
-        # that polls takes window deltas. decode_steps are device steps
-        # (a burst of 8 counts 8; each computes every slot), decode_tokens
+        # that polls takes window deltas. decode_steps are forwards of the
+        # stack (a burst of 8 counts 8, and a burst of 2 steps of 5
+        # forwards 10; each computes every slot), decode_tokens
         # the tokens they gave that a request still wanted (so not its
-        # first, which prefill gives); decode_dispatches_ahead those
+        # first, where prefill gives it); decode_dispatches_ahead those
         # dispatches made while an earlier program's result was still
         # unread, so the device had work; queue_wait_s sums admit - submit
         # over `admitted`, first_token_wait_s first token - admit over
@@ -735,6 +787,9 @@ class LLMEngine:
         self.prefill_kv_positions_read = 0
         self.prefill_kv_positions_reserved = 0
         self._kv_block = self.model.kv_block(self.model_cfg, self.max_seq)
+        # One decode step of one line: positions decided, forwards spent.
+        self._step_positions, self._step_forwards = (
+            self.model.step(self.model_cfg) if self.model.step else (1, 1))
         # What the model's programs count themselves (ServedModel.counters).
         self.model_counts = dict.fromkeys(self.model.counters, 0)
         if self.model.constants is not None:
@@ -824,6 +879,10 @@ class LLMEngine:
                sampling: SamplingParams | None = None,
                stream: bool = False) -> GenerationRequest:
         sampling = sampling or SamplingParams()
+        if sampling.top_k and self.model.step is not None:
+            raise ValueError(
+                f"{type(self.model_cfg).__name__} does not support top_k: "
+                "its step samples on the device, where k is static")
         ids = (self.tokenizer.encode(prompt) if isinstance(prompt, str)
                else list(prompt))
         ids = ids[: self.max_seq - 1]
@@ -1180,24 +1239,45 @@ class LLMEngine:
             return False
         # A line whose newest token only the host has (a KV import) cannot
         # ride behind a burst that runs without it.
-        host_only = (any(e.steps for e in self._in_flight)
+        host_only = (self.model.prefill_token
+                     and any(e.steps for e in self._in_flight)
                      and any(r.out_tokens and not r.ahead
                              for r in active.values()))
-        if self._in_flight and (host_only or self._burst_len(active) <= 1):
+        # So does a single step; a model whose step samples on the device
+        # has none (ServedModel.step).
+        if self._in_flight and (host_only or (
+                self.model.step is None
+                and self._burst_len(active) <= 1)):
             self._read_all()
             active = self._decoding()
         if active:
             self._decode(active)
         return True
 
+    def _position(self, req: GenerationRequest) -> int:
+        """Where the line's next step starts once everything in flight is
+        read."""
+        return req.next_pos + req.ahead * self._step_positions
+
+    def _steps_to_line_end(self, pos: int) -> int:
+        """Whole steps from ``pos`` whose tokens all lie inside the cache
+        line: a step from ``pos`` decides the positions from ``pos`` on, or
+        from ``pos + 1`` on where it takes the token at ``pos`` in (the
+        models whose prefill gives the first token)."""
+        return ((self.max_seq - self.model.prefill_token - pos)
+                // self._step_positions)
+
     def _steps_left(self, req: GenerationRequest) -> int:
         """Decode steps the line can still take once everything in flight
-        is read: the fewer of its token budget and its cache line's end,
-        both less the steps in flight (a decoding line without a token has
-        its first in flight too). 0: it ends there, whatever it samples."""
-        tokens = len(req.out_tokens) + req.ahead + (not req.out_tokens)
-        return min(req.sampling.max_tokens - tokens,
-                   self.max_seq - 1 - req.next_pos - req.ahead)
+        is read: the fewer of what its token budget needs (the last step
+        rounded up) and what its cache line's end allows, both counted from
+        the position it will stand at (the tokens it has and those in
+        flight are the positions past its prompt, and the first token too
+        where prefill gives it). 0: it ends there, whatever it samples."""
+        k, pos = self._step_positions, self._position(req)
+        tokens = pos - len(req.prompt_ids) + self.model.prefill_token
+        return min(-(-(req.sampling.max_tokens - tokens) // k),
+                   self._steps_to_line_end(pos))
 
     def _read_oldest(self) -> bool:
         """Pipelined: read results, oldest first, until one burst is left
@@ -1233,6 +1313,11 @@ class LLMEngine:
             counts, req.chunk_counts = req.chunk_counts, []
             if req.done.is_set():  # failed meanwhile
                 return True
+        else:
+            # Counts of a prompt whose chunks gave no token to be fetched
+            # with (ServedModel.prefill_token): with its first burst.
+            for req in entry.reqs.values():
+                counts, req.chunk_counts = counts + req.chunk_counts, []
         try:
             with tracing.phase("engine.fetch",
                                which="burst" if entry.steps else "prefill"):
@@ -1358,8 +1443,11 @@ class LLMEngine:
                             f"prefix copy failed: {e!r}")
                         req.prefilled_len = 0
             # next_pos < 0 marks "still prefilling" (prefilled_len tracks
-            # progress); _finish frees by identity.
-            req.next_pos = -1
+            # progress); _finish frees by identity. A prompt shorter than a
+            # step of a model whose prefill gives no token has nothing to
+            # prefill: the line decodes from position 0.
+            req.next_pos = -1 if (self.model.prefill_token
+                                  or self._prefill_len(req)) else 0
             req.last_slot = slot
             self._slots[slot] = req
             admitted += 1
@@ -1449,20 +1537,29 @@ class LLMEngine:
                 continue
             self._prefill_rr = slot
             bucket, take = self._chunk_bucket(
-                req.prefilled_len, len(req.prompt_ids) - req.prefilled_len)
+                req.prefilled_len,
+                self._prefill_len(req) - req.prefilled_len)
             with tracing.phase("engine.prefill_dispatch", tokens=take,
                                bucket=bucket):
                 self._dispatch_prefill_chunk(slot, req, bucket, take)
             return True
         return False
 
+    def _prefill_len(self, req: GenerationRequest) -> int:
+        """The prompt's tokens that are prefilled: its whole steps (all of
+        it, where a step is one position)."""
+        p = len(req.prompt_ids)
+        return p - p % self._step_positions
+
     def _dispatch_prefill_chunk(self, slot: int, req: GenerationRequest,
                                 bucket: int, take: int) -> None:
         """The host side of one chunk: pad it to its bucket, dispatch it,
         and on the prompt's last chunk dispatch the first token's sample
         too. That token goes in flight unread: the line decodes from here
-        on, and joins the next burst on the device (_input_tokens)."""
-        p = len(req.prompt_ids)
+        on, and joins the next burst on the device (_input_tokens). Where
+        prefill gives no token the line decodes from the prefilled length
+        on, and the prompt's tail rides into its first step."""
+        p = self._prefill_len(req)
         toks = np.zeros((bucket,), np.int32)
         toks[:take] = req.prompt_ids[req.prefilled_len:
                                      req.prefilled_len + take]
@@ -1482,9 +1579,10 @@ class LLMEngine:
                 # The slot now holds the full prompt's KV: it becomes a
                 # prefix donor for later shared-prefix requests.
                 self._prefix_live[slot] = tuple(req.prompt_ids)
-                out = self._sample_dispatch(logits[None], [req])
                 req.next_pos = p
-                self._in_flight.append(_InFlight({slot: req}, out))
+                if self.model.prefill_token:
+                    out = self._sample_dispatch(logits[None], [req])
+                    self._in_flight.append(_InFlight({slot: req}, out))
         except Exception as e:  # noqa: BLE001 - e.g. OOM on long prompt
             logger.exception("prefill failed for %s", req.request_id)
             self._recover_device_failure(f"prefill failed: {e!r}")
@@ -1548,7 +1646,7 @@ class LLMEngine:
             if req.sampling.top_k:  # static-k sampling: single-step only
                 return 1
             burst = min(burst,
-                        self.max_seq - 1 - req.next_pos - req.ahead)
+                        self._steps_to_line_end(self._position(req)))
             budget = max(budget, self._steps_left(req))
         burst = min(burst, budget)
         d = 1
@@ -1561,7 +1659,9 @@ class LLMEngine:
         (_recover_device_failure ran) — callers mid-tick must then abandon
         the rest of the tick rather than dispatch into rebuilt caches."""
         burst = self._burst_len(active)
-        if burst > 1:
+        # A model whose step samples on the device has no single step
+        # (ServedModel.step): its lone step is a burst of one.
+        if burst > 1 or self.model.step is not None:
             ok = self._decode_burst(active, burst)
             # Not pipelined: strictly serial, a tick reads what it
             # dispatched before the next one begins.
@@ -1607,7 +1707,7 @@ class LLMEngine:
         positions = np.zeros((self.max_slots,), np.int32)
         write = np.zeros((self.max_slots,), bool)
         for slot, req in active.items():
-            positions[slot] = req.next_pos + req.ahead
+            positions[slot] = self._position(req)
             write[slot] = True
         return positions, write
 
@@ -1626,7 +1726,20 @@ class LLMEngine:
         in it); with no burst in flight the host has them all. A line whose
         first token is in flight and in no burst yet takes it from its
         chunk's sampled device value, so it joins the first burst
-        dispatched after that chunk."""
+        dispatched after that chunk.
+
+        Where prefill gives no token (ServedModel.prefill_token) a step's
+        input is no step's output: int32[slots, K], the prompt's tokens
+        that lie in the step (a first step's leading places) and -1 at
+        every position the step has to decide."""
+        if not self.model.prefill_token:
+            k = self._step_positions
+            tokens = np.full((self.max_slots, k), -1, np.int32)
+            for slot, req in active.items():
+                pos = self._position(req)
+                given = req.prompt_ids[pos:pos + k]
+                tokens[slot, :len(given)] = given
+            return jnp.asarray(tokens)
         prev = next((e for e in reversed(self._in_flight) if e.steps), None)
         tokens = (prev.last_row if prev is not None
                   else self._host_tokens(active))
@@ -1640,16 +1753,20 @@ class LLMEngine:
         return tokens
 
     def _count_kv_positions(self, positions, write, steps: int,
-                            k: int = 1) -> None:
+                            k: int | None = None) -> None:
         """One decode dispatch's part of kv_positions_read/_reserved:
-        ``steps`` kernel calls a layer, call i over lines of
-        positions + i + k where ``write``, 0 elsewhere."""
-        lengths = (positions + k)[None, :] + np.arange(steps)[:, None]
+        ``steps`` steps of ``k`` positions (the model's step, or a verify
+        step's), each a kernel call a layer for every forward it costs,
+        step i over lines of positions + (i + 1) * k where ``write``, 0
+        elsewhere."""
+        k, forwards = k or self._step_positions, self._step_forwards
+        lengths = (positions + k)[None, :] + k * np.arange(steps)[:, None]
         lengths = np.where(write[None, :], np.minimum(lengths, self.max_seq),
                            0)
-        self.kv_positions_read += int(
+        self.kv_positions_read += forwards * int(
             kv_positions_read(lengths, self._kv_block).sum())
-        self.kv_positions_reserved += steps * self.max_slots * self.max_seq
+        self.kv_positions_reserved += (steps * forwards * self.max_slots
+                                       * self.max_seq)
 
     def _decode_burst(self, active: dict[int, GenerationRequest],
                       burst: int) -> bool:
@@ -1660,7 +1777,8 @@ class LLMEngine:
         overwrites (same free-rollback property speculative decoding
         relies on)."""
         try:
-            with tracing.phase("engine.decode_dispatch", steps=burst,
+            with tracing.phase("engine.decode_dispatch",
+                               steps=burst * self._step_forwards,
                                slots=len(active)):
                 positions, write = self._decode_inputs(active)
                 temps = np.zeros((self.max_slots,), np.float32)
@@ -1679,7 +1797,8 @@ class LLMEngine:
                 # Every burst leaves its last row on the device, wanted or
                 # not: a lone request then walks the helper at every burst
                 # length, and no later mix of lengths compiles anything.
-                last_row = _last_row(toks)
+                last_row = (_last_row(toks) if self.model.prefill_token
+                            else None)
         except Exception as e:  # noqa: BLE001 - cache donated & lost
             logger.exception("burst decode failed (%d active, burst %d)",
                              len(active), burst)
@@ -1687,7 +1806,7 @@ class LLMEngine:
             return False
         self.decode_dispatches += 1
         self.decode_dispatches_ahead += bool(self._in_flight)
-        self.decode_steps += burst
+        self.decode_steps += burst * self._step_forwards
         self._count_kv_positions(positions, write, burst)
         for req in active.values():
             req.ahead += burst
@@ -1703,14 +1822,21 @@ class LLMEngine:
                 self.model_counts[name] += int(n)
 
     def _emit_burst(self, active, burst: int, toks) -> None:
+        """A burst's tokens to their requests, step by step: every position
+        of a line's step in turn, but a position inside the prompt (a first
+        step's leading places, where the prompt's tail rode in) is the
+        prompt's own and not emitted, and nothing past a line's end is."""
         with tracing.phase("engine.emit") as ph:
             before = self.decode_tokens
+            rows = toks.reshape(burst, self.max_slots, -1).tolist()
             for j in range(burst):
                 for slot, req in active.items():
-                    if req.done.is_set():
-                        continue
-                    req.next_pos += 1
-                    self._emit(req, int(toks[j, slot]))
+                    for tok in rows[j][slot]:
+                        if req.done.is_set():
+                            break
+                        req.next_pos += 1
+                        if req.next_pos > len(req.prompt_ids):
+                            self._emit(req, tok)
             ph.set(tokens=self.decode_tokens - before)
 
     def _spec_decode(self, active: dict[int, GenerationRequest]) -> None:
@@ -1878,9 +2004,9 @@ class LLMEngine:
 
     def _emit(self, req: GenerationRequest, token: int) -> None:
         req.out_tokens.append(token)
-        if len(req.out_tokens) > 1:
+        if len(req.out_tokens) > 1 or not self.model.prefill_token:
             self.decode_tokens += 1
-        else:
+        if len(req.out_tokens) == 1:
             now = req.first_token_ts = time.time()
             self.first_tokens += 1
             self.first_token_wait_s += now - (req.admit_ts or now)
@@ -1894,8 +2020,9 @@ class LLMEngine:
             finish = "stop"
         elif len(req.out_tokens) >= req.sampling.max_tokens:
             finish = "length"
-        elif req.next_pos + 1 >= self.max_seq:
-            finish = "length"
+        elif (not req.next_pos % self._step_positions
+              and self._steps_to_line_end(req.next_pos) <= 0):
+            finish = "length"   # no whole step is left of its cache line
         if finish:
             self._finish(req, finish)
 

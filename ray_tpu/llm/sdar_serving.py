@@ -1,0 +1,264 @@
+"""What models/sdar.py supplies to llm/engine.py: generation by diffusion
+over blocks against the engine's slot cache.
+
+The cache is the Llama one, ``{"k", "v"}`` ``[layers, slots, kv_heads,
+max_seq, head_dim]``. What differs is the step (``ServedModel.step``): one
+step of one line takes a whole block of ``block_length`` positions in and
+gives it back decided, and costs ``denoising_steps + 1`` forwards of the
+stack, each over the block's rows:
+
+- a **denoising forward** writes the K/V of the block's current content
+  (its decided positions, the mask token at the open ones) at the block's
+  positions, attends the line through the block's end (every row sees the
+  whole block: ops/decode_attention.py with ``positions0`` at the block's
+  last position) and gives logits at every row; the row at an open position
+  chooses that position's token (no shift by one) and the rule of the
+  configuration (models/sdar.open_positions) says which open positions
+  take theirs now. Its K/V rows are overwritten by the next forward and
+  never read by another block;
+- the **commit forward** runs the decided block once more; its K/V stay,
+  and no head is computed.
+
+Which positions are open is a mask by position, carried from forward to
+forward: never a comparison of ids with the mask id, which a prompt may
+contain like any other. A line's first block may come partly decided: the
+prompt's tokens past its last whole block (``token0`` holds them in their
+places, -1 at an open position). Every later block starts all open, so a
+burst's input depends on nothing an earlier burst computed but the cache:
+the engine queues one behind the other with nothing handed over.
+
+A prompt's whole blocks are prefilled under the same block-causal mask
+(ops/prefill_attention.py, ``block``) and yield no token
+(``ServedModel.prefill_token``).
+
+The programs keep the engine's names (``prefill_chunk``, ``decode_burst``:
+a device trace shows ``jit_<name>``) and return their counts beside their
+result (:data:`COUNTERS`, int32[10], summed over layers, forwards and
+blocks); the scheduler adds them up where it fetches the tokens.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.llm.engine import ServedModel, init_kv_cache, sample_tokens
+from ray_tpu.models import sdar
+from ray_tpu.models.lfm2 import attention_heads
+from ray_tpu.models.routed import MOE_COUNTERS
+from ray_tpu.models.sdar import SdarConfig
+from ray_tpu.ops.decode_attention import (
+    decode_attention,
+    decode_kv_block,
+    decode_plan_of,
+    kv_row_write,
+)
+from ray_tpu.ops.kernels import KernelMesh
+from ray_tpu.ops.prefill_attention import prefill_attention, prefill_kv_write
+from ray_tpu.ops.rope import rope_frequencies
+from ray_tpu.util import tracing
+
+# Line-blocks run, line-forwards (commits among them), line-commits, and
+# the positions of first blocks that the prompt had decided.
+DIFFUSION_COUNTERS = ("diffusion_blocks", "diffusion_forwards",
+                      "diffusion_commits", "diffusion_given")
+COUNTERS = MOE_COUNTERS + DIFFUSION_COUNTERS
+
+
+def _ran(count, *names):
+    """int32[4] in the order of DIFFUSION_COUNTERS: ``count`` under each of
+    ``names``, added where the thing counted runs."""
+    return count * jnp.asarray([n in names for n in DIFFUSION_COUNTERS],
+                               jnp.int32)
+
+
+def _counts(moe, diffusion=None):
+    """int32[10] in the order of COUNTERS; a prefill's diffusion counts
+    are zeros."""
+    if diffusion is None:
+        diffusion = jnp.zeros((len(DIFFUSION_COUNTERS),), jnp.int32)
+    return jnp.concatenate([moe, diffusion])
+
+
+@partial(jax.jit, static_argnums=(0,), static_argnames=("kmesh",),
+         donate_argnums=(2,))
+def prefill_chunk(cfg: SdarConfig, params, cache, tokens, kv_len, length,
+                  slot, *, kmesh: KernelMesh | None = None):
+    """Prefill ONE chunk of one sequence's whole blocks (the engine's
+    contract, see llm/engine.prefill_chunk; ``kv_len`` and ``length`` are
+    multiples of the block). Returns (cache, None, counts): no row's logits
+    choose a token."""
+    c = tokens.shape[0]
+    with tracing.part("embed"):
+        x = params["embed_tokens"][tokens][None]              # [1, C, H]
+    with tracing.part("attn"):
+        positions = kv_len + jnp.arange(c)
+        valid = (positions < length)[None]
+        inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta)
+
+    def attention(layer, ap, xn, kv):
+        k_all, v_all = kv
+        q, k, v = attention_heads(cfg, ap, xn, positions, inv_freq)
+        with tracing.part("cache"):
+            k_all, v_all = prefill_kv_write(k_all, v_all, k[0], v[0], layer,
+                                            slot, kv_len)
+        o = prefill_attention(q[0], k_all, v_all, layer, slot, kv_len,
+                              length, kmesh=kmesh, block=cfg.block_length)
+        o = o.transpose(1, 0, 2).reshape(1, c, -1)
+        return (o @ ap["wo"]).astype(xn.dtype), (k_all, v_all)
+
+    _, (k_all, v_all), moe = sdar.run_layers(
+        cfg, params, x, attention, (cache["k"], cache["v"]), valid, kmesh)
+    return {"k": k_all, "v": v_all}, None, _counts(moe)
+
+
+def _forward(cfg: SdarConfig, params, cache, tokens, positions0, write_mask,
+             plan, head: bool, kmesh=None):
+    """One forward of every line's block: tokens [B, K] at positions
+    ``positions0 + arange(K)`` (the block's start, a multiple of K). Writes
+    the rows' K/V and attends each line through its block's end. Returns
+    (cache, float32 logits [B, K, V] or None without ``head``, the routed
+    layers' counts). A line with ``write_mask`` false writes nothing, is
+    routed nowhere, and its logits mean nothing."""
+    b, k = tokens.shape
+    with tracing.part("embed"):
+        x = params["embed_tokens"][tokens]                    # [B, K, H]
+    with tracing.part("attn"):
+        positions = positions0[:, None] + jnp.arange(k)[None, :]
+        lengths = jnp.where(write_mask, positions0 + k, 0)
+        valid = jnp.broadcast_to(write_mask[:, None], (b, k))
+        inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta)
+
+    def attention(layer, ap, xn, kv):
+        k_all, v_all = kv
+        q, kk, v = attention_heads(cfg, ap, xn, positions, inv_freq)
+        with tracing.part("cache"):
+            k_all, v_all = kv_row_write(k_all, v_all, kk, v, layer,
+                                        positions0, write_mask, kmesh=kmesh)
+        # The mask's position is the block's last: row j sees keys through
+        # positions0 + k - 1 + j, and none lies past the line's length.
+        o = decode_attention(q, k_all, v_all, layer, lengths,
+                             positions0 + (k - 1), plan=plan, kmesh=kmesh)
+        o = o.transpose(0, 2, 1, 3).reshape(b, k, -1)
+        return (o @ ap["wo"]).astype(xn.dtype), (k_all, v_all)
+
+    x, (k_all, v_all), moe = sdar.run_layers(
+        cfg, params, x, attention, (cache["k"], cache["v"]), valid, kmesh)
+    logits = sdar.lm_head(cfg, params, x, kmesh) if head else None
+    return {"k": k_all, "v": v_all}, logits, moe
+
+
+@tracing.part("sample")
+def _choose(logits, temps, top_ps, key, need_top_p: bool):
+    """logits [B, K, V] -> (the token each row's logits choose [B, K], by
+    the request's temperature and top-p or greedily, and its probability
+    under softmax(logits), float32 [B, K])."""
+    b, k, v = logits.shape
+    flat = logits.reshape(b * k, v)
+    x0 = sample_tokens(flat, jnp.repeat(temps, k), jnp.repeat(top_ps, k), 0,
+                       key, need_top_p).astype(jnp.int32)
+    chosen = jnp.take_along_axis(flat, x0[:, None], axis=-1)[:, 0]
+    confidence = jnp.exp(chosen - jax.nn.logsumexp(flat, axis=-1))
+    return x0.reshape(b, k), confidence.reshape(b, k)
+
+
+@partial(jax.jit, static_argnums=(0, 9, 10), static_argnames=("kmesh",),
+         donate_argnums=(2,))
+def decode_burst(cfg: SdarConfig, params, cache, token0, positions0,
+                 write_mask, temps, top_ps, key, steps: int,
+                 need_top_p: bool = True, *,
+                 kmesh: KernelMesh | None = None):
+    """``steps`` blocks of every line in ONE dispatch, each
+    ``denoising_steps`` denoising forwards and the commit. token0 [B, K]:
+    what the first block has decided already (a prompt's tail in its
+    places, -1 at an open position); positions0 [B]: the first block's
+    start. Returns (cache, tokens [steps, B, K], counts)."""
+    b, k = token0.shape
+    mask_id = jnp.int32(cfg.mask_token_id)
+
+    def block(carry, j):
+        cache, moe, diffusion = carry
+        pos = positions0 + j * k
+        with tracing.part("sample"):
+            is_open = (token0 < 0) | (j > 0)
+            tokens = jnp.where(is_open, mask_id, token0)
+        with tracing.part("attn"):
+            # Every forward of the block attends at the same lengths: one
+            # walk of the live blocks, planned here.
+            plan = decode_plan_of(jnp.where(write_mask, pos + k, 0),
+                                  cache["k"], kmesh=kmesh)
+            lines = write_mask.sum().astype(jnp.int32)
+
+        def denoise(carry, d):
+            cache, tokens, still_open, moe, diffusion = carry
+            cache, logits, n = _forward(cfg, params, cache, tokens, pos,
+                                        write_mask, plan, True, kmesh)
+            diffusion = diffusion + _ran(lines, "diffusion_forwards")
+            x0, confidence = _choose(
+                logits, temps, top_ps,
+                jax.random.fold_in(jax.random.fold_in(key, j), d),
+                need_top_p)
+            with tracing.part("sample"):
+                take = sdar.open_positions(cfg, confidence, still_open)
+                tokens = jnp.where(take, x0, tokens)
+                return (cache, tokens, still_open & ~take, moe + n,
+                        diffusion), None
+
+        with tracing.part("stack"):
+            (cache, tokens, _, moe, diffusion), _ = lax.scan(
+                denoise, (cache, tokens, is_open, moe, diffusion),
+                jnp.arange(cfg.denoising_steps))
+        cache, _, n = _forward(cfg, params, cache, tokens, pos, write_mask,
+                               plan, False, kmesh)
+        with tracing.part("sample"):
+            given = ((~is_open) & write_mask[:, None]).sum().astype(jnp.int32)
+            diffusion = (diffusion + _ran(given, "diffusion_given")
+                         + _ran(lines, "diffusion_blocks",
+                                "diffusion_forwards", "diffusion_commits"))
+            return (cache, moe + n, diffusion), tokens
+
+    with tracing.part("stack"):
+        (cache, moe, diffusion), toks = lax.scan(
+            block, (cache, jnp.zeros((len(MOE_COUNTERS),), jnp.int32),
+                    jnp.zeros((len(DIFFUSION_COUNTERS),), jnp.int32)),
+            jnp.arange(steps))
+    return cache, toks, _counts(moe, diffusion)
+
+
+def _refuse(config) -> None:
+    """What this model does not run, said at construction."""
+    for bad, what in (
+            (config.speculative_model is not None,
+             "a speculative draft: a step decides a block by forwards of "
+             "its own, there is no token to verify"),
+            (config.tensor_parallel_size > 1,
+             "tensor_parallel_size > 1: its programs run on one device")):
+        if bad:
+            raise ValueError(f"SdarConfig does not support {what}")
+
+
+SERVED = ServedModel(
+    init_params=sdar.init_params,
+    param_logical_axes=sdar.param_logical_axes,
+    init_cache=init_kv_cache,
+    prefill_chunk=prefill_chunk,
+    # A step samples between its forwards, on the device: a lone step is a
+    # burst of one.
+    decode_step=None,
+    decode_burst=decode_burst,
+    kv_block=lambda cfg, max_seq: decode_kv_block(
+        max_seq, cfg.head_dim, cfg.jnp_dtype.itemsize),
+    counters=COUNTERS,
+    constants=lambda cfg: {"moe_experts_held": cfg.num_experts,
+                           "attention_lines": cfg.num_layers,
+                           "diffusion_block_length": cfg.block_length},
+    step=lambda cfg: (cfg.block_length, cfg.denoising_steps + 1),
+    # A line's committed blocks could be adopted at block-aligned lengths
+    # and shipped as per-head K/V; neither is done (ROADMAP R6).
+    kv_handoff=False,
+    prefix_from_line=False,
+    refuse=_refuse,
+)

@@ -10,6 +10,7 @@ llm_config.py:141). The engine here is the JAX continuous-batching engine
 from __future__ import annotations
 
 import json
+import queue
 import threading
 import time
 from typing import Any
@@ -77,14 +78,16 @@ class LLMServer:
         prompt = self.engine.tokenizer.apply_chat_template(messages)
         req = self.engine.submit(prompt, sampling, stream=True)
         rid = f"chatcmpl-{req.request_id}"
-        for item in self._stream_tokens(req):
+        def frame(item):
             delta = self.engine.tokenizer.decode([item])
-            frame = {"id": rid, "object": "chat.completion.chunk",
-                     "model": self._model_id,
-                     "choices": [{"index": 0,
-                                  "delta": {"content": delta},
-                                  "finish_reason": None}]}
-            yield f"data: {json.dumps(frame)}\n\n"
+            return {"id": rid, "object": "chat.completion.chunk",
+                    "model": self._model_id,
+                    "choices": [{"index": 0,
+                                 "delta": {"content": delta},
+                                 "finish_reason": None}]}
+
+        for items in self._stream_tokens(req):
+            yield "".join(f"data: {json.dumps(frame(i))}\n\n" for i in items)
         done = {"id": rid, "object": "chat.completion.chunk",
                 "model": self._model_id,
                 "choices": [{"index": 0, "delta": {},
@@ -96,13 +99,15 @@ class LLMServer:
         sampling = _sampling_from(kw)
         req = self.engine.submit(prompt, sampling, stream=True)
         rid = f"cmpl-{req.request_id}"
-        for item in self._stream_tokens(req):
-            frame = {"id": rid, "object": "text_completion",
-                     "model": self._model_id,
-                     "choices": [{"index": 0,
-                                  "text": self.engine.tokenizer.decode([item]),
-                                  "finish_reason": None}]}
-            yield f"data: {json.dumps(frame)}\n\n"
+        def frame(item):
+            return {"id": rid, "object": "text_completion",
+                    "model": self._model_id,
+                    "choices": [{"index": 0,
+                                 "text": self.engine.tokenizer.decode([item]),
+                                 "finish_reason": None}]}
+
+        for items in self._stream_tokens(req):
+            yield "".join(f"data: {json.dumps(frame(i))}\n\n" for i in items)
         done = {"id": rid, "object": "text_completion",
                 "model": self._model_id,
                 "choices": [{"index": 0, "text": "",
@@ -111,19 +116,34 @@ class LLMServer:
         yield "data: [DONE]\n\n"
 
     def _stream_tokens(self, req):
-        """The request's tokens as the engine emits them."""
+        """The request's tokens as the engine emits them: each yield is the
+        tokens that are there now, one or more (the engine emits a burst's
+        tokens of a line together, a block's four or a burst's eight). The
+        caller writes one SSE frame a token, and the frames of one yield as
+        one chunk: what a chunk costs on its way to the client (the
+        replica's streaming call, the proxy's write) is paid once a burst
+        and not once a token."""
         first = True
         while True:
-            item = req.stream_queue.get()
-            if item is None:
+            items = [req.stream_queue.get()]
+            try:
+                while items[-1] is not None:
+                    items.append(req.stream_queue.get_nowait())
+            except queue.Empty:
+                pass
+            ended = items[-1] is None
+            if ended:
+                items.pop()
+            if items:
+                if first:
+                    first = False
+                    lag = time.time() - req.first_token_ts
+                    with self._lag_lock:
+                        self.first_frames += 1
+                        self.first_frame_lag_s += lag
+                yield items
+            if ended:
                 return
-            if first:
-                first = False
-                lag = time.time() - req.first_token_ts
-                with self._lag_lock:
-                    self.first_frames += 1
-                    self.first_frame_lag_s += lag
-            yield item
 
     def stats(self) -> dict:
         with self._lag_lock:

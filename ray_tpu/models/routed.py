@@ -1,5 +1,5 @@
 """The routed expert layer that keeps every token, shared by the models
-that have one (models/longcat.py, models/lfm2.py).
+that have one (models/longcat.py, models/lfm2.py, models/sdar.py).
 
 What differs between the families is the router's rule, and a
 :class:`RouterRule` states it: how a router output becomes a score (softmax
@@ -50,7 +50,8 @@ class RouterRule:
     topk: int                     # experts a token
     score: str = "softmax"        # or "sigmoid"
     use_bias: bool = True         # added to the score for the choice alone
-    renormalize: bool = False     # chosen weights / (their sum + 1e-6)
+    renormalize: bool = False     # chosen weights / (their sum + renorm_eps)
+    renorm_eps: float = 1e-6      # 0 where the family's code adds nothing
     scaling_factor: float = 1.0
     zero_experts: int = 0         # router outputs after the routed experts
     expert_shard: int = 0
@@ -108,7 +109,7 @@ def route(rule: RouterRule, router, bias, u):
     _, idx = lax.top_k(p + bias if rule.use_bias else p, rule.topk)
     w = jnp.take_along_axis(p, idx, axis=-1)
     if rule.renormalize:
-        w = w / (w.sum(axis=-1, keepdims=True) + 1e-6)
+        w = w / (w.sum(axis=-1, keepdims=True) + rule.renorm_eps)
     return idx, rule.scaling_factor * w
 
 
@@ -152,8 +153,8 @@ def dispatch_plan(keys, held: int, tm: int):
 def moe_block(rule: RouterRule, layers: dict, layer, u, valid):
     """The routed layer on u [T, H]: this shard's experts' part and the
     zero experts' part of ``sum_e w_e E_e(u)``. ``layers`` holds the stacked
-    ``router``, ``router_bias``, ``we_gate``, ``we_up`` and ``we_down`` (the
-    expert stacks are read in place), ``layer`` is the routed layer's index
+    ``router``, ``router_bias`` (a rule with ``use_bias``), ``we_gate``,
+    ``we_up`` and ``we_down`` (the expert stacks are read in place), ``layer`` is the routed layer's index
     on their leading axis. A token with ``valid`` false
     (padding, an idle slot) is routed nowhere and counted nowhere. Returns
     (y [T, H], counts int32[6] in the order of MOE_COUNTERS)."""
@@ -162,7 +163,8 @@ def moe_block(rule: RouterRule, layers: dict, layer, u, valid):
     tm = row_tile(t, topk, rule.outputs)
     with tracing.part("moe_route"):
         idx, w = route(rule, layer_of(layers["router"], layer),
-                       layer_of(layers["router_bias"], layer), u)
+                       layer_of(layers["router_bias"], layer)
+                       if rule.use_bias else None, u)
         lo = rule.expert_shard * held
         chosen = valid[:, None]
         local = chosen & (idx >= lo) & (idx < lo + held)

@@ -29,6 +29,12 @@ before the second matmul. Query t sees key positions ``<= kv_len + t`` and
 ``< length``; a row that sees nothing gives zeros. Rows of a padded chunk
 past the prompt's end see what the last real row sees, and mean nothing.
 
+``block`` (static, 1 for a causal model) makes the mask block-causal: the
+positions are cut into blocks of that many and a query sees every key of
+its own block and of the blocks before it, so query t sees through the last
+position of the block that holds ``kv_len + t`` (models/sdar.py). At 1 the
+block is the position and every program is what it was.
+
 The three implementations of ops/kernels.py: the Mosaic kernel on a TPU,
 the same body through the Pallas interpreter for tests, and a jnp reference
 elsewhere (the dense ``[C, max_seq]`` form the engine used to run).
@@ -68,8 +74,16 @@ def prefill_q_block(chunk: int, group: int, itemsize: int = 2) -> int:
     return min(cap, -(-chunk // tile) * tile)
 
 
+def _last_seen(qpos, block: int):
+    """The last key position a query at ``qpos`` may see."""
+    if block == 1:
+        return qpos
+    return qpos + (block - 1 - lax.rem(qpos, block))
+
+
 def prefill_attention_reference(q, k_cache, v_cache, layer, slot, kv_len,
-                                length, sm_scale: float | None = None):
+                                length, sm_scale: float | None = None,
+                                block: int = 1):
     """Masked softmax over the slot's whole line, grouped like the kernel
     (no repeated K/V), float32 scores and accumulation."""
     h, c, d = q.shape
@@ -89,7 +103,7 @@ def prefill_attention_reference(q, k_cache, v_cache, layer, slot, kv_len,
                         preferred_element_type=jnp.float32) * scale
     kpos = jnp.arange(s)[None, :]
     qpos = kv_len + jnp.arange(c)[:, None]
-    visible = (kpos <= qpos) & (kpos < length)                # [C, S]
+    visible = (kpos <= _last_seen(qpos, block)) & (kpos < length)  # [C, S]
     visible = jnp.tile(visible, (h // hkv, 1))[None]          # rows g*C + t
     scores = jnp.where(visible, scores, NEG_INF)
     p = jnp.where(visible,
@@ -100,14 +114,17 @@ def prefill_attention_reference(q, k_cache, v_cache, layer, slot, kv_len,
     return out.astype(q.dtype).reshape(h, c, d)
 
 
-def _tile_end(kv_len, limit, tile, block_q: int):
+def _tile_end(kv_len, limit, tile, block_q: int, block: int = 1):
     """One past the last key position any query of tile ``tile`` sees."""
-    return jnp.minimum(kv_len + (tile + 1) * block_q, limit)
+    end = kv_len + (tile + 1) * block_q
+    if block > 1:   # through the block of the tile's last query
+        end = _last_seen(end - 1, block) + 1
+    return jnp.minimum(end, limit)
 
 
 def _prefill_attention_kernel(sc_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
                               l_ref, acc_ref, *, block_q: int, block_k: int,
-                              sm_scale: float):
+                              sm_scale: float, block: int = 1):
     from jax.experimental import pallas as pl
 
     # sc_ref: layer, slot (read by the index maps), kv_len, limit.
@@ -116,7 +133,7 @@ def _prefill_attention_kernel(sc_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
     group, _, d = q_ref.shape
     rows = group * block_q
     q0 = kv_len + tile * block_q          # position of the tile's first query
-    end = _tile_end(kv_len, limit, tile, block_q)
+    end = _tile_end(kv_len, limit, tile, block_q, block)
 
     @pl.when(blk == 0)
     def _():
@@ -134,7 +151,7 @@ def _prefill_attention_kernel(sc_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
                 jnp.int32, (rows, block_k), 1)
             tok = lax.rem(lax.broadcasted_iota(jnp.int32, (rows, block_k), 0),
                           block_q)
-            visible = (kpos <= q0 + tok) & (kpos < limit)
+            visible = (kpos <= _last_seen(q0 + tok, block)) & (kpos < limit)
             s = jnp.where(visible, s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -171,7 +188,7 @@ def _prefill_attention_kernel(sc_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
 def _prefill_attention_pallas(q, k_cache, v_cache, layer, slot, kv_len,
                               length, *, sm_scale: float,
                               block_q: int | None = None,
-                              block_k: int | None = None):
+                              block_k: int | None = None, block: int = 1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -198,7 +215,7 @@ def _prefill_attention_pallas(q, k_cache, v_cache, layer, slot, kv_len,
                          limit.astype(jnp.int32)])
 
     def kv_index(i, t, j, sc):
-        end = _tile_end(sc[2], sc[3], t, block_q)
+        end = _tile_end(sc[2], sc[3], t, block_q, block)
         last_live = jnp.maximum(pl.cdiv(end, block_k) - 1, 0)
         return (sc[0], sc[1], i, jnp.minimum(j, last_live), 0)
 
@@ -209,7 +226,7 @@ def _prefill_attention_pallas(q, k_cache, v_cache, layer, slot, kv_len,
     kv_spec = pl.BlockSpec((None, None, None, block_k, d), kv_index)
     q_spec = pl.BlockSpec((None, group, block_q, d), q_index)
     kernel = functools.partial(_prefill_attention_kernel, block_q=block_q,
-                               block_k=block_k, sm_scale=sm_scale)
+                               block_k=block_k, sm_scale=sm_scale, block=block)
     out = pl.pallas_call(
         packed_kernel(kernel, 1) if packed else kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -236,21 +253,22 @@ def prefill_attention(q, k_cache, v_cache, layer, slot, kv_len, length, *,
                       sm_scale: float | None = None,
                       kmesh: KernelMesh | None = None,
                       block_q: int | None = None,
-                      block_k: int | None = None):
+                      block_k: int | None = None, block: int = 1):
     """q: [H, C, D], the chunk's queries at positions ``kv_len + arange(C)``
     (query head h of KV head ``h // (H // Hkv)``); k_cache, v_cache:
     [L, B, Hkv, S, D], or the packed stack [L, B, Hkv, S, 2 D] and None
     (ops/decode_attention.py), the chunk's rows already written; layer, slot, kv_len,
     length: int32 scalars. Returns [H, C, D]. ``block_q`` and ``block_k``
     override :func:`prefill_q_block` and ``decode_kv_block`` (tests and the
-    kernel's own benchmark). Under a mesh of several devices pass its
+    kernel's own benchmark); ``block`` > 1 is the block-causal mask of the
+    module's docstring. Under a mesh of several devices pass its
     ``kmesh``: the kernel then runs on each device's heads."""
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if kernel_backend() == "reference":
         return prefill_attention_reference(q, k_cache, v_cache, layer, slot,
-                                           kv_len, length, scale)
+                                           kv_len, length, scale, block)
     fn = functools.partial(_prefill_attention_pallas, sm_scale=scale,
-                           block_q=block_q, block_k=block_k)
+                           block_q=block_q, block_k=block_k, block=block)
     if kmesh is not None:
         heads = P(kmesh.heads, None, None)
         # One slot's line: the slots reach every device whole.

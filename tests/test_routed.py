@@ -9,6 +9,7 @@ import pytest
 from ray_tpu.models import routed
 from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.longcat import LongcatConfig
+from ray_tpu.models.sdar import SdarConfig
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.kernels import force_kernel_backend
 
@@ -28,14 +29,21 @@ RULES = {
         hidden_size=H, moe_intermediate_size=F, num_experts=8,
         num_experts_per_tok=3, use_expert_bias=False,
         routed_scaling_factor=1.5).router_rule,
+    # SDAR's (Qwen3-MoE's): softmax, no bias, renormalised with nothing
+    # added to the sum, factor 1, every expert held.
+    "sdar": SdarConfig.tiny(hidden_size=H, moe_intermediate_size=F,
+                            num_experts=8,
+                            num_experts_per_tok=3).router_rule,
 }
 
 
 def _layers(rule, key):
     keys = jax.random.split(key, 5)
     normal = lambda k, *shape: jax.random.normal(k, shape, jnp.float32)  # noqa: E731
+    bias = {"router_bias": 0.2 * normal(keys[1], LAYERS, rule.outputs)}
+    # a rule without a bias is handed no such leaf, and asks for none
     return {"router": normal(keys[0], LAYERS, H, rule.outputs) / np.sqrt(H),
-            "router_bias": 0.2 * normal(keys[1], LAYERS, rule.outputs),
+            **(bias if rule.use_bias else {}),
             "we_gate": normal(keys[2], LAYERS, rule.held, H, F) / np.sqrt(H),
             "we_up": normal(keys[3], LAYERS, rule.held, H, F) / np.sqrt(H),
             "we_down": normal(keys[4], LAYERS, rule.held, F, H) / np.sqrt(F)}
@@ -56,7 +64,7 @@ def _dense(rule, layers, layer, u, valid):
     idx = np.argsort(-by, axis=-1, kind="stable")[:, :rule.topk]
     w = np.take_along_axis(s, idx, axis=-1)
     if rule.renormalize:
-        w = w / (w.sum(-1, keepdims=True) + 1e-6)
+        w = w / (w.sum(-1, keepdims=True) + rule.renorm_eps)
     weights = np.zeros_like(s)
     np.put_along_axis(weights, idx, w * rule.scaling_factor, axis=-1)
     weights *= np.asarray(valid)[:, None]
@@ -108,6 +116,8 @@ def test_the_shared_layer_is_the_dense_sum_under_each_family_s_rule(name,
     (32, 12, 768, 16),     # its decode step: 0.5
     (128, 4, 64, 16), (129, 4, 64, 32), (256, 4, 64, 32), (257, 4, 64, 64),
     (1024, 4, 64, 128), (8192, 8, 64, 128),    # the largest where none holds
+    (512, 8, 128, 64),     # SDAR's forward of 128 lines x 4 rows: 32
+    (256, 8, 128, 32),     # its chunk of 256: 16
 ])
 def test_the_row_tile_holds_twice_the_mean_fill(tokens, topk, outputs, want):
     assert routed.row_tile(tokens, topk, outputs) == want
@@ -193,6 +203,26 @@ def test_the_shared_layer_is_the_dense_sum_at_the_tile_its_fill_picks(
     n_valid = int(valid.sum())
     assert want_counts[5] >= -(-n_valid // tm) + want_counts[3] - 1
     assert want_counts[3] < rule.held            # expert 2 got no row
+
+
+def test_the_softmax_rule_without_bias_sums_to_one_exactly():
+    """SDAR's rule: the chosen weights are the softmax at the chosen over
+    their sum, with nothing added to it (LFM2's adds 1e-6, and says so)."""
+    rule = RULES["sdar"]
+    assert (rule.score, rule.use_bias, rule.renormalize, rule.renorm_eps) \
+        == ("softmax", False, True, 0.0)
+    assert RULES["lfm2"].renorm_eps == 1e-6
+    gate = jax.random.normal(jax.random.PRNGKey(0), (H, rule.outputs))
+    u = jax.random.normal(jax.random.PRNGKey(1), (TOKENS, H))
+    idx, w = routed.route(rule, gate, None, u)
+    p = jax.nn.softmax(np.asarray(u, np.float64) @ np.asarray(gate, np.float64))
+    want = np.argsort(-np.asarray(p), axis=-1, kind="stable")[:, :rule.topk]
+    np.testing.assert_array_equal(np.asarray(idx), want)
+    picked = np.take_along_axis(np.asarray(p), want, axis=-1)
+    np.testing.assert_allclose(np.asarray(w),
+                               picked / picked.sum(-1, keepdims=True),
+                               rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(w).sum(-1), 1.0, rtol=1e-6)
 
 
 def test_a_rule_refuses_what_it_cannot_be():

@@ -1,0 +1,580 @@
+"""The SDAR-MoE family: ``models/sdar.py`` and ``llm/sdar_serving.py``
+through the one ``llm/engine.py``, against the plain reference of the
+benchmark, at a small size on the CPU.
+
+What is held here is what the family adds to the repository: a step that
+decides a block of 4 positions by 5 forwards (the scheduler counts in such
+steps, a prompt's tail rides into the first one, a request's surplus is not
+emitted), a prefill that yields no token, the block-causal mask in both
+attention ops, and the three rules by which a block's positions take their
+tokens.
+"""
+
+import os
+import sys
+from dataclasses import replace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.llm import LLMConfig
+from ray_tpu.llm import sdar_serving as serving
+from ray_tpu.llm.config import SamplingParams
+from ray_tpu.llm.engine import LLMEngine, require_kv_handoff, served_model
+from ray_tpu.models import sdar
+from ray_tpu.models.sdar import RULES, SdarConfig
+from ray_tpu.ops.decode_attention import (
+    decode_attention,
+    decode_attention_reference,
+)
+from ray_tpu.ops.kernels import force_kernel_backend
+from ray_tpu.ops.prefill_attention import (
+    prefill_attention,
+    prefill_attention_reference,
+)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from reference import sdar as reference  # noqa: E402
+from rtbench.adapters import sdar as adapter  # noqa: E402
+
+CFG = SdarConfig.tiny(max_seq_len=64)
+MASK = CFG.mask_token_id
+SLOTS, MAX_SEQ, CHUNK = 3, 64, 16
+
+
+def config_json(cfg: SdarConfig) -> dict:
+    """The benchmark's configuration keys for ``cfg``."""
+    return {"hidden_size": cfg.hidden_size, "head_dim": cfg.head_dim,
+            "num_hidden_layers": cfg.num_layers,
+            "num_attention_heads": cfg.num_heads,
+            "num_key_value_heads": cfg.num_kv_heads,
+            "rms_norm_eps": cfg.norm_eps, "rope_theta": cfg.rope_theta,
+            "num_experts": cfg.num_experts,
+            "num_experts_per_tok": cfg.num_experts_per_tok,
+            "norm_topk_prob": cfg.norm_topk_prob,
+            "block_length": cfg.block_length,
+            "denoising_steps": cfg.denoising_steps,
+            "remasking_strategy": cfg.remasking_strategy,
+            "confidence_threshold": cfg.confidence_threshold,
+            "mask_token_id": cfg.mask_token_id}
+
+
+@pytest.fixture(scope="module")
+def params():
+    return sdar.init_params(CFG, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def weights(params):
+    return adapter.reference_weights(params)
+
+
+def _prompt(n: int, salt: int = 0) -> list[int]:
+    return [int(t) for t in np.random.RandomState(100 * salt + n).randint(
+        259, CFG.vocab_size, n)]
+
+
+def _want(weights, rule, prompt, n):
+    return reference.generate(config_json(CFG), weights, prompt, n, rule)
+
+
+# ---- the model --------------------------------------------------------------
+
+def test_forward_matches_the_reference(params, weights):
+    tokens = np.asarray(_prompt(23), np.int32)
+    got, counts = jax.jit(sdar.forward, static_argnums=0)(
+        CFG, params, jnp.asarray(tokens)[None])
+    want = reference.forward(config_json(CFG), weights, tokens)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
+                               atol=1e-4)
+    # every row of every layer picks its experts, all of them held here
+    assert int(counts[0]) == int(counts[1]) == \
+        23 * CFG.num_experts_per_tok * CFG.num_layers
+    assert int(counts[4]) == CFG.num_layers
+
+
+def test_the_mask_lets_a_block_see_itself_and_nothing_after(params):
+    """Changing a token changes the logits of its own block's rows and of
+    every later row, and of no row of an earlier block."""
+    fwd = jax.jit(sdar.forward, static_argnums=0)
+    tokens = np.asarray(_prompt(16), np.int32)
+    base = np.asarray(fwd(CFG, params, jnp.asarray(tokens)[None])[0][0])
+    tokens[9] = (tokens[9] + 1) % CFG.vocab_size           # block 2: 8..11
+    moved = np.abs(np.asarray(
+        fwd(CFG, params, jnp.asarray(tokens)[None])[0][0]) - base).max(-1)
+    assert not moved[:8].any()
+    assert (moved[8:] > 1e-6).all()
+
+
+def test_the_published_defaults_and_the_rule():
+    cfg = SdarConfig()
+    assert (cfg.num_layers, cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
+            cfg.head_dim) == (48, 2048, 32, 4, 128)
+    assert (cfg.num_experts, cfg.num_experts_per_tok,
+            cfg.moe_intermediate_size, cfg.vocab_size) == (128, 8, 768, 151936)
+    assert (cfg.block_length, cfg.denoising_steps, cfg.opened_a_forward) == \
+        (4, 4, 1)
+    rule = cfg.router_rule
+    assert (rule.score, rule.use_bias, rule.renormalize, rule.renorm_eps,
+            rule.outputs, rule.topk, rule.held) == \
+        ("softmax", False, True, 0.0, 128, 8, 128)
+    # 30.5B parameters published; a layer is 623,120,640
+    assert cfg.num_params() == 48 * 623_120_640 + 2 * 311_164_928 + 2048
+    assert replace(cfg, num_layers=6).num_params() == 4_361_055_744
+
+
+@pytest.mark.parametrize("kw,message", [
+    ({"remasking_strategy": "random"}, "remasking_strategy"),
+    ({"denoising_steps": 3}, "do not divide"),
+    ({"mask_token_id": 512}, "outside the vocabulary"),
+], ids=["rule", "steps", "mask"])
+def test_a_configuration_that_is_no_sdar_is_refused_at_construction(
+        kw, message):
+    with pytest.raises(ValueError, match=message):
+        SdarConfig.tiny(**kw)
+
+
+def _open(rule, confidence, is_open, **kw):
+    cfg = SdarConfig.tiny(remasking_strategy=rule, **kw)
+    return np.asarray(sdar.open_positions(
+        cfg, jnp.asarray(confidence, jnp.float32),
+        jnp.asarray(is_open))).astype(int).tolist()
+
+
+def test_the_three_rules_open_what_they_say():
+    conf = [[0.2, 0.95, 0.5, 0.97], [0.3, 0.3, 0.1, 0.3]]
+    is_open = [[True, True, False, True], [False, True, True, True]]
+    assert _open("sequential", conf, is_open) == [[1, 0, 0, 0], [0, 1, 0, 0]]
+    # the highest confidence among the open; the earlier of two equal
+    assert _open("low_confidence_static", conf, is_open) == \
+        [[0, 0, 0, 1], [0, 1, 0, 0]]
+    # every open position over the threshold, the static choice where none
+    assert _open("low_confidence_dynamic", conf, is_open) == \
+        [[0, 1, 0, 1], [0, 1, 0, 0]]
+    # two a forward: the two leftmost, the two best
+    assert _open("sequential", conf, is_open, denoising_steps=2) == \
+        [[1, 1, 0, 0], [0, 1, 1, 0]]
+    assert _open("low_confidence_static", conf, is_open,
+                 denoising_steps=2) == [[0, 1, 0, 1], [0, 1, 0, 1]]
+    # nothing open: nothing opened
+    assert _open("low_confidence_static", conf, [[False] * 4] * 2) == \
+        [[0] * 4] * 2
+
+
+# ---- the two attention ops --------------------------------------------------
+
+def _dense_attention(q, k, v, visible):
+    """softmax(q k / sqrt(d)) v under a dense mask, in float64: q
+    [H, R, D], k and v [Hkv, S, D], visible [R, S]."""
+    h, hkv = q.shape[0], k.shape[0]
+    k, v = (np.repeat(np.asarray(a, np.float64), h // hkv, axis=0)
+            for a in (k, v))
+    s = np.einsum("hrd,hsd->hrs", np.asarray(q, np.float64), k) \
+        / np.sqrt(q.shape[-1])
+    s = np.where(visible[None], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return np.einsum("hrs,hsd->hrd", p / p.sum(-1, keepdims=True), v)
+
+
+@pytest.mark.parametrize("backend", ["reference", "interpret"])
+@pytest.mark.parametrize("block", [1, 4])
+def test_prefill_attention_s_diagonal_in_blocks(backend, block):
+    """A chunk of 16 after 8 cached rows, of a prompt of 20: query t sees
+    the keys through its block's end and below the prompt's length. At
+    ``block`` 1 that is the causal mask, by the same code."""
+    h, hkv, d, c, s, kv_len, length = 4, 2, 16, 16, 64, 8, 20
+    key = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(key[0], (h, c, d), jnp.float32)
+    k_cache = jax.random.normal(key[1], (2, SLOTS, hkv, s, d), jnp.float32)
+    v_cache = jax.random.normal(key[2], (2, SLOTS, hkv, s, d), jnp.float32)
+    qpos = kv_len + np.arange(c)
+    visible = ((np.arange(s)[None] // block <= qpos[:, None] // block)
+               & (np.arange(s)[None] < length))
+    want = _dense_attention(q, k_cache[1, 2], v_cache[1, 2], visible)
+    with force_kernel_backend(backend):
+        got = prefill_attention(q, k_cache, v_cache, 1, 2, kv_len, length,
+                                block=block, block_k=16)
+    rows = length - kv_len      # the padded rows past the prompt mean nothing
+    np.testing.assert_allclose(np.asarray(got)[:, :rows], want[:, :rows],
+                               atol=1e-5)
+    if block == 1:
+        with force_kernel_backend(backend):
+            default = prefill_attention(q, k_cache, v_cache, 1, 2, kv_len,
+                                        length, block_k=16)
+        np.testing.assert_array_equal(np.asarray(default), np.asarray(got))
+        np.testing.assert_array_equal(
+            np.asarray(prefill_attention_reference(
+                q, k_cache, v_cache, 1, 2, kv_len, length)),
+            np.asarray(prefill_attention_reference(
+                q, k_cache, v_cache, 1, 2, kv_len, length, block=1)))
+
+
+@pytest.mark.parametrize("backend", ["reference", "interpret"])
+def test_four_rows_a_line_all_see_their_block_through_decode_attention(
+        backend):
+    """The mask's position at the block's last: every one of the 4 rows of
+    a line sees the line through the block's end, a line of length 0
+    nothing."""
+    b, h, hkv, d, k, s = 3, 4, 2, 16, 4, 64
+    key = jax.random.split(jax.random.PRNGKey(1), 3)
+    q = jax.random.normal(key[0], (b, h, k, d), jnp.float32)
+    k_cache = jax.random.normal(key[1], (2, b, hkv, s, d), jnp.float32)
+    v_cache = jax.random.normal(key[2], (2, b, hkv, s, d), jnp.float32)
+    start = jnp.asarray([12, 0, 40], jnp.int32)
+    write = jnp.asarray([True, True, False])
+    lengths = jnp.where(write, start + k, 0)
+    with force_kernel_backend(backend):
+        got = np.asarray(decode_attention(q, k_cache, v_cache, 1, lengths,
+                                          start + (k - 1), block=16))
+    for line in range(2):
+        visible = np.broadcast_to(
+            np.arange(s)[None] < int(lengths[line]), (k, s))
+        # rows of the op are [H, K, D]; of the dense form, head-major too
+        want = _dense_attention(q[line], k_cache[1, line], v_cache[1, line],
+                                visible)
+        np.testing.assert_allclose(got[line], want, atol=1e-5)
+    assert not got[2].any()
+    np.testing.assert_allclose(
+        got, np.asarray(decode_attention_reference(
+            q, k_cache, v_cache, 1, lengths, start + (k - 1))), atol=1e-5)
+
+
+# ---- the programs -----------------------------------------------------------
+
+def _prefill(params, tokens, slot=0, cache=None, chunk=CHUNK):
+    """The whole blocks of ``tokens`` into ``slot``, chunk by chunk."""
+    cache = cache if cache is not None else serving.init_kv_cache(
+        CFG, SLOTS, MAX_SEQ)
+    whole = len(tokens) - len(tokens) % CFG.block_length
+    for at in range(0, whole, chunk):
+        toks = np.zeros((chunk,), np.int32)
+        take = min(chunk, whole - at)
+        toks[:take] = tokens[at:at + take]
+        cache, logits, counts = serving.prefill_chunk(
+            CFG, params, cache, jnp.asarray(toks), jnp.int32(at),
+            jnp.int32(whole), jnp.int32(slot))
+        assert logits is None and counts.shape == (len(serving.COUNTERS),)
+    return cache
+
+
+def _burst(params, cache, given: dict, starts: dict, steps, cfg=CFG):
+    """A burst over the lines of ``starts`` (slot -> block start); ``given``
+    (slot -> the tokens its first block has decided)."""
+    k = cfg.block_length
+    tok = np.full((SLOTS, k), -1, np.int32)
+    pos = np.zeros((SLOTS,), np.int32)
+    write = np.zeros((SLOTS,), bool)
+    for slot, start in starts.items():
+        pos[slot], write[slot] = start, True
+        tok[slot, :len(given.get(slot, []))] = given.get(slot, [])
+    zeros, ones = jnp.zeros((SLOTS,)), jnp.ones((SLOTS,))
+    return serving.decode_burst(
+        cfg, params, cache, jnp.asarray(tok), jnp.asarray(pos),
+        jnp.asarray(write), zeros, ones, jax.random.PRNGKey(0), steps, False)
+
+
+@pytest.mark.parametrize("backend", ["reference", "interpret"])
+def test_a_burst_decides_blocks_as_the_reference_does_and_commits_them(
+        params, weights, backend):
+    """Two lines at different depths and an idle one in one burst of two
+    blocks, the kernels' own bodies included: the tokens are the
+    reference's, the idle line's cache is untouched, and after the commit a
+    line's K/V are those of the clean pass over what it now holds."""
+    a, b = _prompt(22), _prompt(9, salt=1)
+    with force_kernel_backend(backend):
+        cache = _prefill(params, a, slot=0)
+        cache = _prefill(params, b, slot=2, cache=cache)
+        cache = _prefill(params, _prompt(12, salt=2), slot=1, cache=cache)
+        held = np.asarray(cache["k"][:, 1]), np.asarray(cache["v"][:, 1])
+        cache, toks, counts = _burst(params, cache, {0: a[20:], 2: b[8:]},
+                                     {0: 20, 2: 8}, steps=2)
+        toks = np.asarray(toks)
+        assert toks.shape == (2, SLOTS, 4)
+        out_a = toks[:, 0].reshape(-1).tolist()
+        out_b = toks[:, 2].reshape(-1).tolist()
+        # a first block gives the prompt's tail back in its places
+        assert out_a[:2] == a[20:] and out_b[:1] == b[8:]
+        assert out_a[2:] == _want(weights, "sequential", a, 6)
+        assert out_b[1:] == _want(weights, "sequential", b, 7)
+        np.testing.assert_array_equal(np.asarray(cache["k"][:, 1]), held[0])
+        np.testing.assert_array_equal(np.asarray(cache["v"][:, 1]), held[1])
+        # the clean pass over the whole of line 0, into the idle slot
+        clean = _prefill(params, a[:20] + out_a, slot=1,
+                         cache=jax.tree.map(jnp.copy, cache), chunk=32)
+    for leaf in ("k", "v"):
+        np.testing.assert_allclose(
+            np.asarray(cache[leaf][:, 0, :, :28]),
+            np.asarray(clean[leaf][:, 1, :, :28]), atol=1e-5)
+    counts = dict(zip(serving.COUNTERS, np.asarray(counts).tolist()))
+    assert counts["diffusion_blocks"] == counts["diffusion_commits"] == 4
+    assert counts["diffusion_forwards"] == 20 and counts["diffusion_given"] == 3
+    assert counts["moe_layer_steps"] == 2 * 5 * CFG.num_layers
+    assert counts["moe_picks"] == \
+        20 * 4 * CFG.num_experts_per_tok * CFG.num_layers
+
+
+def test_the_counters_count_the_forwards_that_ran(params):
+    """Forwards are counted where a forward runs and commits where the
+    commit does: at 2 denoising forwards a block the same code reads 3
+    forwards a block, a third of them commits, with no edit of a formula."""
+    cfg = replace(CFG, denoising_steps=2)
+    a = _prompt(9)
+    cache = _prefill(params, a)
+    _, _, counts = _burst(params, cache, {0: a[8:]}, {0: 8}, steps=2,
+                          cfg=cfg)
+    counts = dict(zip(serving.COUNTERS, np.asarray(counts).tolist()))
+    assert counts["diffusion_blocks"] == counts["diffusion_commits"] == 2
+    assert counts["diffusion_forwards"] == 6
+    assert counts["moe_layer_steps"] == 6 * cfg.num_layers
+
+
+def test_open_positions_are_tracked_by_place_not_by_the_mask_s_id(params,
+                                                                  weights):
+    """A prompt whose tail *is* the mask id, and one with it inside."""
+    a = _prompt(9)
+    a[8] = MASK
+    a[3] = MASK
+    cache = _prefill(params, a)
+    _, toks, counts = _burst(params, cache, {0: a[8:]}, {0: 8}, steps=1)
+    out = np.asarray(toks)[0, 0].tolist()
+    assert out[0] == MASK
+    assert out[1:] == _want(weights, "sequential", a, 3)
+    assert int(counts[len(serving.COUNTERS) - 1]) == 1
+
+
+# ---- the reference's logits -------------------------------------------------
+
+@pytest.mark.parametrize("p", [8, 10, 3])
+def test_the_reference_s_logits_are_those_its_generation_chose_by(weights,
+                                                                  p):
+    """``logits`` is given a finished sequence and nothing else (not the
+    prompt's length), padded as the harness pads it: row ``i - 1`` is the
+    row that chose position ``i``'s token under the sequential rule."""
+    c = config_json(CFG)
+    prompt, n, trace = _prompt(p, salt=3), 9, []
+    out = reference.generate(c, weights, prompt, n, "sequential", trace)
+    seq = prompt + out
+    rows = np.asarray(reference.logits(
+        c, weights, jnp.asarray(seq + [0] * (-len(seq) % 16), jnp.int32)))
+    assert len(trace) >= n
+    for position, chose in trace:
+        if position >= len(seq):
+            continue           # the last block's surplus
+        np.testing.assert_allclose(rows[position - 1], np.asarray(chose),
+                                   atol=1e-4)
+        assert int(rows[position - 1].argmax()) == seq[position]
+    # and the harness's own comparison reads 0 on the reference's tokens
+    from rtbench.kinds.serve_common import worst_margin
+
+    def logits_of(s):
+        return np.asarray(reference.logits(
+            c, weights, jnp.asarray(s + [0] * (-len(s) % 16), jnp.int32)))
+
+    assert worst_margin(prompt, out, logits_of) == 0.0
+
+
+# ---- through the engine -----------------------------------------------------
+
+def _engine(params, rule="sequential", **kw):
+    base = dict(model=replace(CFG, remasking_strategy=rule), max_num_seqs=3,
+                max_seq_len=MAX_SEQ, prefill_chunk=16, decode_burst=2, seed=3)
+    base.update(kw)
+    return LLMEngine(LLMConfig(**base), params=params)
+
+
+@pytest.fixture(scope="module", params=RULES)
+def engine(request, params):
+    eng = _engine(params, request.param)
+    yield request.param, eng
+    eng.shutdown()
+
+
+def test_the_engine_s_tokens_are_the_reference_s(engine, weights):
+    """Prompts of every length mod 4 (one shorter than a block), answers
+    that are no multiple of 4, a prompt across a chunk boundary, one that
+    contains the mask id; the long one first and alone, so that the rest
+    reuse its slot while several lines stand at different depths."""
+    rule, eng = engine
+    prompts = {"crosses": _prompt(22), "short": _prompt(3, 1),
+               "whole": _prompt(8, 2), "one": _prompt(9, 3),
+               "three": _prompt(11, 4), "masked": _prompt(14, 5)}
+    prompts["masked"][13] = prompts["masked"][6] = MASK
+    n = {"crosses": 7, "short": 5, "whole": 8, "one": 13, "three": 6,
+         "masked": 10}
+    first = eng.generate(prompts["crosses"],
+                         SamplingParams(max_tokens=n["crosses"]))
+    outs = {"crosses": first.token_ids}
+    reqs = {name: eng.submit(prompts[name],
+                             SamplingParams(max_tokens=n[name]), stream=True)
+            for name in prompts if name != "crosses"}
+    for name, req in reqs.items():
+        assert req.done.wait(120), name
+        assert req.error is None, req.error
+        assert req.finish_reason == "length"
+        outs[name] = list(req.out_tokens)
+        # one frame a token, then the end
+        frames = []
+        while (item := req.stream_queue.get(timeout=5)) is not None:
+            frames.append(item)
+        assert frames == outs[name]
+    for name, out in outs.items():
+        assert out == _want(weights, rule, prompts[name], n[name]), name
+    stats = eng.stats()
+    assert stats["requests_failed"] == 0 and stats["device_failures"] == 0
+    assert stats["diffusion_forwards"] == 5 * stats["diffusion_blocks"]
+    assert stats["diffusion_commits"] == stats["diffusion_blocks"]
+    # every token streamed is a decode's: prefill gives none
+    assert stats["decode_tokens"] == sum(n.values())
+    assert stats["first_tokens"] == len(prompts)
+    # the tails of 22, 3, 9, 11 and 14: 2 + 3 + 1 + 3 + 2
+    assert stats["diffusion_given"] == 11
+    assert stats["prompt_tokens_prefilled"] == 20 + 0 + 8 + 8 + 8 + 12
+    assert stats["decode_steps"] == 5 * stats["decode_dispatches"] * 2 or \
+        stats["decode_steps"] % 5 == 0
+    assert (stats["moe_experts_held"], stats["attention_lines"],
+            stats["diffusion_block_length"]) == (8, 3, 4)
+    assert stats["prefix_hits"] == 0 and eng.router_prefix_blocks() is None
+    assert stats["decode_dispatches_ahead"] > 0      # bursts behind bursts
+
+
+@pytest.mark.parametrize("burst,pipeline", [(1, True), (1, False),
+                                            (2, False), (4, True)])
+def test_every_schedule_gives_the_same_tokens(params, weights, burst,
+                                              pipeline):
+    """Bursts of one block and of more, behind one another or strictly
+    serial: the default's tokens (bursts of two, pipelined) are held to the
+    reference above, and these to it too."""
+    eng = _engine(params, decode_burst=burst, decode_pipeline=pipeline)
+    try:
+        prompts = [_prompt(10, 6), _prompt(17, 7), _prompt(4, 8)]
+        reqs = [eng.submit(p, SamplingParams(max_tokens=11))
+                for p in prompts]
+        for p, req in zip(prompts, reqs):
+            assert req.done.wait(120) and req.error is None
+            assert list(req.out_tokens) == _want(weights, "sequential", p,
+                                                 11)
+        stats = eng.stats()
+        assert stats["decode_steps"] % 5 == 0
+        if not pipeline:
+            assert stats["decode_dispatches_ahead"] == 0
+        if burst == 1:
+            assert stats["decode_steps"] == 5 * stats["decode_dispatches"]
+    finally:
+        eng.shutdown()
+
+
+def test_a_stop_token_ends_a_line_inside_its_block(params, weights):
+    eng = _engine(params)
+    try:
+        prompt = _prompt(10, 9)
+        want = _want(weights, "sequential", prompt, 12)
+        stop = want[4]
+        cut = want.index(stop) + 1
+        req = eng.submit(prompt, SamplingParams(max_tokens=12,
+                                                stop_token_ids=(stop,)))
+        assert req.done.wait(120) and req.error is None
+        assert req.finish_reason == "stop"
+        assert list(req.out_tokens) == want[:cut]
+        # sampling by temperature and top-p runs through the same programs
+        hot = eng.generate(prompt, SamplingParams(max_tokens=6,
+                                                  temperature=0.8, top_p=0.9))
+        assert len(hot.token_ids) == 6
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("max_seq,tokens", [(32, 32 - 21), (30, 28 - 21)])
+def test_a_line_ends_with_its_last_whole_block(params, weights, max_seq,
+                                               tokens):
+    """A request that would run past its cache line ends where the line's
+    last whole block does: the line's last position is used (a block's
+    tokens are its own positions'), a block that would cross the end is
+    not begun."""
+    eng = _engine(params, max_seq_len=max_seq)
+    try:
+        prompt = _prompt(21, 10)
+        out = eng.generate(prompt, SamplingParams(max_tokens=40))
+        assert out.finish_reason == "length"
+        assert out.token_ids == _want(weights, "sequential", prompt, tokens)
+    finally:
+        eng.shutdown()
+
+
+# ---- what it says of itself -------------------------------------------------
+
+def test_the_served_model_says_what_it_is():
+    served = served_model(CFG)
+    assert served is serving.SERVED
+    assert served.step(CFG) == (4, 5) and not served.prefill_token
+    assert served.decode_step is None and served.copy_prefix_kv is None
+    assert not served.prefix_from_line and not served.kv_handoff
+    assert served.counters == serving.COUNTERS and len(served.counters) == 10
+    full = replace(SdarConfig(), num_layers=6)
+    assert served.kv_block(full, 1536) == 512
+    cache = jax.eval_shape(lambda: served.init_cache(full, 128, 1536))
+    assert cache["k"].shape == cache["v"].shape == (6, 128, 4, 1536, 128)
+    # a cached position costs its 12 KiB
+    assert 2 * cache["k"].size * 2 // (128 * 1536) == 12 * 1024
+
+
+@pytest.mark.parametrize("kw,message", [
+    ({"speculative_model": "tiny"}, "SdarConfig does not support a "
+                                    "speculative draft"),
+    ({"tensor_parallel_size": 2}, "SdarConfig does not support "
+                                  "tensor_parallel_size > 1"),
+], ids=["draft", "tp"])
+def test_refuse_names_each_thing_refused(kw, message):
+    with pytest.raises(ValueError, match=message):
+        LLMEngine(LLMConfig(model=CFG, max_num_seqs=2, max_seq_len=32, **kw))
+
+
+def test_top_k_and_the_hand_off_are_refused_by_name(params):
+    with pytest.raises(ValueError, match="SdarConfig does not support the "
+                                         "prefill/decode hand-off"):
+        require_kv_handoff(CFG)
+    eng = _engine(params)
+    try:
+        with pytest.raises(ValueError, match="does not support top_k"):
+            eng.submit(_prompt(5), SamplingParams(max_tokens=4, top_k=5))
+        with pytest.raises(ValueError, match="hand-off"):
+            eng.prefill_only(_prompt(5))
+    finally:
+        eng.shutdown()
+
+
+# ---- the stream -------------------------------------------------------------
+
+def test_a_block_s_tokens_reach_the_client_as_one_chunk_of_frames():
+    """The engine emits a block's tokens of a line together; the server
+    writes a frame a token and the frames that are there as one chunk, so
+    the path to the client is walked once a block and not once a token."""
+    import queue
+    import threading
+    import time
+    import types
+
+    from ray_tpu.llm.serving import LLMServer
+
+    server = object.__new__(getattr(LLMServer, "func_or_class", LLMServer))
+    server._lag_lock = threading.Lock()
+    server.first_frames, server.first_frame_lag_s = 0, 0.0
+    req = types.SimpleNamespace(stream_queue=queue.Queue(),
+                                first_token_ts=time.time())
+    for tok in (11, 12, 13):             # a first block of three
+        req.stream_queue.put(tok)
+    chunks = server._stream_tokens(req)
+    assert next(chunks) == [11, 12, 13]
+    for tok in (21, 22, 23, 24, None):   # a block and the end with it
+        req.stream_queue.put(tok)
+    assert next(chunks) == [21, 22, 23, 24]
+    assert list(chunks) == []
+    assert server.first_frames == 1
+    # the end alone yields nothing
+    req.stream_queue.put(None)
+    assert list(server._stream_tokens(req)) == []

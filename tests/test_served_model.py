@@ -16,10 +16,12 @@ import pytest
 
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
 from ray_tpu.llm import engine, lfm2_serving, longcat_serving, ouro_serving
+from ray_tpu.llm import sdar_serving
 from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
 from ray_tpu.models.ouro import OuroConfig
+from ray_tpu.models.sdar import SdarConfig
 
 SLOTS, MAX_SEQ, CHUNK = 3, 64, 16
 
@@ -214,6 +216,7 @@ def test_the_look_ahead_schedule_gives_the_serial_schedules_tokens(model):
     assert outs[0] == outs[1]
     serial, ahead = stats
     assert ahead["decode_dispatches_ahead"] > serial["decode_dispatches_ahead"]
+    # every token but a request's first, which prefill gives
     assert ahead["decode_tokens"] == serial["decode_tokens"] == \
         sum(len(toks) - 1 for toks, _ in outs[0])
     served = engine.served_model(cfg)
@@ -223,3 +226,118 @@ def test_the_look_ahead_schedule_gives_the_serial_schedules_tokens(model):
             assert ahead[name] == 0    # a router with no zero expert
         else:
             assert ahead[name] > 0
+
+
+# ---- a step that is not a token --------------------------------------------
+
+def _sdar():
+    return sdar_serving, SdarConfig.tiny(max_seq_len=MAX_SEQ)
+
+
+@pytest.mark.parametrize("model", **MODELS)
+def test_a_token_a_step_is_what_a_model_is_served_by_unless_it_says(model):
+    """The models the engine served before one said otherwise: no ``step``
+    (one position by one forward), a prefill that gives the first token, a
+    single step of their own. The scheduler's arithmetic in steps of K
+    positions is theirs at K = 1, and their programs take the same
+    arguments as ever: int32[slots] tokens."""
+    _, cfg = model()
+    served = engine.served_model(cfg)
+    assert served.step is None and served.prefill_token
+    assert served.decode_step is not None
+    eng = LLMEngine(LLMConfig(model=cfg, max_num_seqs=SLOTS,
+                              max_seq_len=MAX_SEQ))
+    try:
+        assert (eng._step_positions, eng._step_forwards) == (1, 1)
+        req = eng.submit([5, 6, 7], SamplingParams(max_tokens=2))
+        assert eng._prefill_len(req) == 3
+        assert eng._input_tokens({0: req}).shape == (SLOTS,)
+        assert req.done.wait(60)
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("mix", [
+    {"step": lambda cfg: (4, 5)},          # a block step AND a single step
+    {"decode_step": None},                 # neither
+], ids=["both", "neither"])
+def test_a_step_and_a_single_step_program_exclude_each_other(mix):
+    """One statement tells the two kinds of model apart (``step``), and
+    what follows from it cannot be set against it: a mix the scheduler has
+    no arithmetic for is refused where the ServedModel is built, and
+    ``prefill_token`` is no field to set."""
+    from dataclasses import replace as dc_replace
+
+    with pytest.raises(ValueError, match="one of the two"):
+        dc_replace(engine._LLAMA, **mix)
+    with pytest.raises(TypeError):
+        dc_replace(engine._LLAMA, prefill_token=False)
+
+
+def test_a_model_may_say_its_step_is_a_block_and_its_prefill_gives_no_token():
+    """SDAR's: 4 positions by 5 forwards. Its two programs keep the names a
+    trace is read by and give the donated cache back; the prefill's logits
+    are None; a burst takes int32[slots, 4] and gives [steps, slots, 4]."""
+    module, cfg = _sdar()
+    served = engine.served_model(cfg)
+    assert served.step(cfg) == (4, 5) and not served.prefill_token
+    assert served.decode_step is None and served.copy_prefix_kv is None
+    params = served.init_params(cfg, jax.random.PRNGKey(0))
+    i32 = jnp.int32
+    for program, rest in (
+            ("prefill_chunk", (jnp.arange(CHUNK, dtype=i32), i32(0),
+                               i32(CHUNK), i32(0))),
+            ("decode_burst", (jnp.full((SLOTS, 4), -1, i32),
+                              jnp.array([CHUNK, 0, 0], i32),
+                              jnp.array([True, True, False]),
+                              jnp.zeros((SLOTS,), jnp.float32),
+                              jnp.ones((SLOTS,), jnp.float32),
+                              jax.random.PRNGKey(0), 2, False))):
+        cache = served.init_cache(cfg, SLOTS, MAX_SEQ)
+        went_in = jax.tree.map(lambda a: (a.shape, a.dtype), cache)
+        lowered = getattr(module, program).lower(cfg, params, cache, *rest)
+        assert re.search(r"module @(\w+)", lowered.as_text()).group(1) \
+            == f"jit_{program}"
+        came_back, result, counts = getattr(served, program)(
+            cfg, params, cache, *rest)
+        assert jax.tree.map(lambda a: (a.shape, a.dtype), came_back) \
+            == went_in
+        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(cache))
+        assert counts.shape == (len(served.counters),)
+        if program == "prefill_chunk":
+            assert result is None
+        else:
+            assert result.shape == (2, SLOTS, 4) and result.dtype == i32
+
+
+def test_the_look_ahead_serves_blocks_as_the_serial_schedule_does():
+    """The same equality as above with a step of 4: lines that join bursts
+    in flight get the serial schedule's tokens, every token is a decode's,
+    and a step counts its 5 forwards."""
+    _, cfg = _sdar()
+    prompts = [[7 + i for i in range(n)] for n in (5, 40, 23, 9, 31)]
+    budgets = [30, 17, 22, 9, 13]
+    outs, stats = [], []
+    for pipelined in (False, True):
+        eng = LLMEngine(LLMConfig(
+            model=cfg, max_num_seqs=SLOTS, max_seq_len=MAX_SEQ,
+            prefill_chunk=CHUNK, decode_burst=2, decode_pipeline=pipelined))
+        try:
+            reqs = [eng.submit(p, SamplingParams(max_tokens=n))
+                    for p, n in zip(prompts, budgets)]
+            assert all(r.done.wait(180) and not r.error for r in reqs)
+            outs.append([(r.out_tokens, r.finish_reason) for r in reqs])
+            stats.append(eng.stats())
+        finally:
+            eng.shutdown()
+    assert outs[0] == outs[1]
+    assert [len(toks) for toks, _ in outs[0]] == [30, 17, 22, 9, 13]
+    serial, ahead = stats
+    assert ahead["decode_dispatches_ahead"] > serial["decode_dispatches_ahead"]
+    assert ahead["decode_tokens"] == serial["decode_tokens"] == sum(budgets)
+    for s in stats:
+        assert s["decode_steps"] % 5 == 0
+        assert s["diffusion_forwards"] == 5 * s["diffusion_blocks"]
+        assert s["first_tokens"] == 5
+        # whole blocks of the prompts: 4 + 40 + 20 + 8 + 28
+        assert s["prompt_tokens_prefilled"] == 100

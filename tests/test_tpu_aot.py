@@ -634,3 +634,70 @@ def test_lfm2_programs_move_no_cache_nor_expert_stack_and_fit_the_chip(
     assert mem.temp_size_in_bytes < 1 << 28
     assert 11.5 < (mem.argument_size_in_bytes + mem.temp_size_in_bytes) \
         / 2 ** 30 < 12.5
+
+
+# The first 6 layers of SDAR-30B-A3B-Chat with all 128 experts at the shapes
+# of its serving cell (128 slots x 1,536): the programs of
+# llm/sdar_serving.py as the cell compiles them.
+@pytest.mark.parametrize("program", ["prefill_chunk(512)", "decode_burst(2)"])
+def test_sdar_programs_move_no_cache_nor_expert_stack_and_fit_the_chip(
+        mosaic, program):
+    """The Llama cache rides every loop as carry: the blocks' loop, the
+    denoising forwards' and the layers'. No leaf of it, nor a layer or a
+    line of one, nor the 6.75 GiB of stacked experts or a layer of them,
+    nor the head (0.58 GiB, read once a denoising forward), is the result
+    of anything but a parameter, a loop's tuple, a kernel's in-place
+    operand or an update in place; arguments and temporaries fit the chip's
+    15.75 GiB (10.67: weights 8.13, lines 2.25, the float32 logits of 512
+    rows 0.29). The prefill computes no head, so the head is no argument of
+    it. A block's five forwards attend at the same lengths: one plan a
+    block, outside the forwards' and the layers' loops."""
+    from devbench import sdar_bench as bench
+
+    cfg = bench.config()
+    assert (cfg.num_layers, cfg.num_experts, cfg.block_length) == (6, 128, 4)
+    dev = NamedSharding(build_mesh(MeshSpec(), mosaic[:1]), P())
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=dev), tree)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    params, cache = bench.shapes(cfg, placed)
+    k = cache["k"]
+    assert k.shape == (6, bench.SLOTS, 4, bench.MAX_SEQ, 128)
+    assert 2 * k.size * k.dtype.itemsize // (bench.SLOTS * bench.MAX_SEQ) \
+        == 12 * 1024
+    compiled = bench.lowerings(cfg, params, cache, arg)[program]().compile()
+    text = compiled.as_text()
+    carried = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30
+    if program.startswith("prefill"):
+        kernels = ("prefill_attention", "moe_grouped_matmul")
+        in_place = {"dynamic-update-slice", "custom-call"}
+        # 512 x 8 picks over 128 experts are 32 rows each: tiles of 64
+        assert _grouped_matmul_rows(text) == {(4096 // 64 + 128) * 64}
+        assert mem.temp_size_in_bytes < 1 << 27
+        assert 9.7 < total < 9.95          # no head among the arguments
+    else:
+        kernels = ("decode_attention", "kv_row_write", "moe_grouped_matmul")
+        in_place = {"custom-call"}
+        assert _plans_outside_the_layer_loop(text)
+        # 128 lines x 4 rows x 8 picks: 32 rows an expert here too
+        assert _grouped_matmul_rows(text) == {(4096 // 64 + 128) * 64}
+        # the float32 logits of a forward's 512 rows, and little else
+        assert 512 * 151936 * 4 <= mem.temp_size_in_bytes < 1 << 29
+        assert 10.5 < total < 10.9
+    for name in kernels:
+        assert f'"{name}"' in text or f"%{name}." in text, name
+    big = bench.big_shapes(cfg)
+    assert _opcodes_with_shape(text, big["kv"]) <= carried | in_place
+    for shape in ("kv_layer", "kv_line", "experts_layer_up",
+                  "experts_layer_down", "head_f32"):
+        assert _opcodes_with_shape(text, big[shape]) == set(), shape
+    for shape in ("experts_up", "experts_down", "wq", "embed", "head"):
+        assert _opcodes_with_shape(text, big[shape]) <= \
+            carried | {"fusion", "custom-call", "dynamic-slice"}, shape
