@@ -3,7 +3,7 @@
 1,536): compiled for a described v5e with no chip, and timed on one.
 
     python3 devbench/sdar_bench.py aot            # no chip, about a minute
-    chiprun -- python3 devbench/sdar_bench.py step parity
+    chiprun -- python3 devbench/sdar_bench.py step glue parity
 
 ``aot``: ``llm/sdar_serving.py``'s ``prefill_chunk`` at the buckets 16 and
 512 and ``decode_burst`` of 1 and 2 blocks, compiled for ``v5e:2x2``'s first
@@ -15,7 +15,20 @@ stacked weight, by opcode (a copy of one of those is up to 6.75 GiB moved a
 program). ``step``: wall milliseconds of one block (5 forwards) inside a
 burst of 2 at 128 lines of 256, 768 and 1,280 live positions, a forward
 being a fifth of it, and of a prefill chunk of 512 at 0 and 512 cached rows
-(the clock stops on a host read of the result). ``parity``: a block-causal
+(the clock stops on a host read of the result); every line is prefilled
+with tokens of its own first, so the router reaches the 113 to 128 experts
+a layer that a served forward does (``experts_touched_per_layer``; lines
+that hold the same tokens reach 32, and the forward reads a quarter of the
+experts' bytes). ``glue``: the routed layer's XLA code around the grouped
+matmul (``models/routed.py``) alone, at the five shapes the routed cells'
+programs have (SDAR's forward, LFM2's chunk and decode step, LongCat's
+decode step and chunk): microseconds a call of ``route``, of
+``dispatch_plan``, of the gather into tiles and of the combine, and of the
+whole ``moe_block`` with one layer of seeded experts, each inside one
+``fori_loop`` of 200 calls whose carry is the call's own result (written
+whole every iteration; the inputs change with the counter so nothing is
+hoisted). The same file runs against an older tree laid in ``.parent/``
+(copy it into ``.parent/devbench/``). ``parity``: a block-causal
 prefill of 768 positions and 64 blocks decided by the program, against the
 float32 reference over the finished sequence, as the harness compares
 them: the reference's top logit minus its logit of the program's token,
@@ -212,21 +225,180 @@ def _time_block(serving, cfg, params, cache, live: int, blocks: int = 2):
     return cache, round(min(times[1:]), 2), counts
 
 
+def _own_tokens(serving, cfg, params, cache, positions: int = MAX_SEQ):
+    """Every line prefilled with ``positions`` tokens of its own."""
+    import jax
+    import jax.numpy as jnp
+
+    i32 = jnp.int32
+    ids = jax.random.randint(jax.random.PRNGKey(42), (SLOTS, positions), 0,
+                             cfg.vocab_size, i32)
+    for slot in range(SLOTS):
+        for a in range(0, positions, 512):
+            cache, _, _ = serving.prefill_chunk(
+                cfg, params, cache, ids[slot, a:a + 512], i32(a),
+                i32(positions), i32(slot))
+    return cache
+
+
 def step() -> dict:
     import jax
 
     cfg, params, serving, cache = _programs()
     out = {"mode": "step", "device": jax.devices()[0].device_kind,
-           "block_ms": {}, "forward_ms": {}, "prefill_chunk_ms": {}}
+           "block_ms": {}, "forward_ms": {}, "prefill_chunk_ms": {},
+           "experts_touched_per_layer": {}}
     for kv_len in (0, 512):
         cache, out["prefill_chunk_ms"][kv_len], counts = _time_chunk(
             serving, cfg, params, cache, kv_len)
         out["prefill_counts"] = [int(n) for n in counts]
+    cache = _own_tokens(serving, cfg, params, cache)
     for live in (256, 768, 1280):
         cache, ms, counts = _time_block(serving, cfg, params, cache, live)
         out["block_ms"][live] = ms
         out["forward_ms"][live] = round(ms / (cfg.denoising_steps + 1), 2)
         out["decode_counts"] = [int(n) for n in counts]
+        out["experts_touched_per_layer"][live] = round(
+            int(counts[3]) / max(int(counts[4]), 1), 1)
+    return out
+
+
+# The routed layer's shapes in the three routed cells' programs: tokens a
+# call, hidden and expert widths, and the rule's integers (the cells'
+# configuration files; LongCat's chip holds experts 0 to 15 of 512).
+GLUE_SHAPES = {
+    "sdar forward (P 4096, held 128)": dict(
+        tokens=512, hidden=2048, ffn=768,
+        rule=dict(experts=128, topk=8, use_bias=False, renormalize=True,
+                  renorm_eps=0.0)),
+    "lfm2 chunk (P 2048, held 64)": dict(
+        tokens=512, hidden=2048, ffn=1536,
+        rule=dict(experts=64, topk=4, score="sigmoid", renormalize=True)),
+    "lfm2 step (P 256, held 64)": dict(
+        tokens=64, hidden=2048, ffn=1536,
+        rule=dict(experts=64, topk=4, score="sigmoid", renormalize=True)),
+    "longcat step (P 384, held 16)": dict(
+        tokens=32, hidden=6144, ffn=2048,
+        rule=dict(experts=512, topk=12, scaling_factor=6.0,
+                  zero_experts=256, expert_shards=32)),
+    "longcat chunk (P 6144, held 16)": dict(
+        tokens=512, hidden=6144, ffn=2048,
+        rule=dict(experts=512, topk=12, scaling_factor=6.0,
+                  zero_experts=256, expert_shards=32)),
+}
+GLUE_CALLS = 200
+
+
+def _us_a_call(fn, *args) -> float:
+    """Microseconds a call of ``fn(i, *args)`` inside one ``fori_loop`` of
+    GLUE_CALLS whose carry is the call's result; the best of five."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    shapes = jax.eval_shape(lambda *a: fn(jnp.int32(0), *a), *args)
+
+    @jax.jit
+    def loop(*a):
+        init = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+        return lax.fori_loop(0, GLUE_CALLS, lambda i, c: fn(i, *a), init)
+
+    jax.block_until_ready(loop(*args))
+    times = []
+    for _ in range(5):
+        t0 = time.monotonic()
+        jax.block_until_ready(loop(*args))
+        times.append(time.monotonic() - t0)
+    return round(min(times) / GLUE_CALLS * 1e6, 1)
+
+
+def glue() -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import routed
+
+    out = {"mode": "glue", "device": jax.devices()[0].device_kind,
+           "calls": GLUE_CALLS, "us_a_call": {}}
+    for name, shape in GLUE_SHAPES.items():
+        rule = routed.RouterRule(**shape["rule"])
+        t, h, f = shape["tokens"], shape["hidden"], shape["ffn"]
+        held, topk = rule.held, rule.topk
+        tm = routed.row_tile(t, topk, rule.outputs)
+        keys = jax.random.split(jax.random.PRNGKey(len(name)), 6)
+        dt = jnp.bfloat16
+        # One stacked layer; the router's columns differ in scale so that
+        # the experts' fills do, as a trained router's.
+        layers = {
+            "router": (jax.random.normal(keys[0], (1, h, rule.outputs))
+                       * (1 + 0.5 * jax.random.normal(
+                           keys[1], (1, 1, rule.outputs)))
+                       / np.sqrt(h)).astype(dt),
+            "router_bias": jnp.zeros((1, rule.outputs), jnp.float32),
+            "we_gate": (jax.random.normal(keys[2], (1, held, h, f), dt)
+                        / np.sqrt(h)).astype(dt),
+            "we_up": (jax.random.normal(keys[3], (1, held, h, f), dt)
+                      / np.sqrt(h)).astype(dt),
+            "we_down": (jax.random.normal(keys[4], (1, held, f, h), dt)
+                        / np.sqrt(f)).astype(dt)}
+        u = jax.random.normal(keys[5], (t, h), dt)
+        valid = jnp.ones((t,), bool)
+        bias = layers["router_bias"][0] if rule.use_bias else None
+
+        def vary(i, u):
+            return u + (i & 1).astype(u.dtype) * jnp.asarray(0.001, u.dtype)
+
+        def route(i, u, router):
+            return routed.route(rule, router, bias, vary(i, u))
+
+        idx, w = jax.jit(lambda u, r: routed.route(rule, r, bias, u))(
+            u, layers["router"][0])
+        lo = rule.expert_shard * held
+        local = (idx >= lo) & (idx < lo + held)
+        pick_keys = jnp.where(local, idx - lo, held).reshape(-1).astype(
+            jnp.int32)
+
+        def plan(i, k):
+            # the same fills on other experts: a pick here stays here
+            return routed.dispatch_plan(
+                jnp.where(k < held, (k + i) % held, held), held, tm)
+
+        pick_of_row, row_of_pick, tile_expert, n_live, sizes = jax.jit(
+            lambda k: routed.dispatch_plan(k, held, tm))(pick_keys)
+
+        def rows_until_pr_42(u, pick_of_row, topk):
+            return jnp.where((pick_of_row >= 0)[:, None],
+                             u[jnp.maximum(pick_of_row, 0) // topk], 0)
+
+        tile_rows = getattr(routed, "tile_rows", rows_until_pr_42)
+
+        def gather_in(i, u, pick_of_row):
+            return tile_rows(vary(i, u), pick_of_row, topk)
+
+        out_rows = jax.random.normal(keys[5], (pick_of_row.shape[0], h), dt)
+
+        def combine(i, out_rows, row_of_pick, local, w):
+            # other rows each call, or the gather is hoisted out of the loop
+            rows = (row_of_pick + i) % out_rows.shape[0]
+            picked = out_rows[jnp.where(local, rows.reshape(t, topk), 0)]
+            return jnp.sum(jnp.where(
+                local[..., None], w[..., None] * picked.astype(jnp.float32),
+                0.0), axis=1).astype(out_rows.dtype)
+
+        def block(i, layers, u):
+            return routed.moe_block(rule, layers, 0, vary(i, u), valid)
+
+        out["us_a_call"][name] = {
+            "tile": tm, "rows": int(pick_of_row.shape[0]),
+            "picks_here": int(local.sum()),
+            "experts_touched": int((sizes > 0).sum()),
+            "live_tiles": int(n_live),
+            "route": _us_a_call(route, u, layers["router"][0]),
+            "dispatch_plan": _us_a_call(plan, pick_keys),
+            "gather_in": _us_a_call(gather_in, u, pick_of_row),
+            "combine": _us_a_call(combine, out_rows, row_of_pick, local, w),
+            "moe_block": _us_a_call(block, layers, u)}
     return out
 
 
@@ -355,8 +527,8 @@ def engine(stream: bool = False, window_s: float = 20.0) -> dict:
     return out
 
 
-MODES = {"aot": aot, "step": step, "parity": parity, "engine": engine,
-         "engine_stream": partial(engine, stream=True)}
+MODES = {"aot": aot, "step": step, "glue": glue, "parity": parity,
+         "engine": engine, "engine_stream": partial(engine, stream=True)}
 
 if __name__ == "__main__":
     for mode in sys.argv[1:] or ["aot"]:

@@ -119,35 +119,54 @@ def dispatch_plan(keys, held: int, tm: int):
     (not here). Returns ``pick_of_row`` [Mp] (-1: an empty row),
     ``row_of_pick`` [P] (meaningless for a pick that is not here),
     ``tile_expert`` [Mp // tm], ``n_live`` and the group sizes [held].
-    Mp = (P // tm + held) * tm holds the worst case: every pick local."""
+    Mp = (P // tm + held) * tm holds the worst case: every pick local.
+
+    The index work is done once a pick (P values) or once a tile
+    (Mp // tm), never once a row of Mp: a scalar lookup costs a v5e 8 ns
+    an element, and a sort of 4,096 pairs what 900 lookups do. One stable
+    sort puts the picks in expert order; a sorted pick's row is its place
+    plus the padding of the groups that end before it; the rows' picks are
+    those P picks written to their rows over -1 (the one operation that is
+    Mp wide), and the picks' rows the same values sorted back by pick.
+    The two ``[.., P]`` comparisons below are summed where they are made:
+    XLA fuses each into one pass and no ``[P, held]`` array exists."""
     p = keys.shape[0]
     max_tiles = p // tm + held
-    onehot = keys[:, None] == jnp.arange(held)[None, :]          # [P, held]
-    csum = jnp.cumsum(onehot.astype(jnp.int32), axis=0)
-    sizes = csum[-1]
-    rank = jnp.take_along_axis(
-        csum, jnp.minimum(keys, held - 1)[:, None], axis=1)[:, 0] - 1
+    place = jnp.arange(p, dtype=jnp.int32)
+    sorted_keys, order = lax.sort((keys, place), num_keys=1, is_stable=True)
+    # Expert e's group is sorted picks ends[e] - sizes[e] to ends[e].
+    starts = jnp.searchsorted(
+        sorted_keys, jnp.arange(held + 1, dtype=jnp.int32), side="left",
+        method="compare_all")
+    ends, sizes = starts[1:], starts[1:] - starts[:-1]
     tiles = (sizes + tm - 1) // tm
     tile_end = jnp.cumsum(tiles)
-    tile_start = tile_end - tiles
     n_live = tile_end[-1]
-    group_start = jnp.cumsum(sizes) - sizes
     # Tile t belongs to the first expert whose tiles end past it; a dead
     # tile to the last live tile's expert (a fetch it repeats, not a new
     # one, where a backend visits dead tiles at all).
     t = jnp.minimum(jnp.arange(max_tiles), jnp.maximum(n_live - 1, 0))
-    tile_expert = jnp.minimum(
-        jnp.searchsorted(tile_end, t, side="right"), held - 1)
-    order = jnp.argsort(keys, stable=True)                       # [P]
-    rows = jnp.arange(max_tiles * tm)
-    e = tile_expert[rows // tm]
-    r = rows - tile_start[e] * tm
-    live = (rows // tm < n_live) & (r < sizes[e])
-    pick_of_row = jnp.where(
-        live, order[jnp.clip(group_start[e] + r, 0, p - 1)], -1)
-    row_of_pick = tile_start[jnp.minimum(keys, held - 1)] * tm + rank
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        tile_end, t, side="right", method="compare_all"), held - 1)
+    padding = tiles * tm - sizes
+    row_of_sorted = place + jnp.sum(jnp.where(
+        ends[None, :] <= place[:, None], padding[None, :], 0), axis=1)
+    rows = max_tiles * tm
+    pick_of_row = jnp.full((rows,), -1, jnp.int32).at[
+        jnp.where(sorted_keys < held, row_of_sorted, rows)].set(
+            order, mode="drop")
+    _, row_of_pick = lax.sort((order, row_of_sorted), num_keys=1)
     return pick_of_row, row_of_pick, tile_expert.astype(jnp.int32), \
         n_live.astype(jnp.int32), sizes
+
+
+def tile_rows(u, pick_of_row, topk: int):
+    """u [T, H] laid out as the plan says: [Mp, H], a pick's row its
+    token's, an empty row zeros. One gather under one mask: an empty row
+    reads the row of zeros laid after the tokens' rows (a select over the
+    gathered rows was a second pass over all of them)."""
+    return jnp.concatenate([u, jnp.zeros_like(u[:1])])[
+        jnp.where(pick_of_row >= 0, pick_of_row // topk, u.shape[0])]
 
 
 def moe_block(rule: RouterRule, layers: dict, layer, u, valid):
@@ -173,8 +192,7 @@ def moe_block(rule: RouterRule, layers: dict, layer, u, valid):
         pick_of_row, row_of_pick, tile_expert, n_live, sizes = dispatch_plan(
             keys, held, tm)
     with tracing.part("moe_dispatch"):
-        x_rows = jnp.where((pick_of_row >= 0)[:, None],
-                           u[jnp.maximum(pick_of_row, 0) // topk], 0)
+        x_rows = tile_rows(u, pick_of_row, topk)
     with tracing.part("moe_experts"):
         hidden = grouped_matmul(x_rows, layers["we_gate"], layer, tile_expert,
                                 n_live, tm=tm, w2=layers["we_up"])
