@@ -3,7 +3,7 @@
 1,536): compiled for a described v5e with no chip, and timed on one.
 
     python3 devbench/sdar_bench.py aot            # no chip, about a minute
-    chiprun -- python3 devbench/sdar_bench.py step glue parity
+    chiprun -- python3 devbench/sdar_bench.py step glue head parity
 
 ``aot``: ``llm/sdar_serving.py``'s ``prefill_chunk`` at the buckets 16 and
 512 and ``decode_burst`` of 1 and 2 blocks, compiled for ``v5e:2x2``'s first
@@ -28,7 +28,15 @@ whole ``moe_block`` with one layer of seeded experts, each inside one
 ``fori_loop`` of 200 calls whose carry is the call's own result (written
 whole every iteration; the inputs change with the counter so nothing is
 hoisted). The same file runs against an older tree laid in ``.parent/``
-(copy it into ``.parent/devbench/``). ``parity``: a block-causal
+(copy it into ``.parent/devbench/``). ``head``: what follows the stack
+in a denoising forward, alone, at all 512 rows (128 lines x 4) and at the
+128 a forward of the ``sequential`` rule can read: microseconds a call of
+``sdar.lm_head``, of each piece of the choice on float32 logits that are
+there (the arg-max; ``engine.sample_tokens``, which computes the arg-max
+and a categorical draw for every row and picks afterwards; the chosen
+token's probability; a ``lax.cond`` that makes the draw only where a row
+has a temperature, with none and with one), and of the head with a choice
+on its own product, as the program chains them. ``parity``: a block-causal
 prefill of 768 positions and 64 blocks decided by the program, against the
 float32 reference over the finished sequence, as the harness compares
 them: the reference's top logit minus its logit of the program's token,
@@ -402,6 +410,91 @@ def glue() -> dict:
     return out
 
 
+HEAD_ROWS = (512, 128)
+
+
+def head() -> dict:
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ray_tpu.llm.engine import sample_tokens
+    from ray_tpu.models import sdar
+
+    cfg = config()
+    h, v, k = cfg.hidden_size, cfg.vocab_size, cfg.block_length
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    dt = cfg.jnp_dtype
+    params = {
+        "final_norm": (1 + 0.1 * jax.random.normal(keys[0], (h,))).astype(dt),
+        "lm_head": (jax.random.normal(keys[1], (h, v), jnp.float32)
+                    / h ** 0.5).astype(dt)}
+    x = (3.0 * jax.random.normal(keys[2], (SLOTS, k, h))).astype(dt)
+    key = jax.random.PRNGKey(9)
+    out = {"mode": "head", "device": jax.devices()[0].device_kind,
+           "calls": GLUE_CALLS, "us_a_call": {}}
+
+    def vary(i, a):
+        return a + (i & 1).astype(a.dtype) * jnp.asarray(0.001, a.dtype)
+
+    def logits_of(i, x):
+        return sdar.lm_head(cfg, params, vary(i, x))
+
+    def greedy(flat):
+        return jnp.argmax(flat, axis=-1).astype(jnp.int32)
+
+    def both(i, flat, temps):
+        return sample_tokens(flat, temps, temps + 1.0, 0,
+                             jax.random.fold_in(key, i),
+                             False).astype(jnp.int32)
+
+    def cond(i, flat, temps):
+        return lax.cond((temps > 0).any(), lambda: both(i, flat, temps),
+                        lambda: greedy(flat))
+
+    def confidence(flat, x0):
+        chosen = jnp.take_along_axis(flat, x0[:, None], axis=-1)[:, 0]
+        return jnp.exp(chosen - jax.nn.logsumexp(flat, axis=-1))
+
+    for rows in HEAD_ROWS:
+        xr = x[:, :rows // SLOTS]
+        flat = jax.jit(lambda x: logits_of(jnp.int32(0), x))(xr).reshape(
+            rows, v)
+        cold = jnp.zeros((rows,), jnp.float32)
+        one_hot = cold.at[0].set(0.8)
+        x0 = jax.jit(greedy)(flat)
+
+        def tail(choose, conf):
+            def fn(i, x, temps):
+                flat = logits_of(i, x).reshape(rows, v)
+                x0 = choose(i, flat, temps)
+                return (x0, confidence(flat, x0)) if conf else x0
+            return fn
+
+        out["us_a_call"][rows] = {
+            "head": _us_a_call(logits_of, xr),
+            "argmax": _us_a_call(lambda i, f: greedy(vary(i, f)), flat),
+            "argmax_and_draw": _us_a_call(
+                lambda i, f, t: both(i, vary(i, f), t), flat, cold),
+            "confidence": _us_a_call(
+                lambda i, f, x0: confidence(vary(i, f), x0), flat, x0),
+            "cond_none_drawn": _us_a_call(
+                lambda i, f, t: cond(i, vary(i, f), t), flat, cold),
+            "cond_one_drawn": _us_a_call(
+                lambda i, f, t: cond(i, vary(i, f), t), flat, one_hot),
+            "head+argmax_and_draw+confidence": _us_a_call(
+                tail(both, True), xr, cold),
+            "head+argmax_and_draw": _us_a_call(tail(both, False), xr, cold),
+            "head+cond": _us_a_call(tail(cond, False), xr, cold),
+            "head+argmax": _us_a_call(
+                tail(lambda i, f, t: greedy(f), False), xr, cold)}
+    rows_of = jnp.zeros((SLOTS, 1), jnp.int32)
+    out["us_a_call"]["gather_128_of_512"] = _us_a_call(
+        lambda i, x, r: jnp.take_along_axis(
+            vary(i, x), ((r + i) % k)[:, :, None], axis=1), x, rows_of)
+    return out
+
+
 def parity(prompt: int = 770, blocks: int = 64, seed: int = 7) -> dict:
     """The programs' tokens against the float32 reference, as
     ``kinds/serve_common.worst_margin`` compares a run's."""
@@ -527,7 +620,8 @@ def engine(stream: bool = False, window_s: float = 20.0) -> dict:
     return out
 
 
-MODES = {"aot": aot, "step": step, "glue": glue, "parity": parity,
+MODES = {"aot": aot, "step": step, "glue": glue, "head": head,
+         "parity": parity,
          "engine": engine, "engine_stream": partial(engine, stream=True)}
 
 if __name__ == "__main__":

@@ -11,11 +11,15 @@ stack, each over the block's rows:
   (its decided positions, the mask token at the open ones) at the block's
   positions, attends the line through the block's end (every row sees the
   whole block: ops/decode_attention.py with ``positions0`` at the block's
-  last position) and gives logits at every row; the row at an open position
-  chooses that position's token (no shift by one) and the rule of the
-  configuration (models/sdar.open_positions) says which open positions
-  take theirs now. Its K/V rows are overwritten by the next forward and
-  never read by another block;
+  last position); the row at an open position chooses that position's
+  token (no shift by one) and the rule of the configuration
+  (models/sdar.open_positions) says which open positions take theirs now.
+  The head and the choice run on the rows that rule can read
+  (models/sdar.read_positions: under ``sequential`` the leftmost open ones,
+  known before the forward; every row under a rule that reads
+  confidences), and no draw is made where no line has a temperature. Its
+  K/V rows are overwritten by the next forward and never read by another
+  block;
 - the **commit forward** runs the decided block once more; its K/V stay,
   and no head is computed.
 
@@ -33,7 +37,7 @@ A prompt's whole blocks are prefilled under the same block-causal mask
 
 The programs keep the engine's names (``prefill_chunk``, ``decode_burst``:
 a device trace shows ``jit_<name>``) and return their counts beside their
-result (:data:`COUNTERS`, int32[10], summed over layers, forwards and
+result (:data:`COUNTERS`, int32[11], summed over layers, forwards and
 blocks); the scheduler adds them up where it fetches the tokens.
 """
 
@@ -61,22 +65,24 @@ from ray_tpu.ops.prefill_attention import prefill_attention, prefill_kv_write
 from ray_tpu.ops.rope import rope_frequencies
 from ray_tpu.util import tracing
 
-# Line-blocks run, line-forwards (commits among them), line-commits, and
-# the positions of first blocks that the prompt had decided.
+# Line-blocks run, line-forwards (commits among them), line-commits, the
+# positions of first blocks that the prompt had decided, and the rows that
+# went through the head.
 DIFFUSION_COUNTERS = ("diffusion_blocks", "diffusion_forwards",
-                      "diffusion_commits", "diffusion_given")
+                      "diffusion_commits", "diffusion_given",
+                      "diffusion_head_rows")
 COUNTERS = MOE_COUNTERS + DIFFUSION_COUNTERS
 
 
 def _ran(count, *names):
-    """int32[4] in the order of DIFFUSION_COUNTERS: ``count`` under each of
+    """int32[5] in the order of DIFFUSION_COUNTERS: ``count`` under each of
     ``names``, added where the thing counted runs."""
     return count * jnp.asarray([n in names for n in DIFFUSION_COUNTERS],
                                jnp.int32)
 
 
 def _counts(moe, diffusion=None):
-    """int32[10] in the order of COUNTERS; a prefill's diffusion counts
+    """int32[11] in the order of COUNTERS; a prefill's diffusion counts
     are zeros."""
     if diffusion is None:
         diffusion = jnp.zeros((len(DIFFUSION_COUNTERS),), jnp.int32)
@@ -116,13 +122,13 @@ def prefill_chunk(cfg: SdarConfig, params, cache, tokens, kv_len, length,
 
 
 def _forward(cfg: SdarConfig, params, cache, tokens, positions0, write_mask,
-             plan, head: bool, kmesh=None):
+             plan, kmesh=None):
     """One forward of every line's block: tokens [B, K] at positions
     ``positions0 + arange(K)`` (the block's start, a multiple of K). Writes
     the rows' K/V and attends each line through its block's end. Returns
-    (cache, float32 logits [B, K, V] or None without ``head``, the routed
+    (cache, the stack's output [B, K, H] before the final norm, the routed
     layers' counts). A line with ``write_mask`` false writes nothing, is
-    routed nowhere, and its logits mean nothing."""
+    routed nowhere, and its rows mean nothing."""
     b, k = tokens.shape
     with tracing.part("embed"):
         x = params["embed_tokens"][tokens]                    # [B, K, H]
@@ -147,22 +153,39 @@ def _forward(cfg: SdarConfig, params, cache, tokens, positions0, write_mask,
 
     x, (k_all, v_all), moe = sdar.run_layers(
         cfg, params, x, attention, (cache["k"], cache["v"]), valid, kmesh)
-    logits = sdar.lm_head(cfg, params, x, kmesh) if head else None
-    return {"k": k_all, "v": v_all}, logits, moe
+    return {"k": k_all, "v": v_all}, x, moe
+
+
+def _logits(cfg: SdarConfig, params, x, read, kmesh=None):
+    """The stack's output x [B, K, H] -> float32 logits [B, r, V] of the
+    rows ``read`` names (int32 [B, r] positions of the block), of every row
+    where it is None."""
+    if read is not None:
+        with tracing.part("head"):
+            x = jnp.take_along_axis(x, read[:, :, None], axis=1)
+    return sdar.lm_head(cfg, params, x, kmesh)
 
 
 @tracing.part("sample")
-def _choose(logits, temps, top_ps, key, need_top_p: bool):
-    """logits [B, K, V] -> (the token each row's logits choose [B, K], by
+def _choose(cfg: SdarConfig, logits, temps, top_ps, key, need_top_p: bool):
+    """logits [B, r, V] -> (the token each row's logits choose [B, r], by
     the request's temperature and top-p or greedily, and its probability
-    under softmax(logits), float32 [B, K])."""
-    b, k, v = logits.shape
-    flat = logits.reshape(b * k, v)
-    x0 = sample_tokens(flat, jnp.repeat(temps, k), jnp.repeat(top_ps, k), 0,
-                       key, need_top_p).astype(jnp.int32)
+    under softmax(logits), float32 [B, r]; None under a rule that does not
+    read it). The draw is made only where some line has a temperature:
+    ``sample_tokens`` computes it for every row and picks afterwards."""
+    b, r, v = logits.shape
+    flat = logits.reshape(b * r, v)
+    x0 = lax.cond(
+        (temps > 0).any(),
+        lambda: sample_tokens(flat, jnp.repeat(temps, r),
+                              jnp.repeat(top_ps, r), 0, key,
+                              need_top_p).astype(jnp.int32),
+        lambda: jnp.argmax(flat, axis=-1).astype(jnp.int32))
+    if not cfg.reads_confidence:
+        return x0.reshape(b, r), None
     chosen = jnp.take_along_axis(flat, x0[:, None], axis=-1)[:, 0]
     confidence = jnp.exp(chosen - jax.nn.logsumexp(flat, axis=-1))
-    return x0.reshape(b, k), confidence.reshape(b, k)
+    return x0.reshape(b, r), confidence.reshape(b, r)
 
 
 @partial(jax.jit, static_argnums=(0, 9, 10), static_argnames=("kmesh",),
@@ -194,14 +217,22 @@ def decode_burst(cfg: SdarConfig, params, cache, token0, positions0,
 
         def denoise(carry, d):
             cache, tokens, still_open, moe, diffusion = carry
-            cache, logits, n = _forward(cfg, params, cache, tokens, pos,
-                                        write_mask, plan, True, kmesh)
-            diffusion = diffusion + _ran(lines, "diffusion_forwards")
+            cache, x, n = _forward(cfg, params, cache, tokens, pos,
+                                   write_mask, plan, kmesh)
+            with tracing.part("sample"):
+                read = sdar.read_positions(cfg, still_open)
+            logits = _logits(cfg, params, x, read, kmesh)
+            diffusion = (diffusion + _ran(lines, "diffusion_forwards")
+                         + _ran(lines * logits.shape[1],
+                                "diffusion_head_rows"))
             x0, confidence = _choose(
-                logits, temps, top_ps,
+                cfg, logits, temps, top_ps,
                 jax.random.fold_in(jax.random.fold_in(key, j), d),
                 need_top_p)
             with tracing.part("sample"):
+                # A rule that reads confidences read every row: only the
+                # tokens have positions to go back to.
+                x0 = sdar.at_positions(still_open, x0)
                 take = sdar.open_positions(cfg, confidence, still_open)
                 tokens = jnp.where(take, x0, tokens)
                 return (cache, tokens, still_open & ~take, moe + n,
@@ -211,8 +242,9 @@ def decode_burst(cfg: SdarConfig, params, cache, token0, positions0,
             (cache, tokens, _, moe, diffusion), _ = lax.scan(
                 denoise, (cache, tokens, is_open, moe, diffusion),
                 jnp.arange(cfg.denoising_steps))
+        # The commit: the clean block's K/V stay, and no row is read.
         cache, _, n = _forward(cfg, params, cache, tokens, pos, write_mask,
-                               plan, False, kmesh)
+                               plan, kmesh)
         with tracing.part("sample"):
             given = ((~is_open) & write_mask[:, None]).sum().astype(jnp.int32)
             diffusion = (diffusion + _ran(given, "diffusion_given")

@@ -106,6 +106,20 @@ class SdarConfig:
         return self.block_length // self.denoising_steps
 
     @property
+    def reads_confidence(self) -> bool:
+        """Whether the rule looks at a position's confidence at all."""
+        return self.remasking_strategy != "sequential"
+
+    @property
+    def read_a_forward(self) -> int:
+        """Rows of a block whose logits a denoising forward's rule can read:
+        under ``sequential`` the leftmost open positions it opens, known
+        before the forward runs; under the confidence rules every open
+        position's confidence decides, so all of them."""
+        return (self.block_length if self.reads_confidence
+                else self.opened_a_forward)
+
+    @property
     def router_rule(self) -> RouterRule:
         return RouterRule(
             experts=self.num_experts, topk=self.num_experts_per_tok,
@@ -287,10 +301,44 @@ def forward(cfg: SdarConfig, params: dict, tokens, *,
     return lm_head(cfg, params, x, kmesh), counts
 
 
+def open_rank(is_open):
+    """is_open [B, K] bool -> int32 [B, K]: the open positions up to and
+    with each one, so an open position's rank among its line's open ones,
+    from 1."""
+    return jnp.cumsum(is_open, axis=-1, dtype=jnp.int32)
+
+
+def read_positions(cfg: SdarConfig, is_open):
+    """The positions of each line whose logits this forward's rule can
+    read: int32 [B, ``read_a_forward``], the leftmost open positions in
+    order. Where a line has fewer open the rest name position 0, whose
+    token nothing takes. ``None`` when the rule reads every row of the
+    block."""
+    r, k = cfg.read_a_forward, is_open.shape[-1]
+    if r == k:
+        return None
+    nth = is_open[:, None, :] & (open_rank(is_open)[:, None, :]
+                                 == jnp.arange(1, r + 1)[None, :, None])
+    return jnp.argmax(nth, axis=-1)
+
+
+def at_positions(is_open, rows):
+    """rows [B, r], a value for each position of ``read_positions`` ->
+    [B, K]: each of those positions holds its row's value (any other
+    position some row's, which nothing takes). ``rows`` itself when every
+    row was read."""
+    r = rows.shape[-1]
+    if r == is_open.shape[-1]:
+        return rows
+    return jnp.take_along_axis(
+        rows, jnp.clip(open_rank(is_open) - 1, 0, r - 1), axis=-1)
+
+
 def open_positions(cfg: SdarConfig, confidence, is_open):
     """Which open positions of each block take their token after this
     denoising forward. confidence [B, K] float32 (the probability of the
-    token chosen at each position), is_open [B, K] bool. Returns [B, K]
+    token chosen at each position; ``None`` under a rule that does not
+    read it), is_open [B, K] bool. Returns [B, K]
     bool, a subset of ``is_open`` with ``opened_a_forward`` positions a
     line (all that are open, where fewer are):
 
@@ -301,8 +349,8 @@ def open_positions(cfg: SdarConfig, confidence, is_open):
       ``opened_a_forward`` are."""
     n = cfg.opened_a_forward
     k = is_open.shape[-1]
-    if cfg.remasking_strategy == "sequential":
-        return is_open & (jnp.cumsum(is_open, axis=-1) <= n)
+    if not cfg.reads_confidence:
+        return is_open & (open_rank(is_open) <= n)
     score = jnp.where(is_open, confidence, -jnp.inf)
     # The n best, the earlier position where two are equal: a position's
     # rank is how many stand before it.

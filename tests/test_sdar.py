@@ -13,6 +13,7 @@ tokens.
 import os
 import sys
 from dataclasses import replace
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -22,12 +23,18 @@ import pytest
 from ray_tpu.llm import LLMConfig
 from ray_tpu.llm import sdar_serving as serving
 from ray_tpu.llm.config import SamplingParams
-from ray_tpu.llm.engine import LLMEngine, require_kv_handoff, served_model
+from ray_tpu.llm.engine import (
+    LLMEngine,
+    require_kv_handoff,
+    sample_tokens,
+    served_model,
+)
 from ray_tpu.models import sdar
 from ray_tpu.models.sdar import RULES, SdarConfig
 from ray_tpu.ops.decode_attention import (
     decode_attention,
     decode_attention_reference,
+    decode_plan_of,
 )
 from ray_tpu.ops.kernels import force_kernel_backend
 from ray_tpu.ops.prefill_attention import (
@@ -120,6 +127,14 @@ def test_the_published_defaults_and_the_rule():
             cfg.moe_intermediate_size, cfg.vocab_size) == (128, 8, 768, 151936)
     assert (cfg.block_length, cfg.denoising_steps, cfg.opened_a_forward) == \
         (4, 4, 1)
+    # the rule states the rows a forward can read: what `sequential` opens,
+    # every row where confidences decide
+    assert (cfg.read_a_forward, cfg.reads_confidence) == (1, False)
+    assert replace(cfg, denoising_steps=2).read_a_forward == 2
+    for rule in RULES[1:]:
+        assert (replace(cfg, remasking_strategy=rule).read_a_forward,
+                replace(cfg, remasking_strategy=rule).reads_confidence) == \
+            (4, True)
     rule = cfg.router_rule
     assert (rule.score, rule.use_bias, rule.renormalize, rule.renorm_eps,
             rule.outputs, rule.topk, rule.held) == \
@@ -165,6 +180,32 @@ def test_the_three_rules_open_what_they_say():
     # nothing open: nothing opened
     assert _open("low_confidence_static", conf, [[False] * 4] * 2) == \
         [[0] * 4] * 2
+
+
+def test_the_rows_read_are_the_leftmost_open_ones():
+    is_open = jnp.asarray([[False, True, False, True], [False] * 4,
+                           [True] * 4, [False, False, False, True]])
+    one = SdarConfig.tiny()
+    two = SdarConfig.tiny(denoising_steps=2)
+    assert np.asarray(sdar.read_positions(one, is_open)).tolist() == \
+        [[1], [0], [0], [3]]
+    read = sdar.read_positions(two, is_open)
+    assert np.asarray(read).tolist() == [[1, 3], [0, 0], [0, 1], [3, 0]]
+    # what `sequential` opens lies among them, and gets its own row's value
+    rows = jnp.asarray([[10, 11], [20, 21], [30, 31], [40, 41]])
+    placed = np.asarray(sdar.at_positions(is_open, rows))
+    take = np.asarray(sdar.open_positions(two, None, is_open))
+    assert take.astype(int).tolist() == \
+        [[0, 1, 0, 1], [0] * 4, [1, 1, 0, 0], [0, 0, 0, 1]]
+    assert placed[take].tolist() == [10, 11, 30, 31, 40]
+    # a line with none open takes nothing, whatever its rows chose
+    assert not take[1].any()
+    # a rule that reads confidences reads every row: nothing to gather
+    for rule in RULES[1:]:
+        cfg = SdarConfig.tiny(remasking_strategy=rule)
+        assert sdar.read_positions(cfg, is_open) is None
+    whole = jnp.arange(16).reshape(4, 4)
+    assert sdar.at_positions(is_open, whole) is whole
 
 
 # ---- the two attention ops --------------------------------------------------
@@ -263,9 +304,12 @@ def _prefill(params, tokens, slot=0, cache=None, chunk=CHUNK):
     return cache
 
 
-def _burst(params, cache, given: dict, starts: dict, steps, cfg=CFG):
+def _burst(params, cache, given: dict, starts: dict, steps, cfg=CFG,
+           temps=None, top_ps=None, burst=None):
     """A burst over the lines of ``starts`` (slot -> block start); ``given``
-    (slot -> the tokens its first block has decided)."""
+    (slot -> the tokens its first block has decided). Greedy unless
+    ``temps`` [SLOTS] says otherwise; ``burst`` stands in for the
+    program."""
     k = cfg.block_length
     tok = np.full((SLOTS, k), -1, np.int32)
     pos = np.zeros((SLOTS,), np.int32)
@@ -274,9 +318,11 @@ def _burst(params, cache, given: dict, starts: dict, steps, cfg=CFG):
         pos[slot], write[slot] = start, True
         tok[slot, :len(given.get(slot, []))] = given.get(slot, [])
     zeros, ones = jnp.zeros((SLOTS,)), jnp.ones((SLOTS,))
-    return serving.decode_burst(
+    return (burst or serving.decode_burst)(
         cfg, params, cache, jnp.asarray(tok), jnp.asarray(pos),
-        jnp.asarray(write), zeros, ones, jax.random.PRNGKey(0), steps, False)
+        jnp.asarray(write), zeros if temps is None else jnp.asarray(temps),
+        ones if top_ps is None else jnp.asarray(top_ps),
+        jax.random.PRNGKey(0), steps, top_ps is not None)
 
 
 @pytest.mark.parametrize("backend", ["reference", "interpret"])
@@ -319,19 +365,59 @@ def test_a_burst_decides_blocks_as_the_reference_does_and_commits_them(
         20 * 4 * CFG.num_experts_per_tok * CFG.num_layers
 
 
-def test_the_counters_count_the_forwards_that_ran(params):
+@pytest.mark.parametrize("rule,rows", [("sequential", 2),
+                                       ("low_confidence_static", 4)])
+def test_the_counters_count_the_forwards_that_ran(params, rule, rows):
     """Forwards are counted where a forward runs and commits where the
     commit does: at 2 denoising forwards a block the same code reads 3
-    forwards a block, a third of them commits, with no edit of a formula."""
-    cfg = replace(CFG, denoising_steps=2)
-    a = _prompt(9)
-    cache = _prefill(params, a)
-    _, _, counts = _burst(params, cache, {0: a[8:]}, {0: 8}, steps=2,
-                          cfg=cfg)
+    forwards a block, a third of them commits, with no edit of a formula.
+    The head's rows are counted where the head runs: lines x the rows the
+    rule can read x denoising forwards, the idle line's none."""
+    cfg = replace(CFG, denoising_steps=2, remasking_strategy=rule)
+    a, b = _prompt(9), _prompt(14, salt=1)
+    cache = _prefill(params, b, slot=2, cache=_prefill(params, a))
+    _, _, counts = _burst(params, cache, {0: a[8:], 2: b[12:]},
+                          {0: 8, 2: 12}, steps=2, cfg=cfg)
     counts = dict(zip(serving.COUNTERS, np.asarray(counts).tolist()))
-    assert counts["diffusion_blocks"] == counts["diffusion_commits"] == 2
-    assert counts["diffusion_forwards"] == 6
+    assert counts["diffusion_blocks"] == counts["diffusion_commits"] == 4
+    assert counts["diffusion_forwards"] == 12
     assert counts["moe_layer_steps"] == 6 * cfg.num_layers
+    assert counts["diffusion_head_rows"] == 2 * rows * (2 * 2)
+    assert counts["diffusion_head_rows"] == rows * (
+        counts["diffusion_forwards"] - counts["diffusion_commits"])
+
+
+def test_the_head_s_rows_are_a_metric_of_the_cell():
+    """``diffusion_head_rows_per_forward``: a data file over the reader the
+    benchmark has, read from ``BENCHMARK.json`` as the harness reads it."""
+    from rtbench import manifest
+
+    cell = manifest.load_cell("sdar-30b-serve-generate-512", REPO)
+    spec = next(x for x in cell["per_layer"]
+                if x["name"] == "diffusion_head_rows_per_forward")
+    commit = next(x for x in cell["per_layer"]
+                  if x["name"] == "diffusion_commit_share")
+    assert spec["reader"] == "counter_ratio"
+    assert spec["params"] == {"num": "diffusion_head_rows",
+                              "den": "diffusion_forwards"}
+    assert {spec["params"]["num"], spec["params"]["den"]} <= \
+        set(serving.COUNTERS)
+    assert (spec["moves"], spec["better"], spec["source"], spec["unit"]) == \
+        ("serve_tok_s", "lower", "program_counter", "rows")
+    assert spec["layer"] == commit["layer"]
+    assert spec["workloads"] == ["sdar-30b-serve-generate-512"]
+    assert manifest.check(manifest.load(REPO), REPO) == []
+    # 4 denoising forwards of 1 row and a commit of none: 0.8 a forward
+    from rtbench.readers import counter_ratio
+
+    polls = [(t, {"diffusion_head_rows": 4 * n, "diffusion_forwards": 5 * n})
+             for t, n in ((1.0, 10), (2.0, 30))]
+    obs = {"polls": polls, "t_open": 0.0, "t_close": 3.0}
+    assert counter_ratio.read(obs, spec["params"]) == 0.8
+    # a program without the counter (the parent) reads nothing
+    assert counter_ratio.read(
+        {**obs, "polls": [(t, {"diffusion_forwards": 5}) for t, _ in polls]},
+        spec["params"]) is None
 
 
 def test_open_positions_are_tracked_by_place_not_by_the_mask_s_id(params,
@@ -345,7 +431,147 @@ def test_open_positions_are_tracked_by_place_not_by_the_mask_s_id(params,
     out = np.asarray(toks)[0, 0].tolist()
     assert out[0] == MASK
     assert out[1:] == _want(weights, "sequential", a, 3)
-    assert int(counts[len(serving.COUNTERS) - 1]) == 1
+    assert int(counts[serving.COUNTERS.index("diffusion_given")]) == 1
+
+
+def _choose_every_row(logits, temps, top_ps, key, need_top_p):
+    """``serving._choose`` as it stood while every row of the block went
+    through the head (PR 41, 42): logits [B, K, V]."""
+    b, k, v = logits.shape
+    flat = logits.reshape(b * k, v)
+    x0 = sample_tokens(flat, jnp.repeat(temps, k), jnp.repeat(top_ps, k), 0,
+                       key, need_top_p).astype(jnp.int32)
+    chosen = jnp.take_along_axis(flat, x0[:, None], axis=-1)[:, 0]
+    confidence = jnp.exp(chosen - jax.nn.logsumexp(flat, axis=-1))
+    return x0.reshape(b, k), confidence.reshape(b, k)
+
+
+@partial(jax.jit, static_argnums=(0, 9, 10))
+def _burst_every_row(cfg, params, cache, token0, positions0, write_mask,
+                     temps, top_ps, key, steps, need_top_p):
+    """``decode_burst`` in plain loops, the head and the choice of every
+    row of the block at every denoising forward."""
+    k, out = cfg.block_length, []
+    for j in range(steps):
+        pos = positions0 + j * k
+        is_open = (token0 < 0) | (j > 0)
+        tokens = jnp.where(is_open, cfg.mask_token_id, token0)
+        plan = decode_plan_of(jnp.where(write_mask, pos + k, 0), cache["k"])
+        for d in range(cfg.denoising_steps):
+            cache, x, _ = serving._forward(cfg, params, cache, tokens, pos,
+                                           write_mask, plan)
+            x0, confidence = _choose_every_row(
+                sdar.lm_head(cfg, params, x), temps, top_ps,
+                jax.random.fold_in(jax.random.fold_in(key, j), d),
+                need_top_p)
+            take = sdar.open_positions(cfg, confidence, is_open)
+            tokens, is_open = jnp.where(take, x0, tokens), is_open & ~take
+        cache, _, _ = serving._forward(cfg, params, cache, tokens, pos,
+                                       write_mask, plan)
+        out.append(tokens)
+    return cache, jnp.stack(out), None
+
+
+def _same_as_every_row(params, cfg, steps, given):
+    """Two lines whose first blocks come with ``given`` and ``given + 1``
+    positions decided, and an idle one: the program's tokens against the
+    transcription's."""
+    a, b = _prompt(8 + given), _prompt(12 + (given + 1) % 4, salt=1)
+    cache = _prefill(params, b, slot=2, cache=_prefill(params, a))
+    args = ({0: a[8:], 2: b[12:]}, {0: 8, 2: 12}, steps)
+    _, want, _ = _burst(params, jax.tree.map(jnp.copy, cache), *args,
+                        cfg=cfg, burst=_burst_every_row)
+    _, got, _ = _burst(params, cache, *args, cfg=cfg)
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_array_equal(got[:, [0, 2]], want[:, [0, 2]])
+    # what the prompt decided stands where it stood, through forwards in
+    # which the line had nothing open any more
+    assert got[0, 0, :given].tolist() == a[8:]
+    assert got[0, 2, :len(b) - 12].tolist() == b[12:]
+    assert (got[:, [0, 2]] != MASK).all()
+
+
+@pytest.mark.parametrize("given", [0, 1, 2, 3])
+@pytest.mark.parametrize("steps", [1, 2])
+@pytest.mark.parametrize("rule", RULES)
+def test_the_rows_read_decide_what_every_row_decided(params, rule, steps,
+                                                     given):
+    """The head on the rows the rule can read gives, token for token, what
+    the head on every row gave."""
+    _same_as_every_row(params, replace(CFG, remasking_strategy=rule), steps,
+                       given)
+
+
+@pytest.mark.parametrize("given", [0, 1, 2, 3])
+def test_two_rows_a_line_go_back_to_their_own_positions(params, given):
+    """At 2 denoising forwards a block ``sequential`` reads two rows a
+    line, and a line with one position open reads one that counts."""
+    _same_as_every_row(params, replace(CFG, denoising_steps=2), 2, given)
+
+
+def _top_p_set(logits, temperature, top_p):
+    """The ids ``sample_tokens`` may draw: the smallest prefix of the
+    sorted probabilities whose mass before each is under ``top_p``."""
+    z = np.asarray(logits, np.float64) / temperature
+    p = np.exp(z - z.max())
+    p /= p.sum()
+    order = np.argsort(-p)
+    before = np.cumsum(p[order]) - p[order]
+    return set(order[before < top_p].tolist())
+
+
+def test_a_greedy_line_beside_a_drawn_one_keeps_its_tokens(params):
+    """A request's tokens do not depend on its neighbours: beside a line at
+    temperature 0.8 and top-p 0.9 (so the draw is made for the batch) the
+    greedy line's tokens are those of the all-greedy batch, and each of the
+    other line's lies in the top-p set of the logits that chose it."""
+    a, b = _prompt(9), _prompt(14, salt=1)
+    cache = _prefill(params, b, slot=2, cache=_prefill(params, a))
+    args = ({0: a[8:], 2: b[12:]}, {0: 8, 2: 12}, 2)
+    _, cold, _ = _burst(params, jax.tree.map(jnp.copy, cache), *args)
+    _, mixed, _ = _burst(params, jax.tree.map(jnp.copy, cache), *args,
+                         temps=[0.0, 0.0, 0.8], top_ps=[1.0, 1.0, 0.9])
+    cold, mixed = np.asarray(cold), np.asarray(mixed)
+    np.testing.assert_array_equal(mixed[:, 0], cold[:, 0])
+    assert mixed[0, 2, :2].tolist() == b[12:]
+    # replay line 2 with its own tokens: position i of a block was chosen
+    # with the positions before it decided and the rest masked
+    k, write = CFG.block_length, jnp.asarray([False, False, True])
+    drawn_off_the_top = 0
+    for j in range(2):
+        pos = jnp.asarray([0, 0, 12 + j * k], jnp.int32)
+        plan = decode_plan_of(jnp.where(write, pos + k, 0), cache["k"])
+        block = mixed[j, 2]
+        for i in range(2 if j == 0 else 0, k):
+            tokens = np.full((SLOTS, k), MASK, np.int32)
+            tokens[2, :i] = block[:i]
+            cache, x, _ = serving._forward(CFG, params, cache,
+                                           jnp.asarray(tokens), pos, write,
+                                           plan)
+            logits = np.asarray(sdar.lm_head(CFG, params, x))[2, i]
+            assert int(block[i]) in _top_p_set(logits, 0.8, 0.9), (j, i)
+            drawn_off_the_top += int(block[i]) != int(logits.argmax())
+        tokens = np.full((SLOTS, k), MASK, np.int32)
+        tokens[2] = block
+        cache, _, _ = serving._forward(CFG, params, cache,
+                                       jnp.asarray(tokens), pos, write, plan)
+    # six draws from a flat distribution: some leave the arg-max
+    assert drawn_off_the_top > 0
+
+
+def test_under_a_confidence_rule_no_row_is_gathered_before_the_head(params):
+    """With every row read the head's program is the plain head: the same
+    jaxpr, no gather in it."""
+    x = jnp.zeros((SLOTS, CFG.block_length, CFG.hidden_size))
+    plain = jax.make_jaxpr(lambda p, x: sdar.lm_head(CFG, p, x))(params, x)
+    whole = jax.make_jaxpr(
+        lambda p, x: serving._logits(CFG, p, x, None))(params, x)
+    assert str(whole) == str(plain) and "gather" not in str(whole)
+    read = jnp.zeros((SLOTS, 1), jnp.int32)
+    some = jax.make_jaxpr(
+        lambda p, x, r: serving._logits(CFG, p, x, r))(params, x, read)
+    assert "gather" in str(some)
+    assert some.out_avals[0].shape == (SLOTS, 1, CFG.vocab_size)
 
 
 # ---- the reference's logits -------------------------------------------------
@@ -514,7 +740,7 @@ def test_the_served_model_says_what_it_is():
     assert served.step(CFG) == (4, 5) and not served.prefill_token
     assert served.decode_step is None and served.copy_prefix_kv is None
     assert not served.prefix_from_line and not served.kv_handoff
-    assert served.counters == serving.COUNTERS and len(served.counters) == 10
+    assert served.counters == serving.COUNTERS and len(served.counters) == 11
     full = replace(SdarConfig(), num_layers=6)
     assert served.kv_block(full, 1536) == 512
     cache = jax.eval_shape(lambda: served.init_cache(full, 128, 1536))
