@@ -14,13 +14,13 @@
   and a dead step's cost can be fitted; the bytes of the live K/V at 819
   GB/s; and the kernel's result against the jnp reference. The same file
   runs against an older tree laid in ``.parent/`` (copy it in).
-- ``burst``: ``engine.decode_burst(steps=8)`` as the engine calls it, random
-  weights, ms a step, with the weight bytes' floor beside it.
+- ``burst``: ``llama_serving.decode_burst(steps=8)`` as the engine calls it,
+  random weights, ms a step, with the weight bytes' floor beside it.
 - ``prefill_kernel``: ``ops.prefill_attention`` alone over all layers for a
   chunk of 512 at 0 / 1,024 / 2,560 cached rows, at a few tile sizes, and
   its result against the jnp reference.
-- ``prefill``: ``engine.prefill_chunk(512)`` as the engine calls it at the
-  same cached rows (docqa's and reason's shapes), ms a chunk, with the time
+- ``prefill``: ``llama_serving.prefill_chunk(512)`` as the engine calls it at
+  the same cached rows (docqa's and reason's shapes), ms a chunk, with the time
   its matmuls need at the chip's peak beside it.
 
 Prints one JSON object as its last line. Times are host clock around
@@ -193,7 +193,7 @@ def bench_burst() -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.llm import engine
+    from ray_tpu.llm import llama_serving
     from ray_tpu.models.llama import LlamaConfig, init_params
 
     out = {}
@@ -203,7 +203,7 @@ def bench_burst() -> dict:
             num_layers=layers, max_seq_len=s, dtype="bfloat16",
             tie_embeddings=False, rope_theta=1e6, **WIDTHS)
         params = jax.jit(partial(init_params, cfg))(jax.random.PRNGKey(0))
-        cache = engine.init_kv_cache(cfg, slots, s)
+        cache = llama_serving.init_kv_cache(cfg, slots, s)
         rng = np.random.default_rng(0)
         lens = _lengths(rng, slots, lo, hi, busy)
         write = jnp.asarray(lens > 0)
@@ -215,8 +215,9 @@ def bench_burst() -> dict:
         steps = 8
 
         def run(cache):
-            return engine.decode_burst(cfg, params, cache, tok, pos, write,
-                                       temps, top_ps, key, steps, False)
+            return llama_serving.decode_burst(
+                cfg, params, cache, tok, pos, write, temps, top_ps, key,
+                steps, False)
 
         cache, toks = run(cache)
         jax.block_until_ready(toks)
@@ -296,7 +297,7 @@ def bench_prefill() -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.llm import engine
+    from ray_tpu.llm import llama_serving
     from ray_tpu.models.llama import LlamaConfig, init_params
 
     out = {}
@@ -305,7 +306,7 @@ def bench_prefill() -> dict:
             num_layers=layers, max_seq_len=s, dtype="bfloat16",
             tie_embeddings=False, rope_theta=1e6, **WIDTHS)
         params = jax.jit(partial(init_params, cfg))(jax.random.PRNGKey(0))
-        cache = engine.init_kv_cache(cfg, slots, s)
+        cache = llama_serving.init_kv_cache(cfg, slots, s)
         toks = jnp.asarray(np.random.default_rng(0).integers(
             0, cfg.vocab_size, size=CHUNK).astype(np.int32))
         layer_params = sum(int(np.prod(a.shape))
@@ -314,7 +315,7 @@ def bench_prefill() -> dict:
                "matmul_floor_ms": 1e3 * 2 * CHUNK * layer_params / PEAK_FLOPS}
         for kv_len in CACHED_ROWS:
             def run(cache):
-                return engine.prefill_chunk(
+                return llama_serving.prefill_chunk(
                     cfg, params, cache, toks, jnp.int32(kv_len),
                     jnp.int32(kv_len + CHUNK), jnp.int32(3))
 

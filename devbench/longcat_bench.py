@@ -120,7 +120,7 @@ def main(argv: list[str]) -> int:
         return 1
     from rtbench.adapters import longcat as adapter
 
-    from ray_tpu.llm import engine, longcat_serving as serving
+    from ray_tpu.llm import longcat_serving as serving, served
     from ray_tpu.models.longcat import forward as longcat_forward
     from ray_tpu.ops import grouped_matmul as gmm
     from ray_tpu.ops import latent_attention as la
@@ -182,7 +182,7 @@ def main(argv: list[str]) -> int:
         # weights: where the three part ways.
         from reference import longcat as reference
 
-        params = jax.jit(engine.init_params, static_argnums=0)(cfg, key)
+        params = jax.jit(served.init_params, static_argnums=0)(cfg, key)
         n, prompt = 1300, 1284
         ids = jnp.asarray(rng.integers(300, cfg.vocab_size, n), jnp.int32)
         # (the rms_norm kernel's block of 256 rows of 6,144 does not fit
@@ -237,7 +237,7 @@ def main(argv: list[str]) -> int:
         return 0
     t0 = time.perf_counter()
     params = jax.block_until_ready(
-        jax.jit(engine.init_params, static_argnums=0)(cfg, key))
+        jax.jit(served.init_params, static_argnums=0)(cfg, key))
     cache = jax.block_until_ready(serving.init_cache(cfg, SLOTS, MAX_SEQ))
     stats = jax.local_devices()[0].memory_stats() or {}
     out(setup_s=round(time.perf_counter() - t0, 1),

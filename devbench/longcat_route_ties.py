@@ -45,14 +45,14 @@ def main(argv: list[str]) -> int:
     from rtbench import common, gen
     from rtbench.adapters import longcat as adapter
 
-    from ray_tpu.llm import engine
+    from ray_tpu.llm import served
     from ray_tpu.models import longcat, routed
 
     with open(os.path.join(ROOT, "benchmark", "configs",
                            "longcat-flash-chat.json")) as f:
         config = json.load(f)
     cfg = adapter.model_config(config, "serve_agent", 8192)
-    params = jax.jit(engine.init_params, static_argnums=0)(
+    params = jax.jit(served.init_params, static_argnums=0)(
         cfg, jax.random.PRNGKey(common.jax_seed(seed)))
     ids = jnp.asarray(gen.prompt_ids(seed, 1, tokens, config["vocab_size"]),
                       jnp.int32)

@@ -8,10 +8,14 @@ moves), and the jaxpr, which has the kernels' bodies and no source lines.
 
 To compare two trees lay them at the same path in turn (a kernel's body
 names its files) and diff the two outputs: a PR that touches code the
-models share shows with it that their programs are what they were. Uses
-only names that trees since PR 38 have. DUMP=<dir> also writes the texts."""
+models share shows with it that their programs are what they were. The
+models are those of ``llm/config.SERVING_MODULES``, each at its ``tiny``
+configuration; a tree from before PR 44 has no such table, and there
+``MODULES`` below names where each model's programs lived (PR 38 to 43).
+DUMP=<dir> also writes the texts."""
 import dataclasses
 import hashlib
+import importlib
 import json
 import os
 import re
@@ -22,19 +26,22 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 import jax, jax.numpy as jnp
 from jax.experimental import topologies
 from jax.sharding import NamedSharding, PartitionSpec as P
-from ray_tpu.llm import engine, lfm2_serving, longcat_serving, ouro_serving
-from ray_tpu.models.lfm2 import Lfm2Config
-from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.models.longcat import LongcatConfig
-from ray_tpu.models.ouro import OuroConfig
+from ray_tpu.llm import config as llm_config
 from ray_tpu.ops.kernels import force_kernel_backend
 from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
 SLOTS, MAX_SEQ, CHUNK = 4, 256, 32
-MODELS = {"llama": (engine, dataclasses.replace(LlamaConfig.tiny(), vocab_size=512, dtype="bfloat16")),
-          "longcat": (longcat_serving, LongcatConfig.tiny(expert_shards=2, max_seq_len=MAX_SEQ, dtype="bfloat16")),
-          "ouro": (ouro_serving, OuroConfig.tiny(max_seq_len=MAX_SEQ, dtype="bfloat16")),
-          "lfm2": (lfm2_serving, Lfm2Config.tiny(max_seq_len=MAX_SEQ, dtype="bfloat16"))}
+# What a model's ``tiny`` takes beside the length and the dtype.
+TINY = {"LlamaConfig": lambda c: dataclasses.replace(c.tiny(), vocab_size=512, dtype="bfloat16"),
+        "LongcatConfig": lambda c: c.tiny(expert_shards=2, max_seq_len=MAX_SEQ, dtype="bfloat16")}
+MODULES = getattr(llm_config, "SERVING_MODULES", None) or {
+    kind: "ray_tpu.llm." + ("engine" if kind.__name__ == "LlamaConfig" else kind.__name__[:-len("Config")].lower() + "_serving")
+    for kind in llm_config.ModelConfig.__args__}
+
+def models():
+    for kind, module in MODULES.items():
+        tiny = TINY.get(kind.__name__, lambda c: c.tiny(max_seq_len=MAX_SEQ, dtype="bfloat16"))
+        yield kind.__name__[:-len("Config")].lower(), importlib.import_module(module), tiny(kind)
 
 def run(backend):
     out = {}
@@ -50,15 +57,19 @@ def run(backend):
     def arg(shape, dtype=jnp.int32):
         return sds(jax.ShapeDtypeStruct(shape, dtype))
     with ctx:
-        for name, (module, cfg) in MODELS.items():
-            served = engine.served_model(cfg)
+        for name, module, cfg in models():
+            served = getattr(module, "SERVED", None) or module.served_model(cfg)
             params = jax.tree.map(sds, jax.eval_shape(lambda: served.init_params(cfg, jax.random.PRNGKey(0))))
             cache = jax.tree.map(sds, jax.eval_shape(lambda: served.init_cache(cfg, SLOTS, MAX_SEQ)))
+            # a burst's tokens: [slots], or [slots, K] where a step is a block of K positions
+            token0 = (SLOTS,) if served.step is None else (SLOTS, served.step(cfg)[0])
             progs = {
               "prefill_chunk": (cfg, params, cache, arg((CHUNK,)), arg(()), arg(()), arg(())),
               "decode_step": (cfg, params, cache, arg((SLOTS,)), arg((SLOTS,)), arg((SLOTS,), jnp.bool_)),
-              "decode_burst": (cfg, params, cache, arg((SLOTS,)), arg((SLOTS,)), arg((SLOTS,), jnp.bool_), arg((SLOTS,), jnp.float32), arg((SLOTS,), jnp.float32), arg((2,), jnp.uint32), 4, False),
+              "decode_burst": (cfg, params, cache, arg(token0), arg((SLOTS,)), arg((SLOTS,), jnp.bool_), arg((SLOTS,), jnp.float32), arg((SLOTS,), jnp.float32), arg((2,), jnp.uint32), 4, False),
             }
+            if served.decode_step is None:
+                del progs["decode_step"]
             for prog, args in progs.items():
                 text = getattr(module, prog).lower(*args).as_text()
                 out[f"{name}.{prog}.{backend}"] = hashlib.sha256(text.encode()).hexdigest()[:16]

@@ -32,7 +32,7 @@ hoisted). The same file runs against an older tree laid in ``.parent/``
 in a denoising forward, alone, at all 512 rows (128 lines x 4) and at the
 128 a forward of the ``sequential`` rule can read: microseconds a call of
 ``sdar.lm_head``, of each piece of the choice on float32 logits that are
-there (the arg-max; ``engine.sample_tokens``, which computes the arg-max
+there (the arg-max; ``served.sample_tokens``, which computes the arg-max
 and a categorical draw for every row and picks afterwards; the chosen
 token's probability; a ``lax.cond`` that makes the draw only where a row
 has a temperature, with none and with one), and of the head with a choice
@@ -418,7 +418,7 @@ def head() -> dict:
     import jax.numpy as jnp
     from jax import lax
 
-    from ray_tpu.llm.engine import sample_tokens
+    from ray_tpu.llm.served import sample_tokens
     from ray_tpu.models import sdar
 
     cfg = config()

@@ -3,7 +3,9 @@
 Capability parity with the reference's ray.llm (reference: python/ray/llm/
 — LLMConfig, LLMServer over vLLM, OpenAI ingress; SURVEY.md §2.3 M5). The
 engine is TPU-native: continuous batching over a static-shape slot KV
-cache, jitted prefill/decode, on-device sampling (engine.py).
+cache (the scheduler, engine.py), jitted prefill/decode programs a model
+(<name>_serving.py, found by config.SERVING_MODULES) behind one contract
+with on-device sampling (served.py).
 """
 
 from ray_tpu.llm.config import LLMConfig, SamplingParams

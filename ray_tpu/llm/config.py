@@ -10,7 +10,7 @@ axis, not a worker-process count.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Any
+from typing import Any, Union
 
 from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
@@ -18,9 +18,19 @@ from ray_tpu.models.longcat import LongcatConfig
 from ray_tpu.models.ouro import OuroConfig
 from ray_tpu.models.sdar import SdarConfig
 
-# A model's own configuration: what llm/engine.served_model knows a model by.
-ModelConfig = (LlamaConfig | LongcatConfig | OuroConfig | Lfm2Config
-               | SdarConfig)
+# The served models: a model's own configuration, which is what
+# llm/served.served_model knows a model by, and the module that holds its
+# programs and its ``SERVED`` (imported when the configuration is first
+# asked for). A new model is its two files (models/<name>.py,
+# llm/<name>_serving.py) and one line here.
+SERVING_MODULES = {
+    LlamaConfig: "ray_tpu.llm.llama_serving",
+    LongcatConfig: "ray_tpu.llm.longcat_serving",
+    OuroConfig: "ray_tpu.llm.ouro_serving",
+    Lfm2Config: "ray_tpu.llm.lfm2_serving",
+    SdarConfig: "ray_tpu.llm.sdar_serving",
+}
+ModelConfig = Union[tuple(SERVING_MODULES)]
 
 
 @dataclass

@@ -3,20 +3,22 @@
 Capability parity with the reference's serving engine (reference: ray.llm
 wraps vLLM — _internal/serve/engines/vllm/vllm_models.py:148; continuous
 batching + paged KV are vLLM internals). TPU-native design instead of a
-wrapper:
+wrapper. This file is the scheduler and nothing of a model: it knows a
+model through ``self.model`` only, the contract of llm/served.py
+(``ServedModel``), which llm/config.SERVING_MODULES finds for a
+configuration; a model's programs live in its llm/<name>_serving.py.
 
 - **Static shapes everywhere** (XLA compiles once per prefill bucket):
-  the KV cache is a dense [layers, slots, kv_heads, max_seq, head_dim]
-  pool; a sequence owns one slot for its lifetime — slot admission is the
-  scheduling unit, like vLLM's paged blocks but shaped for XLA/TPU (no
-  dynamic page tables). It is the engine's one KV layout; a pool of
-  blocks shared between lines comes with ROADMAP R3, as tables the
-  attention kernels read. A prefill chunk writes its rows of its slot and
-  layer in place and reads only the live blocks of that slot's line
-  (ops/prefill_attention.py); a decode step does the same for its one
-  row a slot (ops/decode_attention.py), both grouped over the query
-  heads of a KV head: the stacked cache is loop carry, and no operation
-  of a chunk or decode program has a whole layer of it as operand.
+  the cache is a model's pytree of dense ``[lines, slots, ...]`` leaves (for
+  most, per-head K/V ``[lines, slots, kv_heads, max_seq, head_dim]``:
+  llm/served.init_kv_cache); a sequence owns one slot for its lifetime —
+  slot admission is the scheduling unit, like vLLM's paged blocks but
+  shaped for XLA/TPU (no dynamic page tables). It is the engine's one KV
+  layout; a pool of blocks shared between lines comes with ROADMAP R3, as
+  tables the attention kernels read. The programs write a chunk's or a step's rows of
+  a slot in place and read only the live blocks of its line: the stacked
+  cache is loop carry, and no operation of a chunk or decode program has a
+  whole layer of it as operand.
 - **Continuous batching**: every engine tick admits waiting requests into
   free slots (bucketed prefill) and then decodes ALL active slots in one
   batched jitted step — new requests join mid-flight without stalling
@@ -30,7 +32,7 @@ wrapper:
   the device makes them. The host reads, emits, admits and prepares inputs
   beside a running burst, not between two.
 - **Sampling on-device**: temperature/top-k/top-p in fp32 logits, one
-  fused jit; greedy when temperature == 0.
+  fused jit (llm/served.sample_tokens); greedy when temperature == 0.
 - Cache buffers are donated through jit so XLA updates them in place.
 """
 
@@ -42,9 +44,7 @@ import threading
 import time
 import uuid
 from collections import deque
-from collections.abc import Callable
 from dataclasses import dataclass, field, replace
-from functools import partial
 from typing import Any
 
 import jax
@@ -57,26 +57,40 @@ from ray_tpu.devtools.annotations import guarded_by
 from ray_tpu.llm.config import LLMConfig, SamplingParams
 from ray_tpu.util import tracing
 from ray_tpu.llm.tokenizer import get_tokenizer
-from ray_tpu.models import llama as llama_model
-from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.ops.decode_attention import (
-    decode_attention,
-    decode_kv_block,
-    decode_plan_of,
-    kv_positions_read,
-    kv_row_write,
-)
-from ray_tpu.ops.kernels import KernelMesh
-from ray_tpu.ops.norms import rms_norm
-from ray_tpu.ops.prefill_attention import prefill_attention, prefill_kv_write
-from ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ray_tpu.ops.decode_attention import kv_positions_read
 from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 from ray_tpu.parallel.sharding import kernel_mesh, shard_params
 from ray_tpu.utils.compile_cache import ensure_compile_cache
 
-logger = logging.getLogger(__name__)
+# The contract (llm/served.py) is all the scheduler knows of a model. Some
+# of these names are also held in THIS module by other people's files, so
+# they stay importable from here until ROADMAP R0 (f), the ``benchmark`` PR
+# that points those files at llm/served.py and llm/llama_serving.py:
+# - ``init_params``: benchmark/rtbench/kinds/serve_common.py and
+#   benchmark/control.py put a jitted copy in this module's name (the engine
+#   calls it through that name, looked up when it is called), and
+#   tests/bench_harness/test_bh_{longcat,ouro,lfm2,sdar}.py call it here;
+# - ``served_model``, ``ServedModel``, ``sample_tokens``: the contract as
+#   benchmark/rtbench/adapters/{__init__,longcat}.py describe it, by this
+#   module's name;
+# - ``init_kv_cache``, ``prefill_chunk``, ``decode_step``: Llama's programs,
+#   with their two-value returns, for
+#   tests/bench_harness/test_bh_reference.py alone. Nothing in this file
+#   uses them: the scheduler reaches every program through ``self.model``.
+from ray_tpu.llm.served import (  # noqa: F401
+    ServedModel,
+    init_params,
+    require_kv_handoff,
+    sample_tokens,
+    served_model,
+)
+from ray_tpu.llm.llama_serving import (  # noqa: F401
+    decode_step,
+    init_kv_cache,
+    prefill_chunk,
+)
 
-NEG_INF = -1e30
+logger = logging.getLogger(__name__)
 
 
 def _lcp(a, b, cap: int) -> int:
@@ -85,382 +99,6 @@ def _lcp(a, b, cap: int) -> int:
     while i < n and a[i] == b[i]:
         i += 1
     return i
-
-
-def init_kv_cache(cfg: LlamaConfig, max_slots: int, max_seq: int):
-    shape = (cfg.num_layers, max_slots, cfg.num_kv_heads, max_seq,
-             cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.jnp_dtype),
-            "v": jnp.zeros(shape, cfg.jnp_dtype)}
-
-
-def _project_qkv(cfg: LlamaConfig, lp, xn, b, s):
-    q = (xn @ lp["wq"]).reshape(b, s, cfg.num_heads, cfg.head_dim)
-    k = (xn @ lp["wk"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    v = (xn @ lp["wv"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    return (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3))
-
-
-def _repeat_kv(x, n_rep: int):
-    if n_rep == 1:
-        return x
-    b, h, s, d = x.shape
-    return jnp.broadcast_to(x[:, :, None], (b, h, n_rep, s, d)).reshape(
-        b, h * n_rep, s, d)
-
-
-@tracing.part("mlp")
-def _mlp(cfg: LlamaConfig, lp, x, kmesh):
-    dt = x.dtype
-    xn = rms_norm(x, lp["mlp_norm"], cfg.norm_eps, kmesh)
-    gate = jax.nn.silu((xn @ lp["w_gate"]).astype(jnp.float32)).astype(dt)
-    up = xn @ lp["w_up"]
-    # The product is kept as an array of its own: fused into the down
-    # projection as its operand, XLA computes it again for every tile of
-    # the output (a chunk of 512 at Mistral widths: 0.61 ms a layer against
-    # 0.33, my chip run, PR 28).
-    act = lax.optimization_barrier(gate * up)
-    return x + (act @ lp["w_down"]).astype(dt)
-
-
-@tracing.part("head")
-def _lm_head(cfg: LlamaConfig, params, x, kmesh):
-    """x: [B, S, H] → fp32 logits [B, S, V]."""
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps, kmesh)
-    head = (params["embed_tokens"].T if cfg.tie_embeddings
-            else params["lm_head"])
-    return x.astype(jnp.float32) @ head.astype(jnp.float32)
-
-
-@partial(jax.jit, static_argnums=(0,), static_argnames=("kmesh",),
-         donate_argnums=(2,))
-def prefill(cfg: LlamaConfig, params, cache, tokens, length, slot, *,
-            kmesh: KernelMesh | None = None):
-    """Prefill ONE sequence into cache slot ``slot``.
-
-    ``kmesh`` (here and on every program below): the engine's mesh when
-    tensor-parallel, for the Pallas kernels (ops/kernels.py); None on one
-    device.
-
-    tokens: [S_bucket] (padded), length: scalar int32 (true prompt length),
-    returns (cache, next_token_logits [V]).
-    """
-    s = tokens.shape[0]
-    with tracing.part("embed"):
-        x = params["embed_tokens"][tokens][None]  # [1, S, H]
-    with tracing.part("attn"):
-        positions = jnp.arange(s)
-        inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
-                                    cfg.rope_scaling)
-        n_rep = cfg.num_heads // cfg.num_kv_heads
-        causal = (positions[None, :] <= positions[:, None])  # [S, S]
-        valid = positions[None, :] < length
-        mask = (causal & valid)[None, None]  # [1, 1, S, S]
-
-    def body(x, scanned):
-        lp, k_l, v_l = scanned  # k_l/v_l: [slots, Hkv, max_seq, D]
-        b, s_, _ = x.shape
-        with tracing.part("attn"):
-            xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
-            q, k, v = _project_qkv(cfg, lp, xn, b, s_)
-            q = apply_rope(q, positions, inv_freq)
-            k = apply_rope(k, positions, inv_freq)
-            # Write this layer's K/V into the slot (positions 0..S).
-            with tracing.part("cache"):
-                k_l = lax.dynamic_update_slice(
-                    k_l, k[0].astype(k_l.dtype)[None], (slot, 0, 0, 0))
-                v_l = lax.dynamic_update_slice(
-                    v_l, v[0].astype(v_l.dtype)[None], (slot, 0, 0, 0))
-            kr, vr = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
-            scores = jnp.einsum("bhqd,bhkd->bhqk", q, kr).astype(jnp.float32)
-            scores = scores / np.sqrt(cfg.head_dim) \
-                + jnp.where(mask, 0.0, NEG_INF)
-            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-            o = jnp.einsum("bhqk,bhkd->bhqd", probs, vr)
-            o = o.transpose(0, 2, 1, 3).reshape(b, s_, -1)
-            x = x + (o @ lp["wo"]).astype(x.dtype)
-        x = _mlp(cfg, lp, x, kmesh)
-        return x, (k_l, v_l)
-
-    with tracing.part("stack"):
-        x, (new_k, new_v) = lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
-    logits = _lm_head(cfg, params, x, kmesh)[0]  # [S, V]
-    with tracing.part("head"):
-        last = logits[jnp.maximum(length - 1, 0)]
-    return {"k": new_k, "v": new_v}, last
-
-
-@partial(jax.jit, static_argnums=(0,), static_argnames=("kmesh",),
-         donate_argnums=(2,))
-def prefill_chunk(cfg: LlamaConfig, params, cache, tokens, kv_len, length,
-                  slot, *, kmesh: KernelMesh | None = None):
-    """Prefill ONE chunk of one sequence (chunked prefill — long prompts are
-    split so decode steps interleave between chunks instead of stalling
-    behind a whole-prompt prefill; reference shape: vLLM chunked prefill /
-    enable_chunked_prefill).
-
-    tokens: [C] chunk (padded), kv_len: tokens already cached for this slot,
-    length: true total prompt length. Queries attend to cache[0..kv_len) +
-    the chunk's own causal prefix. Returns (cache, last-token logits [V]).
-
-    The convention of ``_multi_token_impl``: the stacked cache rides the
-    layer loop as carry, a layer writes the chunk's C rows of its slot in
-    place and ops/prefill_attention.py reads the slot's live blocks straight
-    out of the stack, so no operation of the program has the whole cache, or
-    a whole layer of it, as operand or result.
-    """
-    c = tokens.shape[0]
-    num_layers = cache["k"].shape[0]
-    with tracing.part("embed"):
-        x = params["embed_tokens"][tokens][None]  # [1, C, H]
-    with tracing.part("attn"):
-        positions = kv_len + jnp.arange(c)
-        inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
-                                    cfg.rope_scaling)
-
-    def body(carry, scanned):
-        x, k_all, v_all = carry
-        lp, layer = scanned
-        with tracing.part("attn"):
-            xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
-            q, k, v = _project_qkv(cfg, lp, xn, 1, c)
-            q = apply_rope(q, positions, inv_freq)
-            k = apply_rope(k, positions, inv_freq)
-            with tracing.part("cache"):
-                k_all, v_all = prefill_kv_write(k_all, v_all, k[0], v[0],
-                                                layer, slot, kv_len)
-            o = prefill_attention(q[0], k_all, v_all, layer, slot, kv_len,
-                                  length, kmesh=kmesh)
-            o = o.transpose(1, 0, 2).reshape(1, c, -1)
-            x = x + (o @ lp["wo"]).astype(x.dtype)
-        x = _mlp(cfg, lp, x, kmesh)
-        return (x, k_all, v_all), None
-
-    with tracing.part("stack"):
-        (x, new_k, new_v), _ = lax.scan(
-            body, (x, cache["k"], cache["v"]),
-            (params["layers"], jnp.arange(num_layers)))
-    # The head on the one row that is kept.
-    with tracing.part("head"):
-        last = lax.dynamic_slice_in_dim(
-            x, jnp.clip(length - 1 - kv_len, 0, c - 1), 1, 1)  # [1, 1, H]
-    return {"k": new_k, "v": new_v}, _lm_head(cfg, params, last, kmesh)[0, 0]
-
-
-def _decode_step_impl(cfg: LlamaConfig, params, cache, tokens, positions,
-                      write_mask=None, *, kmesh: KernelMesh | None = None):
-    """One decode step for EVERY slot.
-
-    tokens: [B] (last sampled token per slot), positions: [B] (where each
-    token is written/attends from). write_mask: [B] bool — slots mid-prefill
-    or empty must not have garbage K/V written into their cache (False =
-    keep the existing cache line). Returns (cache, logits [B, V]).
-
-    Exactly the K=1 case of the multi-token body (speculative
-    verification runs it at K > 1) — ONE implementation of the
-    masked-attention/KV-write math, so the two paths can never diverge.
-    """
-    if write_mask is None:
-        write_mask = jnp.ones(tokens.shape, bool)
-    cache, logits = _multi_token_impl(cfg, params, cache, tokens[:, None],
-                                      positions, write_mask, kmesh)
-    with tracing.part("head"):
-        return cache, logits[:, 0]
-
-
-def _multi_token_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
-                      write_mask, kmesh=None):
-    """Consume K tokens per slot in one pass against the KV cache.
-
-    tokens: [B, K]; positions0: [B] — tokens[:, j] is written at
-    positions0 + j (contiguous); query j attends kv through its own
-    position. Returns (cache, logits [B, K, V]).
-
-    The stacked cache rides the layer loop as carry, not as scan xs/ys: a
-    layer writes its K new rows of each slot in place and
-    ops/decode_attention.py reads the layer's live blocks straight out of
-    the stack, so no operation of the program has a whole layer of the
-    cache, or the whole cache, as operand or result. A slot with
-    ``write_mask`` false has length 0: nothing of its line is read, and its
-    logits mean nothing."""
-    b, k = tokens.shape
-    num_layers = cache["k"].shape[0]
-    with tracing.part("embed"):
-        x = params["embed_tokens"][tokens]  # [B, K, H]
-    with tracing.part("attn"):
-        inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
-                                    cfg.rope_scaling)
-        positions = positions0[:, None] + jnp.arange(k)[None, :]  # [B, K]
-        lengths = jnp.where(write_mask, positions0 + k, 0)
-        # Every layer attends at the same lengths: one walk of the live
-        # blocks, planned here and not in the loop.
-        plan = decode_plan_of(lengths, cache["k"], kmesh=kmesh)
-
-    def body(carry, scanned):
-        x, k_all, v_all = carry
-        lp, layer = scanned
-        with tracing.part("attn"):
-            xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
-            q, kk, v = _project_qkv(cfg, lp, xn, b, k)
-            q = apply_rope(q, positions, inv_freq)
-            kk = apply_rope(kk, positions, inv_freq)
-            with tracing.part("cache"):
-                k_all, v_all = kv_row_write(k_all, v_all, kk, v, layer,
-                                            positions0, write_mask,
-                                            kmesh=kmesh)
-            o = decode_attention(q, k_all, v_all, layer, lengths, positions0,
-                                 plan=plan, kmesh=kmesh)
-            o = o.transpose(0, 2, 1, 3).reshape(b, k, -1)
-            x = x + (o @ lp["wo"]).astype(x.dtype)
-        x = _mlp(cfg, lp, x, kmesh)
-        return (x, k_all, v_all), None
-
-    with tracing.part("stack"):
-        (x, new_k, new_v), _ = lax.scan(
-            body, (x, cache["k"], cache["v"]),
-            (params["layers"], jnp.arange(num_layers)))
-    logits = _lm_head(cfg, params, x, kmesh)  # [B, K, V]
-    return {"k": new_k, "v": new_v}, logits
-
-
-decode_step = partial(jax.jit, static_argnums=(0,),
-                      static_argnames=("kmesh",),
-                      donate_argnums=(2,))(_decode_step_impl)
-
-
-@partial(jax.jit, static_argnums=(0, 9, 10), static_argnames=("kmesh",),
-         donate_argnums=(2,))
-def decode_burst(cfg: LlamaConfig, params, cache, token0, positions0,
-                 write_mask, temps, top_ps, key, steps: int,
-                 need_top_p: bool = True, *,
-                 kmesh: KernelMesh | None = None):
-    """``steps`` chained decode+sample ticks in ONE dispatch: the sampled
-    token feeds the next step on device (lax.scan), so the host⇄device
-    roundtrip — a large part of per-token latency for small models — is
-    paid once per ``steps`` tokens instead of per token. Greedy/temperature/top-p sampling only (top-k
-    needs a static k; the engine falls back to single-step ticks).
-    Returns (cache, tokens [steps, B])."""
-
-    def step(carry, j):
-        c, tok, pos = carry
-        c, logits = _decode_step_impl(cfg, params, c, tok, pos, write_mask,
-                                      kmesh=kmesh)
-        with tracing.part("sample"):
-            nxt = sample_tokens(logits.astype(jnp.float32), temps, top_ps, 0,
-                                jax.random.fold_in(key, j),
-                                need_top_p).astype(jnp.int32)
-            return (c, nxt, pos + 1), nxt
-
-    with tracing.part("stack"):
-        (cache, _, _), toks = lax.scan(step, (cache, token0, positions0),
-                                       jnp.arange(steps))
-    return cache, toks
-
-
-# ---------------------------------------------------------------------------
-# Speculative decoding (reference capability: the vLLM speculative-decoding
-# path behind the reference's llm serving stack). Decode is HBM-bound on
-# TPU — one token per full weight read; verifying K draft tokens in one
-# forward amortizes the weight traffic K-fold when the draft is right.
-# Rollback is FREE in this cache design: entries written beyond the
-# accepted prefix sit at positions >= next_pos, which every later read
-# masks (kv_pos <= position) and every later write overwrites.
-
-
-@partial(jax.jit, static_argnums=(0, 5), static_argnames=("kmesh",),
-         donate_argnums=(2,))
-def draft_propose(cfg: LlamaConfig, params, cache, token0, positions0,
-                  k: int, write_mask, *, kmesh: KernelMesh | None = None):
-    """Greedy-propose ``k`` tokens with the draft model in ONE dispatch
-    (lax.scan over its decode step). Writes draft KV for token0 and the
-    first k-1 proposals. Returns (cache, proposals [B, k])."""
-
-    def step(carry, _):
-        c, tok, pos = carry
-        c, logits = _decode_step_impl(cfg, params, c, tok, pos, write_mask,
-                                      kmesh=kmesh)
-        with tracing.part("sample"):
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (c, nxt, pos + 1), nxt
-
-    # k+1 iterations: the extra step writes the LAST proposal's KV inside
-    # this same dispatch (its own proposal is discarded), so a
-    # full-acceptance tick needs no separate one-token catch-up prefill.
-    with tracing.part("stack"):
-        (cache, _, _), toks = lax.scan(step, (cache, token0, positions0),
-                                       None, length=k + 1)
-    with tracing.part("sample"):
-        return cache, toks.T[:, :k]  # [B, k]
-
-
-@partial(jax.jit, static_argnums=(0,), static_argnames=("kmesh",),
-         donate_argnums=(2,))
-def spec_verify_step(cfg: LlamaConfig, params, cache, tokens, positions0,
-                     write_mask, *, kmesh: KernelMesh | None = None):
-    """Target forward over K tokens per slot in one pass (the jitted
-    multi-token body decode_step is the K=1 case of).
-
-    tokens: [B, K] — the last sampled token followed by the draft
-    proposals; positions0: [B] — where tokens[:, 0] is written. Writes
-    K/V for all K positions (contiguous) and returns (cache,
-    logits [B, K, V]): logits[:, j] scores the token at position
-    positions0 + j + 1, which is what acceptance compares against."""
-    return _multi_token_impl(cfg, params, cache, tokens, positions0,
-                             write_mask, kmesh)
-
-
-@partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
-@tracing.part("cache")
-def copy_prefix_kv(cfg: LlamaConfig, cache, src_slot, dst_slot):
-    """Copy one slot's whole KV line to another slot, all layers at once
-    (prefix-cache adoption from a LIVE donor). Copying the full max_seq
-    line is safe: positions beyond the adopted prefix are masked by
-    ``length``/``positions`` in prefill_chunk/decode_step, and the copy is
-    pure HBM bandwidth — orders of magnitude cheaper than recomputing the
-    prefix (vLLM APC makes the same recompute-vs-reuse trade)."""
-    k_line = lax.dynamic_slice_in_dim(cache["k"], src_slot, 1, 1)
-    v_line = lax.dynamic_slice_in_dim(cache["v"], src_slot, 1, 1)
-    return {
-        "k": lax.dynamic_update_slice(cache["k"], k_line,
-                                      (0, dst_slot, 0, 0, 0)),
-        "v": lax.dynamic_update_slice(cache["v"], v_line,
-                                      (0, dst_slot, 0, 0, 0)),
-    }
-
-
-@partial(jax.jit, static_argnums=(3, 5))
-@tracing.part("sample")
-def sample_tokens(logits, temps, top_ps, top_k: int, key,
-                  need_top_p: bool = True):
-    """logits [B, V] fp32; temps/top_ps [B]. Greedy where temp == 0.
-
-    ``need_top_p=False`` (static) skips the vocab-wide argsort + cumsum of
-    nucleus filtering — with top_p == 1.0 the filter keeps every token
-    anyway (cum − p < 1 holds for all p > 0), and the sort over V=128k per
-    step is BY FAR the most expensive op in the sampler (it dwarfs greedy
-    argmax and even rivals a 1B decode forward). The engine passes it
-    per-batch: only when some active request actually sets top_p < 1."""
-    greedy = jnp.argmax(logits, axis=-1)
-    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-    if top_k > 0:
-        kth = jnp.sort(scaled, axis=-1)[:, -top_k][:, None]
-        scaled = jnp.where(scaled < kth, NEG_INF, scaled)
-    if need_top_p:
-        # top-p: keep the smallest prefix of sorted probs with cumsum <= p
-        sorted_idx = jnp.argsort(-scaled, axis=-1)
-        sorted_logits = jnp.take_along_axis(scaled, sorted_idx, axis=-1)
-        probs = jax.nn.softmax(sorted_logits, axis=-1)
-        cum = jnp.cumsum(probs, axis=-1)
-        keep_sorted = cum - probs < top_ps[:, None]  # always keep the first
-        keep = jnp.zeros_like(keep_sorted).at[
-            jnp.arange(logits.shape[0])[:, None], sorted_idx].set(keep_sorted)
-        masked = jnp.where(keep, scaled, NEG_INF)
-    else:
-        masked = scaled
-    sampled = jax.random.categorical(key, masked, axis=-1)
-    return jnp.where(temps <= 0.0, greedy, sampled)
 
 
 @jax.jit
@@ -479,160 +117,6 @@ def _join_token(tokens, first, slot):
     return lax.dynamic_update_slice(tokens, first.astype(tokens.dtype),
                                     (slot,))
 
-
-@dataclass(frozen=True)
-class ServedModel:
-    """What a model supplies for the engine to serve it. The engine owns
-    the schedule (admission, chunked prefill, bursts and the look-ahead, sampling,
-    prefix adoption, the counters) and knows a model only through this:
-
-    - ``init_params(cfg, key)`` and ``param_logical_axes(cfg)``;
-    - ``init_cache(cfg, slots, max_seq)``: the slot cache, a pytree whose
-      leaves the programs below take donated and give back. A leaf's
-      leading dimension is cache *lines*, of which a model may have more
-      than layers (two attentions a layer, or a line for every pass of a
-      looped stack); the slot is the second;
-    - ``prefill_chunk``, ``decode_step``, ``decode_burst``,
-      ``copy_prefix_kv``: jitted programs with the signatures of this
-      module's own (the Llama ones), under these very names so that a
-      device trace shows ``jit_prefill_chunk`` and ``jit_decode_burst``
-      whatever the model. A model with ``counters`` returns one more
-      value from the first three: an int32 array of that many counts,
-      which the scheduler adds into ``stats()`` under those names where it
-      fetches the tokens; ``constants(cfg)`` gives what ``stats()`` carries
-      beside them unchanged (a denominator of theirs);
-    - ``step(cfg)``: (positions, forwards), the size of one decode step of
-      one line: the positions it takes in and gives back decided, and the
-      forwards of the stack it costs. None is (1, 1), a token in and a
-      token out by one forward: a prompt's last chunk gives the first
-      token (``prefill_chunk`` returns that row's logits) and a step's
-      input is the token the step before sampled, handed on on the device.
-      A model that decides a block of K positions by several forwards says
-      (K, forwards), and everything else that sets it apart follows from
-      this one statement (``prefill_token``; nothing else is looked at):
-      the scheduler counts a line's progress, its budget and its cache
-      line's end in steps of K positions, a burst is whole steps
-      (``decode_burst`` takes tokens ``[slots, K]`` and returns ``[steps,
-      slots, K]``; a line's last step may decide more positions than its
-      request wants, and the surplus is not emitted), ``decode_steps`` and
-      the dispatch phase's ``steps`` count forwards. Such a model samples
-      between its forwards, on the device, so it has no ``decode_step``
-      (None, and only then: a mix is refused here, where it is built): a
-      lone step is a burst of one, and a request with ``top_k`` is
-      refused. Its prefill gives no token: the prompt's whole steps are
-      prefilled (``len(prompt) - len(prompt) % K`` tokens;
-      ``prefill_chunk`` returns None for the logits), nothing is emitted
-      for them, and the tokens past them ride into the line's first step
-      in their places (-1 at every position a step has to decide); the
-      first token comes when that step is read. A step's input is then
-      known to the host before the step before it has run, and a burst is
-      queued behind another with nothing handed over;
-    - ``kv_block(cfg, max_seq)``: the positions its decode attention
-      fetches at a time, behind ``kv_positions_read``;
-    - ``kv_handoff``: whether a line can be exported and imported as
-      per-head K/V (the prefill/decode hand-off);
-    - ``prefix_from_line``: whether a prompt's first tokens can be adopted
-      from another slot's line, at any common length. False for a model
-      that also keeps a state of fixed size a slot (a short convolution's,
-      a recurrence's): the state at the adopted length is nowhere unless
-      it was saved then. The engine then adopts nothing, counts no hit,
-      publishes no prefix to the router and never calls
-      ``copy_prefix_kv``, which may be None;
-    - ``refuse(config)``: raises ValueError for an ``LLMConfig`` it cannot
-      serve (None: it serves them all)."""
-
-    init_params: Callable
-    param_logical_axes: Callable
-    init_cache: Callable
-    prefill_chunk: Callable
-    decode_step: Callable | None
-    decode_burst: Callable
-    kv_block: Callable
-    copy_prefix_kv: Callable | None = None
-    counters: tuple[str, ...] = ()
-    constants: Callable | None = None
-    step: Callable | None = None
-    kv_handoff: bool = True
-    prefix_from_line: bool = True
-    refuse: Callable | None = None
-
-    def __post_init__(self):
-        if (self.step is None) == (self.decode_step is None):
-            raise ValueError(
-                "a ServedModel has a decode_step or states its step, one "
-                "of the two: a step of several positions samples on the "
-                "device and has no single-step program, and a model with "
-                "such a program takes a token in and gives a token out")
-
-    @property
-    def prefill_token(self) -> bool:
-        """Whether a prompt's last chunk gives the first token: for every
-        model but one that states its ``step``."""
-        return self.step is None
-
-
-# The Llama programs are this module's own, reached through its names when
-# they are called (a test puts a failing one in a name's place).
-_LLAMA = ServedModel(
-    init_params=llama_model.init_params,
-    param_logical_axes=llama_model.param_logical_axes,
-    init_cache=init_kv_cache,
-    prefill_chunk=lambda *a, **kw: prefill_chunk(*a, **kw),
-    decode_step=lambda *a, **kw: decode_step(*a, **kw),
-    decode_burst=lambda *a, **kw: decode_burst(*a, **kw),
-    copy_prefix_kv=lambda *a, **kw: copy_prefix_kv(*a, **kw),
-    kv_block=lambda cfg, max_seq: decode_kv_block(
-        max_seq, cfg.head_dim, cfg.jnp_dtype.itemsize),
-)
-
-
-def served_model(cfg) -> ServedModel:
-    """The model behind a configuration, by its type."""
-    if isinstance(cfg, LlamaConfig):
-        return _LLAMA
-    from ray_tpu.models.longcat import LongcatConfig
-
-    if isinstance(cfg, LongcatConfig):
-        from ray_tpu.llm.longcat_serving import SERVED
-
-        return SERVED
-    from ray_tpu.models.ouro import OuroConfig
-
-    if isinstance(cfg, OuroConfig):
-        from ray_tpu.llm.ouro_serving import SERVED
-
-        return SERVED
-    from ray_tpu.models.lfm2 import Lfm2Config
-
-    if isinstance(cfg, Lfm2Config):
-        from ray_tpu.llm.lfm2_serving import SERVED
-
-        return SERVED
-    from ray_tpu.models.sdar import SdarConfig
-
-    if isinstance(cfg, SdarConfig):
-        from ray_tpu.llm.sdar_serving import SERVED
-
-        return SERVED
-    raise TypeError(f"the engine serves no {type(cfg).__name__}: it serves "
-                    "SdarConfig, Lfm2Config, LlamaConfig, LongcatConfig and "
-                    "OuroConfig")
-
-
-def require_kv_handoff(cfg) -> None:
-    """Raise unless the model's cache lines can be handed from a prefill
-    engine to a decode engine (llm/pd.py asks before it builds one)."""
-    if not served_model(cfg).kv_handoff:
-        raise ValueError(
-            f"{type(cfg).__name__} does not support the prefill/decode "
-            "hand-off: its cache is not per-head K/V")
-
-
-def init_params(cfg, key):
-    """The served model's own initialiser. The engine reaches it through
-    this module-level name, looked up when it is called, so that a caller
-    may put a jitted copy in its place."""
-    return served_model(cfg).init_params(cfg, key)
 
 
 @dataclass
@@ -712,6 +196,19 @@ class LLMEngine:
                 "ROADMAP R3, as tables the attention kernels read")
         if self.model.refuse is not None:
             self.model.refuse(config)
+        # Speculative decoding: draft model + its own KV cache. The draft
+        # must share the tokenizer's vocab space with the target; the
+        # target supplies the verify program and the draft the proposals.
+        self.draft_cfg = config.draft_model_config()
+        self.draft_model = (served_model(self.draft_cfg)
+                            if self.draft_cfg is not None else None)
+        if self.draft_model is not None:
+            for cfg, program in (
+                    (self.model_cfg, self.model.spec_verify_step),
+                    (self.draft_cfg, self.draft_model.draft_propose)):
+                if program is None:
+                    raise ValueError(f"{type(cfg).__name__} does not "
+                                     "support a speculative draft")
         self.tokenizer = get_tokenizer(config.tokenizer)
         self.max_slots = config.max_num_seqs
 
@@ -799,9 +296,6 @@ class LLMEngine:
         self.first_token_wait_s = 0.0
         self.cache = self._new_cache(self.model_cfg)
 
-        # Speculative decoding: draft model + its own KV cache. The draft
-        # must share the tokenizer's vocab space with the target.
-        self.draft_cfg = config.draft_model_config()
         self.spec_k = max(1, int(config.speculative_tokens))
         self.draft_params = None
         self.draft_cache = None
@@ -1200,8 +694,7 @@ class LLMEngine:
             return worked
 
     def _prefill_steps(self) -> bool:
-        budget = max(1, int(getattr(self.config,
-                                    "prefill_chunks_per_tick", 1) or 1))
+        budget = max(1, self.config.prefill_chunks_per_tick)
         spent = 0
         while spent < budget and self._prefill_step():
             spent += 1
@@ -1887,14 +1380,14 @@ class LLMEngine:
             # it is dispatched, so dispatch and fetch do not come apart.
             with tracing.phase("engine.decode_dispatch", steps=k + 1,
                                slots=len(active), speculative=1):
-                self.draft_cache, proposals = draft_propose(
+                self.draft_cache, proposals = self.draft_model.draft_propose(
                     self.draft_cfg, self.draft_params, self.draft_cache,
                     jnp.asarray(token0), jnp.asarray(pos0), k,
                     jnp.asarray(write), kmesh=self.kmesh)
                 proposals = np.asarray(proposals)  # [B, k]
                 verify_tokens = np.concatenate(
                     [token0[:, None], proposals], axis=1)  # [B, k+1]
-                self.cache, logits = spec_verify_step(
+                self.cache, logits = self.model.spec_verify_step(
                     self.model_cfg, self.params, self.cache,
                     jnp.asarray(verify_tokens), jnp.asarray(pos0),
                     jnp.asarray(write), kmesh=self.kmesh)
@@ -1954,7 +1447,7 @@ class LLMEngine:
                                                   req.next_pos - start)
                 toks = np.zeros((bucket,), np.int32)
                 toks[:take] = seq[start:start + take]
-                self.draft_cache, _ = prefill_chunk(
+                self.draft_cache, _ = self.draft_model.prefill_chunk(
                     self.draft_cfg, self.draft_params, self.draft_cache,
                     jnp.asarray(toks), jnp.int32(start),
                     jnp.int32(start + take), jnp.int32(slot),

@@ -1,5 +1,5 @@
-"""What models/lfm2.py supplies to llm/engine.py: a cache with two kinds of
-leaf and the programs that run against it.
+"""What models/lfm2.py supplies to the scheduler (llm/served.ServedModel): a
+cache with two kinds of leaf and the programs that run against it.
 
 ``{"kv", "conv"}``, the slot second in both:
 
@@ -26,11 +26,13 @@ a line:
 - the state at an earlier length is nowhere, so a prompt's prefix cannot be
   adopted from another slot's line (``ServedModel.prefix_from_line``).
 
-The programs keep the engine's names (``prefill_chunk``, ``decode_step``,
-``decode_burst``: a device trace shows ``jit_<name>``) and signatures, and
-return the routed layers' counts (models/routed.MOE_COUNTERS, int32[6],
-summed over the program's layers and steps) beside their result; the
-scheduler adds them up where it fetches the tokens.
+The programs keep the contract's names (``prefill_chunk``, ``decode_step``,
+``decode_burst``: a device trace shows ``jit_<name>``; the last two are
+built from ``_decode_impl`` by llm/served.token_step_programs) and
+signatures, and return the routed layers' counts
+(models/routed.MOE_COUNTERS, int32[6], summed over the program's layers and
+steps) beside their result; the scheduler adds them up where it fetches the
+tokens.
 """
 
 from __future__ import annotations
@@ -41,7 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.llm.engine import ServedModel, sample_tokens
+from ray_tpu.llm.served import ServedModel, token_step_programs
 from ray_tpu.models import lfm2
 from ray_tpu.models.lfm2 import ATTENTION, CONV, Lfm2Config
 from ray_tpu.models.routed import MOE_COUNTERS, layer_of
@@ -76,8 +78,8 @@ def _run(cfg, params, x, cache, operators, valid, kmesh):
          donate_argnums=(2,))
 def prefill_chunk(cfg: Lfm2Config, params, cache, tokens, kv_len, length,
                   slot, *, kmesh: KernelMesh | None = None):
-    """Prefill ONE chunk of one sequence (the engine's contract, see
-    llm/engine.prefill_chunk). Returns (cache, last-token logits [V],
+    """Prefill ONE chunk of one sequence (the contract's program, see
+    llm/llama_serving.prefill_chunk). Returns (cache, last-token logits [V],
     counts)."""
     c = tokens.shape[0]
     keep = cfg.conv_L_cache - 1
@@ -169,40 +171,7 @@ def _decode_impl(cfg: Lfm2Config, params, cache, tokens, positions0,
     return cache, lfm2.lm_head(cfg, params, x[:, 0], kmesh), counts
 
 
-@partial(jax.jit, static_argnums=(0,), static_argnames=("kmesh",),
-         donate_argnums=(2,))
-def decode_step(cfg: Lfm2Config, params, cache, tokens, positions,
-                write_mask, *, kmesh: KernelMesh | None = None):
-    """One decode step for every slot. Returns (cache, logits [B, V],
-    counts)."""
-    return _decode_impl(cfg, params, cache, tokens, positions, write_mask,
-                        kmesh)
-
-
-@partial(jax.jit, static_argnums=(0, 9, 10), static_argnames=("kmesh",),
-         donate_argnums=(2,))
-def decode_burst(cfg: Lfm2Config, params, cache, token0, positions0,
-                 write_mask, temps, top_ps, key, steps: int,
-                 need_top_p: bool = True, *,
-                 kmesh: KernelMesh | None = None):
-    """``steps`` chained decode+sample steps in one dispatch. Returns
-    (cache, tokens [steps, B], counts)."""
-
-    def step(carry, j):
-        c, tok, pos, counts = carry
-        c, logits, n = _decode_impl(cfg, params, c, tok, pos, write_mask,
-                                    kmesh)
-        with tracing.part("sample"):
-            nxt = sample_tokens(logits, temps, top_ps, 0,
-                                jax.random.fold_in(key, j),
-                                need_top_p).astype(jnp.int32)
-            return (c, nxt, pos + 1, counts + n), nxt
-
-    zero = jnp.zeros((len(MOE_COUNTERS),), jnp.int32)
-    with tracing.part("stack"):
-        (cache, _, _, counts), toks = lax.scan(
-            step, (cache, token0, positions0, zero), jnp.arange(steps))
-    return cache, toks, counts
+decode_step, decode_burst = token_step_programs(_decode_impl, MOE_COUNTERS)
 
 
 def _refuse(config) -> None:

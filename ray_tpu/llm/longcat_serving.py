@@ -1,5 +1,5 @@
-"""What models/longcat.py supplies to llm/engine.py: the latent cache and
-the programs that run against it.
+"""What models/longcat.py supplies to the scheduler (llm/served.ServedModel):
+the latent cache and the programs that run against it.
 
 The cache is one array ``[2 * num_layers, slots, max_seq, latent_row]``:
 per attention, slot and position the row every head reads
@@ -10,11 +10,13 @@ never as scan xs/ys: prefill writes a chunk's rows in place and reads the
 live blocks of the slot's line; a decode step writes its one row a slot and
 attention in place and attends in the absorbed form.
 
-The programs keep the engine's names (``prefill_chunk``, ``decode_step``,
-``decode_burst``: a device trace shows ``jit_<name>``) and signatures, and
-return the routed layers' counts (models/longcat.MOE_COUNTERS, int32[6],
-summed over the program's layers and steps) beside their result; the
-scheduler adds them up where it fetches the tokens.
+The programs keep the contract's names (``prefill_chunk``, ``decode_step``,
+``decode_burst``: a device trace shows ``jit_<name>``; the last two are
+built from ``_decode_impl`` by llm/served.token_step_programs) and
+signatures, and return the routed layers' counts
+(models/longcat.MOE_COUNTERS, int32[6], summed over the program's layers
+and steps) beside their result; the scheduler adds them up where it fetches
+the tokens.
 """
 
 from __future__ import annotations
@@ -25,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.llm.engine import ServedModel, sample_tokens
+from ray_tpu.llm.served import ServedModel, token_step_programs
 from ray_tpu.models import longcat
 from ray_tpu.models.longcat import LongcatConfig
 from ray_tpu.ops.kernels import KernelMesh
@@ -67,8 +69,8 @@ def _run_layers(cfg, params, x, lat, attn, valid, kmesh):
          donate_argnums=(2,))
 def prefill_chunk(cfg: LongcatConfig, params, cache, tokens, kv_len, length,
                   slot, *, kmesh: KernelMesh | None = None):
-    """Prefill ONE chunk of one sequence (the engine's contract, see
-    llm/engine.prefill_chunk). Returns (cache, last-token logits [V],
+    """Prefill ONE chunk of one sequence (the contract's program, see
+    llm/llama_serving.prefill_chunk). Returns (cache, last-token logits [V],
     counts)."""
     c = tokens.shape[0]
     with tracing.part("embed"):
@@ -100,8 +102,8 @@ def prefill_chunk(cfg: LongcatConfig, params, cache, tokens, kv_len, length,
 
 def _multi_token_impl(cfg: LongcatConfig, params, cache, tokens, positions0,
                       write_mask, kmesh=None):
-    """K tokens per slot in one pass against the latent cache (the engine's
-    contract, see llm/engine._multi_token_impl). Returns (cache, logits
+    """K tokens per slot in one pass against the latent cache (see
+    llm/llama_serving._multi_token_impl). Returns (cache, logits
     [B, K, V], counts)."""
     b, k = tokens.shape
     with tracing.part("embed"):
@@ -134,41 +136,18 @@ def _multi_token_impl(cfg: LongcatConfig, params, cache, tokens, positions0,
     return {"latent": lat}, longcat.lm_head(cfg, params, x, kmesh), counts
 
 
-@partial(jax.jit, static_argnums=(0,), static_argnames=("kmesh",),
-         donate_argnums=(2,))
-def decode_step(cfg: LongcatConfig, params, cache, tokens, positions,
-                write_mask, *, kmesh: KernelMesh | None = None):
-    """One decode step for every slot. Returns (cache, logits [B, V],
+def _decode_impl(cfg: LongcatConfig, params, cache, tokens, positions,
+                 write_mask, kmesh=None):
+    """One decode step for every slot, the single step ``decode_step`` and
+    ``decode_burst`` are built from. Returns (cache, logits [B, V],
     counts)."""
     cache, logits, counts = _multi_token_impl(
         cfg, params, cache, tokens[:, None], positions, write_mask, kmesh)
     return cache, logits[:, 0], counts
 
 
-@partial(jax.jit, static_argnums=(0, 9, 10), static_argnames=("kmesh",),
-         donate_argnums=(2,))
-def decode_burst(cfg: LongcatConfig, params, cache, token0, positions0,
-                 write_mask, temps, top_ps, key, steps: int,
-                 need_top_p: bool = True, *,
-                 kmesh: KernelMesh | None = None):
-    """``steps`` chained decode+sample steps in one dispatch. Returns
-    (cache, tokens [steps, B], counts)."""
-
-    def step(carry, j):
-        c, tok, pos, counts = carry
-        c, logits, n = _multi_token_impl(cfg, params, c, tok[:, None], pos,
-                                         write_mask, kmesh)
-        with tracing.part("sample"):
-            nxt = sample_tokens(logits[:, 0], temps, top_ps, 0,
-                                jax.random.fold_in(key, j),
-                                need_top_p).astype(jnp.int32)
-            return (c, nxt, pos + 1, counts + n), nxt
-
-    zero = jnp.zeros((len(longcat.MOE_COUNTERS),), jnp.int32)
-    with tracing.part("stack"):
-        (cache, _, _, counts), toks = lax.scan(
-            step, (cache, token0, positions0, zero), jnp.arange(steps))
-    return cache, toks, counts
+decode_step, decode_burst = token_step_programs(_decode_impl,
+                                                longcat.MOE_COUNTERS)
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
@@ -183,13 +162,10 @@ def copy_prefix_kv(cfg: LongcatConfig, cache, src_slot, dst_slot):
 
 def _refuse(config) -> None:
     """What this model does not run, said at construction."""
-    for bad, what in (
-            (config.speculative_model is not None,
-             "a speculative draft"),
-            (config.tensor_parallel_size > 1,
-             "tensor_parallel_size > 1: its programs run on one device")):
-        if bad:
-            raise ValueError(f"LongcatConfig does not support {what}")
+    if config.tensor_parallel_size > 1:
+        raise ValueError("LongcatConfig does not support "
+                         "tensor_parallel_size > 1: its programs run on one "
+                         "device")
 
 
 SERVED = ServedModel(

@@ -1,20 +1,23 @@
-"""What models/ouro.py supplies to llm/engine.py: a cache with a line for
-every (pass, layer) and the programs that run the looped stack against it.
+"""What models/ouro.py supplies to the scheduler (llm/served.ServedModel): a
+cache with a line for every (pass, layer) and the programs that run the
+looped stack against it.
 
-The cache is the engine's slot layout, ``{"k", "v"}`` of ``[lines, slots,
-kv_heads, max_seq, head_dim]``, with ``lines = total_ut_steps *
-num_layers``: the weights are shared by the passes, the keys and values
-are not, so a cached position costs ``total_ut_steps`` times a plain
-decoder's. The programs loop over the passes around the scan over the
+The cache is the per-head K/V slot layout (llm/served.py), ``{"k", "v"}``
+of ``[lines, slots, kv_heads, max_seq, head_dim]``, with ``lines =
+total_ut_steps * num_layers``: the weights are shared by the passes, the
+keys and values are not, so a cached position costs ``total_ut_steps``
+times a plain decoder's. The programs loop over the passes around the scan over the
 layers; the stacked weights are read again by every pass and the cache
 rides both loops as carry, never as scan xs/ys: line ``cfg.cache_line(t,
-l)`` is handed to the engine's own kernels (ops/prefill_attention.py,
+l)`` is handed to the dense decoder's kernels (ops/prefill_attention.py,
 ops/decode_attention.py) as their ``layer``.
 
-The programs keep the engine's names (``prefill_chunk``, ``decode_step``,
-``decode_burst``: a device trace shows ``jit_<name>``) and signatures, and
-return models/ouro.LOOP_COUNTERS (int32[2], over valid tokens) beside their
-result; the scheduler adds them up where it fetches the tokens.
+The programs keep the contract's names (``prefill_chunk``, ``decode_step``,
+``decode_burst``: a device trace shows ``jit_<name>``; the last two are
+built from ``_decode_impl`` by llm/served.token_step_programs) and
+signatures, and return models/ouro.LOOP_COUNTERS (int32[2], over valid
+tokens) beside their result; the scheduler adds them up where it fetches
+the tokens.
 """
 
 from __future__ import annotations
@@ -25,7 +28,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.llm.engine import ServedModel, copy_prefix_kv, sample_tokens
+from ray_tpu.llm.served import (
+    ServedModel,
+    copy_prefix_kv,
+    token_step_programs,
+)
 from ray_tpu.models import ouro
 from ray_tpu.models.ouro import OuroConfig
 from ray_tpu.ops.decode_attention import (
@@ -77,8 +84,8 @@ def _run_loop(cfg, params, x, cache, positions, attend_line, valid, kmesh):
          donate_argnums=(2,))
 def prefill_chunk(cfg: OuroConfig, params, cache, tokens, kv_len, length,
                   slot, *, kmesh: KernelMesh | None = None):
-    """Prefill ONE chunk of one sequence (the engine's contract, see
-    llm/engine.prefill_chunk). Returns (cache, last-token logits [V],
+    """Prefill ONE chunk of one sequence (the contract's program, see
+    llm/llama_serving.prefill_chunk). Returns (cache, last-token logits [V],
     counts)."""
     c = tokens.shape[0]
     with tracing.part("embed"):
@@ -105,7 +112,7 @@ def prefill_chunk(cfg: OuroConfig, params, cache, tokens, kv_len, length,
 def _multi_token_impl(cfg: OuroConfig, params, cache, tokens, positions0,
                       write_mask, kmesh=None):
     """K tokens per slot in one pass of the whole loop against the cache
-    (the engine's contract, see llm/engine._multi_token_impl). Returns
+    (see llm/llama_serving._multi_token_impl). Returns
     (cache, logits [B, K, V], counts)."""
     b, k = tokens.shape
     with tracing.part("embed"):
@@ -130,41 +137,18 @@ def _multi_token_impl(cfg: OuroConfig, params, cache, tokens, positions0,
     return cache, ouro.lm_head(params, x), counts
 
 
-@partial(jax.jit, static_argnums=(0,), static_argnames=("kmesh",),
-         donate_argnums=(2,))
-def decode_step(cfg: OuroConfig, params, cache, tokens, positions,
-                write_mask, *, kmesh: KernelMesh | None = None):
-    """One decode step for every slot. Returns (cache, logits [B, V],
+def _decode_impl(cfg: OuroConfig, params, cache, tokens, positions,
+                 write_mask, kmesh=None):
+    """One decode step for every slot, the single step ``decode_step`` and
+    ``decode_burst`` are built from. Returns (cache, logits [B, V],
     counts)."""
     cache, logits, counts = _multi_token_impl(
         cfg, params, cache, tokens[:, None], positions, write_mask, kmesh)
     return cache, logits[:, 0], counts
 
 
-@partial(jax.jit, static_argnums=(0, 9, 10), static_argnames=("kmesh",),
-         donate_argnums=(2,))
-def decode_burst(cfg: OuroConfig, params, cache, token0, positions0,
-                 write_mask, temps, top_ps, key, steps: int,
-                 need_top_p: bool = True, *,
-                 kmesh: KernelMesh | None = None):
-    """``steps`` chained decode+sample steps in one dispatch. Returns
-    (cache, tokens [steps, B], counts)."""
-
-    def step(carry, j):
-        c, tok, pos, counts = carry
-        c, logits, n = _multi_token_impl(cfg, params, c, tok[:, None], pos,
-                                         write_mask, kmesh)
-        with tracing.part("sample"):
-            nxt = sample_tokens(logits[:, 0], temps, top_ps, 0,
-                                jax.random.fold_in(key, j),
-                                need_top_p).astype(jnp.int32)
-            return (c, nxt, pos + 1, counts + n), nxt
-
-    zero = jnp.zeros((len(ouro.LOOP_COUNTERS),), jnp.int32)
-    with tracing.part("stack"):
-        (cache, _, _, counts), toks = lax.scan(
-            step, (cache, token0, positions0, zero), jnp.arange(steps))
-    return cache, toks, counts
+decode_step, decode_burst = token_step_programs(_decode_impl,
+                                                ouro.LOOP_COUNTERS)
 
 
 def _refuse(config) -> None:
@@ -176,8 +160,6 @@ def _refuse(config) -> None:
              "token that leaves the loop early still owes its later passes' "
              "cache lines to the tokens after it, and the scheduler's bursts "
              "and its count of cached positions assume equal work a token"),
-            (config.speculative_model is not None,
-             "a speculative draft"),
             (config.tensor_parallel_size > 1,
              "tensor_parallel_size > 1: its programs run on one device")):
         if bad:
@@ -191,8 +173,8 @@ SERVED = ServedModel(
     prefill_chunk=prefill_chunk,
     decode_step=decode_step,
     decode_burst=decode_burst,
-    # The engine's own: every leaf's second axis is the slot, so it moves
-    # all the lines a slot has, here one a (pass, layer).
+    # The K/V slot cache's own: every leaf's second axis is the slot, so it
+    # moves all the lines a slot has, here one a (pass, layer).
     copy_prefix_kv=copy_prefix_kv,
     kv_block=lambda cfg, max_seq: decode_kv_block(
         max_seq, cfg.head_dim, cfg.jnp_dtype.itemsize),

@@ -28,7 +28,8 @@ from typing import Any
 
 from ray_tpu import serve
 from ray_tpu.llm.config import LLMConfig
-from ray_tpu.llm.engine import LLMEngine, require_kv_handoff
+from ray_tpu.llm.engine import LLMEngine
+from ray_tpu.llm.served import require_kv_handoff
 from ray_tpu.llm.serving import _sampling_from
 from ray_tpu.util import tracing
 

@@ -1,5 +1,5 @@
-"""What models/sdar.py supplies to llm/engine.py: generation by diffusion
-over blocks against the engine's slot cache.
+"""What models/sdar.py supplies to the scheduler (llm/served.ServedModel):
+generation by diffusion over blocks against the per-head K/V slot cache.
 
 The cache is the Llama one, ``{"k", "v"}`` ``[layers, slots, kv_heads,
 max_seq, head_dim]``. What differs is the step (``ServedModel.step``): one
@@ -35,7 +35,7 @@ A prompt's whole blocks are prefilled under the same block-causal mask
 (ops/prefill_attention.py, ``block``) and yield no token
 (``ServedModel.prefill_token``).
 
-The programs keep the engine's names (``prefill_chunk``, ``decode_burst``:
+The programs keep the contract's names (``prefill_chunk``, ``decode_burst``:
 a device trace shows ``jit_<name>``) and return their counts beside their
 result (:data:`COUNTERS`, int32[11], summed over layers, forwards and
 blocks); the scheduler adds them up where it fetches the tokens.
@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.llm.engine import ServedModel, init_kv_cache, sample_tokens
+from ray_tpu.llm.served import ServedModel, init_kv_cache, sample_tokens
 from ray_tpu.models import sdar
 from ray_tpu.models.lfm2 import attention_heads
 from ray_tpu.models.routed import MOE_COUNTERS
@@ -93,8 +93,8 @@ def _counts(moe, diffusion=None):
          donate_argnums=(2,))
 def prefill_chunk(cfg: SdarConfig, params, cache, tokens, kv_len, length,
                   slot, *, kmesh: KernelMesh | None = None):
-    """Prefill ONE chunk of one sequence's whole blocks (the engine's
-    contract, see llm/engine.prefill_chunk; ``kv_len`` and ``length`` are
+    """Prefill ONE chunk of one sequence's whole blocks (the contract's
+    program, see llm/llama_serving.prefill_chunk; ``kv_len`` and ``length`` are
     multiples of the block). Returns (cache, None, counts): no row's logits
     choose a token."""
     c = tokens.shape[0]

@@ -1,0 +1,288 @@
+"""The contract between the scheduler (llm/engine.py) and the models it
+serves (llm/<name>_serving.py): what a model supplies (:class:`ServedModel`),
+how a configuration finds its model (:func:`served_model`, by the table
+``llm/config.SERVING_MODULES``), and what the models share because the
+scheduler gives it one meaning for all of them: the sampler, the per-head
+K/V slot cache, and the builder of the two programs of a model that takes a
+token in and gives a token out a step (:func:`token_step_programs`).
+
+The arrows point one way: ``engine.py`` imports this module, the serving
+modules import this module, and this module imports none of them when it is
+loaded: a model's module is imported when its configuration is first asked
+for.
+"""
+
+from __future__ import annotations
+
+import importlib
+from collections.abc import Callable
+from dataclasses import dataclass
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.llm.config import SERVING_MODULES
+from ray_tpu.ops.kernels import KernelMesh
+from ray_tpu.util import tracing
+
+NEG_INF = -1e30
+
+
+@partial(jax.jit, static_argnums=(3, 5))
+@tracing.part("sample")
+def sample_tokens(logits, temps, top_ps, top_k: int, key,
+                  need_top_p: bool = True):
+    """logits [B, V] fp32; temps/top_ps [B]. Greedy where temp == 0.
+
+    ``need_top_p=False`` (static) skips the vocab-wide argsort + cumsum of
+    nucleus filtering — with top_p == 1.0 the filter keeps every token
+    anyway (cum − p < 1 holds for all p > 0), and the sort over V=128k per
+    step is BY FAR the most expensive op in the sampler (it dwarfs greedy
+    argmax and even rivals a 1B decode forward). The engine passes it
+    per-batch: only when some active request actually sets top_p < 1."""
+    greedy = jnp.argmax(logits, axis=-1)
+    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+    if top_k > 0:
+        kth = jnp.sort(scaled, axis=-1)[:, -top_k][:, None]
+        scaled = jnp.where(scaled < kth, NEG_INF, scaled)
+    if need_top_p:
+        # top-p: keep the smallest prefix of sorted probs with cumsum <= p
+        sorted_idx = jnp.argsort(-scaled, axis=-1)
+        sorted_logits = jnp.take_along_axis(scaled, sorted_idx, axis=-1)
+        probs = jax.nn.softmax(sorted_logits, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        keep_sorted = cum - probs < top_ps[:, None]  # always keep the first
+        keep = jnp.zeros_like(keep_sorted).at[
+            jnp.arange(logits.shape[0])[:, None], sorted_idx].set(keep_sorted)
+        masked = jnp.where(keep, scaled, NEG_INF)
+    else:
+        masked = scaled
+    sampled = jax.random.categorical(key, masked, axis=-1)
+    return jnp.where(temps <= 0.0, greedy, sampled)
+
+
+# ---------------------------------------------------------------------------
+# The per-head K/V slot cache: ``{"k", "v"}`` of ``[lines, slots, kv_heads,
+# positions, head_dim]``, which ops/prefill_attention.py and
+# ops/decode_attention.py read in place. Several models keep this cache
+# (a line a layer, or a line for every pass of a looped stack), so it lives
+# beside the contract that describes it and not in one model's module.
+
+
+def init_kv_cache(cfg, max_slots: int, max_seq: int):
+    """A zeroed cache of a line a layer."""
+    shape = (cfg.num_layers, max_slots, cfg.num_kv_heads, max_seq,
+             cfg.head_dim)
+    return {"k": jnp.zeros(shape, cfg.jnp_dtype),
+            "v": jnp.zeros(shape, cfg.jnp_dtype)}
+
+
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+@tracing.part("cache")
+def copy_prefix_kv(cfg, cache, src_slot, dst_slot):
+    """Copy one slot's whole KV line to another slot, all lines at once
+    (prefix-cache adoption from a LIVE donor). Copying the full max_seq
+    line is safe: positions beyond the adopted prefix are masked by
+    ``length``/``positions`` in prefill_chunk/decode_step, and the copy is
+    pure HBM bandwidth — orders of magnitude cheaper than recomputing the
+    prefix (vLLM APC makes the same recompute-vs-reuse trade)."""
+    k_line = lax.dynamic_slice_in_dim(cache["k"], src_slot, 1, 1)
+    v_line = lax.dynamic_slice_in_dim(cache["v"], src_slot, 1, 1)
+    return {
+        "k": lax.dynamic_update_slice(cache["k"], k_line,
+                                      (0, dst_slot, 0, 0, 0)),
+        "v": lax.dynamic_update_slice(cache["v"], v_line,
+                                      (0, dst_slot, 0, 0, 0)),
+    }
+
+
+@dataclass(frozen=True)
+class ServedModel:
+    """What a model supplies for the engine to serve it. The engine owns
+    the schedule (admission, chunked prefill, bursts and the look-ahead,
+    sampling, prefix adoption, the counters) and knows a model only through
+    this:
+
+    - ``init_params(cfg, key)`` and ``param_logical_axes(cfg)``;
+    - ``init_cache(cfg, slots, max_seq)``: the slot cache, a pytree whose
+      leaves the programs below take donated and give back. A leaf's
+      leading dimension is cache *lines*, of which a model may have more
+      than layers (two attentions a layer, or a line for every pass of a
+      looped stack); the slot is the second;
+    - ``prefill_chunk``, ``decode_step``, ``decode_burst``,
+      ``copy_prefix_kv``: jitted programs with the signatures of
+      llm/llama_serving.py's, under these very names so that a
+      device trace shows ``jit_prefill_chunk`` and ``jit_decode_burst``
+      whatever the model. A model with ``counters`` returns one more
+      value from the first three: an int32 array of that many counts,
+      which the scheduler adds into ``stats()`` under those names where it
+      fetches the tokens; ``constants(cfg)`` gives what ``stats()`` carries
+      beside them unchanged (a denominator of theirs);
+    - ``step(cfg)``: (positions, forwards), the size of one decode step of
+      one line: the positions it takes in and gives back decided, and the
+      forwards of the stack it costs. None is (1, 1), a token in and a
+      token out by one forward: a prompt's last chunk gives the first
+      token (``prefill_chunk`` returns that row's logits) and a step's
+      input is the token the step before sampled, handed on on the device
+      (such a model builds its ``decode_step`` and ``decode_burst`` with
+      :func:`token_step_programs`).
+      A model that decides a block of K positions by several forwards says
+      (K, forwards), and everything else that sets it apart follows from
+      this one statement (``prefill_token``; nothing else is looked at):
+      the scheduler counts a line's progress, its budget and its cache
+      line's end in steps of K positions, a burst is whole steps
+      (``decode_burst`` takes tokens ``[slots, K]`` and returns ``[steps,
+      slots, K]``; a line's last step may decide more positions than its
+      request wants, and the surplus is not emitted), ``decode_steps`` and
+      the dispatch phase's ``steps`` count forwards. Such a model samples
+      between its forwards, on the device, so it has no ``decode_step``
+      (None, and only then: a mix is refused here, where it is built): a
+      lone step is a burst of one, and a request with ``top_k`` is
+      refused. Its prefill gives no token: the prompt's whole steps are
+      prefilled (``len(prompt) - len(prompt) % K`` tokens;
+      ``prefill_chunk`` returns None for the logits), nothing is emitted
+      for them, and the tokens past them ride into the line's first step
+      in their places (-1 at every position a step has to decide); the
+      first token comes when that step is read. A step's input is then
+      known to the host before the step before it has run, and a burst is
+      queued behind another with nothing handed over;
+    - ``kv_block(cfg, max_seq)``: the positions its decode attention
+      fetches at a time, behind ``kv_positions_read``;
+    - ``kv_handoff``: whether a line can be exported and imported as
+      per-head K/V (the prefill/decode hand-off);
+    - ``prefix_from_line``: whether a prompt's first tokens can be adopted
+      from another slot's line, at any common length. False for a model
+      that also keeps a state of fixed size a slot (a short convolution's,
+      a recurrence's): the state at the adopted length is nowhere unless
+      it was saved then. The engine then adopts nothing, counts no hit,
+      publishes no prefix to the router and never calls
+      ``copy_prefix_kv``, which may be None;
+    - ``draft_propose``, ``spec_verify_step``: the two programs of
+      speculative decoding, with llm/llama_serving.py's signatures: the
+      draft's greedy proposals in one dispatch, and the target's forward
+      over them. None where a model's author has written none: the engine
+      then refuses a ``speculative_model`` with this model as target
+      (no ``spec_verify_step``) or as draft (no ``draft_propose``);
+    - ``refuse(config)``: raises ValueError for an ``LLMConfig`` it cannot
+      serve (None: it serves them all)."""
+
+    init_params: Callable
+    param_logical_axes: Callable
+    init_cache: Callable
+    prefill_chunk: Callable
+    decode_step: Callable | None
+    decode_burst: Callable
+    kv_block: Callable
+    copy_prefix_kv: Callable | None = None
+    counters: tuple[str, ...] = ()
+    constants: Callable | None = None
+    step: Callable | None = None
+    kv_handoff: bool = True
+    prefix_from_line: bool = True
+    draft_propose: Callable | None = None
+    spec_verify_step: Callable | None = None
+    refuse: Callable | None = None
+
+    def __post_init__(self):
+        if (self.step is None) == (self.decode_step is None):
+            raise ValueError(
+                "a ServedModel has a decode_step or states its step, one "
+                "of the two: a step of several positions samples on the "
+                "device and has no single-step program, and a model with "
+                "such a program takes a token in and gives a token out")
+
+    @property
+    def prefill_token(self) -> bool:
+        """Whether a prompt's last chunk gives the first token: for every
+        model but one that states its ``step``."""
+        return self.step is None
+
+
+def token_step_programs(step: Callable, counters: tuple[str, ...] = ()):
+    """(``decode_step``, ``decode_burst``): the two jitted decode programs
+    of a model whose step is a token in and a token out, built from its
+    single step
+
+        step(cfg, params, cache, tokens [B], positions [B], write_mask [B],
+             kmesh) -> (cache, logits [B, V][, counts])
+
+    under the names a device trace is read by (``jit_decode_step``,
+    ``jit_decode_burst``), with ``cfg`` and ``kmesh`` static and the cache
+    donated. ``counters`` are the model's own (``ServedModel.counters``):
+    where it has any its step returns their int32 counts as a third value,
+    and so do both programs, the burst's summed over its steps."""
+
+    @partial(jax.jit, static_argnums=(0,), static_argnames=("kmesh",),
+             donate_argnums=(2,))
+    def decode_step(cfg, params, cache, tokens, positions, write_mask=None,
+                    *, kmesh: KernelMesh | None = None):
+        """One decode step for EVERY slot.
+
+        tokens: [B] (last sampled token per slot), positions: [B] (where
+        each token is written/attends from). write_mask: [B] bool — slots
+        mid-prefill or empty must not have garbage K/V written into their
+        cache (False = keep the existing cache line; None = every slot
+        writes). Returns (cache, logits [B, V]) and the model's counts."""
+        if write_mask is None:
+            write_mask = jnp.ones(tokens.shape, bool)
+        return step(cfg, params, cache, tokens, positions, write_mask, kmesh)
+
+    @partial(jax.jit, static_argnums=(0, 9, 10), static_argnames=("kmesh",),
+             donate_argnums=(2,))
+    def decode_burst(cfg, params, cache, token0, positions0, write_mask,
+                     temps, top_ps, key, steps: int, need_top_p: bool = True,
+                     *, kmesh: KernelMesh | None = None):
+        """``steps`` chained decode+sample ticks in ONE dispatch: the sampled
+        token feeds the next step on device (lax.scan), so the host⇄device
+        roundtrip — a large part of per-token latency for small models — is
+        paid once per ``steps`` tokens instead of per token.
+        Greedy/temperature/top-p sampling only (top-k needs a static k; the
+        engine falls back to single-step ticks). Returns (cache, tokens
+        [steps, B]) and the model's counts."""
+
+        def tick(carry, j):
+            c, tok, pos, *counts = carry
+            c, logits, *n = step(cfg, params, c, tok, pos, write_mask, kmesh)
+            with tracing.part("sample"):
+                nxt = sample_tokens(logits, temps, top_ps, 0,
+                                    jax.random.fold_in(key, j),
+                                    need_top_p).astype(jnp.int32)
+                return (c, nxt, pos + 1,
+                        *(a + b for a, b in zip(counts, n))), nxt
+
+        zero = ((jnp.zeros((len(counters),), jnp.int32),) if counters
+                else ())
+        with tracing.part("stack"):
+            (cache, _, _, *counts), toks = lax.scan(
+                tick, (cache, token0, positions0, *zero), jnp.arange(steps))
+        return (cache, toks, *counts)
+
+    return decode_step, decode_burst
+
+
+def served_model(cfg) -> ServedModel:
+    """The model behind a configuration, by its type: the ``SERVED`` of
+    the module ``llm/config.SERVING_MODULES`` names for it, imported when
+    it is first asked for."""
+    for kind in type(cfg).__mro__:
+        if kind in SERVING_MODULES:
+            return importlib.import_module(SERVING_MODULES[kind]).SERVED
+    *names, last = (kind.__name__ for kind in SERVING_MODULES)
+    raise TypeError(f"the engine serves no {type(cfg).__name__}: it serves "
+                    f"{', '.join(names)} and {last}")
+
+
+def require_kv_handoff(cfg) -> None:
+    """Raise unless the model's cache lines can be handed from a prefill
+    engine to a decode engine (llm/pd.py asks before it builds one)."""
+    if not served_model(cfg).kv_handoff:
+        raise ValueError(
+            f"{type(cfg).__name__} does not support the prefill/decode "
+            "hand-off: its cache is not per-head K/V")
+
+
+def init_params(cfg, key):
+    """The served model's own initialiser."""
+    return served_model(cfg).init_params(cfg, key)

@@ -371,7 +371,7 @@ def attention_heads(cfg: Lfm2Config, ap: dict, xn, positions, inv_freq):
 def swiglu(x, w_gate, w_up, w_down):
     dt = x.dtype
     gate = jax.nn.silu((x @ w_gate).astype(jnp.float32)).astype(dt)
-    # Kept as an array of its own, as llm/engine._mlp keeps it: fused into
+    # Kept as an array of its own, as llm/llama_serving._mlp keeps it: fused into
     # the down projection XLA computes it again for every tile of the
     # output.
     act = lax.optimization_barrier(gate * (x @ w_up))

@@ -197,7 +197,7 @@ def block(cfg: OuroConfig, lp, x, positions, inv_freq, attend, state,
     with tracing.part("mlp"):
         xn = rms_norm(x, lp["mlp_norm"], eps, kmesh)
         gate = jax.nn.silu((xn @ lp["w_gate"]).astype(jnp.float32)).astype(dt)
-        # Kept as an array of its own, as llm/engine._mlp keeps it: fused
+        # Kept as an array of its own, as llm/llama_serving._mlp keeps it: fused
         # into the down projection XLA computes it again for every tile of
         # the output.
         act = lax.optimization_barrier(gate * (xn @ lp["w_up"]))

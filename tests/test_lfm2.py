@@ -21,7 +21,8 @@ import pytest
 from ray_tpu.llm import LLMConfig
 from ray_tpu.llm import lfm2_serving as serving
 from ray_tpu.llm.config import SamplingParams
-from ray_tpu.llm.engine import LLMEngine, served_model
+from ray_tpu.llm.engine import LLMEngine
+from ray_tpu.llm.served import served_model
 from ray_tpu.models import lfm2, routed
 from ray_tpu.models.lfm2 import ATTENTION, CONV, Lfm2Config, Segment
 from ray_tpu.ops.kernels import force_kernel_backend
@@ -415,7 +416,7 @@ def test_refuse_names_each_thing_refused(kw, message):
 
 
 def test_the_hand_off_is_refused_by_name():
-    from ray_tpu.llm.engine import require_kv_handoff
+    from ray_tpu.llm.served import require_kv_handoff
 
     with pytest.raises(ValueError, match="Lfm2Config does not support the "
                                          "prefill/decode hand-off"):

@@ -1,6 +1,7 @@
 """LLM engine + serving tests (reference test model: vLLM-engine stage tests
 in ray.llm tests; here the engine itself is under test)."""
 
+import dataclasses
 import threading
 
 import jax
@@ -10,7 +11,8 @@ import pytest
 
 import ray_tpu
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
-from ray_tpu.llm.engine import decode_step, init_kv_cache, prefill, sample_tokens
+from ray_tpu.llm.llama_serving import decode_step, prefill
+from ray_tpu.llm.served import init_kv_cache, sample_tokens
 from ray_tpu.models.llama import LlamaConfig, forward, init_params
 
 
@@ -171,7 +173,8 @@ CHUNK_SCHEDULES = {
 def test_chunked_prefill_matches_full(tiny, schedule, backend):
     """prefill_chunk over N chunks must equal one whole-prompt prefill
     (same cache contents, same last-token logits)."""
-    from ray_tpu.llm.engine import copy_prefix_kv, prefill_chunk
+    from ray_tpu.llm.llama_serving import prefill_chunk
+    from ray_tpu.llm.served import copy_prefix_kv
     from ray_tpu.ops.kernels import force_kernel_backend
 
     cfg, params = tiny
@@ -411,15 +414,13 @@ def test_engine_bad_kv_payload_fails_cleanly():
         eng.shutdown()
 
 
-def test_engine_recovers_from_device_failure(monkeypatch):
+def test_engine_recovers_from_device_failure():
     """decode_step donates the KV cache, so a device-side failure kills the
     cache with it. The engine must fail in-flight requests AND rebuild the
     cache so new traffic still works (engine.py _recover_device_failure)."""
-    import ray_tpu.llm.engine as eng_mod
-
     eng = LLMEngine(LLMConfig(model="tiny", max_num_seqs=2, max_seq_len=64))
-    real_decode = eng_mod.decode_step
-    real_burst = eng_mod.decode_burst
+    real_decode = eng.model.decode_step
+    real_burst = eng.model.decode_burst
     boom = {"n": 0}
 
     def flaky_decode(*a, **kw):
@@ -435,8 +436,9 @@ def test_engine_recovers_from_device_failure(monkeypatch):
         return real_burst(*a, **kw)
 
     try:
-        monkeypatch.setattr(eng_mod, "decode_step", flaky_decode)
-        monkeypatch.setattr(eng_mod, "decode_burst", flaky_burst)
+        # The scheduler reaches every program through ``self.model``.
+        eng.model = dataclasses.replace(
+            eng.model, decode_step=flaky_decode, decode_burst=flaky_burst)
         req = eng.submit([1, 2, 3], SamplingParams(max_tokens=4))
         assert req.done.wait(60)
         assert req.error and "decode failed" in req.error
@@ -583,7 +585,7 @@ class TestSpeculativeDecoding:
     def test_spec_verify_matches_sequential_decode(self, tiny):
         """spec_verify_step over K tokens produces the same logits and
         cache as K sequential decode_step calls."""
-        from ray_tpu.llm.engine import spec_verify_step
+        from ray_tpu.llm.llama_serving import spec_verify_step
 
         cfg, params = tiny
         K = 3
@@ -681,8 +683,7 @@ class TestSpeculativeDecoding:
         # layer so the real _draft_catch_up except path (fail counting,
         # disable-at-3, draft-cache rebuild) is what runs — not a stub
         # re-implementing it.
-        import ray_tpu.llm.engine as engine_mod
-        orig_prefill = engine_mod.prefill_chunk
+        orig_prefill = eng.draft_model.prefill_chunk
 
         def failing_prefill(cfg, params, cache, toks, start, end, slot,
                             **kw):
@@ -693,7 +694,8 @@ class TestSpeculativeDecoding:
                                 **kw)
 
         try:
-            engine_mod.prefill_chunk = failing_prefill
+            eng.draft_model = dataclasses.replace(
+                eng.draft_model, prefill_chunk=failing_prefill)
             # max_tokens must span >= 3 fallback ticks: each failed
             # catch-up tick now burst-decodes up to decode_burst tokens, so
             # a short request could finish before the 3rd failure disables
@@ -710,7 +712,6 @@ class TestSpeculativeDecoding:
             assert not healthy.spec_disabled
             assert eng.stats()["spec_ticks"] > 0
         finally:
-            engine_mod.prefill_chunk = orig_prefill
             eng.shutdown()
 
     def test_spec_tick_abandoned_after_plain_decode_device_failure(self):
@@ -722,10 +723,9 @@ class TestSpeculativeDecoding:
                                   max_seq_len=64,
                                   speculative_model="tiny",
                                   speculative_tokens=3))
-        import ray_tpu.llm.engine as engine_mod
-        orig_decode = engine_mod.decode_step
-        orig_burst = engine_mod.decode_burst
-        orig_propose = engine_mod.draft_propose
+        orig_decode = eng.model.decode_step
+        orig_burst = eng.model.decode_burst
+        orig_propose = eng.draft_model.draft_propose
         spec_dispatch_after_failure = []
         failed_once = []
 
@@ -754,9 +754,11 @@ class TestSpeculativeDecoding:
             return orig_propose(*a, **kw)
 
         try:
-            engine_mod.decode_step = failing_decode
-            engine_mod.decode_burst = failing_burst
-            engine_mod.draft_propose = recording_propose
+            eng.model = dataclasses.replace(
+                eng.model, decode_step=failing_decode,
+                decode_burst=failing_burst)
+            eng.draft_model = dataclasses.replace(
+                eng.draft_model, draft_propose=recording_propose)
             plain = eng.submit("plain one", sampling=SamplingParams(
                 max_tokens=32, temperature=0.0))
             plain.spec_disabled = True  # ride the plain half of the tick
@@ -768,9 +770,6 @@ class TestSpeculativeDecoding:
             assert not spec_dispatch_after_failure, (
                 "speculative half dispatched after device recovery")
         finally:
-            engine_mod.decode_step = orig_decode
-            engine_mod.decode_burst = orig_burst
-            engine_mod.draft_propose = orig_propose
             eng.shutdown()
 
     def test_spec_mixed_batch_stochastic_falls_back(self):
@@ -1132,7 +1131,7 @@ class TestDecodeKernelBody:
                                        rtol=2e-4, atol=2e-4)
 
     def test_burst_of_eight_is_eight_single_steps(self, model):
-        from ray_tpu.llm.engine import decode_burst
+        from ray_tpu.llm.llama_serving import decode_burst
 
         cfg, params = model
         prompt = np.array([5, 7, 11, 13, 17], np.int32)
@@ -1162,7 +1161,7 @@ class TestDecodeKernelBody:
         assert np.argmax(ref[len(prompt):], -1).tolist() == singles
 
     def test_verify_of_three_is_three_single_steps(self, model):
-        from ray_tpu.llm.engine import spec_verify_step
+        from ray_tpu.llm.llama_serving import spec_verify_step
 
         cfg, params = model
         prompt = np.array([5, 7, 11, 13], np.int32)

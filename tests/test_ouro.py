@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
-from ray_tpu.llm import engine, ouro_serving
+from ray_tpu.llm import ouro_serving
 from ray_tpu.models import ouro
 from ray_tpu.models.ouro import OuroConfig
 from ray_tpu.ops.kernels import force_kernel_backend
@@ -264,9 +264,3 @@ def test_a_shipped_line_has_the_caches_lines_not_the_models_layers(served):
 def test_what_the_looped_stack_does_not_serve_is_refused(kw, message):
     with pytest.raises(ValueError, match=message):
         LLMEngine(LLMConfig(max_num_seqs=2, max_seq_len=32, **kw))
-
-
-def test_an_unknown_configuration_is_told_what_is_served():
-    with pytest.raises(TypeError, match="LlamaConfig, LongcatConfig and "
-                                        "OuroConfig"):
-        engine.served_model(object())

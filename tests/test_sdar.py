@@ -23,8 +23,8 @@ import pytest
 from ray_tpu.llm import LLMConfig
 from ray_tpu.llm import sdar_serving as serving
 from ray_tpu.llm.config import SamplingParams
-from ray_tpu.llm.engine import (
-    LLMEngine,
+from ray_tpu.llm.engine import LLMEngine
+from ray_tpu.llm.served import (
     require_kv_handoff,
     sample_tokens,
     served_model,

@@ -1,22 +1,30 @@
 """What the engine holds a served model to, for every model it serves.
 
-The scheduler reaches a model only through ``engine.ServedModel``: four
-jitted programs that take the slot cache donated and give it back, under the
-names the benchmark's readers look for in a device trace
-(``jit_prefill_chunk``, ``jit_decode_burst``). The engine has one KV layout,
-slot lines; ``kv_block_size`` is a field that accepts 0.
+The scheduler (llm/engine.py) reaches a model only through
+``served.ServedModel``: four jitted programs that take the slot cache
+donated and give it back, under the names the benchmark's readers look for
+in a device trace (``jit_prefill_chunk``, ``jit_decode_step``,
+``jit_decode_burst``), found for a configuration by one table
+(``config.SERVING_MODULES``). The engine has one KV layout, slot lines;
+``kv_block_size`` is a field that accepts 0.
 """
 
+import ast
 import dataclasses
+import importlib
+import pathlib
 import re
+import typing
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
-from ray_tpu.llm import engine, lfm2_serving, longcat_serving, ouro_serving
-from ray_tpu.llm import sdar_serving
+from ray_tpu.llm import lfm2_serving, llama_serving, longcat_serving
+from ray_tpu.llm import ouro_serving, sdar_serving
+from ray_tpu.llm.config import SERVING_MODULES, ModelConfig
+from ray_tpu.llm.served import ServedModel, served_model
 from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
@@ -27,7 +35,8 @@ SLOTS, MAX_SEQ, CHUNK = 3, 64, 16
 
 
 def _llama():
-    return engine, dataclasses.replace(LlamaConfig.tiny(), vocab_size=512)
+    return llama_serving, dataclasses.replace(LlamaConfig.tiny(),
+                                              vocab_size=512)
 
 
 def _longcat():
@@ -43,22 +52,27 @@ def _lfm2():
     return lfm2_serving, Lfm2Config.tiny(max_seq_len=MAX_SEQ)
 
 
+def _sdar():
+    return sdar_serving, SdarConfig.tiny(max_seq_len=MAX_SEQ)
+
+
+# The models of a token a step, and all of them.
 MODELS = dict(argvalues=[_llama, _longcat, _ouro, _lfm2],
               ids=["llama", "longcat", "ouro", "lfm2"])
-
-# The Llama single step is jit(_decode_step_impl), so the benchmark's
-# readers of ``jit_decode_step`` would miss it; only a request with top_k
-# takes a single step, and no cell sends one. The name is part of the
-# program's text, so it changes with the move of ROADMAP D1, not before.
-_NOT_YET_NAMED = {(engine, "decode_step"): "jit__decode_step_impl"}
+ALL_MODELS = dict(argvalues=MODELS["argvalues"] + [_sdar],
+                  ids=MODELS["ids"] + ["sdar"])
 
 
-def _arguments(program, params):
+def _arguments(program, params, step_positions=1):
     """The arguments of ``program`` after (cfg, [params,] cache): slot 0
-    holds CHUNK rows, slots 0 and 1 decode."""
+    holds CHUNK rows, slots 0 and 1 decode. A burst's tokens are [slots],
+    or [slots, K] all open (-1) where a step is a block of K positions."""
     i32 = jnp.int32
     slots = jnp.zeros((SLOTS,), i32)
-    positions = jnp.array([CHUNK, 1, 0], i32)
+    if step_positions > 1:
+        slots = jnp.full((SLOTS, step_positions), -1, i32)
+    # (a block's start is a multiple of its length)
+    positions = jnp.array([CHUNK, 1 if step_positions == 1 else 0, 0], i32)
     write = jnp.array([True, True, False])
     return {
         "prefill_chunk": (params, jnp.arange(CHUNK, dtype=i32), i32(0),
@@ -74,30 +88,35 @@ def _arguments(program, params):
 
 @pytest.mark.parametrize("program", ["prefill_chunk", "decode_step",
                                      "decode_burst", "copy_prefix_kv"])
-@pytest.mark.parametrize("model", **MODELS)
+@pytest.mark.parametrize("model", **ALL_MODELS)
 def test_a_program_keeps_its_name_and_gives_the_donated_cache_back(model,
                                                                     program):
     module, cfg = model()
-    served = engine.served_model(cfg)
+    served = served_model(cfg)
     if getattr(served, program) is None:
         # Only a model whose prefix cannot be adopted from a line may lack
-        # the program that copies one.
-        assert program == "copy_prefix_kv" and not served.prefix_from_line
+        # the program that copies one, and only one whose step samples on
+        # the device the single step.
+        assert (program == "copy_prefix_kv" and not served.prefix_from_line
+                or program == "decode_step" and served.step is not None)
         return
     params = served.init_params(cfg, jax.random.PRNGKey(0))
     cache = served.init_cache(cfg, SLOTS, MAX_SEQ)
     went_in = jax.tree.map(lambda a: (a.shape, a.dtype), cache)
     head = (cfg, cache)
-    rest = _arguments(program, params)
+    rest = _arguments(program, params,
+                      served.step(cfg)[0] if served.step else 1)
     if program != "copy_prefix_kv":
         head, rest = (cfg, rest[0], cache), rest[1:]
 
-    # The module's own jitted function carries the name a trace shows.
-    lowered = getattr(module, program).lower(*head, *rest)
+    # The entry the scheduler calls is the jitted program itself, and it
+    # carries the name a trace shows: Llama's single step too.
+    assert getattr(served, program) is getattr(module, program)
+    lowered = getattr(served, program).lower(*head, *rest)
     named = re.search(r"module @(\w+)", lowered.as_text()).group(1)
-    assert named == _NOT_YET_NAMED.get((module, program), f"jit_{program}")
+    assert named == f"jit_{program}"
 
-    # Through the entry the scheduler calls: the cache is the first result.
+    # The cache is the first result.
     out = getattr(served, program)(*head, *rest)
     came_back = out
     if program != "copy_prefix_kv":
@@ -133,7 +152,7 @@ def test_a_decode_step_plans_its_walk_once_before_the_layer_loop(model):
     from ray_tpu.ops.kernels import force_kernel_backend
 
     module, cfg = model()
-    served = engine.served_model(cfg)
+    served = served_model(cfg)
     params = jax.eval_shape(lambda: served.init_params(
         cfg, jax.random.PRNGKey(0)))
     cache = jax.eval_shape(lambda: served.init_cache(cfg, SLOTS, MAX_SEQ))
@@ -219,7 +238,7 @@ def test_the_look_ahead_schedule_gives_the_serial_schedules_tokens(model):
     # every token but a request's first, which prefill gives
     assert ahead["decode_tokens"] == serial["decode_tokens"] == \
         sum(len(toks) - 1 for toks, _ in outs[0])
-    served = engine.served_model(cfg)
+    served = served_model(cfg)
     rule = getattr(cfg, "router_rule", None)
     for name in served.counters:   # counted for valid tokens only
         if name == "moe_picks_zero" and not rule.zero_experts:
@@ -230,10 +249,6 @@ def test_the_look_ahead_schedule_gives_the_serial_schedules_tokens(model):
 
 # ---- a step that is not a token --------------------------------------------
 
-def _sdar():
-    return sdar_serving, SdarConfig.tiny(max_seq_len=MAX_SEQ)
-
-
 @pytest.mark.parametrize("model", **MODELS)
 def test_a_token_a_step_is_what_a_model_is_served_by_unless_it_says(model):
     """The models the engine served before one said otherwise: no ``step``
@@ -242,7 +257,7 @@ def test_a_token_a_step_is_what_a_model_is_served_by_unless_it_says(model):
     positions is theirs at K = 1, and their programs take the same
     arguments as ever: int32[slots] tokens."""
     _, cfg = model()
-    served = engine.served_model(cfg)
+    served = served_model(cfg)
     assert served.step is None and served.prefill_token
     assert served.decode_step is not None
     eng = LLMEngine(LLMConfig(model=cfg, max_num_seqs=SLOTS,
@@ -269,9 +284,9 @@ def test_a_step_and_a_single_step_program_exclude_each_other(mix):
     from dataclasses import replace as dc_replace
 
     with pytest.raises(ValueError, match="one of the two"):
-        dc_replace(engine._LLAMA, **mix)
+        dc_replace(llama_serving.SERVED, **mix)
     with pytest.raises(TypeError):
-        dc_replace(engine._LLAMA, prefill_token=False)
+        dc_replace(llama_serving.SERVED, prefill_token=False)
 
 
 def test_a_model_may_say_its_step_is_a_block_and_its_prefill_gives_no_token():
@@ -279,7 +294,7 @@ def test_a_model_may_say_its_step_is_a_block_and_its_prefill_gives_no_token():
     trace is read by and give the donated cache back; the prefill's logits
     are None; a burst takes int32[slots, 4] and gives [steps, slots, 4]."""
     module, cfg = _sdar()
-    served = engine.served_model(cfg)
+    served = served_model(cfg)
     assert served.step(cfg) == (4, 5) and not served.prefill_token
     assert served.decode_step is None and served.copy_prefix_kv is None
     params = served.init_params(cfg, jax.random.PRNGKey(0))
@@ -341,3 +356,97 @@ def test_the_look_ahead_serves_blocks_as_the_serial_schedule_does():
         assert s["first_tokens"] == 5
         # whole blocks of the prompts: 4 + 40 + 20 + 8 + 28
         assert s["prompt_tokens_prefilled"] == 100
+
+
+# ---- the seam: who imports whom, and how a configuration finds its model ---
+
+LLM = pathlib.Path(importlib.import_module("ray_tpu.llm").__file__).parent
+
+
+def _imports(path, top_level_only=False):
+    """The dotted names a file imports (``from a.b import c`` gives ``a.b``
+    and ``a.b.c``), anywhere in it or at module level only."""
+    tree = ast.parse(path.read_text())
+    names = set()
+    for node in (tree.body if top_level_only else ast.walk(tree)):
+        if isinstance(node, ast.Import):
+            names |= {a.name for a in node.names}
+        elif isinstance(node, ast.ImportFrom) and node.module:
+            names |= {node.module} | {f"{node.module}.{a.name}"
+                                      for a in node.names}
+    return names
+
+
+def test_the_scheduler_names_no_model_and_the_contract_imports_neither():
+    """The arrows point one way. ``engine.py`` imports no model and no
+    serving module but Llama's, for the three names another file holds
+    there; ``served.py`` imports neither the scheduler nor a serving module
+    when it is loaded; a serving module imports the contract, never the
+    scheduler and never another model's serving module."""
+    engine = _imports(LLM / "engine.py")
+    assert not {n for n in engine if n.startswith("ray_tpu.models")}
+    assert {n for n in engine if n.endswith("_serving")} \
+        == {"ray_tpu.llm.llama_serving"}
+    assert {n for n in engine if n.startswith("ray_tpu.llm.llama_serving.")} \
+        == {"ray_tpu.llm.llama_serving." + name for name in
+            ("init_kv_cache", "prefill_chunk", "decode_step")}
+    # no configuration's type is named, so none is asked after
+    text = (LLM / "engine.py").read_text()
+    assert not [kind.__name__ for kind in SERVING_MODULES
+                if kind.__name__ in text]
+
+    contract = _imports(LLM / "served.py", top_level_only=True)
+    assert not {n for n in contract
+                if "engine" in n or n.endswith("_serving")}
+
+    modules = sorted(LLM.glob("*_serving.py"))
+    assert {f"ray_tpu.llm.{p.stem}" for p in modules} \
+        == set(SERVING_MODULES.values())
+    for path in modules:
+        names = _imports(path)
+        assert "ray_tpu.llm.served" in names, path.name
+        assert not {n for n in names
+                    if "llm.engine" in n or "_serving" in n}, path.name
+
+
+@pytest.mark.parametrize("kind", list(SERVING_MODULES),
+                         ids=lambda kind: kind.__name__)
+def test_the_table_finds_every_configuration_its_served_model(kind):
+    """One line of ``config.SERVING_MODULES`` is what the scheduler's side
+    needs of a new model: the configuration's type to the module whose
+    ``SERVED`` is its ``ServedModel``; ``ModelConfig`` is those types."""
+    module = importlib.import_module(SERVING_MODULES[kind])
+    assert isinstance(module.SERVED, ServedModel)
+    assert served_model(kind.tiny()) is module.SERVED
+    assert set(typing.get_args(ModelConfig)) == set(SERVING_MODULES)
+    # a configuration of a subclass is its parent's model
+    assert served_model(type("Sub", (kind,), {}).tiny()) is module.SERVED
+
+
+def test_an_unknown_configuration_is_told_what_is_served():
+    with pytest.raises(TypeError) as refused:
+        served_model(object())
+    assert all(kind.__name__ in str(refused.value)
+               for kind in SERVING_MODULES)
+    assert "LlamaConfig, LongcatConfig" in str(refused.value)
+
+
+@pytest.mark.parametrize("model", **ALL_MODELS)
+def test_a_draft_is_refused_where_the_two_speculative_programs_are_none(
+        model):
+    """``draft_propose`` and ``spec_verify_step`` are what a model's author
+    supplies, and only Llama's has: the engine refuses a
+    ``speculative_model`` for any other target in the same words, and a
+    model's own reason comes first where it has one."""
+    module, cfg = model()
+    served = served_model(cfg)
+    config = LLMConfig(model=cfg, max_num_seqs=2, max_seq_len=MAX_SEQ,
+                       speculative_model="tiny")
+    if module is llama_serving:
+        assert served.draft_propose is module.draft_propose
+        assert served.spec_verify_step is module.spec_verify_step
+        return
+    assert served.draft_propose is None and served.spec_verify_step is None
+    with pytest.raises(ValueError, match=f"{type(cfg).__name__} does not "
+                                         "support a speculative draft"):
+        LLMEngine(config)

@@ -201,7 +201,7 @@ def test_vit_step_partitions_over_four_chips(mosaic):
 
 
 def test_engine_programs_partition_over_tensor_parallel_chips(mosaic):
-    from ray_tpu.llm import engine
+    from ray_tpu.llm import llama_serving
 
     slots, max_seq = 4, 256
     mesh = build_mesh(MeshSpec(tp=4), mosaic)
@@ -214,16 +214,16 @@ def test_engine_programs_partition_over_tensor_parallel_chips(mosaic):
         lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype,
             sharding=NamedSharding(mesh, P(None, None, "tp"))),
-        jax.eval_shape(partial(engine.init_kv_cache, CFG, slots, max_seq)))
+        jax.eval_shape(partial(llama_serving.init_kv_cache, CFG, slots, max_seq)))
 
     def arg(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
 
     # 40 tokens: a chunk clamped to the cache tail, not a power of two.
-    prefill = engine.prefill_chunk.lower(
+    prefill = llama_serving.prefill_chunk.lower(
         CFG, params, cache, arg((40,)), arg(()), arg(()), arg(()),
         kmesh=kmesh).compile().as_text()
-    decode = engine.decode_burst.lower(
+    decode = llama_serving.decode_burst.lower(
         CFG, params, cache, arg((slots,)), arg((slots,)),
         arg((slots,), jnp.bool_), arg((slots,), jnp.float32),
         arg((slots,), jnp.float32), arg((2,), jnp.uint32), 4, False,
@@ -251,7 +251,7 @@ MISTRAL = LlamaConfig(vocab_size=32768, hidden_size=4096,
 def _mistral_state(mesh, slots, max_seq):
     """Shapes of MISTRAL's weights and of a cache of ``slots`` lines on
     ``mesh`` (KV heads over tp), and a maker of replicated arguments."""
-    from ray_tpu.llm import engine
+    from ray_tpu.llm import llama_serving
 
     repl = NamedSharding(mesh, P())
     params = _sds(
@@ -261,7 +261,7 @@ def _mistral_state(mesh, slots, max_seq):
         lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype,
             sharding=NamedSharding(mesh, P(None, None, "tp"))),
-        jax.eval_shape(partial(engine.init_kv_cache, MISTRAL, slots,
+        jax.eval_shape(partial(llama_serving.init_kv_cache, MISTRAL, slots,
                                max_seq)))
 
     def arg(shape, dtype=jnp.int32):
@@ -271,10 +271,10 @@ def _mistral_state(mesh, slots, max_seq):
 
 
 def _decode_burst_compiled(mesh, kmesh, slots, max_seq, steps=8):
-    from ray_tpu.llm import engine
+    from ray_tpu.llm import llama_serving
 
     params, cache, arg = _mistral_state(mesh, slots, max_seq)
-    return engine.decode_burst.lower(
+    return llama_serving.decode_burst.lower(
         MISTRAL, params, cache, arg((slots,)), arg((slots,)),
         arg((slots,), jnp.bool_), arg((slots,), jnp.float32),
         arg((slots,), jnp.float32), arg((2,), jnp.uint32), steps, False,
@@ -341,12 +341,12 @@ def test_prefill_chunk_moves_no_whole_cache_at_mistral_widths(mosaic, slots,
     """The three serving shapes of the benchmark, a chunk of 512: the cache
     rides the layer loop as carry, the chunk's rows go in by an in-place
     dynamic-update-slice, attention reads the stack through the kernel."""
-    from ray_tpu.llm import engine
+    from ray_tpu.llm import llama_serving
 
     chunk = 512
     params, cache, arg = _mistral_state(
         build_mesh(MeshSpec(), mosaic[:1]), slots, max_seq)
-    compiled = engine.prefill_chunk.lower(
+    compiled = llama_serving.prefill_chunk.lower(
         MISTRAL, params, cache, arg((chunk,)), arg(()), arg(()),
         arg(())).compile()
     text = compiled.as_text()

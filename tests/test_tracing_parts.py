@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.llm import engine
+from ray_tpu.llm.served import served_model
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
 from ray_tpu.models.mixtral import MixtralConfig
@@ -110,7 +110,7 @@ LONGCAT_GONE = ("longcat.mla", "longcat.moe", "longcat.moe.experts")
 def test_a_serving_program_opens_its_parts(model, program):
     make, must = SERVED[model]
     module, cfg = make()
-    served = engine.served_model(cfg)
+    served = served_model(cfg)
     params = jax.eval_shape(
         lambda: served.init_params(cfg, jax.random.PRNGKey(0)))
     cache = jax.eval_shape(lambda: served.init_cache(cfg, SLOTS, MAX_SEQ))
