@@ -12,6 +12,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Union
 
+from ray_tpu.models.deepseek import DeepseekV2Config
 from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
@@ -29,6 +30,7 @@ SERVING_MODULES = {
     OuroConfig: "ray_tpu.llm.ouro_serving",
     Lfm2Config: "ray_tpu.llm.lfm2_serving",
     SdarConfig: "ray_tpu.llm.sdar_serving",
+    DeepseekV2Config: "ray_tpu.llm.deepseek_serving",
 }
 ModelConfig = Union[tuple(SERVING_MODULES)]
 

@@ -158,6 +158,12 @@ class LongcatConfig:
     def sm_scale(self) -> float:
         return 1.0 / math.sqrt(self.qk_head_dim)
 
+    @property
+    def rope_scaling(self) -> None:
+        """The published configuration has none (models/deepseek.py, which
+        shares the latent projections below, has YaRN's)."""
+        return None
+
     def num_params(self) -> int:
         """Parameters held here (this shard's experts)."""
         h, nh = self.hidden_size, self.num_heads
@@ -291,23 +297,33 @@ def mla_scales(cfg: LongcatConfig) -> tuple[float, float]:
     return sq, skv
 
 
-def mla_project(cfg: LongcatConfig, ap: dict, xn, positions, kmesh=None):
-    """xn: [B, S, H] (normed); positions [S] or [B, S]. Returns the heads'
+def mla_project(cfg, ap: dict, xn, positions, kmesh=None,
+                keep_product: bool = False):
+    """``cfg`` a LongcatConfig, or another model's with the same latent
+    attention (models/deepseek.py). xn: [B, S, H] (normed); positions [S]
+    or [B, S]. Returns the heads'
     queries q_n [B, S, nh, Dn] and q_r [B, S, nh, Dr] (rotated), and the
     rows to cache [B, S, latent_row]: ``c_kv`` after norm and scale, the
-    rotated shared key, zeros up to the row's width."""
+    rotated shared key, zeros up to the row's width. ``keep_product`` keeps
+    the queries' product an array of its own before it is split into heads
+    (models/ouro.block's finding: XLA otherwise folds the split into the
+    product and copies the whole stacked ``wq_b`` transposed at the top of
+    a decode program, 0.6 GB at 128 heads)."""
     b, s, _ = xn.shape
     sq, skv = mla_scales(cfg)
     dt = xn.dtype
     cq = rms_norm(xn @ ap["wq_a"], ap["q_a_norm"], cfg.norm_eps, kmesh)
-    q = ((cq * sq).astype(dt) @ ap["wq_b"]).reshape(
-        b, s, cfg.num_heads, cfg.qk_head_dim)
+    q = (cq * sq).astype(dt) @ ap["wq_b"]
+    if keep_product:
+        q = lax.optimization_barrier(q)
+    q = q.reshape(b, s, cfg.num_heads, cfg.qk_head_dim)
     q_n, q_r = q[..., :cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]
     kv = xn @ ap["wkv_a"]
     ckv = rms_norm(kv[..., :cfg.kv_lora_rank], ap["kv_a_norm"], cfg.norm_eps,
                    kmesh)
     ckv = (ckv * skv).astype(dt)
-    inv_freq = rope_frequencies(cfg.qk_rope_head_dim, cfg.rope_theta)
+    inv_freq = rope_frequencies(cfg.qk_rope_head_dim, cfg.rope_theta,
+                                cfg.rope_scaling)
     q_r = apply_rope_interleaved(q_r.transpose(0, 2, 1, 3), positions,
                                  inv_freq).transpose(0, 2, 1, 3)
     k_r = apply_rope_interleaved(kv[:, None, :, cfg.kv_lora_rank:],
@@ -325,12 +341,15 @@ def kv_up_projections(cfg: LongcatConfig, wkv_b):
     return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
 
 
-def mla_full(cfg: LongcatConfig, ap: dict, xn, kmesh=None):
+def mla_full(cfg, ap: dict, xn, kmesh=None,
+             up_projections=kv_up_projections):
     """Causal latent attention over whole sequences, keys and values
-    up-projected (no cache). xn: [B, S, H] -> [B, S, H]."""
+    up-projected (no cache). xn: [B, S, H] -> [B, S, H].
+    ``up_projections(cfg, wkv_b)`` gives the two halves [rank, nh, D] of a
+    model's ``wkv_b`` (models/deepseek.py stores its a head at a time)."""
     b, s, _ = xn.shape
     q_n, q_r, rows = mla_project(cfg, ap, xn, jnp.arange(s), kmesh)
-    w_kb, w_vb = kv_up_projections(cfg, ap["wkv_b"])
+    w_kb, w_vb = up_projections(cfg, ap["wkv_b"])
     ckv = rows[..., :cfg.kv_lora_rank]
     k_r = rows[..., cfg.kv_lora_rank:cfg.latent_dim]
     k_n = jnp.einsum("bsr,rhd->bshd", ckv, w_kb)

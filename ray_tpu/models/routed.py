@@ -5,7 +5,11 @@ What differs between the families is the router's rule, and a
 :class:`RouterRule` states it: how a router output becomes a score (softmax
 over all outputs, or a sigmoid of each), whether a selection bias is added
 for the choice (never for the weights), whether the chosen weights are
-renormalised to sum to one, the factor they are scaled by, how many of the
+renormalised to sum to one, the factor they are scaled by, whether the
+choice is limited to the best groups of experts (``groups`` consecutive
+groups of equal size, of which the ``topk_groups`` with the largest single
+score are kept: a token's experts then lie on at most that many devices of
+a deployment that holds a group a device), how many of the
 router's outputs are zero-compute experts (the identity: a weighted add of
 the layer's input), and which of the routed experts this program holds
 (``expert_shard`` of ``expert_shards`` equal shares).
@@ -56,6 +60,8 @@ class RouterRule:
     zero_experts: int = 0         # router outputs after the routed experts
     expert_shard: int = 0
     expert_shards: int = 1
+    groups: int = 1               # consecutive groups of routed experts
+    topk_groups: int = 1          # groups a token may choose among
 
     def __post_init__(self):
         if self.score not in ("softmax", "sigmoid"):
@@ -68,6 +74,21 @@ class RouterRule:
         if not 0 <= self.expert_shard < self.expert_shards:
             raise ValueError(f"expert_shard {self.expert_shard} outside "
                              f"0..{self.expert_shards - 1}")
+        if self.groups > 1:
+            if self.experts % self.groups or self.zero_experts:
+                raise ValueError(
+                    f"{self.experts} routed experts (+ {self.zero_experts} "
+                    f"zero experts) do not divide into {self.groups} groups")
+            if self.use_bias:
+                raise ValueError(
+                    "a grouped rule scores a group by its best expert's "
+                    "score and masks the others' to 0: it has no form for "
+                    "a selection bias, which may be negative")
+            if not (1 <= self.topk_groups <= self.groups and self.topk
+                    <= self.topk_groups * (self.experts // self.groups)):
+                raise ValueError(
+                    f"{self.topk_groups} of {self.groups} groups do not "
+                    f"hold {self.topk} experts a token")
 
     @property
     def held(self) -> int:
@@ -98,15 +119,27 @@ def layer_of(stack, index):
 def route(rule: RouterRule, router, bias, u):
     """u: [T, H] -> (idx [T, topk] over all router outputs, w [T, topk]
     float32). Scores in float32 (true float32: a TPU's default float32
-    matmul is one bfloat16 pass), the choice by score (+ bias), the weights
-    by score alone, renormalised or not, then scaled."""
+    matmul is one bfloat16 pass), the choice by score (+ bias), among the
+    best groups where the rule has groups, the weights by score alone,
+    renormalised or not, then scaled."""
     logits = jnp.dot(u.astype(jnp.float32), router.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
     if rule.score == "softmax":
         p = jax.nn.softmax(logits, axis=-1)
     else:
         p = jax.nn.sigmoid(logits)
-    _, idx = lax.top_k(p + bias if rule.use_bias else p, rule.topk)
+    choice = p + bias if rule.use_bias else p
+    if rule.groups > 1:
+        # A group's score is its best expert's; outside the kept groups a
+        # score is 0, under every score a softmax or a sigmoid gives.
+        best = choice.reshape(-1, rule.groups,
+                              rule.experts // rule.groups).max(axis=-1)
+        _, kept = lax.top_k(best, rule.topk_groups)
+        keep = jnp.any(kept[:, :, None] == jnp.arange(rule.groups), axis=1)
+        choice = jnp.where(
+            jnp.repeat(keep, rule.experts // rule.groups, axis=1), choice,
+            0.0)
+    _, idx = lax.top_k(choice, rule.topk)
     w = jnp.take_along_axis(p, idx, axis=-1)
     if rule.renormalize:
         w = w / (w.sum(axis=-1, keepdims=True) + rule.renorm_eps)
@@ -170,13 +203,20 @@ def tile_rows(u, pick_of_row, topk: int):
 
 
 def moe_block(rule: RouterRule, layers: dict, layer, u, valid):
+    """:func:`moe_block_picks` without the picks."""
+    return moe_block_picks(rule, layers, layer, u, valid)[:2]
+
+
+def moe_block_picks(rule: RouterRule, layers: dict, layer, u, valid):
     """The routed layer on u [T, H]: this shard's experts' part and the
     zero experts' part of ``sum_e w_e E_e(u)``. ``layers`` holds the stacked
     ``router``, ``router_bias`` (a rule with ``use_bias``), ``we_gate``,
     ``we_up`` and ``we_down`` (the expert stacks are read in place), ``layer`` is the routed layer's index
     on their leading axis. A token with ``valid`` false
     (padding, an idle slot) is routed nowhere and counted nowhere. Returns
-    (y [T, H], counts int32[6] in the order of MOE_COUNTERS)."""
+    (y [T, H], counts int32[6] in the order of MOE_COUNTERS, local
+    [T, topk] bool: the picks that fell on a held expert, for a model that
+    counts something of its own from them)."""
     t, _ = u.shape
     held, topk = rule.held, rule.topk
     tm = row_tile(t, topk, rule.outputs)
@@ -210,4 +250,4 @@ def moe_block(rule: RouterRule, layers: dict, layer, u, valid):
         counts = jnp.stack([
             valid.sum() * topk, local.sum(), zero.sum(), (sizes > 0).sum(),
             jnp.ones((), jnp.int32), n_live]).astype(jnp.int32)
-        return y.astype(u.dtype), counts
+        return y.astype(u.dtype), counts, local

@@ -703,6 +703,11 @@ SUBPARTS = (
                      # operator's place): projection in, gates, the taps,
                      # projection out
     "conv_state",    # reading and writing that convolution's state
+    "moe_shared",    # the shared experts' SwiGLU, which every token passes
+                     # beside the routed experts, inside ``mlp``
+    "latent_prefill",  # a prefill chunk's latent attention inside ``attn``:
+                     # the up-projection of the line's live blocks and the
+                     # running softmax over them
 )
 
 

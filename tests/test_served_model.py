@@ -21,10 +21,12 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
-from ray_tpu.llm import lfm2_serving, llama_serving, longcat_serving
+from ray_tpu.llm import deepseek_serving, lfm2_serving, llama_serving
+from ray_tpu.llm import longcat_serving
 from ray_tpu.llm import ouro_serving, sdar_serving
 from ray_tpu.llm.config import SERVING_MODULES, ModelConfig
 from ray_tpu.llm.served import ServedModel, served_model
+from ray_tpu.models.deepseek import DeepseekV2Config
 from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
@@ -56,9 +58,14 @@ def _sdar():
     return sdar_serving, SdarConfig.tiny(max_seq_len=MAX_SEQ)
 
 
+def _deepseek():
+    return deepseek_serving, DeepseekV2Config.tiny(expert_shards=2,
+                                                   max_seq_len=MAX_SEQ)
+
+
 # The models of a token a step, and all of them.
-MODELS = dict(argvalues=[_llama, _longcat, _ouro, _lfm2],
-              ids=["llama", "longcat", "ouro", "lfm2"])
+MODELS = dict(argvalues=[_llama, _longcat, _ouro, _lfm2, _deepseek],
+              ids=["llama", "longcat", "ouro", "lfm2", "deepseek"])
 ALL_MODELS = dict(argvalues=MODELS["argvalues"] + [_sdar],
                   ids=MODELS["ids"] + ["sdar"])
 

@@ -706,3 +706,67 @@ def test_sdar_programs_move_no_cache_nor_expert_stack_and_fit_the_chip(
     for shape in ("experts_up", "experts_down", "wq", "embed", "head"):
         assert _opcodes_with_shape(text, big[shape]) <= \
             carried | {"fusion", "custom-call", "dynamic-slice"}, shape
+
+
+# DeepSeek-V2's dense layer and seven routed ones at the published widths,
+# one group of 20 experts, at the shapes of its serving cell (16 slots x
+# 16,384): the programs of llm/deepseek_serving.py as the cell compiles them.
+@pytest.mark.parametrize("program", ["prefill_chunk(512)", "decode_burst(8)"])
+def test_deepseek_programs_copy_no_cache_nor_stacked_leaf_and_fit_the_chip(
+        mosaic, program):
+    """The latent cache rides both layer loops as carry, and every stacked
+    leaf is indexed where it is used. What the first compile of PR 45 got
+    wrong, kept from coming back: at 128 heads XLA folded the split of the
+    queries into heads into their product and copied the whole stacked
+    ``wq_b`` (bf16[8,1536,24576], 0.56 GiB) transposed at the top of every
+    decode program, and the stacked ``wkv_b`` (0.25 GiB) head-major beside
+    it; the product is kept an array (``mla_project(keep_product=True)``)
+    and ``wkv_b`` is stored a head at a time. Arguments and temporaries fit
+    the chip's 15.75 GiB with room for the float32 reference's check."""
+    from devbench import deepseek_bench as bench
+
+    cfg = bench.config()
+    assert (cfg.num_layers, cfg.num_dense_layers, cfg.experts_held,
+            cfg.num_heads, cfg.latent_row) == (8, 1, 20, 128, 640)
+    dev = NamedSharding(build_mesh(MeshSpec(), mosaic[:1]), P())
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=dev), tree)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    params, cache = bench.shapes(cfg, placed)
+    lat = cache["latent"]
+    assert lat.shape == (8, bench.SLOTS, bench.MAX_SEQ, 640)
+    assert lat.size * lat.dtype.itemsize == 2.5 * 2 ** 30
+    compiled = bench.lowerings(cfg, params, cache, arg)[program]().compile()
+    text = compiled.as_text()
+    carried = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30
+    if program.startswith("prefill"):
+        kernels = ("moe_grouped_matmul",)
+        in_place = {"dynamic-update-slice"}
+        # 512 x 6 picks over 160 outputs are 19 rows an expert: tiles of 64
+        assert _grouped_matmul_rows(text) == {(3072 // 64 + 20) * 64}
+        assert mem.temp_size_in_bytes < 1 << 28
+    else:
+        kernels = ("latent_decode_attention", "latent_row_write",
+                   "moe_grouped_matmul")
+        in_place = {"custom-call"}
+        # 16 x 6 picks: 0.6 rows an expert, tiles of 16
+        assert _grouped_matmul_rows(text) == {(96 // 16 + 20) * 16}
+        assert mem.temp_size_in_bytes < 1 << 27
+    assert 12.0 < total < 12.4
+    for name in kernels:
+        assert f'"{name}"' in text or f"%{name}." in text, name
+    big = bench.big_shapes(cfg)
+    assert _opcodes_with_shape(text, big["cache"]) <= carried | in_place
+    for shape in ("cache_layer", "cache_slot"):
+        assert _opcodes_with_shape(text, big[shape]) == set(), shape
+    for shape in ("we_in", "we_down", "ws_in", "ws_down", "wq_b", "wkv_b",
+                  "wo"):
+        assert _opcodes_with_shape(text, big[shape]) <= \
+            carried | {"custom-call"}, shape

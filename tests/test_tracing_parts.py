@@ -24,8 +24,8 @@ from ray_tpu.models.ouro import OuroConfig
 from ray_tpu.util import tracing
 # The tiny served models and the programs' arguments, as the engine's own
 # contract test builds them.
-from test_served_model import (MAX_SEQ, SLOTS, _arguments, _lfm2, _llama,
-                               _longcat, _ouro)
+from test_served_model import (MAX_SEQ, SLOTS, _arguments, _deepseek, _lfm2,
+                               _llama, _longcat, _ouro)
 
 # What JAX itself puts on a name stack besides primitives' names.
 WRAPPERS = {"transpose", "jvp", "vmap", "pmap", "jit", "pjit", "while",
@@ -46,7 +46,8 @@ def test_the_finer_names_are_a_vocabulary_of_their_own():
     """``SUBPARTS`` are opened inside a part and never stand for one: the
     benchmark's partition (``xplane_meta.PARTS == tracing.PARTS``) does not
     know them and books their operations to the part around them."""
-    assert tracing.SUBPARTS == ("conv", "conv_state")
+    assert tracing.SUBPARTS == ("conv", "conv_state", "moe_shared",
+                                "latent_prefill")
     assert not set(tracing.SUBPARTS) & set(tracing.PARTS)
     assert all(re.fullmatch(r"[a-z_]+", p) for p in tracing.SUBPARTS)
     for name in tracing.SUBPARTS:
@@ -100,6 +101,7 @@ SERVED = {
     "longcat": (_longcat, DENSE | ROUTED),
     "ouro": (_ouro, DENSE | {"loop"}),
     "lfm2": (_lfm2, DENSE | ROUTED),
+    "deepseek": (_deepseek, DENSE | ROUTED),
 }
 LONGCAT_GONE = ("longcat.mla", "longcat.moe", "longcat.moe.experts")
 
@@ -130,6 +132,13 @@ def test_a_serving_program_opens_its_parts(model, program):
         assert re.search(r"attn/conv/dot_general", text)
         assert re.search(r"attn/conv_state/", text)
         assert not re.search(r"[^/\w](conv|conv_state)/", text)
+    if model == "deepseek":
+        # The shared experts' SwiGLU lies inside ``mlp`` and a chunk's
+        # latent attention inside ``attn``, on the path of their operations.
+        assert re.search(r"mlp/moe_shared/dot_general", text)
+        assert bool(re.search(r"attn/latent_prefill/", text)) == (
+            program == "prefill_chunk")
+        assert not re.search(r"[^/\w](moe_shared|latent_prefill)/", text)
 
 
 def _train_step(name):
