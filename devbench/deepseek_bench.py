@@ -6,6 +6,7 @@ a described v5e with no chip, and timed on one.
     python3 devbench/deepseek_bench.py aot        # no chip, about a minute
     DEEPSEEK_LAYERS=9 python3 devbench/deepseek_bench.py aot
     chiprun -- python3 devbench/deepseek_bench.py step parity
+    chiprun -- python3 devbench/deepseek_bench.py prefill_attention
 
 ``aot``: ``llm/deepseek_serving.py``'s ``prefill_chunk(512)`` and
 ``decode_burst(8)``, compiled for ``v5e:2x2``'s first device (nothing runs:
@@ -17,7 +18,13 @@ inside a burst of 8 at 16 lines of 4,096, 8,192 and 15,360 live positions
 (every line prefilled with tokens of its own first, so the router sees
 what a served step does), and of a prefill chunk of 512 against 0, 4,096,
 8,192 and 15,360 cached rows (the clock stops on a host read of the
-result). ``parity``: the programs in bfloat16 against
+result). ``prefill_attention``:
+``ops/latent_attention.latent_prefill_attention`` alone, a chunk of 512
+against the same four cached lengths of one layer's line, the kernel and
+the XLA reference, at 128 heads and at LongCat's 64: milliseconds a call,
+TFLOP/s from ``adapters/deepseek.prefill_attention_flops`` (live rows
+rounded up to the block of 512) and their share of the chip's peak.
+``parity``: the programs in bfloat16 against
 ``benchmark/reference/deepseek.py`` over a prompt of 1,024 in two chunks
 and 16 decoded tokens, the number a run's ``correct`` compares (the
 reference's top logit minus its logit of the program's token, worst over
@@ -224,6 +231,52 @@ def step() -> dict:
     return out
 
 
+def prefill_attention() -> dict:
+    import jax
+    import jax.numpy as jnp
+    from rtbench.adapters import deepseek as adapter
+
+    from devbench.longcat_bench import timed
+    from ray_tpu.ops import latent_attention as la
+    from ray_tpu.ops.kernels import force_kernel_backend
+
+    cfg, cj = config(), config_json()
+    with open(os.path.join(ROOT, "benchmark", "peaks.json")) as f:
+        peak = json.load(f)[jax.devices()[0].device_kind]["bf16_flops_per_s"]
+    dt, i32, chunk = cfg.jnp_dtype, jnp.int32, 512
+    rank, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                        cfg.qk_rope_head_dim, cfg.v_head_dim)
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+    cache = jax.random.normal(keys[0], (2, 2, MAX_SEQ, cfg.latent_row), dt)
+    block = la.latent_kv_block(MAX_SEQ, 512)
+    out = {"mode": "prefill_attention",
+           "device": jax.devices()[0].device_kind, "chunk": chunk,
+           "block": block, "peak_tflops": peak / 1e12, "rows": []}
+    for heads in (cfg.num_heads, 64):
+        q_n = jax.random.normal(keys[1], (chunk, heads, dn), dt)
+        q_r = jax.random.normal(keys[2], (chunk, heads, dr), dt)
+        w_kb = jax.random.normal(keys[3], (rank, heads, dn), dt) * rank ** -.5
+        w_vb = jax.random.normal(keys[4], (rank, heads, dv), dt) * rank ** -.5
+        for backend in ("mosaic", "reference"):
+            with force_kernel_backend(backend):
+                op = jax.jit(partial(la.latent_prefill_attention,
+                                     rope_dim=dr, sm_scale=cfg.sm_scale))
+                for cached in (0, 4096, 8192, 15360):
+                    scalars = (i32(1), i32(1), i32(cached),
+                               i32(cached + chunk))
+                    sec = timed(lambda: op(q_n, q_r, cache, w_kb, w_vb,
+                                           *scalars), 10)
+                    live = -(-(cached + chunk) // block) * block
+                    flops = adapter.prefill_attention_flops(
+                        {**cj, "num_attention_heads": heads}, chunk, live)
+                    out["rows"].append({
+                        "heads": heads, "backend": backend, "cached": cached,
+                        "ms": round(sec * 1e3, 3),
+                        "tflops": round(flops / sec / 1e12, 1),
+                        "peak_share": round(flops / sec / peak, 3)})
+    return out
+
+
 def parity() -> dict:
     import jax
     import jax.numpy as jnp
@@ -330,7 +383,8 @@ def margins() -> dict:
     return out
 
 
-MODES = {"aot": aot, "step": step, "parity": parity, "margins": margins}
+MODES = {"aot": aot, "step": step, "prefill_attention": prefill_attention,
+         "parity": parity, "margins": margins}
 
 if __name__ == "__main__":
     for mode in sys.argv[1:] or ["aot"]:

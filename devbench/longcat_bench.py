@@ -15,10 +15,11 @@ random weights from the program's own ``init_params``.
   of weights: largest logit difference and margin of each pair.
 - ``burst``: ``decode_burst(steps=8)`` as the engine calls it at a few live
   lengths, ms a step, with the floor of its bytes beside it.
-- ``prefill``: ``prefill_chunk(512)`` at a few cached lengths, and the two
-  forms of the chunk's attention side by side: up-projected (the one
-  ops/latent_attention.py keeps) and absorbed (kept here, for the
-  comparison alone).
+- ``prefill``: ``prefill_chunk(512)`` at a few cached lengths, and the
+  forms of the chunk's attention side by side: the kernel of
+  ops/latent_attention.py (up-projected in VMEM), its XLA reference
+  (up-projected, a block's keys, values and scores arrays in HBM) and
+  absorbed (kept here, for the comparison alone).
 - ``trace``: a profiler trace of a few bursts and chunks, its top device
   ops printed (benchmark/rtbench/trace_reduce.py).
 
@@ -272,7 +273,12 @@ def main(argv: list[str]) -> int:
                 ms_per_step=sec * 1e3, floor_ms=floor * 1e3)
 
     if "prefill" in want:
-        forms = {"up_projected": la.latent_prefill_attention,
+        def up_projected(*args, **kw):
+            with force_kernel_backend("reference"):
+                return la.latent_prefill_attention(*args, **kw)
+
+        forms = {"kernel": la.latent_prefill_attention,
+                 "up_projected": up_projected,
                  "absorbed": absorbed_prefill_attention}
         for name, fn in forms.items():
             serving.latent_prefill_attention = fn

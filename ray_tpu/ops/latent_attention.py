@@ -22,13 +22,21 @@ head, so the kernel does H * (D + rank) * 2 FLOPs a position against D * 2
 bytes (121 a byte at 64 heads, 576 and 512): MXU work and HBM reads of the
 same order, where grouped-query decode is bound by bytes alone.
 
-``latent_prefill_attention`` is the chunk's side: C queries of one slot
-against the live blocks of its line, up-projected block by block (plain
-XLA; a loop over live blocks with a running softmax).
+``latent_prefill_attention`` is the chunk's side, the latent form of
+ops/prefill_attention.py: C queries of one slot against the live blocks of
+its line, in the up-projected form (at 512 queries a block it is 38.7 GFLOP
+a visit at 128 heads against 73 absorbed). Its grid is tiles of heads by
+blocks of the line. A step reads the block's rows once, up-projects them
+with the tile's slices of the two up-projections, scores the chunk's
+queries, and mixes, all in VMEM: the heads' keys and values ([512, 128,
+256]) and the float32 scores ([128, 512, 512], 134 MB) of a visit, which
+XLA's loop over live blocks wrote to HBM and read back (19% of the MXU's
+peak, PR 45), do not exist outside the kernel. That loop stays as the
+reference.
 
 Scores, the running maximum and sum, and the accumulation are float32; the
-operands stay in the cache's dtype. The three implementations are those of
-ops/kernels.py.
+operands stay in the cache's dtype, up-projected rows and probabilities are
+rounded to it. The three implementations are those of ops/kernels.py.
 """
 
 from __future__ import annotations
@@ -306,27 +314,15 @@ def latent_row_write(cache, new, layer, positions0, write_mask, *,
               write_mask)
 
 
-def latent_prefill_attention(q_n, q_r, cache, w_kb, w_vb, layer, slot,
-                             kv_len, length, *, rope_dim: int,
-                             sm_scale: float, block: int | None = None):
-    """One chunk of one slot against its line, the chunk's own rows already
-    written. q_n [C, H, Dn] and q_r [C, H, Dr] (rotated) are the queries at
-    positions kv_len .. kv_len + C - 1; cache [L, B, S, W] with W >= rank +
-    Dr = rank + ``rope_dim``; w_kb
-    [rank, H, Dn] and w_vb [rank, H, Dv] the two halves of the key/value
-    up-projection. Query i sees positions <= kv_len + i and < length.
-    Returns [C, H, Dv].
-
-    Up-projected, a block of the line at a time: the block's ``c_kv`` rows
-    give every head's keys and values (one [blk, rank] x [rank, H * (Dn +
-    Dv)] matmul), then scores and the weighted sum at the heads' own widths
-    (Dn + Dr and Dv). Only the blocks that hold a visible position are
-    visited (a loop whose trip count is a run-time scalar), with the running
-    maximum and sum of a blocked softmax."""
+def latent_prefill_attention_reference(q_n, q_r, cache, w_kb, w_vb, layer,
+                                       slot, kv_len, length, *, rope_dim: int,
+                                       sm_scale: float, block: int):
+    """Plain XLA: a loop over the live blocks (its trip count a run-time
+    scalar) with the running maximum and sum of a blocked softmax; a block's
+    up-projected rows and float32 scores are arrays of their own."""
     c, h, _ = q_n.shape
-    s, d = cache.shape[2], cache.shape[3]
+    d = cache.shape[3]
     rank, dv = w_kb.shape[0], w_vb.shape[2]
-    block = block or latent_kv_block(s, 512)
     qpos = kv_len + jnp.arange(c)
     live = jnp.minimum(kv_len + c, length)
     n_blocks = (live + block - 1) // block
@@ -361,3 +357,223 @@ def latent_prefill_attention(q_n, q_r, cache, w_kb, w_vb, layer, slot,
     _, l, acc = lax.fori_loop(0, n_blocks, body, init)
     out = acc / jnp.maximum(l, 1e-30)
     return out.transpose(1, 0, 2).astype(q_n.dtype)
+
+
+# What a head tile's blocks and scratch may take of VMEM (128 MiB on a
+# v5e): the tile is the most heads that fit, so a line's block is re-read
+# for few tiles and the grid has few steps (a dead one costs what a live
+# one's bookkeeping does). At the published widths that is 16 heads; tiles
+# of 8 and of 32 read within 2% of it on the chip (PR 46).
+_PREFILL_VMEM = 56 << 20
+
+
+def _lanes(n: int) -> int:
+    return -(-n // 128) * 128
+
+
+def _prefill_vmem(tile: int, chunk: int, block: int, rank: int, dn: int,
+                  dr: int, dv: int, width: int, itemsize: int) -> int:
+    """Bytes of VMEM a grid step of ``tile`` heads holds: the pipeline's two
+    buffers of each operand and of the output, the float32 accumulator and
+    statistics, and a head's scores, probabilities and up-projected rows."""
+    operands = (block * _lanes(width)
+                + tile * rank * (_lanes(dn) + _lanes(dv))
+                + tile * chunk * (_lanes(dn) + _lanes(dr) + _lanes(dv)))
+    scratch = tile * chunk * (_lanes(dv) + 2 * 128) * 4
+    head = 4 * chunk * _lanes(block) * 4 + 4 * block * _lanes(dn + dv)
+    return 2 * operands * itemsize + scratch + head
+
+
+def latent_prefill_head_tile(heads: int, chunk: int, block: int, rank: int,
+                             dn: int, dr: int, dv: int, width: int,
+                             itemsize: int = 2) -> int:
+    """Heads a tile of the chunk's kernel: the largest divisor of ``heads``
+    whose blocks fit ``_PREFILL_VMEM`` and whose outputs are whole lanes."""
+    fits = [t for t in range(1, heads + 1)
+            if heads % t == 0 and (t == heads or t * dv % 128 == 0)
+            and _prefill_vmem(t, chunk, block, rank, dn, dr, dv, width,
+                              itemsize) <= _PREFILL_VMEM]
+    return fits[-1] if fits else 1
+
+
+def _latent_prefill_kernel(sc_ref, qn_ref, qr_ref, kv_ref, wk_ref, wv_ref,
+                           o_ref, m_ref, l_ref, acc_ref, *, block: int,
+                           sm_scale: float):
+    """qn_ref [T, C, Dn], qr_ref [T, C, Dr] the tile's T heads; kv_ref
+    [block, W] one block of the line, W >= rank + Dr; wk_ref [T, rank, Dn],
+    wv_ref [T, rank, Dv]; o_ref [C, T * Dv]."""
+    from jax.experimental import pallas as pl
+
+    # sc_ref: layer, slot (read by the index maps), kv_len, limit.
+    blk = pl.program_id(1)
+    kv_len, limit = sc_ref[2], sc_ref[3]
+    tile, c, _ = qn_ref.shape
+    rank, dv = wv_ref.shape[1:]
+    dt = qn_ref.dtype
+
+    @pl.when(blk == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def visit(masked: bool):
+        nt = (((1,), (1,)), ((), ()))
+        ckv = kv_ref[:, :rank].astype(dt)
+        kr = kv_ref[:, rank:rank + qr_ref.shape[2]].astype(dt)
+
+        def head(h):
+            # The head's keys and values of the block, rounded as the
+            # reference's einsum rounds them; they never leave VMEM.
+            kn = jnp.dot(ckv, wk_ref[h],
+                         preferred_element_type=jnp.float32).astype(dt)
+            v = jnp.dot(ckv, wv_ref[h],
+                        preferred_element_type=jnp.float32).astype(dt)
+            s = lax.dot_general(qn_ref[h], kn, nt,
+                                preferred_element_type=jnp.float32)
+            s += lax.dot_general(qr_ref[h], kr, nt,
+                                 preferred_element_type=jnp.float32)
+            s *= sm_scale
+            if masked:
+                kpos = blk * block + lax.broadcasted_iota(
+                    jnp.int32, (c, block), 1)
+                qpos = kv_len + lax.broadcasted_iota(jnp.int32, (c, block), 0)
+                visible = (kpos <= qpos) & (kpos < limit)
+                s = jnp.where(visible, s, NEG_INF)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            if masked:
+                # The select keeps a row with nothing visible yet at zero
+                # (exp(NEG_INF - NEG_INF) would be one).
+                p = jnp.where(visible, p, 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = alpha * l_ref[h] + p.sum(axis=-1, keepdims=True)
+            acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
+                p.astype(dt), v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+
+        # Two heads a trip: one's softmax (vector unit) runs beside the
+        # other's products (MXU), 134 against 111 TFLOP/s at 128 heads and
+        # 15,360 cached rows, and a trip of four or of all gains no more;
+        # a whole tile unrolled compiles for 11 s and not 2.
+        pair = 2 - tile % 2
+
+        def heads(g, carry):
+            for k in range(pair):
+                head(g * pair + k)
+            return carry
+
+        lax.fori_loop(0, tile // pair, heads, 0)
+
+    live = blk * block < limit
+    # Every key of the block is at or below the chunk's first query.
+    whole = (blk + 1) * block <= jnp.minimum(kv_len + 1, limit)
+
+    @pl.when(live & whole)
+    def _():
+        visit(masked=False)
+
+    @pl.when(live & jnp.logical_not(whole))
+    def _():
+        visit(masked=True)
+
+    @pl.when(blk == pl.num_programs(1) - 1)
+    def _():
+        for h in range(tile):
+            o = acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)
+            o_ref[:, h * dv:(h + 1) * dv] = o.astype(o_ref.dtype)
+
+
+def _latent_prefill_pallas(q_n, q_r, cache, w_kb, w_vb, layer, slot, kv_len,
+                           length, *, rope_dim: int, sm_scale: float,
+                           block: int):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    c, h, dn = q_n.shape
+    s, width = cache.shape[2], cache.shape[3]
+    rank, dv = w_kb.shape[0], w_vb.shape[2]
+    if s % block:
+        raise ValueError(f"latent_prefill_attention: block {block} does not "
+                         f"divide the cache line of {s} positions")
+    itemsize = q_n.dtype.itemsize
+    sub = 32 // itemsize
+    c_pad = -(-c // sub) * sub
+    tile = latent_prefill_head_tile(h, c_pad, block, rank, dn, rope_dim, dv,
+                                    width, itemsize)
+
+    def heads_first(x):
+        # [C, H, D] -> [H, C_pad, D]: a head is a leading index in the
+        # kernel. XLA folds the transposition into what produces x.
+        return jnp.pad(x.transpose(1, 0, 2),
+                       ((0, 0), (0, c_pad - c), (0, 0)))
+
+    # The chunk's own rows end at kv_len + c: a padded query sees no further.
+    limit = jnp.clip(jnp.minimum(kv_len + c, length), 0, s)
+    scalars = jnp.stack([layer, slot, kv_len, limit])
+
+    def kv_index(i, j, sc):
+        last_live = jnp.maximum(pl.cdiv(sc[3], block) - 1, 0)
+        return (sc[0], sc[1], jnp.minimum(j, last_live), 0)
+
+    def head_index(i, j, sc):
+        return (i, 0, 0)
+
+    out = pl.pallas_call(
+        functools.partial(_latent_prefill_kernel, block=block,
+                          sm_scale=sm_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(h // tile, s // block),
+            in_specs=[pl.BlockSpec((tile, c_pad, dn), head_index),
+                      pl.BlockSpec((tile, c_pad, rope_dim), head_index),
+                      pl.BlockSpec((None, None, block, width), kv_index),
+                      pl.BlockSpec((tile, rank, dn), head_index),
+                      pl.BlockSpec((tile, rank, dv), head_index)],
+            out_specs=pl.BlockSpec((c_pad, tile * dv),
+                                   lambda i, j, sc: (0, i)),
+            scratch_shapes=[pltpu.VMEM((tile, c_pad, 1), jnp.float32),
+                            pltpu.VMEM((tile, c_pad, 1), jnp.float32),
+                            pltpu.VMEM((tile, c_pad, dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((c_pad, h * dv), q_n.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_PREFILL_VMEM + (8 << 20)),
+        interpret=kernel_backend() == "interpret",
+        name="latent_prefill_attention",
+    )(scalars, heads_first(q_n), heads_first(q_r), cache,
+      w_kb.transpose(1, 0, 2), w_vb.transpose(1, 0, 2))
+    return out[:c].reshape(c, h, dv)
+
+
+def latent_prefill_attention(q_n, q_r, cache, w_kb, w_vb, layer, slot,
+                             kv_len, length, *, rope_dim: int,
+                             sm_scale: float, block: int | None = None):
+    """One chunk of one slot against its line, the chunk's own rows already
+    written. q_n [C, H, Dn] and q_r [C, H, Dr] (rotated) are the queries at
+    positions kv_len .. kv_len + C - 1; cache [L, B, S, W] with W >= rank +
+    Dr = rank + ``rope_dim``; w_kb
+    [rank, H, Dn] and w_vb [rank, H, Dv] the two halves of the key/value
+    up-projection. Query i sees positions <= kv_len + i and < length.
+    Returns [C, H, Dv]; a row that sees nothing gives zeros.
+
+    Up-projected, a block of the line at a time: the block's ``c_kv`` rows
+    give a head's keys and values ([blk, rank] x [rank, Dn] and x [rank,
+    Dv]), then scores and the weighted sum at the heads' own widths (Dn +
+    Dr and Dv), with the running maximum and sum of a blocked softmax. The
+    kernel's grid is tiles of heads (:func:`latent_prefill_head_tile`) by
+    blocks of the line: the up-projected rows and the float32 scores of a
+    visit live in VMEM, and the output is all that goes to HBM. ``layer``,
+    ``slot``, ``kv_len`` and ``length`` are scalar-prefetched (one program a
+    chunk size serves every slot and cached length); a block past ``min(kv_len
+    + C, length)`` is neither fetched nor computed, and only a block that
+    holds a position past the chunk's first query or the line's end is
+    masked."""
+    block = block or latent_kv_block(cache.shape[2], 512)
+    impl = (latent_prefill_attention_reference
+            if kernel_backend() == "reference" else _latent_prefill_pallas)
+    as_i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
+    return impl(q_n, q_r, cache, w_kb, w_vb, as_i32(layer), as_i32(slot),
+                as_i32(kv_len), as_i32(length), rope_dim=rope_dim,
+                sm_scale=sm_scale, block=block)
