@@ -5,6 +5,7 @@ compiled for a described v5e with no chip, and timed on one.
     python3 devbench/lfm2_bench.py aot            # no chip, about a minute
     chiprun -- python3 devbench/lfm2_bench.py step parity
     chiprun -- python3 devbench/lfm2_bench.py tile       # about 3 minutes
+    chiprun -- python3 devbench/lfm2_bench.py mixed      # about 4 minutes
 
 ``aot``: ``llm/lfm2_serving.py``'s ``prefill_chunk`` at the buckets 16 and
 512 and ``decode_burst(8)``, compiled for ``v5e:2x2``'s first device
@@ -26,7 +27,18 @@ chunk of 512 tokens and at a decode step of 64 lines: one routed layer
 (``moe_block`` whole, and its two ``grouped_matmul`` calls alone on the same
 plan) with tiles a call, experts touched and the share of the touched
 experts' bytes at 819 GB/s in the two calls' time; then the whole
-``prefill_chunk(512)`` and a step of ``decode_burst(8)``. One JSON object a
+``prefill_chunk(512)`` and a step of ``decode_burst(8)``. ``mixed``: the fit
+behind ``ServedModel.mixed_burst``. One step that carries a chunk
+(``lfm2_serving._mixed_impl``: 512 chunk rows against 2,048 cached and 63
+lines at 4,096 live rows through every layer as one array; other sizes by
+``LFM2_MIXED="rows lines live cached"``) against ``prefill_chunk`` and
+``decode_step`` apart on the same cache: wall milliseconds a call (calls
+chained on the donated cache, the clock stopped on the last), and from one
+device trace of the three programs each one's device milliseconds a call
+with the share of its ``tracing.part`` scopes (``moe_experts``: the routed
+layers' two kernel calls). The mixed step runs at the row tile
+``routed.row_tile`` picks for its tokens and at the next smaller one, so that
+what the tile costs 36 rows an expert is read beside it. One JSON object a
 mode.
 """
 
@@ -85,10 +97,14 @@ def lowerings(cfg, params, cache, arg, slots: int = SLOTS) -> dict:
             cfg, params, cache, arg((b,)), arg(()), arg(()), arg(()))
 
     out = {f"prefill_chunk({b})": chunk(b) for b in BUCKETS}
+    burst = (cfg, params, cache, arg((slots,)), arg((slots,)),
+             arg((slots,), jnp.bool_), arg((slots,), jnp.float32),
+             arg((slots,), jnp.float32), arg((2,), jnp.uint32))
     out["decode_burst(8)"] = lambda: serving.decode_burst.lower(
-        cfg, params, cache, arg((slots,)), arg((slots,)),
-        arg((slots,), jnp.bool_), arg((slots,), jnp.float32),
-        arg((slots,), jnp.float32), arg((2,), jnp.uint32), 8, False)
+        *burst, 8, False)
+    riders = (arg((8, BUCKETS[-1])), arg((8,)), arg((8,)), arg((8,)), arg(()))
+    out["mixed_burst(8)"] = lambda: serving.mixed_burst.lower(
+        *burst, riders, 8, False)
     return out
 
 
@@ -375,7 +391,95 @@ def tile() -> dict:
     return out
 
 
-MODES = {"aot": aot, "step": step, "parity": parity, "tile": tile}
+def mixed(calls: int = 10) -> dict:
+    import shutil
+
+    import jax
+    import jax.numpy as jnp
+    from rtbench import trace_reduce, xplane_meta
+
+    import trace_parts
+    from ray_tpu.models import routed
+
+    rows, lines, live, cached = (
+        int(n) for n in os.environ.get("LFM2_MIXED",
+                                       "512 63 4096 2048").split())
+    cfg, params, serving, cache = _programs()
+    rule, i32 = cfg.router_rule, jnp.int32
+    chunk = jnp.arange(rows, dtype=i32) + 300
+    tokens = jnp.arange(SLOTS, dtype=i32) + 900
+    # The chunk's slot is 0 and does not decode; the next ``lines`` do.
+    write = (jnp.arange(SLOTS) >= 1) & (jnp.arange(SLOTS) <= lines)
+    positions = jnp.full((SLOTS,), live, i32)
+    kv_len, length = i32(cached), i32(cached + 2 * rows)
+    rule_s_own = routed.row_tile
+    rule_s_tile = rule_s_own(rows + SLOTS, rule.topk, rule.outputs)
+    out = {"mode": "mixed", "device": jax.devices()[0].device_kind,
+           "rows": rows, "lines": lines, "live": live, "cached": cached,
+           "rule_picks": rule_s_tile, "calls": calls, "counts": {}}
+    programs = {
+        "prefill_chunk": lambda c: serving.prefill_chunk(
+            cfg, params, c, chunk, kv_len, length, i32(0)),
+        "decode_step": lambda c: serving.decode_step(
+            cfg, params, c, tokens, positions, write)}
+    for tm in [t for t in routed.ROW_TILES if t <= rule_s_tile][:-3:-1]:
+        # A jit a tile under a name of its own (the trace is read by
+        # program name), traced while the tile stands in the rule's place
+        # for this program's token count alone. ``params`` is an argument:
+        # closed over, 9.66 GB of constants are captured at lowering.
+        def mixed_step(cfg, params, cache):
+            return serving._mixed_impl(cfg, params, cache, tokens, positions,
+                                       write, chunk, kv_len, length, i32(0))
+
+        mixed_step.__name__ = f"mixed_step_tile{tm}"
+        jitted = jax.jit(mixed_step, static_argnums=0, donate_argnums=2)
+        routed.row_tile = lambda t, *a, tm=tm: (
+            tm if t == rows + SLOTS else rule_s_own(t, *a))
+        try:
+            cache = jax.block_until_ready(jitted(cfg, params, cache))[0]
+        finally:
+            routed.row_tile = rule_s_own
+        programs[mixed_step.__name__] = (
+            lambda c, jitted=jitted: jitted(cfg, params, c))
+
+    def run(name, cache):
+        for _ in range(calls):
+            cache, _, counts = programs[name](cache)
+        return jax.block_until_ready(cache), counts
+
+    out["wall_ms"] = {}
+    for name in programs:
+        cache, counts = run(name, cache)                  # compiles, warms
+        t0 = time.monotonic()
+        cache, counts = run(name, cache)
+        out["wall_ms"][name] = round(
+            (time.monotonic() - t0) * 1e3 / calls, 3)
+        out["counts"][name] = [int(n) for n in counts]
+    trace_dir = os.path.join(ROOT, ".chipwork", "lfm2_mixed")
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    jax.profiler.start_trace(trace_dir)
+    for name in programs:
+        cache, _ = run(name, cache)
+    jax.profiler.stop_trace()
+    out["device_ms"], out["part_share_pct"] = {}, {}
+    try:
+        by_program = trace_parts.tables(xplane_meta.load(
+            trace_reduce.find_xplane(trace_dir)))["programs"]
+    except Exception as e:  # noqa: BLE001 - the wall times stand alone
+        out["trace_error"], by_program = repr(e), {}
+    for program, parts in by_program.items():
+        name = program.removeprefix("jit_")
+        if name in programs:
+            total = sum(parts.values())
+            out["device_ms"][name] = round(total * 1e3 / calls, 3)
+            out["part_share_pct"][name] = {
+                k: round(100 * v / total, 1) for k, v in
+                sorted(parts.items(), key=lambda kv: -kv[1])}
+    return out
+
+
+MODES = {"aot": aot, "step": step, "parity": parity, "tile": tile,
+         "mixed": mixed}
 
 if __name__ == "__main__":
     for mode in sys.argv[1:] or ["aot"]:

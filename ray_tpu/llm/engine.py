@@ -275,6 +275,10 @@ class LLMEngine:
         self.finished = 0
         self.prompt_tokens_prefilled = 0
         self.prefill_chunks = 0
+        # Of prefill_chunks and prompt_tokens_prefilled, those that rode a
+        # decode step (ServedModel.mixed_burst; 0 for a model without one).
+        self.prefill_chunks_riding = 0
+        self.prefill_tokens_riding = 0
         self.decode_dispatches = 0
         self.decode_dispatches_ahead = 0
         self.decode_steps = 0
@@ -342,6 +346,20 @@ class LLMEngine:
         self._prefix_hash_cache: dict[tuple, tuple[int, ...]] = {}
         self._cache_gen = 0  # bumped when a device failure rebuilds the cache
         self._prefill_rr = -1  # last slot that ran a prefill chunk
+        # A burst that carries chunks (ServedModel.mixed_burst) has one
+        # length, the one _burst_len gives while a slot is mid-prefill and
+        # a line has that many steps left, and its chunks one size, the
+        # full bucket: one compiled shape, which the first riders build.
+        # 0: chunks never ride (the model offers no such program, a draft
+        # model's ticks read the host's tokens, or a burst is one step).
+        self._ride_steps = 0
+        self._ride_rows = self._chunk_bucket(0, config.prefill_chunk)[0]
+        if self.model.mixed_burst is not None and self.draft_cfg is None:
+            steps = min(int(config.decode_burst or 1),
+                        self.PREFILL_PRIORITY_BURST)
+            self._ride_steps = 1 << (steps.bit_length() - 1) if steps > 1 \
+                else 0
+        self._tick_chunks = 0  # chunks this tick has dispatched, riders too
         self._waiting: queue.Queue[GenerationRequest] = queue.Queue()
         # Held slots returned by release_slot (user threads); the
         # scheduler thread frees + retires them at tick start — slot and
@@ -584,6 +602,8 @@ class LLMEngine:
                "finished": self.finished,
                "prompt_tokens_prefilled": self.prompt_tokens_prefilled,
                "prefill_chunks": self.prefill_chunks,
+               "prefill_chunks_riding": self.prefill_chunks_riding,
+               "prefill_tokens_riding": self.prefill_tokens_riding,
                "decode_dispatches": self.decode_dispatches,
                "decode_dispatches_ahead": self.decode_dispatches_ahead,
                "decode_steps": self.decode_steps,
@@ -669,6 +689,7 @@ class LLMEngine:
         # engine.tick encloses the tick's other phases: what a profile
         # shows in it and in none of them is the scheduler's own glue.
         with tracing.phase("engine.tick"):
+            self._tick_chunks = 0
             self._process_releases()
             # Per-PASS chunk budget: the tick has two admission passes
             # (before and after the blocking read) and each gets a full
@@ -695,10 +716,26 @@ class LLMEngine:
 
     def _prefill_steps(self) -> bool:
         budget = max(1, self.config.prefill_chunks_per_tick)
+        riding = self._riding()
         spent = 0
-        while spent < budget and self._prefill_step():
+        while spent < budget and self._prefill_step(riding):
             spent += 1
+        self._tick_chunks += spent
         return spent > 0
+
+    def _riding(self) -> bool:
+        """Whether the tick's decode batch will be a burst that carries
+        chunks: the model offers one and the lines that still decode make a
+        burst of its length. A full, non-final chunk is then left for that
+        burst (_prefill_step, _take_riders). Where a read in between ends
+        the lines and no such burst goes out, the chunk waits for the next
+        tick's passes, which ask again."""
+        if not self._ride_steps or all(
+                r is None or r.next_pos >= 0 for r in self._slots.values()):
+            return False    # no such program, or no slot mid-prefill
+        active = {s: r for s, r in self._decoding().items()
+                  if self._steps_left(r) > 0}
+        return bool(active) and self._burst_len(active) == self._ride_steps
 
     def _decoding(self) -> dict[int, GenerationRequest]:
         return {s: r for s, r in self._slots.items()
@@ -1017,10 +1054,26 @@ class LLMEngine:
         self._prefix_live[slot] = tuple(req.prompt_ids)  # imported KV = donor
         self._emit(req, first_token)
 
-    def _prefill_step(self) -> bool:
+    def _prefill_step(self, riding: bool = False) -> bool:
         """Run ONE chunk of ONE prefilling request, rotating across slots so
         concurrent long prompts interleave chunks (true round-robin — a
-        lowest-slot rescan would monopolize prefill for one prompt)."""
+        lowest-slot rescan would monopolize prefill for one prompt).
+        ``riding``: a slot whose next chunk can ride the tick's burst is
+        passed over, chunk and all (its later chunks come after it)."""
+        for slot, req, bucket, take in self._next_chunks():
+            if riding and self._rides(req, bucket, take):
+                continue
+            self._prefill_rr = slot
+            with tracing.phase("engine.prefill_dispatch", tokens=take,
+                               bucket=bucket):
+                self._dispatch_prefill_chunk(slot, req, bucket, take)
+            return True
+        return False
+
+    def _next_chunks(self):
+        """(slot, request, bucket, take) of every prefilling slot's next
+        chunk, in round-robin order from the slot after the last that ran
+        one."""
         slots = list(self._slots.keys())
         n = len(slots)
         for i in range(n):
@@ -1028,15 +1081,58 @@ class LLMEngine:
             req = self._slots.get(slot)
             if req is None or req.next_pos >= 0:
                 continue
-            self._prefill_rr = slot
-            bucket, take = self._chunk_bucket(
+            yield (slot, req, *self._chunk_bucket(
                 req.prefilled_len,
-                self._prefill_len(req) - req.prefilled_len)
-            with tracing.phase("engine.prefill_dispatch", tokens=take,
-                               bucket=bucket):
-                self._dispatch_prefill_chunk(slot, req, bucket, take)
-            return True
-        return False
+                self._prefill_len(req) - req.prefilled_len))
+
+    def _rides(self, req: GenerationRequest, bucket: int, take: int) -> bool:
+        """Whether a chunk can ride a decode step: a full one of the full
+        bucket that is not its prompt's last (a rider gives no token)."""
+        return (take == bucket == self._ride_rows
+                and req.prefilled_len + take < self._prefill_len(req))
+
+    def _take_riders(self, steps: int):
+        """The chunks that ride a burst of ``steps`` steps, at most one a
+        step and as many as the tick's budget of chunks has left
+        (prefill_chunks_per_tick an admission pass, of which a tick has
+        two), each the next chunk of the next prefilling slot in
+        _prefill_step's round-robin order, so a prompt's consecutive chunks
+        may ride consecutive steps. The host's side of each is
+        _dispatch_prefill_chunk's for a chunk that is not the last; its
+        counts come with the burst's. Returns ``mixed_burst``'s ``riders``,
+        or None where none rides."""
+        room = min(steps, 2 * max(1, self.config.prefill_chunks_per_tick)
+                   - self._tick_chunks)
+        rows = self._ride_rows
+        picked = []  # (slot, cached rows, prefilled length, the chunk)
+        while len(picked) < room:
+            rider = next((c for c in self._next_chunks()
+                          if self._rides(*c[1:])), None)
+            if rider is None:
+                break
+            slot, req = rider[:2]
+            self._prefill_rr = slot
+            at = req.prefilled_len
+            picked.append((slot, at, self._prefill_len(req),
+                           req.prompt_ids[at:at + rows]))
+            self.prefill_kv_positions_read += min(at + rows, self.max_seq)
+            self.prefill_kv_positions_reserved += self.max_seq
+            req.prefilled_len += rows
+        n = len(picked)
+        if not n:
+            return None
+        self._tick_chunks += n
+        self.prefill_chunks += n
+        self.prefill_chunks_riding += n
+        self.prompt_tokens_prefilled += n * rows
+        self.prefill_tokens_riding += n * rows
+        # The steps past the riders carry zeros that nothing reads.
+        chunks = np.zeros((steps, rows), np.int32)
+        chunks[:n] = [p[3] for p in picked]
+        scalars = np.zeros((3, steps), np.int32)
+        scalars[:, :n] = np.array([p[:3] for p in picked]).T
+        return (jnp.asarray(chunks), *(jnp.asarray(a) for a in scalars),
+                jnp.int32(n))
 
     def _prefill_len(self, req: GenerationRequest) -> int:
         """The prompt's tokens that are prefilled: its whole steps (all of
@@ -1281,11 +1377,17 @@ class LLMEngine:
                     top_ps[slot] = req.sampling.top_p
                 need_top_p = bool((top_ps < 1.0).any())
                 self._rng_key, sub = jax.random.split(self._rng_key)
-                self.cache, toks, *counts = self.model.decode_burst(
+                # Chunks left for this burst by _prefill_step ride it.
+                riders = (self._take_riders(burst)
+                          if burst == self._ride_steps else None)
+                program, carried = (
+                    (self.model.decode_burst, ()) if riders is None
+                    else (self.model.mixed_burst, (riders,)))
+                self.cache, toks, *counts = program(
                     self.model_cfg, self.params, self.cache,
                     self._input_tokens(active), jnp.asarray(positions),
                     jnp.asarray(write), jnp.asarray(temps),
-                    jnp.asarray(top_ps), sub, burst, need_top_p,
+                    jnp.asarray(top_ps), sub, *carried, burst, need_top_p,
                     kmesh=self.kmesh)
                 # Every burst leaves its last row on the device, wanted or
                 # not: a lone request then walks the helper at every burst

@@ -28,8 +28,10 @@ a line:
 
 The programs keep the contract's names (``prefill_chunk``, ``decode_step``,
 ``decode_burst``: a device trace shows ``jit_<name>``; the last two are
-built from ``_decode_impl`` by llm/served.token_step_programs) and
-signatures, and return the routed layers' counts
+built from ``_decode_impl`` by llm/served.token_step_programs;
+``mixed_burst``, the burst whose steps carry a prefill chunk each, from it
+and ``_mixed_impl`` by llm/served.mixed_burst_program) and signatures, and
+return the routed layers' counts
 (models/routed.MOE_COUNTERS, int32[6], summed over the program's layers and
 steps) beside their result; the scheduler adds them up where it fetches the
 tokens.
@@ -43,7 +45,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.llm.served import ServedModel, token_step_programs
+from ray_tpu.llm.served import (
+    ServedModel,
+    mixed_burst_program,
+    token_step_programs,
+)
 from ray_tpu.models import lfm2
 from ray_tpu.models.lfm2 import ATTENTION, CONV, Lfm2Config
 from ray_tpu.models.routed import MOE_COUNTERS, layer_of
@@ -74,6 +80,69 @@ def _run(cfg, params, x, cache, operators, valid, kmesh):
     return x, {"kv": kv, "conv": conv}, counts
 
 
+# A program's operators are made of these halves: the chunk's (one slot,
+# ``c`` rows from ``kv_len`` on) and the lines' (every slot, a row each).
+# ``prefill_chunk`` runs the first, ``_decode_impl`` the second, and a mixed
+# step both, on the rows of one array.
+
+def _chunk_prior(cfg, cs, line, slot, kv_len):
+    """The state a chunk's convolution starts from: the slot's, or zeros at
+    a prompt's start."""
+    keep = cfg.conv_L_cache - 1
+    with tracing.part("conv_state"):
+        prior = lax.dynamic_slice(
+            cs, (line, slot, 0), (1, 1, keep * cfg.hidden_size))
+        return jnp.where(kv_len > 0, prior, 0).reshape(
+            1, keep, cfg.hidden_size)
+
+
+def _chunk_keep(cfg, cs, zz, line, slot, n_valid):
+    """The state a chunk leaves its slot. zz is the prior rows, then the
+    chunk's: the rows that end at the last valid token."""
+    with tracing.part("conv_state"):
+        last = lax.dynamic_slice_in_dim(zz, n_valid, cfg.conv_L_cache - 1,
+                                        axis=1)
+        return lax.dynamic_update_slice(
+            cs, last.astype(cs.dtype).reshape(1, 1, -1), (line, slot, 0))
+
+
+def _chunk_attend(kv, q, k, v, line, slot, kv_len, length, kmesh):
+    """A chunk's rows written to its slot's line and attended from it:
+    q [1, nh, C, D], k and v [1, nkv, C, D] -> (kv, o [1, C, nh * D])."""
+    with tracing.part("cache"):
+        kv, _ = prefill_kv_write(kv, None, k[0], v[0], line, slot, kv_len)
+    o = prefill_attention(q[0], kv, None, line, slot, kv_len, length,
+                          kmesh=kmesh)
+    return kv, o.transpose(1, 0, 2).reshape(1, q.shape[2], -1)
+
+
+def _lines_prior(cfg, cs, line):
+    """Every slot's state of one convolution: [B, conv_L_cache - 1, H]."""
+    with tracing.part("conv_state"):
+        return layer_of(cs, line).reshape(
+            cs.shape[1], cfg.conv_L_cache - 1, cfg.hidden_size)
+
+
+def _lines_keep(cs, zz, prior, line, write_mask):
+    """The states after a step: a slot that does not decode keeps its own."""
+    with tracing.part("conv_state"):
+        new = jnp.where(write_mask[:, None, None], zz[:, 1:], prior)
+        return lax.dynamic_update_index_in_dim(
+            cs, new.astype(cs.dtype).reshape(new.shape[0], -1), line, 0)
+
+
+def _lines_attend(kv, q, k, v, line, lengths, positions0, write_mask, plan,
+                  kmesh):
+    """The lines' rows written and attended from: q [B, nh, 1, D], k and v
+    [B, nkv, 1, D] -> (kv, o [B, 1, nh * D])."""
+    with tracing.part("cache"):
+        kv, _ = kv_row_write(kv, None, k, v, line, positions0, write_mask,
+                             kmesh=kmesh)
+    o = decode_attention(q, kv, None, line, lengths, positions0, plan=plan,
+                         kmesh=kmesh)
+    return kv, o.transpose(0, 2, 1, 3).reshape(q.shape[0], 1, -1)
+
+
 @partial(jax.jit, static_argnums=(0,), static_argnames=("kmesh",),
          donate_argnums=(2,))
 def prefill_chunk(cfg: Lfm2Config, params, cache, tokens, kv_len, length,
@@ -82,7 +151,6 @@ def prefill_chunk(cfg: Lfm2Config, params, cache, tokens, kv_len, length,
     llm/llama_serving.prefill_chunk). Returns (cache, last-token logits [V],
     counts)."""
     c = tokens.shape[0]
-    keep = cfg.conv_L_cache - 1
     with tracing.part("embed"):
         x = params["embed_tokens"][tokens][None]              # [1, C, H]
     with tracing.part("attn"):
@@ -95,28 +163,15 @@ def prefill_chunk(cfg: Lfm2Config, params, cache, tokens, kv_len, length,
 
     def conv(line, cp, xn, state):
         kv, cs = state
-        with tracing.part("conv_state"):
-            prior = lax.dynamic_slice(
-                cs, (line, slot, 0), (1, 1, keep * cfg.hidden_size))
-            prior = jnp.where(kv_len > 0, prior, 0).reshape(
-                1, keep, cfg.hidden_size)
-        y, zz = lfm2.short_conv(cfg, cp, xn, prior)
-        with tracing.part("conv_state"):
-            # zz is the prior rows, then the chunk's: the rows that end at
-            # the last valid token.
-            last = lax.dynamic_slice_in_dim(zz, n_valid, keep, axis=1)
-            cs = lax.dynamic_update_slice(
-                cs, last.astype(cs.dtype).reshape(1, 1, -1), (line, slot, 0))
-        return y, (kv, cs)
+        y, zz = lfm2.short_conv(cfg, cp, xn,
+                                _chunk_prior(cfg, cs, line, slot, kv_len))
+        return y, (kv, _chunk_keep(cfg, cs, zz, line, slot, n_valid))
 
     def attention(line, ap, xn, state):
         kv, cs = state
         q, k, v = lfm2.attention_heads(cfg, ap, xn, positions, inv_freq)
-        with tracing.part("cache"):
-            kv, _ = prefill_kv_write(kv, None, k[0], v[0], line, slot, kv_len)
-        o = prefill_attention(q[0], kv, None, line, slot, kv_len, length,
-                              kmesh=kmesh)
-        o = o.transpose(1, 0, 2).reshape(1, c, -1)
+        kv, o = _chunk_attend(kv, q, k, v, line, slot, kv_len, length,
+                              kmesh)
         return (o @ ap["wo"]).astype(xn.dtype), (kv, cs)
 
     x, cache, counts = _run(cfg, params, x, cache,
@@ -132,7 +187,6 @@ def _decode_impl(cfg: Lfm2Config, params, cache, tokens, positions0,
     """One token per slot against the cache and the states. Returns (cache,
     logits [B, V], counts). A slot with ``write_mask`` false writes no row,
     keeps its state, is routed nowhere, and its logits mean nothing."""
-    b, keep = tokens.shape[0], cfg.conv_L_cache - 1
     with tracing.part("embed"):
         x = params["embed_tokens"][tokens][:, None]           # [B, 1, H]
     with tracing.part("attn"):
@@ -146,24 +200,15 @@ def _decode_impl(cfg: Lfm2Config, params, cache, tokens, positions0,
 
     def conv(line, cp, xn, state):
         kv, cs = state
-        with tracing.part("conv_state"):
-            prior = layer_of(cs, line).reshape(b, keep, cfg.hidden_size)
+        prior = _lines_prior(cfg, cs, line)
         y, zz = lfm2.short_conv(cfg, cp, xn, prior)
-        with tracing.part("conv_state"):
-            new = jnp.where(write_mask[:, None, None], zz[:, 1:], prior)
-            cs = lax.dynamic_update_index_in_dim(
-                cs, new.astype(cs.dtype).reshape(b, -1), line, 0)
-        return y, (kv, cs)
+        return y, (kv, _lines_keep(cs, zz, prior, line, write_mask))
 
     def attention(line, ap, xn, state):
         kv, cs = state
         q, k, v = lfm2.attention_heads(cfg, ap, xn, positions, inv_freq)
-        with tracing.part("cache"):
-            kv, _ = kv_row_write(kv, None, k, v, line, positions0,
-                                 write_mask, kmesh=kmesh)
-        o = decode_attention(q, kv, None, line, lengths, positions0,
-                             plan=plan, kmesh=kmesh)
-        o = o.transpose(0, 2, 1, 3).reshape(b, 1, -1)
+        kv, o = _lines_attend(kv, q, k, v, line, lengths, positions0,
+                              write_mask, plan, kmesh)
         return (o @ ap["wo"]).astype(xn.dtype), (kv, cs)
 
     x, cache, counts = _run(cfg, params, x, cache,
@@ -171,7 +216,62 @@ def _decode_impl(cfg: Lfm2Config, params, cache, tokens, positions0,
     return cache, lfm2.lm_head(cfg, params, x[:, 0], kmesh), counts
 
 
+def _mixed_impl(cfg: Lfm2Config, params, cache, tokens, positions0,
+                write_mask, chunk, kv_len, length, slot, kmesh=None):
+    """A decode step that carries a prefill chunk: ``prefill_chunk``'s
+    ``chunk`` [C] of ``slot`` (``write_mask`` false there, as between two
+    chunks) and ``_decode_impl``'s token a line, [1, C + B, H] through every
+    layer. The norms, the projections and the routed layer see all rows at
+    once (a routed layer's experts are fetched once for both: one
+    layer-step in its counts); the operators split them, the chunk's rows
+    to the chunk's halves and the lines' to the lines'. Returns (cache, the
+    lines' logits [B, V], counts): a riding chunk gives no token."""
+    c, b = chunk.shape[0], tokens.shape[0]
+    with tracing.part("embed"):
+        x = params["embed_tokens"][jnp.concatenate([chunk, tokens])][None]
+    with tracing.part("attn"):
+        positions = jnp.concatenate([kv_len + jnp.arange(c), positions0])
+        lengths = jnp.where(write_mask, positions0 + 1, 0)
+        valid = jnp.concatenate([positions[:c] < length, write_mask])[None]
+        inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta)
+        n_valid = jnp.clip(length - kv_len, 0, c)
+        plan = decode_plan_of(lengths, cache["kv"], kmesh=kmesh)
+
+    def conv(line, cp, xn, state):
+        kv, cs = state
+        z, gate_out = lfm2.conv_gates(cp, xn)
+        prior = _lines_prior(cfg, cs, line)
+        zz = lfm2.conv_window(_chunk_prior(cfg, cs, line, slot, kv_len),
+                              z[:, :c])
+        zz_lines = lfm2.conv_window(prior, z[0, c:, None])
+        v = jnp.concatenate([lfm2.conv_taps(cfg, cp, zz, c),
+                             lfm2.conv_taps(cfg, cp, zz_lines, 1)
+                             .reshape(1, b, -1)], axis=1)
+        # The lines' update writes every slot's row, the chunk's slot its
+        # old state: the chunk's comes after it.
+        cs = _lines_keep(cs, zz_lines, prior, line, write_mask)
+        cs = _chunk_keep(cfg, cs, zz, line, slot, n_valid)
+        return lfm2.conv_out(cp, gate_out, v, xn.dtype), (kv, cs)
+
+    def attention(line, ap, xn, state):
+        kv, cs = state
+        q, k, v = lfm2.attention_heads(cfg, ap, xn, positions, inv_freq)
+        kv, o = _chunk_attend(kv, q[:, :, :c], k[:, :, :c], v[:, :, :c],
+                              line, slot, kv_len, length, kmesh)
+        kv, o_lines = _lines_attend(
+            kv, *(a[0, :, c:].transpose(1, 0, 2)[:, :, None]
+                  for a in (q, k, v)),
+            line, lengths, positions0, write_mask, plan, kmesh)
+        o = jnp.concatenate([o, o_lines.reshape(1, b, -1)], axis=1)
+        return (o @ ap["wo"]).astype(xn.dtype), (kv, cs)
+
+    x, cache, counts = _run(cfg, params, x, cache,
+                            {CONV: conv, ATTENTION: attention}, valid, kmesh)
+    return cache, lfm2.lm_head(cfg, params, x[0, c:], kmesh), counts
+
+
 decode_step, decode_burst = token_step_programs(_decode_impl, MOE_COUNTERS)
+mixed_burst = mixed_burst_program(_decode_impl, _mixed_impl, MOE_COUNTERS)
 
 
 def _refuse(config) -> None:
@@ -207,4 +307,7 @@ SERVED = ServedModel(
     kv_handoff=False,
     prefix_from_line=False,
     refuse=_refuse,
+    # A chunk and a step are both bound by the experts' bytes: riding, a
+    # chunk's rows pass the routed layers on the step's fetch.
+    mixed_burst=mixed_burst,
 )

@@ -165,6 +165,15 @@ class ServedModel:
       over them. None where a model's author has written none: the engine
       then refuses a ``speculative_model`` with this model as target
       (no ``spec_verify_step``) or as draft (no ``draft_propose``);
+    - ``mixed_burst``: a burst whose steps may each carry one full,
+      non-final prefill chunk of a slot mid-prefill beside the decoding
+      lines (:func:`mixed_burst_program`, jitted under ``decode_burst``'s
+      name), so that what a chunk and a step both fetch is fetched once;
+      None: the scheduler alternates chunks and bursts. Where it is
+      offered ``stats()`` counts ``prefill_chunks_riding`` and
+      ``prefill_tokens_riding`` (of ``prefill_chunks`` and
+      ``prompt_tokens_prefilled``: those that rode a decode step). Refused
+      beside ``step``: a rider gives no token, a block step's prefill none;
     - ``refuse(config)``: raises ValueError for an ``LLMConfig`` it cannot
       serve (None: it serves them all)."""
 
@@ -184,8 +193,14 @@ class ServedModel:
     draft_propose: Callable | None = None
     spec_verify_step: Callable | None = None
     refuse: Callable | None = None
+    mixed_burst: Callable | None = None
 
     def __post_init__(self):
+        if self.mixed_burst is not None and self.step is not None:
+            raise ValueError(
+                "a ServedModel that states its step offers no mixed_burst: "
+                "a step that carries a chunk is a token a line, sampled "
+                "from the step's own logits")
         if (self.step is None) == (self.decode_step is None):
             raise ValueError(
                 "a ServedModel has a decode_step or states its step, one "
@@ -198,6 +213,15 @@ class ServedModel:
         """Whether a prompt's last chunk gives the first token: for every
         model but one that states its ``step``."""
         return self.step is None
+
+
+def _burst_sample(logits, temps, top_ps, key, j, need_top_p: bool):
+    """Step ``j``'s tokens of a burst: the sampler on the burst's key folded
+    with the step's index."""
+    with tracing.part("sample"):
+        return sample_tokens(logits, temps, top_ps, 0,
+                             jax.random.fold_in(key, j),
+                             need_top_p).astype(jnp.int32)
 
 
 def token_step_programs(step: Callable, counters: tuple[str, ...] = ()):
@@ -245,10 +269,8 @@ def token_step_programs(step: Callable, counters: tuple[str, ...] = ()):
         def tick(carry, j):
             c, tok, pos, *counts = carry
             c, logits, *n = step(cfg, params, c, tok, pos, write_mask, kmesh)
+            nxt = _burst_sample(logits, temps, top_ps, key, j, need_top_p)
             with tracing.part("sample"):
-                nxt = sample_tokens(logits, temps, top_ps, 0,
-                                    jax.random.fold_in(key, j),
-                                    need_top_p).astype(jnp.int32)
                 return (c, nxt, pos + 1,
                         *(a + b for a, b in zip(counts, n))), nxt
 
@@ -260,6 +282,68 @@ def token_step_programs(step: Callable, counters: tuple[str, ...] = ()):
         return (cache, toks, *counts)
 
     return decode_step, decode_burst
+
+
+def mixed_burst_program(step: Callable, mixed_step: Callable,
+                        counters: tuple[str, ...] = ()):
+    """``ServedModel.mixed_burst`` of a model whose step is a token in and a
+    token out: ``decode_burst`` (of :func:`token_step_programs`, the same
+    sampler on the same keys) whose first ``n`` steps each carry a prefill
+    chunk too, built from the model's ``step`` and from
+
+        mixed_step(cfg, params, cache, tokens [B], positions [B],
+                   write_mask [B], chunk [C], kv_len, length, slot, kmesh)
+            -> (cache, logits [B, V][, counts])
+
+    which runs the chunk's C rows (``prefill_chunk``'s arguments; the slot
+    has ``write_mask`` false) and the lines' B through every layer as one
+    array and gives the lines' logits alone. The program takes, after
+    ``decode_burst``'s arguments up to ``key``, ``riders``: (chunks
+    [steps, C], slots [steps], kv_lens [steps], lengths [steps], n), each
+    step's chunk with its own run-time scalars, so that consecutive chunks
+    of one prompt or chunks of several ride one burst; then ``steps`` and
+    ``need_top_p``, static. Steps ``n`` to ``steps`` are ``step``'s: two
+    loops with run-time bounds, one compiled shape a burst length whatever
+    ``n``. It is jitted under the name ``decode_burst``: a device trace is
+    read by program name, and these are decode steps (a reader that divides
+    ``jit_decode_burst``'s time by the steps the dispatch phase carried
+    keeps a true number)."""
+
+    @partial(jax.jit, static_argnums=(0, 10, 11), static_argnames=("kmesh",),
+             donate_argnums=(2,))
+    def decode_burst(cfg, params, cache, token0, positions0, write_mask,
+                     temps, top_ps, key, riders, steps: int,
+                     need_top_p: bool = True, *,
+                     kmesh: KernelMesh | None = None):
+        chunks, slots, kv_lens, lengths, n = riders
+
+        def tick(j, carry, riding: bool):
+            c, tok, pos, toks, *counts = carry
+            if riding:
+                c, logits, *m = mixed_step(
+                    cfg, params, c, tok, pos, write_mask, chunks[j],
+                    kv_lens[j], lengths[j], slots[j], kmesh)
+            else:
+                c, logits, *m = step(cfg, params, c, tok, pos, write_mask,
+                                     kmesh)
+            nxt = _burst_sample(logits, temps, top_ps, key, j, need_top_p)
+            with tracing.part("sample"):
+                toks = lax.dynamic_update_index_in_dim(toks, nxt, j, 0)
+                return (c, nxt, pos + 1, toks,
+                        *(a + b for a, b in zip(counts, m)))
+
+        zero = ((jnp.zeros((len(counters),), jnp.int32),) if counters
+                else ())
+        carry = (cache, token0, positions0,
+                 jnp.zeros((steps, *token0.shape), jnp.int32), *zero)
+        with tracing.part("stack"):
+            carry = lax.fori_loop(0, n, partial(tick, riding=True), carry)
+            carry = lax.fori_loop(n, steps, partial(tick, riding=False),
+                                  carry)
+        cache, _, _, toks, *counts = carry
+        return (cache, toks, *counts)
+
+    return decode_burst
 
 
 def served_model(cfg) -> ServedModel:

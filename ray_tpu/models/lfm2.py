@@ -328,24 +328,52 @@ def init_params(cfg: Lfm2Config, key: jax.Array) -> dict:
 
 # ---------------------------------------------------------------- blocks
 
+def conv_gates(cp: dict, xn):
+    """The convolution's projection in, on xn [..., H] (normed) -> (z,
+    gate_out): the gated input, whose last rows a sequence keeps as its
+    state, and the gate on the output."""
+    with tracing.part("conv"):
+        gate_in, gate_out, x = jnp.split(xn @ cp["conv_in"], 3, axis=-1)
+        return gate_in * x, gate_out
+
+
+def conv_window(prior, z):
+    """``prior`` [B, conv_L_cache - 1, H] and then the rows ``z`` [B, S, H]
+    of this call: what the taps slide over, and what the state is cut
+    from."""
+    with tracing.part("conv_state"):
+        return jnp.concatenate([prior.astype(z.dtype), z], axis=1)
+
+
+def conv_taps(cfg: Lfm2Config, cp: dict, zz, s: int):
+    """The depthwise causal convolution over ``zz`` [B, conv_L_cache - 1 +
+    S, H] at its last ``s`` positions -> [B, S, H]."""
+    with tracing.part("conv"):
+        taps = cp["conv_w"].astype(jnp.float32)              # [taps, H]
+        v = sum(taps[j] * zz[:, j:j + s].astype(jnp.float32)
+                for j in range(cfg.conv_L_cache))
+        return v.astype(zz.dtype)
+
+
+def conv_out(cp: dict, gate_out, v, dtype):
+    """The gate on the taps' output and the projection out."""
+    with tracing.part("conv"):
+        return ((gate_out * v) @ cp["conv_out"]).astype(dtype)
+
+
 def short_conv(cfg: Lfm2Config, cp: dict, xn, prior):
     """The gated short convolution on xn [B, S, H] (normed) after
     ``prior`` [B, conv_L_cache - 1, H], the rows of ``z`` before the first
     position (zeros at a sequence's start). Returns (y [B, S, H], zz
     [B, conv_L_cache - 1 + S, H]): ``prior`` and then this call's rows of
-    ``z``, of which the caller keeps its state."""
-    s = xn.shape[1]
-    with tracing.part("conv"):
-        gate_in, gate_out, x = jnp.split(xn @ cp["conv_in"], 3, axis=-1)
-        z = gate_in * x
-    with tracing.part("conv_state"):
-        zz = jnp.concatenate([prior.astype(z.dtype), z], axis=1)
-    with tracing.part("conv"):
-        taps = cp["conv_w"].astype(jnp.float32)              # [taps, H]
-        v = sum(taps[j] * zz[:, j:j + s].astype(jnp.float32)
-                for j in range(cfg.conv_L_cache))
-        return ((gate_out * v.astype(z.dtype)) @ cp["conv_out"]
-                ).astype(xn.dtype), zz
+    ``z``, of which the caller keeps its state. Its four pieces are
+    functions of their own for a program whose rows are of several
+    sequences (llm/lfm2_serving.py's mixed step): the projections take
+    all rows at once, the window and the taps a sequence's."""
+    z, gate_out = conv_gates(cp, xn)
+    zz = conv_window(prior, z)
+    v = conv_taps(cfg, cp, zz, xn.shape[1])
+    return conv_out(cp, gate_out, v, xn.dtype), zz
 
 
 def attention_heads(cfg: Lfm2Config, ap: dict, xn, positions, inv_freq):

@@ -1022,6 +1022,71 @@ class TestBurstDecoding:
             eng.shutdown()
 
 
+def _dispatches(config: LLMConfig):
+    """(the programs an engine dispatches, in order, each with its chunk's
+    bucket or its burst's steps; the requests' tokens; stats()) for one
+    script: a line decodes, then two long prompts arrive beside it. The
+    scheduler's thread is stopped and the ticks are made by hand, so the
+    sequence is the schedule's alone."""
+    eng = LLMEngine(config)
+    eng.shutdown()
+    calls = []
+
+    def counted(name, size):
+        program = getattr(eng.model, name)
+
+        def call(*args, **kw):
+            calls.append((name, size(args)))
+            return program(*args, **kw)
+        return call
+
+    eng.model = dataclasses.replace(
+        eng.model,
+        prefill_chunk=counted("prefill_chunk", lambda a: a[3].shape[0]),
+        decode_step=counted("decode_step", lambda a: 1),
+        decode_burst=counted("decode_burst", lambda a: a[9]))
+    reqs = [eng.submit(list(range(260, 280)), SamplingParams(max_tokens=30))]
+    while not reqs[0].out_tokens:
+        eng._tick()
+    reqs += [eng.submit(list(range(270, 270 + n)),
+                        SamplingParams(max_tokens=m))
+             for n, m in ((100, 6), (64, 9))]
+    for _ in range(200):
+        if all(r.done.is_set() for r in reqs):
+            break
+        eng._tick()
+    assert all(r.done.is_set() and not r.error for r in reqs)
+    return calls, [r.out_tokens for r in reqs], eng.stats()
+
+
+# What the scheduler dispatched for that script before a served model could
+# offer ``mixed_burst`` (recorded on the commit before it, PR 46's).
+_CHUNK, _BURST, _STEP = "prefill_chunk", "decode_burst", "decode_step"
+_DISPATCHED_BEFORE = {
+    True: [(_CHUNK, 32), (_BURST, 4), (_BURST, 4)] + [
+        (_CHUNK, 32), (_CHUNK, 32), (_BURST, 4)] * 2 + [
+        (_CHUNK, 32), (_CHUNK, 16)] + [(_BURST, 4)] * 3 + [(_STEP, 1)],
+    False: [(_CHUNK, 32), (_BURST, 4)] + [
+        (_CHUNK, 32), (_CHUNK, 32), (_BURST, 4)] * 2 + [
+        (_CHUNK, 32), (_CHUNK, 16)] + [(_BURST, 4)] * 4 + [(_STEP, 1)],
+}
+
+
+@pytest.mark.parametrize("pipeline", [True, False],
+                         ids=["look-ahead", "serial"])
+def test_a_model_without_a_mixed_burst_is_scheduled_as_before(pipeline):
+    """The tiny Llama offers no ``mixed_burst``: call for call the chunks,
+    bursts and steps it was given before the scheduler knew of one, no
+    chunk counted as riding."""
+    calls, _, stats = _dispatches(LLMConfig(
+        model="tiny", max_num_seqs=3, max_seq_len=256, prefill_chunk=32,
+        decode_burst=4, prefill_chunks_per_tick=1, decode_pipeline=pipeline))
+    assert calls == _DISPATCHED_BEFORE[pipeline]
+    assert stats["prefill_chunks"] == 7
+    assert stats["prefill_chunks_riding"] == 0
+    assert stats["prefill_tokens_riding"] == 0
+
+
 def test_hf_checkpoint_conversion_numerical_parity(tmp_path):
     """convert_hf_llama vs the transformers reference implementation:
     identical logits on a tiny random-init HF Llama (layout transposes,

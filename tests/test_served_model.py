@@ -296,6 +296,52 @@ def test_a_step_and_a_single_step_program_exclude_each_other(mix):
         dc_replace(llama_serving.SERVED, prefill_token=False)
 
 
+@pytest.mark.parametrize("model", **ALL_MODELS)
+def test_a_mixed_burst_is_offered_by_a_token_a_step_model_or_by_none(model):
+    """``mixed_burst`` is the contract's one optional program. A model that
+    states its ``step`` is refused one where its ServedModel is built (a
+    step that carries a chunk is a token a line); one that offers it keeps
+    ``decode_burst``'s name for it, which a device trace is read by, and
+    takes the cache donated and gives it back; the others' engines never
+    let a chunk ride (``_ride_steps`` 0)."""
+    from dataclasses import replace as dc_replace
+
+    module, cfg = model()
+    served = served_model(cfg)
+    eng = LLMEngine(LLMConfig(model=cfg, max_num_seqs=SLOTS,
+                              max_seq_len=MAX_SEQ, prefill_chunk=CHUNK,
+                              decode_burst=4))
+    eng.shutdown()
+    if served.step is not None:
+        assert served.mixed_burst is None and eng._ride_steps == 0
+        with pytest.raises(ValueError, match="offers no mixed_burst"):
+            dc_replace(served, mixed_burst=lfm2_serving.mixed_burst)
+        return
+    # nothing else refuses the entry: a token-a-step model may offer one
+    assert dc_replace(served, mixed_burst=lfm2_serving.mixed_burst)
+    if served.mixed_burst is None:
+        assert eng._ride_steps == 0
+        return
+    assert served.mixed_burst is module.mixed_burst
+    assert (eng._ride_steps, eng._ride_rows) == (4, CHUNK)
+    params = served.init_params(cfg, jax.random.PRNGKey(0))
+    cache = served.init_cache(cfg, SLOTS, MAX_SEQ)
+    went_in = jax.tree.map(lambda a: (a.shape, a.dtype), cache)
+    i32 = jnp.int32
+    riders = (jnp.zeros((2, CHUNK), i32), jnp.array([2, 2], i32),
+              jnp.array([0, CHUNK], i32), jnp.full((2,), 3 * CHUNK, i32),
+              i32(2))
+    args = (cfg, params, cache, *_arguments("decode_burst", params)[1:-2],
+            riders, 2, False)
+    named = re.search(r"module @(\w+)",
+                      served.mixed_burst.lower(*args).as_text()).group(1)
+    assert named == "jit_decode_burst"
+    came_back, toks, counts = served.mixed_burst(*args)
+    assert toks.shape == (2, SLOTS) and counts.shape == (len(served.counters),)
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), came_back) == went_in
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(cache))
+
+
 def test_a_model_may_say_its_step_is_a_block_and_its_prefill_gives_no_token():
     """SDAR's: 4 positions by 5 forwards. Its two programs keep the names a
     trace is read by and give the donated cache back; the prefill's logits
