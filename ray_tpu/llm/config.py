@@ -17,6 +17,7 @@ from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
 from ray_tpu.models.ouro import OuroConfig
+from ray_tpu.models.qwen3_next import Qwen3NextConfig
 from ray_tpu.models.sdar import SdarConfig
 
 # The served models: a model's own configuration, which is what
@@ -31,6 +32,7 @@ SERVING_MODULES = {
     Lfm2Config: "ray_tpu.llm.lfm2_serving",
     SdarConfig: "ray_tpu.llm.sdar_serving",
     DeepseekV2Config: "ray_tpu.llm.deepseek_serving",
+    Qwen3NextConfig: "ray_tpu.llm.qwen3_next_serving",
 }
 ModelConfig = Union[tuple(SERVING_MODULES)]
 

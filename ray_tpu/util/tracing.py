@@ -708,6 +708,13 @@ SUBPARTS = (
     "latent_prefill",  # a prefill chunk's latent attention inside ``attn``:
                      # the up-projection of the line's live blocks and the
                      # running softmax over them
+    "linear_attn",   # a linear-attention operator inside ``attn``: its
+                     # projections, the convolution, the gates, the output
+                     # norm and projection
+    "delta_rule",    # inside it, the gated delta rule alone: the chunked
+                     # form of a prefill chunk or a decode step's one token
+    "linear_state",  # reading and writing that operator's state and its
+                     # convolution's window
 )
 
 

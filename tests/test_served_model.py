@@ -23,7 +23,7 @@ import pytest
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
 from ray_tpu.llm import deepseek_serving, lfm2_serving, llama_serving
 from ray_tpu.llm import longcat_serving
-from ray_tpu.llm import ouro_serving, sdar_serving
+from ray_tpu.llm import ouro_serving, qwen3_next_serving, sdar_serving
 from ray_tpu.llm.config import SERVING_MODULES, ModelConfig
 from ray_tpu.llm.served import ServedModel, served_model
 from ray_tpu.models.deepseek import DeepseekV2Config
@@ -31,6 +31,7 @@ from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
 from ray_tpu.models.ouro import OuroConfig
+from ray_tpu.models.qwen3_next import Qwen3NextConfig
 from ray_tpu.models.sdar import SdarConfig
 
 SLOTS, MAX_SEQ, CHUNK = 3, 64, 16
@@ -63,9 +64,16 @@ def _deepseek():
                                                    max_seq_len=MAX_SEQ)
 
 
+def _qwen3_next():
+    return qwen3_next_serving, Qwen3NextConfig.tiny(expert_shards=2,
+                                                    max_seq_len=MAX_SEQ)
+
+
 # The models of a token a step, and all of them.
-MODELS = dict(argvalues=[_llama, _longcat, _ouro, _lfm2, _deepseek],
-              ids=["llama", "longcat", "ouro", "lfm2", "deepseek"])
+MODELS = dict(argvalues=[_llama, _longcat, _ouro, _lfm2, _deepseek,
+                         _qwen3_next],
+              ids=["llama", "longcat", "ouro", "lfm2", "deepseek",
+                   "qwen3_next"])
 ALL_MODELS = dict(argvalues=MODELS["argvalues"] + [_sdar],
                   ids=MODELS["ids"] + ["sdar"])
 

@@ -779,3 +779,78 @@ def test_deepseek_programs_copy_no_cache_nor_stacked_leaf_and_fit_the_chip(
                   "wo"):
         assert _opcodes_with_shape(text, big[shape]) <= \
             carried | {"custom-call"}, shape
+
+
+# Qwen3-Next's first 16 layers (12 Gated DeltaNet, 4 gated attentions) at the
+# published widths with 64 of 512 experts, at the shapes of its serving cell
+# (16 slots x 32,768): the programs of llm/qwen3_next_serving.py as the cell
+# compiles them.
+@pytest.mark.parametrize("program", ["prefill_chunk(512)", "decode_burst(8)"])
+def test_qwen3_next_programs_copy_no_state_nor_expert_stack_and_fit_the_chip(
+        mosaic, program):
+    """Three kinds of cache leaf ride every loop as carry: the attention
+    lines (keys and values at heads of 256), the gated delta rule's state
+    (float32, 2 MiB a slot and layer: 0.375 GiB in all) and the
+    convolutions' windows. None, nor a stacked leaf of the experts or of the
+    operators, is the result of anything but a parameter, a loop's tuple, a
+    kernel's in-place operand or an update in place (the finding of PR 27: a
+    stacked leaf indexed by a loop's counter is copied whole unless it is
+    indexed where it is used): a decode step writes a line of the state by a
+    ``dynamic-update-slice`` into the leaf, the sum of the decayed state and
+    the correction fused into it. Arguments and temporaries are what
+    benchmark/configs/qwen3-next-80b-a3b.json states under ``reduced``."""
+    from devbench import qwen3_next_bench as bench
+
+    cfg = bench.config()
+    assert (cfg.num_layers, cfg.linear_lines, cfg.attention_lines,
+            cfg.experts_held, cfg.router_rule.outputs, cfg.head_dim) == \
+        (16, 12, 4, 64, 512, 256)
+    mem, text, _ = bench.compile_programs(cfg, only=program)[program]
+    carried = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30
+    assert 11.6 < mem.argument_size_in_bytes / 2 ** 30 < 11.7
+    assert total < 15.75 - 0.7
+    if program.startswith("prefill"):
+        kernels = ("prefill_attention", "moe_grouped_matmul")
+        # 512 x 10 picks over 512 outputs are 10 rows an expert: tiles of 32
+        assert _grouped_matmul_rows(text) == {(5120 // 32 + 64) * 32}
+        assert mem.temp_size_in_bytes < 1 << 27
+    else:
+        kernels = ("decode_attention", "kv_row_write", "moe_grouped_matmul")
+        assert _plans_outside_the_layer_loop(text)
+        # 16 x 10 picks: 0.3 rows an expert, tiles of 16
+        assert _grouped_matmul_rows(text) == {(160 // 16 + 64) * 16}
+        assert mem.temp_size_in_bytes < 1 << 25
+    for name in kernels:
+        assert f'"{name}"' in text or f"%{name}." in text, name
+    big = bench.big_shapes(cfg)
+    # float32, as the configuration's departures.state_dtype states it: the
+    # benchmark's comparison cannot tell a bfloat16 state from a sound run
+    # (PERF.md section 7), so the compiled program is held to it here
+    assert big["state"] == "f32[12,16,32,128,128]"
+    assert "parameter" in _opcodes_with_shape(text, big["state"])
+    # and the router to its router_dtype: float32 weights, the product
+    # float32 at true float32 (a TPU's default float32 product is one bfloat16 pass)
+    assert "parameter" in _opcodes_with_shape(
+        text, f"f32[{cfg.num_layers},{cfg.hidden_size},512]")
+    routes = [line for line in text.splitlines()
+              if "moe_route/dot_general" in line
+              and re.search(r" (convolution|dot)\(", line)]
+    assert routes and all(
+        re.search(r"= f32\[\d+,512\]", line)
+        and "operand_precision={highest,highest}" in line
+        for line in routes), routes[:1]
+    assert _opcodes_with_shape(text, big["lines"]) <= \
+        carried | {"dynamic-update-slice", "custom-call"}
+    # the state: read a line (a fusion's parameter), written in place (an
+    # update alone or with the sum fused into it)
+    assert _opcodes_with_shape(text, big["state"]) <= \
+        carried | {"dynamic-update-slice", "fusion"}
+    for line in text.splitlines():
+        head = line.split(" = ", 1)
+        if len(head) == 2 and big["state"] in head[1].split("(", 1)[0] \
+                and " fusion(" in head[1]:
+            assert "dynamic-update-slice_fusion" in head[0], line[:200]
+    for shape in ("we_in", "we_down", "in_qkvz", "wq"):
+        assert _opcodes_with_shape(text, big[shape]) <= \
+            carried | {"custom-call"}, shape

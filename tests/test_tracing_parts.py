@@ -25,7 +25,7 @@ from ray_tpu.util import tracing
 # The tiny served models and the programs' arguments, as the engine's own
 # contract test builds them.
 from test_served_model import (MAX_SEQ, SLOTS, _arguments, _deepseek, _lfm2,
-                               _llama, _longcat, _ouro)
+                               _llama, _longcat, _ouro, _qwen3_next)
 
 # What JAX itself puts on a name stack besides primitives' names.
 WRAPPERS = {"transpose", "jvp", "vmap", "pmap", "jit", "pjit", "while",
@@ -47,7 +47,8 @@ def test_the_finer_names_are_a_vocabulary_of_their_own():
     benchmark's partition (``xplane_meta.PARTS == tracing.PARTS``) does not
     know them and books their operations to the part around them."""
     assert tracing.SUBPARTS == ("conv", "conv_state", "moe_shared",
-                                "latent_prefill")
+                                "latent_prefill", "linear_attn",
+                                "delta_rule", "linear_state")
     assert not set(tracing.SUBPARTS) & set(tracing.PARTS)
     assert all(re.fullmatch(r"[a-z_]+", p) for p in tracing.SUBPARTS)
     for name in tracing.SUBPARTS:
@@ -102,6 +103,7 @@ SERVED = {
     "ouro": (_ouro, DENSE | {"loop"}),
     "lfm2": (_lfm2, DENSE | ROUTED),
     "deepseek": (_deepseek, DENSE | ROUTED),
+    "qwen3_next": (_qwen3_next, DENSE | ROUTED),
 }
 LONGCAT_GONE = ("longcat.mla", "longcat.moe", "longcat.moe.experts")
 
@@ -139,6 +141,16 @@ def test_a_serving_program_opens_its_parts(model, program):
         assert bool(re.search(r"attn/latent_prefill/", text)) == (
             program == "prefill_chunk")
         assert not re.search(r"[^/\w](moe_shared|latent_prefill)/", text)
+    if model == "qwen3_next":
+        # The linear-attention operator's finer names lie inside ``attn``,
+        # the rule alone inside ``linear_attn``; the gated shared expert
+        # inside ``mlp``.
+        assert re.search(r"attn/linear_attn/dot_general", text)
+        assert re.search(r"attn/linear_attn/delta_rule/", text)
+        assert re.search(r"attn/linear_state/", text)
+        assert re.search(r"mlp/moe_shared/dot_general", text)
+        assert not re.search(
+            r"[^/\w](linear_attn|delta_rule|linear_state|moe_shared)/", text)
 
 
 def _train_step(name):
