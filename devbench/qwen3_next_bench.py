@@ -15,15 +15,18 @@ temporaries, their sum against the chip's 15.75 GiB), the Mosaic calls, and
 every instruction whose result has the shape of a cache leaf or of a
 stacked leaf of the experts, by opcode. ``rule``: the gated delta rule
 alone (``ops/gated_delta.py``) at the cell's shapes: the chunked form on 512
-rows x 32 heads and the recurrence it stands in for (the forms that were
-tried and dropped, forward substitution a row at a time, products at the
-default precision and sub-chunks of 32, are in PERF.md section 5 with their
-times); the step on 16 slots x 32 heads reading one line of the stacked state
-leaf in place; milliseconds a call and each one's share of the yardstick
-(``adapters/qwen3_next.delta_rule_token_work`` and ``linear_step_bytes``
-over the chip's peaks). ``step``: wall milliseconds of one decode step
-inside a burst of 8 at 16 lines of 4,096, 12,288 and 30,720 live positions
-and of a prefill chunk of 512 against 0 to 30,720 cached rows (the clock
+rows x 32 heads (16 key heads) from a carried state, three ways: the kernel
+(what ``gated_delta_chunk`` is on a TPU), the jnp body it is held to
+(``gated_delta_chunk_reference``) and the recurrence both stand in for, each
+one's device time a call (32 calls inside one program, each from the state
+the last left), its share of the yardstick and its largest difference from
+the recurrence on outputs and on states, and the kernel again at 2, 4 and 16
+value heads a grid step beside its own 8; then the step on 16 slots x
+32 heads reading one line of the stacked state leaf in place; the
+yardsticks are ``adapters/qwen3_next.delta_rule_token_work`` and
+``linear_step_bytes`` over the chip's peaks. ``step``: wall milliseconds
+of one decode step inside a burst of 8 at 16 lines of 4,096, 12,288 and
+30,720 live positions and of a prefill chunk of 512 against 0 to 30,720 cached rows (the clock
 stops on a host read of the result). ``margins``: the serving programs in
 bfloat16, teacher-forced, against ``benchmark/reference/qwen3_next.py`` on
 the same weights, with the routed experts' output at zero and at the seeded
@@ -52,6 +55,11 @@ from devbench.lfm2_bench import GIB, opcodes_with_shape  # noqa: E402
 from devbench.longcat_bench import timed  # noqa: E402
 
 SLOTS, MAX_SEQ, CHUNK = 16, 32768, 512
+# ``rule``: calls of a form inside one timed program, and the value heads a
+# grid step that the chunk kernel is timed at beside its own
+# (``ops/gated_delta._heads_a_step``).
+CALLS = 32
+FIT = (2, 4, 16)
 # The script's overrides (``__main__`` reads them from the environment).
 LAYERS: int | None = None
 CASES: list[str] | None = None
@@ -188,9 +196,9 @@ def rule() -> dict:
     ks = jax.random.split(jax.random.PRNGKey(0), 8)
     unit = lambda x: x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True))  # noqa: E731
 
-    def inputs(rows):
-        q = unit(jax.random.normal(ks[0], (rows, nv, dk))) * dk ** -0.5
-        k = unit(jax.random.normal(ks[1], (rows, nv, dk)))
+    def inputs(rows, key_heads):
+        q = unit(jax.random.normal(ks[0], (rows, key_heads, dk))) * dk ** -0.5
+        k = unit(jax.random.normal(ks[1], (rows, key_heads, dk)))
         v = jax.random.normal(ks[2], (rows, nv, dv))
         g = -jnp.exp(jax.random.uniform(ks[3], (rows, nv), minval=-7.0,
                                         maxval=-0.4))
@@ -204,25 +212,53 @@ def rule() -> dict:
            "rows": CHUNK, "heads": nv, "slots": SLOTS,
            "chunk_least_us": round(least_chunk * 1e6, 2), "chunk": [],
            "step": []}
-    a = inputs(CHUNK)
+    # the chunk takes a key head once for its value heads, as the prefill
+    # program hands them over; the step a key head a value head
+    a = inputs(CHUNK, cfg.linear_num_key_heads)
     s0 = jax.random.normal(ks[5], (nv, dk, dv))
     want_o, want_s = jax.jit(gd.gated_delta_recurrence)(*a, s0)
-    forms = {"chunk": gd.gated_delta_chunk,
-             "recurrence": gd.gated_delta_recurrence}
-    for name, form in forms.items():
-        fn = jax.jit(form)
-        sec = timed(lambda: fn(*a, s0), 20)
-        o, s1 = fn(*a, s0)
+
+    def chunk_row(name, form, **more):
+        # Device time: ``CALLS`` calls in one program, each from the state
+        # the last left, so the host dispatches once (a call a dispatch
+        # read 0.40 ms for every form under 0.4: the host's time); the
+        # carry reaches ``g`` and ``k`` (plus a number that flushes to
+        # zero), so nothing of a call is the loop's invariant.
+        def calls(q, k, v, g, beta, s):
+            def body(_, carry):
+                nought = carry[1][0, 0, 0] * 1e-38
+                o, s1 = form(q, k + nought, v, g + nought, beta, carry[1])
+                return carry[0] + o, s1
+            return lax.fori_loop(0, CALLS, body, (jnp.zeros_like(v), s))
+
+        fn = jax.jit(calls)
+        sec = timed(lambda: fn(*a, s0), 5) / CALLS
+        o, s1 = jax.jit(form)(*a, s0)
         out["chunk"].append({
-            "form": name, "ms": round(sec * 1e3, 3),
+            "form": name, **more, "ms": round(sec * 1e3, 4),
             "roofline_pct": round(100 * least_chunk / sec, 2),
             "max_err_o": float(jnp.abs(o - want_o).max()),
             "max_err_state": float(jnp.abs(s1 - want_s).max())})
+
+    # ``gated_delta_chunk`` is the kernel on a TPU; the reference body is
+    # the jnp form it is held to, the recurrence what both stand in for.
+    chunk_row("kernel", gd.gated_delta_chunk,
+              heads_a_step=gd._heads_a_step(nv))
+    chunk_row("reference_body", gd.gated_delta_chunk_reference)
+    chunk_row("recurrence", gd.gated_delta_recurrence)
+    # The kernel's fit: value heads a grid step, each in the rule's place.
+    rule_of_heads = gd._heads_a_step
+    for heads in FIT:
+        gd._heads_a_step = lambda h, n=heads: n
+        jax.clear_caches()
+        chunk_row("kernel", gd.gated_delta_chunk, heads_a_step=heads)
+    gd._heads_a_step = rule_of_heads
+    jax.clear_caches()
     # The step on one line of the stacked leaf, as the decode program has
     # it: the leaf is donated and updated in place.
     lines = cfg.linear_lines
     state = jax.random.normal(ks[6], (lines, SLOTS, nv, dk, dv))
-    b = inputs(SLOTS)
+    b = inputs(SLOTS, nv)
     least_step = adapter.linear_step_bytes(cj, SLOTS) \
         / peaks["hbm_bytes_per_s"]
 

@@ -129,7 +129,7 @@ def prefill_chunk(cfg: Qwen3NextConfig, params, cache, tokens, kv_len,
                 st, (line, slot, 0, 0, 0), (1, 1, *st.shape[2:])), 0.0)
         window = qwen3_next.conv_window(
             prior.reshape(1, keep, cfg.conv_dim), mixed)
-        q, k, v = qwen3_next.linear_heads(cfg, lp, window, c)
+        q, k, v = qwen3_next.linear_key_heads(cfg, lp, window, c)
         with tracing.part("linear_attn"), tracing.part("delta_rule"):
             # A padded row decays nothing and corrects nothing.
             o, s1 = gated_delta_chunk(

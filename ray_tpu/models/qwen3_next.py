@@ -378,12 +378,12 @@ def conv_window(prior, mixed):
         return jnp.concatenate([prior.astype(mixed.dtype), mixed], axis=1)
 
 
-def linear_heads(cfg: Qwen3NextConfig, lp: dict, window, s: int):
+def linear_key_heads(cfg: Qwen3NextConfig, lp: dict, window, s: int):
     """The depthwise causal convolution over ``window`` [B, taps - 1 + S,
     conv_dim] at its last ``s`` positions, ``silu``, and the split into
-    heads: q, k [B, S, value heads, Dk] float32 (a key head's repeated for
-    its value heads, L2-normalised, the query scaled), v [B, S, value heads,
-    Dv]."""
+    heads: q, k [B, S, key heads, Dk] float32 (L2-normalised, the query
+    scaled), v [B, S, value heads, Dv]. A key head serves ``value heads //
+    key heads`` value heads in a row."""
     with tracing.part("linear_attn"):
         b = window.shape[0]
         taps = lp["conv_w"].astype(jnp.float32)          # [taps, conv_dim]
@@ -392,16 +392,24 @@ def linear_heads(cfg: Qwen3NextConfig, lp: dict, window, s: int):
             for j in range(cfg.linear_conv_kernel_dim)))
         q, k, v = jnp.split(mixed, (cfg.key_dim, 2 * cfg.key_dim), axis=-1)
         nk, dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
-        nv = cfg.linear_num_value_heads
 
         def unit(x):
             x = x.reshape(b, s, nk, dk)
-            x = x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
-                              + L2_EPS)
-            return jnp.repeat(x, nv // nk, axis=2)
+            return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                                 + L2_EPS)
 
         return (unit(q) * dk ** -0.5, unit(k),
-                v.reshape(b, s, nv, cfg.linear_value_head_dim))
+                v.reshape(b, s, cfg.linear_num_value_heads,
+                          cfg.linear_value_head_dim))
+
+
+def linear_heads(cfg: Qwen3NextConfig, lp: dict, window, s: int):
+    """:func:`linear_key_heads` with a key head's q and k repeated for its
+    value heads, [B, S, value heads, Dk]: what the one-token step takes."""
+    q, k, v = linear_key_heads(cfg, lp, window, s)
+    with tracing.part("linear_attn"):
+        rep = cfg.linear_num_value_heads // cfg.linear_num_key_heads
+        return jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2), v
 
 
 def linear_output(cfg: Qwen3NextConfig, lp: dict, o, z, dtype):
@@ -543,7 +551,7 @@ def forward(cfg: Qwen3NextConfig, params: dict, tokens, *,
         mixed, z, g, beta = linear_inputs(cfg, lp, xn)
         prior = jnp.zeros((b, cfg.linear_conv_kernel_dim - 1, cfg.conv_dim),
                           xn.dtype)
-        q, k, v = linear_heads(cfg, lp, conv_window(prior, mixed), s)
+        q, k, v = linear_key_heads(cfg, lp, conv_window(prior, mixed), s)
         zero = jnp.zeros((cfg.linear_num_value_heads,
                           cfg.linear_key_head_dim,
                           cfg.linear_value_head_dim), jnp.float32)

@@ -11,6 +11,10 @@ query scaled), ``v_t``, a log-decay ``g_t <= 0`` and a step ``beta_t`` in
     S_t = S' + k_t d_t^T
     o_t = S_t^T q_t
 
+``q`` and ``k`` are a key head's, and a key head serves ``H // Hk`` value
+heads in a row (two in Qwen3-Next): the recurrence and the chunked form take
+them once a key head, the one-token step repeated a value head.
+
 Three forms of it:
 
 - :func:`gated_delta_recurrence`: those four lines under a ``lax.scan`` over
@@ -26,17 +30,21 @@ Three forms of it:
       (I + A) D = beta (V - diag(exp G) K S_0)
 
   ``A`` is strictly lower, and ``T = (I + A)^-1`` is made by halves
-  (:func:`unit_lower_inverse`: six levels of small products at 64, of all
-  heads and sub-chunks at once). ``T`` does not depend on the state, so ``U = T (beta V)`` and ``W = T
-  (beta exp(G) K)`` are made for every sub-chunk at once, and the walk from
-  sub-chunk to sub-chunk is three products::
+  (:func:`unit_lower_inverse`: forward substitution a block at a time, six
+  levels at 64). From sub-chunk to sub-chunk::
 
-      D   = U - W S_0
+      D   = T beta (V - diag(exp G) K S_0)
       O   = (exp(G) Q) S_0 + (tril(Q K^T) exp(G_i - G_j)) D
       S_C = exp(G_C) S_0 + (exp(G_C - G) K)^T D
 
   Every exponent is a difference ``G_i - G_j`` with ``j <= i``, at most 0:
-  nothing overflows however long the sub-chunk's decay.
+  nothing overflows however long the sub-chunk's decay. Two
+  implementations, chosen by ``ops/kernels.kernel_backend()``: on a TPU a
+  Pallas kernel (:func:`_chunk_kernel`) whose grid is (value heads, eight
+  a step) by (sub-chunks), in which a sub-chunk's system, its inverse and
+  the state stay in VMEM and the operands are read once, as 128-lane
+  columns of the caller's ``[T, H * D]``; elsewhere, and as what the kernel
+  is held to, :func:`gated_delta_chunk_reference` in plain jnp.
 - :func:`gated_delta_step`: one position of every slot, the recurrence's
   single step on ``[slots, heads]`` states at once. The state is read by
   one pass that gives both ``S^T k`` and ``S^T q`` (``o_t = exp(g) S^T q +
@@ -54,8 +62,13 @@ on states, and the rule's work is small beside the layer's projections.
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ray_tpu.ops.kernels import kernel_backend
 
 # Positions of a sub-chunk: the triangular system's size. 64 is the
 # published kernels' choice and half an MXU's side.
@@ -68,10 +81,21 @@ def _mm(a, b):
     return jnp.matmul(a, b, precision=PRECISION)
 
 
+def _a_value_head(q, k, heads: int):
+    """q, k [T, Hk, Dk] of the key heads, each for the ``heads // Hk`` value
+    heads in a row that it serves: [T, heads, Dk]."""
+    rep = heads // q.shape[1]
+    if rep == 1:
+        return q, k
+    return jnp.repeat(q, rep, axis=1), jnp.repeat(k, rep, axis=1)
+
+
 def gated_delta_recurrence(q, k, v, g, beta, state):
-    """The rule, token by token. q, k: [T, H, Dk]; v: [T, H, Dv]; g, beta:
-    [T, H]; state: [H, Dk, Dv] float32. Returns (o [T, H, Dv] float32,
-    state)."""
+    """The rule, token by token. q, k: [T, Hk, Dk], a key head for H // Hk
+    value heads in a row; v: [T, H, Dv]; g, beta: [T, H]; state: [H, Dk, Dv]
+    float32. Returns (o [T, H, Dv] float32, state)."""
+    q, k = _a_value_head(q, k, v.shape[1])
+
     def token(s, row):
         q_t, k_t, v_t, g_t, b_t = row
         s = jnp.exp(g_t)[:, None, None] * s
@@ -117,12 +141,12 @@ def unit_lower_inverse(a):
     return inv.reshape(lead + (n, n))
 
 
-def gated_delta_chunk(q, k, v, g, beta, state):
-    """A run of positions of one sequence, chunked. q, k: [T, H, Dk]; v:
-    [T, H, Dv]; g, beta: [T, H]; state: [H, Dk, Dv] float32, the state
-    before the first position. Returns (o [T, H, Dv] float32, the state
-    after the last position). T is any length: the run is padded to whole
-    sub-chunks with positions that change nothing."""
+def gated_delta_chunk_reference(q, k, v, g, beta, state):
+    """:func:`gated_delta_chunk` in plain jnp: what runs off a TPU and what
+    the kernel is held to. ``T = (I + A)^-1`` does not depend on the state,
+    so ``U = T (beta V)`` and ``W = T (beta exp(G) K)`` are made for every
+    sub-chunk at once and ``D = U - W S_0`` under the scan."""
+    q, k = _a_value_head(q, k, v.shape[1])
     t, h, _ = q.shape
     pad = -t % SUB
     n = (t + pad) // SUB
@@ -165,6 +189,215 @@ def gated_delta_chunk(q, k, v, g, beta, state):
     # o: [n, H, SUB, Dv] -> [T, H, Dv]
     o = jnp.moveaxis(o, 1, 2).reshape(n * SUB, h, -1)
     return o[:t], state
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return lax.dot_general(a, b, dims, precision=PRECISION,
+                           preferred_element_type=F32)
+
+
+def _pair_inverses(tiles, rows, cols, right):
+    """:func:`unit_lower_inverse` of pairs of heads' ``[SUB, SUB]`` tiles,
+    each of ``tiles`` and of the results ``[SUB, 2 SUB]`` with the second
+    head's tile in the lanes from SUB up, with no reshape: with ``X_s`` the
+    inverses of a tile's diagonal blocks of ``s`` (block-diagonal) and
+    ``M_s`` the mask of the block under the diagonal of every pair of them,
+    ``X_2s = X_s - X_s (a * M_s) X_s``: the same ``-Q^-1 C P^-1`` of every
+    pair, the products' other terms exact zeros. ``X_1 = I``, so the first
+    level is ``I - a * M_1`` and no product. A product of the two heads is
+    one of 128 lanes deep: ``[P_0 | P_1] [[R_0, 0], [0, R_1]]``. Level by
+    level over all the pairs: a pair's next product waits for none of its
+    own."""
+    def under(s):
+        # rows in the odd block of a pair of blocks of s, columns in the
+        # even one
+        return (((rows & s) != 0) & ((cols & s) == 0)
+                & ((rows | (2 * s - 1)) == (cols | (2 * s - 1))))
+
+    def apart(p):
+        # [R_0 | R_1] -> [[R_0, 0], [0, R_1]], no lane moved
+        return jnp.concatenate([jnp.where(right, 0.0, p),
+                                jnp.where(right, p, 0.0)], axis=0)
+
+    eye, first = jnp.where(rows == cols, 1.0, 0.0), under(1)
+    xs = [eye - jnp.where(first, a, 0.0) for a in tiles]
+    s = 2
+    while s < SUB:
+        mask = under(s)
+        ys = [_dot(jnp.where(mask, a, 0.0), apart(x))
+              for a, x in zip(tiles, xs)]
+        xs = [x - _dot(x, apart(y)) for x, y in zip(xs, ys)]
+        s *= 2
+    return xs
+
+
+def _apart(r0, r1):
+    """``[[r0, 0], [0, r1]]``: what two heads' tiles side by side multiply
+    to give ``[P_0 r0 | P_1 r1]``."""
+    return jnp.concatenate(
+        [jnp.concatenate([r0, jnp.zeros_like(r1)], axis=1),
+         jnp.concatenate([jnp.zeros_like(r0), r1], axis=1)], axis=0)
+
+
+def _chunk_kernel(q_ref, k_ref, v_ref, big_ref, beta_ref, s0_ref, o_ref,
+                  s_ref, *, heads: int, rep: int, dk: int, dv: int):
+    """One sub-chunk of ``heads`` value heads, two by two. q_ref, k_ref
+    [SUB, heads // rep * dk], a key head for ``rep`` value heads, and v_ref,
+    o_ref [SUB, heads * dv]: a head's sub-chunk is 128-lane columns of the
+    caller's ``[T, H * D]``; big_ref (the running sums ``G_i``) and beta_ref
+    [SUB, heads]; s0_ref and s_ref [heads, dk, dv]. s_ref's block is the
+    same for every sub-chunk of a head: it is the state, in VMEM from the
+    first sub-chunk to the last.
+
+    A product costs what its rows cost and no less than a fixed 0.1 us,
+    and products run one after another, so two heads share every product
+    that is 64 deep (their ``[SUB, SUB]`` tiles side by side in the lanes,
+    ``[SUB, 2 SUB]``), and the pairs of a step go stage by stage: a pair's
+    next product waits for none of its own."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        s_ref[...] = s0_ref[...]
+
+    rows = lax.broadcasted_iota(jnp.int32, (SUB, 2 * SUB), 0)
+    lanes = lax.broadcasted_iota(jnp.int32, (SUB, 2 * SUB), 1)
+    right = lanes >= SUB                                # the second head
+    cols = lanes & (SUB - 1)
+    upto = rows >= cols                                       # j <= i
+    last_row = lax.broadcasted_iota(jnp.int32, (SUB, dv), 0) == SUB - 1
+    pairs = [(h, h + 1) for h in range(0, heads, 2)]
+
+    def head(ref, h, d):
+        return ref[:, h * d:(h + 1) * d].astype(F32)
+
+    def both(ref, pair):
+        """A column a head, [SUB, 1], over its head's lanes."""
+        return jnp.where(right, ref[:, pair[1]:pair[1] + 1],
+                         ref[:, pair[0]:pair[0] + 1])
+
+    # A value head's keys and queries are its key head's, read once.
+    keys = [head(k_ref, j, dk) for j in range(heads // rep)]
+    queries = [head(q_ref, j, dk) for j in range(heads // rep)]
+    k = {h: keys[h // rep] for h in range(heads)}
+    q = {h: queries[h // rep] for h in range(heads)}
+    grow = {h: jnp.exp(big_ref[:, h:h + 1]) for h in k}     # exp(G_i)
+    decay, a, qk = {}, {}, {}
+    for pair in pairs:
+        h0, h1 = pair
+        if h0 // rep == h1 // rep:
+            # [K; Q] [K; K]^T: the products against the pair's one key
+            # head, for both its value heads' lanes.
+            kq = _dot(jnp.concatenate([k[h0], q[h0]], axis=0),
+                      jnp.concatenate([k[h0], k[h0]], axis=0),
+                      (((1,), (1,)), ((), ())))
+            kk, qk[pair] = kq[:SUB], kq[SUB:]
+        else:
+            # [K_0; Q_0; K_1; Q_1] [K_0; K_1]^T, a head's own in its lanes.
+            kq = _dot(jnp.concatenate([k[h0], q[h0], k[h1], q[h1]], axis=0),
+                      jnp.concatenate([k[h0], k[h1]], axis=0),
+                      (((1,), (1,)), ((), ())))
+            kk = jnp.where(right, kq[2 * SUB:3 * SUB], kq[:SUB])
+            qk[pair] = jnp.where(right, kq[3 * SUB:], kq[SUB:2 * SUB])
+        big = both(big_ref, pair)
+        # G_j along the lanes: the diagonal of the column's broadcast.
+        along = jnp.sum(jnp.where(rows == cols, big, 0.0), axis=0,
+                        keepdims=True)
+        decay[pair] = jnp.where(
+            upto, jnp.exp(jnp.where(upto, big - along, 0.0)), 0.0)
+        a[pair] = jnp.where(
+            rows > cols, both(beta_ref, pair) * decay[pair] * kk, 0.0)
+    inv = _pair_inverses([a[pair] for pair in pairs], rows, cols, right)
+    # [K; exp(G) Q] S_0, a head
+    ks = {h: _dot(jnp.concatenate([k[h], grow[h] * q[h]], axis=0), s_ref[h])
+          for h in k}
+    # (I + A) D = beta (V - exp(G) K S_0)
+    rhs = {h: beta_ref[:, h:h + 1] * (head(v_ref, h, dv)
+                                      - grow[h] * ks[h][:SUB]) for h in k}
+    d = {}
+    for pair, t in zip(pairs, inv):
+        both_d = _dot(t, _apart(rhs[pair[0]], rhs[pair[1]]))
+        d[pair[0]], d[pair[1]] = both_d[:, :dv], both_d[:, dv:]
+    for pair in pairs:
+        h0, h1 = pair
+        within = _dot(decay[pair] * qk[pair], _apart(d[h0], d[h1]))
+        o_ref[:, h0 * dv:(h0 + 1) * dv] = ks[h0][SUB:] + within[:, :dv]
+        o_ref[:, h1 * dv:(h1 + 1) * dv] = ks[h1][SUB:] + within[:, dv:]
+    for h in k:
+        big = big_ref[:, h:h + 1]
+        # exp(G_C) along the lanes, by a sum that keeps the last row: Mosaic
+        # broadcasts one way at a time, not a [1, 1] over a tile.
+        whole = jnp.sum(jnp.where(last_row, grow[h], 0.0), axis=0,
+                        keepdims=True)
+        s_ref[h] = whole * s_ref[h] + _dot(
+            jnp.exp(big[SUB - 1:] - big) * k[h], d[h],
+            (((0,), (0,)), ((), ())))
+
+
+def _heads_a_step(h: int) -> int:
+    """Value heads a grid step: the products of a step run one after
+    another, and a pair's next one waits for the last unless other pairs'
+    lie between. On a v5e 512 rows x 32 heads take 0.47 ms at one pair a
+    step, 0.32 at two, 0.28 at four and at eight (PR 49)."""
+    return next(n for n in (8, 4, 2) if h % n == 0)
+
+
+def _chunk_pallas(q, k, v, g, beta, state):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, h, dv = v.shape
+    dk = q.shape[-1]
+    rep = h // q.shape[1]
+    pad = -t % SUB
+    n = (t + pad) // SUB
+    hs = _heads_a_step(h)
+
+    def rows(a):
+        a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+        return a.reshape(t + pad, -1)                         # [T, H * D]
+
+    def a_step(a):                                            # [T, H] ->
+        return jnp.moveaxis(a.reshape(t + pad, h // hs, hs), 1, 0)
+
+    big = jnp.cumsum(rows(g.astype(F32)).reshape(n, SUB, h), axis=1)
+    wide = lambda d: pl.BlockSpec((SUB, d), lambda i, j: (j, i))  # noqa: E731
+    narrow = pl.BlockSpec((None, SUB, hs), lambda i, j: (i, j, 0))
+    held = pl.BlockSpec((hs, dk, dv), lambda i, j: (i, 0, 0))
+    o, state = pl.pallas_call(
+        functools.partial(_chunk_kernel, heads=hs, rep=rep, dk=dk, dv=dv),
+        grid=(h // hs, n),
+        in_specs=[wide(hs // rep * dk), wide(hs // rep * dk), wide(hs * dv),
+                  narrow, narrow, held],
+        out_specs=[wide(hs * dv), held],
+        out_shape=[jax.ShapeDtypeStruct((t + pad, h * dv), F32),
+                   jax.ShapeDtypeStruct((h, dk, dv), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=kernel_backend() == "interpret",
+        name="gated_delta_chunk",
+    )(rows(q), rows(k), rows(v), a_step(big), a_step(rows(beta.astype(F32))),
+      state.astype(F32))
+    return o[:t].reshape(t, h, dv), state
+
+
+def gated_delta_chunk(q, k, v, g, beta, state):
+    """A run of positions of one sequence, chunked. q, k: [T, Hk, Dk], a key
+    head for H // Hk value heads in a row; v: [T, H, Dv]; g, beta: [T, H];
+    state: [H, Dk, Dv] float32, the state before the first position. Returns
+    (o [T, H, Dv] float32, the state after the last position). T is any
+    length: the run is padded to whole sub-chunks with positions that change
+    nothing.
+
+    The implementation is ``ops/kernels.kernel_backend()``'s: on a TPU the
+    kernel, where the operands' shapes are its own (a head's keys and values
+    whole 128-lane columns, value heads two by two, a step's heads whole key
+    heads); :func:`gated_delta_chunk_reference` otherwise."""
+    h, rep = v.shape[1], v.shape[1] // q.shape[1]
+    if kernel_backend() == "reference" or q.shape[-1] % 128 \
+            or v.shape[-1] % 128 or h % 2 or _heads_a_step(h) % rep:
+        return gated_delta_chunk_reference(q, k, v, g, beta, state)
+    return _chunk_pallas(q, k, v, g, beta, state)
 
 
 def gated_delta_step(q, k, v, g, beta, state):

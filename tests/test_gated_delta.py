@@ -1,36 +1,63 @@
 """The gated delta rule (``ops/gated_delta.py``): the chunked form and the
 one-token step against the token-by-token recurrence, float32 on the CPU.
 
+The chunked form twice: the jnp body that runs off a TPU, at widths of 16
+and 8 (which the kernel does not take), and the kernel's body through the
+Pallas interpreter at widths of 128 (``FORMS``), held to the same
+recurrence at the same tolerance.
+
 Tolerances: every form computes in float32 and the products at true
 float32, so what is left is the order of the sums: observed 2e-7 on outputs
-of about 1 and 8e-7 on states of about 3. 1e-5 would not pass a decay
-applied a position late, a correction without ``beta`` or a sub-chunk that
-starts from another state.
+of about 1 and 8e-7 on states of about 3 (1.1e-6 from the kernel's body at
+512 positions). 1e-5 would not pass a decay applied a position late, a
+correction without ``beta`` or a sub-chunk that starts from another state.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 from ray_tpu.ops import gated_delta as gd
+from ray_tpu.ops.kernels import force_kernel_backend
 
 H, DK, DV = 3, 16, 8
 ATOL = 1e-5
 
+# form: (value heads, key heads, Dk, Dv). The kernel takes heads of whole
+# 128-lane columns two by two: two pairs that each share a key head (what
+# Qwen3-Next has), a pair of heads with keys of their own, and a step of
+# eight heads on two key heads.
+FORMS = {"jnp": (H, H, DK, DV), "kernel": (4, 2, 128, 128),
+         "kernel_own_keys": (2, 2, 128, 128), "kernel_of_8": (8, 2, 128, 128)}
 
-def inputs(t, seed=0, state=True):
+_jitted = jax.jit(gd.gated_delta_chunk)
+
+
+def chunk(form, *a):
+    """``gated_delta_chunk`` as ``form`` runs it: as it is off a TPU, or the
+    kernel's body through the interpreter (one jitted program a shape: its
+    cache holds only what was traced under the forced backend)."""
+    if form == "jnp":
+        return gd.gated_delta_chunk(*a)
+    with force_kernel_backend("interpret"):
+        return _jitted(*a)
+
+
+def inputs(t, seed=0, state=True, form="jnp"):
     """Unit keys, scaled unit queries, decays from 0.0009 to 1.6 a token
     (``exp(g)`` from 0.2 to 0.999), steps in (0, 1), a random state."""
+    h, hk, dk, dv = FORMS.get(form, form)    # a name, or the four numbers
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
-    q = unit(jax.random.normal(ks[0], (t, H, DK))) * DK ** -0.5
-    k = unit(jax.random.normal(ks[1], (t, H, DK)))
-    v = jax.random.normal(ks[2], (t, H, DV))
-    g = -jnp.exp(jax.random.uniform(ks[3], (t, H), minval=-7.0, maxval=0.5))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (t, H)))
-    s = jax.random.normal(ks[5], (H, DK, DV)) if state \
-        else jnp.zeros((H, DK, DV))
+    q = unit(jax.random.normal(ks[0], (t, hk, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (t, hk, dk)))
+    v = jax.random.normal(ks[2], (t, h, dv))
+    g = -jnp.exp(jax.random.uniform(ks[3], (t, h), minval=-7.0, maxval=0.5))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (t, h)))
+    s = jax.random.normal(ks[5], (h, dk, dv)) if state \
+        else jnp.zeros((h, dk, dv))
     return q, k, v, g, beta, s
 
 
@@ -39,39 +66,42 @@ def close(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=ATOL)
 
 
-@pytest.mark.parametrize("t", [1, 5, 63, 64, 65, 100, 128, 200])
+@pytest.mark.parametrize("form,t", [
+    *(("jnp", t) for t in (1, 5, 63, 64, 65, 100, 128, 200)),
+    *(("kernel", t) for t in (64, 330, 512)),
+    ("kernel_own_keys", 330), ("kernel_of_8", 130)])
 @pytest.mark.parametrize("state", [False, True], ids=["zero", "carried"])
-def test_the_chunk_form_is_the_recurrence(t, state):
+def test_the_chunk_form_is_the_recurrence(form, t, state):
     """At lengths that are and are not whole sub-chunks, from zeros and from
     a carried state."""
-    a = inputs(t, seed=t, state=state)
-    close(gd.gated_delta_chunk(*a), gd.gated_delta_recurrence(*a))
+    a = inputs(t, seed=t, state=state, form=form)
+    close(chunk(form, *a), gd.gated_delta_recurrence(*a))
 
 
-def test_the_state_is_handed_from_run_to_run():
+@pytest.mark.parametrize("form", ["jnp", "kernel"])
+def test_the_state_is_handed_from_run_to_run(form):
     """Two runs, the second from the state the first left, are one run: a
     prefill chunk after a prefill chunk."""
-    q, k, v, g, beta, s = inputs(150, seed=3)
+    q, k, v, g, beta, s = inputs(150, seed=3, form=form)
     want_o, want_s = gd.gated_delta_recurrence(q, k, v, g, beta, s)
     cut = 70
-    o1, s1 = gd.gated_delta_chunk(q[:cut], k[:cut], v[:cut], g[:cut],
-                                  beta[:cut], s)
-    o2, s2 = gd.gated_delta_chunk(q[cut:], k[cut:], v[cut:], g[cut:],
-                                  beta[cut:], s1)
+    o1, s1 = chunk(form, q[:cut], k[:cut], v[:cut], g[:cut], beta[:cut], s)
+    o2, s2 = chunk(form, q[cut:], k[cut:], v[cut:], g[cut:], beta[cut:], s1)
     close((jnp.concatenate([o1, o2]), s2), (want_o, want_s))
 
 
-def test_rows_that_are_not_valid_change_no_state():
+@pytest.mark.parametrize("form", ["jnp", "kernel"])
+def test_rows_that_are_not_valid_change_no_state(form):
     """A padded chunk: rows past the last valid one enter with ``g = 0`` and
     ``beta = 0`` and leave the state as the last valid row left it,
     whatever their q, k and v."""
-    q, k, v, g, beta, s = inputs(96, seed=5)
+    q, k, v, g, beta, s = inputs(96, seed=5, form=form)
     n = 41
     valid = jnp.arange(96) < n
     _, want = gd.gated_delta_recurrence(q[:n], k[:n], v[:n], g[:n], beta[:n],
                                         s)
-    o, got = gd.gated_delta_chunk(
-        q, k, jnp.where(valid[:, None, None], v, 100.0 * v),
+    o, got = chunk(
+        form, q, k, jnp.where(valid[:, None, None], v, 100.0 * v),
         jnp.where(valid[:, None], g, 0.0),
         jnp.where(valid[:, None], beta, 0.0), s)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=ATOL)
@@ -94,28 +124,30 @@ def test_the_inverse_by_halves_is_the_substitution():
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-5)
 
 
-def test_keys_that_are_alike_cost_no_precision():
+@pytest.mark.parametrize("form", ["jnp", "kernel", "kernel_own_keys"])
+def test_keys_that_are_alike_cost_no_precision(form):
     """Neighbouring keys nearly parallel and steps near 1: ``A`` has entries
     near 1 all under its diagonal. The product ``(I - A)(I + A^2)(I + A^4)
     ...`` equals the inverse in exact arithmetic and loses it here in
     float32 (its powers grow like binomial coefficients before they cancel:
     it read 1e-3 off on a served model's logits); the inverse by halves
     stays at the recurrence's 1e-5."""
-    q, k, v, g, beta, s = inputs(128, seed=15)
+    q, k, v, g, beta, s = inputs(128, seed=15, form=form)
     k = k[:1] + 0.05 * k
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     beta = jnp.full_like(beta, 0.98)
     g = jnp.full_like(g, -1e-3)
-    close(gd.gated_delta_chunk(q, k, v, g, beta, s),
+    close(chunk(form, q, k, v, g, beta, s),
           gd.gated_delta_recurrence(q, k, v, g, beta, s))
 
 
-def test_a_long_decay_overflows_nothing():
+@pytest.mark.parametrize("form", ["jnp", "kernel"])
+def test_a_long_decay_overflows_nothing(form):
     """Every exponent is a difference ``G_i - G_j <= 0``: a sub-chunk whose
     running decay reaches exp(-64 x 20) still gives the recurrence."""
-    q, k, v, g, beta, s = inputs(128, seed=9)
+    q, k, v, g, beta, s = inputs(128, seed=9, form=form)
     g = jnp.full_like(g, -20.0)
-    got = gd.gated_delta_chunk(q, k, v, g, beta, s)
+    got = chunk(form, q, k, v, g, beta, s)
     assert np.isfinite(np.asarray(got[0])).all()
     close(got, gd.gated_delta_recurrence(q, k, v, g, beta, s))
 
@@ -138,14 +170,96 @@ def test_the_step_is_the_recurrence_s_one_token():
     np.testing.assert_array_equal(np.asarray(new[2]), np.asarray(states[2]))
 
 
-def test_the_forms_compute_in_float32_whatever_they_are_given():
+@pytest.mark.parametrize("form", ["jnp", "kernel"])
+def test_the_forms_compute_in_float32_whatever_they_are_given(form):
     """bfloat16 inputs are cast up, the state and the output are float32:
     the rule's state is never kept below float32."""
-    q, k, v, g, beta, s = inputs(70, seed=13)
+    q, k, v, g, beta, s = inputs(70, seed=13, form=form)
     low = tuple(a.astype(jnp.bfloat16) for a in (q, k, v))
-    o, new = gd.gated_delta_chunk(*low, g, beta, s)
+    o, new = chunk(form, *low, g, beta, s)
     assert o.dtype == new.dtype == jnp.float32
     close((o, new), gd.gated_delta_recurrence(*low, g, beta, s))
+    q, k = gd._a_value_head(*low[:2], v.shape[1])
+    low = (q, k, low[2])
     o, new = gd.gated_delta_step(*(a[:4] for a in low), g[:4], beta[:4],
                                  jnp.broadcast_to(s, (4, *s.shape)))
     assert o.dtype == new.dtype == jnp.float32
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it (a kernel's
+    body, a loop's)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) \
+                    else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def _traced(form, t=128, backend="interpret"):
+    # a function of its own a trace: the forced backend is no part of the
+    # tracing cache's key
+    with force_kernel_backend(backend):
+        return list(_equations(jax.make_jaxpr(
+            lambda *a: gd.gated_delta_chunk(*a))(
+                *inputs(t, form=form)).jaxpr))
+
+
+def test_the_kernel_is_chosen_by_the_backend_and_the_operands_shapes():
+    """One call, named for the trace, where the backend is not the
+    reference's and a head's keys and values are whole 128-lane columns,
+    the value heads even in number and a step's heads whole key heads; the
+    jnp body off a TPU and at every other shape (``Qwen3NextConfig.tiny``'s
+    16 and 8, three heads, a key head for three value heads)."""
+    calls = [e for e in _traced("kernel") if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert calls[0].params["name"] == "gated_delta_chunk"
+    for form in FORMS:
+        names = {e.primitive.name for e in _traced(form, backend="reference")}
+        assert "pallas_call" not in names, form
+    assert "pallas_call" not in {e.primitive.name for e in _traced("jnp")}
+    for h, hk in ((3, 3), (6, 2), (2, 1), (8, 8), (16, 2)):
+        names = {e.primitive.name for e in _traced((h, hk, 128, 128))}
+        fits = h % 2 == 0 and gd._heads_a_step(h) % (h // hk) == 0
+        assert ("pallas_call" in names) == fits, (h, hk)
+
+
+@pytest.mark.parametrize("form", ["kernel", "kernel_own_keys"])
+def test_every_product_of_the_kernel_is_true_float32(form):
+    """The configuration states the precision (``departures.state_dtype``):
+    the state float32, the rule's products at true float32. Every product
+    inside the kernel is float32 by float32 into float32 at
+    ``Precision.HIGHEST`` (a TPU's default is one bfloat16 pass), the state
+    goes in and comes out float32, and nothing in the kernel is cast below
+    float32."""
+    eqns = _traced(form)
+    call, = (e for e in eqns if e.primitive.name == "pallas_call")
+    body = list(_equations(call.params["jaxpr"]))
+    dots = [e for e in body if e.primitive.name == "dot_general"]
+    assert len(dots) >= 8
+    for e in dots:
+        assert e.params["precision"] in (
+            lax.Precision.HIGHEST,
+            (lax.Precision.HIGHEST, lax.Precision.HIGHEST)), e
+        assert e.params["preferred_element_type"] == jnp.float32
+        assert all(v.aval.dtype == jnp.float32 for v in e.invars)
+    for e in body:
+        if e.primitive.name == "convert_element_type":
+            assert e.params["new_dtype"] in (jnp.float32, jnp.int32), e
+    assert [v.aval.dtype for v in call.outvars] == [jnp.float32] * 2
+    assert call.outvars[1].aval.shape == call.invars[-1].aval.shape
+
+
+def test_the_kernel_under_vmap_is_a_sequence_each():
+    """``models/qwen3_next.forward`` runs whole sequences under
+    ``jax.vmap``: ``pallas_call``'s batching rule makes the batch a grid
+    axis, and each sequence reads what it reads alone."""
+    batch = [inputs(130, seed=20 + b, form="kernel") for b in range(2)]
+    stacked = tuple(jnp.stack(a) for a in zip(*batch))
+    with force_kernel_backend("interpret"):
+        got = jax.jit(jax.vmap(gd.gated_delta_chunk))(*stacked)
+    for b, a in enumerate(batch):
+        close((got[0][b], got[1][b]), gd.gated_delta_recurrence(*a))

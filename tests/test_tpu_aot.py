@@ -811,10 +811,18 @@ def test_qwen3_next_programs_copy_no_state_nor_expert_stack_and_fit_the_chip(
     assert 11.6 < mem.argument_size_in_bytes / 2 ** 30 < 11.7
     assert total < 15.75 - 0.7
     if program.startswith("prefill"):
-        kernels = ("prefill_attention", "moe_grouped_matmul")
+        kernels = ("prefill_attention", "moe_grouped_matmul",
+                   "gated_delta_chunk")
         # 512 x 10 picks over 512 outputs are 10 rows an expert: tiles of 32
         assert _grouped_matmul_rows(text) == {(5120 // 32 + 64) * 32}
         assert mem.temp_size_in_bytes < 1 << 27
+        # The chunked rule's system, its inverse and what the walk reads
+        # (``decay`` and ``a``; ``u``, ``w``, ``within``, ``q_in``, ``k_out``
+        # of 32 heads x 8 sub-chunks) stay in the kernel's VMEM: the jnp
+        # body wrote each to HBM in float32, heads first.
+        for shape in ("f32[32,8,64,128]", "f32[8,32,64,128]",
+                      "f32[32,8,64,64]"):
+            assert not _opcodes_with_shape(text, shape), shape
     else:
         kernels = ("decode_attention", "kv_row_write", "moe_grouped_matmul")
         assert _plans_outside_the_layer_loop(text)
@@ -854,3 +862,26 @@ def test_qwen3_next_programs_copy_no_state_nor_expert_stack_and_fit_the_chip(
     for shape in ("we_in", "we_down", "in_qkvz", "wq"):
         assert _opcodes_with_shape(text, big[shape]) <= \
             carried | {"custom-call"}, shape
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["a_chunk", "vmap"])
+def test_gated_delta_chunk_compiles_alone_and_under_vmap(mosaic, batched):
+    """The chunked gated delta rule's kernel at the cell's shapes (512 rows,
+    16 key heads for 32 value heads of 128), as the prefill program calls it
+    and as ``models/qwen3_next.forward`` does, under ``jax.vmap``: no cell's
+    path, but it must still compile on a TPU (``pallas_call``'s batching
+    rule makes the batch a grid axis)."""
+    from ray_tpu.ops.gated_delta import gated_delta_chunk
+
+    dev = NamedSharding(build_mesh(MeshSpec(), mosaic[:1]), P())
+    lead = (2,) if batched else ()
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(lead + shape, jnp.float32, sharding=dev)
+
+    fn = jax.vmap(gated_delta_chunk) if batched else gated_delta_chunk
+    text = jax.jit(fn).lower(
+        sds(512, 16, 128), sds(512, 16, 128), sds(512, 32, 128),
+        sds(512, 32), sds(512, 32), sds(32, 128, 128)).compile().as_text()
+    assert text.count(MOSAIC) == 1
+    assert "gated_delta_chunk" in text
