@@ -151,6 +151,7 @@ class GenerationRequest:
     submit_ts: float = 0.0
     admit_ts: float = 0.0
     first_token_ts: float = 0.0
+    finish_ts: float = 0.0
     kv_imported: bool = False
 
 
@@ -298,6 +299,15 @@ class LLMEngine:
         self.first_tokens = 0
         self.queue_wait_s = 0.0
         self.first_token_wait_s = 0.0
+        # A slot's turn-round: from the time it was freed (a line's finish,
+        # a held slot's release; _slot_freed, by slot) to the admission of
+        # the request that takes it next, summed over `slot_refills`. A
+        # slot's first use counts in neither. With as many closed-loop
+        # clients as slots this is what the device steps over empty while
+        # an answer's end goes out and the next request comes in.
+        self.slot_vacant_s = 0.0
+        self.slot_refills = 0
+        self._slot_freed: dict[int, float] = {}
         self.cache = self._new_cache(self.model_cfg)
 
         self.spec_k = max(1, int(config.speculative_tokens))
@@ -417,7 +427,11 @@ class LLMEngine:
     def generate(self, prompt: str | list[int],
                  sampling: SamplingParams | None = None,
                  timeout: float = 300.0) -> GenerationResult:
-        req = self.submit(prompt, sampling)
+        return self.result(self.submit(prompt, sampling), timeout)
+
+    def result(self, req: GenerationRequest,
+               timeout: float = 300.0) -> GenerationResult:
+        """Wait for a submitted request's end."""
         if not req.done.wait(timeout):
             raise TimeoutError(f"generation {req.request_id} timed out")
         if req.error:
@@ -505,6 +519,7 @@ class LLMEngine:
             for slot, r in self._slots.items():
                 if r is req:
                     self._slots[slot] = None
+                    self._slot_freed[slot] = time.time()
                     self._prefix_live.pop(slot, None)
                     if (req.finish_reason not in (None, "error")
                             and not req.error):
@@ -616,6 +631,8 @@ class LLMEngine:
                "first_tokens": self.first_tokens,
                "queue_wait_s": self.queue_wait_s,
                "first_token_wait_s": self.first_token_wait_s,
+               "slot_vacant_s": self.slot_vacant_s,
+               "slot_refills": self.slot_refills,
                **self.model_counts}
         if self.draft_cfg is not None:
             out["spec_ticks"] = self.spec_ticks
@@ -979,9 +996,18 @@ class LLMEngine:
             req.next_pos = -1 if (self.model.prefill_token
                                   or self._prefill_len(req)) else 0
             req.last_slot = slot
-            self._slots[slot] = req
+            self._occupy(slot, req)
             admitted += 1
         return admitted
+
+    def _occupy(self, slot: int, req: GenerationRequest) -> None:
+        """Put an admitted request into its slot, and book how long the
+        slot stood vacant if it was in use before."""
+        freed_ts = self._slot_freed.pop(slot, None)
+        if freed_ts is not None:
+            self.slot_vacant_s += max(req.admit_ts - freed_ts, 0.0)
+            self.slot_refills += 1
+        self._slots[slot] = req
 
     def _take_slot(self) -> int:
         """An unoccupied slot: prefer one with no cached prefix; otherwise
@@ -1050,7 +1076,7 @@ class LLMEngine:
         req.preloaded = None
         req.next_pos = p
         req.last_slot = slot
-        self._slots[slot] = req
+        self._occupy(slot, req)
         self._prefix_live[slot] = tuple(req.prompt_ids)  # imported KV = donor
         self._emit(req, first_token)
 
@@ -1259,7 +1285,7 @@ class LLMEngine:
         # these lines is in flight (_dispatch_decode read it out).
         try:
             with tracing.phase("engine.decode_dispatch", steps=1,
-                               slots=len(active)):
+                               slots=len(active), riders=0):
                 positions, write = self._decode_inputs(active)
                 self.cache, logits, *counts = self.model.decode_step(
                     self.model_cfg, self.params, self.cache,
@@ -1368,7 +1394,7 @@ class LLMEngine:
         try:
             with tracing.phase("engine.decode_dispatch",
                                steps=burst * self._step_forwards,
-                               slots=len(active)):
+                               slots=len(active)) as ph:
                 positions, write = self._decode_inputs(active)
                 temps = np.zeros((self.max_slots,), np.float32)
                 top_ps = np.ones((self.max_slots,), np.float32)
@@ -1378,8 +1404,11 @@ class LLMEngine:
                 need_top_p = bool((top_ps < 1.0).any())
                 self._rng_key, sub = jax.random.split(self._rng_key)
                 # Chunks left for this burst by _prefill_step ride it.
+                riding = self.prefill_chunks_riding
                 riders = (self._take_riders(burst)
                           if burst == self._ride_steps else None)
+                # Of this burst's steps, those that took a chunk along.
+                ph.set(riders=self.prefill_chunks_riding - riding)
                 program, carried = (
                     (self.model.decode_burst, ()) if riders is None
                     else (self.model.mixed_burst, (riders,)))
@@ -1481,7 +1510,7 @@ class LLMEngine:
             # One phase for draft and verify: each is fetched as soon as
             # it is dispatched, so dispatch and fetch do not come apart.
             with tracing.phase("engine.decode_dispatch", steps=k + 1,
-                               slots=len(active), speculative=1):
+                               slots=len(active), riders=0, speculative=1):
                 self.draft_cache, proposals = self.draft_model.draft_propose(
                     self.draft_cfg, self.draft_params, self.draft_cache,
                     jnp.asarray(token0), jnp.asarray(pos0), k,
@@ -1649,10 +1678,11 @@ class LLMEngine:
 
     def _finish(self, req: GenerationRequest, reason: str) -> None:
         req.finish_reason = reason
+        req.finish_ts = time.time()
         self.finished += 1
         if req.trace_ctx is not None and req.first_token_ts:
             tracing.record_span(
-                "engine.decode", req.first_token_ts, time.time(),
+                "engine.decode", req.first_token_ts, req.finish_ts,
                 ctx=req.trace_ctx,
                 attributes={"request_id": req.request_id,
                             "tokens": len(req.out_tokens),
@@ -1663,6 +1693,7 @@ class LLMEngine:
                 toks = self._prefix_live.pop(slot, None)
                 if not req.hold_slot:
                     self._slots[slot] = None
+                    self._slot_freed[slot] = req.finish_ts
                     if toks is not None and reason != "error":
                         # Retire, don't discard: the slot's KV stays intact
                         # until the slot is reclaimed, so an identical or

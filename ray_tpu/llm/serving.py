@@ -21,7 +21,8 @@ from ray_tpu.llm.config import LLMConfig, SamplingParams
 from ray_tpu.llm.engine import LLMEngine
 
 
-@guarded_by("_lag_lock", "first_frames", "first_frame_lag_s")
+@guarded_by("_lag_lock", "first_frames", "first_frame_lag_s", "last_frames",
+            "last_frame_lag_s", "ingress_requests", "ingress_s")
 class LLMServer:
     """One replica = one engine instance (the engine batches across the
     replica's concurrent requests)."""
@@ -38,12 +39,38 @@ class LLMServer:
         self._lag_lock = threading.Lock()
         self.first_frames = 0
         self.first_frame_lag_s = 0.0
+        # The mirror at a line's end: from the engine's finish stamp to the
+        # stream method taking the end off the queue, summed over
+        # `last_frames`.
+        self.last_frames = 0
+        self.last_frame_lag_s = 0.0
+        # A request's way in: from the proxy's arrival stamp
+        # (serve.Request.received_ts) to engine.submit's, summed over
+        # `ingress_requests`: the body's read and parse, the route hint,
+        # handle and router, the replica's admission and pool thread,
+        # tokenisation. A request no proxy stamped (a handle call) counts
+        # in neither.
+        self.ingress_requests = 0
+        self.ingress_s = 0.0
 
     # -- handle API --
 
-    def completions(self, prompt: str, **kw) -> dict:
-        sampling = _sampling_from(kw)
-        res = self.engine.generate(prompt, sampling)
+    def _submit(self, prompt, kw: dict, received_ts: float,
+                stream: bool = False):
+        """engine.submit under ``kw``'s sampling, and the request's way in
+        booked where it ends. ``received_ts``, on the four methods below:
+        the ``time.time()`` at which an ingress took the request (__call__
+        passes the HTTP proxy's stamp); 0.0, no stamp, books nothing."""
+        req = self.engine.submit(prompt, _sampling_from(kw), stream=stream)
+        if received_ts:
+            with self._lag_lock:
+                self.ingress_requests += 1
+                self.ingress_s += max(req.submit_ts - received_ts, 0.0)
+        return req
+
+    def completions(self, prompt: str, *, received_ts: float = 0.0,
+                    **kw) -> dict:
+        res = self.engine.result(self._submit(prompt, kw, received_ts))
         return {
             "id": f"cmpl-{res.request_id}",
             "object": "text_completion",
@@ -55,10 +82,10 @@ class LLMServer:
                       "total_tokens": len(res.prompt_ids) + len(res.token_ids)},
         }
 
-    def chat(self, messages: list[dict], **kw) -> dict:
-        sampling = _sampling_from(kw)
+    def chat(self, messages: list[dict], *, received_ts: float = 0.0,
+             **kw) -> dict:
         prompt = self.engine.tokenizer.apply_chat_template(messages)
-        res = self.engine.generate(prompt, sampling)
+        res = self.engine.result(self._submit(prompt, kw, received_ts))
         return {
             "id": f"chatcmpl-{res.request_id}",
             "object": "chat.completion",
@@ -71,12 +98,12 @@ class LLMServer:
                       "total_tokens": len(res.prompt_ids) + len(res.token_ids)},
         }
 
-    def chat_stream(self, messages: list[dict], **kw):
+    def chat_stream(self, messages: list[dict], *,
+                    received_ts: float = 0.0, **kw):
         """SSE token stream (reference: OpenAI chat.completion.chunk frames
         through the streaming ingress, serve llm openai compat)."""
-        sampling = _sampling_from(kw)
         prompt = self.engine.tokenizer.apply_chat_template(messages)
-        req = self.engine.submit(prompt, sampling, stream=True)
+        req = self._submit(prompt, kw, received_ts, stream=True)
         rid = f"chatcmpl-{req.request_id}"
         def frame(item):
             delta = self.engine.tokenizer.decode([item])
@@ -95,9 +122,9 @@ class LLMServer:
         yield f"data: {json.dumps(done)}\n\n"
         yield "data: [DONE]\n\n"
 
-    def completions_stream(self, prompt: str, **kw):
-        sampling = _sampling_from(kw)
-        req = self.engine.submit(prompt, sampling, stream=True)
+    def completions_stream(self, prompt: str, *, received_ts: float = 0.0,
+                           **kw):
+        req = self._submit(prompt, kw, received_ts, stream=True)
         rid = f"cmpl-{req.request_id}"
         def frame(item):
             return {"id": rid, "object": "text_completion",
@@ -134,6 +161,10 @@ class LLMServer:
             ended = items[-1] is None
             if ended:
                 items.pop()
+                lag = time.time() - req.finish_ts
+                with self._lag_lock:
+                    self.last_frames += 1
+                    self.last_frame_lag_s += max(lag, 0.0)
             if items:
                 if first:
                     first = False
@@ -148,7 +179,11 @@ class LLMServer:
     def stats(self) -> dict:
         with self._lag_lock:
             own = {"first_frames": self.first_frames,
-                   "first_frame_lag_s": self.first_frame_lag_s}
+                   "first_frame_lag_s": self.first_frame_lag_s,
+                   "last_frames": self.last_frames,
+                   "last_frame_lag_s": self.last_frame_lag_s,
+                   "ingress_requests": self.ingress_requests,
+                   "ingress_s": self.ingress_s}
         return {**self.engine.stats(), **own}
 
     def router_prefix_blocks(self) -> dict | None:
@@ -174,6 +209,9 @@ class LLMServer:
                               "owned_by": "ray_tpu"}]}
         body = request.json() or {}
         stream = bool(body.pop("stream", False))
+        # The proxy's arrival stamp, in place of whatever a client sent
+        # under the parameter's name.
+        body["received_ts"] = request.received_ts
         if path.endswith("/v1/completions") or path == "/completions":
             prompt = body.pop("prompt", "")
             if stream:

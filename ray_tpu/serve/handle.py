@@ -24,6 +24,7 @@ from typing import Any
 import ray_tpu
 from ray_tpu.serve import resilience
 from ray_tpu.serve.long_poll import LongPollClient
+from ray_tpu.serve.replica import StampedChunk
 from ray_tpu.serve.router import Router
 from ray_tpu.util import tracing
 
@@ -117,12 +118,17 @@ class DeploymentResponse:
         # Request-root span: one trace for the whole request lifecycle —
         # every attempt (retries, hedges) parents under it, and the replica/
         # engine/DAG spans ride the propagated context. The head-sampling
-        # verdict is drawn HERE, once, and inherited everywhere downstream.
+        # verdict is drawn HERE, once, and inherited everywhere downstream;
+        # a call made inside a trace that has its verdict (under the HTTP
+        # proxy's root span, from a replica serving a traced request)
+        # inherits that one.
         self._span = None
         self._sampled: bool | None = None
         self._attempt_no = 0
         if ref is None and router is not None and tracing.tracing_enabled():
-            self._sampled = tracing.sample_request(_sample_rate(router))
+            self._sampled = tracing.current_sampled()
+            if self._sampled is None:
+                self._sampled = tracing.sample_request(_sample_rate(router))
             self._span = tracing.start_span(
                 router._trace_req_name, kind="client",
                 attributes={"deployment": router._deployment,
@@ -370,7 +376,10 @@ class DeploymentResponseGenerator:
     DeploymentResponseGenerator, handle.options(stream=True)). The first
     item from the replica is a meta dict ({"streaming": bool}); it is
     consumed here and exposed as ``.streaming``. ``timeout`` bounds the wait
-    for each chunk.
+    for each chunk. A chunk of a user generator comes framed with the time
+    the replica held it (replica.StampedChunk); the frame is taken off here,
+    ``__next__`` returns what the generator yielded, and the stamp of the
+    chunk returned last is ``last_chunk_ts`` (0.0 before any).
 
     Resilience: failures BEFORE the first user chunk re-route like unary
     retries (never-sent always, replica deaths within the policy budget) —
@@ -394,6 +403,7 @@ class DeploymentResponseGenerator:
         self._never_sent_used = False
         self._born = time.perf_counter()
         self._first_chunk_seen = False
+        self.last_chunk_ts = 0.0
 
     @property
     def meta(self) -> dict:
@@ -445,7 +455,10 @@ class DeploymentResponseGenerator:
                     # consumer a {"streaming": ...} payload AND swallow the
                     # real first chunk as meta on the next call.
                     self._meta = ray_tpu.get(self._gen._next(self.timeout))
-                return ray_tpu.get(self._gen._next(self.timeout))
+                item = ray_tpu.get(self._gen._next(self.timeout))
+                if isinstance(item, StampedChunk):
+                    self.last_chunk_ts, item = item
+                return item
             except StopIteration:
                 raise
             except BaseException as err:  # noqa: BLE001 - classified
@@ -646,6 +659,12 @@ class DeploymentHandle:
         return DeploymentResponse(router, self._method_name, args, kwargs,
                                   deadline=deadline, route_hint=hint,
                                   prefix_hashes=hashes)
+
+    def trace_sample_rate(self) -> float:
+        """The head-sampling rate of this deployment's requests, for a
+        caller that opens the request's root span itself (the HTTP
+        proxy) and so draws the verdict."""
+        return _sample_rate(self._ensure_router())
 
     def _ensure_router(self) -> Router:
         from ray_tpu.core.worker import global_worker

@@ -11,9 +11,12 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from dataclasses import dataclass, field
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qs, urlparse
+
+from ray_tpu.util import tracing
 
 
 @dataclass
@@ -26,6 +29,10 @@ class Request:
     query_params: dict[str, str] = field(default_factory=dict)
     headers: dict[str, str] = field(default_factory=dict)
     body: bytes = b""
+    # time.time() when the proxy took the request off its socket, before it
+    # read the body: where the request's way in starts (LLMServer books it
+    # against engine.submit as ``ingress_s``). 0.0: no proxy stamped it.
+    received_ts: float = 0.0
 
     def json(self):
         return json.loads(self.body) if self.body else None
@@ -54,6 +61,7 @@ class ProxyActor:
                 pass
 
             def _dispatch(self):
+                received = time.time()
                 parsed = urlparse(self.path)
                 route, dep = proxy._match(parsed.path)
                 if dep is None:
@@ -61,6 +69,34 @@ class ProxyActor:
                     self.end_headers()
                     self.wfile.write(b"no application at this route")
                     return
+                if not tracing.tracing_enabled():
+                    self._respond(parsed, route, dep, received)
+                    return
+                # The request's root span, arrival to close. The trace
+                # starts here, so the head-sampling verdict is drawn here,
+                # once: the handle's span opens under the root (the thread's
+                # context) and inherits it, as everything downstream does.
+                try:
+                    rate = proxy._get_handle(dep).trace_sample_rate()
+                except Exception:  # noqa: BLE001 - _respond reports it
+                    rate = 1.0
+                sampled = tracing.sample_request(rate)
+                with tracing.span("proxy.request", kind="server",
+                                  attributes={"path": parsed.path},
+                                  ctx={"sampled": sampled}) as root:
+                    root.start_ts = received
+                    status, chunks, size = self._respond(parsed, route, dep,
+                                                         received)
+                    root.attributes.update(status=status, chunks=chunks,
+                                           bytes=size)
+                    if status >= 500:
+                        root.status = f"ERROR: HTTP {status}"
+                if status >= 500 and not sampled:
+                    tracing.mark_keep(root.trace_id, "error")
+
+            def _respond(self, parsed, route, dep, received):
+                """Serve one request; (status, chunks streamed, their
+                bytes)."""
                 length = int(self.headers.get("Content-Length") or 0)
                 body = self.rfile.read(length) if length else b""
                 req = Request(
@@ -70,6 +106,7 @@ class ProxyActor:
                                   parse_qs(parsed.query).items()},
                     headers={k: v for k, v in self.headers.items()},
                     body=body,
+                    received_ts=received,
                 )
                 try:
                     hint = (self.headers.get("x-route-hint")
@@ -100,21 +137,7 @@ class ProxyActor:
                         self.send_header("Cache-Control", "no-cache")
                         self.send_header("Connection", "close")
                         self.end_headers()
-                        try:
-                            for chunk in gen:
-                                if isinstance(chunk, str):
-                                    chunk = chunk.encode()
-                                elif not isinstance(chunk,
-                                                    (bytes, bytearray)):
-                                    chunk = json.dumps(chunk).encode()
-                                self.wfile.write(chunk)
-                                self.wfile.flush()
-                        except Exception:  # noqa: BLE001
-                            # 200 + body already on the wire: terminate the
-                            # stream (connection close) — a second status
-                            # line would corrupt the client's event stream.
-                            pass
-                        return
+                        return (200, *self._stream(gen))
                     result = next(gen)
                 except Exception as e:  # noqa: BLE001 - mapped below
                     # Resilience-aware status mapping (reference: serve
@@ -134,23 +157,59 @@ class ProxyActor:
                         self.end_headers()
                         self.wfile.write(
                             f"overloaded ({cause.where})".encode())
-                        return
+                        return 503, 0, 0
                     if isinstance(cause, (resilience.DeadlineExceeded,
                                           TimeoutError)):
                         self.send_response(504)
                         self.end_headers()
                         self.wfile.write(b"request deadline exceeded")
-                        return
+                        return 504, 0, 0
                     self.send_response(500)
                     self.end_headers()
                     self.wfile.write(repr(e).encode())
-                    return
+                    return 500, 0, 0
                 status, ctype, payload = _encode(result)
                 self.send_response(status)
                 self.send_header("Content-Type", ctype)
                 self.send_header("Content-Length", str(len(payload)))
                 self.end_headers()
                 self.wfile.write(payload)
+                return status, 0, 0
+
+            def _stream(self, gen):
+                """A chunk's way out, timed where it ends: each chunk's
+                write is a ``serve.chunk_out`` phase whose ``lag_us`` is
+                the replica's stamp (``gen.last_chunk_ts``) to ``next(gen)``
+                returning here; the stream's end a ``serve.close`` whose
+                ``lag_us`` is the last chunk's stamp to StopIteration
+                reaching this thread: the end marker's way. Returns
+                (chunks written, their bytes)."""
+                chunks = size = 0
+                try:
+                    for chunk in gen:
+                        lag_us = _us_since(gen.last_chunk_ts)
+                        if isinstance(chunk, str):
+                            chunk = chunk.encode()
+                        elif not isinstance(chunk, (bytes, bytearray)):
+                            chunk = json.dumps(chunk).encode()
+                        with tracing.phase("serve.chunk_out", lag_us=lag_us,
+                                           bytes=len(chunk)):
+                            self.wfile.write(chunk)
+                            self.wfile.flush()
+                        chunks += 1
+                        size += len(chunk)
+                    # An instant: the end has reached this thread (the
+                    # handler closes the connection when it returns).
+                    with tracing.phase(
+                            "serve.close",
+                            lag_us=_us_since(gen.last_chunk_ts)):
+                        pass
+                except Exception:  # noqa: BLE001
+                    # 200 + body already on the wire: terminate the
+                    # stream (connection close) — a second status
+                    # line would corrupt the client's event stream.
+                    pass
+                return chunks, size
 
             do_GET = do_POST = do_PUT = do_DELETE = _dispatch
 
@@ -192,6 +251,12 @@ class ProxyActor:
 
     def shutdown(self) -> None:
         self._server.shutdown()
+
+
+def _us_since(stamp: float) -> int:
+    """Microseconds from a ``time.time()`` stamp to now; 0 for no stamp (a
+    chunk that came unframed) or a clock that stepped back."""
+    return max(int((time.time() - stamp) * 1e6), 0) if stamp else 0
 
 
 def _prefix_route_hint(body: bytes) -> str | None:

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any
+from typing import Any, NamedTuple
 
 from ray_tpu.serve.resilience import (
     DEADLINE_KEY,
@@ -69,6 +69,16 @@ def _get_replica_metrics():
                     tag_keys=("deployment",)),
             }
         return _replica_metrics
+
+
+class StampedChunk(NamedTuple):
+    """A user generator's chunk on its way from the replica to the handle,
+    framed with ``time.time()`` taken where the replica held it: the start
+    of the chunk's way out. DeploymentResponseGenerator takes the frame off
+    (``last_chunk_ts``); neither a handle's caller nor the wire sees it."""
+
+    ts: float
+    chunk: Any
 
 
 def _steps_in_context(gen, ctx: dict):
@@ -343,7 +353,8 @@ class ServeReplica:
         """TTFT on the first user chunk, TPOT on each inter-chunk gap, full
         latency at exhaustion — the streaming triple every serving
         comparison quotes. Each chunk counts toward the deployment's
-        SLO-attained tokens while the deadline holds."""
+        SLO-attained tokens while the deadline holds, and leaves framed
+        with the wall-clock time it left at (StampedChunk)."""
         last = None
         try:
             for chunk in gen:
@@ -358,7 +369,7 @@ class ServeReplica:
                     pass
                 self._count_slo_tokens(1, deadline)
                 last = now
-                yield chunk
+                yield StampedChunk(time.time(), chunk)
         finally:
             try:
                 self._b["latency"].observe(time.perf_counter() - t0,

@@ -34,6 +34,7 @@ off" arm of devbench/trace_bench.py).
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from collections import OrderedDict, deque
@@ -575,6 +576,11 @@ _annotation = None  # jax.profiler.TraceAnnotation; False: no JAX here
 def _trace_annotation():
     global _annotation
     if _annotation is None:
+        if "jax" not in sys.modules:
+            # Nothing here has imported JAX, so no profiler session can be
+            # open: a phase of a process that serves no model (a proxy's
+            # chunk) does not import it. Asked again next time.
+            return False
         try:
             from jax.profiler import TraceAnnotation
 
@@ -608,7 +614,9 @@ class _PhaseCM:
     def __enter__(self) -> "_PhaseCM":
         if self._ann is not None:
             self._ann.__enter__()
-        if _enabled:
+        # Inside a request that head sampling passed over, no span: the
+        # tail ring keeps a trace's skeleton (64 spans), not a span a chunk.
+        if _enabled and getattr(_ctx, "sampled", None) is not False:
             self._t0 = time.time()
         return self
 
@@ -659,10 +667,10 @@ def phase(name: str, **counts) -> _PhaseCM:
     phase lies on the device trace's own clock, between the programs it
     dispatched, with ``counts`` as the event's stats. With
     :func:`enable_tracing` on, the same interval is also recorded as a
-    :class:`Span`, under the thread's current trace or else under the
-    thread's own lane. Without JAX only the span remains. Phases are
-    milliseconds long and a handful a scheduler tick: not for per-token
-    work."""
+    :class:`Span`, under the thread's current trace (unless head sampling
+    passed that request over) or else under the thread's own lane. Without
+    JAX only the span remains. Phases are milliseconds long and a handful a
+    scheduler tick: not for per-token work."""
     ann = _trace_annotation()
     return _PhaseCM(name, counts, ann(name, **counts) if ann else None)
 

@@ -436,10 +436,19 @@ class TestTracingDrops:
     def test_flush_cursor_wraparound_meters_drops(self, monkeypatch):
         from ray_tpu.util import metrics, tracing
 
+        def exported() -> float | None:
+            # The registry is the process's: other tests have dropped spans
+            # into this counter, so it is read before and after.
+            for e in metrics.registry().snapshot()["metrics"]:
+                if e["name"] == "tracing_spans_dropped":
+                    return e["points"][0][1]
+            return None
+
         tracing.clear()
         monkeypatch.setattr(tracing, "_spans", deque(maxlen=4))
         monkeypatch.setattr(tracing, "_spans_total", 0)
         monkeypatch.setattr(tracing, "_dropped_metered", 0)
+        before = exported() or 0.0
         tracing.enable_tracing()
         try:
             for i in range(6):
@@ -456,12 +465,9 @@ class TestTracingDrops:
             # Idempotent metering: a second flush adds no phantom drops.
             _, cursor = tracing.flush_new(cursor)
             assert tracing.dropped_spans() == 2
-            for e in metrics.registry().snapshot()["metrics"]:
-                if e["name"] == "tracing_spans_dropped":
-                    assert e["points"][0][1] == pytest.approx(2.0)
-                    break
-            else:
-                pytest.fail("tracing_spans_dropped not exported")
+            after = exported()
+            assert after is not None, "tracing_spans_dropped not exported"
+            assert after - before == pytest.approx(2.0)
         finally:
             tracing.disable_tracing()
             tracing.clear()

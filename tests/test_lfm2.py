@@ -26,6 +26,7 @@ from ray_tpu.llm.served import served_model
 from ray_tpu.models import lfm2, routed
 from ray_tpu.models.lfm2 import ATTENTION, CONV, Lfm2Config, Segment
 from ray_tpu.ops.kernels import force_kernel_backend
+from ray_tpu.util import tracing
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmark")
@@ -541,9 +542,21 @@ def test_chunks_that_ride_leave_every_answer_as_it_was(monkeypatch, pipeline):
         finally:
             eng.shutdown()
 
-    with_entry, stats = run(recording)
+    tracing.clear()
+    tracing.enable_tracing()
+    try:
+        with_entry, stats = run(recording)
+        dispatches = [s.attributes for s in tracing.spans()
+                      if s.name == "engine.decode_dispatch"]
+    finally:
+        tracing.disable_tracing()
+        tracing.clear()
     without, plain = run(None)
     assert with_entry == without
+    # each burst's dispatch phase says how many of its steps took a chunk
+    assert sum(d["riders"] for d in dispatches) == \
+        stats["prefill_chunks_riding"]
+    assert all(0 <= d["riders"] <= d["steps"] for d in dispatches)
     chunks = sum(-(-len(p) // 32) for p in prompts)
     assert stats["prefill_chunks"] == plain["prefill_chunks"] == chunks
     assert stats["prompt_tokens_prefilled"] == \

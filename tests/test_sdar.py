@@ -790,8 +790,10 @@ def test_a_block_s_tokens_reach_the_client_as_one_chunk_of_frames():
     server = object.__new__(getattr(LLMServer, "func_or_class", LLMServer))
     server._lag_lock = threading.Lock()
     server.first_frames, server.first_frame_lag_s = 0, 0.0
+    server.last_frames, server.last_frame_lag_s = 0, 0.0
     req = types.SimpleNamespace(stream_queue=queue.Queue(),
-                                first_token_ts=time.time())
+                                first_token_ts=time.time(),
+                                finish_ts=time.time())
     for tok in (11, 12, 13):             # a first block of three
         req.stream_queue.put(tok)
     chunks = server._stream_tokens(req)
@@ -801,6 +803,9 @@ def test_a_block_s_tokens_reach_the_client_as_one_chunk_of_frames():
     assert next(chunks) == [21, 22, 23, 24]
     assert list(chunks) == []
     assert server.first_frames == 1
+    # the end is counted where it is taken off the queue, once a stream
+    assert server.last_frames == 1 and server.last_frame_lag_s >= 0.0
     # the end alone yields nothing
     req.stream_queue.put(None)
     assert list(server._stream_tokens(req)) == []
+    assert server.last_frames == 2 and server.first_frames == 1
