@@ -11,8 +11,9 @@ parent's laid in ``.parent/``) in a process of its own, ``--spans`` with
 ``tracing.enable_tracing()`` on and every request sampled, or the share
 ``RATE`` of them (what the request spans cost: compare ``tpot_mean_ms`` or
 ``serve_tok_s`` with a run without it, same seed), ``--watch`` with a thread that keeps the router's own
-metrics (``serve_router_queue_wait_s``, ``serve_breaker_transitions_total``:
-the whole run's, warm-up and ramp among it) for the line. It writes one JSON
+metrics (``serve_router_queue_wait_s``, ``serve_breaker_transitions_total``
+in all and by ``reason``: the whole run's, warm-up and ramp among it) for the
+line. It writes one JSON
 file a run under ``chiprun_out/serve_path/`` (metrics, the counters of the
 budgets, the budgets of a traced run) and prints it. Needs the chips the cell needs:
 
@@ -59,6 +60,14 @@ def watch(path):
                 got["router_wait_boundaries"] = e["boundaries"]
             elif e["name"] == "serve_breaker_transitions_total":
                 got["breaker_opens"] = sum(v for _, v in e["points"])
+                # by the rule that opened it (PR 51); a tree without the
+                # tag gives them all under "untagged"
+                at = (e["tag_keys"].index("reason")
+                      if "reason" in e["tag_keys"] else None)
+                by = got["breaker_opens_by_reason"] = {}
+                for key, v in e["points"]:
+                    r = "untagged" if at is None else key[at]
+                    by[r] = by.get(r, 0) + v
         with open(path + ".tmp", "w") as f:
             json.dump(got, f)
         os.replace(path + ".tmp", path)

@@ -61,7 +61,8 @@ class DeploymentConfig:
     # policy retries; never-sent failures are still retried once.
     retry_policy: RetryPolicy = field(default_factory=RetryPolicy)
     # Per-replica circuit breaker (consecutive failures / latency outlier
-    # → blacklist with half-open recovery probes).
+    # among the replicas that serve the same method → blacklist with
+    # half-open recovery probes).
     circuit_breaker: CircuitBreakerConfig = field(
         default_factory=CircuitBreakerConfig)
     # Head-sampling rate for request tracing, per deployment: fraction of

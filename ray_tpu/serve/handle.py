@@ -390,8 +390,9 @@ class DeploymentResponseGenerator:
 
     def __init__(self, ref_gen, on_done=None, timeout: float = 60.0,
                  router: Router | None = None, replica_id: str = "",
-                 resubmit=None):
+                 resubmit=None, method: str = ""):
         self._gen = ref_gen
+        self._method = method  # names the breaker's first-chunk sample
         self._meta = None
         self._on_done = on_done
         self.timeout = timeout
@@ -442,7 +443,8 @@ class DeploymentResponseGenerator:
             self._first_chunk_seen = True
             if self._router is not None and self._rid:
                 self._router.record_stream_outcome(
-                    self._rid, True, time.perf_counter() - self._born)
+                    self._rid, True, time.perf_counter() - self._born,
+                    self._method)
         return chunk
 
     def _next_chunk(self, for_meta: bool = False) -> Any:
@@ -654,7 +656,7 @@ class DeploymentHandle:
                 router.count_retry()
             return DeploymentResponseGenerator(
                 gen, on_done=on_done, router=router, replica_id=rid,
-                resubmit=resubmit,
+                resubmit=resubmit, method=method,
                 timeout=timeout_s if timeout_s is not None else 60.0)
         return DeploymentResponse(router, self._method_name, args, kwargs,
                                   deadline=deadline, route_hint=hint,

@@ -32,7 +32,12 @@ systems implement server-side):
   cooldown a bounded number of half-open probes decide between closing it
   and re-opening. Open events feed the controller's health checker so a
   sick-but-alive replica is probed (and replaced) instead of eating
-  traffic until its next scheduled check.
+  traffic until its next scheduled check. A latency outlier is one among
+  peers that serve the same call: a replica's recent latencies of a method
+  (a stream's time to first chunk under the stream's method) are held
+  against those of the deployment's OTHER replicas on that method, never
+  against its own history or another method's. A deployment of one
+  replica has no peer, so it never opens on latency.
 """
 
 from __future__ import annotations
@@ -125,10 +130,14 @@ class CircuitBreakerConfig:
     - ``half_open_probes``: concurrent trial requests allowed half-open;
       one success closes the breaker, one failure re-opens it.
     - ``latency_factor`` / ``latency_min_samples``: latency-outlier trip —
-      a replica whose rolling median exceeds ``latency_factor`` × the
-      deployment-wide rolling median (with at least ``latency_min_samples``
-      of its own samples) is treated as sick even though calls succeed
-      (the slow-replica mode a liveness health check never catches).
+      a replica whose median over its last ``latency_min_samples`` calls
+      of a method exceeds ``latency_factor`` × its peers' (the median of
+      the other replicas' medians over their own last
+      ``latency_min_samples`` calls of that method) is treated as sick
+      even though calls succeed (the slow-replica mode a liveness health
+      check never catches). A replica with fewer samples of the method is
+      neither judged nor a yardstick; where no peer has enough, nothing
+      trips.
     """
 
     enabled: bool = True
@@ -228,25 +237,47 @@ def _set_current_deadline(deadline: float | None,
 # ---------------------------------------------------------------- breaker
 
 _CLOSED, _OPEN, _HALF_OPEN = "closed", "open", "half_open"
-_LATENCY_WINDOW = 64  # rolling samples kept per replica / deployment-wide
+_LATENCY_WINDOW = 64  # rolling samples kept per replica and method
+# How an open reason begins, by the rule that opened the breaker: what
+# open_reason_kind reads (consecutive failures begin with their count).
+_REASON_LATENCY, _REASON_PROBE = "latency outlier", "half-open probe failed"
 
 
 class _ReplicaBreaker:
     __slots__ = ("state", "consecutive_failures", "open_until", "probes_out",
-                 "latencies", "opens")
+                 "latencies", "recent", "opens")
 
     def __init__(self):
         self.state = _CLOSED
         self.consecutive_failures = 0
         self.open_until = 0.0
         self.probes_out = 0
-        self.latencies: deque[float] = deque(maxlen=_LATENCY_WINDOW)
+        # method name -> that call's rolling latencies on this replica,
+        # and the median of the last latency_min_samples of them (absent
+        # while there are fewer): what the replica is judged by, and what
+        # its peers are judged against. Kept as each sample lands, so that
+        # a completion costs one sort of its own replica's samples however
+        # many peers the deployment has.
+        self.latencies: dict[str, deque[float]] = {}
+        self.recent: dict[str, float] = {}
         self.opens = 0  # lifetime open transitions (metrics/tests)
 
 
 def _median(values) -> float | None:
     vals = sorted(values)
     return vals[len(vals) // 2] if vals else None
+
+
+def open_reason_kind(reason: str) -> str:
+    """Which rule opened a breaker, from the reason ``on_open`` was given:
+    ``latency`` (an outlier among its peers), ``probe`` (a half-open probe
+    failed) or ``failures`` (consecutive failures). The ``reason`` tag of
+    ``serve_breaker_transitions_total``."""
+    if reason.startswith(_REASON_LATENCY):
+        return "latency"
+    if reason.startswith(_REASON_PROBE):
+        return "probe"
+    return "failures"
 
 
 class CircuitBreaker:
@@ -265,7 +296,6 @@ class CircuitBreaker:
         self.on_open = on_open
         self._lock = threading.Lock()
         self._replicas: dict[str, _ReplicaBreaker] = {}
-        self._fleet_latencies: deque[float] = deque(maxlen=_LATENCY_WINDOW * 4)
         # Sticky "has ANY breaker ever opened" flag, read without the lock:
         # per-request probes (the tracing tail-keep verdict checks every
         # tried replica) skip the lock entirely in the healthy steady
@@ -342,15 +372,31 @@ class CircuitBreaker:
             return sum(1 for rb in self._replicas.values()
                        if rb.state == _OPEN and now < rb.open_until)
 
-    def record_success(self, replica_id: str, latency_s: float) -> None:
+    def record_success(self, replica_id: str, latency_s: float,
+                       method: str = "") -> None:
+        """One healthy answer. ``latency_s`` is a sample of ``method`` (a
+        stream's is its time to first chunk): latencies are compared
+        method by method, a ``generate`` never with a ``stats``."""
         if not self.config.enabled:
             return
         trip = None
         with self._lock:
             rb = self._get(replica_id)
             rb.consecutive_failures = 0
-            rb.latencies.append(latency_s)
-            self._fleet_latencies.append(latency_s)
+            samples = rb.latencies.get(method)
+            if samples is None:
+                samples = rb.latencies[method] = deque(
+                    maxlen=_LATENCY_WINDOW)
+            samples.append(latency_s)
+            # Median over the most RECENT min_samples only: a replica that
+            # turns slow must trip after min_samples slow requests — judged
+            # over the full window, a long fast history would mask the
+            # degradation until half the window had churned.
+            n = max(self.config.latency_min_samples, 1)
+            if len(samples) >= n:
+                rb.recent[method] = _median(list(samples)[-n:])
+            else:
+                rb.recent.pop(method, None)
             if rb.state == _HALF_OPEN:
                 # One good probe closes the breaker (reference behavior:
                 # a single trial success restores traffic; the failure
@@ -358,7 +404,7 @@ class CircuitBreaker:
                 rb.state = _CLOSED
                 rb.probes_out = 0
             elif rb.state == _CLOSED:
-                trip = self._latency_outlier_locked(rb)
+                trip = self._latency_outlier_locked(replica_id, method)
                 if trip:
                     self._open_locked(replica_id, rb)
         if trip and self.on_open is not None:
@@ -373,7 +419,7 @@ class CircuitBreaker:
             rb.consecutive_failures += 1
             if rb.state == _HALF_OPEN:
                 # Failed probe: straight back to open, fresh cooldown.
-                reason = "half-open probe failed"
+                reason = _REASON_PROBE
                 self._open_locked(replica_id, rb)
             elif rb.state == _CLOSED and \
                     rb.consecutive_failures >= self.config.failure_threshold:
@@ -382,21 +428,24 @@ class CircuitBreaker:
         if reason and self.on_open is not None:
             self.on_open(replica_id, reason)
 
-    def _latency_outlier_locked(self, rb: _ReplicaBreaker) -> str | None:
-        cfg = self.config
-        if len(rb.latencies) < cfg.latency_min_samples:
+    def _latency_outlier_locked(self, replica_id: str,
+                                method: str) -> str | None:
+        mine = self._replicas[replica_id].recent.get(method)
+        if mine is None:
             return None
-        fleet = _median(self._fleet_latencies)
-        # Median over the most RECENT min_samples only: a replica that
-        # turns slow must trip after min_samples slow requests — judged
-        # over the full window, a long fast history would mask the
-        # degradation until half the window had churned.
-        mine = _median(list(rb.latencies)[-cfg.latency_min_samples:])
-        if fleet is None or mine is None or fleet <= 0:
+        # The yardstick is the OTHER replicas' recent behaviour on the same
+        # call. The judged replica's own samples are no part of it: held
+        # against a pool it fills itself, a lone replica is an outlier of
+        # its own history whenever slow calls arrive in a clump.
+        peers = _median(rb.recent[method]
+                        for rid, rb in self._replicas.items()
+                        if rid != replica_id and method in rb.recent)
+        if peers is None or peers <= 0:
             return None
-        if mine > cfg.latency_factor * fleet:
-            return (f"latency outlier: median {mine * 1e3:.0f} ms vs fleet "
-                    f"{fleet * 1e3:.0f} ms (> {cfg.latency_factor}x)")
+        factor = self.config.latency_factor
+        if mine > factor * peers:
+            return (f"{_REASON_LATENCY}: median {mine * 1e3:.0f} ms vs peers "
+                    f"{peers * 1e3:.0f} ms (> {factor}x)")
         return None
 
     def _open_locked(self, replica_id: str, rb: _ReplicaBreaker) -> None:
@@ -408,6 +457,7 @@ class CircuitBreaker:
         # A latency-tripped replica's samples are stale once it recovers;
         # drop them so a healed replica isn't re-tripped by history.
         rb.latencies.clear()
+        rb.recent.clear()
 
     def forget(self, live_replica_ids) -> None:
         """Drop state for replicas no longer published (controller replaced

@@ -14,8 +14,9 @@ the request-resilience layer (ray_tpu/serve/resilience.py):
 - the choose loop never picks a draining replica, a replica the caller
   already tried (retry exclusion), or one whose circuit breaker is open;
 - per-replica breakers track consecutive failures and latency outliers
-  from the completion watcher, blacklist sick replicas with half-open
-  recovery probes, and nudge the controller's health check on open.
+  (a replica against its peers, method by method) from the completion
+  watcher, blacklist sick replicas with half-open recovery probes, and
+  nudge the controller's health check on open.
 
 KV-block-aware prefix routing (reference: serve prefix-aware routing
 policy + vLLM prefix caching): replicas publish the chain hashes of the
@@ -54,6 +55,7 @@ from ray_tpu.serve.resilience import (
     DeadlineExceeded,
     Overloaded,
     ResilienceSettings,
+    open_reason_kind,
     shed_metrics,
 )
 from ray_tpu.util import tracing
@@ -102,7 +104,7 @@ def _get_router_metrics():
             "breaker_transitions": Counter(
                 "serve_breaker_transitions_total",
                 "circuit breaker open transitions",
-                tag_keys=("deployment", "replica")),
+                tag_keys=("deployment", "replica", "reason")),
             "breaker_open": Gauge(
                 "serve_breaker_open_replicas",
                 "replicas currently blacklisted by the circuit breaker",
@@ -130,7 +132,8 @@ class _CompletionReaper:
     def __init__(self, router: "Router"):
         self._router = router
         self._cv = threading.Condition()
-        self._pending: dict = {}  # ref -> (rid, t_submit, is_probe)
+        # ref -> (rid, method, t_submit, is_probe)
+        self._pending: dict = {}
         self._stopped = False
         self._obs_backlog = 0  # guarded by _cv
         # Observation pool: outcome gets are usually instant (actor
@@ -143,9 +146,10 @@ class _CompletionReaper:
             name=f"serve-reaper-{router._deployment}")
         self._thread.start()
 
-    def add(self, ref, rid: str, t_submit: float, is_probe: bool) -> None:
+    def add(self, ref, rid: str, method: str, t_submit: float,
+            is_probe: bool) -> None:
         with self._cv:
-            self._pending[ref] = (rid, t_submit, is_probe)
+            self._pending[ref] = (rid, method, t_submit, is_probe)
             self._cv.notify()
 
     def stop(self) -> None:
@@ -200,7 +204,7 @@ class _CompletionReaper:
                     rec = self._pending.pop(ref, None)
                     if rec is not None:
                         done.append((ref, rec))
-            for ref, (rid, t_submit, is_probe) in done:
+            for ref, (rid, method, t_submit, is_probe) in done:
                 # Release first: _settle may block on a result fetch, and
                 # parked callers must not wait out that fetch for a slot
                 # the replica already freed.
@@ -213,15 +217,15 @@ class _CompletionReaper:
                     router._settle_neutral(rid, is_probe)
                     continue
                 try:
-                    self._observe.submit(self._settle_one, ref, rid,
+                    self._observe.submit(self._settle_one, ref, rid, method,
                                          now - t_submit, is_probe)
                 except RuntimeError:  # shutting down
                     return
 
-    def _settle_one(self, ref, rid: str, latency: float,
+    def _settle_one(self, ref, rid: str, method: str, latency: float,
                     is_probe: bool) -> None:
         try:
-            self._router._settle(ref, rid, latency, is_probe)
+            self._router._settle(ref, rid, method, latency, is_probe)
         finally:
             with self._cv:
                 self._obs_backlog -= 1
@@ -238,7 +242,7 @@ class _CompletionReaper:
                 with self._cv:
                     rec = self._pending.pop(ref, None)
                 if rec is not None:
-                    rid, _, is_probe = rec
+                    rid, _, _, is_probe = rec
                     self._router._release(rid)
                     self._router._settle_neutral(rid, is_probe)
 
@@ -317,7 +321,8 @@ class Router:
     def _on_breaker_open(self, replica_id: str, reason: str) -> None:
         try:
             self._mtr["breaker_transitions"].inc(
-                tags={"deployment": self._deployment, "replica": replica_id})
+                tags={"deployment": self._deployment, "replica": replica_id,
+                      "reason": open_reason_kind(reason)})
             self._m_breaker_open.set(self.breaker.open_count())
         except Exception:
             pass
@@ -576,7 +581,8 @@ class Router:
             self._submit_failed(rid, is_probe)
             raise
 
-        self._get_reaper().add(ref, rid, time.perf_counter(), is_probe)
+        self._get_reaper().add(ref, rid, method_name, time.perf_counter(),
+                               is_probe)
         return ref, rid
 
     def _trace_point(self, trace_ctx: dict | None, name: str,
@@ -597,15 +603,17 @@ class Router:
             self.breaker.cancel_probe(rid)
         self.breaker.record_failure(rid)
 
-    def _settle(self, ref, rid: str, latency: float, is_probe: bool) -> None:
-        """Breaker bookkeeping for one completed unary call (runs on the
-        reaper's observation pool; the slot was already released)."""
+    def _settle(self, ref, rid: str, method: str, latency: float,
+                is_probe: bool) -> None:
+        """Breaker bookkeeping for one completed unary call of ``method``
+        (runs on the reaper's observation pool; the slot was already
+        released)."""
         outcome = None
         try:
             outcome = self._observe_outcome(ref)
         finally:
             if outcome is True:
-                self.breaker.record_success(rid, latency)
+                self.breaker.record_success(rid, latency, method)
             elif outcome is False:
                 self.breaker.record_failure(rid)
             elif is_probe:
@@ -660,13 +668,16 @@ class Router:
     # ----------------------------------------------------------- feedback
 
     def record_stream_outcome(self, replica_id: str, ok: bool,
-                              latency_s: float | None = None) -> None:
+                              latency_s: float | None = None,
+                              method: str = "") -> None:
         """Breaker feedback for streaming calls: the generator wrapper
-        reports first-chunk success (with TTFT as the latency sample) or a
-        mid-stream failure (the completion watcher can't see stream
-        errors — they surface in the consumer)."""
+        reports first-chunk success (with TTFT as the latency sample of
+        ``method``, which the breaker holds against the peers' first
+        chunks of the same method and nothing else) or a mid-stream
+        failure (the completion watcher can't see stream errors — they
+        surface in the consumer)."""
         if ok:
-            self.breaker.record_success(replica_id, latency_s or 0.0)
+            self.breaker.record_success(replica_id, latency_s or 0.0, method)
         else:
             self.breaker.record_failure(replica_id)
         self._refresh_breaker_gauge()

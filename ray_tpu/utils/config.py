@@ -294,7 +294,8 @@ class Config:
     #   circuit_breaker (CircuitBreakerConfig): failure_threshold (3)
     #     consecutive failures → open; open_s (2.0) cooldown;
     #     half_open_probes (1) trial requests; latency_factor (5.0) /
-    #     latency_min_samples (16) latency-outlier trip vs fleet median.
+    #     latency_min_samples (16) latency-outlier trip, a replica's
+    #     recent median on a method vs its peers' on the same method.
 
     # --- serve inference fast path (KV-block-aware prefix routing +
     #     disaggregated P/D KV hand-off; serve/prefix.py, serve/router.py,

@@ -21,6 +21,7 @@ from ray_tpu.serve.resilience import (
     ResilienceSettings,
     RetryPolicy,
     classify,
+    open_reason_kind,
 )
 from ray_tpu.serve.router import Router
 
@@ -118,6 +119,227 @@ class TestCircuitBreaker:
         assert cb.is_open("kept")
 
 
+def _feed_docqa_mix(record, clump):
+    """docqa's traffic on one replica as the breaker saw it: the poller's
+    ``stats`` calls of 1 ms, then ``clump`` first chunks of 2.4 s between
+    two polls (16 closed-loop clients at a window's start)."""
+    for _ in range(100):
+        record(0.001, "stats")
+    for _ in range(clump):
+        record(2.4, "generate")
+
+
+class TestLatencyOutlierAmongPeers:
+    """A replica is a latency outlier only among peers that serve the same
+    call (PR 51): the yardstick is the OTHER replicas' recent samples of
+    the same method, never the replica's own history or another method."""
+
+    @pytest.mark.parametrize("clump", [8, 12, 16])
+    @pytest.mark.parametrize("by_method", [True, False],
+                             ids=["by_method", "one_method"])
+    def test_a_fleet_of_one_never_opens_on_latency(self, clump, by_method):
+        """The clump sizes that opened the old rule ("median 2400 ms vs
+        fleet 1 ms"), with the samples named by their call and, as a
+        caller that names none would feed them, all under one name."""
+        cb = CircuitBreaker(CircuitBreakerConfig())
+        opened = []
+        cb.on_open = lambda rid, reason: opened.append((rid, reason))
+        _feed_docqa_mix(
+            lambda lat, m: cb.record_success("r0", lat,
+                                             m if by_method else ""),
+            clump)
+        assert not opened and cb.state("r0") == "closed"
+        assert cb.allow("r0")
+
+    @pytest.mark.parametrize("clump", [8, 12, 16])
+    def test_two_healthy_replicas_under_one_mix_never_open_each_other(
+            self, clump):
+        """One replica's last 16 hold a clump of first chunks while the
+        other's hold polls: alike by method, they are no outliers."""
+        cb = CircuitBreaker(CircuitBreakerConfig())
+        opened = []
+        cb.on_open = lambda rid, reason: opened.append((rid, reason))
+        for _ in range(3):  # several rounds, the replicas out of step
+            _feed_docqa_mix(
+                lambda lat, m: cb.record_success("r0", lat, m), clump)
+            for _ in range(40):
+                cb.record_success("r1", 0.001, "stats")
+            _feed_docqa_mix(
+                lambda lat, m: cb.record_success("r1", lat, m), clump)
+        assert not opened
+        assert cb.state("r0") == cb.state("r1") == "closed"
+
+    def test_a_replica_slow_on_one_method_opens_within_min_samples(self):
+        cfg = CircuitBreakerConfig()
+        cb = CircuitBreaker(cfg)
+        opened = []
+        cb.on_open = lambda rid, reason: opened.append((rid, reason))
+        for rid in ("r0", "r1"):
+            for _ in range(40):
+                cb.record_success(rid, 0.001, "stats")
+                cb.record_success(rid, 0.05, "generate")
+        assert not opened
+        # r1 turns 50 x slower on generate alone; its stats stay fast and
+        # keep arriving between the slow calls.
+        slow_calls = 0
+        while not opened and slow_calls < 2 * cfg.latency_min_samples:
+            cb.record_success("r1", 2.5, "generate")
+            slow_calls += 1
+            cb.record_success("r1", 0.001, "stats")
+            cb.record_success("r0", 0.05, "generate")
+        assert [rid for rid, _ in opened] == ["r1"]
+        assert slow_calls <= cfg.latency_min_samples
+        assert open_reason_kind(opened[0][1]) == "latency"
+        assert cb.is_open("r1") and not cb.is_open("r0")
+
+    def test_five_times_slower_is_the_threshold(self):
+        """Just over ``latency_factor`` x the peers' median opens; just
+        under it does not."""
+        for slow, expect_open in ((0.051, True), (0.049, False)):
+            cb = CircuitBreaker(CircuitBreakerConfig())
+            for _ in range(16):
+                cb.record_success("r0", 0.01, "m")
+                cb.record_success("r1", 0.01, "m")
+            for _ in range(16):
+                cb.record_success("r1", slow, "m")
+            assert cb.is_open("r1") is expect_open, slow
+            assert not cb.is_open("r0")
+
+    def test_a_peer_with_too_few_samples_is_no_yardstick(self):
+        cfg = CircuitBreakerConfig()
+        cb = CircuitBreaker(cfg)
+        for _ in range(cfg.latency_min_samples - 1):
+            cb.record_success("r0", 0.01, "generate")
+        for _ in range(64):  # r0 has plenty of another call: no matter
+            cb.record_success("r0", 0.001, "stats")
+        for _ in range(2 * cfg.latency_min_samples):
+            cb.record_success("r1", 2.5, "generate")
+        assert not cb.is_open("r1")  # nobody to be an outlier among
+        cb.record_success("r0", 0.01, "generate")  # r0's 16th sample
+        cb.record_success("r1", 2.5, "generate")
+        assert cb.is_open("r1")
+
+    def test_the_judged_replica_is_no_part_of_its_own_yardstick(self):
+        """A slow replica that takes three calls of four would fill a
+        pooled yardstick with its own samples and hide behind them; held
+        against its peer alone it opens once the peer has 16 samples."""
+        cb = CircuitBreaker(CircuitBreakerConfig())
+        for i in range(16):
+            assert not cb.is_open("r1"), i
+            for _ in range(3):
+                cb.record_success("r1", 1.0, "m")
+            cb.record_success("r0", 0.01, "m")
+        cb.record_success("r1", 1.0, "m")
+        assert cb.is_open("r1") and not cb.is_open("r0")
+
+    def test_an_opened_replica_s_samples_are_dropped_method_by_method(self):
+        cb = CircuitBreaker(CircuitBreakerConfig(open_s=0.05))
+        for _ in range(16):
+            cb.record_success("r0", 0.01, "m")
+            cb.record_success("r1", 0.01, "m")
+            cb.record_success("r1", 0.01, "other")
+        for _ in range(16):
+            cb.record_success("r1", 1.0, "m")
+        assert cb.is_open("r1")
+        time.sleep(0.07)
+        assert cb.allow("r1")  # half-open probe
+        cb.record_success("r1", 0.01, "m")
+        assert cb.state("r1") == "closed"
+        # Healed: one slow call must not re-trip it on the old history,
+        # and it is no yardstick for r0 until it has 16 samples again.
+        cb.record_success("r1", 1.0, "m")
+        for _ in range(16):
+            cb.record_success("r0", 1.0, "m")
+        assert not cb.is_open("r1") and not cb.is_open("r0")
+
+    def test_forget_drops_a_gone_replica_s_per_method_samples(self):
+        cfg = CircuitBreakerConfig()
+        cb = CircuitBreaker(cfg)
+        for _ in range(cfg.latency_min_samples):
+            cb.record_success("gone", 0.01, "generate")
+            cb.record_success("kept", 0.01, "generate")
+        cb.forget(["kept"])
+        assert "gone" not in cb._replicas
+        # With its only peer gone, "kept" has nobody to be slower than...
+        for _ in range(2 * cfg.latency_min_samples):
+            cb.record_success("kept", 2.5, "generate")
+        assert not cb.is_open("kept")
+        # ...and a replica of the old name starts from no samples.
+        cb.record_success("gone", 0.01, "generate")
+        cb.record_success("kept", 2.5, "generate")
+        assert not cb.is_open("kept")
+
+    def test_samples_hold_under_threads_that_record_and_forget(self):
+        """More threads than cores record two methods on four replicas
+        while one keeps forgetting a replica: no thread raises (a peer
+        walk over a dict another thread resizes would), and what is left
+        is whole: each replica's cached median is the median of its last
+        16 samples of that method."""
+        import sys
+
+        cb = CircuitBreaker(CircuitBreakerConfig(failure_threshold=10**9))
+        stop = time.monotonic() + 0.5
+        errors = []
+
+        def record(i):
+            try:
+                n = 0
+                while time.monotonic() < stop:
+                    n += 1
+                    cb.record_success(f"r{(i + n) % 4}", 0.01 + 1e-6 * n,
+                                      "generate" if n % 3 else "stats")
+            except Exception as e:  # noqa: BLE001 - the test's finding
+                errors.append(e)
+
+        def churn():
+            try:
+                while time.monotonic() < stop:
+                    cb.forget(["r0", "r1", "r2"])
+                    cb.state("r3")
+            except Exception as e:  # noqa: BLE001
+                errors.append(e)
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=record, args=(i,))
+                       for i in range(16)] + [threading.Thread(target=churn)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=10)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in threads) and not errors
+        assert not any(cb.is_open(f"r{i}") for i in range(4))
+        for rb in cb._replicas.values():
+            for method, samples in rb.latencies.items():
+                assert len(samples) <= 64
+                if len(samples) >= 16:
+                    last = sorted(list(samples)[-16:])
+                    assert rb.recent[method] == last[8]
+                else:
+                    assert method not in rb.recent
+
+    def test_every_opening_s_reason_has_its_kind(self):
+        """The reasons the breaker really gives, not copies of them."""
+        cb = CircuitBreaker(CircuitBreakerConfig(
+            failure_threshold=2, open_s=0.05, latency_min_samples=4))
+        opened = []
+        cb.on_open = lambda rid, reason: opened.append(
+            open_reason_kind(reason))
+        cb.record_failure("r0")
+        cb.record_failure("r0")
+        time.sleep(0.07)
+        assert cb.allow("r0")
+        cb.record_failure("r0")
+        for _ in range(4):
+            cb.record_success("r1", 0.01, "m")
+        for _ in range(4):
+            cb.record_success("r2", 1.0, "m")
+        assert opened == ["failures", "probe", "latency"]
+
+
 # ------------------------------------------------------------- router unit
 class TestRouterChurn:
     """Router behavior under replica churn: draining/blacklisted exclusion,
@@ -198,6 +420,83 @@ class TestRouterChurn:
         assert router._choose_locked(reps) is None  # probe budget spent
         router.breaker.record_success("r0", 0.01)
         assert router._choose_locked(reps) is not None  # closed again
+
+    @pytest.mark.parametrize("clump", [8, 12, 16])
+    def test_a_lone_replica_s_first_chunks_park_nobody(self, monkeypatch,
+                                                       clump):
+        """docqa's mix through a Router with one published replica: the
+        poller's unary ``stats`` completions, then a clump of streams'
+        first chunks. The next request is assigned at once, and the
+        router's own histogram holds no wait over 0.1 s."""
+        from ray_tpu.util.metrics import registry
+
+        dep = f"docqa-{clump}"
+        reps = [ReplicaInfo(replica_id="r0", deployment_name=dep,
+                            actor_name="a0", max_ongoing_requests=100)]
+        reported = []
+        router = Router(dep, lambda: reps,
+                        report_unhealthy=lambda *a: reported.append(a))
+        _patch_submission(monkeypatch)
+        monkeypatch.setattr(ray_tpu, "get", lambda ref, **k: None)
+        _feed_docqa_mix(
+            lambda lat, m: (
+                router._settle(_FakeRef(), "r0", m, lat, False)
+                if m == "stats" else
+                router.record_stream_outcome("r0", True, lat, m)),
+            clump)
+        assert router.breaker.state("r0") == "closed" and not reported
+        t0 = time.monotonic()
+        for method in ("generate", "stats"):
+            _, rid = router.assign_request(method, (), {}, timeout=5.0)
+            assert rid == "r0"
+        assert time.monotonic() - t0 < 0.1
+        deadline = time.monotonic() + 2
+        while time.monotonic() < deadline and router.metrics().get("r0"):
+            time.sleep(0.01)
+        router.close()
+        # The reaper's completions are samples of the methods assigned.
+        assert set(router.breaker._replicas["r0"].latencies) == \
+            {"stats", "generate"}
+        wait = next(e for e in registry().snapshot()["metrics"]
+                    if e["name"] == "serve_router_queue_wait_s")
+        over = wait["boundaries"].index(0.1) + 1
+        mine = [b for key, b in wait["buckets"] if key == [dep]]
+        assert mine and sum(mine[0]) == 2 and not any(mine[0][over:])
+
+    def test_a_slow_replica_among_peers_is_counted_by_reason(
+            self, monkeypatch):
+        """Through the Router: the opening is reported to the controller as
+        before and ``serve_breaker_transitions_total`` carries which rule
+        opened it."""
+        from ray_tpu.serve.router import _get_router_metrics
+
+        dep = "two-replicas-one-slow"
+        reps = [ReplicaInfo(replica_id=f"r{i}", deployment_name=dep,
+                            actor_name=f"a{i}", max_ongoing_requests=100)
+                for i in range(2)]
+        reported = []
+        router = Router(dep, lambda: reps,
+                        report_unhealthy=lambda *a: reported.append(a))
+        monkeypatch.setattr(ray_tpu, "get", lambda ref, **k: None)
+        n = router.breaker.config.latency_min_samples
+        for _ in range(n):
+            router._settle(_FakeRef(), "r0", "stats", 0.001, False)
+            router._settle(_FakeRef(), "r1", "stats", 0.001, False)
+            router.record_stream_outcome("r0", True, 0.05, "generate")
+        for _ in range(n):
+            assert not reported
+            router.record_stream_outcome("r1", True, 2.5, "generate")
+        assert [r[0] for r in reported] == ["r1"]
+        assert "latency outlier" in reported[0][1]
+        for _ in range(50):  # r1 takes no traffic while it is open
+            assert router._choose_locked(reps).replica_id == "r0"
+        points = _get_router_metrics()["breaker_transitions"]._points()
+        assert {k: v for k, v in points.items() if k[0] == dep} == \
+            {(dep, "r1", "latency"): 1.0}
+        router.breaker.config = CircuitBreakerConfig(failure_threshold=1)
+        router.breaker.record_failure("r0")
+        points = _get_router_metrics()["breaker_transitions"]._points()
+        assert points[(dep, "r0", "failures")] == 1.0
 
     def test_router_queue_cap_sheds_with_overloaded(self):
         reps = _replicas(1, cap=1)
@@ -369,6 +668,30 @@ def test_stream_retry_consumes_fresh_attempts_meta(monkeypatch):
     assert g.streaming is True          # original attempt's meta
     assert list(g) == ["c1", "c2"], "meta frame leaked or chunk lost"
     assert resubmits == [set()]         # exactly one transparent retry
+
+
+def test_a_stream_s_first_chunk_is_a_sample_of_its_method(monkeypatch):
+    """The generator hands the breaker its time to first chunk under the
+    method it streams, once, and later chunks are no samples."""
+    from ray_tpu.serve.handle import DeploymentResponseGenerator
+
+    class FakeGen:
+        def __init__(self, frames):
+            self.frames = list(frames)
+
+        def _next(self, timeout):
+            if not self.frames:
+                raise StopIteration
+            return self.frames.pop(0)
+
+    monkeypatch.setattr(ray_tpu, "get", lambda r, **k: r)
+    router = Router("d", lambda: _replicas(1))
+    g = DeploymentResponseGenerator(
+        FakeGen([{"streaming": True}, "c1", "c2", "c3"]), router=router,
+        replica_id="r0", method="generate")
+    assert list(g) == ["c1", "c2", "c3"]
+    samples = router.breaker._replicas["r0"].latencies
+    assert list(samples) == ["generate"] and len(samples["generate"]) == 1
 
 
 # -------------------------------------------------------------- taxonomy
