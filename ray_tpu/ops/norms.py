@@ -1,4 +1,4 @@
-"""RMSNorm: Pallas fused kernel + reference implementation.
+"""RMSNorm: Pallas fused kernel + reference implementation; LayerNorm in jnp.
 
 The TPU framework owns its normalization kernels (the reference delegates to
 torch). RMSNorm (no mean subtraction) is the transformer default (Llama-family).
@@ -96,3 +96,16 @@ def _rms_bwd(eps, kmesh, res, g):
 
 
 _rms_norm_cv.defvjp(_rms_fwd, _rms_bwd)
+
+
+def layer_norm(x, weight, bias, eps: float = 1e-5):
+    """``(x - mean(x)) rsqrt(var(x) + eps) w + b`` over the last dimension
+    (``nn.LayerNorm``), statistics in float32. Plain jnp: XLA fuses it into
+    its neighbours."""
+    dtype = x.dtype
+    xf = x.astype(jnp.float32)
+    centred = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(centred * centred, axis=-1, keepdims=True)
+    y = centred * jax.lax.rsqrt(var + eps)
+    return (y * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(dtype)

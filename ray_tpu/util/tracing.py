@@ -723,6 +723,18 @@ SUBPARTS = (
                      # form of a prefill chunk or a decode step's one token
     "linear_state",  # reading and writing that operator's state and its
                      # convolution's window
+    "ssm",           # a selective-scan operator inside ``attn``: its
+                     # projections, the convolution, the step and the gate
+    "ssm_scan",      # inside it, the selective scan alone: the chunk form
+                     # of a prefill chunk or a decode step's one token
+    "ssm_state",     # reading and writing that operator's state and its
+                     # convolution's window
+    "window_attn",   # an attention over the last positions' ring, inside
+                     # ``attn`` (a full layer's stays plain ``attn``)
+    "cross_attn",    # an attention that has a query of its own and reads
+                     # another layer's line, inside ``attn``
+    "gmu",           # a gated memory unit inside ``attn``: two projections
+                     # around a gate of another layer's output
 )
 
 

@@ -25,7 +25,8 @@ from ray_tpu.util import tracing
 # The tiny served models and the programs' arguments, as the engine's own
 # contract test builds them.
 from test_served_model import (MAX_SEQ, SLOTS, _arguments, _deepseek, _lfm2,
-                               _llama, _longcat, _ouro, _qwen3_next)
+                               _llama, _longcat, _ouro, _phi4flash,
+                               _qwen3_next)
 
 # What JAX itself puts on a name stack besides primitives' names.
 WRAPPERS = {"transpose", "jvp", "vmap", "pmap", "jit", "pjit", "while",
@@ -48,7 +49,9 @@ def test_the_finer_names_are_a_vocabulary_of_their_own():
     know them and books their operations to the part around them."""
     assert tracing.SUBPARTS == ("conv", "conv_state", "moe_shared",
                                 "latent_prefill", "linear_attn",
-                                "delta_rule", "linear_state")
+                                "delta_rule", "linear_state", "ssm",
+                                "ssm_scan", "ssm_state", "window_attn",
+                                "cross_attn", "gmu")
     assert not set(tracing.SUBPARTS) & set(tracing.PARTS)
     assert all(re.fullmatch(r"[a-z_]+", p) for p in tracing.SUBPARTS)
     for name in tracing.SUBPARTS:
@@ -104,6 +107,7 @@ SERVED = {
     "lfm2": (_lfm2, DENSE | ROUTED),
     "deepseek": (_deepseek, DENSE | ROUTED),
     "qwen3_next": (_qwen3_next, DENSE | ROUTED),
+    "phi4flash": (_phi4flash, DENSE),
 }
 LONGCAT_GONE = ("longcat.mla", "longcat.moe", "longcat.moe.experts")
 
@@ -151,6 +155,23 @@ def test_a_serving_program_opens_its_parts(model, program):
         assert re.search(r"mlp/moe_shared/dot_general", text)
         assert not re.search(
             r"[^/\w](linear_attn|delta_rule|linear_state|moe_shared)/", text)
+    if model == "phi4flash":
+        # The scan operator's finer names lie inside ``attn``, the
+        # operator's place, the scan alone inside ``ssm``; a window layer's
+        # attention, a cross attention and a gated memory unit each under
+        # its own name there. The full layer's attention stays plain
+        # ``attn``.
+        assert re.search(r"attn/ssm/dot_general", text)
+        assert re.search(r"attn/ssm/ssm_scan/", text)
+        assert re.search(r"attn/ssm_state/", text)
+        assert re.search(r"attn/window_attn/dot_general", text)
+        assert re.search(r"attn/window_attn/cache/", text)
+        assert re.search(r"attn/cross_attn/dot_general", text)
+        assert re.search(r"attn/gmu/dot_general", text)
+        assert re.search(r"attn/dot_general", text)
+        assert not re.search(
+            r"[^/\w](ssm|ssm_scan|ssm_state|window_attn|cross_attn|gmu)/",
+            text)
 
 
 def _train_step(name):
