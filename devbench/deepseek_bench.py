@@ -7,13 +7,14 @@ a described v5e with no chip, and timed on one.
     DEEPSEEK_LAYERS=9 python3 devbench/deepseek_bench.py aot
     chiprun -- python3 devbench/deepseek_bench.py step parity
     chiprun -- python3 devbench/deepseek_bench.py prefill_attention
+    chiprun -- python3 devbench/deepseek_bench.py mixed
 
-``aot``: ``llm/deepseek_serving.py``'s ``prefill_chunk(512)`` and
-``decode_burst(8)``, compiled for ``v5e:2x2``'s first device (nothing runs:
-no time comes out of it): XLA's ``memory_analysis`` (arguments,
-temporaries, their sum against the chip's 15.75 GiB), the Mosaic calls, and
-every instruction whose result has the shape of the whole cache or of a
-stacked leaf, by opcode. ``step``: wall milliseconds of one decode step
+``aot``: ``llm/deepseek_serving.py``'s ``prefill_chunk(512)``,
+``decode_burst(8)`` and ``mixed_burst(8)``, compiled for ``v5e:2x2``'s first
+device (nothing runs: no time comes out of it): XLA's ``memory_analysis``
+(arguments, temporaries, their sum against the chip's 15.75 GiB), the
+Mosaic calls, and every instruction whose result has the shape of the whole
+cache or of a stacked leaf, by opcode. ``step``: wall milliseconds of one decode step
 inside a burst of 8 at 16 lines of 4,096, 8,192 and 15,360 live positions
 (every line prefilled with tokens of its own first, so the router sees
 what a served step does), and of a prefill chunk of 512 against 0, 4,096,
@@ -24,6 +25,15 @@ against the same four cached lengths of one layer's line, the kernel and
 the XLA reference, at 128 heads and at LongCat's 64: milliseconds a call,
 TFLOP/s from ``adapters/deepseek.prefill_attention_flops`` (live rows
 rounded up to the block of 512) and their share of the chip's peak.
+``mixed``: one decode step that carries a chunk of 512
+(``deepseek_serving._mixed_impl``, a jit of its own) against
+``prefill_chunk`` and ``decode_step`` apart, the chunk against 4,096, 8,192
+and 15,360 cached rows of slot 0 beside the 15 other lines at as many live
+positions (16 slots as in the cell: a slot mid-prefill does not decode):
+wall milliseconds a call, device milliseconds a call and each program's
+parts from a device trace a length, and the routed layers' counts; the
+whole, with each program's largest operations, goes to
+``chiprun_out/deepseek_mixed.json``.
 ``parity``: the programs in bfloat16 against
 ``benchmark/reference/deepseek.py`` over a prompt of 1,024 in two chunks
 and 16 decoded tokens, the number a run's ``correct`` compares (the
@@ -46,7 +56,11 @@ for p in (ROOT, os.path.join(ROOT, "benchmark")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from devbench.lfm2_bench import GIB, opcodes_with_shape  # noqa: E402
+from devbench.lfm2_bench import (  # noqa: E402
+    GIB,
+    opcodes_with_shape,
+    program_times,
+)
 
 SLOTS, MAX_SEQ = 16, 16384
 
@@ -85,13 +99,17 @@ def lowerings(cfg, params, cache, arg) -> dict:
 
     from ray_tpu.llm import deepseek_serving as serving
 
+    burst = (cfg, params, cache, arg((SLOTS,)), arg((SLOTS,)),
+             arg((SLOTS,), jnp.bool_), arg((SLOTS,), jnp.float32),
+             arg((SLOTS,), jnp.float32), arg((2,), jnp.uint32))
+    riders = (arg((8, 512)), arg((8,)), arg((8,)), arg((8,)), arg(()))
     return {
         "prefill_chunk(512)": lambda: serving.prefill_chunk.lower(
             cfg, params, cache, arg((512,)), arg(()), arg(()), arg(())),
         "decode_burst(8)": lambda: serving.decode_burst.lower(
-            cfg, params, cache, arg((SLOTS,)), arg((SLOTS,)),
-            arg((SLOTS,), jnp.bool_), arg((SLOTS,), jnp.float32),
-            arg((SLOTS,), jnp.float32), arg((2,), jnp.uint32), 8, False)}
+            *burst, 8, False),
+        "mixed_burst(8)": lambda: serving.mixed_burst.lower(
+            *burst, riders, 8, False)}
 
 
 def big_shapes(cfg) -> dict:
@@ -383,8 +401,82 @@ def margins() -> dict:
     return out
 
 
+def mixed(calls: int = 10, ops: int = 40) -> dict:
+    import shutil
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm import deepseek_serving as serving
+    from ray_tpu.models import deepseek
+
+    cfg = config()
+    params = jax.jit(deepseek.init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(0))
+    i32, rows = jnp.int32, 512
+    cache, _ = _prefilled(cfg, params,
+                          serving.init_cache(cfg, SLOTS, MAX_SEQ), 4096,
+                          range(SLOTS))
+    chunk = jax.random.randint(jax.random.PRNGKey(7), (rows,), 259,
+                               cfg.vocab_size, i32)
+    tokens = jax.random.randint(jax.random.PRNGKey(8), (SLOTS,), 259,
+                                cfg.vocab_size, i32)
+    # The chunk's slot is 0 and does not decode; the others do.
+    write = jnp.arange(SLOTS) >= 1
+
+    # ``params`` is an argument: closed over, its 9.6 GB are captured as
+    # constants at lowering (devbench/lfm2_bench.py's finding).
+    @partial(jax.jit, static_argnums=0, donate_argnums=2)
+    def mixed_step(cfg, params, cache, positions, kv_len, length):
+        return serving._mixed_impl(cfg, params, cache, tokens, positions,
+                                   write, chunk, kv_len, length, i32(0))
+
+    out = {"mode": "mixed", "device": jax.devices()[0].device_kind,
+           "layers": cfg.num_layers, "rows": rows, "lines": SLOTS - 1,
+           "calls": calls, "at": {}}
+    for cached in (4096, 8192, 15360):
+        positions = jnp.full((SLOTS,), cached, i32)
+        kv_len, length = i32(cached), i32(cached + 2 * rows)
+        programs = {
+            "prefill_chunk": lambda c: serving.prefill_chunk(
+                cfg, params, c, chunk, kv_len, length, i32(0)),
+            "decode_step": lambda c: serving.decode_step(
+                cfg, params, c, tokens, positions, write),
+            "mixed_step": lambda c: mixed_step(cfg, params, c, positions,
+                                               kv_len, length)}
+
+        def run(name, cache):
+            for _ in range(calls):
+                cache, _, counts = programs[name](cache)
+            return jax.block_until_ready(cache), counts
+
+        row = out["at"][cached] = {"wall_ms": {}, "counts": {}}
+        for name in programs:
+            cache, counts = run(name, cache)              # compiles, warms
+            t0 = time.monotonic()
+            cache, counts = run(name, cache)
+            row["wall_ms"][name] = round(
+                (time.monotonic() - t0) * 1e3 / calls, 3)
+            row["counts"][name] = [int(n) for n in counts]
+        trace_dir = os.path.join(ROOT, ".chipwork",
+                                 f"deepseek_mixed_{cached}")
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        jax.profiler.start_trace(trace_dir)
+        for name in programs:
+            cache, _ = run(name, cache)
+        jax.profiler.stop_trace()
+        row.update(program_times(trace_dir, programs, calls, ops))
+        print(json.dumps({cached: {k: v for k, v in row.items()
+                                   if k != "top_ops_ms"}}), flush=True)
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out", "deepseek_mixed.json"),
+              "w") as f:
+        json.dump(out, f, indent=1)
+    return {k: v for k, v in out.items() if k != "at"}
+
+
 MODES = {"aot": aot, "step": step, "prefill_attention": prefill_attention,
-         "parity": parity, "margins": margins}
+         "parity": parity, "margins": margins, "mixed": mixed}
 
 if __name__ == "__main__":
     for mode in sys.argv[1:] or ["aot"]:

@@ -391,14 +391,49 @@ def tile() -> dict:
     return out
 
 
+def program_times(trace_dir: str, names, calls: int, ops: int = 0) -> dict:
+    """A device trace of ``calls`` calls of each jitted program in ``names``
+    (found as ``jit_<name>``), reduced: device milliseconds a call, each
+    program's parts in % of it and, where ``ops`` is given, its ``ops``
+    largest operations as [operation, part, source line, calls a call of
+    the program, milliseconds a call]. A trace that cannot be read leaves
+    ``trace_error`` and the tables empty: the wall times stand alone."""
+    from rtbench import trace_reduce, xplane_meta
+
+    import trace_parts
+
+    out = {"device_ms": {}, "part_share_pct": {}}
+    if ops:
+        out["top_ops_ms"] = {}
+    try:
+        tables = trace_parts.tables(xplane_meta.load(
+            trace_reduce.find_xplane(trace_dir)), top=max(20, 10 * ops))
+    except Exception as e:  # noqa: BLE001 - the wall times stand alone
+        out["trace_error"] = repr(e)
+        return out
+    for program, parts in tables["programs"].items():
+        name = program.removeprefix("jit_")
+        if name not in names:
+            continue
+        total = sum(parts.values())
+        out["device_ms"][name] = round(total * 1e3 / calls, 3)
+        out["part_share_pct"][name] = {
+            k: round(100 * v / total, 1) for k, v in
+            sorted(parts.items(), key=lambda kv: -kv[1])}
+        if ops:
+            out["top_ops_ms"][name] = [
+                [op["op"], op["part"], op["source"], op["count"] // calls,
+                 round(op["self_s"] * 1e3 / calls, 3)]
+                for op in tables["top_ops"] if op["program"] == program][:ops]
+    return out
+
+
 def mixed(calls: int = 10) -> dict:
     import shutil
 
     import jax
     import jax.numpy as jnp
-    from rtbench import trace_reduce, xplane_meta
 
-    import trace_parts
     from ray_tpu.models import routed
 
     rows, lines, live, cached = (
@@ -461,20 +496,7 @@ def mixed(calls: int = 10) -> dict:
     for name in programs:
         cache, _ = run(name, cache)
     jax.profiler.stop_trace()
-    out["device_ms"], out["part_share_pct"] = {}, {}
-    try:
-        by_program = trace_parts.tables(xplane_meta.load(
-            trace_reduce.find_xplane(trace_dir)))["programs"]
-    except Exception as e:  # noqa: BLE001 - the wall times stand alone
-        out["trace_error"], by_program = repr(e), {}
-    for program, parts in by_program.items():
-        name = program.removeprefix("jit_")
-        if name in programs:
-            total = sum(parts.values())
-            out["device_ms"][name] = round(total * 1e3 / calls, 3)
-            out["part_share_pct"][name] = {
-                k: round(100 * v / total, 1) for k, v in
-                sorted(parts.items(), key=lambda kv: -kv[1])}
+    out.update(program_times(trace_dir, programs, calls))
     return out
 
 

@@ -26,7 +26,6 @@ from ray_tpu.llm.served import served_model
 from ray_tpu.models import lfm2, routed
 from ray_tpu.models.lfm2 import ATTENTION, CONV, Lfm2Config, Segment
 from ray_tpu.ops.kernels import force_kernel_backend
-from ray_tpu.util import tracing
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmark")
@@ -35,6 +34,10 @@ if BENCH not in sys.path:
 
 from reference import lfm2 as reference  # noqa: E402
 from rtbench.adapters import lfm2 as adapter  # noqa: E402
+
+from test_served_model import (  # noqa: E402
+    chunks_that_ride_leave_every_answer_as_it_was,
+)
 
 CFG = Lfm2Config.tiny()
 PROMPT = 45
@@ -508,80 +511,17 @@ def test_the_engine_serves_each_request_as_if_alone(engine):
                          ids=["look-ahead", "serial"])
 def test_chunks_that_ride_leave_every_answer_as_it_was(monkeypatch, pipeline):
     """Long prompts arrive while a line decodes: with ``mixed_burst`` their
-    full chunks ride the line's bursts, never a prompt's last chunk (one
-    that is full among them), and every request gets token for token what it
-    gets from the engine whose model offers no such program, which counts
-    no chunk as riding."""
-    rng = np.random.default_rng(11)
-    make = lambda n: [int(t) for t in rng.integers(259, CFG.vocab_size, n)]  # noqa: E731
-    # 150 and 97: full chunks and a tail; 96: three full chunks, the last
-    # one the prompt's last; 20: a tail alone
-    prompts = [make(n) for n in (20, 150, 97, 96, 20)]
-    rode = []
-
-    def recording(*args, **kw):
-        chunks, slots, kv_lens, lengths, n = args[9]
-        rode.extend((int(kv_lens[j]) + chunks.shape[1], int(lengths[j]))
-                    for j in range(int(n)))
-        return serving.mixed_burst(*args, **kw)
-
-    def run(entry):
-        monkeypatch.setattr(serving, "SERVED",
-                            replace(serving.SERVED, mixed_burst=entry))
-        eng = LLMEngine(LLMConfig(
-            model=CFG, max_num_seqs=3, max_seq_len=256, prefill_chunk=32,
-            decode_burst=4, decode_pipeline=pipeline, seed=3))
-        try:
-            first = eng.submit(prompts[0], SamplingParams(max_tokens=70))
-            assert _until(lambda: first.out_tokens)
-            reqs = [first] + [eng.submit(p, SamplingParams(max_tokens=12))
-                              for p in prompts[1:]]
-            assert all(r.done.wait(300) for r in reqs)
-            assert not any(r.error for r in reqs)
-            return [list(r.out_tokens) for r in reqs], eng.stats()
-        finally:
-            eng.shutdown()
-
-    tracing.clear()
-    tracing.enable_tracing()
-    try:
-        with_entry, stats = run(recording)
-        dispatches = [s.attributes for s in tracing.spans()
-                      if s.name == "engine.decode_dispatch"]
-    finally:
-        tracing.disable_tracing()
-        tracing.clear()
-    without, plain = run(None)
-    assert with_entry == without
-    # each burst's dispatch phase says how many of its steps took a chunk
-    assert sum(d["riders"] for d in dispatches) == \
-        stats["prefill_chunks_riding"]
-    assert all(0 <= d["riders"] <= d["steps"] for d in dispatches)
-    chunks = sum(-(-len(p) // 32) for p in prompts)
-    assert stats["prefill_chunks"] == plain["prefill_chunks"] == chunks
-    assert stats["prompt_tokens_prefilled"] == \
-        plain["prompt_tokens_prefilled"] == sum(len(p) for p in prompts)
-    assert plain["prefill_chunks_riding"] == \
-        plain["prefill_tokens_riding"] == 0
-    assert 0 < stats["prefill_chunks_riding"] == len(rode) <= chunks - 5
-    assert stats["prefill_tokens_riding"] == 32 * len(rode)
-    # a rider ends before its prompt does
-    assert all(end < length for end, length in rode), rode
-    # a routed layer is counted once a program's step, a rider's with the
-    # step that carried it
-    for s in (stats, plain):
+    full chunks ride the line's bursts, never a prompt's last chunk, and
+    every request gets token for token what it gets from the engine whose
+    model offers no such program (tests/test_served_model.py holds the
+    drive); a routed layer is counted once a program's step, a rider's with
+    the step that carried it."""
+    for s in chunks_that_ride_leave_every_answer_as_it_was(
+            monkeypatch, serving, CFG, pipeline):
         assert s["moe_layer_steps"] == CFG.num_routed_layers * (
             s["prefill_chunks"] - s["prefill_chunks_riding"]
             + s["decode_steps"])
         assert s["moe_picks"] == s["moe_picks_local"] > 0
-
-
-def _until(done, timeout=120.0):
-    import time
-    end = time.monotonic() + timeout
-    while not done() and time.monotonic() < end:
-        time.sleep(0.005)
-    return bool(done())
 
 
 def test_the_served_model_says_what_it_cannot_do():

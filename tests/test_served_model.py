@@ -362,6 +362,83 @@ def test_a_mixed_burst_is_offered_by_a_token_a_step_model_or_by_none(model):
     assert all(leaf.is_deleted() for leaf in jax.tree.leaves(cache))
 
 
+def chunks_that_ride_leave_every_answer_as_it_was(monkeypatch, serving, cfg,
+                                                  pipeline):
+    """What a model's test of its ``mixed_burst`` through the engine holds
+    (tests/test_lfm2.py, tests/test_deepseek.py): long prompts arrive while
+    a line decodes, their full chunks ride the line's bursts, never a
+    prompt's last chunk (one that is full among them), and every request
+    gets token for token what it gets from the engine whose model offers no
+    such program, which counts no chunk as riding. Returns the two engines'
+    last ``stats()``, with and without the entry."""
+    import time
+    from dataclasses import replace
+
+    import numpy as np
+
+    from ray_tpu.util import tracing
+
+    rng = np.random.default_rng(11)
+    # 150 and 97: full chunks and a tail; 96: three full chunks, the last
+    # one the prompt's last; 20: a tail alone
+    prompts = [[int(t) for t in rng.integers(259, cfg.vocab_size, n)]
+               for n in (20, 150, 97, 96, 20)]
+    rode = []
+
+    def recording(*args, **kw):
+        chunks, slots, kv_lens, lengths, n = args[9]
+        rode.extend((int(kv_lens[j]) + chunks.shape[1], int(lengths[j]))
+                    for j in range(int(n)))
+        return serving.mixed_burst(*args, **kw)
+
+    def run(entry):
+        monkeypatch.setattr(serving, "SERVED",
+                            replace(serving.SERVED, mixed_burst=entry))
+        eng = LLMEngine(LLMConfig(
+            model=cfg, max_num_seqs=3, max_seq_len=256, prefill_chunk=32,
+            decode_burst=4, decode_pipeline=pipeline, seed=3))
+        try:
+            first = eng.submit(prompts[0], SamplingParams(max_tokens=70))
+            end = time.monotonic() + 120
+            while not first.out_tokens and time.monotonic() < end:
+                time.sleep(0.005)
+            assert first.out_tokens
+            reqs = [first] + [eng.submit(p, SamplingParams(max_tokens=12))
+                              for p in prompts[1:]]
+            assert all(r.done.wait(300) for r in reqs)
+            assert not any(r.error for r in reqs)
+            return [list(r.out_tokens) for r in reqs], eng.stats()
+        finally:
+            eng.shutdown()
+
+    tracing.clear()
+    tracing.enable_tracing()
+    try:
+        with_entry, stats = run(recording)
+        dispatches = [s.attributes for s in tracing.spans()
+                      if s.name == "engine.decode_dispatch"]
+    finally:
+        tracing.disable_tracing()
+        tracing.clear()
+    without, plain = run(None)
+    assert with_entry == without
+    # each burst's dispatch phase says how many of its steps took a chunk
+    assert sum(d["riders"] for d in dispatches) == \
+        stats["prefill_chunks_riding"]
+    assert all(0 <= d["riders"] <= d["steps"] for d in dispatches)
+    chunks = sum(-(-len(p) // 32) for p in prompts)
+    assert stats["prefill_chunks"] == plain["prefill_chunks"] == chunks
+    assert stats["prompt_tokens_prefilled"] == \
+        plain["prompt_tokens_prefilled"] == sum(len(p) for p in prompts)
+    assert plain["prefill_chunks_riding"] == \
+        plain["prefill_tokens_riding"] == 0
+    assert 0 < stats["prefill_chunks_riding"] == len(rode) <= chunks - 5
+    assert stats["prefill_tokens_riding"] == 32 * len(rode)
+    # a rider ends before its prompt does
+    assert all(end < length for end, length in rode), rode
+    return stats, plain
+
+
 def test_a_model_may_say_its_step_is_a_block_and_its_prefill_gives_no_token():
     """SDAR's: 4 positions by 5 forwards. Its two programs keep the names a
     trace is read by and give the donated cache back; the prefill's logits

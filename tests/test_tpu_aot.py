@@ -715,7 +715,8 @@ def test_sdar_programs_move_no_cache_nor_expert_stack_and_fit_the_chip(
 # DeepSeek-V2's dense layer and seven routed ones at the published widths,
 # one group of 20 experts, at the shapes of its serving cell (16 slots x
 # 16,384): the programs of llm/deepseek_serving.py as the cell compiles them.
-@pytest.mark.parametrize("program", ["prefill_chunk(512)", "decode_burst(8)"])
+@pytest.mark.parametrize("program", ["prefill_chunk(512)", "decode_burst(8)",
+                                     "mixed_burst(8)"])
 def test_deepseek_programs_copy_no_cache_nor_stacked_leaf_and_fit_the_chip(
         mosaic, program):
     """The latent cache rides both layer loops as carry, and every stacked
@@ -726,7 +727,10 @@ def test_deepseek_programs_copy_no_cache_nor_stacked_leaf_and_fit_the_chip(
     decode program, and the stacked ``wkv_b`` (0.25 GiB) head-major beside
     it; the product is kept an array (``mla_project(keep_product=True)``)
     and ``wkv_b`` is stored a head at a time. Arguments and temporaries fit
-    the chip's 15.75 GiB with room for the float32 reference's check."""
+    the chip's 15.75 GiB with room for the float32 reference's check. The
+    burst whose steps carry a chunk (PR 53) holds both loops' bodies: the
+    chunk's rows by an update in place and the lines' by the row kernel on
+    the one stack, 528 rows through the experts in the chunk's tiles."""
     from devbench import deepseek_bench as bench
 
     cfg = bench.config()
@@ -758,6 +762,15 @@ def test_deepseek_programs_copy_no_cache_nor_stacked_leaf_and_fit_the_chip(
         # 512 x 6 picks over 160 outputs are 19 rows an expert: tiles of 64
         assert _grouped_matmul_rows(text) == {(3072 // 64 + 20) * 64}
         assert mem.temp_size_in_bytes < 1 << 26
+    elif program.startswith("mixed"):
+        kernels = ("latent_prefill_attention", "latent_decode_attention",
+                   "latent_row_write", "moe_grouped_matmul")
+        in_place = {"dynamic-update-slice", "custom-call"}
+        # the riding steps' 528 x 6 picks in tiles of 64 (19.8 rows an
+        # expert), the steps after them as ``decode_burst``'s
+        assert _grouped_matmul_rows(text) == {
+            (3168 // 64 + 20) * 64, (96 // 16 + 20) * 16}
+        assert mem.temp_size_in_bytes < 1 << 28
     else:
         kernels = ("latent_decode_attention", "latent_row_write",
                    "moe_grouped_matmul")
