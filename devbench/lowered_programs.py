@@ -33,7 +33,9 @@ from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 SLOTS, MAX_SEQ, CHUNK = 4, 256, 32
 # What a model's ``tiny`` takes beside the length and the dtype.
 TINY = {"LlamaConfig": lambda c: dataclasses.replace(c.tiny(), vocab_size=512, dtype="bfloat16"),
-        "LongcatConfig": lambda c: c.tiny(expert_shards=2, max_seq_len=MAX_SEQ, dtype="bfloat16")}
+        "LongcatConfig": lambda c: c.tiny(expert_shards=2, max_seq_len=MAX_SEQ, dtype="bfloat16"),
+        # a share of the experts, both kinds of layer, the sink: the decode kernel's program with its extra operand
+        "MimoConfig": lambda c: c.tiny(expert_shards=2, max_seq_len=MAX_SEQ, dtype="bfloat16")}
 MODULES = getattr(llm_config, "SERVING_MODULES", None) or {
     kind: "ray_tpu.llm." + ("engine" if kind.__name__ == "LlamaConfig" else kind.__name__[:-len("Config")].lower() + "_serving")
     for kind in llm_config.ModelConfig.__args__}

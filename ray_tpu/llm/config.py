@@ -16,6 +16,7 @@ from ray_tpu.models.deepseek import DeepseekV2Config
 from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
+from ray_tpu.models.mimo import MimoConfig
 from ray_tpu.models.ouro import OuroConfig
 from ray_tpu.models.phi4flash import Phi4FlashConfig
 from ray_tpu.models.qwen3_next import Qwen3NextConfig
@@ -35,6 +36,7 @@ SERVING_MODULES = {
     DeepseekV2Config: "ray_tpu.llm.deepseek_serving",
     Qwen3NextConfig: "ray_tpu.llm.qwen3_next_serving",
     Phi4FlashConfig: "ray_tpu.llm.phi4flash_serving",
+    MimoConfig: "ray_tpu.llm.mimo_serving",
 }
 ModelConfig = Union[tuple(SERVING_MODULES)]
 

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.llm.rings import ring_after_chunk, ring_positions
 from ray_tpu.llm.served import ServedModel, token_step_programs
 from ray_tpu.models import phi4flash
 from ray_tpu.models.phi4flash import Phi4FlashConfig
@@ -99,14 +100,6 @@ def init_cache(cfg: Phi4FlashConfig, max_slots: int, max_seq: int):
                            (cfg.mamba_d_conv - 1) * cfg.d_inner), dt)}
 
 
-def _ring_positions(end, window: int):
-    """The position each row of a ring holds once ``end`` positions have
-    been written: the largest one under ``end`` that falls on the row.
-    Under 0: the row holds nothing of this sequence."""
-    rows = jnp.arange(window)
-    return end - 1 - jnp.mod(end - 1 - rows, window)
-
-
 def _counts(cfg, updates, chunk_tokens, skipped):
     return jnp.stack([cfg.ssm_lines * updates, cfg.ssm_lines * chunk_tokens,
                       skipped]).astype(jnp.int32)
@@ -129,15 +122,13 @@ def _prefill_impl(cfg: Phi4FlashConfig, params, cache, tokens, kv_len,
         # The chunk's rows that are the prompt's: all but a last chunk's
         # padding.
         n_valid = jnp.clip(length - kv_len, 0, c)
-        held = _ring_positions(kv_len, w)
+        held = ring_positions(kv_len, w)
         kpos = jnp.concatenate([held, positions])
         visible = phi4flash.window_visible(positions, kpos, w) \
             & (kpos < length)[None]
         # What each ring row holds after the chunk, and the chunk's row it
         # takes that from.
-        after = _ring_positions(kv_len + n_valid, w)
-        fresh = (after >= kv_len)[None, :, None]
-        source = jnp.clip(after - kv_len, 0, c - 1)
+        fresh, source = ring_after_chunk(kv_len, n_valid, w, c)
 
     def ssm(line, sp, xn, state):
         kc, vc, rk, rv, st, cs = state

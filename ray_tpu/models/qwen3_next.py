@@ -73,7 +73,7 @@ from ray_tpu.ops.attention import blockwise_attention
 from ray_tpu.ops.gated_delta import gated_delta_chunk
 from ray_tpu.ops.kernels import KernelMesh
 from ray_tpu.ops.norms import rms_norm, rms_norm_reference
-from ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ray_tpu.ops.rope import apply_rope_partial, rope_frequencies
 from ray_tpu.util import tracing
 
 LINEAR, ATTENTION = "linear_attention", "full_attention"
@@ -441,13 +441,8 @@ def attention_heads(cfg: Qwen3NextConfig, ap: dict, xn, positions, inv_freq):
     q = rms_norm_reference(q, unit_offset(ap["q_norm"]), cfg.norm_eps)
     k = rms_norm_reference(k, unit_offset(ap["k_norm"]), cfg.norm_eps)
 
-    def rotate(x):
-        r = cfg.rotary_dim
-        return jnp.concatenate(
-            [apply_rope(x[..., :r], positions, inv_freq), x[..., r:]],
-            axis=-1)
-
-    return rotate(q), rotate(k), v, gate
+    return (apply_rope_partial(q, positions, inv_freq),
+            apply_rope_partial(k, positions, inv_freq), v, gate)
 
 
 def attention_output(ap: dict, o, gate, dtype):

@@ -48,6 +48,14 @@ carries the values' mix in its last D lanes, which is what is kept. On a
 128-wide MXU neither product costs more than its unpacked form, and a
 block is fetched once.
 
+**A sink.** A model may give every query head a learned logit that joins
+the softmax's denominator and has no row of values (``sink`` [H] float32;
+None: no such logit, and the program is what it was). It is where a slot's
+running maximum and sum start, at the line's first live block: the maximum
+at the sink, the sum at one, ``exp(sink - sink)``. From there the walk is
+the same, so the sink enters exactly, and a row that sees no key gives
+zeros as before (an accumulator of zeros over a sum of one).
+
 ``kv_row_write`` is the other half of the convention: the step's K new rows
 of each slot go into the same stack in place, through a kernel too, because
 XLA would re-lay the whole cache out around a row update of its own.
@@ -112,8 +120,16 @@ def kv_positions_read(lengths, block: int):
     return -(-lengths // block) * block
 
 
+def _sink_rows(sink, hkv: int, k: int):
+    """sink [H] -> [Hkv, G * K, 1] float32: row ``g * K + j`` of KV head h
+    is query head ``h * G + g``'s."""
+    return jnp.repeat(sink.astype(jnp.float32).reshape(hkv, -1), k,
+                      axis=1)[..., None]
+
+
 def decode_attention_reference(q, k_cache, v_cache, layer, lengths,
-                               positions0, sm_scale: float | None = None):
+                               positions0, sm_scale: float | None = None,
+                               sink=None):
     """Masked softmax over the whole line, grouped like the kernel (no
     repeated K/V), float32 scores and accumulation."""
     b, h, k, d = q.shape
@@ -133,9 +149,14 @@ def decode_attention_reference(q, k_cache, v_cache, layer, lengths,
                & (kpos < lengths[:, None, None]))            # [B, K, S]
     visible = jnp.tile(visible, (1, h // hkv, 1))[:, None]   # rows g*K + j
     scores = jnp.where(visible, scores, NEG_INF)
-    p = jnp.where(visible,
-                  jnp.exp(scores - scores.max(-1, keepdims=True)), 0.0)
+    top = scores.max(-1, keepdims=True)
+    if sink is not None:
+        sunk = _sink_rows(sink, hkv, k)
+        top = jnp.maximum(top, sunk)
+    p = jnp.where(visible, jnp.exp(scores - top), 0.0)
     denom = jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+    if sink is not None:
+        denom = denom + jnp.exp(sunk - top)
     out = jnp.einsum("bhrs,bhsd->bhrd", p.astype(q.dtype),
                      vl.astype(q.dtype),
                      preferred_element_type=jnp.float32) / denom
@@ -205,7 +226,7 @@ def decode_plan_of(lengths, k_cache, *, kmesh: KernelMesh | None = None):
 def _decode_attention_kernel(len_ref, pos_ref, layer_ref, slot_ref, blk_ref,
                              first_ref, last_ref, q_ref, k_ref, v_ref, o_ref,
                              m_ref, l_ref, acc_ref, *, block: int,
-                             k_tokens: int, sm_scale: float):
+                             k_tokens: int, sm_scale: float, sink_ref=None):
     """Grid step t: block ``blk[t]`` of slot ``slot[t]``, all KV heads. The
     one axis carries a slot's running maximum, sum and accumulator from its
     first live block to its last, so it is ``"arbitrary"``; the v5e has one
@@ -221,8 +242,12 @@ def _decode_attention_kernel(len_ref, pos_ref, layer_ref, slot_ref, blk_ref,
 
     @pl.when(first_ref[t] == 1)
     def _():
-        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        if sink_ref is None:
+            m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+            l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        else:   # the softmax starts at the sink: exp(sink - sink) summed
+            m_ref[...] = sink_ref[...]
+            l_ref[...] = jnp.ones(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     kpos = blk * block + lax.broadcasted_iota(jnp.int32, (rows, block), 1)
@@ -260,8 +285,17 @@ def packed_kernel(kernel, n_scalars: int):
     return packed
 
 
+def sunk_kernel(kernel, n_scalars: int):
+    """``kernel`` with one operand more between its scalars and its
+    queries, the sink's rows, handed on as ``sink_ref``."""
+    def sunk(*refs):
+        return kernel(*refs[:n_scalars], *refs[n_scalars + 1:],
+                      sink_ref=refs[n_scalars])
+    return sunk
+
+
 def _decode_attention_pallas(q, k_cache, v_cache, layer, lengths, positions0,
-                             plan, *, sm_scale: float, block: int):
+                             plan, sink=None, *, sm_scale: float, block: int):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -293,13 +327,20 @@ def _decode_attention_pallas(q, k_cache, v_cache, layer, lengths, positions0,
     # and no position lies past its end for a longer length to unmask.
     kernel = functools.partial(_decode_attention_kernel, block=block,
                                k_tokens=k, sm_scale=sm_scale)
+    sunk = []
+    if sink is not None:
+        # One more operand before the queries, the same rows every step.
+        kernel = sunk_kernel(kernel, 7)
+        sunk = [jnp.pad(_sink_rows(sink, hkv, k),
+                        ((0, 0), (0, rows_p - rows), (0, 0)))]
     out = pl.pallas_call(
-        packed_kernel(kernel, 7) if packed else kernel,
+        packed_kernel(kernel, 7 + len(sunk)) if packed else kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=7,
             # A run-time bound: the steps that exist are the live blocks.
             grid=(plan.n_live[0],),
-            in_specs=[q_spec] + [kv_spec] * len(caches),
+            in_specs=[pl.BlockSpec((hkv, rows_p, 1), lambda t, *_: (0, 0, 0))
+                      ] * len(sunk) + [q_spec] + [kv_spec] * len(caches),
             out_specs=q_spec,
             scratch_shapes=[pltpu.VMEM((hkv, rows_p, 1), jnp.float32),
                             pltpu.VMEM((hkv, rows_p, 1), jnp.float32),
@@ -317,7 +358,7 @@ def _decode_attention_pallas(q, k_cache, v_cache, layer, lengths, positions0,
         name="decode_attention",
     )(lengths.astype(jnp.int32), positions0.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), plan.slot, plan.block,
-      plan.first, plan.last, qg, *caches)
+      plan.first, plan.last, *sunk, qg, *caches)
     # The walk never visits a slot with no live block, so nothing wrote its
     # rows of the output.
     out = jnp.where((lengths > 0)[:, None, None, None], out, 0)
@@ -328,7 +369,7 @@ def decode_attention(q, k_cache, v_cache, layer, lengths, positions0, *,
                      plan: DecodePlan | None = None,
                      sm_scale: float | None = None,
                      kmesh: KernelMesh | None = None,
-                     block: int | None = None):
+                     block: int | None = None, sink=None):
     """q: [B, H, K, D] (K new tokens a slot, query head h of KV head
     ``h // (H // Hkv)``); k_cache, v_cache: [L, B, Hkv, S, D], or the packed
     stack [L, B, Hkv, S, 2 D] and None, the new rows
@@ -337,12 +378,14 @@ def decode_attention(q, k_cache, v_cache, layer, lengths, positions0, *,
     the cache's ``decode_kv_block`` and S (and this ``kmesh``), built by a
     caller that attends many layers at the same lengths; without one the
     call plans for itself. ``block`` overrides :func:`decode_kv_block`
-    (tests and the kernel's own benchmark). Under a mesh of several devices
+    (tests and the kernel's own benchmark). ``sink`` [H] float32 is the
+    module docstring's: a logit a query head in every softmax's
+    denominator. Under a mesh of several devices
     pass its ``kmesh``: the kernel then runs on each device's heads."""
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if kernel_backend() == "reference":
         return decode_attention_reference(q, k_cache, v_cache, layer,
-                                          lengths, positions0, scale)
+                                          lengths, positions0, scale, sink)
     s = k_cache.shape[3]
     block = block or decode_kv_block(s, k_cache.shape[-1],
                                      k_cache.dtype.itemsize)
@@ -353,15 +396,17 @@ def decode_attention(q, k_cache, v_cache, layer, lengths, positions0, *,
         plan = decode_plan(lengths, block, s, kmesh=kmesh)
     fn = functools.partial(_decode_attention_pallas, sm_scale=scale,
                            block=block)
+    sunk = () if sink is None else (sink,)
     if kmesh is not None:
         heads, cache = kmesh.heads_spec(4), _stack_spec(kmesh)
         rows = kmesh.rows_spec(1)
         fn = kmesh.shard(fn, in_specs=(heads, cache,
                                        None if v_cache is None else cache,
-                                       P(), rows, rows, _plan_spec(kmesh)),
+                                       P(), rows, rows, _plan_spec(kmesh),
+                                       *[P(kmesh.heads)] * len(sunk)),
                          out_specs=heads)
     return fn(q, k_cache, v_cache, jnp.asarray(layer, jnp.int32), lengths,
-              positions0, plan)
+              positions0, plan, *sunk)
 
 
 def packed_rows(new_k, new_v):

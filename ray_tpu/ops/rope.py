@@ -101,3 +101,15 @@ def apply_rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
     x1, x2 = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def apply_rope_partial(x: jnp.ndarray, positions: jnp.ndarray,
+                       inv_freq: jnp.ndarray) -> jnp.ndarray:
+    """A rotary that turns part of a head: the first ``2 * len(inv_freq)``
+    lanes as :func:`apply_rope` turns a whole head of that size (lane i with
+    lane ``i + len(inv_freq)``), the lanes after them left as they are.
+    x: [B, H, S, D]; positions: [S] or [B, S] absolute."""
+    rot = 2 * inv_freq.shape[0]
+    return jnp.concatenate(
+        [apply_rope(x[..., :rot], positions, inv_freq), x[..., rot:]],
+        axis=-1)

@@ -22,7 +22,7 @@ import pytest
 
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
 from ray_tpu.llm import deepseek_serving, lfm2_serving, llama_serving
-from ray_tpu.llm import longcat_serving
+from ray_tpu.llm import longcat_serving, mimo_serving
 from ray_tpu.llm import ouro_serving, phi4flash_serving, qwen3_next_serving
 from ray_tpu.llm import sdar_serving
 from ray_tpu.llm.config import SERVING_MODULES, ModelConfig
@@ -31,6 +31,7 @@ from ray_tpu.models.deepseek import DeepseekV2Config
 from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
+from ray_tpu.models.mimo import MimoConfig
 from ray_tpu.models.ouro import OuroConfig
 from ray_tpu.models.phi4flash import Phi4FlashConfig
 from ray_tpu.models.qwen3_next import Qwen3NextConfig
@@ -75,11 +76,15 @@ def _phi4flash():
     return phi4flash_serving, Phi4FlashConfig.tiny(max_seq_len=MAX_SEQ)
 
 
+def _mimo():
+    return mimo_serving, MimoConfig.tiny(max_seq_len=MAX_SEQ)
+
+
 # The models of a token a step, and all of them.
 MODELS = dict(argvalues=[_llama, _longcat, _ouro, _lfm2, _deepseek,
-                         _qwen3_next, _phi4flash],
+                         _qwen3_next, _phi4flash, _mimo],
               ids=["llama", "longcat", "ouro", "lfm2", "deepseek",
-                   "qwen3_next", "phi4flash"])
+                   "qwen3_next", "phi4flash", "mimo"])
 ALL_MODELS = dict(argvalues=MODELS["argvalues"] + [_sdar],
                   ids=MODELS["ids"] + ["sdar"])
 
@@ -164,8 +169,8 @@ def _cumsums(jaxpr, in_loop=False):
 
 
 @pytest.mark.parametrize("model",
-                         argvalues=[_llama, _ouro, _lfm2, _phi4flash],
-                         ids=["llama", "ouro", "lfm2", "phi4flash"])
+                         argvalues=[_llama, _ouro, _lfm2, _phi4flash, _mimo],
+                         ids=["llama", "ouro", "lfm2", "phi4flash", "mimo"])
 def test_a_decode_step_plans_its_walk_once_before_the_layer_loop(model):
     """``decode_attention`` walks the live blocks of every line by a plan
     that depends on the lengths alone, so the step builds it once
@@ -173,7 +178,9 @@ def test_a_decode_step_plans_its_walk_once_before_the_layer_loop(model):
     every one of a looped stack's 192 cache lines, is handed the same. A
     model with lines of two lengths (a full line and rings of a window)
     plans twice, once a length: the full line's eight readers share one
-    walk, the eight rings the other."""
+    walk, the eight rings the other; where the two kinds of line also have
+    different head counts (MiMo-V2), it is still two: a plan knows lengths
+    and blocks, not heads."""
     from ray_tpu.ops.kernels import force_kernel_backend
 
     module, cfg = model()
@@ -182,8 +189,9 @@ def test_a_decode_step_plans_its_walk_once_before_the_layer_loop(model):
         cfg, jax.random.PRNGKey(0)))
     cache = jax.eval_shape(lambda: served.init_cache(cfg, SLOTS, MAX_SEQ))
     tokens, positions, write = _arguments("decode_step", None)[1:]
-    if module in (lfm2_serving, phi4flash_serving):
-        # one token a slot (and, for the first, a router's plans)
+    if module in (lfm2_serving, phi4flash_serving, mimo_serving):
+        # one token a slot (and, for the first and the last, a router's
+        # plans)
         def step(p, c):
             return module._decode_impl(cfg, p, c, tokens, positions, write)
     else:
@@ -195,8 +203,9 @@ def test_a_decode_step_plans_its_walk_once_before_the_layer_loop(model):
     outside, inside = _cumsums(jaxpr.jaxpr)
     # the routed layer's dispatch plan sums too, inside the layer loop: its
     # picks differ a layer; the walk of the cache is the one outside
-    assert outside == (2 if module is phi4flash_serving else 1)
-    assert inside == 0 or module is lfm2_serving
+    assert outside == (2 if module in (phi4flash_serving, mimo_serving)
+                       else 1)
+    assert inside == 0 or module in (lfm2_serving, mimo_serving)
 
 
 def test_the_engine_has_one_kv_layout_and_refuses_the_block_pool():

@@ -984,3 +984,69 @@ def test_selective_scan_chunk_compiles_at_the_cell_s_shapes(mosaic):
             sds(rows, 16), sds(5120), sds(16, 5120)).compile().as_text()
         assert text.count(MOSAIC) == 1
         assert "selective_scan_chunk" in text
+
+
+# ISSUE 54: MiMo-V2.5's serving programs at the shapes of
+# ``mimo-v2.5-serve-mixed-32k`` (7 layers at the published widths, 16 of 256
+# experts held, an eighth of the vocabulary, 24 slots x 32,768): the two
+# programs of llm/mimo_serving.py as the cell compiles them.
+@pytest.mark.parametrize("program", ["prefill_chunk(512)", "decode_burst(8)"])
+def test_mimo_programs_copy_no_line_ring_nor_stacked_leaf_and_fit_the_chip(
+        mosaic, program):
+    """Two geometries of cache leaf: two full lines of 4 KV heads that grow
+    with the line's length and five rings of 8 KV heads x 128 positions
+    that do not, a row of either a key of 192 beside a value of 128 in 384
+    lanes. Neither, nor a stacked weight leaf, is the result of anything
+    but a parameter, a loop's tuple, a kernel's in-place operand or an
+    update in place: a chunk writes its rows of a full line by a
+    ``dynamic-update-slice`` into the leaf and the slot's five turned rings
+    by one more, after the layers (carried through the layers' loops the
+    ring leaf was re-laid out whole, twice a chunk: PERF.md, PR 54); a step
+    writes a row of each through ``kv_row_write``. The router's weights and
+    the sinks are float32 as the configuration's departures state.
+    Arguments and temporaries are what benchmark/configs/mimo-v2.5.json
+    states under ``memory``."""
+    from devbench import mimo_bench as bench
+
+    cfg = bench.config()
+    assert (cfg.num_layers, cfg.full_lines, cfg.window_lines,
+            cfg.experts_held, cfg.vocab_size, cfg.kv_row) == (7, 2, 5, 16,
+                                                              19072, 384)
+    mem, text, _ = bench.compile_programs(cfg, only=program)[program]
+    carried = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30
+    assert 10.9 < mem.argument_size_in_bytes / 2 ** 30 < 11.05
+    # the float32 reference of the check wants room beside the weights
+    assert total < 15.75 - 4.0
+    assert mem.temp_size_in_bytes < 1 << 27
+    if program.startswith("prefill"):
+        kernels = ("prefill_attention", "moe_grouped_matmul")
+    else:
+        kernels = ("decode_attention", "kv_row_write", "moe_grouped_matmul")
+    for name in kernels:
+        assert f'"{name}"' in text or f"%{name}." in text, name
+    big = bench.big_shapes(cfg)
+    assert big["kv"] == "bf16[2,24,4,32768,384]"
+    # a ring is 128 positions of 8 KV heads whatever the line's length
+    assert big["ring"] == "bf16[5,24,8,128,384]"
+    assert big["ring"] == bench.big_shapes(cfg, max_seq=4096)["ring"]
+    in_place = carried | {"dynamic-update-slice", "custom-call", "fusion"}
+    for leaf in ("kv", "ring"):
+        assert "parameter" in _opcodes_with_shape(text, big[leaf])
+        assert "copy" not in _opcodes_with_shape(text, big[leaf])
+        assert _opcodes_with_shape(text, big[leaf]) <= in_place, leaf
+    # a fusion that gives a leaf is an update in place, nothing else
+    for line in text.splitlines():
+        head = line.split(" = ", 1)
+        if len(head) == 2 and " fusion(" in head[1] and any(
+                big[k] in head[1].split("(", 1)[0] for k in ("kv", "ring")):
+            assert "dynamic-update-slice_fusion" in head[0], line[:200]
+    # a stacked weight is indexed where it is used: a kernel's operand (the
+    # experts), a fusion's parameter (a layer's slice into its product)
+    for leaf in ("wqkv_window", "wqkv_full", "wo", "we_gate", "we_down",
+                 "embed"):
+        assert _opcodes_with_shape(text, big[leaf]) <= \
+            carried | {"fusion", "custom-call", "dynamic-slice"}, leaf
+    # float32 where the configuration's departures say so
+    assert "f32[6,4096,256]" in text and "f32[5,64]" in text
+    assert "bf16[6,4096,256]" not in text and "bf16[5,64]" not in text

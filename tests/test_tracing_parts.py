@@ -25,7 +25,7 @@ from ray_tpu.util import tracing
 # The tiny served models and the programs' arguments, as the engine's own
 # contract test builds them.
 from test_served_model import (MAX_SEQ, SLOTS, _arguments, _deepseek, _lfm2,
-                               _llama, _longcat, _ouro, _phi4flash,
+                               _llama, _longcat, _mimo, _ouro, _phi4flash,
                                _qwen3_next)
 
 # What JAX itself puts on a name stack besides primitives' names.
@@ -108,6 +108,7 @@ SERVED = {
     "deepseek": (_deepseek, DENSE | ROUTED),
     "qwen3_next": (_qwen3_next, DENSE | ROUTED),
     "phi4flash": (_phi4flash, DENSE),
+    "mimo": (_mimo, DENSE | ROUTED),
 }
 LONGCAT_GONE = ("longcat.mla", "longcat.moe", "longcat.moe.experts")
 
@@ -172,6 +173,19 @@ def test_a_serving_program_opens_its_parts(model, program):
         assert not re.search(
             r"[^/\w](ssm|ssm_scan|ssm_state|window_attn|cross_attn|gmu)/",
             text)
+    if model == "mimo":
+        # A window layer's attention proper (the ring's read with the sink,
+        # a chunk's banded product) lies under ``window_attn`` inside
+        # ``attn``; the projections and the full layers' attention stay
+        # plain ``attn``; both geometries' row writes are ``cache``'s.
+        if program == "prefill_chunk":     # the banded product, in jnp
+            assert re.search(r"attn/window_attn/[^/\"]*/dot_general", text)
+            assert re.search(r"attn/window_attn/exp", text)
+        assert re.search(r"attn/window_attn/", text)
+        assert re.search(r"attn/cache/", text)
+        assert re.search(r"attn/dot_general", text)
+        assert not re.search(r"attn/window_attn/cache/", text)
+        assert not re.search(r"[^/\w]window_attn/", text)
 
 
 def _train_step(name):
