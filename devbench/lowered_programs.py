@@ -4,7 +4,7 @@ the whole StableHLO, the StableHLO outside the kernels' serialized bodies
 (those carry source lines, so they differ whenever a line of a caller
 moves), and the jaxpr, which has the kernels' bodies and no source lines.
 
-    cd <tree> && JAX_PLATFORMS=cpu python3 devbench/lowered_programs.py
+    cd <tree> && JAX_PLATFORMS=cpu python3 devbench/lowered_programs.py [model ...]
 
 To compare two trees lay them at the same path in turn (a kernel's body
 names its files) and diff the two outputs: a PR that touches code the
@@ -12,7 +12,12 @@ models share shows with it that their programs are what they were. The
 models are those of ``llm/config.SERVING_MODULES``, each at its ``tiny``
 configuration; a tree from before PR 44 has no such table, and there
 ``MODULES`` below names where each model's programs lived (PR 38 to 43).
-DUMP=<dir> also writes the texts."""
+DUMP=<dir> also writes the texts. With models named (``llama``, ``lfm2``,
+...) only theirs are lowered: a kernel's body is traced once a process and
+shape and keeps the source lines of the caller that traced it, so in one
+process a model whose file moved hands its new lines to every later model
+that runs the same kernel at the same shapes; a model a process (or all
+but the edited one in one) tells the two apart."""
 import dataclasses
 import hashlib
 import importlib
@@ -43,7 +48,9 @@ MODULES = getattr(llm_config, "SERVING_MODULES", None) or {
 def models():
     for kind, module in MODULES.items():
         tiny = TINY.get(kind.__name__, lambda c: c.tiny(max_seq_len=MAX_SEQ, dtype="bfloat16"))
-        yield kind.__name__[:-len("Config")].lower(), importlib.import_module(module), tiny(kind)
+        name = kind.__name__[:-len("Config")].lower()
+        if name in sys.argv[1:] or not sys.argv[1:]:
+            yield name, importlib.import_module(module), tiny(kind)
 
 def run(backend):
     out = {}

@@ -11,10 +11,14 @@ decode program has a whole layer of the cache as operand.
 
 ``prefill_chunk``, ``decode_step`` and ``decode_burst`` are the scheduler's
 three programs (the last two built from ``_decode_step_impl`` by
-llm/served.token_step_programs); ``draft_propose`` and ``spec_verify_step``
-are the two of speculative decoding, which this model alone supplies;
-``prefill`` is the whole-prompt program no schedule runs: the oracle
-``prefill_chunk`` is held to (tests/test_llm.py).
+llm/served.token_step_programs); ``mixed_burst`` is the burst whose steps
+carry a prefill chunk each (from ``_decode_step_impl`` and ``_mixed_impl`` by
+llm/served.mixed_burst_program): a step reads every layer's weights for a
+row a line, a chunk is bound by its products, and riding, the chunk's rows
+pass every product on the step's fetch; ``draft_propose`` and
+``spec_verify_step`` are the two of speculative decoding, which this model
+alone supplies; ``prefill`` is the whole-prompt program no schedule runs:
+the oracle ``prefill_chunk`` is held to (tests/test_llm.py).
 """
 
 from __future__ import annotations
@@ -31,6 +35,7 @@ from ray_tpu.llm.served import (
     ServedModel,
     copy_prefix_kv,
     init_kv_cache,
+    mixed_burst_program,
     token_step_programs,
 )
 from ray_tpu.models import llama as llama_model
@@ -85,6 +90,38 @@ def _lm_head(cfg: LlamaConfig, params, x, kmesh):
     head = (params["embed_tokens"].T if cfg.tie_embeddings
             else params["lm_head"])
     return x.astype(jnp.float32) @ head.astype(jnp.float32)
+
+
+# A scheduled program's attention is made of these halves: the chunk's (one
+# slot, C rows from ``kv_len`` on) and the lines' (every slot, K rows each
+# from its position on). ``prefill_chunk`` runs the first,
+# ``_multi_token_impl`` the second, and a mixed step both, on the rows of
+# one array.
+
+def _chunk_attend(k_all, v_all, q, k, v, layer, slot, kv_len, length, kmesh):
+    """A chunk's rows written to its slot's line and attended from it:
+    q [1, nh, C, D], k and v [1, nkv, C, D] -> (k_all, v_all,
+    o [1, C, nh * D])."""
+    with tracing.part("cache"):
+        k_all, v_all = prefill_kv_write(k_all, v_all, k[0], v[0], layer,
+                                        slot, kv_len)
+    o = prefill_attention(q[0], k_all, v_all, layer, slot, kv_len, length,
+                          kmesh=kmesh)
+    return k_all, v_all, o.transpose(1, 0, 2).reshape(1, q.shape[2], -1)
+
+
+def _lines_attend(k_all, v_all, q, k, v, layer, lengths, positions0,
+                  write_mask, plan, kmesh):
+    """The lines' rows written and attended from: q [B, nh, K, D], k and v
+    [B, nkv, K, D] -> (k_all, v_all, o [B, K, nh * D])."""
+    with tracing.part("cache"):
+        k_all, v_all = kv_row_write(k_all, v_all, k, v, layer, positions0,
+                                    write_mask, kmesh=kmesh)
+    o = decode_attention(q, k_all, v_all, layer, lengths, positions0,
+                         plan=plan, kmesh=kmesh)
+    b, _, rows, _ = q.shape
+    return k_all, v_all, o.transpose(0, 2, 1, 3).reshape(b, rows, -1)
+
 
 @partial(jax.jit, static_argnums=(0,), static_argnames=("kmesh",),
          donate_argnums=(2,))
@@ -181,12 +218,8 @@ def prefill_chunk(cfg: LlamaConfig, params, cache, tokens, kv_len, length,
             q, k, v = _project_qkv(cfg, lp, xn, 1, c)
             q = apply_rope(q, positions, inv_freq)
             k = apply_rope(k, positions, inv_freq)
-            with tracing.part("cache"):
-                k_all, v_all = prefill_kv_write(k_all, v_all, k[0], v[0],
-                                                layer, slot, kv_len)
-            o = prefill_attention(q[0], k_all, v_all, layer, slot, kv_len,
-                                  length, kmesh=kmesh)
-            o = o.transpose(1, 0, 2).reshape(1, c, -1)
+            k_all, v_all, o = _chunk_attend(k_all, v_all, q, k, v, layer,
+                                            slot, kv_len, length, kmesh)
             x = x + (o @ lp["wo"]).astype(x.dtype)
         x = _mlp(cfg, lp, x, kmesh)
         return (x, k_all, v_all), None
@@ -254,13 +287,9 @@ def _multi_token_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
             q, kk, v = _project_qkv(cfg, lp, xn, b, k)
             q = apply_rope(q, positions, inv_freq)
             kk = apply_rope(kk, positions, inv_freq)
-            with tracing.part("cache"):
-                k_all, v_all = kv_row_write(k_all, v_all, kk, v, layer,
-                                            positions0, write_mask,
-                                            kmesh=kmesh)
-            o = decode_attention(q, k_all, v_all, layer, lengths, positions0,
-                                 plan=plan, kmesh=kmesh)
-            o = o.transpose(0, 2, 1, 3).reshape(b, k, -1)
+            k_all, v_all, o = _lines_attend(k_all, v_all, q, kk, v, layer,
+                                            lengths, positions0, write_mask,
+                                            plan, kmesh)
             x = x + (o @ lp["wo"]).astype(x.dtype)
         x = _mlp(cfg, lp, x, kmesh)
         return (x, k_all, v_all), None
@@ -273,7 +302,88 @@ def _multi_token_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
     return {"k": new_k, "v": new_v}, logits
 
 
+def mixed_rows(chunk, tokens, kv_len, length, positions, write_mask):
+    """The rows of a decode step that carries a prefill chunk, the chunk's C
+    first and then a row a line: their ids [C + B]; their positions [C + B];
+    ``valid`` [C + B], a chunk's row inside its prompt and a line that
+    decodes; the lines' ``lengths`` [B] once their row is written; and
+    ``lines_of``, which takes [1, C + B, ...] to the lines' [B, ...]: the
+    head reads those alone, a rider gives no token. The same in every model
+    that offers a ``mixed_burst``: it waits here for llm/served.py (ROADMAP
+    D1 (h))."""
+    c = chunk.shape[0]
+    ids = jnp.concatenate([chunk, tokens])
+    at = jnp.concatenate([kv_len + jnp.arange(c), positions])
+    valid = jnp.concatenate([at[:c] < length, write_mask])
+    lengths = jnp.where(write_mask, positions + 1, 0)
+    return ids, at, valid, lengths, lambda x: x[0, c:]
+
+
+def _mixed_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
+                write_mask, chunk, kv_len, length, slot, kmesh=None):
+    """A decode step that carries a prefill chunk: ``prefill_chunk``'s
+    ``chunk`` [C] of ``slot`` (``write_mask`` false there, as between two
+    chunks) and ``_decode_step_impl``'s token a line, [1, C + B, H] through
+    every layer. The norms, the ``wq`` / ``wk`` / ``wv`` products, ``wo``
+    and the MLP see all rows at once: a layer's weights are fetched once
+    for both. The attention splits them, the chunk's rows to the chunk's
+    half and the lines' to the lines'. The products' rows are split before
+    their heads: the heads of all 528 rows split first (``_project_qkv``'s
+    order) cost 1.6 ms a step more at docqa's 16 layers, the slices of the
+    stacked ``wq``, ``wk`` and ``wv`` copied out a layer as in
+    ``prefill_chunk``; a barrier on the halves' outputs, the lines' half
+    first and the lines' rows first gave nothing (my chip runs, PR 55).
+    Returns (cache, the lines' logits [B, V]): a riding chunk gives no
+    token."""
+    c, b = chunk.shape[0], tokens.shape[0]
+    num_layers = cache["k"].shape[0]
+    with tracing.part("attn"):
+        ids, at, _, lengths, lines_of = mixed_rows(
+            chunk, tokens, kv_len, length, positions0, write_mask)
+        at_chunk, at_lines = at[:c], at[c:, None]
+        inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
+                                    cfg.rope_scaling)
+        plan = decode_plan_of(lengths, cache["k"], kmesh=kmesh)
+    with tracing.part("embed"):
+        x = params["embed_tokens"][ids][None]  # [1, C + B, H]
+
+    def heads(rows):
+        """A product's rows [1, C + B, n * D], head-major a half: the
+        chunk's [1, n, C, D] and the lines' [B, n, 1, D]."""
+        rows = rows.reshape(c + b, -1, cfg.head_dim)
+        return (rows[:c].transpose(1, 0, 2)[None], rows[c:, :, None])
+
+    def body(carry, scanned):
+        x, k_all, v_all = carry
+        lp, layer = scanned
+        with tracing.part("attn"):
+            xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
+            (q, q_l), (k, k_l), (v, v_l) = (
+                heads(xn @ lp[w]) for w in ("wq", "wk", "wv"))
+            q, k = (apply_rope(a, at_chunk, inv_freq) for a in (q, k))
+            q_l, k_l = (apply_rope(a, at_lines, inv_freq)
+                        for a in (q_l, k_l))
+            k_all, v_all, o = _chunk_attend(k_all, v_all, q, k, v, layer,
+                                            slot, kv_len, length, kmesh)
+            k_all, v_all, o_l = _lines_attend(k_all, v_all, q_l, k_l, v_l,
+                                              layer, lengths, positions0,
+                                              write_mask, plan, kmesh)
+            o = jnp.concatenate([o, o_l.reshape(1, b, -1)], axis=1)
+            x = x + (o @ lp["wo"]).astype(x.dtype)
+        x = _mlp(cfg, lp, x, kmesh)
+        return (x, k_all, v_all), None
+
+    with tracing.part("stack"):
+        (x, new_k, new_v), _ = lax.scan(
+            body, (x, cache["k"], cache["v"]),
+            (params["layers"], jnp.arange(num_layers)))
+    logits = _lm_head(cfg, params, lines_of(x)[:, None], kmesh)  # [B, 1, V]
+    with tracing.part("head"):
+        return {"k": new_k, "v": new_v}, logits[:, 0]
+
+
 decode_step, decode_burst = token_step_programs(_decode_step_impl)
+mixed_burst = mixed_burst_program(_decode_step_impl, _mixed_impl)
 
 
 # ---------------------------------------------------------------------------
@@ -339,4 +449,5 @@ SERVED = ServedModel(
         max_seq, cfg.head_dim, cfg.jnp_dtype.itemsize),
     draft_propose=draft_propose,
     spec_verify_step=spec_verify_step,
+    mixed_burst=mixed_burst,
 )

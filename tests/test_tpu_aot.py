@@ -241,6 +241,45 @@ def test_engine_programs_partition_over_tensor_parallel_chips(mosaic):
                    for (op, n) in _collectives(text, 4))
 
 
+def test_mixed_burst_partitions_over_tensor_parallel_chips(mosaic):
+    """The burst whose steps carry a chunk, on the mesh of the test above:
+    both halves' kernels run on their shard's heads (the rows of the three
+    products are split before their heads, which are the sharded axis), the
+    activations stay replicated and only reductions cross chips."""
+    from ray_tpu.llm import llama_serving
+
+    slots, max_seq, steps, chunk = 4, 256, 4, 32
+    mesh = build_mesh(MeshSpec(tp=4), mosaic)
+    repl = NamedSharding(mesh, P())
+    params = _sds(
+        jax.eval_shape(partial(init_params, CFG), jax.random.PRNGKey(0)),
+        tree_shardings(mesh, param_logical_axes(CFG)))
+    cache = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=NamedSharding(mesh, P(None, None, "tp"))),
+        jax.eval_shape(partial(llama_serving.init_kv_cache, CFG, slots,
+                               max_seq)))
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
+
+    riders = (arg((steps, chunk)), arg((steps,)), arg((steps,)),
+              arg((steps,)), arg(()))
+    text = llama_serving.mixed_burst.lower(
+        CFG, params, cache, arg((slots,)), arg((slots,)),
+        arg((slots,), jnp.bool_), arg((slots,), jnp.float32),
+        arg((slots,), jnp.float32), arg((2,), jnp.uint32), riders, steps,
+        False, kmesh=kernel_mesh(mesh)).compile().as_text()
+    assert "num_partitions=4" in text
+    # a riding step's six and a plain step's five (the test above)
+    assert text.count(MOSAIC) == 6 + 5
+    for name in ("prefill_attention", "decode_attention", "kv_row_write"):
+        assert f'"{name}"' in text or f"%{name}." in text, name
+    assert all(op == "all-reduce" or n <= 64
+               for (op, n) in _collectives(text, 4))
+
+
 # Mistral-7B widths, two layers: the decode program of the two serving cells.
 MISTRAL = LlamaConfig(vocab_size=32768, hidden_size=4096,
                       intermediate_size=14336, num_layers=2, num_heads=32,
@@ -367,6 +406,80 @@ def test_prefill_chunk_moves_no_whole_cache_at_mistral_widths(mosaic, slots,
     assert _opcodes_with_shape(text, stack) <= passing
     assert not _opcodes_with_shape(text, f"[{slots},{hkv},{max_seq},{d}]")
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# The dense module's burst whose steps carry a chunk (PR 55), at the depth
+# and cache of the two closed-loop Mistral cells, as the cells compile it.
+@pytest.mark.parametrize("use,layers,slots,max_seq", [
+    ("serve_docqa", 16, 16, 3200), ("serve_reason", 12, 32, 3072)])
+def test_llama_mixed_burst_copies_no_cache_and_no_weight_of_its_own(
+        mosaic, use, layers, slots, max_seq):
+    """``mixed_burst(8)`` holds both loops' bodies: the chunk's 512 rows go
+    in by an update in place and the lines' by the row kernel on the one
+    stack, which only passes through; a layer of it is nobody's operand.
+    Arguments and temporaries fit the chip's 15.75 GiB. The steps past the
+    riders are ``decode_burst``'s: whatever that program copies of a stacked
+    weight at the top (at these widths XLA re-lays ``wq``, ``wk`` and ``wo``
+    out for 16 or 32 rows: ``PERF.md`` section 5) the mixed one copies too,
+    and no more: its temporaries are the plain burst's, the riding steps'
+    528 or 544 rows of activations aside."""
+    from devbench import llama_bench as bench
+
+    cfg, *cell = bench.config(use)
+    assert (cfg.num_layers, *cell) == (layers, slots, max_seq)
+    dev = NamedSharding(build_mesh(MeshSpec(), mosaic[:1]), P())
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=dev), tree)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    params, cache = bench.shapes(cfg, slots, max_seq, placed)
+    lower = bench.lowerings(cfg, params, cache, arg)
+    mixed, plain = (lower[name]().compile()
+                    for name in ("mixed_burst(8)", "decode_burst(8)"))
+    text, plain_text = mixed.as_text(), plain.as_text()
+    for name in ("prefill_attention", "decode_attention", "kv_row_write"):
+        assert f'"{name}"' in text or f"%{name}." in text, name
+    # A riding step's five a layer (two norms, the chunk's attention, the
+    # rows' write, the lines' attention) and its final norm on the lines'
+    # rows; a plain step's four and its final norm.
+    assert text.count(MOSAIC) == 5 + 1 + 4 + 1
+    mem, plain_mem = mixed.memory_analysis(), plain.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30 \
+        < 15.75
+    assert mem.temp_size_in_bytes < plain_mem.temp_size_in_bytes + (1 << 26)
+    big = bench.big_shapes(cfg, slots, max_seq)
+    carried = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+    assert set(bench.opcodes_with_shape(text, big["cache"])) <= \
+        carried | {"dynamic-update-slice", "custom-call"}
+    assert not bench.opcodes_with_shape(text, big["cache_layer"])
+    for leaf in ("wq", "wk", "wo", "w_gate", "w_down"):
+        ops = bench.opcodes_with_shape(text, big[leaf])
+        was = bench.opcodes_with_shape(plain_text, big[leaf])
+        assert set(ops) <= carried | set(was), leaf
+        assert ops.get("copy", 0) <= was.get("copy", 0), leaf
+    # No logits of the chunk's rows: the head runs on the lines' alone.
+    assert f"f32[{slots},{cfg.vocab_size}]" in text
+    assert f"[{512 + slots},{cfg.vocab_size}]" not in text
+    assert _plans_in_no_layer_loop_of(text, loops=2)
+
+
+def _plans_in_no_layer_loop_of(text: str, loops: int) -> bool:
+    """The plan's cumulative sum (a ``reduce-window``) is in the program,
+    and none of the ``loops`` computations that hold a ``decode_attention``
+    call (a burst's riding steps' layer loop and its plain steps') has it
+    or a sort. A body is found by the call's instruction: the kernel's
+    quoted name stands once a program, in the module's header, which is
+    the one "body" :func:`_plans_outside_the_layer_loop` finds (ROADMAP
+    D8)."""
+    bodies = [c for c in re.split(r"\n(?=(?:ENTRY )?%[\w.-]+ \()", text)[1:]
+              if re.search(r"%decode_attention\.\d+ = ", c)]
+    return (len(bodies) == loops and "reduce-window(" in text
+            and not any("reduce-window(" in b or " sort(" in b
+                        for b in bodies))
 
 
 @pytest.mark.parametrize("slots", [32, 16])
