@@ -483,6 +483,9 @@ def run_smoke(cfg: SmokeConfig, n_devices: int) -> dict:
     import ray_tpu
 
     kernels = kernel_phase(cfg, n_devices)
+    # A runtime that is already up (a test's, earlier in this process) has
+    # no TPU among its resources, and ``init`` would return it as it is.
+    ray_tpu.shutdown()
     ray_tpu.init(resources={"TPU": float(n_devices)})
     try:
         t0 = time.perf_counter()

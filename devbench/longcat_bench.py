@@ -121,7 +121,7 @@ def main(argv: list[str]) -> int:
         return 1
     from rtbench.adapters import longcat as adapter
 
-    from ray_tpu.llm import longcat_serving as serving, served
+    from ray_tpu.llm import latent, longcat_serving as serving, served
     from ray_tpu.models.longcat import forward as longcat_forward
     from ray_tpu.ops import grouped_matmul as gmm
     from ray_tpu.ops import latent_attention as la
@@ -281,7 +281,7 @@ def main(argv: list[str]) -> int:
                  "up_projected": up_projected,
                  "absorbed": absorbed_prefill_attention}
         for name, fn in forms.items():
-            serving.latent_prefill_attention = fn
+            latent.latent_prefill_attention = fn
             serving.prefill_chunk.clear_cache()
             for kv_len in (0, 2048, 5632):
                 def call():
@@ -290,7 +290,7 @@ def main(argv: list[str]) -> int:
                     return logits
                 out(program="prefill_chunk(512)", attention=name,
                     cached=kv_len, ms=timed(call, 5) * 1e3)
-        serving.latent_prefill_attention = la.latent_prefill_attention
+        latent.latent_prefill_attention = la.latent_prefill_attention
         serving.prefill_chunk.clear_cache()
 
     if "trace" in want:

@@ -79,6 +79,10 @@ def run(backend):
             }
             if served.decode_step is None:
                 del progs["decode_step"]
+            if served.mixed_burst is not None:
+                # ``decode_burst``'s arguments with the riders after the key: a chunk a step, its slot, cached rows and length, and how many ride
+                riders = (arg((4, CHUNK)), arg((4,)), arg((4,)), arg((4,)), arg(()))
+                progs["mixed_burst"] = (*progs["decode_burst"][:9], riders, 4, False)
             for prog, args in progs.items():
                 text = getattr(module, prog).lower(*args).as_text()
                 out[f"{name}.{prog}.{backend}"] = hashlib.sha256(text.encode()).hexdigest()[:16]
@@ -86,7 +90,7 @@ def run(backend):
                 bare = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""', text)
                 out[f"{name}.{prog}.{backend}.outside_kernels"] = hashlib.sha256(bare.encode()).hexdigest()[:16]
                 fn = getattr(module, prog)
-                static = tuple(i for i, a in enumerate(args) if not hasattr(a, "shape") and not isinstance(a, dict))
+                static = tuple(i for i, a in enumerate(args) if not hasattr(a, "shape") and not isinstance(a, (dict, tuple)))
                 jp = str(jax.make_jaxpr(fn, static_argnums=static)(*args))
                 out[f"{name}.{prog}.{backend}.jaxpr"] = hashlib.sha256(jp.encode()).hexdigest()[:16]
                 if os.environ.get("DUMP"):

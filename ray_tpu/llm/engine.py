@@ -81,6 +81,7 @@ from ray_tpu.llm.served import (  # noqa: F401
     ServedModel,
     init_params,
     require_kv_handoff,
+    require_tensor_parallel,
     sample_tokens,
     served_model,
 )
@@ -197,6 +198,7 @@ class LLMEngine:
                 "ROADMAP R3, as tables the attention kernels read")
         if self.model.refuse is not None:
             self.model.refuse(config)
+        require_tensor_parallel(self.model_cfg, config.tensor_parallel_size)
         # Speculative decoding: draft model + its own KV cache. The draft
         # must share the tokenizer's vocab space with the target; the
         # target supplies the verify program and the draft the proposals.

@@ -48,6 +48,7 @@ from jax import lax
 from ray_tpu.llm.served import (
     ServedModel,
     mixed_burst_program,
+    mixed_rows,
     token_step_programs,
 )
 from ray_tpu.models import lfm2
@@ -218,24 +219,20 @@ def _decode_impl(cfg: Lfm2Config, params, cache, tokens, positions0,
 
 def _mixed_impl(cfg: Lfm2Config, params, cache, tokens, positions0,
                 write_mask, chunk, kv_len, length, slot, kmesh=None):
-    """A decode step that carries a prefill chunk: ``prefill_chunk``'s
-    ``chunk`` [C] of ``slot`` (``write_mask`` false there, as between two
-    chunks) and ``_decode_impl``'s token a line, [1, C + B, H] through every
-    layer. The norms, the projections and the routed layer see all rows at
-    once (a routed layer's experts are fetched once for both: one
-    layer-step in its counts); the operators split them, the chunk's rows
-    to the chunk's halves and the lines' to the lines'. Returns (cache, the
-    lines' logits [B, V], counts): a riding chunk gives no token."""
+    """``mixed_step`` of llm/served.mixed_burst_program. The norms, the
+    projections and the routed layer see all rows at once (a routed layer's
+    experts are fetched once for both: one layer-step in its counts); the
+    operators split them, the chunk's rows to the chunk's halves and the
+    lines' to the lines'."""
     c, b = chunk.shape[0], tokens.shape[0]
-    with tracing.part("embed"):
-        x = params["embed_tokens"][jnp.concatenate([chunk, tokens])][None]
     with tracing.part("attn"):
-        positions = jnp.concatenate([kv_len + jnp.arange(c), positions0])
-        lengths = jnp.where(write_mask, positions0 + 1, 0)
-        valid = jnp.concatenate([positions[:c] < length, write_mask])[None]
+        ids, positions, valid, lengths, lines_of = mixed_rows(
+            chunk, tokens, kv_len, length, positions0, write_mask)
         inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta)
         n_valid = jnp.clip(length - kv_len, 0, c)
         plan = decode_plan_of(lengths, cache["kv"], kmesh=kmesh)
+    with tracing.part("embed"):
+        x = params["embed_tokens"][ids][None]             # [1, C + B, H]
 
     def conv(line, cp, xn, state):
         kv, cs = state
@@ -266,8 +263,9 @@ def _mixed_impl(cfg: Lfm2Config, params, cache, tokens, positions0,
         return (o @ ap["wo"]).astype(xn.dtype), (kv, cs)
 
     x, cache, counts = _run(cfg, params, x, cache,
-                            {CONV: conv, ATTENTION: attention}, valid, kmesh)
-    return cache, lfm2.lm_head(cfg, params, x[0, c:], kmesh), counts
+                            {CONV: conv, ATTENTION: attention}, valid[None],
+                            kmesh)
+    return cache, lfm2.lm_head(cfg, params, lines_of(x), kmesh), counts
 
 
 decode_step, decode_burst = token_step_programs(_decode_impl, MOE_COUNTERS)
@@ -276,15 +274,11 @@ mixed_burst = mixed_burst_program(_decode_impl, _mixed_impl, MOE_COUNTERS)
 
 def _refuse(config) -> None:
     """What this model does not run, said at construction."""
-    for bad, what in (
-            (config.speculative_model is not None,
-             "a speculative draft: a rejected token's rows lie past the "
-             "accepted length and are overwritten, its step of the "
-             "convolution's state cannot be taken back"),
-            (config.tensor_parallel_size > 1,
-             "tensor_parallel_size > 1: its programs run on one device")):
-        if bad:
-            raise ValueError(f"Lfm2Config does not support {what}")
+    if config.speculative_model is not None:
+        raise ValueError(
+            "Lfm2Config does not support a speculative draft: a rejected "
+            "token's rows lie past the accepted length and are overwritten, "
+            "its step of the convolution's state cannot be taken back")
 
 
 SERVED = ServedModel(

@@ -36,6 +36,7 @@ from ray_tpu.llm.served import (
     copy_prefix_kv,
     init_kv_cache,
     mixed_burst_program,
+    mixed_rows,
     token_step_programs,
 )
 from ray_tpu.models import llama as llama_model
@@ -302,39 +303,18 @@ def _multi_token_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
     return {"k": new_k, "v": new_v}, logits
 
 
-def mixed_rows(chunk, tokens, kv_len, length, positions, write_mask):
-    """The rows of a decode step that carries a prefill chunk, the chunk's C
-    first and then a row a line: their ids [C + B]; their positions [C + B];
-    ``valid`` [C + B], a chunk's row inside its prompt and a line that
-    decodes; the lines' ``lengths`` [B] once their row is written; and
-    ``lines_of``, which takes [1, C + B, ...] to the lines' [B, ...]: the
-    head reads those alone, a rider gives no token. The same in every model
-    that offers a ``mixed_burst``: it waits here for llm/served.py (ROADMAP
-    D1 (h))."""
-    c = chunk.shape[0]
-    ids = jnp.concatenate([chunk, tokens])
-    at = jnp.concatenate([kv_len + jnp.arange(c), positions])
-    valid = jnp.concatenate([at[:c] < length, write_mask])
-    lengths = jnp.where(write_mask, positions + 1, 0)
-    return ids, at, valid, lengths, lambda x: x[0, c:]
-
-
 def _mixed_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
                 write_mask, chunk, kv_len, length, slot, kmesh=None):
-    """A decode step that carries a prefill chunk: ``prefill_chunk``'s
-    ``chunk`` [C] of ``slot`` (``write_mask`` false there, as between two
-    chunks) and ``_decode_step_impl``'s token a line, [1, C + B, H] through
-    every layer. The norms, the ``wq`` / ``wk`` / ``wv`` products, ``wo``
-    and the MLP see all rows at once: a layer's weights are fetched once
-    for both. The attention splits them, the chunk's rows to the chunk's
-    half and the lines' to the lines'. The products' rows are split before
+    """``mixed_step`` of llm/served.mixed_burst_program. The norms, the
+    ``wq`` / ``wk`` / ``wv`` products, ``wo`` and the MLP see all rows at
+    once: a layer's weights are fetched once for both. The attention splits
+    them, the chunk's rows to the chunk's half and the lines' to the
+    lines'. The products' rows are split before
     their heads: the heads of all 528 rows split first (``_project_qkv``'s
     order) cost 1.6 ms a step more at docqa's 16 layers, the slices of the
     stacked ``wq``, ``wk`` and ``wv`` copied out a layer as in
     ``prefill_chunk``; a barrier on the halves' outputs, the lines' half
-    first and the lines' rows first gave nothing (my chip runs, PR 55).
-    Returns (cache, the lines' logits [B, V]): a riding chunk gives no
-    token."""
+    first and the lines' rows first gave nothing (my chip runs, PR 55)."""
     c, b = chunk.shape[0], tokens.shape[0]
     num_layers = cache["k"].shape[0]
     with tracing.part("attn"):
@@ -447,6 +427,8 @@ SERVED = ServedModel(
     copy_prefix_kv=copy_prefix_kv,
     kv_block=lambda cfg, max_seq: decode_kv_block(
         max_seq, cfg.head_dim, cfg.jnp_dtype.itemsize),
+    # Heads and the MLP's columns shard over ``tp``, the cache with them.
+    tensor_parallel=True,
     draft_propose=draft_propose,
     spec_verify_step=spec_verify_step,
     mixed_burst=mixed_burst,

@@ -288,8 +288,6 @@ def _refuse(config) -> None:
             (config.speculative_model is not None,
              "a speculative draft: a rejected token's row of a ring cannot "
              "be taken back"),
-            (config.tensor_parallel_size > 1,
-             "tensor_parallel_size > 1: its programs run on one device"),
             (config.kv_block_size > 0,
              "kv_block_size > 0: a slot has full lines and rings of another "
              "length and head count, and the block pool has one kind of "

@@ -154,16 +154,13 @@ decode_step, decode_burst = token_step_programs(_decode_impl,
 def _refuse(config) -> None:
     """What this model does not run, said at construction."""
     cfg = config.model_config()
-    for bad, what in (
-            (cfg.early_exit_threshold < 1,
-             f"early_exit_threshold {cfg.early_exit_threshold} (under 1): a "
-             "token that leaves the loop early still owes its later passes' "
-             "cache lines to the tokens after it, and the scheduler's bursts "
-             "and its count of cached positions assume equal work a token"),
-            (config.tensor_parallel_size > 1,
-             "tensor_parallel_size > 1: its programs run on one device")):
-        if bad:
-            raise ValueError(f"OuroConfig does not support {what}")
+    if cfg.early_exit_threshold < 1:
+        raise ValueError(
+            "OuroConfig does not support early_exit_threshold "
+            f"{cfg.early_exit_threshold} (under 1): a token that leaves the "
+            "loop early still owes its later passes' cache lines to the "
+            "tokens after it, and the scheduler's bursts and its count of "
+            "cached positions assume equal work a token")
 
 
 SERVED = ServedModel(

@@ -312,8 +312,6 @@ def _refuse(config) -> None:
             (config.speculative_model is not None,
              "a speculative draft: a rejected token's step of the scan's "
              "state and its row of a ring cannot be taken back"),
-            (config.tensor_parallel_size > 1,
-             "tensor_parallel_size > 1: its programs run on one device"),
             (config.kv_block_size > 0,
              "kv_block_size > 0: a slot has a full line and rings of "
              "another length, and the block pool has one kind of line "

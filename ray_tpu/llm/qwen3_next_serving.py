@@ -230,15 +230,11 @@ decode_step, decode_burst = token_step_programs(_decode_impl, COUNTERS)
 
 def _refuse(config) -> None:
     """What this model does not run, said at construction."""
-    for bad, what in (
-            (config.speculative_model is not None,
-             "a speculative draft: a rejected token's rows lie past the "
-             "accepted length and are overwritten, its step of the rule's "
-             "state cannot be taken back"),
-            (config.tensor_parallel_size > 1,
-             "tensor_parallel_size > 1: its programs run on one device")):
-        if bad:
-            raise ValueError(f"Qwen3NextConfig does not support {what}")
+    if config.speculative_model is not None:
+        raise ValueError(
+            "Qwen3NextConfig does not support a speculative draft: a "
+            "rejected token's rows lie past the accepted length and are "
+            "overwritten, its step of the rule's state cannot be taken back")
 
 
 SERVED = ServedModel(

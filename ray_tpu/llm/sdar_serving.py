@@ -262,14 +262,10 @@ def decode_burst(cfg: SdarConfig, params, cache, token0, positions0,
 
 def _refuse(config) -> None:
     """What this model does not run, said at construction."""
-    for bad, what in (
-            (config.speculative_model is not None,
-             "a speculative draft: a step decides a block by forwards of "
-             "its own, there is no token to verify"),
-            (config.tensor_parallel_size > 1,
-             "tensor_parallel_size > 1: its programs run on one device")):
-        if bad:
-            raise ValueError(f"SdarConfig does not support {what}")
+    if config.speculative_model is not None:
+        raise ValueError(
+            "SdarConfig does not support a speculative draft: a step decides "
+            "a block by forwards of its own, there is no token to verify")
 
 
 SERVED = ServedModel(

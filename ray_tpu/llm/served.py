@@ -3,8 +3,11 @@ serves (llm/<name>_serving.py): what a model supplies (:class:`ServedModel`),
 how a configuration finds its model (:func:`served_model`, by the table
 ``llm/config.SERVING_MODULES``), and what the models share because the
 scheduler gives it one meaning for all of them: the sampler, the per-head
-K/V slot cache, and the builder of the two programs of a model that takes a
-token in and gives a token out a step (:func:`token_step_programs`).
+K/V slot cache, the builder of the two programs of a model that takes a
+token in and gives a token out a step (:func:`token_step_programs`) and of
+the burst whose steps carry a prefill chunk (:func:`mixed_burst_program`).
+What two models share and the scheduler knows nothing of is not here: the
+latent cache line is llm/latent.py's.
 
 The arrows point one way: ``engine.py`` imports this module, the serving
 modules import this module, and this module imports none of them when it is
@@ -152,6 +155,9 @@ class ServedModel:
       fetches at a time, behind ``kv_positions_read``;
     - ``kv_handoff``: whether a line can be exported and imported as
       per-head K/V (the prefill/decode hand-off);
+    - ``tensor_parallel``: whether its programs partition over a ``tp``
+      mesh (the engine shards the cache's third axis over it); where they
+      do not, :func:`require_tensor_parallel` refuses the mesh;
     - ``prefix_from_line``: whether a prompt's first tokens can be adopted
       from another slot's line, at any common length. False for a model
       that also keeps a state of fixed size a slot (a short convolution's,
@@ -189,6 +195,7 @@ class ServedModel:
     constants: Callable | None = None
     step: Callable | None = None
     kv_handoff: bool = True
+    tensor_parallel: bool = False
     prefix_from_line: bool = True
     draft_propose: Callable | None = None
     spec_verify_step: Callable | None = None
@@ -307,7 +314,15 @@ def mixed_burst_program(step: Callable, mixed_step: Callable,
     ``n``. It is jitted under the name ``decode_burst``: a device trace is
     read by program name, and these are decode steps (a reader that divides
     ``jit_decode_burst``'s time by the steps the dispatch phase carried
-    keeps a true number)."""
+    keeps a true number).
+
+    A ``mixed_step`` is written by a convention, not from a base: it takes
+    its rows from :func:`mixed_rows` and projects all ``C + B`` at once
+    (what a chunk and a step both fetch), then splits the products' rows
+    before their heads. The attention is two halves, the chunk's write and
+    attend and the lines', the very functions the model's ``prefill_chunk``
+    and ``step`` close over. Their outputs are joined and pass ``wo`` as one
+    array."""
 
     @partial(jax.jit, static_argnums=(0, 10, 11), static_argnames=("kmesh",),
              donate_argnums=(2,))
@@ -346,6 +361,22 @@ def mixed_burst_program(step: Callable, mixed_step: Callable,
     return decode_burst
 
 
+def mixed_rows(chunk, tokens, kv_len, length, positions, write_mask):
+    """The rows of a decode step that carries a prefill chunk, the chunk's C
+    first and then a row a line: their ids [C + B]; their positions [C + B];
+    ``valid`` [C + B], a chunk's row inside its prompt and a line that
+    decodes; the lines' ``lengths`` [B] once their row is written; and
+    ``lines_of``, which takes [1, C + B, ...] to the lines' [B, ...]: the
+    head reads those alone, a rider gives no token. The same in every model
+    that offers a ``mixed_burst``."""
+    c = chunk.shape[0]
+    ids = jnp.concatenate([chunk, tokens])
+    at = jnp.concatenate([kv_len + jnp.arange(c), positions])
+    valid = jnp.concatenate([at[:c] < length, write_mask])
+    lengths = jnp.where(write_mask, positions + 1, 0)
+    return ids, at, valid, lengths, lambda x: x[0, c:]
+
+
 def served_model(cfg) -> ServedModel:
     """The model behind a configuration, by its type: the ``SERVED`` of
     the module ``llm/config.SERVING_MODULES`` names for it, imported when
@@ -365,6 +396,15 @@ def require_kv_handoff(cfg) -> None:
         raise ValueError(
             f"{type(cfg).__name__} does not support the prefill/decode "
             "hand-off: its cache is not per-head K/V")
+
+
+def require_tensor_parallel(cfg, size: int) -> None:
+    """Raise where ``tensor_parallel_size`` asks for more than one device
+    and the model's programs do not partition."""
+    if size > 1 and not served_model(cfg).tensor_parallel:
+        raise ValueError(
+            f"{type(cfg).__name__} does not support tensor_parallel_size > "
+            "1: its programs run on one device")
 
 
 def init_params(cfg, key):

@@ -26,9 +26,9 @@ The shared experts are replicated in such a deployment: every shard
 computes them whole for its own tokens, so where shards' results are summed
 the shared part counts once (tests/test_deepseek.py).
 
-Latent attention is models/longcat.py's (``mla_project``, ``mla_full``;
+Latent attention is models/mla.py's (``mla_project``, ``mla_full``;
 ``kv_up_projections`` is this module's own, for a ``wkv_b`` stored a head
-at a time) without its two norm factors, with
+at a time) without LongCat's two norm factors, with
 YaRN's inverse frequencies (ops/rope.py) and the softmax scale multiplied
 by ``yarn_mscale(factor, mscale_all_dim) ** 2``; the factor on cos and sin
 is ``yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)``,
@@ -39,7 +39,7 @@ Params: a flat pytree, every leaf stacked over the layers that have it (the
 attention's and the norms' over all layers, the dense SwiGLU's over the
 dense layers, the router's, the shared and the routed experts' over the
 routed layers) and indexed by the loop's counter where it is used
-(models/longcat.py's finding on scanned slices).
+(the LongCat model's finding on scanned slices).
 """
 
 from __future__ import annotations
@@ -53,7 +53,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.lfm2 import swiglu
-from ray_tpu.models.longcat import mla_full
+from ray_tpu.models.mla import mla_full
 from ray_tpu.models.routed import (
     MOE_COUNTERS,
     RouterRule,
@@ -104,11 +104,11 @@ class DeepseekV2Config:
     rope_mscale_all_dim: float = 0.707
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
-    # What this program holds of the routed experts (models/longcat.py).
+    # What this program holds of the routed experts (as LongCat's).
     expert_shard: int = 0
     expert_shards: int = 1
 
-    # models/longcat.mla_project's two norm factors: this family has none.
+    # models/mla.mla_project's two norm factors: this family has none.
     mla_scale_q_lora: ClassVar[bool] = False
     mla_scale_kv_lora: ClassVar[bool] = False
 
@@ -194,7 +194,7 @@ class DeepseekV2Config:
     @property
     def latent_row(self) -> int:
         """Width of a cached row: whole 128-lane tiles
-        (models/longcat.LongcatConfig.latent_row)."""
+        (as LongcatConfig.latent_row)."""
         return -(-self.latent_dim // 128) * 128
 
     @property
@@ -267,7 +267,7 @@ def init_params(cfg: DeepseekV2Config, key: jax.Array) -> dict:
     the dense SwiGLU's and the shared experts' output projections are not
     scaled down by depth: every branch adds about unit variance and the
     residual stream grows along the layers as a trained one does
-    (models/longcat.py). The routed experts' down-projections are, by
+    (LongCat's ``init_params``). The routed experts' down-projections are, by
     1 / sqrt(8 x routed layers): models/lfm2.py's and models/sdar.py's
     1 / sqrt(2 x routed layers), for their reason, and half of it again.
     The sixth place of the rule, and here the third place among the groups
@@ -335,7 +335,7 @@ def init_params(cfg: DeepseekV2Config, key: jax.Array) -> dict:
 
 def kv_up_projections(cfg: DeepseekV2Config, wkv_b):
     """wkv_b [nh, rank, Dn + Dv] -> the key half [rank, nh, Dn] and the
-    value half [rank, nh, Dv], as models/longcat.kv_up_projections gives
+    value half [rank, nh, Dv], as models/mla.kv_up_projections gives
     them. The leaf is stored a head at a time (the published matrix is
     [rank, nh * (Dn + Dv)], a head's keys then its values): every product
     with it runs over heads as a batch, the absorbed decode step's two

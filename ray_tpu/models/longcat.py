@@ -24,11 +24,12 @@ experts' part, which every shard computes for its own tokens. What the
 absent shards' experts would add is an expert-parallel exchange this module
 does not have.
 
-Latent attention keeps, per position, ``c_kv`` (after its norm and scale)
-and the rotated shared key ``k_r``: ``kv_lora_rank + qk_rope_head_dim``
-values for all heads. ``forward`` (training-shaped, no cache) up-projects
-keys and values from it; the serving programs (llm/longcat_serving.py)
-attend in the absorbed form against the cached rows.
+Latent attention is models/mla.py's, with this family's two norm factors
+(``mla_scale_q_lora``, ``mla_scale_kv_lora``): per position it keeps
+``kv_lora_rank + qk_rope_head_dim`` values for all heads. ``forward``
+(training-shaped, no cache) up-projects keys and values from them
+(``mla_full``); the serving programs (llm/longcat_serving.py) attend in the
+absorbed form against the cached rows.
 
 Params follow models/llama.py: a flat pytree with layers stacked on the
 leading axis. The leaves of the attentions and dense FFNs are stacked by
@@ -48,6 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models.mla import mla_full, mla_scales
 # MOE_COUNTERS, MOE_TILE and dispatch_plan are this module's names too.
 from ray_tpu.models.routed import (  # noqa: F401
     MOE_COUNTERS,
@@ -59,7 +61,6 @@ from ray_tpu.models.routed import (  # noqa: F401
 )
 from ray_tpu.ops.kernels import KernelMesh
 from ray_tpu.ops.norms import rms_norm
-from ray_tpu.ops.rope import apply_rope_interleaved, rope_frequencies
 from ray_tpu.util import tracing
 
 @dataclass(frozen=True)
@@ -286,83 +287,6 @@ def swiglu(x, w_gate, w_up, w_down):
     dt = x.dtype
     gate = jax.nn.silu((x @ w_gate).astype(jnp.float32)).astype(dt)
     return ((gate * (x @ w_up)) @ w_down).astype(dt)
-
-
-def mla_scales(cfg: LongcatConfig) -> tuple[float, float]:
-    """What the two latent norms' outputs are multiplied by."""
-    sq = (math.sqrt(cfg.hidden_size / cfg.q_lora_rank)
-          if cfg.mla_scale_q_lora else 1.0)
-    skv = (math.sqrt(cfg.hidden_size / cfg.kv_lora_rank)
-           if cfg.mla_scale_kv_lora else 1.0)
-    return sq, skv
-
-
-def mla_project(cfg, ap: dict, xn, positions, kmesh=None,
-                keep_product: bool = False):
-    """``cfg`` a LongcatConfig, or another model's with the same latent
-    attention (models/deepseek.py). xn: [B, S, H] (normed); positions [S]
-    or [B, S]. Returns the heads'
-    queries q_n [B, S, nh, Dn] and q_r [B, S, nh, Dr] (rotated), and the
-    rows to cache [B, S, latent_row]: ``c_kv`` after norm and scale, the
-    rotated shared key, zeros up to the row's width. ``keep_product`` keeps
-    the queries' product an array of its own before it is split into heads
-    (models/ouro.block's finding: XLA otherwise folds the split into the
-    product and copies the whole stacked ``wq_b`` transposed at the top of
-    a decode program, 0.6 GB at 128 heads)."""
-    b, s, _ = xn.shape
-    sq, skv = mla_scales(cfg)
-    dt = xn.dtype
-    cq = rms_norm(xn @ ap["wq_a"], ap["q_a_norm"], cfg.norm_eps, kmesh)
-    q = (cq * sq).astype(dt) @ ap["wq_b"]
-    if keep_product:
-        q = lax.optimization_barrier(q)
-    q = q.reshape(b, s, cfg.num_heads, cfg.qk_head_dim)
-    q_n, q_r = q[..., :cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]
-    kv = xn @ ap["wkv_a"]
-    ckv = rms_norm(kv[..., :cfg.kv_lora_rank], ap["kv_a_norm"], cfg.norm_eps,
-                   kmesh)
-    ckv = (ckv * skv).astype(dt)
-    inv_freq = rope_frequencies(cfg.qk_rope_head_dim, cfg.rope_theta,
-                                cfg.rope_scaling)
-    q_r = apply_rope_interleaved(q_r.transpose(0, 2, 1, 3), positions,
-                                 inv_freq).transpose(0, 2, 1, 3)
-    k_r = apply_rope_interleaved(kv[:, None, :, cfg.kv_lora_rank:],
-                                 positions, inv_freq)[:, 0]
-    pad = jnp.zeros((b, s, cfg.latent_row - cfg.latent_dim), dt)
-    return q_n, q_r, jnp.concatenate([ckv, k_r, pad], axis=-1)
-
-
-def kv_up_projections(cfg: LongcatConfig, wkv_b):
-    """wkv_b [rank, nh * (Dn + Dv)] -> the key half [rank, nh, Dn] and the
-    value half [rank, nh, Dv] (a head's output is its keys, then its
-    values)."""
-    w = wkv_b.reshape(cfg.kv_lora_rank, cfg.num_heads,
-                      cfg.qk_nope_head_dim + cfg.v_head_dim)
-    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
-
-
-def mla_full(cfg, ap: dict, xn, kmesh=None,
-             up_projections=kv_up_projections):
-    """Causal latent attention over whole sequences, keys and values
-    up-projected (no cache). xn: [B, S, H] -> [B, S, H].
-    ``up_projections(cfg, wkv_b)`` gives the two halves [rank, nh, D] of a
-    model's ``wkv_b`` (models/deepseek.py stores its a head at a time)."""
-    b, s, _ = xn.shape
-    q_n, q_r, rows = mla_project(cfg, ap, xn, jnp.arange(s), kmesh)
-    w_kb, w_vb = up_projections(cfg, ap["wkv_b"])
-    ckv = rows[..., :cfg.kv_lora_rank]
-    k_r = rows[..., cfg.kv_lora_rank:cfg.latent_dim]
-    k_n = jnp.einsum("bsr,rhd->bshd", ckv, w_kb)
-    v = jnp.einsum("bsr,rhd->bshd", ckv, w_vb)
-    scores = (jnp.einsum("bqhd,bkhd->bhqk", q_n, k_n,
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("bqhd,bkd->bhqk", q_r, k_r,
-                           preferred_element_type=jnp.float32))
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    scores = jnp.where(causal, scores * cfg.sm_scale, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(xn.dtype)
-    o = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(b, s, -1)
-    return (o @ ap["wo"]).astype(xn.dtype)
 
 
 SUBLAYER_LEAVES = ("attn_norm", "post_norm", "wq_a", "q_a_norm", "wq_b",

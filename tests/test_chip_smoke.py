@@ -9,6 +9,7 @@ import os
 import subprocess
 import sys
 
+import ray_tpu
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.kernels import force_kernel_backend
 
@@ -19,6 +20,9 @@ import chip_smoke  # noqa: E402
 
 
 def test_smoke_phases_run_on_cpu(cpu_mesh_devices):
+    # A runtime an earlier test of this worker left up, with no TPU in it:
+    # the smoke run must start its own and not be handed this one.
+    ray_tpu.init(num_cpus=2)
     # vocab 512: the byte tokenizer (256 bytes + specials) must fit.
     cfg = chip_smoke.SmokeConfig(
         model=dataclasses.replace(LlamaConfig.tiny(), vocab_size=512),

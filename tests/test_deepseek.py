@@ -33,14 +33,11 @@ from ray_tpu.llm.config import SamplingParams  # noqa: E402
 from ray_tpu.llm.engine import LLMEngine  # noqa: E402
 from ray_tpu.models import deepseek, routed  # noqa: E402
 from ray_tpu.models.deepseek import DeepseekV2Config  # noqa: E402
-from ray_tpu.models.longcat import mla_full  # noqa: E402
+from ray_tpu.models.mla import mla_full  # noqa: E402
 from ray_tpu.ops import latent_attention as la  # noqa: E402
 from ray_tpu.ops import rope  # noqa: E402
 from ray_tpu.ops.kernels import force_kernel_backend  # noqa: E402
 
-from test_served_model import (  # noqa: E402
-    chunks_that_ride_leave_every_answer_as_it_was,
-)
 
 LOGIT_ATOL = 5e-5
 
@@ -197,151 +194,6 @@ def test_prefill_then_decode_through_the_cache_matches_the_reference(
     rows = ref_logits(cfg, params, seq)[p:p + 4]
     chosen = rows[np.arange(4), burst]
     assert (rows.max(axis=1) - chosen).max() < LOGIT_ATOL
-
-
-# ---------------------------------- a decode step that carries a chunk
-
-def _prefilled(cfg, params, cache, prompt, slot, upto=None):
-    """``prompt``'s first ``upto`` tokens (all of them by default) through
-    ``prefill_chunk`` in one chunk, into ``slot``'s line."""
-    upto = len(prompt) if upto is None else upto
-    if upto:
-        cache, _, _ = serving.prefill_chunk(
-            cfg, params, cache, jnp.asarray(prompt[:upto], jnp.int32),
-            jnp.int32(0), jnp.int32(len(prompt)), jnp.int32(slot))
-    return cache
-
-
-# The chunk is 16 rows of slot 1's prompt; a line that decodes holds a
-# prompt of its own. (cached rows, the prompt's length, decoding slots,
-# kernel backend)
-RIDES = {
-    "a full chunk at a prompt's start beside one line":
-        (0, 40, [0], "reference"),
-    "a full chunk after cached rows between two lines":
-        (16, 40, [0, 2], "reference"),
-    "a chunk with padding": (16, 28, [2], "reference"),
-    "beside no line at all": (16, 40, [], "reference"),
-    "through the kernels' bodies": (16, 40, [0, 2], "interpret"),
-    "a padded chunk through the kernels' bodies":
-        (16, 28, [0], "interpret"),
-}
-
-
-@pytest.mark.parametrize("name", list(RIDES))
-def test_a_step_that_carries_a_chunk_is_the_chunk_and_then_the_step(case,
-                                                                    name):
-    """``_mixed_impl`` on [chunk rows; a row a line] against
-    ``prefill_chunk`` on the chunk's slot and then ``decode_step`` on the
-    lines: every slot's latent rows (the chunk's slot's and the lines' new
-    ones among them), the lines' logits, and the seven counts, a routed
-    layer counted once for both. Under ``interpret`` the mixed step runs
-    the kernels' bodies (the two programs apart are traced once a shape,
-    whatever backend that was under)."""
-    cfg, params, tokens, _ = case
-    kv_len, length, lines, backend = RIDES[name]
-    t = np.asarray(tokens)
-    slots, chunk, nm = 3, 16, cfg.num_routed_layers
-    held = {0: t[3:23], 2: t[5:38]}
-    with force_kernel_backend(backend):
-        # slot 1 holds junk and then its prompt's rows up to kv_len
-        cache = jax.tree.map(lambda a: jnp.full_like(a, 3.0),
-                             serving.init_cache(cfg, slots, 64))
-        cache = _prefilled(cfg, params, cache, t[:length], 1, kv_len)
-        for slot in lines:
-            cache = _prefilled(cfg, params, cache, held[slot], slot)
-        write = jnp.asarray([slot in lines for slot in range(slots)])
-        tok = jnp.asarray([int(t[35 + slot]) for slot in range(slots)])
-        pos = jnp.asarray([len(held[slot]) if slot in lines else 0
-                           for slot in range(slots)], jnp.int32)
-        rows = np.zeros(chunk, np.int32)
-        take = min(chunk, length - kv_len)
-        rows[:take] = t[kv_len:kv_len + take]
-        rider = (jnp.asarray(rows), jnp.int32(kv_len), jnp.int32(length),
-                 jnp.int32(1))
-        apart, _, chunk_counts = serving.prefill_chunk(
-            cfg, params, jax.tree.map(jnp.copy, cache), *rider)
-        apart, want_logits, step_counts = serving.decode_step(
-            cfg, params, apart, tok, pos, write)
-        # a function of its own: traced here, under this backend
-        got, logits, counts = jax.jit(
-            lambda *a: serving._mixed_impl(cfg, *a))(
-            params, cache, tok, pos, write, *rider)
-    np.testing.assert_allclose(np.asarray(got["latent"]),
-                               np.asarray(apart["latent"]), atol=1e-5)
-    # the rows are there: the chunk's in its slot, a line's at its position
-    assert np.abs(np.asarray(got["latent"][:, 1, kv_len:kv_len + take]
-                             - cache["latent"][:, 1, kv_len:kv_len + take])
-                  ).max() > 0.1
-    np.testing.assert_allclose(np.asarray(logits)[lines],
-                               np.asarray(want_logits)[lines],
-                               atol=LOGIT_ATOL)
-    counts, chunk_counts, step_counts = (
-        dict(zip(deepseek.COUNTERS, (int(n) for n in c)))
-        for c in (counts, chunk_counts, step_counts))
-    for key in ("moe_picks", "moe_picks_local", "moe_picks_zero",
-                "moe_tokens_local"):
-        assert counts[key] == chunk_counts[key] + step_counts[key], key
-    # a padded row and a line that does not decode are routed nowhere
-    assert counts["moe_picks"] == (take + len(lines)) \
-        * cfg.num_experts_per_tok * nm
-    # one layer-step a routed layer, where the two programs count two
-    assert counts["moe_layer_steps"] == nm
-    assert chunk_counts["moe_layer_steps"] + step_counts["moe_layer_steps"] \
-        == 2 * nm
-    # an expert both touched is touched, and fetched, once
-    assert max(chunk_counts["moe_experts_touched"],
-               step_counts["moe_experts_touched"]) \
-        <= counts["moe_experts_touched"] \
-        <= chunk_counts["moe_experts_touched"] \
-        + step_counts["moe_experts_touched"]
-    assert counts["moe_experts_touched"] <= counts["moe_tiles"]
-
-
-@pytest.mark.parametrize("riders", [0, 2, 4], ids=lambda n: f"{n} riders")
-def test_a_mixed_burst_is_its_chunks_and_then_the_burst(case, riders):
-    """Consecutive chunks of one prompt and a chunk of another ride the
-    first steps of one burst, each with its own slot, cached length and
-    length; the steps after them carry none (all of them, with no rider:
-    the program is then ``decode_burst``). Tokens and rows are those of the
-    chunks through ``prefill_chunk`` and then the burst."""
-    cfg, params, tokens, _ = case
-    t = np.asarray(tokens)
-    slots, chunk, nm = 4, 8, cfg.num_routed_layers
-    cache = _prefilled(cfg, params, serving.init_cache(cfg, slots, 64),
-                       t[3:23], 0)
-    # (slot, cached rows, the prompt): slot 1's three chunks, slot 3's first
-    prompts = {1: t[:28], 3: t[7:40]}
-    rode = [(1, 0), (1, 8), (3, 0), (1, 16)][:riders]
-    rows = np.zeros((4, chunk), np.int32)
-    at, kv_lens, lengths = (np.zeros((4,), np.int32) for _ in range(3))
-    apart = jax.tree.map(jnp.copy, cache)
-    for j, (slot, kv_len) in enumerate(rode):
-        rows[j] = prompts[slot][kv_len:kv_len + chunk]
-        at[j], kv_lens[j], lengths[j] = slot, kv_len, len(prompts[slot])
-        apart, _, _ = serving.prefill_chunk(
-            cfg, params, apart, jnp.asarray(rows[j]), jnp.int32(kv_len),
-            jnp.int32(lengths[j]), jnp.int32(slot))
-    write = jnp.asarray([True, False, False, False])
-    tok = jnp.zeros((slots,), jnp.int32).at[0].set(int(t[30]))
-    pos = jnp.zeros((slots,), jnp.int32).at[0].set(20)
-    burst = (tok, pos, write, jnp.zeros((slots,)), jnp.ones((slots,)),
-             jax.random.PRNGKey(0))
-    apart, want, apart_counts = serving.decode_burst(cfg, params, apart,
-                                                     *burst, 4, False)
-    got, toks, counts = serving.mixed_burst(
-        cfg, params, cache, *burst,
-        tuple(jnp.asarray(a) for a in (rows, at, kv_lens, lengths))
-        + (jnp.int32(riders),), 4, False)
-    # (a slot that does not decode samples from logits that mean nothing)
-    np.testing.assert_array_equal(np.asarray(toks[:, 0]),
-                                  np.asarray(want[:, 0]))
-    np.testing.assert_allclose(np.asarray(got["latent"]),
-                               np.asarray(apart["latent"]), atol=1e-5)
-    # four steps' layer-steps whatever rode, and every rider's picks
-    assert int(counts[4]) == int(apart_counts[4]) == 4 * nm
-    assert int(counts[0]) == int(apart_counts[0]) + riders * chunk \
-        * cfg.num_experts_per_tok * nm
 
 
 # ----------------------------------------------------------------- the rule
@@ -588,19 +440,3 @@ def test_the_engine_serves_deepseek_and_counts_its_routing():
         stats["prefill_chunks"] - stats["prefill_chunks_riding"]
         + stats["decode_steps"])
     assert stats["kv_positions_read"] > 0
-
-
-@pytest.mark.parametrize("pipeline", [True, False],
-                         ids=["look-ahead", "serial"])
-def test_chunks_that_ride_leave_every_answer_as_it_was(monkeypatch, pipeline):
-    """The engine with and without the entry, greedy, token for token, a
-    prompt's last chunk never riding (tests/test_served_model.py holds the
-    drive); a routed layer is counted once a program's step, a rider's with
-    the step that carried it."""
-    cfg = DeepseekV2Config.tiny(expert_shards=4, max_seq_len=256)
-    for s in chunks_that_ride_leave_every_answer_as_it_was(
-            monkeypatch, serving, cfg, pipeline):
-        assert s["moe_layer_steps"] == cfg.num_routed_layers * (
-            s["prefill_chunks"] - s["prefill_chunks_riding"]
-            + s["decode_steps"])
-        assert 0 < s["moe_picks_local"] < s["moe_picks"]

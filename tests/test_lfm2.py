@@ -35,9 +35,6 @@ if BENCH not in sys.path:
 from reference import lfm2 as reference  # noqa: E402
 from rtbench.adapters import lfm2 as adapter  # noqa: E402
 
-from test_served_model import (  # noqa: E402
-    chunks_that_ride_leave_every_answer_as_it_was,
-)
 
 CFG = Lfm2Config.tiny()
 PROMPT = 45
@@ -244,119 +241,6 @@ def test_a_burst_is_its_steps_and_keeps_idle_slots_state(params, tokens):
     np.testing.assert_array_equal(np.asarray(burst["conv"][:, 2]), held)
 
 
-# ---- a decode step that carries a prefill chunk -----------------------------
-
-# The chunk's slot is 1 and its prompt ``tokens[:PROMPT]``; a line that
-# decodes holds a prompt of its own. (kv_len, decoding slots)
-RIDES = {"at a prompt's start beside one line": (0, [0]),
-         "after cached rows beside one line": (16, [2]),
-         "between two decoding neighbours": (16, [0, 2]),
-         "beside no line at all": (16, [])}
-
-
-@pytest.mark.parametrize("name", list(RIDES))
-def test_a_step_that_carries_a_chunk_is_the_chunk_and_then_the_step(
-        params, tokens, name):
-    """``_mixed_impl`` on [chunk rows; a row a line] against
-    ``prefill_chunk`` on the chunk's slot and then ``decode_step`` on the
-    lines: the rows written, every slot's states, the lines' logits, and the
-    routed layers' counts, a routed layer counted once for both."""
-    kv_len, lines = RIDES[name]
-    chunk, prompt = 16, tokens[:PROMPT]
-    # slot 1 holds junk and then the prompt's rows up to kv_len
-    cache = jax.tree.map(lambda a: jnp.full_like(a, 3.0),
-                         serving.init_cache(CFG, SLOTS, MAX_SEQ))
-    if kv_len:
-        cache, _ = _prefill(params, prompt, [kv_len], cache=cache)
-    held = {0: tokens[3:23], 2: tokens[5:38]}
-    for slot in lines:
-        cache, _ = _prefill(params, held[slot], [len(held[slot])], slot=slot,
-                            cache=cache)
-    write = jnp.asarray([slot in lines for slot in range(SLOTS)])
-    tok = jnp.asarray([int(tokens[40 + slot]) for slot in range(SLOTS)])
-    pos = jnp.asarray([len(held.get(slot, ())) if slot in lines else 0
-                       for slot in range(SLOTS)], jnp.int32)
-    rider = (jnp.asarray(prompt[kv_len:kv_len + chunk]), jnp.int32(kv_len),
-             jnp.int32(PROMPT), jnp.int32(1))
-    apart, _, chunk_counts = serving.prefill_chunk(
-        CFG, params, jax.tree.map(jnp.copy, cache), *rider)
-    apart, want_logits, step_counts = serving.decode_step(
-        CFG, params, apart, tok, pos, write)
-    got, logits, counts = jax.jit(serving._mixed_impl, static_argnums=0)(
-        CFG, params, cache, tok, pos, write, *rider)
-    for leaf in ("kv", "conv"):
-        np.testing.assert_allclose(np.asarray(got[leaf]),
-                                   np.asarray(apart[leaf]), atol=1e-5,
-                                   err_msg=leaf)
-    np.testing.assert_allclose(np.asarray(logits)[lines],
-                               np.asarray(want_logits)[lines], atol=1e-4)
-    counts, chunk_counts, step_counts = (
-        dict(zip(routed.MOE_COUNTERS, (int(n) for n in c)))
-        for c in (counts, chunk_counts, step_counts))
-    for key in ("moe_picks", "moe_picks_local", "moe_picks_zero"):
-        assert counts[key] == chunk_counts[key] + step_counts[key], key
-    assert counts["moe_picks"] == (chunk + len(lines)) \
-        * CFG.num_experts_per_tok * CFG.num_routed_layers
-    # one layer-step a routed layer, where the two programs count two
-    assert counts["moe_layer_steps"] == CFG.num_routed_layers
-    assert chunk_counts["moe_layer_steps"] + step_counts["moe_layer_steps"] \
-        == 2 * CFG.num_routed_layers
-    # an expert both touched is touched, and fetched, once
-    assert max(chunk_counts["moe_experts_touched"],
-               step_counts["moe_experts_touched"]) \
-        <= counts["moe_experts_touched"] \
-        <= chunk_counts["moe_experts_touched"] \
-        + step_counts["moe_experts_touched"]
-    assert counts["moe_experts_touched"] <= counts["moe_tiles"]
-
-
-@pytest.mark.parametrize("riders", [0, 2, 4], ids=lambda n: f"{n} riders")
-def test_a_mixed_burst_is_its_chunks_and_then_the_burst(params, tokens,
-                                                         riders):
-    """Consecutive chunks of one prompt and a chunk of another ride the
-    first steps of one burst, each with its own slot, cached length and
-    length; the steps after them carry none (all of them, with no rider:
-    the program is then ``decode_burst``). Tokens, rows and states are
-    those of the chunks through ``prefill_chunk`` and then the burst."""
-    slots, chunk = 4, 8
-    fresh = serving.init_cache(CFG, slots, MAX_SEQ)
-    cache, _ = _prefill(params, tokens[3:23], [20], slot=0, cache=fresh)
-    # (slot, cached rows, the prompt): slot 1's three chunks, slot 3's first
-    prompts = {1: tokens[:PROMPT], 3: tokens[7:40]}
-    rode = [(1, 0), (1, 8), (3, 0), (1, 16)][:riders]
-    rows = np.zeros((4, chunk), np.int32)
-    at, kv_lens, lengths = (np.zeros((4,), np.int32) for _ in range(3))
-    apart = jax.tree.map(jnp.copy, cache)
-    for j, (slot, kv_len) in enumerate(rode):
-        rows[j] = prompts[slot][kv_len:kv_len + chunk]
-        at[j], kv_lens[j], lengths[j] = slot, kv_len, len(prompts[slot])
-        apart, _, _ = serving.prefill_chunk(
-            CFG, params, apart, jnp.asarray(rows[j]), jnp.int32(kv_len),
-            jnp.int32(lengths[j]), jnp.int32(slot))
-    write = jnp.asarray([True, False, False, False])
-    tok = jnp.zeros((slots,), jnp.int32).at[0].set(int(tokens[30]))
-    pos = jnp.zeros((slots,), jnp.int32).at[0].set(20)
-    burst = (tok, pos, write, jnp.zeros((slots,)), jnp.ones((slots,)),
-             jax.random.PRNGKey(0))
-    apart, want, apart_counts = serving.decode_burst(CFG, params, apart,
-                                                     *burst, 4, False)
-    got, toks, counts = serving.mixed_burst(
-        CFG, params, cache, *burst,
-        tuple(jnp.asarray(a) for a in (rows, at, kv_lens, lengths))
-        + (jnp.int32(riders),), 4, False)
-    # (a slot that does not decode samples from logits that mean nothing)
-    np.testing.assert_array_equal(np.asarray(toks[:, 0]),
-                                  np.asarray(want[:, 0]))
-    for leaf in ("kv", "conv"):
-        np.testing.assert_allclose(np.asarray(got[leaf]),
-                                   np.asarray(apart[leaf]), atol=1e-5,
-                                   err_msg=leaf)
-    # four steps' layer-steps whatever rode, and every rider's picks
-    assert int(counts[4]) == int(apart_counts[4]) == 4 * CFG.num_routed_layers
-    assert int(counts[0]) == int(apart_counts[0]) + riders * chunk \
-        * CFG.num_experts_per_tok * CFG.num_routed_layers
-
-
 # ---- the router's rule ------------------------------------------------------
 
 def _hand_route(gate, bias, u, topk, renormalize=True, factor=1.0):
@@ -505,23 +389,6 @@ def test_the_engine_serves_each_request_as_if_alone(engine):
     assert stats["moe_picks_zero"] == 0
     assert stats["moe_picks"] == stats["moe_picks_local"] > 0
     assert stats["decode_dispatches"] < stats["decode_steps"]   # bursts ran
-
-
-@pytest.mark.parametrize("pipeline", [True, False],
-                         ids=["look-ahead", "serial"])
-def test_chunks_that_ride_leave_every_answer_as_it_was(monkeypatch, pipeline):
-    """Long prompts arrive while a line decodes: with ``mixed_burst`` their
-    full chunks ride the line's bursts, never a prompt's last chunk, and
-    every request gets token for token what it gets from the engine whose
-    model offers no such program (tests/test_served_model.py holds the
-    drive); a routed layer is counted once a program's step, a rider's with
-    the step that carried it."""
-    for s in chunks_that_ride_leave_every_answer_as_it_was(
-            monkeypatch, serving, CFG, pipeline):
-        assert s["moe_layer_steps"] == CFG.num_routed_layers * (
-            s["prefill_chunks"] - s["prefill_chunks_riding"]
-            + s["decode_steps"])
-        assert s["moe_picks"] == s["moe_picks_local"] > 0
 
 
 def test_the_served_model_says_what_it_cannot_do():

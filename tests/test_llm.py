@@ -1092,170 +1092,6 @@ def test_a_model_without_a_mixed_burst_is_scheduled_as_before(monkeypatch,
     assert stats["prefill_tokens_riding"] == 0
 
 
-# ------------------------------------- a decode step that carries a chunk
-
-def _line_prefilled(cfg, params, cache, prompt, slot, upto=None):
-    """``prompt``'s first ``upto`` tokens (all of them by default) through
-    ``prefill_chunk`` in one chunk, into ``slot``'s line."""
-    from ray_tpu.llm.llama_serving import prefill_chunk
-
-    upto = len(prompt) if upto is None else upto
-    if upto:
-        cache, _ = prefill_chunk(
-            cfg, params, cache, jnp.asarray(prompt[:upto], jnp.int32),
-            jnp.int32(0), jnp.int32(len(prompt)), jnp.int32(slot))
-    return cache
-
-
-# The chunk is 16 rows of slot 1's prompt; a line that decodes holds a
-# prompt of its own. (cached rows, the prompt's length, decoding slots,
-# kernel backend)
-RIDES = {
-    "a full chunk at a prompt's start beside one line":
-        (0, 40, [0], "reference"),
-    "a full chunk after cached rows between two lines":
-        (16, 40, [0, 2], "reference"),
-    "a chunk with a padded tail": (16, 28, [2], "reference"),
-    "cached rows that are no multiple of the chunk, as after an adopted "
-    "prefix": (5, 40, [0, 2], "reference"),
-    "a slot that does not decode beside the chunk's": (16, 40, [2],
-                                                      "reference"),
-    "beside no line at all": (16, 40, [], "reference"),
-    "through the kernels' bodies": (16, 40, [0, 2], "interpret"),
-    "a padded chunk after an odd prefix through the kernels' bodies":
-        (5, 17, [0], "interpret"),
-}
-
-
-@pytest.mark.parametrize("name", list(RIDES))
-def test_a_step_that_carries_a_chunk_is_the_chunk_and_then_the_step(tiny,
-                                                                    name):
-    """``_mixed_impl`` on [chunk rows; a row a line] against
-    ``prefill_chunk`` on the chunk's slot and then ``_decode_step_impl`` on
-    the lines, from the same cache: every slot's K and V rows (the chunk's
-    in its slot, a line's new one at its position, a slot that neither
-    prefills nor decodes as it was) and the lines' logits. Under
-    ``interpret`` the mixed step runs the kernels' bodies (the two programs
-    apart are traced once a shape, whatever backend that was under)."""
-    from ray_tpu.llm import llama_serving as serving
-    from ray_tpu.ops.kernels import force_kernel_backend
-
-    cfg, params = tiny
-    kv_len, length, lines, backend = RIDES[name]
-    t = np.asarray(jax.random.randint(jax.random.PRNGKey(5), (48,), 1,
-                                      cfg.vocab_size))
-    slots, chunk = 3, 16
-    held = {0: t[3:23], 2: t[5:38]}
-    with force_kernel_backend(backend):
-        # every line holds junk, then what was prefilled into it
-        cache = jax.tree.map(lambda a: jnp.full_like(a, 3.0),
-                             init_kv_cache(cfg, slots, 64))
-        cache = _line_prefilled(cfg, params, cache, t[:length], 1, kv_len)
-        for slot in lines:
-            cache = _line_prefilled(cfg, params, cache, held[slot], slot)
-        write = jnp.asarray([slot in lines for slot in range(slots)])
-        tok = jnp.asarray([int(t[35 + slot]) for slot in range(slots)])
-        pos = jnp.asarray([len(held[slot]) if slot in lines else 0
-                           for slot in range(slots)], jnp.int32)
-        rows = np.zeros(chunk, np.int32)
-        take = min(chunk, length - kv_len)
-        rows[:take] = t[kv_len:kv_len + take]
-        rider = (jnp.asarray(rows), jnp.int32(kv_len), jnp.int32(length),
-                 jnp.int32(1))
-        before = jax.tree.map(np.asarray, cache)
-        apart, _ = serving.prefill_chunk(
-            cfg, params, jax.tree.map(jnp.copy, cache), *rider)
-        apart, want_logits = serving.decode_step(cfg, params, apart, tok,
-                                                 pos, write)
-        # a function of its own: traced here, under this backend
-        got, logits = jax.jit(lambda *a: serving._mixed_impl(cfg, *a))(
-            params, cache, tok, pos, write, *rider)
-    assert logits.shape == (slots, cfg.vocab_size)
-    for leaf in ("k", "v"):
-        np.testing.assert_allclose(np.asarray(got[leaf]),
-                                   np.asarray(apart[leaf]), atol=1e-5)
-        # the rows are there: the chunk's in its slot, a line's at its
-        # position; the slots that wrote nothing hold what they held
-        new = np.asarray(got[leaf])
-        assert np.abs(new[:, 1, :, kv_len:kv_len + take]
-                      - before[leaf][:, 1, :, kv_len:kv_len + take]
-                      ).max() > 0.1
-        for slot in lines:
-            at = len(held[slot])
-            assert np.abs(new[:, slot, :, at]
-                          - before[leaf][:, slot, :, at]).max() > 0.1
-        for slot in set(range(slots)) - set(lines) - {1}:
-            np.testing.assert_array_equal(new[:, slot], before[leaf][:, slot])
-        np.testing.assert_array_equal(new[:, 1, :, kv_len + chunk:],
-                                      before[leaf][:, 1, :, kv_len + chunk:])
-    np.testing.assert_allclose(np.asarray(logits)[lines],
-                               np.asarray(want_logits)[lines],
-                               rtol=2e-4, atol=2e-4)
-
-
-@pytest.mark.parametrize("riders", [0, 2, 4], ids=lambda n: f"{n} riders")
-def test_a_mixed_burst_is_its_chunks_and_then_the_burst(tiny, riders):
-    """Consecutive chunks of one prompt and a chunk of another ride the
-    first steps of one burst, each with its own slot, cached length and
-    length; the steps after them carry none (all of them, with no rider:
-    the program is then ``decode_burst``). Tokens and rows are those of the
-    chunks through ``prefill_chunk`` and then the burst."""
-    from ray_tpu.llm import llama_serving as serving
-
-    cfg, params = tiny
-    t = np.asarray(jax.random.randint(jax.random.PRNGKey(6), (48,), 1,
-                                      cfg.vocab_size))
-    slots, chunk = 4, 8
-    cache = _line_prefilled(cfg, params, init_kv_cache(cfg, slots, 64),
-                            t[3:23], 0)
-    # (slot, cached rows, the prompt): slot 1's three chunks, slot 3's first
-    prompts = {1: t[:28], 3: t[7:40]}
-    rode = [(1, 0), (1, 8), (3, 0), (1, 16)][:riders]
-    rows = np.zeros((4, chunk), np.int32)
-    at, kv_lens, lengths = (np.zeros((4,), np.int32) for _ in range(3))
-    apart = jax.tree.map(jnp.copy, cache)
-    for j, (slot, kv_len) in enumerate(rode):
-        rows[j] = prompts[slot][kv_len:kv_len + chunk]
-        at[j], kv_lens[j], lengths[j] = slot, kv_len, len(prompts[slot])
-        apart, _ = serving.prefill_chunk(
-            cfg, params, apart, jnp.asarray(rows[j]), jnp.int32(kv_len),
-            jnp.int32(lengths[j]), jnp.int32(slot))
-    write = jnp.asarray([True, False, False, False])
-    tok = jnp.zeros((slots,), jnp.int32).at[0].set(int(t[30]))
-    pos = jnp.zeros((slots,), jnp.int32).at[0].set(20)
-    burst = (tok, pos, write, jnp.zeros((slots,)), jnp.ones((slots,)),
-             jax.random.PRNGKey(0))
-    apart, want = serving.decode_burst(cfg, params, apart, *burst, 4, False)
-    got, toks = serving.mixed_burst(
-        cfg, params, cache, *burst,
-        tuple(jnp.asarray(a) for a in (rows, at, kv_lens, lengths))
-        + (jnp.int32(riders),), 4, False)
-    # (a slot that does not decode samples from logits that mean nothing)
-    np.testing.assert_array_equal(np.asarray(toks[:, 0]),
-                                  np.asarray(want[:, 0]))
-    for leaf in ("k", "v"):
-        np.testing.assert_allclose(np.asarray(got[leaf]),
-                                   np.asarray(apart[leaf]), atol=1e-5)
-
-
-@pytest.mark.parametrize("pipeline", [True, False],
-                         ids=["look-ahead", "serial"])
-def test_chunks_that_ride_leave_every_answer_as_it_was(monkeypatch, pipeline):
-    """The engine with and without the entry, greedy, token for token, a
-    prompt's last chunk never riding (tests/test_served_model.py holds the
-    drive)."""
-    from test_served_model import (
-        chunks_that_ride_leave_every_answer_as_it_was,
-    )
-
-    from ray_tpu.llm import llama_serving
-
-    cfg = dataclasses.replace(LlamaConfig.tiny(), vocab_size=512)
-    stats, plain = chunks_that_ride_leave_every_answer_as_it_was(
-        monkeypatch, llama_serving, cfg, pipeline)
-    assert stats["requests_failed"] == plain["requests_failed"] == 0
-
-
 def test_an_engine_with_a_draft_model_lets_no_chunk_ride():
     """A speculative tick reads the host's tokens and runs the two programs
     of speculation, not a burst: with a draft model the engine never asks

@@ -30,7 +30,7 @@ from ray_tpu.llm import LLMConfig  # noqa: E402
 from ray_tpu.llm import longcat_serving as serving  # noqa: E402
 from ray_tpu.llm.config import SamplingParams  # noqa: E402
 from ray_tpu.llm.engine import LLMEngine  # noqa: E402
-from ray_tpu.models import longcat  # noqa: E402
+from ray_tpu.models import longcat, mla  # noqa: E402
 from ray_tpu.models.longcat import LongcatConfig  # noqa: E402
 from ray_tpu.ops import grouped_matmul as gmm  # noqa: E402
 from ray_tpu.ops import latent_attention as la  # noqa: E402
@@ -151,16 +151,16 @@ def test_prefill_then_decode_through_the_cache_matches_the_reference(
 def test_absorbed_attention_equals_the_unabsorbed():
     """One attention alone: every position's output by the absorbed form
     over cached rows (what decode runs) against the up-projected causal
-    attention of models/longcat.mla_full (what the reference writes)."""
+    attention of models/mla.mla_full (what the reference writes)."""
     cfg = LongcatConfig.tiny()
     params = longcat.init_params(cfg, jax.random.PRNGKey(2))
     ap = {k: v[1] for k, v in params["layers"].items()
           if k in longcat.SUBLAYER_LEAVES}
     s = 24
     xn = jax.random.normal(jax.random.PRNGKey(3), (1, s, cfg.hidden_size))
-    want = longcat.mla_full(cfg, ap, xn)
-    q_n, q_r, rows = longcat.mla_project(cfg, ap, xn, jnp.arange(s))
-    w_kb, w_vb = longcat.kv_up_projections(cfg, ap["wkv_b"])
+    want = mla.mla_full(cfg, ap, xn)
+    q_n, q_r, rows = mla.mla_project(cfg, ap, xn, jnp.arange(s))
+    w_kb, w_vb = mla.kv_up_projections(cfg, ap["wkv_b"])
     q = jnp.concatenate([jnp.einsum("bkhd,rhd->bkhr", q_n, w_kb), q_r], -1)
     # Every position as a one-token decode of a slot of its own.
     cache = jnp.broadcast_to(rows[0][None, None], (1, s, s, cfg.latent_row))
@@ -295,7 +295,7 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_reference():
         adapter.reference_weights(params)["layers"], 1)
 
     def attn(i, ap, xn, state):
-        return longcat.mla_full(full, ap, xn), state
+        return mla.mla_full(full, ap, xn), state
 
     def layer_of(cfg, layers):
         out, _, counts = longcat.double_layer(

@@ -613,3 +613,81 @@ def test_a_draft_is_refused_where_the_two_speculative_programs_are_none(
     with pytest.raises(ValueError, match=f"{type(cfg).__name__} does not "
                                          "support a speculative draft"):
         LLMEngine(config)
+
+
+@pytest.mark.parametrize("model", **ALL_MODELS)
+def test_only_a_model_that_says_it_partitions_is_given_a_tp_mesh(model):
+    """Whether a model's programs partition is its author's statement
+    (``ServedModel.tensor_parallel``, Llama's alone says so), checked once,
+    by the contract, at construction: every other model refuses
+    ``tensor_parallel_size > 1`` in one sentence that no serving module
+    writes."""
+    from ray_tpu.llm.served import require_tensor_parallel
+
+    module, cfg = model()
+    served = served_model(cfg)
+    assert served.tensor_parallel == (module is llama_serving)
+    require_tensor_parallel(cfg, 1)
+    sentence = (f"{type(cfg).__name__} does not support "
+                "tensor_parallel_size > 1: its programs run on one device")
+    if served.tensor_parallel:
+        require_tensor_parallel(cfg, 2)
+    else:
+        with pytest.raises(ValueError) as refused:
+            LLMEngine(LLMConfig(model=cfg, max_num_seqs=2,
+                                max_seq_len=MAX_SEQ, tensor_parallel_size=2))
+        assert str(refused.value) == sentence
+    assert [p.name for p in LLM.glob("*.py")
+            if "its programs run on one device" in p.read_text()] \
+        == ["served.py"]
+
+
+# ---- the latent line: one module a layer, imported by both models ----------
+
+@pytest.mark.parametrize("model", argvalues=[_longcat, _deepseek],
+                         ids=["longcat", "deepseek"])
+def test_the_latent_cache_is_one_module_s_for_both_models(model):
+    """``llm/latent.py`` holds the latent slot cache for the two models that
+    keep one: ``init_cache`` at either model's count of lines (two a
+    double layer, one a layer) and row, and one ``copy_prefix_kv`` that
+    moves a slot's whole line of every cache line at once and touches no
+    other slot."""
+    from ray_tpu.llm import latent
+
+    module, cfg = model()
+    served = served_model(cfg)
+    lines = {longcat_serving: 2 * cfg.num_layers,
+             deepseek_serving: cfg.num_layers}[module]
+    cache = served.init_cache(cfg, SLOTS, MAX_SEQ)
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), cache) == jax.tree.map(
+        lambda a: (a.shape, a.dtype),
+        latent.init_cache(cfg, lines, SLOTS, MAX_SEQ))
+    assert cache["latent"].shape == (lines, SLOTS, MAX_SEQ, cfg.latent_row)
+    assert served.copy_prefix_kv is module.copy_prefix_kv \
+        is latent.copy_prefix_kv
+    assert served.kv_block is latent.kv_block
+    # every (line, slot, position) its own value
+    held = jnp.arange(cache["latent"].size, dtype=jnp.float32).reshape(
+        cache["latent"].shape).astype(cache["latent"].dtype)
+    got = latent.copy_prefix_kv(cfg, {"latent": jnp.copy(held)},
+                                jnp.int32(0), jnp.int32(2))["latent"]
+    assert jnp.array_equal(got[:, 2], held[:, 0])
+    assert jnp.array_equal(got[:, :2], held[:, :2])
+
+
+def test_deepseek_imports_nothing_of_longcat_s():
+    """What the two latent models share is a module of its own a layer
+    (``models/mla.py``, ``llm/latent.py``): neither file of the newer model
+    imports the older model's, and the older model's keeps no alias of what
+    moved."""
+    models = LLM.parent / "models"
+    for path in (models / "deepseek.py", LLM / "deepseek_serving.py"):
+        names = _imports(path)
+        assert not {n for n in names if "longcat" in n}, path.name
+        assert {n for n in names if n.startswith("ray_tpu.models.mla")}, \
+            path.name
+    assert {"ray_tpu.llm.latent", "ray_tpu.models.mla"} \
+        <= _imports(LLM / "longcat_serving.py")
+    longcat = importlib.import_module("ray_tpu.models.longcat")
+    assert not hasattr(longcat, "mla_project")
+    assert not hasattr(longcat, "kv_up_projections")
