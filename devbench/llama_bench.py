@@ -6,6 +6,7 @@ described v5e with no chip, and timed on one.
     python3 devbench/llama_bench.py aot           # no chip, about a minute
     LLAMA_USE=serve_reason python3 devbench/llama_bench.py aot
     chiprun -- python3 devbench/llama_bench.py mixed
+    chiprun -- python3 devbench/llama_bench.py step
 
 ``aot``: ``llm/llama_serving.py``'s ``prefill_chunk(512)``,
 ``decode_burst(8)`` and ``mixed_burst(8)``, compiled for ``v5e:2x2``'s first
@@ -20,7 +21,14 @@ clamped to the line's 3,200) beside the 15 other lines at as many live
 positions (16 slots as in the cell: a slot mid-prefill does not decode):
 wall milliseconds a call, device milliseconds a call and each program's
 parts from a device trace a length; the whole, with each program's largest
-operations, goes to ``chiprun_out/llama_mixed.json``. The configuration is
+operations, goes to ``chiprun_out/llama_mixed.json``. ``step``: a step of
+``decode_burst(8)`` and ``prefill_chunk(512)`` alone at the three uses (one
+where ``LLAMA_USE`` names it), the same numbers and each program's largest
+operations, to ``chiprun_out/llama_step.json``: run from two trees in one
+call (the older laid in ``.parent/``, this file copied into its
+``devbench/``), it says what a change of the programs did to the step apart
+from the schedule. Every mode hands the programs the tree as the engine
+places it (``ServedModel.program_params``). The configuration is
 the benchmark's file through its adapter; ``LLAMA_USE`` names the use whose
 depth and cache it takes (``serve_docqa``, ``serve_reason``,
 ``serve_chat``).
@@ -72,16 +80,26 @@ def config(use: str | None = None):
 
 
 def shapes(cfg, slots: int, max_seq: int, place):
+    """The tree as the engine hands it over (``program_params`` of
+    ``init_params``' tree) and the cell's cache, as shapes."""
     import jax
 
     from ray_tpu.llm import llama_serving as serving
     from ray_tpu.models import llama
 
-    params = place(jax.eval_shape(partial(llama.init_params, cfg),
-                                  jax.random.PRNGKey(0)))
+    params = place(jax.eval_shape(
+        lambda key: served_tree(serving, cfg, llama.init_params(cfg, key)),
+        jax.random.PRNGKey(0)))
     cache = place(jax.eval_shape(partial(serving.init_kv_cache, cfg, slots,
                                          max_seq)))
     return params, cache
+
+
+def served_tree(serving, cfg, params):
+    """``params`` as the engine places it. A tree laid in ``.parent/`` from
+    before the contract had the entry is served as it is."""
+    make = getattr(serving.SERVED, "program_params", None)
+    return make(cfg, params) if make else params
 
 
 def lowerings(cfg, params, cache, arg) -> dict:
@@ -108,14 +126,20 @@ def lowerings(cfg, params, cache, arg) -> dict:
 def big_shapes(cfg, slots: int, max_seq: int) -> dict:
     """The shapes no instruction should produce but a parameter, a loop's
     tuple, a kernel's in-place operand or an update in place: the stacked
-    cache, a layer of it, and each stacked weight."""
+    cache, a layer of it, each stacked weight (``wv``'s is ``wk``'s) and a
+    layer's slice of each projection (``<leaf>_layer``; a quarter of a
+    stack, which a refetch from the fast memory comes in, is found by the
+    opcode: ``slice-done``)."""
     L, h, f = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size
     nq, nkv = (n * cfg.head_dim for n in (cfg.num_heads, cfg.num_kv_heads))
     line = f"{slots},{cfg.num_kv_heads},{max_seq},{cfg.head_dim}]"
-    return {"cache": f"bf16[{L},{line}", "cache_layer": f"bf16[{line}",
-            "wq": f"bf16[{L},{h},{nq}]", "wk": f"bf16[{L},{h},{nkv}]",
-            "wo": f"bf16[{L},{nq},{h}]", "w_gate": f"bf16[{L},{h},{f}]",
-            "w_down": f"bf16[{L},{f},{h}]"}
+    big = {"cache": f"bf16[{L},{line}", "cache_layer": f"bf16[{line}",
+           "w_gate": f"bf16[{L},{h},{f}]", "w_down": f"bf16[{L},{f},{h}]"}
+    for leaf, (rows, cols) in {"wq": (h, nq), "wk": (h, nkv), "wo": (nq, h),
+                               "wqkv": (h, nq + 2 * nkv)}.items():
+        big[leaf] = f"bf16[{L},{rows},{cols}]"
+        big[f"{leaf}_layer"] = f"bf16[1,{rows},{cols}]"
+    return big
 
 
 def aot() -> dict:
@@ -176,27 +200,74 @@ def mixed_step_of(impl, name: str):
     return jax.jit(mixed_step, static_argnums=0, donate_argnums=2)
 
 
-def mixed(calls: int = 10, ops: int = 40, impls: dict | None = None) -> dict:
-    """``impls``: further mixed steps to time beside the module's, {name:
-    a ``_mixed_impl``} (a variant tried on the chip; each is traced under
-    the program name ``mixed_step_<name>``)."""
-    import shutil
-
+def on_device(cfg, slots: int, max_seq: int):
+    """(params, cache, chunk, tokens) of a cell on the chip: the tree as
+    the engine places it, a zeroed cache, ``ROWS`` prompt ids and an id a
+    slot."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.llm import llama_serving as serving
     from ray_tpu.models import llama
 
-    cfg, slots, max_seq = config()
-    params = jax.jit(llama.init_params, static_argnums=0)(
-        cfg, jax.random.PRNGKey(0))
-    i32 = jnp.int32
+    params = served_tree(serving, cfg, jax.jit(
+        llama.init_params, static_argnums=0)(cfg, jax.random.PRNGKey(0)))
     cache = serving.init_kv_cache(cfg, slots, max_seq)
     chunk = jax.random.randint(jax.random.PRNGKey(7), (ROWS,), 259,
-                               cfg.vocab_size, i32)
+                               cfg.vocab_size, jnp.int32)
     tokens = jax.random.randint(jax.random.PRNGKey(8), (slots,), 259,
-                                cfg.vocab_size, i32)
+                                cfg.vocab_size, jnp.int32)
+    return params, cache, chunk, tokens
+
+
+def timed(programs: dict, cache, calls: int, ops: int, trace: str):
+    """(cache, row): each of ``programs`` ({name: cache -> cache}) warmed,
+    then ``calls`` calls of it by the host's clock and as many under a
+    device trace (``.chipwork/<trace>``): wall and device milliseconds a
+    call, each program's parts and its ``ops`` largest operations; a
+    ``decode_burst`` is a burst of ``BURST``, and ``decode_step`` its time
+    over that."""
+    import shutil
+
+    import jax
+
+    def run(name, cache):
+        for _ in range(calls):
+            cache = programs[name](cache)
+        return jax.block_until_ready(cache)
+
+    row = {"wall_ms": {}}
+    for name in programs:
+        cache = run(name, cache)                      # compiles, warms
+        t0 = time.monotonic()
+        cache = run(name, cache)
+        row["wall_ms"][name] = round(
+            (time.monotonic() - t0) * 1e3 / calls, 3)
+    trace_dir = os.path.join(ROOT, ".chipwork", trace)
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    jax.profiler.start_trace(trace_dir)
+    for name in programs:
+        cache = run(name, cache)
+    jax.profiler.stop_trace()
+    row.update(program_times(trace_dir, programs, calls, ops))
+    for table in (row["wall_ms"], row["device_ms"]):
+        if "decode_burst" in table:
+            table["decode_step"] = round(table["decode_burst"] / BURST, 3)
+    return cache, row
+
+
+def mixed(calls: int = 10, ops: int = 40, impls: dict | None = None) -> dict:
+    """``impls``: further mixed steps to time beside the module's, {name:
+    a ``_mixed_impl``} (a variant tried on the chip; each is traced under
+    the program name ``mixed_step_<name>``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm import llama_serving as serving
+
+    cfg, slots, max_seq = config()
+    params, cache, chunk, tokens = on_device(cfg, slots, max_seq)
+    i32 = jnp.int32
     # The chunk's slot is 0 and does not decode; the others do.
     write = jnp.arange(slots) >= 1
     steps = {"mixed_step": serving._mixed_impl}
@@ -228,28 +299,9 @@ def mixed(calls: int = 10, ops: int = 40, impls: dict | None = None) -> dict:
                 lambda c, f: f(cfg, params, c, tokens, positions, write,
                                chunk, kv_len, length, i32(0))[0], f=jitted)
 
-        def run(name, cache):
-            for _ in range(calls):
-                cache = programs[name](cache)
-            return jax.block_until_ready(cache)
-
-        row = out["at"][cached] = {"wall_ms": {}}
-        for name in programs:
-            cache = run(name, cache)                      # compiles, warms
-            t0 = time.monotonic()
-            cache = run(name, cache)
-            row["wall_ms"][name] = round(
-                (time.monotonic() - t0) * 1e3 / calls, 3)
-        trace_dir = os.path.join(ROOT, ".chipwork", f"llama_mixed_{cached}")
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        jax.profiler.start_trace(trace_dir)
-        for name in programs:
-            cache = run(name, cache)
-        jax.profiler.stop_trace()
-        row.update(program_times(trace_dir, programs, calls, ops))
-        for table in (row["wall_ms"], row["device_ms"]):
-            if "decode_burst" in table:
-                table["decode_step"] = round(table["decode_burst"] / BURST, 3)
+        cache, row = timed(programs, cache, calls, ops,
+                           f"llama_mixed_{cached}")
+        out["at"][cached] = row
         dev = row["device_ms"]
         for name in jits:
             if {name, "prefill_chunk", "decode_step"} <= set(dev):
@@ -264,7 +316,58 @@ def mixed(calls: int = 10, ops: int = 40, impls: dict | None = None) -> dict:
     return {k: v for k, v in out.items() if k != "at"}
 
 
-MODES = {"aot": aot, "mixed": mixed}
+def step(calls: int = 10, ops: int = 12) -> dict:
+    """A step of ``decode_burst(8)`` with every line live at half its
+    length, and ``prefill_chunk(512)`` against 1,024 cached rows of slot 0,
+    at each use (``LLAMA_USE`` names one; all three without it), the tree as
+    the engine places it: ``timed``'s row a use, printed and written to
+    ``chiprun_out/llama_step.json``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm import llama_serving as serving
+
+    uses = ([os.environ["LLAMA_USE"]] if "LLAMA_USE" in os.environ
+            else ["serve_chat", "serve_reason", "serve_docqa"])
+    i32 = jnp.int32
+    out = {"mode": "step", "device": jax.devices()[0].device_kind,
+           "rows": ROWS, "burst": BURST, "calls": calls, "use": {}}
+    for use in uses:
+        cfg, slots, max_seq = config(use)
+        params, cache, chunk, tokens = on_device(cfg, slots, max_seq)
+        positions = jnp.full((slots,), max_seq // 2, i32)
+        kv_len = i32(min(1024, max_seq - ROWS))
+        burst = (jnp.ones((slots,), bool), jnp.zeros((slots,)),
+                 jnp.ones((slots,)), jax.random.PRNGKey(0))
+        programs = {
+            "decode_burst": lambda c: serving.decode_burst(
+                cfg, params, c, tokens, positions, *burst, BURST, False)[0],
+            "prefill_chunk": lambda c: serving.prefill_chunk(
+                cfg, params, c, chunk, kv_len, kv_len + 2 * ROWS,
+                i32(0))[0]}
+        cache, row = timed(programs, cache, calls, ops, f"llama_step_{use}")
+        held = sum(a.nbytes for a in jax.tree.leaves((params, cache)))
+        stats = jax.devices()[0].memory_stats() or {}
+        # what the allocator counts beside the tree and the cache: whether a
+        # program's temporaries are in ``peak_bytes_in_use`` (the
+        # benchmark's ``memory_peak_bytes``); a process's peak, so the
+        # first use's alone says it
+        row["memory"] = {"tree_and_cache_bytes": held, **{
+            k: stats[k] for k in ("bytes_in_use", "peak_bytes_in_use")
+            if k in stats}}
+        out["use"][use] = {"layers": cfg.num_layers, "slots": slots,
+                           "max_seq": max_seq, **row}
+        print(json.dumps({use: {k: v for k, v in row.items()
+                                if k != "top_ops_ms"}}), flush=True)
+        del params, cache, programs
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out", "llama_step.json"),
+              "w") as f:
+        json.dump(out, f, indent=1)
+    return {k: v for k, v in out.items() if k != "use"}
+
+
+MODES = {"aot": aot, "mixed": mixed, "step": step}
 
 if __name__ == "__main__":
     for mode in sys.argv[1:] or ["aot"]:

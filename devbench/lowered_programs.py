@@ -68,7 +68,9 @@ def run(backend):
     with ctx:
         for name, module, cfg in models():
             served = getattr(module, "SERVED", None) or module.served_model(cfg)
-            params = jax.tree.map(sds, jax.eval_shape(lambda: served.init_params(cfg, jax.random.PRNGKey(0))))
+            # the tree as the engine places it (``ServedModel.program_params``, which a tree from before PR 57 has not)
+            placed = getattr(served, "program_params", None) or (lambda cfg, tree: tree)
+            params = jax.tree.map(sds, jax.eval_shape(lambda: placed(cfg, served.init_params(cfg, jax.random.PRNGKey(0)))))
             cache = jax.tree.map(sds, jax.eval_shape(lambda: served.init_cache(cfg, SLOTS, MAX_SEQ)))
             # a burst's tokens: [slots], or [slots, K] where a step is a block of K positions
             token0 = (SLOTS,) if served.step is None else (SLOTS, served.step(cfg)[0])

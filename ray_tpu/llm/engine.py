@@ -246,7 +246,7 @@ class LLMEngine:
         if config.tensor_parallel_size > 1:
             self.mesh = _tp_mesh(config.tensor_parallel_size)
             self.kmesh = kernel_mesh(self.mesh)
-        self.params = self._shard_params(params, self.model_cfg)
+        self.params = self._place_params(params, self.model_cfg)
         # Times _recover_device_failure ran, and requests failed for any
         # reason: a failed device step fails the slotted requests and
         # serving goes on, so only these counters (in stats()) tell a
@@ -330,7 +330,7 @@ class LLMEngine:
             if dp is None:
                 dp = init_params(self.draft_cfg,
                                  jax.random.PRNGKey(config.seed + 7))
-            self.draft_params = self._shard_params(dp, self.draft_cfg)
+            self.draft_params = self._place_params(dp, self.draft_cfg)
             self.draft_cache = self._new_cache(self.draft_cfg)
 
         self._slots: dict[int, GenerationRequest | None] = {
@@ -1718,11 +1718,15 @@ class LLMEngine:
 
     # ---- device placement ----
 
-    def _shard_params(self, params, cfg):
+    def _place_params(self, params, cfg):
+        """The tree ``cfg``'s programs take (``ServedModel.program_params``),
+        split over the mesh where there is one."""
+        model = served_model(cfg)
+        if model.program_params is not None:
+            params = model.program_params(cfg, params)
         if self.mesh is None:
             return params
-        return shard_params(params, self.mesh,
-                            served_model(cfg).param_logical_axes(cfg))
+        return shard_params(params, self.mesh, model.param_logical_axes(cfg))
 
     def _new_cache(self, cfg):
         """A zeroed slot cache of ``cfg``'s model, its kv-head dim split

@@ -9,6 +9,11 @@ does the same for its one row a slot (ops/decode_attention.py), both
 grouped over the query heads of a KV head, so no operation of a chunk or
 decode program has a whole layer of the cache as operand.
 
+The weights ride every layer loop as scan xs and are read where they lie:
+q, k and v are one product against one stacked leaf, ``wqkv``, which
+``program_params`` fuses once where the engine places a tree, so that no
+scheduled program copies a weight stack or a layer's slice of one.
+
 ``prefill_chunk``, ``decode_step`` and ``decode_burst`` are the scheduler's
 three programs (the last two built from ``_decode_step_impl`` by
 llm/served.token_step_programs); ``mixed_burst`` is the burst whose steps
@@ -54,12 +59,78 @@ from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.util import tracing
 
 
-def _project_qkv(cfg: LlamaConfig, lp, xn, b, s):
-    q = (xn @ lp["wq"]).reshape(b, s, cfg.num_heads, cfg.head_dim)
-    k = (xn @ lp["wk"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    v = (xn @ lp["wv"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    return (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3))
+def _fused_qkv(cfg: LlamaConfig, wq, wk, wv):
+    """The stacked ``wq``, ``wk`` and ``wv`` as one leaf ``[L, H, nkv *
+    (n_rep + 2) * D]``, its columns grouped by KV head: a group's ``n_rep``
+    query heads in their published order, then its key head, then its value
+    head. Grouped so, the last axis splits over ``tp`` on whole groups and
+    a product's result reshapes to ``[rows, nkv, n_rep + 2, D]``."""
+    L, h = wq.shape[:2]
+    return jnp.concatenate(
+        [w.reshape(L, h, cfg.num_kv_heads, -1, cfg.head_dim)
+         for w in (wq, wk, wv)], axis=3).reshape(L, h, -1)
+
+
+_fuse_qkv = jax.jit(_fused_qkv, static_argnums=0)
+
+
+def program_params(cfg: LlamaConfig, params):
+    """``ServedModel.program_params``: the tree with ``layers.wqkv``
+    (:func:`_fused_qkv`) beside its leaves, every one of which is the buffer
+    it was. The scheduled programs multiply q, k and v as one product against
+    that leaf, which a layer loop reads in place from HBM: three stacked
+    leaves XLA re-lays out whole once a burst, copies a layer's slice of
+    each out of, and, where a re-laid stack fits the fast memory (``wk`` at
+    12 layers, 96 MiB), evicts and fetches back in every layer (PERF.md
+    section 6, PR 57). ``wq``, ``wk`` and ``wv`` stay in the tree for the
+    oracle ``prefill`` and for who reads the weights by name (ROADMAP
+    R0 (f)); no scheduled program reads them, so they cost memory and no
+    time. A tree that has the leaf is returned as it is. The programs call
+    this on entry: handed a tree without the leaf (a test, a probe), they
+    fuse it inside the program, at the cost this function is there to pay
+    once."""
+    layers = params["layers"]
+    if "wqkv" in layers:
+        return params
+    wqkv = _fuse_qkv(cfg, layers["wq"], layers["wk"], layers["wv"])
+    return {**params, "layers": {**layers, "wqkv": wqkv}}
+
+
+def param_logical_axes(cfg: LlamaConfig) -> dict:
+    """models/llama.py's axes and the fused leaf's: whole groups over
+    ``tp``."""
+    axes = llama_model.param_logical_axes(cfg)
+    return {**axes, "layers": {**axes["layers"],
+                               "wqkv": ("layers", "embed", "kv_heads")}}
+
+
+def _split_qkv(cfg: LlamaConfig, rows):
+    """A product against ``wqkv`` [..., nkv * (n_rep + 2) * D] -> q [...,
+    nh, D], k and v [..., nkv, D]."""
+    n_rep = cfg.num_heads // cfg.num_kv_heads
+    rows = rows.reshape(*rows.shape[:-1], cfg.num_kv_heads, n_rep + 2,
+                        cfg.head_dim)
+    q = rows[..., :n_rep, :].reshape(*rows.shape[:-3], cfg.num_heads,
+                                     cfg.head_dim)
+    return q, rows[..., n_rep, :], rows[..., n_rep + 1, :]
+
+
+def _qkv_rows(lp, xn):
+    """xn [..., H] @ ``wqkv`` -> [..., nkv * (n_rep + 2) * D], kept as an
+    array of its own. With the split into heads fused into the product XLA
+    computes it as ``[rows, nkv, n_rep + 2, D]``, wants the leaf
+    output-major for that, re-lays the whole stack out once a burst (0.56
+    GiB of temporaries at 12 layers) and copies a layer's slice of it into
+    the fast memory in every layer; behind the barrier the product is the
+    MLP's plain kind and a ``dynamic-slice`` of the stack feeds it in place
+    (``devbench/llama_bench.py aot``, PR 57)."""
+    return lax.optimization_barrier(xn @ lp["wqkv"])
+
+
+def _project_qkv(cfg: LlamaConfig, lp, xn):
+    """xn [B, S, H] -> q [B, nh, S, D], k and v [B, nkv, S, D]."""
+    return tuple(a.transpose(0, 2, 1, 3)
+                 for a in _split_qkv(cfg, _qkv_rows(lp, xn)))
 
 
 def _repeat_kv(x, n_rep: int):
@@ -154,7 +225,10 @@ def prefill(cfg: LlamaConfig, params, cache, tokens, length, slot, *,
         b, s_, _ = x.shape
         with tracing.part("attn"):
             xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
-            q, k, v = _project_qkv(cfg, lp, xn, b, s_)
+            # The three leaves, not the fused one the scheduled programs
+            # read: the oracle holds the leaf's grouping too.
+            q, k, v = ((xn @ lp[w]).reshape(b, s_, -1, cfg.head_dim)
+                       .transpose(0, 2, 1, 3) for w in ("wq", "wk", "wv"))
             q = apply_rope(q, positions, inv_freq)
             k = apply_rope(k, positions, inv_freq)
             # Write this layer's K/V into the slot (positions 0..S).
@@ -204,6 +278,7 @@ def prefill_chunk(cfg: LlamaConfig, params, cache, tokens, kv_len, length,
     """
     c = tokens.shape[0]
     num_layers = cache["k"].shape[0]
+    params = program_params(cfg, params)
     with tracing.part("embed"):
         x = params["embed_tokens"][tokens][None]  # [1, C, H]
     with tracing.part("attn"):
@@ -216,7 +291,7 @@ def prefill_chunk(cfg: LlamaConfig, params, cache, tokens, kv_len, length,
         lp, layer = scanned
         with tracing.part("attn"):
             xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
-            q, k, v = _project_qkv(cfg, lp, xn, 1, c)
+            q, k, v = _project_qkv(cfg, lp, xn)
             q = apply_rope(q, positions, inv_freq)
             k = apply_rope(k, positions, inv_freq)
             k_all, v_all, o = _chunk_attend(k_all, v_all, q, k, v, layer,
@@ -269,6 +344,7 @@ def _multi_token_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
     logits mean nothing."""
     b, k = tokens.shape
     num_layers = cache["k"].shape[0]
+    params = program_params(cfg, params)
     with tracing.part("embed"):
         x = params["embed_tokens"][tokens]  # [B, K, H]
     with tracing.part("attn"):
@@ -285,7 +361,7 @@ def _multi_token_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
         lp, layer = scanned
         with tracing.part("attn"):
             xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
-            q, kk, v = _project_qkv(cfg, lp, xn, b, k)
+            q, kk, v = _project_qkv(cfg, lp, xn)
             q = apply_rope(q, positions, inv_freq)
             kk = apply_rope(kk, positions, inv_freq)
             k_all, v_all, o = _lines_attend(k_all, v_all, q, kk, v, layer,
@@ -306,17 +382,17 @@ def _multi_token_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
 def _mixed_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
                 write_mask, chunk, kv_len, length, slot, kmesh=None):
     """``mixed_step`` of llm/served.mixed_burst_program. The norms, the
-    ``wq`` / ``wk`` / ``wv`` products, ``wo`` and the MLP see all rows at
-    once: a layer's weights are fetched once for both. The attention splits
-    them, the chunk's rows to the chunk's half and the lines' to the
-    lines'. The products' rows are split before
-    their heads: the heads of all 528 rows split first (``_project_qkv``'s
-    order) cost 1.6 ms a step more at docqa's 16 layers, the slices of the
-    stacked ``wq``, ``wk`` and ``wv`` copied out a layer as in
-    ``prefill_chunk``; a barrier on the halves' outputs, the lines' half
-    first and the lines' rows first gave nothing (my chip runs, PR 55)."""
+    ``wqkv`` product, ``wo`` and the MLP see all rows at once: a layer's
+    weights are fetched once for both. The attention splits them, the
+    chunk's rows to the chunk's half and the lines' to the lines'. The
+    product's rows are split before its heads: the heads of all 528 rows
+    split first (``_project_qkv``'s order) cost 1.6 ms a step more at
+    docqa's 16 layers; a barrier on the halves' outputs, the lines' half
+    first and the lines' rows first gave nothing (my chip runs, PR 55, when
+    the product was three)."""
     c, b = chunk.shape[0], tokens.shape[0]
     num_layers = cache["k"].shape[0]
+    params = program_params(cfg, params)
     with tracing.part("attn"):
         ids, at, _, lengths, lines_of = mixed_rows(
             chunk, tokens, kv_len, length, positions0, write_mask)
@@ -327,19 +403,18 @@ def _mixed_impl(cfg: LlamaConfig, params, cache, tokens, positions0,
     with tracing.part("embed"):
         x = params["embed_tokens"][ids][None]  # [1, C + B, H]
 
-    def heads(rows):
-        """A product's rows [1, C + B, n * D], head-major a half: the
-        chunk's [1, n, C, D] and the lines' [B, n, 1, D]."""
-        rows = rows.reshape(c + b, -1, cfg.head_dim)
-        return (rows[:c].transpose(1, 0, 2)[None], rows[c:, :, None])
-
     def body(carry, scanned):
         x, k_all, v_all = carry
         lp, layer = scanned
         with tracing.part("attn"):
             xn = rms_norm(x, lp["attn_norm"], cfg.norm_eps, kmesh)
-            (q, q_l), (k, k_l), (v, v_l) = (
-                heads(xn @ lp[w]) for w in ("wq", "wk", "wv"))
+            rows = _qkv_rows(lp, xn)[0]  # [C + B, nkv * (n_rep + 2) * D]
+            # head-major a half: the chunk's [1, n, C, D], the lines'
+            # [B, n, 1, D]
+            q, k, v = (a.transpose(1, 0, 2)[None]
+                       for a in _split_qkv(cfg, rows[:c]))
+            q_l, k_l, v_l = (a[:, :, None]
+                             for a in _split_qkv(cfg, rows[c:]))
             q, k = (apply_rope(a, at_chunk, inv_freq) for a in (q, k))
             q_l, k_l = (apply_rope(a, at_lines, inv_freq)
                         for a in (q_l, k_l))
@@ -419,7 +494,8 @@ def spec_verify_step(cfg: LlamaConfig, params, cache, tokens, positions0,
 
 SERVED = ServedModel(
     init_params=llama_model.init_params,
-    param_logical_axes=llama_model.param_logical_axes,
+    param_logical_axes=param_logical_axes,
+    program_params=program_params,
     init_cache=init_kv_cache,
     prefill_chunk=prefill_chunk,
     decode_step=decode_step,

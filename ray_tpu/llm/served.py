@@ -109,6 +109,10 @@ class ServedModel:
     this:
 
     - ``init_params(cfg, key)`` and ``param_logical_axes(cfg)``;
+    - ``program_params(cfg, params) -> tree``: the tree its programs take,
+      made once from whatever tree the engine was given (an initialiser's,
+      a checkpoint's) where the engine places it, its leaves' axes in
+      ``param_logical_axes``. None: the tree as it is;
     - ``init_cache(cfg, slots, max_seq)``: the slot cache, a pytree whose
       leaves the programs below take donated and give back. A leaf's
       leading dimension is cache *lines*, of which a model may have more
@@ -201,6 +205,7 @@ class ServedModel:
     spec_verify_step: Callable | None = None
     refuse: Callable | None = None
     mixed_burst: Callable | None = None
+    program_params: Callable | None = None
 
     def __post_init__(self):
         if self.mixed_burst is not None and self.step is not None:

@@ -11,15 +11,18 @@ import pytest
 
 import ray_tpu
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
-from ray_tpu.llm.llama_serving import decode_step, prefill
+from ray_tpu.llm.llama_serving import decode_step, prefill, program_params
 from ray_tpu.llm.served import init_kv_cache, sample_tokens
 from ray_tpu.models.llama import LlamaConfig, forward, init_params
 
 
 @pytest.fixture(scope="module")
 def tiny():
+    """The tree as the engine places it: the scheduled programs read its
+    fused ``wqkv``; the oracles (``prefill``, models/llama.py's ``forward``)
+    the ``wq``, ``wk`` and ``wv`` it was fused from."""
     cfg = LlamaConfig.tiny()
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = program_params(cfg, init_params(cfg, jax.random.PRNGKey(0)))
     return cfg, params
 
 
@@ -1189,6 +1192,64 @@ def test_engine_loads_hf_checkpoint_dir(tmp_path):
         eng.shutdown()
 
 
+@pytest.mark.parametrize("source", ["init_params", "checkpoint", "hf"])
+def test_a_tree_from_anywhere_is_served_through_the_fused_leaf(tmp_path,
+                                                                source):
+    """The engine fuses ``wqkv`` where it places a tree, whoever made the
+    tree: ``init_params``, a saved checkpoint, ``convert_hf_llama``. Its
+    greedy tokens, prefilled in two chunks and decoded in bursts, are those
+    of models/llama.py's whole forward, which multiplies ``wq``, ``wk`` and
+    ``wv`` apart."""
+    cfg = dataclasses.replace(LlamaConfig.tiny(), vocab_size=512,
+                              max_seq_len=64)
+    params, given = init_params(cfg, jax.random.PRNGKey(5)), {}
+    if source == "init_params":
+        given = {"params": params}
+    elif source == "checkpoint":
+        import orbax.checkpoint as ocp
+
+        with ocp.StandardCheckpointer() as ckptr:
+            ckptr.save(tmp_path / "ck", params)
+        given = {"checkpoint_path": str(tmp_path / "ck")}
+    else:
+        torch = pytest.importorskip("torch")
+        tfs = pytest.importorskip("transformers")
+        from ray_tpu.llm.hf import convert_hf_llama
+
+        torch.manual_seed(0)
+        tfs.LlamaForCausalLM(tfs.LlamaConfig(
+            vocab_size=512, hidden_size=64, intermediate_size=128,
+            num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, max_position_embeddings=64,
+            rope_theta=10000.0, tie_word_embeddings=False,
+            attn_implementation="eager")).save_pretrained(tmp_path / "hf")
+        cfg, params = convert_hf_llama(str(tmp_path / "hf"), dtype="float32")
+        given = {"checkpoint_path": str(tmp_path / "hf")}
+
+    prompt, n = list(range(300, 321)), 9
+    ids = np.zeros((1, 32), np.int32)
+    ids[0, :len(prompt)] = prompt
+    whole = jax.jit(lambda ids: forward(cfg, params, ids,
+                                        attn_impl="blockwise", remat=False))
+    for at in range(len(prompt), len(prompt) + n):
+        ids[0, at] = int(jnp.argmax(whole(jnp.asarray(ids))[0, at - 1]))
+
+    eng = LLMEngine(
+        LLMConfig(model=cfg, dtype="float32", max_num_seqs=2, max_seq_len=64,
+                  prefill_chunk=16, decode_burst=4,
+                  checkpoint_path=given.get("checkpoint_path")),
+        params=given.get("params"))
+    try:
+        lay = eng.params["layers"]
+        assert lay["wqkv"].shape == (2, 64, lay["wq"].shape[-1]
+                                     + 2 * lay["wk"].shape[-1])
+        req = eng.submit(prompt, SamplingParams(max_tokens=n))
+        assert req.done.wait(120) and req.error is None
+        assert req.out_tokens == list(ids[0, len(prompt):len(prompt) + n])
+    finally:
+        eng.shutdown()
+
+
 class TestDecodeKernelBody:
     """The decode programs with ops/decode_attention.py's kernel bodies run
     through the Pallas interpreter (the CPU default is their jnp reference).
@@ -1199,7 +1260,8 @@ class TestDecodeKernelBody:
         from dataclasses import replace
 
         cfg = replace(LlamaConfig.tiny(), vocab_size=264)
-        return cfg, init_params(cfg, jax.random.PRNGKey(0))
+        return cfg, program_params(cfg, init_params(cfg,
+                                                    jax.random.PRNGKey(0)))
 
     @pytest.fixture(autouse=True)
     def _interpret(self):

@@ -76,9 +76,11 @@ def _llama() -> Fixture:
     def no_request_fails(stats):
         assert stats["requests_failed"] == 0
 
+    # the tree as the engine places it: the fused leaf beside the three
+    params = llama_serving.program_params(
+        cfg, init_params(cfg, jax.random.PRNGKey(0)))
     return Fixture(
-        llama_serving, cfg, init_params(cfg, jax.random.PRNGKey(0)),
-        _tokens(5, 1, cfg), rows={"k": 3, "v": 3},
+        llama_serving, cfg, params, _tokens(5, 1, cfg), rows={"k": 3, "v": 3},
         logits={"rtol": 2e-4, "atol": 2e-4},
         engine_cfg=dataclasses.replace(cfg, vocab_size=512),
         engine_holds=no_request_fails)

@@ -642,6 +642,105 @@ def test_only_a_model_that_says_it_partitions_is_given_a_tp_mesh(model):
         == ["served.py"]
 
 
+# ---- the tree a model's programs take ---------------------------------------
+
+@pytest.mark.parametrize("heads,kv_heads", [(4, 4), (8, 2), (3, 1)],
+                         ids=["mha", "gqa_4_to_1", "one_kv_head"])
+def test_llama_s_program_params_adds_the_fused_leaf_and_keeps_the_rest(
+        heads, kv_heads):
+    """``wqkv``'s columns are ``wq``'s, ``wk``'s and ``wv``'s grouped by KV
+    head (a group's query heads in their published order, its key head, its
+    value head); every leaf the tree came with is the buffer it was, the
+    three projections among them; a tree that has the leaf passes as it is;
+    the axes name the leaf, whole groups over ``tp``."""
+    import numpy as np
+
+    from ray_tpu.models.llama import init_params
+
+    cfg = dataclasses.replace(LlamaConfig.tiny(), num_heads=heads,
+                              num_kv_heads=kv_heads)
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    tree = llama_serving.program_params(cfg, params)
+    assert set(tree["layers"]) == set(params["layers"]) | {"wqkv"}
+    given = dict(jax.tree_util.tree_leaves_with_path(params))
+    for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
+        assert path[-1].key == "wqkv" or leaf is given[path], path
+    assert llama_serving.program_params(cfg, tree) is tree
+
+    lay, n_rep, d = params["layers"], heads // kv_heads, cfg.head_dim
+    L, h = cfg.num_layers, cfg.hidden_size
+    assert tree["layers"]["wqkv"].shape == (L, h, kv_heads * (n_rep + 2) * d)
+    cols = np.asarray(tree["layers"]["wqkv"]).reshape(
+        L, h, kv_heads, n_rep + 2, d)
+    wq, wk, wv = (np.asarray(lay[w]).reshape(L, h, -1, d)
+                  for w in ("wq", "wk", "wv"))
+    for group in range(kv_heads):
+        for r in range(n_rep):
+            np.testing.assert_array_equal(cols[:, :, group, r],
+                                          wq[:, :, group * n_rep + r])
+        np.testing.assert_array_equal(cols[:, :, group, n_rep],
+                                      wk[:, :, group])
+        np.testing.assert_array_equal(cols[:, :, group, n_rep + 1],
+                                      wv[:, :, group])
+
+    axes = llama_serving.SERVED.param_logical_axes(cfg)
+    assert axes["layers"]["wqkv"] == ("layers", "embed", "kv_heads")
+    assert jax.tree.structure(axes, is_leaf=lambda a: isinstance(a, tuple)) \
+        == jax.tree.structure(tree)
+
+
+@pytest.mark.parametrize("model", **ALL_MODELS)
+def test_the_engine_holds_the_tree_its_model_s_programs_take(model):
+    """``ServedModel.program_params`` is the model's statement, called once
+    where the engine places a tree: a model without the entry (every one
+    but Llama's) is handed the tree it gave, leaf for leaf; Llama's holds
+    the fused leaf beside the leaves it was given, which the programs were
+    not left to fuse themselves."""
+    module, cfg = model()
+    served = served_model(cfg)
+    assert (served.program_params is not None) == (module is llama_serving)
+    params = served.init_params(cfg, jax.random.PRNGKey(0))
+    eng = LLMEngine(LLMConfig(model=cfg, max_num_seqs=SLOTS,
+                              max_seq_len=MAX_SEQ), params=params)
+    try:
+        held = dict(jax.tree_util.tree_leaves_with_path(eng.params))
+        given = dict(jax.tree_util.tree_leaves_with_path(params))
+        assert all(held[path] is leaf for path, leaf in given.items())
+        extra = [jax.tree_util.keystr(path) for path in held.keys() - given]
+        assert extra == (["['layers']['wqkv']"]
+                         if module is llama_serving else [])
+    finally:
+        eng.shutdown()
+
+
+def test_a_tensor_parallel_engine_splits_the_fused_leaf_and_serves_the_same(
+        cpu_mesh_devices):
+    """``tensor_parallel_size=2``: the fused leaf is split over ``tp`` on
+    its last axis (a KV head's group a shard at the tiny model's 2 KV
+    heads), the three it was made of as ever, and greedy tokens are the one
+    device's."""
+    _, cfg = _llama()
+    prompts = [[5, 6, 7, 8, 9], list(range(40, 40 + CHUNK + 3))]
+
+    def served(tp):
+        eng = LLMEngine(LLMConfig(model=cfg, max_num_seqs=SLOTS,
+                                  max_seq_len=MAX_SEQ, prefill_chunk=CHUNK,
+                                  decode_burst=4, tensor_parallel_size=tp))
+        try:
+            placed = [eng.params["layers"][w].sharding
+                      for w in ("wqkv", "wq", "wk")]
+            reqs = [eng.submit(p, SamplingParams(max_tokens=9))
+                    for p in prompts]
+            assert all(r.done.wait(120) for r in reqs)
+            return placed, [r.out_tokens for r in reqs]
+        finally:
+            eng.shutdown()
+
+    placed, tokens = served(2)
+    assert placed[0].spec[-1] == "tp" and placed == placed[:1] * 3
+    assert tokens == served(1)[1]
+
+
 # ---- the latent line: one module a layer, imported by both models ----------
 
 @pytest.mark.parametrize("model", argvalues=[_longcat, _deepseek],

@@ -200,6 +200,30 @@ def test_vit_step_partitions_over_four_chips(mosaic):
     assert "num_partitions=4" in text and text.count(MOSAIC) > 0
 
 
+def _served_llama_tree(cfg, mesh):
+    """The tree as the engine hands it to Llama's programs
+    (``program_params`` of ``init_params``' tree: the fused ``wqkv`` beside
+    the three), its shapes placed by the served model's own axes."""
+    from ray_tpu.llm import llama_serving
+
+    return _sds(
+        jax.eval_shape(lambda key: llama_serving.program_params(
+            cfg, init_params(cfg, key)), jax.random.PRNGKey(0)),
+        tree_shardings(mesh, llama_serving.SERVED.param_logical_axes(cfg)))
+
+
+def _gathers_no_fused_leaf(text: str, cfg, tp: int) -> bool:
+    """The fused leaf comes in split on its last axis, ``1 / tp`` of its
+    columns a chip, and no all-gather makes it whole."""
+    cols = (cfg.num_heads + 2 * cfg.num_kv_heads) * cfg.head_dim
+    shard = f"[{cfg.num_layers},{cfg.hidden_size},{cols // tp}]"
+    whole = f"[{cfg.num_layers},{cfg.hidden_size},{cols}]"
+    return (any(shard in line and " parameter(" in line
+                for line in text.splitlines())
+            and whole not in text and f"[1,{cfg.hidden_size},{cols}]"
+            not in text)
+
+
 def test_engine_programs_partition_over_tensor_parallel_chips(mosaic):
     from ray_tpu.llm import llama_serving
 
@@ -207,9 +231,7 @@ def test_engine_programs_partition_over_tensor_parallel_chips(mosaic):
     mesh = build_mesh(MeshSpec(tp=4), mosaic)
     kmesh = kernel_mesh(mesh)
     repl = NamedSharding(mesh, P())
-    params = _sds(
-        jax.eval_shape(partial(init_params, CFG), jax.random.PRNGKey(0)),
-        tree_shardings(mesh, param_logical_axes(CFG)))
+    params = _served_llama_tree(CFG, mesh)
     cache = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype,
@@ -239,6 +261,7 @@ def test_engine_programs_partition_over_tensor_parallel_chips(mosaic):
         # (and the sampled tokens' few bytes).
         assert all(op == "all-reduce" or n <= 64
                    for (op, n) in _collectives(text, 4))
+        assert _gathers_no_fused_leaf(text, CFG, 4)
 
 
 def test_mixed_burst_partitions_over_tensor_parallel_chips(mosaic):
@@ -251,9 +274,7 @@ def test_mixed_burst_partitions_over_tensor_parallel_chips(mosaic):
     slots, max_seq, steps, chunk = 4, 256, 4, 32
     mesh = build_mesh(MeshSpec(tp=4), mosaic)
     repl = NamedSharding(mesh, P())
-    params = _sds(
-        jax.eval_shape(partial(init_params, CFG), jax.random.PRNGKey(0)),
-        tree_shardings(mesh, param_logical_axes(CFG)))
+    params = _served_llama_tree(CFG, mesh)
     cache = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype,
@@ -278,6 +299,7 @@ def test_mixed_burst_partitions_over_tensor_parallel_chips(mosaic):
         assert f'"{name}"' in text or f"%{name}." in text, name
     assert all(op == "all-reduce" or n <= 64
                for (op, n) in _collectives(text, 4))
+    assert _gathers_no_fused_leaf(text, CFG, 4)
 
 
 # Mistral-7B widths, two layers: the decode program of the two serving cells.
@@ -293,9 +315,7 @@ def _mistral_state(mesh, slots, max_seq):
     from ray_tpu.llm import llama_serving
 
     repl = NamedSharding(mesh, P())
-    params = _sds(
-        jax.eval_shape(partial(init_params, MISTRAL), jax.random.PRNGKey(0)),
-        tree_shardings(mesh, param_logical_axes(MISTRAL)))
+    params = _served_llama_tree(MISTRAL, mesh)
     cache = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype,
@@ -408,21 +428,16 @@ def test_prefill_chunk_moves_no_whole_cache_at_mistral_widths(mosaic, slots,
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
-# The dense module's burst whose steps carry a chunk (PR 55), at the depth
-# and cache of the two closed-loop Mistral cells, as the cells compile it.
-@pytest.mark.parametrize("use,layers,slots,max_seq", [
-    ("serve_docqa", 16, 16, 3200), ("serve_reason", 12, 32, 3072)])
-def test_llama_mixed_burst_copies_no_cache_and_no_weight_of_its_own(
-        mosaic, use, layers, slots, max_seq):
-    """``mixed_burst(8)`` holds both loops' bodies: the chunk's 512 rows go
-    in by an update in place and the lines' by the row kernel on the one
-    stack, which only passes through; a layer of it is nobody's operand.
-    Arguments and temporaries fit the chip's 15.75 GiB. The steps past the
-    riders are ``decode_burst``'s: whatever that program copies of a stacked
-    weight at the top (at these widths XLA re-lays ``wq``, ``wk`` and ``wo``
-    out for 16 or 32 rows: ``PERF.md`` section 5) the mixed one copies too,
-    and no more: its temporaries are the plain burst's, the riding steps'
-    528 or 544 rows of activations aside."""
+# The dense module's programs at the depth and cache of the three Mistral
+# serve cells, as the cells compile them: the tree as the engine hands it
+# over (devbench/llama_bench.shapes).
+LLAMA_CELLS = [("serve_docqa", 16, 16, 3200), ("serve_reason", 12, 32, 3072),
+               ("serve_chat", 12, 32, 2048)]
+
+
+def _llama_cell(mosaic, use, layers, slots, max_seq):
+    """(cfg, {program: a function that lowers it}, the shapes to look for)
+    of ``use``'s cell on one described chip."""
     from devbench import llama_bench as bench
 
     cfg, *cell = bench.config(use)
@@ -437,10 +452,67 @@ def test_llama_mixed_burst_copies_no_cache_and_no_weight_of_its_own(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
 
     params, cache = bench.shapes(cfg, slots, max_seq, placed)
-    lower = bench.lowerings(cfg, params, cache, arg)
+    assert "wqkv" in params["layers"]
+    return (cfg, bench.lowerings(cfg, params, cache, arg),
+            bench.big_shapes(cfg, slots, max_seq))
+
+
+CARRIED = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk(512)", "decode_burst(8)",
+                                     "mixed_burst(8)"])
+@pytest.mark.parametrize("use,layers,slots,max_seq", LLAMA_CELLS)
+def test_llama_programs_read_every_projection_in_place(
+        mosaic, use, layers, slots, max_seq, program):
+    """q, k and v are one product against the fused ``wqkv``, which the
+    layer loop reads where it lies, as it reads ``wo``: a stacked projection
+    passes through, a layer of it is a ``dynamic-slice`` that feeds the
+    product, and nothing else has either shape. With three leaves XLA
+    re-laid each stack out once a burst (0.56 GiB of temporaries at 12
+    layers), copied a layer's slice of each out in every layer, and, where
+    the re-laid ``wk`` fitted the fast memory (96 MiB at 12 layers),
+    evicted it whole and fetched it back in four quarters in every layer
+    (``copy-done``, four ``slice-done``, a ``ConcatBitcast``): a third of
+    chat's and reason's step (PERF.md section 6, PR 57). The three leaves
+    the tree still carries are nobody's operand."""
+    from devbench import llama_bench as bench
+
+    cfg, lower, big = _llama_cell(mosaic, use, layers, slots, max_seq)
+    compiled = lower[program]().compile()
+    text = compiled.as_text()
+    for leaf in ("wq", "wk", "wo", "wqkv"):
+        for shape in (big[leaf], big[f"{leaf}_layer"]):
+            ops = set(bench.opcodes_with_shape(text, shape))
+            assert ops <= CARRIED | {"dynamic-slice"}, (leaf, shape, ops)
+    assert big["wqkv_layer"] in text and big["wk_layer"] not in text
+    # no stack in the fast memory, none fetched back from it
+    stack = rf"bf16\[{layers},\d{{4,}},\d{{4,}}\]"     # widths, not rows
+    assert re.search(stack, text)
+    assert not re.search(stack + r"{[^}]*S\(1\)", text)
+    assert "slice-done(" not in text and "ConcatBitcast" not in text
+    if program != "prefill_chunk(512)":
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 26
+
+
+@pytest.mark.parametrize("use,layers,slots,max_seq", LLAMA_CELLS)
+def test_llama_mixed_burst_copies_no_cache_and_no_weight_of_its_own(
+        mosaic, use, layers, slots, max_seq):
+    """``mixed_burst(8)`` holds both loops' bodies: the chunk's 512 rows go
+    in by an update in place and the lines' by the row kernel on the one
+    stack, which only passes through; a layer of it is nobody's operand.
+    Arguments and temporaries fit the chip's 15.75 GiB. The steps past the
+    riders are ``decode_burst``'s, and neither program copies a stacked
+    weight (until PR 57 XLA re-laid ``wq``, ``wk`` and ``wv`` out once a
+    burst for a step's 16 or 32 rows: ``PERF.md`` section 6): the mixed
+    one's temporaries are the plain burst's, the riding steps' 528 or 544
+    rows of activations aside."""
+    from devbench import llama_bench as bench
+
+    cfg, lower, big = _llama_cell(mosaic, use, layers, slots, max_seq)
     mixed, plain = (lower[name]().compile()
                     for name in ("mixed_burst(8)", "decode_burst(8)"))
-    text, plain_text = mixed.as_text(), plain.as_text()
+    text = mixed.as_text()
     for name in ("prefill_attention", "decode_attention", "kv_row_write"):
         assert f'"{name}"' in text or f"%{name}." in text, name
     # A riding step's five a layer (two norms, the chunk's attention, the
@@ -451,16 +523,12 @@ def test_llama_mixed_burst_copies_no_cache_and_no_weight_of_its_own(
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30 \
         < 15.75
     assert mem.temp_size_in_bytes < plain_mem.temp_size_in_bytes + (1 << 26)
-    big = bench.big_shapes(cfg, slots, max_seq)
-    carried = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
     assert set(bench.opcodes_with_shape(text, big["cache"])) <= \
-        carried | {"dynamic-update-slice", "custom-call"}
+        CARRIED | {"dynamic-update-slice", "custom-call"}
     assert not bench.opcodes_with_shape(text, big["cache_layer"])
-    for leaf in ("wq", "wk", "wo", "w_gate", "w_down"):
+    for leaf in ("wq", "wk", "wo", "wqkv", "w_gate", "w_down"):
         ops = bench.opcodes_with_shape(text, big[leaf])
-        was = bench.opcodes_with_shape(plain_text, big[leaf])
-        assert set(ops) <= carried | set(was), leaf
-        assert ops.get("copy", 0) <= was.get("copy", 0), leaf
+        assert set(ops) <= CARRIED, leaf    # no copy
     # No logits of the chunk's rows: the head runs on the lines' alone.
     assert f"f32[{slots},{cfg.vocab_size}]" in text
     assert f"[{512 + slots},{cfg.vocab_size}]" not in text
