@@ -17,7 +17,9 @@ DUMP=<dir> also writes the texts. With models named (``llama``, ``lfm2``,
 shape and keeps the source lines of the caller that traced it, so in one
 process a model whose file moved hands its new lines to every later model
 that runs the same kernel at the same shapes; a model a process (or all
-but the edited one in one) tells the two apart."""
+but the edited one in one) tells the two apart. Imported
+(tests/test_tpu_aot.py holds three models' jaxpr hashes), nothing runs:
+``run(backend, names)`` gives the hashes of the models named."""
 import dataclasses
 import hashlib
 import importlib
@@ -45,14 +47,14 @@ MODULES = getattr(llm_config, "SERVING_MODULES", None) or {
     kind: "ray_tpu.llm." + ("engine" if kind.__name__ == "LlamaConfig" else kind.__name__[:-len("Config")].lower() + "_serving")
     for kind in llm_config.ModelConfig.__args__}
 
-def models():
+def models(names):
     for kind, module in MODULES.items():
         tiny = TINY.get(kind.__name__, lambda c: c.tiny(max_seq_len=MAX_SEQ, dtype="bfloat16"))
         name = kind.__name__[:-len("Config")].lower()
-        if name in sys.argv[1:] or not sys.argv[1:]:
+        if name in names or not names:
             yield name, importlib.import_module(module), tiny(kind)
 
-def run(backend):
+def run(backend, names=()):
     out = {}
     if backend == "mosaic":
         devices = topologies.get_topology_desc("v5e:2x2", "tpu").devices
@@ -66,7 +68,7 @@ def run(backend):
     def arg(shape, dtype=jnp.int32):
         return sds(jax.ShapeDtypeStruct(shape, dtype))
     with ctx:
-        for name, module, cfg in models():
+        for name, module, cfg in models(names):
             served = getattr(module, "SERVED", None) or module.served_model(cfg)
             # the tree as the engine places it (``ServedModel.program_params``, which a tree from before PR 57 has not)
             placed = getattr(served, "program_params", None) or (lambda cfg, tree: tree)
@@ -99,7 +101,8 @@ def run(backend):
                     open(os.path.join(os.environ["DUMP"], f"{name}.{prog}.{backend}.jaxpr.txt"), "w").write(jp)
                     open(os.path.join(os.environ["DUMP"], f"{name}.{prog}.{backend}.mlir.txt"), "w").write(bare)
     return out
-res = {}
-for b in ("reference", "mosaic"):
-    res.update(run(b))
-print(json.dumps(res, indent=0, sort_keys=True))
+if __name__ == "__main__":
+    res = {}
+    for b in ("reference", "mosaic"):
+        res.update(run(b, sys.argv[1:]))
+    print(json.dumps(res, indent=0, sort_keys=True))

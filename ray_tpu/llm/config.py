@@ -16,6 +16,7 @@ from ray_tpu.models.deepseek import DeepseekV2Config
 from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
+from ray_tpu.models.ling import LingConfig
 from ray_tpu.models.mimo import MimoConfig
 from ray_tpu.models.ouro import OuroConfig
 from ray_tpu.models.phi4flash import Phi4FlashConfig
@@ -37,6 +38,7 @@ SERVING_MODULES = {
     Qwen3NextConfig: "ray_tpu.llm.qwen3_next_serving",
     Phi4FlashConfig: "ray_tpu.llm.phi4flash_serving",
     MimoConfig: "ray_tpu.llm.mimo_serving",
+    LingConfig: "ray_tpu.llm.ling_serving",
 }
 ModelConfig = Union[tuple(SERVING_MODULES)]
 

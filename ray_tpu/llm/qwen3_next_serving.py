@@ -48,8 +48,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
+from ray_tpu.llm import linear_state
 from ray_tpu.llm.served import ServedModel, token_step_programs
 from ray_tpu.models import qwen3_next
 from ray_tpu.models.qwen3_next import ATTENTION, LINEAR, Qwen3NextConfig
@@ -75,13 +75,10 @@ def init_cache(cfg: Qwen3NextConfig, max_slots: int, max_seq: int):
              cfg.head_dim)
     return {
         "k": jnp.zeros(lines, dt), "v": jnp.zeros(lines, dt),
-        "state": jnp.zeros(
-            (cfg.linear_lines, max_slots, cfg.linear_num_value_heads,
-             cfg.linear_key_head_dim, cfg.linear_value_head_dim),
-            jnp.float32),
-        "conv": jnp.zeros(
-            (cfg.linear_lines, max_slots,
-             (cfg.linear_conv_kernel_dim - 1) * cfg.conv_dim), dt)}
+        **linear_state.init_leaves(
+            cfg.linear_lines, max_slots, cfg.linear_num_value_heads,
+            cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+            cfg.linear_conv_kernel_dim, cfg.conv_dim, dt)}
 
 
 _LEAVES = ("k", "v", "state", "conv")
@@ -121,12 +118,7 @@ def prefill_chunk(cfg: Qwen3NextConfig, params, cache, tokens, kv_len,
     def linear(line, lp, xn, state):
         kc, vc, st, cs = state
         mixed, z, g, beta = qwen3_next.linear_inputs(cfg, lp, xn)
-        with tracing.part("linear_state"):
-            # The slot's window and state, or zeros at a prompt's start.
-            prior = jnp.where(kv_len > 0, lax.dynamic_slice(
-                cs, (line, slot, 0), (1, 1, keep * cfg.conv_dim)), 0)
-            s0 = jnp.where(kv_len > 0, lax.dynamic_slice(
-                st, (line, slot, 0, 0, 0), (1, 1, *st.shape[2:])), 0.0)
+        prior, s0 = linear_state.chunk_start(st, cs, line, slot, kv_len)
         window = qwen3_next.conv_window(
             prior.reshape(1, keep, cfg.conv_dim), mixed)
         q, k, v = qwen3_next.linear_key_heads(cfg, lp, window, c)
@@ -135,13 +127,8 @@ def prefill_chunk(cfg: Qwen3NextConfig, params, cache, tokens, kv_len,
             o, s1 = gated_delta_chunk(
                 q[0], k[0], v[0], jnp.where(valid[0, :, None], g[0], 0.0),
                 jnp.where(valid[0, :, None], beta[0], 0.0), s0[0, 0])
-        with tracing.part("linear_state"):
-            st = lax.dynamic_update_slice(st, s1[None, None],
-                                          (line, slot, 0, 0, 0))
-            # The window's rows that end at the last valid token.
-            last = lax.dynamic_slice_in_dim(window, n_valid, keep, axis=1)
-            cs = lax.dynamic_update_slice(
-                cs, last.astype(cs.dtype).reshape(1, 1, -1), (line, slot, 0))
+        st, cs = linear_state.chunk_end(st, cs, s1, window, line, slot,
+                                        n_valid)
         return (qwen3_next.linear_output(cfg, lp, o[None], z, xn.dtype),
                 (kc, vc, st, cs))
 
@@ -173,7 +160,6 @@ def _decode_impl(cfg: Qwen3NextConfig, params, cache, tokens, positions0,
     keeps its state and its window, is routed nowhere, and its logits mean
     nothing."""
     b = tokens.shape[0]
-    keep = cfg.linear_conv_kernel_dim - 1
     with tracing.part("embed"):
         x = params["embed_tokens"][tokens][:, None]           # [B, 1, H]
     with tracing.part("attn"):
@@ -188,8 +174,7 @@ def _decode_impl(cfg: Qwen3NextConfig, params, cache, tokens, positions0,
     def linear(line, lp, xn, state):
         kc, vc, st, cs = state
         mixed, z, g, beta = qwen3_next.linear_inputs(cfg, lp, xn)
-        with tracing.part("linear_state"):
-            prior = layer_of(cs, line).reshape(b, keep, cfg.conv_dim)
+        prior = linear_state.step_start(cs, line, cfg.conv_dim)
         window = qwen3_next.conv_window(prior, mixed)
         q, k, v = qwen3_next.linear_heads(cfg, lp, window, 1)
         with tracing.part("linear_attn"), tracing.part("delta_rule"):
@@ -198,11 +183,8 @@ def _decode_impl(cfg: Qwen3NextConfig, params, cache, tokens, positions0,
             o, s1 = gated_delta_step(
                 q[:, 0], k[:, 0], v[:, 0], jnp.where(valid, g[:, 0], 0.0),
                 jnp.where(valid, beta[:, 0], 0.0), layer_of(st, line))
-        with tracing.part("linear_state"):
-            st = lax.dynamic_update_index_in_dim(st, s1, line, 0)
-            new = jnp.where(write_mask[:, None, None], window[:, 1:], prior)
-            cs = lax.dynamic_update_index_in_dim(
-                cs, new.astype(cs.dtype).reshape(b, -1), line, 0)
+        st, cs = linear_state.step_end(st, cs, s1, window, prior, line,
+                                       write_mask)
         return (qwen3_next.linear_output(cfg, lp, o[:, None], z, xn.dtype),
                 (kc, vc, st, cs))
 

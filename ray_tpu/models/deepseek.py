@@ -111,6 +111,7 @@ class DeepseekV2Config:
     # models/mla.mla_project's two norm factors: this family has none.
     mla_scale_q_lora: ClassVar[bool] = False
     mla_scale_kv_lora: ClassVar[bool] = False
+    mla_rope_interleaved: ClassVar[bool] = True
 
     def __post_init__(self):
         self.router_rule  # refuses shares and groups that do not divide

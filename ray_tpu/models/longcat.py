@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from typing import ClassVar
 
 import jax
 import jax.numpy as jnp
@@ -91,6 +92,9 @@ class LongcatConfig:
     # * held). One shard holds them all.
     expert_shard: int = 0
     expert_shards: int = 1
+
+    # models/mla.mla_project's rotary turns adjacent pairs.
+    mla_rope_interleaved: ClassVar[bool] = True
 
     def __post_init__(self):
         self.router_rule  # refuses a share the experts do not divide into

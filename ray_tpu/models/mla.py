@@ -1,11 +1,15 @@
-"""Latent (MLA) attention as models/longcat.py and models/deepseek.py both
-have it: the projections down to the latent row and out to the heads'
+"""Latent (MLA) attention as models/longcat.py, models/deepseek.py and
+models/ling.py have it: the projections down to the latent row and out to the heads'
 queries, the up-projections of the cached rows, and the attention over whole
 sequences that the two ``forward``s run. Per position a model caches
 ``c_kv`` (after its norm and scale) and the rotated shared key ``k_r``,
 ``kv_lora_rank + qk_rope_head_dim`` values for all heads; the serving
-programs attend against those rows (llm/latent.py). ``cfg`` is either
-model's configuration.
+programs attend against those rows (llm/latent.py). ``cfg`` is any of
+the models' configurations: it says whether the queries come through a
+low-rank pair (``q_lora_rank``; None: one matrix ``wq``, no ``wq_a``,
+``q_a_norm``, ``wq_b``) and which pairs its rotary turns
+(``mla_rope_interleaved``: adjacent pairs where true, a half against the
+other where false).
 """
 
 from __future__ import annotations
@@ -17,7 +21,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.ops.norms import rms_norm
-from ray_tpu.ops.rope import apply_rope_interleaved, rope_frequencies
+from ray_tpu.ops.rope import (
+    apply_rope,
+    apply_rope_interleaved,
+    rope_frequencies,
+)
 
 
 def mla_scales(cfg) -> tuple[float, float]:
@@ -42,8 +50,11 @@ def mla_project(cfg, ap: dict, xn, positions, kmesh=None,
     b, s, _ = xn.shape
     sq, skv = mla_scales(cfg)
     dt = xn.dtype
-    cq = rms_norm(xn @ ap["wq_a"], ap["q_a_norm"], cfg.norm_eps, kmesh)
-    q = (cq * sq).astype(dt) @ ap["wq_b"]
+    if cfg.q_lora_rank is None:
+        q = xn @ ap["wq"]
+    else:
+        cq = rms_norm(xn @ ap["wq_a"], ap["q_a_norm"], cfg.norm_eps, kmesh)
+        q = (cq * sq).astype(dt) @ ap["wq_b"]
     if keep_product:
         q = lax.optimization_barrier(q)
     q = q.reshape(b, s, cfg.num_heads, cfg.qk_head_dim)
@@ -54,10 +65,10 @@ def mla_project(cfg, ap: dict, xn, positions, kmesh=None,
     ckv = (ckv * skv).astype(dt)
     inv_freq = rope_frequencies(cfg.qk_rope_head_dim, cfg.rope_theta,
                                 cfg.rope_scaling)
-    q_r = apply_rope_interleaved(q_r.transpose(0, 2, 1, 3), positions,
-                                 inv_freq).transpose(0, 2, 1, 3)
-    k_r = apply_rope_interleaved(kv[:, None, :, cfg.kv_lora_rank:],
-                                 positions, inv_freq)[:, 0]
+    rope = apply_rope_interleaved if cfg.mla_rope_interleaved else apply_rope
+    q_r = rope(q_r.transpose(0, 2, 1, 3), positions,
+               inv_freq).transpose(0, 2, 1, 3)
+    k_r = rope(kv[:, None, :, cfg.kv_lora_rank:], positions, inv_freq)[:, 0]
     pad = jnp.zeros((b, s, cfg.latent_row - cfg.latent_dim), dt)
     return q_n, q_r, jnp.concatenate([ckv, k_r, pad], axis=-1)
 
@@ -71,12 +82,13 @@ def kv_up_projections(cfg, wkv_b):
     return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
 
 
-def mla_full(cfg, ap: dict, xn, kmesh=None,
-             up_projections=kv_up_projections):
+def mla_attend_full(cfg, ap: dict, xn, kmesh=None,
+                    up_projections=kv_up_projections):
     """Causal latent attention over whole sequences, keys and values
-    up-projected (no cache). xn: [B, S, H] -> [B, S, H].
-    ``up_projections(cfg, wkv_b)`` gives the two halves [rank, nh, D] of a
-    model's ``wkv_b`` (models/deepseek.py stores its a head at a time)."""
+    up-projected (no cache), before the output projection. xn: [B, S, H]
+    -> [B, S, nh * Dv]. ``up_projections(cfg, wkv_b)`` gives the two halves
+    [rank, nh, D] of a model's ``wkv_b`` (models/deepseek.py stores its a
+    head at a time)."""
     b, s, _ = xn.shape
     q_n, q_r, rows = mla_project(cfg, ap, xn, jnp.arange(s), kmesh)
     w_kb, w_vb = up_projections(cfg, ap["wkv_b"])
@@ -91,5 +103,11 @@ def mla_full(cfg, ap: dict, xn, kmesh=None,
     causal = jnp.tril(jnp.ones((s, s), bool))
     scores = jnp.where(causal, scores * cfg.sm_scale, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(xn.dtype)
-    o = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(b, s, -1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(b, s, -1)
+
+
+def mla_full(cfg, ap: dict, xn, kmesh=None,
+             up_projections=kv_up_projections):
+    """:func:`mla_attend_full` through ``wo``: xn [B, S, H] -> [B, S, H]."""
+    o = mla_attend_full(cfg, ap, xn, kmesh, up_projections)
     return (o @ ap["wo"]).astype(xn.dtype)

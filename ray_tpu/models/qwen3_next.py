@@ -378,6 +378,22 @@ def conv_window(prior, mixed):
         return jnp.concatenate([prior.astype(mixed.dtype), mixed], axis=1)
 
 
+def short_conv_silu(conv_w, window, s: int):
+    """A depthwise causal convolution, then ``silu``: ``conv_w`` [taps,
+    channels] over ``window`` [B, taps - 1 + S, channels] at its last ``s``
+    positions, float32 (models/ling.py's too)."""
+    taps = conv_w.astype(jnp.float32)
+    return jax.nn.silu(sum(
+        taps[j] * window[:, j:j + s].astype(jnp.float32)
+        for j in range(taps.shape[0])))
+
+
+def unit_heads(x, heads: int):
+    """x [B, S, heads * D] -> [B, S, heads, D], L2-normalised a head."""
+    x = x.reshape(*x.shape[:2], heads, -1)
+    return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+
+
 def linear_key_heads(cfg: Qwen3NextConfig, lp: dict, window, s: int):
     """The depthwise causal convolution over ``window`` [B, taps - 1 + S,
     conv_dim] at its last ``s`` positions, ``silu``, and the split into
@@ -386,19 +402,10 @@ def linear_key_heads(cfg: Qwen3NextConfig, lp: dict, window, s: int):
     key heads`` value heads in a row."""
     with tracing.part("linear_attn"):
         b = window.shape[0]
-        taps = lp["conv_w"].astype(jnp.float32)          # [taps, conv_dim]
-        mixed = jax.nn.silu(sum(
-            taps[j] * window[:, j:j + s].astype(jnp.float32)
-            for j in range(cfg.linear_conv_kernel_dim)))
+        mixed = short_conv_silu(lp["conv_w"], window, s)
         q, k, v = jnp.split(mixed, (cfg.key_dim, 2 * cfg.key_dim), axis=-1)
         nk, dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
-
-        def unit(x):
-            x = x.reshape(b, s, nk, dk)
-            return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
-                                 + L2_EPS)
-
-        return (unit(q) * dk ** -0.5, unit(k),
+        return (unit_heads(q, nk) * dk ** -0.5, unit_heads(k, nk),
                 v.reshape(b, s, cfg.linear_num_value_heads,
                           cfg.linear_value_head_dim))
 

@@ -7,9 +7,12 @@ over all outputs, or a sigmoid of each), whether a selection bias is added
 for the choice (never for the weights), whether the chosen weights are
 renormalised to sum to one, the factor they are scaled by, whether the
 choice is limited to the best groups of experts (``groups`` consecutive
-groups of equal size, of which the ``topk_groups`` with the largest single
-score are kept: a token's experts then lie on at most that many devices of
-a deployment that holds a group a device), how many of the
+groups of equal size, of which the ``topk_groups`` best are kept: a token's
+experts then lie on at most that many devices of a deployment that holds a
+group a device; a group scores as its largest score where the rule has no
+bias (DeepSeek-V2's), and as the sum of its two largest ``score + bias``
+where it has one (the only form any family has for the two together:
+DeepSeek-V3's, Ling's), how many of the
 router's outputs are zero-compute experts (the identity: a weighted add of
 the layer's input), and which of the routed experts this program holds
 (``expert_shard`` of ``expert_shards`` equal shares).
@@ -79,11 +82,11 @@ class RouterRule:
                 raise ValueError(
                     f"{self.experts} routed experts (+ {self.zero_experts} "
                     f"zero experts) do not divide into {self.groups} groups")
-            if self.use_bias:
+            if self.use_bias and self.experts // self.groups < 2:
                 raise ValueError(
-                    "a grouped rule scores a group by its best expert's "
-                    "score and masks the others' to 0: it has no form for "
-                    "a selection bias, which may be negative")
+                    "a grouped rule with a selection bias scores a group "
+                    "by its two best experts: groups of "
+                    f"{self.experts // self.groups} have none")
             if not (1 <= self.topk_groups <= self.groups and self.topk
                     <= self.topk_groups * (self.experts // self.groups)):
                 raise ValueError(
@@ -129,16 +132,25 @@ def route(rule: RouterRule, router, bias, u):
     else:
         p = jax.nn.sigmoid(logits)
     choice = p + bias if rule.use_bias else p
-    if rule.groups > 1:
+    if rule.groups > 1 and rule.use_bias:
+        # A group's score is the sum of its two largest ``score + bias``;
+        # outside the kept groups a pick never falls: a bias may be
+        # negative, so no finite number is under every ``score + bias``.
+        best = lax.top_k(choice.reshape(
+            -1, rule.groups, rule.experts // rule.groups), 2)[0].sum(axis=-1)
+        outside = -jnp.inf
+    elif rule.groups > 1:
         # A group's score is its best expert's; outside the kept groups a
         # score is 0, under every score a softmax or a sigmoid gives.
         best = choice.reshape(-1, rule.groups,
                               rule.experts // rule.groups).max(axis=-1)
+        outside = 0.0
+    if rule.groups > 1:
         _, kept = lax.top_k(best, rule.topk_groups)
         keep = jnp.any(kept[:, :, None] == jnp.arange(rule.groups), axis=1)
         choice = jnp.where(
             jnp.repeat(keep, rule.experts // rule.groups, axis=1), choice,
-            0.0)
+            outside)
     _, idx = lax.top_k(choice, rule.topk)
     w = jnp.take_along_axis(p, idx, axis=-1)
     if rule.renormalize:

@@ -54,6 +54,28 @@ A position with ``g = 0`` and ``beta = 0`` changes no state: a caller marks
 so the rows of a padded chunk past the prompt's end and the slots of a step
 that do not decode.
 
+**A decay a key channel** (Kimi Delta Attention). ``g`` is ``[T, H]`` as
+above or ``[T, H, Dk]``: ``S' = Diag(exp(g_t)) S_{t-1}``, a number a row of
+the state. That is the general rule and the scalar one its case; every form
+takes either. In the chunked form ``G_i`` is then a vector and the system's
+matrix ``A_ij = beta_i sum_c k_ic k_jc exp(G_ic - G_jc)``, a product of two
+matrices only where the exponent is split into a factor on row ``i`` and a
+factor on row ``j``, and ``exp(-G_j)`` alone overflows float32 after 18
+tokens of a decay of -5. So rows are paired about a sum between theirs
+(:func:`_channel_pairs`): inside blocks of ``BLOCK`` (16) positions about
+``M``, the mean of the block's first and last running sums, ``exp(G_i - M)
+exp(M - G_j)``, both exponents within ``15 |floor| / 2`` of 0; a row with a
+row of an earlier block about the later block's ``M``, the second exponent
+at most 0. The caller states the floor of its gate (``g_floor``: a token's
+``g`` is never under it) and the chunked form refuses, at trace time, a
+floor at which a block's whole decay ``exp(BLOCK |floor|)`` would not fit
+float32, or no floor at all: nothing is clamped, floored or dropped. The
+other exponents
+(``G_i``, ``G_C - G_j``) are at most 0 as they were. On a TPU the chunked
+form is a kernel of its own (:func:`_chunk_kernel_channel`: the scalar
+kernel's grid, system, inverse and walk; the pairs a block at a time),
+where a key head is a value head; the jnp body otherwise.
+
 All three compute in float32 whatever they are given, and their products at
 ``PRECISION``, true float32: a TPU's default float32 product is one bfloat16
 pass, which left the chunk form 8e-4 off the recurrence on outputs and 4e-3
@@ -73,6 +95,11 @@ from ray_tpu.ops.kernels import kernel_backend
 # Positions of a sub-chunk: the triangular system's size. 64 is the
 # published kernels' choice and half an MXU's side.
 SUB = 64
+# Positions of a block inside a sub-chunk, for a decay a key channel: rows of
+# one block are paired about the middle of its decay, and the block's whole
+# decay ``exp(BLOCK |g_floor|)`` is held to float32's range, ``exp(88.7)``.
+BLOCK = 16
+F32_MAX_EXPONENT = 88.0
 PRECISION = lax.Precision.HIGHEST
 F32 = jnp.float32
 
@@ -92,13 +119,17 @@ def _a_value_head(q, k, heads: int):
 
 def gated_delta_recurrence(q, k, v, g, beta, state):
     """The rule, token by token. q, k: [T, Hk, Dk], a key head for H // Hk
-    value heads in a row; v: [T, H, Dv]; g, beta: [T, H]; state: [H, Dk, Dv]
-    float32. Returns (o [T, H, Dv] float32, state)."""
+    value heads in a row; v: [T, H, Dv]; beta: [T, H]; g: [T, H], or
+    [T, H, Dk] for a decay a key channel; state: [H, Dk, Dv] float32.
+    Returns (o [T, H, Dv] float32, state)."""
     q, k = _a_value_head(q, k, v.shape[1])
 
     def token(s, row):
         q_t, k_t, v_t, g_t, b_t = row
-        s = jnp.exp(g_t)[:, None, None] * s
+        if g_t.ndim == 2:                     # a row of the state its own
+            s = jnp.exp(g_t)[:, :, None] * s
+        else:
+            s = jnp.exp(g_t)[:, None, None] * s
         d = b_t[:, None] * (v_t - jnp.einsum(
             "hk,hkv->hv", k_t, s, precision=PRECISION))
         s = s + k_t[:, :, None] * d[:, None, :]
@@ -141,11 +172,67 @@ def unit_lower_inverse(a):
     return inv.reshape(lead + (n, n))
 
 
-def gated_delta_chunk_reference(q, k, v, g, beta, state):
+def _require_floor(g_floor) -> None:
+    """A decay a channel is chunked only under the caller's stated floor."""
+    if g_floor is None:
+        raise ValueError(
+            "gated_delta_chunk: a decay a key channel needs g_floor, the "
+            "floor of the caller's gate: the chunked form pairs rows inside "
+            f"blocks of {BLOCK} and exp({BLOCK} |g_floor|) must fit "
+            "float32; nothing is clamped here")
+    if not BLOCK * abs(g_floor) < F32_MAX_EXPONENT:
+        raise ValueError(
+            f"gated_delta_chunk: g_floor {g_floor} over a block of {BLOCK} "
+            f"positions gives a factor of exp({BLOCK * abs(g_floor)}), and "
+            f"float32 holds exp({F32_MAX_EXPONENT})")
+
+
+def _channel_pairs(q, k, big):
+    """For a decay a key channel: ``sum_c k_ic k_jc exp(G_ic - G_jc)`` and
+    the same of ``q_i``, [..., SUB, SUB] each, sound where ``j <= i`` (above
+    the diagonal a finite number the caller masks). q, k, big: [..., SUB,
+    Dk]. A block of ``BLOCK`` rows is one product against every row up to
+    its own end, about ``M``, the mean of the block's first and last
+    running sums, a channel: its rows carry ``exp(G_i - M)`` and the rows
+    ``j`` it is paired with ``exp(M - G_j)``, which multiply to ``exp(G_i -
+    G_j)`` under the sum over channels. Inside the block both exponents lie
+    within ``(BLOCK - 1) |g_floor| / 2`` of 0 (37.5 at a floor of -5); a row
+    of an earlier block has ``M - G_j <= 0``, and where that underflows the
+    pair's true weight is under ``exp(-49)``. About the block's first row
+    the factors would reach ``exp(75)`` and ``exp(-75)``: float32 holds
+    them, but a TPU's true-float32 product splits a factor in three
+    bfloat16 pieces and the third of ``exp(-75)`` is a denormal; about the
+    middle no piece of any factor is.
+    A row past the block's end is given exponent 0."""
+    nb = SUB // BLOCK
+    lead = big.shape[:-2]
+
+    def blocks(x):
+        return x.reshape(lead + (nb, BLOCK, x.shape[-1]))
+
+    ends = blocks(big)
+    mid = 0.5 * (ends[..., :1, :] + ends[..., -1:, :])   # M, a block
+    left = jnp.exp(ends - mid)
+    seen = jnp.arange(SUB)[None, :] < (jnp.arange(nb)[:, None] + 1) * BLOCK
+    right = jnp.exp(jnp.where(seen[..., None],
+                              mid - big[..., None, :, :], 0.0)) \
+        * k[..., None, :, :]                           # [..., nb, SUB, Dk]
+    both = jnp.concatenate([blocks(k) * left, blocks(q) * left], axis=-2)
+    pairs = _mm(both, jnp.swapaxes(right, -1, -2))     # [.., nb, 2 BLOCK, SUB]
+    return (pairs[..., :BLOCK, :].reshape(lead + (SUB, SUB)),
+            pairs[..., BLOCK:, :].reshape(lead + (SUB, SUB)))
+
+
+def gated_delta_chunk_reference(q, k, v, g, beta, state, *, g_floor=None):
     """:func:`gated_delta_chunk` in plain jnp: what runs off a TPU and what
-    the kernel is held to. ``T = (I + A)^-1`` does not depend on the state,
-    so ``U = T (beta V)`` and ``W = T (beta exp(G) K)`` are made for every
-    sub-chunk at once and ``D = U - W S_0`` under the scan."""
+    the kernels are held to, for a decay a head and for a decay a key
+    channel (``g`` [T, H, Dk], under ``g_floor``). ``T = (I + A)^-1`` does not
+    depend on the state, so ``U = T (beta V)`` and ``W = T (beta exp(G) K)``
+    are made for every sub-chunk at once and ``D = U - W S_0`` under the
+    scan."""
+    channel = g.ndim == 3
+    if channel:
+        _require_floor(g_floor)
     q, k = _a_value_head(q, k, v.shape[1])
     t, h, _ = q.shape
     pad = -t % SUB
@@ -157,30 +244,45 @@ def gated_delta_chunk_reference(q, k, v, g, beta, state):
         return jnp.moveaxis(a, 2, 0)              # [H, n, SUB, ...]
 
     q, k, v, g, beta = (heads_first(a) for a in (q, k, v, g, beta))
-    big = jnp.cumsum(g, axis=-1)                              # G_i
+    big = jnp.cumsum(g, axis=2)                               # G_i
     rows = jnp.arange(SUB)
     upto = rows[:, None] >= rows[None, :]                     # j <= i
-    # exp(G_i - G_j) where j <= i: every exponent at most 0.
-    decay = jnp.where(upto, jnp.exp(jnp.where(
-        upto, big[..., :, None] - big[..., None, :], 0.0)), 0.0)
-    kt = jnp.swapaxes(k, -1, -2)
-    a = jnp.where(rows[:, None] > rows[None, :],
-                  beta[..., None] * decay * _mm(k, kt), 0.0)
-    grow = jnp.exp(big)[..., None]                            # exp(G_i)
+    if channel:
+        kk, qk = _channel_pairs(q, k, big)
+        a = jnp.where(rows[:, None] > rows[None, :],
+                      beta[..., None] * kk, 0.0)
+        grow = jnp.exp(big)                                   # exp(G_i)
+    else:
+        # exp(G_i - G_j) where j <= i: every exponent at most 0.
+        decay = jnp.where(upto, jnp.exp(jnp.where(
+            upto, big[..., :, None] - big[..., None, :], 0.0)), 0.0)
+        kt = jnp.swapaxes(k, -1, -2)
+        a = jnp.where(rows[:, None] > rows[None, :],
+                      beta[..., None] * decay * _mm(k, kt), 0.0)
+        grow = jnp.exp(big)[..., None]                        # exp(G_i)
     rhs_v, rhs_k = beta[..., None] * v, beta[..., None] * grow * k
     inv = unit_lower_inverse(a)
     u, w = _mm(inv, rhs_v), _mm(inv, rhs_k)
-    within = decay * _mm(q, kt)                   # zero above the diagonal
+    # within: zero above the diagonal; k_out: exp(G_C - G_j) K, transposed
+    # for the state's update; whole: exp(G_C), a number a head or a row of
+    # the state its own (``lift`` lays it along the state).
+    within = jnp.where(upto, qk, 0.0) if channel else decay * _mm(q, kt)
     q_in = grow * q
-    # exp(G_C - G_j) K, transposed for the state's update, and exp(G_C).
-    k_out = jnp.swapaxes(jnp.exp(big[..., -1:] - big)[..., None] * k, -1, -2)
-    whole = jnp.exp(big[..., -1])
+    if channel:
+        k_out = jnp.swapaxes(jnp.exp(big[..., -1:, :] - big) * k, -1, -2)
+        whole = jnp.exp(big[..., -1, :])
+        lift = (slice(None), slice(None), None)
+    else:
+        k_out = jnp.swapaxes(
+            jnp.exp(big[..., -1:] - big)[..., None] * k, -1, -2)
+        whole = jnp.exp(big[..., -1])
+        lift = (slice(None), None, None)
 
     def sub_chunk(s, xs):
         u_n, w_n, within_n, q_n, k_n, whole_n = xs
         d = u_n - _mm(w_n, s)
         o = _mm(q_n, s) + _mm(within_n, d)
-        return whole_n[:, None, None] * s + _mm(k_n, d), o
+        return whole_n[lift] * s + _mm(k_n, d), o
 
     state, o = lax.scan(
         sub_chunk, state.astype(F32),
@@ -334,6 +436,101 @@ def _chunk_kernel(q_ref, k_ref, v_ref, big_ref, beta_ref, s0_ref, o_ref,
             (((0,), (0,)), ((), ())))
 
 
+def _chunk_kernel_channel(q_ref, k_ref, v_ref, big_ref, beta_ref, s0_ref,
+                          o_ref, s_ref, *, heads: int, dk: int, dv: int):
+    """:func:`_chunk_kernel` for a decay a key channel: one sub-chunk of
+    ``heads`` heads, two by two, a key head a value head. q_ref, k_ref and
+    big_ref (the running sums ``G_i``, a number a row and key channel)
+    [SUB, heads * dk]; v_ref, o_ref [SUB, heads * dv]; beta_ref [SUB,
+    heads]; s0_ref and s_ref [heads, dk, dv], the state in VMEM from the
+    first sub-chunk to the last.
+
+    What the scalar kernel gets from one product and a matrix of decays,
+    ``exp(G_i - G_j) (k_i . k_j)``, is here :func:`_channel_pairs`'s: a
+    block of ``BLOCK`` rows at a time, its rows carrying ``exp(G_i - M)``
+    and the rows it meets ``exp(M - G_j)`` about the middle ``M`` of the
+    block's decay, two heads a product (``[K_0; Q_0; K_1; Q_1]`` of a block
+    against ``[K_0; K_1]`` of the sub-chunk, a head's own in its lanes). The
+    system, its inverse and the walk are the scalar kernel's; the state's
+    decay is a number a row, ``exp(G_C)`` turned from a row of lanes into a
+    column by a sum over a diagonal."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        s_ref[...] = s0_ref[...]
+
+    rows = lax.broadcasted_iota(jnp.int32, (SUB, 2 * SUB), 0)
+    lanes = lax.broadcasted_iota(jnp.int32, (SUB, 2 * SUB), 1)
+    right = lanes >= SUB                                # the second head
+    cols = lanes & (SUB - 1)
+    upto = rows >= cols                                       # j <= i
+    block_right = lax.broadcasted_iota(
+        jnp.int32, (BLOCK, 2 * SUB), 1) >= SUB
+    row_of = lax.broadcasted_iota(jnp.int32, (SUB, dk), 0)
+    diagonal = (lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+                == lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
+    pairs = [(h, h + 1) for h in range(0, heads, 2)]
+
+    def head(ref, h, d):
+        return ref[:, h * d:(h + 1) * d].astype(F32)
+
+    def both(ref, pair):
+        """A column a head, [SUB, 1], over its head's lanes."""
+        return jnp.where(right, ref[:, pair[1]:pair[1] + 1],
+                         ref[:, pair[0]:pair[0] + 1])
+
+    k = {h: head(k_ref, h, dk) for h in range(heads)}
+    q = {h: head(q_ref, h, dk) for h in range(heads)}
+    big = {h: head(big_ref, h, dk) for h in range(heads)}
+    grow = {h: jnp.exp(big[h]) for h in k}                  # exp(G_i)
+    a, within = {}, {}
+    for pair in pairs:
+        kk, qk = [], []
+        for lo in range(0, SUB, BLOCK):
+            hi = lo + BLOCK
+            mine, met = [], []
+            for h in pair:
+                ends = big[h][lo:hi]
+                mid = 0.5 * (ends[:1] + ends[BLOCK - 1:])   # M, a channel
+                left = jnp.exp(ends - mid)
+                mine += [k[h][lo:hi] * left, q[h][lo:hi] * left]
+                met.append(jnp.exp(jnp.where(row_of < hi, mid - big[h], 0.0))
+                           * k[h])
+            kq = _dot(jnp.concatenate(mine, axis=0),
+                      jnp.concatenate(met, axis=0), (((1,), (1,)), ((), ())))
+            kk.append(jnp.where(block_right, kq[2 * BLOCK:3 * BLOCK],
+                                kq[:BLOCK]))
+            qk.append(jnp.where(block_right, kq[3 * BLOCK:],
+                                kq[BLOCK:2 * BLOCK]))
+        a[pair] = jnp.where(rows > cols, both(beta_ref, pair)
+                            * jnp.concatenate(kk, axis=0), 0.0)
+        within[pair] = jnp.where(upto, jnp.concatenate(qk, axis=0), 0.0)
+    inv = _pair_inverses([a[pair] for pair in pairs], rows, cols, right)
+    # [exp(G) K; exp(G) Q] S_0, a head
+    ks = {h: _dot(jnp.concatenate([grow[h] * k[h], grow[h] * q[h]], axis=0),
+                  s_ref[h]) for h in k}
+    # (I + A) D = beta (V - (exp(G) K) S_0)
+    rhs = {h: beta_ref[:, h:h + 1] * (head(v_ref, h, dv) - ks[h][:SUB])
+           for h in k}
+    d = {}
+    for pair, t in zip(pairs, inv):
+        both_d = _dot(t, _apart(rhs[pair[0]], rhs[pair[1]]))
+        d[pair[0]], d[pair[1]] = both_d[:, :dv], both_d[:, dv:]
+    for pair in pairs:
+        h0, h1 = pair
+        inside = _dot(within[pair], _apart(d[h0], d[h1]))
+        o_ref[:, h0 * dv:(h0 + 1) * dv] = ks[h0][SUB:] + inside[:, :dv]
+        o_ref[:, h1 * dv:(h1 + 1) * dv] = ks[h1][SUB:] + inside[:, dv:]
+    for h in k:
+        last = big[h][SUB - 1:]                             # G_C, [1, dk]
+        # exp(G_C) down the state's rows: the row of lanes as a column.
+        whole = jnp.sum(jnp.where(diagonal, jnp.exp(last), 0.0), axis=1,
+                        keepdims=True)
+        s_ref[h] = whole * s_ref[h] + _dot(
+            jnp.exp(last - big[h]) * k[h], d[h], (((0,), (0,)), ((), ())))
+
+
 def _heads_a_step(h: int) -> int:
     """Value heads a grid step: the products of a step run one after
     another, and a pair's next one waits for the last unless other pairs'
@@ -360,15 +557,23 @@ def _chunk_pallas(q, k, v, g, beta, state):
     def a_step(a):                                            # [T, H] ->
         return jnp.moveaxis(a.reshape(t + pad, h // hs, hs), 1, 0)
 
-    big = jnp.cumsum(rows(g.astype(F32)).reshape(n, SUB, h), axis=1)
+    big = jnp.cumsum(rows(g.astype(F32)).reshape(n, SUB, -1), axis=1)
     wide = lambda d: pl.BlockSpec((SUB, d), lambda i, j: (j, i))  # noqa: E731
     narrow = pl.BlockSpec((None, SUB, hs), lambda i, j: (i, j, 0))
     held = pl.BlockSpec((hs, dk, dv), lambda i, j: (i, 0, 0))
+    if g.ndim == 3:              # a decay a key channel: sums as wide as k
+        kernel = functools.partial(_chunk_kernel_channel, heads=hs, dk=dk,
+                                   dv=dv)
+        sums, laid = wide(hs * dk), lambda b: b.reshape(t + pad, h * dk)
+    else:
+        kernel = functools.partial(_chunk_kernel, heads=hs, rep=rep, dk=dk,
+                                   dv=dv)
+        sums, laid = narrow, a_step
     o, state = pl.pallas_call(
-        functools.partial(_chunk_kernel, heads=hs, rep=rep, dk=dk, dv=dv),
+        kernel,
         grid=(h // hs, n),
         in_specs=[wide(hs // rep * dk), wide(hs // rep * dk), wide(hs * dv),
-                  narrow, narrow, held],
+                  sums, narrow, held],
         out_specs=[wide(hs * dv), held],
         out_shape=[jax.ShapeDtypeStruct((t + pad, h * dv), F32),
                    jax.ShapeDtypeStruct((h, dk, dv), F32)],
@@ -376,14 +581,16 @@ def _chunk_pallas(q, k, v, g, beta, state):
             dimension_semantics=("parallel", "arbitrary")),
         interpret=kernel_backend() == "interpret",
         name="gated_delta_chunk",
-    )(rows(q), rows(k), rows(v), a_step(big), a_step(rows(beta.astype(F32))),
-      state.astype(F32))
+    )(rows(q), rows(k), rows(v), laid(big),
+      a_step(rows(beta.astype(F32))), state.astype(F32))
     return o[:t].reshape(t, h, dv), state
 
 
-def gated_delta_chunk(q, k, v, g, beta, state):
+def gated_delta_chunk(q, k, v, g, beta, state, *, g_floor=None):
     """A run of positions of one sequence, chunked. q, k: [T, Hk, Dk], a key
-    head for H // Hk value heads in a row; v: [T, H, Dv]; g, beta: [T, H];
+    head for H // Hk value heads in a row; v: [T, H, Dv]; beta: [T, H]; g:
+    [T, H], or [T, H, Dk] for a decay a key channel, which needs
+    ``g_floor``, the floor of the caller's gate (no ``g`` is under it);
     state: [H, Dk, Dv] float32, the state before the first position. Returns
     (o [T, H, Dv] float32, the state after the last position). T is any
     length: the run is padded to whole sub-chunks with positions that change
@@ -391,23 +598,38 @@ def gated_delta_chunk(q, k, v, g, beta, state):
 
     The implementation is ``ops/kernels.kernel_backend()``'s: on a TPU the
     kernel, where the operands' shapes are its own (a head's keys and values
-    whole 128-lane columns, value heads two by two, a step's heads whole key
-    heads); :func:`gated_delta_chunk_reference` otherwise."""
+    whole 128-lane columns, value heads two by two, a step's heads whole
+    key heads; with a decay a channel, a key head a value head);
+    :func:`gated_delta_chunk_reference` otherwise."""
     h, rep = v.shape[1], v.shape[1] // q.shape[1]
     if kernel_backend() == "reference" or q.shape[-1] % 128 \
-            or v.shape[-1] % 128 or h % 2 or _heads_a_step(h) % rep:
-        return gated_delta_chunk_reference(q, k, v, g, beta, state)
+            or v.shape[-1] % 128 or h % 2 or _heads_a_step(h) % rep \
+            or (g.ndim == 3 and rep != 1):
+        return gated_delta_chunk_reference(q, k, v, g, beta, state,
+                                           g_floor=g_floor)
+    if g.ndim == 3:
+        _require_floor(g_floor)
     return _chunk_pallas(q, k, v, g, beta, state)
 
 
 def gated_delta_step(q, k, v, g, beta, state):
-    """One position of every slot. q, k: [B, H, Dk]; v: [B, H, Dv]; g,
-    beta: [B, H]; state: [B, H, Dk, Dv] float32. Returns (o [B, H, Dv]
-    float32, state). Sums over ``Dk`` and not products of ``[1, Dk]`` by
-    ``[Dk, Dv]``: a matrix unit would load every state as its weights for
-    one row."""
+    """One position of every slot. q, k: [B, H, Dk]; v: [B, H, Dv]; beta:
+    [B, H]; g: [B, H], or [B, H, Dk] for a decay a key channel (a row of the
+    state scaled by its own number, where the scalar scales the whole
+    state); state: [B, H, Dk, Dv] float32. Returns (o [B, H, Dv] float32,
+    state). Sums over ``Dk`` and not products of ``[1, Dk]`` by ``[Dk,
+    Dv]``: a matrix unit would load every state as its weights for one
+    row."""
     q, k, v, g, beta = (a.astype(F32) for a in (q, k, v, g, beta))
     decay = jnp.exp(g)[..., None]
+    if g.ndim == k.ndim:
+        # ``S'^T k = S^T (exp(g) k)``: the decayed state is read through
+        # decayed keys and queries, in the one pass, and written once.
+        sk = jnp.sum(state * (decay * k[..., None]), axis=-2)  # S'^T k
+        sq = jnp.sum(state * (decay * q[..., None]), axis=-2)  # S'^T q
+        d = beta[..., None] * (v - sk)
+        o = sq + jnp.sum(k * q, axis=-1, keepdims=True) * d
+        return o, decay * state + k[..., None] * d[..., None, :]
     # One pass over the state for both reads.
     sk = jnp.sum(state * k[..., None], axis=-2)               # S^T k
     sq = jnp.sum(state * q[..., None], axis=-2)               # S^T q

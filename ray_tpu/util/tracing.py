@@ -723,6 +723,11 @@ SUBPARTS = (
                      # form of a prefill chunk or a decode step's one token
     "linear_state",  # reading and writing that operator's state and its
                      # convolution's window
+    "kda_rule",      # inside ``linear_attn``, the delta rule with a decay a
+                     # key channel alone (Kimi Delta Attention): the chunk
+                     # and step forms and nothing else
+    "kda_gate",      # inside ``linear_attn``, that decay's projection and
+                     # its bounded sigmoid
     "ssm",           # a selective-scan operator inside ``attn``: its
                      # projections, the convolution, the step and the gate
     "ssm_scan",      # inside it, the selective scan alone: the chunk form

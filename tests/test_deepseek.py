@@ -237,7 +237,9 @@ def test_the_grouped_rule_keeps_three_groups_and_the_ungrouped_top_differs():
     (dict(groups=4, zero_experts=4), "do not divide"),
     (dict(groups=8, topk_groups=1, topk=3), "do not hold"),
     (dict(groups=4, topk_groups=5), "do not hold"),
-    (dict(groups=4, topk_groups=2, use_bias=True), "selection bias"),
+    # with a bias a group scores as its two best: groups of one have none
+    # (groups of two or more are Ling's rule, tests/test_ling.py)
+    (dict(groups=16, topk_groups=4, use_bias=True), "selection bias"),
 ])
 def test_a_grouped_rule_that_cannot_be_is_refused(bad, says):
     kw = dict(experts=16, topk=2, use_bias=False)

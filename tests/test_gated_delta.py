@@ -11,6 +11,14 @@ float32, so what is left is the order of the sums: observed 2e-7 on outputs
 of about 1 and 8e-7 on states of about 3 (1.1e-6 from the kernel's body at
 512 positions). 1e-5 would not pass a decay applied a position late, a
 correction without ``beta`` or a sub-chunk that starts from another state.
+
+A decay a key channel (``g`` of [T, H, Dk], Kimi Delta Attention; form
+``channel``) goes through the same cases at the same tolerance: its chunk
+form pairs rows about the middle of a block's decay (blocks of 16 inside the
+sub-chunk of 64), so the lengths sit on both sides of 16 and of 64 too,
+channel 0 is at the gate's floor (-5 every token: a block's decay is
+``exp(-75)``, its factors ``exp(37.5)`` and ``exp(-37.5)``) beside channel 1
+at -0.001, and the scalar cases are what they were.
 """
 
 import jax
@@ -30,9 +38,16 @@ ATOL = 1e-5
 # Qwen3-Next has), a pair of heads with keys of their own, and a step of
 # eight heads on two key heads.
 FORMS = {"jnp": (H, H, DK, DV), "kernel": (4, 2, 128, 128),
-         "kernel_own_keys": (2, 2, 128, 128), "kernel_of_8": (8, 2, 128, 128)}
+         "kernel_own_keys": (2, 2, 128, 128), "kernel_of_8": (8, 2, 128, 128),
+         # a decay a key channel, a key head a value head (KDA): the jnp
+         # body, and the kernel's body at two pairs of heads and at eight
+         "channel": (H, H, DK, DV), "channel_kernel": (4, 4, 128, 128),
+         "channel_kernel_of_8": (8, 8, 128, 128)}
+# The floor of the channel form's gate (``kda_lower_bound``).
+FLOOR = -5.0
 
 _jitted = jax.jit(gd.gated_delta_chunk)
+_jitted_channel = jax.jit(lambda *a: gd.gated_delta_chunk(*a, g_floor=FLOOR))
 
 
 def chunk(form, *a):
@@ -41,8 +56,11 @@ def chunk(form, *a):
     cache holds only what was traced under the forced backend)."""
     if form == "jnp":
         return gd.gated_delta_chunk(*a)
+    if form == "channel":
+        return gd.gated_delta_chunk(*a, g_floor=FLOOR)
     with force_kernel_backend("interpret"):
-        return _jitted(*a)
+        return (_jitted_channel if form.startswith("channel")
+                else _jitted)(*a)
 
 
 def inputs(t, seed=0, state=True, form="jnp"):
@@ -55,6 +73,12 @@ def inputs(t, seed=0, state=True, form="jnp"):
     k = unit(jax.random.normal(ks[1], (t, hk, dk)))
     v = jax.random.normal(ks[2], (t, h, dv))
     g = -jnp.exp(jax.random.uniform(ks[3], (t, h), minval=-7.0, maxval=0.5))
+    if str(form).startswith("channel"):
+        # log-uniform over 0.001 to 5 a channel and token, channel 0 at the
+        # floor every token and channel 1 at -0.001
+        g = -jnp.exp(jax.random.uniform(
+            ks[3], (t, h, dk), minval=np.log(1e-3), maxval=np.log(-FLOOR)))
+        g = g.at[..., 0].set(FLOOR).at[..., 1].set(-1e-3)
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (t, h)))
     s = jax.random.normal(ks[5], (h, dk, dv)) if state \
         else jnp.zeros((h, dk, dv))
@@ -69,7 +93,11 @@ def close(got, want):
 @pytest.mark.parametrize("form,t", [
     *(("jnp", t) for t in (1, 5, 63, 64, 65, 100, 128, 200)),
     *(("kernel", t) for t in (64, 330, 512)),
-    ("kernel_own_keys", 330), ("kernel_of_8", 130)])
+    ("kernel_own_keys", 330), ("kernel_of_8", 130),
+    *(("channel", t) for t in (1, 5, 15, 16, 17, 63, 64, 65, 100, 128,
+                               200)),
+    *(("channel_kernel", t) for t in (64, 330, 512)),
+    ("channel_kernel_of_8", 130)])
 @pytest.mark.parametrize("state", [False, True], ids=["zero", "carried"])
 def test_the_chunk_form_is_the_recurrence(form, t, state):
     """At lengths that are and are not whole sub-chunks, from zeros and from
@@ -78,7 +106,8 @@ def test_the_chunk_form_is_the_recurrence(form, t, state):
     close(chunk(form, *a), gd.gated_delta_recurrence(*a))
 
 
-@pytest.mark.parametrize("form", ["jnp", "kernel"])
+@pytest.mark.parametrize("form", ["jnp", "kernel", "channel",
+                                  "channel_kernel"])
 def test_the_state_is_handed_from_run_to_run(form):
     """Two runs, the second from the state the first left, are one run: a
     prefill chunk after a prefill chunk."""
@@ -90,7 +119,8 @@ def test_the_state_is_handed_from_run_to_run(form):
     close((jnp.concatenate([o1, o2]), s2), (want_o, want_s))
 
 
-@pytest.mark.parametrize("form", ["jnp", "kernel"])
+@pytest.mark.parametrize("form", ["jnp", "kernel", "channel",
+                                  "channel_kernel"])
 def test_rows_that_are_not_valid_change_no_state(form):
     """A padded chunk: rows past the last valid one enter with ``g = 0`` and
     ``beta = 0`` and leave the state as the last valid row left it,
@@ -102,7 +132,7 @@ def test_rows_that_are_not_valid_change_no_state(form):
                                         s)
     o, got = chunk(
         form, q, k, jnp.where(valid[:, None, None], v, 100.0 * v),
-        jnp.where(valid[:, None], g, 0.0),
+        jnp.where(valid.reshape((-1,) + (1,) * (g.ndim - 1)), g, 0.0),
         jnp.where(valid[:, None], beta, 0.0), s)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=ATOL)
     want_o, _ = gd.gated_delta_recurrence(q[:n], k[:n], v[:n], g[:n],
@@ -124,7 +154,8 @@ def test_the_inverse_by_halves_is_the_substitution():
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-5)
 
 
-@pytest.mark.parametrize("form", ["jnp", "kernel", "kernel_own_keys"])
+@pytest.mark.parametrize("form", ["jnp", "kernel", "kernel_own_keys",
+                                  "channel", "channel_kernel"])
 def test_keys_that_are_alike_cost_no_precision(form):
     """Neighbouring keys nearly parallel and steps near 1: ``A`` has entries
     near 1 all under its diagonal. The product ``(I - A)(I + A^2)(I + A^4)
@@ -141,25 +172,58 @@ def test_keys_that_are_alike_cost_no_precision(form):
           gd.gated_delta_recurrence(q, k, v, g, beta, s))
 
 
-@pytest.mark.parametrize("form", ["jnp", "kernel"])
+@pytest.mark.parametrize("form", ["jnp", "kernel", "channel",
+                                  "channel_kernel"])
 def test_a_long_decay_overflows_nothing(form):
     """Every exponent is a difference ``G_i - G_j <= 0``: a sub-chunk whose
-    running decay reaches exp(-64 x 20) still gives the recurrence."""
+    running decay reaches exp(-64 x 20) still gives the recurrence. A decay
+    a channel at its floor everywhere: a block's factors reach exp(37.5) and
+    exp(-37.5), a sub-chunk's decay exp(-320)."""
     q, k, v, g, beta, s = inputs(128, seed=9, form=form)
-    g = jnp.full_like(g, -20.0)
+    g = jnp.full_like(g, FLOOR if form.startswith("channel") else -20.0)
     got = chunk(form, q, k, v, g, beta, s)
     assert np.isfinite(np.asarray(got[0])).all()
     close(got, gd.gated_delta_recurrence(q, k, v, g, beta, s))
 
 
-def test_the_step_is_the_recurrence_s_one_token():
+def test_a_decay_a_channel_is_chunked_under_a_stated_floor_alone():
+    """No floor is refused, and so is one at which a block's factor would
+    not fit float32 (16 x 5.5 = 88); nothing is clamped to make it fit. The
+    scalar decay needs none."""
+    a = inputs(70, form="channel")
+    with pytest.raises(ValueError, match="g_floor"):
+        gd.gated_delta_chunk(*a)
+    with pytest.raises(ValueError, match="float32 holds"):
+        gd.gated_delta_chunk(*a, g_floor=-5.5)
+    gd.gated_delta_chunk(*a, g_floor=-5.49)
+    gd.gated_delta_chunk(*inputs(70))
+
+
+def test_a_decay_the_same_in_every_channel_is_the_scalar_rule():
+    """The scalar rule is the channel rule's case: ``g`` a head broadcast
+    over its channels gives what ``g`` a head gives, in all three forms."""
+    q, k, v, g, beta, s = inputs(100, seed=21)
+    g = jnp.maximum(g, FLOOR)
+    wide = jnp.broadcast_to(g[..., None], g.shape + (DK,))
+    close(gd.gated_delta_chunk(q, k, v, wide, beta, s, g_floor=FLOOR),
+          gd.gated_delta_chunk(q, k, v, g, beta, s))
+    close(gd.gated_delta_recurrence(q, k, v, wide, beta, s),
+          gd.gated_delta_recurrence(q, k, v, g, beta, s))
+    states = jnp.broadcast_to(s, (4, *s.shape))
+    close(gd.gated_delta_step(q[:4], k[:4], v[:4], wide[:4], beta[:4],
+                              states),
+          gd.gated_delta_step(q[:4], k[:4], v[:4], g[:4], beta[:4], states))
+
+
+@pytest.mark.parametrize("form", ["jnp", "channel"])
+def test_the_step_is_the_recurrence_s_one_token(form):
     """Every slot its own state, a token each; a slot with ``g = 0`` and
     ``beta = 0`` keeps its state bit for bit."""
     slots = 4
-    q, k, v, g, beta, _ = inputs(slots, seed=11)
+    q, k, v, g, beta, _ = inputs(slots, seed=11, form=form)
     states = jax.random.normal(jax.random.PRNGKey(1), (slots, H, DK, DV))
     idle = jnp.arange(slots) == 2
-    g = jnp.where(idle[:, None], 0.0, g)
+    g = jnp.where(idle.reshape((-1,) + (1,) * (g.ndim - 1)), 0.0, g)
     beta = jnp.where(idle[:, None], 0.0, beta)
     o, new = gd.gated_delta_step(q, k, v, g, beta, states)
     for b in range(slots):
@@ -170,7 +234,8 @@ def test_the_step_is_the_recurrence_s_one_token():
     np.testing.assert_array_equal(np.asarray(new[2]), np.asarray(states[2]))
 
 
-@pytest.mark.parametrize("form", ["jnp", "kernel"])
+@pytest.mark.parametrize("form", ["jnp", "kernel", "channel",
+                                  "channel_kernel"])
 def test_the_forms_compute_in_float32_whatever_they_are_given(form):
     """bfloat16 inputs are cast up, the state and the output are float32:
     the rule's state is never kept below float32."""
@@ -202,9 +267,10 @@ def _equations(jaxpr):
 def _traced(form, t=128, backend="interpret"):
     # a function of its own a trace: the forced backend is no part of the
     # tracing cache's key
+    floor = FLOOR if str(form).startswith("channel") else None
     with force_kernel_backend(backend):
         return list(_equations(jax.make_jaxpr(
-            lambda *a: gd.gated_delta_chunk(*a))(
+            lambda *a: gd.gated_delta_chunk(*a, g_floor=floor))(
                 *inputs(t, form=form)).jaxpr))
 
 
@@ -220,14 +286,22 @@ def test_the_kernel_is_chosen_by_the_backend_and_the_operands_shapes():
     for form in FORMS:
         names = {e.primitive.name for e in _traced(form, backend="reference")}
         assert "pallas_call" not in names, form
-    assert "pallas_call" not in {e.primitive.name for e in _traced("jnp")}
+    for form in ("jnp", "channel"):
+        assert "pallas_call" not in {e.primitive.name for e in _traced(form)}
+    # a decay a channel has a kernel of its own under the same name, where
+    # a key head is a value head
+    calls = [e for e in _traced("channel_kernel")
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1 and calls[0].params["name"] == "gated_delta_chunk"
+    assert calls[0].invars[3].aval.shape == calls[0].invars[1].aval.shape
     for h, hk in ((3, 3), (6, 2), (2, 1), (8, 8), (16, 2)):
         names = {e.primitive.name for e in _traced((h, hk, 128, 128))}
         fits = h % 2 == 0 and gd._heads_a_step(h) % (h // hk) == 0
         assert ("pallas_call" in names) == fits, (h, hk)
 
 
-@pytest.mark.parametrize("form", ["kernel", "kernel_own_keys"])
+@pytest.mark.parametrize("form", ["kernel", "kernel_own_keys",
+                                  "channel_kernel"])
 def test_every_product_of_the_kernel_is_true_float32(form):
     """The configuration states the precision (``departures.state_dtype``):
     the state float32, the rule's products at true float32. Every product

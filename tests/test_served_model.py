@@ -22,7 +22,7 @@ import pytest
 
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
 from ray_tpu.llm import deepseek_serving, lfm2_serving, llama_serving
-from ray_tpu.llm import longcat_serving, mimo_serving
+from ray_tpu.llm import ling_serving, longcat_serving, mimo_serving
 from ray_tpu.llm import ouro_serving, phi4flash_serving, qwen3_next_serving
 from ray_tpu.llm import sdar_serving
 from ray_tpu.llm.config import SERVING_MODULES, ModelConfig
@@ -31,6 +31,7 @@ from ray_tpu.models.deepseek import DeepseekV2Config
 from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
+from ray_tpu.models.ling import LingConfig
 from ray_tpu.models.mimo import MimoConfig
 from ray_tpu.models.ouro import OuroConfig
 from ray_tpu.models.phi4flash import Phi4FlashConfig
@@ -80,11 +81,16 @@ def _mimo():
     return mimo_serving, MimoConfig.tiny(max_seq_len=MAX_SEQ)
 
 
+def _ling():
+    return ling_serving, LingConfig.tiny(expert_shards=2,
+                                         max_seq_len=MAX_SEQ)
+
+
 # The models of a token a step, and all of them.
 MODELS = dict(argvalues=[_llama, _longcat, _ouro, _lfm2, _deepseek,
-                         _qwen3_next, _phi4flash, _mimo],
+                         _qwen3_next, _phi4flash, _mimo, _ling],
               ids=["llama", "longcat", "ouro", "lfm2", "deepseek",
-                   "qwen3_next", "phi4flash", "mimo"])
+                   "qwen3_next", "phi4flash", "mimo", "ling"])
 ALL_MODELS = dict(argvalues=MODELS["argvalues"] + [_sdar],
                   ids=MODELS["ids"] + ["sdar"])
 

@@ -1058,13 +1058,17 @@ def test_qwen3_next_programs_copy_no_state_nor_expert_stack_and_fit_the_chip(
             carried | {"custom-call"}, shape
 
 
+@pytest.mark.parametrize("decay", ["a_head", "a_channel"])
 @pytest.mark.parametrize("batched", [False, True], ids=["a_chunk", "vmap"])
-def test_gated_delta_chunk_compiles_alone_and_under_vmap(mosaic, batched):
-    """The chunked gated delta rule's kernel at the cell's shapes (512 rows,
-    16 key heads for 32 value heads of 128), as the prefill program calls it
-    and as ``models/qwen3_next.forward`` does, under ``jax.vmap``: no cell's
-    path, but it must still compile on a TPU (``pallas_call``'s batching
-    rule makes the batch a grid axis)."""
+def test_gated_delta_chunk_compiles_alone_and_under_vmap(mosaic, batched,
+                                                         decay):
+    """The chunked gated delta rule's kernels at their cells' shapes (512
+    rows; a decay a head with 16 key heads for 32 value heads of 128,
+    Qwen3-Next's; a decay a key channel with 32 heads of 128, Ling's, under
+    the gate's floor of -5), as the prefill programs call them and as the
+    models' ``forward`` does, under ``jax.vmap``: no cell's path, but it
+    must still compile on a TPU (``pallas_call``'s batching rule makes the
+    batch a grid axis)."""
     from ray_tpu.ops.gated_delta import gated_delta_chunk
 
     dev = NamedSharding(build_mesh(MeshSpec(), mosaic[:1]), P())
@@ -1073,10 +1077,15 @@ def test_gated_delta_chunk_compiles_alone_and_under_vmap(mosaic, batched):
     def sds(*shape):
         return jax.ShapeDtypeStruct(lead + shape, jnp.float32, sharding=dev)
 
-    fn = jax.vmap(gated_delta_chunk) if batched else gated_delta_chunk
+    if decay == "a_channel":
+        rule = partial(gated_delta_chunk, g_floor=-5.0)
+        keys, g = sds(512, 32, 128), sds(512, 32, 128)
+    else:
+        rule, keys, g = gated_delta_chunk, sds(512, 16, 128), sds(512, 32)
+    fn = jax.vmap(rule) if batched else rule
     text = jax.jit(fn).lower(
-        sds(512, 16, 128), sds(512, 16, 128), sds(512, 32, 128),
-        sds(512, 32), sds(512, 32), sds(32, 128, 128)).compile().as_text()
+        keys, keys, sds(512, 32, 128), g, sds(512, 32),
+        sds(32, 128, 128)).compile().as_text()
     assert text.count(MOSAIC) == 1
     assert "gated_delta_chunk" in text
 
@@ -1231,3 +1240,133 @@ def test_mimo_programs_copy_no_line_ring_nor_stacked_leaf_and_fit_the_chip(
     # float32 where the configuration's departures say so
     assert "f32[6,4096,256]" in text and "f32[5,64]" in text
     assert "bf16[6,4096,256]" not in text and "bf16[5,64]" not in text
+
+
+# ISSUE 58: Ling-3.0-flash-VL's serving programs at the shapes of
+# ``ling3-flash-serve-rollout-8k`` (12 layers at the published widths: 10
+# Kimi Delta Attention and 2 gated latent attentions, 64 of 512 experts held,
+# an eighth of the vocabulary, 96 slots x 8,192): the two programs of
+# llm/ling_serving.py as the cell compiles them.
+@pytest.mark.parametrize("program", ["prefill_chunk(512)", "decode_burst(8)"])
+def test_ling_programs_copy_no_state_line_nor_stacked_leaf_and_fit_the_chip(
+        mosaic, program):
+    """The cache's leaves ride every loop as carry: the latent lines, the
+    convolutions' windows and the delta rule's state (float32, 2 MiB a slot
+    and layer: 1.875 GiB in all, at this depth a leaf of 0.19 GiB a KDA
+    layer: llm/ling_serving._state_leaves). None, nor a stacked leaf of the
+    experts or of the mixers, is the result of anything but a parameter, a
+    loop's tuple, a kernel's in-place operand or an update in place: a
+    decode step writes a layer's states over themselves, the sum of the
+    decayed state and the correction one fusion whose result takes its
+    operand's buffer (a copy would show in the temporaries, held under 0.7
+    GiB), and no such update is computed twice. The state leaves and the
+    router (weights, bias and product) are float32 as the configuration's
+    departures state. Arguments and temporaries are what
+    benchmark/configs/ling-3.0-flash-vl.json states under ``reduced``."""
+    from devbench import ling_bench as bench
+
+    cfg = bench.config()
+    assert (cfg.num_layers, cfg.linear_lines, cfg.latent_lines,
+            cfg.num_dense_layers, cfg.experts_held, cfg.router_rule.outputs,
+            cfg.vocab_size) == (12, 10, 2, 2, 64, 512, 19648)
+    mem, text, _ = bench.compile_programs(cfg, only=program)[program]
+    carried = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30
+    assert 12.6 < mem.argument_size_in_bytes / 2 ** 30 < 12.7
+    # the float32 reference of the check wants room beside the weights
+    assert total < 15.75 - 2.3
+    if program.startswith("prefill"):
+        kernels = ("latent_prefill_attention", "moe_grouped_matmul",
+                   "gated_delta_chunk")
+        # 512 x 8 picks over 512 outputs are 8 rows an expert: tiles of 16
+        assert _grouped_matmul_rows(text) == {(4096 // 16 + 64) * 16}
+        assert mem.temp_size_in_bytes < 1 << 28
+    else:
+        kernels = ("latent_decode_attention", "latent_row_write",
+                   "moe_grouped_matmul")
+        # 96 x 8 picks: 1.5 rows an expert, tiles of 16
+        assert _grouped_matmul_rows(text) == {(768 // 16 + 64) * 16}
+        assert mem.temp_size_in_bytes < 0.7 * 2 ** 30
+    for name in kernels:
+        assert f'"{name}"' in text or f"%{name}." in text, name
+    big = bench.big_shapes(cfg)
+    # float32, as the configuration's departures.state_dtype states it
+    assert big["state"] == "f32[1,96,32,128,128]"
+    assert big["latent"] == "bf16[2,96,8192,640]"
+    assert "parameter" in _opcodes_with_shape(text, big["state"])
+    # No update of a state leaf is rematerialised: under one leaf over all
+    # ten layers the compiler, short of memory by its own count, computed a
+    # layer's ``decay * S + k d^T`` a second time from the buffer the first
+    # had written in place (the next layer's read and the next update both
+    # used it), and a burst's tokens left the reference by 2 to 5 on the chip
+    # where a single step's were sound (PR 58).
+    assert not [line for line in text.splitlines()
+                if ".remat" in line.split(" = ", 1)[0]
+                and big["state"] in line.split("(", 1)[0]]
+    # and the router to its router_dtype: float32 weights and bias, the
+    # product float32 at true float32
+    for shape in ("f32[10,2560,512]", "f32[10,512]"):
+        assert "parameter" in _opcodes_with_shape(text, shape), shape
+    assert "bf16[10,2560,512]" not in text
+    routes = [line for line in text.splitlines()
+              if "moe_route/dot_general" in line
+              and re.search(r" (convolution|dot)\(", line)]
+    assert routes and all(
+        re.search(r"= f32\[\d+,512\]", line)
+        and "operand_precision={highest,highest}" in line
+        for line in routes), routes[:1]
+    assert _opcodes_with_shape(text, big["latent"]) <= \
+        carried | {"dynamic-update-slice", "custom-call"}
+    # the state: read by fusions (their parameter), written over itself by
+    # a chunk's update of a slot's row or a step's of every slot (the
+    # products and the sum inside one fusion), never copied
+    assert _opcodes_with_shape(text, big["state"]) <= carried | {
+        "dynamic-update-slice", "fusion", "broadcast", "multiply", "add"}
+    # no stacked leaf is copied: not the decay's projection either, which
+    # XLA copied whole and transposed at the top of a decode program until
+    # its product was kept an array of its own (models/ling.kda_inputs)
+    # The compiler may fetch a leaf of a layer written out (the first
+    # group's, indexed statically) ahead into fast memory a layer at a time:
+    # a ``slice-start`` of one layer whose tuple names the stack, joined by
+    # a ``ConcatBitcast`` call; that moves no stack in HBM. (``wq``'s shape
+    # is the dense SwiGLUs' ``w_gate``'s and ``w_up``'s too.)
+    for shape in ("we_in", "we_down", "in_qkvz", "in_f", "wq", "wkv_b"):
+        assert _opcodes_with_shape(text, big[shape]) <= \
+            carried | {"custom-call", "slice-start", "slice-done"}, shape
+
+
+# The programs of the three served models that share the modules ISSUE 58
+# opened (ops/gated_delta.py: Qwen3-Next; models/mla.py and models/routed.py:
+# DeepSeek-V2 and LongCat), at their tiny sizes with the Mosaic kernels in:
+# sha256 of the jaxpr (the kernels' bodies in it, no source line), as
+# devbench/lowered_programs.py prints them, taken on PR 57's tree. A PR that
+# means to change one of these programs runs that script and pins anew; one
+# that does not has changed it all the same when this fails.
+LOWERED = {
+    "qwen3next": {"prefill_chunk": "62b7e07bbe773b70",
+                  "decode_step": "faef59afe4f9ed22",
+                  "decode_burst": "18c799a1c09027f8"},
+    "deepseekv2": {"prefill_chunk": "0230fdb3ce4f44a3",
+                   "decode_step": "1d6ff99e6a1fb74b",
+                   "decode_burst": "d84d66289132b474",
+                   "mixed_burst": "512060dc33c529ad"},
+    "longcat": {"prefill_chunk": "ef7a22e047e0c149",
+                "decode_step": "f85b2315a011e456",
+                "decode_burst": "27e806083760d96a"},
+}
+
+
+@pytest.mark.parametrize("model", sorted(LOWERED))
+def test_the_models_that_share_ling_s_modules_lower_to_what_they_were(
+        v5e_2x2, model):
+    """A decay a key channel in ``ops/gated_delta.py``, a query without a
+    low-rank pair and a rotary by halves in ``models/mla.py``, a grouped
+    rule with a bias in ``models/routed.py``: none reaches the programs of
+    the models that had these modules before."""
+    from devbench import lowered_programs
+
+    got = lowered_programs.run("mosaic", [model])
+    assert {prog: got[f"{model}.{prog}.mosaic.jaxpr"]
+            for prog in LOWERED[model]} == LOWERED[model]
+    assert len([k for k in got if k.endswith(".mosaic.jaxpr")]) \
+        == len(LOWERED[model])

@@ -25,8 +25,8 @@ from ray_tpu.util import tracing
 # The tiny served models and the programs' arguments, as the engine's own
 # contract test builds them.
 from test_served_model import (MAX_SEQ, SLOTS, _arguments, _deepseek, _lfm2,
-                               _llama, _longcat, _mimo, _ouro, _phi4flash,
-                               _qwen3_next)
+                               _ling, _llama, _longcat, _mimo, _ouro,
+                               _phi4flash, _qwen3_next)
 
 # What JAX itself puts on a name stack besides primitives' names.
 WRAPPERS = {"transpose", "jvp", "vmap", "pmap", "jit", "pjit", "while",
@@ -49,9 +49,9 @@ def test_the_finer_names_are_a_vocabulary_of_their_own():
     know them and books their operations to the part around them."""
     assert tracing.SUBPARTS == ("conv", "conv_state", "moe_shared",
                                 "latent_prefill", "linear_attn",
-                                "delta_rule", "linear_state", "ssm",
-                                "ssm_scan", "ssm_state", "window_attn",
-                                "cross_attn", "gmu")
+                                "delta_rule", "linear_state", "kda_rule",
+                                "kda_gate", "ssm", "ssm_scan", "ssm_state",
+                                "window_attn", "cross_attn", "gmu")
     assert not set(tracing.SUBPARTS) & set(tracing.PARTS)
     assert all(re.fullmatch(r"[a-z_]+", p) for p in tracing.SUBPARTS)
     for name in tracing.SUBPARTS:
@@ -109,6 +109,7 @@ SERVED = {
     "qwen3_next": (_qwen3_next, DENSE | ROUTED),
     "phi4flash": (_phi4flash, DENSE),
     "mimo": (_mimo, DENSE | ROUTED),
+    "ling": (_ling, DENSE | ROUTED),
 }
 LONGCAT_GONE = ("longcat.mla", "longcat.moe", "longcat.moe.experts")
 
@@ -173,6 +174,23 @@ def test_a_serving_program_opens_its_parts(model, program):
         assert not re.search(
             r"[^/\w](ssm|ssm_scan|ssm_state|window_attn|cross_attn|gmu)/",
             text)
+    if model == "ling":
+        # KDA's finer names lie inside ``attn``: the rule alone, the
+        # decay's gate and the convolution inside ``linear_attn``, the
+        # state's reads and writes beside it; a chunk's latent attention
+        # inside ``attn`` too; the shared expert inside ``mlp``.
+        assert re.search(r"attn/linear_attn/dot_general", text)
+        assert re.search(r"attn/linear_attn/kda_rule/", text)
+        assert re.search(r"attn/linear_attn/kda_gate/dot_general", text)
+        assert re.search(r"attn/linear_attn/kda_gate/logistic", text)
+        assert re.search(r"attn/linear_attn/conv/", text)
+        assert re.search(r"attn/linear_state/", text)
+        assert re.search(r"mlp/moe_shared/dot_general", text)
+        assert bool(re.search(r"attn/latent_prefill/", text)) == (
+            program == "prefill_chunk")
+        assert not re.search(
+            r"[^/\w](linear_attn|kda_rule|kda_gate|conv|linear_state"
+            r"|moe_shared|latent_prefill)/", text)
     if model == "mimo":
         # A window layer's attention proper (the ring's read with the sink,
         # a chunk's banded product) lies under ``window_attn`` inside
