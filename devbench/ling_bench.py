@@ -19,8 +19,9 @@ against a decay a head at the same shapes: the chunked form on 512 rows x
 is held to; the scalar kernel and its jnp body), each one's device time a
 call, its share of the yardstick and its largest difference from the
 recurrence on outputs and on states (the per-channel inputs have channels
-at the gate's floor of -5 every token); then the step on 96 slots x 32 heads reading one line of the
-stacked state leaf in place, both decays; the yardsticks are
+at the gate's floor of -5 every token); then the step on 96 slots x 32
+heads, a line of a stacked state leaf in place, both decays, the kernel
+beside the jnp body it is held to; the yardsticks are
 ``adapters/ling.delta_rule_token_work`` and ``linear_step_bytes`` over the
 chip's peaks. ``step``: wall milliseconds of one decode step inside a burst
 of 8 at 96 lines of 1,024 and 4,096 live positions and of a prefill chunk
@@ -50,6 +51,7 @@ for p in (ROOT, os.path.join(ROOT, "benchmark")):
 
 from devbench.lfm2_bench import GIB, opcodes_with_shape  # noqa: E402
 from devbench.longcat_bench import timed  # noqa: E402
+from devbench.qwen3_next_bench import step_seconds_a_line  # noqa: E402
 
 SLOTS, MAX_SEQ, CHUNK = 96, 8192, 512
 # ``rule``: calls of a form inside one timed program.
@@ -186,7 +188,6 @@ def rule() -> dict:
     from jax import lax
     from rtbench.adapters import ling as adapter
 
-    from ray_tpu.models.routed import layer_of
     from ray_tpu.ops import gated_delta as gd
 
     cfg, cj, peaks = config(), config_json(), _peaks()
@@ -272,37 +273,23 @@ def rule() -> dict:
     chunk_row("scalar_kernel", gd.gated_delta_chunk, inputs(CHUNK, False))
     chunk_row("scalar_jnp", gd.gated_delta_chunk_reference,
               inputs(CHUNK, False))
-    # The step on one line of the stacked leaf, as the decode program has
-    # it: the leaf is donated and updated in place.
+    # The step on every line of a stacked leaf in turn, as the decode
+    # program has it (the leaf donated, a line updated in place), the
+    # kernel beside the jnp body it is held to, both decays: all from this
+    # one call.
     lines = cfg.linear_lines
     least_step = adapter.linear_step_bytes(cj, SLOTS) \
         / peaks["hbm_bytes_per_s"]
-
-    def all_lines(state, q, k, v, g, beta):
-        def body(line, carry):
-            o, s1 = gd.gated_delta_step(q, k, v, g, beta,
-                                        layer_of(carry[1], line))
-            return o, lax.dynamic_update_index_in_dim(carry[1], s1, line, 0)
-        return lax.fori_loop(0, lines, body,
-                             (jnp.zeros((SLOTS, nh, d)), state))
-
     for channel in (True, False):
-        state = jax.random.normal(ks[6], (lines, SLOTS, nh, d, d))
         b = inputs(SLOTS, channel)
-        fn = jax.jit(all_lines, donate_argnums=0)
-        o, state = fn(state, *b)
-        jax.block_until_ready(state)
-        t0 = time.perf_counter()
-        for _ in range(10):
-            o, state = fn(state, *b)
-        jax.block_until_ready(state)
-        sec = (time.perf_counter() - t0) / 10 / lines
-        out["step"].append({
-            "form": "channel_xla" if channel else "scalar_xla",
-            "ms_per_line": round(sec * 1e3, 4),
-            "least_us": round(least_step * 1e6, 2),
-            "roofline_pct": round(100 * least_step / sec, 2)})
-        del state
+        for form, backend in (("kernel", "mosaic"), ("jnp", "reference")):
+            sec = step_seconds_a_line(b, lines, backend, ks[6], 10)
+            out["step"].append({
+                "form": ("channel_" if channel else "scalar_") + form,
+                "states_a_step": gd._states_a_step(nh, d, d),
+                "ms_per_line": round(sec * 1e3, 4),
+                "least_us": round(least_step * 1e6, 2),
+                "roofline_pct": round(100 * least_step / sec, 2)})
     return out
 
 

@@ -22,8 +22,8 @@ one's device time a call (32 calls inside one program, each from the state
 the last left), its share of the yardstick and its largest difference from
 the recurrence on outputs and on states, and the kernel again at 2, 4 and 16
 value heads a grid step beside its own 8; then the step on 16 slots x
-32 heads reading one line of the stacked state leaf in place; the
-yardsticks are ``adapters/qwen3_next.delta_rule_token_work`` and
+32 heads, a line of the stacked state leaf in place, the kernel beside the
+jnp body it is held to; the yardsticks are ``adapters/qwen3_next.delta_rule_token_work`` and
 ``linear_step_bytes`` over the chip's peaks. ``step``: wall milliseconds
 of one decode step inside a burst of 8 at 16 lines of 4,096, 12,288 and
 30,720 live positions and of a prefill chunk of 512 against 0 to 30,720 cached rows (the clock
@@ -181,13 +181,42 @@ def _peaks():
         return json.load(f)[jax.devices()[0].device_kind]
 
 
+def step_seconds_a_line(a, lines: int, backend: str, key, reps: int):
+    """Seconds a line of ``gated_delta_step`` on every line in turn of a
+    stacked leaf of ``lines`` lines, as a decode program has it (the leaf
+    donated, a line updated in place), under ``backend`` (``"mosaic"``: the
+    kernel; ``"reference"``: the jnp body). ``a``: q, k, v, g, beta of
+    every slot. devbench/ling_bench.py times its step with it too."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ray_tpu.ops import gated_delta as gd
+    from ray_tpu.ops.kernels import force_kernel_backend
+
+    def all_lines(state, *a):
+        def body(line, carry):
+            return gd.gated_delta_step(*a, carry[1], line)
+        return lax.fori_loop(0, lines, body, (jnp.zeros(a[2].shape), state))
+
+    state = jax.random.normal(key, (lines, *a[1].shape, a[2].shape[-1]))
+    with force_kernel_backend(backend):
+        fn = jax.jit(all_lines, donate_argnums=0)
+        _, state = fn(state, *a)
+    jax.block_until_ready(state)
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        _, state = fn(state, *a)
+    jax.block_until_ready(state)
+    return (time.perf_counter() - t0) / reps / lines
+
+
 def rule() -> dict:
     import jax
     import jax.numpy as jnp
     from jax import lax
     from rtbench.adapters import qwen3_next as adapter
 
-    from ray_tpu.models.routed import layer_of
     from ray_tpu.ops import gated_delta as gd
 
     cfg, cj, peaks = config(), config_json(), _peaks()
@@ -254,35 +283,20 @@ def rule() -> dict:
         chunk_row("kernel", gd.gated_delta_chunk, heads_a_step=heads)
     gd._heads_a_step = rule_of_heads
     jax.clear_caches()
-    # The step on one line of the stacked leaf, as the decode program has
-    # it: the leaf is donated and updated in place.
+    # The step on every line of the stacked leaf in turn, as the decode
+    # program has it (the leaf donated, a line updated in place), the
+    # kernel beside the jnp body it is held to: both from this one call.
     lines = cfg.linear_lines
-    state = jax.random.normal(ks[6], (lines, SLOTS, nv, dk, dv))
     b = inputs(SLOTS, nv)
     least_step = adapter.linear_step_bytes(cj, SLOTS) \
         / peaks["hbm_bytes_per_s"]
-
-    def one_line(state, line, q, k, v, g, beta):
-        o, s1 = gd.gated_delta_step(q, k, v, g, beta, layer_of(state, line))
-        return o, lax.dynamic_update_index_in_dim(state, s1, line, 0)
-
-    def all_lines(state, q, k, v, g, beta):
-        def body(line, carry):
-            return one_line(carry[1], line, q, k, v, g, beta)
-        return lax.fori_loop(0, lines, body,
-                             (jnp.zeros((SLOTS, nv, dv)), state))
-
-    fn = jax.jit(all_lines, donate_argnums=0)
-    o, state = fn(state, *b)
-    jax.block_until_ready(state)
-    t0 = time.perf_counter()
-    for _ in range(20):
-        o, state = fn(state, *b)
-    jax.block_until_ready(state)
-    sec = (time.perf_counter() - t0) / 20 / lines
-    out["step"].append({"form": "xla_in_place", "ms_per_line": round(
-        sec * 1e3, 4), "least_us": round(least_step * 1e6, 2),
-        "roofline_pct": round(100 * least_step / sec, 2)})
+    for form, backend in (("kernel", "mosaic"), ("jnp", "reference")):
+        sec = step_seconds_a_line(b, lines, backend, ks[6], 20)
+        out["step"].append({
+            "form": form, "states_a_step": gd._states_a_step(nv, dk, dv),
+            "ms_per_line": round(sec * 1e3, 4),
+            "least_us": round(least_step * 1e6, 2),
+            "roofline_pct": round(100 * least_step / sec, 2)})
     return out
 
 

@@ -10,15 +10,20 @@ llm/ling_serving.py), the slot second in both:
   other in a slot's row (llm/lfm2_serving.py's layout and for its reason).
 
 A model may keep the state as several such leaves, each over some of its
-linear layers (llm/ling_serving.py, and why), and then a layer's line in its ``state`` leaf is not its line in ``conv``: the
-four functions take both (``conv_line``, the same where it is not given).
+linear layers (llm/ling_serving.py, and why), and then a layer's line in its
+``state`` leaf is not its line in ``conv``: the chunk's two functions take
+both (``conv_line``, the same where it is not given).
 
 Both ride every loop as carry. A prefill chunk reads its slot's two rows, or
 zeros where the chunk is a prompt's first (whatever the slot held before),
 and writes the state after its last valid row and the window that ends
-there; a decode step reads a line of every slot and writes it back, a slot
-that does not decode its window as it was (its state the rule itself leaves
-bit for bit, given ``g = 0`` and ``beta = 0``). All under ``linear_state``.
+there; a decode step reads a line of every slot's window and writes it
+back, a slot that does not decode its window as it was. A step's states are
+not this module's: ``ops/gated_delta.gated_delta_step`` takes the ``state``
+leaf and the line and updates that line in place (a slot that does not
+decode it leaves bit for bit, given ``g = 0`` and ``beta = 0``), so no
+program slices a line of states out of the leaf or writes one back. All
+under ``linear_state``.
 """
 
 from __future__ import annotations
@@ -82,14 +87,11 @@ def step_start(cs, line, conv_dim: int):
         return layer_of(cs, line).reshape(cs.shape[1], -1, conv_dim)
 
 
-def step_end(st, cs, s1, window, prior, line, write_mask, conv_line=None):
-    """Every slot after a step: the states ``s1`` [slots, heads, Dk, Dv]
-    and the windows moved on a row, a slot with ``write_mask`` false its
-    window as it was."""
-    conv_line = line if conv_line is None else conv_line
+def step_end(cs, window, prior, line, write_mask):
+    """Every slot's window after a step, moved on a row; a slot with
+    ``write_mask`` false its window as it was. (The states the rule's step
+    has already written, in place in their leaf.)"""
     with tracing.part("linear_state"):
-        st = lax.dynamic_update_index_in_dim(st, s1, line, 0)
         new = jnp.where(write_mask[:, None, None], window[:, 1:], prior)
-        cs = lax.dynamic_update_index_in_dim(
-            cs, new.astype(cs.dtype).reshape(new.shape[0], -1), conv_line, 0)
-    return st, cs
+        return lax.dynamic_update_index_in_dim(
+            cs, new.astype(cs.dtype).reshape(new.shape[0], -1), line, 0)

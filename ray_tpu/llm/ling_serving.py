@@ -24,7 +24,10 @@ against both.
   d^T`` a second time from the buffer the first had already written in
   place), so that layer's states were decayed and corrected twice a step.
   An update whose only use is the step's result has nothing to be
-  rematerialised for (tests/test_tpu_aot.py holds that none is).
+  rematerialised for (tests/test_tpu_aot.py holds that none is). Since
+  PR 59 a step's update is a kernel's in-place operand, which the compiler
+  cannot compute a second time, so the hazard is gone from the decode
+  programs; whether the ten leaves could be one again is ROADMAP R5 (g).
 
 All ride every loop as carry. What llm/qwen3_next_serving.py says of a
 state that is not a line holds here: a padded chunk's rows past the prompt's
@@ -35,7 +38,10 @@ prefix cannot be adopted from another slot's line.
 
 Prefill runs the rule's chunked form with a decay a key channel under the
 gate's floor (``cfg.kda_lower_bound``); a decode step its one-token case on
-every slot's state, updated in place in the stacked leaf.
+every slot's state: ``gated_delta_step`` takes a layer's leaf and the line
+and writes the states in place (a kernel on a TPU, the leaf aliased to its
+result: this module neither slices a line of states out nor writes one
+back; ``linear_state.step_end`` keeps the window).
 
 The programs keep the contract's names and signatures and return, beside
 their result, int32[8] counts summed over the program's layers and steps
@@ -58,7 +64,7 @@ from ray_tpu.models.deepseek import kv_up_projections
 from ray_tpu.models.ling import KDA, LATENT, LingConfig
 from ray_tpu.models.mla import mla_project
 from ray_tpu.models.qwen3_next import conv_window
-from ray_tpu.models.routed import MOE_COUNTERS, layer_of
+from ray_tpu.models.routed import MOE_COUNTERS
 from ray_tpu.ops.gated_delta import gated_delta_chunk, gated_delta_step
 from ray_tpu.ops.kernels import KernelMesh
 from ray_tpu.util import tracing
@@ -202,13 +208,12 @@ def _decode_impl(cfg: LingConfig, params, cache, tokens, positions,
         q, k, v = ling.kda_heads(cfg, lp, window, 1)
         with tracing.part("linear_attn"), tracing.part("kda_rule"):
             # A slot that does not decode decays nothing and corrects
-            # nothing: its state is written back as it was.
-            o, s1 = gated_delta_step(
+            # nothing: its state is left as it was.
+            o, st = gated_delta_step(
                 q[:, 0], k[:, 0], v[:, 0],
                 jnp.where(valid[..., None], g[:, 0], 0.0),
-                jnp.where(valid, beta[:, 0], 0.0), layer_of(st, at_line))
-        st, cs = linear_state.step_end(st, cs, s1, window, prior, at_line,
-                                       write_mask, conv_line=line)
+                jnp.where(valid, beta[:, 0], 0.0), st, at_line)
+        cs = linear_state.step_end(cs, window, prior, line, write_mask)
         return (ling.kda_output(cfg, lp, o[:, None], z, xn.dtype),
                 _with_state(state, leaf, st, cs))
 

@@ -31,8 +31,11 @@ is not a line holds here for two leaves:
 
 Prefill runs the rule's chunked form (sub-chunks of 64 positions, the state
 handed from sub-chunk to sub-chunk and, through the cache, from chunk to
-chunk); a decode step its one-token case on every slot's state, updated in
-place in the stacked leaf.
+chunk); a decode step its one-token case on every slot's state:
+``gated_delta_step`` takes the stacked leaf and the scan's line and writes
+that line's states in place (a kernel on a TPU, the leaf aliased to its
+result: this module neither slices a line of states out nor writes one
+back; ``linear_state.step_end`` keeps the window).
 
 The programs keep the contract's names and signatures and return, beside
 their result, int32[8] counts summed over the program's layers and steps
@@ -53,7 +56,7 @@ from ray_tpu.llm import linear_state
 from ray_tpu.llm.served import ServedModel, token_step_programs
 from ray_tpu.models import qwen3_next
 from ray_tpu.models.qwen3_next import ATTENTION, LINEAR, Qwen3NextConfig
-from ray_tpu.models.routed import MOE_COUNTERS, layer_of
+from ray_tpu.models.routed import MOE_COUNTERS
 from ray_tpu.ops.decode_attention import (
     decode_attention,
     decode_kv_block,
@@ -179,12 +182,11 @@ def _decode_impl(cfg: Qwen3NextConfig, params, cache, tokens, positions0,
         q, k, v = qwen3_next.linear_heads(cfg, lp, window, 1)
         with tracing.part("linear_attn"), tracing.part("delta_rule"):
             # A slot that does not decode decays nothing and corrects
-            # nothing: its state is written back as it was.
-            o, s1 = gated_delta_step(
+            # nothing: its state is left as it was.
+            o, st = gated_delta_step(
                 q[:, 0], k[:, 0], v[:, 0], jnp.where(valid, g[:, 0], 0.0),
-                jnp.where(valid, beta[:, 0], 0.0), layer_of(st, line))
-        st, cs = linear_state.step_end(st, cs, s1, window, prior, line,
-                                       write_mask)
+                jnp.where(valid, beta[:, 0], 0.0), st, line)
+        cs = linear_state.step_end(cs, window, prior, line, write_mask)
         return (qwen3_next.linear_output(cfg, lp, o[:, None], z, xn.dtype),
                 (kc, vc, st, cs))
 

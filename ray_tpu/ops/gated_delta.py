@@ -46,9 +46,26 @@ Three forms of it:
   columns of the caller's ``[T, H * D]``; elsewhere, and as what the kernel
   is held to, :func:`gated_delta_chunk_reference` in plain jnp.
 - :func:`gated_delta_step`: one position of every slot, the recurrence's
-  single step on ``[slots, heads]`` states at once. The state is read by
-  one pass that gives both ``S^T k`` and ``S^T q`` (``o_t = exp(g) S^T q +
-  (k . q) d``, so the new state need not be read again) and written by one.
+  single step on a line of a state leaf ``[lines, slots, heads, Dk, Dv]``,
+  in place. The update needs ``d_t`` and ``d_t`` a sum over the whole
+  state, so the state is gone over twice; in plain jnp
+  (:func:`gated_delta_step_reference`, what runs off a TPU and what the
+  kernel is held to) that is two fusions, the state read from HBM twice
+  and written once. On a TPU a Pallas kernel (:func:`_step_kernel`) takes
+  the leaf whole, aliased to its result, and the line as a prefetched
+  scalar: a grid step holds one slot's states (32 of 128 x 128, 2 MiB) in
+  VMEM, both passes go over VMEM, and a state crosses HBM once each way;
+  the blocks of other lines are not visited, so no caller slices a line
+  out of a leaf or writes one back. Sums over ``Dk`` on the vector units
+  and not products of ``[1, Dk]`` by ``[Dk, Dv]``: a matrix unit would load
+  every state as its weights for one row. A state's rows lie on the
+  sublanes, so a key, a query and a channel's decay are wanted as columns:
+  the kernel lays a head's row over the sublanes and transposes the tile
+  (handed over as ``[..., Dk, 1]`` they would pad to the states' own size
+  in HBM). The kernel's time is its traffic's: with a copy for its body it
+  takes the same 0.634 ms a line of 96 slots, 77% of the chip's 819 GB/s,
+  which is what a read and a write together reach on a v5e by any route
+  (PR 59).
 
 A position with ``g = 0`` and ``beta = 0`` changes no state: a caller marks
 so the rows of a padded chunk past the prompt's end and the slots of a step
@@ -100,6 +117,9 @@ SUB = 64
 # decay ``exp(BLOCK |g_floor|)`` is held to float32's range, ``exp(88.7)``.
 BLOCK = 16
 F32_MAX_EXPONENT = 88.0
+# Bytes of states a grid step of the step's kernel holds, read once and
+# written once (:func:`_states_a_step`).
+STEP_BLOCK_BYTES = 2 << 20
 PRECISION = lax.Precision.HIGHEST
 F32 = jnp.float32
 
@@ -612,28 +632,155 @@ def gated_delta_chunk(q, k, v, g, beta, state, *, g_floor=None):
     return _chunk_pallas(q, k, v, g, beta, state)
 
 
-def gated_delta_step(q, k, v, g, beta, state):
-    """One position of every slot. q, k: [B, H, Dk]; v: [B, H, Dv]; beta:
-    [B, H]; g: [B, H], or [B, H, Dk] for a decay a key channel (a row of the
-    state scaled by its own number, where the scalar scales the whole
-    state); state: [B, H, Dk, Dv] float32. Returns (o [B, H, Dv] float32,
-    state). Sums over ``Dk`` and not products of ``[1, Dk]`` by ``[Dk,
-    Dv]``: a matrix unit would load every state as its weights for one
-    row."""
+def gated_delta_step_reference(q, k, v, g, beta, state, line):
+    """:func:`gated_delta_step` in plain jnp: what runs off a TPU and what
+    the kernel is held to. A line is sliced out of the leaf, read by one
+    pass that gives both ``S^T k`` and ``S^T q`` (``o_t = exp(g) S^T q +
+    (k . q) d``) and written back by a second."""
     q, k, v, g, beta = (a.astype(F32) for a in (q, k, v, g, beta))
+    s = lax.dynamic_index_in_dim(state, line, 0, keepdims=False)
     decay = jnp.exp(g)[..., None]
     if g.ndim == k.ndim:
         # ``S'^T k = S^T (exp(g) k)``: the decayed state is read through
         # decayed keys and queries, in the one pass, and written once.
-        sk = jnp.sum(state * (decay * k[..., None]), axis=-2)  # S'^T k
-        sq = jnp.sum(state * (decay * q[..., None]), axis=-2)  # S'^T q
+        sk = jnp.sum(s * (decay * k[..., None]), axis=-2)      # S'^T k
+        sq = jnp.sum(s * (decay * q[..., None]), axis=-2)      # S'^T q
         d = beta[..., None] * (v - sk)
         o = sq + jnp.sum(k * q, axis=-1, keepdims=True) * d
-        return o, decay * state + k[..., None] * d[..., None, :]
-    # One pass over the state for both reads.
-    sk = jnp.sum(state * k[..., None], axis=-2)               # S^T k
-    sq = jnp.sum(state * q[..., None], axis=-2)               # S^T q
-    d = beta[..., None] * (v - decay * sk)
-    o = decay * sq + jnp.sum(k * q, axis=-1, keepdims=True) * d
-    state = decay[..., None] * state + k[..., None] * d[..., None, :]
+        s = decay * s + k[..., None] * d[..., None, :]
+    else:
+        sk = jnp.sum(s * k[..., None], axis=-2)                # S^T k
+        sq = jnp.sum(s * q[..., None], axis=-2)                # S^T q
+        d = beta[..., None] * (v - decay * sk)
+        o = decay * sq + jnp.sum(k * q, axis=-1, keepdims=True) * d
+        s = decay[..., None] * s + k[..., None] * d[..., None, :]
+    return o, lax.dynamic_update_index_in_dim(state, s, line, 0)
+
+
+# Heads the step's kernel writes out in a row inside its loop over a block's
+# heads: a head's two passes wait for its own sums, and the next heads' fill
+# the wait. On a v5e a line of 96 slots x 32 heads takes 0.799 ms at one,
+# 0.643 at two, 0.638 at four, 0.636 at eight and at all 32 written out,
+# which takes three times as long to trace and compile (PR 59).
+STEP_HEADS_IN_A_ROW = 8
+
+
+def _step_kernel(line_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, s_ref,
+                 o_ref, out_ref):
+    """One slot's ``heads`` states. q_ref, k_ref [heads, Dk]; v_ref, o_ref
+    [heads, Dv]; beta_ref [1, heads]; g_ref [1, heads] for a decay a head,
+    [heads, Dk] for a decay a key channel; s_ref and out_ref [heads, Dk,
+    Dv], the same block of the leaf: read from HBM once, written once.
+
+    A state's rows lie on the sublanes, so what scales a row (a key's and a
+    query's channels, a channel's decay) is wanted down a column, the same
+    in every lane: a head's row of ``Dk`` numbers is laid over the
+    sublanes and that tile transposed. A decay a head is one number over
+    the whole state. The sums over ``Dk`` are sums of a state's
+    sublanes."""
+    from jax.experimental import pallas as pl
+
+    del line_ref  # read by the block specs' index maps
+    heads, dk, dv = s_ref.shape
+    lane = lax.broadcasted_iota(jnp.int32, (1, heads), 1)
+
+    def column(row):              # [1, Dk] -> [Dk, Dv]
+        return jnp.broadcast_to(row, (dv, dk)).T
+
+    def of_head(ref, h):          # [1, heads] -> head h's number, [1, 1]
+        return jnp.sum(jnp.where(lane == h, ref[...], 0.0), axis=1,
+                       keepdims=True)
+
+    def head(h):
+        row = pl.ds(h, 1)
+        k, q = k_ref[row, :], q_ref[row, :]
+        k_col = column(k)
+        if g_ref.shape == k_ref.shape:
+            decay = column(jnp.exp(g_ref[row, :]))
+        else:
+            decay = jnp.broadcast_to(jnp.exp(of_head(g_ref, h)), (1, dv))
+        s = decay * s_ref[h]                                  # S'
+        sk = jnp.sum(s * k_col, axis=0, keepdims=True)        # S'^T k
+        sq = jnp.sum(s * column(q), axis=0, keepdims=True)    # S'^T q
+        d = of_head(beta_ref, h) * (v_ref[row, :] - sk)
+        o_ref[row, :] = sq + jnp.sum(k * q, axis=1, keepdims=True) * d
+        out_ref[h] = s + k_col * d
+
+    n = next(n for n in (STEP_HEADS_IN_A_ROW, 4, 2, 1) if heads % n == 0)
+
+    def heads_in_a_row(i, carry):
+        for j in range(n):
+            head(i * n + j)
+        return carry
+
+    lax.fori_loop(0, heads // n, heads_in_a_row, 0)
+
+
+def _states_a_step(h: int, dk: int, dv: int) -> int:
+    """States a grid step of the step kernel: heads of one slot, the most
+    that are ``STEP_BLOCK_BYTES`` or less, divide ``h`` and tile the
+    operands' ``[h, D]`` (a multiple of 8, or all of them); 0 where no
+    number does. On a v5e a line of 96 slots x 32 heads of 128 x 128 takes
+    0.710 ms at 8 a step (0.5 MiB), 0.638 at 16 and 0.638 at 32 (2 MiB); a
+    line of 16 slots 0.118, 0.114 and 0.111: a step's fixed cost shows
+    under 1 MiB, and the block's first read and last write, which nothing
+    hides, do not grow past it (PR 59)."""
+    most = min(h, STEP_BLOCK_BYTES // (4 * dk * dv))
+    return next((n for n in range(most, 0, -1)
+                 if h % n == 0 and (n % 8 == 0 or n == h)), 0)
+
+
+def _step_pallas(q, k, v, g, beta, state, line):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, dk = k.shape
+    dv = v.shape[-1]
+    hs = _states_a_step(h, dk, dv)
+
+    def a_step(a):                 # [B, H] -> [B, H // hs, 1, hs]
+        return a.reshape(b, h // hs, 1, hs)
+
+    wide = lambda d: pl.BlockSpec(                            # noqa: E731
+        (None, hs, d), lambda i, j, line: (i, j, 0))
+    narrow = pl.BlockSpec((None, None, 1, hs),
+                          lambda i, j, line: (i, j, 0, 0))
+    held = pl.BlockSpec((None, None, hs, dk, dv),
+                        lambda i, j, line: (line[0], i, j, 0, 0))
+    channel = g.ndim == 3
+    o, state = pl.pallas_call(
+        _step_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h // hs),
+            in_specs=[wide(dk), wide(dk), wide(dv),
+                      wide(dk) if channel else narrow, narrow, held],
+            out_specs=[wide(dv), held]),
+        out_shape=[jax.ShapeDtypeStruct((b, h, dv), F32),
+                   jax.ShapeDtypeStruct(state.shape, F32)],
+        # Operands count the scalar-prefetch argument: 6 is the leaf,
+        # written in place.
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=kernel_backend() == "interpret",
+        name="gated_delta_step",
+    )(jnp.asarray(line, jnp.int32).reshape(1), q, k, v,
+      g if channel else a_step(g), a_step(beta), state)
     return o, state
+
+
+def gated_delta_step(q, k, v, g, beta, state, line):
+    """One position of every slot, on line ``line`` of a state leaf, in
+    place. q, k: [B, H, Dk]; v: [B, H, Dv]; beta: [B, H]; g: [B, H], or
+    [B, H, Dk] for a decay a key channel (a row of the state scaled by its
+    own number, where the scalar scales the whole state); state: [lines, B,
+    H, Dk, Dv] float32, the leaf as a serving module holds it; line: an
+    index, traced or not. Returns (o [B, H, Dv] float32, the leaf with that
+    line's states after the position and every other line as it was)."""
+    if kernel_backend() == "reference" or k.shape[-1] % 128 \
+            or v.shape[-1] % 128 \
+            or not _states_a_step(*k.shape[1:], v.shape[-1]):
+        return gated_delta_step_reference(q, k, v, g, beta, state, line)
+    q, k, v, g, beta = (a.astype(F32) for a in (q, k, v, g, beta))
+    return _step_pallas(q, k, v, g, beta, state, line)

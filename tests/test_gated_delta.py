@@ -4,7 +4,8 @@ one-token step against the token-by-token recurrence, float32 on the CPU.
 The chunked form twice: the jnp body that runs off a TPU, at widths of 16
 and 8 (which the kernel does not take), and the kernel's body through the
 Pallas interpreter at widths of 128 (``FORMS``), held to the same
-recurrence at the same tolerance.
+recurrence at the same tolerance. The step the same way: its jnp body, and
+its kernel's body on a line of a state leaf (``STEP_CASES``).
 
 Tolerances: every form computes in float32 and the products at true
 float32, so what is left is the order of the sums: observed 2e-7 on outputs
@@ -209,10 +210,11 @@ def test_a_decay_the_same_in_every_channel_is_the_scalar_rule():
           gd.gated_delta_chunk(q, k, v, g, beta, s))
     close(gd.gated_delta_recurrence(q, k, v, wide, beta, s),
           gd.gated_delta_recurrence(q, k, v, g, beta, s))
-    states = jnp.broadcast_to(s, (4, *s.shape))
+    states = jnp.broadcast_to(s, (1, 4, *s.shape))
     close(gd.gated_delta_step(q[:4], k[:4], v[:4], wide[:4], beta[:4],
-                              states),
-          gd.gated_delta_step(q[:4], k[:4], v[:4], g[:4], beta[:4], states))
+                              states, 0),
+          gd.gated_delta_step(q[:4], k[:4], v[:4], g[:4], beta[:4], states,
+                              0))
 
 
 @pytest.mark.parametrize("form", ["jnp", "channel"])
@@ -225,7 +227,8 @@ def test_the_step_is_the_recurrence_s_one_token(form):
     idle = jnp.arange(slots) == 2
     g = jnp.where(idle.reshape((-1,) + (1,) * (g.ndim - 1)), 0.0, g)
     beta = jnp.where(idle[:, None], 0.0, beta)
-    o, new = gd.gated_delta_step(q, k, v, g, beta, states)
+    o, new = gd.gated_delta_step(q, k, v, g, beta, states[None], 0)
+    new, = new
     for b in range(slots):
         want_o, want_s = gd.gated_delta_recurrence(
             q[b:b + 1], k[b:b + 1], v[b:b + 1], g[b:b + 1], beta[b:b + 1],
@@ -247,8 +250,131 @@ def test_the_forms_compute_in_float32_whatever_they_are_given(form):
     q, k = gd._a_value_head(*low[:2], v.shape[1])
     low = (q, k, low[2])
     o, new = gd.gated_delta_step(*(a[:4] for a in low), g[:4], beta[:4],
-                                 jnp.broadcast_to(s, (4, *s.shape)))
+                                 jnp.broadcast_to(s, (1, 4, *s.shape)), 0)
     assert o.dtype == new.dtype == jnp.float32
+
+
+# The step's kernel: (decay, lines of the leaf, the line stepped: a traced
+# index where the leaf has several).
+STEP_CASES = [("kernel", 1, 0), ("kernel", 3, 1), ("channel_kernel", 1, 0),
+              ("channel_kernel", 3, 2)]
+
+
+def step_inputs(form, lines, seed=31, slots=3):
+    """A token a slot at the kernel's widths, slot 1 idle (``g = 0``,
+    ``beta = 0``), and a leaf of ``lines`` lines of states."""
+    q, k, v, g, beta, _ = inputs(slots, seed=seed, form=form)
+    q, k = gd._a_value_head(q, k, v.shape[1])
+    idle = jnp.arange(slots) == 1
+    g = jnp.where(idle.reshape((-1,) + (1,) * (g.ndim - 1)), 0.0, g)
+    beta = jnp.where(idle[:, None], 0.0, beta)
+    leaf = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                             (lines, slots, *v.shape[1:], v.shape[-1]))
+    return (q, k, v, g, beta), leaf
+
+
+def kernel_step(a, leaf, line):
+    """``gated_delta_step``'s kernel through the interpreter, the line a
+    traced index (a function of its own a trace: the forced backend is no
+    part of the tracing cache's key)."""
+    with force_kernel_backend("interpret"):
+        return jax.jit(lambda *x: gd.gated_delta_step(*x))(
+            *a, leaf, jnp.int32(line))
+
+
+@pytest.mark.parametrize("form,lines,line", STEP_CASES)
+def test_the_step_s_kernel_is_the_recurrence_s_one_token(form, lines, line):
+    """Outputs and the line stepped against the recurrence's one token, a
+    slot at a time, and against the jnp step."""
+    a, leaf = step_inputs(form, lines)
+    o, new = kernel_step(a, leaf, line)
+    assert o.dtype == new.dtype == jnp.float32 and new.shape == leaf.shape
+    for b in range(leaf.shape[1]):
+        want_o, want_s = gd.gated_delta_recurrence(
+            *(x[b:b + 1] for x in a), leaf[line, b])
+        close((o[b], new[line, b]), (want_o[0], want_s))
+    close((o, new), gd.gated_delta_step_reference(*a, leaf, line))
+
+
+@pytest.mark.parametrize("form,lines,line", STEP_CASES)
+def test_the_step_s_kernel_leaves_what_it_does_not_step_bit_for_bit(
+        form, lines, line):
+    """Every other line of the leaf, and on the line stepped the slot that
+    does not decode (``g = 0``, ``beta = 0``): the kernel visits no other
+    line's blocks, and an idle state goes through ``1 * S + k 0``."""
+    a, leaf = step_inputs(form, lines, seed=33)
+    _, new = kernel_step(a, leaf, line)
+    for other in range(lines):
+        if other != line:
+            np.testing.assert_array_equal(np.asarray(new[other]),
+                                          np.asarray(leaf[other]))
+    np.testing.assert_array_equal(np.asarray(new[line, 1]),
+                                  np.asarray(leaf[line, 1]))
+    assert not np.array_equal(np.asarray(new[line, 0]),
+                              np.asarray(leaf[line, 0]))
+
+
+@pytest.mark.parametrize("form,lines,line", STEP_CASES)
+def test_the_step_s_kernel_computes_in_float32_whatever_it_is_given(
+        form, lines, line):
+    """bfloat16 queries, keys and values are cast up before the kernel:
+    what it gives is the recurrence's on the same rounded operands, at the
+    float32 tolerance."""
+    a, leaf = step_inputs(form, lines, seed=35)
+    low = tuple(x.astype(jnp.bfloat16) for x in a[:3]) + a[3:]
+    o, new = kernel_step(low, leaf, line)
+    assert o.dtype == new.dtype == jnp.float32
+    for b in range(leaf.shape[1]):
+        want_o, want_s = gd.gated_delta_recurrence(
+            *(x[b:b + 1] for x in low), leaf[line, b])
+        close((o[b], new[line, b]), (want_o[0], want_s))
+
+
+def _step_traced(heads, dk, dv, channel, backend="interpret"):
+    shape = jax.ShapeDtypeStruct
+    g = shape((2, heads, dk) if channel else (2, heads), jnp.float32)
+    with force_kernel_backend(backend):
+        return list(_equations(jax.make_jaxpr(
+            lambda *a: gd.gated_delta_step(*a))(
+                shape((2, heads, dk), jnp.float32),
+                shape((2, heads, dk), jnp.float32),
+                shape((2, heads, dv), jnp.float32), g,
+                shape((2, heads), jnp.float32),
+                shape((3, 2, heads, dk, dv), jnp.float32),
+                shape((), jnp.int32)).jaxpr))
+
+
+@pytest.mark.parametrize("channel", [False, True], ids=["a_head", "a_channel"])
+def test_the_step_s_kernel_is_chosen_by_the_backend_and_the_operands_shapes(
+        channel):
+    """One call, named for the trace, the leaf its in-place operand, where
+    the backend is not the reference's and a state's two sides are
+    multiples of 128; the jnp body (a line sliced out and written back) off
+    a TPU and at every other width. The block is a function of the shapes:
+    32 states of 128 x 128 (2 MiB) a step, eight of 256 x 256."""
+    call, = (e for e in _step_traced(4, 128, 128, channel)
+             if e.primitive.name == "pallas_call")
+    assert call.params["name"] == "gated_delta_step"
+    assert dict(call.params["input_output_aliases"]) == {6: 1}
+    assert call.invars[6].aval.shape == call.outvars[1].aval.shape \
+        == (3, 2, 4, 128, 128)
+    # the decay as wide as the keys, or a number a head
+    assert call.invars[4].aval.shape == \
+        ((2, 4, 128) if channel else (2, 1, 1, 4))
+    for shape in ((4, 128, 128), (3, 16, 8)):
+        names = {e.primitive.name
+                 for e in _step_traced(*shape, channel, backend="reference")}
+        assert "pallas_call" not in names
+        assert "dynamic_update_slice" in names
+    for h, dk, dv in ((3, 16, 8), (4, 128, 64), (4, 64, 128)):
+        assert "pallas_call" not in {
+            e.primitive.name for e in _step_traced(h, dk, dv, channel)}
+    assert "pallas_call" in {
+        e.primitive.name for e in _step_traced(3, 256, 128, channel)}
+    assert [gd._states_a_step(*a) for a in (
+        (32, 128, 128), (64, 128, 128), (4, 128, 128), (48, 128, 128),
+        (16, 256, 256), (12, 256, 256), (2, 1024, 1024))] == \
+        [32, 32, 4, 24, 8, 0, 0]
 
 
 def _equations(jaxpr):

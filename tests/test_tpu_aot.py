@@ -975,6 +975,26 @@ def test_deepseek_programs_copy_no_cache_nor_stacked_leaf_and_fit_the_chip(
             carried | {"custom-call"}, shape
 
 
+def _step_calls(text: str, state: str) -> int:
+    """``gated_delta_step`` calls of a compiled program, each a Mosaic call
+    whose result holds a leaf of shape ``state``."""
+    calls = [line for line in text.splitlines()
+             if " custom-call(" in line
+             and line.split(" = ", 1)[0].split()[-1].startswith(
+                 "%gated_delta_step")]
+    assert all(MOSAIC in line and state in line.split(" custom-call(")[0]
+               for line in calls), calls[:1]
+    return len(calls)
+
+
+def _rematerialised(text: str, shape: str) -> list[str]:
+    """The instructions of ``shape`` that the compiler computes a second
+    time (``.remat`` in their names)."""
+    return [line for line in text.splitlines()
+            if ".remat" in line.split(" = ", 1)[0]
+            and shape in line.split("(", 1)[0]]
+
+
 # Qwen3-Next's first 16 layers (12 Gated DeltaNet, 4 gated attentions) at the
 # published widths with 64 of 512 experts, at the shapes of its serving cell
 # (16 slots x 32,768): the programs of llm/qwen3_next_serving.py as the cell
@@ -989,9 +1009,12 @@ def test_qwen3_next_programs_copy_no_state_nor_expert_stack_and_fit_the_chip(
     operators, is the result of anything but a parameter, a loop's tuple, a
     kernel's in-place operand or an update in place (the finding of PR 27: a
     stacked leaf indexed by a loop's counter is copied whole unless it is
-    indexed where it is used): a decode step writes a line of the state by a
-    ``dynamic-update-slice`` into the leaf, the sum of the decayed state and
-    the correction fused into it. Arguments and temporaries are what
+    indexed where it is used). A prefill chunk writes a slot's state by a
+    ``dynamic-update-slice`` into the leaf; a decode step hands the leaf and
+    the line to ``gated_delta_step``, whose kernel reads a line's states
+    once and writes them once in place (PR 59: no line is sliced out of the
+    leaf, no fusion reads it, nothing writes one back), one call a linear
+    layer of the scanned group. Arguments and temporaries are what
     benchmark/configs/qwen3-next-80b-a3b.json states under ``reduced``."""
     from devbench import qwen3_next_bench as bench
 
@@ -1044,15 +1067,23 @@ def test_qwen3_next_programs_copy_no_state_nor_expert_stack_and_fit_the_chip(
         for line in routes), routes[:1]
     assert _opcodes_with_shape(text, big["lines"]) <= \
         carried | {"dynamic-update-slice", "custom-call"}
-    # the state: read a line (a fusion's parameter), written in place (an
-    # update alone or with the sum fused into it)
-    assert _opcodes_with_shape(text, big["state"]) <= \
-        carried | {"dynamic-update-slice", "fusion"}
-    for line in text.splitlines():
-        head = line.split(" = ", 1)
-        if len(head) == 2 and big["state"] in head[1].split("(", 1)[0] \
-                and " fusion(" in head[1]:
-            assert "dynamic-update-slice_fusion" in head[0], line[:200]
+    if program.startswith("prefill"):
+        # the state: a slot's read (a fusion's parameter), written in place
+        # (an update alone or with the sum fused into it)
+        assert _opcodes_with_shape(text, big["state"]) <= \
+            carried | {"dynamic-update-slice", "fusion"}
+        for line in text.splitlines():
+            head = line.split(" = ", 1)
+            if len(head) == 2 and big["state"] in head[1].split("(", 1)[0] \
+                    and " fusion(" in head[1]:
+                assert "dynamic-update-slice_fusion" in head[0], line[:200]
+    else:
+        # the state: the step kernel's in-place operand and nothing else,
+        # a call a linear layer of the scanned group of four
+        assert _opcodes_with_shape(text, big["state"]) <= \
+            carried | {"custom-call"}
+        assert _step_calls(text, big["state"]) == 3
+    assert not _rematerialised(text, big["state"])
     for shape in ("we_in", "we_down", "in_qkvz", "wq"):
         assert _opcodes_with_shape(text, big[shape]) <= \
             carried | {"custom-call"}, shape
@@ -1088,6 +1119,40 @@ def test_gated_delta_chunk_compiles_alone_and_under_vmap(mosaic, batched,
         sds(32, 128, 128)).compile().as_text()
     assert text.count(MOSAIC) == 1
     assert "gated_delta_chunk" in text
+
+
+@pytest.mark.parametrize("cell", ["ling_96_slots_a_channel",
+                                  "qwen3_next_16_slots_a_head"])
+def test_gated_delta_step_compiles_alone_at_the_cells_shapes(mosaic, cell):
+    """The step's kernel at its two cells' shapes (32 heads of 128 x 128:
+    Ling's 96 slots on a leaf of one line with a decay a key channel,
+    Qwen3-Next's 16 slots on a leaf of 12 lines with a decay a head, the
+    line a traced index): one Mosaic call named for the trace, the donated
+    leaf its in-place operand (the result's buffer is the argument's, no
+    copy of a leaf and no temporary of a line's size)."""
+    from ray_tpu.ops.gated_delta import gated_delta_step
+
+    dev = NamedSharding(build_mesh(MeshSpec(), mosaic[:1]), P())
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    lines, slots, channel = (1, 96, True) if cell.startswith("ling") \
+        else (12, 16, False)
+    leaf = sds(lines, slots, 32, 128, 128)
+    compiled = jax.jit(gated_delta_step, donate_argnums=5).lower(
+        sds(slots, 32, 128), sds(slots, 32, 128), sds(slots, 32, 128),
+        sds(slots, 32, 128) if channel else sds(slots, 32), sds(slots, 32),
+        leaf, sds(dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count(MOSAIC) == 1
+    state = f"f32[{lines},{slots},32,128,128]"
+    assert _step_calls(text, state) == 1
+    assert _opcodes_with_shape(text, state) <= {
+        "parameter", "get-tuple-element", "tuple", "bitcast", "custom-call"}
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= lines * slots * 32 * 128 * 128 * 4
+    assert mem.temp_size_in_bytes < 1 << 20
 
 
 # Phi-4-mini-flash whole (32 layers at the published widths, the whole
@@ -1256,10 +1321,12 @@ def test_ling_programs_copy_no_state_line_nor_stacked_leaf_and_fit_the_chip(
     layer: llm/ling_serving._state_leaves). None, nor a stacked leaf of the
     experts or of the mixers, is the result of anything but a parameter, a
     loop's tuple, a kernel's in-place operand or an update in place: a
-    decode step writes a layer's states over themselves, the sum of the
-    decayed state and the correction one fusion whose result takes its
-    operand's buffer (a copy would show in the temporaries, held under 0.7
-    GiB), and no such update is computed twice. The state leaves and the
+    prefill chunk writes a slot's state into its leaf by an update in
+    place; a decode step hands a leaf and the line to ``gated_delta_step``,
+    whose kernel reads a layer's states once and writes them once over
+    themselves (PR 59: no fusion reads a leaf, nothing writes one back; a
+    copy would show in the temporaries, held under 0.7 GiB), one call a KDA
+    layer, and no update is computed twice. The state leaves and the
     router (weights, bias and product) are float32 as the configuration's
     departures state. Arguments and temporaries are what
     benchmark/configs/ling-3.0-flash-vl.json states under ``reduced``."""
@@ -1299,10 +1366,10 @@ def test_ling_programs_copy_no_state_line_nor_stacked_leaf_and_fit_the_chip(
     # layer's ``decay * S + k d^T`` a second time from the buffer the first
     # had written in place (the next layer's read and the next update both
     # used it), and a burst's tokens left the reference by 2 to 5 on the chip
-    # where a single step's were sound (PR 58).
-    assert not [line for line in text.splitlines()
-                if ".remat" in line.split(" = ", 1)[0]
-                and big["state"] in line.split("(", 1)[0]]
+    # where a single step's were sound (PR 58). Since PR 59 a step's update
+    # is a kernel's in-place operand, which the compiler cannot compute
+    # twice; the chunk's is still XLA's.
+    assert not _rematerialised(text, big["state"])
     # and the router to its router_dtype: float32 weights and bias, the
     # product float32 at true float32
     for shape in ("f32[10,2560,512]", "f32[10,512]"):
@@ -1317,11 +1384,17 @@ def test_ling_programs_copy_no_state_line_nor_stacked_leaf_and_fit_the_chip(
         for line in routes), routes[:1]
     assert _opcodes_with_shape(text, big["latent"]) <= \
         carried | {"dynamic-update-slice", "custom-call"}
-    # the state: read by fusions (their parameter), written over itself by
-    # a chunk's update of a slot's row or a step's of every slot (the
-    # products and the sum inside one fusion), never copied
-    assert _opcodes_with_shape(text, big["state"]) <= carried | {
-        "dynamic-update-slice", "fusion", "broadcast", "multiply", "add"}
+    if program.startswith("prefill"):
+        # the state: written over itself by a chunk's update of a slot's
+        # row, never copied
+        assert _opcodes_with_shape(text, big["state"]) <= carried | {
+            "dynamic-update-slice", "fusion"}
+    else:
+        # the state: the step kernel's in-place operand and nothing else, a
+        # call a KDA layer
+        assert _opcodes_with_shape(text, big["state"]) <= \
+            carried | {"custom-call"}
+        assert _step_calls(text, big["state"]) == cfg.linear_lines
     # no stacked leaf is copied: not the decay's projection either, which
     # XLA copied whole and transposed at the top of a decode program until
     # its product was kept an array of its own (models/ling.kda_inputs)
