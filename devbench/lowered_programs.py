@@ -12,7 +12,8 @@ models share shows with it that their programs are what they were. The
 models are those of ``llm/config.SERVING_MODULES``, each at its ``tiny``
 configuration; a tree from before PR 44 has no such table, and there
 ``MODULES`` below names where each model's programs lived (PR 38 to 43).
-DUMP=<dir> also writes the texts. With models named (``llama``, ``lfm2``,
+DUMP=<dir> also writes the texts; BURST=<n> lowers ``decode_burst`` at n
+steps and not at 4. With models named (``llama``, ``lfm2``,
 ...) only theirs are lowered: a kernel's body is traced once a process and
 shape and keeps the source lines of the caller that traced it, so in one
 process a model whose file moved hands its new lines to every later model
@@ -38,6 +39,8 @@ from ray_tpu.ops.kernels import force_kernel_backend
 from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
 SLOTS, MAX_SEQ, CHUNK = 4, 256, 32
+# Steps of the burst lowered; BURST=1 lowers the program of a lone step (SDAR's burst of one block).
+BURST = int(os.environ.get("BURST", 4))
 # What a model's ``tiny`` takes beside the length and the dtype.
 TINY = {"LlamaConfig": lambda c: dataclasses.replace(c.tiny(), vocab_size=512, dtype="bfloat16"),
         "LongcatConfig": lambda c: c.tiny(expert_shards=2, max_seq_len=MAX_SEQ, dtype="bfloat16"),
@@ -79,7 +82,7 @@ def run(backend, names=()):
             progs = {
               "prefill_chunk": (cfg, params, cache, arg((CHUNK,)), arg(()), arg(()), arg(())),
               "decode_step": (cfg, params, cache, arg((SLOTS,)), arg((SLOTS,)), arg((SLOTS,), jnp.bool_)),
-              "decode_burst": (cfg, params, cache, arg(token0), arg((SLOTS,)), arg((SLOTS,), jnp.bool_), arg((SLOTS,), jnp.float32), arg((SLOTS,), jnp.float32), arg((2,), jnp.uint32), 4, False),
+              "decode_burst": (cfg, params, cache, arg(token0), arg((SLOTS,)), arg((SLOTS,), jnp.bool_), arg((SLOTS,), jnp.float32), arg((SLOTS,), jnp.float32), arg((2,), jnp.uint32), BURST, False),
             }
             if served.decode_step is None:
                 del progs["decode_step"]

@@ -213,8 +213,8 @@ def _time_chunk(serving, cfg, params, cache, kv_len: int = 0):
     return cache, round(min(times[1:]), 2), counts
 
 
-def _time_block(serving, cfg, params, cache, live: int, blocks: int = 2):
-    """(cache, ms a block, counts) of ``decode_burst(blocks)`` at every line
+def _time_burst(serving, cfg, params, cache, live: int, blocks: int):
+    """(cache, ms, counts) of ``decode_burst(blocks)`` at every line
     ``live`` long, timed as ``_time_chunk`` does."""
     import jax
     import jax.numpy as jnp
@@ -229,8 +229,50 @@ def _time_block(serving, cfg, params, cache, live: int, blocks: int = 2):
             jnp.full((SLOTS,), live, i32), jnp.ones((SLOTS,), bool),
             temps, temps + 1.0, jax.random.PRNGKey(1), blocks, False)
         np.asarray(toks)
-        times.append((time.monotonic() - t0) * 1e3 / blocks)
+        times.append((time.monotonic() - t0) * 1e3)
     return cache, round(min(times[1:]), 2), counts
+
+
+FORWARDS = 8
+
+
+def _time_forwards(serving, cfg, params, cache, live: int, blocks: int):
+    """(cache, ms a forward) of ``_forward`` alone over ``blocks`` blocks a
+    line side by side (1: a denoising forward without its head, or a
+    commit; 2: the forward a commit rides), every line ``live`` long before
+    the rows: FORWARDS calls in one program, timed as ``_time_chunk``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from ray_tpu.ops.decode_attention import decode_plan_of
+
+    k = cfg.block_length
+    write = jnp.ones((SLOTS,), bool)
+    # the last of the side-by-side blocks starts at ``live``
+    start = jnp.full((SLOTS,), live - (blocks - 1) * k, jnp.int32)
+    tokens = jnp.full((SLOTS, blocks * k), cfg.mask_token_id, jnp.int32)
+
+    @partial(jax.jit, donate_argnums=(1,))
+    def run(params, cache):
+        plan = decode_plan_of(jnp.where(write, live + k, 0), cache["k"])
+
+        def one(i, carry):
+            cache, seen = carry
+            cache, x, _ = serving._forward(cfg, params, cache, tokens, start,
+                                           write, plan)
+            return cache, seen + x[0, 0, 0].astype(jnp.float32)
+
+        return lax.fori_loop(0, FORWARDS, one, (cache, jnp.float32(0)))
+
+    times = []
+    for _ in range(4):
+        t0 = time.monotonic()
+        cache, seen = run(params, cache)
+        np.asarray(seen)
+        times.append((time.monotonic() - t0) * 1e3 / FORWARDS)
+    return cache, round(min(times[1:]), 2)
 
 
 def _own_tokens(serving, cfg, params, cache, positions: int = MAX_SEQ):
@@ -254,20 +296,29 @@ def step() -> dict:
 
     cfg, params, serving, cache = _programs()
     out = {"mode": "step", "device": jax.devices()[0].device_kind,
-           "block_ms": {}, "forward_ms": {}, "prefill_chunk_ms": {},
-           "experts_touched_per_layer": {}}
+           "burst_ms": {}, "forwards_a_burst": {}, "forward_ms": {},
+           "prefill_chunk_ms": {}, "experts_touched_per_layer": {}}
+    # a tree from before PR 61 commits every block by a forward of its own
+    # and has no forward of two blocks to time
+    wide = hasattr(serving, "burst_forwards")
     for kv_len in (0, 512):
         cache, out["prefill_chunk_ms"][kv_len], counts = _time_chunk(
             serving, cfg, params, cache, kv_len)
         out["prefill_counts"] = [int(n) for n in counts]
     cache = _own_tokens(serving, cfg, params, cache)
     for live in (256, 768, 1280):
-        cache, ms, counts = _time_block(serving, cfg, params, cache, live)
-        out["block_ms"][live] = ms
-        out["forward_ms"][live] = round(ms / (cfg.denoising_steps + 1), 2)
+        out["burst_ms"][live], out["forward_ms"][live] = {}, {}
+        for blocks in BURSTS:
+            cache, out["burst_ms"][live][blocks], counts = _time_burst(
+                serving, cfg, params, cache, live, blocks)
+            # the router's layer-steps over the layers: forwards that ran
+            out["forwards_a_burst"][blocks] = int(counts[4]) // cfg.num_layers
         out["decode_counts"] = [int(n) for n in counts]
         out["experts_touched_per_layer"][live] = round(
             int(counts[3]) / max(int(counts[4]), 1), 1)
+        for name, blocks in (("4_rows", 1), ("8_rows", 2))[:1 + wide]:
+            cache, out["forward_ms"][live][name] = _time_forwards(
+                serving, cfg, params, cache, live, blocks)
     return out
 
 

@@ -1376,14 +1376,23 @@ class LLMEngine:
         step's), each a kernel call a layer for every forward it costs,
         step i over lines of positions + (i + 1) * k where ``write``, 0
         elsewhere."""
-        k, forwards = k or self._step_positions, self._step_forwards
+        k, forwards = k or self._step_positions, self._burst_forwards(steps)
         lengths = (positions + k)[None, :] + k * np.arange(steps)[:, None]
         lengths = np.where(write[None, :], np.minimum(lengths, self.max_seq),
                            0)
-        self.kv_positions_read += forwards * int(
-            kv_positions_read(lengths, self._kv_block).sum())
-        self.kv_positions_reserved += (steps * forwards * self.max_slots
+        self.kv_positions_read += int(
+            (forwards * kv_positions_read(lengths, self._kv_block).sum(1))
+            .sum())
+        self.kv_positions_reserved += (int(forwards.sum()) * self.max_slots
                                        * self.max_seq)
+
+    def _burst_forwards(self, steps: int) -> np.ndarray:
+        """int[steps]: the forwards of the stack each step of a dispatch of
+        ``steps`` steps costs (ServedModel.burst_forwards; the step's own
+        each where the model states nothing of a burst)."""
+        if self.model.burst_forwards is None:
+            return np.full((steps,), self._step_forwards)
+        return np.asarray(self.model.burst_forwards(self.model_cfg, steps))
 
     def _decode_burst(self, active: dict[int, GenerationRequest],
                       burst: int) -> bool:
@@ -1393,9 +1402,9 @@ class LLMEngine:
         device wrote past its end sits at positions a later slot reuse
         overwrites (same free-rollback property speculative decoding
         relies on)."""
+        forwards = int(self._burst_forwards(burst).sum())
         try:
-            with tracing.phase("engine.decode_dispatch",
-                               steps=burst * self._step_forwards,
+            with tracing.phase("engine.decode_dispatch", steps=forwards,
                                slots=len(active)) as ph:
                 positions, write = self._decode_inputs(active)
                 temps = np.zeros((self.max_slots,), np.float32)
@@ -1432,7 +1441,7 @@ class LLMEngine:
             return False
         self.decode_dispatches += 1
         self.decode_dispatches_ahead += bool(self._in_flight)
-        self.decode_steps += burst * self._step_forwards
+        self.decode_steps += forwards
         self._count_kv_positions(positions, write, burst)
         for req in active.values():
             req.ahead += burst

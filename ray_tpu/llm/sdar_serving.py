@@ -5,7 +5,7 @@ The cache is the Llama one, ``{"k", "v"}`` ``[layers, slots, kv_heads,
 max_seq, head_dim]``. What differs is the step (``ServedModel.step``): one
 step of one line takes a whole block of ``block_length`` positions in and
 gives it back decided, and costs ``denoising_steps + 1`` forwards of the
-stack, each over the block's rows:
+stack by itself, each over the block's rows:
 
 - a **denoising forward** writes the K/V of the block's current content
   (its decided positions, the mask token at the open ones) at the block's
@@ -23,6 +23,19 @@ stack, each over the block's rows:
 - the **commit forward** runs the decided block once more; its K/V stay,
   and no head is computed.
 
+Inside a burst the lines move in lockstep, and a block's commit **rides**
+the next block's first denoising forward: that forward takes ``2 K`` rows a
+line, the clean block's and then the open one's, writes the K/V of both and
+attends the line once (``decode_attention``'s ``rows_a_limit``: the clean
+rows see keys up to the open block's start, the open rows through their
+block's end, so each row sees what it saw in a forward of its own, the
+clean block's K/V of the same layer included). The clean rows' K/V stay;
+the head runs on the open block's rows. So a burst of ``n`` blocks is ``n x
+denoising_steps + 1`` forwards, ``n - 1`` of them wide
+(:func:`burst_forwards`), where blocks by themselves cost ``n x
+(denoising_steps + 1)``; only the burst's last block is committed by a
+forward of its own, and in a burst of one block nothing rides.
+
 Which positions are open is a mask by position, carried from forward to
 forward: never a comparison of ids with the mask id, which a prompt may
 contain like any other. A line's first block may come partly decided: the
@@ -37,7 +50,7 @@ A prompt's whole blocks are prefilled under the same block-causal mask
 
 The programs keep the contract's names (``prefill_chunk``, ``decode_burst``:
 a device trace shows ``jit_<name>``) and return their counts beside their
-result (:data:`COUNTERS`, int32[11], summed over layers, forwards and
+result (:data:`COUNTERS`, int32[12], summed over layers, forwards and
 blocks); the scheduler adds them up where it fetches the tokens.
 """
 
@@ -65,24 +78,26 @@ from ray_tpu.ops.prefill_attention import prefill_attention, prefill_kv_write
 from ray_tpu.ops.rope import rope_frequencies
 from ray_tpu.util import tracing
 
-# Line-blocks run, line-forwards (commits among them), line-commits, the
-# positions of first blocks that the prompt had decided, and the rows that
-# went through the head.
+# Line-blocks run, line-forwards (a forward of two blocks' rows once; the
+# commits that cost one of their own among them), those line-commits, the
+# positions of first blocks that the prompt had decided, the rows that went
+# through the head, and the line-commits that rode the next block's first
+# forward.
 DIFFUSION_COUNTERS = ("diffusion_blocks", "diffusion_forwards",
                       "diffusion_commits", "diffusion_given",
-                      "diffusion_head_rows")
+                      "diffusion_head_rows", "diffusion_commits_riding")
 COUNTERS = MOE_COUNTERS + DIFFUSION_COUNTERS
 
 
 def _ran(count, *names):
-    """int32[5] in the order of DIFFUSION_COUNTERS: ``count`` under each of
+    """int32[6] in the order of DIFFUSION_COUNTERS: ``count`` under each of
     ``names``, added where the thing counted runs."""
     return count * jnp.asarray([n in names for n in DIFFUSION_COUNTERS],
                                jnp.int32)
 
 
 def _counts(moe, diffusion=None):
-    """int32[11] in the order of COUNTERS; a prefill's diffusion counts
+    """int32[12] in the order of COUNTERS; a prefill's diffusion counts
     are zeros."""
     if diffusion is None:
         diffusion = jnp.zeros((len(DIFFUSION_COUNTERS),), jnp.int32)
@@ -121,21 +136,24 @@ def prefill_chunk(cfg: SdarConfig, params, cache, tokens, kv_len, length,
     return {"k": k_all, "v": v_all}, None, _counts(moe)
 
 
-def _forward(cfg: SdarConfig, params, cache, tokens, positions0, write_mask,
+def _forward(cfg: SdarConfig, params, cache, tokens, start, write_mask,
              plan, kmesh=None):
-    """One forward of every line's block: tokens [B, K] at positions
-    ``positions0 + arange(K)`` (the block's start, a multiple of K). Writes
-    the rows' K/V and attends each line through its block's end. Returns
-    (cache, the stack's output [B, K, H] before the final norm, the routed
-    layers' counts). A line with ``write_mask`` false writes nothing, is
-    routed nowhere, and its rows mean nothing."""
-    b, k = tokens.shape
+    """One forward of every line's rows: tokens [B, R] at positions
+    ``start + arange(R)``, R one block of K (``start`` the block's, a
+    multiple of K) or two of them side by side, the earlier one clean.
+    Writes the rows' K/V and attends each line once: a row sees the line
+    through its own block's end. Returns (cache, the stack's output [B, R,
+    H] before the final norm, the routed layers' counts). A line with
+    ``write_mask`` false writes nothing, is routed nowhere, and its rows
+    mean nothing."""
+    b, r = tokens.shape
+    k = cfg.block_length
     with tracing.part("embed"):
-        x = params["embed_tokens"][tokens]                    # [B, K, H]
+        x = params["embed_tokens"][tokens]                    # [B, R, H]
     with tracing.part("attn"):
-        positions = positions0[:, None] + jnp.arange(k)[None, :]
-        lengths = jnp.where(write_mask, positions0 + k, 0)
-        valid = jnp.broadcast_to(write_mask[:, None], (b, k))
+        positions = start[:, None] + jnp.arange(r)[None, :]
+        lengths = jnp.where(write_mask, start + r, 0)
+        valid = jnp.broadcast_to(write_mask[:, None], (b, r))
         inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta)
 
     def attention(layer, ap, xn, kv):
@@ -143,12 +161,15 @@ def _forward(cfg: SdarConfig, params, cache, tokens, positions0, write_mask,
         q, kk, v = attention_heads(cfg, ap, xn, positions, inv_freq)
         with tracing.part("cache"):
             k_all, v_all = kv_row_write(k_all, v_all, kk, v, layer,
-                                        positions0, write_mask, kmesh=kmesh)
-        # The mask's position is the block's last: row j sees keys through
-        # positions0 + k - 1 + j, and none lies past the line's length.
+                                        start, write_mask, kmesh=kmesh)
+        # The mask's position is the first block's last. One block: row j
+        # sees keys through start + k - 1 + j, and none lies past the
+        # line's length. Two: the rows of a block share its limit, the
+        # clean block's stops where the open one starts.
         o = decode_attention(q, k_all, v_all, layer, lengths,
-                             positions0 + (k - 1), plan=plan, kmesh=kmesh)
-        o = o.transpose(0, 2, 1, 3).reshape(b, k, -1)
+                             start + (k - 1), plan=plan, kmesh=kmesh,
+                             rows_a_limit=1 if r == k else k)
+        o = o.transpose(0, 2, 1, 3).reshape(b, r, -1)
         return (o @ ap["wo"]).astype(xn.dtype), (k_all, v_all)
 
     x, (k_all, v_all), moe = sdar.run_layers(
@@ -194,31 +215,44 @@ def decode_burst(cfg: SdarConfig, params, cache, token0, positions0,
                  write_mask, temps, top_ps, key, steps: int,
                  need_top_p: bool = True, *,
                  kmesh: KernelMesh | None = None):
-    """``steps`` blocks of every line in ONE dispatch, each
-    ``denoising_steps`` denoising forwards and the commit. token0 [B, K]:
-    what the first block has decided already (a prompt's tail in its
-    places, -1 at an open position); positions0 [B]: the first block's
-    start. Returns (cache, tokens [steps, B, K], counts)."""
-    b, k = token0.shape
+    """``steps`` blocks of every line in ONE dispatch: ``denoising_steps``
+    denoising forwards a block and one commit forward at the end; every
+    block but the last is committed by the next block's first forward
+    (:func:`burst_forwards`). token0 [B, K]: what the first block has
+    decided already (a prompt's tail in its places, -1 at an open
+    position); positions0 [B]: the first block's start. Returns (cache,
+    tokens [steps, B, K], counts)."""
+    k = token0.shape[1]
     mask_id = jnp.int32(cfg.mask_token_id)
+    with tracing.part("attn"):
+        lines = write_mask.sum().astype(jnp.int32)
+
+    def place(j):
+        """(Block j's start, the walk of the live blocks through its end):
+        every forward of the block attends at the same lengths, so the walk
+        is planned once a block."""
+        pos = positions0 + j * k
+        with tracing.part("attn"):
+            return pos, decode_plan_of(jnp.where(write_mask, pos + k, 0),
+                                       cache["k"], kmesh=kmesh)
 
     def block(carry, j):
-        cache, moe, diffusion = carry
-        pos = positions0 + j * k
+        """Block j through its denoising forwards. The carry's ``last``
+        [B, K] is the block before, decided and not committed (None: there
+        is none, the burst's first block): its rows ride this block's first
+        forward, and their K/V are its commit."""
+        cache, last, moe, diffusion = carry
+        pos, plan = place(j)
         with tracing.part("sample"):
             is_open = (token0 < 0) | (j > 0)
             tokens = jnp.where(is_open, mask_id, token0)
-        with tracing.part("attn"):
-            # Every forward of the block attends at the same lengths: one
-            # walk of the live blocks, planned here.
-            plan = decode_plan_of(jnp.where(write_mask, pos + k, 0),
-                                  cache["k"], kmesh=kmesh)
-            lines = write_mask.sum().astype(jnp.int32)
+            given = ((~is_open) & write_mask[:, None]).sum().astype(jnp.int32)
+            diffusion = (diffusion + _ran(given, "diffusion_given")
+                         + _ran(lines, "diffusion_blocks"))
 
-        def denoise(carry, d):
-            cache, tokens, still_open, moe, diffusion = carry
-            cache, x, n = _forward(cfg, params, cache, tokens, pos,
-                                   write_mask, plan, kmesh)
+        def decide(x, tokens, still_open, diffusion, d):
+            """What forward ``d``'s output x [B, K, H] decides: (tokens,
+            the positions still open, diffusion)."""
             with tracing.part("sample"):
                 read = sdar.read_positions(cfg, still_open)
             logits = _logits(cfg, params, x, read, kmesh)
@@ -234,30 +268,60 @@ def decode_burst(cfg: SdarConfig, params, cache, token0, positions0,
                 # tokens have positions to go back to.
                 x0 = sdar.at_positions(still_open, x0)
                 take = sdar.open_positions(cfg, confidence, still_open)
-                tokens = jnp.where(take, x0, tokens)
-                return (cache, tokens, still_open & ~take, moe + n,
-                        diffusion), None
+                return (jnp.where(take, x0, tokens), still_open & ~take,
+                        diffusion)
 
+        def denoise(carry, d):
+            cache, tokens, still_open, moe, diffusion = carry
+            cache, x, n = _forward(cfg, params, cache, tokens, pos,
+                                   write_mask, plan, kmesh)
+            tokens, still_open, diffusion = decide(x, tokens, still_open,
+                                                   diffusion, d)
+            return (cache, tokens, still_open, moe + n, diffusion), None
+
+        carry, done = (cache, tokens, is_open, moe, diffusion), 0
+        if last is not None:
+            # The block's first forward, peeled off the scan: 2 K rows a
+            # line, and the head on the open block's alone.
+            cache, x, n = _forward(
+                cfg, params, cache, jnp.concatenate([last, tokens], axis=1),
+                pos - k, write_mask, plan, kmesh)
+            tokens, still_open, diffusion = decide(
+                x[:, k:], tokens, is_open,
+                diffusion + _ran(lines, "diffusion_commits_riding"), 0)
+            carry, done = (cache, tokens, still_open, moe + n, diffusion), 1
         with tracing.part("stack"):
             (cache, tokens, _, moe, diffusion), _ = lax.scan(
-                denoise, (cache, tokens, is_open, moe, diffusion),
-                jnp.arange(cfg.denoising_steps))
-        # The commit: the clean block's K/V stay, and no row is read.
-        cache, _, n = _forward(cfg, params, cache, tokens, pos, write_mask,
-                               plan, kmesh)
-        with tracing.part("sample"):
-            given = ((~is_open) & write_mask[:, None]).sum().astype(jnp.int32)
-            diffusion = (diffusion + _ran(given, "diffusion_given")
-                         + _ran(lines, "diffusion_blocks",
-                                "diffusion_forwards", "diffusion_commits"))
-            return (cache, moe + n, diffusion), tokens
+                denoise, carry, jnp.arange(done, cfg.denoising_steps))
+        return (cache, tokens, moe, diffusion), tokens
 
     with tracing.part("stack"):
-        (cache, moe, diffusion), toks = lax.scan(
-            block, (cache, jnp.zeros((len(MOE_COUNTERS),), jnp.int32),
-                    jnp.zeros((len(DIFFUSION_COUNTERS),), jnp.int32)),
-            jnp.arange(steps))
+        carry, last = block(
+            (cache, None, jnp.zeros((len(MOE_COUNTERS),), jnp.int32),
+             jnp.zeros((len(DIFFUSION_COUNTERS),), jnp.int32)), 0)
+        toks = last[None]
+        if steps > 1:
+            carry, rest = lax.scan(block, carry, jnp.arange(1, steps))
+            toks = jnp.concatenate([toks, rest])
+        cache, last, moe, diffusion = carry
+    # The burst's last block is committed by a forward of its own: the clean
+    # block's K/V stay, and no row is read.
+    pos, plan = place(steps - 1)
+    cache, _, n = _forward(cfg, params, cache, last, pos, write_mask, plan,
+                           kmesh)
+    moe = moe + n
+    diffusion = diffusion + _ran(lines, "diffusion_forwards",
+                                 "diffusion_commits")
     return cache, toks, _counts(moe, diffusion)
+
+
+def burst_forwards(cfg: SdarConfig, steps: int) -> list[int]:
+    """The forwards of the stack each block of a burst of ``steps`` costs,
+    each a kernel call a layer at that block's lengths: its denoising
+    forwards (the first of every block but the burst's first is the wide
+    one that commits the block before, at this block's lengths), and the
+    last block's commit."""
+    return [cfg.denoising_steps] * (steps - 1) + [cfg.denoising_steps + 1]
 
 
 def _refuse(config) -> None:
@@ -284,6 +348,7 @@ SERVED = ServedModel(
                            "attention_lines": cfg.num_layers,
                            "diffusion_block_length": cfg.block_length},
     step=lambda cfg: (cfg.block_length, cfg.denoising_steps + 1),
+    burst_forwards=burst_forwards,
     # A line's committed blocks could be adopted at block-aligned lengths
     # and shipped as per-head K/V; neither is done (ROADMAP R6).
     kv_handoff=False,

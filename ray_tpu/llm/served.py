@@ -155,6 +155,12 @@ class ServedModel:
       first token comes when that step is read. A step's input is then
       known to the host before the step before it has run, and a burst is
       queued behind another with nothing handed over;
+    - ``burst_forwards(cfg, steps)``: the forwards each step of a burst of
+      ``steps`` steps costs, in order, where a burst is cheaper than its
+      steps alone (a forward that serves two of them is counted at the
+      step whose lengths it attends at). None: ``step``'s forwards each.
+      ``decode_steps``, the dispatch phase's ``steps`` and
+      ``kv_positions_read`` (a kernel call a layer a forward) count these;
     - ``kv_block(cfg, max_seq)``: the positions its decode attention
       fetches at a time, behind ``kv_positions_read``;
     - ``kv_handoff``: whether a line can be exported and imported as
@@ -198,6 +204,7 @@ class ServedModel:
     counters: tuple[str, ...] = ()
     constants: Callable | None = None
     step: Callable | None = None
+    burst_forwards: Callable | None = None
     kv_handoff: bool = True
     tensor_parallel: bool = False
     prefix_from_line: bool = True

@@ -33,7 +33,12 @@ long (``max_seq``), mostly not yet written, and shared by the
 Scores, the running maximum and sum, and the PV accumulation are float32;
 the operands stay in the cache's dtype. Query j of a slot sees key positions
 ``<= positions0 + j`` and ``< length``; a row that sees nothing (an empty
-slot) gives zeros.
+slot) gives zeros. Where the new rows are blocks that attend both ways
+(``rows_a_limit`` g > 1, a static fact about the input) the g rows of a
+block share their limit: query j sees ``<= positions0 + (j // g) * g``, so
+one read of the line serves two blocks whose ends differ
+(llm/sdar_serving.py: a block's clean rows beside the next block's open
+ones).
 
 **Heads of half a lane row.** A head of 64 values fills half of the 128
 lanes a row of the cache is stored in, so a ``[..., S, 64]`` cache costs a
@@ -129,7 +134,7 @@ def _sink_rows(sink, hkv: int, k: int):
 
 def decode_attention_reference(q, k_cache, v_cache, layer, lengths,
                                positions0, sm_scale: float | None = None,
-                               sink=None):
+                               sink=None, rows_a_limit: int = 1):
     """Masked softmax over the whole line, grouped like the kernel (no
     repeated K/V), float32 scores and accumulation."""
     b, h, k, d = q.shape
@@ -145,6 +150,8 @@ def decode_attention_reference(q, k_cache, v_cache, layer, lengths,
                         preferred_element_type=jnp.float32) * scale
     kpos = jnp.arange(s)[None, None, :]
     qpos = positions0[:, None] + jnp.arange(k)[None, :]      # [B, K]
+    if rows_a_limit > 1:    # the rows of a block share its first row's limit
+        qpos = qpos - jnp.arange(k)[None, :] % rows_a_limit
     visible = ((kpos <= qpos[:, :, None])
                & (kpos < lengths[:, None, None]))            # [B, K, S]
     visible = jnp.tile(visible, (1, h // hkv, 1))[:, None]   # rows g*K + j
@@ -226,7 +233,8 @@ def decode_plan_of(lengths, k_cache, *, kmesh: KernelMesh | None = None):
 def _decode_attention_kernel(len_ref, pos_ref, layer_ref, slot_ref, blk_ref,
                              first_ref, last_ref, q_ref, k_ref, v_ref, o_ref,
                              m_ref, l_ref, acc_ref, *, block: int,
-                             k_tokens: int, sm_scale: float, sink_ref=None):
+                             k_tokens: int, sm_scale: float,
+                             rows_a_limit: int = 1, sink_ref=None):
     """Grid step t: block ``blk[t]`` of slot ``slot[t]``, all KV heads. The
     one axis carries a slot's running maximum, sum and accumulator from its
     first live block to its last, so it is ``"arbitrary"``; the v5e has one
@@ -253,6 +261,8 @@ def _decode_attention_kernel(len_ref, pos_ref, layer_ref, slot_ref, blk_ref,
     kpos = blk * block + lax.broadcasted_iota(jnp.int32, (rows, block), 1)
     # Row r of the tile is query head g, token j, r = g * K + j.
     tok = lax.rem(lax.broadcasted_iota(jnp.int32, (rows, block), 0), k_tokens)
+    if rows_a_limit > 1:    # the rows of a block share its first row's limit
+        tok = tok - lax.rem(tok, rows_a_limit)
     visible = (kpos <= pos_ref[slot] + tok) & (kpos < length)
     for h in range(hkv):
         s = lax.dot_general(q_ref[h], k_ref[h], (((1,), (1,)), ((), ())),
@@ -295,7 +305,8 @@ def sunk_kernel(kernel, n_scalars: int):
 
 
 def _decode_attention_pallas(q, k_cache, v_cache, layer, lengths, positions0,
-                             plan, sink=None, *, sm_scale: float, block: int):
+                             plan, sink=None, *, sm_scale: float, block: int,
+                             rows_a_limit: int = 1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -326,7 +337,8 @@ def _decode_attention_pallas(q, k_cache, v_cache, layer, lengths, positions0,
     # The lengths go in as given: the plan keeps the walk inside the line,
     # and no position lies past its end for a longer length to unmask.
     kernel = functools.partial(_decode_attention_kernel, block=block,
-                               k_tokens=k, sm_scale=sm_scale)
+                               k_tokens=k, sm_scale=sm_scale,
+                               rows_a_limit=rows_a_limit)
     sunk = []
     if sink is not None:
         # One more operand before the queries, the same rows every step.
@@ -369,7 +381,8 @@ def decode_attention(q, k_cache, v_cache, layer, lengths, positions0, *,
                      plan: DecodePlan | None = None,
                      sm_scale: float | None = None,
                      kmesh: KernelMesh | None = None,
-                     block: int | None = None, sink=None):
+                     block: int | None = None, sink=None,
+                     rows_a_limit: int = 1):
     """q: [B, H, K, D] (K new tokens a slot, query head h of KV head
     ``h // (H // Hkv)``); k_cache, v_cache: [L, B, Hkv, S, D], or the packed
     stack [L, B, Hkv, S, 2 D] and None, the new rows
@@ -380,12 +393,18 @@ def decode_attention(q, k_cache, v_cache, layer, lengths, positions0, *,
     call plans for itself. ``block`` overrides :func:`decode_kv_block`
     (tests and the kernel's own benchmark). ``sink`` [H] float32 is the
     module docstring's: a logit a query head in every softmax's
-    denominator. Under a mesh of several devices
+    denominator. ``rows_a_limit`` g (static; it divides K): the new rows
+    in runs of g see the same keys, those ``<= positions0 + (j // g) * g``
+    (the module docstring's). Under a mesh of several devices
     pass its ``kmesh``: the kernel then runs on each device's heads."""
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    if q.shape[2] % rows_a_limit:
+        raise ValueError(f"decode_attention: rows_a_limit {rows_a_limit} "
+                         f"does not divide the {q.shape[2]} new rows")
     if kernel_backend() == "reference":
         return decode_attention_reference(q, k_cache, v_cache, layer,
-                                          lengths, positions0, scale, sink)
+                                          lengths, positions0, scale, sink,
+                                          rows_a_limit)
     s = k_cache.shape[3]
     block = block or decode_kv_block(s, k_cache.shape[-1],
                                      k_cache.dtype.itemsize)
@@ -395,7 +414,7 @@ def decode_attention(q, k_cache, v_cache, layer, lengths, positions0, *,
     if plan is None:
         plan = decode_plan(lengths, block, s, kmesh=kmesh)
     fn = functools.partial(_decode_attention_pallas, sm_scale=scale,
-                           block=block)
+                           block=block, rows_a_limit=rows_a_limit)
     sunk = () if sink is None else (sink,)
     if kmesh is not None:
         heads, cache = kmesh.heads_spec(4), _stack_spec(kmesh)

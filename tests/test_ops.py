@@ -824,6 +824,98 @@ def test_decode_attention_reads_a_packed_stack_as_keys_and_values(
     assert not np.asarray(got, np.float32)[lengths == 0].any()
 
 
+def _softmax_rows(q, kc, vc, lengths, limits, sink=None):
+    """float64 softmax a row over one layer's lines: row (b, h, j) sees the
+    keys at positions ``<= limits[b, j]`` and ``< lengths[b]``; ``sink``
+    [H] joins each head's denominator with no row of values."""
+    b, h, k, d = q.shape
+    hkv, s = kc.shape[1], kc.shape[2]
+    q, kc, vc = (np.asarray(a, np.float64) for a in (q, kc, vc))
+    kl, vl = (np.repeat(a, h // hkv, axis=1) for a in (kc, vc))
+    scores = np.einsum("bhkd,bhsd->bhks", q, kl) / np.sqrt(d)
+    kpos = np.arange(s)[None, None, :]
+    visible = ((kpos <= limits[:, :, None])
+               & (kpos < lengths[:, None, None]))[:, None]
+    scores = np.where(visible, scores, -np.inf)
+    top = scores.max(-1, keepdims=True)
+    if sink is not None:
+        sunk = np.asarray(sink, np.float64)[None, :, None, None]
+        top = np.maximum(top, sunk)
+    top = np.where(np.isfinite(top), top, 0.0)
+    p = np.where(visible, np.exp(scores - top), 0.0)
+    denom = p.sum(-1, keepdims=True)
+    if sink is not None:
+        denom = denom + np.exp(sunk - top)
+    return np.einsum("bhks,bhsd->bhkd", p / np.maximum(denom, 1e-30), vl)
+
+
+@pytest.mark.parametrize("backend", ["interpret", "reference"])
+@pytest.mark.parametrize("stack", ["two_stacks", "packed", "sink"])
+def test_decode_attention_s_rows_of_a_block_share_its_limit(stack, backend):
+    """``rows_a_limit``: 8 new rows a line in two blocks of 4, the mask's
+    position at the first block's last. The first 4 rows see the line up to
+    the second block's start, the last 4 through its end (the line's
+    length), whatever a row's place in its block; lines that end inside a
+    block of the walk, at its edge and one past it, a line of the 8 rows
+    alone, an empty slot; two stacks, the packed one, and a sink. The
+    kernel's body gives what the reference gives, and both what a dense
+    softmax under that mask does. At 1 (the default) the rows' limits are
+    a row apart, as ever."""
+    from ray_tpu.ops.decode_attention import (
+        decode_attention,
+        decode_attention_reference,
+    )
+
+    hkv, group, s, d, k, g = 2, 4, 384, 64, 8, 4
+    lengths = np.array([0, 8, 127, 128, 130, 133, 200, s], np.int32)
+    b = len(lengths)
+    kc, vc, packed = _packed_case(21, 2, b, hkv, s, d)
+    live = (np.arange(s)[None, :] < lengths[:, None])[None, :, None, :, None]
+    # Rows past a line's length hold garbage a wrong limit would show.
+    kc, vc = (jnp.where(live, a, fill).astype(jnp.bfloat16)
+              for a, fill in ((kc, 3e4), (vc, -3e4)))
+    if stack == "packed":
+        caches = (jnp.concatenate([kc, vc], axis=-1), None)
+    else:
+        caches = (kc, vc)
+    keys = jax.random.split(jax.random.PRNGKey(22), 2)
+    q = jax.random.normal(keys[0], (b, hkv * group, k, d), jnp.bfloat16)
+    sink = (2.0 * jax.random.normal(keys[1], (hkv * group,))
+            if stack == "sink" else None)
+    start = lengths - k
+    pos0 = jnp.asarray(start + (g - 1))
+    limits = start[:, None] + (g - 1) + np.arange(k)[None, :] // g * g
+    want = _softmax_rows(q, kc[1], vc[1], lengths, limits, sink)
+    with force_kernel_backend(backend):
+        got = decode_attention(q, *caches, 1, jnp.asarray(lengths), pos0,
+                               block=DECODE_BLOCK, sink=sink, rows_a_limit=g)
+        one = decode_attention(q, *caches, 1, jnp.asarray(lengths), pos0,
+                               block=DECODE_BLOCK, sink=sink, rows_a_limit=1)
+        default = decode_attention(q, *caches, 1, jnp.asarray(lengths), pos0,
+                                   block=DECODE_BLOCK, sink=sink)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, atol=3e-2)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(decode_attention_reference(
+            q, *caches, 1, jnp.asarray(lengths), pos0, sink=sink,
+            rows_a_limit=g), np.float32), atol=3e-2)
+    assert not np.asarray(got, np.float32)[0].any()
+    # the limit a row apart, bit for bit the call that names no grouping;
+    # rows 1 to 3 of the first block then see keys of the second
+    np.testing.assert_array_equal(np.asarray(one), np.asarray(default))
+    np.testing.assert_allclose(
+        np.asarray(default, np.float32),
+        _softmax_rows(q, kc[1], vc[1], lengths,
+                      start[:, None] + (g - 1) + np.arange(k)[None, :], sink),
+        atol=3e-2)
+    assert np.abs(np.asarray(default, np.float32)[1:, :, 1:g]
+                  - np.asarray(got, np.float32)[1:, :, 1:g]).max() > 0.1
+    with pytest.raises(ValueError, match="does not divide"):
+        decode_attention(q, *caches, 1, jnp.asarray(lengths), pos0,
+                         rows_a_limit=3)
+
+
 @pytest.mark.parametrize("backend", ["interpret", "reference"])
 @pytest.mark.parametrize("k_tokens", [1, 5])
 def test_kv_row_write_packs_a_heads_key_and_value_into_one_row(k_tokens,

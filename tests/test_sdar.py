@@ -358,31 +358,75 @@ def test_a_burst_decides_blocks_as_the_reference_does_and_commits_them(
             np.asarray(cache[leaf][:, 0, :, :28]),
             np.asarray(clean[leaf][:, 1, :, :28]), atol=1e-5)
     counts = dict(zip(serving.COUNTERS, np.asarray(counts).tolist()))
-    assert counts["diffusion_blocks"] == counts["diffusion_commits"] == 4
-    assert counts["diffusion_forwards"] == 20 and counts["diffusion_given"] == 3
-    assert counts["moe_layer_steps"] == 2 * 5 * CFG.num_layers
+    # two lines x two blocks; a line's first commit rode its second block's
+    # first forward, which is one forward of 8 rows
+    assert counts["diffusion_blocks"] == 4
+    assert counts["diffusion_commits"] == 2 == counts["diffusion_commits_riding"]
+    assert counts["diffusion_forwards"] == 18 and counts["diffusion_given"] == 3
+    assert counts["moe_layer_steps"] == 9 * CFG.num_layers
     assert counts["moe_picks"] == \
         20 * 4 * CFG.num_experts_per_tok * CFG.num_layers
 
 
+@pytest.mark.parametrize("backend", ["reference", "interpret"])
+@pytest.mark.parametrize("steps", [2, 4])
+def test_a_burst_leaves_what_its_blocks_leave_one_by_one(params, backend,
+                                                         steps):
+    """The commits that ride the next block's first forward are the commits:
+    after a burst of 2 or of 4 blocks the tokens and every committed
+    block's K/V are those of the same blocks run as bursts of one, each
+    committed by a forward of its own; the idle line's cache is untouched
+    by either."""
+    a, b = _prompt(22), _prompt(9, salt=1)
+    given, starts = {0: a[20:], 2: b[8:]}, {0: 20, 2: 8}
+    with force_kernel_backend(backend):
+        cache = _prefill(params, b, slot=2, cache=_prefill(params, a))
+        alone, want = jax.tree.map(jnp.copy, cache), []
+        for j in range(steps):
+            alone, toks, _ = _burst(
+                params, alone, given if j == 0 else {},
+                {slot: at + 4 * j for slot, at in starts.items()}, steps=1)
+            want.append(np.asarray(toks)[0])
+        cache, got, counts = _burst(params, cache, given, starts, steps=steps)
+    np.testing.assert_array_equal(np.asarray(got)[:, [0, 2]],
+                                  np.stack(want)[:, [0, 2]])
+    for leaf in ("k", "v"):
+        for slot, at in starts.items():
+            np.testing.assert_allclose(
+                np.asarray(cache[leaf][:, slot, :, :at + 4 * steps]),
+                np.asarray(alone[leaf][:, slot, :, :at + 4 * steps]),
+                atol=1e-5)
+        assert not np.asarray(cache[leaf][:, 1]).any()
+    counts = dict(zip(serving.COUNTERS, np.asarray(counts).tolist()))
+    assert counts["diffusion_commits_riding"] == 2 * (steps - 1)
+    assert counts["diffusion_commits"] == 2
+    assert counts["moe_layer_steps"] == \
+        sum(serving.burst_forwards(CFG, steps)) * CFG.num_layers
+
+
+@pytest.mark.parametrize("steps", [1, 2, 3])
 @pytest.mark.parametrize("rule,rows", [("sequential", 2),
                                        ("low_confidence_static", 4)])
-def test_the_counters_count_the_forwards_that_ran(params, rule, rows):
+def test_the_counters_count_the_forwards_that_ran(params, rule, rows, steps):
     """Forwards are counted where a forward runs and commits where the
-    commit does: at 2 denoising forwards a block the same code reads 3
-    forwards a block, a third of them commits, with no edit of a formula.
-    The head's rows are counted where the head runs: lines x the rows the
-    rule can read x denoising forwards, the idle line's none."""
+    commit does: at 2 denoising forwards a block a burst of n blocks reads
+    2 n + 1 forwards a line, one of them a commit of its own and n - 1
+    that carried the commit of the block before, with no edit of a
+    formula. The head's rows are counted where the head runs: lines x the
+    rows the rule can read x denoising forwards, the idle line's none."""
     cfg = replace(CFG, denoising_steps=2, remasking_strategy=rule)
     a, b = _prompt(9), _prompt(14, salt=1)
     cache = _prefill(params, b, slot=2, cache=_prefill(params, a))
     _, _, counts = _burst(params, cache, {0: a[8:], 2: b[12:]},
-                          {0: 8, 2: 12}, steps=2, cfg=cfg)
+                          {0: 8, 2: 12}, steps=steps, cfg=cfg)
     counts = dict(zip(serving.COUNTERS, np.asarray(counts).tolist()))
-    assert counts["diffusion_blocks"] == counts["diffusion_commits"] == 4
-    assert counts["diffusion_forwards"] == 12
-    assert counts["moe_layer_steps"] == 6 * cfg.num_layers
-    assert counts["diffusion_head_rows"] == 2 * rows * (2 * 2)
+    assert counts["diffusion_blocks"] == 2 * steps
+    assert counts["diffusion_commits"] == 2 * 1
+    assert counts["diffusion_commits_riding"] == 2 * (steps - 1)
+    assert counts["diffusion_forwards"] == 2 * (2 * steps + 1)
+    assert sum(serving.burst_forwards(cfg, steps)) == 2 * steps + 1
+    assert counts["moe_layer_steps"] == (2 * steps + 1) * cfg.num_layers
+    assert counts["diffusion_head_rows"] == 2 * rows * (2 * steps)
     assert counts["diffusion_head_rows"] == rows * (
         counts["diffusion_forwards"] - counts["diffusion_commits"])
 
@@ -653,16 +697,22 @@ def test_the_engine_s_tokens_are_the_reference_s(engine, weights):
         assert out == _want(weights, rule, prompts[name], n[name]), name
     stats = eng.stats()
     assert stats["requests_failed"] == 0 and stats["device_failures"] == 0
-    assert stats["diffusion_forwards"] == 5 * stats["diffusion_blocks"]
-    assert stats["diffusion_commits"] == stats["diffusion_blocks"]
+    # four denoising forwards a block, and a forward more for every commit
+    # that did not ride the next block's first one
+    assert stats["diffusion_forwards"] == \
+        4 * stats["diffusion_blocks"] + stats["diffusion_commits"]
+    assert stats["diffusion_commits"] + stats["diffusion_commits_riding"] \
+        == stats["diffusion_blocks"]
+    assert stats["diffusion_commits_riding"] > 0
     # every token streamed is a decode's: prefill gives none
     assert stats["decode_tokens"] == sum(n.values())
     assert stats["first_tokens"] == len(prompts)
     # the tails of 22, 3, 9, 11 and 14: 2 + 3 + 1 + 3 + 2
     assert stats["diffusion_given"] == 11
     assert stats["prompt_tokens_prefilled"] == 20 + 0 + 8 + 8 + 8 + 12
-    assert stats["decode_steps"] == 5 * stats["decode_dispatches"] * 2 or \
-        stats["decode_steps"] % 5 == 0
+    # a burst of n blocks is 4 n + 1 forwards
+    assert (stats["decode_steps"] - stats["decode_dispatches"]) % 4 == 0
+    assert stats["decode_dispatches"] < stats["decode_steps"] // 5
     assert (stats["moe_experts_held"], stats["attention_lines"],
             stats["diffusion_block_length"]) == (8, 3, 4)
     assert stats["prefix_hits"] == 0 and eng.router_prefix_blocks() is None
@@ -686,7 +736,8 @@ def test_every_schedule_gives_the_same_tokens(params, weights, burst,
             assert list(req.out_tokens) == _want(weights, "sequential", p,
                                                  11)
         stats = eng.stats()
-        assert stats["decode_steps"] % 5 == 0
+        assert (stats["decode_steps"] - stats["decode_dispatches"]) % 4 == 0
+        assert (stats["diffusion_commits_riding"] > 0) == (burst > 1)
         if not pipeline:
             assert stats["decode_dispatches_ahead"] == 0
         if burst == 1:
@@ -740,7 +791,10 @@ def test_the_served_model_says_what_it_is():
     assert served.step(CFG) == (4, 5) and not served.prefill_token
     assert served.decode_step is None and served.copy_prefix_kv is None
     assert not served.prefix_from_line and not served.kv_handoff
-    assert served.counters == serving.COUNTERS and len(served.counters) == 11
+    assert served.counters == serving.COUNTERS and len(served.counters) == 12
+    # a burst is cheaper than its blocks alone: the last one's commit only
+    assert served.burst_forwards(CFG, 1) == [5]
+    assert served.burst_forwards(CFG, 4) == [4, 4, 4, 5]
     full = replace(SdarConfig(), num_layers=6)
     assert served.kv_block(full, 1536) == 512
     cache = jax.eval_shape(lambda: served.init_cache(full, 128, 1536))

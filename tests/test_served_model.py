@@ -18,6 +18,7 @@ import typing
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
@@ -306,6 +307,9 @@ def test_a_token_a_step_is_what_a_model_is_served_by_unless_it_says(model):
                               max_seq_len=MAX_SEQ))
     try:
         assert (eng._step_positions, eng._step_forwards) == (1, 1)
+        # nothing said of a burst: its steps are what they are alone
+        assert served.burst_forwards is None
+        assert eng._burst_forwards(8).tolist() == [1] * 8
         req = eng.submit([5, 6, 7], SamplingParams(max_tokens=2))
         assert eng._prefill_len(req) == 3
         assert eng._input_tokens({0: req}).shape == (SLOTS,)
@@ -494,6 +498,60 @@ def test_a_model_may_say_its_step_is_a_block_and_its_prefill_gives_no_token():
             assert result.shape == (2, SLOTS, 4) and result.dtype == i32
 
 
+def test_a_model_may_say_what_a_burst_costs_and_is_counted_so():
+    """SDAR's bursts: 4 n + 1 forwards, the one more at the last block's
+    lengths. ``decode_steps``, the dispatch phase's ``steps`` and
+    ``kv_positions_read`` (a kernel call a layer a forward) count what the
+    model states, step by step at that step's lengths; a model that states
+    nothing counts its step's forwards each, as it did."""
+    from dataclasses import replace as dc_replace
+
+    from ray_tpu.util import tracing
+
+    _, cfg = _sdar()
+    served = served_model(cfg)
+    assert served.burst_forwards(cfg, 3) == [4, 4, 5]
+    eng = LLMEngine(LLMConfig(model=cfg, max_num_seqs=SLOTS,
+                              max_seq_len=MAX_SEQ, prefill_chunk=CHUNK,
+                              decode_burst=2, decode_pipeline=False))
+    tracing.clear()
+    tracing.enable_tracing()
+    try:
+        assert eng._burst_forwards(1).tolist() == [5]
+        assert eng._burst_forwards(2).tolist() == [4, 5]
+        out = eng.generate([7 + i for i in range(9)],
+                           SamplingParams(max_tokens=15))
+        assert len(out.token_ids) == 15
+        # a first block of 1 + 3, then 12: four blocks as two bursts of two
+        stats = eng.stats()
+        assert (stats["decode_dispatches"], stats["decode_steps"]) == (2, 18)
+        assert [s.attributes["steps"] for s in tracing.spans()
+                if s.name == "engine.decode_dispatch"] == [9, 9]
+        # one block of the line (MAX_SEQ) a kernel call
+        assert stats["kv_positions_read"] == 18 * eng._kv_block
+        # scripted, in blocks shorter than the line: a burst of 2 from
+        # positions 120 and 300 (slot 2 idle), in blocks of 128 of 512
+        eng.max_seq, eng._kv_block = 512, 128
+        before = eng.kv_positions_read, eng.kv_positions_reserved
+        eng._count_kv_positions(np.array([120, 300, 0]),
+                                np.array([True, True, False]), steps=2)
+        # lengths 124 and 304, 4 forwards; 128 (a whole block) and 308, 5
+        assert eng.kv_positions_read - before[0] == \
+            4 * (128 + 384) + 5 * (128 + 384)
+        assert eng.kv_positions_reserved - before[1] == 9 * SLOTS * 512
+        # stating nothing of a burst: 5 forwards a step, as before PR 61
+        eng.model = dc_replace(eng.model, burst_forwards=None)
+        assert eng._burst_forwards(2).tolist() == [5, 5]
+        eng._count_kv_positions(np.array([125, 300, 0]),
+                                np.array([True, True, False]), steps=2)
+        assert eng.kv_positions_read - before[0] == \
+            9 * 512 + 5 * (256 + 384) + 5 * (256 + 384)
+    finally:
+        tracing.disable_tracing()
+        tracing.clear()
+        eng.shutdown()
+
+
 def test_the_look_ahead_serves_blocks_as_the_serial_schedule_does():
     """The same equality as above with a step of 4: lines that join bursts
     in flight get the serial schedule's tokens, every token is a decode's,
@@ -520,8 +578,12 @@ def test_the_look_ahead_serves_blocks_as_the_serial_schedule_does():
     assert ahead["decode_dispatches_ahead"] > serial["decode_dispatches_ahead"]
     assert ahead["decode_tokens"] == serial["decode_tokens"] == sum(budgets)
     for s in stats:
-        assert s["decode_steps"] % 5 == 0
-        assert s["diffusion_forwards"] == 5 * s["diffusion_blocks"]
+        # a burst of n blocks is 4 n + 1 forwards: the last block's commit
+        assert (s["decode_steps"] - s["decode_dispatches"]) % 4 == 0
+        assert s["diffusion_forwards"] == \
+            4 * s["diffusion_blocks"] + s["diffusion_commits"]
+        assert s["diffusion_commits"] + s["diffusion_commits_riding"] == \
+            s["diffusion_blocks"]
         assert s["first_tokens"] == 5
         # whole blocks of the prompts: 4 + 40 + 20 + 8 + 28
         assert s["prompt_tokens_prefilled"] == 100

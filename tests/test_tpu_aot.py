@@ -837,9 +837,9 @@ def test_sdar_programs_move_no_cache_nor_expert_stack_and_fit_the_chip(
     those of the 128 rows the ``sequential`` rule can read, not of the
     block's 512, and at 74 MiB the compiler keeps them in fast memory, so
     they are no temporary at all). The prefill computes no head, so the
-    head is no argument of it. A block's five forwards attend at the same
-    lengths: one plan a block, outside the forwards' and the layers'
-    loops."""
+    head is no argument of it. A block's forwards attend at the same
+    lengths, the wide one that commits the block before among them: one
+    plan a block, outside the forwards' and the layers' loops."""
     from devbench import sdar_bench as bench
 
     cfg = bench.config()
@@ -874,12 +874,18 @@ def test_sdar_programs_move_no_cache_nor_expert_stack_and_fit_the_chip(
         kernels = ("decode_attention", "kv_row_write", "moe_grouped_matmul")
         in_place = {"custom-call"}
         assert _plans_outside_the_layer_loop(text)
-        # 128 lines x 4 rows x 8 picks: 32 rows an expert here too
-        assert _grouped_matmul_rows(text) == {(4096 // 64 + 128) * 64}
-        # a row a line goes through the head: no product of all 512 rows,
-        # and the 128 rows' logits are no 0.29 GiB of temporaries
+        # 128 lines x 4 rows x 8 picks: 32 rows an expert here too; and the
+        # forward a commit rides (PR 61), 8 rows a line: 64 an expert, in
+        # tiles of 128, one tile and one fetch of its weights an expert
+        assert _grouped_matmul_rows(text) == {(4096 // 64 + 128) * 64,
+                                              (8192 // 128 + 128) * 128}
+        # a row a line goes through the head: no product of all 512 rows
+        # (nor of the wide forward's 1,024), and the 128 rows' logits are
+        # no 0.29 GiB of temporaries: what there is (0.14 GiB) is the wide
+        # forward's rows in tiles, 24,576 x 2,048 (96 MiB), and its products
         assert "f32[128,151936]" in text and "f32[512,151936]" not in text
-        assert mem.temp_size_in_bytes < 128 * 151936 * 4
+        assert "f32[1024,151936]" not in text
+        assert mem.temp_size_in_bytes < 160 << 20
         assert 10.3 < total < 10.6
     for name in kernels:
         assert f'"{name}"' in text or f"%{name}." in text, name
