@@ -286,7 +286,7 @@ def rule() -> dict:
             sec = step_seconds_a_line(b, lines, backend, ks[6], 10)
             out["step"].append({
                 "form": ("channel_" if channel else "scalar_") + form,
-                "states_a_step": gd._states_a_step(nh, d, d),
+                "states_a_step": gd.states_a_step(nh, d, d),
                 "ms_per_line": round(sec * 1e3, 4),
                 "least_us": round(least_step * 1e6, 2),
                 "roofline_pct": round(100 * least_step / sec, 2)})

@@ -293,7 +293,7 @@ def rule() -> dict:
     for form, backend in (("kernel", "mosaic"), ("jnp", "reference")):
         sec = step_seconds_a_line(b, lines, backend, ks[6], 20)
         out["step"].append({
-            "form": form, "states_a_step": gd._states_a_step(nv, dk, dv),
+            "form": form, "states_a_step": gd.states_a_step(nv, dk, dv),
             "ms_per_line": round(sec * 1e3, 4),
             "least_us": round(least_step * 1e6, 2),
             "roofline_pct": round(100 * least_step / sec, 2)})

@@ -13,6 +13,7 @@ from dataclasses import dataclass, field
 from typing import Any, Union
 
 from ray_tpu.models.deepseek import DeepseekV2Config
+from ray_tpu.models.granite import GraniteConfig
 from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
@@ -39,6 +40,7 @@ SERVING_MODULES = {
     Phi4FlashConfig: "ray_tpu.llm.phi4flash_serving",
     MimoConfig: "ray_tpu.llm.mimo_serving",
     LingConfig: "ray_tpu.llm.ling_serving",
+    GraniteConfig: "ray_tpu.llm.granite_serving",
 }
 ModelConfig = Union[tuple(SERVING_MODULES)]
 

@@ -64,12 +64,11 @@ from ray_tpu.models.deepseek import kv_up_projections
 from ray_tpu.models.ling import KDA, LATENT, LingConfig
 from ray_tpu.models.mla import mla_project
 from ray_tpu.models.qwen3_next import conv_window
-from ray_tpu.models.routed import MOE_COUNTERS
 from ray_tpu.ops.gated_delta import gated_delta_chunk, gated_delta_step
 from ray_tpu.ops.kernels import KernelMesh
 from ray_tpu.util import tracing
 
-COUNTERS = MOE_COUNTERS + ("linear_state_updates", "linear_chunk_tokens")
+COUNTERS = linear_state.COUNTERS
 
 
 def _state_leaves(cfg: LingConfig) -> list[int]:
@@ -113,9 +112,7 @@ def _run(cfg, params, x, cache, operators, valid, own, kmesh):
     x, leaves, counts = ling.run_layers(
         cfg, params, x, operators, tuple(cache[k] for k in names), valid,
         kmesh)
-    with tracing.part("moe_combine"):
-        counts = jnp.concatenate(
-            [counts, cfg.linear_lines * jnp.stack(own).astype(jnp.int32)])
+    counts = linear_state.with_own_counts(counts, cfg.linear_lines, own)
     return x, dict(zip(names, leaves)), counts
 
 

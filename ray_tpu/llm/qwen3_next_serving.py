@@ -56,7 +56,6 @@ from ray_tpu.llm import linear_state
 from ray_tpu.llm.served import ServedModel, token_step_programs
 from ray_tpu.models import qwen3_next
 from ray_tpu.models.qwen3_next import ATTENTION, LINEAR, Qwen3NextConfig
-from ray_tpu.models.routed import MOE_COUNTERS
 from ray_tpu.ops.decode_attention import (
     decode_attention,
     decode_kv_block,
@@ -69,7 +68,7 @@ from ray_tpu.ops.prefill_attention import prefill_attention, prefill_kv_write
 from ray_tpu.ops.rope import rope_frequencies
 from ray_tpu.util import tracing
 
-COUNTERS = MOE_COUNTERS + ("linear_state_updates", "linear_chunk_tokens")
+COUNTERS = linear_state.COUNTERS
 
 
 def init_cache(cfg: Qwen3NextConfig, max_slots: int, max_seq: int):
@@ -93,9 +92,7 @@ def _run(cfg, params, x, cache, operators, valid, own, kmesh):
     x, leaves, counts = qwen3_next.run_layers(
         cfg, params, x, operators, tuple(cache[k] for k in _LEAVES), valid,
         kmesh)
-    with tracing.part("moe_combine"):
-        counts = jnp.concatenate(
-            [counts, cfg.linear_lines * jnp.stack(own).astype(jnp.int32)])
+    counts = linear_state.with_own_counts(counts, cfg.linear_lines, own)
     return x, dict(zip(_LEAVES, leaves)), counts
 
 

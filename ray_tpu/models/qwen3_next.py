@@ -378,14 +378,16 @@ def conv_window(prior, mixed):
         return jnp.concatenate([prior.astype(mixed.dtype), mixed], axis=1)
 
 
-def short_conv_silu(conv_w, window, s: int):
+def short_conv_silu(conv_w, window, s: int, bias=None):
     """A depthwise causal convolution, then ``silu``: ``conv_w`` [taps,
     channels] over ``window`` [B, taps - 1 + S, channels] at its last ``s``
-    positions, float32 (models/ling.py's too)."""
+    positions, float32 (models/ling.py's too), plus ``bias`` [channels]
+    where the family has one (models/granite.py's)."""
     taps = conv_w.astype(jnp.float32)
-    return jax.nn.silu(sum(
-        taps[j] * window[:, j:j + s].astype(jnp.float32)
-        for j in range(taps.shape[0])))
+    mixed = sum(taps[j] * window[:, j:j + s].astype(jnp.float32)
+                for j in range(taps.shape[0]))
+    return jax.nn.silu(
+        mixed if bias is None else mixed + bias.astype(jnp.float32))
 
 
 def unit_heads(x, heads: int):

@@ -118,7 +118,7 @@ SUB = 64
 BLOCK = 16
 F32_MAX_EXPONENT = 88.0
 # Bytes of states a grid step of the step's kernel holds, read once and
-# written once (:func:`_states_a_step`).
+# written once (:func:`states_a_step`).
 STEP_BLOCK_BYTES = 2 << 20
 PRECISION = lax.Precision.HIGHEST
 F32 = jnp.float32
@@ -716,8 +716,9 @@ def _step_kernel(line_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, s_ref,
     lax.fori_loop(0, heads // n, heads_in_a_row, 0)
 
 
-def _states_a_step(h: int, dk: int, dv: int) -> int:
-    """States a grid step of the step kernel: heads of one slot, the most
+def states_a_step(h: int, dk: int, dv: int) -> int:
+    """States a grid step of a step kernel (this module's and
+    ops/ssd.py's): heads of one slot, the most
     that are ``STEP_BLOCK_BYTES`` or less, divide ``h`` and tile the
     operands' ``[h, D]`` (a multiple of 8, or all of them); 0 where no
     number does. On a v5e a line of 96 slots x 32 heads of 128 x 128 takes
@@ -730,44 +731,58 @@ def _states_a_step(h: int, dk: int, dv: int) -> int:
                  if h % n == 0 and (n % 8 == 0 or n == h)), 0)
 
 
-def _step_pallas(q, k, v, g, beta, state, line):
+def step_in_place(kernel, name: str, line, rows, state, hs: int):
+    """A step kernel's call on a line of a state leaf ``[lines, B, h, Dk,
+    Dv]``, in place (this module's step and ops/ssd.py's): the grid is
+    (slots, ``h // hs``), the leaf is the last operand and aliased to the
+    second result, its block ``hs`` states of the slot on the line that the
+    prefetched scalar names, and the first result is ``[B, h, Dv]`` float32
+    in blocks of ``hs`` rows. ``rows`` are the operands before the leaf:
+    ``[B, h, D]`` in blocks of ``hs`` rows, or ``[B, h // hs, 1, n]`` a row
+    a block."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    b, h, dk, dv = state.shape[1:]
+    wide = lambda d: pl.BlockSpec(                            # noqa: E731
+        (None, hs, d), lambda i, j, line: (i, j, 0))
+    narrow = lambda n: pl.BlockSpec(                          # noqa: E731
+        (None, None, 1, n), lambda i, j, line: (i, j, 0, 0))
+    held = pl.BlockSpec((None, None, hs, dk, dv),
+                        lambda i, j, line: (line[0], i, j, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h // hs),
+            in_specs=[*((wide if a.ndim == 3 else narrow)(a.shape[-1])
+                        for a in rows), held],
+            out_specs=[wide(dv), held]),
+        out_shape=[jax.ShapeDtypeStruct((b, h, dv), F32),
+                   jax.ShapeDtypeStruct(state.shape, F32)],
+        # Operands count the scalar-prefetch argument: the leaf, written in
+        # place, comes after it and the rows.
+        input_output_aliases={len(rows) + 1: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=kernel_backend() == "interpret",
+        name=name,
+    )(jnp.asarray(line, jnp.int32).reshape(1), *rows, state)
+
+
+def _step_pallas(q, k, v, g, beta, state, line):
     b, h, dk = k.shape
-    dv = v.shape[-1]
-    hs = _states_a_step(h, dk, dv)
+    hs = states_a_step(h, dk, v.shape[-1])
+    # (before the rows' reshapes, where it stood before the call was shared:
+    # the program's equations keep their order)
+    line = jnp.asarray(line, jnp.int32).reshape(1)
 
     def a_step(a):                 # [B, H] -> [B, H // hs, 1, hs]
         return a.reshape(b, h // hs, 1, hs)
 
-    wide = lambda d: pl.BlockSpec(                            # noqa: E731
-        (None, hs, d), lambda i, j, line: (i, j, 0))
-    narrow = pl.BlockSpec((None, None, 1, hs),
-                          lambda i, j, line: (i, j, 0, 0))
-    held = pl.BlockSpec((None, None, hs, dk, dv),
-                        lambda i, j, line: (line[0], i, j, 0, 0))
-    channel = g.ndim == 3
-    o, state = pl.pallas_call(
-        _step_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, h // hs),
-            in_specs=[wide(dk), wide(dk), wide(dv),
-                      wide(dk) if channel else narrow, narrow, held],
-            out_specs=[wide(dv), held]),
-        out_shape=[jax.ShapeDtypeStruct((b, h, dv), F32),
-                   jax.ShapeDtypeStruct(state.shape, F32)],
-        # Operands count the scalar-prefetch argument: 6 is the leaf,
-        # written in place.
-        input_output_aliases={6: 1},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        interpret=kernel_backend() == "interpret",
-        name="gated_delta_step",
-    )(jnp.asarray(line, jnp.int32).reshape(1), q, k, v,
-      g if channel else a_step(g), a_step(beta), state)
-    return o, state
+    return step_in_place(
+        _step_kernel, "gated_delta_step", line,
+        [q, k, v, g if g.ndim == 3 else a_step(g), a_step(beta)], state, hs)
 
 
 def gated_delta_step(q, k, v, g, beta, state, line):
@@ -780,7 +795,7 @@ def gated_delta_step(q, k, v, g, beta, state, line):
     line's states after the position and every other line as it was)."""
     if kernel_backend() == "reference" or k.shape[-1] % 128 \
             or v.shape[-1] % 128 \
-            or not _states_a_step(*k.shape[1:], v.shape[-1]):
+            or not states_a_step(*k.shape[1:], v.shape[-1]):
         return gated_delta_step_reference(q, k, v, g, beta, state, line)
     q, k, v, g, beta = (a.astype(F32) for a in (q, k, v, g, beta))
     return _step_pallas(q, k, v, g, beta, state, line)

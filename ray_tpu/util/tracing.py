@@ -728,6 +728,9 @@ SUBPARTS = (
                      # and step forms and nothing else
     "kda_gate",      # inside ``linear_attn``, that decay's projection and
                      # its bounded sigmoid
+    "ssd",           # inside ``linear_attn``, Mamba-2's rule alone (a decay a
+                     # head, no correction): the chunk and step forms and
+                     # nothing else
     "ssm",           # a selective-scan operator inside ``attn``: its
                      # projections, the convolution, the step and the gate
     "ssm_scan",      # inside it, the selective scan alone: the chunk form

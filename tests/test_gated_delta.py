@@ -371,7 +371,7 @@ def test_the_step_s_kernel_is_chosen_by_the_backend_and_the_operands_shapes(
             e.primitive.name for e in _step_traced(h, dk, dv, channel)}
     assert "pallas_call" in {
         e.primitive.name for e in _step_traced(3, 256, 128, channel)}
-    assert [gd._states_a_step(*a) for a in (
+    assert [gd.states_a_step(*a) for a in (
         (32, 128, 128), (64, 128, 128), (4, 128, 128), (48, 128, 128),
         (16, 256, 256), (12, 256, 256), (2, 1024, 1024))] == \
         [32, 32, 4, 24, 8, 0, 0]

@@ -22,13 +22,15 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
-from ray_tpu.llm import deepseek_serving, lfm2_serving, llama_serving
+from ray_tpu.llm import deepseek_serving, granite_serving, lfm2_serving
+from ray_tpu.llm import llama_serving
 from ray_tpu.llm import ling_serving, longcat_serving, mimo_serving
 from ray_tpu.llm import ouro_serving, phi4flash_serving, qwen3_next_serving
 from ray_tpu.llm import sdar_serving
 from ray_tpu.llm.config import SERVING_MODULES, ModelConfig
 from ray_tpu.llm.served import ServedModel, served_model
 from ray_tpu.models.deepseek import DeepseekV2Config
+from ray_tpu.models.granite import GraniteConfig
 from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
@@ -40,6 +42,17 @@ from ray_tpu.models.qwen3_next import Qwen3NextConfig
 from ray_tpu.models.sdar import SdarConfig
 
 SLOTS, MAX_SEQ, CHUNK = 3, 64, 16
+
+
+@pytest.fixture(scope="module", autouse=True)
+def release_the_compiled_programs():
+    """After the module: its programs are its own (eleven models at sizes no
+    other file uses), and a compiled program keeps its memory mappings for
+    as long as JAX's caches hold it; a worker of the suite that never lets
+    one go runs into ``vm.max_map_count`` and XLA's CPU compile dies under
+    whichever test comes next (tests/test_granite.py, PERF.md section 7)."""
+    yield
+    jax.clear_caches()
 
 
 def _llama():
@@ -87,11 +100,16 @@ def _ling():
                                          max_seq_len=MAX_SEQ)
 
 
+def _granite():
+    return granite_serving, GraniteConfig.tiny(expert_shards=2,
+                                               max_seq_len=MAX_SEQ)
+
+
 # The models of a token a step, and all of them.
 MODELS = dict(argvalues=[_llama, _longcat, _ouro, _lfm2, _deepseek,
-                         _qwen3_next, _phi4flash, _mimo, _ling],
+                         _qwen3_next, _phi4flash, _mimo, _ling, _granite],
               ids=["llama", "longcat", "ouro", "lfm2", "deepseek",
-                   "qwen3_next", "phi4flash", "mimo", "ling"])
+                   "qwen3_next", "phi4flash", "mimo", "ling", "granite"])
 ALL_MODELS = dict(argvalues=MODELS["argvalues"] + [_sdar],
                   ids=MODELS["ids"] + ["sdar"])
 

@@ -24,9 +24,9 @@ from ray_tpu.models.ouro import OuroConfig
 from ray_tpu.util import tracing
 # The tiny served models and the programs' arguments, as the engine's own
 # contract test builds them.
-from test_served_model import (MAX_SEQ, SLOTS, _arguments, _deepseek, _lfm2,
-                               _ling, _llama, _longcat, _mimo, _ouro,
-                               _phi4flash, _qwen3_next)
+from test_served_model import (MAX_SEQ, SLOTS, _arguments, _deepseek,
+                               _granite, _lfm2, _ling, _llama, _longcat,
+                               _mimo, _ouro, _phi4flash, _qwen3_next)
 
 # What JAX itself puts on a name stack besides primitives' names.
 WRAPPERS = {"transpose", "jvp", "vmap", "pmap", "jit", "pjit", "while",
@@ -50,7 +50,8 @@ def test_the_finer_names_are_a_vocabulary_of_their_own():
     assert tracing.SUBPARTS == ("conv", "conv_state", "moe_shared",
                                 "latent_prefill", "linear_attn",
                                 "delta_rule", "linear_state", "kda_rule",
-                                "kda_gate", "ssm", "ssm_scan", "ssm_state",
+                                "kda_gate", "ssd", "ssm", "ssm_scan",
+                                "ssm_state",
                                 "window_attn", "cross_attn", "gmu")
     assert not set(tracing.SUBPARTS) & set(tracing.PARTS)
     assert all(re.fullmatch(r"[a-z_]+", p) for p in tracing.SUBPARTS)
@@ -110,6 +111,7 @@ SERVED = {
     "phi4flash": (_phi4flash, DENSE),
     "mimo": (_mimo, DENSE | ROUTED),
     "ling": (_ling, DENSE | ROUTED),
+    "granite": (_granite, DENSE | ROUTED),
 }
 LONGCAT_GONE = ("longcat.mla", "longcat.moe", "longcat.moe.experts")
 
@@ -191,6 +193,19 @@ def test_a_serving_program_opens_its_parts(model, program):
         assert not re.search(
             r"[^/\w](linear_attn|kda_rule|kda_gate|conv|linear_state"
             r"|moe_shared|latent_prefill)/", text)
+    if model == "granite":
+        # The Mamba-2 mixer's finer names lie inside ``attn``: the rule
+        # alone and the convolution inside ``linear_attn``, the state's and
+        # the window's reads and writes beside it; the attention layer's
+        # products stay plain ``attn``; the shared SwiGLU inside ``mlp``.
+        assert re.search(r"attn/linear_attn/dot_general", text)
+        assert re.search(r"attn/linear_attn/ssd/", text)
+        assert re.search(r"attn/linear_attn/conv/", text)
+        assert re.search(r"attn/linear_state/", text)
+        assert re.search(r"attn/dot_general", text)
+        assert re.search(r"mlp/moe_shared/dot_general", text)
+        assert not re.search(
+            r"[^/\w](linear_attn|ssd|conv|linear_state|moe_shared)/", text)
     if model == "mimo":
         # A window layer's attention proper (the ring's read with the sink,
         # a chunk's banded product) lies under ``window_attn`` inside
