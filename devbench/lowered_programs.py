@@ -80,11 +80,14 @@ def run(backend, names=()):
             params = jax.tree.map(sds, jax.eval_shape(lambda: placed(cfg, served.init_params(cfg, jax.random.PRNGKey(0)))))
             cache = jax.tree.map(sds, jax.eval_shape(lambda: served.init_cache(cfg, SLOTS, MAX_SEQ)))
             # a burst's tokens: [slots], or [slots, K] where a step is a block of K positions
-            token0 = (SLOTS,) if served.step is None else (SLOTS, served.step(cfg)[0])
+            token0 = arg((SLOTS,) if served.step is None else (SLOTS, served.step(cfg)[0]))
+            if getattr(served, "pending_step", False):
+                # with the step the line decided last and who has one (``ServedModel.pending_step``, since PR 63)
+                token0 = (token0, token0, arg((SLOTS,), jnp.bool_))
             progs = {
               "prefill_chunk": (cfg, params, cache, arg((CHUNK,)), arg(()), arg(()), arg(())),
               "decode_step": (cfg, params, cache, arg((SLOTS,)), arg((SLOTS,)), arg((SLOTS,), jnp.bool_)),
-              "decode_burst": (cfg, params, cache, arg(token0), arg((SLOTS,)), arg((SLOTS,), jnp.bool_), arg((SLOTS,), jnp.float32), arg((SLOTS,), jnp.float32), arg((2,), jnp.uint32), BURST, False),
+              "decode_burst": (cfg, params, cache, token0, arg((SLOTS,)), arg((SLOTS,), jnp.bool_), arg((SLOTS,), jnp.float32), arg((SLOTS,), jnp.float32), arg((2,), jnp.uint32), BURST, False),
             }
             if served.decode_step is None:
                 del progs["decode_step"]

@@ -12,9 +12,11 @@ device (nothing runs: no time comes out of it): XLA's ``memory_analysis``
 a cached position takes, the Mosaic calls, and every instruction whose
 result has the shape of a cache leaf, of one layer or line of it, or of a
 stacked weight, by opcode (a copy of one of those is up to 6.75 GiB moved a
-program). ``step``: wall milliseconds of one block (5 forwards) inside a
-burst of 2 at 128 lines of 256, 768 and 1,280 live positions, a forward
-being a fifth of it, and of a prefill chunk of 512 at 0 and 512 cached rows
+program). ``step``: wall milliseconds of a burst of 1 and of 2 blocks at 128
+lines of 256, 768 and 1,280 live positions (4 forwards a block since PR 63,
+every line with a block pending; 4 n + 1 a burst from PR 61, 5 n before),
+of a forward of 4 and of 8 rows a line alone, and of a prefill chunk of 512
+at 0 and 512 cached rows
 (the clock stops on a host read of the result); every line is prefilled
 with tokens of its own first, so the router reaches the 113 to 128 experts
 a layer that a served forward does (``experts_touched_per_layer``; lines
@@ -40,7 +42,14 @@ on its own product, as the program chains them. ``parity``: a block-causal
 prefill of 768 positions and 64 blocks decided by the program, against the
 float32 reference over the finished sequence, as the harness compares
 them: the reference's top logit minus its logit of the program's token,
-worst over the generated positions. ``engine`` and ``engine_stream``: the
+worst over the generated positions. ``handover`` (PR 63): at 768 live
+positions a forward of 4 rows a line, the wide one of 8 with every line's
+clean half live, with a quarter of them dead and with all dead (a tree that
+has no dead half times the first two), and a chain of four bursts of 2
+queued behind one another with nothing read between them, each handed the
+last block of the one before where the tree hands one over: milliseconds a
+burst, old (9 forwards, in ``.parent/``) against new (8).
+``engine`` and ``engine_stream``: the
 engine's schedule alone under the cell's traffic, no HTTP and no router: 128
 closed-loop clients on ``LLMEngine.submit`` for 20 s (the second with
 ``stream=True`` and a thread a client that drains the token queue, as the
@@ -106,8 +115,11 @@ def lowerings(cfg, params, cache, arg, slots: int = SLOTS) -> dict:
             cfg, params, cache, arg((b,)), arg(()), arg(()), arg(()))
 
     def burst(n):
+        token0 = arg((slots, cfg.block_length))
+        if _hands_over(serving):
+            token0 = (token0, token0, arg((slots,), jnp.bool_))
         return lambda: serving.decode_burst.lower(
-            cfg, params, cache, arg((slots, cfg.block_length)),
+            cfg, params, cache, token0,
             arg((slots,)), arg((slots,), jnp.bool_),
             arg((slots,), jnp.float32), arg((slots,), jnp.float32),
             arg((2,), jnp.uint32), n, False)
@@ -115,6 +127,26 @@ def lowerings(cfg, params, cache, arg, slots: int = SLOTS) -> dict:
     out = {f"prefill_chunk({b})": chunk(b) for b in BUCKETS}
     out.update({f"decode_burst({n})": burst(n) for n in BURSTS})
     return out
+
+
+def _hands_over(serving) -> bool:
+    """Whether the tree's bursts take the block before in (since PR 63)."""
+    return getattr(serving.SERVED, "pending_step", False)
+
+
+def _inputs(serving, token0, pending=None, has=None):
+    """A burst's first argument after the cache: ``token0`` alone on a tree
+    from before PR 63, with the block before and who has one since (every
+    line, of zeros, where nothing is said)."""
+    import jax.numpy as jnp
+
+    if not _hands_over(serving):
+        return token0
+    if pending is None:
+        pending = jnp.zeros_like(token0)
+    if has is None:
+        has = jnp.ones(token0.shape[:1], bool)
+    return token0, pending, has
 
 
 def big_shapes(cfg, slots: int = SLOTS) -> dict:
@@ -225,7 +257,8 @@ def _time_burst(serving, cfg, params, cache, live: int, blocks: int):
     for _ in range(4):
         t0 = time.monotonic()
         cache, toks, counts = serving.decode_burst(
-            cfg, params, cache, jnp.full((SLOTS, cfg.block_length), -1, i32),
+            cfg, params, cache,
+            _inputs(serving, jnp.full((SLOTS, cfg.block_length), -1, i32)),
             jnp.full((SLOTS,), live, i32), jnp.ones((SLOTS,), bool),
             temps, temps + 1.0, jax.random.PRNGKey(1), blocks, False)
         np.asarray(toks)
@@ -236,11 +269,14 @@ def _time_burst(serving, cfg, params, cache, live: int, blocks: int):
 FORWARDS = 8
 
 
-def _time_forwards(serving, cfg, params, cache, live: int, blocks: int):
+def _time_forwards(serving, cfg, params, cache, live: int, blocks: int,
+                   clean=None):
     """(cache, ms a forward) of ``_forward`` alone over ``blocks`` blocks a
-    line side by side (1: a denoising forward without its head, or a
-    commit; 2: the forward a commit rides), every line ``live`` long before
-    the rows: FORWARDS calls in one program, timed as ``_time_chunk``."""
+    line side by side (1: a denoising forward without its head; 2: the
+    forward a commit rides), every line ``live`` long before the rows:
+    FORWARDS calls in one program, timed as ``_time_chunk``. ``clean``
+    [SLOTS] bool: the wide forward as a burst's first (PR 63), each half
+    written under its own mask, the clean half dead where it says false."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -253,6 +289,8 @@ def _time_forwards(serving, cfg, params, cache, live: int, blocks: int):
     # the last of the side-by-side blocks starts at ``live``
     start = jnp.full((SLOTS,), live - (blocks - 1) * k, jnp.int32)
     tokens = jnp.full((SLOTS, blocks * k), cfg.mask_token_id, jnp.int32)
+    # (a tree from before PR 63 has no such argument)
+    clean = {} if clean is None else {"clean": clean}
 
     @partial(jax.jit, donate_argnums=(1,))
     def run(params, cache):
@@ -261,8 +299,8 @@ def _time_forwards(serving, cfg, params, cache, live: int, blocks: int):
         def one(i, carry):
             cache, seen = carry
             cache, x, _ = serving._forward(cfg, params, cache, tokens, start,
-                                           write, plan)
-            return cache, seen + x[0, 0, 0].astype(jnp.float32)
+                                           write, plan, **clean)
+            return cache, seen + x[0, -1, 0].astype(jnp.float32)
 
         return lax.fori_loop(0, FORWARDS, one, (cache, jnp.float32(0)))
 
@@ -574,14 +612,18 @@ def parity(prompt: int = 770, blocks: int = 64, seed: int = 7) -> dict:
     write[slot] = True
     temps = jnp.zeros((SLOTS,), jnp.float32)
     out_ids: list[int] = []
+    toks = jnp.zeros((1, SLOTS, k), jnp.int32)
     for j in range(0, blocks, 2):
         tok = np.full((SLOTS, k), -1, np.int32)
         pos = np.zeros(SLOTS, np.int32)
         pos[slot] = whole + j * k
         if j == 0:
             tok[slot, :prompt - whole] = ids[whole:]
+        # each burst is handed the last block of the one before (PR 63)
         cache, toks, _ = serving.decode_burst(
-            cfg, params, cache, jnp.asarray(tok), jnp.asarray(pos),
+            cfg, params, cache,
+            _inputs(serving, jnp.asarray(tok), toks[-1],
+                    jnp.asarray(write & (j > 0))), jnp.asarray(pos),
             jnp.asarray(write), temps, temps + 1.0, jax.random.PRNGKey(j), 2,
             False)
         out_ids += np.asarray(toks)[:, slot].reshape(-1).tolist()
@@ -596,6 +638,44 @@ def parity(prompt: int = 770, blocks: int = 64, seed: int = 7) -> dict:
     return {"mode": "parity", "prompt": prompt, "generated": len(out_ids),
             "device": jax.devices()[0].device_kind,
             "worst_margin": worst_margin(ids, out_ids, logits_of)}
+
+
+def handover(live: int = 768, bursts: int = 4) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    cfg, params, serving, cache = _programs()
+    cache = _own_tokens(serving, cfg, params, cache)
+    out = {"mode": "handover", "device": jax.devices()[0].device_kind,
+           "live": live, "hands_over": _hands_over(serving),
+           "forward_ms": {}}
+    forwards = [("4_rows", 1, None), ("8_rows", 2, None)]
+    if _hands_over(serving):
+        line = jnp.arange(SLOTS)
+        forwards += [("8_rows_two_writes", 2, line >= 0),
+                     ("8_rows_quarter_dead", 2, line % 4 != 0),
+                     ("8_rows_all_dead", 2, line < 0)]
+    for name, blocks, clean in forwards:
+        cache, out["forward_ms"][name] = _time_forwards(
+            serving, cfg, params, cache, live, blocks, clean)
+    i32, k, times = jnp.int32, cfg.block_length, []
+    temps = jnp.zeros((SLOTS,), jnp.float32)
+    open_block = jnp.full((SLOTS, k), -1, i32)
+    write = jnp.ones((SLOTS,), bool)
+    for _ in range(4):
+        toks, t0 = jnp.zeros((1, SLOTS, k), i32), time.monotonic()
+        for j in range(bursts):
+            cache, toks, counts = serving.decode_burst(
+                cfg, params, cache, _inputs(serving, open_block, toks[-1]),
+                jnp.full((SLOTS,), live + 2 * j * k, i32), write, temps,
+                temps + 1.0, jax.random.PRNGKey(j), 2, False)
+        np.asarray(toks)
+        times.append((time.monotonic() - t0) * 1e3 / bursts)
+    out["chain_ms_a_burst"] = round(min(times[1:]), 2)
+    out["forwards_a_burst"] = int(counts[4]) // cfg.num_layers
+    out["burst_counts"] = [int(n) for n in counts]
+    return out
 
 
 def engine(stream: bool = False, window_s: float = 20.0) -> dict:
@@ -672,7 +752,7 @@ def engine(stream: bool = False, window_s: float = 20.0) -> dict:
 
 
 MODES = {"aot": aot, "step": step, "glue": glue, "head": head,
-         "parity": parity,
+         "parity": parity, "handover": handover,
          "engine": engine, "engine_stream": partial(engine, stream=True)}
 
 if __name__ == "__main__":

@@ -105,8 +105,9 @@ def _lcp(a, b, cap: int) -> int:
 @jax.jit
 @tracing.part("sample")
 def _last_row(toks):
-    """A burst's tokens [steps, B] -> its last step's [B]: every continuing
-    line's input to the next burst, left on the device."""
+    """A burst's tokens [steps, B] -> its last step's [B] ([steps, B, K] ->
+    [B, K] from a model whose step is K positions): what every continuing
+    line hands to the next burst, left on the device."""
     return toks[-1]
 
 
@@ -161,7 +162,8 @@ class _InFlight:
     """A dispatched program whose tokens the host has not read: a prompt's
     first token (``steps`` 0, ``toks`` [1]) or a burst of ``steps`` steps
     (``toks`` [steps, slots], or [steps, slots, K] from a model whose step
-    is K positions; ``last_row`` its final row, the next burst's input).
+    is K positions; ``last_row`` its final row, handed to the next burst:
+    a line's input token, or its ServedModel.pending_step).
     ``reqs`` are the lines it computes for, by slot; ``counts`` the model's
     own (ServedModel.counters), fetched with the tokens."""
     reqs: dict[int, GenerationRequest]
@@ -786,9 +788,9 @@ class LLMEngine:
                   if self._steps_left(r) > 0}
         if not active:
             return False
-        # A line whose newest token only the host has (a KV import) cannot
+        # A line whose newest step only the host has (a KV import) cannot
         # ride behind a burst that runs without it.
-        host_only = (self.model.prefill_token
+        host_only = ((self.model.prefill_token or self.model.pending_step)
                      and any(e.steps for e in self._in_flight)
                      and any(r.out_tokens and not r.ahead
                              for r in active.values()))
@@ -1348,7 +1350,9 @@ class LLMEngine:
         Where prefill gives no token (ServedModel.prefill_token) a step's
         input is no step's output: int32[slots, K], the prompt's tokens
         that lie in the step (a first step's leading places) and -1 at
-        every position the step has to decide."""
+        every position the step has to decide; with the step before it
+        where the model takes one in (_pending_step)."""
+        prev = next((e for e in reversed(self._in_flight) if e.steps), None)
         if not self.model.prefill_token:
             k = self._step_positions
             tokens = np.full((self.max_slots, k), -1, np.int32)
@@ -1356,8 +1360,10 @@ class LLMEngine:
                 pos = self._position(req)
                 given = req.prompt_ids[pos:pos + k]
                 tokens[slot, :len(given)] = given
+            if self.model.pending_step:
+                return (jnp.asarray(tokens),
+                        *self._pending_step(active, prev))
             return jnp.asarray(tokens)
-        prev = next((e for e in reversed(self._in_flight) if e.steps), None)
         tokens = (prev.last_row if prev is not None
                   else self._host_tokens(active))
         for entry in self._in_flight:
@@ -1368,6 +1374,33 @@ class LLMEngine:
                     tokens = _join_token(tokens, entry.toks,
                                          jnp.int32(slot))
         return tokens
+
+    def _pending_step(self, active: dict[int, GenerationRequest], prev):
+        """(pending int32[slots, K], has_pending bool[slots]) of a model
+        with a ServedModel.pending_step: the step each line decided last,
+        handed over like a token, and whether the line has one. Behind a
+        burst in flight (``prev``, the newest) it is that burst's last row
+        as it lies on the device, for the lines that were in it: not for
+        one that joins from its prefill, as a slot's new tenant does
+        whatever the burst computed for the slot. With nothing in flight
+        the host has every token, and a line that has decoded has its last
+        step among them. A finished line's last step goes to nobody, and a
+        device failure clears what was in flight with the cache."""
+        has_pending = np.zeros((self.max_slots,), bool)
+        if prev is not None:
+            for slot, req in active.items():
+                has_pending[slot] = prev.reqs.get(slot) is req
+            return prev.last_row, jnp.asarray(has_pending)
+        k = self._step_positions
+        pending = np.zeros((self.max_slots, k), np.int32)
+        for slot, req in active.items():
+            if req.out_tokens:
+                pos, p = self._position(req), len(req.prompt_ids)
+                has_pending[slot] = True
+                pending[slot] = (
+                    list(req.prompt_ids[pos - k:pos])
+                    + req.out_tokens[max(pos - k - p, 0):pos - p])
+        return jnp.asarray(pending), jnp.asarray(has_pending)
 
     def _count_kv_positions(self, positions, write, steps: int,
                             k: int | None = None) -> None:
@@ -1433,7 +1466,7 @@ class LLMEngine:
                 # not: a lone request then walks the helper at every burst
                 # length, and no later mix of lengths compiles anything.
                 last_row = (_last_row(toks) if self.model.prefill_token
-                            else None)
+                            or self.model.pending_step else None)
         except Exception as e:  # noqa: BLE001 - cache donated & lost
             logger.exception("burst decode failed (%d active, burst %d)",
                              len(active), burst)

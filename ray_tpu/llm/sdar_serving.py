@@ -4,8 +4,8 @@ generation by diffusion over blocks against the per-head K/V slot cache.
 The cache is the Llama one, ``{"k", "v"}`` ``[layers, slots, kv_heads,
 max_seq, head_dim]``. What differs is the step (``ServedModel.step``): one
 step of one line takes a whole block of ``block_length`` positions in and
-gives it back decided, and costs ``denoising_steps + 1`` forwards of the
-stack by itself, each over the block's rows:
+gives it back decided, and costs ``denoising_steps`` forwards of the stack,
+each over the block's rows:
 
 - a **denoising forward** writes the K/V of the block's current content
   (its decided positions, the mask token at the open ones) at the block's
@@ -20,29 +20,40 @@ stack by itself, each over the block's rows:
   confidences), and no draw is made where no line has a temperature. Its
   K/V rows are overwritten by the next forward and never read by another
   block;
-- the **commit forward** runs the decided block once more; its K/V stay,
-  and no head is computed.
+- the **commit** runs the decided block once more, clean; its K/V stay,
+  and no head is computed. No forward is spent on it: a block's commit
+  **rides** the next block's first denoising forward.
 
-Inside a burst the lines move in lockstep, and a block's commit **rides**
-the next block's first denoising forward: that forward takes ``2 K`` rows a
-line, the clean block's and then the open one's, writes the K/V of both and
-attends the line once (``decode_attention``'s ``rows_a_limit``: the clean
-rows see keys up to the open block's start, the open rows through their
-block's end, so each row sees what it saw in a forward of its own, the
-clean block's K/V of the same layer included). The clean rows' K/V stay;
-the head runs on the open block's rows. So a burst of ``n`` blocks is ``n x
-denoising_steps + 1`` forwards, ``n - 1`` of them wide
-(:func:`burst_forwards`), where blocks by themselves cost ``n x
-(denoising_steps + 1)``; only the burst's last block is committed by a
-forward of its own, and in a burst of one block nothing rides.
+The lines of a burst move in lockstep. A block's first denoising forward
+takes ``2 K`` rows a line, the clean block before it and then the open
+one, writes the K/V of both and attends the line once
+(``decode_attention``'s ``rows_a_limit``: the clean rows see keys up to the
+open block's start, the open rows through their block's end, so each row
+sees what it saw in a forward of its own, the clean block's K/V of the same
+layer included). The clean rows' K/V stay; the head runs on the open
+block's rows. A burst's **last** block is left decided and not committed:
+its tokens go back to the scheduler with the burst's other tokens, stay on
+the device as well, and come in again with the next burst
+(``ServedModel.pending_step``: ``pending`` [B, K] beside ``token0``, and
+``has_pending`` [B]), whose first forward commits them. So a burst of ``n``
+blocks is ``n x denoising_steps`` forwards (:func:`burst_forwards`), the
+first of every block a wide one.
+
+A line with nothing pending (one that joins from its prefill, a slot's new
+tenant) goes through the same wide forward with its clean half **dead**:
+those ``K`` rows write no K/V (they would overwrite the prompt's last whole
+block, and lie before the line where its prompt is shorter than a block),
+are routed to no expert, are counted nowhere, and nothing reads what they
+give (an attention over no key gives zeros, not NaN). And a line's last
+block is never committed: a finished line is in no later burst, so nobody
+carries its commit, and nothing reads a finished line's K/V
+(``prefix_from_line`` and ``kv_handoff`` are both false, and have to be).
 
 Which positions are open is a mask by position, carried from forward to
 forward: never a comparison of ids with the mask id, which a prompt may
 contain like any other. A line's first block may come partly decided: the
 prompt's tokens past its last whole block (``token0`` holds them in their
-places, -1 at an open position). Every later block starts all open, so a
-burst's input depends on nothing an earlier burst computed but the cache:
-the engine queues one behind the other with nothing handed over.
+places, -1 at an open position). Every later block starts all open.
 
 A prompt's whole blocks are prefilled under the same block-causal mask
 (ops/prefill_attention.py, ``block``) and yield no token
@@ -78,11 +89,12 @@ from ray_tpu.ops.prefill_attention import prefill_attention, prefill_kv_write
 from ray_tpu.ops.rope import rope_frequencies
 from ray_tpu.util import tracing
 
-# Line-blocks run, line-forwards (a forward of two blocks' rows once; the
-# commits that cost one of their own among them), those line-commits, the
-# positions of first blocks that the prompt had decided, the rows that went
-# through the head, and the line-commits that rode the next block's first
-# forward.
+# Line-blocks run, line-forwards (a forward of two blocks' rows once), the
+# line-commits that cost a forward of their own (none: the benchmark's
+# share of them reads this name), the positions of first blocks that the
+# prompt had decided, the rows that went through the head, and the
+# line-commits that rode the next block's first forward (a dead clean half
+# is no commit of either kind).
 DIFFUSION_COUNTERS = ("diffusion_blocks", "diffusion_forwards",
                       "diffusion_commits", "diffusion_given",
                       "diffusion_head_rows", "diffusion_commits_riding")
@@ -137,7 +149,7 @@ def prefill_chunk(cfg: SdarConfig, params, cache, tokens, kv_len, length,
 
 
 def _forward(cfg: SdarConfig, params, cache, tokens, start, write_mask,
-             plan, kmesh=None):
+             plan, kmesh=None, clean=None):
     """One forward of every line's rows: tokens [B, R] at positions
     ``start + arange(R)``, R one block of K (``start`` the block's, a
     multiple of K) or two of them side by side, the earlier one clean.
@@ -145,7 +157,11 @@ def _forward(cfg: SdarConfig, params, cache, tokens, start, write_mask,
     through its own block's end. Returns (cache, the stack's output [B, R,
     H] before the final norm, the routed layers' counts). A line with
     ``write_mask`` false writes nothing, is routed nowhere, and its rows
-    mean nothing."""
+    mean nothing. ``clean`` [B] bool (two blocks; None: every line's):
+    whether the line's earlier block is there; where it is not, that half
+    is dead (no K/V written, its rows routed nowhere and meaning nothing,
+    ``start`` may be negative) and the later block is a forward of its
+    own."""
     b, r = tokens.shape
     k = cfg.block_length
     with tracing.part("embed"):
@@ -154,18 +170,31 @@ def _forward(cfg: SdarConfig, params, cache, tokens, start, write_mask,
         positions = start[:, None] + jnp.arange(r)[None, :]
         lengths = jnp.where(write_mask, start + r, 0)
         valid = jnp.broadcast_to(write_mask[:, None], (b, r))
+        if clean is not None:
+            valid = valid & (clean[:, None] | (jnp.arange(r) >= k)[None, :])
         inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta)
 
-    def attention(layer, ap, xn, kv):
+    def write(kv, kk, v, layer):
         k_all, v_all = kv
+        if clean is None:
+            return kv_row_write(k_all, v_all, kk, v, layer, start,
+                                write_mask, kmesh=kmesh)
+        # Each half under its own mask: the kernel's mask is a line's.
+        k_all, v_all = kv_row_write(
+            k_all, v_all, kk[:, :, :k], v[:, :, :k], layer, start,
+            write_mask & clean, kmesh=kmesh)
+        return kv_row_write(k_all, v_all, kk[:, :, k:], v[:, :, k:], layer,
+                            start + k, write_mask, kmesh=kmesh)
+
+    def attention(layer, ap, xn, kv):
         q, kk, v = attention_heads(cfg, ap, xn, positions, inv_freq)
         with tracing.part("cache"):
-            k_all, v_all = kv_row_write(k_all, v_all, kk, v, layer,
-                                        start, write_mask, kmesh=kmesh)
+            k_all, v_all = write(kv, kk, v, layer)
         # The mask's position is the first block's last. One block: row j
         # sees keys through start + k - 1 + j, and none lies past the
         # line's length. Two: the rows of a block share its limit, the
-        # clean block's stops where the open one starts.
+        # clean block's stops where the open one starts (a dead half at the
+        # line's very start sees no key, and gives zeros).
         o = decode_attention(q, k_all, v_all, layer, lengths,
                              start + (k - 1), plan=plan, kmesh=kmesh,
                              rows_a_limit=1 if r == k else k)
@@ -211,44 +240,48 @@ def _choose(cfg: SdarConfig, logits, temps, top_ps, key, need_top_p: bool):
 
 @partial(jax.jit, static_argnums=(0, 9, 10), static_argnames=("kmesh",),
          donate_argnums=(2,))
-def decode_burst(cfg: SdarConfig, params, cache, token0, positions0,
+def decode_burst(cfg: SdarConfig, params, cache, inputs, positions0,
                  write_mask, temps, top_ps, key, steps: int,
                  need_top_p: bool = True, *,
                  kmesh: KernelMesh | None = None):
-    """``steps`` blocks of every line in ONE dispatch: ``denoising_steps``
-    denoising forwards a block and one commit forward at the end; every
-    block but the last is committed by the next block's first forward
-    (:func:`burst_forwards`). token0 [B, K]: what the first block has
-    decided already (a prompt's tail in its places, -1 at an open
-    position); positions0 [B]: the first block's start. Returns (cache,
-    tokens [steps, B, K], counts)."""
+    """``steps`` blocks of every line in ONE dispatch, ``denoising_steps``
+    forwards a block (:func:`burst_forwards`): a block's first forward
+    commits the block before it, and the last block is left for the next
+    burst's first forward. ``inputs`` is (token0, pending, has_pending):
+    token0 [B, K], what the first block has decided already (a prompt's
+    tail in its places, -1 at an open position); pending [B, K], the block
+    the line decided last and nobody has committed (the burst before's last
+    tokens), at ``positions0 - K``; has_pending [B], whether the line has
+    one. positions0 [B]: the first block's start. Returns (cache, tokens
+    [steps, B, K], counts); ``tokens[-1]`` is the next burst's ``pending``."""
+    token0, pending, has_pending = inputs
     k = token0.shape[1]
     mask_id = jnp.int32(cfg.mask_token_id)
     with tracing.part("attn"):
         lines = write_mask.sum().astype(jnp.int32)
+        has_pending = has_pending & write_mask
 
-    def place(j):
-        """(Block j's start, the walk of the live blocks through its end):
-        every forward of the block attends at the same lengths, so the walk
-        is planned once a block."""
+    def block(carry, j, clean=None):
+        """Block j through its denoising forwards. The carry's ``last``
+        [B, K] is the block before, decided and not committed: its rows
+        ride this block's first forward, and their K/V are its commit.
+        ``clean`` [B] (the burst's first block): the lines that have one."""
+        cache, last, moe, diffusion = carry
         pos = positions0 + j * k
         with tracing.part("attn"):
-            return pos, decode_plan_of(jnp.where(write_mask, pos + k, 0),
-                                       cache["k"], kmesh=kmesh)
-
-    def block(carry, j):
-        """Block j through its denoising forwards. The carry's ``last``
-        [B, K] is the block before, decided and not committed (None: there
-        is none, the burst's first block): its rows ride this block's first
-        forward, and their K/V are its commit."""
-        cache, last, moe, diffusion = carry
-        pos, plan = place(j)
+            # Every forward of the block attends at the same lengths, so
+            # the walk of the live blocks is planned once a block.
+            plan = decode_plan_of(jnp.where(write_mask, pos + k, 0),
+                                  cache["k"], kmesh=kmesh)
         with tracing.part("sample"):
             is_open = (token0 < 0) | (j > 0)
             tokens = jnp.where(is_open, mask_id, token0)
             given = ((~is_open) & write_mask[:, None]).sum().astype(jnp.int32)
+            riding = (lines if clean is None
+                      else clean.sum().astype(jnp.int32))
             diffusion = (diffusion + _ran(given, "diffusion_given")
-                         + _ran(lines, "diffusion_blocks"))
+                         + _ran(lines, "diffusion_blocks")
+                         + _ran(riding, "diffusion_commits_riding"))
 
         def decide(x, tokens, still_open, diffusion, d):
             """What forward ``d``'s output x [B, K, H] decides: (tokens,
@@ -279,49 +312,38 @@ def decode_burst(cfg: SdarConfig, params, cache, token0, positions0,
                                                    diffusion, d)
             return (cache, tokens, still_open, moe + n, diffusion), None
 
-        carry, done = (cache, tokens, is_open, moe, diffusion), 0
-        if last is not None:
-            # The block's first forward, peeled off the scan: 2 K rows a
-            # line, and the head on the open block's alone.
-            cache, x, n = _forward(
-                cfg, params, cache, jnp.concatenate([last, tokens], axis=1),
-                pos - k, write_mask, plan, kmesh)
-            tokens, still_open, diffusion = decide(
-                x[:, k:], tokens, is_open,
-                diffusion + _ran(lines, "diffusion_commits_riding"), 0)
-            carry, done = (cache, tokens, still_open, moe + n, diffusion), 1
+        # The block's first forward, peeled off the scan: 2 K rows a line,
+        # and the head on the open block's alone.
+        cache, x, n = _forward(
+            cfg, params, cache, jnp.concatenate([last, tokens], axis=1),
+            pos - k, write_mask, plan, kmesh, clean)
+        tokens, still_open, diffusion = decide(x[:, k:], tokens, is_open,
+                                               diffusion, 0)
         with tracing.part("stack"):
             (cache, tokens, _, moe, diffusion), _ = lax.scan(
-                denoise, carry, jnp.arange(done, cfg.denoising_steps))
+                denoise, (cache, tokens, still_open, moe + n, diffusion),
+                jnp.arange(1, cfg.denoising_steps))
         return (cache, tokens, moe, diffusion), tokens
 
     with tracing.part("stack"):
-        carry, last = block(
-            (cache, None, jnp.zeros((len(MOE_COUNTERS),), jnp.int32),
-             jnp.zeros((len(DIFFUSION_COUNTERS),), jnp.int32)), 0)
-        toks = last[None]
+        carry, first = block(
+            (cache, pending, jnp.zeros((len(MOE_COUNTERS),), jnp.int32),
+             jnp.zeros((len(DIFFUSION_COUNTERS),), jnp.int32)), 0,
+            has_pending)
+        toks = first[None]
         if steps > 1:
             carry, rest = lax.scan(block, carry, jnp.arange(1, steps))
             toks = jnp.concatenate([toks, rest])
-        cache, last, moe, diffusion = carry
-    # The burst's last block is committed by a forward of its own: the clean
-    # block's K/V stay, and no row is read.
-    pos, plan = place(steps - 1)
-    cache, _, n = _forward(cfg, params, cache, last, pos, write_mask, plan,
-                           kmesh)
-    moe = moe + n
-    diffusion = diffusion + _ran(lines, "diffusion_forwards",
-                                 "diffusion_commits")
+        cache, _, moe, diffusion = carry
     return cache, toks, _counts(moe, diffusion)
 
 
 def burst_forwards(cfg: SdarConfig, steps: int) -> list[int]:
     """The forwards of the stack each block of a burst of ``steps`` costs,
     each a kernel call a layer at that block's lengths: its denoising
-    forwards (the first of every block but the burst's first is the wide
-    one that commits the block before, at this block's lengths), and the
-    last block's commit."""
-    return [cfg.denoising_steps] * (steps - 1) + [cfg.denoising_steps + 1]
+    forwards, the first of them the wide one that commits the block before
+    (the burst before's last, for the first block)."""
+    return [cfg.denoising_steps] * steps
 
 
 def _refuse(config) -> None:
@@ -347,10 +369,15 @@ SERVED = ServedModel(
     constants=lambda cfg: {"moe_experts_held": cfg.num_experts,
                            "attention_lines": cfg.num_layers,
                            "diffusion_block_length": cfg.block_length},
-    step=lambda cfg: (cfg.block_length, cfg.denoising_steps + 1),
+    step=lambda cfg: (cfg.block_length, cfg.denoising_steps),
     burst_forwards=burst_forwards,
-    # A line's committed blocks could be adopted at block-aligned lengths
-    # and shipped as per-head K/V; neither is done (ROADMAP R6).
+    pending_step=True,
+    # Both load-bearing: a line's newest block is decided and not committed
+    # (the next burst's first forward commits it, and a finished line's last
+    # block nobody), so its K/V rows there are a denoising forward's and no
+    # reader but the line's own next burst may come by them. A line's
+    # committed blocks could be adopted at block-aligned lengths and shipped
+    # as per-head K/V once the pending block went with them (ROADMAP R6).
     kv_handoff=False,
     prefix_from_line=False,
     refuse=_refuse,

@@ -152,9 +152,25 @@ class ServedModel:
       ``prefill_chunk`` returns None for the logits), nothing is emitted
       for them, and the tokens past them ride into the line's first step
       in their places (-1 at every position a step has to decide); the
-      first token comes when that step is read. A step's input is then
-      known to the host before the step before it has run, and a burst is
-      queued behind another with nothing handed over;
+      first token comes when that step is read. What a step has to
+      decide is then known to the host before the step before it has run;
+    - ``pending_step`` (a model that states its ``step``): a burst takes
+      in, beside its first step's input, the step each line decided last,
+      and finishes what that step left undone (a block's K/V, committed by
+      the next block's first forward). ``decode_burst`` then takes
+      ``(token0, pending, has_pending)`` where it took ``token0``:
+      ``pending`` int32 ``[slots, K]`` and ``has_pending`` bool
+      ``[slots]``, true for a line that has been in a decode burst before
+      and false for one that joins from its prefill (its ``pending`` row
+      means nothing). The scheduler hands the step over as it hands a
+      token over for the models of a token a step: the last step of the
+      newest burst in flight, left on the device and never read by the
+      host before the dispatch; from the host's own tokens where nothing
+      is in flight. A line's last step is handed to nobody (a finished
+      line is in no later burst) and a slot's new tenant, or a cache
+      rebuilt after a device failure, starts with nothing pending; so
+      nothing but the line's own next burst may read the positions of its
+      newest step (``kv_handoff`` and ``prefix_from_line`` both false);
     - ``burst_forwards(cfg, steps)``: the forwards each step of a burst of
       ``steps`` steps costs, in order, where a burst is cheaper than its
       steps alone (a forward that serves two of them is counted at the
@@ -213,6 +229,7 @@ class ServedModel:
     refuse: Callable | None = None
     mixed_burst: Callable | None = None
     program_params: Callable | None = None
+    pending_step: bool = False
 
     def __post_init__(self):
         if self.mixed_burst is not None and self.step is not None:
@@ -226,6 +243,13 @@ class ServedModel:
                 "of the two: a step of several positions samples on the "
                 "device and has no single-step program, and a model with "
                 "such a program takes a token in and gives a token out")
+        if self.pending_step and (self.step is None or self.kv_handoff
+                                  or self.prefix_from_line):
+            raise ValueError(
+                "a ServedModel with a pending_step states its step and "
+                "offers neither kv_handoff nor prefix_from_line: a line's "
+                "newest step is unfinished in its cache until the line's "
+                "next burst, and a token a step leaves nothing pending")
 
     @property
     def prefill_token(self) -> bool:

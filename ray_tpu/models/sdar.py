@@ -21,10 +21,11 @@ against the stored K/V of the blocks before it and its own rows' K/V (of its
 current, partly masked content), the logits **at** an open position choose
 that position's token (no shift by one), and a rule
 (:func:`open_positions`) says which of the open positions take their token
-now; when none is open a *commit forward* runs the clean block once more
-and its K/V are the ones later blocks see. llm/sdar_serving.py runs that
-against the engine's slot cache; :func:`forward` here is the clean
-block-causal pass over whole sequences.
+now; when none is open the *commit* runs the clean block once more and its
+K/V are the ones later blocks see. llm/sdar_serving.py runs that against
+the engine's slot cache, the commit's rows beside the next block's first
+denoising forward's; :func:`forward` here is the clean block-causal pass
+over whole sequences.
 
 Params are a flat pytree; every leaf of ``layers`` is stacked over the
 layers, which are all alike.
@@ -67,7 +68,7 @@ class SdarConfig:
     norm_eps: float = 1e-6
     # Generation (the family's generate.py; none is a key of config.json).
     block_length: int = 4
-    denoising_steps: int = 4               # forwards a block before the commit
+    denoising_steps: int = 4               # forwards a block
     remasking_strategy: str = "sequential"
     confidence_threshold: float = 0.9      # the dynamic rule's
     mask_token_id: int = 151669

@@ -3,11 +3,12 @@ through the one ``llm/engine.py``, against the plain reference of the
 benchmark, at a small size on the CPU.
 
 What is held here is what the family adds to the repository: a step that
-decides a block of 4 positions by 5 forwards (the scheduler counts in such
+decides a block of 4 positions by 4 forwards (the scheduler counts in such
 steps, a prompt's tail rides into the first one, a request's surplus is not
-emitted), a prefill that yields no token, the block-causal mask in both
-attention ops, and the three rules by which a block's positions take their
-tokens.
+emitted, and a burst hands its last block to the next burst's first forward,
+which commits it), a prefill that yields no token, the block-causal mask in
+both attention ops, and the three rules by which a block's positions take
+their tokens.
 """
 
 import os
@@ -70,6 +71,16 @@ def config_json(cfg: SdarConfig) -> dict:
             "remasking_strategy": cfg.remasking_strategy,
             "confidence_threshold": cfg.confidence_threshold,
             "mask_token_id": cfg.mask_token_id}
+
+
+@pytest.fixture(scope="module", autouse=True)
+def release_the_compiled_programs():
+    """After the module: a compiled program keeps its memory mappings for as
+    long as JAX's caches hold it, and a worker of the suite that never lets
+    one go runs into ``vm.max_map_count`` (tests/test_granite.py, PERF.md
+    section 7: a worker died under this file in two whole runs)."""
+    yield
+    jax.clear_caches()
 
 
 @pytest.fixture(scope="module")
@@ -305,24 +316,50 @@ def _prefill(params, tokens, slot=0, cache=None, chunk=CHUNK):
 
 
 def _burst(params, cache, given: dict, starts: dict, steps, cfg=CFG,
-           temps=None, top_ps=None, burst=None):
+           temps=None, top_ps=None, burst=None, pending=None, slots=SLOTS):
     """A burst over the lines of ``starts`` (slot -> block start); ``given``
-    (slot -> the tokens its first block has decided). Greedy unless
-    ``temps`` [SLOTS] says otherwise; ``burst`` stands in for the
-    program."""
+    (slot -> the tokens its first block has decided); ``pending`` (slot ->
+    the block before it, decided and not committed: no line has one where
+    None, and a row of ``tokens [slots, K]`` (a burst's last) stands for
+    every line of ``starts``). Greedy unless ``temps`` [slots] says
+    otherwise; ``burst`` stands in for the program."""
     k = cfg.block_length
-    tok = np.full((SLOTS, k), -1, np.int32)
-    pos = np.zeros((SLOTS,), np.int32)
-    write = np.zeros((SLOTS,), bool)
+    tok = np.full((slots, k), -1, np.int32)
+    pos = np.zeros((slots,), np.int32)
+    write = np.zeros((slots,), bool)
     for slot, start in starts.items():
         pos[slot], write[slot] = start, True
         tok[slot, :len(given.get(slot, []))] = given.get(slot, [])
-    zeros, ones = jnp.zeros((SLOTS,)), jnp.ones((SLOTS,))
+    if not isinstance(pending, dict):
+        pending = {} if pending is None else {
+            slot: np.asarray(pending)[slot] for slot in starts}
+    last, has = np.zeros((slots, k), np.int32), np.zeros((slots,), bool)
+    for slot, block in pending.items():
+        last[slot], has[slot] = block, True
+    zeros, ones = jnp.zeros((slots,)), jnp.ones((slots,))
     return (burst or serving.decode_burst)(
-        cfg, params, cache, jnp.asarray(tok), jnp.asarray(pos),
-        jnp.asarray(write), zeros if temps is None else jnp.asarray(temps),
+        cfg, params, cache,
+        (jnp.asarray(tok), jnp.asarray(last), jnp.asarray(has)),
+        jnp.asarray(pos), jnp.asarray(write),
+        zeros if temps is None else jnp.asarray(temps),
         ones if top_ps is None else jnp.asarray(top_ps),
         jax.random.PRNGKey(0), steps, top_ps is not None)
+
+
+def _commit(params, cache, blocks: dict, starts: dict, cfg=CFG, slots=SLOTS):
+    """A commit forward of its own, as every block had one before a commit
+    could ride: the clean ``blocks`` (slot -> tokens) at ``starts`` once
+    through the stack, their K/V left in the cache."""
+    k = cfg.block_length
+    tok = np.zeros((slots, k), np.int32)
+    pos = np.zeros((slots,), np.int32)
+    write = np.zeros((slots,), bool)
+    for slot, start in starts.items():
+        tok[slot], pos[slot], write[slot] = blocks[slot], start, True
+    plan = decode_plan_of(jnp.where(jnp.asarray(write),
+                                    jnp.asarray(pos) + k, 0), cache["k"])
+    return serving._forward(cfg, params, cache, jnp.asarray(tok),
+                            jnp.asarray(pos), jnp.asarray(write), plan)[0]
 
 
 @pytest.mark.parametrize("backend", ["reference", "interpret"])
@@ -330,8 +367,9 @@ def test_a_burst_decides_blocks_as_the_reference_does_and_commits_them(
         params, weights, backend):
     """Two lines at different depths and an idle one in one burst of two
     blocks, the kernels' own bodies included: the tokens are the
-    reference's, the idle line's cache is untouched, and after the commit a
-    line's K/V are those of the clean pass over what it now holds."""
+    reference's, the idle line's cache is untouched, a line's K/V through
+    its first block are those of the clean pass over what it now holds, and
+    its last block's are once the next burst has taken that block in."""
     a, b = _prompt(22), _prompt(9, salt=1)
     with force_kernel_backend(backend):
         cache = _prefill(params, a, slot=0)
@@ -350,85 +388,216 @@ def test_a_burst_decides_blocks_as_the_reference_does_and_commits_them(
         assert out_b[1:] == _want(weights, "sequential", b, 7)
         np.testing.assert_array_equal(np.asarray(cache["k"][:, 1]), held[0])
         np.testing.assert_array_equal(np.asarray(cache["v"][:, 1]), held[1])
+        # the next burst's first forward commits the block handed to it
+        later, more, _ = _burst(params, jax.tree.map(jnp.copy, cache), {},
+                                {0: 28, 2: 16}, steps=1, pending=toks[-1])
+        out_a += np.asarray(more)[0, 0].tolist()
+        assert out_a[2:] == _want(weights, "sequential", a, 10)
         # the clean pass over the whole of line 0, into the idle slot
         clean = _prefill(params, a[:20] + out_a, slot=1,
-                         cache=jax.tree.map(jnp.copy, cache), chunk=32)
+                         cache=jax.tree.map(jnp.copy, later), chunk=32)
     for leaf in ("k", "v"):
-        np.testing.assert_allclose(
-            np.asarray(cache[leaf][:, 0, :, :28]),
-            np.asarray(clean[leaf][:, 1, :, :28]), atol=1e-5)
+        want = np.asarray(clean[leaf][:, 1])
+        np.testing.assert_allclose(np.asarray(cache[leaf][:, 0, :, :24]),
+                                   want[:, :, :24], atol=1e-5)
+        # the burst's last block stood as its last denoising forward left
+        # it (a position still masked), until the next burst came
+        assert np.abs(np.asarray(cache[leaf][:, 0, :, 24:28])
+                      - want[:, :, 24:28]).max() > 1e-3
+        np.testing.assert_allclose(np.asarray(later[leaf][:, 0, :, :28]),
+                                   want[:, :, :28], atol=1e-5)
     counts = dict(zip(serving.COUNTERS, np.asarray(counts).tolist()))
     # two lines x two blocks; a line's first commit rode its second block's
-    # first forward, which is one forward of 8 rows
+    # first forward, its second is the next burst's, and its first block's
+    # first forward carried a dead half: 4 + 8 + 6 x 4 rows a line
     assert counts["diffusion_blocks"] == 4
-    assert counts["diffusion_commits"] == 2 == counts["diffusion_commits_riding"]
-    assert counts["diffusion_forwards"] == 18 and counts["diffusion_given"] == 3
-    assert counts["moe_layer_steps"] == 9 * CFG.num_layers
+    assert counts["diffusion_commits"] == 0
+    assert counts["diffusion_commits_riding"] == 2
+    assert counts["diffusion_forwards"] == 16 and counts["diffusion_given"] == 3
+    assert counts["moe_layer_steps"] == 8 * CFG.num_layers
     assert counts["moe_picks"] == \
-        20 * 4 * CFG.num_experts_per_tok * CFG.num_layers
+        2 * 36 * CFG.num_experts_per_tok * CFG.num_layers
 
 
 @pytest.mark.parametrize("backend", ["reference", "interpret"])
-@pytest.mark.parametrize("steps", [2, 4])
-def test_a_burst_leaves_what_its_blocks_leave_one_by_one(params, backend,
-                                                         steps):
-    """The commits that ride the next block's first forward are the commits:
-    after a burst of 2 or of 4 blocks the tokens and every committed
-    block's K/V are those of the same blocks run as bursts of one, each
-    committed by a forward of its own; the idle line's cache is untouched
-    by either."""
+@pytest.mark.parametrize("steps", [1, 2, 4])
+def test_a_burst_leaves_what_its_blocks_leave_one_by_one(params, weights,
+                                                         backend, steps):
+    """The commits that ride the next block's first forward are the commits,
+    across bursts too: a chain of bursts of 1, 2 or 4 blocks, each handed
+    the last block of the one before, gives the reference's tokens, and the
+    tokens and every committed block's K/V of the same four blocks run one
+    at a time, each committed by a forward of its own; the idle line's
+    cache is untouched by either."""
     a, b = _prompt(22), _prompt(9, salt=1)
-    given, starts = {0: a[20:], 2: b[8:]}, {0: 20, 2: 8}
+    given, starts, blocks = {0: a[20:], 2: b[8:]}, {0: 20, 2: 8}, 4
+    totals = np.zeros((len(serving.COUNTERS),), np.int64)
     with force_kernel_backend(backend):
         cache = _prefill(params, b, slot=2, cache=_prefill(params, a))
         alone, want = jax.tree.map(jnp.copy, cache), []
-        for j in range(steps):
-            alone, toks, _ = _burst(
-                params, alone, given if j == 0 else {},
-                {slot: at + 4 * j for slot, at in starts.items()}, steps=1)
+        for j in range(blocks):
+            at = {slot: start + 4 * j for slot, start in starts.items()}
+            alone, toks, _ = _burst(params, alone, given if j == 0 else {},
+                                    at, steps=1)
             want.append(np.asarray(toks)[0])
-        cache, got, counts = _burst(params, cache, given, starts, steps=steps)
-    np.testing.assert_array_equal(np.asarray(got)[:, [0, 2]],
-                                  np.stack(want)[:, [0, 2]])
+            alone = _commit(params, alone, want[-1], at)
+        got, last = [], None
+        for j in range(0, blocks, steps):
+            cache, toks, counts = _burst(
+                params, cache, given if j == 0 else {},
+                {slot: start + 4 * j for slot, start in starts.items()},
+                steps=steps, pending=last)
+            got += list(np.asarray(toks))
+            last, totals = np.asarray(toks)[-1], totals + np.asarray(counts)
+    got, want = np.stack(got), np.stack(want)
+    np.testing.assert_array_equal(got[:, [0, 2]], want[:, [0, 2]])
+    assert got[:, 0].reshape(-1)[2:].tolist() == \
+        _want(weights, "sequential", a, 14)
+    assert got[:, 2].reshape(-1)[1:].tolist() == \
+        _want(weights, "sequential", b, 15)
     for leaf in ("k", "v"):
         for slot, at in starts.items():
+            # every block but the chain's last, which nobody has committed
             np.testing.assert_allclose(
-                np.asarray(cache[leaf][:, slot, :, :at + 4 * steps]),
-                np.asarray(alone[leaf][:, slot, :, :at + 4 * steps]),
+                np.asarray(cache[leaf][:, slot, :, :at + 4 * (blocks - 1)]),
+                np.asarray(alone[leaf][:, slot, :, :at + 4 * (blocks - 1)]),
                 atol=1e-5)
         assert not np.asarray(cache[leaf][:, 1]).any()
-    counts = dict(zip(serving.COUNTERS, np.asarray(counts).tolist()))
-    assert counts["diffusion_commits_riding"] == 2 * (steps - 1)
-    assert counts["diffusion_commits"] == 2
-    assert counts["moe_layer_steps"] == \
+    counts = dict(zip(serving.COUNTERS, totals.tolist()))
+    assert counts["diffusion_blocks"] == 2 * blocks
+    assert counts["diffusion_commits_riding"] == 2 * (blocks - 1)
+    assert counts["diffusion_commits"] == 0
+    assert counts["moe_layer_steps"] == (blocks // steps) * \
         sum(serving.burst_forwards(CFG, steps)) * CFG.num_layers
 
 
+def _lines_kv(cache, slot):
+    return [np.asarray(cache[leaf][:, slot]) for leaf in ("k", "v")]
+
+
+@pytest.mark.parametrize("backend", ["reference", "interpret"])
+def test_a_dead_clean_half_touches_nothing(params, weights, backend):
+    """Beside a line with a block pending: a line fresh from its prefill, a
+    line whose prompt is shorter than a block (it stands at position 0, its
+    dead half before the line) and a slot's new tenant (the slot's cache
+    and the pending row hold the old tenant's) go through the same wide
+    first forward. Each line's tokens are the reference's and its K/V those
+    of the line run alone; the prompt's last whole block is bit-equal
+    before and after; and whatever stands in a dead half's ``pending`` row,
+    every line's tokens and the whole cache come out bit-equal."""
+    slots = 4
+    a, b, c, d = (_prompt(22), _prompt(9, salt=1), _prompt(3, salt=2),
+                  _prompt(14, salt=3))
+    old = _prompt(21, salt=4)
+    with force_kernel_backend(backend):
+        cache = serving.init_kv_cache(CFG, slots, MAX_SEQ)
+        # slot 3's old tenant, then line a, each through a burst of two
+        cache = _prefill(params, old, slot=3, cache=_prefill(params, a,
+                                                             cache=cache))
+        cache, toks, _ = _burst(params, cache, {0: a[20:], 3: old[20:]},
+                                {0: 20, 3: 20}, steps=2, slots=slots)
+        last = np.asarray(toks)[-1]
+        out_a = np.asarray(toks)[:, 0].reshape(-1).tolist()
+        # the new tenant's prompt over the old line; b's; c has no block
+        cache = _prefill(params, d, slot=3, cache=cache)
+        cache = _prefill(params, b, slot=1, cache=cache)
+        before = {slot: _lines_kv(cache, slot) for slot in range(slots)}
+        given = {1: b[8:], 2: c, 3: d[12:]}
+        starts = {0: 28, 1: 8, 2: 0, 3: 12}
+
+        def run(junk):
+            rows = np.full((slots, 4), junk, np.int32)
+            # a's own last block, and what the burst before left at slot 3
+            rows[0], rows[3] = last[0], last[3]
+            tok = np.full((slots, 4), -1, np.int32)
+            for slot, tail in given.items():
+                tok[slot, :len(tail)] = tail
+            return serving.decode_burst(
+                CFG, params, jax.tree.map(jnp.copy, cache),
+                (jnp.asarray(tok), jnp.asarray(rows),
+                 jnp.asarray([True, False, False, False])),
+                jnp.asarray([starts[s] for s in range(slots)], jnp.int32),
+                jnp.ones((slots,), bool), jnp.zeros((slots,)),
+                jnp.ones((slots,)), jax.random.PRNGKey(0), 2, False)
+
+        after, got, counts = run(0)
+        other, got_other, _ = run(MASK)
+        got = np.asarray(got)
+        np.testing.assert_array_equal(got, np.asarray(got_other))
+        for leaf in ("k", "v"):
+            np.testing.assert_array_equal(np.asarray(after[leaf]),
+                                          np.asarray(other[leaf]))
+        # each line alone in the same cache, the others idle
+        for slot in range(slots):
+            alone, toks, _ = _burst(
+                params, jax.tree.map(jnp.copy, cache),
+                {slot: given.get(slot, [])}, {slot: starts[slot]}, steps=2,
+                pending={0: last[0]} if slot == 0 else None, slots=slots)
+            np.testing.assert_array_equal(np.asarray(toks)[:, slot],
+                                          got[:, slot])
+            for mine, theirs in zip(_lines_kv(after, slot),
+                                    _lines_kv(alone, slot)):
+                np.testing.assert_allclose(mine, theirs, atol=1e-5)
+    for slot, prompt, n in ((1, b, 7), (2, c, 5), (3, d, 6)):
+        out = got[:, slot].reshape(-1).tolist()
+        assert out[:len(given[slot])] == given[slot]
+        assert out[len(given[slot]):] == _want(weights, "sequential",
+                                               prompt, n)
+    assert (out_a + got[:, 0].reshape(-1).tolist())[2:] == \
+        _want(weights, "sequential", a, 14)
+    for slot, at in starts.items():
+        # a burst writes its own two blocks' positions, the pending line
+        # its pending block's too, and nothing else of any line: the
+        # prompt's last whole block least of all
+        wrote = slice(at - 4 if slot == 0 else at, at + 8)
+        for was, now in zip(before[slot], _lines_kv(after, slot)):
+            was, now = was.copy(), now.copy()
+            was[:, :, wrote], now[:, :, wrote] = 0, 0
+            np.testing.assert_array_equal(was, now)
+    counts = dict(zip(serving.COUNTERS, np.asarray(counts).tolist()))
+    # the one pending block, and every line's first block inside the burst
+    assert counts["diffusion_commits_riding"] == 1 + 4
+    assert counts["diffusion_commits"] == 0
+    assert counts["diffusion_blocks"] == 8
+    assert counts["diffusion_forwards"] == 32
+    # rows routed: 3 dead halves of 4 rows are not
+    assert counts["moe_picks"] == (4 * (8 + 3 * 4 + 8 + 3 * 4) - 3 * 4) * \
+        CFG.num_experts_per_tok * CFG.num_layers
+
+
+@pytest.mark.parametrize("pending", [False, True], ids=["fresh", "pending"])
 @pytest.mark.parametrize("steps", [1, 2, 3])
 @pytest.mark.parametrize("rule,rows", [("sequential", 2),
                                        ("low_confidence_static", 4)])
-def test_the_counters_count_the_forwards_that_ran(params, rule, rows, steps):
+def test_the_counters_count_the_forwards_that_ran(params, rule, rows, steps,
+                                                  pending):
     """Forwards are counted where a forward runs and commits where the
     commit does: at 2 denoising forwards a block a burst of n blocks reads
-    2 n + 1 forwards a line, one of them a commit of its own and n - 1
-    that carried the commit of the block before, with no edit of a
-    formula. The head's rows are counted where the head runs: lines x the
-    rows the rule can read x denoising forwards, the idle line's none."""
+    2 n forwards a line, none of them a commit of its own, n - 1 that
+    carried the commit of the block before and one more where the line
+    came with a block pending, with no edit of a formula. The head's rows
+    are counted where the head runs: lines x the rows the rule can read x
+    denoising forwards, the idle line's none."""
     cfg = replace(CFG, denoising_steps=2, remasking_strategy=rule)
-    a, b = _prompt(9), _prompt(14, salt=1)
+    a, b = _prompt(13), _prompt(18, salt=1)
     cache = _prefill(params, b, slot=2, cache=_prefill(params, a))
-    _, _, counts = _burst(params, cache, {0: a[8:], 2: b[12:]},
-                          {0: 8, 2: 12}, steps=steps, cfg=cfg)
+    _, _, counts = _burst(
+        params, cache, {0: a[12:], 2: b[16:]}, {0: 12, 2: 16}, steps=steps,
+        cfg=cfg, pending={0: a[8:12], 2: b[12:16]} if pending else None)
     counts = dict(zip(serving.COUNTERS, np.asarray(counts).tolist()))
     assert counts["diffusion_blocks"] == 2 * steps
-    assert counts["diffusion_commits"] == 2 * 1
-    assert counts["diffusion_commits_riding"] == 2 * (steps - 1)
-    assert counts["diffusion_forwards"] == 2 * (2 * steps + 1)
-    assert sum(serving.burst_forwards(cfg, steps)) == 2 * steps + 1
-    assert counts["moe_layer_steps"] == (2 * steps + 1) * cfg.num_layers
+    assert counts["diffusion_commits"] == 0
+    assert counts["diffusion_commits_riding"] == 2 * (steps - 1 + pending)
+    assert counts["diffusion_forwards"] == 2 * (2 * steps)
+    assert sum(serving.burst_forwards(cfg, steps)) == 2 * steps
+    assert counts["moe_layer_steps"] == 2 * steps * cfg.num_layers
     assert counts["diffusion_head_rows"] == 2 * rows * (2 * steps)
     assert counts["diffusion_head_rows"] == rows * (
         counts["diffusion_forwards"] - counts["diffusion_commits"])
+    # the rows routed: a forward's 4 a line, 4 more where a commit rode
+    assert counts["moe_picks"] == 4 * (
+        counts["diffusion_forwards"] + counts["diffusion_commits_riding"]) \
+        * cfg.num_experts_per_tok * cfg.num_layers
 
 
 def test_the_head_s_rows_are_a_metric_of_the_cell():
@@ -464,6 +633,42 @@ def test_the_head_s_rows_are_a_metric_of_the_cell():
         spec["params"]) is None
 
 
+def test_the_riding_share_is_a_metric_of_the_cell():
+    """``diffusion_commit_riding_share`` (PR 63): a data file over the
+    reader the benchmark has. Of the blocks run in a window, those whose
+    commit rode a forward: 1 in 2 where only a burst of 2's first block's
+    does (PR 61: 88.9 in the cell once first blocks are rare), every block
+    but a line's last once bursts hand their last block on."""
+    from rtbench import manifest
+    from rtbench.readers import counter_ratio
+
+    cell = manifest.load_cell("sdar-30b-serve-generate-512", REPO)
+    spec = next(x for x in cell["per_layer"]
+                if x["name"] == "diffusion_commit_riding_share")
+    commit = next(x for x in cell["per_layer"]
+                  if x["name"] == "diffusion_commit_share")
+    assert spec["reader"] == "counter_ratio"
+    assert spec["params"] == {"num": "diffusion_commits_riding",
+                              "den": "diffusion_blocks", "scale": 100.0}
+    assert {spec["params"]["num"], spec["params"]["den"]} <= \
+        set(serving.COUNTERS)
+    assert (spec["moves"], spec["better"], spec["source"], spec["unit"]) == \
+        ("serve_tok_s", "higher", "program_counter", "%")
+    assert spec["layer"] == commit["layer"]
+    assert spec["workloads"] == ["sdar-30b-serve-generate-512"]
+    assert manifest.load(REPO)["per_layer"][-1]["name"] == spec["name"]
+    # 128 lines, each 127 blocks of 128 with a commit riding
+    polls = [(t, {"diffusion_commits_riding": 127 * n,
+                  "diffusion_blocks": 128 * n})
+             for t, n in ((1.0, 10), (2.0, 30))]
+    obs = {"polls": polls, "t_open": 0.0, "t_close": 3.0}
+    assert counter_ratio.read(obs, spec["params"]) == 100.0 * 127 / 128
+    # a program without the counter (before PR 61) reads nothing
+    assert counter_ratio.read(
+        {**obs, "polls": [(t, {"diffusion_blocks": 5}) for t, _ in polls]},
+        spec["params"]) is None
+
+
 def test_open_positions_are_tracked_by_place_not_by_the_mask_s_id(params,
                                                                   weights):
     """A prompt whose tail *is* the mask id, and one with it inside."""
@@ -491,11 +696,13 @@ def _choose_every_row(logits, temps, top_ps, key, need_top_p):
 
 
 @partial(jax.jit, static_argnums=(0, 9, 10))
-def _burst_every_row(cfg, params, cache, token0, positions0, write_mask,
+def _burst_every_row(cfg, params, cache, inputs, positions0, write_mask,
                      temps, top_ps, key, steps, need_top_p):
-    """``decode_burst`` in plain loops, the head and the choice of every
-    row of the block at every denoising forward."""
-    k, out = cfg.block_length, []
+    """``decode_burst`` in plain loops as it stood before a commit could
+    ride (PR 41 to 60): the head and the choice of every row of the block
+    at every denoising forward, every block committed by a forward of its
+    own, nothing taken in but ``token0``."""
+    token0, k, out = inputs[0], cfg.block_length, []
     for j in range(steps):
         pos = positions0 + j * k
         is_open = (token0 < 0) | (j > 0)
@@ -697,22 +904,23 @@ def test_the_engine_s_tokens_are_the_reference_s(engine, weights):
         assert out == _want(weights, rule, prompts[name], n[name]), name
     stats = eng.stats()
     assert stats["requests_failed"] == 0 and stats["device_failures"] == 0
-    # four denoising forwards a block, and a forward more for every commit
-    # that did not ride the next block's first one
-    assert stats["diffusion_forwards"] == \
-        4 * stats["diffusion_blocks"] + stats["diffusion_commits"]
-    assert stats["diffusion_commits"] + stats["diffusion_commits_riding"] \
-        == stats["diffusion_blocks"]
-    assert stats["diffusion_commits_riding"] > 0
+    # four denoising forwards a block and none for a commit: every block's
+    # rode the next block's first forward, in its burst or in the next,
+    # but each line's last block's, which nobody commits (a finished
+    # line's surplus blocks in a burst already queued ride on, unread)
+    assert stats["diffusion_forwards"] == 4 * stats["diffusion_blocks"]
+    assert stats["diffusion_commits"] == 0
+    assert stats["diffusion_commits_riding"] == \
+        stats["diffusion_blocks"] - len(prompts)
     # every token streamed is a decode's: prefill gives none
     assert stats["decode_tokens"] == sum(n.values())
     assert stats["first_tokens"] == len(prompts)
     # the tails of 22, 3, 9, 11 and 14: 2 + 3 + 1 + 3 + 2
     assert stats["diffusion_given"] == 11
     assert stats["prompt_tokens_prefilled"] == 20 + 0 + 8 + 8 + 8 + 12
-    # a burst of n blocks is 4 n + 1 forwards
-    assert (stats["decode_steps"] - stats["decode_dispatches"]) % 4 == 0
-    assert stats["decode_dispatches"] < stats["decode_steps"] // 5
+    # a burst of n blocks is 4 n forwards
+    assert stats["decode_steps"] % 4 == 0
+    assert stats["decode_dispatches"] < stats["decode_steps"] // 4
     assert (stats["moe_experts_held"], stats["attention_lines"],
             stats["diffusion_block_length"]) == (8, 3, 4)
     assert stats["prefix_hits"] == 0 and eng.router_prefix_blocks() is None
@@ -736,14 +944,81 @@ def test_every_schedule_gives_the_same_tokens(params, weights, burst,
             assert list(req.out_tokens) == _want(weights, "sequential", p,
                                                  11)
         stats = eng.stats()
-        assert (stats["decode_steps"] - stats["decode_dispatches"]) % 4 == 0
-        assert (stats["diffusion_commits_riding"] > 0) == (burst > 1)
+        assert stats["decode_steps"] == 4 * burst * stats["decode_dispatches"]
+        # handed over on the device or made by the host from its own
+        # tokens, whatever the burst's length: every block but a line's
+        # last is committed by the forward after it
+        assert stats["diffusion_commits"] == 0
+        assert stats["diffusion_commits_riding"] == \
+            stats["diffusion_blocks"] - len(prompts)
         if not pipeline:
             assert stats["decode_dispatches_ahead"] == 0
-        if burst == 1:
-            assert stats["decode_steps"] == 5 * stats["decode_dispatches"]
     finally:
         eng.shutdown()
+
+
+def test_the_engine_hands_a_burst_s_last_block_to_the_next(params, weights):
+    """The scheduler's thread stopped and the ticks made by hand: a burst
+    queued behind one in flight takes that burst's last row as it lies on
+    the device (the look-ahead holds: nothing in flight is read before the
+    dispatch), a line that joins from its prefill has nothing pending
+    whatever the burst in flight computed for its slot, and with nothing in
+    flight the host makes the pending blocks from its own tokens; either
+    way the tokens are the reference's and no block pays a commit."""
+    outs = {}
+    for pipeline in (True, False):
+        eng = _engine(params, decode_pipeline=pipeline, max_num_seqs=2)
+        eng.shutdown()
+        program, seen = eng.model.decode_burst, []
+
+        def burst(cfg, params, cache, inputs, positions0, write, *rest,
+                  program=program, seen=seen, eng=eng, **kw):
+            _, pending, has = inputs
+            prev = next((e for e in reversed(eng._in_flight) if e.steps),
+                        None)
+            seen.append({
+                "behind": prev is not None,
+                "handed_on_the_device": prev is not None
+                and pending is prev.last_row,
+                "has": np.asarray(has).tolist(),
+                "steps_before": eng.decode_steps, "burst": rest[3]})
+            return program(cfg, params, cache, inputs, positions0, write,
+                           *rest, **kw)
+
+        eng.model = replace(eng.model, decode_burst=burst)
+        prompts = [_prompt(10, 6), _prompt(17, 7), _prompt(3, 8)]
+        budgets = [22, 9, 10]
+        reqs = [eng.submit(p, SamplingParams(max_tokens=n))
+                for p, n in zip(prompts, budgets)]
+        for _ in range(200):
+            if all(r.done.is_set() for r in reqs):
+                break
+            eng._tick()
+        for p, n, r in zip(prompts, budgets, reqs):
+            assert r.done.is_set() and r.error is None
+            assert list(r.out_tokens) == _want(weights, "sequential", p, n)
+        outs[pipeline] = [list(r.out_tokens) for r in reqs]
+        stats = eng.stats()
+        assert stats["diffusion_commits"] == 0
+        assert stats["diffusion_commits_riding"] == \
+            stats["diffusion_blocks"] - len(prompts)
+        # ``decode_steps`` grows by 4 forwards a block of the burst
+        grown = [b["steps_before"] - a["steps_before"]
+                 for a, b in zip(seen, seen[1:])]
+        assert grown == [4 * a["burst"] for a in seen[:-1]]
+        assert stats["decode_steps"] == 4 * sum(a["burst"] for a in seen)
+        # the first burst of all finds nothing pending; the third request
+        # takes a slot whose old tenant was in the burst before
+        assert seen[0]["has"] == [False, False]
+        assert any(a["has"] == [True, True] for a in seen)
+        assert any(sorted(a["has"]) == [False, True] for a in seen[1:])
+        if pipeline:
+            behind = [a for a in seen if a["behind"]]
+            assert behind and stats["decode_dispatches_ahead"] == len(behind)
+            assert all(a["handed_on_the_device"] for a in behind)
+        else:
+            assert not any(a["behind"] for a in seen)
+    assert outs[True] == outs[False]
 
 
 def test_a_stop_token_ends_a_line_inside_its_block(params, weights):
@@ -788,13 +1063,20 @@ def test_a_line_ends_with_its_last_whole_block(params, weights, max_seq,
 def test_the_served_model_says_what_it_is():
     served = served_model(CFG)
     assert served is serving.SERVED
-    assert served.step(CFG) == (4, 5) and not served.prefill_token
+    assert served.step(CFG) == (4, 4) and not served.prefill_token
+    # a burst hands its last block on; so nobody else may read a line
+    assert served.pending_step
+    with pytest.raises(ValueError, match="pending_step"):
+        replace(served, prefix_from_line=True)
+    with pytest.raises(ValueError, match="pending_step"):
+        replace(served_model(LLMConfig(model="tiny").model_config()),
+                pending_step=True)
     assert served.decode_step is None and served.copy_prefix_kv is None
     assert not served.prefix_from_line and not served.kv_handoff
     assert served.counters == serving.COUNTERS and len(served.counters) == 12
-    # a burst is cheaper than its blocks alone: the last one's commit only
-    assert served.burst_forwards(CFG, 1) == [5]
-    assert served.burst_forwards(CFG, 4) == [4, 4, 4, 5]
+    # no block pays a commit forward: the next block's first one carries it
+    assert served.burst_forwards(CFG, 1) == [4]
+    assert served.burst_forwards(CFG, 4) == [4, 4, 4, 4]
     full = replace(SdarConfig(), num_layers=6)
     assert served.kv_block(full, 1536) == 512
     cache = jax.eval_shape(lambda: served.init_cache(full, 128, 1536))

@@ -114,14 +114,19 @@ ALL_MODELS = dict(argvalues=MODELS["argvalues"] + [_sdar],
                   ids=MODELS["ids"] + ["sdar"])
 
 
-def _arguments(program, params, step_positions=1):
+def _arguments(program, params, step_positions=1, pending_step=False):
     """The arguments of ``program`` after (cfg, [params,] cache): slot 0
     holds CHUNK rows, slots 0 and 1 decode. A burst's tokens are [slots],
-    or [slots, K] all open (-1) where a step is a block of K positions."""
+    or [slots, K] all open (-1) where a step is a block of K positions,
+    with the step before it (slot 0 has one pending, the prompt's last)
+    where the model takes one in (``ServedModel.pending_step``)."""
     i32 = jnp.int32
     slots = jnp.zeros((SLOTS,), i32)
     if step_positions > 1:
         slots = jnp.full((SLOTS, step_positions), -1, i32)
+    if pending_step:
+        slots = (slots, jnp.zeros((SLOTS, step_positions), i32),
+                 jnp.array([True, False, False]))
     # (a block's start is a multiple of its length)
     positions = jnp.array([CHUNK, 1 if step_positions == 1 else 0, 0], i32)
     write = jnp.array([True, True, False])
@@ -156,7 +161,8 @@ def test_a_program_keeps_its_name_and_gives_the_donated_cache_back(model,
     went_in = jax.tree.map(lambda a: (a.shape, a.dtype), cache)
     head = (cfg, cache)
     rest = _arguments(program, params,
-                      served.step(cfg)[0] if served.step else 1)
+                      served.step(cfg)[0] if served.step else 1,
+                      served.pending_step)
     if program != "copy_prefix_kv":
         head, rest = (cfg, rest[0], cache), rest[1:]
 
@@ -481,19 +487,23 @@ def chunks_that_ride_leave_every_answer_as_it_was(monkeypatch, serving, cfg,
 
 
 def test_a_model_may_say_its_step_is_a_block_and_its_prefill_gives_no_token():
-    """SDAR's: 4 positions by 5 forwards. Its two programs keep the names a
+    """SDAR's: 4 positions by 4 forwards. Its two programs keep the names a
     trace is read by and give the donated cache back; the prefill's logits
-    are None; a burst takes int32[slots, 4] and gives [steps, slots, 4]."""
+    are None; a burst takes int32[slots, 4] (with the block before it and
+    who has one: ``pending_step``) and gives [steps, slots, 4]."""
     module, cfg = _sdar()
     served = served_model(cfg)
-    assert served.step(cfg) == (4, 5) and not served.prefill_token
+    assert served.step(cfg) == (4, 4) and not served.prefill_token
+    assert served.pending_step
     assert served.decode_step is None and served.copy_prefix_kv is None
     params = served.init_params(cfg, jax.random.PRNGKey(0))
     i32 = jnp.int32
     for program, rest in (
             ("prefill_chunk", (jnp.arange(CHUNK, dtype=i32), i32(0),
                                i32(CHUNK), i32(0))),
-            ("decode_burst", (jnp.full((SLOTS, 4), -1, i32),
+            ("decode_burst", ((jnp.full((SLOTS, 4), -1, i32),
+                               jnp.zeros((SLOTS, 4), i32),
+                               jnp.array([True, False, False])),
                               jnp.array([CHUNK, 0, 0], i32),
                               jnp.array([True, True, False]),
                               jnp.zeros((SLOTS,), jnp.float32),
@@ -517,8 +527,10 @@ def test_a_model_may_say_its_step_is_a_block_and_its_prefill_gives_no_token():
 
 
 def test_a_model_may_say_what_a_burst_costs_and_is_counted_so():
-    """SDAR's bursts: 4 n + 1 forwards, the one more at the last block's
-    lengths. ``decode_steps``, the dispatch phase's ``steps`` and
+    """SDAR's bursts: 4 n forwards, a block's commit among the next
+    block's (a step alone would state 4 too since PR 63; a model whose
+    burst is cheaper than its steps states what each costs, as SDAR's did
+    from PR 61: 4 n + 1). ``decode_steps``, the dispatch phase's ``steps`` and
     ``kv_positions_read`` (a kernel call a layer a forward) count what the
     model states, step by step at that step's lengths; a model that states
     nothing counts its step's forwards each, as it did."""
@@ -528,42 +540,56 @@ def test_a_model_may_say_what_a_burst_costs_and_is_counted_so():
 
     _, cfg = _sdar()
     served = served_model(cfg)
-    assert served.burst_forwards(cfg, 3) == [4, 4, 5]
+    assert served.burst_forwards(cfg, 3) == [4, 4, 4]
     eng = LLMEngine(LLMConfig(model=cfg, max_num_seqs=SLOTS,
                               max_seq_len=MAX_SEQ, prefill_chunk=CHUNK,
                               decode_burst=2, decode_pipeline=False))
     tracing.clear()
     tracing.enable_tracing()
     try:
-        assert eng._burst_forwards(1).tolist() == [5]
-        assert eng._burst_forwards(2).tolist() == [4, 5]
+        assert eng._burst_forwards(1).tolist() == [4]
+        assert eng._burst_forwards(2).tolist() == [4, 4]
         out = eng.generate([7 + i for i in range(9)],
                            SamplingParams(max_tokens=15))
         assert len(out.token_ids) == 15
         # a first block of 1 + 3, then 12: four blocks as two bursts of two
         stats = eng.stats()
-        assert (stats["decode_dispatches"], stats["decode_steps"]) == (2, 18)
+        assert (stats["decode_dispatches"], stats["decode_steps"]) == (2, 16)
         assert [s.attributes["steps"] for s in tracing.spans()
-                if s.name == "engine.decode_dispatch"] == [9, 9]
+                if s.name == "engine.decode_dispatch"] == [8, 8]
         # one block of the line (MAX_SEQ) a kernel call
-        assert stats["kv_positions_read"] == 18 * eng._kv_block
+        assert stats["kv_positions_read"] == 16 * eng._kv_block
+        # the second burst committed the first's last block, the host
+        # having made the pending block from its own tokens (no look-ahead)
+        assert (stats["diffusion_commits"], stats["diffusion_commits_riding"],
+                stats["diffusion_blocks"]) == (0, 3, 4)
         # scripted, in blocks shorter than the line: a burst of 2 from
         # positions 120 and 300 (slot 2 idle), in blocks of 128 of 512
         eng.max_seq, eng._kv_block = 512, 128
         before = eng.kv_positions_read, eng.kv_positions_reserved
         eng._count_kv_positions(np.array([120, 300, 0]),
                                 np.array([True, True, False]), steps=2)
-        # lengths 124 and 304, 4 forwards; 128 (a whole block) and 308, 5
+        # lengths 124 and 304, 4 forwards; 128 (a whole block) and 308, 4
         assert eng.kv_positions_read - before[0] == \
-            4 * (128 + 384) + 5 * (128 + 384)
-        assert eng.kv_positions_reserved - before[1] == 9 * SLOTS * 512
-        # stating nothing of a burst: 5 forwards a step, as before PR 61
+            4 * (128 + 384) + 4 * (128 + 384)
+        assert eng.kv_positions_reserved - before[1] == 8 * SLOTS * 512
+        # a model whose burst costs what it states, step by step at that
+        # step's lengths (SDAR's own from PR 61 to 62)
+        eng.model = dc_replace(
+            eng.model, burst_forwards=lambda cfg, n: [4] * (n - 1) + [5])
+        assert eng._burst_forwards(2).tolist() == [4, 5]
+        eng._count_kv_positions(np.array([120, 300, 0]),
+                                np.array([True, True, False]), steps=2)
+        assert eng.kv_positions_read - before[0] == \
+            8 * 512 + 4 * (128 + 384) + 5 * (128 + 384)
+        assert eng.kv_positions_reserved - before[1] == (8 + 9) * SLOTS * 512
+        # stating nothing of a burst: its step's forwards each
         eng.model = dc_replace(eng.model, burst_forwards=None)
-        assert eng._burst_forwards(2).tolist() == [5, 5]
+        assert eng._burst_forwards(2).tolist() == [4, 4]
         eng._count_kv_positions(np.array([125, 300, 0]),
                                 np.array([True, True, False]), steps=2)
         assert eng.kv_positions_read - before[0] == \
-            9 * 512 + 5 * (256 + 384) + 5 * (256 + 384)
+            17 * 512 + 4 * (256 + 384) + 4 * (256 + 384)
     finally:
         tracing.disable_tracing()
         tracing.clear()
@@ -573,7 +599,9 @@ def test_a_model_may_say_what_a_burst_costs_and_is_counted_so():
 def test_the_look_ahead_serves_blocks_as_the_serial_schedule_does():
     """The same equality as above with a step of 4: lines that join bursts
     in flight get the serial schedule's tokens, every token is a decode's,
-    and a step counts its 5 forwards."""
+    and a step counts its 4 forwards: handed over on the device or made
+    by the host, every block but a line's last is committed by the forward
+    after it."""
     _, cfg = _sdar()
     prompts = [[7 + i for i in range(n)] for n in (5, 40, 23, 9, 31)]
     budgets = [30, 17, 22, 9, 13]
@@ -596,12 +624,12 @@ def test_the_look_ahead_serves_blocks_as_the_serial_schedule_does():
     assert ahead["decode_dispatches_ahead"] > serial["decode_dispatches_ahead"]
     assert ahead["decode_tokens"] == serial["decode_tokens"] == sum(budgets)
     for s in stats:
-        # a burst of n blocks is 4 n + 1 forwards: the last block's commit
-        assert (s["decode_steps"] - s["decode_dispatches"]) % 4 == 0
-        assert s["diffusion_forwards"] == \
-            4 * s["diffusion_blocks"] + s["diffusion_commits"]
-        assert s["diffusion_commits"] + s["diffusion_commits_riding"] == \
-            s["diffusion_blocks"]
+        # a burst of n blocks is 4 n forwards, no commit among them
+        assert s["decode_steps"] % 4 == 0
+        assert s["diffusion_forwards"] == 4 * s["diffusion_blocks"]
+        assert s["diffusion_commits"] == 0
+        assert s["diffusion_commits_riding"] == \
+            s["diffusion_blocks"] - len(prompts)
         assert s["first_tokens"] == 5
         # whole blocks of the prompts: 4 + 40 + 20 + 8 + 28
         assert s["prompt_tokens_prefilled"] == 100

@@ -824,7 +824,8 @@ def test_lfm2_programs_move_no_cache_nor_expert_stack_and_fit_the_chip(
 # The first 6 layers of SDAR-30B-A3B-Chat with all 128 experts at the shapes
 # of its serving cell (128 slots x 1,536): the programs of
 # llm/sdar_serving.py as the cell compiles them.
-@pytest.mark.parametrize("program", ["prefill_chunk(512)", "decode_burst(2)"])
+@pytest.mark.parametrize("program", ["prefill_chunk(512)", "decode_burst(1)",
+                                     "decode_burst(2)"])
 def test_sdar_programs_move_no_cache_nor_expert_stack_and_fit_the_chip(
         mosaic, program):
     """The Llama cache rides every loop as carry: the blocks' loop, the
@@ -839,7 +840,12 @@ def test_sdar_programs_move_no_cache_nor_expert_stack_and_fit_the_chip(
     they are no temporary at all). The prefill computes no head, so the
     head is no argument of it. A block's forwards attend at the same
     lengths, the wide one that commits the block before among them: one
-    plan a block, outside the forwards' and the layers' loops."""
+    plan a block, outside the forwards' and the layers' loops. Since PR 63
+    a burst's first forward is a wide one too (the block the burst before
+    handed over, each half written under its own mask), so a burst of one
+    block holds the wide forward's temporaries as a burst of two did, and
+    neither more than that; both K/V stacks come back in the buffers they
+    went in."""
     from devbench import sdar_bench as bench
 
     cfg = bench.config()
@@ -874,6 +880,12 @@ def test_sdar_programs_move_no_cache_nor_expert_stack_and_fit_the_chip(
         kernels = ("decode_attention", "kv_row_write", "moe_grouped_matmul")
         in_place = {"custom-call"}
         assert _plans_outside_the_layer_loop(text)
+        # the donated cache is the result's buffers: arguments 15 and 16
+        # (after the 15 leaves of the weights), outputs 0 and 1
+        alias = text[text.index("input_output_alias="):
+                     text.index("entry_computation_layout=")]
+        assert re.findall(r"{(\d)}: \((\d+),", alias) == \
+            [("0", "15"), ("1", "16")], alias
         # 128 lines x 4 rows x 8 picks: 32 rows an expert here too; and the
         # forward a commit rides (PR 61), 8 rows a line: 64 an expert, in
         # tiles of 128, one tile and one fetch of its weights an expert
