@@ -47,7 +47,9 @@ TINY = {"LlamaConfig": lambda c: dataclasses.replace(c.tiny(), vocab_size=512, d
         # a share of the experts, both kinds of layer, the sink: the decode kernel's program with its extra operand
         "MimoConfig": lambda c: c.tiny(expert_shards=2, max_seq_len=MAX_SEQ, dtype="bfloat16"),
         # a share of the experts, both kinds of mixer in a period that repeats
-        "GraniteConfig": lambda c: c.tiny(expert_shards=2, max_seq_len=MAX_SEQ, dtype="bfloat16")}
+        "GraniteConfig": lambda c: c.tiny(expert_shards=2, max_seq_len=MAX_SEQ, dtype="bfloat16"),
+        # a share of the experts, and more positions in a line than the 8 a query keeps
+        "KeyeConfig": lambda c: c.tiny(expert_shards=2, max_seq_len=MAX_SEQ, dtype="bfloat16")}
 MODULES = getattr(llm_config, "SERVING_MODULES", None) or {
     kind: "ray_tpu.llm." + ("engine" if kind.__name__ == "LlamaConfig" else kind.__name__[:-len("Config")].lower() + "_serving")
     for kind in llm_config.ModelConfig.__args__}

@@ -14,6 +14,7 @@ from typing import Any, Union
 
 from ray_tpu.models.deepseek import DeepseekV2Config
 from ray_tpu.models.granite import GraniteConfig
+from ray_tpu.models.keye import KeyeConfig
 from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
@@ -41,6 +42,7 @@ SERVING_MODULES = {
     MimoConfig: "ray_tpu.llm.mimo_serving",
     LingConfig: "ray_tpu.llm.ling_serving",
     GraniteConfig: "ray_tpu.llm.granite_serving",
+    KeyeConfig: "ray_tpu.llm.keye_serving",
 }
 ModelConfig = Union[tuple(SERVING_MODULES)]
 

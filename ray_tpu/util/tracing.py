@@ -743,6 +743,13 @@ SUBPARTS = (
                      # another layer's line, inside ``attn``
     "gmu",           # a gated memory unit inside ``attn``: two projections
                      # around a gate of another layer's output
+    "indexer",       # a learned sparse attention's indexer inside ``attn``:
+                     # its three projections, the key's norm, the rotary and
+                     # the scores of every position a row sees
+    "index_select",  # inside ``attn``, each row's best positions alone: the
+                     # k-th largest score and the tie's cut
+    "sparse_attn",   # inside ``attn``, the attention over the selected
+                     # positions: the pass under the mask and its softmax
 )
 
 

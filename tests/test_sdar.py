@@ -656,7 +656,10 @@ def test_the_riding_share_is_a_metric_of_the_cell():
         ("serve_tok_s", "higher", "program_counter", "%")
     assert spec["layer"] == commit["layer"]
     assert spec["workloads"] == ["sdar-30b-serve-generate-512"]
-    assert manifest.load(REPO)["per_layer"][-1]["name"] == spec["name"]
+    # appended after the share it replaced (never "the last": a later PR
+    # appends its own, as PR 64 did)
+    names = [x["name"] for x in manifest.load(REPO)["per_layer"]]
+    assert names.index(spec["name"]) > names.index(commit["name"])
     # 128 lines, each 127 blocks of 128 with a commit riding
     polls = [(t, {"diffusion_commits_riding": 127 * n,
                   "diffusion_blocks": 128 * n})

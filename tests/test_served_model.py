@@ -22,7 +22,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
-from ray_tpu.llm import deepseek_serving, granite_serving, lfm2_serving
+from ray_tpu.llm import deepseek_serving, granite_serving, keye_serving
+from ray_tpu.llm import lfm2_serving
 from ray_tpu.llm import llama_serving
 from ray_tpu.llm import ling_serving, longcat_serving, mimo_serving
 from ray_tpu.llm import ouro_serving, phi4flash_serving, qwen3_next_serving
@@ -31,6 +32,7 @@ from ray_tpu.llm.config import SERVING_MODULES, ModelConfig
 from ray_tpu.llm.served import ServedModel, served_model
 from ray_tpu.models.deepseek import DeepseekV2Config
 from ray_tpu.models.granite import GraniteConfig
+from ray_tpu.models.keye import KeyeConfig
 from ray_tpu.models.lfm2 import Lfm2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.longcat import LongcatConfig
@@ -46,7 +48,7 @@ SLOTS, MAX_SEQ, CHUNK = 3, 64, 16
 
 @pytest.fixture(scope="module", autouse=True)
 def release_the_compiled_programs():
-    """After the module: its programs are its own (eleven models at sizes no
+    """After the module: its programs are its own (twelve models at sizes no
     other file uses), and a compiled program keeps its memory mappings for
     as long as JAX's caches hold it; a worker of the suite that never lets
     one go runs into ``vm.max_map_count`` and XLA's CPU compile dies under
@@ -105,11 +107,19 @@ def _granite():
                                                max_seq_len=MAX_SEQ)
 
 
+def _keye():
+    # 8 positions a query, fewer than a line holds
+    return keye_serving, KeyeConfig.tiny(expert_shards=2,
+                                         max_seq_len=MAX_SEQ)
+
+
 # The models of a token a step, and all of them.
 MODELS = dict(argvalues=[_llama, _longcat, _ouro, _lfm2, _deepseek,
-                         _qwen3_next, _phi4flash, _mimo, _ling, _granite],
+                         _qwen3_next, _phi4flash, _mimo, _ling, _granite,
+                         _keye],
               ids=["llama", "longcat", "ouro", "lfm2", "deepseek",
-                   "qwen3_next", "phi4flash", "mimo", "ling", "granite"])
+                   "qwen3_next", "phi4flash", "mimo", "ling", "granite",
+                   "keye"])
 ALL_MODELS = dict(argvalues=MODELS["argvalues"] + [_sdar],
                   ids=MODELS["ids"] + ["sdar"])
 

@@ -1602,3 +1602,119 @@ def test_ssd_chunk_and_step_compile_alone_at_the_cell_s_shapes(mosaic, form):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= lines * slots * 64 * 128 * 128 * 4
     assert mem.temp_size_in_bytes < 1 << 24
+
+
+# ISSUE 64: Keye-VL-2.0's first 12 layers at the published widths with 16 of
+# 128 experts, at the shapes of ``keye-vl2-serve-longctx-48k`` (8 slots x
+# 49,152): the two programs of llm/keye_serving.py as the cell compiles them.
+@pytest.mark.parametrize("program", ["prefill_chunk(512)", "decode_burst(8)"])
+def test_keye_programs_copy_no_cache_leaf_and_fit_the_chip(mosaic, program):
+    """The cache's three leaves ride every loop as carry: keys and values
+    (4.5 GiB each) and the index keys, ``[12, 8, 1, 64, 49152]``, the
+    positions last: stored a position a row of 64 values XLA laid the
+    parameter out positions-minor by itself and copied the leaf in and out
+    of every program around the kernels (two copies of 0.5625 GiB: the
+    first compile's 1.128 GiB of temporaries). None is the result of
+    anything but a parameter, a loop's tuple, a kernel's in-place operand
+    or an update in place; a chunk's ``[512, 49152]`` float32 scores are the
+    temporaries (0.095 GiB), a decode step's fit fast memory. The three
+    kernels of ops/sparse_attention.py are in both programs under the names
+    the trace is read by, and the index key's write is a kernel in a step
+    and an update in place in a chunk. Arguments and temporaries are what
+    benchmark/configs/keye-vl-2.0-30b-a3b.json states under ``reduced``."""
+    from devbench import keye_bench as bench
+
+    cfg = bench.config()
+    assert (cfg.num_layers, cfg.experts_held, cfg.router_rule.outputs,
+            cfg.vocab_size, cfg.index_topk, cfg.index_heads) == (
+                12, 16, 128, 18992, 2048, 16)
+    mem, text, _ = bench.compile_programs(cfg, only=program)[program]
+    carried = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+    assert 11.8 < mem.argument_size_in_bytes / 2 ** 30 < 11.9
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30
+    # the float32 reference of the check wants room beside the weights
+    assert total < 15.75 - 3.5
+    kernels = ["index_scores", "index_select", "moe_grouped_matmul"]
+    if program.startswith("prefill"):
+        kernels += ["sparse_prefill_attention"]
+        assert mem.temp_size_in_bytes < 0.15 * 2 ** 30
+        # 512 x 8 picks over 128 outputs are 32 rows an expert: tiles of 64
+        assert _grouped_matmul_rows(text) == {(4096 // 64 + 16) * 64}
+    else:
+        kernels += ["kv_row_write", "index_rows_write",
+                    "sparse_decode_attention"]
+        assert mem.temp_size_in_bytes < 1 << 26
+        # 8 x 8 picks: half a row an expert, tiles of 16
+        assert _grouped_matmul_rows(text) == {(64 // 16 + 16) * 16}
+    for name in kernels:
+        assert f'"{name}"' in text or f"%{name}." in text, name
+    # the program's own attention kernels are not on its path
+    assert "%decode_attention." not in text
+    assert "%prefill_attention." not in text
+    big = bench.big_shapes(cfg)
+    assert big["lines"] == "bf16[12,8,4,49152,128]"
+    assert big["index_k"] == "bf16[12,8,1,64,49152]"
+    for leaf in ("lines", "index_k"):
+        assert "parameter" in _opcodes_with_shape(text, big[leaf])
+        assert _opcodes_with_shape(text, big[leaf]) <= \
+            carried | {"dynamic-update-slice", "custom-call"}, leaf
+        assert not _rematerialised(text, big[leaf])
+    # the router's weights and its product float32 at true float32
+    assert "parameter" in _opcodes_with_shape(text, "f32[12,2048,128]")
+    routes = [line for line in text.splitlines()
+              if "moe_route/dot_general" in line
+              and re.search(r" (convolution|dot)\(", line)]
+    assert routes and all(
+        re.search(r"= f32\[\d+,128\]", line)
+        and "operand_precision={highest,highest}" in line
+        for line in routes), routes[:1]
+    for shape in ("we_in", "we_down", "wq"):
+        assert _opcodes_with_shape(text, big[shape]) <= \
+            carried | {"custom-call", "slice-start", "slice-done"}, shape
+
+
+@pytest.mark.parametrize("form", ["chunk", "step"])
+def test_the_sparse_attention_s_kernels_compile_alone_at_the_cell_s_shapes(
+        mosaic, form):
+    """The three ops at the cell's shapes, a chunk's 512 rows of one line
+    and a step's row of each of 8: one Mosaic call each, the scores the
+    only array of a line's size that any of them makes."""
+    from ray_tpu.ops import sparse_attention as sa
+
+    dev = NamedSharding(build_mesh(MeshSpec(), mosaic[:1]), P())
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    n, c = (1, 512) if form == "chunk" else (8, 1)
+    s, i32 = 49152, jnp.int32
+    lines = sds(n, dtype=i32)
+    scores = sds(n, c, s, dtype=jnp.float32)
+    compiled = [
+        jax.jit(sa.index_scores).lower(
+            sds(n, 16, c, 64), sds(n, 16, c, dtype=jnp.float32),
+            sds(12, 8, 1, 64, s), sds(dtype=i32), lines, lines,
+            lines).compile(),
+        jax.jit(sa.topk_threshold, static_argnums=1).lower(
+            sds(n * c, s, dtype=jnp.float32), 2048,
+            sds(n * c, dtype=i32)).compile(),
+        jax.jit(sa.sparse_attention).lower(
+            sds(n, 32, c, 128), sds(12, 8, 4, s, 128), sds(12, 8, 4, s, 128),
+            scores, sds(n, c, dtype=jnp.float32), sds(n, c, dtype=i32),
+            sds(dtype=i32), lines, lines, lines).compile()]
+    for program in compiled:
+        assert program.as_text().count(MOSAIC) == 1
+        # (a step's rows are padded to a tile of 16 beside the scores)
+        assert program.memory_analysis().temp_size_in_bytes < 1 << 26
+
+
+def test_sdar_s_programs_lower_to_what_they_were(v5e_2x2):
+    """models/keye.py builds its stack with models/sdar.py's ``layer``,
+    ``run_layers`` and ``lm_head`` and opens none of them: SDAR's programs
+    are what they were on PR 63's tree (``LOWERED``'s way)."""
+    from devbench import lowered_programs
+
+    got = lowered_programs.run("mosaic", ["sdar"])
+    assert {k: v for k, v in got.items() if k.endswith(".mosaic.jaxpr")} == {
+        "sdar.prefill_chunk.mosaic.jaxpr": "0d7d9d0919e51ad2",
+        "sdar.decode_burst.mosaic.jaxpr": "d52414571d8bc9f2"}

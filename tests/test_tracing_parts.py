@@ -25,8 +25,9 @@ from ray_tpu.util import tracing
 # The tiny served models and the programs' arguments, as the engine's own
 # contract test builds them.
 from test_served_model import (MAX_SEQ, SLOTS, _arguments, _deepseek,
-                               _granite, _lfm2, _ling, _llama, _longcat,
-                               _mimo, _ouro, _phi4flash, _qwen3_next)
+                               _granite, _keye, _lfm2, _ling, _llama,
+                               _longcat, _mimo, _ouro, _phi4flash,
+                               _qwen3_next)
 
 # What JAX itself puts on a name stack besides primitives' names.
 WRAPPERS = {"transpose", "jvp", "vmap", "pmap", "jit", "pjit", "while",
@@ -52,7 +53,8 @@ def test_the_finer_names_are_a_vocabulary_of_their_own():
                                 "delta_rule", "linear_state", "kda_rule",
                                 "kda_gate", "ssd", "ssm", "ssm_scan",
                                 "ssm_state",
-                                "window_attn", "cross_attn", "gmu")
+                                "window_attn", "cross_attn", "gmu",
+                                "indexer", "index_select", "sparse_attn")
     assert not set(tracing.SUBPARTS) & set(tracing.PARTS)
     assert all(re.fullmatch(r"[a-z_]+", p) for p in tracing.SUBPARTS)
     for name in tracing.SUBPARTS:
@@ -112,6 +114,8 @@ SERVED = {
     "mimo": (_mimo, DENSE | ROUTED),
     "ling": (_ling, DENSE | ROUTED),
     "granite": (_granite, DENSE | ROUTED),
+    # every layer's feed-forward is routed: no ``mlp``
+    "keye": (_keye, (DENSE - {"mlp"}) | ROUTED),
 }
 LONGCAT_GONE = ("longcat.mla", "longcat.moe", "longcat.moe.experts")
 
@@ -206,6 +210,20 @@ def test_a_serving_program_opens_its_parts(model, program):
         assert re.search(r"mlp/moe_shared/dot_general", text)
         assert not re.search(
             r"[^/\w](linear_attn|ssd|conv|linear_state|moe_shared)/", text)
+    if model == "keye":
+        # The learned sparse attention's three steps lie inside ``attn``,
+        # each under its own name: the indexer's projections and scores, the
+        # selection, the pass under the mask; the index key's write is
+        # ``cache``'s like the keys' and values'; the attention's own
+        # projections stay plain ``attn``.
+        assert re.search(r"attn/indexer/dot_general", text)
+        assert re.search(r"attn/indexer/[^\"]*max", text)      # the ReLU
+        assert re.search(r"attn/index_select/", text)
+        assert re.search(r"attn/sparse_attn/", text)
+        assert re.search(r"attn/cache/", text)
+        assert re.search(r"attn/dot_general", text)
+        assert not re.search(
+            r"[^/\w](indexer|index_select|sparse_attn)/", text)
     if model == "mimo":
         # A window layer's attention proper (the ring's read with the sink,
         # a chunk's banded product) lies under ``window_attn`` inside
