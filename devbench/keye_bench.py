@@ -17,9 +17,11 @@ stacked leaf of the experts, by opcode. ``kernel``: the three ops of
 chunk's 512 rows against 4,096 to 44,544 cached rows and a decode step's 8
 rows at lines of 8,192 to 45,056: device milliseconds a call (12 calls
 inside one program), each beside its yardstick (``adapters/keye.py``'s
-work over the chip's peaks), the kernels against their jnp references at
-one shape, and what the selection's and the decode attention's other forms
-cost there: ``lax.top_k`` in the threshold's place, and a gather of the
+work over the chip's peaks) and the attention beside what its pass does
+besides: a chunk's call as a share of the dense pass's FLOPs at the peak, a
+step's as a share of its whole lines' bytes at the peak; the kernels
+against their jnp references at one shape, and what the selection's and the
+decode attention's other forms cost there: ``lax.top_k`` in the threshold's place, and a gather of the
 2,048 chosen rows in the masked pass's place. ``step``: wall milliseconds of
 one decode step inside a burst of 8 and of a prefill chunk of 512 (the clock
 stops on a host read of the result). ``margins``: the serving programs in
@@ -252,7 +254,18 @@ def kernel() -> dict:
                 cached + c)
         row["sparse_attention_ms"] = ms
         row["sparse_attention_roofline_pct"] = 100 * least_ms(work) / ms
-        if form == "step":
+        # What the pass under the mask does, where the yardstick above
+        # counts the chosen positions alone: a chunk multiplies every
+        # position a row sees, a step fetches its lines whole.
+        if form == "chunk":
+            dense = 4.0 * cfg.head_dim * cfg.num_heads * seen
+            row["sparse_attention_dense_flops_pct"] = (
+                100 * 1e3 * dense / peaks["bf16_flops_per_s"] / ms)
+        else:
+            whole = adapter.selected_bytes_per_position(cj) * seen
+            row["sparse_attention_line_bytes_pct"] = (
+                100 * 1e3 * whole / peaks["hbm_bytes_per_s"] / ms)
+
             def gathered(l, q, kc, vc, scores):
                 """The other form: the chosen rows gathered, then a dense
                 attention over 2,048."""

@@ -35,7 +35,11 @@ decode step a row of every slot.
   the mask does 16 index heads of extra work a tile and no gather) and for
   a decode row alike: a list of 2,048 scattered positions is 2,048 x 8
   fetches of 256 bytes from a cache laid out a head a line, which costs
-  more than the line (PERF.md).
+  more than the line (PERF.md). A grid step takes a block of 1,024 keys of
+  every KV head: a chunk's tile is each head's group x 128 rows under 128
+  masks, a step's its token's group of heads under the token's one mask,
+  and the key blocks past the longest line's last row are no grid steps
+  at all (a run-time bound).
 
 The three implementations of ops/kernels.py each: the Mosaic kernel on a
 TPU, its body through the Pallas interpreter for tests, a jnp reference
@@ -51,7 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops.decode_attention import NEG_INF, decode_kv_block
+from ray_tpu.ops.decode_attention import NEG_INF
 from ray_tpu.ops.kernels import kernel_backend
 from ray_tpu.ops.prefill_attention import prefill_q_block
 
@@ -364,54 +368,72 @@ def sparse_attention_reference(q, k_cache, v_cache, scores, thr, pcut, layer,
     return out.astype(q.dtype).reshape(n, h, c, d)
 
 
+# Where a running maximum starts, and not at NEG_INF: a masked logit under it
+# gives exp(NEG_INF - floor) == 0 with no compare and no second select, so a
+# row that has kept nothing yet (or never does) adds zeros; every real logit
+# lies above it.
+_MAX_FLOOR = 0.5 * NEG_INF
+# Keys a grid step: a step of this body costs about 2 us before its first
+# key (a chunk's tile took 2.99 us for 512 keys where its products take 1.36,
+# and takes 4.0 for 1,024), and past 1,024 the tiles of logits outgrow what
+# is saved (PERF.md section 6, PR 65).
+_KEY_BLOCK = 1024
+
+
 def _sparse_attention_kernel(layer_ref, slot_ref, q0_ref, lim_ref, q_ref,
                              k_ref, v_ref, s_ref, thr_ref, pcut_ref, o_ref,
-                             m_ref, l_ref, acc_ref, *, block_q: int,
-                             block_k: int, sm_scale: float):
+                             m_ref, l_ref, acc_ref, *, block_k: int,
+                             sm_scale: float):
     from jax.experimental import pallas as pl
 
     del layer_ref, slot_ref  # read by the index maps
-    n, tile, blk = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-    group, _, d = q_ref.shape
-    rows = group * block_q
-    end = jnp.minimum(q0_ref[n] + (tile + 1) * block_q, lim_ref[n])
+    n, tile, blk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    # The tile: every KV head's ``heads`` x ``per`` rows under the tile's
+    # ``tokens`` masks, a mask a token whatever the head. A chunk's is
+    # (group, tokens): row g * tokens + t is query head g of token t. A
+    # step's is (1, group): the one token's heads, all under its mask.
+    hkv, heads, per, d = q_ref.shape
+    rows = heads * per
+    tokens = s_ref.shape[0]
+    end = jnp.minimum(q0_ref[n] + (tile + 1) * tokens, lim_ref[n])
 
     @pl.when(blk == 0)
     def _():
-        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        m_ref[...] = jnp.full(m_ref.shape, _MAX_FLOOR, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     @pl.when(blk * block_k < end)
     def _():
-        # Row r of the tile is query head g, token t, r = g * block_q + t;
-        # the set is a token's, the same for its heads.
         index = s_ref[...]
         kpos = blk * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
+            jnp.int32, (tokens, block_k), 1)
         keep = (index > thr_ref[...]) | (
             (index == thr_ref[...]) & (kpos <= pcut_ref[...]))
-        s = lax.dot_general(q_ref[...].reshape(rows, d), k_ref[...],
-                            (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        s = jnp.where(keep[None], s.reshape(group, block_q, block_k),
-                      NEG_INF).reshape(rows, block_k)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        # The select keeps a row with nothing kept yet at zero
-        # (exp(NEG_INF - NEG_INF) would be one).
-        p = jnp.where(s > 0.5 * NEG_INF, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
-            p.astype(v_ref.dtype), v_ref[...],
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        for g in range(hkv):
+            at = pl.ds(g * rows, rows)
+            s = lax.dot_general(q_ref[g].reshape(rows, d), k_ref[g],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            s = jnp.where(keep[None], s.reshape(heads, per, block_k),
+                          NEG_INF).reshape(rows, block_k)
+            # The maximum runs over the products as they are; the scale
+            # goes into the exponent.
+            m_prev = m_ref[at, :]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp((s - m_new) * sm_scale)
+            alpha = jnp.exp((m_prev - m_new) * sm_scale)
+            l_ref[at, :] = alpha * l_ref[at, :] + p.sum(axis=-1,
+                                                        keepdims=True)
+            acc_ref[at, :] = alpha * acc_ref[at, :] + jnp.dot(
+                p.astype(v_ref.dtype), v_ref[g],
+                preferred_element_type=jnp.float32)
+            m_ref[at, :] = m_new
 
-    @pl.when(blk == pl.num_programs(3) - 1)
+    @pl.when(blk == pl.num_programs(2) - 1)
     def _():
         o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = o.reshape(group, block_q, d).astype(o_ref.dtype)
+        o_ref[...] = o.reshape(o_ref.shape).astype(o_ref.dtype)
 
 
 def _sparse_attention_pallas(q, k_cache, v_cache, scores, thr, pcut, layer,
@@ -422,61 +444,79 @@ def _sparse_attention_pallas(q, k_cache, v_cache, scores, thr, pcut, layer,
     n, h, c, d = q.shape
     hkv, s = k_cache.shape[2:4]
     group = h // hkv
-    block_q = prefill_q_block(c, group, q.dtype.itemsize)
-    # A chunk's tile of 1,024 rows takes the decode kernel's block; a decode
-    # row's tile of 128 takes four of them a grid step, for the steps' cost.
-    block_k = (decode_kv_block(s, d, k_cache.dtype.itemsize)
-               if group * block_q > 256 else _divisor_block(s, 2048))
-    c_pad = -(-c // block_q) * block_q
-    qg = q.reshape(n, hkv, group, c, d)
-    if c_pad != c:
-        pad = c_pad - c
-        qg = jnp.pad(qg, ((0, 0),) * 3 + ((0, pad), (0, 0)))
+    block_k = _divisor_block(s, _KEY_BLOCK)
+    if c == 1:
+        # A step's tile is its token's heads under the token's one mask,
+        # the scores as they come (a tile of tokens would be padding but
+        # for one row a head, the scores padded to match). Whole float32
+        # sublane tiles a KV head.
+        tokens, heads, per = 1, 1, -(-group // 8) * 8
+    else:
+        tokens = prefill_q_block(c, group, q.dtype.itemsize)
+        heads, per = group, tokens
+    tiles = -(-c // tokens)
+    # [N, Hkv, 1, group, D] for a step, [N, Hkv, group, C, D] for a chunk.
+    qg = q.reshape(n, hkv, heads, -1, d)
+    real = qg.shape[3]
+    if per * tiles != real:
+        qg = jnp.pad(qg, ((0, 0),) * 3 + ((0, per * tiles - real), (0, 0)))
+    if tokens * tiles != c:
+        pad = tokens * tiles - c
         scores = jnp.pad(scores, ((0, 0), (0, pad), (0, 0)),
                          constant_values=-jnp.inf)
         thr = jnp.pad(thr, ((0, 0), (0, pad)))
         pcut = jnp.pad(pcut, ((0, 0), (0, pad)), constant_values=-1)
     scalars = _line_scalars(layer, slots, q0, limits, s)
+    # A run-time bound: key blocks past every line's last row do not exist
+    # as grid steps (0.35 us each, dead). One step at the least, which
+    # writes an idle call's zeros.
+    blocks = jnp.maximum(pl.cdiv(jnp.max(jnp.minimum(
+        scalars[2] + c, scalars[3])), block_k), 1)
 
     def last_live(i, t, p0, lim):
-        end = jnp.minimum(p0[i] + (t + 1) * block_q, lim[i])
+        end = jnp.minimum(p0[i] + (t + 1) * tokens, lim[i])
         return jnp.maximum(pl.cdiv(end, block_k) - 1, 0)
 
-    def kv_index(i, g, t, j, lyr, slot, p0, lim):
-        return (lyr[0], slot[i], g, jnp.minimum(j, last_live(i, t, p0, lim)),
+    def kv_index(i, t, j, lyr, slot, p0, lim):
+        return (lyr[0], slot[i], 0, jnp.minimum(j, last_live(i, t, p0, lim)),
                 0)
 
-    def q_index(i, g, t, j, *_):
-        return (i, g, 0, t, 0)
+    def q_index(i, t, j, *_):
+        return (i, 0, 0, t, 0)
 
-    def score_index(i, g, t, j, lyr, slot, p0, lim):
+    def score_index(i, t, j, lyr, slot, p0, lim):
         return (i, t, jnp.minimum(j, last_live(i, t, p0, lim)))
 
-    def row_index(i, g, t, j, *_):
+    def row_index(i, t, j, *_):
         return (i, t, 0)
 
-    rows = group * block_q
-    kv_spec = pl.BlockSpec((None, None, None, block_k, d), kv_index)
-    q_spec = pl.BlockSpec((None, None, group, block_q, d), q_index)
-    row_spec = pl.BlockSpec((None, block_q, 1), row_index)
+    rows = hkv * heads * per
+    # Every KV head a grid step: the mask is built and the scores fetched
+    # once for the four, and the steps are a quarter as many.
+    kv_spec = pl.BlockSpec((None, None, hkv, block_k, d), kv_index)
+    q_spec = pl.BlockSpec((None, hkv, heads, per, d), q_index)
+    row_spec = pl.BlockSpec((None, tokens, 1), row_index)
     out = pl.pallas_call(
-        functools.partial(_sparse_attention_kernel, block_q=block_q,
-                          block_k=block_k, sm_scale=sm_scale),
+        functools.partial(_sparse_attention_kernel, block_k=block_k,
+                          sm_scale=sm_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(n, hkv, c_pad // block_q, s // block_k),
+            grid=(n, tiles, blocks),
             in_specs=[q_spec, kv_spec, kv_spec,
-                      pl.BlockSpec((None, block_q, block_k), score_index),
+                      pl.BlockSpec((None, tokens, block_k), score_index),
                       row_spec, row_spec],
             out_specs=q_spec,
             scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
                             pltpu.VMEM((rows, 1), jnp.float32),
                             pltpu.VMEM((rows, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((n, hkv, group, c_pad, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-            vmem_limit_bytes=max(32 << 20, 12 * rows * block_k * 4)),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # A head's float32 logits and what is made of them, and the
+            # heads' key and value blocks twice over.
+            vmem_limit_bytes=max(
+                32 << 20, 12 * heads * per * block_k * 4,
+                8 * hkv * block_k * d * k_cache.dtype.itemsize)),
         interpret=kernel_backend() == "interpret",
         # Two names for a trace to tell a decode step's calls (a row a line)
         # from a chunk's.
@@ -484,7 +524,7 @@ def _sparse_attention_pallas(q, k_cache, v_cache, scores, thr, pcut, layer,
         "sparse_prefill_attention",
     )(*scalars, qg, k_cache, v_cache, scores, thr[..., None],
       pcut[..., None])
-    return out[:, :, :, :c].reshape(n, h, c, d)
+    return out[:, :, :, :real].reshape(n, h, c, d)
 
 
 def sparse_attention(q, k_cache, v_cache, scores, thr, pcut, layer, slots,

@@ -17,12 +17,23 @@ from ray_tpu.ops.kernels import force_kernel_backend
 
 L, B, S, DI, J, K = 2, 3, 256, 64, 4, 16
 H, HKV, D = 4, 2, 128
-# (lines, rows a line, their slots, first positions, limits): a chunk in the
-# middle of a prompt, a prompt's padded last chunk (its rows past the limit
-# see what the last real row sees), and a decode step with an idle slot.
-SHAPES = {"chunk": (1, 24, [2], [100], [124]),
-          "padded chunk": (1, 24, [1], [100], [110]),
-          "step": (3, 1, [0, 1, 2], [5, 200, 17], [6, 201, 0])}
+# A line long enough for several of the attention's key blocks (1,024 keys a
+# grid step), and the positions of slot 1 whose index keys are zero there:
+# they score 0.0, below every kept score of a row that sees thousands.
+LONG, BLANK = 4096, 2048
+# (lines, rows a line, their slots, first positions, limits, the lines'
+# length): a chunk in the middle of a prompt, a prompt's padded last chunk
+# (its rows past the limit see what the last real row sees), a decode step
+# with an idle slot; a step over several key blocks whose second line keeps
+# nothing in its first two (the running maximum stays at its floor through
+# them), and a chunk of two tiles across a key block's edge, the first
+# tile's last query (position 1,021) short of it.
+SHAPES = {"chunk": (1, 24, [2], [100], [124], S),
+          "padded chunk": (1, 24, [1], [100], [110], S),
+          "step": (3, 1, [0, 1, 2], [5, 200, 17], [6, 201, 0], S),
+          "long step": (3, 1, [0, 1, 2], [1500, 3900, 17], [1501, 3901, 0],
+                        LONG),
+          "long chunk": (1, 520, [2], [510], [1030], LONG)}
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -32,15 +43,25 @@ def release_the_compiled_programs():
 
 
 @pytest.fixture(scope="module")
-def leaves():
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    return (jax.random.normal(ks[0], (L, B, 1, DI, S)),
-            jax.random.normal(ks[1], (L, B, HKV, S, D)),
-            jax.random.normal(ks[2], (L, B, HKV, S, D)))
+def caches():
+    """{a line's length: (index keys, keys, values)}."""
+    def make(s, seed):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+        return (jax.random.normal(ks[0], (L, B, 1, DI, s)),
+                jax.random.normal(ks[1], (L, B, HKV, s, D)),
+                jax.random.normal(ks[2], (L, B, HKV, s, D)))
+    index_k, k, v = make(LONG, 1)
+    return {S: make(S, 0),
+            LONG: (index_k.at[:, 1, :, :, :BLANK].set(0.0), k, v)}
+
+
+@pytest.fixture
+def leaves(caches, name):
+    return caches[SHAPES[name][5]]
 
 
 def _inputs(name):
-    n, c, slots, q0, lim = SHAPES[name]
+    n, c, slots, q0, lim, _ = SHAPES[name]
     ks = jax.random.split(jax.random.PRNGKey(len(name)), 3)
     return (jax.random.normal(ks[0], (n, J, c, DI)),
             jax.random.normal(ks[1], (n, J, c)),
@@ -51,7 +72,7 @@ def _inputs(name):
 def _plain_scores(q, w, index_k, layer, slots, q0, lim):
     """The equation, a loop a row: sum_j w relu(q . k) over what it sees."""
     n, _, c, _ = q.shape
-    out = np.full((n, c, S), -np.inf, np.float32)
+    out = np.full((n, c, index_k.shape[4]), -np.inf, np.float32)
     for i in range(n):
         keys = np.asarray(index_k[layer, int(slots[i]), 0]).T      # [S, Di]
         for t in range(c):
@@ -97,7 +118,7 @@ def test_the_threshold_and_the_cut_are_top_k_s_sets(leaves, name, ties,
     q, w, _, slots, q0, lim = _inputs(name)
     n, _, c, _ = q.shape
     scores = _plain_scores(q, w, leaves[0], 1, slots, q0, lim).reshape(
-        n * c, S)
+        n * c, -1)
     if ties:
         scores = np.where(np.isfinite(scores), np.round(scores * 2) / 2,
                           scores).astype(np.float32)
@@ -137,7 +158,7 @@ def test_the_attention_is_a_softmax_over_each_row_s_set(leaves, name,
     index_k, kc, vc = leaves
     scores = np.round(_plain_scores(q, w, index_k, 1, slots, q0, lim) * 2) / 2
     scores = jnp.asarray(scores.astype(np.float32))
-    thr, pcut = sa.topk_threshold_reference(scores.reshape(n * c, S), K)
+    thr, pcut = sa.topk_threshold_reference(scores.reshape(n * c, -1), K)
     thr, pcut = thr.reshape(n, c), pcut.reshape(n, c)
     keep = _top_k_sets(np.asarray(scores), K)
     with force_kernel_backend(backend):
@@ -160,8 +181,8 @@ def test_the_attention_is_a_softmax_over_each_row_s_set(leaves, name,
 
 
 @pytest.mark.parametrize("backend", ["reference", "interpret"])
-def test_the_index_key_s_writes_land_where_the_rows_are(leaves, backend):
-    index_k = leaves[0]
+def test_the_index_key_s_writes_land_where_the_rows_are(caches, backend):
+    index_k = caches[S][0]
     new = jax.random.normal(jax.random.PRNGKey(7), (B, DI))
     pos, mask = jnp.asarray([5, 200, 130]), jnp.asarray([True, False, True])
     with force_kernel_backend(backend):

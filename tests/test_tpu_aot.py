@@ -1648,6 +1648,15 @@ def test_keye_programs_copy_no_cache_leaf_and_fit_the_chip(mosaic, program):
         assert _grouped_matmul_rows(text) == {(64 // 16 + 16) * 16}
     for name in kernels:
         assert f'"{name}"' in text or f"%{name}." in text, name
+    # ISSUE 65: a step's pass under the mask takes the scores as they come,
+    # a row a line: nothing pads them to ``f32[8,16,49152]`` (25 MB a layer
+    # and step); the one result of that shape is ``index_scores``' own, its
+    # tile of 16 rows
+    if program.startswith("decode"):
+        assert len(re.findall(r"= f32\[8,16,49152\]\S* ([a-z-]+)\(",
+                              text)) == 1
+        assert _opcodes_with_shape(text, "f32[8,16,49152]") == {
+            "custom-call"}
     # the program's own attention kernels are not on its path
     assert "%decode_attention." not in text
     assert "%prefill_attention." not in text
@@ -1704,7 +1713,7 @@ def test_the_sparse_attention_s_kernels_compile_alone_at_the_cell_s_shapes(
             sds(dtype=i32), lines, lines, lines).compile()]
     for program in compiled:
         assert program.as_text().count(MOSAIC) == 1
-        # (a step's rows are padded to a tile of 16 beside the scores)
+        # (a step's scores go in as they are, a row a line: ISSUE 65)
         assert program.memory_analysis().temp_size_in_bytes < 1 << 26
 
 
