@@ -127,12 +127,15 @@ def host_phases(root: str) -> dict:
     """The trace's ``serve.chunk_out`` and ``serve.close`` events as
     (seconds, stats) pairs, and under ``last`` each stream's last chunk:
     the ``serve.chunk_out`` before a ``serve.close`` on the same thread's
-    line (a request thread writes its stream's chunks, then closes it)."""
+    line (a request thread writes its stream's chunks, then closes it);
+    the scheduler's blocking reads, ``engine.fetch``, likewise (their stats
+    say ``which`` program was read and, since PR 66, ``why``)."""
     sys.path.insert(0, os.path.join(root, "benchmark"))
     import jax
     from rtbench import trace_reduce
 
-    out = {"serve.chunk_out": [], "serve.close": [], "last": []}
+    out = {"serve.chunk_out": [], "serve.close": [], "last": [],
+           "engine.fetch": []}
     path = trace_reduce.find_xplane(os.path.join(root, ".bench_trace"))
     if not path:
         return out
@@ -233,7 +236,16 @@ def budgets(root: str, metrics: dict, stats: dict) -> dict:
     if all(v is not None for v in [turn["slot_vacant"], *named]):
         turn["sum"] = sum(named)
         turn["remainder"] = turn["slot_vacant"] - turn["sum"]
+    # The scheduler's blocking reads by what was read and why: a span's
+    # idle seconds under ``engine.fetch`` belong to one of these.
+    fetches: dict = {}
+    for seconds, st in ph["engine.fetch"]:
+        row = fetches.setdefault(f"{st.get('which')}/{st.get('why')}",
+                                 {"n": 0, "ms": 0.0})
+        row["n"] += 1
+        row["ms"] += seconds * 1e3
     return {"first_token": first, "turn_round": turn, "client": client,
+            "fetches": fetches,
             "events": {"chunk_out": len(out_ev), "last": len(last_ev),
                        "close": len(close_ev)}}
 

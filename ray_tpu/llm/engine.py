@@ -142,9 +142,6 @@ class GenerationRequest:
     draft_len: int = 0  # draft-cache positions filled (speculative decoding)
     draft_fail_count: int = 0  # consecutive draft catch-up failures
     spec_disabled: bool = False  # excluded from speculation (see _spec_decode)
-    # Unfetched model counts of this prompt's earlier chunks (a model with
-    # ServedModel.counters); fetched with the first token.
-    chunk_counts: list = field(default_factory=list)
     # Request tracing: the submitter's propagated context (None = untraced)
     # plus the phase timestamps the scheduler thread stamps engine spans
     # from (engine.queue / engine.prefill / engine.decode — the TTFT
@@ -165,7 +162,9 @@ class _InFlight:
     is K positions; ``last_row`` its final row, handed to the next burst:
     a line's input token, or its ServedModel.pending_step).
     ``reqs`` are the lines it computes for, by slot; ``counts`` the model's
-    own (ServedModel.counters), fetched with the tokens."""
+    own (ServedModel.counters), fetched with the tokens: the program's and
+    those of the prefill chunks dispatched since the entry before it
+    (LLMEngine._take_counts), which the device has run by then."""
     reqs: dict[int, GenerationRequest]
     toks: Any
     counts: list = field(default_factory=list)
@@ -391,6 +390,10 @@ class LLMEngine:
         # otherwise (and with a draft model, whose ticks read the host's
         # tokens) a tick reads out all it dispatched.
         self._in_flight: deque[_InFlight] = deque()
+        # Model counts (ServedModel.counters) of prefill chunks dispatched
+        # since the last program whose result the host reads: they go with
+        # the next such program (_take_counts).
+        self._chunk_counts: list = []
         self._pipelined = bool(config.decode_pipeline) \
             and self.draft_cfg is None
         self._stop = threading.Event()
@@ -668,7 +671,7 @@ class LLMEngine:
         # Read out what is in flight so its requests get their tokens
         # instead of hanging to their timeouts.
         try:
-            self._read_all()
+            self._read_all("stop")
         except Exception:  # noqa: BLE001 - shutdown path
             pass
 
@@ -731,7 +734,7 @@ class LLMEngine:
             # Nothing to queue behind what runs (every line in flight ends
             # there, or only first tokens are): read it out.
             if self._in_flight:
-                self._read_all()
+                self._read_all("tail")
                 return True
             return worked
 
@@ -769,7 +772,7 @@ class LLMEngine:
         or a last token) first read out everything, because they take
         their tokens from the host."""
         if self.draft_params is not None:
-            self._read_all()
+            self._read_all("speculative")
             decoding = self._decoding()
             # Speculative path serves greedy requests with spec headroom;
             # the rest (stochastic sampling, near end-of-cache) ride the
@@ -799,7 +802,7 @@ class LLMEngine:
         if self._in_flight and (host_only or (
                 self.model.step is None
                 and self._burst_len(active) <= 1)):
-            self._read_all()
+            self._read_all("host_only" if host_only else "single_step")
             active = self._decoding()
         if active:
             self._decode(active)
@@ -842,37 +845,51 @@ class LLMEngine:
             if bursts <= 1 and (self._in_flight[0].steps or not bursts):
                 break
             read = True
-            if not self._read(self._in_flight.popleft()):
+            if not self._read(self._in_flight.popleft(), "oldest"):
                 break
         return read
 
-    def _read_all(self) -> bool:
-        """Read out everything in flight, oldest first. False iff a device
-        failure wiped the engine state on the way."""
+    def _read_all(self, why: str) -> bool:
+        """Read out everything in flight, oldest first; ``why`` is the
+        caller's reason, which every ``engine.fetch`` it blocks in carries:
+        ``tail`` (nothing to queue behind what runs), ``single_step`` and
+        ``host_only`` (a decode batch that takes its tokens from the host),
+        ``speculative``, ``serial`` (the schedule without the look-ahead)
+        and ``stop``; ``oldest`` is _read_oldest's, the look-ahead's own
+        read. False iff a device failure wiped the engine state on the
+        way."""
         while self._in_flight:
-            if not self._read(self._in_flight.popleft()):
+            if not self._read(self._in_flight.popleft(), why):
                 return False
         return True
 
-    def _read(self, entry: _InFlight) -> bool:
+    def _take_counts(self) -> list:
+        """The model counts of the prefill chunks dispatched since the last
+        call, for the program the caller has just dispatched: its fetch
+        brings them, and the device, which runs programs in dispatch
+        order, has run those chunks when that program's tokens are there.
+        Never for a program dispatched before them: a fetch of the burst
+        before last would wait on chunks queued behind the last."""
+        counts, self._chunk_counts = self._chunk_counts, []
+        return counts
+
+    def _read(self, entry: _InFlight, why: str) -> bool:
         """Block on one program's tokens and emit them. False iff they did
         not come: an asynchronous dispatch error surfaces at
         materialization, and the engine state is suspect."""
-        counts = entry.counts
+        toks = entry.toks
         if not entry.steps:
             (req,) = entry.reqs.values()
-            counts, req.chunk_counts = req.chunk_counts, []
-            if req.done.is_set():  # failed meanwhile
-                return True
-        else:
-            # Counts of a prompt whose chunks gave no token to be fetched
-            # with (ServedModel.prefill_token): with its first burst.
-            for req in entry.reqs.values():
-                counts, req.chunk_counts = counts + req.chunk_counts, []
+            if req.done.is_set():  # failed meanwhile: no token is wanted,
+                # but the chunks that came with it ran and are counted
+                if not entry.counts:
+                    return True
+                toks = None
         try:
             with tracing.phase("engine.fetch",
-                               which="burst" if entry.steps else "prefill"):
-                toks, counts = jax.device_get((entry.toks, counts))
+                               which="burst" if entry.steps else "prefill",
+                               why=why):
+                toks, counts = jax.device_get((toks, entry.counts))
             self._add_model_counts(*counts)
         except Exception as e:  # noqa: BLE001 - cache donated & lost
             what = "decode" if entry.steps else "prefill"
@@ -884,7 +901,7 @@ class LLMEngine:
             for req in entry.reqs.values():
                 req.ahead -= entry.steps
             self._emit_burst(entry.reqs, entry.steps, toks)
-        else:
+        elif toks is not None:
             with tracing.phase("engine.emit", tokens=1):
                 self._emit(req, int(toks[0]))
         return True
@@ -1187,7 +1204,7 @@ class LLMEngine:
                 self.model_cfg, self.params, self.cache,
                 jnp.asarray(toks), jnp.int32(req.prefilled_len),
                 jnp.int32(p), jnp.int32(slot), kmesh=self.kmesh)
-            req.chunk_counts += counts
+            self._chunk_counts += counts
             self.prefill_kv_positions_read += min(
                 req.prefilled_len + bucket, self.max_seq)
             self.prefill_kv_positions_reserved += self.max_seq
@@ -1201,7 +1218,8 @@ class LLMEngine:
                 req.next_pos = p
                 if self.model.prefill_token:
                     out = self._sample_dispatch(logits[None], [req])
-                    self._in_flight.append(_InFlight({slot: req}, out))
+                    self._in_flight.append(
+                        _InFlight({slot: req}, out, self._take_counts()))
         except Exception as e:  # noqa: BLE001 - e.g. OOM on long prompt
             logger.exception("prefill failed for %s", req.request_id)
             self._recover_device_failure(f"prefill failed: {e!r}")
@@ -1215,6 +1233,7 @@ class LLMEngine:
         self.device_failures += 1
         self._cache_gen += 1  # invalidates in-flight prefill_only exports
         self._in_flight.clear()  # dispatched into the lost cache
+        self._chunk_counts.clear()
         for req in list(self._slots.values()):
             if req is None:
                 continue
@@ -1284,7 +1303,7 @@ class LLMEngine:
             ok = self._decode_burst(active, burst)
             # Not pipelined: strictly serial, a tick reads what it
             # dispatched before the next one begins.
-            return ok if self._pipelined else ok and self._read_all()
+            return ok if self._pipelined else ok and self._read_all("serial")
         # A single step takes each line's token from the host: nothing of
         # these lines is in flight (_dispatch_decode read it out).
         try:
@@ -1304,9 +1323,11 @@ class LLMEngine:
         self._count_kv_positions(positions, write, 1)
         try:
             reqs = [active.get(s) for s in range(self.max_slots)]
-            with tracing.phase("engine.fetch", which="step"):
+            with tracing.phase("engine.fetch", which="step",
+                               why="single_step"):
                 sampled, counts = jax.device_get(
-                    (self._sample_dispatch(logits, reqs), counts))
+                    (self._sample_dispatch(logits, reqs),
+                     counts + self._take_counts()))
             self._add_model_counts(*counts)
         except Exception as e:  # noqa: BLE001 - cache survived; only this
             # batch's requests lack tokens — fail them, keep other contexts.
@@ -1478,8 +1499,9 @@ class LLMEngine:
         self._count_kv_positions(positions, write, burst)
         for req in active.values():
             req.ahead += burst
-        self._in_flight.append(
-            _InFlight(dict(active), toks, counts, burst, last_row))
+        self._in_flight.append(_InFlight(
+            dict(active), toks, counts + self._take_counts(), burst,
+            last_row))
         return True
 
     def _add_model_counts(self, *fetched) -> None:

@@ -388,7 +388,12 @@ def mixed_burst_program(step: Callable, mixed_step: Callable,
         carry = (cache, token0, positions0,
                  jnp.zeros((steps, *token0.shape), jnp.int32), *zero)
         with tracing.part("stack"):
-            carry = lax.fori_loop(0, n, partial(tick, riding=True), carry)
+            # The device says which of its steps carried a chunk: a trace
+            # has one event a program, and the riding ticks' operations
+            # carry the kind on their paths (tracing.STEP_KINDS).
+            with tracing.part("mixed_step"):
+                carry = lax.fori_loop(0, n, partial(tick, riding=True),
+                                      carry)
             carry = lax.fori_loop(n, steps, partial(tick, riding=False),
                                   carry)
         cache, _, _, toks, *counts = carry

@@ -753,6 +753,20 @@ SUBPARTS = (
 )
 
 
+# The kinds of step of a program whose steps are not all alike, opened
+# *around* a step's parts and never inside one: the parts and finer names of
+# a step of that kind lie under it on the path, and the readers that
+# partition a trace by PARTS or look for SUBPARTS pass over a segment they do
+# not know, so they read what they read. A reader that is given a kind finds
+# it anywhere on the path (benchmark/rtbench/readers/step_kind_ms.py). The
+# same rule on clashes. A step without one is the program's plain step.
+STEP_KINDS = (
+    "mixed_step",    # a decode step that carries a prefill chunk: the riding
+                     # loop of served.mixed_burst_program (a block model's
+                     # wide forward is the next name this may take)
+)
+
+
 def part(name: str):
     """A part of a jitted program: where :func:`phase` is the host's
     interval on the profiler's clock, this is the device's. It returns
@@ -760,11 +774,11 @@ def part(name: str):
     function: the name lands on the name stack of every operation traced
     under it (``tf_op`` in the device trace, the grouping in XProf) and
     costs nothing once the program is compiled. A name outside
-    :data:`PARTS` and :data:`SUBPARTS` is refused here, at trace time, and
-    not silently in a reader."""
-    if name not in PARTS and name not in SUBPARTS:
-        raise ValueError(f"tracing.part({name!r}): not one of "
-                         f"{PARTS + SUBPARTS}")
+    :data:`PARTS`, :data:`SUBPARTS` and :data:`STEP_KINDS` is refused here,
+    at trace time, and not silently in a reader."""
+    names = PARTS + SUBPARTS + STEP_KINDS
+    if name not in names:
+        raise ValueError(f"tracing.part({name!r}): not one of {names}")
     import jax
 
     return jax.named_scope(name)

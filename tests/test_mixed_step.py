@@ -19,7 +19,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from test_served_model import chunks_that_ride_leave_every_answer_as_it_was
+from test_served_model import (
+    chunks_that_ride_leave_every_answer_as_it_was,
+    counts_arrive_with_the_program_behind_their_chunk)
 
 from ray_tpu.ops.kernels import force_kernel_backend
 
@@ -333,6 +335,67 @@ def test_a_mixed_burst_is_its_chunks_and_then_the_burst(model, riders):
             + riders * chunk * cfg.num_experts_per_tok * nm
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("model", list(DRIVEN))
+def test_the_device_is_told_which_steps_of_a_burst_carried_a_chunk(model):
+    """The riding loop of a lowered ``mixed_burst`` carries ``mixed_step``
+    (``tracing.STEP_KINDS``) on the path of every operation, inside
+    ``stack`` and around the step's own parts, and the loop of plain steps
+    on none: a device trace has one event a program, and the path is what
+    tells a step that took a chunk from one that did not."""
+    import re
+
+    m = DRIVEN[model]()
+    cfg, serving = m.cfg, m.serving
+    slots, chunk, steps = 4, 8, 4
+    i32 = jnp.int32
+    args = (m.params, serving.SERVED.init_cache(cfg, slots, 64),
+            jnp.zeros((slots,), i32), jnp.zeros((slots,), i32),
+            jnp.ones((slots,), bool), jnp.zeros((slots,)),
+            jnp.ones((slots,)), jax.random.PRNGKey(0),
+            (jnp.zeros((steps, chunk), i32),
+             *(jnp.zeros((steps,), i32) for _ in range(3)), i32(2)))
+    text = serving.mixed_burst.lower(cfg, *args, steps, False).as_text(
+        debug_info=True)
+    paths = set(re.findall(r'loc\("(jit\(decode_burst\)/[^"]*)"', text))
+    # the burst's two loops, by what their operations' paths begin with
+    loops = {}
+    for path in paths:
+        if "/while/" in path:
+            loops.setdefault(path.split("/while/")[0], []).append(path)
+    kind, plain = "jit(decode_burst)/stack/mixed_step", \
+        "jit(decode_burst)/stack"
+    assert set(loops) == {kind, plain}
+    # the kind is opened there and nowhere else, and a step's parts lie
+    # under it as they lie under the plain loop
+    assert all(p.startswith(kind + "/while") for p in paths
+               if "mixed_step" in p)
+    for loop in (kind, plain):
+        for part in ("embed", "attn", "head", "sample"):
+            assert any(re.search(rf"/while/body/(.*/)?{part}/", p)
+                       for p in loops[loop]), (loop, part)
+    # and it is the riding loop that has it: the loop whose body joins the
+    # chunk's rows to the lines' (served.mixed_rows)
+    (burst,) = jax.make_jaxpr(
+        lambda *a: serving.mixed_burst(cfg, *a, steps, False))(*args).eqns
+    whiles = [e for e in burst.params["jaxpr"].eqns
+              if e.primitive.name == "while"]
+    assert [str(e.source_info.name_stack) for e in whiles] == [
+        "stack/mixed_step", "stack"]
+    joined = [any(e.primitive.name == "concatenate"
+                  and e.outvars[0].aval.shape == (chunk + slots,)
+                  for e in _eqns(w.params["body_jaxpr"].jaxpr))
+              for w in whiles]
+    assert joined == [True, False]
+
+
 @pytest.mark.parametrize("pipeline", [True, False],
                          ids=["look-ahead", "serial"])
 @pytest.mark.parametrize("model", list(DRIVEN))
@@ -347,3 +410,116 @@ def test_chunks_that_ride_leave_every_answer_as_it_was(monkeypatch, model,
     for stats in chunks_that_ride_leave_every_answer_as_it_was(
             monkeypatch, m.serving, m.engine_cfg, pipeline):
         m.engine_holds(stats)
+
+
+def _routed(model):
+    """(serving module, configuration with lines of 256) of a model with
+    counters of its own."""
+    m = DRIVEN[model]()
+    return m.serving, dataclasses.replace(m.engine_cfg, max_seq_len=256)
+
+
+# What ``stats()`` held of each model's own counters, in ``counters``' order,
+# after counts_arrive_with_the_program_behind_their_chunk's requests on the
+# tree before a chunk's counts left its request (recorded there, PR 65's):
+# (its mixed_burst offered, the look-ahead on) -> the totals.
+_TOTALS_BEFORE = {
+    "lfm2": {(True, True): [1312, 1312, 0, 353, 108, 353],
+             (True, False): [1280, 1280, 0, 329, 96, 329],
+             (False, True): [1312, 1312, 0, 377, 120, 379],
+             (False, False): [1280, 1280, 0, 345, 104, 347]},
+    "deepseek": {(True, True): [1266, 331, 0, 129, 124, 131, 220],
+                 (True, False): [1266, 331, 0, 132, 124, 134, 220],
+                 (False, True): [1266, 331, 0, 135, 130, 137, 220],
+                 (False, False): [1266, 331, 0, 139, 130, 141, 220]},
+}
+
+
+@pytest.mark.parametrize("pipeline", [True, False],
+                         ids=["look-ahead", "serial"])
+@pytest.mark.parametrize("mixed", [True, False],
+                         ids=["chunks ride", "no chunk rides"])
+@pytest.mark.parametrize("model", list(_TOTALS_BEFORE))
+def test_a_chunk_s_counts_come_with_the_program_queued_behind_it(
+        monkeypatch, model, mixed, pipeline):
+    """The model's own counts of a prefill chunk reach ``stats()`` with the
+    first program dispatched after the chunk whose result the host reads (a
+    burst, a first token's sample, a single step), in that program's fetch:
+    never with one dispatched before it, whose fetch would then wait a
+    burst longer than its tokens take, and every count once. What a run
+    adds up to is what it added up to while a prompt's counts waited for
+    its first token."""
+    serving, cfg = _routed(model)
+    reads, ticks, made, entries, stats = \
+        counts_arrive_with_the_program_behind_their_chunk(
+            monkeypatch, serving, cfg, mixed, pipeline)
+    # every count a program returned was fetched, and once
+    assert sorted(at for _, at, _, _ in reads) == [at for _, at, _ in made]
+    for kind, at, entry, _ in reads:
+        later = [e for e in entries if e > at]
+        if kind == "chunk":
+            # with the entry that went in flight next, and no older one
+            assert entry == min(later), (at, entry, entries)
+        else:
+            # a program's own: with its tokens
+            assert entry == min(e for e in entries if e >= at)
+    assert any(kind == "chunk" for kind, *_ in reads)
+    counters = serving.SERVED.counters
+    assert [stats[k] for k in counters] == list(
+        sum(values for _, _, values in made)) \
+        == _TOTALS_BEFORE[model][mixed, pipeline]
+    # between two ticks ``stats()`` holds every routed layer of what was
+    # fetched, chunks that rode with their steps
+    nm = cfg.num_routed_layers
+    for n, _, steps_read, then in ticks:
+        chunks_read = sum(kind == "chunk" for kind, *_ in reads[:n])
+        assert then["moe_layer_steps"] == nm * (chunks_read + steps_read)
+
+
+@pytest.mark.parametrize("pipeline,chunks", [(False, 3), (True, 7)],
+                         ids=["serial", "look-ahead"])
+def test_stats_between_two_chunks_of_a_prompt_hold_the_earlier_chunk_s_counts(
+        monkeypatch, pipeline, chunks):
+    """A line decodes beside a long prompt whose chunks go out call for
+    call: once the burst queued behind the prompt's first chunk is read,
+    ``moe_layer_steps`` has grown by that chunk's layers, and the prompt's
+    last chunk is not dispatched yet. Serial, a tick reads the burst it
+    dispatched, so three chunks show it; under the look-ahead the burst
+    behind a chunk is read two ticks after it (the last burst stays in
+    flight), of two chunks each, so the prompt has seven."""
+    serving, cfg = _routed("deepseek")
+    reads, ticks, *_ = counts_arrive_with_the_program_behind_their_chunk(
+        monkeypatch, serving, cfg, mixed=False, pipeline=pipeline,
+        chunks=chunks)
+    (first,) = [i for i, (*_, first) in enumerate(reads) if first]
+    n, last_dispatched, steps_read, stats = next(
+        t for t in ticks if t[0] > first)
+    assert not last_dispatched
+    # it came with a burst, the program queued behind it
+    kinds = {at: kind for kind, at, _, _ in reads}
+    fetched_with = reads[first][2]
+    assert kinds[max(at for at in kinds if at < fetched_with)] == "burst"
+    chunks_read = sum(kind == "chunk" for kind, *_ in reads[:n])
+    assert chunks_read >= 2     # the line's own prompt, and this one
+    assert stats["moe_layer_steps"] == cfg.num_routed_layers * (
+        chunks_read + steps_read)
+
+
+@pytest.mark.parametrize("pipeline", [True, False],
+                         ids=["look-ahead", "serial"])
+def test_alone_in_the_engine_a_prompt_s_counts_come_with_its_first_token(
+        monkeypatch, pipeline):
+    """No program goes in flight between the chunks of a prompt that has
+    the engine to itself: all its chunks' counts come with the first
+    token's sample, as they did."""
+    serving, cfg = _routed("lfm2")
+    reads, ticks, _, entries, _ = \
+        counts_arrive_with_the_program_behind_their_chunk(
+            monkeypatch, serving, cfg, pipeline=pipeline, beside=False)
+    assert [kind for kind, *_ in reads[:3]] == ["chunk"] * 3
+    assert {entry for _, _, entry, _ in reads[:3]} == {entries[0]}
+    assert all(entries[0] > at for _, at, _, _ in reads[:3])
+    # nothing is counted until the prompt's last chunk is out
+    assert all(last for n, last, _, _ in ticks if n)
+    assert [t[3]["moe_layer_steps"] for t in ticks if not t[0]] == [0] * sum(
+        not t[0] for t in ticks)

@@ -419,6 +419,118 @@ def test_a_mixed_burst_is_offered_by_a_token_a_step_model_or_by_none(model):
     assert all(leaf.is_deleted() for leaf in jax.tree.leaves(cache))
 
 
+def counts_arrive_with_the_program_behind_their_chunk(
+        monkeypatch, serving, cfg, mixed=True, pipeline=True, beside=True,
+        chunks=3):
+    """A prompt of ``chunks`` chunks (the last a tail) through an engine
+    whose scheduler's thread is stopped and whose ticks are made by hand,
+    with a line decoding beside it and a shorter prompt behind it
+    (``beside``) or alone; ``mixed`` false takes the model's ``mixed_burst``
+    away. The model's programs return their counts wrapped: a count knows
+    the instant it was dispatched and records the instant it was fetched
+    and the entry in flight it was fetched with, all on one counter of
+    events. Returns ``(reads, ticks, made, entries, stats)``:
+
+    - ``reads``: (``chunk`` | ``burst`` | ``step``, dispatched, the stamp of
+      the entry it was fetched with, whether it is the long prompt's first
+      chunk) of every count fetched, in order;
+    - ``ticks``: after every tick, (how many of ``reads`` had been made,
+      whether the long prompt's last chunk has been dispatched, the decode
+      steps whose tokens the host has read, ``stats()``);
+    - ``made``: every count a program returned, (kind, dispatched, values);
+    - ``entries``: the stamps of the entries that went in flight (a single
+      step, which is fetched where it is dispatched, among them)."""
+    import itertools
+    from collections import deque
+    from dataclasses import replace
+
+    if not mixed:
+        monkeypatch.setattr(serving, "SERVED",
+                            replace(serving.SERVED, mixed_burst=None))
+    eng = LLMEngine(LLMConfig(
+        model=cfg, max_num_seqs=3, max_seq_len=256, prefill_chunk=32,
+        decode_burst=4, prefill_chunks_per_tick=1, decode_pipeline=pipeline,
+        seed=3))
+    eng.shutdown()
+    clock = itertools.count()
+    reads, made, entries, reading = [], [], [], [None]
+
+    class Counted:
+        def __init__(self, kind, counts, first):
+            self.kind, self.at, self.counts = kind, next(clock), counts
+            self.first = first
+            made.append((kind, self.at, np.asarray(counts)))
+
+        def __array__(self, *args, **kw):
+            reads.append((self.kind, self.at, reading[0], self.first))
+            return np.asarray(self.counts)
+
+    def counted(name, kind):
+        program = getattr(eng.model, name)
+        if program is None:
+            return None
+
+        def call(*args, **kw):
+            *out, counts = program(*args, **kw)
+            # a chunk's (tokens, cached rows, length, slot) follow the cache
+            first = kind == "chunk" and (int(args[4]), int(args[5])) == (
+                0, len(long))
+            counts = Counted(kind, counts, first)
+            if kind == "step":      # fetched where it is dispatched
+                reading[0] = counts.at
+                entries.append(counts.at)
+            return (*out, counts)
+        return call
+
+    class Stamped(deque):
+        def append(self, entry):
+            entry.at = next(clock)
+            entries.append(entry.at)
+            super().append(entry)
+
+    read = eng._read
+
+    def stamped_read(entry, why):
+        reading[0] = entry.at
+        try:
+            return read(entry, why)
+        finally:
+            reading[0] = None
+
+    eng.model = replace(
+        eng.model, prefill_chunk=counted("prefill_chunk", "chunk"),
+        decode_step=counted("decode_step", "step"),
+        decode_burst=counted("decode_burst", "burst"),
+        mixed_burst=counted("mixed_burst", "burst"))
+    eng._in_flight, eng._read = Stamped(), stamped_read
+    rng = np.random.default_rng(11)
+    line, long, short = ([int(t) for t in rng.integers(259, cfg.vocab_size, n)]
+                         for n in (20, 32 * chunks - 16, 40))
+    reqs = []
+    if beside:
+        reqs.append(eng.submit(line, SamplingParams(max_tokens=60)))
+        while not reqs[0].out_tokens:
+            eng._tick()
+    prompt = eng.submit(long, SamplingParams(max_tokens=9))
+    reqs.append(prompt)
+    if beside:
+        reqs.append(eng.submit(short, SamplingParams(max_tokens=5)))
+    ticks = []
+    for _ in range(400):
+        if all(r.done.is_set() for r in reqs):
+            break
+        eng._tick()
+        stats = eng.stats()
+        ticks.append((
+            len(reads), prompt.prefilled_len >= len(long),
+            stats["decode_steps"] - sum(e.steps for e in eng._in_flight),
+            stats))
+    assert all(r.done.is_set() and not r.error for r in reqs)
+    eng._read_all("stop")
+    assert not eng._chunk_counts
+    return reads, ticks, made, entries, eng.stats()
+
+
 def chunks_that_ride_leave_every_answer_as_it_was(monkeypatch, serving, cfg,
                                                   pipeline):
     """What a model's test of its ``mixed_burst`` through the engine holds

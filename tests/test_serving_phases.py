@@ -221,6 +221,49 @@ def test_scheduler_phases_reach_a_profiler_session(engine, tmp_path):
                if n == "engine.decode_dispatch")
     assert {st["which"] for n, st in events if n == "engine.fetch"} <= \
         {"burst", "pending", "prefill", "step"}
+    assert {st["why"] for n, st in events if n == "engine.fetch"} <= WHYS
+
+
+# Why the scheduler read (``engine.fetch``'s ``why``, beside ``which``).
+WHYS = {"oldest", "tail", "single_step", "host_only", "speculative",
+        "serial", "stop"}
+
+
+@pytest.mark.parametrize("pipeline", [True, False],
+                         ids=["look-ahead", "serial"])
+def test_a_fetch_says_why_the_scheduler_read(pipeline):
+    """Every blocking read carries the reason of the call that made it.
+    Two lines of 14 tokens at bursts of 4: a first token, three bursts and
+    a single step each. Under the look-ahead a burst is read as the oldest
+    in flight once another is queued behind it, and what is left when
+    nothing more can be queued as the tail (or because the single step
+    that follows takes its tokens from the host); serial, a tick reads the
+    burst it dispatched."""
+    from ray_tpu.llm import LLMConfig, LLMEngine
+
+    eng = LLMEngine(LLMConfig(model="tiny", max_num_seqs=4, max_seq_len=128,
+                              seed=5, decode_burst=4, prefix_block_tokens=0,
+                              decode_pipeline=pipeline))
+    try:
+        _run(eng, _prompts(2, 6), [14, 14])   # compile outside the record
+        tracing.clear()
+        tracing.enable_tracing()
+        _run(eng, _prompts(2, 7), [14, 14])
+        tracing.disable_tracing()
+    finally:
+        eng.shutdown()
+    reads = [s.attributes for s in tracing.spans()
+             if s.name == "engine.fetch"]
+    tracing.clear()
+    assert reads and all(r["why"] in WHYS for r in reads)
+    bursts = {r["why"] for r in reads if r["which"] == "burst"}
+    assert {r["why"] for r in reads if r["which"] == "step"} == \
+        {"single_step"}
+    if pipeline:
+        assert "oldest" in bursts
+        assert bursts <= {"oldest", "tail", "single_step"}
+    else:
+        assert bursts == {"serial"}
 
 
 def test_an_idle_engine_waits_in_one_phase(engine):

@@ -62,6 +62,29 @@ def test_the_finer_names_are_a_vocabulary_of_their_own():
             pass
 
 
+def test_a_kind_of_step_is_a_third_vocabulary_opened_around_the_parts():
+    """``STEP_KINDS`` name a whole step of a program whose steps are not all
+    alike: ``tracing.part`` takes one as it takes a part, the three tuples
+    share no name, and the parts opened under a kind lie after it on the
+    path, so a reader that walks a path for the names it knows finds the
+    part it found."""
+    assert tracing.STEP_KINDS == ("mixed_step",)
+    vocabularies = (tracing.PARTS, tracing.SUBPARTS, tracing.STEP_KINDS)
+    names = [n for v in vocabularies for n in v]
+    assert len(set(names)) == len(names)
+    assert all(re.fullmatch(r"[a-z_]+", k) for k in tracing.STEP_KINDS)
+    with pytest.raises(ValueError, match="mixed_step"):
+        tracing.part("mixed")      # the message lists what may be opened
+
+    def f(x):
+        with tracing.part("stack"), tracing.part("mixed_step"):
+            with tracing.part("attn"):
+                return x * 2
+
+    text = jax.jit(f).lower(jnp.ones(4)).as_text(debug_info=True)
+    assert "stack/mixed_step/attn/mul" in text
+
+
 def test_part_is_a_named_scope_and_nothing_else():
     def f(x):
         with tracing.part("mlp"):
@@ -82,6 +105,7 @@ def test_no_part_is_a_jax_primitive_or_wrapper():
     assert len(primitives) > 100 and "dot_general" in primitives
     assert not set(tracing.PARTS) & (primitives | WRAPPERS)
     assert not set(tracing.SUBPARTS) & (primitives | WRAPPERS)
+    assert not set(tracing.STEP_KINDS) & (primitives | WRAPPERS)
     assert len(set(tracing.PARTS)) == len(tracing.PARTS)
     assert all(re.fullmatch(r"[a-z_]+", p) for p in tracing.PARTS)
 
