@@ -5,6 +5,7 @@ timed on one.
 
     python3 devbench/keye_bench.py aot          # no chip, about a minute
     chiprun -- python3 devbench/keye_bench.py kernel step
+    chiprun -- python3 devbench/keye_bench.py pair   # .parent/ beside this
     chiprun -- python3 devbench/keye_bench.py margins
 
 ``aot``: ``llm/keye_serving.py``'s ``prefill_chunk(512)`` and
@@ -21,8 +22,14 @@ work over the chip's peaks) and the attention beside what its pass does
 besides: a chunk's call as a share of the dense pass's FLOPs at the peak, a
 step's as a share of its whole lines' bytes at the peak; the kernels
 against their jnp references at one shape, and what the selection's and the
-decode attention's other forms cost there: ``lax.top_k`` in the threshold's place, and a gather of the
-2,048 chosen rows in the masked pass's place. ``step``: wall milliseconds of
+decode attention's other forms cost there: ``lax.top_k`` in the
+threshold's place, and a gather of the 2,048 chosen rows in the masked
+pass's place; every check reads the scores below the bound they are written
+to (``written``) and a digest of ``thr`` and ``pcut`` says whether two trees
+select the same sets. ``pair``: ``kernel`` on the tree laid in ``.parent/``
+(this file copied over its own, so both sides are timed one way) and on this
+one, a process each, and the two beside each other, before | after.
+``step``: wall milliseconds of
 one decode step inside a burst of 8 and of a prefill chunk of 512 (the clock
 stops on a host read of the result). ``margins``: the serving programs in
 bfloat16, a prompt of 8,192 in chunks of 512 and then 256 positions
@@ -40,6 +47,7 @@ names the rows of ``margins`` to run, ``KEYE_SEEDS`` its seeds.
 
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import sys
@@ -56,6 +64,9 @@ from devbench.qwen3_next_bench import _peaks  # noqa: E402
 
 SLOTS, MAX_SEQ, CHUNK = 8, 49152, 512
 CALLS = 12
+# ``kernel``'s rows: the cached rows under a chunk, and a step's 8 lines.
+CHUNK_CACHED = (4096, 16384, 32768, 44544)
+STEP_LINES = (8192, 24576, 45056)
 CASES: list[str] | None = None
 SEEDS = (11, 12)
 
@@ -207,19 +218,22 @@ def kernel() -> dict:
         return q, qi, w
 
     def loop(op):
-        """``op(layer)`` CALLS times in one program, the results summed so
-        that none is dropped."""
+        """``op(layer)`` CALLS times in one program, a corner of each result
+        summed so that none is dropped (the first 128 of its last axis: a
+        sum over a chunk's whole ``[512, 49152]`` scores is a pass of XLA's
+        over 100 MB, timed with the kernel until PR 67, and reads what no
+        kernel wrote)."""
         def run(*args):
             def body(i, acc):
-                return acc + op(i % L, *args).astype(jnp.float32).sum()
+                return acc + op(i % L, *args)[..., :128].astype(
+                    jnp.float32).sum()
             return lax.fori_loop(0, CALLS, body, jnp.float32(0))
         return jax.jit(run)
 
     out = {"mode": "kernel", "device": jax.devices()[0].device_kind,
            "calls": CALLS, "rows": []}
-    shapes = [("chunk", 1, CHUNK, cached) for cached in
-              (4096, 16384, 32768, 44544)] + \
-             [("step", SLOTS, 1, live) for live in (8192, 24576, 45056)]
+    shapes = [("chunk", 1, CHUNK, cached) for cached in CHUNK_CACHED] + \
+             [("step", SLOTS, 1, live) for live in STEP_LINES]
     for form, n, c, cached in shapes:
         q, qi, w = inputs(n, c)
         slots = jnp.arange(n, dtype=jnp.int32)
@@ -231,7 +245,16 @@ def kernel() -> dict:
         live = (q0[:, None] + jnp.arange(1, c + 1)[None, :]).reshape(-1)
         thr, pcut = sa.topk_threshold(scores.reshape(n * c, -1), topk, live)
         thr, pcut = thr.reshape(n, c), pcut.reshape(n, c)
-        row["kept_a_row"] = float(sa.kept(scores, thr, pcut).sum() / (n * c))
+        # ``index_scores`` writes whole chunks of the selection's 2,048
+        # columns up to the last seen position and nothing above: what is
+        # checked, counted or handed to ``lax.top_k`` is what lies below.
+        written = -(-(cached + c) // 2048) * 2048
+        below = scores[..., :written]
+        row["kept_a_row"] = float(sa.kept(below, thr, pcut).sum() / (n * c))
+        row["scores_digest"], row["thr_pcut_digest"] = (
+            hashlib.sha1(b"".join(np.asarray(a).tobytes() for a in group)
+                         ).hexdigest()[:16]
+            for group in ((below,), (thr, pcut)))
         ms = _device_ms(loop(lambda l, qi, w, ic: sa.index_scores(
             qi, w, ic, l, slots, q0, lim)), qi, w, ic)
         work = (adapter.index_chunk_work(cj, seen, c) if form == "chunk"
@@ -239,10 +262,15 @@ def kernel() -> dict:
         row["index_scores_ms"] = ms
         row["index_scores_roofline_pct"] = 100 * least_ms(work) / ms
         flat = scores.reshape(n * c, -1)
+        # The selection alone: what varies a call is ``live`` (by one
+        # position, as the layer varies the other rows' calls), an input the
+        # kernel takes; ``flat + l`` was a pass of XLA's over the whole
+        # ``[rows, 49152]`` outside it, timed with it until PR 67.
         row["index_select_ms"] = _device_ms(loop(
-            lambda l, flat: sa.topk_threshold(flat + l, topk, live)[0]), flat)
+            lambda l, flat: sa.topk_threshold(flat, topk, live - l)[0]), flat)
+        flat_below = below.reshape(n * c, -1)
         row["lax_top_k_ms"] = _device_ms(loop(
-            lambda l, flat: lax.top_k(flat + l, topk)[0][:, -1]), flat)
+            lambda l, flat: lax.top_k(flat + l, topk)[0][:, -1]), flat_below)
         ms = _device_ms(loop(lambda l, q, kc, vc, scores: sa.sparse_attention(
             q, kc, vc, scores, thr, pcut, l, slots, q0, lim)),
             q, kc, vc, scores)
@@ -269,7 +297,7 @@ def kernel() -> dict:
             def gathered(l, q, kc, vc, scores):
                 """The other form: the chosen rows gathered, then a dense
                 attention over 2,048."""
-                _, idx = lax.top_k(scores[:, 0], topk)         # [N, topk]
+                _, idx = lax.top_k(scores[:, 0, :written], topk)  # [N, topk]
                 def rows(stack):
                     line = lax.dynamic_index_in_dim(stack, l, 0, False)
                     return jnp.take_along_axis(
@@ -282,7 +310,8 @@ def kernel() -> dict:
                 return jnp.einsum("nhgk,nhkd->nhgd", p.astype(dt), vv)
             row["gather_attention_ms"] = _device_ms(
                 loop(gathered), q, kc, vc, scores)
-        if (form, cached) in (("chunk", 4096), ("step", 8192)):
+        if (form, cached) in (("chunk", CHUNK_CACHED[0]),
+                              ("step", STEP_LINES[0])):
             # The kernels against their jnp references, here where the
             # reference's [rows, heads, positions] products still fit.
             with force_kernel_backend("reference"):
@@ -290,26 +319,69 @@ def kernel() -> dict:
                 t_ref, p_ref = sa.topk_threshold(
                     want.reshape(n * c, -1), topk)
                 same = sa.kept(want, t_ref.reshape(n, c), p_ref.reshape(n, c))
-                o_ref = sa.sparse_attention(q, kc, vc, scores, thr, pcut, 0,
-                                            slots, q0, lim)
+                # (the jnp form reads the whole array: -inf where the
+                # kernel wrote nothing)
+                o_ref = sa.sparse_attention(
+                    q, kc, vc, scores.at[..., written:].set(-jnp.inf), thr,
+                    pcut, 0, slots, q0, lim)
+            want, same = want[..., :written], same[..., :written]
             fin = jnp.isfinite(want)
             row["index_scores_max_diff"] = float(jnp.max(jnp.where(
-                fin, jnp.abs(scores - want), 0.0)))
+                fin, jnp.abs(below - want), 0.0)))
             row["index_scores_inf_agree"] = bool(
-                jnp.all(fin == jnp.isfinite(scores)))
+                jnp.all(fin == jnp.isfinite(below)))
             # The selection on the kernel's own scores, against top_k's.
-            t2, p2 = sa.topk_threshold_reference(flat, topk)
+            t2, p2 = sa.topk_threshold_reference(flat_below, topk)
             row["select_sets_equal"] = bool(jnp.all(
-                sa.kept(flat, t2, p2) == sa.kept(flat, thr.reshape(-1),
-                                                 pcut.reshape(-1))))
+                sa.kept(flat_below, t2, p2) == sa.kept(
+                    flat_below, thr.reshape(-1), pcut.reshape(-1))))
             row["sets_differ_rows_vs_reference_scores"] = int(jnp.sum(jnp.any(
-                same != sa.kept(scores, thr, pcut), axis=-1)))
+                same != sa.kept(below, thr, pcut), axis=-1)))
             o = sa.sparse_attention(q, kc, vc, scores, thr, pcut, 0, slots,
                                     q0, lim)
             row["sparse_attention_max_diff"] = float(jnp.max(jnp.abs(
                 o.astype(jnp.float32) - o_ref.astype(jnp.float32))))
         out["rows"].append(row)
         print(json.dumps(row), flush=True)
+    return out
+
+
+PAIRED = ("index_scores_ms", "index_select_ms", "sparse_attention_ms")
+
+
+def pair() -> dict:
+    """``kernel`` before | after: the tree in ``.parent/`` (``git archive
+    <commit> | tar -x -C .parent``) and this one, a process each (neither
+    may find the chip held: this process stays off JAX)."""
+    import shutil
+    import subprocess
+
+    parent = os.path.join(ROOT, ".parent")
+    shutil.copy(os.path.abspath(__file__),
+                os.path.join(parent, "devbench", "keye_bench.py"))
+    sides = {}
+    for side, tree in (("before", parent), ("after", ROOT)):
+        done = subprocess.run(
+            [sys.executable, os.path.join(tree, "devbench", "keye_bench.py"),
+             "kernel"], capture_output=True, text=True)
+        if done.returncode:
+            raise RuntimeError(f"{side}: {done.stderr[-2000:]}")
+        sides[side] = json.loads(done.stdout.splitlines()[-1])["rows"]
+    out = {"mode": "pair", "rows": []}
+    for before, after in zip(sides["before"], sides["after"]):
+        row = {"form": before["form"], "cached": before["cached"],
+               "same_scores": before["scores_digest"]
+               == after["scores_digest"],
+               "same_thr_pcut": before["thr_pcut_digest"]
+               == after["thr_pcut_digest"]}
+        for key in PAIRED + ("kept_a_row",):
+            row[key] = [before[key], after[key]]
+        out["rows"].append(row)
+        print(f"{row['form']:>5} {row['cached']:>6}  " + "  ".join(
+            f"{key[:-3]} {before[key]:.3f} | {after[key]:.3f}"
+            for key in PAIRED) + f"  same scores {row['same_scores']}"
+            f"  same thr, pcut {row['same_thr_pcut']}", flush=True)
+    out["sides"] = sides
     return out
 
 
@@ -501,7 +573,8 @@ def _sets_row(cfg, cj, params, weights, ids, seed: int, length: int = 4096
             "swapped_share_of_a_set_pct": 100.0 * swaps / max(rows, 1) / topk}
 
 
-MODES = {"aot": aot, "kernel": kernel, "step": step, "margins": margins}
+MODES = {"aot": aot, "kernel": kernel, "pair": pair, "step": step,
+         "margins": margins}
 
 if __name__ == "__main__":
     if "KEYE_CASES" in os.environ:

@@ -14,7 +14,11 @@ decode step a row of every slot.
   the positions last (a row of 64 values is half a lane row, and XLA lays
   such an array out positions-minor by itself: stored the other way round
   every program copied the leaf in and out around its kernels).
-  Key blocks past a tile's last query are neither fetched nor multiplied.
+  Key blocks past a tile's last query are neither fetched nor multiplied,
+  and past the longest line's last seen position (up to a whole chunk of
+  the selection's columns) there is no grid step and nothing is written:
+  neither reader looks there. A chunk's tile is 16 index heads x 64 rows,
+  a step's its row's 16 heads and its result the one row.
 - :func:`topk_threshold`: the selection as two numbers a row. ``thr`` is
   the row's k-th largest score (``-inf`` where it sees no more than k
   positions: it keeps them all) and ``pcut`` the position of the last score
@@ -97,11 +101,16 @@ def index_scores_reference(q, w, index_k, layer, slots, q0, limits):
 
 
 def _index_scores_kernel(layer_ref, slot_ref, q0_ref, lim_ref, q_ref, w_ref,
-                         k_ref, o_ref, *, tq: int, bk: int):
+                         k_ref, o_ref, *, bk: int):
     from jax.experimental import pallas as pl
 
     del layer_ref, slot_ref  # read by the index maps
     n, t, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    # The tile: ``heads`` x ``tq`` rows of products, row h * tq + t index
+    # head h of the line's row t. A chunk's ``q_ref`` is [heads, tq, Di]; a
+    # step's [heads, Di], its one row's heads and nothing else.
+    tq = o_ref.shape[0]
+    heads, di = q_ref.shape[0], q_ref.shape[-1]
     limit = lim_ref[n]
     first = q0_ref[n] + t * tq
     end = jnp.minimum(first + tq, limit)
@@ -109,12 +118,19 @@ def _index_scores_kernel(layer_ref, slot_ref, q0_ref, lim_ref, q_ref, w_ref,
 
     @pl.when(live)
     def _():
-        heads, _, di = q_ref.shape
-        # Row r of the tile is index head h, row t: r = h * tq + t.
         dots = jnp.dot(q_ref[...].reshape(heads * tq, di), k_ref[...],
                        preferred_element_type=jnp.float32)
         dots = jnp.maximum(dots, 0.0) * w_ref[...].reshape(heads * tq, 1)
-        scores = dots.reshape(heads, tq, bk).sum(axis=0)
+        if tq == 1:
+            # The heads one after another: the order in which a chunk's
+            # tile adds its heads' slabs, so a step's scores are a chunk's
+            # bit for bit (a tree over the sublanes is 4% of the call
+            # faster and differs in the last place of one score in five).
+            scores = dots[0:1]
+            for h in range(1, heads):
+                scores = scores + dots[h:h + 1]
+        else:
+            scores = dots.reshape(heads, tq, bk).sum(axis=0)
         kpos = j * bk + lax.broadcasted_iota(jnp.int32, (tq, bk), 1)
         qpos = first + lax.broadcasted_iota(jnp.int32, (tq, bk), 0)
         o_ref[...] = jnp.where((kpos <= qpos) & (kpos < limit), scores,
@@ -126,11 +142,22 @@ def _index_scores_kernel(layer_ref, slot_ref, q0_ref, lim_ref, q_ref, w_ref,
 
 
 def index_q_block(c: int, itemsize: int = 2) -> int:
-    """Rows of a line in one tile of index queries: whole packed sublane
-    tiles, 64 at the most (16 heads x 64 rows x a block of 1,024 keys is
-    4 MiB of float32 products)."""
+    """Rows of a chunk's line in one tile of index queries: whole packed
+    sublane tiles, 64 at the most (16 heads x 64 rows x a block of 1,024
+    keys is 4 MiB of float32 products)."""
     tile = 32 // itemsize
     return min(64, -(-c // tile) * tile)
+
+
+# Columns the selection takes at a time (``_select_kernel`` reads whole
+# chunks of them up to a tile's last seen position), and so what the scores
+# are written in: ``index_scores`` stops at the end of the chunk that holds
+# the longest line's last seen position.
+_SELECT_COLUMNS = 2048
+
+
+def _select_chunk(s: int) -> int:
+    return _divisor_block(s, _SELECT_COLUMNS)
 
 
 def _index_scores_pallas(q, w, index_k, layer, slots, q0, limits):
@@ -139,41 +166,61 @@ def _index_scores_pallas(q, w, index_k, layer, slots, q0, limits):
 
     n, heads, c, di = q.shape
     s = index_k.shape[4]
-    tq = index_q_block(c, q.dtype.itemsize)
-    # A decode row's tile is 16 rows: blocks of 4,096 keys, or the grid's
-    # steps cost more than their keys (0.35 us a step against 0.16 us).
-    bk = _divisor_block(s, 1024 if tq > 16 else 4096)
-    c_pad = -(-c // tq) * tq
-    if c_pad != c:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, c_pad - c), (0, 0)))
-        w = jnp.pad(w, ((0, 0), (0, 0), (0, c_pad - c)))
+    w = w.astype(jnp.float32)
+    if c == 1:
+        # A step's tile is its token's index heads (16 bfloat16 rows are
+        # one packed sublane tile; a tile of the line's rows would be
+        # padding but for one row a head) and its result the one row.
+        # Blocks of 4,096 keys, or the grid's steps cost more than their
+        # keys (0.35 us a step against 0.16 us a block of 1,024).
+        tq, bk = 1, _divisor_block(s, 4096)
+        q, q_block, w_block = q.reshape(n, heads, di), (heads, di), (heads, 1)
+
+        def q_index(i, t, j, *_):
+            return (i, 0, 0)
+    else:
+        tq, bk = index_q_block(c, q.dtype.itemsize), _divisor_block(s, 1024)
+        pad = -c % tq
+        if pad:
+            q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
+            w = jnp.pad(w, ((0, 0), (0, 0), (0, pad)))
+        w, q_block, w_block = w[..., None], (heads, tq, di), (heads, tq, 1)
+
+        def q_index(i, t, j, *_):
+            return (i, 0, t, 0)
+    tiles = -(-c // tq)
     scalars = _line_scalars(layer, slots, q0, limits, s)
+    # A run-time bound: past the chunk of the selection's columns that holds
+    # the longest line's last seen position there is no grid step, and
+    # nothing is written (a dead step stores a block of -inf: 0.003 ms and
+    # 256 KB each, 70% of a chunk's steps over a prompt). One chunk at the
+    # least, which an idle call's readers are given.
+    ch = _select_chunk(s)
+    seen = jnp.max(jnp.minimum(scalars[2] + c, scalars[3]))
+    blocks = pl.cdiv(jnp.maximum(pl.cdiv(seen, ch), 1) * ch, bk)
 
     def k_index(i, t, j, lyr, slot, p0, lim):
         end = jnp.minimum(p0[i] + (t + 1) * tq, lim[i])
         last_live = jnp.maximum(pl.cdiv(end, bk) - 1, 0)
         return (lyr[0], slot[i], 0, 0, jnp.minimum(j, last_live))
 
-    def q_index(i, t, j, *_):
-        return (i, 0, t, 0)
-
     out = pl.pallas_call(
-        functools.partial(_index_scores_kernel, tq=tq, bk=bk),
+        functools.partial(_index_scores_kernel, bk=bk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(n, c_pad // tq, s // bk),
-            in_specs=[pl.BlockSpec((None, heads, tq, di), q_index),
-                      pl.BlockSpec((None, heads, tq, 1), q_index),
+            grid=(n, tiles, blocks),
+            in_specs=[pl.BlockSpec((None, *q_block), q_index),
+                      pl.BlockSpec((None, *w_block), q_index),
                       pl.BlockSpec((None, None, None, di, bk), k_index)],
             out_specs=pl.BlockSpec((None, tq, bk),
                                    lambda i, t, j, *_: (i, t, j))),
-        out_shape=jax.ShapeDtypeStruct((n, c_pad, s), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n, tiles * tq, s), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=max(32 << 20, 8 * heads * tq * bk * 4)),
         interpret=kernel_backend() == "interpret",
         name="index_scores",
-    )(*scalars, q, w.astype(jnp.float32)[..., None], index_k)
+    )(*scalars, q, w, index_k)
     return out[:, :c]
 
 
@@ -184,7 +231,14 @@ def index_scores(q, w, index_k, layer, slots, q0, limits):
     written; layer: a scalar; slots, q0, limits: int32 [N] (the line's slot,
     its first row's position, and the positions that exist: a row sees
     ``s <= its own`` and ``s < limit``). Returns float32 [N, C, S]:
-    ``sum_j w relu(q . k)``, ``-inf`` at a position the row does not see."""
+    ``sum_j w relu(q . k)``, and ``-inf`` at a position the row does not
+    see, *below the bound*: the longest line's last seen position,
+    ``max_n min(q0[n] + C, limits[n])``, rounded up to whole chunks of the
+    selection's columns (2,048 where they divide S), one chunk at the
+    least. At and above the bound nothing is written and the values are
+    unspecified: :func:`topk_threshold` under ``live`` and
+    :func:`sparse_attention` read nothing there. (The jnp reference writes
+    the whole array.)"""
     fn = (index_scores_reference if kernel_backend() == "reference"
           else _index_scores_pallas)
     return fn(q, w, index_k, _as_i32(layer), _as_i32(slots), _as_i32(q0),
@@ -307,7 +361,7 @@ def _topk_threshold_pallas(scores, live, k: int):
         live = jnp.pad(live, (0, r_pad - r))
     tiles = r_pad // rows
     live = jnp.clip(live.reshape(tiles, rows).max(axis=1), 0, s)
-    ch = _divisor_block(s, 2048)
+    ch = _select_chunk(s)
     row_spec = pl.BlockSpec((rows, 1), lambda i, *_: (i, 0))
     thr, pcut = pl.pallas_call(
         functools.partial(_select_kernel, k=k, ch=ch,
@@ -332,7 +386,10 @@ def _topk_threshold_pallas(scores, live, k: int):
 def topk_threshold(scores, k: int, live=None):
     """scores: float32 [R, S], ``-inf`` where a row sees nothing; ``live``
     int32 [R] (optional): one past the last position a row sees, so that
-    nothing past it is looked at. Returns (thr float32 [R], pcut int32
+    nothing past it is looked at: the kernel reads whole chunks of 2,048
+    columns up to the one that holds the last of a tile of 8 rows, which is
+    below the bound :func:`index_scores` writes to, and nothing above (the
+    jnp reference reads the whole array). Returns (thr float32 [R], pcut int32
     [R]): the ``k`` largest of each row, a tie at the k-th place going to
     the lower position as ``lax.top_k`` settles it, are exactly
     ``kept(scores, thr, pcut)``; a row that sees no more than ``k``
@@ -535,7 +592,10 @@ def sparse_attention(q, k_cache, v_cache, scores, thr, pcut, layer, slots,
     pcut [N, C]: each row's set as :func:`index_scores` and
     :func:`topk_threshold` give it (a position the row does not see scores
     ``-inf`` and is in no set); layer, slots, q0, limits as
-    :func:`index_scores` takes them. Returns [N, H, C, D]; a row with an
+    :func:`index_scores` takes them. Of the scores the kernel reads the
+    blocks of 1,024 columns up to each tile's last seen position, all below
+    the bound :func:`index_scores` writes to, and nothing above (the jnp
+    reference reads the whole array). Returns [N, H, C, D]; a row with an
     empty set gives zeros."""
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if kernel_backend() == "reference":

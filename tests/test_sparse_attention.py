@@ -3,7 +3,8 @@ threshold and a tie's cut, the attention under the selected set and the two
 writes of the index key's leaf, each against its plain form; the Pallas
 bodies through the interpreter against the jnp references, a prefill
 chunk's shape (one line of many rows) and a decode step's (a row of every
-slot, one of them idle) alike.
+slot, one of them idle) alike. The scores are held to their contract below
+the bound they are written to, and their readers to reading nothing above.
 """
 
 import jax
@@ -21,19 +22,32 @@ H, HKV, D = 4, 2, 128
 # grid step), and the positions of slot 1 whose index keys are zero there:
 # they score 0.0, below every kept score of a row that sees thousands.
 LONG, BLANK = 4096, 2048
+# Two of a step's blocks of 4,096 index keys: a step whose lines all end in
+# the first has no grid step, and writes nothing, in the second.
+LONGER = 8192
 # (lines, rows a line, their slots, first positions, limits, the lines'
 # length): a chunk in the middle of a prompt, a prompt's padded last chunk
 # (its rows past the limit see what the last real row sees), a decode step
 # with an idle slot; a step over several key blocks whose second line keeps
 # nothing in its first two (the running maximum stays at its floor through
 # them), and a chunk of two tiles across a key block's edge, the first
-# tile's last query (position 1,021) short of it.
+# tile's last query (position 1,021) short of it; a step of eight lines of
+# different lengths (the slots read twice over, one line idle) that ends
+# inside the first of its two blocks of index keys, and one that ends inside
+# the second.
 SHAPES = {"chunk": (1, 24, [2], [100], [124], S),
           "padded chunk": (1, 24, [1], [100], [110], S),
           "step": (3, 1, [0, 1, 2], [5, 200, 17], [6, 201, 0], S),
           "long step": (3, 1, [0, 1, 2], [1500, 3900, 17], [1501, 3901, 0],
                         LONG),
-          "long chunk": (1, 520, [2], [510], [1030], LONG)}
+          "long chunk": (1, 520, [2], [510], [1030], LONG),
+          "step of eight": (8, 1, [0, 1, 2, 0, 1, 2, 0, 1],
+                            [1500, 3000, 17, 2500, 3, 1200, 640, 100],
+                            [1501, 3001, 0, 2501, 4, 1201, 641, 101], LONGER),
+          "long step of eight": (8, 1, [0, 1, 2, 0, 1, 2, 0, 1],
+                                 [1500, 6100, 17, 4500, 3, 5000, 640, 4095],
+                                 [1501, 6101, 0, 4501, 4, 5001, 641, 4096],
+                                 LONGER)}
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -52,7 +66,8 @@ def caches():
                 jax.random.normal(ks[2], (L, B, HKV, s, D)))
     index_k, k, v = make(LONG, 1)
     return {S: make(S, 0),
-            LONG: (index_k.at[:, 1, :, :, :BLANK].set(0.0), k, v)}
+            LONG: (index_k.at[:, 1, :, :, :BLANK].set(0.0), k, v),
+            LONGER: make(LONGER, 2)}
 
 
 @pytest.fixture
@@ -67,6 +82,31 @@ def _inputs(name):
             jax.random.normal(ks[1], (n, J, c)),
             jax.random.normal(ks[2], (n, H, c, D)),
             jnp.asarray(slots), jnp.asarray(q0), jnp.asarray(lim))
+
+
+def _fresh_jit(fn, **static):
+    """``jax.jit`` of a function nothing has traced: jax keeps an op's
+    traces by the function, and the backend is read while tracing, so
+    ``jax.jit(sa.op)`` under "interpret" at the shapes "reference" ran
+    first would run the reference's trace again."""
+    return jax.jit(lambda *args: fn(*args), **static)
+
+
+def _bound(name):
+    """One past the last column ``index_scores`` owes: the longest line's
+    last seen position, up to whole chunks of the selection's columns
+    (2,048, or the line where it is shorter), one at the least."""
+    _, c, _, q0, lim, s = SHAPES[name]
+    ch = min(s, 2048)
+    seen = max(min(first + c, limit) for first, limit in zip(q0, lim))
+    return max(-(-seen // ch), 1) * ch
+
+
+def _live(name):
+    """One past the last position each row sees, [lines x rows]."""
+    _, c, _, q0, lim, _ = SHAPES[name]
+    return np.minimum(np.asarray(q0)[:, None] + np.arange(1, c + 1)[None],
+                      np.asarray(lim)[:, None]).reshape(-1)
 
 
 def _plain_scores(q, w, index_k, layer, slots, q0, lim):
@@ -98,8 +138,11 @@ def test_index_scores_are_the_equation_over_what_a_row_sees(leaves, name,
     q, w, _, slots, q0, lim = _inputs(name)
     want = _plain_scores(q, w, leaves[0], 1, slots, q0, lim)
     with force_kernel_backend(backend):
-        got = np.asarray(jax.jit(sa.index_scores)(q, w, leaves[0], 1, slots,
-                                                  q0, lim))
+        got = np.asarray(_fresh_jit(sa.index_scores)(q, w, leaves[0], 1,
+                                                     slots, q0, lim))
+    # (above the bound the kernel writes nothing: the interpreter leaves nan)
+    assert got.shape == want.shape
+    got, want = got[..., :_bound(name)], want[..., :_bound(name)]
     seen = np.isfinite(want)
     np.testing.assert_array_equal(np.isfinite(got), seen)
     assert (got[~seen] == -np.inf).all()
@@ -123,10 +166,9 @@ def test_the_threshold_and_the_cut_are_top_k_s_sets(leaves, name, ties,
         scores = np.where(np.isfinite(scores), np.round(scores * 2) / 2,
                           scores).astype(np.float32)
         assert (scores == 0).any() and np.signbit(scores[scores == 0]).any()
-    live = np.minimum(np.asarray(q0)[:, None] + np.arange(1, c + 1)[None],
-                      np.asarray(lim)[:, None]).reshape(-1)
+    live = _live(name)
     with force_kernel_backend(backend):
-        thr, pcut = jax.jit(sa.topk_threshold, static_argnums=1)(
+        thr, pcut = _fresh_jit(sa.topk_threshold, static_argnums=1)(
             jnp.asarray(scores), K, jnp.asarray(live))
     got = np.asarray(sa.kept(jnp.asarray(scores), thr, pcut))
     np.testing.assert_array_equal(got, _top_k_sets(scores, K))
@@ -162,7 +204,7 @@ def test_the_attention_is_a_softmax_over_each_row_s_set(leaves, name,
     thr, pcut = thr.reshape(n, c), pcut.reshape(n, c)
     keep = _top_k_sets(np.asarray(scores), K)
     with force_kernel_backend(backend):
-        got = np.asarray(jax.jit(sa.sparse_attention)(
+        got = np.asarray(_fresh_jit(sa.sparse_attention)(
             qq, kc, vc, scores, thr, pcut, 1, slots, q0, lim))
     group = H // HKV
     for i in range(n):
@@ -180,14 +222,49 @@ def test_the_attention_is_a_softmax_over_each_row_s_set(leaves, name,
                                            atol=2e-5)
 
 
+@pytest.mark.parametrize("poison", [np.nan, np.inf])
+@pytest.mark.parametrize("name", ["long chunk", "step of eight"])
+def test_no_reader_looks_above_the_bound_the_scores_are_written_to(
+        leaves, name, poison):
+    """``index_scores`` writes nothing at or above its bound, so whatever
+    lies there (``nan`` and ``+inf``, which would win every count and every
+    comparison) moves no bit of the selection's two numbers under ``live``
+    nor of the attention, for a chunk and for a step; scores in halves, so
+    that the tie's cut runs over the positions too."""
+    q, w, qq, slots, q0, lim = _inputs(name)
+    n, _, c, _ = q.shape
+    index_k, kc, vc = leaves
+    bound = _bound(name)
+    assert bound < index_k.shape[4]
+    clean = (np.round(_plain_scores(q, w, index_k, 1, slots, q0, lim) * 2)
+             / 2).astype(np.float32)
+    dirty = clean.copy()
+    dirty[..., bound:] = poison
+    live = jnp.asarray(_live(name))
+    got = []
+    with force_kernel_backend("interpret"):
+        select = _fresh_jit(sa.topk_threshold, static_argnums=1)
+        attend = _fresh_jit(sa.sparse_attention)
+        for scores in (clean, dirty):
+            scores = jnp.asarray(scores)
+            thr, pcut = select(scores.reshape(n * c, -1), K, live)
+            out = attend(qq, kc, vc, scores, thr.reshape(n, c),
+                         pcut.reshape(n, c), 1, slots, q0, lim)
+            got.append([np.asarray(a) for a in (thr, pcut, out)])
+    assert (got[0][1] >= 0).any() and np.isfinite(got[0][2]).all()
+    for want, have in zip(*got):
+        np.testing.assert_array_equal(have.view(np.int32),
+                                      want.view(np.int32))
+
+
 @pytest.mark.parametrize("backend", ["reference", "interpret"])
 def test_the_index_key_s_writes_land_where_the_rows_are(caches, backend):
     index_k = caches[S][0]
     new = jax.random.normal(jax.random.PRNGKey(7), (B, DI))
     pos, mask = jnp.asarray([5, 200, 130]), jnp.asarray([True, False, True])
     with force_kernel_backend(backend):
-        got = np.asarray(jax.jit(sa.index_rows_write)(index_k, new, 1, pos,
-                                                      mask))
+        got = np.asarray(_fresh_jit(sa.index_rows_write)(index_k, new, 1,
+                                                         pos, mask))
     want = np.asarray(index_k).copy()
     want[1, 0, 0, :, 5] = np.asarray(new[0])
     want[1, 2, 0, :, 130] = np.asarray(new[2])

@@ -1648,15 +1648,28 @@ def test_keye_programs_copy_no_cache_leaf_and_fit_the_chip(mosaic, program):
         assert _grouped_matmul_rows(text) == {(64 // 16 + 16) * 16}
     for name in kernels:
         assert f'"{name}"' in text or f"%{name}." in text, name
-    # ISSUE 65: a step's pass under the mask takes the scores as they come,
-    # a row a line: nothing pads them to ``f32[8,16,49152]`` (25 MB a layer
-    # and step); the one result of that shape is ``index_scores``' own, its
-    # tile of 16 rows
+    # ISSUE 67 (ISSUE 65 pinned the other way round: one result of
+    # ``f32[8,16,49152]`` was left, ``index_scores``' tile of 16 rows a
+    # line): a step's scores are a row a line from the kernel that makes
+    # them to the kernel that attends under them, 1.5 MB a layer and step
+    # for 25, relaid once for the selection's tile of 8 rows; and in both
+    # programs the one ``index_scores`` call takes its grid's last bound at
+    # run time (the operand before the scalars that are prefetched)
+    calls = [line for line in text.splitlines()
+             if re.search(r"%index_scores\.\d+ = .* custom-call\(", line)]
+    assert len(calls) == 1
+    assert "operand_layout_constraints={s32[], s32[1]{0}, " in calls[0]
     if program.startswith("decode"):
-        assert len(re.findall(r"= f32\[8,16,49152\]\S* ([a-z-]+)\(",
+        assert _opcodes_with_shape(text, "f32[8,16,49152]") == set()
+        assert re.search(r"%index_scores\.\d+ = f32\[8,1,49152\]", calls[0])
+        assert _opcodes_with_shape(text, "f32[8,1,49152]") == {
+            "custom-call", "parameter", "copy"}
+        assert len(re.findall(r"= f32\[8,1,49152\]\S* custom-call\(",
                               text)) == 1
-        assert _opcodes_with_shape(text, "f32[8,16,49152]") == {
-            "custom-call"}
+        assert mem.temp_size_in_bytes < 1 << 22
+    else:
+        assert re.search(r"%index_scores\.\d+ = f32\[1,512,49152\]",
+                         calls[0])
     # the program's own attention kernels are not on its path
     assert "%decode_attention." not in text
     assert "%prefill_attention." not in text
@@ -1715,6 +1728,11 @@ def test_the_sparse_attention_s_kernels_compile_alone_at_the_cell_s_shapes(
         assert program.as_text().count(MOSAIC) == 1
         # (a step's scores go in as they are, a row a line: ISSUE 65)
         assert program.memory_analysis().temp_size_in_bytes < 1 << 26
+    # and come out so: a step's tile is its row's index heads (ISSUE 67)
+    scored = compiled[0].as_text()
+    assert f"-> f32[{n},{c},{s}]" in scored.replace("{2,1,0}", "")
+    assert "f32[8,16,49152]" not in scored
+    assert compiled[0].memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 def test_sdar_s_programs_lower_to_what_they_were(v5e_2x2):
