@@ -4,18 +4,31 @@ over the sequence.
 Per head a state ``S`` of ``[Dk, Dv]`` in float32 that every token decays,
 corrects and reads. With ``q_t``, ``k_t`` (L2-normalised by the caller, the
 query scaled), ``v_t``, a log-decay ``g_t <= 0`` and a step ``beta_t`` in
-(0, 1), from the state before the token::
+(0, 2), from the state before the token::
 
     S'  = exp(g_t) S_{t-1}
     d_t = beta_t (v_t - S'^T k_t)          # what the state gets wrong at k_t
     S_t = S' + k_t d_t^T
     o_t = S_t^T q_t
 
+A step of ``I - beta_t k_t k_t^T`` on the decayed state: with unit keys its
+eigenvalue along ``k_t`` is ``1 - beta_t``, in (0, 1) for a step in (0, 1)
+(Qwen3-Next's ``sigmoid``) and in (-1, 1) for a step in (0, 2)
+(Olmo-Hybrid's ``2 sigmoid``, ``linear_allow_neg_eigval``: a token may
+overshoot and flip what the state holds along its key). Every form takes
+either range: the recurrence multiplies by ``beta``, and the chunked form's
+system ``I + A`` has ``beta`` as a factor of ``A``'s rows, all under the
+diagonal, so it stays unit lower triangular whatever ``beta`` and its
+inverse by substitution divides by nothing.
+
 ``q`` and ``k`` are a key head's, and a key head serves ``H // Hk`` value
 heads in a row (two in Qwen3-Next): the recurrence and the chunked form take
-them once a key head, the one-token step repeated a value head.
+them once a key head, the one-token step repeated a value head. Keys are
+``Dk`` wide and values ``Dv``, any two numbers (128 and 128 in Qwen3-Next,
+96 and 192 in Olmo-Hybrid); the kernels take whole 128-lane columns and the
+jnp bodies any widths.
 
-Three forms of it:
+Four forms of it:
 
 - :func:`gated_delta_recurrence`: those four lines under a ``lax.scan`` over
   the positions. What the tests hold the other two to; never a program's
@@ -45,6 +58,21 @@ Three forms of it:
   the state stay in VMEM and the operands are read once, as 128-lane
   columns of the caller's ``[T, H * D]``; elsewhere, and as what the kernel
   is held to, :func:`gated_delta_chunk_reference` in plain jnp.
+- :func:`gated_delta_chunk_batch` (``gated_delta_chunk`` on operands with a
+  leading batch): the chunked form of a batch of whole sequences with a
+  backward, what a model trains through. A ``jax.custom_vjp``: the forward
+  walks chunks of ``TRAIN_CHUNK`` positions (eight sub-chunks) and keeps
+  its operands and the state at each chunk's start; the backward walks the
+  chunks in reverse, makes a chunk's systems and states again from the
+  state it started from and runs their transposes, the inverse's as ``dA =
+  -T^T dT T^T``: the same kind of recurrence run backwards, matrix
+  products throughout. On a TPU the forward is the scalar chunk kernel in
+  one call, the batch folded into the heads and keys and values
+  zero-padded to whole 128-lane columns (96 and 192 become 128 and 256:
+  exact, and 1.9 times the needed products), the kernel keeping the
+  chunks' states as a third result; the backward is plain jnp on every
+  backend (XLA's batched products at the operands' own widths: a kernel
+  for it is later work).
 - :func:`gated_delta_step`: one position of every slot, the recurrence's
   single step on a line of a state leaf ``[lines, slots, heads, Dk, Dv]``,
   in place. The update needs ``d_t`` and ``d_t`` a sum over the whole
@@ -93,7 +121,7 @@ form is a kernel of its own (:func:`_chunk_kernel_channel`: the scalar
 kernel's grid, system, inverse and walk; the pairs a block at a time),
 where a key head is a value head; the jnp body otherwise.
 
-All three compute in float32 whatever they are given, and their products at
+All of them compute in float32 whatever they are given, and their products at
 ``PRECISION``, true float32: a TPU's default float32 product is one bfloat16
 pass, which left the chunk form 8e-4 off the recurrence on outputs and 4e-3
 on states, and the rule's work is small beside the layer's projections.
@@ -120,6 +148,10 @@ F32_MAX_EXPONENT = 88.0
 # Bytes of states a grid step of the step's kernel holds, read once and
 # written once (:func:`states_a_step`).
 STEP_BLOCK_BYTES = 2 << 20
+# Bytes of states a grid step of the chunk's kernel may hold: the block comes
+# in and goes out, each with a copy in flight, beside a sub-chunk's operands
+# and the system, in 16 MiB of VMEM. Eight heads of 128 x 128 are 0.5 MiB.
+CHUNK_STATE_BYTES = 2 << 20
 PRECISION = lax.Precision.HIGHEST
 F32 = jnp.float32
 
@@ -128,13 +160,14 @@ def _mm(a, b):
     return jnp.matmul(a, b, precision=PRECISION)
 
 
-def _a_value_head(q, k, heads: int):
-    """q, k [T, Hk, Dk] of the key heads, each for the ``heads // Hk`` value
-    heads in a row that it serves: [T, heads, Dk]."""
-    rep = heads // q.shape[1]
+def _a_value_head(q, k, heads: int, axis: int = 1):
+    """q, k [T, Hk, Dk] of the key heads (the heads on ``axis``), each for
+    the ``heads // Hk`` value heads in a row that it serves: [T, heads,
+    Dk]."""
+    rep = heads // q.shape[axis]
     if rep == 1:
         return q, k
-    return jnp.repeat(q, rep, axis=1), jnp.repeat(k, rep, axis=1)
+    return jnp.repeat(q, rep, axis=axis), jnp.repeat(k, rep, axis=axis)
 
 
 def gated_delta_recurrence(q, k, v, g, beta, state):
@@ -243,28 +276,16 @@ def _channel_pairs(q, k, big):
             pairs[..., BLOCK:, :].reshape(lead + (SUB, SUB)))
 
 
-def gated_delta_chunk_reference(q, k, v, g, beta, state, *, g_floor=None):
-    """:func:`gated_delta_chunk` in plain jnp: what runs off a TPU and what
-    the kernels are held to, for a decay a head and for a decay a key
-    channel (``g`` [T, H, Dk], under ``g_floor``). ``T = (I + A)^-1`` does not
-    depend on the state, so ``U = T (beta V)`` and ``W = T (beta exp(G) K)``
-    are made for every sub-chunk at once and ``D = U - W S_0`` under the
-    scan."""
-    channel = g.ndim == 3
-    if channel:
-        _require_floor(g_floor)
-    q, k = _a_value_head(q, k, v.shape[1])
-    t, h, _ = q.shape
-    pad = -t % SUB
-    n = (t + pad) // SUB
-
-    def heads_first(a):
-        a = jnp.pad(a.astype(F32), ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-        a = a.reshape(n, SUB, *a.shape[1:])
-        return jnp.moveaxis(a, 2, 0)              # [H, n, SUB, ...]
-
-    q, k, v, g, beta = (heads_first(a) for a in (q, k, v, g, beta))
-    big = jnp.cumsum(g, axis=2)                               # G_i
+def _sub_chunk_terms(q, k, v, g, beta, channel: bool,
+                     inverse=unit_lower_inverse):
+    """What a sub-chunk's walk needs and no state enters, for every
+    sub-chunk at once: q, k [..., n, SUB, Dk], v [..., n, SUB, Dv], beta
+    [..., n, SUB] and g as beta or, for a decay a key channel, as k, all
+    float32. ``T = (I + A)^-1`` does not depend on the state, so ``U = T
+    (beta V)`` and ``W = T (beta exp(G) K)`` are made here and ``D = U - W
+    S_0`` under the walk. Returns (U, W, the products inside the sub-chunk,
+    exp(G) Q, (exp(G_C - G) K)^T, exp(G_C))."""
+    big = jnp.cumsum(g, axis=-2 if channel else -1)           # G_i
     rows = jnp.arange(SUB)
     upto = rows[:, None] >= rows[None, :]                     # j <= i
     if channel:
@@ -281,22 +302,31 @@ def gated_delta_chunk_reference(q, k, v, g, beta, state, *, g_floor=None):
                       beta[..., None] * decay * _mm(k, kt), 0.0)
         grow = jnp.exp(big)[..., None]                        # exp(G_i)
     rhs_v, rhs_k = beta[..., None] * v, beta[..., None] * grow * k
-    inv = unit_lower_inverse(a)
+    inv = inverse(a)
     u, w = _mm(inv, rhs_v), _mm(inv, rhs_k)
     # within: zero above the diagonal; k_out: exp(G_C - G_j) K, transposed
     # for the state's update; whole: exp(G_C), a number a head or a row of
-    # the state its own (``lift`` lays it along the state).
+    # the state its own.
     within = jnp.where(upto, qk, 0.0) if channel else decay * _mm(q, kt)
     q_in = grow * q
     if channel:
         k_out = jnp.swapaxes(jnp.exp(big[..., -1:, :] - big) * k, -1, -2)
         whole = jnp.exp(big[..., -1, :])
-        lift = (slice(None), slice(None), None)
     else:
         k_out = jnp.swapaxes(
             jnp.exp(big[..., -1:] - big)[..., None] * k, -1, -2)
         whole = jnp.exp(big[..., -1])
-        lift = (slice(None), None, None)
+    return u, w, within, q_in, k_out, whole
+
+
+def _walk(state, terms, channel: bool):
+    """From sub-chunk to sub-chunk: ``terms`` of :func:`_sub_chunk_terms`
+    with the sub-chunks on the axis after the state's leading ones (heads,
+    or a batch and heads). Returns (the state after the last, o [n, ...,
+    SUB, Dv])."""
+    at = state.ndim - 2
+    # ``whole`` laid along the state: a number a head, or a row its own.
+    lift = (Ellipsis, None) if channel else (Ellipsis, None, None)
 
     def sub_chunk(s, xs):
         u_n, w_n, within_n, q_n, k_n, whole_n = xs
@@ -304,10 +334,30 @@ def gated_delta_chunk_reference(q, k, v, g, beta, state, *, g_floor=None):
         o = _mm(q_n, s) + _mm(within_n, d)
         return whole_n[lift] * s + _mm(k_n, d), o
 
-    state, o = lax.scan(
-        sub_chunk, state.astype(F32),
-        tuple(jnp.moveaxis(x, 1, 0)
-              for x in (u, w, within, q_in, k_out, whole)))
+    return lax.scan(sub_chunk, state,
+                    tuple(jnp.moveaxis(x, at, 0) for x in terms))
+
+
+def gated_delta_chunk_reference(q, k, v, g, beta, state, *, g_floor=None):
+    """:func:`gated_delta_chunk` of one sequence in plain jnp: what runs off
+    a TPU and what the kernels are held to, for a decay a head and for a
+    decay a key channel (``g`` [T, H, Dk], under ``g_floor``)."""
+    channel = g.ndim == 3
+    if channel:
+        _require_floor(g_floor)
+    q, k = _a_value_head(q, k, v.shape[1])
+    t, h, _ = q.shape
+    pad = -t % SUB
+    n = (t + pad) // SUB
+
+    def heads_first(a):
+        a = jnp.pad(a.astype(F32), ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+        a = a.reshape(n, SUB, *a.shape[1:])
+        return jnp.moveaxis(a, 2, 0)              # [H, n, SUB, ...]
+
+    terms = _sub_chunk_terms(*(heads_first(a) for a in (q, k, v, g, beta)),
+                             channel)
+    state, o = _walk(state.astype(F32), terms, channel)
     # o: [n, H, SUB, Dv] -> [T, H, Dv]
     o = jnp.moveaxis(o, 1, 2).reshape(n * SUB, h, -1)
     return o[:t], state
@@ -362,14 +412,18 @@ def _apart(r0, r1):
 
 
 def _chunk_kernel(q_ref, k_ref, v_ref, big_ref, beta_ref, s0_ref, o_ref,
-                  s_ref, *, heads: int, rep: int, dk: int, dv: int):
+                  s_ref, *kept, heads: int, rep: int, dk: int, dv: int,
+                  keep: int = 0):
     """One sub-chunk of ``heads`` value heads, two by two. q_ref, k_ref
     [SUB, heads // rep * dk], a key head for ``rep`` value heads, and v_ref,
     o_ref [SUB, heads * dv]: a head's sub-chunk is 128-lane columns of the
     caller's ``[T, H * D]``; big_ref (the running sums ``G_i``) and beta_ref
     [SUB, heads]; s0_ref and s_ref [heads, dk, dv]. s_ref's block is the
     same for every sub-chunk of a head: it is the state, in VMEM from the
-    first sub-chunk to the last.
+    first sub-chunk to the last. With ``keep``, one more result ``kept[0]``
+    [heads, dk, dv], a block for every ``keep`` sub-chunks: the state those
+    sub-chunks start from, which the trained form's backward walks back
+    from.
 
     A product costs what its rows cost and no less than a fixed 0.1 us,
     and products run one after another, so two heads share every product
@@ -381,6 +435,11 @@ def _chunk_kernel(q_ref, k_ref, v_ref, big_ref, beta_ref, s0_ref, o_ref,
     @pl.when(pl.program_id(1) == 0)
     def _():
         s_ref[...] = s0_ref[...]
+
+    if keep:
+        @pl.when(pl.program_id(1) % keep == 0)
+        def _():
+            kept[0][...] = s_ref[...]
 
     rows = lax.broadcasted_iota(jnp.int32, (SUB, 2 * SUB), 0)
     lanes = lax.broadcasted_iota(jnp.int32, (SUB, 2 * SUB), 1)
@@ -559,7 +618,10 @@ def _heads_a_step(h: int) -> int:
     return next(n for n in (8, 4, 2) if h % n == 0)
 
 
-def _chunk_pallas(q, k, v, g, beta, state):
+def _chunk_pallas(q, k, v, g, beta, state, keep: int = 0):
+    """The chunk kernels' call. With ``keep`` (a decay a head; T whole
+    multiples of ``keep`` sub-chunks) a third result: the states every
+    ``keep`` sub-chunks start from, [T / (keep SUB), H, Dk, Dv]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -569,6 +631,14 @@ def _chunk_pallas(q, k, v, g, beta, state):
     pad = -t % SUB
     n = (t + pad) // SUB
     hs = _heads_a_step(h)
+    if 4 * hs * dk * dv > CHUNK_STATE_BYTES:
+        raise ValueError(
+            f"gated_delta_chunk: {hs} heads of {dk} x {dv} float32 are "
+            f"{4 * hs * dk * dv / 2 ** 20:.1f} MiB of states a grid step, "
+            f"and the kernel holds {CHUNK_STATE_BYTES >> 20} MiB in VMEM "
+            "beside their copies in flight; under "
+            "force_kernel_backend('reference') the jnp body takes any "
+            "widths")
 
     def rows(a):
         a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
@@ -587,27 +657,32 @@ def _chunk_pallas(q, k, v, g, beta, state):
         sums, laid = wide(hs * dk), lambda b: b.reshape(t + pad, h * dk)
     else:
         kernel = functools.partial(_chunk_kernel, heads=hs, rep=rep, dk=dk,
-                                   dv=dv)
+                                   dv=dv, keep=keep)
         sums, laid = narrow, a_step
-    o, state = pl.pallas_call(
+    kept_spec, kept_shape = [], []
+    if keep:
+        kept_spec = [pl.BlockSpec((None, hs, dk, dv),
+                                  lambda i, j: (j // keep, i, 0, 0))]
+        kept_shape = [jax.ShapeDtypeStruct((n // keep, h, dk, dv), F32)]
+    o, state, *kept = pl.pallas_call(
         kernel,
         grid=(h // hs, n),
         in_specs=[wide(hs // rep * dk), wide(hs // rep * dk), wide(hs * dv),
                   sums, narrow, held],
-        out_specs=[wide(hs * dv), held],
+        out_specs=[wide(hs * dv), held, *kept_spec],
         out_shape=[jax.ShapeDtypeStruct((t + pad, h * dv), F32),
-                   jax.ShapeDtypeStruct((h, dk, dv), F32)],
+                   jax.ShapeDtypeStruct((h, dk, dv), F32), *kept_shape],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=kernel_backend() == "interpret",
         name="gated_delta_chunk",
     )(rows(q), rows(k), rows(v), laid(big),
       a_step(rows(beta.astype(F32))), state.astype(F32))
-    return o[:t].reshape(t, h, dv), state
+    return (o[:t].reshape(t, h, dv), state, *kept)
 
 
 def gated_delta_chunk(q, k, v, g, beta, state, *, g_floor=None):
-    """A run of positions of one sequence, chunked. q, k: [T, Hk, Dk], a key
+    """A run of positions, chunked. One sequence, q, k: [T, Hk, Dk], a key
     head for H // Hk value heads in a row; v: [T, H, Dv]; beta: [T, H]; g:
     [T, H], or [T, H, Dk] for a decay a key channel, which needs
     ``g_floor``, the floor of the caller's gate (no ``g`` is under it);
@@ -616,20 +691,221 @@ def gated_delta_chunk(q, k, v, g, beta, state, *, g_floor=None):
     length: the run is padded to whole sub-chunks with positions that change
     nothing.
 
+    Or a batch of sequences, every operand with a leading ``B`` (q [B, T,
+    Hk, Dk] ... state [B, H, Dk, Dv]) and a decay a head: the form that
+    trains, :func:`gated_delta_chunk_batch`, which has a backward.
+
     The implementation is ``ops/kernels.kernel_backend()``'s: on a TPU the
     kernel, where the operands' shapes are its own (a head's keys and values
     whole 128-lane columns, value heads two by two, a step's heads whole
     key heads; with a decay a channel, a key head a value head);
     :func:`gated_delta_chunk_reference` otherwise."""
+    if v.ndim == 4:
+        if g.ndim != 3:
+            raise ValueError(
+                "gated_delta_chunk: a batch of sequences takes a decay a "
+                f"head, g [B, T, H]; g is {g.shape} beside v {v.shape} (a "
+                "decay a key channel is chunked one sequence a call)")
+        return gated_delta_chunk_batch(q, k, v, g, beta, state)
     h, rep = v.shape[1], v.shape[1] // q.shape[1]
-    if kernel_backend() == "reference" or q.shape[-1] % 128 \
-            or v.shape[-1] % 128 or h % 2 or _heads_a_step(h) % rep \
-            or (g.ndim == 3 and rep != 1):
+    if kernel_backend() == "reference" or not _kernel_takes(
+            h, rep, q.shape[-1], v.shape[-1], g.ndim == 3):
         return gated_delta_chunk_reference(q, k, v, g, beta, state,
                                            g_floor=g_floor)
     if g.ndim == 3:
         _require_floor(g_floor)
     return _chunk_pallas(q, k, v, g, beta, state)
+
+
+def _kernel_takes(h: int, rep: int, dk: int, dv: int, channel: bool) -> bool:
+    """Whether the chunk kernel's blocks hold such operands: a head's keys
+    and values whole 128-lane columns, value heads two by two, a step's
+    heads whole key heads; with a decay a channel, a key head a value
+    head."""
+    return not (dk % 128 or dv % 128 or h % 2 or _heads_a_step(h) % rep
+                or (channel and rep != 1))
+
+
+# --------------------------------------------------- the form that trains
+
+# Positions between two states the forward keeps for the backward: a state
+# is Dk x Dv float32 a head (72 KiB at 96 x 192), so a sequence of 8,192
+# keeps 16 of them a head where a state a sub-chunk would be 128, and the
+# backward makes a chunk's eight again from the one it starts from.
+TRAIN_CHUNK = 512
+
+
+@jax.custom_vjp
+def _inverse_with_transposes(a):
+    """:func:`unit_lower_inverse` whose backward is two products with the
+    inverse's transpose, ``dA = -T^T dT T^T``, and not the transposes of
+    the six levels that made it."""
+    return unit_lower_inverse(a)
+
+
+def _inverse_fwd(a):
+    inv = unit_lower_inverse(a)
+    return inv, inv
+
+
+def _inverse_bwd(inv, ct):
+    inv_t = jnp.swapaxes(inv, -1, -2)
+    return (-_mm(inv_t, _mm(ct, inv_t)),)
+
+
+_inverse_with_transposes.defvjp(_inverse_fwd, _inverse_bwd)
+
+
+def _a_chunk(q, k, v, g, beta, state):
+    """One chunk of a batch in plain jnp, matrix products throughout: q, k
+    [B, C, H, Dk], v [B, C, H, Dv], g, beta [B, C, H] float32 with C whole
+    sub-chunks; state [B, H, Dk, Dv]. Returns (o [B, C, H, Dv], the state
+    after the chunk). Differentiable: the backward of the trained form is
+    ``jax.vjp`` of this, a chunk at a time."""
+    b, c, h = beta.shape
+    n = c // SUB
+
+    def heads_first(a):                           # -> [B, H, n, SUB, ...]
+        return jnp.moveaxis(a.reshape(b, n, SUB, *a.shape[2:]), 3, 1)
+
+    terms = _sub_chunk_terms(*(heads_first(a) for a in (q, k, v, g, beta)),
+                             False, _inverse_with_transposes)
+    state, o = _walk(state, terms, False)
+    # o: [n, B, H, SUB, Dv] -> [B, C, H, Dv]
+    return jnp.moveaxis(o, (0, 3), (1, 2)).reshape(b, c, h, -1), state
+
+
+def _batch_forward_kernel(q, k, v, g, beta, state, c: int):
+    """:func:`_batch_forward` through the chunk kernel, one call for every
+    chunk of every sequence: a head of one sequence is a head like any
+    other, so the batch folds into the heads; keys and values are
+    zero-padded to whole 128-lane columns and the heads to a whole grid
+    step. Exact: a padded channel of a key meets zeros, a padded column of
+    a value and a padded head (``g = 0``, ``beta = 0``) leave a zero state
+    zero. What is padded is time spent and not work needed. The kernel
+    keeps the state every ``c`` positions start from."""
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    heads = b * h
+
+    def fold(a):                      # [B, T, H, ...] -> [T, heads', ...']
+        a = jnp.moveaxis(a, 0, 1).reshape(t, heads, *a.shape[3:])
+        return jnp.pad(a, ((0, 0), (0, -heads % 8))
+                       + ((0, -a.shape[-1] % 128),) * (a.ndim - 2))
+
+    s = state.reshape(heads, dk, dv)
+    s = jnp.pad(s, ((0, -heads % 8), (0, -dk % 128), (0, -dv % 128)))
+    o, s, starts = _chunk_pallas(*(fold(a) for a in (q, k, v, g, beta)), s,
+                                 keep=c // SUB)
+    o = jnp.moveaxis(o[:, :heads, :dv].reshape(t, b, h, dv), 0, 1)
+    return (o, s[:heads, :dk, :dv].reshape(state.shape),
+            starts[:, :heads, :dk, :dv].reshape(-1, *state.shape))
+
+
+def _kernel_takes_a_batch(dk: int, dv: int) -> bool:
+    """Whether the trained forward goes through the kernel: its eight padded
+    states a grid step have to fit (:data:`CHUNK_STATE_BYTES`)."""
+    return kernel_backend() != "reference" and \
+        4 * 8 * (dk + -dk % 128) * (dv + -dv % 128) <= CHUNK_STATE_BYTES
+
+
+def _chunks(t: int) -> tuple[int, int]:
+    """(positions of a chunk, chunks) for a run of ``t`` positions: whole
+    sub-chunks, ``TRAIN_CHUNK`` where the run is longer than one."""
+    c = min(TRAIN_CHUNK, t + -t % SUB)
+    return c, -(-t // c)
+
+
+def _a_slice(a, i, c: int):
+    return lax.dynamic_slice_in_dim(a, i * c, c, axis=1)
+
+
+def _batch_forward(q, k, v, g, beta, state):
+    """The chunks in order. Returns (o, the last state, the state at every
+    chunk's start [chunks, B, H, Dk, Dv])."""
+    c, n = _chunks(beta.shape[1])
+    if _kernel_takes_a_batch(q.shape[-1], v.shape[-1]):
+        return _batch_forward_kernel(q, k, v, g, beta, state, c)
+
+    def chunk(s, i):
+        o, after = _a_chunk(*(_a_slice(a, i, c) for a in (q, k, v, g, beta)),
+                            s)
+        return after, (o, s)
+
+    state, (o, starts) = lax.scan(chunk, state, jnp.arange(n))
+    # o: [chunks, B, C, H, Dv] -> [B, T, H, Dv]
+    o = jnp.moveaxis(o, 0, 1).reshape(beta.shape[0], n * c, *o.shape[3:])
+    return o, state, starts
+
+
+@jax.custom_vjp
+def _batch_rule(q, k, v, g, beta, state):
+    o, state, _ = _batch_forward(q, k, v, g, beta, state)
+    return o, state
+
+
+def _batch_rule_fwd(q, k, v, g, beta, state):
+    o, state, starts = _batch_forward(q, k, v, g, beta, state)
+    return (o, state), (q, k, v, g, beta, starts)
+
+
+def _batch_rule_bwd(saved, cts):
+    """The chunks in reverse: from the state a chunk started from, the
+    chunk's forward again and its transposes, which carry the gradient of
+    the state to the chunk before."""
+    q, k, v, g, beta, starts = saved
+    d_o, d_state = cts
+    c, n = _chunks(beta.shape[1])
+
+    def chunk(d_s, i):
+        operands = tuple(_a_slice(a, i, c) for a in (q, k, v, g, beta))
+        _, pull = jax.vjp(_a_chunk, *operands, starts[i])
+        *d_operands, d_s = pull((_a_slice(d_o, i, c), d_s))
+        return d_s, tuple(d_operands)
+
+    d_state, grads = lax.scan(chunk, d_state, jnp.arange(n), reverse=True)
+    # a gradient: [chunks, B, C, H, ...] -> [B, T, H, ...]
+    return tuple(jnp.moveaxis(x, 0, 1).reshape(a.shape)
+                 for x, a in zip(grads, (q, k, v, g, beta))) + (d_state,)
+
+
+_batch_rule.defvjp(_batch_rule_fwd, _batch_rule_bwd)
+
+
+def gated_delta_chunk_batch(q, k, v, g, beta, state):
+    """The chunked form of a batch of whole sequences, with a backward: what
+    a model trains through. q, k: [B, T, Hk, Dk]; v: [B, T, H, Dv]; g,
+    beta: [B, T, H] (a decay a head); state: [B, H, Dk, Dv]. Returns (o [B,
+    T, H, Dv] float32, the states after the last position). Any T, any
+    number of heads, ``Dk`` and ``Dv`` their own.
+
+    The forward walks chunks of ``TRAIN_CHUNK`` positions and keeps, for the
+    backward, its operands and the state at each chunk's start: not a state
+    a token, not a sub-chunk's matrices. The backward walks the chunks in
+    reverse; a chunk makes its sub-chunks' systems and states again from the
+    state it started from and runs their transposes (``jax.vjp`` of
+    :func:`_a_chunk`, the inverse's by ``dA = -T^T dT T^T``): matrix
+    products throughout, no scan a token. What it is held to is ``jax.grad``
+    through :func:`gated_delta_recurrence` (tests/test_gated_delta.py).
+
+    The forward is ``ops/kernels.kernel_backend()``'s: on a TPU one call of
+    the chunk kernel at padded widths (:func:`_batch_forward_kernel`), which
+    keeps the chunks' states itself; the jnp chunks under a scan elsewhere.
+    The backward is plain jnp on every backend: XLA's batched products on a
+    TPU, at the operands' own widths."""
+    q, k = _a_value_head(q, k, v.shape[2], axis=2)
+    t = beta.shape[1]
+    c, n = _chunks(t)
+    pad = n * c - t
+
+    def whole_chunks(a):
+        a = a.astype(F32)
+        return a if not pad else jnp.pad(
+            a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+
+    o, state = _batch_rule(*(whole_chunks(a) for a in (q, k, v, g, beta)),
+                           state.astype(F32))
+    return o[:, :t], state
 
 
 def gated_delta_step_reference(q, k, v, g, beta, state, line):

@@ -565,6 +565,32 @@ def make_mixtral_train_step(
     )
 
 
+def make_olmo_hybrid_train_step(
+    cfg,
+    mesh: Mesh,
+    rules: ShardingRules | None = None,
+    optimizer: optax.GradientTransformation | None = None,
+    attn_impl: str = "flash",
+    remat: bool | str = True,
+    seed: int = 0,
+    **step_options,
+) -> tuple[Callable, Callable, Callable]:
+    """Olmo-Hybrid specialization: a model with a state made by a scan over
+    the sequence (Gated DeltaNet layers beside a full attention,
+    models/olmo_hybrid.py); the rule's backward is ops/gated_delta.py's."""
+    from ray_tpu.models import olmo_hybrid
+
+    return make_train_step(
+        mesh,
+        loss=lambda p, tokens, targets, kmesh: olmo_hybrid.loss_fn(
+            cfg, p, tokens, targets, attn_impl=attn_impl, remat=remat,
+            kmesh=kmesh),
+        init_fn=partial(olmo_hybrid.init_params, cfg),
+        logical_axes=olmo_hybrid.param_logical_axes(cfg),
+        rules=rules, optimizer=optimizer, seed=seed, **step_options,
+    )
+
+
 def make_vit_train_step(
     cfg,
     mesh: Mesh,

@@ -66,7 +66,8 @@ def chunk(form, *a):
 
 def inputs(t, seed=0, state=True, form="jnp"):
     """Unit keys, scaled unit queries, decays from 0.0009 to 1.6 a token
-    (``exp(g)`` from 0.2 to 0.999), steps in (0, 1), a random state."""
+    (``exp(g)`` from 0.2 to 0.999), steps in (0, 1), a random state
+    (:func:`wide_steps` draws them over (0, 2))."""
     h, hk, dk, dv = FORMS.get(form, form)    # a name, or the four numbers
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
@@ -463,3 +464,204 @@ def test_the_kernel_under_vmap_is_a_sequence_each():
         got = jax.jit(jax.vmap(gd.gated_delta_chunk))(*stacked)
     for b, a in enumerate(batch):
         close((got[0][b], got[1][b]), gd.gated_delta_recurrence(*a))
+
+
+# --------------------------------------- steps up to 2, and the form that trains
+
+def wide_steps(a, seed=0):
+    """``a`` with its steps drawn anew over (0, 2): ``I - beta k k^T`` then
+    has the eigenvalue ``1 - beta`` in (-1, 1) (Olmo-Hybrid's
+    ``linear_allow_neg_eigval``), and about half of the tokens overshoot."""
+    q, k, v, g, beta, s = a
+    beta = jax.random.uniform(jax.random.PRNGKey(1000 + seed), beta.shape,
+                              minval=0.02, maxval=1.98)
+    return q, k, v, g, beta, s
+
+
+@pytest.mark.parametrize("form,t", [("jnp", 200), ("kernel", 330),
+                                    ("channel", 100)])
+def test_steps_up_to_two_are_the_recurrence(form, t):
+    """The chunked form holds whatever ``beta``: ``I + A`` stays unit lower
+    triangular (``beta`` scales ``A``'s rows, all under the diagonal), so
+    the substitution that inverts it divides by nothing. Same tolerance as
+    steps in (0, 1)."""
+    a = wide_steps(inputs(t, seed=t, form=form), t)
+    assert float(a[4].max()) > 1.5
+    close(chunk(form, *a), gd.gated_delta_recurrence(*a))
+
+
+# The trained form's widths: a batch of 2, three heads (no multiple of 8 or
+# of 2), keys of 12 beside values of 24.
+TRAINED = (2, 3, 12, 24)
+# A gradient's largest difference from ``jax.grad`` through the recurrence,
+# over that gradient's largest value. Both compute in float32 at true
+# float32 products: observed 3e-7 to 1.1e-6 at 40 to 700 positions (the
+# order of the sums). A state rounded to bfloat16 at a chunk's boundary
+# moves every gradient by 1e-4 to 2e-3 and must not pass.
+GRAD_RTOL = 1e-5
+OPERANDS = ("q", "k", "v", "g", "beta", "state")
+
+
+def batch_inputs(t, seed=0, state=True, widths=TRAINED):
+    """A batch for the trained form: unit keys, scaled unit queries,
+    ``exp(g)`` from 0.5 to 0.999, steps over (0, 2), a random state, and
+    the weights of a scalar that reads every output and every state."""
+    b, h, dk, dv = widths
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(ks[0], (b, t, h, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (b, t, h, dk)))
+    v = jax.random.normal(ks[2], (b, t, h, dv))
+    g = jnp.log(jax.random.uniform(ks[3], (b, t, h), minval=0.5,
+                                   maxval=0.999))
+    beta = jax.random.uniform(ks[4], (b, t, h), minval=0.02, maxval=1.98)
+    s = jax.random.normal(ks[5], (b, h, dk, dv)) if state \
+        else jnp.zeros((b, h, dk, dv))
+    weights = (jax.random.normal(ks[6], (b, t, h, dv)),
+               jax.random.normal(ks[7], (b, h, dk, dv)))
+    return (q, k, v, g, beta, s), weights
+
+
+def _scalar(rule, weights):
+    def read(*a):
+        o, s = rule(*a)
+        return jnp.sum(o * weights[0]) + jnp.sum(s * weights[1])
+    return read
+
+
+def _grads(rule, a, weights):
+    # (a fresh function a call: nothing traced under a patched module is
+    # found again)
+    return jax.jit(jax.value_and_grad(_scalar(rule, weights),
+                                      argnums=tuple(range(6))))(*a)
+
+
+@pytest.mark.parametrize("t", [40, 512, 700])
+@pytest.mark.parametrize("state", [False, True], ids=["zero", "carried"])
+def test_the_trained_form_and_its_backward_are_the_recurrence_s(t, state):
+    """``gated_delta_chunk`` on a batch against ``jax.vmap`` of the
+    recurrence: outputs and states, and by ``jax.grad`` the gradients of all
+    five operands and of the initial state, at lengths under a sub-chunk's,
+    one whole chunk, and more than a chunk that is not whole chunks."""
+    a, weights = batch_inputs(t, seed=t, state=state)
+    got_o, got_s = gd.gated_delta_chunk(*a)
+    want_o, want_s = jax.vmap(gd.gated_delta_recurrence)(*a)
+    close((got_o, got_s), (want_o, want_s))
+    _, got = _grads(gd.gated_delta_chunk, a, weights)
+    _, want = _grads(jax.vmap(gd.gated_delta_recurrence), a, weights)
+    for name, x, y in zip(OPERANDS, got, want):
+        assert float(jnp.abs(x - y).max() / jnp.abs(y).max()) < GRAD_RTOL, \
+            name
+
+
+def test_a_state_rounded_to_bfloat16_at_a_chunk_s_boundary_fails(monkeypatch):
+    """The control of the tolerance: the same comparison with the state a
+    chunk starts from rounded to bfloat16, forward and backward (the next
+    step down from the float32 state the configuration states)."""
+    a, weights = batch_inputs(700, seed=5)
+    _, want = _grads(jax.vmap(gd.gated_delta_recurrence), a, weights)
+    sound = gd._a_chunk
+    monkeypatch.setattr(gd, "_a_chunk", lambda q, k, v, g, beta, s: sound(
+        q, k, v, g, beta, s.astype(jnp.bfloat16).astype(jnp.float32)))
+    _, got = _grads(gd.gated_delta_chunk, a, weights)
+    worst = {name: float(jnp.abs(x - y).max() / jnp.abs(y).max())
+             for name, x, y in zip(OPERANDS, got, want)}
+    assert all(err > 3 * GRAD_RTOL for err in worst.values()), worst
+
+
+def test_the_trained_form_keeps_a_state_a_chunk_and_walks_them_back():
+    """What the forward hands the backward: its operands and the state at
+    each chunk's start (two for 700 positions), not a state a sub-chunk;
+    and the backward is matrix products, with no loop a token: its loops
+    are the chunks' and a chunk's sub-chunks'."""
+    a, weights = batch_inputs(700)
+    b, h, dk, dv = TRAINED
+    _, saved = jax.eval_shape(gd._batch_rule_fwd, *(
+        jnp.pad(x, ((0, 0), (0, 1024 - 700)) + ((0, 0),) * (x.ndim - 2))
+        for x in a[:5]), a[5])
+    assert saved[5].shape == (2, b, h, dk, dv)
+    assert [x.shape[1] for x in saved[:5]] == [1024] * 5
+    jaxpr = jax.make_jaxpr(jax.grad(_scalar(gd.gated_delta_chunk, weights),
+                                    argnums=(0, 1, 2, 3, 4, 5)))(*a)
+    lengths = {e.params["length"] for e in _equations(jaxpr.jaxpr)
+               if e.primitive.name == "scan"}
+    assert lengths == {2, gd.TRAIN_CHUNK // gd.SUB}
+
+
+def test_the_inverse_s_backward_is_two_products_with_its_transpose():
+    """``dA = -T^T dT T^T`` against the transposes of the six levels, on a
+    system like a sub-chunk's (entries of a few tenths)."""
+    a = 0.2 * jnp.tril(jax.random.normal(jax.random.PRNGKey(3),
+                                         (2, 64, 64)), -1)
+    w = jax.random.normal(jax.random.PRNGKey(4), (2, 64, 64))
+    got = jax.grad(lambda a: jnp.sum(gd._inverse_with_transposes(a) * w))(a)
+    want = jax.grad(lambda a: jnp.sum(gd.unit_lower_inverse(a) * w))(a)
+    low = jnp.tril(jnp.ones((64, 64), bool), -1)
+    want = jnp.where(low, want, 0.0)
+    assert float(jnp.abs(jnp.where(low, got, 0.0) - want).max()
+                 / jnp.abs(want).max()) < GRAD_RTOL
+
+
+def test_a_batch_takes_key_heads_that_serve_several_value_heads():
+    """q and k a key head, repeated for its value heads outside the rule's
+    backward: the gradient of a key head is the sum over them."""
+    (q, k, v, g, beta, s), weights = batch_inputs(100, seed=9)
+    got = _grads(gd.gated_delta_chunk, (q[:, :, :1], k[:, :, :1], v, g,
+                                        beta, s), weights)
+    rep = lambda x: jnp.repeat(x[:, :, :1], 3, axis=2)  # noqa: E731
+    want = _grads(jax.vmap(gd.gated_delta_recurrence),
+                  (rep(q), rep(k), v, g, beta, s), weights)
+    assert float(abs(got[0] - want[0])) < 1e-3 * float(abs(want[0]))
+    for x, y in zip(got[1][:2], want[1][:2]):
+        y = y.sum(axis=2, keepdims=True)
+        assert float(jnp.abs(x - y).max() / jnp.abs(y).max()) < GRAD_RTOL
+
+
+def test_the_trained_forward_s_kernel_is_the_recurrence_at_padded_widths():
+    """On a TPU the forward is one call of the chunk kernel with the batch
+    folded into the heads, keys of 96 and values of 192 zero-padded to 128 and 256
+    and six heads to eight: its body through the interpreter gives the
+    recurrence's outputs, states and (the backward being the jnp chunks'
+    from the kernel's saved states) gradients; off a TPU, and where eight
+    padded states would not fit a grid step, the jnp body runs."""
+    a, weights = batch_inputs(600, seed=77, widths=(2, 3, 96, 192))
+    wide = tuple(jnp.zeros(shape) for shape in (
+        (1, 64, 2, 512), (1, 64, 2, 512), (1, 64, 2, 1024), (1, 64, 2),
+        (1, 64, 2), (1, 2, 512, 1024)))
+    with force_kernel_backend("interpret"):
+        # (a fresh function a trace: what was traced under another backend
+        # is not found again)
+        calls = [e for e in _equations(jax.make_jaxpr(
+            lambda *a: gd.gated_delta_chunk(*a))(*a).jaxpr)
+            if e.primitive.name == "pallas_call"]
+        loss, got = _grads(gd.gated_delta_chunk, a, weights)
+        wide = jax.make_jaxpr(lambda *a: gd.gated_delta_chunk(*a))(*wide)
+    assert len(calls) == 1 and calls[0].params["name"] == "gated_delta_chunk"
+    # one call for both chunks of both sequences, and a third result: the
+    # states the two chunks start from
+    assert [v.aval.shape for v in calls[0].invars[:3]] == [
+        (1024, 8 * 128), (1024, 8 * 128), (1024, 8 * 256)]
+    assert [v.aval.shape for v in calls[0].outvars] == [
+        (1024, 8 * 256), (8, 128, 256), (2, 8, 128, 256)]
+    assert "pallas_call" not in str(wide)
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        lambda *a: gd.gated_delta_chunk(*a))(*a))
+    want_loss, want = _grads(jax.vmap(gd.gated_delta_recurrence), a, weights)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+    for name, x, y in zip(OPERANDS, got, want):
+        assert float(jnp.abs(x - y).max() / jnp.abs(y).max()) < GRAD_RTOL, \
+            name
+
+
+def test_what_no_form_takes_is_refused_where_it_is_traced():
+    """A batch with a decay a key channel (chunked one sequence a call),
+    and states of a grid step that no VMEM holds: a message at trace time,
+    not a failure inside Mosaic."""
+    (q, k, v, g, beta, s), _ = batch_inputs(70)
+    with pytest.raises(ValueError, match="a batch of sequences takes a "
+                                         "decay a head"):
+        gd.gated_delta_chunk(q, k, v, g[..., None] * jnp.ones(12), beta, s)
+    big = inputs(64, form=(8, 8, 256, 1024))
+    with force_kernel_backend("interpret"), \
+            pytest.raises(ValueError, match="MiB of states a grid step"):
+        jax.eval_shape(gd.gated_delta_chunk, *big)

@@ -1745,3 +1745,33 @@ def test_sdar_s_programs_lower_to_what_they_were(v5e_2x2):
     assert {k: v for k, v in got.items() if k.endswith(".mosaic.jaxpr")} == {
         "sdar.prefill_chunk.mosaic.jaxpr": "0d7d9d0919e51ad2",
         "sdar.decode_burst.mosaic.jaxpr": "d52414571d8bc9f2"}
+
+
+# ISSUE 68: Olmo-Hybrid's train step (``make_olmo_hybrid_train_step``) at a
+# reduced size that keeps what the cell's step has: one period of three Gated
+# DeltaNet layers and a full attention under full remat, heads of 128 for the
+# flash kernels, keys and values of two widths, the traffic file's optimizer.
+def test_olmo_hybrid_step_compiles_with_the_rule_named_on_all_three_passes(
+        v5e_2x2):
+    """The step compiles for one v5e chip; every operation with a path lies
+    under a part (none ``unnamed``); the rule's scope is on the forward,
+    on the forward that ``jax.checkpoint`` runs again and on the backward
+    (``xplane_meta.pass_of``), and so are the scopes around it; the flash
+    kernels and the norms are Mosaic calls."""
+    from devbench import olmo_hybrid_bench as bench
+
+    config, traffic = bench.cell_files()
+    cfg = bench.model(config, traffic, hidden_size=256, intermediate_size=512,
+                      num_heads=2, num_kv_heads=2, linear_num_key_heads=3,
+                      linear_num_value_heads=3, linear_key_head_dim=32,
+                      linear_value_head_dim=64, vocab_size=512)
+    mem, text, _ = bench.compile_step(cfg, traffic, 2, 1024)
+    scopes = bench.scopes_by_pass(text)
+    assert "unnamed" not in scopes, scopes["unnamed"]
+    for scope in ("delta_rule", "linear_attn", "conv", "attn", "mlp"):
+        assert set(scopes[scope]) == {"fwd", "bwd", "remat"}, scope
+    assert set(scopes["optim"]) == {"fwd"}
+    assert {"loss", "head", "embed"} <= set(scopes)
+    # flash forward twice (once recomputed) and backward once; the norms
+    assert text.count(MOSAIC) >= 3
+    assert mem.temp_size_in_bytes > 0
