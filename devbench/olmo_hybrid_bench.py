@@ -12,17 +12,22 @@ on one.
 ``aot``: ``train/spmd.make_olmo_hybrid_train_step``'s step under the traffic
 file's optimizer and remat policy, compiled for ``v5e:2x2``'s first device
 (nothing runs: no time comes out of it): XLA's ``memory_analysis``
-(arguments, temporaries, their sum against the chip's 15.75 GiB), the Mosaic
-calls, and the operations of the compiled step by ``tracing.part`` scope and
+(arguments, temporaries, their sum against the chip's 15.75 GiB: run it on
+the parent's tree too, the figures are what the two are compared by), the
+Mosaic calls, the rule's kernels by pass (``gated_delta_chunk`` on ``fwd``
+and ``remat``, ``gated_delta_chunk_bwd`` on ``bwd``, one a linear layer
+each), and the operations of the compiled step by ``tracing.part`` scope and
 pass (``delta_rule`` on ``fwd``, ``bwd`` and ``remat``; none ``unnamed``).
 ``rule``: the gated delta rule alone (``ops/gated_delta.gated_delta_chunk``
 on a batch) at the cell's shapes, forward (the kernel at padded widths, and
-the jnp body) and forward + backward, at the stated precision and with the
+the jnp body), the backward alone (its kernel, and the jnp chunks in
+reverse) and forward + backward, at the stated precision and with the
 products in fewer bfloat16 passes: seconds a call, the share of the
 yardstick
 (``adapters/olmo_hybrid.delta_rule_train_token_work`` over the chip's peaks)
 and the largest difference from the recurrence (forward) and from
-``jax.grad`` through it (one sequence of 1,024). ``grads``: at the cell's
+``jax.grad`` through it (one sequence of 1,024: on the chip the backward
+is the kernel's, and the jnp backward's is printed beside it). ``grads``: at the cell's
 widths and one sequence of 8,192 (``OLMO_SEQ`` overrides), the program's
 gradient of the loss against the float32 reference's, computed a layer at a
 time (``jax.vjp`` of ``benchmark/reference/olmo_hybrid.layer``) so that it
@@ -156,6 +161,25 @@ def scopes_by_pass(text: str) -> dict:
     return out
 
 
+def rule_kernels_by_pass(text: str) -> dict:
+    """{kernel: {pass: calls}} of the Mosaic calls of a compiled step's HLO
+    under the scope ``delta_rule``, a kernel by the name of its
+    ``pallas_call``."""
+    import re
+
+    from rtbench import xplane_meta
+
+    out: dict = {}
+    for line in text.splitlines():
+        m = MOSAIC in line and re.search(
+            r'op_name="(jit\([^"]*/delta_rule/(\w+)/pallas_call)"', line)
+        if m:
+            calls = out.setdefault(m.group(2), {})
+            which = xplane_meta.pass_of(m.group(1))
+            calls[which] = calls.get(which, 0) + 1
+    return out
+
+
 def aot() -> dict:
     config, traffic = cell_files()
     if os.environ.get("OLMO_REMAT"):
@@ -176,6 +200,7 @@ def aot() -> dict:
                               - mem.alias_size_in_bytes
                               + mem.temp_size_in_bytes) / GIB, 3),
             "mosaic_calls": text.count(MOSAIC),
+            "rule_kernels": rule_kernels_by_pass(text),
             "scopes": scopes_by_pass(text)}
 
 
@@ -208,24 +233,32 @@ def rule_inputs(key, batch: int, seq: int, heads: int, dk: int, dv: int):
 @contextlib.contextmanager
 def bf16_states(gd):
     """``ops/gated_delta`` with the state rounded to bfloat16 at every chunk
-    boundary, in the backward's chunks and in the forward's jnp chunks (the
-    kernel's forward keeps its float32 states): the control. Nothing traced
-    on either side of it is found on the other."""
+    boundary: the states the backward walks back from (the jnp chunks' and
+    those the forward's kernel keeps for the backward's kernel) and the
+    forward's jnp chunks (the kernel's forward keeps its float32 states
+    inside): the control. Nothing traced on either side of it is found on
+    the other."""
     import jax
     import jax.numpy as jnp
 
-    sound = gd._a_chunk
+    sound, sound_kernel = gd._a_chunk, gd._batch_forward_kernel
+
+    def low(state):
+        return state.astype(jnp.bfloat16).astype(jnp.float32)
 
     def rounded(q, k, v, g, beta, state):
-        state = state.astype(jnp.bfloat16).astype(jnp.float32)
-        return sound(q, k, v, g, beta, state)
+        return sound(q, k, v, g, beta, low(state))
 
-    gd._a_chunk = rounded
+    def rounded_kernel(*a):
+        o, state, starts = sound_kernel(*a)
+        return o, state, low(starts)
+
+    gd._a_chunk, gd._batch_forward_kernel = rounded, rounded_kernel
     jax.clear_caches()
     try:
         yield
     finally:
-        gd._a_chunk = sound
+        gd._a_chunk, gd._batch_forward_kernel = sound, sound_kernel
         jax.clear_caches()
 
 
@@ -269,6 +302,18 @@ def rule() -> dict:
         fwd_jnp = jax.jit(lambda *a: gd.gated_delta_chunk(*a, zero))
         out["forward_jnp_ms"] = round(timed(lambda: fwd_jnp(*a), 5) * 1e3, 3)
     out["forward_and_backward_ms"] = round(sec_both * 1e3, 3)
+
+    def backward_ms():
+        """The backward alone, from what the forward keeps for it
+        (functions of their own: the backend is read where they are
+        traced)."""
+        saved = jax.jit(lambda *a: gd._batch_rule_fwd(*a, zero)[1])(*a)
+        bwd = jax.jit(lambda saved, w: gd._batch_rule_bwd(saved, (w, zero)))
+        return round(timed(lambda: bwd(saved, weight), 5) * 1e3, 3)
+
+    out["backward_ms"] = backward_ms()
+    with force_kernel_backend("reference"):
+        out["backward_jnp_ms"] = backward_ms()
     # A train step runs the forward twice under full remat (the second is
     # time spent and not work needed) and the backward once.
     out["a_step_and_layer_ms"] = round((sec_fwd + sec_both) * 1e3, 3)
@@ -297,6 +342,8 @@ def rule() -> dict:
                                    want)}
 
     out["gradient_rel_err"] = errors()
+    with force_kernel_backend("reference"):
+        out["gradient_rel_err_jnp"] = errors()
     with bf16_states(gd):
         out["gradient_rel_err_bf16_states"] = errors()
     # The other choice of precision the issue leaves to measurement: the

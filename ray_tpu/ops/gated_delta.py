@@ -70,9 +70,14 @@ Four forms of it:
   one call, the batch folded into the heads and keys and values
   zero-padded to whole 128-lane columns (96 and 192 become 128 and 256:
   exact, and 1.9 times the needed products), the kernel keeping the
-  chunks' states as a third result; the backward is plain jnp on every
-  backend (XLA's batched products at the operands' own widths: a kernel
-  for it is later work).
+  chunks' states as a third result; and the backward is a kernel too
+  (:func:`_chunk_bwd_kernel`, one call, the same folding, padding and
+  pairing): a chunk's sub-chunks walked forward from the kept state with
+  their states, inverses and corrections kept in VMEM, then walked back,
+  the gradient of the state in VMEM from the last chunk to the first and
+  the operands' gradients written once as ``[T, H * D]`` columns.
+  Elsewhere, and as what that kernel is held to, the jnp chunks in reverse
+  under a scan (XLA's batched products at the operands' own widths).
 - :func:`gated_delta_step`: one position of every slot, the recurrence's
   single step on a line of a state leaf ``[lines, slots, heads, Dk, Dv]``,
   in place. The update needs ``d_t`` and ``d_t`` a sum over the whole
@@ -130,6 +135,7 @@ on states, and the rule's work is small beside the layer's projections.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -775,6 +781,39 @@ def _a_chunk(q, k, v, g, beta, state):
     return jnp.moveaxis(o, (0, 3), (1, 2)).reshape(b, c, h, -1), state
 
 
+def _fold(a):
+    """A batch's operand as the chunk kernels take it: [B, T, H, ...] ->
+    [T, B * H (to a whole grid step of eight), ... (to whole 128-lane
+    columns)], zero-padded."""
+    b, t, h = a.shape[:3]
+    a = jnp.moveaxis(a, 0, 1).reshape(t, b * h, *a.shape[3:])
+    return jnp.pad(a, ((0, 0), (0, -(b * h) % 8))
+                   + ((0, -a.shape[-1] % 128),) * (a.ndim - 2))
+
+
+def _fold_states(s):
+    """[..., B, H, Dk, Dv] -> [..., heads', Dk', Dv'], as :func:`_fold`."""
+    *lead, b, h, dk, dv = s.shape
+    s = s.reshape(*lead, b * h, dk, dv)
+    return jnp.pad(s, ((0, 0),) * len(lead) + (
+        (0, -(b * h) % 8), (0, -dk % 128), (0, -dv % 128)))
+
+
+def _unfold(x, like):
+    """:func:`_fold`'s way back: [T, heads', ...'] as ``like`` [B, T, H,
+    ...], what was padded dropped."""
+    b, t, h = like.shape[:3]
+    x = x[:, :b * h] if like.ndim == 3 else x[:, :b * h, :like.shape[-1]]
+    return jnp.moveaxis(x.reshape(t, b, h, *like.shape[3:]), 0, 1)
+
+
+def _unfold_states(s, like):
+    """:func:`_fold_states`'s way back: [..., heads', Dk', Dv'] with the
+    trailing dimensions of ``like`` [..., B, H, Dk, Dv]."""
+    b, h, dk, dv = like.shape[-4:]
+    return s[..., :b * h, :dk, :dv].reshape(*s.shape[:-3], b, h, dk, dv)
+
+
 def _batch_forward_kernel(q, k, v, g, beta, state, c: int):
     """:func:`_batch_forward` through the chunk kernel, one call for every
     chunk of every sequence: a head of one sequence is a head like any
@@ -784,22 +823,10 @@ def _batch_forward_kernel(q, k, v, g, beta, state, c: int):
     a value and a padded head (``g = 0``, ``beta = 0``) leave a zero state
     zero. What is padded is time spent and not work needed. The kernel
     keeps the state every ``c`` positions start from."""
-    b, t, h, dk = q.shape
-    dv = v.shape[-1]
-    heads = b * h
-
-    def fold(a):                      # [B, T, H, ...] -> [T, heads', ...']
-        a = jnp.moveaxis(a, 0, 1).reshape(t, heads, *a.shape[3:])
-        return jnp.pad(a, ((0, 0), (0, -heads % 8))
-                       + ((0, -a.shape[-1] % 128),) * (a.ndim - 2))
-
-    s = state.reshape(heads, dk, dv)
-    s = jnp.pad(s, ((0, -heads % 8), (0, -dk % 128), (0, -dv % 128)))
-    o, s, starts = _chunk_pallas(*(fold(a) for a in (q, k, v, g, beta)), s,
-                                 keep=c // SUB)
-    o = jnp.moveaxis(o[:, :heads, :dv].reshape(t, b, h, dv), 0, 1)
-    return (o, s[:heads, :dk, :dv].reshape(state.shape),
-            starts[:, :heads, :dk, :dv].reshape(-1, *state.shape))
+    o, s, starts = _chunk_pallas(*(_fold(a) for a in (q, k, v, g, beta)),
+                                 _fold_states(state), keep=c // SUB)
+    return (_unfold(o, v), _unfold_states(s, state),
+            _unfold_states(starts, state))
 
 
 def _kernel_takes_a_batch(dk: int, dv: int) -> bool:
@@ -807,6 +834,337 @@ def _kernel_takes_a_batch(dk: int, dv: int) -> bool:
     states a grid step have to fit (:data:`CHUNK_STATE_BYTES`)."""
     return kernel_backend() != "reference" and \
         4 * 8 * (dk + -dk % 128) * (dv + -dv % 128) <= CHUNK_STATE_BYTES
+
+
+def _chunk_bwd_kernel(q_ref, k_ref, v_ref, big_ref, beta_ref, s0_ref, do_ref,
+                      dsn_ref, dq_ref, dk_ref, dv_ref, dbig_ref, dbeta_ref,
+                      ds_ref, states, inverses, corrections, *, heads: int,
+                      dk: int, dv: int, subs: int):
+    """One sub-chunk of ``heads`` heads, two by two, of the trained form's
+    backward: a key head a value head, the operands' blocks as
+    :func:`_chunk_kernel`'s. The grid is (heads a step) by (chunks, last to
+    first) by (``2 subs`` steps a chunk): the first ``subs`` walk the chunk's
+    sub-chunks forward from the state the forward kept (``s0_ref``) and keep
+    in VMEM what the way back needs of each, the state it starts from
+    (``states``), its inverse ``T`` (``inverses``, a pair's two side by
+    side) and its corrections ``D`` (``corrections``); the last ``subs``
+    walk them back. ``ds_ref`` [heads, dk, dv] is the gradient of the state,
+    one block for every step of a head: in VMEM from the last sub-chunk of
+    the last chunk (where it is ``dsn_ref``, the cotangent of the state
+    after the run) to the first of the first, where what it holds is the
+    gradient of the state before the run. do_ref is the outputs' cotangent;
+    dq_ref, dk_ref, dv_ref the operands' gradients and dbig_ref, dbeta_ref
+    [SUB, heads] those of the running sums ``G_i`` and of the steps, whose
+    blocks stay at the chunk's last sub-chunk while the walk goes forward
+    and are written on the way back alone.
+
+    With ``Γ_ij = exp(G_i - G_j)``, ``P = Γ (Q K^T)``, ``R = beta (V -
+    exp(G) K S_0)``, ``D = T R`` and ``K' = exp(G_C - G) K``, given ``dO``
+    and ``dS_C``::
+
+        dD   = P^T dO + K' dS_C
+        dR   = T^T dD                    # dV = beta dR
+        dA   = -strict_lower(dR D^T)     # -T^T (dD R^T) T^T, one product
+        dP   = lower(dO D^T)
+        dS_0 = exp(G_C) dS_C + (exp(G) Q)^T dO - (beta exp(G) K)^T dR
+
+    ``dQ`` and ``dK`` from ``Γ dP`` and ``M = beta Γ dA`` (``[Γ dP; M]
+    [[K_0, 0], [0, K_1]]`` and, with the tiles' rows contracted, ``[Γ dP;
+    M]^T [Q; K]``), ``dO S_0^T``, ``dR S_0^T`` and ``D dS_C^T``; ``dG`` and
+    ``dbeta`` row sums of elementwise products. A tile's transpose is never
+    taken: ``P^T`` is made from ``K Q^T`` and ``exp(G_j - G_i)`` as ``P``
+    from ``Q K^T``, and a product with ``T^T`` or ``M^T`` contracts the
+    tile's rows. Every exponent is a difference ``G_i - G_j <= 0``."""
+    from jax.experimental import pallas as pl
+
+    # (read here and not under a ``pl.when``: the interpreter knows a grid
+    # index at the body's top level alone)
+    step, first = pl.program_id(2), pl.program_id(1) == 0
+    rows = lax.broadcasted_iota(jnp.int32, (SUB, 2 * SUB), 0)
+    lanes = lax.broadcasted_iota(jnp.int32, (SUB, 2 * SUB), 1)
+    right = lanes >= SUB                                # the second head
+    cols = lanes & (SUB - 1)
+    upto = rows >= cols                                       # j <= i
+    last_row = lax.broadcasted_iota(jnp.int32, (SUB, dv), 0) == SUB - 1
+    last_of = lax.broadcasted_iota(jnp.int32, (SUB, 1), 0) == SUB - 1
+    head_of = lax.broadcasted_iota(jnp.int32, (SUB, heads), 1)
+    pairs = [(h, h + 1) for h in range(0, heads, 2)]
+    nt = (((1,), (1,)), ((), ()))                 # a b^T
+    tn = (((0,), (0,)), ((), ()))                 # a^T b
+
+    def head(ref, h, d):
+        return ref[:, h * d:(h + 1) * d].astype(F32)
+
+    def both(ref, pair):
+        """A column a head, [SUB, 1], over its head's lanes."""
+        return jnp.where(right, ref[:, pair[1]:pair[1] + 1],
+                         ref[:, pair[0]:pair[0] + 1])
+
+    def of_pair(x):
+        """A pair's two [2 SUB, 2 SUB] products, a head's own in its lanes:
+        [SUB, 2 SUB]."""
+        return jnp.where(right, x[SUB:], x[:SUB])
+
+    def side(x):
+        return jnp.concatenate(x, axis=1)
+
+    def stack(x):
+        return jnp.concatenate(x, axis=0)
+
+    def summed(x):
+        return jnp.sum(x, axis=1, keepdims=True)
+
+    def half_summed(x, n):
+        """The rows' sums of a pair's tile over the lanes of its head ``n``
+        (0 or 1)."""
+        return summed(jnp.where(right if n else ~right, x, 0.0))
+
+    def decays(pair, transposed=False):
+        """exp(G_i - G_j) where j <= i of a pair, and with ``transposed``
+        its transpose beside it."""
+        big = both(big_ref, pair)
+        # G_j along the lanes: the diagonal of the column's broadcast.
+        along = jnp.sum(jnp.where(rows == cols, big, 0.0), axis=0,
+                        keepdims=True)
+        fall = jnp.where(upto, jnp.exp(jnp.where(upto, big - along, 0.0)),
+                         0.0)
+        if not transposed:
+            return fall
+        return fall, jnp.where(cols >= rows, jnp.exp(jnp.where(
+            cols >= rows, along - big, 0.0)), 0.0)
+
+    def whole_of(h):
+        """exp(G_C) along the lanes, [1, dv]: a sum that keeps the last row
+        (Mosaic broadcasts one way at a time)."""
+        return jnp.sum(jnp.where(last_row, jnp.exp(big_ref[:, h:h + 1]), 0.0),
+                       axis=0, keepdims=True)
+
+    def fade_of(h):
+        big = big_ref[:, h:h + 1]
+        return jnp.exp(big[SUB - 1:] - big)                   # exp(G_C - G)
+
+    @pl.when(step < subs)
+    def _():
+        """The way forward: sub-chunk ``step`` from ``states[step]``."""
+        @pl.when(step == 0)
+        def _():
+            states[0] = s0_ref[...]
+
+        k = {h: head(k_ref, h, dk) for h in range(heads)}
+        a = []
+        for pair in pairs:
+            both_k = stack([k[pair[0]], k[pair[1]]])
+            kk = of_pair(_dot(both_k, both_k, nt))
+            a.append(jnp.where(rows > cols, both(beta_ref, pair)
+                               * decays(pair) * kk, 0.0))
+        inv = _pair_inverses(a, rows, cols, right)
+        s = {h: states[step, h] for h in k}
+        # (I + A) D = beta (V - exp(G) K S_0)
+        rhs = {h: beta_ref[:, h:h + 1] * (
+            head(v_ref, h, dv) - jnp.exp(big_ref[:, h:h + 1])
+            * _dot(k[h], s[h])) for h in k}
+        d = {}
+        for n, (pair, t) in enumerate(zip(pairs, inv)):
+            inverses[step, n] = t
+            both_d = _dot(t, _apart(rhs[pair[0]], rhs[pair[1]]))
+            d[pair[0]], d[pair[1]] = both_d[:, :dv], both_d[:, dv:]
+        for h in k:
+            corrections[step, h] = d[h]
+
+        @pl.when(step < subs - 1)
+        def _():
+            for h in k:
+                states[step + 1, h] = whole_of(h) * s[h] + _dot(
+                    fade_of(h) * k[h], d[h], tn)
+
+    @pl.when(step >= subs)
+    def _():
+        """The way back: sub-chunk ``2 subs - 1 - step``."""
+        sub = 2 * subs - 1 - step
+
+        @pl.when((step == subs) & first)
+        def _():
+            ds_ref[...] = dsn_ref[...]
+
+        k = {h: head(k_ref, h, dk) for h in range(heads)}
+        q = {h: head(q_ref, h, dk) for h in k}
+        d_o = {h: head(do_ref, h, dv) for h in k}
+        s = {h: states[sub, h] for h in k}
+        d = {h: corrections[sub, h] for h in k}
+        d_s = {h: ds_ref[h] for h in k}
+        grow = {h: jnp.exp(big_ref[:, h:h + 1]) for h in k}     # exp(G_i)
+        fade = {h: fade_of(h) for h in k}
+        whole = {h: whole_of(h) for h in k}
+        kout = {h: fade[h] * k[h] for h in k}                 # K'
+        decay, kk, qk, d_d = {}, {}, {}, {}
+        for pair in pairs:
+            h0, h1 = pair
+            # [K_0; Q_0; K_1; Q_1] [K_0; K_1]^T, a head's own in its lanes
+            kq = _dot(stack([k[h0], q[h0], k[h1], q[h1]]),
+                      stack([k[h0], k[h1]]), nt)
+            kk[pair] = jnp.where(right, kq[2 * SUB:3 * SUB], kq[:SUB])
+            qk[pair] = jnp.where(right, kq[3 * SUB:], kq[SUB:2 * SUB])
+            # (K Q^T)_ji exp(G_i - G_j): P^T with no transpose
+            decay[pair], back = decays(pair, transposed=True)
+            p_t = back * of_pair(_dot(stack([k[h0], k[h1]]),
+                                      stack([q[h0], q[h1]]), nt))
+            # dD = P^T dO + K' dS_C
+            both_dd = _dot(p_t, _apart(d_o[h0], d_o[h1]))
+            d_d[h0] = both_dd[:, :dv] + _dot(kout[h0], d_s[h0])
+            d_d[h1] = both_dd[:, dv:] + _dot(kout[h1], d_s[h1])
+        d_r, grad, lower = {}, {}, {}
+        for n, pair in enumerate(pairs):
+            h0, h1 = pair
+            # dR = T^T dD: the tiles' rows contracted, a head's own block
+            both_dr = _dot(inverses[sub, n], side([d_d[h0], d_d[h1]]), tn)
+            d_r[h0], d_r[h1] = both_dr[:SUB, :dv], both_dr[SUB:, dv:]
+            # [dR; dO] D^T: dA = -strict_lower(dR D^T), dP = lower(dO D^T)
+            x = _dot(stack([side([d_r[h0], d_r[h1]]),
+                            side([d_o[h0], d_o[h1]])]),
+                     _apart(d[h0], d[h1]), nt)
+            gda = decay[pair] * jnp.where(rows > cols, -x[:SUB], 0.0)  # Γ dA
+            gdp = decay[pair] * jnp.where(upto, x[SUB:], 0.0)      # Γ dP
+            m = both(beta_ref, pair) * gda                    # beta Γ dA
+            tiles = stack([gdp, m])
+            # [Γ dP; M] [[K_0, 0], [0, K_1]] and [Γ dP; M]^T [Q; K]
+            grad[pair] = (_dot(tiles, _apart(k[h0], k[h1])),
+                          _dot(tiles, stack([side([q[h0], q[h1]]),
+                                             side([k[h0], k[h1]])]), tn))
+            # what the rows' sums read: dbeta's Γ dA (K K^T), and dG's dP P +
+            # dA A, a row's sum less its column's
+            w = gdp * qk[pair] + m * kk[pair]
+            w = w - jnp.where(rows == cols,
+                              jnp.sum(w, axis=0, keepdims=True), 0.0)
+            lower[pair] = (gda * kk[pair], w)
+        d_big = jnp.zeros((SUB, heads), F32)
+        d_beta = jnp.zeros((SUB, heads), F32)
+        for pair in pairs:
+            for n, h in enumerate(pair):
+                at = slice(n * dk, (n + 1) * dk)
+                beta = beta_ref[:, h:h + 1]
+                # [dO; dR] S_0^T, and D dS_C^T
+                from_s = _dot(stack([d_o[h], d_r[h]]), s[h], nt)
+                d_os, d_rs = from_s[:SUB], from_s[SUB:]
+                d_kout = _dot(d[h], d_s[h], nt)
+                dq_ref[:, h * dk:(h + 1) * dk] = \
+                    grad[pair][0][:SUB, at] + grow[h] * d_os
+                dk_ref[:, h * dk:(h + 1) * dk] = (
+                    grad[pair][0][SUB:, at]
+                    + grad[pair][1][n * SUB:(n + 1) * SUB, at]
+                    - beta * grow[h] * d_rs + fade[h] * d_kout)
+                dv_ref[:, h * dv:(h + 1) * dv] = beta * d_r[h]
+                r_s = grow[h] * summed(k[h] * d_rs)
+                d_beta = jnp.where(
+                    head_of == h, summed(d_r[h] * head(v_ref, h, dv)) - r_s
+                    + half_summed(lower[pair][0], n), d_beta)
+                # exp(G_C) in K' and before S_0: the last row's, less a
+                # row's own
+                out = summed(d_kout * kout[h])
+                ends = jnp.sum(out, axis=0, keepdims=True) + jnp.sum(
+                    whole[h] * jnp.sum(d_s[h] * s[h], axis=0, keepdims=True),
+                    axis=1, keepdims=True)
+                d_big = jnp.where(
+                    head_of == h, half_summed(lower[pair][1], n)
+                    + grow[h] * summed(q[h] * d_os) - beta * r_s - out
+                    + jnp.where(last_of, ends, 0.0), d_big)
+                ds_ref[h] = whole[h] * d_s[h] + _dot(
+                    stack([grow[h] * q[h], -(beta * grow[h]) * k[h]]),
+                    stack([d_o[h], d_r[h]]), tn)
+        dbig_ref[...] = d_big
+        dbeta_ref[...] = d_beta
+
+
+def _chunk_bwd_pallas(q, k, v, g, beta, starts, d_o, d_state, subs: int):
+    """The backward kernel's call, on operands as :func:`_chunk_pallas`
+    takes them (a key head a value head, T whole chunks of ``subs``
+    sub-chunks; starts [T / (subs SUB), H, Dk, Dv], the state every chunk
+    starts from). Returns the gradients of q, k, v, g, beta and the state
+    before the run."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, h, dv = v.shape
+    dk = q.shape[-1]
+    hs = _heads_a_step(h)
+    chunks = t // (subs * SUB)
+
+    def rows(a):
+        return a.reshape(t, -1)                               # [T, H * D]
+
+    def a_step(a):                                            # [T, H] ->
+        return jnp.moveaxis(a.reshape(t, h // hs, hs), 1, 0)
+
+    def from_steps(a):                                        # -> [T, H]
+        return jnp.moveaxis(a, 0, 1).reshape(t, h)
+
+    def sub(j, s, waits: bool):
+        """The sub-chunk a step is at: forward, then back; what only the
+        way back reads or writes waits at the chunk's last."""
+        s = jnp.maximum(s, subs) if waits else jnp.maximum(s, 2 * subs - 1 - s)
+        return (chunks - 1 - j) * subs + 2 * subs - 1 - s
+
+    def wide(d, waits=False):
+        return pl.BlockSpec((SUB, d), lambda i, j, s: (sub(j, s, waits), i))
+
+    def narrow(waits=False):
+        return pl.BlockSpec((None, SUB, hs),
+                            lambda i, j, s: (i, sub(j, s, waits), 0))
+
+    held = pl.BlockSpec((hs, dk, dv), lambda i, j, s: (i, 0, 0))
+    # what a chunk keeps for its way back: the states, the pairs' inverses
+    # and the corrections (8 + 1 + 4 MiB at eight heads of 128 x 256)
+    kept = [(subs, hs, dk, dv), (subs, hs // 2, SUB, 2 * SUB),
+            (subs, hs, SUB, dv)]
+    big = jnp.cumsum(g.reshape(t // SUB, SUB, h), axis=1).reshape(t, h)
+    dq, dk_, dv_, d_big, d_beta, d_state = pl.pallas_call(
+        functools.partial(_chunk_bwd_kernel, heads=hs, dk=dk, dv=dv,
+                          subs=subs),
+        grid=(h // hs, chunks, 2 * subs),
+        in_specs=[wide(hs * dk), wide(hs * dk), wide(hs * dv), narrow(),
+                  narrow(),
+                  pl.BlockSpec((None, hs, dk, dv),
+                               lambda i, j, s: (chunks - 1 - j, i, 0, 0)),
+                  wide(hs * dv, True), held],
+        out_specs=[wide(hs * dk, True), wide(hs * dk, True),
+                   wide(hs * dv, True), narrow(True), narrow(True), held],
+        out_shape=[jax.ShapeDtypeStruct((t, h * dk), F32),
+                   jax.ShapeDtypeStruct((t, h * dk), F32),
+                   jax.ShapeDtypeStruct((t, h * dv), F32),
+                   jax.ShapeDtypeStruct((h // hs, t, hs), F32),
+                   jax.ShapeDtypeStruct((h // hs, t, hs), F32),
+                   jax.ShapeDtypeStruct((h, dk, dv), F32)],
+        scratch_shapes=[pltpu.VMEM(shape, F32) for shape in kept],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            # beside them a sub-chunk's operands and gradients with their
+            # copies in flight and what the body spills (Mosaic's default
+            # is 16 MiB; a v5e has 128)
+            vmem_limit_bytes=(32 << 20) + 4 * sum(
+                math.prod(shape) for shape in kept)),
+        interpret=kernel_backend() == "interpret",
+        name="gated_delta_chunk_bwd",
+    )(rows(q), rows(k), rows(v), a_step(big), a_step(beta), starts,
+      rows(d_o), d_state)
+    # G_i is the sum of g up to i inside a sub-chunk: dg_i the sum of dG from
+    # i to the sub-chunk's end
+    d_g = lax.cumsum(from_steps(d_big).reshape(t // SUB, SUB, h), axis=1,
+                     reverse=True).reshape(t, h)
+    return (dq.reshape(t, h, dk), dk_.reshape(t, h, dk),
+            dv_.reshape(t, h, dv), d_g, from_steps(d_beta), d_state)
+
+
+def _batch_backward_kernel(saved, cts):
+    """:func:`_batch_rule_bwd` through the backward's kernel, one call for
+    every chunk of every sequence, folded and padded as
+    :func:`_batch_forward_kernel` folds the forward's: a padded channel's
+    and a padded head's gradients are dropped."""
+    *operands, starts = saved
+    d_o, d_state = cts
+    *grads, d_first = _chunk_bwd_pallas(
+        *(_fold(a) for a in operands), _fold_states(starts), _fold(d_o),
+        _fold_states(d_state), _chunks(d_o.shape[1])[0] // SUB)
+    return tuple(_unfold(x, a) for x, a in zip(grads, operands)) + (
+        _unfold_states(d_first, d_state),)
 
 
 def _chunks(t: int) -> tuple[int, int]:
@@ -852,8 +1210,12 @@ def _batch_rule_fwd(q, k, v, g, beta, state):
 def _batch_rule_bwd(saved, cts):
     """The chunks in reverse: from the state a chunk started from, the
     chunk's forward again and its transposes, which carry the gradient of
-    the state to the chunk before."""
+    the state to the chunk before. The kernel where the forward went
+    through its own (:func:`_kernel_takes_a_batch`), else ``jax.vjp`` of the
+    jnp chunk under a scan."""
     q, k, v, g, beta, starts = saved
+    if _kernel_takes_a_batch(q.shape[-1], v.shape[-1]):
+        return _batch_backward_kernel(saved, cts)
     d_o, d_state = cts
     c, n = _chunks(beta.shape[1])
 
@@ -883,16 +1245,17 @@ def gated_delta_chunk_batch(q, k, v, g, beta, state):
     backward, its operands and the state at each chunk's start: not a state
     a token, not a sub-chunk's matrices. The backward walks the chunks in
     reverse; a chunk makes its sub-chunks' systems and states again from the
-    state it started from and runs their transposes (``jax.vjp`` of
+    state it started from and runs their transposes (in jnp ``jax.vjp`` of
     :func:`_a_chunk`, the inverse's by ``dA = -T^T dT T^T``): matrix
     products throughout, no scan a token. What it is held to is ``jax.grad``
     through :func:`gated_delta_recurrence` (tests/test_gated_delta.py).
 
-    The forward is ``ops/kernels.kernel_backend()``'s: on a TPU one call of
-    the chunk kernel at padded widths (:func:`_batch_forward_kernel`), which
-    keeps the chunks' states itself; the jnp chunks under a scan elsewhere.
-    The backward is plain jnp on every backend: XLA's batched products on a
-    TPU, at the operands' own widths."""
+    Both passes are ``ops/kernels.kernel_backend()``'s: on a TPU one call
+    each at padded widths, the chunk kernel (:func:`_batch_forward_kernel`),
+    which keeps the chunks' states itself, and the backward's kernel
+    (:func:`_batch_backward_kernel`: the same transposes, a sub-chunk a grid
+    step, what a chunk's way back needs kept in VMEM); the jnp chunks under
+    a scan elsewhere, forward and in reverse."""
     q, k = _a_value_head(q, k, v.shape[2], axis=2)
     t = beta.shape[1]
     c, n = _chunks(t)

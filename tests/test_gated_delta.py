@@ -54,11 +54,12 @@ _jitted_channel = jax.jit(lambda *a: gd.gated_delta_chunk(*a, g_floor=FLOOR))
 def chunk(form, *a):
     """``gated_delta_chunk`` as ``form`` runs it: as it is off a TPU, or the
     kernel's body through the interpreter (one jitted program a shape: its
-    cache holds only what was traced under the forced backend)."""
+    cache holds only what was traced under the forced backend, and no
+    kernel form has a jnp form's widths)."""
     if form == "jnp":
-        return gd.gated_delta_chunk(*a)
+        return _jitted(*a)
     if form == "channel":
-        return gd.gated_delta_chunk(*a, g_floor=FLOOR)
+        return _jitted_channel(*a)
     with force_kernel_backend("interpret"):
         return (_jitted_channel if form.startswith("channel")
                 else _jitted)(*a)
@@ -427,17 +428,32 @@ def test_the_kernel_is_chosen_by_the_backend_and_the_operands_shapes():
         assert ("pallas_call" in names) == fits, (h, hk)
 
 
+def _traced_backward():
+    """The trained form's backward at the kernel's widths, as the
+    interpreter's backend traces it."""
+    a, weights = batch_inputs(128, widths=TRAINED_FORMS["kernel_of_128"])
+    with force_kernel_backend("interpret"):
+        return list(_equations(jax.make_jaxpr(jax.grad(
+            _scalar(lambda *a: gd.gated_delta_chunk(*a), weights),
+            argnums=tuple(range(6))))(*a).jaxpr))
+
+
 @pytest.mark.parametrize("form", ["kernel", "kernel_own_keys",
-                                  "channel_kernel"])
+                                  "channel_kernel", "backward"])
 def test_every_product_of_the_kernel_is_true_float32(form):
     """The configuration states the precision (``departures.state_dtype``):
     the state float32, the rule's products at true float32. Every product
     inside the kernel is float32 by float32 into float32 at
     ``Precision.HIGHEST`` (a TPU's default is one bfloat16 pass), the state
     goes in and comes out float32, and nothing in the kernel is cast below
-    float32."""
-    eqns = _traced(form)
-    call, = (e for e in eqns if e.primitive.name == "pallas_call")
+    float32. The trained form's backward kernel (``backward``) the same:
+    its gradients, the state's among them, leave float32."""
+    if form == "backward":
+        call, = (e for e in _traced_backward()
+                 if e.primitive.name == "pallas_call"
+                 and e.params["name"] == "gated_delta_chunk_bwd")
+    else:
+        call, = (e for e in _traced(form) if e.primitive.name == "pallas_call")
     body = list(_equations(call.params["jaxpr"]))
     dots = [e for e in body if e.primitive.name == "dot_general"]
     assert len(dots) >= 8
@@ -450,8 +466,10 @@ def test_every_product_of_the_kernel_is_true_float32(form):
     for e in body:
         if e.primitive.name == "convert_element_type":
             assert e.params["new_dtype"] in (jnp.float32, jnp.int32), e
-    assert [v.aval.dtype for v in call.outvars] == [jnp.float32] * 2
-    assert call.outvars[1].aval.shape == call.invars[-1].aval.shape
+    assert [v.aval.dtype for v in call.outvars] == [jnp.float32] * (
+        6 if form == "backward" else 2)
+    # the state, or its gradient, as it came in
+    assert call.outvars[-1].aval.shape == call.invars[-1].aval.shape
 
 
 def test_the_kernel_under_vmap_is_a_sequence_each():
@@ -522,10 +540,13 @@ def batch_inputs(t, seed=0, state=True, widths=TRAINED):
     return (q, k, v, g, beta, s), weights
 
 
-def _scalar(rule, weights):
+def _scalar(rule, weights, beside=False):
+    """The scalar that reads every output and state of ``rule``; with
+    ``beside``, (the scalar, what it read)."""
     def read(*a):
         o, s = rule(*a)
-        return jnp.sum(o * weights[0]) + jnp.sum(s * weights[1])
+        loss = jnp.sum(o * weights[0]) + jnp.sum(s * weights[1])
+        return (loss, (o, s)) if beside else loss
     return read
 
 
@@ -536,37 +557,116 @@ def _grads(rule, a, weights):
                                       argnums=tuple(range(6))))(*a)
 
 
-@pytest.mark.parametrize("t", [40, 512, 700])
-@pytest.mark.parametrize("state", [False, True], ids=["zero", "carried"])
-def test_the_trained_form_and_its_backward_are_the_recurrence_s(t, state):
+# The trained form twice, as ``FORMS`` has the chunk form: the jnp chunks and
+# their ``jax.vjp`` at widths no kernel takes, and both kernels' bodies
+# through the interpreter (the forward's, and since PR 69 the backward's,
+# ``gated_delta_chunk_bwd``), held to the same recurrence at the same
+# tolerance: Olmo-Hybrid's 96 x 192 with six heads padded to the eight of a
+# grid step, and one sequence of two heads of 128 x 128 with nothing padded
+# but the heads.
+TRAINED_FORMS = {"jnp": TRAINED, "kernel": (2, 3, 96, 192),
+                 "kernel_of_128": (1, 2, 128, 128)}
+
+# (one jitted program a shape: it is traced under the interpreter's backend
+# alone, and the weights are operands)
+_kernel_grads = jax.jit(lambda a, weights: jax.value_and_grad(
+    _scalar(lambda *a: gd.gated_delta_chunk(*a), weights, beside=True),
+    argnums=tuple(range(6)), has_aux=True)(*a))
+
+
+def trained_grads(form, a, weights):
+    """((outputs, states), the six gradients of the scalar that reads them)
+    as ``form`` runs the trained form."""
+    if form == "jnp":
+        return (gd.gated_delta_chunk(*a),
+                _grads(gd.gated_delta_chunk, a, weights)[1])
+    with force_kernel_backend("interpret"):
+        (_, results), grads = _kernel_grads(a, weights)
+    return results, grads
+
+
+def worst(got, want):
+    return {name: float(jnp.abs(x - y).max() / jnp.abs(y).max())
+            for name, x, y in zip(OPERANDS, got, want)}
+
+
+@pytest.mark.parametrize("form,t,state", [
+    *(("jnp", t, state) for t in (40, 512, 700) for state in (False, True)),
+    ("kernel", 512, True), ("kernel", 700, False),
+    ("kernel_of_128", 40, False), ("kernel_of_128", 1024, True)],
+    ids=lambda x: {False: "zero", True: "carried"}.get(x, str(x)))
+def test_the_trained_form_and_its_backward_are_the_recurrence_s(form, t,
+                                                                state):
     """``gated_delta_chunk`` on a batch against ``jax.vmap`` of the
     recurrence: outputs and states, and by ``jax.grad`` the gradients of all
     five operands and of the initial state, at lengths under a sub-chunk's,
-    one whole chunk, and more than a chunk that is not whole chunks."""
-    a, weights = batch_inputs(t, seed=t, state=state)
-    got_o, got_s = gd.gated_delta_chunk(*a)
-    want_o, want_s = jax.vmap(gd.gated_delta_recurrence)(*a)
-    close((got_o, got_s), (want_o, want_s))
-    _, got = _grads(gd.gated_delta_chunk, a, weights)
+    one whole chunk, more than a chunk that is not whole chunks, and two
+    whole chunks; steps over (0, 2). The jnp chunks, and both kernels'
+    bodies through the interpreter."""
+    a, weights = batch_inputs(t, seed=t, state=state,
+                              widths=TRAINED_FORMS[form])
+    assert float(a[4].max()) > 1.5
+    results, got = trained_grads(form, a, weights)
+    close(results, jax.vmap(gd.gated_delta_recurrence)(*a))
     _, want = _grads(jax.vmap(gd.gated_delta_recurrence), a, weights)
-    for name, x, y in zip(OPERANDS, got, want):
-        assert float(jnp.abs(x - y).max() / jnp.abs(y).max()) < GRAD_RTOL, \
-            name
+    assert max(worst(got, want).values()) < GRAD_RTOL, worst(got, want)
 
 
-def test_a_state_rounded_to_bfloat16_at_a_chunk_s_boundary_fails(monkeypatch):
+@pytest.mark.parametrize("form", ["jnp", "kernel"])
+def test_a_state_rounded_to_bfloat16_at_a_chunk_s_boundary_fails(monkeypatch,
+                                                                 form):
     """The control of the tolerance: the same comparison with the state a
     chunk starts from rounded to bfloat16, forward and backward (the next
-    step down from the float32 state the configuration states)."""
-    a, weights = batch_inputs(700, seed=5)
+    step down from the float32 state the configuration states). With the
+    kernels in, the states rounded are those the forward's kernel keeps and
+    the backward's walks back from (the forward's own, and the gradient of
+    the state, stay float32 in VMEM): every gradient that reads a state
+    fails, the queries' by 150 times the tolerance; the values' and the
+    first state's read none (the rule is linear in the two together) and
+    are the only ones that pass."""
+    a, weights = batch_inputs(700, seed=5, widths=TRAINED_FORMS[form])
     _, want = _grads(jax.vmap(gd.gated_delta_recurrence), a, weights)
-    sound = gd._a_chunk
+    low = lambda s: s.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+    sound, sound_kernel = gd._a_chunk, gd._batch_forward_kernel
     monkeypatch.setattr(gd, "_a_chunk", lambda q, k, v, g, beta, s: sound(
-        q, k, v, g, beta, s.astype(jnp.bfloat16).astype(jnp.float32)))
-    _, got = _grads(gd.gated_delta_chunk, a, weights)
-    worst = {name: float(jnp.abs(x - y).max() / jnp.abs(y).max())
-             for name, x, y in zip(OPERANDS, got, want)}
-    assert all(err > 3 * GRAD_RTOL for err in worst.values()), worst
+        q, k, v, g, beta, low(s)))
+
+    def rounded_kernel(*a):
+        o, state, starts = sound_kernel(*a)
+        return o, state, low(starts)
+
+    monkeypatch.setattr(gd, "_batch_forward_kernel", rounded_kernel)
+    with force_kernel_backend("reference" if form == "jnp" else "interpret"):
+        _, got = _grads(gd.gated_delta_chunk, a, weights)
+    errs = worst(got, want)
+    if form == "kernel":
+        assert errs.pop("v") < GRAD_RTOL and errs.pop("state") < GRAD_RTOL
+        assert errs["q"] > 100 * GRAD_RTOL
+        assert all(err > 2 * GRAD_RTOL for err in errs.values()), errs
+    else:
+        assert all(err > 3 * GRAD_RTOL for err in errs.values()), errs
+
+
+def test_the_backward_s_kernel_gives_the_jnp_backward_s_six_gradients():
+    """What the kernel is held to beside the recurrence: the backward it
+    took the place of on a TPU, the chunks in reverse and ``jax.vjp`` of
+    :func:`_a_chunk`, from the same kept operands and states and the same
+    cotangents, to 1e-6 of a gradient's largest value (two chunks, so the
+    state's gradient crosses a chunk's boundary in both); and it is chosen
+    as the forward's is: one call named for the trace where the backend is
+    not the reference's."""
+    a, weights = batch_inputs(1024, seed=41, widths=TRAINED_FORMS["kernel"])
+    saved = gd._batch_rule_fwd(*a)[1]
+    with force_kernel_backend("interpret"):
+        got = jax.jit(lambda *x: gd._batch_rule_bwd(*x))(saved, weights)
+        calls = [e.params["name"] for e in _equations(jax.make_jaxpr(
+            lambda *x: gd._batch_rule_bwd(*x))(saved, weights).jaxpr)
+            if e.primitive.name == "pallas_call"]
+    want = jax.jit(lambda *x: gd._batch_rule_bwd(*x))(saved, weights)
+    assert calls == ["gated_delta_chunk_bwd"]
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        lambda *x: gd._batch_rule_bwd(*x))(saved, weights))
+    assert max(worst(got, want).values()) < 1e-6, worst(got, want)
 
 
 def test_the_trained_form_keeps_a_state_a_chunk_and_walks_them_back():
@@ -594,21 +694,28 @@ def test_the_inverse_s_backward_is_two_products_with_its_transpose():
     a = 0.2 * jnp.tril(jax.random.normal(jax.random.PRNGKey(3),
                                          (2, 64, 64)), -1)
     w = jax.random.normal(jax.random.PRNGKey(4), (2, 64, 64))
-    got = jax.grad(lambda a: jnp.sum(gd._inverse_with_transposes(a) * w))(a)
-    want = jax.grad(lambda a: jnp.sum(gd.unit_lower_inverse(a) * w))(a)
+    # (jitted: op by op the six levels' transposes take 12 s to dispatch)
+    got = jax.jit(jax.grad(
+        lambda a: jnp.sum(gd._inverse_with_transposes(a) * w)))(a)
+    want = jax.jit(jax.grad(
+        lambda a: jnp.sum(gd.unit_lower_inverse(a) * w)))(a)
     low = jnp.tril(jnp.ones((64, 64), bool), -1)
     want = jnp.where(low, want, 0.0)
     assert float(jnp.abs(jnp.where(low, got, 0.0) - want).max()
                  / jnp.abs(want).max()) < GRAD_RTOL
 
 
-def test_a_batch_takes_key_heads_that_serve_several_value_heads():
+@pytest.mark.parametrize("form", ["jnp", "kernel_of_128"])
+def test_a_batch_takes_key_heads_that_serve_several_value_heads(form):
     """q and k a key head, repeated for its value heads outside the rule's
-    backward: the gradient of a key head is the sum over them."""
-    (q, k, v, g, beta, s), weights = batch_inputs(100, seed=9)
-    got = _grads(gd.gated_delta_chunk, (q[:, :, :1], k[:, :, :1], v, g,
-                                        beta, s), weights)
-    rep = lambda x: jnp.repeat(x[:, :, :1], 3, axis=2)  # noqa: E731
+    backward: the gradient of a key head is the sum over them (three value
+    heads through the jnp chunks, two through the kernels)."""
+    (q, k, v, g, beta, s), weights = batch_inputs(
+        100, seed=9, widths=TRAINED_FORMS[form])
+    narrow = (q[:, :, :1], k[:, :, :1], v, g, beta, s)
+    got = (_scalar(gd.gated_delta_chunk, weights)(*narrow),
+           trained_grads(form, narrow, weights)[1])
+    rep = lambda x: jnp.repeat(x[:, :, :1], v.shape[2], axis=2)  # noqa: E731
     want = _grads(jax.vmap(gd.gated_delta_recurrence),
                   (rep(q), rep(k), v, g, beta, s), weights)
     assert float(abs(got[0] - want[0])) < 1e-3 * float(abs(want[0]))
@@ -621,10 +728,10 @@ def test_the_trained_forward_s_kernel_is_the_recurrence_at_padded_widths():
     """On a TPU the forward is one call of the chunk kernel with the batch
     folded into the heads, keys of 96 and values of 192 zero-padded to 128 and 256
     and six heads to eight: its body through the interpreter gives the
-    recurrence's outputs, states and (the backward being the jnp chunks'
-    from the kernel's saved states) gradients; off a TPU, and where eight
-    padded states would not fit a grid step, the jnp body runs."""
-    a, weights = batch_inputs(600, seed=77, widths=(2, 3, 96, 192))
+    recurrence's outputs, states and (the backward being its own kernel
+    from the forward kernel's kept states) gradients; off a TPU, and where
+    eight padded states would not fit a grid step, the jnp body runs."""
+    a, weights = batch_inputs(700, seed=77, widths=TRAINED_FORMS["kernel"])
     wide = tuple(jnp.zeros(shape) for shape in (
         (1, 64, 2, 512), (1, 64, 2, 512), (1, 64, 2, 1024), (1, 64, 2),
         (1, 64, 2), (1, 2, 512, 1024)))
@@ -634,7 +741,8 @@ def test_the_trained_forward_s_kernel_is_the_recurrence_at_padded_widths():
         calls = [e for e in _equations(jax.make_jaxpr(
             lambda *a: gd.gated_delta_chunk(*a))(*a).jaxpr)
             if e.primitive.name == "pallas_call"]
-        loss, got = _grads(gd.gated_delta_chunk, a, weights)
+    results, got = trained_grads("kernel", a, weights)
+    with force_kernel_backend("interpret"):
         wide = jax.make_jaxpr(lambda *a: gd.gated_delta_chunk(*a))(*wide)
     assert len(calls) == 1 and calls[0].params["name"] == "gated_delta_chunk"
     # one call for both chunks of both sequences, and a third result: the
@@ -646,11 +754,9 @@ def test_the_trained_forward_s_kernel_is_the_recurrence_at_padded_widths():
     assert "pallas_call" not in str(wide)
     assert "pallas_call" not in str(jax.make_jaxpr(
         lambda *a: gd.gated_delta_chunk(*a))(*a))
-    want_loss, want = _grads(jax.vmap(gd.gated_delta_recurrence), a, weights)
-    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
-    for name, x, y in zip(OPERANDS, got, want):
-        assert float(jnp.abs(x - y).max() / jnp.abs(y).max()) < GRAD_RTOL, \
-            name
+    close(results, jax.vmap(gd.gated_delta_recurrence)(*a))
+    _, want = _grads(jax.vmap(gd.gated_delta_recurrence), a, weights)
+    assert max(worst(got, want).values()) < GRAD_RTOL, worst(got, want)
 
 
 def test_what_no_form_takes_is_refused_where_it_is_traced():

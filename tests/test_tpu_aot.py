@@ -1140,6 +1140,29 @@ def test_gated_delta_chunk_compiles_alone_and_under_vmap(mosaic, batched,
     assert "gated_delta_chunk" in text
 
 
+def test_gated_delta_chunk_bwd_compiles_alone_at_the_cell_s_shapes(mosaic):
+    """The trained rule's backward at ``olmo-hybrid-train-8k``'s shapes (2
+    sequences of 8,192, 30 heads, keys of 96 and values of 192: 64 folded
+    heads of 128 x 256, sixteen chunks of eight sub-chunks): one Mosaic
+    call, named for the trace, whose 13 MiB of a chunk's states, inverses
+    and corrections fit the VMEM it asks for."""
+    from ray_tpu.ops import gated_delta as gd
+
+    dev = NamedSharding(build_mesh(MeshSpec(), mosaic[:1]), P())
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=dev)
+
+    b, t, h, dk, dv = 2, 8192, 30, 96, 192
+    saved = (sds(b, t, h, dk), sds(b, t, h, dk), sds(b, t, h, dv),
+             sds(b, t, h), sds(b, t, h),
+             sds(t // gd.TRAIN_CHUNK, b, h, dk, dv))
+    text = jax.jit(gd._batch_rule_bwd).lower(
+        saved, (sds(b, t, h, dv), sds(b, h, dk, dv))).compile().as_text()
+    assert text.count(MOSAIC) == 1
+    assert "gated_delta_chunk_bwd" in text
+
+
 @pytest.mark.parametrize("cell", ["ling_96_slots_a_channel",
                                   "qwen3_next_16_slots_a_head"])
 def test_gated_delta_step_compiles_alone_at_the_cells_shapes(mosaic, cell):
@@ -1757,7 +1780,9 @@ def test_olmo_hybrid_step_compiles_with_the_rule_named_on_all_three_passes(
     under a part (none ``unnamed``); the rule's scope is on the forward,
     on the forward that ``jax.checkpoint`` runs again and on the backward
     (``xplane_meta.pass_of``), and so are the scopes around it; the flash
-    kernels and the norms are Mosaic calls."""
+    kernels and the norms are Mosaic calls, and so is the rule on every
+    pass: the forward's kernel on ``fwd`` and ``remat`` and the backward's
+    on ``bwd``, one a linear layer of the scan's body (three of a period)."""
     from devbench import olmo_hybrid_bench as bench
 
     config, traffic = bench.cell_files()
@@ -1772,6 +1797,9 @@ def test_olmo_hybrid_step_compiles_with_the_rule_named_on_all_three_passes(
         assert set(scopes[scope]) == {"fwd", "bwd", "remat"}, scope
     assert set(scopes["optim"]) == {"fwd"}
     assert {"loss", "head", "embed"} <= set(scopes)
+    assert bench.rule_kernels_by_pass(text) == {
+        "gated_delta_chunk": {"fwd": 3, "remat": 3},
+        "gated_delta_chunk_bwd": {"bwd": 3}}
     # flash forward twice (once recomputed) and backward once; the norms
     assert text.count(MOSAIC) >= 3
     assert mem.temp_size_in_bytes > 0
